@@ -47,7 +47,7 @@ def pool_env():
     (spawn cost); tests that kill workers run last via ordering below."""
     cfg = make_cfg()
     model = build(cfg)
-    pool = DeferredPool(cfg, "", model)
+    pool = DeferredPool(cfg, model)
     pool.prewarm()
     loop = asyncio.new_event_loop()
     loop.run_until_complete(pool.start())
@@ -58,7 +58,7 @@ def pool_env():
 
 def test_timeout_floor_applied():
     cfg = make_cfg(request_timeout_ms=100.0, relay_epoch_ms=200.0)
-    DeferredPool(cfg, "", build(cfg))
+    DeferredPool(cfg, build(cfg))
     assert cfg.request_timeout_ms == pytest.approx(2 * 200.0 + 1000.0)
 
 
@@ -114,7 +114,7 @@ def test_clean_shutdown_resolves_pending():
     """stop() must wait for the epoch readback: pending futures resolve with
     results, not 'worker died' (the r2 judge-observed 50 ms strand)."""
     cfg = make_cfg(relay_workers=2, relay_epoch_ms=5_000.0)
-    pool = DeferredPool(cfg, "", build(cfg))
+    pool = DeferredPool(cfg, build(cfg))
     pool.prewarm()
     loop = asyncio.new_event_loop()
 
@@ -239,7 +239,7 @@ def test_results_during_slot_copy_reroutes_batch(monkeypatch):
     cfg = make_cfg(relay_workers=2, relay_epoch_images=8,
                    relay_epoch_ms=150.0)
     model = build(cfg)
-    pool = DeferredPool(cfg, "", model)
+    pool = DeferredPool(cfg, model)
 
     orig_write = DeferredPool._write_slot
     slow_from: dict = {"t": None}
@@ -284,7 +284,7 @@ def test_warm_pool_replenishes_in_background():
     rotations don't mean many dry respawns)."""
     cfg = make_cfg(relay_workers=2, relay_epoch_images=4, relay_epoch_ms=5_000.0)
     model = build(cfg)
-    pool = DeferredPool(cfg, "", model)
+    pool = DeferredPool(cfg, model)
     pool.prewarm()
     loop = asyncio.new_event_loop()
     loop.run_until_complete(pool.start())

@@ -574,7 +574,7 @@ def test_deferred_worker_death_retried_and_swept():
                         relay_workers=2, relay_epoch_images=64,
                         relay_epoch_ms=300.0, request_timeout_ms=30_000.0)
     model = build(cfg)
-    pool = DeferredPool(cfg, "", model,
+    pool = DeferredPool(cfg, model,
                         injector=FaultInjector.single("worker_death", count=1))
     pool.prewarm()
 

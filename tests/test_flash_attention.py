@@ -62,6 +62,15 @@ def test_unalignable_seq_rejected(rng):
         flash_attention(q, k, v, block_q=36)
 
 
+def test_unknown_platform_raises(rng, monkeypatch):
+    """interpret=None interprets on cpu and compiles on tpu; any other
+    platform raises instead of silently taking the interpreter."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    q, k, v = rand_qkv(rng, b=1, s=24, h=1, d=8)  # a shape no test compiled
+    with pytest.raises(ValueError, match="platform 'gpu'"):
+        flash_attention(q, k, v)
+
+
 def test_bf16_inputs(rng):
     q, k, v = (x.astype(jnp.bfloat16) for x in rand_qkv(rng, s=128))
     raw = flash_attention(q, k, v)
@@ -268,11 +277,7 @@ def test_check_vma_false_still_required_canary():
     delete the check_vma=False escapes in tpuserve/ops/ring_attention.py
     and tpuserve/models/bert.py and regain the stronger collective
     checking (VERDICT r4 weak 7 asked for exactly this tripwire)."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        pytest.skip("this jax predates vma tracking (check_rep era); the "
-                    "escapes route through tpuserve.utils.compat instead")
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from tpuserve.parallel import make_mesh

@@ -203,7 +203,16 @@ def test_every_replica_receives_batches_under_sustained_load():
 
 # -- sharded vs replica parity ------------------------------------------------
 
-def test_sharded_batch_results_bit_identical_to_replica_mode():
+# Two partitionings of one model are two XLA programs, and XLA promises no
+# bit identity between them: float32 reductions may associate differently,
+# which is worth a few ulp (f32 eps 1.2e-7; the observed difference is 1 ulp).
+# 1e-5 relative is ~80 ulp of slack and still ~400x below bfloat16's 2^-8
+# step, so a layout that silently computed in lower precision fails it.
+# chip_smoke.py's four-chip phase applies the same rule (PROB_RTOL).
+PARTITION_PROB_RTOL = 1e-5
+
+
+def test_sharded_batch_results_match_replica_mode():
     bucket = (N_DEV,)
     rng = np.random.default_rng(7)
     items = [rng.integers(0, 255, (8, 8, 3), np.uint8) for _ in range(N_DEV)]
@@ -219,7 +228,8 @@ def test_sharded_batch_results_bit_identical_to_replica_mode():
     out_sh = rt_sh.fetch(rt_sh.run(bucket, batch))
     for replica in range(rt_rep.n_replicas):
         out_rep = rt_rep.fetch(rt_rep.run(bucket, batch, replica=replica))
-        np.testing.assert_array_equal(out_sh["probs"], out_rep["probs"])
+        np.testing.assert_allclose(out_sh["probs"], out_rep["probs"],
+                                   rtol=PARTITION_PROB_RTOL, atol=0)
         np.testing.assert_array_equal(out_sh["indices"], out_rep["indices"])
 
 

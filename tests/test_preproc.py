@@ -105,3 +105,26 @@ def test_decode_npy_items_single_vs_batch():
 
     with pytest.raises(ValueError, match="limit"):
         preproc.decode_npy_items(npy(np.zeros((9, 4, 4, 3), np.uint8)), 4, max_items=8)
+
+
+def test_native_load_rebuilds_a_stale_so(tmp_path, monkeypatch):
+    """load() runs make every time: a .so older than jpegyuv.c — here a
+    file that is not even a library — is rebuilt, never loaded as found."""
+    import os
+    import shutil
+
+    if not native.available():
+        pytest.skip("native jpegyuv shim unavailable (no toolchain/libjpeg)")
+    src = native._NATIVE_DIR
+    for name in ("Makefile", "jpegyuv.c"):
+        shutil.copy(os.path.join(src, name), tmp_path / name)
+    so = tmp_path / "libjpegyuv.so"
+    so.write_bytes(b"stale")
+    os.utime(so, (1, 1))  # older than the source
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_SO_PATH", str(so))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_load_failed", False)
+    assert native.load() is not None
+    assert so.stat().st_size > 1000
+    assert native.decode_yuv420(photo_jpeg(), 256) is not None

@@ -265,7 +265,7 @@ def test_recycle_mode_with_int8_weights():
         request_timeout_ms=30_000.0, quantize="int8", quantize_min_size=1024,
     )
     model = build(cfg)
-    pool = DeferredPool(cfg, "", model)
+    pool = DeferredPool(cfg, model)
     pool.prewarm()
     loop = asyncio.new_event_loop()
     loop.run_until_complete(pool.start())

@@ -135,3 +135,83 @@ def test_hot_reload_rejects_mismatched_tree(toy_runtime):
     assert rt.params_per_mesh is before  # still serving the old weights
     batch = np.full((2, 8, 8, 3), 9, dtype=np.uint8)
     assert rt.fetch(rt.run((2,), batch))["probs"].shape == (2, 3)
+
+
+# -- start-up rules: the device guard and the compile-cache placement ---------
+
+def test_device_guard_refuses_a_cpu_backend_nobody_asked_for():
+    """jax falls back to the CPU when the accelerator cannot be opened; the
+    serve path refuses that unless JAX_PLATFORMS names cpu."""
+    from tpuserve.runtime import check_backend
+
+    for requested in ("", "tpu"):
+        with pytest.raises(RuntimeError, match="'cpu' platform.*JAX_PLATFORMS"):
+            check_backend("cpu", requested)
+    check_backend("cpu", "cpu")
+    check_backend("cpu", "tpu,cpu")
+    check_backend("tpu", "")
+
+
+@pytest.mark.parametrize("placed", [None, "/somewhere/else"])
+def test_compile_cache_rule(monkeypatch, placed):
+    """JAX_COMPILATION_CACHE_DIR is the one way to move the cache: set, no
+    code names another directory; unset, it is <checkout>/.jaxcache."""
+    import os
+
+    from tpuserve import runtime
+
+    updates = {}
+    monkeypatch.setattr(jax.config, "update", updates.__setitem__)
+    if placed is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    got = runtime.configure_compile_cache()
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if placed is None:
+        assert got == os.path.join(checkout, ".jaxcache")
+        assert updates["jax_compilation_cache_dir"] == got
+    else:
+        assert got == placed
+        assert "jax_compilation_cache_dir" not in updates
+
+
+def test_chip_smoke_result_line_has_exactly_the_contract_keys():
+    """The driver reads the smoke's last stdout line and refuses anything but
+    {"ok", "device": {"platform", "kind", "count"}}; the per-phase report
+    goes on the line before it."""
+    import importlib.util
+    import json
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(repo, "chip_smoke.py"))
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    line = smoke.result_line(True, {
+        "platform": "tpu", "device_kind": "TPU v5 lite", "device_count": 4,
+        "jax_version": "0.9.0", "devices": [], "mesh": {}})
+    assert "\n" not in line
+    assert json.loads(line) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 4}}
+
+
+def test_chip_smoke_fails_on_cpu_in_its_device_phase():
+    """The smoke must not pass by accident: on a CPU backend it exits
+    non-zero naming the platform, prints no result line, and has compiled
+    nothing (it never gets past ``describe``)."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(repo, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode != 0
+    assert proc.stdout == ""
+    assert "platform 'cpu'" in proc.stderr
+    assert "native build: start" not in proc.stderr

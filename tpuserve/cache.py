@@ -1,9 +1,8 @@
 """Content-addressed result cache + single-flight coalescing (ISSUE 5).
 
-BENCH_r05 put the chip-side ceiling at ~10,628 img/s with the HTTP path
-delivering 606 img/s — the request path, not the executable, is the
-bottleneck. Clipper (PAPERS.md P1) closed the same gap with a prediction
-cache in front of the model containers; this module is that layer for
+Where the request path, not the executable, is the bottleneck, work that
+never reaches the device is the cheapest work. Clipper (PAPERS.md P1) put a
+prediction cache in front of the model containers; this module is that layer for
 tpuserve, sitting between ``handle_predict`` and ``ModelBatcher``:
 
 - **Content addressing** — key = (live model version, digest of the

@@ -382,9 +382,13 @@ def main(argv: list[str] | None = None) -> int:
         from tpuserve.parallel import make_mesh
 
         mesh = make_mesh()
+        devs = jax.devices()
         print(json.dumps({
-            "devices": [str(d) for d in jax.devices()],
-            "platform": jax.devices()[0].platform,
+            "devices": [str(d) for d in devs],
+            "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs),
+            "jax_version": jax.__version__,
             "mesh": {k: int(v) for k, v in mesh.shape.items()},
         }, indent=2))
         return 0

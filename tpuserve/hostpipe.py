@@ -1,10 +1,11 @@
 """Pipelined host execution engine primitives (ISSUE 3; PAPERS.md P3/P4).
 
-BENCH_r05 measured the chip sustaining ~10,628 img/s while HTTP serving
-delivered 606: the gap was the host path, where one shared ThreadPoolExecutor
-ran assemble -> device_put -> blocking fetch sequentially per batch, so stage
-time summed instead of overlapping and the "compute" phase absorbed the whole
-wire wait. Clockwork (P3) treats each serving stage as deterministic-duration
+The host path used to run assemble -> device_put -> blocking fetch
+sequentially per batch on one shared ThreadPoolExecutor, so stage time summed
+instead of overlapping and the "compute" phase absorbed every wait (the last
+driver record of that design, 2026-07-31 on an earlier installation, had HTTP
+serving at 606 img/s beside an executable that ran 10,628; not re-measured on
+today's code). Clockwork (P3) treats each serving stage as deterministic-duration
 work that must be scheduled, not queued behind unrelated stages; Orca (P4)
 re-forms work at stage granularity. This module provides the three primitives
 the batcher composes into that staged pipeline:

@@ -6,8 +6,11 @@ ships 1.5 B/px over the wire instead of 3 B/px and the device does the color
 math (tpuserve.preproc.device_prepare_images_yuv420). ctypes releases the
 GIL for the call, so decode threads scale on multi-core hosts.
 
-``load()`` builds the .so on first use (make, ~1s) and returns None when the
-toolchain or libjpeg is absent — callers fall back to the PIL RGB path.
+``load()`` runs ``make`` once per process (~1 s to build, a no-op when the
+.so is newer than jpegyuv.c), so a stale or foreign .so left on disk is never
+loaded as-is. When the build fails (no toolchain, no libjpeg) it returns None
+and callers fall back to the PIL path at about twice the cost per JPEG —
+counted per request in ``native_decode_fallback_total``, never silent.
 """
 
 from __future__ import annotations
@@ -50,7 +53,7 @@ def load():
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not os.path.exists(_SO_PATH) and not _build():
+        if not _build():
             _load_failed = True
             return None
         try:

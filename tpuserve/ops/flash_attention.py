@@ -33,9 +33,10 @@ to ``l`` or ``acc``; a row with at least one live key (BERT always has
 
 CPU/test story: ``pallas_call(interpret=True)`` runs the kernel in the
 Pallas interpreter, so the same code is unit-tested on the CI's fake-device
-CPU mesh and compiled for real on TPU (``interpret=None`` auto-detects from
-the effective default device, honoring ``jax.default_device(cpu)`` blocks
-like the runtime's CPU-pinned param init).
+CPU mesh and compiled for real on TPU. ``interpret=None`` decides from the
+effective default device (honoring ``jax.default_device(cpu)`` blocks like
+the runtime's CPU-pinned param init): interpret on ``cpu``, compile on
+``tpu``, raise on anything else — never the interpreter by accident.
 
 When to use — MEASURED, see BASELINE.md:
 - "SD 1.5 chip profile" (2026-07-30, v5e): at SD-UNet head dims 40/80 the
@@ -177,11 +178,7 @@ def _flash(q, k, v, bias, block_q, block_k, interpret, return_stats):
     # Inside shard_map (the sharded-BERT / ring-local composition) outputs
     # must declare which mesh axes they vary over; inherit the inputs' union
     # (outside shard_map these are empty sets — no-op).
-    vma = frozenset()
-    typeof = getattr(jax, "typeof", None)
-    if typeof is not None:  # jax < 0.6 has no typeof (and no vma on avals)
-        for x in (q, k, v, bias):
-            vma = vma | getattr(typeof(x), "vma", frozenset())
+    vma = frozenset().union(*(jax.typeof(x).vma for x in (q, k, v, bias)))
 
     def out_struct(shape, dtype):
         if vma:
@@ -233,6 +230,24 @@ def _flash_bwd(block_q, block_k, interpret, return_stats, res, ct):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _interpret_here() -> bool:
+    """Interpret on ``cpu``, compile on ``tpu``, raise on anything else.
+
+    Decided from the effective platform, honoring ``with
+    jax.default_device(cpu)`` (the runtime pins param init there):
+    default_backend() alone would still say 'tpu' and compile the TPU kernel
+    for a CPU trace."""
+    dev = jax.config.jax_default_device  # a Device, a platform name, or None
+    platform = (dev if isinstance(dev, str)
+                else getattr(dev, "platform", None)) or jax.default_backend()
+    if platform not in ("cpu", "tpu"):  # tps-ok[TPS503]: a platform name, host-side
+        raise ValueError(
+            f"flash_attention is a Mosaic TPU kernel: platform {platform!r} "
+            "can neither compile it nor should silently run the "
+            "interpreter; use dense attention there")
+    return platform == "cpu"
+
+
 @functools.partial(jax.jit,
                    static_argnames=("block_q", "block_k", "interpret",
                                     "return_stats"))
@@ -275,12 +290,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 f"seq_{name} {size} only admits a {blk}-row {name} block, "
                 "which the TPU lowering rejects; use a multiple of 8")
     if interpret is None:
-        # The effective platform, honoring `with jax.default_device(cpu)`
-        # (the runtime pins param init there): default_backend() alone would
-        # still say 'tpu' and compile the TPU kernel for a CPU trace.
-        dev = jax.config.jax_default_device
-        platform = getattr(dev, "platform", None) or jax.default_backend()
-        interpret = platform != "tpu"
+        interpret = _interpret_here()
     if bias is None:
         bias = jnp.zeros((b, sk), jnp.float32)
     return _flash(q, k, v, bias, block_q, block_k, interpret, return_stats)

@@ -27,7 +27,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from tpuserve.utils.compat import pcast_varying, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -89,13 +88,15 @@ def _ring_body(q, k, v, kbias, axis_name: str, vary_axes: tuple = (),
     scale = q.shape[-1] ** -0.5
     b, sq, h, d = q.shape
 
-    # Online-softmax state, (B, H, Sq) / (B, Sq, H, D). pvary marks the
+    # Online-softmax state, (B, H, Sq) / (B, Sq, H, D). pcast marks the
     # constants as varying over every sharded axis so scan carry types match
     # the loop outputs (which inherit q/k/v's varying axes).
     vary = vary_axes or (axis_name,)
-    m0 = pcast_varying(jnp.full((b, h, sq), -jnp.inf, jnp.float32), vary)
-    l0 = pcast_varying(jnp.zeros((b, h, sq), jnp.float32), vary)
-    acc0 = pcast_varying(jnp.zeros((b, sq, h, d), jnp.float32), vary)
+    m0 = jax.lax.pcast(jnp.full((b, h, sq), -jnp.inf, jnp.float32), vary,
+                       to="varying")
+    l0 = jax.lax.pcast(jnp.zeros((b, h, sq), jnp.float32), vary, to="varying")
+    acc0 = jax.lax.pcast(jnp.zeros((b, sq, h, d), jnp.float32), vary,
+                         to="varying")
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def step(carry, _):
@@ -191,7 +192,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         if entry is None:
             continue
         vary_axes.extend(entry if isinstance(entry, (tuple, list)) else [entry])
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_body, axis_name=axis_name, vary_axes=tuple(vary_axes),
                 local_impl=local_impl),
         mesh=mesh,

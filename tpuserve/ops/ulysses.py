@@ -31,7 +31,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from tpuserve.utils.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tpuserve.ops.ring_attention import dense_attention
@@ -111,7 +110,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     elif local_impl not in ("dense", "flash"):
         raise ValueError(f"unknown local_impl {local_impl!r}")
     bias_spec = P(qkv_spec[0], axis_name)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ulysses_body, axis_name=axis_name, local_impl=local_impl),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, bias_spec),

@@ -9,7 +9,7 @@ and compiles identical SPMD programs. This module is that seam:
 - ``init_distributed(cfg)`` — call ONCE, before any other JAX API touches a
   device (backend init freezes the topology). No-op unless
   ``DistributedConfig.coordinator_address`` is set, so single-host serving
-  (the dev box, CI) never pays anything.
+  never pays anything.
 - ``process_info()`` — rank/host facts for /stats and logs.
 
 Mesh layout for the multi-host case lives in ``tpuserve.parallel.mesh``: the
@@ -67,4 +67,5 @@ def process_info() -> dict:
         "global_devices": len(jax.devices()),
         "local_devices": len(jax.local_devices()),
         "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
     }

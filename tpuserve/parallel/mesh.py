@@ -8,7 +8,7 @@ Axis conventions used throughout tpuserve:
 
 An inference mesh is usually ``("data",)`` or ``("data", "model")``; the
 training step used by the multi-chip dry run adds ``"seq"``. The same code
-path handles 1 local core (dev box), 8 cores (v5e-8), and — via
+path handles 1 local chip, 8 chips (v5e-8), and — via
 ``jax.distributed`` — multi-host slices: the mesh is always built from
 ``jax.devices()``, never hard-coded counts (SURVEY.md §7 hard part 7).
 """

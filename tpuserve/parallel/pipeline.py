@@ -34,7 +34,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from tpuserve.utils.compat import pcast_varying, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 STAGE_AXIS = "stage"
@@ -84,7 +83,8 @@ def _pp_body(params: Any, xs: jax.Array, *, stage_fn: Callable,
 
     # pcast: the zero init must carry the same varying-over-stage type the
     # loop outputs have (cf. the ring-attention scan carries).
-    init = pcast_varying(jnp.zeros(mb_shape, xs.dtype), (axis_name,))
+    init = jax.lax.pcast(jnp.zeros(mb_shape, xs.dtype), (axis_name,),
+                         to="varying")
     _, outs = jax.lax.scan(tick, init, jnp.arange(n_micro + n_stages - 1))
     # Only the last stage contributed non-zeros; replicate its results.
     outs = jax.lax.psum(outs, axis_name)
@@ -118,6 +118,6 @@ def pipeline_forward(stage_fn: Callable, stacked_params: Any, xs: jax.Array,
     param_specs = jax.tree_util.tree_map(lambda _: P(axis_name), stacked_params)
     body = partial(_pp_body, stage_fn=stage_fn, n_stages=n_stages,
                    n_micro=n_micro, axis_name=axis_name)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(param_specs, P()), out_specs=P())
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(param_specs, P()), out_specs=P())
     return fn(stacked_params, xs)

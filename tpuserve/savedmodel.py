@@ -200,12 +200,9 @@ def load_orbax(path: str, model) -> Any:
 
     apath = os.path.abspath(path)
     with ocp.StandardCheckpointer() as ckptr:
-        meta = ckptr.metadata(apath)
-        # orbax >= 0.9 wraps the tree in .item_metadata; 0.7 returns it raw.
-        saved = getattr(meta, "item_metadata", meta)
         target = jax.tree_util.tree_map(
             lambda m: jax.ShapeDtypeStruct(tuple(m.shape), np.dtype(str(m.dtype))),
-            saved)
+            ckptr.metadata(apath).item_metadata)
         # Restore as host numpy; the runtime device_puts with shardings.
         restored = ckptr.restore(apath, target)
 

@@ -352,8 +352,7 @@ class ServerState:
                     # own one PJRT session each.
                     from tpuserve.deferred import DeferredPool
 
-                    rt = DeferredPool(mcfg, self.cfg.compilation_cache_dir,
-                                      model, injector=self.injector)
+                    rt = DeferredPool(mcfg, model, injector=self.injector)
                     rt.prewarm()
                 elif self.cfg.genserve.enabled \
                         and getattr(model, "generative", False):

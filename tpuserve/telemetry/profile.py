@@ -144,11 +144,7 @@ class ProfileCapture:
         try:
             import jax
 
-            try:
-                jax.profiler.start_trace(log_dir,
-                                         create_perfetto_trace=True)
-            except TypeError:  # older jax: no perfetto kwarg
-                jax.profiler.start_trace(log_dir)
+            jax.profiler.start_trace(log_dir, create_perfetto_trace=True)
             return True
         except Exception:  # noqa: BLE001 — best-effort by contract
             log.exception("jax.profiler.start_trace failed")

@@ -190,16 +190,11 @@ def make_train_step(model, tx, mesh: Mesh, param_shardings):
         params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
-    # Donation is a memory optimization only; older jaxlib (no jax.typeof)
-    # mis-aliases donated buffers whose inferred opt-state output sharding
-    # differs from the input under sp/tp meshes (XlaRuntimeError INTERNAL
-    # "aliased input ... to have the same size"), so skip it there.
-    donate = (0, 1) if hasattr(jax, "typeof") else ()
     return jax.jit(  # tps-ok[TPS501,TPS505]: setup-time factory, jitted once per run
         step,
         in_shardings=(param_shardings, None, batch_sharding),
         out_shardings=(param_shardings, None, None),
-        donate_argnums=donate,
+        donate_argnums=(0, 1),
     ), batch_sharding
 
 

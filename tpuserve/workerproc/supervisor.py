@@ -149,17 +149,17 @@ class WorkerSupervisor:
 
     # -- lifecycle -----------------------------------------------------------
     async def start(self) -> None:
-        """Spawn the fleet and start the health loop. With a persistent
-        compile cache configured, the first worker boots alone so it
-        populates the cache and the rest (and every future respawn) hit
-        it — the deferred pool's prewarm trick at process scale."""
+        """Spawn the fleet and start the health loop. The first worker
+        boots alone so it populates the persistent compile cache and the
+        rest (and every future respawn) hit it — the deferred pool's
+        prewarm trick at process scale."""
         import aiohttp
 
         loop = asyncio.get_running_loop()
         self._session = aiohttp.ClientSession(
             timeout=aiohttp.ClientTimeout(
                 total=self.rcfg.health_timeout_ms / 1e3))
-        first_alone = bool(self.cfg.compilation_cache_dir) and self.n > 1
+        first_alone = self.n > 1
         rest = range(self.n)
         if first_alone:
             self.slots[0] = await loop.run_in_executor(
