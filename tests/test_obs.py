@@ -37,7 +37,7 @@ def test_metrics_prometheus_render():
     m = Metrics()
     m.counter("requests_total{model=rn}").inc(3)
     m.gauge("queue_depth{model=rn}").set(7)
-    m.observe_phase("rn", "total", 12.5)
+    m.histogram("latency_ms{model=rn,phase=total}").observe(12.5)
     text = m.render_prometheus()
     assert 'requests_total{model="rn"} 3' in text  # label values quoted
     assert 'queue_depth{model="rn"} 7' in text
@@ -51,8 +51,8 @@ def test_metrics_prometheus_render():
 
 def test_metrics_summary():
     m = Metrics()
-    m.observe_phase("rn", "total", 10.0)
-    m.observe_phase("rn", "total", 20.0)
+    m.histogram("latency_ms{model=rn,phase=total}").observe(10.0)
+    m.histogram("latency_ms{model=rn,phase=total}").observe(20.0)
     s = m.summary()
     key = "latency_ms{model=rn,phase=total}"
     assert s["latency"][key]["n"] == 2

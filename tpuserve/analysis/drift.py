@@ -9,8 +9,8 @@ a chaos fault kind no test references is recovery machinery nobody proves.
   whole token) in ``examples/serve_all.toml`` AND in the docs corpus
   (README.md + docs/*.md). docs/REFERENCE.md is the canonical fix location.
 - **TPS402** — every metric name emitted anywhere in ``tpuserve/`` (the
-  ``counter(f"name{...}")`` / ``gauge`` / ``histogram`` / ``observe_phase``
-  call sites) appears in the docs corpus.
+  ``counter(f"name{...}")`` / ``gauge`` / ``histogram`` call sites) appears
+  in the docs corpus.
 - **TPS403** — every fault kind in ``config.FAULT_KINDS`` is referenced by
   at least one file under ``tests/``.
 - **TPS404** — every shed/terminal reason string in the closed label
@@ -112,8 +112,6 @@ def metric_names(package_dir: Path) -> dict[str, Path]:
         text = path.read_text()
         for m in _METRIC_RE.finditer(text):
             out.setdefault(m.group(1), path)
-        if "observe_phase(" in text:
-            out.setdefault("latency_ms", path)
     return out
 
 

@@ -66,8 +66,9 @@ from typing import Any, Hashable
 from tpuserve.config import AdaptiveConfig, PipelineConfig
 from tpuserve.hostpipe import AssemblyArena, SlotPool, StageExecutors
 from tpuserve.models.base import ServingModel
-from tpuserve.obs import PHASES, PRIORITIES, Counter, Metrics
-from tpuserve.runtime import ModelRuntime
+from tpuserve.obs import (BATCH_PHASES, PRIORITIES, Counter, Metrics,
+                          trace_mark)
+from tpuserve.runtime import ModelRuntime, bucket_label
 
 log = logging.getLogger("tpuserve.batcher")
 
@@ -164,9 +165,18 @@ class ModelBatcher:
         self._c_retry_failures = metrics.counter(
             f"batch_retry_failures_total{{model={name}}}")
         self._c_poison = metrics.counter(f"poison_items_total{{model={name}}}")
+        # Why a group stopped accumulating: it reached its target, or its
+        # timer (deadline_ms / the deadline headroom) ran out first.
+        self._c_flushes = {
+            reason: metrics.counter(
+                f"batcher_flushes_total{{model={name},reason={reason}}}")
+            for reason in ("target", "timer")}
+        # Batch ids, minted where a batch is formed (_group_loop) so that
+        # its accumulation and slot wait carry the id its stages will.
+        self._bid_seq = 0
         self._h_phase = {
             p: metrics.histogram(f"latency_ms{{model={name},phase={p}}}")
-            for p in PHASES}
+            for p in BATCH_PHASES}
         # Per-priority queue-wait split (tpuserve.scheduler): requests
         # without a resolved priority land under the model's default class.
         self._default_priority = getattr(model.cfg, "priority", "interactive")
@@ -561,6 +571,14 @@ class ModelBatcher:
                     except asyncio.TimeoutError:
                         timer_flush = True
                         break
+                # The flush decision: the batch exists from here on.
+                t_flush = time.perf_counter()
+                bid = self._mint_bid()
+                reason = "timer" if timer_flush else "target"
+                self._c_flushes[reason].inc()
+                trace_mark("tpuserve.accumulate", req.enqueued_at, t_flush,
+                           model=self.model.name, batch=bid, n=len(batch),
+                           reason=reason)
                 if adaptive:
                     self._aimd_update(group, tgt, len(batch), target_n,
                                       timer_flush, pressure=not q.empty())
@@ -590,6 +608,10 @@ class ModelBatcher:
                     batch = self._expire_dead(batch, adjust_pending=True)
                 if not batch:
                     continue  # everything expired; no admission was taken
+                t_slot = time.perf_counter()
+                self._h_phase["slot_wait"].observe((t_slot - t_flush) * 1e3)
+                trace_mark("tpuserve.slot_wait", t_flush, t_slot,
+                           model=self.model.name, batch=bid)
             except asyncio.CancelledError:
                 # stop() cancelled us mid-accumulation: requests already
                 # pulled off the queue must fail, not hang their clients.
@@ -629,13 +651,20 @@ class ModelBatcher:
                 if r.ctx is not None:
                     r.ctx.span("queue", now_wall - wait_ms / 1e3, now_wall,
                                tid=self.model.name)
-            task = asyncio.get_running_loop().create_task(self._dispatch(live, group))
+            task = asyncio.get_running_loop().create_task(
+                self._dispatch(live, group, bid))
             self._dispatch_tasks.add(task)
             task.add_done_callback(self._dispatch_tasks.discard)
             task.add_done_callback(lambda _t: self._maybe_idle())
 
     # -- dispatch (stage executors do the blocking work) ---------------------
-    async def _dispatch(self, reqs: list[_Request], group: Hashable) -> None:
+    def _mint_bid(self) -> int:
+        """A batch id, unique per model (event loop only)."""
+        self._bid_seq += 1
+        return self._bid_seq
+
+    async def _dispatch(self, reqs: list[_Request], group: Hashable,
+                        bid: int | None = None) -> None:
         """Run one batch through the pipeline; on failure, retry/split per
         config before failing futures. Failure is contained to this batch
         either way: the group task and server keep serving."""
@@ -646,7 +675,7 @@ class ModelBatcher:
         self._g_inflight.set(self._inflight_now)
         try:
             try:
-                await self._execute(reqs, group, released)
+                await self._execute(reqs, group, released, bid)
             except Exception as e:
                 log.exception("batch dispatch failed for %s", name)
                 self._c_batch_errors.inc()
@@ -723,7 +752,7 @@ class ModelBatcher:
                 self._staging[replica].in_use)
 
     async def _execute(self, reqs: list[_Request], group: Hashable,
-                       released: list[bool]) -> None:
+                       released: list[bool], bid: int | None = None) -> None:
         """Assemble + run + postprocess one batch through the stage
         pipeline, resolving futures on success. Raises on failure WITHOUT
         failing futures — the caller owns the retry policy."""
@@ -732,14 +761,17 @@ class ModelBatcher:
         fill = len(reqs) / bucket[0]
         self._g_fill.set(fill)
         self._c_batches.inc()
-        # Batch identity for trace correlation (ISSUE 12): the lifetime
-        # batch counter read right after its tick — unique per model (all
-        # increments happen on the owning loop). The ring's batch span
-        # carries its member trace ids; each member's per-phase spans carry
-        # this id back, so a request tree and the batch timeline join both
-        # ways. Retries/splits re-enter here and get their own batch id —
-        # a retried request's tree visibly contains BOTH attempts.
-        bid = int(self._c_batches.value)
+        # Batch identity for trace correlation (ISSUE 12), minted where the
+        # batch was formed (_group_loop), unique per model. The ring's batch
+        # span carries its member trace ids; each member's per-phase spans
+        # carry this id back, so a request tree and the batch timeline join
+        # both ways, and the profiler's spans (tpuserve.accumulate ..
+        # tpuserve.postproc) carry it too. Retries/splits re-enter here
+        # without one and get their own — a retried request's tree visibly
+        # contains BOTH attempts.
+        if bid is None:
+            bid = self._mint_bid()
+        span = {"batch": bid, "bucket": bucket_label(bucket), "n": len(reqs)}
         ctxs = [r.ctx for r in reqs if r.ctx is not None]
         ex_tid = ctxs[0].trace_id if ctxs else None
 
@@ -762,10 +794,11 @@ class ModelBatcher:
             if lease is not None:
                 host_batch = await self.stages.run(
                     name, "assemble", self.model.assemble_into,
-                    items, bucket, lease.buf)
+                    items, bucket, lease.buf, span=span)
             else:
                 host_batch = await self.stages.run(
-                    name, "assemble", self.model.assemble, items, bucket)
+                    name, "assemble", self.model.assemble, items, bucket,
+                    span=span)
             t1 = time.perf_counter()
             mark("preproc", t0, t1)
 
@@ -800,6 +833,8 @@ class ModelBatcher:
                 replica, slot = await self._acquire_staging(reqs)
                 if replica is None:
                     return  # every request expired; nothing to run
+                trace_mark("tpuserve.staging_wait", t1, time.perf_counter(),
+                           model=name, batch=bid, replica=replica)
                 try:
                     if self.injector is not None:
                         delay = self.injector.delay_s("slow_dispatch", name)
@@ -810,7 +845,7 @@ class ModelBatcher:
                     # async dispatch of the compiled call.
                     outputs = await self.stages.run(
                         name, "h2d", self.runtime.run, bucket, host_batch,
-                        replica)
+                        replica, span=span)
                     t2 = time.perf_counter()
                     mark("h2d", t1, t2)
 
@@ -820,7 +855,7 @@ class ModelBatcher:
                     # transfer waits the way the shared-pool path did
                     # (docs/PERFORMANCE.md "Phase semantics").
                     np_out = await self.stages.run(
-                        name, "fetch", self.runtime.fetch, outputs)
+                        name, "fetch", self.runtime.fetch, outputs, span=span)
                     t3 = time.perf_counter()
                     mark("compute", t2, t3)
                     if replica < len(self._c_device_seconds):
@@ -839,7 +874,8 @@ class ModelBatcher:
                 self.arena.release(lease)
 
         results = await self.stages.run(
-            name, "postproc", self.model.host_postprocess, np_out, len(reqs))
+            name, "postproc", self.model.host_postprocess, np_out, len(reqs),
+            span=span)
         t4 = time.perf_counter()
         mark("postproc", t3, t4)
         self._c_items.inc(len(reqs))

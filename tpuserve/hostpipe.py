@@ -40,7 +40,7 @@ import jax
 import numpy as np
 
 from tpuserve.config import PipelineConfig
-from tpuserve.obs import PIPELINE_STAGES, Metrics
+from tpuserve.obs import PIPELINE_STAGES, Metrics, trace_call
 from tpuserve.utils.locks import new_lock
 
 log = logging.getLogger("tpuserve.hostpipe")
@@ -145,8 +145,12 @@ class StageExecutors:
         self._submitted: dict[str, int] = {s: 0 for s in PIPELINE_STAGES}
         self._shut = False
 
-    async def run(self, model: str, stage: str, fn: Callable, *args) -> Any:
-        """Run ``fn(*args)`` on the stage's pool; returns its result."""
+    async def run(self, model: str, stage: str, fn: Callable, *args,
+                  span: dict | None = None) -> Any:
+        """Run ``fn(*args)`` on the stage's pool; returns its result. In the
+        pool thread the call sits inside a ``tpuserve.<stage>`` span on the
+        profiler's clock, carrying ``model`` and whatever ``span`` holds
+        (the batcher's batch id, bucket and item count)."""
         loop = asyncio.get_running_loop()
         key = (model, stage)
         self._depth[key] = self._depth.get(key, 0) + 1
@@ -156,7 +160,9 @@ class StageExecutors:
                 f"pipeline_stage_depth{{model={model},stage={stage}}}"
             ).set(self._depth[key])
         try:
-            return await loop.run_in_executor(self._pools[stage], fn, *args)
+            return await loop.run_in_executor(
+                self._pools[stage], trace_call, f"tpuserve.{stage}",
+                {"model": model, **(span or {})}, fn, *args)
         finally:
             self._depth[key] -= 1
             if self.metrics is not None:

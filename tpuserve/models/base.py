@@ -58,6 +58,11 @@ class ServingModel(abc.ABC):
                 lines.pop()
             self.class_labels = lines
 
+    def bind_metrics(self, metrics: Any) -> None:
+        """Prebind whatever the family measures inside its own host code
+        (the server calls this once at start, with its obs.Metrics).
+        Nothing by default; a model nobody bound measures nothing."""
+
     # -- parameters ---------------------------------------------------------
     @abc.abstractmethod
     def init_params(self, rng: jax.Array) -> Any:

@@ -25,6 +25,7 @@ a standard vocab.txt; otherwise the deterministic synthetic dev vocab is used
 from __future__ import annotations
 
 import json
+import time
 from typing import Any
 
 import flax.linen as nn
@@ -36,6 +37,7 @@ from jax.sharding import PartitionSpec as P
 from tpuserve import quantize as qz
 from tpuserve.config import ModelConfig
 from tpuserve.models.base import ServingModel
+from tpuserve.obs import trace_span
 from tpuserve.text import WordPieceTokenizer, synthetic_vocab
 
 
@@ -204,6 +206,7 @@ class BertClassifier(nn.Module):
 class BertServing(ServingModel):
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)
+        self._tokenize_obs = None  # bind_metrics
         opt = cfg.options
         attention = str(opt.get("attention", "dense"))
         if attention not in ("dense", "flash", "ring", "ulysses"):
@@ -557,7 +560,7 @@ class BertServing(ServingModel):
         """One JSON parse: {"text": str} is single, {"texts": [...]} a batch;
         non-JSON bodies are one plain-text item."""
         if not content_type.startswith("application/json"):
-            return [self._encode(payload.decode("utf-8"))], False
+            return self._encode_all([payload.decode("utf-8")]), False
         body = json.loads(payload.decode("utf-8"))
         texts = body.get("texts")
         if texts is not None:
@@ -567,11 +570,36 @@ class BertServing(ServingModel):
                 raise ValueError(
                     f"batch of {len(texts)} exceeds the per-request limit "
                     f"({self.MAX_ITEMS_PER_REQUEST})")
-            return [self._encode(t) for t in texts], True
+            return self._encode_all(texts), True
         text = body.get("text")
         if not isinstance(text, str):
             raise ValueError('JSON body must contain "text": str')
-        return [self._encode(text)], False
+        return self._encode_all([text]), False
+
+    def bind_metrics(self, metrics) -> None:
+        name = self.name
+        self._tokenize_obs = (
+            metrics.histogram(f"latency_ms{{model={name},phase=tokenize}}"),
+            metrics.counter(f"ingest_tokenize_cpu_seconds_total{{model={name}}}"),
+            metrics.counter(f"ingest_tokens_total{{model={name}}}"))
+
+    def _encode_all(self, texts: list[str]) -> list[np.ndarray]:
+        """The tokenizer alone (no JSON parse), measured in the thread that
+        runs it: wall time, this thread's CPU time (wall less CPU is the
+        wait for the GIL and the scheduler) and ids produced, [CLS] and
+        [SEP] included."""
+        wall0, cpu0 = time.perf_counter(), time.thread_time()
+        with trace_span("tpuserve.tokenize", model=self.name,
+                        items=len(texts)) as span:
+            items = [self._encode(t) for t in texts]
+            tokens = sum(it.shape[0] for it in items)
+            span.set_metadata(tokens=tokens)
+        if self._tokenize_obs is not None:
+            hist, cpu_s, n_tokens = self._tokenize_obs
+            hist.observe((time.perf_counter() - wall0) * 1e3)
+            cpu_s.inc(time.thread_time() - cpu0)
+            n_tokens.inc(tokens)
+        return items
 
     def _encode(self, text: str) -> np.ndarray:
         tok = self.tokenizer

@@ -494,15 +494,20 @@ class FlightRecorder:
             }
 
 
-# Per-request/per-batch phase labels on latency_ms{model=,phase=}. The
-# ingest phases (ISSUE 11) are request-scoped and observed by the HTTP
-# layer — "body_read" is the time to read the request body off the socket
-# (the HTTP ingress wire), "parse" the host decode/frame-parse time; the
-# rest are batch-scoped and observed by the batcher. Together with the
-# roofline ceilings they attribute where an ingest-bound config loses time
-# (docs/PERFORMANCE.md "The ingest fast path").
-PHASES = ("body_read", "parse", "queue", "preproc", "h2d", "compute",
-          "postproc", "total")
+# Phase labels on latency_ms{model=,phase=}. The ingest phases (ISSUE 11)
+# are request-scoped and observed by the HTTP layer — "body_read" is the
+# time to read the request body off the socket (the HTTP ingress wire),
+# "parse" the host decode/frame-parse time, "tokenize" the tokenizer alone
+# inside it, timed in the decode thread by the text families (ISSUE 25) —
+# and "total" is the whole request. BATCH_PHASES are observed by the
+# batcher, which binds those and no others: "queue" per item (arrival to
+# admission), "slot_wait" per batch (flush decision to admission: the part
+# of "queue" that is not accumulation), the rest per batch around its
+# stages. Together with the roofline ceilings they attribute where an
+# ingest-bound config loses time (docs/PERFORMANCE.md "The ingest fast
+# path").
+BATCH_PHASES = ("queue", "slot_wait", "preproc", "h2d", "compute", "postproc")
+PHASES = ("body_read", "parse", "tokenize") + BATCH_PHASES + ("total",)
 
 # Host-pipeline stage executors (tpuserve.hostpipe, docs/PERFORMANCE.md):
 # the stage label on pipeline_stage_depth{model=,stage=} and the keys of the
@@ -635,9 +640,6 @@ class Metrics:
         return {name: c.value for name, c in counters}
 
     # -- convenience --------------------------------------------------------
-    def observe_phase(self, model: str, phase: str, ms: float) -> None:
-        self.histogram(f"latency_ms{{model={model},phase={phase}}}").observe(ms)
-
     def cache_counter(self, model: str, event: str) -> Counter:
         """cache_<event>_total{model=}: one of CACHE_EVENTS
         (tpuserve.cache). Prebound by ModelCache at construction — never
@@ -1017,26 +1019,55 @@ def _split(name: str) -> tuple[str, str]:
     return base, labels + "," if labels else ""
 
 
-class phase_timer:
-    """Context manager: time a phase into Metrics (+ optional trace span)."""
+# -- spans on the profiler's clock (ISSUE 25) ----------------------------------
+# The ring and the request trees above are on time.time() and are recorded
+# after the fact from the event loop. These two write into jax.profiler's own
+# trace instead: on its clock, beside the device's lines, from the thread
+# that does the work. They record exactly while a profiler session is on
+# (/debug/profile, profiler_port, the benchmark's traced run) and cost an
+# atomic load otherwise. The profiler's trace is the store; nothing is kept
+# here. jax is imported on first use, so a process that emits no span (the
+# router) never pays for it.
 
-    def __init__(self, metrics: Metrics, model: str, phase: str, trace: bool = False) -> None:
-        self.metrics = metrics
-        self.model = model
-        self.phase = phase
-        self.trace = trace
+_annotation = None
 
-    def __enter__(self) -> "phase_timer":
-        self.t0 = time.perf_counter()
-        self.wall0 = time.time()
-        return self
 
-    def __exit__(self, *exc) -> None:
-        t1 = time.perf_counter()
-        ms = (t1 - self.t0) * 1e3
-        self.metrics.observe_phase(self.model, self.phase, ms)
-        if self.trace:
-            self.metrics.tracer.add(self.phase, self.wall0, self.wall0 + (t1 - self.t0), tid=self.model)
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+def trace_span(name: str, **args):
+    """``with trace_span("tpuserve.parse", model=name, bytes=n): ...`` — a
+    span around work done in THIS thread."""
+    return _trace_annotation()(name, **args)
+
+
+def trace_call(name: str, args: dict, fn, *fn_args):
+    """``fn(*fn_args)`` inside a span: what to hand an executor, so that the
+    span is taken in the thread that does the work."""
+    with trace_span(name, **args):
+        return fn(*fn_args)
+
+
+def trace_mark(name: str, start_s: float, end_s: float, **args) -> None:
+    """An interval measured after the fact on the event loop
+    (``time.perf_counter()`` seconds), where a ``with`` cannot span the
+    awaits of interleaved coroutines and the start may lie before the
+    coroutine ran at all: written as a zero-length annotation that carries
+    ``dur_us`` (the interval's length) and ``ago_us`` (how long before this
+    write it ended). A reader places it at [t - ago - dur, t - ago)."""
+    ann = _trace_annotation()
+    if not ann.is_enabled():
+        return
+    now = time.perf_counter()
+    with ann(name, dur_us=round((end_s - start_s) * 1e6),
+             ago_us=round((now - end_s) * 1e6), **args):
+        pass
 
 
 def percentile(values: Iterable[float], q: float) -> float:

@@ -19,7 +19,8 @@ Sequence parallelism for long contexts lives at the op level:
 and ``tpuserve.ops.ulysses`` (head all-to-all).
 """
 
-from tpuserve.parallel.distributed import init_distributed, process_info  # noqa: F401
+from tpuserve.parallel.distributed import (  # noqa: F401
+    init_distributed, local_devices_info, process_info)
 from tpuserve.parallel.mesh import (  # noqa: F401
     MeshPlan,
     axis_size,

@@ -69,3 +69,24 @@ def process_info() -> dict:
         "platform": jax.devices()[0].platform,
         "device_kind": jax.devices()[0].device_kind,
     }
+
+
+_MEMORY_KEYS = ("bytes_in_use", "peak_bytes_in_use", "peak_bytes_reserved",
+                "bytes_limit")
+
+
+def local_devices_info() -> list[dict]:
+    """This process's devices with their memory, for /stats
+    ``topology.devices``: read from ``device.memory_stats()`` when asked,
+    nothing kept and nothing on the hot path. ``memory`` is None where the
+    backend reports none (the CPU). On the TPU ``peak_bytes_in_use`` counts
+    live buffers and ``peak_bytes_reserved`` the loaded programs' scratch:
+    what a chip had committed at its peak is their sum (PERF.md)."""
+    out = []
+    for d in jax.local_devices():
+        stats = d.memory_stats()
+        out.append({
+            "id": d.id, "kind": d.device_kind,
+            "memory": ({k: int(stats[k]) for k in _MEMORY_KEYS if k in stats}
+                       if stats else None)})
+    return out

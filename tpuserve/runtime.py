@@ -43,7 +43,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from tpuserve.analysis import witness
 from tpuserve.config import ModelConfig, ParallelConfig, ServerConfig
 from tpuserve.models.base import ServingModel
-from tpuserve.obs import Metrics
+from tpuserve.obs import Metrics, trace_span
 from tpuserve.utils.retrace import allow_transfers, host_fetch
 from tpuserve.parallel import make_mesh, match_partition_rules
 from tpuserve.parallel.mesh import MeshPlan, plan_for, select_devices
@@ -131,6 +131,12 @@ class Executable:
     donated: bool = False  # batch input buffers donated to the outputs
 
 
+def bucket_label(bucket: tuple) -> str:
+    """(256, 512) -> "256x512": a bucket in metric labels and span
+    arguments."""
+    return "x".join(str(d) for d in bucket)
+
+
 @dataclass(frozen=True)
 class VariantKey:
     """Identity of one fully-specialized compiled variant (ISSUE 6).
@@ -155,8 +161,8 @@ class VariantKey:
     @property
     def label(self) -> str:
         """Compact metric-label form: "<bucket>/<dtype>/<quantize>/<mode>"."""
-        b = "x".join(str(d) for d in self.bucket)
-        return f"{b}/{self.dtype}/{self.quantize or 'fp'}/{self.parallelism}"
+        return (f"{bucket_label(self.bucket)}/{self.dtype}/"
+                f"{self.quantize or 'fp'}/{self.parallelism}")
 
 
 @dataclass
@@ -604,7 +610,9 @@ class ModelRuntime:
                 out_shardings=out_shardings,
                 donate_argnums=(1,) if donate else (),
             )
-            compiled = jitted.lower(params_struct, batch_struct).compile()
+            with trace_span("tpuserve.compile", model=self.model.name,
+                            bucket=bucket_label(bucket)):
+                compiled = jitted.lower(params_struct, batch_struct).compile()
             exes.append(Executable(bucket, compiled, in_batch_sharding,
                                    device_index=i, donated=donate))
         key = self.variant_key(bucket)
@@ -823,7 +831,13 @@ class ModelRuntime:
         self._c_replica_batches[replica].inc()
         params = (params_override if params_override is not None
                   else self.params_per_mesh)
-        return exe.compiled(params[replica], dev_batch)
+        # On the profiler's clock, from the thread that launches: the span
+        # ends when XLA has the program queued, before the device's own
+        # `XLA Modules` event begins. Nested in the batcher's tpuserve.h2d
+        # span, which names the batch.
+        with trace_span("tpuserve.launch", model=self.model.name,
+                        bucket=bucket_label(bucket), replica=replica):
+            return exe.compiled(params[replica], dev_batch)
 
     def run(self, bucket: tuple, host_batch: Any, replica: int | None = None,
             params_override: list[Any] | None = None) -> Any:

@@ -70,7 +70,7 @@ from tpuserve.genserve import GenEngine, GenEngineGroup, KVPressure
 from tpuserve.hostpipe import StageExecutors
 from tpuserve.lifecycle import ModelLifecycle, ReloadRejected
 from tpuserve.obs import (PRIORITIES, FlightRecorder, Metrics, TraceContext,
-                          exposition_content_type, spans_to_chrome)
+                          exposition_content_type, spans_to_chrome, trace_call)
 from tpuserve.runtime import ModelRuntime, build_runtime, configure_jax
 from tpuserve.scheduler import FleetScheduler
 from tpuserve.scheduler.tenants import TenantLedger
@@ -456,6 +456,7 @@ class ServerState:
                 await b.start()
             self.batchers[name] = b
             self.handles[name] = ModelHandles(name, model.cfg, self.metrics)
+            model.bind_metrics(self.metrics)
             if self.cfg.cache.enabled and getattr(model, "cacheable", True):
                 # Keys carry the LIVE runtime version, so a lifecycle
                 # publish/rollback atomically invalidates older entries;
@@ -1172,12 +1173,18 @@ async def _predict_traced(request: web.Request, state: ServerState,
         # "parse" phase for them is offset-table validation, not pixel work.
         t_parse = time.perf_counter()
         w_parse = time.time()
+        # The tpuserve.parse span is taken in the thread that decodes:
+        # unlike the phase=parse histogram, timed here around the executor
+        # hop, it holds no wait for a decode thread.
+        span = {"model": name, "bytes": len(body)}
         if state.cfg.decode_inline:
-            items, batched = model.host_decode_items(body, ctype)
+            items, batched = trace_call(
+                "tpuserve.parse", span, model.host_decode_items, body, ctype)
         else:
             loop = asyncio.get_running_loop()
             items, batched = await loop.run_in_executor(
-                state.pool, model.host_decode_items, body, ctype)
+                state.pool, trace_call, "tpuserve.parse", span,
+                model.host_decode_items, body, ctype)
         if not items:
             raise ValueError("empty batch")
         parse_s = time.perf_counter() - t_parse
@@ -1569,7 +1576,7 @@ async def handle_profile(request: web.Request) -> web.Response:
 
 
 async def handle_stats(request: web.Request) -> web.Response:
-    from tpuserve.parallel import process_info
+    from tpuserve.parallel import local_devices_info, process_info
 
     state: ServerState = request.app[STATE_KEY]
     out = state.metrics.summary()
@@ -1580,6 +1587,7 @@ async def handle_stats(request: web.Request) -> web.Response:
     # This is what a future multi-machine `[router] hosts` maps onto.
     out["topology"] = {
         **process_info(),
+        "devices": local_devices_info(),
         "worker_id": state.worker_id,
         "distributed": bool(state.cfg.distributed.coordinator_address),
     }

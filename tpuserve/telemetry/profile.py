@@ -1,18 +1,22 @@
-"""On-demand deep profiling (ISSUE 14 tentpole part 5).
+"""On-demand deep profiling (ISSUE 14 tentpole part 5; repaired for what
+jax 0.9 writes by ISSUE 25).
 
-``POST /debug/profile?duration_ms=`` arms a ``jax.profiler`` device trace
-for the window, then merges whatever the profiler produced (the perfetto
-trace JSON when the backend emits one) with the span ring's events from
-the same window into ONE Chrome-trace artifact. The workflow this closes:
-``/debug/slow`` names a slow request → its span tree says *which phase*
-(queue/h2d/compute) — but not which kernel; arming a capture during a
-repro answers at device-op granularity, device lanes and serving-path
-spans on one timeline.
+``POST /debug/profile?duration_ms=`` arms a ``jax.profiler`` trace for the
+window, reads the capture's ``*.xplane.pb`` with ``jax.profiler.ProfileData``
+and answers ONE Chrome trace: every chip's ``XLA Modules`` and ``XLA Ops``
+lines beside the program's own ``tpuserve.*`` spans (obs.trace_span /
+obs.trace_mark, written into the same trace from the threads that do the
+work), all on the profiler's clock. The workflow this closes:
+``/debug/slow`` names a slow request -> its span tree says *which phase*
+(queue/h2d/compute) — but not which kernel, nor what the host was doing
+while the device sat idle; a capture during a repro answers both on one
+timeline. The span ring (``/debug/trace``) is on ``time.time()`` and is NOT
+merged in: two clocks on one timeline is how gaps get misattributed.
 
 Degradation contract: profiling is best-effort by construction — a
-backend that emits only an xplane (no perfetto JSON), or a profiler that
-refuses to start, still yields the span-ring half with
-``device_trace: "unavailable"`` in the metadata, and never a 5xx for the
+profiler that refuses to start, or a capture with no device plane (the CPU
+backend), still answers 200 with what it has and
+``device_trace: "unavailable: ..."`` in the metadata, never a 5xx for the
 capture having less to say than hoped. One capture at a time (409 while
 armed): the profiler is process-global state.
 
@@ -24,10 +28,9 @@ from __future__ import annotations
 
 import asyncio
 import glob
-import gzip
-import json
 import logging
 import os
+import re
 import shutil
 import tempfile
 import time
@@ -36,45 +39,65 @@ from tpuserve.obs import Metrics
 
 log = logging.getLogger("tpuserve.telemetry")
 
+_DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):(\d+)$")
+_DEVICE_LINES = ("XLA Modules", "XLA Ops")
+SPAN_PREFIX = "tpuserve."
+# Device lanes get pids from here up, apart from the host spans' pid 0.
+DEVICE_PID_BASE = 1000
+
 
 class CaptureBusy(Exception):
     """A capture is already armed (-> 409): jax.profiler is one-at-a-time
     process-global state."""
 
 
-def _find_device_events(log_dir: str) -> "list | None":
-    """Pull Chrome/perfetto trace events out of a finished profiler dir.
+def read_capture(log_dir: str) -> "tuple[list, list] | None":
+    """(device events, host spans) of a finished profiler directory as
+    Chrome ``ph: "X"`` events, microseconds on the profiler's clock. Device
+    events: one lane per (chip, line) for ``XLA Modules`` and ``XLA Ops``.
+    Host spans: the ``tpuserve.*`` annotations of every host plane, one
+    lane per thread (lines are keyed by id: threads share names), their
+    keyword arguments as ``args``. A zero-length span that carries
+    ``dur_us``/``ago_us`` (obs.trace_mark) is drawn where it was measured.
+    None when the profiler wrote no xplane."""
+    hits = sorted(glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not hits:
+        return None
+    from jax.profiler import ProfileData
 
-    jax writes ``plugins/profile/<run>/*.trace.json.gz`` (and, when asked,
-    ``perfetto_trace.json.gz``); both are Chrome-trace JSON. None when the
-    backend emitted nothing parseable (xplane-only captures)."""
-    patterns = [
-        os.path.join(log_dir, "**", "*.trace.json.gz"),
-        os.path.join(log_dir, "**", "*trace.json"),
-    ]
-    for pattern in patterns:
-        for path in sorted(glob.glob(pattern, recursive=True)):
-            try:
-                if path.endswith(".gz"):
-                    with gzip.open(path, "rt", encoding="utf-8") as f:
-                        data = json.load(f)
-                else:
-                    with open(path, encoding="utf-8") as f:
-                        data = json.load(f)
-            except (OSError, ValueError):
-                continue
-            events = data.get("traceEvents")
-            if isinstance(events, list) and events:
-                return events
-    return None
+    device: list = []
+    host: list = []
+    for plane in ProfileData.from_file(hits[-1]).planes:
+        m = _DEVICE_PLANE.match(plane.name)
+        if m:
+            pid = DEVICE_PID_BASE + int(m.group(2))
+            for line in plane.lines:
+                if line.name in _DEVICE_LINES:
+                    device.extend(
+                        {"name": ev.name[:160], "ph": "X",
+                         "ts": ev.start_ns / 1e3, "dur": ev.duration_ns / 1e3,
+                         "pid": pid, "tid": line.name}
+                        for ev in line.events)
+            continue
+        if not plane.name.startswith("/host:"):
+            continue
+        for n, line in enumerate(plane.lines):
+            for ev in line.events:
+                if not ev.name.startswith(SPAN_PREFIX):
+                    continue
+                args = dict(ev.stats)
+                ts, dur = ev.start_ns / 1e3, ev.duration_ns / 1e3
+                if "dur_us" in args:  # measured after the fact
+                    dur = float(args["dur_us"])
+                    ts -= float(args.get("ago_us", 0)) + dur
+                host.append({"name": ev.name, "ph": "X", "ts": ts, "dur": dur,
+                             "pid": 0, "tid": f"{line.name}#{n}", "args": args})
+    return device, host
 
 
 class ProfileCapture:
     """One process's profiling endpoint state."""
-
-    # Device lanes are re-based onto pids >= this so they never collide
-    # with the serving tiers' span lanes (0 router, worker id + 1 workers).
-    DEVICE_PID_BASE = 1000
 
     def __init__(self, metrics: Metrics) -> None:
         self.metrics = metrics
@@ -87,64 +110,75 @@ class ProfileCapture:
         return self._armed
 
     async def capture(self, duration_ms: float) -> dict:
-        """Run one capture; returns the merged Chrome-trace dict. Raises
+        """Run one capture; returns the Chrome-trace dict. Raises
         CaptureBusy when one is already in flight."""
         if self._armed:
             raise CaptureBusy()
         self._armed = True
         loop = asyncio.get_running_loop()
         tmpdir = tempfile.mkdtemp(prefix="tpuserve_profile_")
-        t0_us = time.time() * 1e6
+        t0 = time.time()
         device_note = "ok"
-        device_events: "list | None" = None
+        device: list = []
+        host: list = []
         try:
             started = await loop.run_in_executor(
                 None, self._start_trace, tmpdir)
             await asyncio.sleep(duration_ms / 1e3)
             if started:
                 await loop.run_in_executor(None, self._stop_trace)
-                device_events = await loop.run_in_executor(
-                    None, _find_device_events, tmpdir)
-                if device_events is None:
-                    device_note = ("unavailable: profiler emitted no "
-                                   "parseable trace JSON (xplane-only "
-                                   "backend output)")
+                read = await loop.run_in_executor(None, self._read, tmpdir)
+                if read is None:
+                    device_note = ("unavailable: the profiler wrote no "
+                                   "readable xplane")
+                else:
+                    device, host = read
+                    if not device:
+                        device_note = ("unavailable: the capture holds no "
+                                       "device plane (CPU backend, or "
+                                       "nothing ran on a chip)")
             else:
                 device_note = "unavailable: jax.profiler failed to start"
         finally:
             self._armed = False
             shutil.rmtree(tmpdir, ignore_errors=True)
 
-        # The span ring's slice of the SAME window: serving-path batch /
-        # generation spans beside the device lanes.
-        ring = json.loads(self.metrics.tracer.chrome_trace(
-            limit=None, since_us=t0_us))["traceEvents"]
-        merged = list(ring)
-        if device_events:
-            for ev in device_events:
-                ev = dict(ev)
-                if isinstance(ev.get("pid"), int):
-                    ev["pid"] = self.DEVICE_PID_BASE + ev["pid"]
-                else:
-                    ev["pid"] = self.DEVICE_PID_BASE
-                merged.append(ev)
         self.captures.inc()
+        spans: dict[str, int] = {}
+        for ev in host:
+            spans[ev["name"]] = spans.get(ev["name"], 0) + 1
         meta = {
             "duration_ms": duration_ms,
             "device_trace": device_note,
-            "ring_events": len(ring),
-            "device_events": len(device_events or []),
-            "captured_at": round(t0_us / 1e6, 3),
+            "clock": "profiler",
+            "device_events": len(device),
+            "host_spans": len(host),
+            "span_names": spans,
+            "captured_at": round(t0, 3),
         }
         self.last_capture = meta
+        merged = sorted(device + host, key=lambda e: e["ts"])
         return {"traceEvents": merged, "tpuserve_profile": meta}
+
+    @staticmethod
+    def _read(log_dir: str) -> "tuple[list, list] | None":
+        try:
+            return read_capture(log_dir)
+        except Exception:  # noqa: BLE001 — best-effort by contract
+            log.exception("reading the profiler's xplane failed")
+            return None
 
     @staticmethod
     def _start_trace(log_dir: str) -> bool:
         try:
             import jax
 
-            jax.profiler.start_trace(log_dir, create_perfetto_trace=True)
+            # Host spans at the level TraceAnnotation writes at; no Python
+            # call tracer (it multiplies the capture's size and cost).
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 1
+            jax.profiler.start_trace(log_dir, profiler_options=opts)
             return True
         except Exception:  # noqa: BLE001 — best-effort by contract
             log.exception("jax.profiler.start_trace failed")
