@@ -143,28 +143,38 @@ def make_fake_batcher(rec=None, compute_s=0.1, per_batch=None, assemble_s=0.0,
 def test_pipeline_overlaps_assembly_with_compute():
     """Batch N+1's assemble runs while batch N's compute is in flight, and
     aggregate stage busy time exceeds elapsed wall time (the acceptance
-    criterion's pipelining proof, at unit scale)."""
+    criterion's pipelining proof, at unit scale). Assembly (80 ms) is not
+    small against the compute (50 ms) here, so once the first batch has
+    been measured the batcher closes batches ahead of the device section
+    (ISSUE 26: when the device time queued falls to twice the staging
+    time, bounded by assemble_ahead); where it is small nothing is
+    assembled ahead (tests/test_late_close.py)."""
     async def go():
         b, metrics, rec = make_fake_batcher(
-            compute_s=0.12, assemble_s=0.05,
+            compute_s=0.05, assemble_s=0.08,
             pipeline_cfg=PipelineConfig(depth=2, assemble_ahead=2))
         await b.start()
         assert b._use_arena and b.arena is not None
+        # Nothing measured yet: the gate counts the device section.
+        assert b._close_wait_s(b.depth - 1, False) == 0
+        assert b._close_wait_s(b.depth, False) == float("inf")
         t0 = time.perf_counter()
-        futs = [b.submit(float(i + 1)) for i in range(4)]
+        futs = [b.submit(float(i + 1)) for i in range(6)]
         res = await asyncio.wait_for(asyncio.gather(*futs), timeout=10)
         elapsed = time.perf_counter() - t0
+        assert b._inflight_peak > b.depth  # batches were closed ahead
+        assert b.pipeline_stats()["admission"] == b.depth + 2
         await b.stop()
 
-        assert res == [1.0, 2.0, 3.0, 4.0]
+        assert res == [1.0, 2.0, 3.0, 4.0, 5.0, 6.0]
         fetches = rec.intervals("fetch")
         assembles = rec.intervals("assemble")
-        assert len(fetches) == 4 and len(assembles) == 4
+        assert len(fetches) == 6 and len(assembles) == 6
         busy = sum(t1 - t0 for t0, t1, _ in fetches + assembles)
-        # 4 x 0.12 fetch + 4 x 0.05 assemble = 0.68 s of stage time; with
+        # 6 x 0.05 fetch + 6 x 0.08 assemble = 0.78 s of stage time; with
         # depth 2 it must pack into well under the sequential sum.
         assert busy > elapsed, (busy, elapsed)
-        assert elapsed < 0.55, elapsed  # sequential would be >= 0.68
+        assert elapsed < 0.62, elapsed  # sequential would be >= 0.78
         # Direct interval evidence: a later batch's assemble ran
         # concurrently with an earlier batch's compute (>= 20 ms overlap).
         overlapped = any(
@@ -180,7 +190,9 @@ def test_pipeline_overlaps_assembly_with_compute():
 
 def test_depth_bounds_concurrent_device_batches():
     """depth=1 serializes the device section: fetch intervals never
-    overlap each other even though admission allows more batches in."""
+    overlap each other, however many batches admission closes ahead (at
+    most assemble_ahead = 3 past the one slot) — at depth 1 the section's
+    spare slots are never given out."""
     async def go():
         b, _, rec = make_fake_batcher(
             compute_s=0.08,
@@ -216,15 +228,21 @@ def test_depth_k_preserves_result_ordering():
     run(go())
 
 
-def test_deadline_504_while_waiting_for_staging_slot():
+@pytest.mark.parametrize("ahead", [0, 1])
+def test_deadline_504_while_waiting_for_staging_slot(ahead):
     """PR-2 semantics through the pipelined path: a deadlined request stuck
     behind a slow in-flight batch fails AT its deadline (DeadlineExceeded,
-    counted), not when the staging slot finally frees."""
+    counted), not when the device section finally frees — whether its batch
+    still waits to close (the gate counts the one staging slot) or was
+    closed ahead and waits, assembled, for the slot itself."""
     async def go():
-        b, metrics, _ = make_fake_batcher(
+        b, metrics, rec = make_fake_batcher(
             compute_s=0.5,
             pipeline_cfg=PipelineConfig(depth=1, assemble_ahead=4))
         await b.start()
+        # The gate by count, one slot and `ahead` batches past it.
+        b._gate._wait_s = lambda held, full: \
+            0.0 if held < 1 + ahead else float("inf")
         slow = b.submit(1.0)
         await asyncio.sleep(0.05)  # batch 1 occupies the only staging slot
         t0 = time.perf_counter()
@@ -235,6 +253,8 @@ def test_deadline_504_while_waiting_for_staging_slot():
         assert waited < 0.35, waited
         assert metrics.counter(
             "deadline_exceeded_total{model=fake}").value == 1
+        # Closed and assembled only where it was admitted ahead.
+        assert len(rec.intervals("assemble")) == 1 + ahead
         assert await asyncio.wait_for(slow, timeout=10) == 1.0
         await b.stop()
 

@@ -1,0 +1,593 @@
+"""The close rule (ISSUE 26): a batch's membership is fixed when the device
+is about to need it (the device time still queued has fallen to twice the
+time a batch takes to be staged), not when one of ``depth x replicas +
+assemble_ahead`` admissions frees.
+
+The device here is a fake that runs its launches in order, one at a time,
+and takes the same time full or empty — what makes an early close dear:
+outstanding work divided by the batches in the pipeline is the batch size,
+so two batches frozen outside the device section halve every launch's fill.
+``parent_rule`` puts the old gate back (``depth + assemble_ahead`` batches
+by count, whatever is measured), so each claim is checked against what it
+replaces.
+"""
+
+import asyncio
+import concurrent.futures as cf
+import math
+import random
+import threading
+import time
+from collections import deque
+
+import numpy as np
+import pytest
+
+from tpuserve.batcher import DeadlineExceeded, ModelBatcher
+from tpuserve.config import ModelConfig, PipelineConfig
+from tpuserve.hostpipe import AdmissionGate, SlotPool
+from tpuserve.obs import Metrics
+
+
+def run(coro):
+    loop = asyncio.new_event_loop()
+    try:
+        return loop.run_until_complete(coro)
+    finally:
+        loop.close()
+
+
+class Model:
+    """Items are floats; the host batch is (bucket, 2): value, validity."""
+
+    def __init__(self, cfg, assemble_s=0.0):
+        self.cfg = cfg
+        self.name = cfg.name
+        self.assemble_s = assemble_s
+        self.batcher = None
+        # (n items, bucket size, staging slots in use when assembly began)
+        self.assembled: list[tuple[int, int, int]] = []
+
+    def bucket_for(self, n, **kw):
+        for b in self.cfg.batch_buckets:
+            if b >= n:
+                return (b,)
+        return (self.cfg.batch_buckets[-1],)
+
+    def input_signature(self, bucket):
+        import jax
+
+        return jax.ShapeDtypeStruct((bucket[0], 2), np.float32)
+
+    def assemble(self, items, bucket):
+        return self.assemble_into(items, bucket,
+                                  np.zeros((bucket[0], 2), np.float32))
+
+    def assemble_into(self, items, bucket, out):
+        self.assembled.append((len(items), bucket[0],
+                               self.batcher._staging[0].in_use))
+        if self.assemble_s:
+            time.sleep(self.assemble_s)
+        out[:] = 0
+        out[:len(items), 0] = items
+        out[:len(items), 1] = 1
+        return out
+
+    def host_postprocess(self, outputs, n_valid):
+        return [float(outputs[i, 0]) for i in range(n_valid)]
+
+
+class FifoDevice:
+    """One device: launches run in order, one at a time, each for
+    ``launch_s`` whatever it holds (or per bucket size, given a dict).
+    ``run`` books the launch on the device's timeline; ``fetch`` returns
+    when it has ended."""
+
+    n_replicas = 1
+
+    def __init__(self, launch_s):
+        self.launch_s = launch_s
+        self._lock = threading.Lock()
+        self._free_at = 0.0
+        # (items, bucket size, start, end) per launch
+        self.launches: list[tuple[int, int, float, float]] = []
+
+    def run(self, bucket, host_batch, replica=0, params_override=None):
+        out = np.array(host_batch, copy=True)
+        with self._lock:
+            start = max(time.perf_counter(), self._free_at)
+            took = self.launch_s[bucket[0]] \
+                if isinstance(self.launch_s, dict) else self.launch_s
+            end = self._free_at = start + took
+            self.launches.append((int(out[:, 1].sum()), bucket[0], start, end))
+        return out, end
+
+    def fetch(self, outputs):
+        out, end = outputs
+        time.sleep(max(0.0, end - time.perf_counter()))
+        return out
+
+    def fill(self, skip):
+        """Items over lanes of every launch after the first ``skip``."""
+        ran = self.launches[skip:]
+        return sum(n for n, *_ in ran) / sum(b for _, b, *_ in ran)
+
+    def idle_share(self, skip):
+        """Share of the time from launch ``skip`` on in which nothing ran."""
+        ran = self.launches[skip:]
+        gaps = sum(max(0.0, s - e0) for (_, _, _, e0), (_, _, s, _)
+                   in zip(ran, ran[1:]))
+        return gaps / (ran[-1][3] - ran[0][2])
+
+
+def make(launch_s=0.03, assemble_s=0.0, parent_rule=False, depth=2,
+         buckets=(4, 32), **cfg_over):
+    base = dict(name="fake", family="toy", batch_buckets=list(buckets),
+                deadline_ms=5.0, dtype="float32", num_classes=10,
+                parallelism="single", max_queue=4096, max_inflight=2)
+    base.update(cfg_over)
+    model = Model(ModelConfig(**base), assemble_s)
+    dev = FifoDevice(launch_s)
+    metrics = Metrics()
+    b = ModelBatcher(model, dev, metrics, cf.ThreadPoolExecutor(max_workers=2),
+                     pipeline_cfg=PipelineConfig(depth=depth, assemble_ahead=2))
+    model.batcher = b
+    b.parent_rule = parent_rule
+    return b, model, dev, metrics
+
+
+def by_count(b, cap):
+    """Put a gate by count in place of the close rule (after start())."""
+    b._gate._wait_s = lambda held, full: 0.0 if held < cap else math.inf
+
+
+async def start(b):
+    await b.start()
+    if b.parent_rule:
+        by_count(b, b.depth + b.pipeline_cfg.assemble_ahead)
+
+
+async def closed_loop(b, callers, seconds, think_s, seed=0):
+    """``callers`` callers, each with one request outstanding; an answer is
+    followed by up to ``think_s`` away from the batcher (a caller's reply,
+    its next request's parse), then the next request."""
+    rng = random.Random(seed)
+    stop_at = time.perf_counter() + seconds
+
+    async def caller(i):
+        n = 0
+        while time.perf_counter() < stop_at:
+            x = float(i * 100000 + n)
+            assert await b.submit(x) == x
+            n += 1
+            await asyncio.sleep(rng.uniform(0, think_s))
+        return n
+
+    return sum(await asyncio.gather(*[caller(i) for i in range(callers)]))
+
+
+# -- (a) the gain: fuller launches from the same outstanding work -------------
+
+def run_closed(parent_rule, callers=64, seconds=1.6, think_s=0.006, **kw):
+    async def go():
+        b, model, dev, metrics = make(parent_rule=parent_rule, **kw)
+        await start(b)
+        n = await closed_loop(b, callers, seconds, think_s=think_s)
+        await b.stop()
+        return b, model, dev, metrics, n
+
+    return run(go())
+
+
+def test_closed_loop_of_two_buckets_outstanding_fills_launches():
+    """64 outstanding over buckets [4, 32] and a launch that costs the same
+    full or empty: with no batch frozen outside the device section the
+    launches run at least 80% full after warm-up: each batch closes just
+    before the running launch ends, with everything that launch's callers
+    have sent back."""
+    _, _, dev, _, _ = run_closed(parent_rule=False)
+    assert len(dev.launches) > 20
+    assert dev.fill(skip=8) >= 0.80, dev.fill(skip=8)
+
+
+def test_the_parents_cap_leaves_launches_half_empty():
+    """The control of the test above: the same loop under the old cap
+    (depth + assemble_ahead = 4 batches) answers fewer items from launches
+    no better than two thirds full."""
+    _, _, dev, _, n_new = run_closed(parent_rule=False)
+    _, _, old, _, n_old = run_closed(parent_rule=True)
+    assert old.fill(skip=8) < 0.70, old.fill(skip=8)
+    assert dev.fill(skip=8) > old.fill(skip=8) + 0.15
+    assert n_new > 1.15 * n_old, (n_new, n_old)
+
+
+# -- (b) nothing is assembled before the device section has room --------------
+
+def test_no_batch_is_assembled_before_a_slot_is_free():
+    """Staging small against a launch: every batch's assembly begins with a
+    device-section slot free for it, from the first batch on (before a
+    measurement the gate counts the device section), and no more than the
+    device section holds is ever closed."""
+    b, model, dev, _, _ = run_closed(parent_rule=False, seconds=0.8)
+    assert len(model.assembled) > 10
+    assert b._reserve_ms() < 10.0 < b._device_ms[(32,)]
+    assert all(in_use < b.depth for _, _, in_use in model.assembled), \
+        model.assembled
+    assert b._inflight_peak <= b.depth
+
+
+def test_the_parents_cap_assembles_behind_a_full_device_section():
+    _, model, _, _, _ = run_closed(parent_rule=True, seconds=0.8)
+    assert any(in_use == 2 for _, _, in_use in model.assembled)
+
+
+# -- (c) a launch short against assembly still gets a batch ahead -------------
+
+def test_short_launches_still_get_a_batch_assembled_ahead():
+    """Assembly of 12 ms against a launch of 8 ms: the reserve comes out
+    longer than a launch, so batches close ahead of the device section (up
+    to assemble_ahead of them), assembly runs behind a full device section,
+    and the device is kept fed (it idles less than under a gate that counts
+    the device section alone)."""
+    def go(count_only):
+        async def inner():
+            b, model, dev, _ = make(launch_s=0.008, assemble_s=0.012,
+                                    buckets=(4,))
+            await b.start()
+            if count_only:
+                by_count(b, b.depth)
+            await closed_loop(b, 32, 1.0, think_s=0.0)
+            await b.stop()
+            return b, model, dev
+
+        return run(inner())
+
+    b, model, dev = go(count_only=False)
+    assert b._reserve_ms() > b._device_ms[(4,)]
+    assert b.depth < b._inflight_peak <= b.depth + 2
+    assert any(in_use >= 2 for _, _, in_use in model.assembled[8:])
+    _, _, starved = go(count_only=True)
+    assert dev.idle_share(skip=8) < starved.idle_share(skip=8), \
+        (dev.idle_share(skip=8), starved.idle_share(skip=8))
+    assert dev.idle_share(skip=8) < 0.25, dev.idle_share(skip=8)
+
+
+@pytest.mark.parametrize("case,stage_ms,launches,closed,held,full,want_ms", [
+    ("nothing measured: by count, room", None, [[]], {}, 1, False, 0.0),
+    ("nothing measured: by count, none", None, [[]], {}, 2, True, math.inf),
+    ("empty section: at once", 7.0, [[]], {}, 0, False, 0.0),
+    # BERT-base: a 305 ms launch just staged, 7 ms to stage a batch.
+    ("one launch queued", 7.0, [[305.0]], {}, 1, False, 305.0 - 14.0),
+    ("a launch behind it counts whole", 7.0, [[305.0, 305.0]], {}, 2, False,
+     610.0 - 14.0),
+    ("the reserve covers what is queued", 200.0, [[305.0]], {}, 1, False,
+     0.0),
+    ("closed, not yet staged, counts too", 7.0, [[]], {5: 305.0}, 1, False,
+     305.0 - 14.0),
+    ("a full batch does not wait", 7.0, [[305.0, 305.0]], {}, 2, True, 0.0),
+    ("never past assemble_ahead", 200.0, [[5.0]], {}, 4, True, math.inf),
+    ("the replica that runs dry first decides", 7.0, [[305.0], []], {}, 1,
+     False, 0.0),
+    ("and takes what was closed for it", 7.0, [[305.0], []], {5: 100.0}, 2,
+     False, 100.0 - 14.0),
+])
+def test_close_waits_for_the_device_time_queued(case, stage_ms, launches,
+                                                closed, held, full, want_ms):
+    b, _, dev, _ = make()
+    dev.n_replicas = len(launches)
+
+    async def go():
+        await b.start()
+        now = time.perf_counter()
+        b._stage_ms = stage_ms
+        b._device_ms[(32,)] = 305.0
+        b._last_done = [now] * len(launches)
+        b._launches = [deque([ms, now, True] for ms in staged)
+                       for staged in launches]
+        b._closed_ms.update(closed)
+        got = b._close_wait_s(held, full) * 1e3
+        await b.stop()
+        return got
+
+    got = run(go())
+    assert got == want_ms if want_ms in (0.0, math.inf) \
+        else want_ms - 5.0 < got <= want_ms, (case, got)
+
+
+def test_a_fetch_that_overtakes_gives_no_sample_of_device_time():
+    """A 2 ms launch ran behind a 40 ms one and its fetch came back first:
+    that says the long one has ended too (nothing of it is queued any
+    more), but neither end gives a sample of device time."""
+    b, _, _, _ = make()
+
+    async def go():
+        await b.start()
+        now = time.perf_counter()
+        b._device_ms.update({(4,): 2.0, (32,): 40.0})
+        b._last_done = [now - 0.1]
+        long_, short = [40.0, now - 0.05, True], [2.0, now - 0.04, True]
+        b._launches = [deque([long_, short])]
+        b._observe_launch_end((4,), 0, short, now - 0.04, now)
+        assert b._device_ms == {(4,): 2.0, (32,): 40.0}
+        assert long_[:1] + long_[2:] == [0.0, False]
+        b._launches[0].remove(short)
+        assert b._queued_ms(0, now) == 0.0
+        b._observe_launch_end((32,), 0, long_, now - 0.05, now + 0.001)
+        assert b._device_ms == {(4,): 2.0, (32,): 40.0}
+        b._launches[0].remove(long_)
+        # In order again: the next ends are samples, and the estimate is
+        # the second smallest of them (an end is only ever seen late).
+        for took, want in ((0.050, 50.0), (0.090, 90.0), (0.044, 50.0),
+                           (0.047, 47.0)):
+            t0 = b._last_done[0]
+            nxt = [40.0, t0, True]
+            b._launches[0].append(nxt)
+            b._observe_launch_end((32,), 0, nxt, t0, t0 + took)
+            b._launches[0].remove(nxt)
+            assert abs(b._device_ms[(32,)] - want) < 0.01
+        await b.stop()
+
+    run(go())
+
+
+def test_a_short_launch_behind_a_long_one_does_not_hold_a_slot():
+    """Hazard 2: both device-section slots are taken, by a 40 ms launch
+    three quarters through and a 2 ms launch queued behind it. The batch
+    the device needs next still gets a (spare) slot, because little device
+    time is queued; with a full launch queued behind it would not, nor at
+    depth 1."""
+    async def go(depth, queued_ms):
+        b, _, _, _ = make(depth=depth)
+        await b.start()
+        now = time.perf_counter()
+        b._device_ms.update({(4,): 2.0, (32,): 40.0})
+        b._last_done = [now - 1.0]
+        b._launches = [deque([[40.0, now - 0.03, True],
+                              [queued_ms, now, True]])]
+        pool = b._staging[0]
+        while pool.in_use < depth:
+            assert pool.try_acquire() is not None
+        slot = pool.try_acquire()
+        await b.stop()
+        return slot
+
+    assert run(go(depth=2, queued_ms=2.0)) is not None
+    assert run(go(depth=2, queued_ms=40.0)) is None
+    assert run(go(depth=1, queued_ms=2.0)) is None
+
+
+def test_slotpool_spare_slots_go_by_the_callers_rule():
+    async def go():
+        ok = [False]
+        p = SlotPool(2, spare=1, spare_ok=lambda: ok[0])
+        a, b_ = p.try_acquire(), p.try_acquire()
+        assert p.in_use == 2 and p.try_acquire() is None
+        ok[0] = True
+        c = p.try_acquire()
+        assert c is not None and p.in_use == 3
+        assert p.try_acquire() is None  # never past n + spare
+        waiter = asyncio.ensure_future(p.acquire())
+        await asyncio.sleep(0.01)
+        p.release(c)
+        assert await asyncio.wait_for(waiter, timeout=1) == c
+        for slot in (a, b_, c):
+            p.release(slot)
+        assert p.in_use == 0
+
+    run(go())
+
+
+# -- (d) a deadline that passes while the batch waits to close ---------------
+
+def test_deadline_passes_while_the_batch_waits_to_close():
+    """The request fails AT its deadline, not when the device section frees
+    half a second later; its batch never closed, so nothing was assembled
+    for it."""
+    async def go():
+        b, model, _, metrics = make(launch_s=0.5, depth=1, buckets=(1,))
+        await b.start()
+        slow = b.submit(1.0)
+        await asyncio.sleep(0.05)  # the one device-section slot is taken
+        t0 = time.perf_counter()
+        doomed = b.submit(2.0, deadline_at=t0 + 0.08)
+        with pytest.raises(DeadlineExceeded):
+            await asyncio.wait_for(doomed, timeout=10)
+        waited = time.perf_counter() - t0
+        assert 0.07 < waited < 0.35, waited
+        assert len(model.assembled) == 1
+        assert metrics.counter(
+            "deadline_exceeded_total{model=fake}").value == 1
+        assert await asyncio.wait_for(slow, timeout=10) == 1.0
+        await b.stop()
+
+    run(go())
+
+
+# -- (e) light load does not change ------------------------------------------
+
+def test_one_request_and_a_free_slot_flushes_at_once():
+    """At target 1 (where light load converges) a lone request does not
+    wait for the 200 ms timer or for admission: it is answered in about one
+    launch, and its wait for a place is nothing."""
+    async def go():
+        b, model, _, metrics = make(launch_s=0.02, deadline_ms=200.0)
+        await b.start()
+        b._targets[None] = 1.0
+        for i in range(3):
+            t0 = time.perf_counter()
+            assert await b.submit(float(i)) == float(i)
+            assert time.perf_counter() - t0 < 0.15
+        await b.stop()
+        h = metrics.histogram("latency_ms{model=fake,phase=slot_wait}")
+        assert h.n == 3 and h.quantile(1.0) < 5.0
+        assert [n for n, _, _ in model.assembled] == [1, 1, 1]
+
+    run(go())
+
+
+# -- (f) the bucket-edge guard -------------------------------------------------
+
+@pytest.mark.parametrize("n,queued,device,stage_ms,want", [
+    # 64 of 128 outstanding must not ride a 256-wide launch at 305 ms when
+    # 32-wide launches cost 38: stop at the edge of the bucket occupied.
+    (16, 48, {(32,): 38.0, (256,): 305.0}, 60.0, 32),
+    (32, 32, {(32,): 38.0, (256,): 305.0}, 60.0, 32),
+    # 200 queued make the larger launch no dearer per item once the staging
+    # every launch pays is counted (1.8 ms against 3.1): one launch, not
+    # seven.
+    (16, 184, {(32,): 38.0, (256,): 305.0}, 60.0, 256),
+    # Already past the edge at the flush: nothing to guard.
+    (40, 24, {(32,): 38.0, (256,): 305.0}, 60.0, 256),
+    # Everything fits the bucket occupied.
+    (10, 12, {(32,): 38.0, (256,): 305.0}, 60.0, 256),
+    # A bucket not measured yet is not held against the batch.
+    (16, 48, {(32,): 38.0}, 60.0, 256),
+    (16, 48, {}, None, 256),
+    # A larger bucket that is cheaper per item even part full is taken.
+    (16, 48, {(32,): 38.0, (256,): 80.0}, 60.0, 256),
+])
+def test_close_limit_at_a_bucket_edge(n, queued, device, stage_ms, want):
+    b, _, _, _ = make(buckets=(32, 256))
+    b._device_ms.update(device)
+    b._stage_ms = stage_ms
+    assert b._close_limit(n, queued, None) == want
+
+
+def test_low_concurrency_stays_in_the_small_bucket():
+    """8 outstanding over buckets [4, 32] where a 32-wide launch costs 8x a
+    4-wide one, and batches that flush small (a short timer) and grow at
+    the close. With both buckets measured the close stops at the edge of
+    the narrow bucket rather than folding 5-8 items into the wide launch,
+    and answers more than a close that always takes everything."""
+    def go(guard):
+        async def inner():
+            b, _, dev, _ = make(buckets=(4, 32), deadline_ms=0.5)
+            dev.launch_s = {4: 0.004, 32: 0.032}
+            if not guard:
+                b._close_limit = lambda n, queued, group: 32
+            await b.start()
+            b._device_ms.update({(4,): 4.0, (32,): 32.0})
+            b._stage_ms = 1.0
+            n = await closed_loop(b, 8, 0.8, think_s=0.004)
+            await b.stop()
+            return dev, n
+
+        return run(inner())
+
+    dev, n = go(guard=True)
+    wide = [k for k, bucket, _, _ in dev.launches[4:] if bucket == 32]
+    assert len(dev.launches) > 40
+    assert len(wide) <= len(dev.launches) // 10, (len(wide), len(dev.launches))
+    unguarded, n_unguarded = go(guard=False)
+    assert n > 1.2 * n_unguarded, (n, n_unguarded)
+
+
+# -- (g) the counter the mechanism brings --------------------------------------
+
+def test_joined_counter_sums_to_items_total():
+    _, _, _, metrics, n = run_closed(parent_rule=False, seconds=0.6)
+    joined = {j: metrics.counter(
+        f"batcher_batch_items_total{{model=fake,joined={j}}}").value
+        for j in ("accumulate", "close")}
+    assert joined["accumulate"] > 0 and joined["close"] > 0, joined
+    assert sum(joined.values()) == \
+        metrics.counter("items_total{model=fake}").value == n
+
+
+# -- the gate itself -----------------------------------------------------------
+
+def test_gate_admits_in_arrival_order_one_per_decision():
+    async def go():
+        cap = [1]
+        g = AdmissionGate(
+            lambda held, eager: 0.0 if held < cap[0] else math.inf)
+        await g.acquire()
+        order = []
+
+        async def waiter(i):
+            await g.acquire()
+            order.append(i)
+
+        tasks = [asyncio.ensure_future(waiter(i)) for i in range(3)]
+        await asyncio.sleep(0.01)
+        assert order == [] and g.held == 1
+        cap[0] = 3
+        g.poke()             # one place per decision ...
+        await asyncio.sleep(0.01)
+        assert order == [0] and g.held == 2
+        g.poke()             # ... the next at the next
+        await asyncio.sleep(0.01)
+        assert order == [0, 1] and g.held == 3
+        g.release()
+        await asyncio.sleep(0.01)
+        assert order == [0, 1, 2] and g.held == 3
+        await asyncio.gather(*tasks)
+
+    run(go())
+
+
+def test_gate_opens_by_itself_when_its_wait_has_run_out():
+    async def go():
+        opens_at = time.perf_counter() + 0.05
+        g = AdmissionGate(
+            lambda held, eager: max(0.0, opens_at - time.perf_counter()))
+        t0 = time.perf_counter()
+        await asyncio.wait_for(g.acquire(), timeout=1)
+        assert 0.04 < time.perf_counter() - t0 < 0.3
+        assert g.held == 1
+
+    run(go())
+
+
+def test_gate_asks_the_waiter_whether_it_is_eager_at_each_decision():
+    async def go():
+        g = AdmissionGate(
+            lambda held, eager: 0.0 if eager and held < 2 else math.inf)
+        full = [False]
+        await g.acquire(eager=lambda: True)
+        waiter = asyncio.ensure_future(g.acquire(eager=lambda: full[0]))
+        await asyncio.sleep(0.01)
+        assert not waiter.done() and g.held == 1
+        full[0] = True
+        g.poke()
+        await asyncio.wait_for(waiter, timeout=1)
+        assert g.held == 2
+
+    run(go())
+
+
+def test_gate_timeout_leaves_no_place_taken():
+    async def go():
+        g = AdmissionGate(
+            lambda held, eager: 0.0 if held < 1 else math.inf)
+        await g.acquire()
+        with pytest.raises(asyncio.TimeoutError):
+            await g.acquire(timeout_s=0.02)
+        assert g.held == 1
+        nxt = asyncio.ensure_future(g.acquire())
+        await asyncio.sleep(0.01)
+        g.release()
+        await asyncio.wait_for(nxt, timeout=1)
+        assert g.held == 1
+        g.release()
+        assert g.held == 0
+
+    run(go())
+
+
+def test_gate_cancelled_waiter_passes_its_place_on():
+    async def go():
+        g = AdmissionGate(
+            lambda held, eager: 0.0 if held < 1 else math.inf)
+        await g.acquire()
+        first = asyncio.ensure_future(g.acquire())
+        second = asyncio.ensure_future(g.acquire())
+        await asyncio.sleep(0.01)
+        g.release()          # admits `first` ...
+        first.cancel()       # ... which is cancelled before it runs
+        with pytest.raises(asyncio.CancelledError):
+            await first
+        await asyncio.wait_for(second, timeout=1)
+        assert g.held == 1
+
+    run(go())

@@ -176,8 +176,12 @@ def test_every_replica_receives_batches_under_sustained_load():
     async def go():
         b = ModelBatcher(model, rt, metrics, pool)
         await b.start()
-        # Replica-aware admission: depth x replicas + assemble_ahead.
-        assert b._admission_cap == b.depth * N_DEV + b.pipeline_cfg.assemble_ahead
+        # Replica-aware admission: before a measurement the gate counts
+        # every chip's device section (depth x replicas); after, it goes
+        # by the device time queued on the chip that runs dry first, with
+        # at most assemble_ahead batches past the device sections.
+        assert b._close_wait_s(b.depth * N_DEV - 1, False) == 0
+        assert b._close_wait_s(b.depth * N_DEV, False) == float("inf")
         try:
             rng = np.random.default_rng(0)
             items = [rng.integers(0, 255, (8, 8, 3), np.uint8)
@@ -185,6 +189,10 @@ def test_every_replica_receives_batches_under_sustained_load():
             results = await asyncio.gather(*[b.submit(it) for it in items])
             assert len(results) == 12 * N_DEV
             assert all(r["top_k"] for r in results)
+            cap = b.depth * N_DEV + b.pipeline_cfg.assemble_ahead
+            assert b._close_wait_s(cap, True) == float("inf")
+            assert b._inflight_peak <= cap
+            assert b.pipeline_stats()["admission"] == cap
         finally:
             await b.stop()
 

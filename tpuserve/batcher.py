@@ -21,9 +21,30 @@ stages concurrently — batch N+1 assembles and transfers while batch N
 computes. Assembly writes into preallocated per-bucket arena buffers
 (AssemblyArena) recycled through a free-list instead of np.stack-allocating
 per batch, and a depth-k staging-slot pool per replica (SlotPool) bounds how
-many batches occupy the device section [h2d..fetch] at once. Admission into
-the pipeline (depth x replicas + assemble_ahead batches) replaces the old
-single semaphore acquired before assembly even started.
+many batches occupy the device section [h2d..fetch] at once.
+
+A batch **closes as late as keeps the device fed** (ISSUE 26;
+docs/PERFORMANCE.md "Admission"). Its membership is fixed by the final drain
+of ``_group_loop``, and the drain runs only when the admission gate opens:
+when the device time still queued in the device section (predicted from
+the launch durations seen per bucket) has fallen to the *reserve*, twice the
+time a batch has lately taken from its close to its launch (assemble, h2d
+and the hops between), or at once when the batch is full. Until then its
+requests stay where late arrivals join them. Outstanding work divided by
+the batches in the pipeline is the batch size (Little's law), so every batch
+frozen early shrinks all of them: where staging is small against a launch
+(BERT-large at 512 tokens: 7-70 ms against 847) one batch runs and the next
+closes just before it ends; where it is not (a vision model whose launch
+takes milliseconds, a host too busy to stage in time) the same rule closes
+batches ahead, up to ``depth x replicas + assemble_ahead`` of them, and the
+device-section slots stay occupied. Nothing is set: both sides are measured
+here, and before the first batch has been the gate counts ``depth x
+replicas``. The device section itself counts ``depth`` launches per replica,
+but goes by queued device time past that (``assemble_ahead`` spare slots):
+a launch of a few milliseconds queued behind a long one does not hold a slot
+against the batch the device needs next. The close does not grow a batch
+past the edge of the bucket it occupies unless what is queued makes the
+larger launch no dearer per item by the durations measured per bucket.
 
 Flush scheduling is **SLO-aware and adaptive** (ISSUE 5; docs/PERFORMANCE.md
 "Adaptive batching"): instead of always accumulating toward the largest
@@ -60,17 +81,29 @@ import concurrent.futures as cf
 import logging
 import math
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Hashable
 
 from tpuserve.config import AdaptiveConfig, PipelineConfig
-from tpuserve.hostpipe import AssemblyArena, SlotPool, StageExecutors
+from tpuserve.hostpipe import (AdmissionGate, AssemblyArena, SlotPool,
+                               StageExecutors)
 from tpuserve.models.base import ServingModel
 from tpuserve.obs import (BATCH_PHASES, PRIORITIES, Counter, Metrics,
                           trace_mark)
 from tpuserve.runtime import ModelRuntime, bucket_label
 
 log = logging.getLogger("tpuserve.batcher")
+
+# Durations the close rule goes by are estimated from this many samples.
+_RECENT = 8
+
+
+def _second(samples, largest: bool = False) -> float:
+    """The second smallest (or largest) of a few recent samples: an
+    estimate on the safe side that one stray sample cannot move."""
+    ranked = sorted(samples, reverse=largest)
+    return ranked[min(1, len(ranked) - 1)]
 
 
 class QueueFull(Exception):
@@ -111,6 +144,9 @@ class _Request:
     # appends per-request queue + phase spans (tagged with the batch id)
     # to it; None when the caller doesn't trace (tests, embedding).
     ctx: Any = None
+    # Joined its batch at the close (the final drain) rather than while the
+    # group accumulated: batcher_batch_items_total{joined=}.
+    at_close: bool = False
 
 
 class ModelBatcher:
@@ -145,6 +181,25 @@ class ModelBatcher:
         # per group, batch-duration EWMA per bucket key.
         self._targets: dict[Hashable, float] = {}
         self._ewma_ms: dict[tuple, float] = {}
+        # What the close rule reads (event loop only). Per bucket: the
+        # device time of a launch (from the later of its launch and the
+        # previous completion on its replica to its own completion; the
+        # device runs them in order), a LOW estimate of the recent samples:
+        # an end is only ever seen late, and a launch thought longer than
+        # it is leaves the device dry. Model-wide: the time from a batch's
+        # close to its launch, waits for a slot left out, a HIGH estimate:
+        # a batch staged late costs far more than one staged early. Per
+        # replica: the launches staged and not yet complete ([predicted ms,
+        # staged at, still running as far as known], oldest first) and when
+        # the last one completed. And the batches closed but not yet staged
+        # (batch id -> predicted ms).
+        self._device_ms: dict[tuple, float] = {}
+        self._device_seen: dict[tuple, deque[float]] = {}
+        self._stage_ms: float | None = None
+        self._stage_seen: deque[float] = deque(maxlen=_RECENT)
+        self._launches: list[deque[list]] = []
+        self._last_done: list[float] = []
+        self._closed_ms: dict[int, float] = {}
         # Hot-path metric handles, prebound once (ISSUE 5 satellite: the
         # per-request/per-flush f-string format + registry lookup was pure
         # overhead on every submit).
@@ -159,6 +214,13 @@ class ModelBatcher:
             f"deadline_exceeded_total{{model={name}}}")
         self._c_batches = metrics.counter(f"batches_total{{model={name}}}")
         self._c_items = metrics.counter(f"items_total{{model={name}}}")
+        # The same items by when they joined their batch: while the group
+        # accumulated, or at the close (the final drain, after the wait for
+        # a place). The close's share is what an earlier close turns away.
+        self._c_joined = {
+            j: metrics.counter(
+                f"batcher_batch_items_total{{model={name},joined={j}}}")
+            for j in ("accumulate", "close")}
         self._c_batch_errors = metrics.counter(
             f"batch_errors_total{{model={name}}}")
         self._c_retries = metrics.counter(f"batch_retries_total{{model={name}}}")
@@ -200,12 +262,12 @@ class ModelBatcher:
         self._queues: dict[Hashable, asyncio.Queue[_Request]] = {}
         self._tasks: dict[Hashable, asyncio.Task] = {}
         self._dispatch_tasks: set[asyncio.Task] = set()
-        self._inflight: asyncio.Semaphore | None = None
+        self._gate: AdmissionGate | None = None
         self._staging: list[SlotPool] = []
         self._g_replica_inflight: list[Any] = []
         self.arena: AssemblyArena | None = None
         self.depth = 0
-        self._admission_cap = 0
+        self._device_cap = 0
         self._inflight_now = 0
         self._inflight_peak = 0
         self._idle_event: asyncio.Event | None = None
@@ -238,9 +300,10 @@ class ModelBatcher:
         pcfg = self.pipeline_cfg
         if self.deferred:
             # Deferred mode: enqueue's shm-slot wait is the device
-            # backpressure; the semaphore bounds batches between assembly
-            # and enqueue exactly as before.
-            self._admission_cap = max(1, self.cfg.max_inflight)
+            # backpressure; the gate bounds batches between assembly and
+            # enqueue by count, exactly as before (nothing is measured of a
+            # device section that is not in this process).
+            self._device_cap = max(1, self.cfg.max_inflight)
             self._staging = []
             self.arena = None
             self.depth = 0
@@ -270,12 +333,19 @@ class ModelBatcher:
                     # configured depth (per-device execution streams
                     # serialize safely there).
                     self.depth = 1
-            self._staging = [SlotPool(self.depth) for _ in range(n_rep)]
-            # Replica-aware admission: depth-k batches per DEVICE section
-            # plus the assembly ramp — with 8 replicas the pipeline admits
-            # 8x the single-chip batch count, which is what keeps every
-            # chip's staging slots full instead of one chip's (ISSUE 7).
-            self._admission_cap = self.depth * n_rep + pcfg.assemble_ahead
+            # depth-k launches per device section; past that, by the device
+            # time queued there (_section_is_short), not by count.
+            self._staging = [
+                SlotPool(self.depth, spare=pcfg.assemble_ahead,
+                         spare_ok=lambda r=r: self._section_is_short(r))
+                for r in range(n_rep)]
+            self._launches = [deque() for _ in range(n_rep)]
+            self._last_done = [0.0] * n_rep
+            # Replica-aware admission: depth-k batches per DEVICE section —
+            # with 8 replicas the pipeline admits 8x the single-chip batch
+            # count, which is what keeps every chip's staging slots full
+            # instead of one chip's (ISSUE 7).
+            self._device_cap = self.depth * n_rep
             # Per-chip occupancy gauges (docs/PERFORMANCE.md "Serving on
             # the mesh"), prebound once per replica.
             self._g_replica_inflight = [
@@ -289,7 +359,7 @@ class ModelBatcher:
             arena_slots = pcfg.arena_slots or (self.depth + pcfg.assemble_ahead)
             self.arena = (AssemblyArena(self.model, arena_slots, self.metrics)
                           if self._use_arena else None)
-        self._inflight = asyncio.Semaphore(self._admission_cap)
+        self._gate = AdmissionGate(self._close_wait_s)
         self._idle_event = asyncio.Event()
         self._idle_event.set()
 
@@ -342,7 +412,7 @@ class ModelBatcher:
         request is admitted either way). ``ctx`` (obs.TraceContext)
         collects the request's queue/phase spans when the HTTP layer is
         tracing it."""
-        if not self._running or self._inflight is None:
+        if not self._running or self._gate is None:
             raise RuntimeError(f"batcher for {self.model.name} not started")
         if self._pending >= self.cfg.max_queue:
             self._c_shed.inc()
@@ -535,6 +605,107 @@ class ModelBatcher:
         self._ewma_ms[bucket] = ewma
         self._g_ewma.set(ewma)
 
+    # -- the close rule (event loop) ------------------------------------------
+    def _predicted_ms(self, bucket: tuple) -> float:
+        """Device time a launch of ``bucket`` is expected to take; the
+        longest launch seen where this bucket has not run yet."""
+        return self._device_ms.get(bucket) or max(
+            self._device_ms.values(), default=0.0)
+
+    def _queued_ms(self, replica: int, now: float) -> float:
+        """Device time still queued on a replica: its staged launches, less
+        what the oldest has run (never more than was predicted for it)."""
+        staged = self._launches[replica]
+        if not staged:
+            return 0.0
+        head_ms, head_at, _ = staged[0]
+        ran_ms = (now - max(head_at, self._last_done[replica])) * 1e3
+        return sum(e[0] for e in staged) - min(head_ms, max(0.0, ran_ms))
+
+    def _observe_launch_end(self, bucket: tuple, replica: int, entry: list,
+                            t_launched: float, t_done: float) -> None:
+        """A launch's fetch has returned. The device runs a replica's
+        launches in order, so those staged before it have ended too, even
+        where their fetch is still on its way back (two fetch threads under
+        one GIL: a 5 ms launch's fetch can overtake the 300 ms launch's it
+        ran behind); they count as ended from here on. Only a launch whose
+        predecessors' ends were all seen gives a sample of device time:
+        from the later of its launch and the previous end to its own."""
+        in_order = entry[2]
+        for ahead in self._launches[replica]:
+            if ahead is entry:
+                break
+            ahead[0], ahead[2] = 0.0, False
+            in_order = False
+        if in_order:
+            seen = self._device_seen.setdefault(bucket, deque(maxlen=_RECENT))
+            seen.append(
+                (t_done - max(t_launched, self._last_done[replica])) * 1e3)
+            self._device_ms[bucket] = _second(seen)
+        self._last_done[replica] = max(t_done, self._last_done[replica])
+
+    def _fetch_timed(self, outputs: Any) -> tuple[Any, float]:
+        """``runtime.fetch``, and when it returned by the fetch thread's own
+        clock: with the host busy the event loop comes to it much later."""
+        return self.runtime.fetch(outputs), time.perf_counter()
+
+    def _section_is_short(self, replica: int) -> bool:
+        """Less than ``depth - 1`` full launches of device time are queued
+        behind the running one: the section is full by count, not by time
+        (never so at depth 1, which serialises it)."""
+        return self._queued_ms(replica, time.perf_counter()) < \
+            (self.depth - 1) * max(self._device_ms.values(), default=0.0)
+
+    def _reserve_ms(self) -> float:
+        """How long before the device runs dry a batch must close: twice
+        what a batch has lately taken from its close to its launch."""
+        return 2.0 * (self._stage_ms or 0.0)
+
+    def _close_wait_s(self, held: int, full: bool) -> float:
+        """Seconds until the next batch may close, ``held`` being closed and
+        not yet through the pipeline (AdmissionGate). Before a batch has
+        been measured, and in deferred mode where none is, by count: the
+        device section (``max_inflight`` there). After: when the replica
+        that runs dry first has no more than the reserve queued, batches
+        closed but not yet staged going to the emptiest — or at once if
+        the batch is ``full``: waiting adds nothing to it, and staging it
+        early is what hides a slow host. Never more than ``assemble_ahead``
+        batches past the device section."""
+        if self._stage_ms is None:
+            return 0.0 if held < self._device_cap else math.inf
+        if held >= self._device_cap + self.pipeline_cfg.assemble_ahead:
+            return math.inf
+        if full:
+            return 0.0
+        now = time.perf_counter()
+        queued = [self._queued_ms(r, now) for r in range(len(self._launches))]
+        for ms in self._closed_ms.values():
+            queued[queued.index(min(queued))] += ms
+        return max(0.0, min(queued) - self._reserve_ms()) / 1e3
+
+    def _close_limit(self, n: int, queued: int, group: Hashable) -> int:
+        """How far the close may grow a batch of ``n`` with ``queued`` more
+        waiting: to the largest bucket, unless that takes the batch past
+        the edge of the bucket it occupies into a launch that is dearer per
+        item than filling this one, by the device time measured per bucket
+        plus the staging every launch pays (128 outstanding items must not
+        ride 64 at a time in a 256-wide launch that costs the same full or
+        empty). A bucket not measured yet is not held against the batch."""
+        max_bucket = max(self.cfg.batch_buckets)
+        total = min(n + queued, max_bucket)
+        here = self.model.bucket_for(n, group=group)
+        there = self.model.bucket_for(total, group=group)
+        if there == here:
+            return max_bucket
+        ms_here = self._device_ms.get(here)
+        ms_there = self._device_ms.get(there)
+        if ms_here is None or ms_there is None:
+            return max_bucket
+        stage_ms = self._stage_ms or 0.0
+        if (ms_there + stage_ms) / total <= (ms_here + stage_ms) / here[0]:
+            return max_bucket
+        return here[0]
+
     # -- accumulation (event loop) ------------------------------------------
     async def _group_loop(self, group: Hashable, q: asyncio.Queue) -> None:
         max_bucket = max(self.cfg.batch_buckets)
@@ -582,26 +753,32 @@ class ModelBatcher:
                 if adaptive:
                     self._aimd_update(group, tgt, len(batch), target_n,
                                       timer_flush, pressure=not q.empty())
-                # Backpressure: admission bounds batches inside the pipeline
-                # (depth x replicas in the device section + assemble_ahead
-                # ramping through assembly); the group task itself waits
-                # here. The wait is bounded by the earliest per-request
+                # Backpressure, and the close: the gate opens when the
+                # device time still queued has fallen to the reserve
+                # (_close_wait_s), so the group task waits HERE, with its
+                # membership still open, until the device is about to need
+                # the batch — not two launches earlier, frozen behind
+                # assembled batches while later arrivals go to the batch
+                # behind. The wait is bounded by the earliest per-request
                 # deadline in the batch (P3): a request that dies behind
                 # slow in-flight work fails fast AT its deadline, instead of
                 # being discovered dead only when capacity finally frees.
                 batch = self._expire_dead(batch, adjust_pending=True)
+
+                def is_full() -> bool:
+                    return len(batch) + q.qsize() >= max_bucket
+
                 while batch:
                     earliest = min((r.deadline_at for r in batch
                                     if r.deadline_at is not None),
                                    default=None)
                     if earliest is None:
-                        await self._inflight.acquire()
+                        await self._gate.acquire(None, is_full)
                         break
                     slot_wait = earliest - time.perf_counter()
                     if slot_wait > 0:
                         try:
-                            await asyncio.wait_for(self._inflight.acquire(),
-                                                   slot_wait)
+                            await self._gate.acquire(slot_wait, is_full)
                             break
                         except asyncio.TimeoutError:
                             pass
@@ -623,12 +800,16 @@ class ModelBatcher:
                         r.future.set_exception(err)
                 self._maybe_idle()
                 raise
-            # Adaptive drain: anything that queued while we waited (deadline or
-            # admission) would only wait longer — fold it into this batch up
-            # to the largest bucket. This makes batch size track device speed
-            # instead of deadline x arrival-rate (SURVEY.md §7 hard-part 2).
-            while len(batch) < max_bucket and not q.empty():
-                batch.append(q.get_nowait())
+            # The close: anything that queued while we waited (deadline or
+            # admission) would only wait longer — fold it into this batch, up
+            # to the largest bucket or the edge _close_limit holds it to. This
+            # makes batch size track device speed instead of deadline x
+            # arrival-rate (SURVEY.md §7 hard-part 2).
+            limit = self._close_limit(len(batch), q.qsize(), group)
+            while len(batch) < limit and not q.empty():
+                late = q.get_nowait()
+                late.at_close = True
+                batch.append(late)
             self._pending -= len(batch)
             self._g_queue_depth.set(self._pending)
             live = [r for r in batch if not r.future.cancelled()]
@@ -637,7 +818,7 @@ class ModelBatcher:
             # settled in the batch-wide decrement.
             live = self._expire_dead(live, adjust_pending=False)
             if not live:
-                self._inflight.release()
+                self._gate.release()
                 self._maybe_idle()
                 continue
             now = time.perf_counter()
@@ -651,8 +832,13 @@ class ModelBatcher:
                 if r.ctx is not None:
                     r.ctx.span("queue", now_wall - wait_ms / 1e3, now_wall,
                                tid=self.model.name)
+            # What the gate reads next: this batch's device time is queued
+            # from now on, before it has reached a replica.
+            self._closed_ms[bid] = self._predicted_ms(
+                self.model.bucket_for(len(live), group=group))
+            self._gate.poke()
             task = asyncio.get_running_loop().create_task(
-                self._dispatch(live, group, bid))
+                self._dispatch(live, group, bid, t_slot))
             self._dispatch_tasks.add(task)
             task.add_done_callback(self._dispatch_tasks.discard)
             task.add_done_callback(lambda _t: self._maybe_idle())
@@ -664,7 +850,7 @@ class ModelBatcher:
         return self._bid_seq
 
     async def _dispatch(self, reqs: list[_Request], group: Hashable,
-                        bid: int | None = None) -> None:
+                        bid: int, t_close: float) -> None:
         """Run one batch through the pipeline; on failure, retry/split per
         config before failing futures. Failure is contained to this batch
         either way: the group task and server keep serving."""
@@ -675,7 +861,7 @@ class ModelBatcher:
         self._g_inflight.set(self._inflight_now)
         try:
             try:
-                await self._execute(reqs, group, released, bid)
+                await self._execute(reqs, group, released, bid, t_close)
             except Exception as e:
                 log.exception("batch dispatch failed for %s", name)
                 self._c_batch_errors.inc()
@@ -698,8 +884,9 @@ class ModelBatcher:
         finally:
             self._inflight_now -= 1
             self._g_inflight.set(self._inflight_now)
+            self._closed_ms.pop(bid, None)  # failed before it was staged
             if not released[0]:
-                self._inflight.release()
+                self._gate.release()
 
     async def _acquire_staging(self, reqs: list[_Request]) -> tuple[int | None, int | None]:
         """Pick a replica and take one of its depth-k staging slots, bounded
@@ -752,7 +939,8 @@ class ModelBatcher:
                 self._staging[replica].in_use)
 
     async def _execute(self, reqs: list[_Request], group: Hashable,
-                       released: list[bool], bid: int | None = None) -> None:
+                       released: list[bool], bid: int | None = None,
+                       t_close: float | None = None) -> None:
         """Assemble + run + postprocess one batch through the stage
         pipeline, resolving futures on success. Raises on failure WITHOUT
         failing futures — the caller owns the retry policy."""
@@ -817,7 +1005,7 @@ class ModelBatcher:
                 t2 = time.perf_counter()
                 mark("h2d", t1, t2)
                 if not released[0]:
-                    self._inflight.release()
+                    self._gate.release()
                     released[0] = True
                 np_out = await out_fut
                 t3 = time.perf_counter()
@@ -833,8 +1021,12 @@ class ModelBatcher:
                 replica, slot = await self._acquire_staging(reqs)
                 if replica is None:
                     return  # every request expired; nothing to run
-                trace_mark("tpuserve.staging_wait", t1, time.perf_counter(),
+                t_staged = time.perf_counter()
+                trace_mark("tpuserve.staging_wait", t1, t_staged,
                            model=name, batch=bid, replica=replica)
+                entry = [self._closed_ms.pop(bid, None)
+                         or self._predicted_ms(bucket), t_staged, True]
+                self._launches[replica].append(entry)
                 try:
                     if self.injector is not None:
                         delay = self.injector.delay_s("slow_dispatch", name)
@@ -854,10 +1046,15 @@ class ModelBatcher:
                     # queue + MXU time; it no longer absorbs other batches'
                     # transfer waits the way the shared-pool path did
                     # (docs/PERFORMANCE.md "Phase semantics").
-                    np_out = await self.stages.run(
-                        name, "fetch", self.runtime.fetch, outputs, span=span)
+                    np_out, t_ready = await self.stages.run(
+                        name, "fetch", self._fetch_timed, outputs, span=span)
                     t3 = time.perf_counter()
                     mark("compute", t2, t3)
+                    self._observe_launch_end(bucket, replica, entry, t2,
+                                             t_ready)
+                    self._stage_seen.append(
+                        ((t2 - (t_close or t0)) - (t_staged - t1)) * 1e3)
+                    self._stage_ms = _second(self._stage_seen, largest=True)
                     if replica < len(self._c_device_seconds):
                         self._c_device_seconds[replica].inc(t3 - t2)
                     if self.device_time_cb is not None:
@@ -865,7 +1062,9 @@ class ModelBatcher:
                         # (dispatch-to-ready) is what models compete for.
                         self.device_time_cb(t3 - t2)
                 finally:
+                    self._launches[replica].remove(entry)
                     self._release_staging(replica, slot)
+                    self._gate.poke()  # less is queued: decide again
         finally:
             if lease is not None:
                 # Safe only now: the fetch completing proves the device is
@@ -879,6 +1078,9 @@ class ModelBatcher:
         t4 = time.perf_counter()
         mark("postproc", t3, t4)
         self._c_items.inc(len(reqs))
+        n_close = sum(r.at_close for r in reqs)
+        self._c_joined["close"].inc(n_close)
+        self._c_joined["accumulate"].inc(len(reqs) - n_close)
         # Feed the adaptive scheduler's per-bucket duration model (tracked
         # even with adaptive off: the gauge is useful on its own).
         self._observe_batch_duration(bucket, (t4 - t0) * 1e3)
@@ -984,7 +1186,17 @@ class ModelBatcher:
         (docs/PERFORMANCE.md "Reading the metrics")."""
         out = {
             "mode": "deferred" if self.deferred else "direct",
-            "admission": self._admission_cap,
+            "admission": self._device_cap + (
+                0 if self.deferred else self.pipeline_cfg.assemble_ahead),
+            # What the close rule goes by (ms): a batch closes when the
+            # device time queued has fallen to the reserve.
+            "close": {
+                "reserve_ms": round(self._reserve_ms(), 2),
+                "stage_ms": (None if self._stage_ms is None
+                             else round(self._stage_ms, 2)),
+                "device_ms": {repr(b): round(v, 2)
+                              for b, v in self._device_ms.items()},
+            },
             "inflight": self._inflight_now,
             "inflight_peak": self._inflight_peak,
             "adaptive": {

@@ -149,8 +149,10 @@ class PipelineConfig:
     # Batches in flight per replica inside [h2d..fetch] ("staging slots");
     # 0 derives it from each model's max_inflight.
     depth: int = 0
-    # Extra batches admitted past the device depth so assembly runs ahead of
-    # the device (the pipeline's ramp): admission = depth*replicas + this.
+    # The most batches that may be closed (or staged) past depth*replicas.
+    # How many are is derived, not set: a batch closes when the device time
+    # still queued has fallen to twice its measured staging time, so where
+    # staging is small against a launch none is closed ahead (batcher.py).
     assemble_ahead: int = 2
     # Preallocated assembly buffers per (model, bucket); 0 sizes it to
     # depth + assemble_ahead. Acquires beyond this fall back to one-shot

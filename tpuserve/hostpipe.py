@@ -7,7 +7,7 @@ driver record of that design, 2026-07-31 on an earlier installation, had HTTP
 serving at 606 img/s beside an executable that ran 10,628; not re-measured on
 today's code). Clockwork (P3) treats each serving stage as deterministic-duration
 work that must be scheduled, not queued behind unrelated stages; Orca (P4)
-re-forms work at stage granularity. This module provides the three primitives
+re-forms work at stage granularity. This module provides the primitives
 the batcher composes into that staged pipeline:
 
 - :class:`StageExecutors` — one dedicated thread pool per pipeline stage
@@ -23,6 +23,9 @@ the batcher composes into that staged pipeline:
   The batcher uses one per replica to keep a configurable depth-k of batches
   in flight on the device ([h2d..fetch]); the deferred pool uses it for its
   per-worker shared-memory batch slots (the shared staging-slot abstraction).
+- :class:`AdmissionGate` — the batcher's admission: a FIFO gate whose
+  opening is a function of the device time still queued, so a batch closes
+  when the device is about to need it rather than when a count frees.
 
 Knobs live in ``config.PipelineConfig`` (``[pipeline]`` TOML); semantics and
 how to read the metrics are documented in docs/PERFORMANCE.md.
@@ -57,17 +60,30 @@ class SlotPool:
     slot frees, bounded by ``timeout`` (raises ``asyncio.TimeoutError``);
     ``close`` wakes every waiter with :class:`SlotsClosed`. Construction
     touches no event loop, so pools can be built from executor threads (the
-    deferred pool spawns workers off-loop)."""
+    deferred pool spawns workers off-loop).
 
-    def __init__(self, n: int) -> None:
+    ``spare`` further slots are handed out only while ``spare_ok()`` holds:
+    the batcher's device section counts ``n`` launches, but a launch of a
+    few milliseconds queued behind a long one must not hold one of them
+    against the batch the device needs next, so past ``n`` it goes by the
+    device time queued (``spare_ok``), up to ``n + spare`` batches."""
+
+    def __init__(self, n: int, spare: int = 0,
+                 spare_ok: Callable[[], bool] | None = None) -> None:
         self.capacity = max(1, n)
-        self._free: list[int] = list(range(self.capacity))
+        self._spare = spare if spare_ok is not None else 0
+        self._spare_ok = spare_ok
+        self._free: list[int] = list(range(self.capacity + self._spare))
         self._waiters: deque[asyncio.Future] = deque()
         self._closed = False
 
     @property
     def in_use(self) -> int:
-        return self.capacity - len(self._free)
+        return self.capacity + self._spare - len(self._free)
+
+    def _has_room(self) -> bool:
+        return self.in_use < self.capacity or (
+            bool(self._free) and self._spare_ok())
 
     def _wake_one(self) -> None:
         while self._waiters:
@@ -77,7 +93,7 @@ class SlotPool:
                 return
 
     def try_acquire(self) -> int | None:
-        if self._closed or not self._free:
+        if self._closed or not self._has_room():
             return None
         return self._free.pop()
 
@@ -85,7 +101,7 @@ class SlotPool:
         while True:
             if self._closed:
                 raise SlotsClosed("slot pool closed")
-            if self._free:
+            if self._has_room():
                 return self._free.pop()
             fut = asyncio.get_running_loop().create_future()
             self._waiters.append(fut)
@@ -96,7 +112,7 @@ class SlotPool:
                     self._waiters.remove(fut)
                 # Pass the baton: if a release woke us concurrently with the
                 # timeout, another waiter must get the free slot we abandon.
-                if self._free:
+                if self._has_room():
                     self._wake_one()
                 raise
 
@@ -112,6 +128,69 @@ class SlotPool:
             fut = self._waiters.popleft()
             if not fut.done():
                 fut.set_exception(SlotsClosed("slot pool closed"))
+
+
+class AdmissionGate:
+    """FIFO gate whose opening is computed, not counted.
+
+    The batcher's admission: ``wait_s(held, eager)`` says how long until
+    the next place may be taken (0 = now, ``inf`` = not before a release),
+    from the device time still queued and the time a batch takes to be
+    staged, so a batch closes when the device is about to need it; ``eager``
+    is what the waiter's own ``eager()`` answers at that moment (its batch
+    is full: waiting adds nothing). Places go to waiters in arrival order,
+    ONE per decision: the one admitted changes what ``wait_s`` reads (it
+    registers its batch) and calls :meth:`poke` for the next decision.
+    Event-loop-side only, like :class:`SlotPool`."""
+
+    def __init__(self, wait_s: Callable[[int, bool], float]) -> None:
+        self._wait_s = wait_s
+        self.held = 0
+        self._waiters: deque[tuple[asyncio.Future, Callable[[], bool]]] = \
+            deque()
+        self._timer: asyncio.TimerHandle | None = None
+
+    def poke(self) -> None:
+        """Decide again: what ``wait_s`` reads has changed."""
+        if self._timer is not None:
+            self._timer.cancel()
+            self._timer = None
+        while self._waiters and self._waiters[0][0].done():
+            self._waiters.popleft()  # gave up (timeout, cancellation)
+        if not self._waiters:
+            return
+        fut, eager = self._waiters[0]
+        wait = self._wait_s(self.held, eager())
+        if wait <= 0:
+            self.held += 1
+            self._waiters.popleft()
+            fut.set_result(None)
+        elif wait != float("inf"):
+            self._timer = asyncio.get_running_loop().call_later(
+                wait, self.poke)
+
+    async def acquire(self, timeout_s: float | None = None,
+                      eager: Callable[[], bool] = lambda: False) -> None:
+        """Take one place, waiting at most ``timeout_s`` (raises
+        ``asyncio.TimeoutError``)."""
+        if not self._waiters and self._wait_s(self.held, eager()) <= 0:
+            self.held += 1
+            return
+        fut = asyncio.get_running_loop().create_future()
+        self._waiters.append((fut, eager))
+        self.poke()
+        try:
+            await asyncio.wait_for(fut, timeout_s)
+        except (asyncio.TimeoutError, asyncio.CancelledError):
+            if fut.done() and not fut.cancelled():
+                self.release()  # admitted as we gave up: pass it on
+            else:
+                self.poke()
+            raise
+
+    def release(self) -> None:
+        self.held -= 1
+        self.poke()
 
 
 class StageExecutors:
