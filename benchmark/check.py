@@ -1,4 +1,5 @@
-"""The comparison that decides `correct`: the served class probabilities of a
+"""The comparison that decides `correct` for a family that brings none of its
+own (`reference/<family>.py` `compare`): the served class probabilities of a
 seeded sample against the family's plain reference.
 
 The statistic is ONE number over the whole sample, not a maximum: the RMS of
@@ -44,3 +45,17 @@ def between_texts_rms(ref_logp: np.ndarray) -> float:
     all answer alike could not show a swapped or repeated lane."""
     c = centred(np.asarray(ref_logp, np.float64))
     return float(np.sqrt(np.mean(np.square(c - c.mean(axis=0, keepdims=True)))))
+
+
+def compare_class_probs(served: list, ref_logp: np.ndarray, cfg: dict) -> tuple[float, str]:
+    """What a family that gives no `compare` of its own is held to: the served
+    answers' class probabilities against the reference's log-probabilities by
+    `rms_centred_logit_error`. Returns the statistic and the line a run prints
+    beside its limit."""
+    n_classes = int(cfg["assumed"]["num_classes"])
+    probs = np.stack([probs_by_class(a, n_classes) for a in served])
+    stat = rms_centred_logit_error(probs, ref_logp)
+    apart = between_texts_rms(ref_logp)
+    return stat, (f"rms_centred_logit_error={stat:.6g} over {len(served)} texts x {n_classes} "
+                  f"classes (the reference's texts answer {apart:.4g} apart, so a swapped "
+                  f"lane reads about {apart * 2 ** 0.5:.4g})")

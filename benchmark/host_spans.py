@@ -60,13 +60,10 @@ attributed (all idle time is `unknown`, and the note says so).
 from __future__ import annotations
 
 import bisect
-import glob
-import os
 import statistics
 
-from benchmark.trace_reduce import DEVICE_PLANE, MODULES_LINE, OPS_LINE, find_xplane, gaps_of
+from benchmark.trace_reduce import DEVICE_PLANE, MODULES_LINE, OPS_LINE, gaps_of
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PREFIX = "tpuserve."
 LONG_GAP_NS = 1_000_000  # shorter gaps are not named
 BATCH_STATES = ("h2d", "assemble", "staging_wait", "slot_wait", "accumulate")
@@ -74,15 +71,6 @@ STATES = BATCH_STATES + ("tokenize", "no_request", "unknown")
 MAX_SHIFT = 4            # launches or modules cut off by the tracer's edges
 MATCH_SLACK_NS = 50_000  # rounding of the two planes' timestamps
 MAX_OFFSET_NS = 10_000_000  # the planes of one session were seen 0.8 to 2.9 ms apart
-
-
-def find_run_xplane() -> str | None:
-    """The xplane of the run in progress: the newest one under
-    .benchmark_work/*/trace/ (run.py hands readers no path; a run removes
-    its work directory at its end unless --keep)."""
-    hits = [p for p in (find_xplane(os.path.join(work, "trace")) for work in
-                        glob.glob(os.path.join(REPO, ".benchmark_work", "*"))) if p]
-    return max(hits, key=os.path.getmtime) if hits else None
 
 
 # -- reading -------------------------------------------------------------------
@@ -328,11 +316,11 @@ def analyse(profile, window_s: float) -> dict | None:
 def for_run(run: dict) -> dict | None:
     """What the readers of one run share, computed once: None where the run
     has no device trace (`run["trace"]` is None on the CPU rehearsal, so no
-    device metric comes from a CPU run), no xplane is found, or the program
-    wrote no spans."""
+    device metric comes from a CPU run), no `run["xplane"]` (the trace's
+    file, from run.py), or the program wrote no spans."""
     if "host_spans" not in run:
         trace = run.get("trace")
-        path = find_run_xplane() if trace else None
+        path = run.get("xplane") if trace else None
         if path:
             from jax.profiler import ProfileData
 

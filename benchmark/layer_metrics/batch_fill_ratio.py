@@ -9,11 +9,10 @@ from benchmark import prom
 
 
 def read(run: dict):
-    d = run["metrics_delta"]
-    items = sum(prom.select(d, "items_total", model=run["model_name"]).values())
+    d, model = run.get("metrics_delta") or {}, run.get("model_name")
+    items = sum(prom.select(d, "items_total", model=model).values())
     slots = 0.0
-    for key, n in prom.select(d, "runtime_variant_batches_total",
-                              model=run["model_name"]).items():
+    for key, n in prom.select(d, "runtime_variant_batches_total", model=model).items():
         m = re.search(r'variant="(\d+)x', key)
         if m:
             slots += n * int(m.group(1))

@@ -3,4 +3,5 @@ of the two scrapes. The harness fails the run when it is above 0."""
 
 
 def read(run: dict):
-    return float(run["compiles_in_window"])
+    n = run.get("compiles_in_window")
+    return None if n is None else float(n)

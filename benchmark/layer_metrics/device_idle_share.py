@@ -3,7 +3,7 @@ the union of the device's operation intervals over the window, in percent."""
 
 
 def read(run: dict):
-    t = run["trace"]
+    t = run.get("trace")
     if not t or not t["window_s"]:
         return None
     return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

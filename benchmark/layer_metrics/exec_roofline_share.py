@@ -10,8 +10,8 @@ from benchmark.trace_reduce import bucket_of
 
 
 def read(run: dict):
-    top = (run["trace"] or {}).get("top_module")
-    peaks = run["peaks"]
+    top = (run.get("trace") or {}).get("top_module")
+    peaks = run.get("peaks")
     bucket = bucket_of(top, run["sizes"]["d_model"]) if top else None
     if not peaks or not bucket:
         return None
@@ -19,7 +19,7 @@ def read(run: dict):
     ops, nbytes = run["flops"].ops_and_bytes(run["sizes"], batch, seq)
     t_ops = ops / peaks["bf16_flops_per_s"]
     t_bytes = nbytes / peaks["hbm_bytes_per_s"]
-    run["notes"].append(
+    run.setdefault("notes", []).append(
         f"exec_roofline_share: bucket ({batch}, {seq}) bound by "
         f"{'compute' if t_ops >= t_bytes else 'memory'} "
         f"(ops {ops:.4g} -> {t_ops * 1e3:.3f} ms, bytes {nbytes:.4g} -> "

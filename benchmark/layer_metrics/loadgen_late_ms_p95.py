@@ -6,5 +6,5 @@ from benchmark.loadgen import percentile
 
 
 def read(run: dict):
-    late = run["load"].late_ms
+    late = run["load"].late_ms if run.get("load") else None
     return percentile(late, 0.95) if late else None

@@ -9,5 +9,5 @@ from benchmark.loadgen import percentile
 
 
 def read(run: dict):
-    lat = run["load"].latencies_ms
+    lat = run["load"].latencies_ms if run.get("load") else None
     return percentile(lat, 0.95) if lat else None

@@ -5,7 +5,7 @@ device."""
 
 
 def read(run: dict):
-    items = run["load"].items_in_window
-    if not items or run["server_cpu_s"] is None:
+    items = run["load"].items_in_window if run.get("load") else 0
+    if not items or run.get("server_cpu_s") is None:
         return None
     return run["server_cpu_s"] * 1e3 / items

@@ -8,5 +8,5 @@ from benchmark import prom
 
 
 def read(run: dict):
-    return prom.histogram_quantile(run["metrics_delta"], "latency_ms", 0.5,
-                                   model=run["model_name"], phase="slot_wait")
+    return prom.histogram_quantile(run.get("metrics_delta") or {}, "latency_ms", 0.5,
+                                   model=run.get("model_name"), phase="slot_wait")

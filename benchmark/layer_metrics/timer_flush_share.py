@@ -8,8 +8,8 @@ from benchmark import prom
 
 
 def read(run: dict):
-    flushes = prom.select(run["metrics_delta"], "batcher_flushes_total",
-                          model=run["model_name"])
+    flushes = prom.select(run.get("metrics_delta") or {}, "batcher_flushes_total",
+                          model=run.get("model_name"))
     total = sum(flushes.values())
     if total <= 0:
         return None

@@ -16,7 +16,7 @@ from benchmark import prom
 
 
 def read(run: dict):
-    d, model = run["metrics_delta"], run["model_name"]
+    d, model = run.get("metrics_delta") or {}, run.get("model_name")
     tokens = prom.select(d, "ingest_tokens_total", model=model)
     slots = 0.0
     for key, n in prom.select(d, "runtime_variant_batches_total", model=model).items():

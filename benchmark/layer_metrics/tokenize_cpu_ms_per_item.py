@@ -9,7 +9,7 @@ from benchmark import prom
 
 
 def read(run: dict):
-    d, model = run["metrics_delta"], run["model_name"]
+    d, model = run.get("metrics_delta") or {}, run.get("model_name")
     cpu = prom.select(d, "ingest_tokenize_cpu_seconds_total", model=model)
     items = sum(prom.select(d, "items_total", model=model).values())
     if not cpu or items <= 0:

@@ -53,9 +53,19 @@ def percentile(values: list[float], q: float) -> float:
     return s[min(len(s) - 1, max(0, int(-(-q * len(s) // 1)) - 1))]
 
 
-async def _post(session, url: str, req, deadline: float) -> tuple[bool, str]:
+def answers_of(obj: dict) -> list:
+    """The answers one response holds, for a traffic kind that does not say
+    (`traffic/<kind>.py` `answers_of`): a classifier's `{"results": [one per
+    text]}`, or one text's own `{"top_k": [...]}`; anything else holds none."""
+    if "results" in obj:
+        return obj["results"]
+    return [obj] if "top_k" in obj else []
+
+
+async def _post(session, url: str, req, deadline: float,
+                answers=answers_of) -> tuple[bool, str]:
     """One POST, bounded by `deadline` (perf_counter). Correct means 200 and
-    as many results as texts were sent."""
+    as many answers, by the traffic kind's `answers`, as items were sent."""
     import aiohttp
 
     left = deadline - time.perf_counter()
@@ -73,8 +83,7 @@ async def _post(session, url: str, req, deadline: float) -> tuple[bool, str]:
     except aiohttp.ClientError as e:
         return False, type(e).__name__
     try:
-        obj = json.loads(raw)
-        n = len(obj["results"]) if "results" in obj else int("top_k" in obj)
+        n = len(answers(json.loads(raw)))
     except (ValueError, TypeError, KeyError):
         return False, "bad_answer"
     return (True, "") if n == req.items else (False, "wrong_item_count")
@@ -110,11 +119,12 @@ class _Recorder:
 
 
 async def closed_loop(url: str, requests: list, clients: int, warmup_s: float,
-                      seconds: float, drain_s: float, on_window=None) -> LoadResult:
+                      seconds: float, drain_s: float, on_window=None,
+                      answers=answers_of) -> LoadResult:
     """`clients` callers, each with one request out, through warm-up and
     window without a pause. A request is due when its caller's previous
     answer arrived. `on_window(which)` is awaited at the window's start
-    ("start") and end ("end")."""
+    ("start") and end ("end"). `answers` is `_post`'s."""
     import aiohttp
 
     start = time.perf_counter()
@@ -133,7 +143,7 @@ async def closed_loop(url: str, requests: list, clients: int, warmup_s: float,
             req = requests[cursor % len(requests)]
             cursor += 1
             sent = time.perf_counter()
-            ok, why = await _post(session, url, req, deadline)
+            ok, why = await _post(session, url, req, deadline, answers)
             done = time.perf_counter()
             rec.record(req, due, sent, done, ok, why)
             due = done
@@ -146,11 +156,12 @@ async def closed_loop(url: str, requests: list, clients: int, warmup_s: float,
 
 
 async def open_loop(url: str, warm: list, warm_due, requests: list, due,
-                    seconds: float, drain_s: float, on_window=None) -> LoadResult:
+                    seconds: float, drain_s: float, on_window=None,
+                    answers=answers_of) -> LoadResult:
     """Requests sent at their due times whatever the server does. `warm_due`
     are offsets in [0, warmup_s) before the window and `due` offsets in
     [0, seconds) inside it; the warm-up runs into the window without a
-    pause."""
+    pause. `answers` is `_post`'s."""
     import aiohttp
 
     warmup_s = float(warm_due[-1]) + 0.01 if len(warm_due) else 0.0
@@ -165,7 +176,7 @@ async def open_loop(url: str, warm: list, warm_due, requests: list, due,
 
     async def one(session, req, at: float) -> None:
         sent = time.perf_counter()
-        ok, why = await _post(session, url, req, deadline)
+        ok, why = await _post(session, url, req, deadline, answers)
         rec.record(req, at, sent, time.perf_counter(), ok, why)
 
     conn = aiohttp.TCPConnector(limit=0)

@@ -10,7 +10,10 @@
 # Four runs: closed loop; open loop, traced; a deliberate over-budget run,
 # which must END with a failing result line and a non-zero exit, not hang;
 # and the command as the driver gives it, which must refuse to run without an
-# accelerator and print no result line.
+# accelerator and print no result line. Then one run for every file of
+# benchmark/rehearsals/: a later PR that brings a family or a traffic kind
+# adds its rehearsal there, as data (its arguments, the metrics its line must
+# and must not hold, the counters that must have moved in its window).
 set -u
 cd "$(dirname "$0")/.."
 export JAX_PLATFORMS=cpu
@@ -23,7 +26,8 @@ run() {  # name, expected exit code, arguments...
   local rc=$?
   grep '^\[benchmark' "$out" | tail -n 6
   if [ "$rc" -ne "$want" ]; then echo "REHEARSAL FAILED: $name exited $rc, expected $want"; fail=1; fi
-  LAST=$(tail -n 1 "$out"); rm -f "$out"
+  LAST=$(tail -n 1 "$out"); MOVED=$(grep 'counters that moved in the window' "$out" | tail -n 1)
+  rm -f "$out"
 }
 
 common=(--workload rehearsal --config rehearsal-tiny --rehearse --seconds 3)
@@ -68,7 +72,7 @@ if pgrep -f benchmark/serve_child.py >/dev/null; then echo "REHEARSAL FAILED: a 
 
 echo "== the driver's command on a machine with no accelerator"
 out=$(mktemp)
-env -u JAX_PLATFORMS timeout 300 python3 benchmark/run.py --workload bert-base-s512.docs-closed \
+env -u JAX_PLATFORMS timeout 300 python3 benchmark/run.py --workload bert-base-s512.docs-closed-64 \
   --seed 1 --seconds 3 --trace 0 >"$out" 2>/dev/null
 rc=$?
 if [ "$rc" -eq 0 ] || tail -n 1 "$out" | grep -q '^{'; then
@@ -77,5 +81,24 @@ else
   echo "ok: refused without an accelerator (exit $rc, no result line)"
 fi
 rm -f "$out"
+
+for f in benchmark/rehearsals/*.json; do
+  [ -e "$f" ] || continue
+  name=$(basename "$f" .json)
+  echo "== rehearsals/$name: $(python3 -c 'import json, sys; print(json.load(open(sys.argv[1]))["why"])' "$f")"
+  mapfile -t extra < <(python3 -c 'import json, sys; print("\n".join(json.load(open(sys.argv[1]))["args"]))' "$f")
+  run "$name" 0 --workload "rehearsal-$name" --rehearse --seconds 3 "${extra[@]}"
+  python3 - "$f" "$LAST" "$MOVED" <<'PY' || fail=1
+import json, sys
+want, r, moved = json.load(open(sys.argv[1])), json.loads(sys.argv[2]), sys.argv[3]
+assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > 0, r
+assert r["device"]["platform"] == "cpu"
+assert set(want.get("metrics", [])) <= set(r["metrics"]), (want["metrics"], sorted(r["metrics"]))
+assert not set(want.get("not_metrics", [])) & set(r["metrics"]), sorted(r["metrics"])
+for c in want.get("counters", []):
+    assert f"{c}=" in moved, f"{c} did not move in the window: {moved}"
+print("ok: result line, metrics and counters as the rehearsal's file says")
+PY
+done
 [ "$fail" -eq 0 ] && echo "REHEARSAL PASSED" || echo "REHEARSAL FAILED"
 exit $fail

@@ -84,18 +84,29 @@ def toml_value(v) -> str:
     raise TypeError(f"cannot write {v!r} to TOML")
 
 
-def write_serve_toml(path: str, cfg: dict, port: int, weights: str,
+def write_serve_toml(path: str, cfg: dict, port: int, weights: str | None,
                      options_extra: dict) -> None:
     """The cell's serve file from the configuration file: the `serve.server`
-    keys at the top, one [[model]] from `serve.model`, and its options from
-    the published keys that `serve.options_from` names."""
+    keys at the top, each table of `serve.tables` (`genserve`, `pipeline`, ...:
+    scalars and lists), one [[model]] from `serve.model`, and its options from
+    the published keys that `serve.options_from` names. `weights` and
+    `num_classes` are written where there are any: no checkpoint means the
+    program draws its weights itself, and a family that generates has no
+    classes."""
     serve = cfg["serve"]
-    model = {"name": MODEL_NAME, "family": cfg["family"], "weights": weights,
-             "num_classes": cfg["assumed"]["num_classes"], **serve["model"]}
+    model = {"name": MODEL_NAME, "family": cfg["family"]}
+    if weights is not None:
+        model["weights"] = weights
+    if "num_classes" in cfg.get("assumed", {}):
+        model["num_classes"] = cfg["assumed"]["num_classes"]
+    model.update(serve["model"])
     options = {opt: cfg[key] for opt, key in serve.get("options_from", {}).items()}
     options.update(options_extra)
     lines = [f"{k} = {toml_value(v)}" for k, v in
              {"host": "127.0.0.1", "port": port, **serve.get("server", {})}.items()]
+    for name, table in serve.get("tables", {}).items():
+        lines += ["", f"[{name}]"]
+        lines += [f"{k} = {toml_value(v)}" for k, v in table.items()]
     lines += ["", "[[model]]"]
     lines += [f"{k} = {toml_value(v)}" for k, v in model.items()]
     lines += ["", "[model.options]"]
@@ -228,14 +239,14 @@ def load_cell(args) -> Cell:
                 family.sizes_from_config(cfg))
 
 
-def start_server(cell: Cell, args, work: str, trace_ms: float, state: dict):
-    """Weights and vocabulary from the seed, then the server child, which
-    restores them through its ordinary `weights =` path. Returns the server,
-    its base URL, the drawn parameters and the vocabulary."""
+def checkpoint_from_seed(cell: Cell, args, state: dict):
+    """How weights reach the server and the reference where the family's
+    module has no `prepare` of its own: every tensor drawn from the seed in
+    one jitted call (`make_params`), written as a checkpoint
+    (`save_checkpoint`) that the server restores through its ordinary
+    `weights =` path, and handed to the reference as they are."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     params = jax.block_until_ready(cell.family.make_params(args.seed, cell.sizes))
     say(f"weights drawn from seed {args.seed}")
     # Under TMPDIR: up to 0.7 GB that is read once and removed as soon as the
@@ -244,13 +255,42 @@ def start_server(cell: Cell, args, work: str, trace_ms: float, state: dict):
         tempfile.mkdtemp(prefix="tpuserve-benchmark-"), "weights")
     cell.family.save_checkpoint(weights_dir, params, cell.sizes, cell.cfg)
     say("checkpoint written")
+    return weights_dir, {}, params
+
+
+def start_server(cell: Cell, args, work: str, trace_ms: float, state: dict):
+    """Weights and vocabulary, then the server child. The family's `prepare`
+    says how the weights reach the server (a checkpoint's directory, or None
+    where the program draws them itself by the recipe the configuration
+    states) and what the reference gets (`ref`: whatever the family's
+    `reference_answers` takes). Returns the server, its base URL, `ref` and
+    the vocabulary."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if hasattr(cell.family, "prepare"):
+        weights_dir, family_options, ref = cell.family.prepare(
+            args.seed, cell.sizes, cell.cfg, work)
+        say("weights: " + (f"checkpoint {weights_dir}" if weights_dir else
+                           "none handed over, the program draws them by assumed.weights"))
+    else:
+        weights_dir, family_options, ref = checkpoint_from_seed(cell, args, state)
     vocab, options_extra = cell.traffic.prepare(work, cell.cfg)
     port = free_port()
     toml = os.path.join(work, "serve.toml")
-    write_serve_toml(toml, cell.cfg, port, weights_dir, options_extra)
+    write_serve_toml(toml, cell.cfg, port, weights_dir, {**family_options, **options_extra})
     server = state["server"] = Server(work, toml, trace_ms, args.rehearse)
     say(f"server child {server.proc.pid} started on port {port}")
-    return server, f"http://127.0.0.1:{port}", params, vocab
+    return server, f"http://127.0.0.1:{port}", ref, vocab
+
+
+def answers_of(cell: Cell):
+    """What the answers of one response are: the traffic kind's
+    `answers_of(obj) -> list`, else the classifier's (`loadgen.answers_of`)."""
+    from benchmark import loadgen
+
+    return getattr(cell.traffic, "answers_of", loadgen.answers_of)
 
 
 def make_load(cell: Cell, args, vocab, url: str, seconds: float, on_window):
@@ -268,11 +308,11 @@ def make_load(cell: Cell, args, vocab, url: str, seconds: float, on_window):
         warm_due = traffic.due_times(mix, args.seed + 1, n_warm, warmup_s)
         say(f"traffic made: {n_warm} + {n_window} requests at their due times")
         return loadgen.open_loop(url, warm, warm_due, requests, due, seconds,
-                                 drain_s, on_window)
+                                 drain_s, on_window, answers_of(cell))
     requests = traffic.make_requests(mix, args.seed, vocab, int(mix["pool_requests"]))
     say(f"traffic made: a pool of {len(requests)} requests")
     return loadgen.closed_loop(url, requests, int(mix["clients"]), warmup_s,
-                               seconds, drain_s, on_window)
+                               seconds, drain_s, on_window, answers_of(cell))
 
 
 def read_device(cell: Cell, args, base: str, bud) -> tuple[dict, dict | None]:
@@ -294,29 +334,46 @@ def read_device(cell: Cell, args, base: str, bud) -> tuple[dict, dict | None]:
             "memory_peak_bytes": 0}, peaks
 
 
-def check_outputs(cell: Cell, sample, ref_logp, url: str, bud) -> bool:
-    """Send the sample, compare every class probability with the reference,
-    print the number compared beside its limit."""
-    import numpy as np
+def read_memory_peak(base: str, bud) -> int:
+    """Peak bytes the fullest chip had committed, from `/stats`
+    `topology.devices[i].memory` (the program reads `device.memory_stats()`
+    when asked): its live buffers at their peak plus what the runtime reserved
+    for the loaded programs' scratch. On the TPU `peak_bytes_in_use` counts
+    buffers alone (parameters, inputs, outputs); a program's temporaries are
+    `peak_bytes_reserved` (seen on the chip, PR 24: a program whose compiler
+    analysis says 3,221,257,728 bytes of temp moved `peak_bytes_reserved` by
+    3,221,241,856 and `peak_bytes_in_use` by 2 MB). A peak never falls, so
+    read once, when the window has closed. 0 where the backend reports no
+    memory (the CPU)."""
+    _status, raw = http_get(f"{base}/stats", timeout=bud.wait_s("/stats", 10))
+    return max((int(m.get("peak_bytes_in_use", 0)) + int(m.get("peak_bytes_reserved", 0))
+                for m in (d.get("memory") or {} for d in json.loads(raw)["topology"]["devices"])),
+               default=0)
+
+
+def check_outputs(cell: Cell, sample, reference, url: str, bud, state: dict) -> bool:
+    """Send the sample, compare its answers with the reference's by the
+    family's `compare(served, reference, cfg) -> (statistic, line)` (else
+    `check.compare_class_probs`), print the number compared beside its
+    limit."""
     from benchmark import check as check_mod
 
-    n_classes = cell.sizes["num_classes"]
+    answers = answers_of(cell)
     served = []
     for req, _texts in sample:
         status, obj = http_post_json(url, req.body, bud.wait_s("a check request", 60))
         if status != 200:
             raise RunFailed(f"check request answered {status}: {obj}")
-        answers = obj["results"] if "results" in obj else [obj]
-        if len(answers) != req.items:
-            raise RunFailed(f"check request of {req.items} texts got {len(answers)} answers")
-        served += [check_mod.probs_by_class(a, n_classes) for a in answers]
-    stat = check_mod.rms_centred_logit_error(np.stack(served), ref_logp)
-    apart = check_mod.between_texts_rms(ref_logp)
+        got = answers(obj)
+        if len(got) != req.items:
+            raise RunFailed(f"check request of {req.items} items got {len(got)} answers")
+        served += got
+    compare = getattr(cell.family, "compare", check_mod.compare_class_probs)
+    stat, line = compare(served, reference, cell.cfg)
     limit = float(cell.cfg["check"]["limit"])
-    say(f"check: rms_centred_logit_error={stat:.6g} limit={limit:.6g} over "
-        f"{len(served)} texts x {n_classes} classes -> "
-        f"{'ok' if stat <= limit else 'NOT CORRECT'} (the reference's texts answer "
-        f"{apart:.4g} apart, so a swapped lane reads about {apart * 2 ** 0.5:.4g})")
+    state["check_line"] = (f"check: {line} limit={limit:.6g} -> "
+                           f"{'ok' if stat <= limit else 'NOT CORRECT'}")
+    say(state["check_line"])
     return stat <= limit
 
 
@@ -334,11 +391,30 @@ def wait_for_trace(server: Server, work: str, bud) -> dict:
     return info
 
 
+def named_idle_gaps(run_info: dict, top: int = 10) -> list | None:
+    """The device's longest idle gaps, each named by where the batch that
+    ended it spent most of the gap (`slot_wait`, `staging_wait`, `tokenize`,
+    ...: host_spans.py has the rule), longest first, for `breakdown.idle_gaps`.
+    None where the trace holds no span of the program: the gaps then stay
+    `host:unknown`."""
+    from benchmark import host_spans
+
+    hs = host_spans.for_run(run_info)
+    if not hs:
+        return None
+    named = [[max(g["parts_ms"], key=g["parts_ms"].get) if g["parts_ms"] else "unknown",
+              g["ms"] / 1e3] for g in hs["gaps"]]
+    # Gaps under a millisecond have no name of their own: the rule sums them as `unknown`.
+    short = [["unknown", s] for _n, s in run_info["trace"]["idle_gaps"]
+             if s < host_spans.LONG_GAP_NS / 1e9]
+    return (named + short)[:top]
+
+
 def per_layer_metrics(cell: Cell, args, work: str, trace_info: dict, device: dict,
                       run_info: dict) -> tuple[dict, dict | None]:
     """Reduce the trace, then let each of the cell's per-layer readers take its
     number from the run. A reader that finds nothing returns None and its
-    metric is left out of the line."""
+    metric is left out of the line; the run prints which were."""
     from benchmark import trace_reduce
 
     xplane = trace_reduce.find_xplane(os.path.join(work, "trace"))
@@ -355,14 +431,21 @@ def per_layer_metrics(cell: Cell, args, work: str, trace_info: dict, device: dic
         breakdown = {"device_ops": reduced["device_ops"],
                      "idle_gaps": reduced["idle_gaps"]}
         say("modules in the trace: " + json.dumps(reduced["modules"]))
-    run_info.update(trace=reduced, notes=[])
-    out = {}
+    run_info.update(trace=reduced, xplane=xplane, notes=[])
+    out, left_out = {}, []
     for m in spec.cell_metrics(cell.bench, "per_layer", cell.listed):
         v = spec.load_module("layer_metrics", m["name"]).read(run_info)
         if v is not None:
             out[m["name"]] = {"value": v, "unit": m["unit"]}
+        else:
+            left_out.append(m["name"])
     for note in run_info["notes"]:
         say(note)
+    if left_out:
+        say("per_layer readers that found nothing to read in this run, left out of the "
+            "line: " + ", ".join(left_out))
+    if breakdown:
+        breakdown["idle_gaps"] = named_idle_gaps(run_info) or breakdown["idle_gaps"]
     return out, breakdown
 
 
@@ -382,8 +465,8 @@ def run(args, bud: budget_mod.Budget, state: dict) -> dict:
     work = state["work"] = os.path.join(WORK, cell.name)
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)
-    server, base, params, vocab = start_server(cell, args, work, trace_ms, state)
-    url = f"{base}/v1/models/{MODEL_NAME}:classify"
+    server, base, ref, vocab = start_server(cell, args, work, trace_ms, state)
+    url = f"{base}/v1/models/{MODEL_NAME}:{mix.get('verb', 'classify')}"
 
     # While it starts: the traffic and the reference's answers for the sample.
     from benchmark import loadgen, prom
@@ -404,17 +487,20 @@ def run(args, bud: budget_mod.Budget, state: dict) -> dict:
 
     load_coro = make_load(cell, args, vocab, url, seconds, on_window)
     sample = cell.traffic.make_check(mix, args.seed, vocab)
-    ref_logp = cell.family.class_log_probs(
-        params, cell.traffic.check_inputs(sample, vocab), cell.sizes)
-    del params
-    say(f"reference computed for {len(ref_logp)} texts")
+    inputs = cell.traffic.check_inputs(sample, vocab)
+    reference = getattr(cell.family, "reference_answers", None) or cell.family.class_log_probs
+    reference = reference(ref, inputs, cell.sizes)
+    del ref
+    say(f"reference computed for {len(inputs)} texts")
 
     wait_ready(server, base, bud, reserve_s=after_ready_s)
     say("server ready")
-    shutil.rmtree(os.path.dirname(state["weights"]), ignore_errors=True)  # restored
+    if state.get("weights"):
+        shutil.rmtree(os.path.dirname(state["weights"]), ignore_errors=True)  # restored
     device, peaks = read_device(cell, args, base, bud)
     state["device"] = device
-    check_ok = check_outputs(cell, sample, ref_logp, url, bud)
+    check_ok = check_outputs(cell, sample, reference, url, bud, state)
+    del reference
 
     bud.need(warmup_s + seconds + drain_s + 10.0, "warm-up, window and drain")
     load = asyncio.run(asyncio.wait_for(
@@ -423,19 +509,23 @@ def run(args, bud: budget_mod.Budget, state: dict) -> dict:
         f"items_in_window={load.items_in_window} errors={load.errors}"
         + (" (request pool reused)" if load.wrapped else ""))
     delta = prom.delta(scrapes["end"], scrapes["start"])
+    moved: dict[str, float] = {}
+    for k, v in delta.items():
+        family = k.partition("{")[0]
+        if family.endswith("_total") and v > 0:
+            moved[family] = moved.get(family, 0.0) + v
+    say("counters that moved in the window: "
+        + ", ".join(f"{k}={v:.6g}" for k, v in sorted(moved.items())))
     compiles = sum(prom.select(delta, "runtime_compiles_total").values())
     if compiles > 0:
         say(f"NOT CORRECT: {compiles:.0f} program(s) compiled inside the window")
 
     trace_info = wait_for_trace(server, work, bud) if trace_ms > 0 else None
+    device["memory_peak_bytes"] = read_memory_peak(base, bud)
     rc = server.stop(bud.wait_s("the server to drain", 40.0, reserve_s=5.0))
     if rc != 0:
         raise RunFailed(f"the server did not drain to exit 0 (exit {rc}):\n{server.log_tail()}")
-    with open(os.path.join(work, "device.json"), encoding="utf-8") as f:
-        device = state["device"] = json.load(f)
-    # The result line's `device` holds the contract's keys and no others.
     say(f"server exited 0; device {device}")
-    device.pop("memory_stats", None)
     floor = 0.25 * peaks["hbm_bytes"] if peaks else 0
     say(f"memory: peak {device['memory_peak_bytes'] / 2**30:.2f} GiB on the fullest "
         f"chip (live buffers + reserved program scratch); a cell's floor is "
@@ -468,6 +558,7 @@ def run(args, bud: budget_mod.Budget, state: dict) -> dict:
         say(f"work directory kept: {work}")
     else:
         shutil.rmtree(work, ignore_errors=True)
+    state["check_line"] += f"; compiles_in_window={compiles:.0f} limit=0"
     result = {"correct": bool(check_ok and compiles == 0),
               "attempted": load.attempted, "failed": load.failed,
               "metrics": metrics_out, "device": device}
@@ -524,6 +615,8 @@ def main() -> int:
     finally:
         if state.get("weights"):  # half a gigabyte; never left behind
             shutil.rmtree(os.path.dirname(state["weights"]), ignore_errors=True)
+    if state.get("check_line"):  # each number compared beside its limit, here too
+        print(f"[benchmark] {state['check_line']}", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return rc
 

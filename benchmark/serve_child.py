@@ -1,14 +1,13 @@
 """The server child: the program's own entry, `tpuserve.cli.main(["serve",
-...])` (what `python -m tpuserve serve` runs), plus the two things only the
-process that holds the chip can give:
-
-- on SIGUSR1, a profiler trace of `--trace-ms` of whatever is running, written
-  to `--out`/trace, with the traced window's length by the host clock in
-  `--out`/trace_done.json;
-- after the server has drained and returned, `--out`/device.json: platform,
-  kind, device count and the peak bytes in use on the fullest device.
+...])` (what `python -m tpuserve serve` runs), plus the one thing only the
+process that holds the chip can give: on SIGUSR1, a profiler trace of
+`--trace-ms` of whatever is running, written to `--out`/trace, with the traced
+window's length by the host clock in `--out`/trace_done.json.
 
 It changes nothing of the program and passes it no option it does not have.
+The device's kind, count and peak memory are read from the program's own
+`/stats` (run.py `read_device`, `read_memory_peak`): the exit-time
+`device.json` this file wrote until PR 27 held the same bytes (chip, PR 27).
 """
 
 from __future__ import annotations
@@ -49,16 +48,6 @@ def _trace(out: str, trace_ms: float) -> None:
                {"ok": False, "error": f"{type(e).__name__}: {e}"})
 
 
-def _committed(stats: dict) -> int:
-    """Peak bytes a chip had committed: its live buffers at their peak plus
-    what the runtime reserved for the loaded programs' scratch. On the TPU
-    `peak_bytes_in_use` counts buffers alone (parameters, inputs, outputs); a
-    program's temporaries are `peak_bytes_reserved` (seen on the chip, PR 24:
-    a program whose compiler analysis says 3,221,257,728 bytes of temp moved
-    `peak_bytes_reserved` by 3,221,241,856 and `peak_bytes_in_use` by 2 MB)."""
-    return int(stats.get("peak_bytes_in_use", 0)) + int(stats.get("peak_bytes_reserved", 0))
-
-
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", required=True)
@@ -72,19 +61,7 @@ def main() -> int:
 
     from tpuserve.cli import main as tpuserve_main
 
-    rc = tpuserve_main(["serve", "--config", args.config])
-
-    import jax
-
-    devs = jax.local_devices()
-    stats = [d.memory_stats() or {} for d in devs]
-    fullest = max(stats, key=_committed)
-    _write(os.path.join(args.out, "device.json"),
-           {"platform": devs[0].platform, "kind": devs[0].device_kind,
-            "count": len(jax.devices()),
-            "memory_peak_bytes": _committed(fullest),
-            "memory_stats": fullest})
-    return int(rc or 0)
+    return int(tpuserve_main(["serve", "--config", args.config]) or 0)
 
 
 if __name__ == "__main__":

@@ -199,7 +199,7 @@ def test_counter_readers():
         assert spec.load_module("layer_metrics", name).read(bare) is None
 
 
-def test_the_recording_from_the_chip(monkeypatch):
+def test_the_recording_from_the_chip():
     """The cut-down recording (fixtures/recorded_v5e_spans.md) against facts
     computed apart from the reader (recorded_v5e_spans.json)."""
     path = os.path.join(FIXTURES, "recorded_v5e_spans.xplane.pb")
@@ -227,9 +227,9 @@ def test_the_recording_from_the_chip(monkeypatch):
     assert gap["parts_ms"]["staging_wait"] == pytest.approx(facts["longest_gap_staging_wait_ms"])
     assert gap["parts_ms"]["h2d"] == pytest.approx(facts["longest_gap_h2d_ms"])
     assert set(gap["parts_ms"]) == {"staging_wait", "h2d", "unknown"}
-    # through the readers, as a run would: for_run finds the file itself
-    monkeypatch.setattr(host_spans, "find_run_xplane", lambda: path)
-    run = {"trace": trace_reduce.reduce_profile(ProfileData.from_file(path)), "notes": []}
+    # through the readers, as a run would: run.py hands them the trace's file
+    run = {"trace": trace_reduce.reduce_profile(ProfileData.from_file(path)), "xplane": path,
+           "notes": []}
     read = {n: spec.load_module("layer_metrics", n).read(run) for n in IDLE}
     share = spec.load_module("layer_metrics", "device_idle_share").read(run)
     assert sum(read.values()) == pytest.approx(share)

@@ -114,19 +114,19 @@ def test_a_later_pr_adds_only_files_and_entries(scratch_tree):
     assert spec.load_module("layer_metrics", "answer").read({}) == 42.0
     # the new metric lists its cell, so the old cells do not report it
     assert "answer" not in [m["name"] for m in spec.cell_metrics(
-        b, "per_layer", "bert-base-s512.docs-closed")]
+        b, "per_layer", "bert-base-s512.docs-closed-64")]
     assert before == json.dumps(spec.load_benchmark(), sort_keys=True)
 
 
 def test_a_metric_that_lists_its_cells_is_left_out_elsewhere():
     b = spec.load_benchmark()
     names = lambda cell: [m["name"] for m in spec.cell_metrics(b, "end_to_end", cell)]  # noqa: E731
-    assert "latency_p50_ms" in names("bert-base-s512.docs-closed")
+    assert "latency_p50_ms" in names("bert-base-s512.docs-closed-64")
     assert "latency_p50_ms" not in names("a-cell-it-does-not-list")
     assert "latency_p50_ms" in names(None), "a run that is no cell reports every metric"
     for cell in (w["name"] for w in b["workloads"]):
         assert {"setup_s", "items_per_s", "latency_p50_ms"} <= set(names(cell))
         per_layer = spec.cell_metrics(b, "per_layer", cell)
-        assert len(per_layer) == 10 and all(m["moves"] in names(cell) for m in per_layer)
+        assert len(per_layer) >= 10 and all(m["moves"] in names(cell) for m in per_layer)
         assert "request_p95_ms" in [m["name"] for m in per_layer], "the tail, per layer: it has no bound"
     assert "latency_p95_ms" not in names(None), "no tail is held to a bound (PERF.md, section 2)"

@@ -119,12 +119,17 @@ def reduce_profile(profile, window_s: float | None = None, top: int = 10) -> dic
             per_device.append(ops)
         mods.sort()
         starts = [s for s, _d, _n in mods]
-        for s, d, name in mods:
-            m = modules.setdefault(name, {"launches": 0, "device_s": 0.0,
-                                          "durations": [], "shapes": {}})
+        for i, (s, d, name) in enumerate(mods):
+            m = modules.setdefault(name, {"launches": 0, "device_s": 0.0, "durations": [],
+                                          "whole": [], "shapes": {}})
             m["launches"] += 1
             m["device_s"] += d / 1e9
             m["durations"].append(d / 1e9)
+            # A chip runs one program at a time, so the tracer's edges can cut
+            # only the first and the last launch on its line: a launch with
+            # another before it and another after it lies whole in the window.
+            if 0 < i < len(mods) - 1:
+                m["whole"].append(d / 1e9)
         for s, d, shape in shaped:  # time per 3-d result shape, by the launch it ran in
             i = bisect.bisect_right(starts, s) - 1
             if i >= 0 and s < mods[i][0] + mods[i][1]:
@@ -133,9 +138,15 @@ def reduce_profile(profile, window_s: float | None = None, top: int = 10) -> dic
     for m in modules.values():  # the shapes most time went to, longest first
         shapes = m["shapes"]
         m["shapes"] = [list(k) for k in sorted(shapes, key=shapes.get, reverse=True)[:8]]
-        # The median launch: the tracer's edges cut the first and the last
-        # launch short, and a mean would count the pieces as whole launches.
-        m["launch_s"] = statistics.median(m.pop("durations"))
+        # The median launch, over the launches that lie whole inside the
+        # window: the tracer's edges cut the first and the last launch short,
+        # and with four launches in the window a median over all of them is
+        # half made of the pieces (PERF.md, Findings, PR 27). Where no launch
+        # is known to be whole the median is over all, and
+        # `whole_launches` = 0 says so.
+        durations, whole = m.pop("durations"), m.pop("whole")
+        m["whole_launches"] = len(whole)
+        m["launch_s"] = statistics.median(whole or durations)
     if not per_device:
         return None
     lo = min(s for ops in per_device for s, _ in ops)
@@ -160,8 +171,8 @@ def reduce_profile(profile, window_s: float | None = None, top: int = 10) -> dic
         "top_module": top_module,
         "device_ops": [[n, t] for n, t in sorted(
             op_time.items(), key=lambda kv: -kv[1])[:top]],
-        # Gaps cannot be named yet: the program's spans are not on the
-        # trace's clock (PERF.md Open questions, the `tracing` issue).
+        # Unnamed here: run.py names them from the program's spans where the
+        # trace holds any (host_spans.py), and keeps these where it does not.
         "idle_gaps": [["host:unknown", g] for g in gaps[:top]],
     }
 
