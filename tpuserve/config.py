@@ -258,7 +258,7 @@ class GenserveConfig:
     The static-bucket batcher locks a batch for its whole run — correct for
     one-shot classifiers, wrong for multi-step generative work. With this
     block enabled, models whose family implements the generative contract
-    (``tpuserve.genserve.GenerativeModel``: textgen, sd15) serve through an
+    (``tpuserve.genserve.GenerativeModel``: textgen, decoder, sd15) serve through an
     iteration-level engine instead (Orca, PAPERS.md P4): the active batch
     re-forms every model iteration, finished sequences retire immediately,
     queued requests fold into free slots mid-flight, and past-deadline
@@ -288,7 +288,7 @@ class GenserveConfig:
     stream_drain_s: float = 5.0
     # Paged KV cache (ISSUE 18, docs/PERFORMANCE.md "Paged KV & chunked
     # prefill"; PagedAttention/vLLM): families that implement the paged
-    # contract (textgen) allocate KV as fixed-size pages behind a
+    # contract (textgen, decoder) allocate KV as fixed-size pages behind a
     # device-resident block table instead of one dense worst-case-ctx slab
     # per slot. Pages are reserved at fold-in (prompt + decode budget) and
     # returned on retire/evict/disconnect; exhaustion sheds 503 with a

@@ -54,7 +54,8 @@ from tpuserve.genserve.arena import SlotArena, SlotInfo
 from tpuserve.genserve.model import GenerativeModel
 from tpuserve.genserve.pages import PageLedger
 from tpuserve.hostpipe import StageExecutors
-from tpuserve.obs import GEN_STREAM_REASONS, PRIORITIES, Metrics
+from tpuserve.obs import (GEN_STREAM_REASONS, PRIORITIES, Metrics, trace_mark,
+                          trace_span)
 from tpuserve.utils.locks import new_lock
 from tpuserve.utils.retrace import allow_transfers, host_fetch
 
@@ -180,6 +181,7 @@ class GenEngine:
         self.pages: PageLedger | None = None
         self._pps = 0            # block-table width (pages per max-ctx slot)
         self._prefill_chunk = 0  # static chunk width of the prefill program
+        self._ring_tokens = 0    # window-ring length (0: the family has none)
         if self.paging:
             pt = self.gcfg.kv_page_tokens
             self._pps = int(model.kv_pages_per_slot(pt))
@@ -189,7 +191,11 @@ class GenEngine:
                     f"{model.cfg.name}: [genserve] kv_pages={n_pages} cannot "
                     f"cover one max-context request ({self._pps} pages + the "
                     "sentinel)")
-            self.pages = PageLedger(n_pages, pt)
+            # A family with window layers keeps one ring a slot beside the
+            # full pages (ISSUE 28): the same ledger owns both pools.
+            self._ring_tokens = int(model.kv_ring_tokens())
+            self.pages = PageLedger(
+                n_pages, pt, rings=self.slots + 1 if self._ring_tokens else 0)
             self._prefill_chunk = int(
                 model.kv_prefill_chunk(self.gcfg.prefill_chunk))
         # High-water active-slot mark (bench's max_concurrent_slots).
@@ -244,6 +250,19 @@ class GenEngine:
         self._c_prefill_chunks = metrics.counter(
             f"gen_prefill_chunks_total{{model={name}}}")
         self._c_kv_shed = metrics.sched_shed_counter(name, "kv_pressure")
+        # What the two phases processed, and what the caches held while they
+        # did (ISSUE 28): per-step sums, so a window's mean is a ratio of
+        # two deltas (lanes = decode tokens / iterations).
+        self._c_prefill_tokens = metrics.counter(
+            f"gen_prefill_tokens_total{{model={name}}}")
+        self._c_decode_tokens = metrics.counter(
+            f"gen_decode_tokens_total{{model={name}}}")
+        self._c_pages_held = metrics.counter(
+            f"gen_kv_page_steps_total{{model={name}}}")
+        self._c_rings_held = metrics.counter(
+            f"gen_kv_ring_steps_total{{model={name}}}")
+        self._g_kv_rings_free = metrics.gauge(
+            f"gen_kv_rings_free{{model={name}}}")
         self._default_priority = getattr(model.cfg, "priority", "interactive")
         self._h_qwait = {p: metrics.queue_wait_histogram(name, p)
                          for p in PRIORITIES}
@@ -348,7 +367,9 @@ class GenEngine:
 
         if self.paging:
             start_struct = jax.ShapeDtypeStruct((), np.int32)
-            pages_struct = jax.ShapeDtypeStruct((self._pps,), np.int32)
+            pages_struct = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                self._cache_row([], 0))
             chunk = self._prefill_chunk
 
             def prefill_fn(params, state, slot, item, start, pages):
@@ -395,7 +416,7 @@ class GenEngine:
             state = self._host_zeros(self._state_struct)
             with self._dispatch_guard():
                 if self.paging:
-                    row = np.arange(1, self._pps + 1, dtype=np.int32)
+                    row = self._cache_row(list(range(1, self._pps + 1)), 1)
                     n_prompt = model.prompt_tokens(item)
                     start = 0
                     while True:
@@ -732,6 +753,7 @@ class GenEngine:
 
     def _update_kv_gauges(self) -> None:
         self._g_replica_kv_free.set(float(self.pages.n_free))
+        self._g_kv_rings_free.set(float(self.pages.n_free_rings))
         peers = [e for e in (self.peers or [self]) if e.pages is not None]
         usable = sum(e.pages.usable for e in peers)
         self._g_kv_pages_free.set(float(sum(e.pages.n_free for e in peers)))
@@ -744,12 +766,16 @@ class GenEngine:
         (the committed-demand term of the admission pressure check)."""
         return sum(r.pages_needed for r in self._pending)
 
-    def _pages_row(self, page_list: "list[int]") -> np.ndarray:
-        """One slot's block-table row: its pages in position order, padded
-        with the sentinel (page 0) past its reservation."""
+    def _cache_row(self, page_list: "list[int]", ring: int) -> Any:
+        """What the prefill program is told of one slot's caches: its
+        block-table row (its pages in position order, padded with the
+        sentinel, page 0, past its reservation) and, for a family with
+        window layers, its ring beside it."""
         row = np.zeros((self._pps,), np.int32)
         row[:len(page_list)] = page_list
-        return row
+        if not self._ring_tokens:
+            return row
+        return {"pages": row, "ring": np.int32(ring)}
 
     def _observe_pages(self, need: int) -> None:
         prev = self._ewma_pages
@@ -813,6 +839,7 @@ class GenEngine:
                     self.device_time_cb(step_ms / 1e3)
                 self._c_iterations.inc()
                 self._c_replica_steps.inc()
+                self._count_step(out)
             except asyncio.CancelledError:
                 raise
             except Exception as e:  # noqa: BLE001 — contained per batch
@@ -820,6 +847,18 @@ class GenEngine:
                 continue
             await self._emit_step_units(out)
             await self._retire(out)
+
+    def _count_step(self, out: dict) -> None:
+        """One step's part of the per-step sums: the lanes that decoded a
+        token in it, the pages and rings reserved while it ran, and whatever
+        the family sums on the device (``observe_step``)."""
+        self.model.observe_step(out)
+        self._c_decode_tokens.inc(sum(
+            1 for s in self.arena.active_slots()
+            if "prefill_next" not in self.arena.peek(s).meta))
+        if self.pages is not None:
+            self._c_pages_held.inc(self.pages.n_reserved)
+            self._c_rings_held.inc(self.pages.n_reserved_rings)
 
     def _dispatch_guard(self):
         """Context for one device-dispatch section: the group's shared
@@ -833,9 +872,12 @@ class GenEngine:
         """One compiled iteration over the slot block + the small host
         fetch of the out pytree. Runs on the fetch stage executor."""
         with self._dispatch_guard():
-            self._state, out = self.runtime.run_program(
-                "step", self._state, replica=self.replica)
-            return host_fetch(out)
+            with trace_span("tpuserve.gen_step", model=self.name,
+                            lanes=self.arena.n_active):
+                self._state, out = self.runtime.run_program(
+                    "step", self._state, replica=self.replica)
+            with trace_span("tpuserve.gen_fetch", model=self.name):
+                return host_fetch(out)
 
     def _insert_sync(self, slot: int, item: Any) -> None:
         with self._dispatch_guard():
@@ -845,7 +887,8 @@ class GenEngine:
 
     def _prefill_sync(self, slot: int, item: Any, start: int,
                       pages_row: np.ndarray) -> None:
-        with self._dispatch_guard():
+        with self._dispatch_guard(), trace_span(
+                "tpuserve.gen_prefill", model=self.name, slot=slot, start=start):
             self._state = self.runtime.run_program(
                 "prefill", self._state, np.int32(slot), item,
                 np.int32(start), pages_row, replica=self.replica)
@@ -859,6 +902,8 @@ class GenEngine:
         await self.stages.run(self.name, "h2d", self._prefill_sync, slot,
                               info.item, start, info.meta["pages_row"])
         self._c_prefill_chunks.inc()
+        self._c_prefill_tokens.inc(
+            min(self._prefill_chunk, info.meta["prefill_n"] - start))
         nxt = start + self._prefill_chunk
         if nxt >= info.meta["prefill_n"]:
             del info.meta["prefill_next"]  # prefill complete: decode owns it
@@ -1005,7 +1050,7 @@ class GenEngine:
                 self._c_deadline.inc()
                 continue
             if self.pages is not None \
-                    and self.pages.n_free < req.pages_needed:
+                    and not self.pages.can_cover(req.pages_needed):
                 # Head-of-line waits for pages to free (strict FIFO —
                 # skipping ahead would starve long-context requests); the
                 # admission-time pressure check bounds how long.
@@ -1030,7 +1075,8 @@ class GenEngine:
                     self._update_kv_gauges()
                     self._observe_pages(req.pages_needed)
                     n_prompt = self.model.prompt_tokens(req.item)
-                    info.meta["pages_row"] = self._pages_row(page_list)
+                    info.meta["pages_row"] = self._cache_row(
+                        page_list, self.pages.ring_of(slot))
                     info.meta["prefill_n"] = n_prompt
                     info.meta["prefill_next"] = 0
                     info.meta["prefill_chunks"] = \
@@ -1047,6 +1093,8 @@ class GenEngine:
                     req.ctx.span("queue", wall - wait_ms / 1e3, wall,
                                  tid=self.name)
                 t0 = time.perf_counter()
+                trace_mark("tpuserve.gen_admit", now, t0, model=self.name,
+                           slot=slot)
                 if self.pages is not None:
                     # Paged fold-in is incremental: the FIRST prompt chunk
                     # lands now, later chunks interleave with decode steps
@@ -1163,6 +1211,8 @@ class GenEngine:
                 if self.breaker is not None:
                     self.breaker.record_success()
                 wall1 = time.time()
+                trace_mark("tpuserve.gen_retire", t0, time.perf_counter(),
+                           model=self.name, slot=slot)
                 if info.ctx is not None:
                     # Retire event: extract + finalize for this slot, the
                     # tail of the request's step-span stack.
@@ -1218,7 +1268,7 @@ class GenEngine:
         state = self._host_zeros(self._state_struct)
         with self._dispatch_guard():
             if self.paging:
-                row = np.arange(1, self._pps + 1, dtype=np.int32)
+                row = self._cache_row(list(range(1, self._pps + 1)), 1)
                 n_prompt = model.prompt_tokens(item)
                 start = 0
                 while True:

@@ -142,6 +142,20 @@ class GenerativeModel(ServingModel):
         lanes scribble there, live lanes never attend through it."""
         raise NotImplementedError
 
+    def kv_ring_tokens(self) -> int:
+        """Host-side: positions in a slot's WINDOW RING, the second cache
+        kind (ISSUE 28): a family with sliding-window layers keeps each
+        slot's last ``window`` positions of those layers in one ring a slot,
+        beside the full pages. The engine's ledger then hands out a ring
+        with the pages and ``prefill_chunk`` is given ``{"pages": row,
+        "ring": index}`` instead of the bare row. 0: no rings (the default)."""
+        return 0
+
+    def observe_step(self, step_out: dict) -> None:
+        """Host-side, after every fetched step: a family that sums counts on
+        the device (experts hit, context read) moves them into its own
+        counters here (``bind_metrics`` bound them). Nothing by default."""
+
     def kv_pages_per_slot(self, page_tokens: int) -> int:
         """Host-side: block-table width — pages covering one slot's
         worst-case context (ceil(max_ctx / page_tokens))."""

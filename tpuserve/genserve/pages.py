@@ -15,6 +15,15 @@ are zeros), so a retired slot can never scribble into pages the ledger has
 already re-handed to a new request. The sentinel's contents are garbage by
 design; no live lane ever attends through it.
 
+TWO CACHE KINDS (ISSUE 28). A model with window layers keeps, beside the
+full pages, the last ``window`` positions of a slot in ONE RING a slot
+(``(rings, window, heads, head_dim)`` a layer, written at ``position %
+window``). The ledger built with ``rings=N`` owns that pool too: ring 0 is
+the rings' sentinel (free and frozen lanes write there), rings 1..N-1 are
+handed out one a slot, in the SAME ``acquire`` that reserves the slot's
+pages and returned by the SAME ``release``: both or neither, so no path can
+leak one kind, and a ring is never double-handed any more than a page is.
+
 Event-loop-side only (the engine's step loop owns all mutation), so there
 is deliberately no lock to witness.
 """
@@ -40,13 +49,21 @@ class PageLedger:
 
     SENTINEL = 0
 
-    def __init__(self, pages: int, page_tokens: int) -> None:
+    def __init__(self, pages: int, page_tokens: int, rings: int = 0) -> None:
         if int(pages) < 2:
             raise ValueError("PageLedger needs >= 2 pages (sentinel + 1)")
         if int(page_tokens) < 1:
             raise ValueError("page_tokens must be >= 1")
+        if int(rings) == 1 or int(rings) < 0:
+            raise ValueError("rings must be 0 (no window layers) or >= 2 "
+                             "(sentinel + 1)")
         self.pages = int(pages)
         self.page_tokens = int(page_tokens)
+        # Window rings [1, rings): one a slot, beside its pages.
+        self.rings = int(rings)
+        self._free_rings: list[int] = list(range(self.rings - 1, 0, -1))
+        self._ring: dict[int, int] = {}          # slot -> its ring
+        self._ring_owner: dict[int, int] = {}    # ring -> owning slot
         # LIFO free-list, popping from the low end first (1, 2, ...).
         self._free: list[int] = list(range(self.pages - 1, 0, -1))
         self._owned: dict[int, list[int]] = {}   # slot -> its pages
@@ -70,6 +87,28 @@ class PageLedger:
     def utilization(self) -> float:
         """Reserved fraction of the usable pool in [0, 1]."""
         return self.n_reserved / self.usable if self.usable else 0.0
+
+    @property
+    def usable_rings(self) -> int:
+        return max(0, self.rings - 1)
+
+    @property
+    def n_free_rings(self) -> int:
+        return len(self._free_rings)
+
+    @property
+    def n_reserved_rings(self) -> int:
+        return len(self._ring_owner)
+
+    def ring_of(self, slot: int) -> int:
+        """The slot's window ring (the sentinel where the pool has none)."""
+        return self._ring.get(slot, self.SENTINEL)
+
+    def can_cover(self, count: int) -> bool:
+        """Whether ``acquire(slot, count)`` would find its pages AND its
+        ring: what admission gates on."""
+        return count <= len(self._free) \
+            and (not self.rings or bool(self._free_rings))
 
     def pages_of(self, slot: int) -> list[int]:
         return list(self._owned.get(slot, ()))
@@ -96,6 +135,16 @@ class PageLedger:
         if count > len(self._free):
             raise IndexError(
                 f"page pool exhausted: need {count}, free {len(self._free)}")
+        if self.rings and not self._free_rings:
+            raise IndexError("ring pool exhausted: every window ring is held")
+        if self.rings:
+            ring = self._free_rings.pop()
+            if ring in self._ring_owner or ring == self.SENTINEL:
+                self._free_rings.append(ring)
+                raise PageCorrupted(
+                    f"ring {ring} is on the free-list AND owned — double-hand")
+            self._ring_owner[ring] = slot
+            self._ring[slot] = ring
         out: list[int] = []
         for _ in range(count):
             page = self._free.pop()
@@ -110,13 +159,22 @@ class PageLedger:
         return out
 
     def release(self, slot: int) -> list[int]:
-        """Return ALL of a slot's pages to the free list; raises
-        PageCorrupted for a slot holding nothing (foreign or double
-        release) or for a page whose owner record disagrees."""
+        """Return ALL of a slot's pages (and its window ring) to the free
+        lists; raises PageCorrupted for a slot holding nothing (foreign or
+        double release) or for a page or ring whose owner record disagrees."""
         pages = self._owned.pop(slot, None)
         if pages is None:
             raise PageCorrupted(
                 f"release of slot {slot} that holds no pages")
+        ring = self._ring.pop(slot, None)
+        if ring is not None:
+            if self._ring_owner.pop(ring, None) != slot:
+                raise PageCorrupted(
+                    f"ring {ring} owner ledger disagrees, released by "
+                    f"slot {slot}")
+            self._free_rings.append(ring)
+        elif self.rings:
+            raise PageCorrupted(f"slot {slot} holds pages but no ring")
         for page in pages:
             owner = self._owner.pop(page, None)
             if owner != slot:
@@ -143,10 +201,14 @@ class PageLedger:
             "reserved": self.n_reserved,
             "usable": self.usable,
             "utilization": round(self.utilization(), 4),
+            **({"rings_reserved": self.n_reserved_rings,
+                "rings_usable": self.usable_rings} if self.rings else {}),
         }
 
     def stats(self) -> dict:
         return {
+            **({"rings": self.rings, "rings_reserved": self.n_reserved_rings}
+               if self.rings else {}),
             "pages": self.pages,
             "usable": self.usable,
             "free": self.n_free,

@@ -1,4 +1,4 @@
-"""Model zoo (SURVEY.md §2 C4): the five benchmark families.
+"""Model zoo (SURVEY.md §2 C4): the families the server can build.
 
 Each family implements the ``ServingModel`` interface in ``base.py``:
 a jittable on-device ``forward`` (with fused resize/normalize preproc and
@@ -13,6 +13,10 @@ Families (BASELINE.json ``configs``):
 - sd15           — Stable Diffusion 1.5 txt2img, fori_loop denoise
 - textgen        — autoregressive prefix-LM text generation (KV-cache
                    decode via the iteration-level engine, ISSUE 9)
+- decoder        — a decoder-only language model built from a published
+                   config.json: window and full attention, routed experts
+                   with a share, two cache kinds (ISSUE 28)
+- toy            — a linear classifier for tests and drills
 """
 
 from __future__ import annotations
@@ -30,6 +34,7 @@ _REGISTRY: dict[str, str] = {
     "efficientdet": "tpuserve.models.efficientdet",
     "sd15": "tpuserve.models.sd15",
     "textgen": "tpuserve.models.textgen",
+    "decoder": "tpuserve.models.decoder",
     "toy": "tpuserve.models.toy",
 }
 

@@ -584,6 +584,12 @@ TENANT_SHED_REASONS = ("tenant_unknown", "tenant_rate_exceeded",
 GEN_STREAM_REASONS = ("done", "disconnect", "deadline_exceeded",
                       "engine_error", "drain", "shutdown")
 
+# Phases on the generation engine's device-side sums (ISSUE 28):
+# moe_tokens_routed_total / moe_experts_hit_total / moe_expert_steps_total /
+# gen_context_tokens_total {model=,phase=}: a prompt chunk's tokens or a
+# decode step's lanes. The device keeps one row of sums a phase.
+GEN_PHASES = ("prefill", "decode")
+
 # Reasons on router_stream_terminated_total{model=,reason=} — the
 # worker-router's stream proxy (tpuserve.workerproc.router): same
 # contract as GEN_STREAM_REASONS, seen from the proxy side ("done" the

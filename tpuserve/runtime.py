@@ -391,8 +391,33 @@ class ModelRuntime:
         # Init/load on the host CPU backend, cast on host, then device_put
         # exactly once per mesh: a host-side numpy cast (ml_dtypes handles
         # bf16) beats dispatching hundreds of tiny convert ops.
+        drawn = self._params_drawn_on_device()
+        if drawn is not None:
+            self.params_per_mesh = drawn
+            return
         self.params_per_mesh = self._shard_onto_meshes(
             self.model.prepare_host_params(self._load_host_params()))
+
+    def _params_drawn_on_device(self) -> "list | None":
+        """Weights by recipe (ISSUE 28): a family with ``device_params``
+        draws its tensors on the chip that serves them, in the served type,
+        so that a model of ten gigabytes never crosses the host. One device a
+        mesh only (a drawn tree has no partition specs), and no quantization
+        of what was never on the host. None where the family has no such
+        hook or declines (a checkpoint is configured, no recipe is set)."""
+        hook = getattr(self.model, "device_params", None)
+        if hook is None or self.cfg.quantize in ("int8", "int8c") \
+                or any(m.devices.size != 1 for m in self.meshes):
+            return None
+        out = []
+        for mesh in self.meshes:
+            params = hook(mesh.devices.flat[0])
+            if params is None:
+                return None
+            out.append(params)
+        log.info("%s: params drawn on the device by the family's recipe",
+                 self.model.name)
+        return out
 
     def _load_host_params(self, verify_integrity: bool = True,
                           require_manifest: bool = False) -> Any:
