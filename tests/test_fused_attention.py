@@ -1,0 +1,268 @@
+"""The whole-sequence attention kernel (`ops.flash_attention.fused_attention`)
+in the Pallas interpreter, the rule that routes BERT's buckets to it
+(`attention_path`), and what the runtime shows of the choice."""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpuserve.config import ModelConfig
+from tpuserve.models import build
+from tpuserve.models.bert import _masked_attention
+from tpuserve.obs import Metrics
+
+# `tpuserve.ops.flash_attention` as an attribute is the function: the package
+# imports it under the module's name.
+fa = importlib.import_module("tpuserve.ops.flash_attention")
+
+B, H, D = 2, 2, 64
+# (bucket, live keys of the first row; the second row is full)
+CASES = [(128, 1), (128, 17), (128, 77), (128, 128),
+         (512, 1), (512, 130), (512, 300), (512, 512)]
+
+
+def _inputs(s: int, n_live: int, dtype=jnp.bfloat16, seed: int = 0):
+    rng = np.random.default_rng(seed + s + n_live)
+    q, k, v = (jnp.asarray(rng.normal(size=(B, s, H, D)), dtype)
+               for _ in range(3))
+    live = np.arange(s)[None, :] < np.array([n_live, s])[:, None]
+    return q, k, v, live, jnp.asarray(np.where(live, 0.0, -1e9), jnp.float32)
+
+
+
+def _float64(q, k, v, live):
+    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
+    sc = np.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    sc = np.where(live[:, None, None, :], sc, -np.inf)
+    p = np.exp(sc - sc.max(-1, keepdims=True))
+    return np.einsum("bhqk,bkhd->bqhd", p / p.sum(-1, keepdims=True), v)
+
+
+@pytest.mark.parametrize("s,n_live", CASES)
+def test_fused_matches_float64_reference(s, n_live):
+    """bf16 in and out: no further from plain float64 attention than the
+    XLA pair it replaces (whose scores leave the first product in bf16)."""
+    q, k, v, live, bias = _inputs(s, n_live)
+    out = fa.fused_attention(q, k, v, live)
+    assert out.dtype == jnp.bfloat16 and out.shape == q.shape
+    want = _float64(q, k, v, live)
+    err = np.abs(np.asarray(out, np.float64) - want)[live]
+    dense = np.abs(np.asarray(_masked_attention(
+        q, k, v, bias[:, None, None, :]), np.float64) - want)[live]
+    assert err.max() < 0.03
+    assert np.sqrt((err ** 2).mean()) <= 1.1 * np.sqrt((dense ** 2).mean())
+
+
+@pytest.mark.parametrize("s,n_live", CASES)
+def test_fused_matches_masked_attention(s, n_live):
+    q, k, v, live, bias = _inputs(s, n_live, seed=1)
+    out = np.asarray(fa.fused_attention(q, k, v, live), np.float32)
+    ref = np.asarray(_masked_attention(q, k, v, bias[:, None, None, :]),
+                     np.float32)
+    np.testing.assert_allclose(out[live], ref[live], atol=0.04)
+
+
+@pytest.mark.parametrize("s,n_live,d", [(128, 40, 64), (512, 200, 64),
+                                        (128, 40, 32)])
+def test_fused_float32_is_exact_to_rounding(s, n_live, d):
+    """float32 operands take the same kernel: the mathematics alone. Head 32
+    has a scale that is no power of two, applied to the scores instead."""
+    q, k, v, live, bias = _inputs(s, n_live, dtype=jnp.float32)
+    q, k, v = (x[..., :d] for x in (q, k, v))
+    out = np.asarray(fa.fused_attention(q, k, v, live), np.float64)
+    np.testing.assert_allclose(out[live], _float64(q, k, v, live)[live],
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("s,n_live", [(128, 1), (128, 77), (512, 300)])
+def test_padded_keys_weigh_exactly_nothing(s, n_live):
+    """Whatever a padded key and its value hold, live rows' answers are the
+    same bits: the weight is 0.0, not small."""
+    q, k, v, live, bias = _inputs(s, n_live)
+    noise = jnp.asarray(np.random.default_rng(7).normal(size=k.shape) * 50,
+                        k.dtype)
+    pad = jnp.asarray(~live)[:, :, None, None]
+    a = fa.fused_attention(q, k, v, live)
+    b = fa.fused_attention(q, jnp.where(pad, noise, k),
+                           jnp.where(pad, noise, v), live)
+    assert np.array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("block_h", [1, 2])
+def test_heads_a_step_do_not_change_the_answer(block_h):
+    rng = np.random.default_rng(3)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 128, 4, 64)), jnp.bfloat16)
+               for _ in range(3))
+    live = jnp.asarray(np.arange(128)[None, :] < np.array([[50], [128]]))
+    want = fa.fused_attention(q, k, v, live)
+    got = fa.fused_attention(q, k, v, live, block_h=block_h)
+    assert np.array_equal(np.asarray(want, np.float32),
+                          np.asarray(got, np.float32))
+
+
+@pytest.mark.parametrize("shape", [(2, 100, 2, 64), (2, 128, 2, 40),
+                                   (2, 128, 3, 64)])
+def test_fused_refuses_what_it_cannot_tile(shape):
+    x = jnp.zeros(shape, jnp.bfloat16)
+    with pytest.raises(ValueError, match="use dense attention"):
+        fa.fused_attention(x, x, x, jnp.ones(shape[:2]), block_h=2)
+
+
+# -- the rule ------------------------------------------------------------------
+
+BF16, F32 = "bfloat16", "float32"
+
+
+@pytest.mark.parametrize("platform,dtype,seq,head_dim,want", [
+    ("tpu", BF16, 512, 64, "fused"),     # both BERT cells' long bucket
+    ("tpu", BF16, 384, 64, "fused"),
+    ("tpu", BF16, 256, 64, "fused"),     # measured: 6-9% at batch 256
+    ("tpu", BF16, 128, 64, "dense"),     # the short bucket: XLA's pair wins
+    ("tpu", BF16, 1024, 64, "dense"),    # past what was measured
+    ("tpu", BF16, 64, 64, "dense"),      # not whole lanes
+    ("tpu", BF16, 500, 64, "dense"),
+    ("tpu", F32, 512, 64, "dense"),      # the kernel's case is bf16
+    ("tpu", BF16, 512, 40, "dense"),     # SD's head widths
+    ("tpu", BF16, 512, 128, "dense"),    # not measured
+    ("cpu", BF16, 512, 64, "dense"),     # tier-1 runs here
+    ("cpu", F32, 128, 64, "dense"),
+    ("gpu", BF16, 512, 64, "dense"),
+])
+def test_attention_path_rule(platform, dtype, seq, head_dim, want):
+    assert fa.attention_path(platform, dtype, seq, head_dim) == want
+
+
+def _bert_cfg(**options) -> ModelConfig:
+    parallelism = options.pop("parallelism", "single")
+    return ModelConfig(
+        name="b", family="bert", dtype="bfloat16", num_classes=4,
+        batch_buckets=[2], seq_buckets=[512], parallelism=parallelism,
+        options={"layers": 1, "d_model": 128, "heads": 2, "d_ff": 128,
+                 "vocab_size": 512, **options})
+
+
+def _trace(model, bucket=(2, 512)):
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    jax.eval_shape(model.forward, params, model.input_signature(bucket))
+    return model.traced_paths(bucket)
+
+
+@pytest.mark.parametrize("stated", ["dense", "flash"])
+def test_a_stated_attention_is_never_overruled(stated, monkeypatch):
+    monkeypatch.setattr(fa, "platform_here", lambda: "tpu")
+    monkeypatch.setattr(fa, "_interpret_here", lambda: True)
+    assert _trace(build(_bert_cfg(attention=stated))) == {"attention": stated}
+
+
+def test_the_cpu_keeps_the_xla_path():
+    assert _trace(build(_bert_cfg())) == {"attention": "dense"}
+
+
+@pytest.mark.parametrize("parallelism,want", [
+    ("single", "fused"), ("replica", "fused"), ("sharded", "dense")])
+def test_unset_attention_chooses_on_one_device_only(parallelism, want,
+                                                    monkeypatch):
+    """Steered to the TPU's answer in the test, never by an option: a mesh
+    keeps the path GSPMD can partition."""
+    monkeypatch.setattr(fa, "platform_here", lambda: "tpu")
+    monkeypatch.setattr(fa, "_interpret_here", lambda: True)
+    model = build(_bert_cfg(parallelism=parallelism))
+    assert model.traced_paths((2, 512)) == {}        # nothing traced yet
+    assert _trace(model) == {"attention": want}
+
+
+def test_chosen_kernel_serves_the_dense_answer(monkeypatch):
+    dense = build(_bert_cfg(attention="dense"))
+    chosen = build(_bert_cfg())
+    params = dense.init_params(jax.random.key(0))
+    items = [dense.host_decode(b'{"text": "%s"}' % t, "application/json")
+             for t in (b"one live text", b"and a longer one " * 40)]
+    batch = dense.assemble(items, (2, 512))
+    want = jax.jit(dense.forward)(params, batch)
+    monkeypatch.setattr(fa, "platform_here", lambda: "tpu")
+    monkeypatch.setattr(fa, "_interpret_here", lambda: True)
+    got = jax.jit(chosen.forward)(params, batch)
+    assert chosen.traced_paths((2, 512)) == {"attention": "fused"}
+    np.testing.assert_allclose(np.asarray(got["probs"]),
+                               np.asarray(want["probs"]), atol=0.02)
+
+
+# -- what the runtime shows ------------------------------------------------------
+
+def test_stats_and_series_name_the_traced_path():
+    from tpuserve.runtime import build_runtime
+
+    metrics = Metrics()
+    cfg = _bert_cfg()
+    cfg.seq_buckets = [128]
+    model = build(cfg)
+    rt = build_runtime(model, metrics=metrics)
+    (variant,) = rt.describe()["variants"]
+    assert variant["bucket"] == [2, 128] and variant["attention"] == "dense"
+    item = model.canary_item()
+    for _ in range(3):
+        rt.fetch(rt.run((2, 128), model.assemble([item], (2, 128))))
+    label = "2x128/bfloat16/fp/single"
+    both = [metrics.counter(name).value for name in (
+        f"runtime_variant_batches_total{{model=b,variant={label}}}",
+        f"runtime_variant_path_batches_total{{model=b,variant={label},"
+        "attention=dense}")]
+    assert both[0] == both[1] >= 3
+    assert "runtime_variant_path_batches_total" in metrics.render_prometheus()
+
+
+def test_a_family_that_chooses_nothing_adds_no_series():
+    from tpuserve.runtime import build_runtime
+
+    metrics = Metrics()
+    rt = build_runtime(build(ModelConfig(
+        name="toy", family="toy", batch_buckets=[1], dtype="float32",
+        num_classes=10, parallelism="single")), metrics=metrics)
+    assert "attention" not in rt.describe()["variants"][0]
+    assert "runtime_variant_path_batches_total" not in metrics.render_prometheus()
+
+
+# -- the chip's compiler, without the chip ---------------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip: the TPU's compiler is installed where no TPU is.
+    Made inside the fixture, never at import: one process may hold libtpu."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps it from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("heads", [16, 12])
+def test_kernel_compiles_for_the_v5e_at_the_cells_widths(one_chip, heads):
+    """What the interpreter cannot show: Mosaic takes the transposed first
+    product, the sublane concatenations and 16 unrolled heads within VMEM,
+    and XLA adds no copy around the call when q, k and v arrive
+    sequence-minor, as the projections write them."""
+    b, s, d = 8, 512, 64
+    x = jax.ShapeDtypeStruct((b, heads, d, s), jnp.bfloat16, sharding=one_chip)
+    live = jax.ShapeDtypeStruct((b, s), jnp.bool_, sharding=one_chip)
+
+    def attend(q, k, v, live):
+        to = lambda a: a.transpose(0, 3, 1, 2)  # noqa: E731
+        o = fa.fused_attention(to(q), to(k), to(v), live, interpret=False)
+        return o.transpose(0, 2, 3, 1)
+
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
+    try:
+        text = jax.jit(attend).lower(x, x, x, live).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert "tpu_custom_call" in text
+    assert " copy(" not in text and " transpose(" not in text

@@ -1,5 +1,5 @@
-"""Fresh-subprocess device probes shared by bench.py, scripts/bench_configs.py
-and scripts/bench_flash.py.
+"""Fresh-subprocess device probes shared by bench.py and
+scripts/bench_configs.py.
 
 A chip belongs to one process at a time, so every probe here is a child that
 opens the device, measures, prints one JSON line and exits BEFORE the caller

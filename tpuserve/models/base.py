@@ -140,6 +140,14 @@ class ServingModel(abc.ABC):
         """Jittable: on-device preproc (via ``device_preprocess``) + network
         + on-device postproc."""
 
+    def traced_paths(self, bucket: tuple) -> dict:
+        """What ``forward`` chose from the platform, the dtype and this
+        bucket's shape while its program was traced, by name: a family that
+        picks a kernel then says which (BERT: ``{"attention": "fused"}``).
+        The runtime shows it beside the variant in ``/stats`` and counts
+        launches under it. Default: nothing was chosen."""
+        return {}
+
     def prepare_host_params(self, params: Any) -> Any:
         """Restructure loaded host params for the serving mode before
         sharding (runtime calls this between load and device_put). Default

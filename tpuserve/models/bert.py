@@ -45,8 +45,9 @@ class BertBlock(nn.Module):
     heads: int
     d_ff: int
     dtype: Any = jnp.bfloat16
-    # "dense" (XLA einsum) | "flash" (Pallas fused kernel) | "ring" /
-    # "ulysses" (sequence-parallel over the serving mesh's "seq" axis).
+    # "dense" (XLA einsum) | "fused" (Pallas kernel, a whole short sequence
+    # a step) | "flash" (Pallas kernel, tiled) | "ring" / "ulysses"
+    # (sequence-parallel over the serving mesh's "seq" axis).
     attention_impl: str = "dense"
     ln_eps: float = 1e-12  # original BERT value; keeps imported weights exact
     mesh: Any = None  # required for "ring" / "ulysses"
@@ -63,7 +64,13 @@ class BertBlock(nn.Module):
         # Post-LN (original BERT): sublayer -> add -> LayerNorm. Masking is an
         # explicit additive bias inside attention_fn so the semantics stay
         # bucket-invariant (padded keys get -1e9 before the f32 softmax).
-        if self.attention_impl == "flash":
+        if self.attention_impl == "fused":
+            from tpuserve.ops.flash_attention import fused_attention
+
+            # The kernel takes the keys' mask: a live key's bias is 0.0.
+            fn = lambda q, k, v, **kw: fused_attention(  # noqa: E731
+                q, k, v, mask_bias[:, 0, 0, :] == 0.0)
+        elif self.attention_impl == "flash":
             from tpuserve.ops.flash_attention import flash_attention
 
             # mask_bias is (B, 1, 1, S) additive; flash takes per-key (B, S).
@@ -208,6 +215,12 @@ class BertServing(ServingModel):
         super().__init__(cfg)
         self._tokenize_obs = None  # bind_metrics
         opt = cfg.options
+        # options.attention unset: on one device the path is chosen for each
+        # bucket while its program is traced (forward); a mesh keeps the XLA
+        # path, which GSPMD can partition. A stated value holds everywhere.
+        self._choose_attention = "attention" not in opt and \
+            cfg.parallelism in ("single", "replica")
+        self._attention_traced: dict[tuple, str] = {}
         attention = str(opt.get("attention", "dense"))
         if attention not in ("dense", "flash", "ring", "ulysses"):
             raise ValueError("options.attention must be 'dense', 'flash', "
@@ -464,10 +477,24 @@ class BertServing(ServingModel):
         if self.cfg.parallelism == "pipeline":
             logits = self._pipeline_logits(params, ids, mask)
         else:
-            logits = self.module.apply(params, ids, mask)
+            module = self.module
+            if self._choose_attention:
+                from tpuserve.ops.flash_attention import attention_path, platform_here
+
+                module = module.clone(attention_impl=attention_path(
+                    platform_here(), self.dtype, ids.shape[1],
+                    module.d_model // module.heads))
+            # Runs while the bucket is traced, never per call: the record of
+            # what this bucket's program holds (traced_paths).
+            self._attention_traced[tuple(ids.shape)] = module.attention_impl
+            logits = module.apply(params, ids, mask)
         probs = jax.nn.softmax(logits, axis=-1)
         top_p, top_i = jax.lax.top_k(probs, self.top_k)
         return {"probs": top_p, "indices": top_i}
+
+    def traced_paths(self, bucket: tuple) -> dict:
+        path = self._attention_traced.get(tuple(bucket))
+        return {"attention": path} if path else {}
 
     # -- pipeline serving (parallelism = "pipeline") -------------------------
     def prepare_host_params(self, params: Any) -> Any:

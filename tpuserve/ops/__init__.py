@@ -10,9 +10,14 @@
   head slice over the full sequence, then an inverse all-to-all restores seq
   sharding. Lower step latency than the ring for short/medium sequences; the
   ring wins on memory for very long ones.
-- ``flash_attention`` — the single-device realization of the same recurrence
-  as a fused Pallas TPU kernel: K/V stream through VMEM in blocks, the score
-  matrix never touches HBM. Used by BERT via ``options.attention = "flash"``.
+- ``flash_attention`` (the module): two Pallas TPU kernels that keep the
+  score matrix out of HBM. ``fused_attention`` takes a whole sequence of up
+  to 512 a step and is what BERT's (x, 512) buckets run on one TPU chip,
+  chosen from the shape by ``attention_path`` (v5e, 2026-09-28: 4.0 ms a
+  layer against the XLA pair's 12.5 at (256, 512, 16, 64)); the tiled
+  ``flash_attention`` streams K/V in blocks with an online softmax, serves
+  any length and ring/Ulysses's per-device step, and is 3x slower than
+  XLA at serving shapes: ``options.attention = "flash"`` opts in.
 - ``moe`` — Switch-style mixture-of-experts FFN: static top-1 routing with
   fixed capacity (all einsums, no dynamic shapes), expert dim sharded on
   "model" for expert parallelism (XLA inserts the token all-to-alls).

@@ -1,52 +1,64 @@
-"""Fused blockwise (flash) attention as a Pallas TPU kernel (SURVEY.md §7 M8).
+"""Attention without a score matrix in device memory: two Pallas TPU kernels.
 
-Why a hand kernel here and nowhere else: **memory, not speed.** Dense
-attention materializes the (Sq, Sk) score matrix — O(S^2) f32 per
-(batch, head) — which caps the sequence length a device can run at all.
-This kernel keeps the whole online-softmax recurrence in VMEM: for each
-query tile, K/V stream through the MXU in ``block_k`` tiles while the
-running max ``m``, normalizer ``l``, and f32 accumulator live in VMEM
-scratch — O(S) memory, one HBM write per output tile. It is the
-single-device realization of the same recurrence
-``tpuserve.ops.ring_attention`` runs *across* chips (there the blocks arrive
-over ICI via ppermute; here they arrive from HBM via the BlockSpec pipeline).
+**``fused_attention``: a whole short sequence a step (S <= 512).** What
+BERT's (x, 512) buckets run on one TPU chip since PR 29, chosen by
+``attention_path`` while a bucket is traced (platform, dtype, sequence
+length, head width; no option names it). With S <= 512 a head's whole K and
+V sit in VMEM, so the softmax is one pass with no rescale; bf16 operands go
+into both MXU products with float32 accumulation, maximum, exponentials and
+sum are float32, probabilities are rounded to bf16 for the second product
+as the dense path rounds them, and a padded key weighs exactly 0.0. It
+reads and writes ``(B, H, D, S)``, the layout XLA gives the projections on
+the TPU, so no transpose goes through device memory on either side.
+Measured on the v5e (2026-09-28, jax 0.9.0, ``scripts/bench_flash.py``,
+PERF.md section 6, PR 29), attention alone at the serving shapes, inputs as
+the projections leave them: (256, 512, 16, 64) 4.0 ms against 15.8 ms for
+the XLA pair the dense path lowers to (12.5 ms a layer inside BERT-large's
+program), 9.9 ms for jax's own ``pallas.ops.tpu.flash_attention`` with
+whole-sequence blocks and its transposes, and 47.0 ms for
+``flash_attention`` below; (256, 512, 12, 64) 3.2 against 12.0, 7.6 and
+35.6 ms. The whole (256, 512) forward: BERT-large 847.8 -> 646.9 ms,
+BERT-base 306.4 -> 204.1 ms; (256, 256): 346.3 -> 325.2 and 111.8 -> 101.5.
+At S = 128 the XLA pair wins or draws inside the program ((256, 128):
+148.1 against 152.7 ms; (32, 128): 21.2 against 22.7) though the kernel
+alone is faster there, so ``attention_path`` routes 256..512 only. What
+bounds the kernel now is the vector unit: five to six vector operations a
+score.
 
-On raw speed the r5 measurement is unambiguous (BASELINE.md "Flash vs
-dense"): XLA's dense path is FASTER at every judged serving shape on v5e
-(this kernel = 0.45-0.70x), so serving defaults everywhere are dense and
-``ring/ulysses local_impl="auto"`` switches here only when the dense score
-tile would blow the HBM budget. The earlier "the kernel wins when head_dim
-is lane-aligned" claim was measured false and is retracted.
+**``flash_attention``: tiled, online softmax, any length.** For each query
+tile, K/V stream through the MXU in ``block_k`` tiles while the running max
+``m``, normalizer ``l`` and f32 accumulator live in VMEM scratch: O(S)
+memory, one HBM write per output tile. It is the single-device realization
+of the recurrence ``tpuserve.ops.ring_attention`` runs *across* chips
+(there the blocks arrive over ICI via ppermute; here from HBM via the
+BlockSpec pipeline), and ``return_stats`` hands ring and Ulysses the
+unnormalised accumulator with ``(m, l)``. Its use is memory and those
+merges, not speed at serving shapes: it casts its operands to float32
+before both products, works in 128 x 128 tiles one head a step and
+transposes through device memory around the call, and reads 3.0x the dense
+pair's time at (256, 512, 16, 64) (above; BASELINE.md's "0.45-0.70x" of
+2026-07-30 was the same verdict at (16, 512)). ``options.attention =
+"flash"`` and ``ring/ulysses local_impl="auto"`` past the dense score
+tile's memory budget are its callers; at SD-UNet head widths 40/80 the
+zero-padded lanes cost it another 2.4-2.8x (BASELINE.md "SD 1.5 chip
+profile"), so the SD 1.5 UNet stays dense.
 
-Kernel shape: grid = (B*H, Sq/block_q, Sk/block_k). The TPU grid executes
-the innermost dimension sequentially, so the k-block axis lives in the GRID
-(the BlockSpec pipeline double-buffers the K/V tiles from HBM) and the
-online-softmax state persists in scratch across k iterations — no in-kernel
-dynamic slicing, which Mosaic rejects for some tile offsets. State is
-initialized at ki == 0 and the output tile is written once at the last ki.
+Kernel shape of ``flash_attention``: grid = (B*H, Sq/block_q, Sk/block_k).
+The TPU grid executes the innermost dimension sequentially, so the k-block
+axis lives in the GRID and the online-softmax state persists in scratch
+across k iterations: no in-kernel dynamic slicing. State is initialized at
+ki == 0 and the output tile is written once at the last ki. Interface:
+(B, S, H, D) layout, optional additive per-key bias (B, S), exactly what
+BERT's padding mask lowers to. Padded keys get -1e9 bias => exp underflows
+to 0 => they contribute nothing to ``l`` or ``acc``; a row with at least
+one live key (BERT always has [CLS]) never divides by zero.
 
-Interface matches the rest of the stack: (B, S, H, D) layout, optional
-additive per-key bias (B, S) — exactly what BERT's padding mask lowers to.
-Padded keys get -1e9 bias => exp underflows to 0 => they contribute nothing
-to ``l`` or ``acc``; a row with at least one live key (BERT always has
-[CLS]) never divides by zero.
-
-CPU/test story: ``pallas_call(interpret=True)`` runs the kernel in the
-Pallas interpreter, so the same code is unit-tested on the CI's fake-device
-CPU mesh and compiled for real on TPU. ``interpret=None`` decides from the
-effective default device (honoring ``jax.default_device(cpu)`` blocks like
-the runtime's CPU-pinned param init): interpret on ``cpu``, compile on
-``tpu``, raise on anything else — never the interpreter by accident.
-
-When to use — MEASURED, see BASELINE.md:
-- "SD 1.5 chip profile" (2026-07-30, v5e): at SD-UNet head dims 40/80 the
-  zero-padded lanes waste 37-50% of the MXU and the kernel runs the UNet
-  step 2.4-2.8x SLOWER than XLA's dense einsum — the SD 1.5 UNet
-  therefore defaults to dense (``options.unet_attention = "flash"`` is
-  opt-in, parity-tested, and exists for lane-aligned custom variants).
-- "Flash vs dense, chip level" (same date): BERT-family numbers
-  (head_dim 64, lane-aligned) per seq length; ``ring_attention``'s
-  ``local_impl="auto"`` thresholds cite that table.
+CPU/test story, both kernels: ``pallas_call(interpret=True)`` runs them in
+the Pallas interpreter, so the same code is unit-tested on the CI's
+fake-device CPU mesh and compiled for real on TPU. ``interpret=None``
+decides from the effective default device (honoring
+``jax.default_device(cpu)`` blocks like the runtime's CPU-pinned param
+init): interpret on ``cpu``, compile on ``tpu``, raise on anything else.
 """
 
 from __future__ import annotations
@@ -230,16 +242,20 @@ def _flash_bwd(block_q, block_k, interpret, return_stats, res, ct):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _interpret_here() -> bool:
-    """Interpret on ``cpu``, compile on ``tpu``, raise on anything else.
+def platform_here() -> str:
+    """The platform a trace started now would run on.
 
-    Decided from the effective platform, honoring ``with
-    jax.default_device(cpu)`` (the runtime pins param init there):
-    default_backend() alone would still say 'tpu' and compile the TPU kernel
-    for a CPU trace."""
+    Honors ``with jax.default_device(cpu)`` (the runtime pins param init
+    there): default_backend() alone would still say 'tpu' and compile the TPU
+    kernel for a CPU trace."""
     dev = jax.config.jax_default_device  # a Device, a platform name, or None
-    platform = (dev if isinstance(dev, str)
-                else getattr(dev, "platform", None)) or jax.default_backend()
+    return (dev if isinstance(dev, str)
+            else getattr(dev, "platform", None)) or jax.default_backend()
+
+
+def _interpret_here() -> bool:
+    """Interpret on ``cpu``, compile on ``tpu``, raise on anything else."""
+    platform = platform_here()
     if platform not in ("cpu", "tpu"):  # tps-ok[TPS503]: a platform name, host-side
         raise ValueError(
             f"flash_attention is a Mosaic TPU kernel: platform {platform!r} "
@@ -294,3 +310,102 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if bias is None:
         bias = jnp.zeros((b, sk), jnp.float32)
     return _flash(q, k, v, bias, block_q, block_k, interpret, return_stats)
+
+
+# -- whole-sequence kernel: the serving shapes (S <= 512, head 64) ---------------
+_LANES = 128
+# Sequence lengths at which the whole forward was faster with the
+# whole-sequence kernel than with the XLA pair on the v5e
+# (scripts/bench_flash.py --forward; PERF.md section 6, PR 29): at 512 by
+# 24-33%, at 256 by 6-9% at batch 256 and even at batch 32, at 128 slower.
+FUSED_SEQ_RANGE = (256, 512)
+
+
+def attention_path(platform: str, dtype, seq: int, head_dim: int) -> str:
+    """"fused" or "dense" for self-attention over one bucket, from what a
+    trace can see: the platform it runs on, the compute dtype, the bucket's
+    sequence length and the head width. A pure function, so that the rule
+    is tested where no TPU is. Only what was measured is routed."""
+    lo, hi = FUSED_SEQ_RANGE
+    if platform == "tpu" and jnp.dtype(dtype) == jnp.bfloat16 \
+            and head_dim == 64 and seq % _LANES == 0 and lo <= seq <= hi:
+        return "fused"
+    return "dense"
+
+
+def _fused_kernel(q_ref, k_ref, v_ref, live_ref, o_ref):
+    """One row's block of ``(heads, head_dim, S)``: a head's features on
+    sublanes, the sequence on lanes, which is how XLA lays the projections'
+    outputs out on the TPU. Scores are held transposed, keys on sublanes and
+    queries on lanes, so that the softmax's maximum and sum run down the
+    sublanes (plain vector maxima and adds) and come out as rows, the shape
+    that normalises the ``(head_dim, S)`` output. The key mask rides in the
+    first product: sixteen more contraction rows, -1e9 on padded keys
+    against ones, which the MXU takes in the same pass. The heads are
+    unrolled, so that one head's products overlap the next one's softmax."""
+    _, heads, head_dim, s = q_ref.shape
+    dt = q_ref.dtype
+    scale = head_dim ** -0.5
+    exact = math.frexp(scale)[0] == 0.5     # a power of two: exact in bfloat16
+    first = (jax.lax.broadcasted_iota(jnp.int32, (16, s), 0) == 0).astype(dt)
+    bias = jnp.broadcast_to(
+        jnp.where(live_ref[0] != 0, 0.0, -1e9), (16, s)).astype(dt)
+    for h in range(heads):
+        q = q_ref[0, h]
+        if exact:                           # on (head_dim, S), not on (S, S)
+            q = q * jnp.asarray(scale, dt)
+        sc = jax.lax.dot_general(                        # (keys, queries)
+            jnp.concatenate([k_ref[0, h], bias], axis=0),
+            jnp.concatenate([q, first], axis=0),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        if not exact:                       # a masked key stays at -1e9 * scale
+            sc = sc * scale
+        p = jnp.exp(sc - jnp.max(sc, axis=0, keepdims=True))
+        norm = 1.0 / jnp.sum(p, axis=0, keepdims=True)             # (1, queries)
+        out = jnp.dot(v_ref[0, h], p.astype(dt),
+                      preferred_element_type=jnp.float32)  # (head_dim, queries)
+        o_ref[0, h] = (out * norm).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_h", "interpret"))
+def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    live: jax.Array, *, block_h: int | None = None,
+                    interpret: bool | None = None):
+    """Attention over a whole short sequence in one kernel, (B, S, H, D) in
+    and out, ``live`` the keys' mask (B, S): nonzero where a key counts.
+
+    The mathematics of ``models.bert._masked_attention``: scaled scores (the
+    products' float32 accumulators, where the dense path rounds them to the
+    input dtype first), float32 softmax over the live keys, weights rounded
+    to the input dtype for the product with the values; the normaliser is
+    applied to the float32 output. A masked key weighs exactly 0.0; a row
+    with no live key gets finite values that mean nothing. S in whole lanes
+    (128), head width in whole bf16 tiles (16); ``block_h`` heads a grid
+    step (default: all, which is fastest where it fits VMEM: 512 x 16 x 64
+    does). No VJP: it serves."""
+    b, s, h, d = q.shape
+    block_h = h if block_h is None else block_h
+    if d % 16 or s % _LANES or h % block_h:
+        raise ValueError(
+            f"fused_attention wants head width {d} in whole bfloat16 tiles, "
+            f"sequence {s} in whole lanes and {block_h} heads a step that "
+            f"divide {h}; use dense attention")
+    if interpret is None:
+        interpret = _interpret_here()
+    # (B, S, H, D) -> (B, H, D, S): on the TPU XLA writes the projections
+    # sequence-minor already, so these are views there, not copies.
+    spec = pl.BlockSpec((1, block_h, d, s), lambda bi, hi: (bi, hi, 0, 0))
+    out = pl.pallas_call(
+        _fused_kernel,
+        grid=(b, h // block_h),
+        in_specs=[spec, spec, spec,
+                  pl.BlockSpec((1, 1, s), lambda bi, hi: (bi, 0, 0))],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, d, s), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+    )(*(x.transpose(0, 2, 3, 1) for x in (q, k, v)),
+      (live != 0).astype(jnp.int32).reshape(b, 1, s))
+    return out.transpose(0, 3, 1, 2)

@@ -32,7 +32,7 @@ import concurrent.futures as cf
 import logging
 import os
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any
 
 import jax
@@ -172,9 +172,13 @@ class Variant:
     key: VariantKey
     executables: list[Executable]
     compile_ms: float = 0.0
+    # What the model chose while this bucket was traced, by name
+    # (ServingModel.traced_paths): BERT's {"attention": "fused" | "dense"}.
+    paths: dict = field(default_factory=dict)
 
     def summary(self) -> dict:
         return {
+            **self.paths,
             "bucket": list(self.key.bucket),
             "dtype": self.key.dtype,
             "quantize": self.key.quantize,
@@ -361,6 +365,7 @@ class ModelRuntime:
         # Batches dispatched per specialized variant, prebound at compile
         # time (one locked inc per batch, not per request).
         self._c_variant_batches: dict[tuple, Any] = {}
+        self._c_path_batches: dict[tuple, Any] = {}
         # Per-chip dispatch attribution (Clockwork P3: predictability needs
         # per-device accounting shipped WITH the parallel placement, not
         # after it): one prebound counter per replica, ticked in dispatch().
@@ -641,8 +646,9 @@ class ModelRuntime:
             exes.append(Executable(bucket, compiled, in_batch_sharding,
                                    device_index=i, donated=donate))
         key = self.variant_key(bucket)
+        paths = self.model.traced_paths(bucket)
         self.variants[key] = Variant(
-            key, exes, compile_ms=(time.perf_counter() - t0) * 1e3)
+            key, exes, compile_ms=(time.perf_counter() - t0) * 1e3, paths=paths)
         self.executables[bucket] = exes
         # Registered before the counters tick so a scrape can never observe
         # a compile with no variant behind it.
@@ -656,6 +662,13 @@ class ModelRuntime:
         self._c_variant_batches[bucket] = self.metrics.counter(
             f"runtime_variant_batches_total{{model={self.model.name},"
             f"variant={key.label}}}")
+        if paths:
+            # The same launches under the traced paths' names: over the
+            # series above, the share of launches that went through a kernel.
+            chosen = ",".join(f"{k}={v}" for k, v in sorted(paths.items()))
+            self._c_path_batches[bucket] = self.metrics.counter(
+                f"runtime_variant_path_batches_total{{model={self.model.name},"
+                f"variant={key.label},{chosen}}}")
 
     # -- generative programs (tpuserve.genserve) ------------------------------
     def register_program(self, tag: str, fn, arg_structs: tuple,
@@ -851,6 +864,9 @@ class ModelRuntime:
             self.injector.check("device_error", self.model.name)
         exe = self.executables[bucket][replica]
         c = self._c_variant_batches.get(bucket)
+        if c is not None:
+            c.inc()
+        c = self._c_path_batches.get(bucket)
         if c is not None:
             c.inc()
         self._c_replica_batches[replica].inc()
