@@ -1,13 +1,12 @@
 #!/usr/bin/env python
 """Measure benchmark configs 2-5 end-to-end over HTTP on the local chip.
 
-BASELINE.json names five judged configs; `bench.py` measures config 1
-(ResNet-50, the headline metric). This script produces measured rows for
-the others — MobileNetV3-Large (replica/latency mode), BERT-base (text,
+BASELINE.json names five judged configs; config 1 is ResNet-50, the
+headline metric. This script produces measured rows for the others — MobileNetV3-Large (replica/latency mode), BERT-base (text,
 (batch, seq) buckets), its Switch-MoE expert-parallel variant (bert-moe),
 EfficientDet-D0 (detection + on-device NMS), and Stable Diffusion 1.5
-(txt2img, device-resident denoise loop) — using the
-same method as bench.py: real aiohttp server, out-of-process load generator,
+(txt2img, device-resident denoise loop) — with a
+real aiohttp server, out-of-process load generator,
 closed-loop peak + per-phase breakdown on stderr. Results are recorded in
 BASELINE.md ("Per-config measured rows").
 
@@ -35,8 +34,8 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-# Per-family serving config + load shape. Wire sizes follow the same
-# deployment philosophy as bench.py (host decodes to a compact wire; device
+# Per-family serving config + load shape. Wire sizes follow one
+# deployment philosophy (host decodes to a compact wire; device
 # resizes): each row records its wire so the number carries its context.
 FAMILIES: dict[str, dict] = {
     "mobilenetv3": dict(

@@ -41,7 +41,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PORT = int(os.environ.get("HOSTBENCH_PORT", 18471))
-EDGE = int(os.environ.get("HOSTBENCH_EDGE", 160))  # matches bench.py wire
+EDGE = int(os.environ.get("HOSTBENCH_EDGE", 160))  # the compact wire
 DURATION = float(os.environ.get("HOSTBENCH_DURATION", 8))
 CLIENT_BATCH = int(os.environ.get("HOSTBENCH_CLIENT_BATCH", 64))
 

@@ -2,7 +2,6 @@
 fault containment, load shedding, cancellation. SURVEY.md §4-2."""
 
 import asyncio
-import concurrent.futures as cf
 
 import numpy as np
 import pytest
@@ -30,8 +29,7 @@ def make_batcher(rt_model, **cfg_over):
     for k, v in cfg_over.items():
         setattr(model.cfg, k, v)
     metrics = Metrics()
-    pool = cf.ThreadPoolExecutor(max_workers=4)
-    return ModelBatcher(model, rt, metrics, pool), metrics
+    return ModelBatcher(model, rt, metrics), metrics
 
 
 def item():
@@ -145,8 +143,7 @@ def test_poison_item_isolated_by_split_retry(rt_model):
                          retry_split=True).items():
             setattr(model.cfg, k, v)
         metrics = Metrics()
-        pool = cf.ThreadPoolExecutor(max_workers=4)
-        b = ModelBatcher(_PoisonModel(model), rt, metrics, pool)
+        b = ModelBatcher(_PoisonModel(model), rt, metrics)
         await b.start()
         good = [b.submit(item()) for _ in range(3)]
         poison = b.submit(np.full((8, 8, 3), 255, dtype=np.uint8))
@@ -253,6 +250,61 @@ def test_deadline_expired_in_queue_fails_fast(rt_model):
     run(go())
 
 
+@pytest.mark.parametrize("path", [
+    "served", "retried", "failed", "retry_exhausted",
+    "expired_at_the_gate", "expired_waiting_for_a_slot"])
+def test_every_batch_gives_its_admission_back(rt_model, path):
+    """One acquire of the admission gate, one release, whatever becomes of
+    the batch: served, retried, failed, or expired before it was admitted or
+    while it waited for a staging slot. A place that is never given back
+    closes the gate for good once ``depth`` of them are gone."""
+    import time
+
+    from tpuserve.batcher import DeadlineExceeded
+
+    async def go():
+        model, _ = rt_model
+        retry = path in ("retried", "retry_exhausted")
+        b, metrics = make_batcher(rt_model, deadline_ms=5.0, max_inflight=1,
+                                  batch_retry=retry, retry_split=retry)
+        await b.start()
+        try:
+            if path in ("served", "retried", "failed", "retry_exhausted"):
+                if path != "served":
+                    b.injector = FaultInjector.single(
+                        "batch_error", count=1 if path == "retried" else -1)
+                fut = b.submit(item())
+                if path in ("served", "retried"):
+                    assert "top_k" in await asyncio.wait_for(fut, timeout=10)
+                else:
+                    with pytest.raises(FaultInjected):
+                        await asyncio.wait_for(fut, timeout=10)
+            else:
+                if path == "expired_waiting_for_a_slot":
+                    # Admit a second batch past the one-launch device
+                    # section: it assembles, then waits there for the slot.
+                    b._gate._wait_s = lambda held, full: \
+                        0.0 if held < 2 else float("inf")
+                b.injector = FaultInjector.single("slow_dispatch",
+                                                  delay_ms=400.0, count=1)
+                slow = b.submit(item())
+                await asyncio.sleep(0.05)  # dispatched: the one slot is held
+                doomed = b.submit(item(),
+                                  deadline_at=time.perf_counter() + 0.05)
+                with pytest.raises(DeadlineExceeded):
+                    await asyncio.wait_for(doomed, timeout=10)
+                assert "top_k" in await asyncio.wait_for(slow, timeout=10)
+        finally:
+            await b.stop()  # waits for the dispatch tasks' own clean-up
+            model.cfg.max_inflight = 2  # module-scoped cfg: restore defaults
+            model.cfg.batch_retry = model.cfg.retry_split = True
+        assert b._gate.held == 0
+        assert b._inflight_now == 0 and b._pending == 0
+        assert [p.in_use for p in b._staging] == [0]
+
+    run(go())
+
+
 def test_generous_deadline_dispatches_normally(rt_model):
     async def go():
         b, metrics = make_batcher(rt_model, deadline_ms=20.0)
@@ -281,9 +333,8 @@ def make_adaptive_batcher(rt_model, adaptive, **cfg_over):
     for k, v in cfg_over.items():
         setattr(model.cfg, k, v)
     metrics = Metrics()
-    pool = cf.ThreadPoolExecutor(max_workers=4)
     acfg = adaptive if isinstance(adaptive, AdaptiveConfig) else AdaptiveConfig(**adaptive)
-    return ModelBatcher(model, rt, metrics, pool, adaptive_cfg=acfg), metrics
+    return ModelBatcher(model, rt, metrics, adaptive_cfg=acfg), metrics
 
 
 def test_aimd_grows_on_pressure_shrinks_on_timer():
@@ -302,7 +353,6 @@ def test_aimd_grows_on_pressure_shrinks_on_timer():
                       parallelism="single")
     model = build_model(cfg)
     b = ModelBatcher(model, _brt(model), Metrics(),
-                     cf.ThreadPoolExecutor(max_workers=2),
                      adaptive_cfg=AdaptiveConfig(increase=1.0, decrease=0.5))
     g = None
     b._aimd_update(g, 2.0, n=2, target_n=2, timer_flush=False, pressure=True)
@@ -333,7 +383,6 @@ def test_batch_duration_ewma_tracks_observations():
     model = build_model(cfg)
     metrics = Metrics()
     b = ModelBatcher(model, _brt(model), metrics,
-                     cf.ThreadPoolExecutor(max_workers=2),
                      adaptive_cfg=AdaptiveConfig(ewma_alpha=0.5))
     b._observe_batch_duration((4,), 10.0)
     assert b._ewma_ms[(4,)] == 10.0
@@ -360,7 +409,6 @@ def test_flush_headroom_from_earliest_deadline():
                       parallelism="single")
     model = build_model(cfg)
     b = ModelBatcher(model, _brt(model), Metrics(),
-                     cf.ThreadPoolExecutor(max_workers=2),
                      adaptive_cfg=AdaptiveConfig(slack_ms=2.0))
 
     async def go():

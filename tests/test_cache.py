@@ -1,6 +1,6 @@
 """Content-addressed result cache + single-flight coalescing (ISSUE 5):
 digest stability, LRU/TTL bookkeeping, coalescing fan-out, version-churn
-stale drops, and the honest-accounting invariants bench.py relies on.
+stale drops, and the honest-accounting invariants a measurement relies on.
 
 Everything here is unit-level against ModelCache with hand-driven futures;
 the HTTP integration (hit fast path, client-batch slot merge) lives in
@@ -245,7 +245,7 @@ def test_coalesce_disabled_every_miss_submits():
 
 
 # ---------------------------------------------------------------------------
-# Accounting helpers (shared by bench.py and the cache smoke)
+# Accounting helpers (the cache smoke's)
 # ---------------------------------------------------------------------------
 
 def test_hit_rate_definition():

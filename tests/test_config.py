@@ -630,3 +630,20 @@ def test_autopilot_block_validation():
         AutopilotConfig(pressure_low=2.0, pressure_high=1.0)
     with pytest.raises(ValueError, match="min_slots"):
         AutopilotConfig(min_slots=0)
+
+
+@pytest.mark.parametrize("key, value", [
+    ("session_mode", '"recycle"'),
+    ("relay_workers", "2"),
+    ("relay_epoch_images", "4096"),
+    ("relay_epoch_ms", "2000.0"),
+    ("relay_slots", "4"),
+])
+def test_removed_recycle_keys_are_refused(tmp_path, key, value):
+    """The deferred-readback execution mode is gone with its five keys: a
+    TOML that still sets one fails at load, naming the key, and can never
+    come up silently in another mode."""
+    p = tmp_path / "old.toml"
+    p.write_text(f'[[model]]\nname = "m"\nfamily = "toy"\n{key} = {value}\n')
+    with pytest.raises(ValueError, match=f"unknown ModelConfig keys.*{key}"):
+        load_config(str(p))

@@ -556,54 +556,3 @@ def test_decode_corrupt_maps_to_400(loop):
             await client.close()
 
     loop.run_until_complete(go())
-
-
-# ---------------------------------------------------------------------------
-# Deferred pool: worker death is contained, retried, and swept
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_deferred_worker_death_retried_and_swept():
-    import concurrent.futures as cf
-
-    from tpuserve.batcher import ModelBatcher
-    from tpuserve.deferred import DeferredPool
-    from tpuserve.models import build
-
-    cfg = toy_model_cfg(batch_buckets=[1, 2], session_mode="recycle",
-                        relay_workers=2, relay_epoch_images=64,
-                        relay_epoch_ms=300.0, request_timeout_ms=30_000.0)
-    model = build(cfg)
-    pool = DeferredPool(cfg, model,
-                        injector=FaultInjector.single("worker_death", count=1))
-    pool.prewarm()
-
-    async def go():
-        await pool.start()
-        metrics = Metrics()
-        tp = cf.ThreadPoolExecutor(max_workers=4)
-        b = ModelBatcher(model, pool, metrics, tp)
-        await b.start()
-        item = np.random.default_rng(0).integers(0, 200, (8, 8, 3),
-                                                 dtype=np.uint8)
-        # First request lands on worker A; the second enqueue kills A
-        # (chaos), failing the first batch's future -> batcher retries it
-        # onto the replacement worker. Both clients still get results.
-        f1 = b.submit(item)
-        await asyncio.sleep(0.05)
-        f2 = b.submit(item)
-        r1, r2 = await asyncio.wait_for(asyncio.gather(f1, f2), timeout=60)
-        assert "top_k" in r1 and "top_k" in r2
-        assert metrics.counter("batch_retries_total{model=toy}").value >= 1
-        pool.watchdog_sweep()  # reaps the killed worker handle
-        assert all(w.proc.is_alive() or w.retired or not w.pending
-                   for w in pool._workers)
-        await b.stop()
-        await pool.stop()
-        tp.shutdown(wait=False)
-
-    loop = asyncio.new_event_loop()
-    try:
-        loop.run_until_complete(go())
-    finally:
-        loop.close()

@@ -11,7 +11,6 @@ PR-2 deadline 504 semantics.
 """
 
 import asyncio
-import concurrent.futures as cf
 import threading
 import time
 
@@ -133,8 +132,7 @@ def make_fake_batcher(rec=None, compute_s=0.1, per_batch=None, assemble_s=0.0,
     model = FakeModel(cfg, rec, assemble_s=assemble_s)
     rt = FakeRuntime(rec, compute_s=compute_s, per_batch=per_batch)
     metrics = Metrics()
-    pool = cf.ThreadPoolExecutor(max_workers=2)
-    b = ModelBatcher(model, rt, metrics, pool, pipeline_cfg=pipeline_cfg)
+    b = ModelBatcher(model, rt, metrics, pipeline_cfg=pipeline_cfg)
     return b, metrics, rec
 
 
@@ -430,8 +428,7 @@ def test_custom_assemble_without_assemble_into_skips_arena():
         rec = Recorder()
         cfg = fake_cfg()
         model = Custom(cfg, rec)
-        b = ModelBatcher(model, FakeRuntime(rec, compute_s=0.0), Metrics(),
-                         cf.ThreadPoolExecutor(2))
+        b = ModelBatcher(model, FakeRuntime(rec, compute_s=0.0), Metrics())
         await b.start()
         assert not b._use_arena and b.arena is None
         assert await asyncio.wait_for(b.submit(3.0), timeout=10) == 3.0

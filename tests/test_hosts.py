@@ -164,16 +164,6 @@ def test_down_domains_names_hosts_and_agent_respawns():
     assert set(sup.down_domains()) == {host_name(1), "host0:worker1"}
 
 
-def test_recycle_rejected_at_host_supervisor_construction():
-    from tpuserve.obs import Metrics
-    from tpuserve.workerproc.hosts import HostSupervisor
-
-    cfg = ServerConfig(models=[_toy("rc", session_mode="recycle")],
-                       router=RouterConfig(enabled=True, hosts=2))
-    with pytest.raises(ValueError, match="recycle"):
-        HostSupervisor(cfg, Metrics(16))
-
-
 # ---------------------------------------------------------------------------
 # The host fleet (module-scoped: 2 real host agents x 2 real workers)
 # ---------------------------------------------------------------------------

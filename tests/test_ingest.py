@@ -171,14 +171,13 @@ def test_submit_threadsafe_from_worker_thread():
     from tpuserve.obs import Metrics
     from tpuserve.runtime import build_runtime
     from tpuserve.batcher import ModelBatcher
-    import concurrent.futures as cf
 
     cfg = ModelConfig(name="toy", family="toy", batch_buckets=[1, 2],
                       deadline_ms=2.0, dtype="float32", num_classes=10,
                       parallelism="single", max_queue=4)
     model = build_model(cfg)
     rt = build_runtime(model)
-    b = ModelBatcher(model, rt, Metrics(), cf.ThreadPoolExecutor(2))
+    b = ModelBatcher(model, rt, Metrics())
     item = np.zeros((EDGE, EDGE, 3), dtype=np.uint8)
 
     async def go():
@@ -216,12 +215,11 @@ def test_submit_threadsafe_before_start_raises():
     from tpuserve.obs import Metrics
     from tpuserve.runtime import build_runtime
     from tpuserve.batcher import ModelBatcher
-    import concurrent.futures as cf
 
     cfg = ModelConfig(name="toy", family="toy", dtype="float32",
                       num_classes=10, parallelism="single")
     b = ModelBatcher(build_model(cfg), build_runtime(build_model(cfg)),
-                     Metrics(), cf.ThreadPoolExecutor(1))
+                     Metrics())
     with pytest.raises(RuntimeError, match="not started"):
         b.submit_threadsafe(np.zeros((EDGE, EDGE, 3), dtype=np.uint8))
 

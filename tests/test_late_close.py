@@ -13,7 +13,6 @@ replaces.
 """
 
 import asyncio
-import concurrent.futures as cf
 import math
 import random
 import threading
@@ -129,7 +128,7 @@ def make(launch_s=0.03, assemble_s=0.0, parent_rule=False, depth=2,
     model = Model(ModelConfig(**base), assemble_s)
     dev = FifoDevice(launch_s)
     metrics = Metrics()
-    b = ModelBatcher(model, dev, metrics, cf.ThreadPoolExecutor(max_workers=2),
+    b = ModelBatcher(model, dev, metrics,
                      pipeline_cfg=PipelineConfig(depth=depth, assemble_ahead=2))
     model.batcher = b
     b.parent_rule = parent_rule

@@ -18,7 +18,6 @@ proven without TPU hardware:
 """
 
 import asyncio
-import concurrent.futures as cf
 import io
 
 import jax
@@ -140,10 +139,9 @@ def test_acquire_staging_falls_back_least_loaded_not_index_order():
     batch to the next index, starving high-index replicas under bursts."""
     model = build(toy_cfg(batch_buckets=[1]))
     rt = _FakeStagedRuntime(3, first=0)
-    pool = cf.ThreadPoolExecutor(max_workers=1)
 
     async def go():
-        b = ModelBatcher(model, rt, Metrics(), pool)
+        b = ModelBatcher(model, rt, Metrics())
         await b.start()
         try:
             assert len(b._staging) == 3
@@ -161,7 +159,6 @@ def test_acquire_staging_falls_back_least_loaded_not_index_order():
             await b.stop()
 
     asyncio.run(go())
-    pool.shutdown()
 
 
 # -- every replica serves under load ------------------------------------------
@@ -171,10 +168,9 @@ def test_every_replica_receives_batches_under_sustained_load():
     metrics = Metrics()
     rt = build_runtime(build(toy_cfg(batch_buckets=[1])), metrics=metrics)
     assert rt.n_replicas == N_DEV
-    pool = cf.ThreadPoolExecutor(max_workers=2)
 
     async def go():
-        b = ModelBatcher(model, rt, metrics, pool)
+        b = ModelBatcher(model, rt, metrics)
         await b.start()
         # Replica-aware admission: before a measurement the gate counts
         # every chip's device section (depth x replicas); after, it goes
@@ -197,7 +193,6 @@ def test_every_replica_receives_batches_under_sustained_load():
             await b.stop()
 
     asyncio.run(go())
-    pool.shutdown()
     batches = rt.replica_batches()
     assert len(batches) == N_DEV
     assert all(v > 0 for v in batches), (
@@ -278,7 +273,6 @@ def test_publish_rollback_under_load_never_serves_torn_versions():
     model = build(toy_cfg(batch_buckets=[1]))
     rt = build_runtime(build(toy_cfg(batch_buckets=[1])))
     assert rt.n_replicas == N_DEV
-    pool = cf.ThreadPoolExecutor(max_workers=2)
     item = np.random.default_rng(3).integers(0, 255, (8, 8, 3), np.uint8)
 
     def probs(r):
@@ -296,7 +290,7 @@ def test_publish_rollback_under_load_never_serves_torn_versions():
         return 1 if m1 else 2
 
     async def go():
-        b = ModelBatcher(model, rt, Metrics(), pool)
+        b = ModelBatcher(model, rt, Metrics())
         await b.start()
         try:
             ref_v1 = await b.submit(item.copy())
@@ -337,7 +331,6 @@ def test_publish_rollback_under_load_never_serves_torn_versions():
             await b.stop()
 
     asyncio.run(go())
-    pool.shutdown()
 
 
 def test_staged_canary_proves_every_replica():

@@ -249,36 +249,6 @@ def test_int8_conv1x1_matches_dense_conv():
             < 0.02 * np.abs(np.asarray(ref)).max()
 
 
-@pytest.mark.slow
-def test_recycle_mode_with_int8_weights():
-    """Regression: the deferred worker must compile the dequant-wrapped
-    forward, not raw model.forward, when weights are stored int8."""
-    import asyncio
-
-    from tpuserve.deferred import DeferredPool
-
-    cfg = ModelConfig(
-        name="toy", family="toy", batch_buckets=[2], deadline_ms=10.0,
-        dtype="float32", num_classes=10, parallelism="single",
-        session_mode="recycle", relay_workers=1, relay_slots=2,
-        relay_epoch_images=4, relay_epoch_ms=300.0,
-        request_timeout_ms=30_000.0, quantize="int8", quantize_min_size=1024,
-    )
-    model = build(cfg)
-    pool = DeferredPool(cfg, model)
-    pool.prewarm()
-    loop = asyncio.new_event_loop()
-    loop.run_until_complete(pool.start())
-    try:
-        imgs = np.random.default_rng(5).integers(0, 255, (2, 8, 8, 3), np.uint8)
-
-        out = loop.run_until_complete(pool.run_deferred((2,), np.asarray(imgs)))
-        assert np.isfinite(out["probs"]).all()
-    finally:
-        loop.run_until_complete(pool.stop())
-        loop.close()
-
-
 def test_quantize_tree_is_idempotent():
     tree = {"k": np.random.default_rng(6).normal(size=(4096, 8)).astype(np.float32)}
     once = qz.quantize_tree(tree, min_size=1024)

@@ -84,9 +84,8 @@ def loop():
 def test_drain_stops_revival_machinery_before_flush(loop):
     """SIGTERM sequencing (ISSUE 8 satellite): drain() must stop the
     watchdog and the periodic canary BEFORE quiescing the batchers, so a
-    sweep can never revive a group loop (or background-respawn a deferred
-    worker) that the shutdown is intentionally stopping, and no canary can
-    inject new work after admission closed."""
+    sweep can never revive a group loop that the shutdown is intentionally
+    stopping, and no canary can inject new work after admission closed."""
     from tpuserve.server import ServerState
 
     cfg = ServerConfig(models=[_toy("toy")], decode_threads=2,
@@ -174,11 +173,9 @@ def test_estimate_clear_s_from_ewma(loop):
     loop.run_until_complete(go())
 
 
-def test_worker_config_derivation_and_recycle_rejection():
+def test_worker_config_derivation():
     """Worker configs derive once from the deployment config: loopback
-    bind, router recursion and the router-owned cache forced off; recycle
-    mode (its own process split, incompatible with daemonic workers) is
-    rejected up front."""
+    bind, router recursion and the router-owned cache forced off."""
     from tpuserve.workerproc.worker import worker_config
 
     cfg = ServerConfig(models=[_toy("toy")],
@@ -194,11 +191,6 @@ def test_worker_config_derivation_and_recycle_rejection():
     assert worker_config(cfg, 3).port == 9203
     cfg.worker.drain_timeout_s = 2.0
     assert worker_config(cfg, 0).drain_timeout_s == 2.0
-
-    bad = ServerConfig(models=[_toy("rc", session_mode="recycle")],
-                       router=RouterConfig(enabled=True))
-    with pytest.raises(ValueError, match="recycle"):
-        worker_config(bad, 0)
 
 
 # ---------------------------------------------------------------------------

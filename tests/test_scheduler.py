@@ -269,8 +269,6 @@ def test_scheduler_config_validation_and_toml(tmp_path):
         SchedulerConfig(window_s=0.0)
     with pytest.raises(ValueError, match="priority"):
         ModelConfig(name="m", priority="urgent")
-    with pytest.raises(ValueError, match="cold_start"):
-        ModelConfig(name="m", cold_start=True, session_mode="recycle")
     p = tmp_path / "sched.toml"
     p.write_text(
         "[scheduler]\n"

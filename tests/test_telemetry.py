@@ -272,26 +272,6 @@ def test_utilization_from_device_seconds_rate():
     assert stats["toy"]["mean_utilization"] == pytest.approx(0.5, abs=0.05)
 
 
-def test_bench_utilization_and_burn_helpers():
-    import bench
-
-    block = bench.utilization_block({0: 1.0, 1: 0.0},
-                                    {0: 9.0, 1: 4.0}, wall_s=10.0, n_chips=2)
-    assert block["per_replica"] == {"0": 0.8, "1": 0.4}
-    assert block["mean_utilization"] == pytest.approx(0.6)
-    assert block["device_seconds"] == pytest.approx(12.0)
-
-    m = Metrics(16)
-    h = m.histogram("latency_ms{model=resnet50,phase=total}")
-    before = h.snapshot()
-    for _ in range(99):
-        h.observe(1.0)
-    h.observe(10_000.0)
-    burn = bench.burn_from_snapshots(h.bounds, before, h.snapshot(),
-                                     objective_ms=100.0, availability=0.999)
-    assert burn == pytest.approx(10.0, rel=0.05)  # 1% bad / 0.1% budget
-
-
 # ---------------------------------------------------------------------------
 # Fleet merge
 # ---------------------------------------------------------------------------

@@ -255,7 +255,6 @@ def test_flush_reasons_target_and_timer():
     """A full bucket flushes for `target`; a lone item waits out the timer
     and flushes for `timer` (fixed-timer batching, so the target is the
     largest bucket)."""
-    import concurrent.futures as cf
 
     from tpuserve import models as modelzoo
     from tpuserve.batcher import ModelBatcher
@@ -266,11 +265,10 @@ def test_flush_reasons_target_and_timer():
     model = modelzoo.build(_bert_cfg(name="bertf", batch_buckets=[2],
                                      deadline_ms=20.0))
     rt = build_runtime(model, metrics=metrics)
-    pool = cf.ThreadPoolExecutor(2)
     item = model.host_decode(b'{"text": "x y"}', "application/json")
 
     async def go():
-        b = ModelBatcher(model, rt, metrics, pool,
+        b = ModelBatcher(model, rt, metrics,
                          adaptive_cfg=AdaptiveConfig(enabled=False))
         await b.start()
         try:
@@ -282,7 +280,6 @@ def test_flush_reasons_target_and_timer():
             await b.stop()
 
     asyncio.new_event_loop().run_until_complete(go())
-    pool.shutdown()
     c = metrics.counter_values()
     assert c["batcher_flushes_total{model=bertf,reason=target}"] == 1
     assert c["batcher_flushes_total{model=bertf,reason=timer}"] == 1

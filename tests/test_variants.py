@@ -186,16 +186,14 @@ def test_probe_raw_ms_and_h2d_sync():
 
 
 def test_batcher_start_propagates_h2d_sync(toy_cfg):
-    import concurrent.futures as cf
 
     from tpuserve.batcher import ModelBatcher
 
     model = build(toy_cfg)
     rt = build_runtime(model)
-    pool = cf.ThreadPoolExecutor(max_workers=2)
 
     async def go(sync: bool) -> bool:
-        b = ModelBatcher(model, rt, Metrics(), pool,
+        b = ModelBatcher(model, rt, Metrics(),
                          pipeline_cfg=PipelineConfig(h2d_sync=sync))
         await b.start()
         try:
@@ -205,7 +203,6 @@ def test_batcher_start_propagates_h2d_sync(toy_cfg):
 
     assert asyncio.run(go(True)) is True
     assert asyncio.run(go(False)) is False
-    pool.shutdown()
 
 
 def test_stats_roofline_block_over_http():

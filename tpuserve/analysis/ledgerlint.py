@@ -2,7 +2,7 @@
 
 The serving path owns four acquire/release ledgers — ``SlotArena`` /
 ``PageLedger`` (genserve slot blocks + paged KV), ``AssemblyArena``
-(recycled host batch buffers), ``SlotPool`` (staging / shm-slot
+(recycled host batch buffers), ``SlotPool`` (staging-slot
 admission) — and each already carries a runtime tripwire
 (``SlotCorrupted`` / ``PageCorrupted``) for double-release. This rule
 catches the *other* direction ahead of runtime: an acquisition that

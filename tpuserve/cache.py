@@ -24,7 +24,7 @@ tpuserve, sitting between ``handle_predict`` and ``ModelBatcher``:
   retries, PR 1) fans the error out and populates nothing.
 - **Honest accounting** — hits, misses, and coalesced waiters are disjoint
   counters so cache traffic can never masquerade as model throughput in a
-  bench (bench.py reports ``cache_hit_rate`` separately).
+  measurement.
 
 Threading: every method runs on the server's single asyncio event loop
 (handle_predict and future done-callbacks); there is deliberately no lock
@@ -116,8 +116,7 @@ class ModelCache:
                  version_fn: Callable[[], int]) -> None:
         self.name = name
         self.cfg = cfg
-        # Live weight-tree version (ModelRuntime.version); recycle-mode
-        # pools have no in-process version and pin 0.
+        # Live weight-tree version (ModelRuntime.version).
         self._version_fn = version_fn
         self._entries: dict[str, CacheEntry] = {}  # dicts iterate in LRU order
         self._flights: dict[str, _Flight] = {}
@@ -314,8 +313,8 @@ class ModelCache:
 
 def hit_rate(counters: dict[str, float]) -> float | None:
     """hits / (hits + misses + coalesced) from a counter snapshot or delta;
-    None when no cacheable traffic was seen. Shared by bench.py and the
-    cache smoke so the reported rate has one definition."""
+    None when no cacheable traffic was seen: the one definition of the
+    reported rate."""
     total = sum(counters.get(k, 0.0) for k in ("hits", "misses", "coalesced"))
     if total <= 0:
         return None

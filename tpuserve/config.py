@@ -30,7 +30,6 @@ FAULT_KINDS = (
     "batch_error",      # raise inside batch dispatch (batcher._execute)
     "slow_dispatch",    # sleep delay_ms inside batch dispatch
     "decode_corrupt",   # fail request decode -> HTTP 400
-    "worker_death",     # kill the active deferred worker process
     "canary_fail",      # fail the per-model canary probe
     "device_error",     # raise inside ModelRuntime.run (below the batcher)
     "slow_compute",     # sleep delay_ms inside ModelRuntime.run
@@ -1045,25 +1044,8 @@ class ModelConfig:
     num_classes: int = 1000
     # Device-section pipeline depth per replica (>=1): how many of this
     # model's batches occupy [h2d..fetch] staging slots at once. The
-    # server-wide [pipeline] block's `depth` overrides it when nonzero; in
-    # recycle mode it bounds batches between assembly and shm enqueue.
+    # server-wide [pipeline] block's `depth` overrides it when nonzero.
     max_inflight: int = 2
-    # Execution mode (SURVEY.md C5; tpuserve/deferred.py):
-    # - "direct":  per-batch dispatch + readback in-process (real TPU / CPU).
-    # - "recycle": deferred-readback worker pool — results are read back in
-    #   bulk once per epoch by single-use worker processes. A CPU-test
-    #   topology today: its workers each open the same device, which a chip
-    #   refuses (one process per chip).
-    session_mode: str = "direct"
-    # recycle mode: worker processes to pre-warm at startup.
-    relay_workers: int = 2
-    # recycle mode: epoch budget — a worker retires after this many image
-    # rows, or relay_epoch_ms after its first batch, whichever first. Bounds
-    # result latency.
-    relay_epoch_images: int = 4096
-    relay_epoch_ms: float = 2000.0
-    # recycle mode: per-worker shared-memory batch slots (in-flight batches).
-    relay_slots: int = 4
     # Default priority class for requests that carry no X-Priority header
     # ("interactive" or "batch"). Only consulted when the fleet scheduler
     # ([scheduler] enabled) arbitrates: under overload, batch-class work
@@ -1073,7 +1055,7 @@ class ModelConfig:
     # variants and device params are not built/resident until the first
     # request (or POST .../{name}:warm) stages them through the lifecycle
     # path, and [scheduler] idle_demote_s can demote them back, freeing
-    # HBM. Requires [scheduler] enabled and session_mode = "direct".
+    # HBM. Requires [scheduler] enabled.
     cold_start: bool = False
     # Result-cache eligibility: False keeps this model out of every result
     # cache (server-side ModelCache AND the router tier's wire-level cache).
@@ -1125,10 +1107,6 @@ class ModelConfig:
             raise ValueError(
                 f"stream_policy must be 'drop' or 'block', "
                 f"got {self.stream_policy!r}")
-        if self.cold_start and self.session_mode != "direct":
-            raise ValueError(
-                "cold_start requires session_mode = 'direct' (recycle-mode "
-                "workers own their params out of process)")
 
 
 @dataclass
@@ -1249,8 +1227,8 @@ class ServerConfig:
     faults: FaultsConfig = field(default_factory=FaultsConfig)
     # Versioned reload lifecycle (integrity checks, staged canary, rollback).
     lifecycle: LifecycleConfig = field(default_factory=LifecycleConfig)
-    # Watchdog sweep interval: restart dead group-accumulation tasks and reap
-    # dead deferred workers every this many seconds (0 disables).
+    # Watchdog sweep interval: restart dead group-accumulation tasks (and
+    # the generation engine's loop) every this many seconds (0 disables).
     watchdog_interval_s: float = 1.0
     # Graceful-drain budget on SIGTERM: new requests 503 immediately while
     # every accepted request gets this long to finish before hard stop.

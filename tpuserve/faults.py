@@ -1,7 +1,7 @@
 """Fault injection + recovery machinery (ISSUE 1; docs/ROBUSTNESS.md).
 
 At serving scale the common case is partial failure — a poisoned batch, a
-hung deferred worker, a dead group loop — not a clean crash. This module
+wedged worker process, a dead group loop — not a clean crash. This module
 holds both sides of that story:
 
 - **FaultInjector**: a deterministic, config-driven chaos layer (replacing
@@ -10,10 +10,9 @@ holds both sides of that story:
   site on the serving path — plus model / probability / count, and draw from
   rule-local seeded RNGs so a chaos run replays exactly. Call sites live in
   the batcher (batch_error, slow_dispatch, kill_group_loop), the runtime
-  (device_error, slow_compute), the deferred pool (worker_death), the
-  server (decode_corrupt, canary_fail, plus the process-boundary kinds
-  worker_slow / worker_hang / worker_crash that degrade, wedge, or
-  os._exit the serving process — behind the router split
+  (device_error, slow_compute), the server (decode_corrupt, canary_fail,
+  plus the process-boundary kinds worker_slow / worker_hang / worker_crash
+  that degrade, wedge, or os._exit the serving process — behind the router split
   (tpuserve.workerproc) they prove hedging/retry/supervision, drilled by
   ``tpuserve chaos --drill worker_kill``), and the reload lifecycle
   (reload_corrupt / reload_nan at the staging gates in
@@ -30,7 +29,8 @@ holds both sides of that story:
   holds its SLO.
 
 - **Watchdog**: periodic sweep that restarts dead group-accumulation tasks
-  and reaps/replenishes dead deferred workers, with restart counters in
+  and engine loops (and, behind the router, reaps dead worker processes and
+  schedules their respawn), with restart counters in
   ``/metrics`` (``watchdog_restarts_total{model=...,component=...}``).
 
 - **run_chaos**: the ``python -m tpuserve chaos`` backend — serve a
@@ -88,7 +88,7 @@ class FaultInjector:
     """Deterministic config-driven fault injection for the serving path.
 
     Thread-safe: call sites run on the event loop, in the decode/fetch
-    threadpool (runtime.run), and in deferred readers."""
+    threadpool (runtime.run)."""
 
     def __init__(self, cfg: FaultsConfig, metrics: Metrics | None = None) -> None:
         self.cfg = cfg
@@ -256,10 +256,10 @@ class CircuitBreaker:
 class Watchdog:
     """Periodic sweep restarting dead serving machinery.
 
-    Components register a sweep callable returning how many restarts (or
-    reaps of un-retired dead workers) it performed; non-zero sweeps land in
-    ``watchdog_restarts_total{model=...,component=...}``. Registered sweeps
-    run on the event loop and must be non-blocking."""
+    Components register a sweep callable returning how many restarts it
+    performed; non-zero sweeps land in
+    ``watchdog_restarts_total{model=...,component=...}``. Registered
+    sweeps run on the event loop and must be non-blocking."""
 
     def __init__(self, interval_s: float, metrics: Metrics) -> None:
         self.interval_s = interval_s

@@ -114,9 +114,9 @@ class GenerativeModel(ServingModel):
     def result_units(self, result: Any) -> float:
         """Headline output units one finished result carries — tokens for
         text, images for diffusion (default 1). Feeds the engine's
-        ``gen_units_total`` counter, which is what bench.py's generative
-        mode divides by wall time for its tokens/s / images-per-minute
-        headline (counting requests would hide mixed output lengths)."""
+        ``gen_units_total`` counter, which a measurement divides by wall
+        time for tokens/s or images per minute (counting requests would
+        hide mixed output lengths)."""
         return 1.0
 
     # -- paged KV contract (ISSUE 18) -----------------------------------------

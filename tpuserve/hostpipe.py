@@ -21,8 +21,7 @@ the batcher composes into that staged pipeline:
   memory, so the buffer must outlive the compute that reads it).
 - :class:`SlotPool` — a bounded pool of integer slots with async acquire.
   The batcher uses one per replica to keep a configurable depth-k of batches
-  in flight on the device ([h2d..fetch]); the deferred pool uses it for its
-  per-worker shared-memory batch slots (the shared staging-slot abstraction).
+  in flight on the device ([h2d..fetch]).
 - :class:`AdmissionGate` — the batcher's admission: a FIFO gate whose
   opening is a function of the device time still queued, so a batch closes
   when the device is about to need it rather than when a count frees.
@@ -59,8 +58,7 @@ class SlotPool:
     Event-loop-side only (no thread safety needed): ``acquire`` waits until a
     slot frees, bounded by ``timeout`` (raises ``asyncio.TimeoutError``);
     ``close`` wakes every waiter with :class:`SlotsClosed`. Construction
-    touches no event loop, so pools can be built from executor threads (the
-    deferred pool spawns workers off-loop).
+    touches no event loop.
 
     ``spare`` further slots are handed out only while ``spare_ok()`` holds:
     the batcher's device section counts ``n`` launches, but a launch of a
@@ -282,11 +280,10 @@ class AssemblyArena:
     """Preallocated host-batch buffers per bucket, recycled via a free-list.
 
     Buffers are pytrees of np arrays shaped like ``model.input_signature``
-    for the bucket (the host batch layout — the same contract the deferred
-    pool's shm slots rely on). ``acquire`` never blocks and never hands out a
-    buffer that is currently leased: when the per-bucket pool (``slots``
-    buffers, allocated lazily) is exhausted it falls back to a fresh
-    *overflow* allocation that is GC'd instead of pooled, counted in
+    for the bucket (the host batch layout). ``acquire`` never blocks and
+    never hands out a buffer that is currently leased: when the per-bucket
+    pool (``slots`` buffers, allocated lazily) is exhausted it falls back to
+    a fresh *overflow* allocation that is GC'd instead of pooled, counted in
     ``arena_overflow_total{model=}`` — persistent overflow means the arena is
     undersized relative to the admission depth ([pipeline] arena_slots)."""
 

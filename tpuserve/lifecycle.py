@@ -78,10 +78,8 @@ class ModelLifecycle:
     """Per-model version lifecycle manager.
 
     Owns the reload/rollback state machine for one served model. The server
-    constructs one per direct-mode runtime at start() and routes the admin
-    endpoints through it; recycle-mode (DeferredPool) models have no
-    in-process param tree to stage, so they get no lifecycle (reload 409s,
-    as before)."""
+    constructs one per runtime at start() and routes the admin endpoints
+    through it."""
 
     def __init__(self, name: str, runtime: Any, model: Any,
                  cfg: LifecycleConfig, metrics: Metrics,

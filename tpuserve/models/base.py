@@ -240,10 +240,9 @@ class ServingModel(abc.ABC):
         """Assemble into a preallocated host-batch buffer (arena recycling).
 
         ``out`` is a pytree of np arrays shaped like
-        ``input_signature(bucket)`` — the same host-batch contract the
-        deferred pool's shm slots rely on. Must produce exactly what
-        ``assemble`` would, writing in place: real rows copied, padded rows
-        zeroed. The batcher only uses this when it can prove equivalence
+        ``input_signature(bucket)``, a buffer of the batcher's
+        AssemblyArena. Must produce exactly what ``assemble`` would, writing
+        in place: real rows copied, padded rows zeroed. The batcher only uses this when it can prove equivalence
         (``assemble`` not overridden, or ``assemble_into`` overridden
         alongside it); families that customize ``assemble`` should override
         this too to keep the allocation-free hot path."""

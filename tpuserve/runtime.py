@@ -58,7 +58,7 @@ log = logging.getLogger("tpuserve.runtime")
 
 # Where the persistent XLA compile cache lives unless the environment places
 # it (configure_compile_cache): fixed to the checkout, so every process of
-# one command — server, restart, bench probe — reads what the first wrote.
+# one command — server, restart, probe child — reads what the first wrote.
 DEFAULT_COMPILE_CACHE = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jaxcache")
 
@@ -101,9 +101,9 @@ def check_backend(platform: str, requested: str) -> None:
 
 
 def configure_backend() -> None:
-    """The start-up rules of EVERY process that compiles — server, deferred
-    worker, bench probe, smoke child: place the compile cache, say which
-    backend this is, and refuse a CPU nobody asked for."""
+    """The start-up rules of EVERY process that compiles — server, router
+    worker, smoke child: place the compile cache, say which backend this
+    is, and refuse a CPU nobody asked for."""
     cache = configure_compile_cache()
     devs = jax.devices()
     log.info("backend: platform=%s device_kind=%s devices=%d "

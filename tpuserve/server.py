@@ -190,8 +190,7 @@ class ServerState:
         # built in build() so program compilation happens at startup).
         self.engines: dict[str, GenEngine] = {}
         self.breakers: dict[str, CircuitBreaker] = {}
-        # Versioned reload lifecycle (tpuserve.lifecycle); direct-mode
-        # runtimes only — recycle-mode workers own their params.
+        # Versioned reload lifecycle (tpuserve.lifecycle).
         self.lifecycles: dict[str, ModelLifecycle] = {}
         # Demand-shaping layer (tpuserve.cache): per-model result cache +
         # single-flight coalescing; empty unless [cache] enabled.
@@ -312,12 +311,10 @@ class ServerState:
         # mesh"): applied at the CONFIG level, before the model is built,
         # so family-level mode validation (e.g. BERT ring attention
         # rejecting replica) and the model's own batch_spec see the real
-        # serving mode. Recycle-mode models keep their own parallelism —
-        # their runtimes live in worker processes with one device each.
+        # serving mode.
         if self.cfg.parallel.mode:
             for mcfg in self.cfg.models:
-                if mcfg.session_mode != "recycle" \
-                        and mcfg.parallelism != self.cfg.parallel.mode:
+                if mcfg.parallelism != self.cfg.parallel.mode:
                     log.info("model %s: [parallel] mode overrides "
                              "parallelism %r -> %r", mcfg.name,
                              mcfg.parallelism, self.cfg.parallel.mode)
@@ -346,14 +343,6 @@ class ServerState:
                     rt = ModelRuntime(model, metrics=self.metrics,
                                       parallel=self.cfg.parallel)
                     rt.injector = self.injector
-                elif mcfg.session_mode == "recycle":
-                    # Deferred-readback worker pool (tpuserve.deferred): this
-                    # process never touches the accelerator; forked workers
-                    # own one PJRT session each.
-                    from tpuserve.deferred import DeferredPool
-
-                    rt = DeferredPool(mcfg, model, injector=self.injector)
-                    rt.prewarm()
                 elif self.cfg.genserve.enabled \
                         and getattr(model, "generative", False):
                     # Iteration-level engine (docs/PERFORMANCE.md "The
@@ -433,8 +422,6 @@ class ServerState:
             log.info("lock witness installed (TPUSERVE_LOCK_WITNESS)")
         for name, model in self.models.items():
             rt = self.runtimes[name]
-            if hasattr(rt, "enqueue"):  # DeferredPool: bind to the loop
-                await rt.start()
             br = CircuitBreaker(name, model.cfg.breaker_threshold,
                                 self.metrics,
                                 retry_after_s=model.cfg.breaker_retry_after_s)
@@ -448,7 +435,7 @@ class ServerState:
                 await eng.start()
                 b: "ModelBatcher | GenEngine" = eng
             else:
-                b = ModelBatcher(model, rt, self.metrics, self.pool,
+                b = ModelBatcher(model, rt, self.metrics,
                                  breaker=br, injector=self.injector,
                                  stages=self.stages,
                                  pipeline_cfg=self.cfg.pipeline,
@@ -459,40 +446,34 @@ class ServerState:
             model.bind_metrics(self.metrics)
             if self.cfg.cache.enabled and getattr(model, "cacheable", True):
                 # Keys carry the LIVE runtime version, so a lifecycle
-                # publish/rollback atomically invalidates older entries;
-                # recycle-mode pools have no in-process version and pin 0.
+                # publish/rollback atomically invalidates older entries.
                 # Models with cacheable = false never get a cache: their
                 # results are not a pure function of the decoded item.
                 self.caches[name] = ModelCache(
                     name, self.cfg.cache, self.metrics,
-                    version_fn=functools.partial(getattr, rt, "version", 0))
+                    version_fn=functools.partial(getattr, rt, "version"))
             self.watchdog.register(name, "group_loop", b.revive_group_loops)
-            if hasattr(rt, "watchdog_sweep"):
-                self.watchdog.register(name, "worker", rt.watchdog_sweep)
-            if hasattr(rt, "stage_params"):
-                # functools.partial, not a lambda: late binding would hand
-                # every lifecycle the last loop iteration's name. Engine
-                # models swap in the engine's staged canary: a SHORT
-                # generation end-to-end through the real compiled programs
-                # against the candidate tree.
-                self.lifecycles[name] = ModelLifecycle(
-                    name, rt, model, self.cfg.lifecycle, self.metrics,
-                    breaker=br,
-                    canary=functools.partial(self.run_canary, name),
-                    canary_status=functools.partial(self.canary_ok.get, name),
-                    injector=self.injector,
-                    staged_canary_fn=eng.staged_canary_sync
-                    if eng is not None else None)
+            # functools.partial, not a lambda: late binding would hand every
+            # lifecycle the last loop iteration's name. Engine models swap
+            # in the engine's staged canary: a SHORT generation end-to-end
+            # through the real compiled programs against the candidate tree.
+            lc = self.lifecycles[name] = ModelLifecycle(
+                name, rt, model, self.cfg.lifecycle, self.metrics,
+                breaker=br,
+                canary=functools.partial(self.run_canary, name),
+                canary_status=functools.partial(self.canary_ok.get, name),
+                injector=self.injector,
+                staged_canary_fn=eng.staged_canary_sync
+                if eng is not None else None)
             if self.scheduler is not None:
                 # Fleet registration: the scheduler reads each batcher's
                 # demand (pending, raw clear estimate, duration EWMAs) and
                 # feeds its device-seconds ledger from dispatch timings;
                 # cold models warm through the lifecycle's staged path so
                 # no request is ever answered by unvalidated weights.
-                lc = self.lifecycles.get(name)
                 self.scheduler.register(
                     name, batcher=b, mcfg=model.cfg, runtime=rt,
-                    warm_fn=lc.reload if lc is not None else None,
+                    warm_fn=lc.reload,
                     cold=bool(model.cfg.cold_start))
         if self.tenants is not None:
             # Tenant-partitioned cache capacity (ISSUE 16): each tenant's
@@ -677,13 +658,11 @@ class ServerState:
         accepted requests. Returns False if the budget expired first.
 
         The revival machinery stops FIRST: the watchdog must not revive a
-        group loop (or background-respawn a deferred worker) that this
-        drain is intentionally quiescing, and the periodic canary must not
-        inject new probe work after admission closed. The old ordering left
-        both running until state.stop() — a stop/revive race window where a
-        post-drain sweep could recreate machinery stop() was about to tear
-        down (and, for deferred pools, fork a multi-second replacement
-        worker nobody would ever use)."""
+        group loop that this drain is intentionally quiescing, and the
+        periodic canary must not inject new probe work after admission
+        closed. The old ordering left both running until state.stop() — a
+        stop/revive race window where a post-drain sweep could recreate
+        machinery stop() was about to tear down."""
         t_drain = time.perf_counter()
         await self.watchdog.stop()
         await self._stop_canary_loop()
@@ -700,11 +679,6 @@ class ServerState:
             # stop() is idempotent for the non-drain teardown path.
             await asyncio.get_running_loop().run_in_executor(
                 None, self.sampler.stop)
-        # Early-retire deferred epochs so pending futures resolve in
-        # readback time instead of at the epoch deadline.
-        for rt in self.runtimes.values():
-            if hasattr(rt, "retire_active"):
-                rt.retire_active()
         loop = asyncio.get_running_loop()
         deadline = loop.time() + self.cfg.drain_timeout_s
         ok = True
@@ -731,8 +705,6 @@ class ServerState:
         compute phase split into device-time vs host-wait."""
         out: dict = {}
         for name, rt in self.runtimes.items():
-            if not hasattr(rt, "variants"):
-                continue  # deferred pools own their executables out-of-process
             row: dict = {
                 "variants": rt.variants_summary(),
                 "compiles_total": rt.compiles_total,
@@ -767,8 +739,6 @@ class ServerState:
         has one mesh-wide count, reported with its per-chip share."""
         out: dict = {}
         for name, rt in self.runtimes.items():
-            if not hasattr(rt, "parallel_signature"):
-                continue  # deferred pools own their devices out-of-process
             batches = rt.replica_batches()
             out[name] = {
                 "mode": rt.mode,
@@ -844,17 +814,8 @@ class ServerState:
         for lc in self.lifecycles.values():
             lc.close()  # stop soak monitors
         await self._stop_canary_loop()
-        # Deferred pools first retire their active workers (fast) so batcher
-        # dispatch tasks awaiting epoch readback resolve in readback time,
-        # not at the epoch deadline; then drain batchers, then stop pools.
-        for rt in self.runtimes.values():
-            if hasattr(rt, "retire_active"):
-                rt.retire_active()
         for b in self.batchers.values():
             await b.stop()
-        for rt in self.runtimes.values():
-            if hasattr(rt, "enqueue"):
-                await rt.stop()
         self.stages.shutdown()
         self.pool.shutdown(wait=False, cancel_futures=True)
         if self.events is not None:
@@ -1819,9 +1780,7 @@ async def handle_reload(request: web.Request) -> web.Response:
     name = request.match_info["name"]
     if name not in state.runtimes:
         return _err(404, f"unknown model {name!r}")
-    lc = state.lifecycles.get(name)
-    if lc is None:
-        return _err(409, "weight reload is not supported in recycle mode")
+    lc = state.lifecycles[name]
     t0 = time.perf_counter()
 
     def _audit(outcome: str, **fields) -> None:
@@ -1857,9 +1816,7 @@ async def handle_rollback(request: web.Request) -> web.Response:
     name = request.match_info["name"]
     if name not in state.runtimes:
         return _err(404, f"unknown model {name!r}")
-    lc = state.lifecycles.get(name)
-    if lc is None:
-        return _err(409, "versioned lifecycle is not supported in recycle mode")
+    lc = state.lifecycles[name]
     t0 = time.perf_counter()
     try:
         info = await lc.rollback(reason="manual")
@@ -1885,9 +1842,7 @@ async def handle_versions(request: web.Request) -> web.Response:
     name = request.match_info["name"]
     if name not in state.runtimes:
         return _err(404, f"unknown model {name!r}")
-    lc = state.lifecycles.get(name)
-    if lc is None:
-        return _err(409, "versioned lifecycle is not supported in recycle mode")
+    lc = state.lifecycles[name]
     return web.json_response(lc.describe())
 
 

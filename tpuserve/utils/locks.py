@@ -6,7 +6,7 @@ raw primitives (zero overhead); with ``TPUSERVE_LOCK_WITNESS=1`` they return
 witness wrappers (tpuserve.analysis.witness) that maintain the global
 lock-order graph and raise on an inversion or a threading lock held across an
 ``await``. The ``name`` is the graph node: name the *role* at the creation
-site (``"obs.Metrics"``, ``"deferred.spawn"``) so every instance of one role
+site (``"obs.Metrics"``, ``"faults.FaultInjector"``) so every instance of one role
 shares a node and cross-instance inversions are still caught.
 """
 

@@ -339,7 +339,7 @@ class HostSupervisor:
         self.per_host = cfg.router.workers
         self.n = self.n_hosts * self.per_host
         # Derived once so every respawn (host or worker) serves identical
-        # config; recycle rejection fires here, at construction.
+        # config.
         self._worker_cfgs = [worker_config(cfg, i) for i in range(self.n)]
         self.hosts: list[HostHandle | None] = [None] * self.n_hosts
         # wid -> last known ref (kept across down/up so /stats can show a

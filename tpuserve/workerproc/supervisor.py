@@ -125,8 +125,7 @@ class WorkerSupervisor:
         self.metrics = metrics
         self.postmortems = postmortems
         self.n = cfg.router.workers
-        # Derived once so every respawn serves an identical config (and so
-        # recycle-mode rejection fires at construction, not mid-respawn).
+        # Derived once so every respawn serves an identical config.
         self._worker_cfgs = [worker_config(cfg, i) for i in range(self.n)]
         self.slots: list[WorkerHandle | None] = [None] * self.n
         self._fails = [0] * self.n          # consecutive failed boots
@@ -151,8 +150,7 @@ class WorkerSupervisor:
     async def start(self) -> None:
         """Spawn the fleet and start the health loop. The first worker
         boots alone so it populates the persistent compile cache and the
-        rest (and every future respawn) hit it — the deferred pool's
-        prewarm trick at process scale."""
+        rest (and every future respawn) hit it."""
         import aiohttp
 
         loop = asyncio.get_running_loop()

@@ -12,16 +12,12 @@ build:
 
 - binds ``[worker] host`` (loopback) on ``port_base + id`` or an ephemeral
   port, and reports the bound port to the supervisor over a pipe handshake
-  (``{"op": "ready", "port": ...}``) — the same handshake idiom as the
-  deferred pool's workers;
+  (``{"op": "ready", "port": ...}``);
 - the result cache is forced OFF: caching + single-flight coalescing are
   router-owned (one shared cache beats N private ones, and a cached answer
   must survive the worker that computed it);
 - ``[router]`` is forced off (a worker must never recurse into spawning
-  its own workers);
-- recycle-mode models are rejected up front: the deferred pool is its own
-  process-isolation story, and workers run as daemonic children which
-  cannot fork grandchildren.
+  its own workers).
 
 Deadlines cross the boundary as REMAINING budget (the gRPC convention):
 the router stamps the absolute deadline at admission and forwards
@@ -48,13 +44,6 @@ from tpuserve.telemetry.events import redirect_stderr, resolve_blackbox_dir
 
 def worker_config(cfg: ServerConfig, worker_id: int) -> ServerConfig:
     """Derive one worker's ServerConfig from the deployment config."""
-    for m in cfg.models:
-        if m.session_mode == "recycle":
-            raise ValueError(
-                f"model {m.name!r}: recycle-mode models cannot run behind "
-                "the router tier (the deferred pool is its own process "
-                "split, and daemonic workers cannot fork grandchildren); "
-                "serve them single-process")
     wcfg = copy.deepcopy(cfg)
     wcfg.host = cfg.worker.host
     wcfg.port = (cfg.worker.port_base + worker_id
