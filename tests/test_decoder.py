@@ -17,6 +17,7 @@ import pytest
 
 from tests import decoder_reference as ref
 from tpuserve.config import ModelConfig
+from tpuserve.genserve.model import PrefillPiece
 from tpuserve.models import build, seeded
 from tpuserve.models import decoder as dec
 from tpuserve.ops.moe import held_experts_swiglu, topk_route
@@ -57,28 +58,42 @@ def zeros(struct):
     return jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), struct)
 
 
-def serve(model, params, prompts, max_news, chunk=CHUNK, order=None):
-    """What the engine does, by hand: each prompt into its own slot in chunks,
-    then steps until every lane is done. Returns extract() per slot."""
+def serve(model, params, prompts, max_news, chunk=CHUNK, order=None, launches=None,
+          state=None, slots=SLOTS):
+    """What the engine does, by hand: the prompts' pieces through the prefill
+    program, then steps until every lane is done. ``launches`` is a list of
+    launches, each a list of (slot, start, length); without it each prompt
+    goes alone, a chunk a launch, in ``order``. ``state``: the block an earlier
+    call left, its pages and rings handed out again. Returns extract() per
+    slot, the last step's out-block and the state."""
     pps = model.kv_pages_per_slot(PAGE)
-    state = zeros(model.kv_page_signature(SLOTS, SLOTS * pps + 1, PAGE))
+    if state is None:
+        state = zeros(model.kv_page_signature(slots, slots * pps + 1, PAGE))
+    k = model.kv_prefill_pieces(chunk, PAGE)
     prefill = jax.jit(model.prefill_chunk, static_argnames=("chunk",))
     step = jax.jit(model.step)
-    for slot in order or range(len(prompts)):
+    if launches is None:
+        launches = [[(slot, start, min(chunk, len(prompts[slot]) - start))]
+                    for slot in order or range(len(prompts))
+                    for start in range(0, len(prompts[slot]), chunk)]
+
+    def piece(slot, start, length):
         ids = np.zeros((MAX_PROMPT,), np.int32)
         ids[: len(prompts[slot])] = prompts[slot]
         item = (ids, np.int32(len(prompts[slot])), np.int32(3), np.int32(max_news[slot]),
                 np.float32(0.0), np.int32(dec.LOGPROBS))
-        row = {"pages": np.arange(1 + slot * pps, 1 + (slot + 1) * pps, dtype=np.int32),
-               "ring": np.int32(slot + 1)}
-        for start in range(0, len(prompts[slot]), chunk):
-            state = prefill(params, state, np.int32(slot), item, np.int32(start), row,
-                            chunk=chunk)
+        cache = {"pages": np.arange(1 + slot * pps, 1 + (slot + 1) * pps, dtype=np.int32),
+                 "ring": np.int32(slot + 1)}
+        return PrefillPiece(slot, item, start, length, cache)
+
+    for pieces in launches:
+        state = prefill(params, state, model.pack_prefill([piece(*p) for p in pieces], chunk, k),
+                        chunk=chunk)
     for _ in range(max(max_news) + 1):
         state, out = step(params, state)
     assert bool(np.all(np.asarray(out["done"])[: len(prompts)]))
     return [jax.tree_util.tree_map(np.asarray, model.extract(params, state, np.int32(s)))
-            for s in range(len(prompts))], out
+            for s in range(len(prompts))], out, state
 
 
 @pytest.fixture(scope="module")
@@ -103,7 +118,7 @@ def test_chunked_prefill_then_decode_is_the_full_forward_pass(whole):
     rng = np.random.default_rng(5)
     prompts = [rng.integers(0, 96, n).astype(np.int32) for n in (19, 3, 24)]
     max_news = [12, 12, 7]
-    served, out = serve(model, params, prompts, max_news, order=[2, 0, 1])
+    served, out, _ = serve(model, params, prompts, max_news, order=[2, 0, 1])
     want = reference_log_probs(ARCH, prompts, served)
     for s, lp, n_new in zip(served, want, max_news):
         assert s["n_new"] == n_new
@@ -126,10 +141,79 @@ def test_chunked_prefill_then_decode_is_the_full_forward_pass(whole):
 def test_chunk_width_does_not_change_the_answer(whole, chunk):
     model, params = whole
     prompts = [np.arange(5, 22, dtype=np.int32)]
-    a, _ = serve(model, params, prompts, [6], chunk=CHUNK)
-    b, _ = serve(model, params, prompts, [6], chunk=chunk)
+    a, _, _ = serve(model, params, prompts, [6], chunk=CHUNK)
+    b, _, _ = serve(model, params, prompts, [6], chunk=chunk)
     assert np.array_equal(a[0]["tokens"], b[0]["tokens"])
     np.testing.assert_allclose(a[0]["lp"][:6], b[0]["lp"][:6], atol=1e-4)
+
+
+# -- a launch of several prompts' pieces (ISSUE 31) --------------------------------------
+# A launch of 16 rows in K = 4 tiles of one page (4); window 8. Each case: the
+# prompts' lengths and the launches as lists of (slot, start, length).
+PACKED = {
+    "a-K-short-prompts-in-one-launch":
+        ([3, 4, 2, 4], [[(0, 0, 3), (1, 0, 4), (2, 0, 2), (3, 0, 4)]]),
+    "b-a-long-tail-not-aligned-then-two-short":
+        ([23, 3, 4], [[(0, 0, 16)], [(0, 16, 7), (1, 0, 3), (2, 0, 4)]]),
+    "c-cut-at-a-start-no-multiple-of-chunk-or-window":
+        ([4, 21], [[(0, 0, 4), (1, 0, 12)], [(1, 12, 9)]]),
+    "d-longer-than-the-window-in-one-launch-and-a-ring-that-wraps-between":
+        ([14, 22], [[(0, 0, 14)], [(1, 0, 12)], [(1, 12, 10)]]),
+    "e-a-ring-and-pages-a-retired-request-left-full":
+        ([5, 7], [[(0, 0, 5), (1, 0, 7)]]),
+    "f-two-prompts-end-in-one-launch":
+        ([6, 3, 9], [[(2, 0, 8)], [(0, 0, 6), (1, 0, 3), (2, 8, 1)]]),
+}
+
+
+@pytest.mark.parametrize("case", list(PACKED))
+def test_a_packed_launch_is_each_prompt_alone(whole, case):
+    """Against the float32 reference and against one prompt a launch: every
+    generated position's log-probabilities and the tokens (the first and the
+    next 8), each request through its own lane."""
+    model, params = whole
+    lengths, launches = PACKED[case]
+    assert model.kv_prefill_pieces(16, PAGE) == 4
+    rng = np.random.default_rng(31)
+    prompts = [rng.integers(0, 96, n).astype(np.int32) for n in lengths]
+    max_news = [9, 7, 9, 9][: len(prompts)]
+    state = None
+    if case.startswith("e-"):  # three requests that fill every page and ring they hold
+        full = [rng.integers(0, 96, MAX_PROMPT).astype(np.int32) for _ in range(3)]
+        _, _, state = serve(model, params, full, [MAX_NEW] * 3, chunk=16, slots=4)
+    packed, out, _ = serve(model, params, prompts, max_news, chunk=16, launches=launches,
+                           state=state, slots=4)
+    alone, _, _ = serve(model, params, prompts, max_news, chunk=16, slots=4)
+    want = reference_log_probs(ARCH, prompts, packed)
+    for got, one, lp, n_new in zip(packed, alone, want, max_news):
+        assert got["n_new"] == n_new
+        at_ids = np.take_along_axis(lp, got["lp_ids"][:n_new].astype(np.int64), axis=-1)
+        np.testing.assert_allclose(got["lp"][:n_new], at_ids, atol=2e-4)
+        assert np.array_equal(got["tokens"][:n_new], np.argmax(lp, axis=-1))
+        assert np.array_equal(got["tokens"][:n_new], one["tokens"][:n_new])
+        np.testing.assert_allclose(got["lp"][:n_new], one["lp"][:n_new], atol=1e-4)
+    # the device's sums run over all pieces: every prompt token once, at its own position
+    if state is None:
+        acc = np.asarray(out["acc"]).astype(np.int64)
+        assert acc[0, 0] + acc[0, 1] == 4 * ARCH["num_experts_per_tok"] * sum(lengths)
+        assert acc[0, 4] == sum(n * (n + 1) // 2 for n in lengths)
+
+
+def test_pack_prefill_lays_pieces_at_whole_tiles_and_refuses_too_many(whole):
+    model, _ = whole
+    item = model.host_decode(json.dumps({"prompt_ids": list(range(1, 12))}).encode(),
+                             "application/json")
+    cache = {"pages": np.arange(1, 10, dtype=np.int32), "ring": np.int32(2)}
+    launch = model.pack_prefill(
+        [PrefillPiece(2, item, 3, 5, cache), PrefillPiece(0, item, 0, 2, cache)], 16, 4)
+    assert launch["ids"].tolist() == [4, 5, 6, 7, 8, 0, 0, 0, 1, 2] + [0] * 6
+    assert launch["slot"].tolist() == [2, 0, 0, 0] and launch["length"].tolist() == [5, 2, 0, 0]
+    assert launch["pages"].shape == (4, 9) and launch["ring"].tolist() == [2, 2, 0, 0]
+    with pytest.raises(ValueError, match="do not fit"):
+        model.pack_prefill([PrefillPiece(s, item, 0, 5, cache) for s in range(3)], 16, 4)
+    # K by the chunk and the page: whole pages a tile, at most MAX_PIECES of them
+    sizes = ((8, 4), (24, 4), (1024, 128), (2048, 128), (24, 5))
+    assert [model.kv_prefill_pieces(c, p) for c, p in sizes] == [2, 6, 8, 8, 1]
 
 
 # -- the shares add up -----------------------------------------------------------------
@@ -193,7 +277,7 @@ def test_a_share_serves_what_the_reference_gives_for_the_same_share(tmp_path):
     model = make_model(tmp_path, arch, name="share")
     params = model.init_params(jax.random.key(0))
     prompts = [np.random.default_rng(8).integers(0, 48, 13).astype(np.int32)]
-    served, _ = serve(model, params, prompts, [9])
+    served, _, _ = serve(model, params, prompts, [9])
     lp = reference_log_probs(arch, prompts, served)[0]
     got = served[0]["lp"][:9]
     np.testing.assert_allclose(
@@ -367,7 +451,7 @@ def test_through_the_engine_both_cache_kinds_come_back_and_the_counters_move(tmp
     assert eng.pages.n_reserved == 0 and eng.pages.n_reserved_rings == 0
     assert eng.pages.n_free_rings == SLOTS
     params = rt.params_per_mesh[0]
-    by_hand, _ = serve(model, params, [np.asarray(p, np.int32) for p in prompts[:3]], max_news[:3])
+    by_hand, _, _ = serve(model, params, [np.asarray(p, np.int32) for p in prompts[:3]], max_news[:3])
     for got, want, n in zip(results, by_hand, max_news):
         assert got["tokens"] == want["tokens"][:n].tolist() and got["n_tokens"] == n
         np.testing.assert_allclose(got["logprobs"]["values"], want["lp"][:n], atol=1e-4)

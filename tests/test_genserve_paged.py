@@ -418,3 +418,282 @@ def test_http_kv_pressure_503_and_stats():
             await client.close()
 
     run(go())
+
+
+# ---------------------------------------------------------------------------
+# Packed prefill (ISSUE 31): the engine's rule, an iteration at a time
+# ---------------------------------------------------------------------------
+# The tiny decoder takes K = 4 pieces a launch of 16 rows (tiles of one page,
+# 4); textgen takes one. The passes of _step_loop are called by hand, in its
+# order, so that what an iteration sees is exact.
+
+from tpuserve.genserve import engine as engine_mod  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def dec_rt(tmp_path_factory):
+    from tests.test_decoder import make_model
+    model = make_model(tmp_path_factory.mktemp("packed"), name="pk")
+    rt = build_runtime(model, compile_forward=False)
+    GenEngine(model, rt, Metrics(), GenserveConfig(
+        slots=6, kv_paging=True, kv_page_tokens=4, prefill_chunk=16)).compile()
+    return model, rt
+
+
+def dec_item(model, n, max_new=12, first=1):
+    body = {"prompt_ids": list(range(first, first + n)), "max_new_tokens": max_new}
+    return model.host_decode(json.dumps(body).encode(), "application/json")
+
+
+class ByHand:
+    """An engine whose loop does not run: `iterate` is one pass of it."""
+
+    def __init__(self, fix, hold, monkeypatch, **gc_over):
+        monkeypatch.setattr(engine_mod, "PREFILL_HOLD", hold)
+        self.eng, self.metrics = make_engine(fix, slots=gc_over.pop("slots", 6), **gc_over)
+        self.launches = []   # one list of (slot, start, length) a launch
+        sync = self.eng._prefill_sync
+
+        def recorded(pieces):
+            self.launches.append([(p.slot, p.start, p.length) for p in pieces])
+            sync(pieces)
+        self.eng._prefill_sync = recorded
+
+    async def __aenter__(self):
+        eng = self.eng
+        eng._state = eng._host_zeros(eng._state_struct)
+        eng._work_event, eng._idle_event = asyncio.Event(), asyncio.Event()
+        eng._running = True
+        return self
+
+    async def __aexit__(self, *exc):
+        await self.eng.stop()
+
+    async def iterate(self, n=1):
+        eng = self.eng
+        for _ in range(n):
+            eng._expire_pending()
+            eng._evict_expired()
+            await eng._admit()
+            await eng._advance_prefills()
+            if eng.arena.n_active:
+                out = await eng.stages.run(eng.name, "fetch", eng._step_sync)
+                eng._c_iterations.inc()
+                eng._count_step(out)
+                await eng._retire(out)
+
+    def count(self, family):
+        return self.metrics.counter(f"{family}{{model={self.eng.name}}}").value
+
+
+@pytest.mark.parametrize("hold", [0, 1, 2])
+def test_a_full_launch_goes_at_once_an_unfilled_one_waits_at_most_n(dec_rt, monkeypatch, hold):
+    model, _ = dec_rt
+
+    async def go():
+        async with ByHand(dec_rt, hold, monkeypatch, **paged_over(
+                kv_page_tokens=4, prefill_chunk=16)) as h:
+            eng = h.eng
+            assert eng._prefill_pieces == 4
+            a = eng.submit(dec_item(model, 5))
+            await h.iterate()
+            # no lane decodes yet: two tiles of four go at once, held by nothing
+            assert h.launches == [[(0, 0, 5)]] and h.count("gen_prefill_held_total") == 0
+            b = eng.submit(dec_item(model, 6, first=20))
+            for waited in range(hold):   # lane 0 decodes: the launch waits, N iterations at most
+                await h.iterate()
+                assert len(h.launches) == 1 and eng.arena.peek(1).meta["prefill_held"] == waited + 1
+                assert eng._decoding() and "prefill_next" in eng.arena.peek(1).meta
+            if hold == 0:
+                await h.iterate()
+                assert h.launches[1:] == [[(1, 0, 6)]]
+            c = eng.submit(dec_item(model, 7, first=40))
+            d = eng.submit(dec_item(model, 8, first=60))
+            await h.iterate()
+            if hold == 0:   # c and d fill a launch between them: at once
+                assert h.launches[2:] == [[(2, 0, 7), (3, 0, 8)]]
+            else:           # b, which waited, and c fill one: at once; d has waited 0 and waits
+                assert h.launches[1:] == [[(1, 0, 6), (2, 0, 7)]]
+            await h.iterate(hold + 1)
+            assert sum(len(x) for x in h.launches) == h.count("gen_prefill_pieces_total")
+            assert h.count("gen_prefill_chunks_total") == len(h.launches)
+            assert h.count("gen_prefill_tokens_total") == 5 + 6 + 7 + 8
+            assert sorted(p for x in h.launches for p in x) \
+                == [(0, 0, 5), (1, 0, 6), (2, 0, 7), (3, 0, 8)]
+            assert h.count("gen_prefill_held_total") == (0 if hold == 0 else 2)
+            await h.iterate(14)
+            res = await asyncio.gather(a, b, c, d)
+            assert [r["n_tokens"] for r in res] == [12] * 4
+            assert eng.pages.n_reserved == 0 and eng.pages.n_reserved_rings == 0
+            kv = eng.pipeline_stats()["kv"]
+            assert kv["prefill_pieces"] == 4 and kv["prefill_hold"] == hold
+            assert kv["pieces_per_launch"] == round(4 / len(h.launches), 3)
+            assert kv["tokens_per_launch"] == round(26 / len(h.launches), 1)
+
+    run(go())
+
+
+def test_order_of_admission_is_kept_and_a_long_prompt_advances_a_launch_an_iteration(
+        dec_rt, monkeypatch):
+    model, _ = dec_rt
+
+    async def go():
+        async with ByHand(dec_rt, 1, monkeypatch, **paged_over(
+                kv_page_tokens=4, prefill_chunk=16)) as h:
+            eng = h.eng
+            a = eng.submit(dec_item(model, 3))
+            await h.iterate()   # lane 0 decodes from here on
+            long_f = eng.submit(dec_item(model, 24, first=10))
+            short_f = eng.submit(dec_item(model, 3, first=50))
+            await h.iterate()
+            # the long prompt's first 16 tokens fill a launch: at once, before the short one,
+            # which is not starved behind it (it waits for the tail, an iteration, and no more)
+            assert h.launches[1:] == [[(1, 0, 16)]]
+            assert eng.arena.peek(1).meta["prefill_next"] == 16 and eng._prefilling == [1, 2]
+            await h.iterate()
+            assert h.launches[2:] == [[(1, 16, 8), (2, 0, 3)]]
+            assert eng._prefilling == [] and h.count("gen_prefill_held_total") == 1
+            # a piece that does not fit what is left of a launch is cut at a tile's edge
+            x = eng.submit(dec_item(model, 10, first=60))
+            y = eng.submit(dec_item(model, 9, first=70))
+            await h.iterate()
+            assert h.launches[3:] == [[(3, 0, 10), (4, 0, 4)]]
+            await h.iterate()   # the rest of y has not waited yet
+            await h.iterate()
+            assert h.launches[4:] == [[(4, 4, 5)]]
+            await h.iterate(14)
+            res = await asyncio.gather(a, long_f, short_f, x, y)
+            assert [r["n_tokens"] for r in res] == [12] * 5
+            return res
+
+    res = run(go())
+    # what was cut, held and packed is what each prompt gives alone
+    async def alone():
+        async with ByHand(dec_rt, 0, monkeypatch, **paged_over(
+                kv_page_tokens=4, prefill_chunk=16)) as h:
+            out = []
+            for n, first in ((3, 1), (24, 10), (3, 50), (10, 60), (9, 70)):
+                f = h.eng.submit(dec_item(model, n, first=first))
+                await h.iterate(15)
+                out.append(await f)
+            assert all(len(x) == 1 for x in h.launches)
+            return out
+
+    assert [r["tokens"] for r in res] == [r["tokens"] for r in run(alone())]
+
+
+@pytest.mark.parametrize("how", ["disconnect", "deadline"])
+def test_a_slot_that_goes_while_its_piece_waits_leaves_the_launch(dec_rt, monkeypatch, how):
+    import time
+
+    from tpuserve.batcher import DeadlineExceeded
+    model, _ = dec_rt
+
+    async def go():
+        async with ByHand(dec_rt, 2, monkeypatch, **paged_over(
+                kv_page_tokens=4, prefill_chunk=16)) as h:
+            eng = h.eng
+            a = eng.submit(dec_item(model, 3))
+            await h.iterate()
+            pages0, rings0 = eng.pages.n_free, eng.pages.n_free_rings
+            b = eng.submit(dec_item(model, 6, first=20),
+                           deadline_at=time.perf_counter() + 3600.0)
+            await h.iterate()
+            assert eng._prefilling == [1] and eng.pages.n_free < pages0   # b waits, holding pages
+            if how == "disconnect":
+                b.cancel()
+            else:
+                eng.arena.peek(1).deadline_at = time.perf_counter() - 1.0
+            c = eng.submit(dec_item(model, 5, first=40))
+            await h.iterate()
+            # b's pages and ring went back before c took its own; b's piece is in no launch
+            assert eng._prefilling == [1] and eng.arena.peek(1).item is not None
+            assert (1, 0, 6) not in [p for x in h.launches for p in x]
+            await h.iterate(16)
+            assert (await a)["n_tokens"] == 12 and (await c)["n_tokens"] == 12
+            if how == "deadline":
+                with pytest.raises(DeadlineExceeded):
+                    await b
+            assert [p for x in h.launches for p in x] == [(0, 0, 3), (1, 0, 5)]
+            assert eng.pages.n_free == eng.pages.usable and eng.pages.n_free_rings == rings0 + 1
+
+    run(go())
+
+
+def test_a_launch_that_raises_fails_everything_in_flight_and_the_engine_goes_on(
+        dec_rt, monkeypatch):
+    model, rt = dec_rt
+
+    async def go():
+        async with ByHand(dec_rt, 1, monkeypatch, **paged_over(
+                kv_page_tokens=4, prefill_chunk=16)) as h:
+            eng = h.eng
+            a = eng.submit(dec_item(model, 3))
+            await h.iterate()
+            b = eng.submit(dec_item(model, 8, first=20))
+            c = eng.submit(dec_item(model, 8, first=40))
+            real = rt.run_program
+
+            def boom(tag, *args, **kw):
+                if tag == "prefill":
+                    raise RuntimeError("device said no")
+                return real(tag, *args, **kw)
+            monkeypatch.setattr(rt, "run_program", boom)
+            await h.iterate()
+            # today's blast radius: the decoding lane and both pieces of the launch
+            for f in (a, b, c):
+                with pytest.raises(RuntimeError, match="device said no"):
+                    await f
+            assert eng.arena.n_active == 0 and eng._prefilling == []
+            assert eng.pages.n_free == eng.pages.usable
+            assert h.count("batch_errors_total") == 1 and h.count("gen_prefill_chunks_total") == 1
+            monkeypatch.setattr(rt, "run_program", real)
+            d = eng.submit(dec_item(model, 4, first=60))
+            await h.iterate(14)
+            assert (await d)["n_tokens"] == 12
+
+    run(go())
+
+
+def test_textgen_takes_one_prompt_a_launch_exactly_as_before(chunked_rt, monkeypatch):
+    """K = 1 through the same path: a launch a chunk a slot, starts at
+    multiples of the chunk, every launch full, nothing ever held."""
+    model, _ = chunked_rt
+
+    async def go():
+        async with ByHand(chunked_rt, 2, monkeypatch, slots=4, **paged_over(prefill_chunk=4)) as h:
+            eng = h.eng
+            assert eng._prefill_pieces == 1
+            items = [prompt_item(model, p, seed=s, max_new=n)
+                     for p, s, n in (("hi", 3, 8), (LONG16, 1, 4), ("hi there", 4, 4))]
+            n_a, _, n_b = (model.prompt_tokens(i) for i in items)
+            assert n_a <= 4 and n_b <= 8
+            a = eng.submit(items[0])
+            await h.iterate(2)   # decoding
+            long_f, b = eng.submit(items[1]), eng.submit(items[2])
+            await h.iterate(4)
+            # an iteration: the next chunk of every prefilling slot, each a launch of its own
+            want = [[(0, 0, n_a)]]
+            for start in range(0, 16, 4):
+                want.append([(1, start, 4)])
+                if start < n_b:
+                    want.append([(2, start, min(4, n_b - start))])
+            assert h.launches == want
+            assert h.count("gen_prefill_held_total") == 0
+            assert h.count("gen_prefill_pieces_total") == h.count("gen_prefill_chunks_total") \
+                == len(want)
+            assert h.count("gen_prefill_tokens_total") == n_a + 16 + n_b
+            await h.iterate(8)
+            return [(await f)["tokens"] for f in (a, long_f, b)]
+
+    packed = run(go())
+    eng, _ = make_engine(chunked_rt, **paged_over(prefill_chunk=4))
+
+    async def loop():
+        await eng.start()
+        out = [(await eng.submit(prompt_item(model, p, seed=s, max_new=n)))["tokens"]
+               for p, s, n in (("hi", 3, 8), (LONG16, 1, 4), ("hi there", 4, 4))]
+        await eng.stop()
+        return out
+
+    assert packed == run(loop())

@@ -51,7 +51,7 @@ import numpy as np
 from tpuserve.batcher import DeadlineExceeded, QueueFull
 from tpuserve.config import GenserveConfig, PipelineConfig
 from tpuserve.genserve.arena import SlotArena, SlotInfo
-from tpuserve.genserve.model import GenerativeModel
+from tpuserve.genserve.model import GenerativeModel, PrefillPiece
 from tpuserve.genserve.pages import PageLedger
 from tpuserve.hostpipe import StageExecutors
 from tpuserve.obs import (GEN_STREAM_REASONS, PRIORITIES, Metrics, trace_mark,
@@ -60,6 +60,14 @@ from tpuserve.utils.locks import new_lock
 from tpuserve.utils.retrace import allow_transfers, host_fetch
 
 log = logging.getLogger("tpuserve.genserve")
+
+# A prefill launch that is not full may wait this many iterations, counted
+# from its oldest piece, for more pieces to fill it while lanes decode
+# (ISSUE 31). Chosen by a chip sweep (PERF.md section 6, PR 31: 0, 1, 2 read
+# +5%, +16%, +19% requests/s in the generating cell); a request's first
+# token comes up to that many iterations later, which no benchmark metric
+# sees.
+PREFILL_HOLD = 2
 
 
 class KVPressure(QueueFull):
@@ -180,8 +188,12 @@ class GenEngine:
                      model.cfg.name)
         self.pages: PageLedger | None = None
         self._pps = 0            # block-table width (pages per max-ctx slot)
-        self._prefill_chunk = 0  # static chunk width of the prefill program
+        self._prefill_chunk = 0  # static width of the prefill program
+        self._prefill_pieces = 1  # prompts' pieces a launch takes (K)
+        self._prefill_tile = 0   # rows a tile: a piece takes whole tiles
         self._ring_tokens = 0    # window-ring length (0: the family has none)
+        # Slots with prompt left to launch, in order of admission.
+        self._prefilling: list[int] = []
         if self.paging:
             pt = self.gcfg.kv_page_tokens
             self._pps = int(model.kv_pages_per_slot(pt))
@@ -198,6 +210,14 @@ class GenEngine:
                 n_pages, pt, rings=self.slots + 1 if self._ring_tokens else 0)
             self._prefill_chunk = int(
                 model.kv_prefill_chunk(self.gcfg.prefill_chunk))
+            self._prefill_pieces = int(
+                model.kv_prefill_pieces(self._prefill_chunk, pt))
+            if self._prefill_pieces < 1 \
+                    or self._prefill_chunk % self._prefill_pieces:
+                raise ValueError(
+                    f"{model.cfg.name}: {self._prefill_pieces} pieces do not "
+                    f"divide a prefill launch of {self._prefill_chunk}")
+            self._prefill_tile = self._prefill_chunk // self._prefill_pieces
         # High-water active-slot mark (bench's max_concurrent_slots).
         self.peak_active = 0
         self._own_stages = stages is None
@@ -249,6 +269,12 @@ class GenEngine:
             f"gen_kv_page_utilization{{model={name}}}")
         self._c_prefill_chunks = metrics.counter(
             f"gen_prefill_chunks_total{{model={name}}}")
+        # A launch of the prefill program carries one or more prompts'
+        # pieces (ISSUE 31): chunks counts launches, pieces what they carried.
+        self._c_prefill_pieces = metrics.counter(
+            f"gen_prefill_pieces_total{{model={name}}}")
+        self._c_prefill_held = metrics.counter(
+            f"gen_prefill_held_total{{model={name}}}")
         self._c_kv_shed = metrics.sched_shed_counter(name, "kv_pressure")
         # What the two phases processed, and what the caches held while they
         # did (ISSUE 28): per-step sums, so a window's mean is a ratio of
@@ -345,8 +371,8 @@ class GenEngine:
                     f"{self.name}: runtime programs were compiled for "
                     f"geometry {prior}, engine wants {geometry}")
             return
-        item_struct = model.gen_item_signature()
         slot_struct = jax.ShapeDtypeStruct((), np.int32)
+        item = model.canary_item()
         # Sharded decode (ISSUE 20): on a sharded mesh the family may pin
         # state-block dims to mesh axes (textgen: KV heads on "model").
         # The SAME spec tree goes in as the state arg's sharding and out
@@ -366,21 +392,21 @@ class GenEngine:
                     "out_specs": sspecs}
 
         if self.paging:
-            start_struct = jax.ShapeDtypeStruct((), np.int32)
-            pages_struct = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                self._cache_row([], 0))
+            # ONE prefill program: a launch's shapes are what the family
+            # packs, whatever pieces it carries.
+            launch_struct = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(np.shape(a),
+                                               np.asarray(a).dtype),
+                self._canary_launches(item)[0])
             chunk = self._prefill_chunk
 
-            def prefill_fn(params, state, slot, item, start, pages):
-                return model.prefill_chunk(params, state, slot, item,
-                                           start, pages, chunk=chunk)
+            def prefill_fn(params, state, launch):
+                return model.prefill_chunk(params, state, launch, chunk=chunk)
 
             rt.register_program("prefill", prefill_fn,
-                                (self._state_struct, slot_struct,
-                                 item_struct, start_struct, pages_struct),
+                                (self._state_struct, launch_struct),
                                 width=self.slots, donate_argnums=(0,),
-                                **_specs(4))
+                                **_specs(1))
         else:
             def insert_fn(params, state, slot, item):
                 fresh = model.init_state(params, item)
@@ -391,7 +417,7 @@ class GenEngine:
 
             rt.register_program("insert", insert_fn,
                                 (self._state_struct, slot_struct,
-                                 item_struct),
+                                 model.gen_item_signature()),
                                 width=self.slots, donate_argnums=(0,),
                                 **_specs(2))
         step_specs = {} if sspecs is None else {
@@ -411,21 +437,13 @@ class GenEngine:
         # the chunked program loads too. EVERY replica mesh prewarms —
         # PJRT program load must come off replica k's first request too,
         # not just replica 0's.
-        item = model.canary_item()
         for r in range(getattr(rt, "n_replicas", 1)):
             state = self._host_zeros(self._state_struct)
             with self._dispatch_guard():
                 if self.paging:
-                    row = self._cache_row(list(range(1, self._pps + 1)), 1)
-                    n_prompt = model.prompt_tokens(item)
-                    start = 0
-                    while True:
-                        state = rt.run_program("prefill", state, np.int32(0),
-                                               item, np.int32(start), row,
+                    for launch in self._canary_launches(item):
+                        state = rt.run_program("prefill", state, launch,
                                                replica=r)
-                        start += self._prefill_chunk
-                        if start >= n_prompt:
-                            break
                 else:
                     state = rt.run_program("insert", state, np.int32(0),
                                            item, replica=r)
@@ -481,6 +499,7 @@ class GenEngine:
             self._terminate_stream(info.stream, "shutdown", str(err))
             if not info.future.done():
                 info.future.set_exception(err)
+        self._prefilling.clear()
         if self.pages is not None:
             self.pages.release_all()
             self._update_kv_gauges()
@@ -749,6 +768,8 @@ class GenEngine:
         if self.pages is not None and self.pages.holds(slot):
             self.pages.release(slot)
             self._update_kv_gauges()
+        if slot in self._prefilling:  # its waiting piece leaves the launch
+            self._prefilling.remove(slot)
         return self.arena.release(slot)
 
     def _update_kv_gauges(self) -> None:
@@ -776,6 +797,16 @@ class GenEngine:
         if not self._ring_tokens:
             return row
         return {"pages": row, "ring": np.int32(ring)}
+
+    def _canary_launches(self, item: Any) -> list:
+        """One request's whole prompt as the launches of a lone slot 0 over
+        the first pages and ring (compile's shapes and prewarm, the staged
+        canary): a launch a chunk."""
+        row = self._cache_row(list(range(1, self._pps + 1)), 1)
+        n, chunk = self.model.prompt_tokens(item), self._prefill_chunk
+        return [self.model.pack_prefill(
+            [PrefillPiece(0, item, s, min(chunk, n - s), row)], chunk,
+            self._prefill_pieces) for s in range(0, max(n, 1), chunk)]
 
     def _observe_pages(self, need: int) -> None:
         prev = self._ewma_pages
@@ -885,54 +916,97 @@ class GenEngine:
                 "insert", self._state, np.int32(slot), item,
                 replica=self.replica)
 
-    def _prefill_sync(self, slot: int, item: Any, start: int,
-                      pages_row: np.ndarray) -> None:
+    def _prefill_sync(self, pieces: "list[PrefillPiece]") -> None:
+        launch = self.model.pack_prefill(pieces, self._prefill_chunk,
+                                         self._prefill_pieces)
         with self._dispatch_guard(), trace_span(
-                "tpuserve.gen_prefill", model=self.name, slot=slot, start=start):
+                "tpuserve.gen_prefill", model=self.name, slot=pieces[0].slot,
+                start=pieces[0].start, pieces=len(pieces),
+                tokens=sum(p.length for p in pieces)):
             self._state = self.runtime.run_program(
-                "prefill", self._state, np.int32(slot), item,
-                np.int32(start), pages_row, replica=self.replica)
+                "prefill", self._state, launch, replica=self.replica)
 
-    async def _prefill_advance(self, slot: int, info: SlotInfo) -> None:
-        """Fold ONE more prompt chunk for a prefilling slot (runs on the
-        h2d stage like a dense insert). The compiled program arms the lane
-        for decode on the final chunk; the host cursor here is what tells
-        retire/step scheduling the slot is still mid-prefill."""
-        start = info.meta["prefill_next"]
-        await self.stages.run(self.name, "h2d", self._prefill_sync, slot,
-                              info.item, start, info.meta["pages_row"])
-        self._c_prefill_chunks.inc()
-        self._c_prefill_tokens.inc(
-            min(self._prefill_chunk, info.meta["prefill_n"] - start))
-        nxt = start + self._prefill_chunk
-        if nxt >= info.meta["prefill_n"]:
-            del info.meta["prefill_next"]  # prefill complete: decode owns it
-        else:
-            info.meta["prefill_next"] = nxt
+    def _pack_launches(self, pieces: "list[PrefillPiece]"
+                       ) -> "list[list[PrefillPiece]]":
+        """Waiting pieces, in order, into as few launches as hold them: a
+        launch has K tiles, a piece takes whole tiles, and a piece that does
+        not fit what is left of a launch is cut at a tile's edge. Only the
+        last launch can be short of full."""
+        k, tile = self._prefill_pieces, self._prefill_tile
+        launches: list[list[PrefillPiece]] = [[]]
+        room = k
+        for p in pieces:
+            start, left = p.start, p.length
+            while left > 0:
+                if room == 0:
+                    launches.append([])
+                    room = k
+                take = min(left, room * tile)
+                launches[-1].append(
+                    PrefillPiece(p.slot, p.item, start, take, p.cache))
+                room -= -(-take // tile)
+                start, left = start + take, left - take
+        return launches if launches[-1] else launches[:-1]
+
+    def _decoding(self) -> bool:
+        """Is any lane past its prefill (the step has live work to do)?"""
+        return any("prefill_next" not in self.arena.peek(s).meta
+                   for s in self.arena.active_slots())
 
     async def _advance_prefills(self) -> None:
-        """One chunk per prefilling slot per engine iteration, interleaved
+        """Once an iteration: every prefilling slot's next piece (at most a
+        launch's width of its prompt, so a long prompt advances as it did
+        alone and a short one is not starved behind it), in order of
+        admission, packed into launches. A full launch goes at once. The
+        last, if it is not full, goes too when no lane is decoding or its
+        oldest piece has waited ``PREFILL_HOLD`` iterations; else it waits
+        for the next iteration's pieces while the lanes step. Interleaved
         with decode steps (Orca's iteration-level scheduling applied to
-        prefill) — in-flight decoders see a bounded per-iteration stall
-        instead of a whole-prompt one."""
-        if self.pages is None:
+        prefill), in-flight decoders see a bounded per-iteration stall."""
+        if not self._prefilling:
             return
-        for slot in self.arena.active_slots():
+        pieces = []
+        for slot in self._prefilling:
             info = self.arena.peek(slot)
-            if "prefill_next" not in info.meta or info.future.done():
+            if info.future.done():  # abandoned: _evict_expired frees it
                 continue
+            start = info.meta["prefill_next"]
+            pieces.append(PrefillPiece(
+                slot, info.item, start,
+                min(self._prefill_chunk, info.meta["prefill_n"] - start),
+                info.meta["pages_row"]))
+        for launch in self._pack_launches(pieces):
+            metas = [self.arena.peek(p.slot).meta for p in launch]
+            waited = max(m["prefill_held"] for m in metas)
+            tiles = sum(-(-p.length // self._prefill_tile) for p in launch)
+            if tiles < self._prefill_pieces and waited < PREFILL_HOLD \
+                    and self._decoding():
+                for m in metas:
+                    m["prefill_held"] += 1
+                return
+            t0 = time.perf_counter()
             try:
-                await self._prefill_advance(slot, info)
+                await self.stages.run(self.name, "h2d", self._prefill_sync,
+                                      launch)
             except asyncio.CancelledError:
                 raise
-            except Exception as e:  # noqa: BLE001 — same blast radius as
-                # an insert failure: the block may be half-written.
-                self._release_slot(slot)
-                self._terminate_stream(info.stream, "engine_error", str(e))
-                if not info.future.done():
-                    info.future.set_exception(e)
+            except Exception as e:  # noqa: BLE001 — the block may be
+                # half-written: the blast radius of a failed insert.
                 await self._fail_active(e)
                 return
+            self._h_insert.observe((time.perf_counter() - t0) * 1e3)
+            self._c_prefill_chunks.inc()
+            self._c_prefill_pieces.inc(len(launch))
+            self._c_prefill_tokens.inc(sum(p.length for p in launch))
+            if waited:
+                self._c_prefill_held.inc()
+            for p, m in zip(launch, metas):
+                m["prefill_held"] = 0
+                m["prefill_next"] = p.start + p.length
+                if m["prefill_next"] >= m["prefill_n"]:
+                    # The program armed the lane: decode owns it now.
+                    del m["prefill_next"]
+                    self._prefilling.remove(p.slot)
 
     def _extract_sync(self, slot: int) -> Any:
         with self._dispatch_guard():
@@ -1078,9 +1152,13 @@ class GenEngine:
                     info.meta["pages_row"] = self._cache_row(
                         page_list, self.pages.ring_of(slot))
                     info.meta["prefill_n"] = n_prompt
-                    info.meta["prefill_next"] = 0
+                    info.meta["prefill_next"] = 0   # the launched cursor
+                    info.meta["prefill_held"] = 0   # iterations waited
+                    # Iterations its prefill can take: a tile at least every
+                    # iteration that does not hold it.
                     info.meta["prefill_chunks"] = \
-                        -(-n_prompt // self._prefill_chunk)
+                        -(-n_prompt // self._prefill_tile) \
+                        * (PREFILL_HOLD + 1)
                 if self.arena.n_active > self.peak_active:
                     self.peak_active = self.arena.n_active
                 wait_ms = (now - req.enqueued_at) * 1e3
@@ -1096,11 +1174,12 @@ class GenEngine:
                 trace_mark("tpuserve.gen_admit", now, t0, model=self.name,
                            slot=slot)
                 if self.pages is not None:
-                    # Paged fold-in is incremental: the FIRST prompt chunk
-                    # lands now, later chunks interleave with decode steps
-                    # (_advance_prefills) so a long prompt never stalls
-                    # the block for one monolithic prefill.
-                    await self._prefill_advance(slot, info)
+                    # Paged fold-in is the host's part only: the prompt's
+                    # pieces go to the device in _advance_prefills, packed
+                    # with whatever else waits and interleaved with decode
+                    # steps, so a long prompt never stalls the block for
+                    # one monolithic prefill.
+                    self._prefilling.append(slot)
                 else:
                     await self.stages.run(self.name, "h2d",
                                           self._insert_sync, slot, req.item)
@@ -1117,11 +1196,14 @@ class GenEngine:
                 await self._fail_active(e)
                 return
             insert_s = time.perf_counter() - t0
-            self._h_insert.observe(insert_s * 1e3, trace_id=trace_id)
+            if self.pages is None:  # paged: a launch's time, where it goes
+                self._h_insert.observe(insert_s * 1e3, trace_id=trace_id)
             if req.ctx is not None:
                 # "fold_in" = admitted into an ALREADY-generating block
                 # (the continuous-batching property); "admit" = joined a
-                # fresh one. Span covers the compiled insert program.
+                # fresh one. Span covers the compiled insert program (the
+                # paged path launches later: its span is the fold-in's
+                # moment).
                 wall = time.time()
                 req.ctx.span("fold_in" if fold else "admit",
                              wall - insert_s, wall, tid=self.name,
@@ -1246,6 +1328,7 @@ class GenEngine:
                 info.ctx.span("engine_failure", wall, wall, tid=self.name,
                               iterations=info.iterations,
                               error=type(e).__name__)
+        self._prefilling.clear()
         if self.pages is not None:
             self.pages.release_all()
             self._update_kv_gauges()
@@ -1268,16 +1351,9 @@ class GenEngine:
         state = self._host_zeros(self._state_struct)
         with self._dispatch_guard():
             if self.paging:
-                row = self._cache_row(list(range(1, self._pps + 1)), 1)
-                n_prompt = model.prompt_tokens(item)
-                start = 0
-                while True:
-                    state = rt.run_program("prefill", state, np.int32(0),
-                                           item, np.int32(start), row,
+                for launch in self._canary_launches(item):
+                    state = rt.run_program("prefill", state, launch,
                                            params_override=staged, replica=r)
-                    start += self._prefill_chunk
-                    if start >= n_prompt:
-                        break
             else:
                 state = rt.run_program("insert", state, np.int32(0), item,
                                        params_override=staged, replica=r)
@@ -1391,8 +1467,7 @@ class GenEngine:
         if self.pages is not None:
             stats["kv"] = {
                 **self.pages.stats(),
-                "prefill_chunk": self._prefill_chunk,
-                "prefill_chunks_total": self._c_prefill_chunks.value,
+                **self._prefill_stats(),
                 "queued_pages": self._queued_pages(),
                 "kv_bytes": self.kv_cache_bytes(),
             }
@@ -1401,6 +1476,25 @@ class GenEngine:
         # above and composes these) — uniform shape either way.
         stats["per_replica"] = [self.replica_row()]
         return stats
+
+    def _prefill_stats(self) -> dict:
+        """Launches of the prefill program and what they carried (the
+        counters are the model's: a group's members share them)."""
+        launches = self._c_prefill_chunks.value
+        return {
+            "prefill_chunk": self._prefill_chunk,
+            "prefill_pieces": self._prefill_pieces,
+            "prefill_hold": PREFILL_HOLD,
+            "prefill_chunks_total": launches,
+            "prefill_pieces_total": self._c_prefill_pieces.value,
+            "prefill_held_total": self._c_prefill_held.value,
+            "pieces_per_launch": round(
+                self._c_prefill_pieces.value / launches, 3)
+            if launches else None,
+            "tokens_per_launch": round(
+                self._c_prefill_tokens.value / launches, 1)
+            if launches else None,
+        }
 
     def replica_row(self) -> dict:
         """One engine's row of the /stats genserve ``per_replica`` block:
@@ -1659,8 +1753,7 @@ class GenEngineGroup:
                 "page_tokens": e0.pages.page_tokens,
                 "utilization": round(reserved / usable, 4) if usable else 0.0,
                 "acquires_total": sum(e.pages.acquires_total for e in paged),
-                "prefill_chunk": e0._prefill_chunk,
-                "prefill_chunks_total": e0._c_prefill_chunks.value,
+                **e0._prefill_stats(),
                 "queued_pages": sum(e._queued_pages() for e in paged),
                 "kv_bytes": self.kv_cache_bytes(),
             }

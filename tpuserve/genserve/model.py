@@ -35,9 +35,25 @@ from __future__ import annotations
 
 import abc
 import json
+from dataclasses import dataclass
 from typing import Any
 
+import numpy as np
+
 from tpuserve.models.base import ServingModel
+
+
+@dataclass
+class PrefillPiece:
+    """Tokens [start, start + length) of the prompt held by ``slot``, waiting
+    for a prefill launch. ``cache`` is what the program is told of the slot's
+    caches: its block-table row, or ``{"pages": row, "ring": index}`` for a
+    family with window rings."""
+    slot: int
+    item: Any
+    start: int
+    length: int
+    cache: Any
 
 
 class GenerativeModel(ServingModel):
@@ -147,7 +163,7 @@ class GenerativeModel(ServingModel):
         kind (ISSUE 28): a family with sliding-window layers keeps each
         slot's last ``window`` positions of those layers in one ring a slot,
         beside the full pages. The engine's ledger then hands out a ring
-        with the pages and ``prefill_chunk`` is given ``{"pages": row,
+        with the pages and a prefill piece's ``cache`` is ``{"pages": row,
         "ring": index}`` instead of the bare row. 0: no rings (the default)."""
         return 0
 
@@ -178,14 +194,38 @@ class GenerativeModel(ServingModel):
         prompt in one chunk)."""
         raise NotImplementedError
 
-    def prefill_chunk(self, params: Any, state: Any, slot: Any, item: Any,
-                      start: Any, pages: Any, *, chunk: int) -> Any:
-        """Jittable with TRACED slot/start/page indices, STATIC chunk
-        width: fold tokens [start, start+chunk) of one prompt into the
-        slot's pages and return the new state. The final chunk (start +
-        chunk >= prompt length) also samples the first token and arms the
-        lane for decode; earlier chunks leave the lane frozen
-        (done=True) so interleaved decode steps skip it."""
+    # Packed prefill (ISSUE 31). One launch of the prefill program has the
+    # static width ``chunk`` and carries the waiting PIECES of up to K
+    # prompts: a piece is tokens [start, start + length) of one slot's
+    # prompt, and takes whole tiles of ``chunk // K`` rows. The engine
+    # builds every launch from pieces, in order of admission, and hands a
+    # launch that is full to the device at once; one that is not full may
+    # wait a bounded number of iterations for more (engine.PREFILL_HOLD). A
+    # family declares K; K = 1 (the default) is one prompt a launch, for
+    # which every launch is full and nothing waits.
+
+    def kv_prefill_pieces(self, chunk: int, page_tokens: int) -> int:
+        """Host-side: K, how many prompts' pieces one launch of the prefill
+        program takes; it divides ``chunk``."""
+        return 1
+
+    def pack_prefill(self, pieces: "list[PrefillPiece]", chunk: int,
+                     k: int) -> Any:
+        """Host-side: the ``launch`` argument of ``prefill_chunk`` for these
+        pieces (1..K of them, of distinct slots, together at most K tiles):
+        a pytree of fixed-shape np arrays whatever the pieces are. The
+        default is the one-prompt launch: (slot, item, start, cache row)."""
+        (p,) = pieces
+        return (np.int32(p.slot), p.item, np.int32(p.start), p.cache)
+
+    def prefill_chunk(self, params: Any, state: Any, launch: Any, *,
+                      chunk: int) -> Any:
+        """Jittable with every index of ``launch`` TRACED and a STATIC
+        width: fold each piece's tokens into its slot's pages (and ring)
+        and return the new state. A piece that ends its prompt (start +
+        length >= prompt length) also samples that request's first token
+        and arms its lane for decode; an earlier piece leaves the lane
+        frozen so interleaved decode steps skip it."""
         raise NotImplementedError
 
     # -- streaming contract (ISSUE 17) ----------------------------------------

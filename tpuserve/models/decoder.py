@@ -54,7 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpuserve.config import ModelConfig
-from tpuserve.genserve.model import GenerativeModel
+from tpuserve.genserve.model import GenerativeModel, PrefillPiece
 from tpuserve.models import seeded
 from tpuserve.obs import GEN_PHASES
 from tpuserve.ops.moe import held_experts_swiglu, topk_route
@@ -62,6 +62,8 @@ from tpuserve.ops.moe import held_experts_swiglu, topk_route
 LOGPROBS = 8  # top log-probabilities kept per generated position
 ACC = 5       # device-side sums a phase (kv_page_signature says which)
 NEG = -1e9
+MAX_PIECES = 8    # prompts' pieces one prefill launch takes at most
+KEY_BLOCK = 1024  # key positions a block of a full layer's prefill attention
 
 # Standard deviations of the drawn tensors, by role (``weight_scales`` in the
 # config file overrides any). Projections keep a unit-RMS stream at unit RMS;
@@ -435,51 +437,86 @@ class DecoderServing(GenerativeModel):
         return tok, ids.astype(jnp.int32), lp
 
     # -- prefill ------------------------------------------------------------------
-    def _prefill_window(self, q, k, v, ring_k, ring_v, cpos, rpos, valid):
-        """A window layer's attention of one chunk: q, k, v (C, ., hd) at
-        positions ``cpos`` (``valid`` where they are prompt), over what the
-        ring held before the chunk (``ring_k``/``ring_v`` (W, KV, hd) at
-        positions ``rpos``, negative where nothing was written) and the
-        chunk itself. A chunk of several windows goes in blocks of W queries
-        against the block before (the ring, for the first) and their own: a
-        query sees no further back, so the scores are (C, 2W) a head and not
-        (C, W + C)."""
-        C, W = q.shape[0], self.window
-        if C > W and C % W == 0:
-            nb = C // W
+    # One launch of the static width C carries the waiting pieces of up to K
+    # prompts (ISSUE 31), in K tiles of T = C / K rows; a piece takes whole
+    # tiles, so a tile belongs to one prompt. Whatever a token passes
+    # through alone (embedding, norms, projections, the feed-forwards, the
+    # experts) runs once over the C packed rows; attention goes tile by tile,
+    # each over its own prompt's caches.
 
-            def blocks(own, first):
-                own = own.reshape((nb, W) + own.shape[1:])
-                return jnp.concatenate(
-                    [jnp.concatenate([first[None], own[:-1]], axis=0), own], axis=1)
+    def kv_prefill_pieces(self, chunk: int, page_tokens: int) -> int:
+        """K: tiles of whole pages, as many as divide the chunk, at most
+        ``MAX_PIECES``. (The window does not enter: a window layer's tile
+        reads its ring and the ``window`` rows before it whatever its width.)"""
+        return next((k for k in range(min(MAX_PIECES, max(1, chunk // page_tokens)), 1, -1)
+                     if chunk % (k * page_tokens) == 0), 1)
 
-            kpos = blocks(cpos, rpos)                                    # (nb, 2W)
-            ok = blocks(valid, jnp.ones((W,), bool)) & (kpos >= 0)
-            dist = cpos.reshape(nb, W)[:, :, None] - kpos[:, None, :]
-            mask = (dist >= 0) & (dist < W) & ok[:, None, :]
-            o = self._attend(q.reshape((nb, W) + q.shape[1:]), blocks(k, ring_k),
-                             blocks(v, ring_v), mask)
-            return o.reshape(q.shape)
-        kpos = jnp.concatenate([rpos, cpos])
-        dist = cpos[:, None] - kpos[None, :]
-        mask = (dist >= 0) & (dist < W) & (kpos >= 0)[None, :] \
-            & jnp.concatenate([jnp.ones((W,), bool), valid])[None, :]
-        return self._attend(q, jnp.concatenate([ring_k, k], axis=0),
-                            jnp.concatenate([ring_v, v], axis=0), mask)
+    def pack_prefill(self, pieces: list[PrefillPiece], chunk: int, k: int) -> Any:
+        """Host-side: what one launch is told of its pieces, each at the next
+        free tile: the packed token ids and, a piece, its slot, range, block-
+        table row, ring and the request's sampling parameters. Entries past
+        ``len(pieces)`` have length 0 and write nothing."""
+        tile = chunk // k
+        if sum(-(-p.length // tile) for p in pieces) > k:
+            raise ValueError(f"{self.name}: pieces of {[p.length for p in pieces]} tokens "
+                             f"do not fit a launch of {k} tiles of {tile}")
+        out = {"ids": np.zeros((chunk,), np.int32),
+               "pages": np.zeros((k, pieces[0].cache["pages"].shape[0]), np.int32),
+               **{f: np.zeros((k,), np.int32) for f in
+                  ("slot", "start", "length", "n", "seed", "max_new", "ring")},
+               "temp": np.zeros((k,), np.float32)}
+        at = 0
+        for j, p in enumerate(pieces):
+            ids, n, seed, max_new, temp, _want = p.item
+            out["ids"][at:at + p.length] = ids[p.start:p.start + p.length]
+            at += -(-p.length // tile) * tile
+            for f, v in (("slot", p.slot), ("start", p.start), ("length", p.length),
+                         ("n", n), ("seed", seed), ("max_new", max_new), ("temp", temp),
+                         ("ring", p.cache["ring"]), ("pages", p.cache["pages"])):
+                out[f][j] = v
+        return out
 
-    def _prefill_full(self, q, kp, vp, row, cpos, kv_limit):
-        """A full layer's attention of one chunk, q (C, H, hd) at positions
-        ``cpos``, over the slot's pages up to the chunk's own end: key blocks
-        of about a chunk's width, as many as the chunk's position needs (a
-        traced count: the first chunk of a prompt reads one block, not the
-        whole padded context), summed with a running softmax in float32."""
-        C, P, pps = q.shape[0], kp.shape[2], row.shape[0]
-        kb = -(-C // P)                       # pages a key block
+    def _prefill_window(self, q, k, v, ring_k, ring_v, qpos, rpos, kpos, ok):
+        """A window layer's attention of one launch, tile by tile: q (K, T,
+        H, hd) at positions ``qpos`` (K, T); k and v (C, KV, hd), the
+        launch's own rows at positions ``kpos`` (``ok`` (K, C) where a row is
+        a live token of the tile's prompt); ``ring_k``/``ring_v`` (K, W, KV,
+        hd), what each tile's ring held BEFORE the launch, at positions
+        ``rpos`` (K, W), negative where nothing was written. A tile sees its
+        ring and the W rows before its own last: a query sees no further
+        back, so the scores are (T, 2W + T) a head."""
+        n_tiles, T = qpos.shape
+        W = self.window
+        # Row r of the launch at W + r; tile t reads rows [tT - W, tT + T).
+        idx = jnp.arange(n_tiles)[:, None] * T + jnp.arange(W + T)[None, :]
+
+        def near(a, fill):
+            pad = jnp.full((W,) + a.shape[1:], fill, a.dtype)
+            return jnp.take(jnp.concatenate([pad, a], axis=0), idx, axis=0)
+
+        seen = jnp.take_along_axis(jnp.pad(ok, ((0, 0), (W, 0))), idx, axis=1)
+        keys_at = jnp.concatenate([rpos, near(kpos, -1)], axis=1)       # (K, 2W + T)
+        dist = qpos[:, :, None] - keys_at[:, None, :]
+        mask = (dist >= 0) & (dist < W) \
+            & jnp.concatenate([rpos >= 0, seen], axis=1)[:, None, :]
+        return self._attend(q, jnp.concatenate([ring_k, near(k, 0)], axis=1),
+                            jnp.concatenate([ring_v, near(v, 0)], axis=1), mask)
+
+    def _prefill_full(self, q, kp, vp, row, qpos, last):
+        """A full layer's attention of one tile, q (T, H, hd) at positions
+        ``qpos``, over its prompt's pages (block-table row ``row``) up to the
+        tile's last live position ``last``: key blocks of ``KEY_BLOCK``
+        positions, as many as that position needs (a traced count: a
+        prompt's first tile reads one block, not the padded context), summed
+        with a running softmax in float32. Every row of the launch is in the
+        pages before any tile reads them."""
+        T, P, pps = q.shape[0], kp.shape[2], row.shape[0]
+        kb = max(1, min(KEY_BLOCK // P, pps))     # pages a key block
         n_blocks = -(-pps // kb)
         rowp = jnp.pad(row, (0, n_blocks * kb - pps))
         g = q.shape[1] // self.kv
-        qg = q.reshape(C, self.kv, g, self.hd)
-        need = jnp.minimum((cpos[-1] // (kb * P)) + 1, n_blocks)
+        qg = q.reshape(T, self.kv, g, self.hd)
+        need = jnp.minimum(last // (kb * P) + 1, n_blocks)
 
         def body(j, carry):
             m, l, acc = carry
@@ -487,7 +524,7 @@ class DecoderServing(GenerativeModel):
             kblk = jnp.take(kp, pg, axis=1).reshape(self.kv, kb * P, self.hd)
             vblk = jnp.take(vp, pg, axis=1).reshape(self.kv, kb * P, self.hd)
             kpos = j * kb * P + jnp.arange(kb * P)
-            see = (kpos[None, :] <= cpos[:, None]) & (kpos[None, :] < kv_limit)
+            see = kpos[None, :] <= qpos[:, None]
             s = jnp.einsum("tkgd,kcd->kgtc", qg, kblk,
                            preferred_element_type=jnp.float32) * (self.hd ** -0.5)
             s = jnp.where(see[None, None], s, NEG)
@@ -499,80 +536,99 @@ class DecoderServing(GenerativeModel):
                 preferred_element_type=jnp.float32)
             return m2, l * scale + jnp.sum(p, axis=-1), acc
 
-        m0 = jnp.full((self.kv, g, C), NEG, jnp.float32)
+        m0 = jnp.full((self.kv, g, T), NEG, jnp.float32)
         _m, l, acc = jax.lax.fori_loop(
             0, need, body, (m0, jnp.zeros_like(m0),
-                            jnp.zeros((self.kv, g, C, self.hd), jnp.float32)))
+                            jnp.zeros((self.kv, g, T, self.hd), jnp.float32)))
         return (acc / l[..., None]).transpose(2, 0, 1, 3).reshape(q.shape)
 
-    def prefill_chunk(self, params: Any, state: Any, slot: Any, item: Any,
-                      start: Any, pages: Any, *, chunk: int) -> Any:
-        """Tokens [start, start + chunk) of one prompt, causal within the
-        chunk and over what earlier chunks left in the caches. ``pages`` is
-        ``{"pages": block-table row, "ring": the slot's ring}``."""
-        ids, n, seed, max_new, temp, _want = item
-        row, ring = pages["pages"], pages["ring"]
-        C, W = int(chunk), max(self.window, 1)  # W = 1: no window layer reads it
+    def prefill_chunk(self, params: Any, state: Any, launch: Any, *, chunk: int) -> Any:
+        """One launch of ``pack_prefill``: piece j is tokens [start[j],
+        start[j] + length[j]) of the prompt in slot[j], causal within the
+        piece and over what earlier launches left in that slot's caches. A
+        token sees its own prompt only, at its own positions; a piece that
+        ends its prompt samples the first token at its own last row and arms
+        its own lane."""
+        slot, start, length, n = (launch[f] for f in ("slot", "start", "length", "n"))
+        C, K = int(chunk), slot.shape[0]
+        T, W = C // K, max(self.window, 1)   # W = 1: no window layer reads it
         P = state["kf"][0].shape[2] if self.full_layers else 1
         pps = state["bt"].shape[1]
-        cpos = start + jnp.arange(C)
-        valid = cpos < n
-        x = jnp.take(params["embed"],
-                     jnp.take(ids, jnp.minimum(cpos, self.max_prompt - 1)), axis=0)
-        kv_limit = jnp.minimum(start + C, n)
-        w_page = jnp.where(valid, jnp.take(row, jnp.minimum(cpos // P, pps - 1)), 0)
+        # Tile t belongs to the piece whose run of tiles holds it (K: none).
+        n_tiles = -(-length // T)
+        tiles_to = jnp.cumsum(n_tiles)
+        tiles = jnp.arange(K)
+        piece = jnp.searchsorted(tiles_to, tiles, side="right")
+        has = piece < K
+        piece = jnp.minimum(piece, K - 1)
+        first_tile = tiles_to - n_tiles
+        end = jnp.where(has, (start + length)[piece], 0)                  # (K,) by tile
+        qpos = (start[piece] + (tiles - first_tile[piece]) * T)[:, None] \
+            + jnp.arange(T)[None, :]                                       # (K, T)
+        cpos, of_piece = qpos.reshape(C), jnp.repeat(piece, T)
+        valid = (qpos < end[:, None]).reshape(C)
+        last = jnp.maximum(jnp.minimum(qpos[:, -1], end - 1), 0)          # (K,) by tile
+        rows, rings = launch["pages"][piece], launch["ring"][piece]       # by tile
+        x = jnp.take(params["embed"], launch["ids"], axis=0)
+        w_page = jnp.where(valid, jnp.take_along_axis(
+            jnp.repeat(rows, T, axis=0), jnp.minimum(cpos // P, pps - 1)[:, None],
+            axis=1)[:, 0], 0)
         off = cpos % P
-        # Window layers: of several chunk positions that fall on one ring
-        # place only the last lands; the rest, and padding, go to ring 0.
-        w_ring = jnp.where(valid & (cpos >= kv_limit - W), ring, 0)
+        # Window layers: of a piece's positions that fall on one ring place
+        # only the last lands; the rest, and padding, go to ring 0.
+        w_ring = jnp.where(valid & (cpos >= jnp.repeat(end, T) - W),
+                           jnp.repeat(rings, T), 0)
         roff = cpos % W
-        # What the ring held before this chunk: place r has the newest
+        # What a ring held before this launch: place r has the newest
         # position <= start - 1 that is r modulo W.
-        rpos = (start - 1) - ((start - 1 - jnp.arange(W)) % W)
+        before = (start[piece] - 1)[:, None]
+        rpos = jnp.where(has[:, None], before - ((before - jnp.arange(W)[None, :]) % W), -1)
+        own = valid[None, :] & (of_piece[None, :] == piece[:, None]) & has[:, None]
         kf, vf, kw, vw = (list(state[k]) for k in ("kf", "vf", "kw", "vw"))
         stats = []
         for i in range(self.n_layers):
             lp = params[f"layer{i}"]
             q, k, v, gate = self._qkv(lp, i, rms_norm(x, lp["norm1"], self.eps), cpos)
+            qt = q.reshape((K, T) + q.shape[1:])
             if self.layer_types[i] == "full_attention":
                 j = self.full_layers.index(i)
                 kf[j] = self._write_pages(kf[j], w_page, off, k)
                 vf[j] = self._write_pages(vf[j], w_page, off, v)
-                o = self._prefill_full(q, kf[j], vf[j], row, cpos, kv_limit)
+                o = jax.lax.map(
+                    lambda a, kp=kf[j], vp=vf[j]: self._prefill_full(a[0], kp, vp, *a[1:]),
+                    (qt, rows, qpos, last))
             else:
                 j = self.win_layers.index(i)
-                o = self._prefill_window(q, k, v, jnp.take(kw[j], ring, axis=0),
-                                         jnp.take(vw[j], ring, axis=0), cpos, rpos, valid)
+                o = self._prefill_window(qt, k, v, jnp.take(kw[j], rings, axis=0),
+                                         jnp.take(vw[j], rings, axis=0), qpos, rpos,
+                                         cpos, own)
                 kw[j] = kw[j].at[w_ring, roff].set(k)
                 vw[j] = vw[j].at[w_ring, roff].set(v)
-            x = x + self._attn_out(lp, o, gate).astype(self.dtype)
+            x = x + self._attn_out(lp, o.reshape(q.shape), gate).astype(self.dtype)
             y, st = self._ffn(lp, i, rms_norm(x, lp["norm2"], self.eps), valid)
             if st is not None:
                 stats.append(st)
             x = x + y.astype(self.dtype)
-        h_last = jax.lax.dynamic_index_in_dim(
-            x, jnp.clip(n - 1 - start, 0, C - 1), 0, keepdims=True)
+        # Each piece that ends its prompt samples at its own last row; a
+        # piece of no tokens writes nothing (its slot is out of range).
+        is_final = (length > 0) & (start + length >= n)
+        h_last = jnp.take(x, jnp.clip(first_tile * T + n - 1 - start, 0, C - 1), axis=0)
         first, lp_ids, lp_vals = self._sample(
-            self._head(params, h_last), seed[None], n[None], temp[None])
-        is_final = (start + C) >= n
+            self._head(params, h_last), launch["seed"], n, launch["temp"])
         new = dict(state, kf=kf, vf=vf, kw=kw, vw=vw,
                    acc=self._accumulate(state["acc"], 0, stats,
                                         jnp.sum(jnp.where(valid, cpos + 1, 0))))
-        upd = jax.lax.dynamic_update_index_in_dim
-        new["bt"] = upd(state["bt"], row, slot, 0)
-        new["tokens"] = upd(state["tokens"],
-                            jnp.zeros((self.max_new,), jnp.int32).at[0].set(first[0]),
-                            slot, 0)
-        lane = {"ring": ring, "pos": jnp.where(is_final, n, 0),
-                "n_new": jnp.where(is_final, 1, 0), "last": first[0],
-                "armed": is_final, "done": is_final & (max_new <= 1),
-                "seed": seed, "max_new": max_new, "temp": temp}
-        for name, val in lane.items():
-            new[name] = upd(state[name], jnp.asarray(val).astype(state[name].dtype),
-                            slot, 0)
-        for name, val in (("lp_ids", lp_ids[0]), ("lp", lp_vals[0])):
-            new[name] = jax.lax.dynamic_update_slice(
-                state[name], val[None, None].astype(state[name].dtype), (slot, 0, 0))
+        at = jnp.where(length > 0, slot, state["pos"].shape[0])
+        lanes = {"bt": launch["pages"], "ring": launch["ring"],
+                 "tokens": jnp.zeros((K, self.max_new), jnp.int32).at[:, 0].set(first),
+                 "pos": jnp.where(is_final, n, 0), "n_new": jnp.where(is_final, 1, 0),
+                 "last": first, "armed": is_final,
+                 "done": is_final & (launch["max_new"] <= 1), "seed": launch["seed"],
+                 "max_new": launch["max_new"], "temp": launch["temp"]}
+        for name, val in lanes.items():
+            new[name] = state[name].at[at].set(val.astype(state[name].dtype), mode="drop")
+        for name, val in (("lp_ids", lp_ids), ("lp", lp_vals)):
+            new[name] = state[name].at[at, 0].set(val.astype(state[name].dtype), mode="drop")
         return new
 
     # -- decode -------------------------------------------------------------------

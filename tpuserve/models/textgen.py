@@ -493,8 +493,10 @@ class TextGenServing(GenerativeModel):
         return jax.lax.dynamic_update_index_in_dim(
             arr, jnp.asarray(value).astype(arr.dtype), slot, 0)
 
-    def prefill_chunk(self, params: Any, state: Any, slot: Any, item: Any,
-                      start: Any, pages: Any, *, chunk: int) -> Any:
+    def prefill_chunk(self, params: Any, state: Any, launch: Any, *,
+                      chunk: int) -> Any:
+        # One prompt a launch (the contract's default, K = 1).
+        slot, item, start, pages = launch
         # Whole-prompt chunk (the prefill_chunk = 0 default) routes through
         # init_state VERBATIM and only changes where K/V is stored, so
         # paged == dense token parity holds by construction.
