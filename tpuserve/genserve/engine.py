@@ -289,6 +289,8 @@ class GenEngine:
             f"gen_kv_ring_steps_total{{model={name}}}")
         self._g_kv_rings_free = metrics.gauge(
             f"gen_kv_rings_free{{model={name}}}")
+        self._g_state_bytes = metrics.gauge(
+            f"gen_state_bytes{{model={name}}}")
         self._default_priority = getattr(model.cfg, "priority", "interactive")
         self._h_qwait = {p: metrics.queue_wait_histogram(name, p)
                          for p in PRIORITIES}
@@ -347,6 +349,7 @@ class GenEngine:
             # makes — the zero-recompile obligation extends to page churn.
             self._state_struct = model.kv_page_signature(
                 self.slots, self.pages.pages, self.pages.page_tokens)
+            self._g_state_bytes.set(float(self.slot_state_bytes()))
         else:
             self._state_struct = model.state_signature(self.slots)
         geometry = {"kv_paging": self.paging, "slots": self.slots,
@@ -1470,6 +1473,9 @@ class GenEngine:
                 **self._prefill_stats(),
                 "queued_pages": self._queued_pages(),
                 "kv_bytes": self.kv_cache_bytes(),
+                # The third kind (ISSUE 32): a fixed block a slot, beside pages.
+                "state_bytes_per_slot": self.slot_state_bytes() // self.slots,
+                "state_bytes": self.slot_state_bytes(),
             }
         # Per-replica rows (ISSUE 20): one row for a single engine, one per
         # member for a GenEngineGroup (which overrides the aggregate keys
@@ -1511,6 +1517,16 @@ class GenEngine:
         if self.pages is not None:
             row["kv"] = self.pages.snapshot()
         return row
+
+    def slot_state_bytes(self) -> int:
+        """Device bytes of the leaves the family keeps as one block A SLOT
+        (``kv_slot_state``: a recurrent layer's state, the same size whatever
+        the context), all slots; 0 for a family without any."""
+        if not isinstance(self._state_struct, dict):
+            return 0
+        leaves = jax.tree_util.tree_leaves(
+            [self._state_struct.get(k) for k in self.model.kv_slot_state])
+        return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize for x in leaves)
 
     def kv_cache_bytes(self) -> int:
         """Device bytes the KV storage leaves occupy (dense slab k/v or the

@@ -167,6 +167,13 @@ class GenerativeModel(ServingModel):
         "ring": index}`` instead of the bare row. 0: no rings (the default)."""
         return 0
 
+    # The third cache kind (ISSUE 32): keys of ``kv_page_signature`` whose
+    # leaves are ONE BLOCK A SLOT (leading dimension ``slots``), the same size
+    # at any context: a recurrent layer's state. The family addresses them by
+    # slot and starts a request's first piece from zeros; the engine only
+    # reports their bytes (/stats ``kv.state_bytes_per_slot``, ``gen_state_bytes``).
+    kv_slot_state: tuple = ()
+
     def observe_step(self, step_out: dict) -> None:
         """Host-side, after every fetched step: a family that sums counts on
         the device (experts hit, context read) moves them into its own

@@ -16,6 +16,10 @@ Families (BASELINE.json ``configs``):
 - decoder        — a decoder-only language model built from a published
                    config.json: window and full attention, routed experts
                    with a share, two cache kinds (ISSUE 28)
+- hybrid         — a language model whose layers are single mixers by a
+                   pattern string (Mamba-2 state-space, attention, routed
+                   experts in a latent), built from a published config.json:
+                   a recurrent state a slot beside the paged KV (ISSUE 32)
 - toy            — a linear classifier for tests and drills
 """
 
@@ -35,6 +39,7 @@ _REGISTRY: dict[str, str] = {
     "sd15": "tpuserve.models.sd15",
     "textgen": "tpuserve.models.textgen",
     "decoder": "tpuserve.models.decoder",
+    "hybrid": "tpuserve.models.hybrid",
     "toy": "tpuserve.models.toy",
 }
 

@@ -1,0 +1,536 @@
+"""What the language-model families that serve through paged KV share
+(ISSUE 32; moved out of ``decoder.py`` so that ``hybrid.py`` is not a copy).
+
+``PagedLM`` is the part of a decoder-only model that is the same whatever its
+layers are: the model's ``config.json`` and what is served of it, the draw of
+every tensor by recipe, the per-lane block of the paged state, a launch's
+tile arithmetic (ISSUE 31's packed prefill), paged full attention for
+prefill tiles and for decode, page writes, the head, the sampler with its
+served log-probabilities, the arming of a lane and a step's token
+bookkeeping, the device's sums into counters, and the whole host side of a
+``:generate`` request. A family adds its layers: ``_tensors``, ``_gains``,
+``kv_page_signature``, ``prefill_chunk``, ``step``, ``bind_metrics``.
+
+A subclass sets, in its constructor: ``dtype``, ``d``, ``hd``, ``kv`` (KV
+heads held), ``eps``, ``vocab_full``, ``v_first``, ``vocab``, ``scales``, and
+calls ``_serve_options``.
+"""
+
+from __future__ import annotations
+
+import json
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from tpuserve.config import ModelConfig
+from tpuserve.genserve.model import GenerativeModel, PrefillPiece
+from tpuserve.models import seeded
+
+LOGPROBS = 8  # top log-probabilities kept per generated position
+NEG = -1e9
+MAX_PIECES = 8    # prompts' pieces one prefill launch takes at most
+KEY_BLOCK = 1024  # key positions a block of a full layer's prefill attention
+
+
+def rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+def _mm(a: jax.Array, w: jax.Array) -> jax.Array:
+    """Product in the served type with float32 accumulation."""
+    return jnp.matmul(a, w, preferred_element_type=jnp.float32)
+
+
+def read_config_file(cfg: ModelConfig) -> dict:
+    """The model's own ``config.json``, named by ``options.config_file``."""
+    if not cfg.options.get("config_file"):
+        raise ValueError(f"{cfg.name}: family {cfg.family} needs options.config_file "
+                         "(the model's config.json)")
+    with open(cfg.options["config_file"], encoding="utf-8") as f:
+        return json.load(f)
+
+
+def head_share(name: str, idx: int, of: int, heads: list[int], kv_full: int):
+    """``share.attention_heads = [index, of]``: chip ``index`` of ``of`` equal
+    parts of the query heads -> (held query heads by layer, their first, KV
+    heads held, the first of them). Where the chips outnumber the KV heads a
+    chip holds ONE, head ``index * kv_full // of``, which its neighbours hold
+    too (as every tensor-parallel server replicates them)."""
+    if any(h % of for h in heads) or (kv_full % of and of % kv_full):
+        raise ValueError(f"{name}: share.attention_heads = [{idx}, {of}] "
+                         "does not divide the head counts")
+    held = [h // of for h in heads]
+    kv = max(1, kv_full // of)
+    if any(h % kv for h in held):
+        raise ValueError(f"{name}: held query heads {held} do not "
+                         f"group over {kv} held KV heads")
+    return held, [idx * h for h in held], kv, idx * kv_full // of
+
+
+class PagedLM(GenerativeModel):
+    supports_kv_paging = True
+
+    def _serve_options(self, cfg: ModelConfig, a: dict) -> None:
+        """What is served of the model: the context, the draw's seed and
+        scales, and the counters' state (``acc`` columns: the family's)."""
+        o = cfg.options
+        self.max_prompt = int(o.get("max_prompt_tokens", 64))
+        self.max_new = int(o.get("max_new_tokens", 32))
+        self.max_ctx = self.max_prompt + self.max_new
+        seed = o.get("draw_weights_seed")
+        self.draw_seed = None if seed is None else int(seed)
+        self._counters: list | None = None
+        self._seen = np.zeros((2, self.ACC), np.uint32)
+
+    # -- params ---------------------------------------------------------------
+    def draw_params(self, seed: int) -> Any:
+        """Jittable: every tensor by the recipe of ``tpuserve.models.seeded``,
+        in the served type; norms' gains are ones."""
+        p: dict = {}
+
+        def put(path, value):
+            node = p
+            for key in path[:-1]:
+                node = node.setdefault(key, {})
+            node[path[-1]] = value
+
+        for path, shape in self._gains():
+            put(path, jnp.ones(shape, self.dtype))
+        for path, shape, full, start, scale, fan_in in self._tensors():
+            put(path, seeded.draw(seed, "/".join(path), shape, scale / math.sqrt(fan_in),
+                                  self.dtype, full_shape=full, start=start))
+        return p
+
+    def _drawn(self) -> Any:
+        return jax.jit(self.draw_params, static_argnums=0)(self.draw_seed or 0)
+
+    def init_params(self, rng: jax.Array) -> Any:
+        return self._drawn()
+
+    def device_params(self, device: Any) -> Any:
+        """The runtime's hook for weights that never cross the host: drawn on
+        ``device`` in one jitted call where ``draw_weights_seed`` is set."""
+        if self.draw_seed is None or self.cfg.weights:
+            return None
+        with jax.default_device(device):
+            return jax.block_until_ready(self._drawn())
+
+    # -- the locked-batch contract: not served ----------------------------------
+    def _paged_only(self, *_a, **_k):
+        raise NotImplementedError(
+            f"{self.name}: family {self.cfg.family} serves through the generation "
+            "engine alone: set [genserve] enabled = true and kv_paging = true")
+
+    input_signature = forward = host_postprocess = _paged_only
+    state_signature = init_state = _paged_only
+
+    # -- shapes -----------------------------------------------------------------
+    def gen_item_signature(self) -> Any:
+        i32 = jnp.int32
+        return (jax.ShapeDtypeStruct((self.max_prompt,), i32),  # held-row ids
+                jax.ShapeDtypeStruct((), i32),                  # prompt length
+                jax.ShapeDtypeStruct((), i32),                  # seed
+                jax.ShapeDtypeStruct((), i32),                  # max_new_tokens
+                jax.ShapeDtypeStruct((), jnp.float32),          # temperature
+                jax.ShapeDtypeStruct((), i32))                  # logprobs asked
+
+    def kv_pages_per_slot(self, page_tokens: int) -> int:
+        return -(-self.max_ctx // int(page_tokens))
+
+    def _lane_signature(self, slots: int, page_tokens: int) -> dict:
+        """The per-lane part of the paged state block: a slot's block-table
+        row, its position and sampling parameters, the tokens and
+        log-probabilities generated so far, and the device's sums."""
+        S = jax.ShapeDtypeStruct
+        i32, n = jnp.int32, self.max_new
+        return {
+            "bt": S((slots, self.kv_pages_per_slot(page_tokens)), i32),
+            "pos": S((slots,), i32), "n_new": S((slots,), i32),
+            "last": S((slots,), i32), "armed": S((slots,), jnp.bool_),
+            "done": S((slots,), jnp.bool_), "seed": S((slots,), i32),
+            "max_new": S((slots,), i32), "temp": S((slots,), jnp.float32),
+            "tokens": S((slots, n), i32),
+            "lp_ids": S((slots, n, LOGPROBS), i32),
+            "lp": S((slots, n, LOGPROBS), jnp.float32),
+            # Cumulative, wrapping; row 0 prefill chunks, row 1 decode steps
+            # (the family's ``bind_metrics`` says what each column sums).
+            "acc": S((2, self.ACC), jnp.uint32),
+        }
+
+    def pages_needed(self, item: Any, page_tokens: int) -> int:
+        return -(-(int(item[1]) + int(item[3])) // int(page_tokens))
+
+    def prompt_tokens(self, item: Any) -> int:
+        return int(item[1])
+
+    def kv_prefill_chunk(self, requested: int) -> int:
+        if requested <= 0 or requested >= self.max_prompt:
+            return self.max_prompt
+        return int(requested)
+
+    def gen_max_steps(self) -> int:
+        return self.max_new
+
+    # -- device math --------------------------------------------------------------
+    def _attend(self, q, k, v, mask):
+        """q (..., T, H, hd), k and v (..., C, KV, hd), mask (..., T, C) True
+        where a query may see a key -> (..., T, H, hd) in float32. Query head
+        h reads KV head h // (H / KV)."""
+        kvh = k.shape[-2]
+        g = q.shape[-2] // kvh
+        qg = q.reshape(q.shape[:-2] + (kvh, g, q.shape[-1]))
+        s = jnp.einsum("...tkgd,...ckd->...kgtc", qg, k,
+                       preferred_element_type=jnp.float32) * (self.hd ** -0.5)
+        s = jnp.where(mask[..., None, None, :, :], s, NEG)
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        o = jnp.einsum("...kgtc,...ckd->...tkgd", p, v,
+                       preferred_element_type=jnp.float32)
+        return o.reshape(q.shape)
+
+    @staticmethod
+    def _write_pages(pool, page, off, rows):
+        """``rows`` (T, KV, hd) into the pool (KV, pages, P, hd) at (page[t],
+        off[t]) of every KV head: as ONE scatter of rows into the pool seen
+        as (KV * pages * P, hd). (Scattered over two middle dimensions, the
+        compiler copied the whole pool to another layout and back, eight
+        times a step: 13 of a step's 33 ms, my chip run, PR 28.)"""
+        kv, n_pages, p_tokens, hd = pool.shape
+        at = (jnp.arange(kv)[None, :] * n_pages + page[:, None]) * p_tokens + off[:, None]
+        flat = pool.reshape(kv * n_pages * p_tokens, hd)
+        return flat.at[at.reshape(-1)].set(rows.reshape(-1, hd)).reshape(pool.shape)
+
+    def _head(self, params, x):
+        """(T, d) -> (T, vocab held) float32 logits."""
+        return _mm(rms_norm(x, params["norm_f"], self.eps), params["head"])
+
+    def _sample(self, logits, seed, position, temp):
+        """Greedy where temp == 0, Gumbel-max otherwise, keyed by the
+        request's seed and the position sampled for; also the top
+        log-probabilities of the distribution sampled from."""
+        def one(lg, sd, pos, t):
+            key = jax.random.fold_in(jax.random.fold_in(jax.random.key(0), sd), pos)
+            g = jax.random.gumbel(key, lg.shape, jnp.float32)
+            sampled = jnp.argmax(lg / jnp.where(t > 0, t, 1.0) + g)
+            return jnp.where(t > 0, sampled, jnp.argmax(lg)).astype(jnp.int32)
+
+        tok = jax.vmap(one)(logits, seed, position, temp)
+        lp, ids = jax.lax.top_k(jax.nn.log_softmax(logits, axis=-1), LOGPROBS)
+        return tok, ids.astype(jnp.int32), lp
+
+    # -- prefill ------------------------------------------------------------------
+    # One launch of the static width C carries the waiting pieces of up to K
+    # prompts (ISSUE 31), in K tiles of T = C / K rows; a piece takes whole
+    # tiles, so a tile belongs to one prompt. Whatever a token passes
+    # through alone (embedding, norms, projections, the feed-forwards, the
+    # experts) runs once over the C packed rows; what reads a prompt's own
+    # caches (attention, a scan's state) goes tile by tile.
+
+    def kv_prefill_pieces(self, chunk: int, page_tokens: int) -> int:
+        """K: tiles of whole pages, as many as divide the chunk, at most
+        ``MAX_PIECES``. (A window does not enter: a window layer's tile
+        reads its ring and the ``window`` rows before it whatever its width.)"""
+        return next((k for k in range(min(MAX_PIECES, max(1, chunk // page_tokens)), 1, -1)
+                     if chunk % (k * page_tokens) == 0), 1)
+
+    def pack_prefill(self, pieces: list[PrefillPiece], chunk: int, k: int) -> Any:
+        """Host-side: what one launch is told of its pieces, each at the next
+        free tile: the packed token ids and, a piece, its slot, range, block-
+        table row, ring (where the engine hands rings out) and the request's
+        sampling parameters. Entries past ``len(pieces)`` have length 0 and
+        write nothing."""
+        tile = chunk // k
+        if sum(-(-p.length // tile) for p in pieces) > k:
+            raise ValueError(f"{self.name}: pieces of {[p.length for p in pieces]} tokens "
+                             f"do not fit a launch of {k} tiles of {tile}")
+        ringed = isinstance(pieces[0].cache, dict)
+        rows = [p.cache["pages"] if ringed else p.cache for p in pieces]
+        out = {"ids": np.zeros((chunk,), np.int32),
+               "pages": np.zeros((k, rows[0].shape[0]), np.int32),
+               **{f: np.zeros((k,), np.int32) for f in
+                  ("slot", "start", "length", "n", "seed", "max_new")
+                  + (("ring",) if ringed else ())},
+               "temp": np.zeros((k,), np.float32)}
+        at = 0
+        for j, p in enumerate(pieces):
+            ids, n, seed, max_new, temp, _want = p.item
+            out["ids"][at:at + p.length] = ids[p.start:p.start + p.length]
+            at += -(-p.length // tile) * tile
+            for f, v in (("slot", p.slot), ("start", p.start), ("length", p.length),
+                         ("n", n), ("seed", seed), ("max_new", max_new), ("temp", temp),
+                         ("pages", rows[j])):
+                out[f][j] = v
+            if ringed:
+                out["ring"][j] = p.cache["ring"]
+        return out
+
+    @staticmethod
+    def _tiles(launch: Any, chunk: int) -> dict:
+        """A launch's tile arithmetic, all traced: tile t belongs to the
+        piece whose run of tiles holds it (``has`` False: to none), at
+        positions ``qpos`` (K, T) of that piece's prompt; ``valid`` (C,) marks
+        the rows that are live tokens, ``last`` (K,) a tile's last live
+        position, ``end`` (K,) by tile where its piece ends."""
+        slot, start, length = launch["slot"], launch["start"], launch["length"]
+        C, K = int(chunk), slot.shape[0]
+        T = C // K
+        n_tiles = -(-length // T)
+        tiles_to = jnp.cumsum(n_tiles)
+        tiles = jnp.arange(K)
+        piece = jnp.searchsorted(tiles_to, tiles, side="right")
+        has = piece < K
+        piece = jnp.minimum(piece, K - 1)
+        first_tile = tiles_to - n_tiles
+        end = jnp.where(has, (start + length)[piece], 0)                  # (K,) by tile
+        qpos = (start[piece] + (tiles - first_tile[piece]) * T)[:, None] \
+            + jnp.arange(T)[None, :]                                       # (K, T)
+        cpos = qpos.reshape(C)
+        return {"C": C, "K": K, "T": T, "piece": piece, "has": has, "tiles": tiles,
+                "n_tiles": n_tiles, "first_tile": first_tile, "end": end, "qpos": qpos,
+                "cpos": cpos, "of_piece": jnp.repeat(piece, T),
+                "valid": (qpos < end[:, None]).reshape(C),
+                "last": jnp.maximum(jnp.minimum(qpos[:, -1], end - 1), 0),
+                "rows": launch["pages"][piece]}
+
+    @staticmethod
+    def _page_of(t: dict, P: int, pps: int):
+        """Where a launch's rows go in the full pages: (page (C,), offset
+        (C,)); padding goes to the sentinel, page 0."""
+        cpos = t["cpos"]
+        page = jnp.where(t["valid"], jnp.take_along_axis(
+            jnp.repeat(t["rows"], t["T"], axis=0), jnp.minimum(cpos // P, pps - 1)[:, None],
+            axis=1)[:, 0], 0)
+        return page, cpos % P
+
+    def _prefill_full(self, q, kp, vp, row, qpos, last):
+        """A full layer's attention of one tile, q (T, H, hd) at positions
+        ``qpos``, over its prompt's pages (block-table row ``row``) up to the
+        tile's last live position ``last``: key blocks of ``KEY_BLOCK``
+        positions, as many as that position needs (a traced count: a
+        prompt's first tile reads one block, not the padded context), summed
+        with a running softmax in float32. Every row of the launch is in the
+        pages before any tile reads them."""
+        T, P, pps = q.shape[0], kp.shape[2], row.shape[0]
+        kb = max(1, min(KEY_BLOCK // P, pps))     # pages a key block
+        n_blocks = -(-pps // kb)
+        rowp = jnp.pad(row, (0, n_blocks * kb - pps))
+        g = q.shape[1] // self.kv
+        qg = q.reshape(T, self.kv, g, self.hd)
+        need = jnp.minimum(last // (kb * P) + 1, n_blocks)
+
+        def body(j, carry):
+            m, l, acc = carry
+            pg = jax.lax.dynamic_slice(rowp, (j * kb,), (kb,))
+            kblk = jnp.take(kp, pg, axis=1).reshape(self.kv, kb * P, self.hd)
+            vblk = jnp.take(vp, pg, axis=1).reshape(self.kv, kb * P, self.hd)
+            kpos = j * kb * P + jnp.arange(kb * P)
+            see = kpos[None, :] <= qpos[:, None]
+            s = jnp.einsum("tkgd,kcd->kgtc", qg, kblk,
+                           preferred_element_type=jnp.float32) * (self.hd ** -0.5)
+            s = jnp.where(see[None, None], s, NEG)
+            m2 = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m2[..., None])
+            scale = jnp.exp(m - m2)
+            acc = acc * scale[..., None] + jnp.einsum(
+                "kgtc,kcd->kgtd", p.astype(vblk.dtype), vblk,
+                preferred_element_type=jnp.float32)
+            return m2, l * scale + jnp.sum(p, axis=-1), acc
+
+        m0 = jnp.full((self.kv, g, T), NEG, jnp.float32)
+        _m, l, acc = jax.lax.fori_loop(
+            0, need, body, (m0, jnp.zeros_like(m0),
+                            jnp.zeros((self.kv, g, T, self.hd), jnp.float32)))
+        return (acc / l[..., None]).transpose(2, 0, 1, 3).reshape(q.shape)
+
+    def _prefill_full_tiles(self, qt, kp, vp, t: dict):
+        """``_prefill_full`` tile by tile: qt (K, T, H, hd) over each tile's
+        own prompt's pages."""
+        return jax.lax.map(
+            lambda a: self._prefill_full(a[0], kp, vp, *a[1:]),
+            (qt, t["rows"], t["qpos"], t["last"]))
+
+    def _arm(self, params, state, new: dict, launch: Any, t: dict, x, extra: dict) -> dict:
+        """The end of a prefill launch: each piece that ends its prompt
+        samples at its own last row and arms its own lane; a piece of no
+        tokens writes nothing (its slot is out of range). ``extra``: further
+        lanes the family keeps (a ring's index)."""
+        slot, start, length, n = (launch[f] for f in ("slot", "start", "length", "n"))
+        K, T, C = t["K"], t["T"], t["C"]
+        is_final = (length > 0) & (start + length >= n)
+        h_last = jnp.take(x, jnp.clip(t["first_tile"] * T + n - 1 - start, 0, C - 1), axis=0)
+        first, lp_ids, lp_vals = self._sample(
+            self._head(params, h_last), launch["seed"], n, launch["temp"])
+        at = jnp.where(length > 0, slot, state["pos"].shape[0])
+        lanes = {"bt": launch["pages"], **extra,
+                 "tokens": jnp.zeros((K, self.max_new), jnp.int32).at[:, 0].set(first),
+                 "pos": jnp.where(is_final, n, 0), "n_new": jnp.where(is_final, 1, 0),
+                 "last": first, "armed": is_final,
+                 "done": is_final & (launch["max_new"] <= 1), "seed": launch["seed"],
+                 "max_new": launch["max_new"], "temp": launch["temp"]}
+        for name, val in lanes.items():
+            new[name] = state[name].at[at].set(val.astype(state[name].dtype), mode="drop")
+        for name, val in (("lp_ids", lp_ids), ("lp", lp_vals)):
+            new[name] = state[name].at[at, 0].set(val.astype(state[name].dtype), mode="drop")
+        return new
+
+    # -- decode -------------------------------------------------------------------
+    def _decode_full(self, q, kp, vp, bt, pos):
+        """One full layer's decode attention through the block table: q
+        (b, H, hd), pages (KV, pages, P, hd), bt (b, pps) -> (b, H, hd)
+        float32. On the TPU a kernel that reads live pages only; elsewhere
+        (tests, toys) a gather of the padded block table."""
+        on_tpu = jax.default_backend() == "tpu" and self.dtype == jnp.bfloat16 \
+            and self.hd % 128 == 0 and kp.shape[2] % 8 == 0
+        if on_tpu:  # tps-ok[TPS503]: backend and static shapes, at trace time
+            # The Pallas paged-attention kernel (my chip runs, PR 28: 0.8 ms a
+            # layer for 128 lanes holding 172,000 positions, within 0.002 of
+            # plain attention). It does not scale the scores, so the queries are.
+            from jax.experimental.pallas.ops.tpu.paged_attention import \
+                paged_attention
+
+            ppcb = max(c for c in range(1, 33) if bt.shape[1] % c == 0)
+            qs = (q.astype(jnp.float32) * (self.hd ** -0.5)).astype(q.dtype)
+            return paged_attention(qs, kp, vp, pos + 1, bt,
+                                   pages_per_compute_block=ppcb
+                                   ).astype(jnp.float32)
+        b, (P, pps) = q.shape[0], (kp.shape[2], bt.shape[1])
+        kc = jnp.take(kp, bt, axis=1).reshape(self.kv, b, pps * P, self.hd)
+        vc = jnp.take(vp, bt, axis=1).reshape(self.kv, b, pps * P, self.hd)
+        mask = (jnp.arange(pps * P)[None, :] <= pos[:, None])[:, None, :]
+        return self._attend(q[:, None], kc.transpose(1, 2, 0, 3),
+                            vc.transpose(1, 2, 0, 3), mask)[:, 0]
+
+    def _emit(self, params, state, new: dict, x, live, pos, acc) -> tuple[Any, dict]:
+        """The end of a decode step: every live lane samples its next token
+        from its last row ``x`` (b, d), keeps it with its log-probabilities,
+        and moves on; the others stay as they were."""
+        rows = jnp.arange(pos.shape[0])
+        nxt = jnp.clip(pos + 1, 0, self.max_ctx - 1)
+        tok, lp_ids, lp_vals = self._sample(self._head(params, x), state["seed"],
+                                            nxt, state["temp"])
+        n_new = state["n_new"]
+        at = jnp.clip(n_new, 0, self.max_new - 1)
+        keep = ~live
+        tokens = state["tokens"].at[rows, at].set(
+            jnp.where(keep, state["tokens"][rows, at], tok))
+        new_lp_ids = state["lp_ids"].at[rows, at].set(
+            jnp.where(keep[:, None], state["lp_ids"][rows, at], lp_ids))
+        new_lp = state["lp"].at[rows, at].set(
+            jnp.where(keep[:, None], state["lp"][rows, at], lp_vals))
+        n_new2 = jnp.where(live, n_new + 1, n_new)
+        done2 = state["done"] | (live & (n_new2 >= state["max_new"]))
+        new = dict(new, tokens=tokens, lp_ids=new_lp_ids, lp=new_lp, n_new=n_new2,
+                   done=done2, pos=jnp.where(live, nxt, state["pos"]),
+                   last=jnp.where(live, tok, state["last"]), acc=acc)
+        return new, {"done": done2 | ~state["armed"], "n_new": n_new2,
+                     "first": tokens[:, 0], "last": new["last"], "acc": acc}
+
+    def extract(self, params: Any, state: Any, slot: Any) -> Any:
+        idx = jax.lax.dynamic_index_in_dim
+        return {k: idx(state[k], slot, 0, keepdims=False)
+                for k in ("tokens", "n_new", "lp_ids", "lp")}
+
+    # -- host side ----------------------------------------------------------------
+    def observe_step(self, step_out: dict) -> None:
+        """The device's cumulative counts (prefill chunks and steps since
+        the last fetch) into the program's counters (``bind_metrics``: a
+        counter a phase and a column of ``acc``, None where a column means
+        nothing in a phase)."""
+        if self._counters is None:
+            return
+        now = np.asarray(step_out["acc"], np.uint32)
+        delta = now - self._seen  # wraps as the device's sums do
+        # A sum that went "back" by more than half the range did not wrap:
+        # the engine rebuilt its state block from zeros.
+        delta = np.where(delta > np.uint32(2 ** 31), now, delta)
+        self._seen = now
+        for row, counters in zip(delta, self._counters):
+            for v, c in zip(row, counters):
+                if v and c is not None:
+                    c.inc(float(v))
+
+    def _expert_counters(self, metrics: Any, ph: str) -> list:
+        """The counters of ``acc``'s first five columns in phase ``ph``: the
+        expert layers' four and the context read."""
+        name = self.name
+        return [metrics.counter(f"moe_tokens_routed_total{{model={name},phase={ph},held=yes}}"),
+                metrics.counter(f"moe_tokens_routed_total{{model={name},phase={ph},held=no}}"),
+                metrics.counter(f"moe_experts_hit_total{{model={name},phase={ph}}}"),
+                metrics.counter(f"moe_expert_steps_total{{model={name},phase={ph}}}"),
+                metrics.counter(f"gen_context_tokens_total{{model={name},phase={ph}}}")]
+
+    def host_decode(self, payload: bytes, content_type: str) -> Any:
+        body = json.loads(payload.decode("utf-8"))
+        ids = body.get("prompt_ids") if isinstance(body, dict) else None
+        if not isinstance(ids, list) or not ids \
+                or not all(isinstance(t, int) and not isinstance(t, bool) for t in ids):
+            raise ValueError('JSON body must contain "prompt_ids": a non-empty '
+                             "list of token ids")
+        if len(ids) > self.max_prompt:
+            raise ValueError(f"prompt of {len(ids)} tokens; this server takes up "
+                             f"to {self.max_prompt}")
+        arr = np.asarray(ids, np.int64) - self.v_first
+        if arr.min() < 0 or arr.max() >= self.vocab:
+            raise ValueError(
+                f"prompt_ids must lie in the vocabulary rows held here, "
+                f"[{self.v_first}, {self.v_first + self.vocab})")
+        max_new = int(body.get("max_new_tokens", self.max_new))
+        temp = float(body.get("temperature", 0.0))
+        want = int(body.get("logprobs", 0) or 0)
+        if not 1 <= max_new <= self.max_new:
+            raise ValueError(f"max_new_tokens must be in [1, {self.max_new}], "
+                             f"got {max_new}")
+        if temp < 0:
+            raise ValueError(f"temperature must be >= 0, got {temp}")
+        if not 0 <= want <= LOGPROBS:
+            raise ValueError(f"logprobs must be in [0, {LOGPROBS}], got {want}")
+        padded = np.zeros((self.max_prompt,), np.int32)
+        padded[: len(ids)] = arr
+        # Every parameter of the answer is part of the item: the result
+        # cache digests the whole tuple.
+        return (padded, np.int32(len(ids)), np.int32(int(body.get("seed", 0))),
+                np.int32(max_new), np.float32(temp), np.int32(want))
+
+    def canary_item(self) -> Any:
+        body = {"prompt_ids": [self.v_first], "seed": 1, "max_new_tokens": 2}
+        return self.host_decode(json.dumps(body).encode(), "application/json")
+
+    def finalize(self, extracted: Any, item: Any) -> Any:
+        n = int(extracted["n_new"])
+        toks = [int(t) + self.v_first for t in np.asarray(extracted["tokens"])[:n]]
+        out = {"tokens": toks, "n_tokens": n}
+        want = int(item[5])
+        if want:
+            out["logprobs"] = {
+                "ids": (np.asarray(extracted["lp_ids"])[:n, :want]
+                        + self.v_first).tolist(),
+                "values": np.asarray(extracted["lp"])[:n, :want].astype(float).tolist()}
+        return out
+
+    def result_units(self, result: Any) -> float:
+        return float(result.get("n_tokens", 1))
+
+    def stream_units(self, step_out: dict, slot: int, stream: dict) -> list:
+        """One token a step, the lane's ``last``; the first fetch of a lane
+        brings the prefill's token with it."""
+        n, sent = int(step_out["n_new"][slot]), int(stream.get("sent", 0))
+        if n <= sent:
+            return []
+        stream["sent"] = n
+        units = [{"type": "token", "index": n - 1,
+                  "token": int(step_out["last"][slot]) + self.v_first}]
+        if sent == 0 and n > 1:
+            units.insert(0, {"type": "token", "index": 0,
+                             "token": int(step_out["first"][slot]) + self.v_first})
+        return units
+
+    def stream_finish_reason(self, result: Any) -> str:
+        return "length"
+
+    def stream_usage(self, result: Any) -> dict:
+        return {"completion_tokens": int(result.get("n_tokens", 0))}

@@ -131,15 +131,35 @@ class SwitchFFN(nn.Module):
 
 
 def topk_route(logits: jax.Array, k: int, *, normalize: bool = True,
-               scale: float = 1.0) -> tuple[jax.Array, jax.Array]:
+               scale: float = 1.0, scoring: str = "softmax",
+               select_bias: "jax.Array | None" = None
+               ) -> tuple[jax.Array, jax.Array]:
     """(T, E) router logits -> (weights (T, k) float32, experts (T, k)
-    int32): softmax over all E in float32, the k largest, their weights
-    over the k's own sum where ``normalize``, times ``scale``."""
-    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, e = jax.lax.top_k(p, k)
+    int32): scores over all E in float32 (``scoring``: a ``softmax``, or a
+    ``sigmoid`` of each logit alone), the k largest, their weights over the
+    k's own sum where ``normalize``, times ``scale``. With ``select_bias``
+    (E,) the k are picked by score plus bias and weighted by the score
+    alone: the bias moves picks, never weights."""
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"scoring {scoring!r}: softmax or sigmoid")
+    x = logits.astype(jnp.float32)
+    p = jax.nn.softmax(x, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(x)
+    if select_bias is None:
+        w, e = jax.lax.top_k(p, k)
+    else:
+        _, e = jax.lax.top_k(p + select_bias.astype(jnp.float32), k)
+        w = jnp.take_along_axis(p, e, axis=-1)
     if normalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     return w * jnp.float32(scale), e.astype(jnp.int32)
+
+
+def _tile(n: int) -> int:
+    """The grouped product's tile of a kernel dimension ``n``: the largest
+    multiple of 128, at most 1024, that divides it (1024 -> 1024, 3072 ->
+    1024, 2688 -> 896); 0 where none does."""
+    return next((t for t in range(min(1024, n) // 128 * 128, 0, -128)
+                 if n % t == 0), 0)
 
 
 def _grouped_dot(rows: int, dtype, *kernel_shapes):
@@ -151,8 +171,7 @@ def _grouped_dot(rows: int, dtype, *kernel_shapes):
     5.6 against 9.6 ms for 20,480 rows); ``jax.lax.ragged_dot`` everywhere
     else. Chosen when the program is traced, from what can be seen then."""
     tm = 128 if rows <= 4096 else 256
-    tiles = all(kk % min(1024, kk) == 0 and n % min(1024, n) == 0
-                and min(kk, n) >= 128 for _g, kk, n in kernel_shapes)
+    tiles = all(_tile(kk) and _tile(n) for _g, kk, n in kernel_shapes)
     if jax.default_backend() == "tpu" and dtype == jnp.bfloat16 \
             and rows % tm == 0 and tiles:
         from jax.experimental.pallas.ops.tpu.megablox import gmm
@@ -160,22 +179,40 @@ def _grouped_dot(rows: int, dtype, *kernel_shapes):
         def dot(lhs, rhs, sizes):
             _g, kk, n = rhs.shape
             return gmm(lhs, rhs, sizes, preferred_element_type=jnp.float32,
-                       tiling=(tm, min(1024, kk), min(1024, n)))
+                       tiling=(tm, _tile(kk), _tile(n)))
         return dot
     return lambda lhs, rhs, sizes: jax.lax.ragged_dot(
         lhs, rhs, sizes, preferred_element_type=jnp.float32)
 
 
-def held_experts_swiglu(x: jax.Array, weights: jax.Array, experts: jax.Array,
-                        first: int, w_gate: jax.Array, w_up: jax.Array,
-                        w_down: jax.Array,
-                        live: "jax.Array | None" = None
-                        ) -> tuple[jax.Array, dict]:
-    """This chip's part of a routed SwiGLU layer.
+def swiglu(gate: jax.Array, up: jax.Array) -> jax.Array:
+    """An expert's hidden rows from its two in-products: silu(gate) * up."""
+    return jax.nn.silu(gate) * up
+
+
+def relu2(a: jax.Array) -> jax.Array:
+    """An un-gated expert's hidden rows from its one in-product: relu(a)^2."""
+    return jnp.square(jax.nn.relu(a))
+
+
+def held_experts_swiglu(x, weights, experts, first, w_gate, w_up, w_down, live=None):
+    """This chip's part of a routed SwiGLU layer: :func:`held_experts` with
+    the two in-kernels ``w_gate``/``w_up`` and the SiLU gate."""
+    return held_experts(x, weights, experts, first, (w_gate, w_up), w_down, swiglu,
+                        live=live)
+
+
+def held_experts(x: jax.Array, weights: jax.Array, experts: jax.Array,
+                 first: int, w_in: "tuple[jax.Array, ...]", w_out: jax.Array,
+                 body, live: "jax.Array | None" = None
+                 ) -> tuple[jax.Array, dict]:
+    """This chip's part of a routed expert layer, whatever an expert is.
 
     ``x`` (T, D); ``weights``/``experts`` (T, k) from :func:`topk_route`;
-    the held experts' kernels ``w_gate``/``w_up`` (count, D, F) and
-    ``w_down`` (count, F, D), expert ``first + i`` at row i. ``live`` (T,)
+    the held experts' in-kernels ``w_in`` (each (count, D, F)) and
+    ``w_out`` (count, F, D), expert ``first + i`` at row i; ``body`` makes an
+    expert's hidden rows from its in-products (:func:`swiglu` of two,
+    :func:`relu2` of one). ``live`` (T,)
     bool marks the tokens that are real (padding and frozen lanes route too,
     since shapes are static, but count for nothing and add nothing).
 
@@ -185,7 +222,7 @@ def held_experts_swiglu(x: jax.Array, weights: jax.Array, experts: jax.Array,
     tokens on held / absent experts) and ``experts_hit`` (held experts with
     at least one live pick)."""
     t, k = experts.shape
-    count = w_gate.shape[0]
+    count = w_out.shape[0]
     local = experts - jnp.int32(first)
     held = (local >= 0) & (local < count)
     if live is not None:
@@ -200,9 +237,9 @@ def held_experts_swiglu(x: jax.Array, weights: jax.Array, experts: jax.Array,
     order = jnp.argsort(key, stable=True)
     sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
     xs = jnp.take(x, order % t, axis=0)
-    dot = _grouped_dot(t * k, x.dtype, w_gate.shape, w_down.shape)
-    h = (jax.nn.silu(dot(xs, w_gate, sizes)) * dot(xs, w_up, sizes)).astype(x.dtype)
-    out = dot(h, w_down, sizes)
+    dot = _grouped_dot(t * k, x.dtype, w_in[0].shape, w_out.shape)
+    h = body(*(dot(xs, w, sizes) for w in w_in)).astype(x.dtype)
+    out = dot(h, w_out, sizes)
     # Back to pick order, weighted, summed over a token's k picks. Rows past
     # the groups' sum hold whatever the product left there (seen on the chip:
     # neither implementation zeroes them), so they are selected away, not
