@@ -608,13 +608,15 @@ class BertServing(ServingModel):
         self._tokenize_obs = (
             metrics.histogram(f"latency_ms{{model={name},phase=tokenize}}"),
             metrics.counter(f"ingest_tokenize_cpu_seconds_total{{model={name}}}"),
-            metrics.counter(f"ingest_tokens_total{{model={name}}}"))
+            metrics.counter(f"ingest_tokens_total{{model={name}}}"),
+            metrics.counter(f"ingest_tokenize_path_total{{model={name},path=ascii}}"),
+            metrics.counter(f"ingest_tokenize_path_total{{model={name},path=unicode}}"))
 
     def _encode_all(self, texts: list[str]) -> list[np.ndarray]:
         """The tokenizer alone (no JSON parse), measured in the thread that
         runs it: wall time, this thread's CPU time (wall less CPU is the
-        wait for the GIL and the scheduler) and ids produced, [CLS] and
-        [SEP] included."""
+        wait for the GIL and the scheduler), ids produced, [CLS] and [SEP]
+        included, and documents by the split text.py chose for them."""
         wall0, cpu0 = time.perf_counter(), time.thread_time()
         with trace_span("tpuserve.tokenize", model=self.name,
                         items=len(texts)) as span:
@@ -622,16 +624,18 @@ class BertServing(ServingModel):
             tokens = sum(it.shape[0] for it in items)
             span.set_metadata(tokens=tokens)
         if self._tokenize_obs is not None:
-            hist, cpu_s, n_tokens = self._tokenize_obs
+            hist, cpu_s, n_tokens, n_ascii, n_unicode = self._tokenize_obs
             hist.observe((time.perf_counter() - wall0) * 1e3)
             cpu_s.inc(time.thread_time() - cpu0)
             n_tokens.inc(tokens)
+            ascii_docs = sum(t.isascii() for t in texts)
+            n_ascii.inc(ascii_docs)
+            n_unicode.inc(len(texts) - ascii_docs)
         return items
 
     def _encode(self, text: str) -> np.ndarray:
         tok = self.tokenizer
-        pieces = tok.tokenize(text)  # once; encode() would re-tokenize
-        ids = [tok.cls_id] + [tok.vocab.get(t, tok.unk_id) for t in pieces]
+        ids = [tok.cls_id] + tok.ids(text)
         ids = ids[: self.max_seq - 1] + [tok.sep_id]
         return np.asarray(ids, np.int32)  # unpadded; assemble pads per bucket
 

@@ -7,9 +7,14 @@ pretrained tokenizer downloads, so this implements the standard BERT scheme
 from scratch:
 
 - Basic tokenization: NFD accent stripping, optional lowercasing, punctuation
-  splitting, CJK isolation, whitespace split.
+  splitting, CJK isolation, whitespace split. A text that ``str.isascii()``
+  leaves none of those Unicode questions open, so ONE compiled pattern splits
+  it (``_ASCII_SPLIT``); any other text takes the per-character walk
+  (``_walk``), whole. The choice is by the text and by nothing else, and the
+  two give the same words letter for letter.
 - WordPiece: greedy longest-match-first against a vocab, "##" continuations,
-  [UNK] fallback.
+  [UNK] fallback. ``WordPieceTokenizer.ids`` looks each word up whole first
+  (greedy search's own first probe) and searches only on a miss.
 
 Vocabularies: ``WordPieceTokenizer.from_vocab_file`` loads a standard BERT
 ``vocab.txt`` (one token per line, id = line number). For no-artifact dev
@@ -17,12 +22,14 @@ serving, ``synthetic_vocab`` builds a deterministic vocab (special tokens,
 printable ASCII pieces, common English subwords) so tokenization is stable
 across processes without any file.
 
-Tokenization runs on the host threadpool (pure Python, per-request); the
-(ids, mask) arrays it emits are what crosses to the device.
+Tokenization runs on the host threadpool, per request and under the GIL: for
+ASCII text one ``re`` call and one dictionary lookup a word, for the rest the
+Python walk. The (ids, mask) arrays it emits are what crosses to the device.
 """
 
 from __future__ import annotations
 
+import re
 import unicodedata
 
 import numpy as np
@@ -45,8 +52,23 @@ def _is_cjk(cp: int) -> bool:
     )
 
 
+# What the walk does to ASCII, as one pattern: NFD is the identity, nothing is
+# Mn or CJK, the separators are the code points below 33 and 127, and
+# punctuation (each its own token) is 33-47, 58-64, 91-96 (\x60 is the
+# backtick) and 123-126.
+_ASCII_SPLIT = re.compile(r"[0-9A-Za-z]+|[!-/:-@\[-\x60{-~]").findall
+
+
 def basic_tokenize(text: str, lower: bool = True) -> list[str]:
     """Whitespace/punctuation/CJK split with accent stripping."""
+    if text.isascii():  # a flag CPython keeps: no scan
+        return _ASCII_SPLIT(text.lower() if lower else text)
+    return _walk(text, lower)
+
+
+def _walk(text: str, lower: bool = True) -> list[str]:
+    """The split a character at a time: the path for text that is not ASCII,
+    and the oracle the tests hold the pattern to."""
     if lower:
         text = text.lower()
     text = unicodedata.normalize("NFD", text)
@@ -131,10 +153,23 @@ class WordPieceTokenizer:
             out.extend(self.wordpiece(word))
         return out
 
+    def ids(self, text: str) -> list[int]:
+        """The ids of tokenize(text), without the piece strings: each word is
+        looked up whole (wordpiece's own first probe) and only a miss, or a
+        word over max_word_chars, goes through wordpiece."""
+        vocab, longest = self.vocab, self.max_word_chars
+        out: list[int] = []
+        for word in basic_tokenize(text, self.lower):
+            i = vocab.get(word)
+            if i is not None and len(word) <= longest:
+                out.append(i)
+            else:
+                out.extend(vocab[p] for p in self.wordpiece(word))
+        return out
+
     def encode(self, text: str, max_len: int) -> tuple[np.ndarray, np.ndarray]:
         """Text -> ([CLS] pieces [SEP], mask), truncated+padded to max_len."""
-        ids = [self.cls_id]
-        ids += [self.vocab.get(t, self.unk_id) for t in self.tokenize(text)]
+        ids = [self.cls_id] + self.ids(text)
         ids = ids[: max_len - 1] + [self.sep_id]
         n = len(ids)
         arr = np.full((max_len,), self.pad_id, np.int32)
@@ -145,7 +180,7 @@ class WordPieceTokenizer:
 
     def n_tokens(self, text: str) -> int:
         """Sequence length encode() would need (incl. [CLS]/[SEP])."""
-        return len(self.tokenize(text)) + 2
+        return len(self.ids(text)) + 2
 
 
 def synthetic_vocab(size: int = 8192, seed: int = 0) -> dict[str, int]:
