@@ -266,3 +266,22 @@ def test_kernel_compiles_for_the_v5e_at_the_cells_widths(one_chip, heads):
         jax.config.update("jax_enable_compilation_cache", True)
     assert "tpu_custom_call" in text
     assert " copy(" not in text and " transpose(" not in text
+
+
+def test_the_tile_kernel_of_latent_attention_compiles_for_the_v5e_at_the_cells_widths(one_chip):
+    """`ops/tile_attention.py` at the JoyAI cell's sizes (ISSUE 34): 32 heads,
+    a tile and a key block of 1,024, keys 192 wide and values 128: Mosaic
+    takes the 192-wide contraction and the scalar-prefetched offset."""
+    from tpuserve.ops.tile_attention import tile_attention
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
+    try:
+        text = jax.jit(lambda q, k, v, off: tile_attention(q, k, v, off, scale=192 ** -0.5)).lower(
+            shape(32, 1024, 192), shape(32, 1024, 192), shape(32, 1024, 128),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert "tpu_custom_call" in text and "tile_attention" in text

@@ -291,6 +291,8 @@ class GenEngine:
             f"gen_kv_rings_free{{model={name}}}")
         self._g_state_bytes = metrics.gauge(
             f"gen_state_bytes{{model={name}}}")
+        self._g_kv_row_bytes = metrics.gauge(
+            f"gen_kv_row_bytes{{model={name}}}")
         self._default_priority = getattr(model.cfg, "priority", "interactive")
         self._h_qwait = {p: metrics.queue_wait_histogram(name, p)
                          for p in PRIORITIES}
@@ -350,6 +352,7 @@ class GenEngine:
             self._state_struct = model.kv_page_signature(
                 self.slots, self.pages.pages, self.pages.page_tokens)
             self._g_state_bytes.set(float(self.slot_state_bytes()))
+            self._g_kv_row_bytes.set(float(self.kv_row_bytes()))
         else:
             self._state_struct = model.state_signature(self.slots)
         geometry = {"kv_paging": self.paging, "slots": self.slots,
@@ -1473,6 +1476,7 @@ class GenEngine:
                 **self._prefill_stats(),
                 "queued_pages": self._queued_pages(),
                 "kv_bytes": self.kv_cache_bytes(),
+                "row_bytes_per_token": self.kv_row_bytes(),
                 # The third kind (ISSUE 32): a fixed block a slot, beside pages.
                 "state_bytes_per_slot": self.slot_state_bytes() // self.slots,
                 "state_bytes": self.slot_state_bytes(),
@@ -1522,24 +1526,29 @@ class GenEngine:
         """Device bytes of the leaves the family keeps as one block A SLOT
         (``kv_slot_state``: a recurrent layer's state, the same size whatever
         the context), all slots; 0 for a family without any."""
+        return self._leaf_bytes(self.model.kv_slot_state)
+
+    def _leaf_bytes(self, keys: tuple) -> int:
         if not isinstance(self._state_struct, dict):
             return 0
-        leaves = jax.tree_util.tree_leaves(
-            [self._state_struct.get(k) for k in self.model.kv_slot_state])
+        leaves = jax.tree_util.tree_leaves([self._state_struct.get(k) for k in keys])
         return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize for x in leaves)
 
     def kv_cache_bytes(self) -> int:
-        """Device bytes the KV storage leaves occupy (dense slab k/v or the
-        paged pool kp/vp) — the denominator of the bench's fixed-memory
-        slot-count comparison."""
-        total = 0
-        if isinstance(self._state_struct, dict):
-            for key in ("k", "v", "kp", "vp"):
-                leaf = self._state_struct.get(key)
-                if leaf is not None:
-                    total += (int(np.prod(leaf.shape))
-                              * np.dtype(leaf.dtype).itemsize)
-        return total
+        """Device bytes the KV storage leaves occupy (the dense slab k/v or
+        the family's page pools, ``kv_page_leaves``) — the denominator of
+        the bench's fixed-memory slot-count comparison."""
+        return self._leaf_bytes(("k", "v") + self.model.kv_page_leaves)
+
+    def kv_row_bytes(self) -> int:
+        """Device bytes ONE position of context takes in the page pools, all
+        layers, reckoned from the signature (``kv_page_leaves`` over pages x
+        page tokens): K and V by head for ``decoder``, one latent row for
+        ``mla``. 0 without paging."""
+        if self.pages is None:
+            return 0
+        return self._leaf_bytes(self.model.kv_page_leaves) \
+            // (self.pages.pages * self.pages.page_tokens)
 
 
 class GenEngineGroup:
@@ -1772,6 +1781,7 @@ class GenEngineGroup:
                 **e0._prefill_stats(),
                 "queued_pages": sum(e._queued_pages() for e in paged),
                 "kv_bytes": self.kv_cache_bytes(),
+                "row_bytes_per_token": e0.kv_row_bytes(),
             }
         stats["per_replica"] = [e.replica_row() for e in self.engines]
         return stats

@@ -174,6 +174,12 @@ class GenerativeModel(ServingModel):
     # reports their bytes (/stats ``kv.state_bytes_per_slot``, ``gen_state_bytes``).
     kv_slot_state: tuple = ()
 
+    # Keys of ``kv_page_signature`` whose leaves are the PAGE POOLS (a
+    # dimension of ``pages`` and one of ``page_tokens``): the engine reckons a
+    # position's bytes from them (/stats ``kv.row_bytes_per_token``,
+    # ``gen_kv_row_bytes``; ``kv.kv_bytes``).
+    kv_page_leaves: tuple = ("kp", "vp")
+
     def observe_step(self, step_out: dict) -> None:
         """Host-side, after every fetched step: a family that sums counts on
         the device (experts hit, context read) moves them into its own

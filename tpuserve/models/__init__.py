@@ -20,6 +20,11 @@ Families (BASELINE.json ``configs``):
                    pattern string (Mamba-2 state-space, attention, routed
                    experts in a latent), built from a published config.json:
                    a recurrent state a slot beside the paged KV (ISSUE 32)
+- mla            — a language model with latent attention (MLA: one
+                   compressed row a token a layer in the page pool, an
+                   absorbed decode and an expanded prefill form) and routed
+                   SwiGLU experts, built from a published config.json
+                   (ISSUE 34)
 - toy            — a linear classifier for tests and drills
 """
 
@@ -40,6 +45,7 @@ _REGISTRY: dict[str, str] = {
     "textgen": "tpuserve.models.textgen",
     "decoder": "tpuserve.models.decoder",
     "hybrid": "tpuserve.models.hybrid",
+    "mla": "tpuserve.models.mla",
     "toy": "tpuserve.models.toy",
 }
 

@@ -104,13 +104,19 @@ def rope_inv_freq(rp: dict, head_dim: int) -> tuple[np.ndarray, float, int]:
 
 
 def apply_rope(x: jax.Array, pos: jax.Array, inv_freq: np.ndarray,
-               factor: float, dim: int) -> jax.Array:
+               factor: float, dim: int, interleave: bool = False) -> jax.Array:
     """``x`` (..., T, H, head_dim) at positions ``pos`` (..., T): the first
-    ``dim`` dimensions turn in pairs (i, i + dim/2), in float32."""
+    ``dim`` dimensions turn in pairs (i, i + dim/2), or with ``interleave``
+    in pairs (2i, 2i + 1), in float32."""
     ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
     cos = (jnp.cos(ang) * factor)[..., None, :]
     sin = (jnp.sin(ang) * factor)[..., None, :]
     xf = x.astype(jnp.float32)
+    if interleave:
+        x1, x2, rest = xf[..., 0:dim:2], xf[..., 1:dim:2], xf[..., dim:]
+        turned = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return jnp.concatenate([turned.reshape(xf.shape[:-1] + (dim,)), rest],
+                               axis=-1).astype(x.dtype)
     x1, x2, rest = xf[..., :dim // 2], xf[..., dim // 2:dim], xf[..., dim:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1).astype(x.dtype)
@@ -258,10 +264,6 @@ class DecoderServing(PagedLM):
             o = o * gate[..., None]
         return jnp.einsum("thk,hkd->td", o.astype(self.dtype), lp["wo"],
                           preferred_element_type=jnp.float32)
-
-    def _swiglu(self, u, w_gate, w_up, w_down):
-        h = (jax.nn.silu(_mm(u, w_gate)) * _mm(u, w_up)).astype(self.dtype)
-        return _mm(h, w_down)
 
     def _ffn(self, lp, i, u, live):
         """(T, d) -> ((T, d) float32, the expert layer's counts or None)."""

@@ -65,7 +65,6 @@ import jax
 import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
-from tpuserve.models import seeded
 from tpuserve.models.paged_lm import (LOGPROBS, PagedLM, _mm,  # noqa: F401
                                       head_share, read_config_file, rms_norm)
 from tpuserve.obs import GEN_PHASES
@@ -224,11 +223,6 @@ class HybridServing(PagedLM):
 
     def draw_params(self, seed: int) -> Any:
         p = super().draw_params(seed)
-        for path, shape, full, start, lo, hi in self._vectors():
-            # The four summed bytes over their range, in [0, 1], then the range.
-            u = 0.5 + seeded.draw(seed, "/".join(path), shape, seeded.BELL_STD / 1020.0,
-                                  jnp.float32, full_shape=full, start=start)
-            p[path[0]][path[1]] = jnp.float32(lo) + jnp.float32(hi - lo) * u
         for i in self.m_layers:
             lp, flat = p[f"layer{i}"], lambda t, lead: t.reshape(t.shape[:lead] + (-1,))
             lp["w_in"] = jnp.concatenate(

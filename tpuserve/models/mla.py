@@ -1,0 +1,417 @@
+"""A decoder-only language model with LATENT ATTENTION (multi-head latent
+attention, MLA) and routed SwiGLU experts, built from a published
+``config.json`` (ISSUE 34) and served through the generation engine with a
+paged cache of ONE compressed row a token a layer.
+
+Nothing here knows a model's name. The architecture is read, under the
+published key names, from the JSON file that ``options.config_file`` names.
+Layer ``i``: ``x <- x + attention(RMSNorm(x; g1))``, ``x <- x +
+ffn(RMSNorm(x; g2))``, eps ``rms_norm_eps``, no biases, an untied head.
+
+ATTENTION, with ``u`` the normed stream at position ``t``:
+``c_q = RMSNorm(u W_qa; g_q)`` (``q_lora_rank``); ``q = c_q W_qb``, a head
+``[q_nope (qk_nope_head_dim) | q_rope (qk_rope_head_dim)]``, ``q_rope <-
+RoPE(q_rope, t)``; ``[c_kv | k_r] = u W_kva`` (``kv_lora_rank`` | rope);
+``c_kv <- RMSNorm(c_kv; g_kv)``; ``k_r <- RoPE(k_r, t)``, ONE rotary key for
+every head; ``[k_nope_h | v_h] = c_kv W_kvb``. ``score_h(t, s) = (q_nope_h(t) .
+k_nope_h(s) + q_rope_h(t) . k_r(s)) / sqrt(qk_nope + qk_rope)``, causal
+softmax in float32, ``o_h = sum_s p v_h(s)``, out ``= concat_h(o_h) W_o``.
+Rotary: plain (``rope_scaling`` null), ``rope_theta`` over the rotary columns;
+with ``rope_interleave`` the columns (2i, 2i + 1) turn as a pair.
+
+THE CACHE: the normed ``c_kv`` and the rotated ``k_r`` of a token, one row of
+``kv_lora_rank + qk_rope_head_dim`` values a layer, in pages of the engine's
+ledger; nothing by head. The row lies in two leaves, each a whole number of
+128-lane rows: ``ckv`` (pages, P, kv_lora_rank) and ``kr`` (pages, P / g, g x
+rope), ``g`` positions' rotary keys side by side in one row of 128 lanes (2
+at the published 64). Why not one leaf of 576: 4.5 lanes of 128 is not a
+layout the v5e compiler keeps: it turned every layer's whole pool to another
+layout before the walk and back after it, twice 0.44 GiB a layer a launch (a
+compile of the cell's programs for a described v5e, PR 34); widths that are
+multiples of 128 stay as they lie. A prefill launch writes its rotary keys
+``g`` positions at a time (a piece begins on a tile's edge); a decode step
+reads a lane's row of 128, sets its own part and writes the row back.
+
+TWO FORMS OF ONE FUNCTION, over the same walk of a block table in key blocks
+(``paged_lm._over_key_blocks``). *Absorbed*: ``q_lat_h = q_nope_h (W_kvb^K_h)^T``,
+``score = [q_lat_h | q_rope_h] . [c_kv | k_r](s)``, ``o_h = (sum_s p c_kv(s))
+W_kvb^V_h``: the cache is read as it lies, every head over one row whose
+first ``kv_lora_rank`` columns are also the value; ``2 H (2 r + rope)``
+operations a (query, key) pair. *Expanded*: a key block's ``k_nope`` and ``v``
+are made from its latents, ``2 r H (nope + v)`` operations a key ONCE A TILE,
+then ``2 H (nope + rope + v)`` a pair. ``_form`` takes the one with the fewer
+operations at a tile's static width when the program is traced: a decode
+step (a tile of one query a lane) is absorbed; a prefill tile of
+``TILE_ROWS`` rows is expanded, and that is why this family's tiles are that
+wide (at the published sizes the forms break even at 171 rows).
+
+FEED-FORWARD: the first ``first_k_dense_replace`` layers a dense SwiGLU of
+``intermediate_size``; the others ``s = sigmoid(u W_r)`` in float32, the
+``num_experts_per_tok`` largest of ``s + b`` (``noaux_tc``: the bias moves
+picks, not weights; one group), weights over their own sum times
+``routed_scaling_factor``, SwiGLU experts of ``moe_intermediate_size`` plus a
+shared expert of ``n_shared_experts`` times that on the same ``u``
+(``tpuserve.ops.moe``). Every expert is held: the family takes no ``share``.
+
+NOT SERVED: a multi-token-prediction module (``num_nextn_predict_layers``).
+Requests, weights by recipe and the served log-probabilities are
+``decoder``'s (``paged_lm``).
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from tpuserve.config import ModelConfig
+from tpuserve.models.decoder import apply_rope, rope_inv_freq
+from tpuserve.models.paged_lm import (KEY_BLOCK, LOGPROBS, NEG, PagedLM,  # noqa: F401
+                                      _mm, read_config_file, rms_norm)
+from tpuserve.obs import GEN_PHASES
+from tpuserve.ops import tile_attention as ta
+from tpuserve.ops.moe import held_experts_swiglu, topk_route
+
+# Standard deviations of the drawn tensors, by role (``weight_scales`` in the
+# config file overrides any). The query's up-projection, the keys'
+# up-projection and the rotary key are drawn wider (2x: scores of standard
+# deviation 4), so that attention is decided over thousands of keys and what
+# the cache holds is decisive.
+DEFAULT_SCALES = {
+    "embed": 1.0, "head": 1.0, "q_a": 1.0, "q_b": 2.0, "kv_a": 1.0, "k_rope": 2.0,
+    "k_b": 2.0, "v": 1.0, "o": 1.0, "ffn_in": 1.0, "ffn_out": 1.0, "expert_out": 1.0,
+    "router": 1.0, "router_bias": 0.02,
+}
+FORMS = ("absorbed", "expanded")
+
+
+class LatentServing(PagedLM):
+    # Device-side sums a phase: the expert layers' four and the context (as
+    # ``decoder``), then cache rows attended over (each once a piece or a
+    # lane: the least a launch reads), cache rows the walk gathered (whole key
+    # blocks a tile or a lane, a layer), and launches by form.
+    ACC = 9
+    TILE_ROWS = KEY_BLOCK
+    kv_page_leaves = ("ckv", "kr")
+
+    def __init__(self, cfg: ModelConfig) -> None:
+        super().__init__(cfg)
+        a = read_config_file(cfg)
+        self.dtype = jnp.dtype(cfg.dtype)
+        for key, want in (("attention_bias", False), ("tie_word_embeddings", False),
+                          ("rope_scaling", None), ("n_group", 1), ("topk_group", 1),
+                          ("scoring_func", "sigmoid"), ("topk_method", "noaux_tc"),
+                          ("hidden_act", "silu"), ("moe_layer_freq", 1), ("share", None)):
+            if a.get(key, want) != want:
+                raise NotImplementedError(f"{cfg.name}: {key} = {a[key]!r}")
+        if not a.get("q_lora_rank"):
+            raise NotImplementedError(f"{cfg.name}: q_lora_rank = {a.get('q_lora_rank')!r} "
+                                      "(queries without a low-rank projection)")
+        self.d = int(a["hidden_size"])
+        self.n_layers = int(a["num_hidden_layers"])
+        self.eps = float(a.get("rms_norm_eps", 1e-6))
+        self.heads = int(a["num_attention_heads"])
+        self.q_rank, self.r = int(a["q_lora_rank"]), int(a["kv_lora_rank"])
+        self.dn, self.dr, self.dv = (int(a[k]) for k in (
+            "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"))
+        self.rope = rope_inv_freq({"rope_theta": float(a.get("rope_theta", 10000.0))}, self.dr)
+        self.rope_interleave = bool(a.get("rope_interleave", False))
+        self.first_dense = int(a.get("first_k_dense_replace", 0))
+        self.sparse_layers = list(range(self.first_dense, self.n_layers))
+        self.dense_width = int(a["intermediate_size"])
+        self.n_experts = int(a.get("n_routed_experts", 0))
+        self.top_k = int(a.get("num_experts_per_tok", 0))
+        self.expert_width = int(a.get("moe_intermediate_size", 0))
+        self.shared_width = self.expert_width * int(a.get("n_shared_experts", 0))
+        self.norm_topk = bool(a.get("norm_topk_prob", True))
+        self.route_scale = float(a.get("routed_scaling_factor", 1.0))
+        self.vocab_full = self.vocab = int(a["vocab_size"])
+        self.v_first = 0
+        self.scales = {**DEFAULT_SCALES, **a.get("weight_scales", {})}
+        self._serve_options(cfg, a)
+
+    # -- params ---------------------------------------------------------------
+    def _gains(self):
+        yield ("norm_f",), (self.d,)
+        for i in range(self.n_layers):
+            for name, n in (("norm1", self.d), ("norm2", self.d), ("q_norm", self.q_rank),
+                            ("kv_norm", self.r)):
+                yield (f"layer{i}", name), (n,)
+
+    def _tensors(self):
+        """(path, shape, full shape, start, role, fan-in) of every matrix, in
+        a fixed order. ``W_qb`` and ``W_kva`` are drawn in their two parts
+        (``w_qb_nope`` | ``w_qb_rope``, ``w_kva_c`` | ``w_kva_r``), ``W_kvb`` in
+        its key and its value side (``w_kb``, ``w_vb``), each a tensor of its
+        own; ``draw_params`` joins the first two pairs."""
+        d, s, h = self.d, self.scales, self.heads
+
+        def whole(path, shape, role, fan_in):
+            return path, shape, shape, (0,) * len(shape), s[role], fan_in
+
+        yield whole(("embed",), (self.vocab, d), "embed", 1)
+        yield whole(("head",), (d, self.vocab), "head", d)
+        for i in range(self.n_layers):
+            L = f"layer{i}"
+            yield whole((L, "w_qa"), (d, self.q_rank), "q_a", d)
+            yield whole((L, "w_qb_nope"), (self.q_rank, h, self.dn), "q_b", self.q_rank)
+            yield whole((L, "w_qb_rope"), (self.q_rank, h, self.dr), "q_b", self.q_rank)
+            yield whole((L, "w_kva_c"), (d, self.r), "kv_a", d)
+            yield whole((L, "w_kva_r"), (d, self.dr), "k_rope", d)
+            yield whole((L, "w_kb"), (self.r, h, self.dn), "k_b", self.r)
+            yield whole((L, "w_vb"), (self.r, h, self.dv), "v", self.r)
+            yield whole((L, "wo"), (h, self.dv, d), "o", h * self.dv)
+            if i < self.first_dense:
+                f = self.dense_width
+                for name in ("w_gate", "w_up"):
+                    yield whole((L, name), (d, f), "ffn_in", d)
+                yield whole((L, "w_down"), (f, d), "ffn_out", f)
+                continue
+            e, f, fs = self.n_experts, self.expert_width, self.shared_width
+            yield whole((L, "router"), (d, e), "router", d)
+            for name in ("e_gate", "e_up"):
+                yield whole((L, name), (e, d, f), "ffn_in", d)
+            yield whole((L, "e_down"), (e, f, d), "expert_out", f)
+            for name in ("s_gate", "s_up"):
+                yield whole((L, name), (d, fs), "ffn_in", d)
+            yield whole((L, "s_down"), (fs, d), "ffn_out", fs)
+
+    def _vectors(self):
+        """The router's selection bias: small, about 0, so that it changes
+        some picks."""
+        b3 = 3.0 * self.scales["router_bias"]
+        for i in self.sparse_layers:
+            yield ((f"layer{i}", "e_bias"), (self.n_experts,), (self.n_experts,), (0,), -b3, b3)
+
+    def draw_params(self, seed: int) -> Any:
+        p = super().draw_params(seed)
+        for i in range(self.n_layers):
+            lp = p[f"layer{i}"]
+            lp["w_qb"] = jnp.concatenate([lp.pop("w_qb_nope"), lp.pop("w_qb_rope")], axis=2)
+            lp["w_kva"] = jnp.concatenate([lp.pop("w_kva_c"), lp.pop("w_kva_r")], axis=1)
+        return p
+
+    # -- shapes -----------------------------------------------------------------
+    def kv_page_signature(self, slots: int, pages: int, page_tokens: int) -> Any:
+        S = jax.ShapeDtypeStruct
+        g = 128 // self.dr if 128 % self.dr == 0 else 1   # positions a row of 128 lanes
+        g = g if page_tokens % g == 0 else 1
+        layers = range(self.n_layers)
+        return {"ckv": [S((pages, page_tokens, self.r), self.dtype) for _ in layers],
+                "kr": [S((pages, page_tokens // g, g * self.dr), self.dtype) for _ in layers],
+                **self._lane_signature(slots, page_tokens)}
+
+    # -- device math --------------------------------------------------------------
+    def _form(self, tile_rows: int) -> str:
+        """Which form a tile of ``tile_rows`` queries takes: the one with the
+        fewer operations a key (module docstring)."""
+        h, r = self.heads, self.r
+        expanded = 2 * r * h * (self.dn + self.dv) \
+            + tile_rows * 2 * h * (self.dn + self.dr + self.dv)
+        return "expanded" if expanded < tile_rows * 2 * h * (2 * r + self.dr) else "absorbed"
+
+    def _project(self, lp: dict, u: jax.Array, pos: jax.Array):
+        """``u`` (T, d) normed stream at positions ``pos`` (T,) -> q_nope (T, H,
+        nope), rotated q_rope (T, H, rope), and what a token keeps: the normed
+        ``c_kv`` (T, r) and the rotated ``k_r`` (T, rope)."""
+        dt = self.dtype
+        inv, factor, dim = self.rope
+        c_q = rms_norm(_mm(u, lp["w_qa"]).astype(dt), lp["q_norm"], self.eps)
+        q = jnp.einsum("tq,qhk->thk", c_q, lp["w_qb"],
+                       preferred_element_type=jnp.float32).astype(dt)
+        kva = _mm(u, lp["w_kva"]).astype(dt)
+        c_kv = rms_norm(kva[:, :self.r], lp["kv_norm"], self.eps)
+        k_r = apply_rope(kva[:, None, self.r:], pos, inv, factor, dim,
+                         self.rope_interleave)[:, 0]
+        q_rope = apply_rope(q[..., self.dn:], pos, inv, factor, dim, self.rope_interleave)
+        return q[..., :self.dn], q_rope, c_kv, k_r
+
+    def _write_keys(self, pool, page, off, k_r, runs: bool):
+        """Rotary keys ``k_r`` (T, rope) into their pool (pages, P / g, g x
+        rope) at (page[t], off[t]). ``runs`` (a prefill launch): the rows come
+        in runs of g positions that begin on a row's edge, written g at a
+        time; a run that a prompt's end cuts writes its padding beside the
+        last key, at a position no query sees before a step writes it. Else
+        (a step: one position a lane) each lane's row is read, its own part
+        set and the row written back."""
+        n, rows, lanes = pool.shape
+        g = lanes // self.dr
+        flat = pool.reshape(n * rows, lanes)
+        at = page * rows + off // g
+        k_r = k_r.astype(pool.dtype)
+        if runs:
+            return flat.at[at[0::g]].set(k_r.reshape(-1, lanes)).reshape(pool.shape)
+        mine = (jnp.arange(lanes)[None, :] // self.dr) == (off % g)[:, None]
+        return flat.at[at].set(jnp.where(mine, jnp.tile(k_r, (1, g)), flat[at])).reshape(pool.shape)
+
+    def _attend_tile(self, lp: dict, qn, qr, pools, row, qpos, last, form: str):
+        """One tile's attention: q_nope ``qn`` (T, H, nope) and rotated q_rope
+        ``qr`` (T, H, rope) at positions ``qpos`` (T,), over the latent rows of
+        its prompt's pages (``pools``: the layer's ``ckv`` and ``kr``;
+        block-table row ``row``) up to position ``last``, in the ``form``
+        given -> (T, H, v) float32. Every row of the launch is in the pages
+        before any tile reads them."""
+        dt, r, h = self.dtype, self.r, self.heads
+        ckv, kr = pools
+        T, P = qn.shape[0], ckv.shape[1]
+        kb, rowp = self._key_blocks(row, P)
+        need, c = self._blocks_needed(last, P, row.shape[0]), kb * P
+        f32 = {"preferred_element_type": jnp.float32}
+        scale = (self.dn + self.dr) ** -0.5
+        if form == "absorbed":
+            qn = jnp.einsum("thn,rhn->thr", qn, lp["w_kb"], **f32).astype(dt)   # q_lat
+
+        def latents(j):
+            pg = jax.lax.dynamic_slice(rowp, (j * kb,), (kb,))
+            return (jnp.take(ckv, pg, axis=0).reshape(c, r).astype(dt),
+                    jnp.take(kr, pg, axis=0).reshape(c, self.dr).astype(dt))
+
+        # On the TPU the expanded form's scores stay on the chip: one kernel
+        # call a key block (``ops/tile_attention.py`` says why), its parts
+        # merged here. A tile's positions are consecutive, so ``qpos[0]`` less
+        # the block's first position is the causal offset.
+        if form == "expanded" and jax.default_backend() == "tpu" \
+                and ta.fits(T, c, self.dn + self.dr, self.dv, dt):  # tps-ok[TPS503]: at trace time
+            q = jnp.concatenate([qn, qr], axis=-1).transpose(1, 0, 2)
+
+            def whole_block(j):
+                c_kv, k_r = latents(j)
+                k_nope = jnp.einsum("cr,rhn->hcn", c_kv, lp["w_kb"], **f32).astype(dt)
+                val = jnp.einsum("cr,rhv->hcv", c_kv, lp["w_vb"], **f32).astype(dt)
+                keys = jnp.concatenate(
+                    [k_nope, jnp.broadcast_to(k_r[None], (h, c, self.dr))], axis=-1)
+                return ta.tile_attention(q, keys, val, qpos[0] - j * c, scale=scale)
+
+            return self._merge_key_blocks(need, (h, T), self.dv, whole_block).transpose(1, 0, 2)
+
+        def block(j):
+            c_kv, k_r = latents(j)
+            see = (j * c + jnp.arange(c))[None, :] <= qpos[:, None]
+            s = jnp.einsum("thd,cd->htc", qr, k_r, **f32)
+            if form == "absorbed":
+                s = s + jnp.einsum("thr,cr->htc", qn, c_kv, **f32)
+                val, weigh = c_kv, "htc,cr->htr"
+            else:
+                k_nope = jnp.einsum("cr,rhn->chn", c_kv, lp["w_kb"], **f32).astype(dt)
+                val = jnp.einsum("cr,rhv->chv", c_kv, lp["w_vb"], **f32).astype(dt)
+                s = s + jnp.einsum("thn,chn->htc", qn, k_nope, **f32)
+                weigh = "htc,chv->htv"
+            return jnp.where(see[None], s * scale, NEG), \
+                lambda p: jnp.einsum(weigh, p.astype(dt), val, **f32)
+
+        o = self._over_key_blocks(need, (h, T), r if form == "absorbed" else self.dv, block)
+        if form == "absorbed":
+            return jnp.einsum("htr,rhv->thv", o.astype(dt), lp["w_vb"], **f32)
+        return o.transpose(1, 0, 2)
+
+    def _attn_out(self, lp, o):
+        return jnp.einsum("thv,hvd->td", o.astype(self.dtype), lp["wo"],
+                          preferred_element_type=jnp.float32)
+
+    def _ffn(self, lp, i, u, live):
+        """(T, d) -> ((T, d) float32, the expert layer's counts or None)."""
+        if i < self.first_dense:
+            return self._swiglu(u, lp["w_gate"], lp["w_up"], lp["w_down"]), None
+        r = jnp.matmul(u.astype(jnp.float32), lp["router"].astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+        w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
+                          scoring="sigmoid", select_bias=lp["e_bias"])
+        y, stats = held_experts_swiglu(u, w, e, 0, lp["e_gate"], lp["e_up"], lp["e_down"],
+                                       live=live)
+        return y + self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"]), stats
+
+    def _accumulate(self, acc, phase: int, stats_list, context, attended, walked, form: str):
+        row = jnp.stack([jnp.asarray(v, jnp.int32) for v in (
+            sum(st["routed_held"] for st in stats_list),
+            sum(st["routed_absent"] for st in stats_list),
+            sum(st["experts_hit"] for st in stats_list),
+            self.n_experts * len(stats_list), context, attended, walked,
+            form == "absorbed", form == "expanded")])
+        return acc.at[phase].add(row.astype(jnp.uint32))
+
+    # -- prefill ------------------------------------------------------------------
+    def prefill_chunk(self, params: Any, state: Any, launch: Any, *, chunk: int) -> Any:
+        """One launch of ``pack_prefill``: piece j is tokens [start[j],
+        start[j] + length[j]) of the prompt in slot[j], causal within the
+        piece and over the latent rows earlier launches left in that slot's
+        pages."""
+        t = self._tiles(launch, chunk)
+        K, T = t["K"], t["T"]
+        start, length = launch["start"], launch["length"]
+        valid, cpos = t["valid"], t["cpos"]
+        P, pps = state["ckv"][0].shape[1], state["bt"].shape[1]
+        form = self._form(T)
+        x = jnp.take(params["embed"], launch["ids"], axis=0)
+        w_page, off = self._page_of(t, P, pps)
+        ckv, kr, stats = list(state["ckv"]), list(state["kr"]), []
+        for i in range(self.n_layers):
+            lp = params[f"layer{i}"]
+            with jax.named_scope("mla_prefill"):
+                qn, qr, c_kv, k_r = self._project(lp, rms_norm(x, lp["norm1"], self.eps), cpos)
+                ckv[i] = self._write_pages(ckv[i], w_page, off, c_kv.astype(ckv[i].dtype))
+                kr[i] = self._write_keys(kr[i], w_page, off, k_r, runs=True)
+                o = jax.lax.map(
+                    lambda a, lp=lp, pools=(ckv[i], kr[i]): self._attend_tile(
+                        lp, *a[:2], pools, *a[2:], form),
+                    (qn.reshape((K, T) + qn.shape[1:]), qr.reshape((K, T) + qr.shape[1:]),
+                     t["rows"], t["qpos"], t["last"]))
+                y = self._attn_out(lp, o.reshape((K * T,) + o.shape[2:]))
+            x = x + y.astype(self.dtype)
+            y, st = self._ffn(lp, i, rms_norm(x, lp["norm2"], self.eps), valid)
+            if st is not None:
+                stats.append(st)
+            x = x + y.astype(self.dtype)
+        walked = jnp.sum(self._blocks_needed(t["last"], P, pps)) * self._block_pages(P, pps) * P
+        new = dict(state, ckv=ckv, kr=kr, acc=self._accumulate(
+            state["acc"], 0, stats, jnp.sum(jnp.where(valid, cpos + 1, 0)),
+            jnp.sum(jnp.where(length > 0, start + length, 0)), walked, form))
+        return self._arm(params, state, new, launch, t, x, {})
+
+    # -- decode -------------------------------------------------------------------
+    def step(self, params: Any, state: Any) -> tuple[Any, dict]:
+        live = state["armed"] & ~state["done"]
+        pos = jnp.clip(state["pos"], 0, self.max_ctx - 1)
+        P, bt = state["ckv"][0].shape[1], state["bt"]
+        form = self._form(1)
+        x = jnp.take(params["embed"], state["last"], axis=0)
+        page_of = jnp.take_along_axis(bt, (pos // P)[:, None], axis=1)[:, 0]
+        w_page, off = jnp.where(live, page_of, 0), pos % P
+        # A lane that is not live walks one block of whatever its row names:
+        # its result is discarded.
+        last = jnp.where(live, pos, 0)
+        ckv, kr, stats = list(state["ckv"]), list(state["kr"]), []
+        for i in range(self.n_layers):
+            lp = params[f"layer{i}"]
+            with jax.named_scope("mla_decode"):
+                qn, qr, c_kv, k_r = self._project(lp, rms_norm(x, lp["norm1"], self.eps), pos)
+                ckv[i] = self._write_pages(ckv[i], w_page, off, c_kv.astype(ckv[i].dtype))
+                kr[i] = self._write_keys(kr[i], w_page, off, k_r, runs=False)
+                o = jax.lax.map(
+                    lambda a, lp=lp, pools=(ckv[i], kr[i]): self._attend_tile(
+                        lp, *a[:2], pools, *a[2:], form),
+                    (qn[:, None], qr[:, None], bt, pos[:, None], last))
+                y = self._attn_out(lp, o[:, 0])
+            x = x + y.astype(self.dtype)
+            y, st = self._ffn(lp, i, rms_norm(x, lp["norm2"], self.eps), live)
+            if st is not None:
+                stats.append(st)
+            x = x + y.astype(self.dtype)
+        context = jnp.sum(jnp.where(live, pos + 1, 0))
+        walked = jnp.sum(self._blocks_needed(last, P, bt.shape[1])) \
+            * self._block_pages(P, bt.shape[1]) * P
+        acc = self._accumulate(state["acc"], 1, stats, context, context, walked, form)
+        return self._emit(params, state, dict(state, ckv=ckv, kr=kr), x, live, pos, acc)
+
+    # -- host side ----------------------------------------------------------------
+    def bind_metrics(self, metrics: Any) -> None:
+        name = self.name
+        self._counters = [self._expert_counters(metrics, ph) + [
+            metrics.counter(f"mla_rows_attended_total{{model={name},phase={ph}}}"),
+            metrics.counter(f"mla_rows_walked_total{{model={name},phase={ph}}}"),
+        ] + [metrics.counter(f"mla_launches_total{{model={name},phase={ph},form={form}}}")
+             for form in FORMS] for ph in GEN_PHASES]
+
+
+def create(cfg: ModelConfig) -> LatentServing:
+    return LatentServing(cfg)

@@ -4,16 +4,20 @@
 ``PagedLM`` is the part of a decoder-only model that is the same whatever its
 layers are: the model's ``config.json`` and what is served of it, the draw of
 every tensor by recipe, the per-lane block of the paged state, a launch's
-tile arithmetic (ISSUE 31's packed prefill), paged full attention for
-prefill tiles and for decode, page writes, the head, the sampler with its
-served log-probabilities, the arming of a lane and a step's token
-bookkeeping, the device's sums into counters, and the whole host side of a
-``:generate`` request. A family adds its layers: ``_tensors``, ``_gains``,
+tile arithmetic (ISSUE 31's packed prefill), the walk of a block table in key
+blocks under a running softmax (``_key_blocks``, ``_over_key_blocks``,
+``_merge_key_blocks``: what attention by head and latent attention share,
+ISSUE 34), paged full attention
+by head for prefill tiles and for decode, page writes (rows by head or one
+latent row a token), the head, the sampler with its served
+log-probabilities, the arming of a lane and a step's token bookkeeping, the
+device's sums into counters, and the whole host side of a ``:generate``
+request. A family adds its layers: ``_tensors``, ``_gains``,
 ``kv_page_signature``, ``prefill_chunk``, ``step``, ``bind_metrics``.
 
-A subclass sets, in its constructor: ``dtype``, ``d``, ``hd``, ``kv`` (KV
-heads held), ``eps``, ``vocab_full``, ``v_first``, ``vocab``, ``scales``, and
-calls ``_serve_options``.
+A subclass sets, in its constructor: ``dtype``, ``d``, ``eps``,
+``vocab_full``, ``v_first``, ``vocab``, ``scales``, where it attends by head
+``hd`` and ``kv`` (KV heads held), and calls ``_serve_options``.
 """
 
 from __future__ import annotations
@@ -75,6 +79,7 @@ def head_share(name: str, idx: int, of: int, heads: list[int], kv_full: int):
 
 class PagedLM(GenerativeModel):
     supports_kv_paging = True
+    kv_page_leaves = ("kf", "vf")  # K and V by head; a family with another row says so
 
     def _serve_options(self, cfg: ModelConfig, a: dict) -> None:
         """What is served of the model: the context, the draw's seed and
@@ -105,7 +110,18 @@ class PagedLM(GenerativeModel):
         for path, shape, full, start, scale, fan_in in self._tensors():
             put(path, seeded.draw(seed, "/".join(path), shape, scale / math.sqrt(fan_in),
                                   self.dtype, full_shape=full, start=start))
+        for path, shape, full, start, lo, hi in self._vectors():
+            # The four summed bytes over their range, in [0, 1], then the range.
+            u = 0.5 + seeded.draw(seed, "/".join(path), shape, seeded.BELL_STD / 1020.0,
+                                  jnp.float32, full_shape=full, start=start)
+            put(path, jnp.float32(lo) + jnp.float32(hi - lo) * u)
         return p
+
+    def _vectors(self):
+        """(path, shape, full shape, start, low, high) of the float32 vectors
+        drawn INSIDE a range (a bell over it, by the same recipe): none
+        unless the family has some (a router's selection bias)."""
+        return ()
 
     def _drawn(self) -> Any:
         return jax.jit(self.draw_params, static_argnums=0)(self.draw_seed or 0)
@@ -199,11 +215,19 @@ class PagedLM(GenerativeModel):
         off[t]) of every KV head: as ONE scatter of rows into the pool seen
         as (KV * pages * P, hd). (Scattered over two middle dimensions, the
         compiler copied the whole pool to another layout and back, eight
-        times a step: 13 of a step's 33 ms, my chip run, PR 28.)"""
+        times a step: 13 of a step's 33 ms, my chip run, PR 28.) A pool
+        with no heads, (pages, P, width), takes ``rows`` (T, width): one
+        latent row a token."""
+        if pool.ndim == 3:
+            return PagedLM._write_pages(pool[None], page, off, rows[:, None])[0]
         kv, n_pages, p_tokens, hd = pool.shape
         at = (jnp.arange(kv)[None, :] * n_pages + page[:, None]) * p_tokens + off[:, None]
         flat = pool.reshape(kv * n_pages * p_tokens, hd)
         return flat.at[at.reshape(-1)].set(rows.reshape(-1, hd)).reshape(pool.shape)
+
+    def _swiglu(self, u, w_gate, w_up, w_down):
+        h = (jax.nn.silu(_mm(u, w_gate)) * _mm(u, w_up)).astype(self.dtype)
+        return _mm(h, w_down)
 
     def _head(self, params, x):
         """(T, d) -> (T, vocab held) float32 logits."""
@@ -231,12 +255,15 @@ class PagedLM(GenerativeModel):
     # experts) runs once over the C packed rows; what reads a prompt's own
     # caches (attention, a scan's state) goes tile by tile.
 
+    TILE_ROWS = 0  # rows a tile has at least, where a family's tile pays a price of its own
+
     def kv_prefill_pieces(self, chunk: int, page_tokens: int) -> int:
-        """K: tiles of whole pages, as many as divide the chunk, at most
-        ``MAX_PIECES``. (A window does not enter: a window layer's tile
-        reads its ring and the ``window`` rows before it whatever its width.)"""
-        return next((k for k in range(min(MAX_PIECES, max(1, chunk // page_tokens)), 1, -1)
-                     if chunk % (k * page_tokens) == 0), 1)
+        """K: tiles of whole pages (of ``TILE_ROWS`` rows or more), as many as
+        divide the chunk, at most ``MAX_PIECES``. (A window does not enter: a
+        window layer's tile reads its ring and the ``window`` rows before it
+        whatever its width.)"""
+        most = min(MAX_PIECES, max(1, chunk // max(page_tokens, self.TILE_ROWS)))
+        return next((k for k in range(most, 1, -1) if chunk % (k * page_tokens) == 0), 1)
 
     def pack_prefill(self, pieces: list[PrefillPiece], chunk: int, k: int) -> Any:
         """Host-side: what one launch is told of its pieces, each at the next
@@ -307,6 +334,68 @@ class PagedLM(GenerativeModel):
             axis=1)[:, 0], 0)
         return page, cpos % P
 
+    @staticmethod
+    def _block_pages(P: int, pps: int) -> int:
+        """Pages a key block of ``KEY_BLOCK`` positions holds."""
+        return max(1, min(KEY_BLOCK // P, pps))
+
+    @staticmethod
+    def _key_blocks(row, P: int):
+        """The walk of one block-table row ``row`` (pps,) of pages of ``P``
+        positions in key blocks -> (pages a block, the row padded to whole
+        blocks)."""
+        kb = PagedLM._block_pages(P, row.shape[0])
+        return kb, jnp.pad(row, (0, -row.shape[0] % kb))
+
+    @staticmethod
+    def _blocks_needed(last, P: int, pps: int):
+        """Key blocks a walk up to position ``last`` takes (traced: a
+        prompt's first tile reads one block, not the padded context)."""
+        kb = PagedLM._block_pages(P, pps)
+        return jnp.minimum(last // (kb * P) + 1, -(-pps // kb))
+
+    @staticmethod
+    def _over_key_blocks(need, lead: tuple, width: int, block):
+        """Attention over key blocks 0 .. need - 1 (a traced count) under a
+        running softmax in float32. ``block(j)`` -> (the block's masked
+        scores (*lead, c) float32, a function of its un-normalised
+        probabilities (*lead, c) -> what they weigh (*lead, width) float32).
+        -> (*lead, width)."""
+        def body(j, carry):
+            m, l, acc = carry
+            s, weigh = block(j)
+            m2 = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m2[..., None])
+            scale = jnp.exp(m - m2)
+            acc = acc * scale[..., None] + weigh(p)
+            return m2, l * scale + jnp.sum(p, axis=-1), acc
+
+        m0 = jnp.full(lead, NEG, jnp.float32)
+        _m, l, acc = jax.lax.fori_loop(
+            0, need, body, (m0, jnp.zeros_like(m0),
+                            jnp.zeros(lead + (width,), jnp.float32)))
+        return acc / l[..., None]
+
+    @staticmethod
+    def _merge_key_blocks(need, lead: tuple, width: int, block):
+        """As ``_over_key_blocks`` where a kernel attends a whole key block at
+        once: ``block(j)`` -> (the block's un-normalised context (*lead,
+        width) float32, its rows' max (*lead,) and sum (*lead,) float32).
+        A row that sees no key of a block comes with a max so low that the
+        block's part weighs nothing."""
+        def body(j, carry):
+            m, l, acc = carry
+            part, m_j, l_j = block(j)
+            m2 = jnp.maximum(m, m_j)
+            old, new = jnp.exp(m - m2), jnp.exp(m_j - m2)
+            return m2, l * old + l_j * new, acc * old[..., None] + part * new[..., None]
+
+        m0 = jnp.full(lead, NEG, jnp.float32)
+        _m, l, acc = jax.lax.fori_loop(
+            0, need, body, (m0, jnp.zeros_like(m0),
+                            jnp.zeros(lead + (width,), jnp.float32)))
+        return acc / l[..., None]
+
     def _prefill_full(self, q, kp, vp, row, qpos, last):
         """A full layer's attention of one tile, q (T, H, hd) at positions
         ``qpos``, over its prompt's pages (block-table row ``row``) up to the
@@ -315,16 +404,13 @@ class PagedLM(GenerativeModel):
         prompt's first tile reads one block, not the padded context), summed
         with a running softmax in float32. Every row of the launch is in the
         pages before any tile reads them."""
-        T, P, pps = q.shape[0], kp.shape[2], row.shape[0]
-        kb = max(1, min(KEY_BLOCK // P, pps))     # pages a key block
-        n_blocks = -(-pps // kb)
-        rowp = jnp.pad(row, (0, n_blocks * kb - pps))
+        T, P = q.shape[0], kp.shape[2]
+        kb, rowp = self._key_blocks(row, P)
         g = q.shape[1] // self.kv
         qg = q.reshape(T, self.kv, g, self.hd)
-        need = jnp.minimum(last // (kb * P) + 1, n_blocks)
+        need = self._blocks_needed(last, P, row.shape[0])
 
-        def body(j, carry):
-            m, l, acc = carry
+        def block(j):
             pg = jax.lax.dynamic_slice(rowp, (j * kb,), (kb,))
             kblk = jnp.take(kp, pg, axis=1).reshape(self.kv, kb * P, self.hd)
             vblk = jnp.take(vp, pg, axis=1).reshape(self.kv, kb * P, self.hd)
@@ -332,20 +418,12 @@ class PagedLM(GenerativeModel):
             see = kpos[None, :] <= qpos[:, None]
             s = jnp.einsum("tkgd,kcd->kgtc", qg, kblk,
                            preferred_element_type=jnp.float32) * (self.hd ** -0.5)
-            s = jnp.where(see[None, None], s, NEG)
-            m2 = jnp.maximum(m, jnp.max(s, axis=-1))
-            p = jnp.exp(s - m2[..., None])
-            scale = jnp.exp(m - m2)
-            acc = acc * scale[..., None] + jnp.einsum(
+            return jnp.where(see[None, None], s, NEG), lambda p: jnp.einsum(
                 "kgtc,kcd->kgtd", p.astype(vblk.dtype), vblk,
                 preferred_element_type=jnp.float32)
-            return m2, l * scale + jnp.sum(p, axis=-1), acc
 
-        m0 = jnp.full((self.kv, g, T), NEG, jnp.float32)
-        _m, l, acc = jax.lax.fori_loop(
-            0, need, body, (m0, jnp.zeros_like(m0),
-                            jnp.zeros((self.kv, g, T, self.hd), jnp.float32)))
-        return (acc / l[..., None]).transpose(2, 0, 1, 3).reshape(q.shape)
+        o = self._over_key_blocks(need, (self.kv, g, T), self.hd, block)
+        return o.transpose(2, 0, 1, 3).reshape(q.shape)
 
     def _prefill_full_tiles(self, qt, kp, vp, t: dict):
         """``_prefill_full`` tile by tile: qt (K, T, H, hd) over each tile's
