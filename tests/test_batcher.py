@@ -524,3 +524,152 @@ def test_adaptive_deadline_headroom_preempts_accumulation(rt_model):
             "deadline_exceeded_total{model=toy}").value == 0
 
     run(go())
+
+
+# -- batches counted in rows (ISSUE 35) ----------------------------------------
+
+def _place_all(units, width, per_row, limit):
+    """Place requests of these units in order; rows by request (None: it
+    fitted nowhere), and the rows."""
+    from tpuserve.batcher import _Request, _Rows
+
+    rows = _Rows(width, per_row)
+    reqs = [_Request(item=None, group=None, future=None, units=u)
+            for u in units]
+    return [r.row if rows.place(r, limit) else None for r in reqs], rows
+
+
+@pytest.mark.parametrize("case,units,width,per_row,limit,want", [
+    ("one item a row: rows are items", [1] * 5, 1, 1, 4, [0, 1, 2, 3, None]),
+    ("the tightest open row", [60, 70, 80, 25], 100, 8, 8, [0, 1, 2, 1]),
+    ("a new row while the batch may grow one", [60, 50], 100, 8, 2, [0, 1]),
+    ("no row fits and none is left: looked past, later ones still placed",
+     [60, 70, 50, 30, 90, 35], 100, 8, 2, [0, 1, None, 1, None, 0]),
+    ("exactly to a row's end, and the row is closed",
+     [40, 60, 1], 100, 8, 2, [0, 0, 1]),
+    ("at most per_row items however small", [1] * 7, 100, 3, 3,
+     [0, 0, 0, 1, 1, 1, 2]),
+    ("the cap of items a row closes it with units left",
+     [10, 10, 50, 10], 100, 2, 2, [0, 0, 1, 1]),
+])
+def test_rows_place_by_best_fit(case, units, width, per_row, limit, want):
+    got, rows = _place_all(units, width, per_row, limit)
+    assert got == want, case
+    assert rows.n == len({r for r in got if r is not None}) <= limit
+    assert rows.units == sum(u for u, r in zip(units, got) if r is not None)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_rows_never_break_a_row_or_the_limit(seed):
+    """Lengths as the benchmark's mix draws them, 1,024 waiting against 256
+    rows of 512: no row over its width or its eight items, no more rows
+    than the limit, and the launch over 90% full of tokens."""
+    rng = np.random.default_rng(seed)
+    units = np.clip(rng.lognormal(np.log(300), 0.6, 1024), 16, 510).astype(int) + 2
+    got, rows = _place_all([int(u) for u in units], 512, 8, 256)
+    used, held = np.zeros(256, int), np.zeros(256, int)
+    for u, r in zip(units, got):
+        if r is not None:
+            used[r] += u
+            held[r] += 1
+    assert rows.n == 256 and used.max() <= 512 and held.max() <= 8
+    assert used.sum() == rows.units > 0.90 * 256 * 512
+    assert 1.3 < held.sum() / 256 < 2.0
+
+
+def test_rows_kept_when_members_go():
+    """What is left of a batch keeps each item in the row it had, renumbered:
+    never more rows than before, and the open rows take again."""
+    from tpuserve.batcher import _Request, _Rows
+
+    rows = _Rows(100, 8)
+    reqs = [_Request(item=None, group=None, future=None, units=u)
+            for u in (60, 70, 30, 25)]
+    assert [rows.place(r, 4) and r.row for r in reqs] == [0, 1, 1, 0]
+    left = _Rows.of([reqs[1], reqs[2], reqs[3]], 100, 8)
+    assert [r.row for r in reqs[1:]] == [0, 0, 1]
+    assert (left.n, left.units) == (2, 125)
+    late = _Request(item=None, group=None, future=None, units=75)
+    assert left.place(late, 2) and late.row == 1
+
+
+def test_placing_1024_waiting_documents_takes_milliseconds():
+    """The close runs on the event loop: 1,024 documents against 256 rows
+    of 512 in under 10 ms of this thread's CPU time (a plain walk of the
+    open rows takes tens; measured here at 0.3 to 0.6), the best of five so
+    that a busy host does not decide it."""
+    import time as _time
+
+    rng = np.random.default_rng(0)
+    units = [int(u) for u in np.clip(
+        rng.lognormal(np.log(300), 0.6, 1024), 16, 510).astype(int) + 2]
+    best = float("inf")
+    for _ in range(5):
+        t0 = _time.thread_time()
+        _place_all(units, 512, 8, 256)
+        best = min(best, _time.thread_time() - t0)
+    assert best < 0.010, best
+
+
+@pytest.fixture(scope="module")
+def bert_rt():
+    cfg = ModelConfig(
+        name="bert", family="bert", batch_buckets=[2, 4], seq_buckets=[32],
+        deadline_ms=20.0, dtype="float32", num_classes=4, parallelism="single",
+        max_queue=256, request_timeout_ms=30_000.0,
+        options=dict(layers=1, d_model=32, heads=2, d_ff=64, vocab_size=512))
+    model = build(cfg)
+    return model, build_runtime(model)
+
+
+def test_bert_documents_share_rows_through_the_batcher(bert_rt):
+    """The real family end to end: twelve documents of 5-14 tokens against
+    buckets of 2 and 4 rows of 32 ride fewer rows than documents, every
+    one answered as it is answered alone, and /metrics counts the rows."""
+    import jax
+
+    model, rt = bert_rt
+    assert model.packs_rows
+    rng = np.random.default_rng(0)
+    docs = [np.concatenate([[2], rng.integers(5, 500, n), [3]]).astype(np.int32)
+            for n in (3, 12, 5, 9, 7, 4, 11, 6, 8, 3, 10, 5)]
+    params = rt.params_per_mesh[0]
+    fwd = jax.jit(model.forward)
+    alone = [model.host_postprocess(jax.tree_util.tree_map(
+        np.asarray, fwd(params, model.assemble([d], (2, 32)))), 1)[0]
+        for d in docs]
+
+    async def go():
+        metrics = Metrics()
+        b = ModelBatcher(model, rt, metrics)
+        await b.start()
+        futs = [b.submit(d, group=model.group_key(d)) for d in docs]
+        res = await asyncio.wait_for(asyncio.gather(*futs), timeout=30)
+        await b.stop()
+        return res, metrics, b.pipeline_stats()
+
+    res, metrics, stats = run(go())
+    for got, want in zip(res, alone):
+        assert [e["class"] for e in got["top_k"]] == \
+            [e["class"] for e in want["top_k"]]
+        np.testing.assert_allclose([e["prob"] for e in got["top_k"]],
+                                   [e["prob"] for e in want["top_k"]], atol=1e-5)
+    rows = metrics.counter("batcher_batch_rows_total{model=bert}").value
+    assert metrics.counter("items_total{model=bert}").value == 12
+    assert 4 <= rows < 12                    # 103 tokens need 4 rows of 32
+    assert stats["rows"]["items_per_row"] == round(12 / rows, 3)
+    assert "batcher_batch_rows_total" in metrics.render_prometheus()
+
+
+def test_toy_model_counts_rows_as_items(rt_model):
+    async def go():
+        b, metrics = make_batcher(rt_model, deadline_ms=10_000.0)
+        await b.start()
+        await asyncio.wait_for(
+            asyncio.gather(*[b.submit(item()) for _ in range(4)]), timeout=10)
+        await b.stop()
+        return metrics
+
+    metrics = run(go())
+    assert metrics.counter("batcher_batch_rows_total{model=toy}").value == \
+        metrics.counter("items_total{model=toy}").value == 4

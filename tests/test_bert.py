@@ -154,6 +154,155 @@ def test_bad_json_raises(served):
         model.host_decode(b'{"no_text": 1}', "application/json")
 
 
+# -- documents that share a row ------------------------------------------------
+
+def _packing_cfg(path, **over):
+    """A model whose rows may be shared, on the XLA pair (float32) or, with
+    the test steering the platform's name, on the whole-sequence kernel."""
+    if path == "fused":
+        return tiny_cfg(batch_buckets=[2], seq_buckets=[256], dtype="bfloat16",
+                        options=dict(layers=1, d_model=128, heads=2, d_ff=128,
+                                     vocab_size=512), **over)
+    return tiny_cfg(batch_buckets=[2], seq_buckets=[64],
+                    options=dict(TINY, layers=2), **over)
+
+
+def _documents(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [np.concatenate([[2], rng.integers(5, 500, n - 2), [3]])
+            .astype(np.int32) for n in lengths]
+
+
+def _forward(model, path, monkeypatch):
+    import importlib
+
+    import jax
+
+    if path == "fused":
+        fa = importlib.import_module("tpuserve.ops.flash_attention")
+        monkeypatch.setattr(fa, "platform_here", lambda: "tpu")
+        monkeypatch.setattr(fa, "_interpret_here", lambda: True)
+    return jax.jit(model.forward)
+
+
+# Documents a row as fractions of the row's width, in the order they lie in
+# row 0 (row 1 holds one more): one, two and the cap of eight, ending exactly
+# at the row's end or leaving part of it empty.
+SHARED = {"one": [0.5], "two-to-the-end": [0.25, 0.75], "two-short": [0.2, 0.3],
+          "eight-to-the-end": [0.125] * 8,
+          "eight-short": [0.06, 0.1, 0.12, 0.05, 0.2, 0.08, 0.1, 0.07]}
+
+
+@pytest.mark.parametrize("path", ["dense", "fused"])
+@pytest.mark.parametrize("layout", sorted(SHARED))
+def test_a_document_answers_in_a_shared_row_as_alone(layout, path, monkeypatch):
+    import jax
+
+    model = build(_packing_cfg(path))
+    assert model.packs_rows
+    (bucket,) = [b for b in model.buckets() if b[0] == 2]
+    s = bucket[1]
+    docs = _documents([max(3, int(f * s)) for f in SHARED[layout]] + [s // 3])
+    params = model.init_params(jax.random.key(0))
+    fwd = _forward(model, path, monkeypatch)
+    rows = [0] * (len(docs) - 1) + [1]
+    together = fwd(params, model.assemble(docs, bucket, rows))
+    assert together["probs"].shape == (2 * model.ROW_ITEMS, 4)
+    assert model.traced_paths(bucket) == {"attention": path}
+    atol = 1e-5 if path == "dense" else 0.02
+    for i, doc in enumerate(docs):
+        alone = fwd(params, model.assemble([doc], bucket))
+        np.testing.assert_allclose(np.asarray(together["probs"])[i],
+                                   np.asarray(alone["probs"])[0], atol=atol)
+    results = model.host_postprocess(
+        jax.tree_util.tree_map(np.asarray, together), len(docs))
+    assert len(results) == len(docs)
+
+
+def test_one_document_a_row_is_the_unshared_program_bit_for_bit():
+    """On the XLA path a launch of one document a row answers what the
+    program of (ids, mask) answers: the mesh's, and every launch's before
+    rows were shared."""
+    import jax
+
+    shared = build(_packing_cfg("dense"))
+    plain = build(_packing_cfg("dense", parallelism="replica"))
+    assert shared.packs_rows and not plain.packs_rows
+    docs = _documents([64, 20])
+    params = shared.init_params(jax.random.key(0))
+    got = jax.jit(shared.forward)(params, shared.assemble(docs, (2, 64)))
+    want = jax.jit(plain.forward)(params, plain.assemble(docs, (2, 64)))
+    assert np.array_equal(np.asarray(got["probs"])[:2], np.asarray(want["probs"]))
+    assert np.array_equal(np.asarray(got["indices"])[:2],
+                          np.asarray(want["indices"]))
+
+
+@pytest.mark.parametrize("fault", ["cls_off_by_one", "segments_swapped",
+                                   "positions_not_restarted"])
+def test_a_misplaced_document_is_caught(fault):
+    """What assemble hands the program, got wrong one way at a time: the
+    second document of a row no longer answers as it does alone."""
+    import jax
+
+    model = build(_packing_cfg("dense"))
+    docs = _documents([20, 30])
+    params = model.init_params(jax.random.key(0))
+    fwd = jax.jit(model.forward)
+    alone = np.asarray(fwd(params, model.assemble([docs[1]], (2, 64)))["probs"])[0]
+    ids, seg, cls_at = model.assemble(docs, (2, 64), [0, 0])
+    right = np.asarray(fwd(params, (ids, seg, cls_at))["probs"])[1]
+    np.testing.assert_allclose(right, alone, atol=1e-5)
+    if fault == "cls_off_by_one":
+        cls_at = cls_at.copy()
+        cls_at[1] += 1
+    elif fault == "segments_swapped":
+        seg = np.where(seg == 1, 2, np.where(seg == 2, 1, 0)).astype(np.int32)
+        seg[0, 19], seg[0, 20] = seg[0, 20], seg[0, 19]
+    else:   # one run of a single number: positions run on through both
+        seg = (seg != 0).astype(np.int32)
+    wrong = np.asarray(fwd(params, (ids, seg, cls_at))["probs"])[1]
+    assert np.abs(wrong - alone).max() > 1e-3
+
+
+def test_assemble_lays_the_documents_of_a_row_one_after_another():
+    model = build(_packing_cfg("dense"))
+    docs = _documents([10, 54, 7, 20])
+    ids, seg, cls_at = model.assemble(docs, (2, 64), [0, 0, 1, 1])
+    assert list(cls_at[:4]) == [0, 10, 64, 71] and not cls_at[4:].any()
+    assert (seg[0, :10] == 1).all() and (seg[0, 10:] == 2).all()
+    assert np.array_equal(ids[0], np.concatenate(docs[:2]))
+    assert (seg[1, :7] == 1).all() and (seg[1, 7:27] == 2).all()
+    assert not seg[1, 27:].any() and (ids[1, 27:] == model.tokenizer.pad_id).all()
+    # What fits no row is refused, not cut: too many tokens, too many items.
+    with pytest.raises(ValueError, match="cannot take"):
+        model.assemble(_documents([40, 30]), (2, 64), [0, 0])
+    with pytest.raises(ValueError, match="cannot take"):
+        model.assemble(_documents([3] * 9), (2, 64), [0] * 9)
+
+
+@pytest.mark.parametrize("over,want", [
+    ({}, True),
+    ({"parallelism": "replica"}, False),
+    ({"parallelism": "sharded"}, False),
+    ({"parallelism": "pipeline"}, False),
+    ({"options": {**TINY, "attention": "flash"}}, False),
+    ({"options": {**TINY, "moe_experts": 2}}, False),
+    ({"options": {**TINY, "attention": "dense"}}, True),
+    ({"quantize": "int8c"}, True),
+])
+def test_rows_are_shared_where_the_program_keeps_documents_apart(over, want):
+    """Decided from the configuration the model was built with, by no key
+    of its own: one device, a dense feed-forward, the XLA pair or the
+    whole-sequence kernel. Everything else answers one item a row and a
+    program of (ids, mask)."""
+    model = build(tiny_cfg(**over))
+    assert model.packs_rows is want
+    assert model.row_shape(16) == ((16, model.ROW_ITEMS) if want else (1, 1))
+    item = np.arange(11, dtype=np.int32)
+    assert model.item_units(item, 16) == (11 if want else 1)
+    assert len(model.input_signature((2, 16))) == (3 if want else 2)
+
+
 # -- sequence-parallel serving -----------------------------------------------
 
 @pytest.mark.slow
@@ -175,14 +324,16 @@ def test_sequence_parallel_serving_matches_dense(impl):
     items = [dense.host_decode(
         json.dumps({"text": f"sequence parallel serving {i}"}).encode(),
         "application/json") for i in range(3)]  # 3 of 4 lanes real
-    batch = dense.assemble(items, (4, 16))
+    # Each its own batch: the one-device model's rows may be shared (its
+    # program takes segments), the mesh's keep one document a row.
+    batch = sp_model.assemble(items, (4, 16))
     params = dense.init_params(jax.random.key(0))  # same tree either impl
     # Same params: the runtime loaded its own; rerun the SP forward with
     # dense's params for the apples-to-apples check.
     out_sp = jax.jit(sp_model.forward)(params, batch)
-    out_dense = jax.jit(dense.forward)(params, batch)
-    np.testing.assert_allclose(np.asarray(out_sp["probs"]),
-                               np.asarray(out_dense["probs"]), atol=1e-5)
+    out_dense = jax.jit(dense.forward)(params, dense.assemble(items, (4, 16)))
+    np.testing.assert_allclose(np.asarray(out_sp["probs"])[:3],
+                               np.asarray(out_dense["probs"])[:3], atol=1e-5)
     assert np.asarray(rt.run((4, 16), batch)["probs"]).shape == (4, 4)
 
 

@@ -228,10 +228,12 @@ def test_bert_sharded_flash_serving_matches_dense():
     items = [dense.host_decode(
         json.dumps({"text": f"sharded flash {i}"}).encode(),
         "application/json") for i in range(5)]  # 5 of 8 lanes real
-    batch = dense.assemble(items, (8, 64))
+    # Each its own batch: the one-device model's program takes segments.
+    batch = flash.assemble(items, (8, 64))
     o_f = np.asarray(jax.jit(flash.forward)(params, batch)["probs"])
-    o_d = np.asarray(jax.jit(dense.forward)(params, batch)["probs"])
-    np.testing.assert_allclose(o_f, o_d, atol=1e-5)
+    o_d = np.asarray(jax.jit(dense.forward)(
+        params, dense.assemble(items, (8, 64)))["probs"])
+    np.testing.assert_allclose(o_f[:5], o_d[:5], atol=1e-5)
     assert np.asarray(rt.run((8, 64), batch)["probs"]).shape == (8, 4)
 
 

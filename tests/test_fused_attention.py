@@ -11,7 +11,7 @@ import pytest
 
 from tpuserve.config import ModelConfig
 from tpuserve.models import build
-from tpuserve.models.bert import _masked_attention
+from tpuserve.models.bert import _masked_attention, _segment_bias
 from tpuserve.obs import Metrics
 
 # `tpuserve.ops.flash_attention` as an attribute is the function: the package
@@ -109,6 +109,106 @@ def test_fused_refuses_what_it_cannot_tile(shape):
     x = jnp.zeros(shape, jnp.bfloat16)
     with pytest.raises(ValueError, match="use dense attention"):
         fa.fused_attention(x, x, x, jnp.ones(shape[:2]), block_h=2)
+
+
+# -- documents that share a row --------------------------------------------------
+
+# Lengths of the documents of one row of 512, in the order they lie in it:
+# one, two and the cap of eight, ending exactly at the row's end or short of it.
+ROWS = {"one-full": [512], "one-short": [300], "two-full": [200, 312],
+        "two-short": [100, 150], "eight-full": [64] * 8,
+        "eight-short": [30, 40, 50, 60, 70, 80, 20, 16]}
+
+
+def _segments(lengths, s=512):
+    seg, at = np.zeros(s, np.int32), 0
+    for j, n in enumerate(lengths):
+        seg[at:at + n] = j + 1
+        at += n
+    return seg
+
+
+def _packed_inputs(lengths, seed=0, s=512):
+    """Row 0 holds the documents; row 1 is one document of the whole row."""
+    rng = np.random.default_rng(seed + len(lengths) + sum(lengths))
+    q, k, v = (jnp.asarray(rng.normal(size=(B, s, H, D)), jnp.bfloat16)
+               for _ in range(3))
+    seg = np.stack([_segments(lengths, s), np.ones(s, np.int32)])
+    return q, k, v, seg
+
+
+def _attend(path, q, k, v, seg):
+    if path == "fused":
+        return fa.fused_attention(q, k, v, jnp.asarray(seg))
+    return _masked_attention(q, k, v, _segment_bias(jnp.asarray(seg)))
+
+
+@pytest.mark.parametrize("path", ["fused", "dense"])
+@pytest.mark.parametrize("layout", sorted(ROWS))
+def test_a_document_answers_in_a_shared_row_as_alone(layout, path):
+    """Each document of the row, moved alone to the start of a row of its
+    own, gets the answer it got among its neighbours."""
+    lengths = ROWS[layout]
+    q, k, v, seg = _packed_inputs(lengths)
+    together = np.asarray(_attend(path, q, k, v, seg), np.float32)
+    at = 0
+    for n in lengths:
+        alone = [jnp.zeros_like(x).at[0, :n].set(x[0, at:at + n])
+                 for x in (q, k, v)]
+        seg1 = np.stack([_segments([n]), np.ones(512, np.int32)])
+        got = np.asarray(_attend(path, *alone, seg1), np.float32)
+        # Other keys weigh exactly 0.0; what is left is the order in which
+        # the second product adds the live ones up.
+        np.testing.assert_allclose(together[0, at:at + n], got[0, :n],
+                                   atol=2e-3)
+        at += n
+
+
+@pytest.mark.parametrize("layout", sorted(ROWS))
+def test_fused_matches_the_dense_path_on_shared_rows(layout):
+    q, k, v, seg = _packed_inputs(ROWS[layout], seed=1)
+    out = np.asarray(_attend("fused", q, k, v, seg), np.float32)
+    ref = np.asarray(_attend("dense", q, k, v, seg), np.float32)
+    np.testing.assert_allclose(out[seg != 0], ref[seg != 0], atol=0.04)
+
+
+@pytest.mark.parametrize("path", ["fused", "dense"])
+def test_keys_of_a_neighbour_weigh_exactly_nothing(path):
+    """Whatever the other documents of the row hold, a document's answer is
+    the same bits."""
+    lengths = ROWS["eight-short"]
+    q, k, v, seg = _packed_inputs(lengths)
+    mine = jnp.asarray(seg == 3)[:, :, None, None]
+    noise = jnp.asarray(np.random.default_rng(7).normal(size=k.shape) * 50,
+                        k.dtype)
+    a = _attend(path, q, k, v, seg)
+    b = _attend(path, q, jnp.where(mine, k, noise), jnp.where(mine, v, noise),
+                seg)
+    assert np.array_equal(np.asarray(a, np.float32)[seg == 3],
+                          np.asarray(b, np.float32)[seg == 3])
+
+
+@pytest.mark.parametrize("path", ["fused", "dense"])
+def test_a_swapped_segment_number_is_caught(path):
+    """Two neighbours under each other's number at one token each: both
+    answers move, far past rounding."""
+    q, k, v, seg = _packed_inputs(ROWS["two-full"])
+    wrong = seg.copy()
+    wrong[0, 199], wrong[0, 200] = 2, 1
+    right = np.asarray(_attend(path, q, k, v, seg), np.float32)
+    got = np.asarray(_attend(path, q, k, v, wrong), np.float32)
+    assert np.abs(got[0, :199] - right[0, :199]).max() > 0.05
+    assert np.array_equal(got[1], right[1])
+
+
+def test_one_document_a_row_is_the_key_mask_bit_for_bit():
+    """Segment numbers 0 / 1 are the mask the kernel took before rows were
+    shared, padded queries included."""
+    q, k, v, live, _ = _inputs(512, 300)
+    seg = np.where(live, 1, 0).astype(np.int32)
+    assert np.array_equal(
+        np.asarray(fa.fused_attention(q, k, v, live), np.float32),
+        np.asarray(fa.fused_attention(q, k, v, jnp.asarray(seg)), np.float32))
 
 
 # -- the rule ------------------------------------------------------------------
@@ -244,15 +344,18 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("heads", [16, 12])
-def test_kernel_compiles_for_the_v5e_at_the_cells_widths(one_chip, heads):
+@pytest.mark.parametrize("heads,mask", [(16, jnp.bool_), (12, jnp.bool_),
+                                        (16, jnp.int32), (12, jnp.int32)])
+def test_kernel_compiles_for_the_v5e_at_the_cells_widths(one_chip, heads,
+                                                         mask):
     """What the interpreter cannot show: Mosaic takes the transposed first
     product, the sublane concatenations and 16 unrolled heads within VMEM,
     and XLA adds no copy around the call when q, k and v arrive
     sequence-minor, as the projections write them."""
     b, s, d = 8, 512, 64
     x = jax.ShapeDtypeStruct((b, heads, d, s), jnp.bfloat16, sharding=one_chip)
-    live = jax.ShapeDtypeStruct((b, s), jnp.bool_, sharding=one_chip)
+    # A key mask, or the segment numbers of documents sharing a row.
+    live = jax.ShapeDtypeStruct((b, s), mask, sharding=one_chip)
 
     def attend(q, k, v, live):
         to = lambda a: a.transpose(0, 3, 1, 2)  # noqa: E731

@@ -408,12 +408,15 @@ def test_bert_assemble_into_matches_assemble():
         options=dict(layers=1, d_model=16, heads=2, d_ff=32, vocab_size=64))
     model = build(cfg)
     items = [np.array([5, 6, 7], np.int32), np.array([9], np.int32)]
-    want_ids, want_mask = model.assemble(items, (2, 8))
-    buf_ids = np.full((2, 8), 33, np.int32)
-    buf_mask = np.full((2, 8), 1, np.int32)
-    got_ids, got_mask = model.assemble_into(items, (2, 8), (buf_ids, buf_mask))
-    np.testing.assert_array_equal(got_ids, want_ids)
-    np.testing.assert_array_equal(got_mask, want_mask)
+    want = model.assemble(items, (2, 8))
+    # A dirty buffer of the program's signature (on one device: ids,
+    # segments and the [CLS] positions of documents that may share rows).
+    bufs = tuple(np.full(s.shape, 33, s.dtype)
+                 for s in model.input_signature((2, 8)))
+    got = model.assemble_into(items, (2, 8), bufs)
+    assert len(got) == len(want) == len(bufs)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
 
 
 def test_custom_assemble_without_assemble_into_skips_arena():

@@ -206,10 +206,15 @@ def test_no_batch_is_assembled_before_a_slot_is_free():
     """Staging small against a launch: every batch's assembly begins with a
     device-section slot free for it, from the first batch on (before a
     measurement the gate counts the device section), and no more than the
-    device section holds is ever closed."""
-    b, model, dev, _, _ = run_closed(parent_rule=False, seconds=0.8)
+    device section holds is ever closed. The launch is 100 ms so that the
+    claim's premise, a reserve (twice close -> launch: two thread hops and
+    the assembly) under ONE LAUNCH, holds on a host whose other cores run
+    five more test workers: at 30 ms a launch and a reserve held under 10 ms
+    the test failed there by its own margins, not by the rule's."""
+    b, model, dev, _, _ = run_closed(parent_rule=False, launch_s=0.1,
+                                     seconds=1.6)
     assert len(model.assembled) > 10
-    assert b._reserve_ms() < 10.0 < b._device_ms[(32,)]
+    assert b._reserve_ms() < 0.5 * b._device_ms[(32,)]
     assert all(in_use < b.depth for _, _, in_use in model.assembled), \
         model.assembled
     assert b._inflight_peak <= b.depth
@@ -590,3 +595,199 @@ def test_gate_cancelled_waiter_passes_its_place_on():
         assert g.held == 1
 
     run(go())
+
+
+# -- (h) rows that are shared (ISSUE 35) ---------------------------------------
+# A batch is counted in ROWS against the batch buckets. For a model that says
+# nothing (Model above: every test before this line) rows are items. Sharing
+# here: a row of WIDTH units, at most PER_ROW items, an item's units from its
+# value. The host batch is (bucket x PER_ROW, 2) in arrival order, so the
+# device's count of items and the answers work as they do above.
+
+WIDTH, PER_ROW = 100, 4
+
+
+def units_of(x):
+    return 5 + int(x * 37) % 90          # 5 .. 94 of a row of 100
+
+
+class SharingModel(Model):
+    def __init__(self, cfg, assemble_s=0.0):
+        super().__init__(cfg, assemble_s)
+        # (bucket rows, rows occupied, most units in a row, most items in a
+        # row, the items in arrival order) of every assembly
+        self.laid: list[tuple[int, int, int, int, list[float]]] = []
+
+    def row_shape(self, group=None):
+        return WIDTH, PER_ROW
+
+    def item_units(self, item, group=None):
+        return units_of(item)
+
+    def input_signature(self, bucket):
+        import jax
+
+        return jax.ShapeDtypeStruct((bucket[0] * PER_ROW, 2), np.float32)
+
+    def assemble(self, items, bucket, rows=None):
+        return self.assemble_into(
+            items, bucket, np.zeros((bucket[0] * PER_ROW, 2), np.float32), rows)
+
+    def assemble_into(self, items, bucket, out, rows=None):
+        rows = list(range(len(items))) if rows is None else rows
+        used, held = {}, {}
+        for x, r in zip(items, rows):
+            used[r] = used.get(r, 0) + units_of(x)
+            held[r] = held.get(r, 0) + 1
+        assert sorted(used) == list(range(len(used))), rows
+        self.laid.append((bucket[0], len(used), max(used.values()),
+                          max(held.values()), list(items)))
+        return super().assemble_into(items, bucket, out)
+
+
+def make_sharing(launch_s=0.03, buckets=(4, 32), **cfg_over):
+    b, _, dev, metrics = make(launch_s=launch_s, buckets=buckets, **cfg_over)
+    model = SharingModel(b.cfg)
+    model.batcher = b
+    b = ModelBatcher(model, dev, metrics,
+                     pipeline_cfg=PipelineConfig(depth=2, assemble_ahead=2))
+    model.batcher = b
+    b.parent_rule = False
+    return b, model, dev, metrics
+
+
+def run_sharing(callers=96, seconds=1.0, **kw):
+    async def go():
+        b, model, dev, metrics = make_sharing(**kw)
+        await b.start()
+        n = await closed_loop(b, callers, seconds, think_s=0.004)
+        await b.stop()
+        return b, model, dev, metrics, n
+
+    return run(go())
+
+
+@pytest.fixture(scope="module")
+def shared_run():
+    return run_sharing()
+
+
+@pytest.mark.parametrize("claim", [
+    "no launch exceeds its bucket's rows", "no row exceeds its units",
+    "no row exceeds its items", "rows are shared", "every answer is its own",
+    "the counter equals the rows launched", "stats show items a row"])
+def test_a_model_that_shares_rows_is_batched_in_rows(shared_run, claim):
+    """96 callers over buckets [4, 32] of rows of 100 units, items of 5-94
+    (about 50): a launch of 32 rows carries more items than rows, and
+    nothing the model is handed breaks the row's width, the items a row or
+    the bucket's rows."""
+    b, model, dev, metrics, n = shared_run
+    assert len(model.laid) > 10
+    if claim == "no launch exceeds its bucket's rows":
+        assert all(rows <= bucket for bucket, rows, *_ in model.laid)
+        assert all(bucket in (4, 32) for bucket, *_ in model.laid)
+    elif claim == "no row exceeds its units":
+        assert max(units for _, _, units, _, _ in model.laid) <= WIDTH
+    elif claim == "no row exceeds its items":
+        assert max(held for _, _, _, held, _ in model.laid) <= PER_ROW
+    elif claim == "rows are shared":
+        # Over the run, not launch by launch: which items meet in a launch
+        # is the host's timing, how many rows they need is not.
+        laid = [(rows, len(items)) for _, rows, _, _, items in model.laid[4:]]
+        assert sum(n for _, n in laid) > 1.3 * sum(r for r, _ in laid), laid
+        assert any(n_items > rows for rows, n_items in laid)
+    elif claim == "every answer is its own":
+        assert n == sum(len(items) for *_, items in model.laid)   # closed_loop asserts each
+    elif claim == "the counter equals the rows launched":
+        assert metrics.counter("batcher_batch_rows_total{model=fake}").value \
+            == sum(rows for _, rows, *_ in model.laid)
+        assert metrics.counter("items_total{model=fake}").value == n
+    else:
+        rows = b.pipeline_stats()["rows"]
+        assert rows["launched"] == sum(r for _, r, *_ in model.laid)
+        assert rows["items"] == n and rows["items_per_row"] > 1.3
+        assert rows["looked_past"] == 0      # stop() failed what was left
+
+
+def test_one_item_a_row_counts_rows_as_items():
+    """The default: the new counter and the old move together."""
+    b, _, dev, metrics, n = run_closed(parent_rule=False, seconds=0.5)
+    assert metrics.counter("batcher_batch_rows_total{model=fake}").value == n
+    assert b.pipeline_stats()["rows"]["items_per_row"] == 1.0
+
+
+def test_a_request_looked_past_heads_the_next_batch():
+    """One bucket of 2 rows of 100 units, the device busy: six requests
+    wait. The close fills both rows and looks past what fits neither for
+    the later ones that do; what it looked past leads the next batch in the
+    order it arrived, ahead of everything that came after."""
+    async def go():
+        b, model, dev, _ = make_sharing(launch_s=0.05, buckets=(2,),
+                                        deadline_ms=1.0)
+        sizes = {}
+
+        def item(units, tag):
+            x = next(x for x in (tag * 1000 + k for k in range(1000))
+                     if units_of(float(x)) == units)
+            sizes[float(x)] = units
+            return float(x)
+
+        await b.start()
+        first = [b.submit(item(60, 0))]          # runs alone, at once
+        await asyncio.sleep(0.01)
+        waiting = [item(u, t + 1) for t, u in enumerate((70, 60, 50, 30, 90, 35))]
+        futs = first + [b.submit(x) for x in waiting]
+        await asyncio.gather(*futs)
+        await b.stop()
+        return model.laid, waiting
+
+    laid, w = run(go())
+    batches = [items for *_, items in laid]
+    assert len(batches[0]) == 1
+    # 70 and 60 open the two rows; 50 fits neither and is looked past; 30
+    # shares 70's row; 90 is looked past; 35 shares 60's row.
+    assert batches[1] == [w[0], w[1], w[3], w[5]]
+    # The next batch starts with what was looked past, oldest first.
+    assert batches[2] == [w[2], w[4]]
+
+
+def test_sharing_rows_never_puts_a_close_off():
+    """A lone request with the device free goes alone and at once, and a
+    timer's flush is not held for rows to fill."""
+    async def go():
+        b, model, dev, _ = make_sharing(deadline_ms=2.0)
+        await b.start()
+        t0 = time.perf_counter()
+        assert await b.submit(7.0) == 7.0
+        took = time.perf_counter() - t0
+        await b.stop()
+        return took, dev.launches, model.laid
+
+    took, launches, laid = run(go())
+    assert len(launches) == 1 and laid[0][1] == 1
+    assert took < 0.03 + 0.5, took          # the launch, not a wait for more
+
+
+def test_what_the_close_looked_past_fails_with_the_queue_at_stop():
+    """One row a launch and three requests too large to share it: the second
+    launch takes the first, the next batch (waiting for the device) the
+    second, and the third is still where the close left it when stop()
+    comes: it fails there with the queue, nothing hangs, nothing is
+    counted pending."""
+    async def go():
+        b, model, dev, _ = make_sharing(launch_s=0.2, buckets=(1,),
+                                        deadline_ms=1.0)
+        await b.start()
+        big = [float(x) for x in range(2000, 3000)
+               if units_of(float(x)) > 60][:3]
+        futs = [b.submit(x) for x in [1.0] + big]
+        await asyncio.sleep(0.05)
+        looked_past = b.pipeline_stats()["rows"]["looked_past"]
+        await b.stop()
+        return futs, b, looked_past
+
+    futs, b, looked_past = run(go())
+    assert looked_past == 1
+    assert all(f.done() for f in futs)
+    assert isinstance(futs[-1].exception(), RuntimeError)
+    assert b.pending == 0 and not any(b._skipped.values())

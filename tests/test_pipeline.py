@@ -145,7 +145,8 @@ def test_bert_pipeline_serving_matches_single():
                              "application/json")] * 3
     out_s = rt_s.fetch(rt_s.run(bucket, m_s.assemble(items, bucket)))
     out_p = rt_p.fetch(rt_p.run(bucket, m_p.assemble(items, bucket)))
-    np.testing.assert_allclose(out_p["probs"], out_s["probs"],
+    # The one-device program answers a row a document it could hold.
+    np.testing.assert_allclose(out_p["probs"][:3], out_s["probs"][:3],
                                rtol=1e-4, atol=1e-5)
     np.testing.assert_array_equal(out_p["indices"][:3], out_s["indices"][:3])
 

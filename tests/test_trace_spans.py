@@ -254,7 +254,8 @@ def test_counters_and_both_flush_reasons_tick(served):
 def test_flush_reasons_target_and_timer():
     """A full bucket flushes for `target`; a lone item waits out the timer
     and flushes for `timer` (fixed-timer batching, so the target is the
-    largest bucket)."""
+    largest bucket). The bucket is full when its ROWS are: documents of 12
+    tokens, so that no two share a row of 16."""
 
     from tpuserve import models as modelzoo
     from tpuserve.batcher import ModelBatcher
@@ -265,7 +266,9 @@ def test_flush_reasons_target_and_timer():
     model = modelzoo.build(_bert_cfg(name="bertf", batch_buckets=[2],
                                      deadline_ms=20.0))
     rt = build_runtime(model, metrics=metrics)
-    item = model.host_decode(b'{"text": "x y"}', "application/json")
+    item = model.host_decode(b'{"text": "a b c d e f g h i j"}',
+                             "application/json")
+    assert model.item_units(item, model.group_key(item)) == 12
 
     async def go():
         b = ModelBatcher(model, rt, metrics,

@@ -147,6 +147,98 @@ class _Request:
     # Joined its batch at the close (the final drain) rather than while the
     # group accumulated: batcher_batch_items_total{joined=}.
     at_close: bool = False
+    # What the item takes of a row (ServingModel.item_units), and the row of
+    # its batch it was placed in (_Rows.place).
+    units: int = 1
+    row: int = 0
+
+
+class _Rows:
+    """The rows a batch occupies while it fills: what the batcher compares
+    with a batch bucket. An item goes into the open row it fits most
+    tightly (best fit), else into a new row while the batch may still grow
+    one; a row stays open while it has units left and fewer than
+    ``per_row`` items. Open rows are kept by their free units with one bit
+    a size, so a placement is a shift and a lowest set bit, not a walk of
+    the rows. A model of one item a row (width 1) never has an open row:
+    every item takes a new one and rows are items."""
+
+    __slots__ = ("width", "per_row", "n", "units", "_items", "_free",
+                 "_open", "_sizes")
+
+    def __init__(self, width: int, per_row: int) -> None:
+        self.width = width
+        self.per_row = per_row
+        self.n = 0                          # rows occupied
+        self.units = 0                      # units placed
+        self._items: list[int] = []         # items by row
+        self._free: list[int] = []          # free units by row
+        self._open: dict[int, list[int]] = {}   # free units -> open rows
+        self._sizes = 0                     # bit f: a row with f free is open
+
+    @property
+    def has_open(self) -> bool:
+        return self._sizes != 0
+
+    def place(self, req: _Request, limit: int) -> bool:
+        """Give ``req`` its row; False if no open row takes it and the batch
+        already occupies ``limit`` rows."""
+        units = req.units
+        fits = self._sizes >> units
+        if fits:
+            free = units + (fits & -fits).bit_length() - 1
+            rows = self._open[free]
+            row = rows.pop()
+            if not rows:
+                self._sizes ^= 1 << free
+        elif self.n < limit:
+            free, row = self.width, self.n
+            self.n += 1
+            self._items.append(0)
+            self._free.append(free)
+        else:
+            return False
+        self._items[row] += 1
+        self._free[row] = free - units
+        self._keep_open(row)
+        self.units += units
+        req.row = row
+        return True
+
+    @classmethod
+    def of(cls, reqs: "list[_Request]", width: int, per_row: int) -> "_Rows":
+        """The rows of ``reqs`` as they were placed, renumbered from 0 in
+        the order they first appear: what is left of a batch when some of it
+        has gone (expired, cancelled, a retry's half). Keeping each item's
+        row, rather than placing again, can never need more rows."""
+        rows = cls(width, per_row)
+        for r, row in zip(reqs, _renumbered(reqs)):
+            r.row = row
+            if row == rows.n:
+                rows.n += 1
+                rows._items.append(0)
+                rows._free.append(width)
+            rows._items[row] += 1
+            rows._free[row] -= r.units
+            rows.units += r.units
+        for row in range(rows.n):
+            rows._keep_open(row)
+        return rows
+
+    def _keep_open(self, row: int) -> None:
+        """``row`` takes more while it has units left and room for an item."""
+        free = self._free[row]
+        if free > 0 and self._items[row] < self.per_row:
+            self._open.setdefault(free, []).append(row)
+            self._sizes |= 1 << free
+
+
+def _renumbered(reqs: "list[_Request]") -> list[int]:
+    """The row of each request, numbered from 0 in the order the rows first
+    appear: what is left of a batch (expired, cancelled, a retry's half)
+    keeps each item in the row it had."""
+    renumber: dict[int, int] = {}
+    return [renumber.setdefault(r.row, len(renumber)) for r in reqs]
 
 
 class ModelBatcher:
@@ -213,6 +305,10 @@ class ModelBatcher:
             j: metrics.counter(
                 f"batcher_batch_items_total{{model={name},joined={j}}}")
             for j in ("accumulate", "close")}
+        # Rows those items occupied in their launches: items over rows is
+        # items a row (1.0 for a model of one item a row).
+        self._c_rows = metrics.counter(
+            f"batcher_batch_rows_total{{model={name}}}")
         self._c_batch_errors = metrics.counter(
             f"batch_errors_total{{model={name}}}")
         self._c_retries = metrics.counter(f"batch_retries_total{{model={name}}}")
@@ -252,6 +348,15 @@ class ModelBatcher:
         self.stages = stages if stages is not None \
             else StageExecutors(self.pipeline_cfg, metrics)
         self._queues: dict[Hashable, asyncio.Queue[_Request]] = {}
+        # Per group: the requests a close looked past (they fitted no row of
+        # its batch), ahead of the queue in arrival order, and the units of
+        # everything waiting in either.
+        self._skipped: dict[Hashable, deque[_Request]] = {}
+        # What a row holds and what an item takes of it (ServingModel); a
+        # stand-in model that says nothing has one item a row.
+        self._row_shape = getattr(model, "row_shape", lambda group: (1, 1))
+        self._item_units = getattr(model, "item_units", lambda item, group: 1)
+        self._waiting_units: dict[Hashable, int] = {}
         self._tasks: dict[Hashable, asyncio.Task] = {}
         self._dispatch_tasks: set[asyncio.Task] = set()
         self._gate: AdmissionGate | None = None
@@ -361,13 +466,16 @@ class ModelBatcher:
         # clients: fail them explicitly (ADVICE r1: stop() cleared queues
         # without resolving futures).
         err = RuntimeError(f"server shutting down; {self.model.name} not served")
-        for q in self._queues.values():
+        for group, q in self._queues.items():
+            waiting = list(self._skipped.pop(group, ()))
             while not q.empty():
-                req = q.get_nowait()
+                waiting.append(q.get_nowait())
+            for req in waiting:
                 self._pending -= 1
                 if not req.future.done():
                     req.future.set_exception(err)
         self._queues.clear()
+        self._waiting_units.clear()
         if self._dispatch_tasks:
             await asyncio.gather(*self._dispatch_tasks, return_exceptions=True)
         self._maybe_idle()
@@ -398,12 +506,16 @@ class ModelBatcher:
         fut: asyncio.Future = loop.create_future()
         req = _Request(item=item, group=group, future=fut,
                        enqueued_at=time.perf_counter(), deadline_at=deadline_at,
-                       priority=priority, ctx=ctx)
+                       priority=priority, ctx=ctx,
+                       units=self._item_units(item, group))
         q = self._queues.get(group)
         if q is None:
             q = self._queues[group] = asyncio.Queue()
+            self._skipped[group] = deque()
+            self._waiting_units[group] = 0
             self._tasks[group] = loop.create_task(self._group_loop(group, q))
         q.put_nowait(req)
+        self._waiting_units[group] += req.units
         self._pending += 1
         self._idle_event.clear()
         self._g_queue_depth.set(self._pending)
@@ -542,7 +654,8 @@ class ModelBatcher:
         return live
 
     # -- adaptive flush scheduling (event loop) ------------------------------
-    def _flush_headroom(self, batch: list[_Request]) -> float:
+    def _flush_headroom(self, batch: list[_Request],
+                        rows: "_Rows | None" = None) -> float:
         """Earliest-deadline flush bound (perf_counter clock): the batch must
         dispatch while ~EWMA(batch duration) + slack still fits before the
         earliest per-request deadline (Clockwork P3 — duration is
@@ -552,7 +665,8 @@ class ModelBatcher:
                         if r.deadline_at is not None), default=None)
         if earliest is None:
             return float("inf")
-        bucket = self.model.bucket_for(len(batch), group=batch[0].group)
+        bucket = self.model.bucket_for(
+            len(batch) if rows is None else rows.n, group=batch[0].group)
         est_ms = self._ewma_ms.get(bucket, 0.0)
         return earliest - (est_ms + self.adaptive_cfg.slack_ms) / 1e3
 
@@ -660,8 +774,8 @@ class ModelBatcher:
         return max(0.0, min(queued) - self._reserve_ms()) / 1e3
 
     def _close_limit(self, n: int, queued: int, group: Hashable) -> int:
-        """How far the close may grow a batch of ``n`` with ``queued`` more
-        waiting: to the largest bucket, unless that takes the batch past
+        """How far the close may grow a batch of ``n`` rows with ``queued``
+        more rows' worth waiting: to the largest bucket, unless that takes the batch past
         the edge of the bucket it occupies into a launch that is dearer per
         item than filling this one, by the device time measured per bucket
         plus the staging every launch pays (128 outstanding items must not
@@ -683,8 +797,32 @@ class ModelBatcher:
         return here[0]
 
     # -- accumulation (event loop) ------------------------------------------
+    def _join(self, batch: list[_Request], rows: _Rows, req: _Request,
+              limit: int) -> bool:
+        """Place ``req`` in a row of ``batch`` if one takes it, or a new row
+        while the batch occupies fewer than ``limit``."""
+        if not rows.place(req, limit):
+            return False
+        batch.append(req)
+        self._waiting_units[req.group] -= req.units
+        return True
+
+    def _still_live(self, batch: list[_Request], rows: _Rows
+                    ) -> tuple[list[_Request], _Rows]:
+        """``batch`` less what has expired or gone while it waited, and the
+        rows of what is left."""
+        live = self._expire_dead(batch, adjust_pending=True)
+        if len(live) != len(batch):
+            rows = _Rows.of(live, rows.width, rows.per_row)
+        return live, rows
+
     async def _group_loop(self, group: Hashable, q: asyncio.Queue) -> None:
+        # A batch is counted in ROWS against the batch buckets. For a model
+        # of one item a row (ServingModel.row_shape's default) rows are
+        # items and every comparison below is the count of requests.
         max_bucket = max(self.cfg.batch_buckets)
+        width, per_row = self._row_shape(group)
+        skipped = self._skipped[group]
         deadline_s = self.cfg.deadline_ms / 1e3
         acfg = self.adaptive_cfg
         adaptive = acfg.enabled
@@ -694,8 +832,11 @@ class ModelBatcher:
                 # Chaos: an escaped exception kills this task, exactly the
                 # failure revive_group_loops exists to repair.
                 self.injector.check("kill_group_loop", self.model.name)
-            req = await q.get()
-            batch = [req]
+            # What the last close looked past is older than the queue.
+            req = skipped.popleft() if skipped else await q.get()
+            batch: list[_Request] = []
+            rows = _Rows(width, per_row)
+            self._join(batch, rows, req, max_bucket)
             tgt = self._targets.get(group, init_target)
             target_n = (min(max_bucket, max(acfg.min_target, math.ceil(tgt)))
                         if adaptive else max_bucket)
@@ -705,16 +846,21 @@ class ModelBatcher:
                 # wait by the deadline headroom, and stops accumulating at
                 # the AIMD target instead of the largest bucket.
                 flush_at = req.enqueued_at + deadline_s
-                while len(batch) < target_n:
+                while rows.n < target_n:
                     limit = flush_at
                     if adaptive:
-                        limit = min(limit, self._flush_headroom(batch))
+                        limit = min(limit, self._flush_headroom(batch, rows))
                     timeout = limit - time.perf_counter()
                     if timeout <= 0:
                         timer_flush = True
                         break
+                    if skipped:
+                        self._join(batch, rows, skipped.popleft(), max_bucket)
+                        continue
                     try:
-                        batch.append(await asyncio.wait_for(q.get(), timeout))
+                        self._join(batch, rows,
+                                   await asyncio.wait_for(q.get(), timeout),
+                                   max_bucket)
                     except asyncio.TimeoutError:
                         timer_flush = True
                         break
@@ -727,8 +873,9 @@ class ModelBatcher:
                            model=self.model.name, batch=bid, n=len(batch),
                            reason=reason)
                 if adaptive:
-                    self._aimd_update(group, tgt, len(batch), target_n,
-                                      timer_flush, pressure=not q.empty())
+                    self._aimd_update(
+                        group, tgt, rows.n, target_n, timer_flush,
+                        pressure=bool(skipped) or not q.empty())
                 # Backpressure, and the close: the gate opens when the
                 # device time still queued has fallen to the reserve
                 # (_close_wait_s), so the group task waits HERE, with its
@@ -739,10 +886,12 @@ class ModelBatcher:
                 # deadline in the batch (P3): a request that dies behind
                 # slow in-flight work fails fast AT its deadline, instead of
                 # being discovered dead only when capacity finally frees.
-                batch = self._expire_dead(batch, adjust_pending=True)
+                batch, rows = self._still_live(batch, rows)
 
                 def is_full() -> bool:
-                    return len(batch) + q.qsize() >= max_bucket
+                    # What waits would fill the largest bucket's rows.
+                    return rows.units + self._waiting_units[group] >= \
+                        max_bucket * width
 
                 while batch:
                     earliest = min((r.deadline_at for r in batch
@@ -758,7 +907,7 @@ class ModelBatcher:
                             break
                         except asyncio.TimeoutError:
                             pass
-                    batch = self._expire_dead(batch, adjust_pending=True)
+                    batch, rows = self._still_live(batch, rows)
                 if not batch:
                     continue  # everything expired; no admission was taken
                 t_slot = time.perf_counter()
@@ -767,7 +916,8 @@ class ModelBatcher:
                            model=self.model.name, batch=bid)
             except asyncio.CancelledError:
                 # stop() cancelled us mid-accumulation: requests already
-                # pulled off the queue must fail, not hang their clients.
+                # pulled off the queue must fail, not hang their clients
+                # (what a close looked past is stop()'s, with the queue).
                 err = RuntimeError(
                     f"server shutting down; {self.model.name} not served")
                 self._pending -= len(batch)
@@ -780,12 +930,28 @@ class ModelBatcher:
             # admission) would only wait longer — fold it into this batch, up
             # to the largest bucket or the edge _close_limit holds it to. This
             # makes batch size track device speed instead of deadline x
-            # arrival-rate (SURVEY.md §7 hard-part 2).
-            limit = self._close_limit(len(batch), q.qsize(), group)
-            while len(batch) < limit and not q.empty():
-                late = q.get_nowait()
-                late.at_close = True
-                batch.append(late)
+            # arrival-rate (SURVEY.md §7 hard-part 2). Where rows are shared
+            # the close looks past a request that fits no row for later ones
+            # that fill holes, at most as many as the batch could hold: those
+            # keep their place ahead of the queue, so the oldest request
+            # waiting is always in the next batch. The close is never put
+            # off for it.
+            limit = self._close_limit(
+                rows.n, -(-self._waiting_units[group] // width), group)
+            past: list[_Request] = []
+            while (rows.n < limit or rows.has_open) \
+                    and len(past) < limit * per_row:
+                if skipped:
+                    late = skipped.popleft()
+                elif not q.empty():
+                    late = q.get_nowait()
+                else:
+                    break
+                if self._join(batch, rows, late, limit):
+                    late.at_close = True
+                else:
+                    past.append(late)
+            skipped.extendleft(reversed(past))
             self._pending -= len(batch)
             self._g_queue_depth.set(self._pending)
             live = [r for r in batch if not r.future.cancelled()]
@@ -810,8 +976,8 @@ class ModelBatcher:
                                tid=self.model.name)
             # What the gate reads next: this batch's device time is queued
             # from now on, before it has reached a replica.
-            self._closed_ms[bid] = self._predicted_ms(
-                self.model.bucket_for(len(live), group=group))
+            self._closed_ms[bid] = self._predicted_ms(self.model.bucket_for(
+                len({r.row for r in live}), group=group))
             self._gate.poke()
             task = asyncio.get_running_loop().create_task(
                 self._dispatch(live, group, bid, t_slot))
@@ -915,8 +1081,10 @@ class ModelBatcher:
         pipeline, resolving futures on success. Raises on failure WITHOUT
         failing futures — the caller owns the retry policy."""
         name = self.model.name
-        bucket = self.model.bucket_for(len(reqs), group=group)
-        fill = len(reqs) / bucket[0]
+        rows = _renumbered(reqs)    # as the close placed them
+        n_rows = max(rows) + 1
+        bucket = self.model.bucket_for(n_rows, group=group)
+        fill = n_rows / bucket[0]
         self._g_fill.set(fill)
         self._c_batches.inc()
         # Batch identity for trace correlation (ISSUE 12), minted where the
@@ -929,7 +1097,8 @@ class ModelBatcher:
         # contains BOTH attempts.
         if bid is None:
             bid = self._mint_bid()
-        span = {"batch": bid, "bucket": bucket_label(bucket), "n": len(reqs)}
+        span = {"batch": bid, "bucket": bucket_label(bucket), "n": len(reqs),
+                "rows": n_rows}
         ctxs = [r.ctx for r in reqs if r.ctx is not None]
         ex_tid = ctxs[0].trace_id if ctxs else None
 
@@ -947,16 +1116,19 @@ class ModelBatcher:
         items = [r.item for r in reqs]
         # Assemble stage: into a recycled arena buffer when provably
         # equivalent, else the model's allocating assemble.
+        # Only a batch whose items share rows says which row each is in
+        # (ServingModel.row_shape); one item a row is the order they come in.
+        placed = (rows,) if n_rows < len(reqs) else ()
         lease = self.arena.acquire(bucket) if self.arena is not None else None
         try:
             if lease is not None:
                 host_batch = await self.stages.run(
                     name, "assemble", self.model.assemble_into,
-                    items, bucket, lease.buf, span=span)
+                    items, bucket, lease.buf, *placed, span=span)
             else:
                 host_batch = await self.stages.run(
                     name, "assemble", self.model.assemble, items, bucket,
-                    span=span)
+                    *placed, span=span)
             t1 = time.perf_counter()
             mark("preproc", t0, t1)
 
@@ -1021,6 +1193,7 @@ class ModelBatcher:
         t4 = time.perf_counter()
         mark("postproc", t3, t4)
         self._c_items.inc(len(reqs))
+        self._c_rows.inc(n_rows)
         n_close = sum(r.at_close for r in reqs)
         self._c_joined["close"].inc(n_close)
         self._c_joined["accumulate"].inc(len(reqs) - n_close)
@@ -1144,6 +1317,18 @@ class ModelBatcher:
                              else round(self._stage_ms, 2)),
                 "device_ms": {repr(b): round(v, 2)
                               for b, v in self._device_ms.items()},
+            },
+            # Batches are counted in rows: what the launches carried. Items
+            # over rows is items a row (1.0 where rows are not shared);
+            # looked_past: requests the last closes fitted into no row,
+            # waiting ahead of their queue.
+            "rows": {
+                "launched": int(self._c_rows.value),
+                "items": int(self._c_items.value),
+                "items_per_row": (round(self._c_items.value
+                                        / self._c_rows.value, 3)
+                                  if self._c_rows.value else None),
+                "looked_past": sum(len(d) for d in self._skipped.values()),
             },
             "inflight": self._inflight_now,
             "inflight_peak": self._inflight_peak,

@@ -117,6 +117,24 @@ class ServingModel(abc.ABC):
                 return (b,)
         return (self.cfg.batch_buckets[-1],)
 
+    def row_shape(self, group: Any = None) -> tuple[int, int]:
+        """``(units, items)`` a row of this group's launches holds. The
+        batcher counts a batch in ROWS against the batch buckets, and places
+        an item of ``item_units`` in an open row with that much left and
+        fewer than ``items`` in it. ``(1, 1)``, the default, is one item a
+        row: rows are items. A family whose program keeps the items of a
+        shared row apart answers more (BERT: the group's tokens); its
+        ``assemble`` / ``assemble_into`` then take ``rows=``, the row of each
+        item, and its outputs lead with ``bucket[0] * items`` rows in the
+        items' order, so ``host_postprocess(outputs, n_valid)`` reads the
+        first ``n_valid`` as ever."""
+        return 1, 1
+
+    def item_units(self, item: Any, group: Any = None) -> int:
+        """What ``item`` takes of a row of ``row_shape(group)[0]`` units
+        (at least 1, at most the row)."""
+        return 1
+
     # -- device-side --------------------------------------------------------
     def device_preprocess(self, batch: HostBatch) -> Any:
         """Jittable fused-preprocessing seam: raw wire bytes -> network input.

@@ -60,16 +60,28 @@ class BertBlock(nn.Module):
     quantize_compute: bool = False
 
     @nn.compact
-    def __call__(self, x, mask_bias):
+    def __call__(self, x, mask_bias, segments=None):
         # Post-LN (original BERT): sublayer -> add -> LayerNorm. Masking is an
         # explicit additive bias inside attention_fn so the semantics stay
         # bucket-invariant (padded keys get -1e9 before the f32 softmax).
+        # ``segments`` (B, S) numbers the documents that share a row (0 =
+        # padding): mask_bias is then (B, 1, S, S), built from it, and only
+        # the two paths that keep documents apart may run.
+        if segments is not None and (
+                self.moe_experts
+                or self.attention_impl not in ("fused", "dense")):
+            raise ValueError(
+                f"attention={self.attention_impl!r}, moe_experts="
+                f"{self.moe_experts}: cannot keep the documents of a shared "
+                "row apart")
         if self.attention_impl == "fused":
             from tpuserve.ops.flash_attention import fused_attention
 
-            # The kernel takes the keys' mask: a live key's bias is 0.0.
-            fn = lambda q, k, v, **kw: fused_attention(  # noqa: E731
-                q, k, v, mask_bias[:, 0, 0, :] == 0.0)
+            # The kernel takes segment numbers; a 0 / 1 key mask (a live
+            # key's bias is 0.0) is one document a row.
+            seg = (mask_bias[:, 0, 0, :] == 0.0 if segments is None
+                   else segments)
+            fn = lambda q, k, v, **kw: fused_attention(q, k, v, seg)  # noqa: E731
         elif self.attention_impl == "flash":
             from tpuserve.ops.flash_attention import flash_attention
 
@@ -165,12 +177,30 @@ class BertBlock(nn.Module):
 
 
 def _masked_attention(q, k, v, mask_bias):
-    """(B,S,H,D) attention with additive (B,1,1,S) key bias, f32 softmax."""
+    """(B,S,H,D) attention with an additive bias, by key (B,1,1,S) or by pair
+    (B,1,S,S: documents sharing a row), f32 softmax."""
     scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     s = s + mask_bias
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def _positions_in_segment(segments):
+    """(B, S) position of each token within its own run of one segment
+    number: a row's documents lie one after another, each from 0."""
+    at = jnp.arange(segments.shape[1], dtype=jnp.int32)[None, :]
+    starts = segments != jnp.pad(segments[:, :-1], ((0, 0), (1, 0)))
+    return at - jax.lax.cummax(jnp.where(starts, at, 0), axis=1)
+
+
+def _segment_bias(segments):
+    """(B, 1, S, S) additive bias: 0.0 between two tokens of one document,
+    -1e9 for a key of another document or of padding. A padded query sees
+    every document's keys (as with one document a row) and means nothing."""
+    keys, queries = segments[:, None, None, :], segments[:, None, :, None]
+    seen = ((queries == keys) | (queries == 0)) & (keys != 0)
+    return jnp.where(seen, 0.0, -1e9).astype(jnp.float32)
 
 
 class BertClassifier(nn.Module):
@@ -190,13 +220,27 @@ class BertClassifier(nn.Module):
     quantize_compute: bool = False
 
     @nn.compact
-    def __call__(self, ids, mask):
+    def __call__(self, ids, mask, cls_at=None):
+        """One document a row: ``mask`` is the 0 / 1 token mask and the
+        answer has a row a batch row. Documents sharing rows (``cls_at``
+        given): ``mask`` numbers each token's document within its row (0 =
+        padding, 1 .. J), ``cls_at`` holds the flat position ``row * S +
+        offset`` of every document's [CLS] in arrival order, and the answer
+        has a row for each of them. A document takes positions 0 .. n-1 from
+        the table wherever it lies in its row, attends over its own keys
+        only, and the pooler reads its own [CLS]: what it answers alone."""
         x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype, name="embed")(ids)
         pos = self.param("pos_embed", nn.initializers.normal(0.02),
                          (self.max_seq, self.d_model))
-        x = x + pos[None, : ids.shape[1], :].astype(self.dtype)
+        if cls_at is None:
+            segments = None
+            x = x + pos[None, : ids.shape[1], :].astype(self.dtype)
+            mask_bias = (1.0 - mask.astype(jnp.float32))[:, None, None, :] * -1e9
+        else:
+            segments = mask
+            x = x + pos.astype(self.dtype)[_positions_in_segment(segments)]
+            mask_bias = _segment_bias(segments)
         x = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.dtype, name="ln_embed")(x)
-        mask_bias = (1.0 - mask.astype(jnp.float32))[:, None, None, :] * -1e9
         for i in range(self.layers):
             x = BertBlock(self.heads, self.d_ff, dtype=self.dtype,
                           attention_impl=self.attention_impl,
@@ -204,13 +248,19 @@ class BertClassifier(nn.Module):
                           moe_experts=self.moe_experts,
                           moe_capacity_factor=self.moe_capacity_factor,
                           quantize_compute=self.quantize_compute,
-                          name=f"layer{i}")(x, mask_bias)
-        cls = x[:, 0, :]
+                          name=f"layer{i}")(x, mask_bias, segments)
+        cls = (x[:, 0, :] if cls_at is None
+               else x.reshape(-1, x.shape[-1])[cls_at])
         pooled = jnp.tanh(nn.Dense(self.d_model, dtype=self.dtype, name="pooler")(cls))
         return nn.Dense(self.num_classes, dtype=jnp.float32, name="classifier")(pooled)
 
 
 class BertServing(ServingModel):
+    # Most documents a row of a launch holds: a constant of the program (the
+    # answer has this many rows a batch row). The mix's own draw never put
+    # more than five in 512 tokens; the kernel's mask has room for fifteen.
+    ROW_ITEMS = 8
+
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)
         self._tokenize_obs = None  # bind_metrics
@@ -314,6 +364,12 @@ class BertServing(ServingModel):
             quantize_compute=cfg.quantize == "int8c",
         )
         self.top_k = min(5, cfg.num_classes)
+        # Documents may share a row where the program can keep them apart:
+        # one device and no mesh, a dense feed-forward (a routed one counts
+        # its capacity by row), and an attention path that takes segments
+        # (the XLA pair, or the whole-sequence kernel chosen per bucket).
+        self.packs_rows = (cfg.parallelism == "single" and not moe_experts
+                           and attention == "dense")
 
     def int8c_native_kernel_paths(self) -> list[str]:
         """The kernels the int8c modules consume natively: FFN matmuls
@@ -465,15 +521,29 @@ class BertServing(ServingModel):
         return (self.cfg.batch_buckets[-1], s)
 
     def input_signature(self, bucket: tuple) -> Any:
+        """(ids, mask); where rows are shared (ids, segments, cls_at): each
+        token's document within its row, and every document's [CLS] as a
+        flat position in arrival order."""
         b, s = bucket
-        return (
-            jax.ShapeDtypeStruct((b, s), jnp.int32),
-            jax.ShapeDtypeStruct((b, s), jnp.int32),
-        )
+        sig = (jax.ShapeDtypeStruct((b, s), jnp.int32),
+               jax.ShapeDtypeStruct((b, s), jnp.int32))
+        if self.packs_rows:
+            sig += (jax.ShapeDtypeStruct((b * self.ROW_ITEMS,), jnp.int32),)
+        return sig
+
+    def row_shape(self, group=None) -> tuple[int, int]:
+        if not self.packs_rows:
+            return super().row_shape(group)
+        return (group if group is not None else self.max_seq), self.ROW_ITEMS
+
+    def item_units(self, item: np.ndarray, group=None) -> int:
+        if not self.packs_rows:
+            return 1
+        return min(item.shape[0], self.row_shape(group)[0])
 
     # -- device side ---------------------------------------------------------
     def forward(self, params: Any, batch: Any) -> dict:
-        ids, mask = batch
+        ids, mask, *cls_at = batch   # mask: segment numbers beside cls_at
         if self.cfg.parallelism == "pipeline":
             logits = self._pipeline_logits(params, ids, mask)
         else:
@@ -487,7 +557,7 @@ class BertServing(ServingModel):
             # Runs while the bucket is traced, never per call: the record of
             # what this bucket's program holds (traced_paths).
             self._attention_traced[tuple(ids.shape)] = module.attention_impl
-            logits = module.apply(params, ids, mask)
+            logits = module.apply(params, ids, mask, *cls_at)
         probs = jax.nn.softmax(logits, axis=-1)
         top_p, top_i = jax.lax.top_k(probs, self.top_k)
         return {"probs": top_p, "indices": top_i}
@@ -649,25 +719,44 @@ class BertServing(ServingModel):
     def canary_item(self) -> np.ndarray:
         return self.host_decode(b'{"text": "canary"}', "application/json")
 
-    def assemble(self, items: list[np.ndarray], bucket: tuple) -> Any:
-        b, s = bucket
-        ids = np.full((b, s), self.tokenizer.pad_id, np.int32)
-        mask = np.zeros((b, s), np.int32)
-        return self._fill_ids_mask(items, s, ids, mask)
+    def assemble(self, items: list[np.ndarray], bucket: tuple,
+                 rows: "list[int] | None" = None) -> Any:
+        return self.assemble_into(items, bucket, tuple(
+            np.empty(s.shape, s.dtype) for s in self.input_signature(bucket)),
+            rows)
 
-    def assemble_into(self, items: list[np.ndarray], bucket: tuple, out) -> Any:
-        ids, mask = out
+    def assemble_into(self, items: list[np.ndarray], bucket: tuple, out,
+                      rows: "list[int] | None" = None) -> Any:
+        """``rows`` (the batcher's, where rows are shared): the row of each
+        item, items of one row in the order they lie in it; None is one item
+        a row in order."""
+        s = bucket[1]
+        ids, mask = out[0], out[1]
         ids[:] = self.tokenizer.pad_id
         mask[:] = 0
-        return self._fill_ids_mask(items, bucket[1], ids, mask)
-
-    @staticmethod
-    def _fill_ids_mask(items, s, ids, mask):
+        if not self.packs_rows:
+            for i, it in enumerate(items):
+                n = min(it.shape[0], s)
+                ids[i, :n] = it[:n]
+                mask[i, :n] = 1
+            return ids, mask
+        cls_at = out[2]
+        cls_at[:] = 0
+        used = [0] * ids.shape[0]      # tokens in each row so far
+        held = [0] * ids.shape[0]      # documents in each row so far
         for i, it in enumerate(items):
-            n = min(it.shape[0], s)
-            ids[i, :n] = it[:n]
-            mask[i, :n] = 1
-        return ids, mask
+            r = i if rows is None else rows[i]
+            at, n = used[r], min(it.shape[0], s)
+            if at + n > s or held[r] >= self.ROW_ITEMS:
+                raise ValueError(
+                    f"row {r} of a {bucket} launch cannot take item {i}: "
+                    f"{at} + {n} tokens, {held[r]} documents")
+            ids[r, at:at + n] = it[:n]
+            held[r] += 1
+            mask[r, at:at + n] = held[r]
+            cls_at[i] = r * s + at
+            used[r] = at + n
+        return ids, mask, cls_at
 
     def host_postprocess(self, outputs: dict, n_valid: int) -> list[dict]:
         return self.format_top_k(outputs, n_valid)

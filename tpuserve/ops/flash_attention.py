@@ -319,6 +319,9 @@ _LANES = 128
 # (scripts/bench_flash.py --forward; PERF.md section 6, PR 29): at 512 by
 # 24-33%, at 256 by 6-9% at batch 256 and even at batch 32, at 128 slower.
 FUSED_SEQ_RANGE = (256, 512)
+# Contraction rows the whole-sequence kernel adds to its first product for the
+# mask (one bfloat16 tile): row 0 for padding, one for each document of a row.
+MASK_ROWS = 16
 
 
 def attention_path(platform: str, dtype, seq: int, head_dim: int) -> str:
@@ -333,30 +336,38 @@ def attention_path(platform: str, dtype, seq: int, head_dim: int) -> str:
     return "dense"
 
 
-def _fused_kernel(q_ref, k_ref, v_ref, live_ref, o_ref):
+def _fused_kernel(q_ref, k_ref, v_ref, seg_ref, o_ref):
     """One row's block of ``(heads, head_dim, S)``: a head's features on
     sublanes, the sequence on lanes, which is how XLA lays the projections'
     outputs out on the TPU. Scores are held transposed, keys on sublanes and
     queries on lanes, so that the softmax's maximum and sum run down the
     sublanes (plain vector maxima and adds) and come out as rows, the shape
-    that normalises the ``(head_dim, S)`` output. The key mask rides in the
-    first product: sixteen more contraction rows, -1e9 on padded keys
-    against ones, which the MXU takes in the same pass. The heads are
-    unrolled, so that one head's products overlap the next one's softmax."""
+    that normalises the ``(head_dim, S)`` output. The mask rides in the
+    first product: sixteen more contraction rows, which the MXU takes in
+    the same pass. Row j of the key side holds -1e9 where the key's segment
+    is j; row j of the query side holds 1 where the query's segment is NOT
+    j, and row 0 (padding) holds 1 for every query. A pair of one segment
+    sums to exactly 0.0 and any other pair to exactly one -1e9: nothing
+    large is ever cancelled. (A padded query's rows past 0 hold 0: it sees
+    every document's keys, as it saw the row's one document before rows
+    were shared, and means as little.) The heads are unrolled, so that one head's
+    products overlap the next one's softmax."""
     _, heads, head_dim, s = q_ref.shape
     dt = q_ref.dtype
     scale = head_dim ** -0.5
     exact = math.frexp(scale)[0] == 0.5     # a power of two: exact in bfloat16
-    first = (jax.lax.broadcasted_iota(jnp.int32, (16, s), 0) == 0).astype(dt)
-    bias = jnp.broadcast_to(
-        jnp.where(live_ref[0] != 0, 0.0, -1e9), (16, s)).astype(dt)
+    row = jax.lax.broadcasted_iota(jnp.int32, (MASK_ROWS, s), 0)
+    seg = jnp.broadcast_to(seg_ref[0], (MASK_ROWS, s))
+    k_mask = jnp.where(row == seg, -1e9, 0.0).astype(dt)
+    q_mask = jnp.where((row == 0) | ((row != seg) & (seg != 0)),
+                       1.0, 0.0).astype(dt)
     for h in range(heads):
         q = q_ref[0, h]
         if exact:                           # on (head_dim, S), not on (S, S)
             q = q * jnp.asarray(scale, dt)
         sc = jax.lax.dot_general(                        # (keys, queries)
-            jnp.concatenate([k_ref[0, h], bias], axis=0),
-            jnp.concatenate([q, first], axis=0),
+            jnp.concatenate([k_ref[0, h], k_mask], axis=0),
+            jnp.concatenate([q, q_mask], axis=0),
             (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         if not exact:                       # a masked key stays at -1e9 * scale
             sc = sc * scale
@@ -369,20 +380,23 @@ def _fused_kernel(q_ref, k_ref, v_ref, live_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_h", "interpret"))
 def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    live: jax.Array, *, block_h: int | None = None,
+                    segments: jax.Array, *, block_h: int | None = None,
                     interpret: bool | None = None):
     """Attention over a whole short sequence in one kernel, (B, S, H, D) in
-    and out, ``live`` the keys' mask (B, S): nonzero where a key counts.
+    and out. ``segments`` (B, S) numbers the documents that share a row: 0
+    is padding, 1 .. ``MASK_ROWS`` - 1 a document, and a query attends over
+    the keys of its own number only. A plain 0 / 1 key mask is the case of
+    one document a row.
 
     The mathematics of ``models.bert._masked_attention``: scaled scores (the
     products' float32 accumulators, where the dense path rounds them to the
-    input dtype first), float32 softmax over the live keys, weights rounded
-    to the input dtype for the product with the values; the normaliser is
-    applied to the float32 output. A masked key weighs exactly 0.0; a row
-    with no live key gets finite values that mean nothing. S in whole lanes
-    (128), head width in whole bf16 tiles (16); ``block_h`` heads a grid
-    step (default: all, which is fastest where it fits VMEM: 512 x 16 x 64
-    does). No VJP: it serves."""
+    input dtype first), float32 softmax over the query's own keys, weights
+    rounded to the input dtype for the product with the values; the
+    normaliser is applied to the float32 output. A key of padding or of
+    another document weighs exactly 0.0; a padded query gets finite values
+    that mean nothing. S in whole lanes (128), head width in whole bf16
+    tiles (16); ``block_h`` heads a grid step (default: all, which is
+    fastest where it fits VMEM: 512 x 16 x 64 does). No VJP: it serves."""
     b, s, h, d = q.shape
     block_h = h if block_h is None else block_h
     if d % 16 or s % _LANES or h % block_h:
@@ -407,5 +421,5 @@ def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
     )(*(x.transpose(0, 2, 3, 1) for x in (q, k, v)),
-      (live != 0).astype(jnp.int32).reshape(b, 1, s))
+      segments.astype(jnp.int32).reshape(b, 1, s))
     return out.transpose(0, 3, 1, 2)
