@@ -17,6 +17,7 @@ import pytest
 
 from tests import decoder_reference as ref
 from tpuserve.config import ModelConfig
+from tpuserve.genserve.engine import LOOP_PHASES
 from tpuserve.genserve.model import PrefillPiece
 from tpuserve.models import build, seeded
 from tpuserve.models import decoder as dec
@@ -435,18 +436,36 @@ def test_through_the_engine_both_cache_kinds_come_back_and_the_counters_move(tmp
 
     from jax.profiler import ProfileData
     seen: dict[str, set] = {}
+    phases, iters = set(), {}
     for path in glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"), recursive=True):
         for plane in ProfileData.from_file(path).planes:
             for line in plane.lines:
                 for e in line.events:
                     if e.name.startswith("tpuserve.gen_"):
-                        seen.setdefault(e.name, set()).update(k for k, _v in e.stats)
+                        stats = dict(e.stats)
+                        seen.setdefault(e.name, set()).update(stats)
+                        phases.add(stats.get("phase"))
+                        if "iter" in stats:
+                            iters.setdefault(e.name, set()).add(int(stats["iter"]))
     assert set(seen) == {"tpuserve.gen_admit", "tpuserve.gen_prefill", "tpuserve.gen_step",
-                         "tpuserve.gen_fetch", "tpuserve.gen_retire"}
+                         "tpuserve.gen_fetch", "tpuserve.gen_retire",
+                         # ISSUE 36: the loop by phase, and the workers' spans it was blind to
+                         "tpuserve.gen_loop", "tpuserve.gen_pack", "tpuserve.gen_extract",
+                         "tpuserve.gen_finalize"}
     assert {"model", "slot", "start"} <= seen["tpuserve.gen_prefill"]
     assert {"model", "lanes"} <= seen["tpuserve.gen_step"]
     assert {"model", "slot", "dur_us", "ago_us"} <= seen["tpuserve.gen_admit"]
     assert {"model", "slot", "dur_us", "ago_us"} <= seen["tpuserve.gen_retire"]
+    assert {"model", "phase", "iter", "dur_us", "ago_us"} <= seen["tpuserve.gen_loop"]
+    for name in ("gen_pack", "gen_prefill", "gen_step", "gen_fetch", "gen_extract", "gen_finalize"):
+        assert {"model", "iter"} <= seen["tpuserve." + name], name
+    # every phase but the idle engine's wait was entered with work to do, and
+    # a worker's span carries the number of a pass of the loop that the marks know
+    assert phases - {None} >= {"sweep", "admit", "prefill", "step", "account", "emit", "retire"}
+    assert phases - {None} <= set(LOOP_PHASES)
+    for name, seen_iters in iters.items():
+        assert seen_iters <= iters["tpuserve.gen_loop"] | {max(iters["tpuserve.gen_loop"]) + 1}, name
+    assert iters["tpuserve.gen_step"] == iters["tpuserve.gen_fetch"]
     # five requests through three slots: pages and rings were handed out again
     assert eng.pages.n_reserved == 0 and eng.pages.n_reserved_rings == 0
     assert eng.pages.n_free_rings == SLOTS

@@ -479,7 +479,7 @@ class ByHand:
             if eng.arena.n_active:
                 out = await eng.stages.run(eng.name, "fetch", eng._step_sync)
                 eng._c_iterations.inc()
-                eng._count_step(out)
+                eng._count_step(out, eng._stamp("account"))
                 await eng._retire(out)
 
     def count(self, family):

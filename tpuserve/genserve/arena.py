@@ -42,6 +42,10 @@ class SlotInfo:
     # None for unary. Rides the ledger so every release path — retire,
     # evict, disconnect, engine failure — can push the terminal unit.
     stream: Any = None
+    # When the slot's first generated token reached the host (perf_counter
+    # clock; ISSUE 36), streamed or not; the stream keeps its own stamp of
+    # the first unit it EMITTED.
+    first_unit_at: float | None = None
     meta: dict = field(default_factory=dict)
 
 

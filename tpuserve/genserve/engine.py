@@ -54,8 +54,8 @@ from tpuserve.genserve.arena import SlotArena, SlotInfo
 from tpuserve.genserve.model import GenerativeModel, PrefillPiece
 from tpuserve.genserve.pages import PageLedger
 from tpuserve.hostpipe import StageExecutors
-from tpuserve.obs import (GEN_STREAM_REASONS, PRIORITIES, Metrics, trace_mark,
-                          trace_span)
+from tpuserve.obs import (GEN_STREAM_REASONS, PRIORITIES, Metrics, trace_call,
+                          trace_mark, trace_span)
 from tpuserve.utils.locks import new_lock
 from tpuserve.utils.retrace import allow_transfers, host_fetch
 
@@ -68,6 +68,12 @@ log = logging.getLogger("tpuserve.genserve")
 # token comes up to that many iterations later, which no benchmark metric
 # sees.
 PREFILL_HOLD = 2
+
+# What the step loop is doing, one name for every instant of its life
+# (ISSUE 36; docs/OBSERVABILITY.md "The engine's loop by phase"): the labels of
+# ``gen_loop_seconds_total{phase=}`` and of the ``tpuserve.gen_loop`` marks.
+LOOP_PHASES = ("sweep", "admit", "prefill", "step", "account", "emit",
+               "retire", "wait")
 
 
 class KVPressure(QueueFull):
@@ -254,6 +260,12 @@ class GenEngine:
         # SLO; the terminated counter is per-reason (created on demand).
         self._h_first_unit = metrics.histogram(
             f"gen_first_unit_ms{{model={name}}}")
+        self._h_token_gap = metrics.histogram(
+            f"gen_token_gap_ms{{model={name}}}")
+        # The loop's wall time by phase (ISSUE 36): the eight sum to it.
+        self._c_loop = {p: metrics.counter(
+            f"gen_loop_seconds_total{{model={name},phase={p}}}")
+            for p in LOOP_PHASES}
         self._c_streams = metrics.counter(f"gen_streams_total{{model={name}}}")
         self._c_disconnects = metrics.counter(
             f"gen_client_disconnects_total{{model={name}}}")
@@ -335,6 +347,13 @@ class GenEngine:
         # still-open streams past it terminate with the "drain" error
         # event instead of holding the drain hostage.
         self._stream_kill_at: float | None = None
+        # The loop's own clock (``_stamp``): the phase it is in, since when,
+        # the pass of the loop it belongs to, and when a decoding iteration's
+        # out-block last reached the host (None across a wait).
+        self._phase = "sweep"
+        self._phase_t = 0.0
+        self._iter = 0
+        self._last_decode_at: float | None = None
 
     # -- compilation ----------------------------------------------------------
     def compile(self) -> None:
@@ -820,8 +839,25 @@ class GenEngine:
                             else prev + 0.2 * (need - prev))
 
     # -- step loop (event loop) -----------------------------------------------
+    def _stamp(self, phase: str) -> float:
+        """The loop's ONE clock reading at a boundary (ISSUE 36). Entering
+        another phase ends the one the loop was in: its length goes to
+        ``gen_loop_seconds_total{phase=}`` and, while a profiler session is
+        on, into the trace as a ``tpuserve.gen_loop`` mark. Within a phase
+        it only reads the clock, so that whatever reports a piece of the
+        loop's time (``gen_step_ms``, ``gen_insert_ms``, ``gen_extract_ms``,
+        the request trees' events) takes it from these readings."""
+        now = time.perf_counter()
+        if phase != self._phase:
+            self._c_loop[self._phase].inc(now - self._phase_t)
+            trace_mark("tpuserve.gen_loop", self._phase_t, now,
+                       model=self.name, phase=self._phase, iter=self._iter)
+            self._phase, self._phase_t = phase, now
+        return now
+
     async def _step_loop(self) -> None:
         name = self.name
+        self._phase, self._phase_t = "sweep", time.perf_counter()
         # The loop condition (not just task cancellation) gates each
         # iteration: asyncio.wait_for can swallow a cancel that lands the
         # same tick its inner future completes, and a step loop that
@@ -829,6 +865,8 @@ class GenEngine:
         # forever. _running goes False before stop() cancels, so either
         # path exits.
         while self._running:
+            self._stamp("sweep")
+            self._iter += 1
             if self.injector is not None:
                 # Chaos: an escaped exception kills this task — exactly the
                 # failure revive_group_loops exists to repair.
@@ -836,24 +874,30 @@ class GenEngine:
             self._expire_pending()
             self._evict_expired()
             if not self.arena.n_active and not self._pending:
+                self._stamp("wait")
+                self._last_decode_at = None  # no token gap across a wait
                 self._maybe_idle()
                 self._work_event.clear()
                 if not self._pending and not self.arena.n_active:
                     await self._work_event.wait()
                 continue
+            self._stamp("admit")
             await self._admit()
+            self._stamp("prefill")
             await self._advance_prefills()
             if not self.arena.n_active:
                 continue
             try:
+                t0 = self._stamp("step")
                 if self.injector is not None:
                     delay = self.injector.delay_s("slow_dispatch", name)
                     if delay > 0:
                         await asyncio.sleep(delay)
+                        t0 = self._stamp("step")  # the phase's, not the step's
                     self.injector.check("batch_error", name)
-                t0 = time.perf_counter()
                 out = await self.stages.run(name, "fetch", self._step_sync)
-                step_ms = (time.perf_counter() - t0) * 1e3
+                t1 = self._stamp("account")
+                step_s = t1 - t0
                 # Step events per traced slot (ISSUE 12): every mid-flight
                 # request's tree shows each iteration it rode, tagged with
                 # its slot — bounded by the model's own step cap, and what
@@ -866,33 +910,55 @@ class GenEngine:
                     if info.ctx is not None:
                         if ex_tid is None:
                             ex_tid = info.ctx.trace_id
-                        info.ctx.span("gen_step", wall - step_ms / 1e3,
-                                      wall, tid=name, slot=s,
+                        info.ctx.span("gen_step", wall - step_s, wall,
+                                      tid=name, slot=s,
                                       iteration=info.iterations)
-                self._h_step.observe(step_ms, trace_id=ex_tid)
-                self._observe_step(step_ms)
-                self._c_device_seconds.inc(step_ms / 1e3)
+                self._h_step.observe(step_s * 1e3, trace_id=ex_tid)
+                self._observe_step(step_s * 1e3)
+                self._c_device_seconds.inc(step_s)
                 if self.device_time_cb is not None:
-                    self.device_time_cb(step_ms / 1e3)
+                    self.device_time_cb(step_s)
                 self._c_iterations.inc()
                 self._c_replica_steps.inc()
-                self._count_step(out)
+                self._count_step(out, t1)
             except asyncio.CancelledError:
                 raise
             except Exception as e:  # noqa: BLE001 — contained per batch
                 await self._fail_active(e)
                 continue
+            self._stamp("emit")
             await self._emit_step_units(out)
+            self._stamp("retire")
             await self._retire(out)
 
-    def _count_step(self, out: dict) -> None:
+    def _count_step(self, out: dict, at: float) -> None:
         """One step's part of the per-step sums: the lanes that decoded a
         token in it, the pages and rings reserved while it ran, and whatever
-        the family sums on the device (``observe_step``)."""
+        the family sums on the device (``observe_step``). ``at`` is when the
+        step's out-block reached the host (the ``step`` phase's end): a lane
+        of a paged family that decodes for the first time has its first
+        token there (``gen_first_unit_ms``, streamed or not: a streamed
+        request is observed where its unit is emitted), and two such
+        moments in a row are one token gap (``gen_token_gap_ms``)."""
         self.model.observe_step(out)
-        self._c_decode_tokens.inc(sum(
-            1 for s in self.arena.active_slots()
-            if "prefill_next" not in self.arena.peek(s).meta))
+        decoded = 0
+        for s in self.arena.active_slots():
+            info = self.arena.peek(s)
+            if "prefill_next" in info.meta:
+                continue
+            decoded += 1
+            if info.first_unit_at is None:
+                info.first_unit_at = at
+                if self.paging and info.stream is None:
+                    self._h_first_unit.observe(
+                        (at - info.enqueued_at) * 1e3,
+                        trace_id=info.ctx.trace_id if info.ctx is not None
+                        else None)
+        self._c_decode_tokens.inc(decoded)
+        if decoded:
+            if self._last_decode_at is not None:
+                self._h_token_gap.observe((at - self._last_decode_at) * 1e3)
+            self._last_decode_at = at
         if self.pages is not None:
             self._c_pages_held.inc(self.pages.n_reserved)
             self._c_rings_held.inc(self.pages.n_reserved_rings)
@@ -910,10 +976,11 @@ class GenEngine:
         fetch of the out pytree. Runs on the fetch stage executor."""
         with self._dispatch_guard():
             with trace_span("tpuserve.gen_step", model=self.name,
-                            lanes=self.arena.n_active):
+                            lanes=self.arena.n_active, iter=self._iter):
                 self._state, out = self.runtime.run_program(
                     "step", self._state, replica=self.replica)
-            with trace_span("tpuserve.gen_fetch", model=self.name):
+            with trace_span("tpuserve.gen_fetch", model=self.name,
+                            iter=self._iter):
                 return host_fetch(out)
 
     def _insert_sync(self, slot: int, item: Any) -> None:
@@ -923,12 +990,14 @@ class GenEngine:
                 replica=self.replica)
 
     def _prefill_sync(self, pieces: "list[PrefillPiece]") -> None:
-        launch = self.model.pack_prefill(pieces, self._prefill_chunk,
-                                         self._prefill_pieces)
+        with trace_span("tpuserve.gen_pack", model=self.name,
+                        pieces=len(pieces), iter=self._iter):
+            launch = self.model.pack_prefill(pieces, self._prefill_chunk,
+                                             self._prefill_pieces)
         with self._dispatch_guard(), trace_span(
                 "tpuserve.gen_prefill", model=self.name, slot=pieces[0].slot,
                 start=pieces[0].start, pieces=len(pieces),
-                tokens=sum(p.length for p in pieces)):
+                tokens=sum(p.length for p in pieces), iter=self._iter):
             self._state = self.runtime.run_program(
                 "prefill", self._state, launch, replica=self.replica)
 
@@ -990,7 +1059,7 @@ class GenEngine:
                 for m in metas:
                     m["prefill_held"] += 1
                 return
-            t0 = time.perf_counter()
+            t0 = self._stamp("prefill")
             try:
                 await self.stages.run(self.name, "h2d", self._prefill_sync,
                                       launch)
@@ -1000,7 +1069,7 @@ class GenEngine:
                 # half-written: the blast radius of a failed insert.
                 await self._fail_active(e)
                 return
-            self._h_insert.observe((time.perf_counter() - t0) * 1e3)
+            self._h_insert.observe((self._stamp("prefill") - t0) * 1e3)
             self._c_prefill_chunks.inc()
             self._c_prefill_pieces.inc(len(launch))
             self._c_prefill_tokens.inc(sum(p.length for p in launch))
@@ -1015,7 +1084,9 @@ class GenEngine:
                     self._prefilling.remove(p.slot)
 
     def _extract_sync(self, slot: int) -> Any:
-        with self._dispatch_guard():
+        with self._dispatch_guard(), trace_span(
+                "tpuserve.gen_extract", model=self.name, slot=slot,
+                iter=self._iter):
             return host_fetch(
                 self.runtime.run_program("extract", self._state,
                                          np.int32(slot),
@@ -1255,15 +1326,16 @@ class GenEngine:
                 continue
             early = self.arena.n_active > 1 or bool(self._pending)
             trace_id = info.ctx.trace_id if info.ctx is not None else None
-            t0 = time.perf_counter()
+            t0 = self._stamp("retire")
             try:
                 extracted = await self.stages.run(
                     self.name, "fetch", self._extract_sync, slot)
-                self._h_extract.observe((time.perf_counter() - t0) * 1e3,
+                self._h_extract.observe((self._stamp("retire") - t0) * 1e3,
                                         trace_id=trace_id)
                 result = await self.stages.run(
-                    self.name, "postproc", self.model.finalize, extracted,
-                    info.item)
+                    self.name, "postproc", trace_call, "tpuserve.gen_finalize",
+                    {"model": self.name, "slot": slot, "iter": self._iter},
+                    self.model.finalize, extracted, info.item)
             except asyncio.CancelledError:
                 raise
             except Exception as e:  # noqa: BLE001 — contained to this slot
@@ -1298,18 +1370,19 @@ class GenEngine:
                     self._c_early_exits.inc()
                 if self.breaker is not None:
                     self.breaker.record_success()
+                t1 = self._stamp("retire")
                 wall1 = time.time()
-                trace_mark("tpuserve.gen_retire", t0, time.perf_counter(),
-                           model=self.name, slot=slot)
+                trace_mark("tpuserve.gen_retire", t0, t1, model=self.name,
+                           slot=slot)
                 if info.ctx is not None:
                     # Retire event: extract + finalize for this slot, the
                     # tail of the request's step-span stack.
-                    info.ctx.span("retire", wall1 - (time.perf_counter() - t0),
-                                  wall1, tid=self.name, slot=slot,
+                    info.ctx.span("retire", wall1 - (t1 - t0), wall1,
+                                  tid=self.name, slot=slot,
                                   iterations=info.iterations)
                 self.metrics.tracer.add(
                     f"gen[{info.iterations}it]",
-                    wall1 - (time.perf_counter() - info.enqueued_at), wall1,
+                    wall1 - (t1 - info.enqueued_at), wall1,
                     tid=self.name, trace_id=trace_id, slot=slot,
                     iterations=info.iterations)
             self._release_slot(slot)
@@ -1339,6 +1412,7 @@ class GenEngine:
             self.pages.release_all()
             self._update_kv_gauges()
         self._state = self._host_zeros(self._state_struct)
+        self._last_decode_at = None  # no lane is left to feel a gap
         self._publish_active()
         self._maybe_idle()
 
@@ -1469,6 +1543,7 @@ class GenEngine:
             "iters_per_request_ewma": round(self._ewma_iters, 2)
             if self._ewma_iters else None,
             "per_slot": per_slot,
+            "loop": self._loop_stats(),
         }
         if self.pages is not None:
             stats["kv"] = {
@@ -1486,6 +1561,17 @@ class GenEngine:
         # above and composes these) — uniform shape either way.
         stats["per_replica"] = [self.replica_row()]
         return stats
+
+    def _loop_stats(self) -> dict:
+        """Where the step loop's time went since start, in milliseconds an
+        iteration by phase (``gen_loop_seconds_total`` over
+        ``gen_iterations_total``: the model's counters, which a group's
+        members share)."""
+        iters = self._c_iterations.value
+        return {"iterations": iters,
+                "ms_per_iteration": {
+                    p: round(c.value * 1e3 / iters, 3)
+                    for p, c in self._c_loop.items()} if iters else None}
 
     def _prefill_stats(self) -> dict:
         """Launches of the prefill program and what they carried (the
