@@ -9,9 +9,11 @@ says of the operations under the program's `ssm_update` / `ssm_scan` scopes
     python scripts/bench_hybrid.py --rehearse --config benchmark/configs/rehearsal-hybrid-tiny.json
 
 This is where PERF.md section 3's note on what identifies a scope's
-operations in a device trace comes from. One JSON line a case on stdout and in
-`chiprun_out/bench_hybrid/`. Off the TPU it walks the path (`--rehearse`) and
-prints no time.
+operations in a device trace comes from, and section 5's table of a launch and
+a step by operation (`scripts/op_table.py`, printed last, with the rows the
+expert layers' dispatch carried beside `t * k` and the branch that ran). One
+JSON line a case on stdout and in `chiprun_out/bench_hybrid/`. Off the TPU it
+walks the path (`--rehearse`) and prints no time.
 """
 
 from __future__ import annotations
@@ -32,6 +34,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+import op_table  # noqa: E402  (scripts/, beside this file)
 from benchmark import spec  # noqa: E402
 from tpuserve.config import ModelConfig  # noqa: E402
 from tpuserve.genserve.model import PrefillPiece  # noqa: E402
@@ -91,8 +94,12 @@ def main() -> None:
         rows.append(row)
         print(json.dumps(row), flush=True)
 
+    def dispatch(case: str, phase: int, tokens: int, before) -> None:
+        d = (np.asarray(state["acc"]) - before)[phase].astype(np.int64)
+        emit(case=case, **op_table.dispatch_of(model, tokens, d))
+
     # every lane armed: launches of K prompts, each a tile short of three rows
-    times = []
+    times, before = [], np.asarray(state["acc"])
     for first in range(0, slots, k):
         launch = model.pack_prefill([piece(s, n_prompt) for s in range(first, min(slots, first + k))],
                                     chunk, k)
@@ -103,13 +110,15 @@ def main() -> None:
     if on_tpu:
         emit(case=f"prefill launch, {k} pieces of {n_prompt}", launches=len(times),
              first_s=times[0], median_ms=statistics.median(times[1:] or times) * 1e3)
-    times = []
+    dispatch("prefill launches: the dispatch", 0, chunk, before)
+    times, before = [], np.asarray(state["acc"])
     for _ in range(args.iters + 2):
         t0 = time.perf_counter()
         state, out = step(params, state)
         np.asarray(out["n_new"])
         times.append(time.perf_counter() - t0)
     assert int(np.sum(np.asarray(out["n_new"]) > 1)) == slots, "every lane decodes"
+    dispatch("decode steps: the dispatch", 1, slots, before)
     if on_tpu:
         emit(case=f"decode step, {slots} live lanes", first_s=times[0],
              median_ms=statistics.median(times[2:]) * 1e3)
@@ -153,6 +162,7 @@ def main() -> None:
                                   for a, b in ev.stats if "ps" not in str(a)}
                             f.write(json.dumps({"in": mod, "name": ev.name[:700],
                                                 "ns": int(ev.duration_ns), "stats": st}) + "\n")
+            op_table.print_tables(path)
     shutil.rmtree(trace_dir, ignore_errors=True)
     with open(os.path.join(out_dir, "report.jsonl"), "w", encoding="utf-8") as f:
         f.writelines(json.dumps(r) + "\n" for r in rows)

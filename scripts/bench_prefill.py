@@ -13,6 +13,10 @@ around a dependent read.
 
 `--parent DIR` also times `DIR/tpuserve/models/decoder.py` (a `git archive` of
 a commit whose program takes one prompt a launch) on the same weights.
+`--ops` (with `--cases K` to time only the full launches) then traces two
+launches of the last case and prints that launch by operation
+(`scripts/op_table.py`) under the rows its expert layers' dispatch carried
+beside `t * k` and the branch that ran.
 One JSON line a case on stdout and in `chiprun_out/bench_prefill.jsonl`. It
 refuses to run off the TPU: a time from the CPU is no device number.
 """
@@ -20,9 +24,11 @@ refuses to run off the TPU: a time from the CPU is no device number.
 from __future__ import annotations
 
 import argparse
+import glob
 import importlib.util
 import json
 import os
+import shutil
 import statistics
 import sys
 import time
@@ -32,6 +38,8 @@ sys.path.insert(0, REPO)
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
+
+import op_table  # noqa: E402  (scripts/, beside this file)
 
 from benchmark.reference.decoder import arch_from_config  # noqa: E402
 from tpuserve.config import ModelConfig  # noqa: E402
@@ -78,6 +86,9 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=1)
     ap.add_argument("--key-block", type=int, nargs="*", default=[dec.KEY_BLOCK],
                     help="key positions a block of a full layer's attention, each tried")
+    ap.add_argument("--cases", default="", help="only the cases whose name starts so")
+    ap.add_argument("--ops", action="store_true",
+                    help="trace two launches of the last case: the launch by operation")
     ap.add_argument("--rehearse", action="store_true",
                     help="walk every case anywhere, once, and print no time")
     args = ap.parse_args()
@@ -131,17 +142,35 @@ def main() -> None:
         packed = jax.jit(lambda p, s, launch: model.prefill_chunk(p, s, launch, chunk=C),
                          donate_argnums=(1,))
         for name, pieces in CASES:
+            if not name.startswith(args.cases):
+                continue
             pieces = [scaled(n, ctx) for n, ctx in pieces]
             if sum(-(-n // (C // K)) for n, _ in pieces) > K:
                 continue
             launch = model.pack_prefill(
                 [PrefillPiece(j, item_of(ctx + n), ctx, n, cache_of(j))
                  for j, (n, ctx) in enumerate(pieces)], C, K)
+            last = name
             state, med, best = timed(lambda s, l: packed(params, s, l), state, (launch,),
                                      args.iters)
             emit(program="packed", pieces_max=K, key_block=dec.KEY_BLOCK, case=name,
                  tokens=sum(n for n, _ in pieces), ms_median=round(med, 3),
                  ms_min=round(best, 3))
+    if args.ops and not lines:
+        sys.exit(f"bench_prefill.py --ops: no case starts with {args.cases!r} and fits a launch")
+    if args.ops:
+        before = np.asarray(state["acc"])
+        trace_dir = os.path.join(out_dir, "bench_prefill_trace")
+        jax.profiler.start_trace(trace_dir)
+        for _ in range(2):
+            state = packed(params, state, launch)
+        np.asarray(state["pos"])
+        jax.profiler.stop_trace()
+        d = (np.asarray(state["acc"]) - before)[0].astype(np.int64)
+        emit(case=f"{last}: the dispatch of two launches", **op_table.dispatch_of(model, C, d))
+        for path in glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True):
+            op_table.print_tables(path)
+        shutil.rmtree(trace_dir, ignore_errors=True)
     if args.parent:
         spec = importlib.util.spec_from_file_location(
             "parent_decoder", os.path.join(args.parent, "tpuserve", "models", "decoder.py"))

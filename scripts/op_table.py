@@ -1,0 +1,116 @@
+"""One launch of a program by operation, from a device trace: the table of
+PERF.md section 5 ("a launch's N ms by operation") in one call.
+
+An operation's event on a chip's `XLA Ops` line is named by its HLO text; the
+trace's own copy of the program (`benchmark/ssm_window.py` `scope_map`) gives
+each instruction the `op_name` it was traced under, `jit(step)/.../moe_dispatch/
+sort`. A row of the table is a `jax.named_scope` of the program (`SCOPES`)
+with the kind of instruction under it, or, outside every scope, the kind
+alone. Operations nest on that line (a `while` or a `conditional` and what runs
+inside it), so the containers are listed apart and left out of the sum.
+
+Used by `scripts/bench_hybrid.py` and `scripts/bench_prefill.py`; reads any
+`*.xplane.pb`:  python scripts/op_table.py <trace.xplane.pb> [module prefix ...]
+"""
+
+from __future__ import annotations
+
+import os
+import re
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from benchmark.ssm_window import scope_map  # noqa: E402
+from benchmark.trace_reduce import DEVICE_PLANE, MODULES_LINE, OPS_LINE, op_name  # noqa: E402
+
+SCOPES = ("moe_experts", "moe_dispatch", "ssm_scan", "ssm_update", "mla_prefill", "mla_decode")
+CONTAINERS = ("while", "conditional", "call")
+
+
+def dispatch_of(model, tokens: int, acc_delta) -> dict:
+    """What the expert layers' dispatch carried in the launches that moved one
+    phase's row of ``acc`` by ``acc_delta``: the picks of a launch of ``tokens``
+    rows, the rows its compact branch carries (``ops/moe.py`` ``_row_bound``),
+    and the layers that ran each branch (the row's fourth column is held
+    experts x expert layers run, its last the layers that ran compact)."""
+    from tpuserve.ops.moe import _row_bound
+
+    picks = tokens * model.top_k
+    ran = int(acc_delta[3]) // max(1, model.e_count)
+    return {"picks": picks, "rows_carried_compact": _row_bound(picks, model.e_count,
+                                                               model.n_experts),
+            "expert_layers_run": ran, "compact": int(acc_delta[-1]),
+            "wide": ran - int(acc_delta[-1])}
+
+
+def _kind(instruction: str) -> str:
+    """`fusion.12` -> `fusion`; `gather_fusion.3` -> `gather_fusion`."""
+    return re.sub(r"[.\d]+$", "", instruction) or instruction
+
+
+def last_launches(path: str) -> dict[str, dict]:
+    """{module's base name: {"name", "ns", "ops": [(instruction, ns, op_name)]}}
+    for the LAST launch of each program on the first chip that ran any."""
+    from jax.profiler import ProfileData
+
+    names = scope_map(path)
+    for plane in ProfileData.from_file(path).planes:
+        lines = {line.name: line for line in plane.lines}
+        if not DEVICE_PLANE.match(plane.name) or OPS_LINE not in lines \
+                or MODULES_LINE not in lines:
+            continue
+        out = {}
+        for ev in lines[MODULES_LINE].events:
+            out[ev.name.split("(")[0]] = {"name": ev.name, "lo": int(ev.start_ns),
+                                          "ns": int(ev.duration_ns), "ops": []}
+        for ev in lines[OPS_LINE].events:
+            for base, m in out.items():
+                if m["lo"] <= ev.start_ns < m["lo"] + m["ns"]:
+                    inst = op_name(ev.name)
+                    m["ops"].append((inst, int(ev.duration_ns), names.get(base, {}).get(inst, "")))
+        return out
+    return {}
+
+
+def table(ops: list[tuple[str, int, str]]) -> tuple[list[tuple[str, float, int]], float]:
+    """[(row, ms, operations)] most time first, and the containers' ms."""
+    rows: dict[str, list] = {}
+    inside = 0
+    for inst, ns, traced_as in ops:
+        kind = _kind(inst)
+        if kind in CONTAINERS:
+            inside += ns
+            continue
+        scope = next((s for s in SCOPES if f"/{s}/" in traced_as or traced_as.endswith("/" + s)),
+                     None)
+        prim = traced_as.rsplit("/", 1)[-1] if traced_as else ""
+        row = f"{scope}: {kind}" if scope else f"{kind} ({prim})" if prim else kind
+        got = rows.setdefault(row, [0, 0])
+        got[0] += ns
+        got[1] += 1
+    return sorted(((r, ns / 1e6, n) for r, (ns, n) in rows.items()), key=lambda x: -x[1]), \
+        inside / 1e6
+
+
+def print_tables(path: str, prefixes: tuple[str, ...] = (), top: int = 28, out=print) -> None:
+    for base, m in last_launches(path).items():
+        if prefixes and not base.startswith(prefixes) or m["ns"] < 1e6:
+            continue   # not asked for, or a program of under a millisecond
+        rows, inside = table(m["ops"])
+        total = sum(ms for _r, ms, _n in rows)
+        out(f"-- {m['name']}: {m['ns'] / 1e6:.3f} ms a launch, {total:.3f} ms in "
+            f"{sum(n for _r, _ms, n in rows)} operations ({inside:.3f} ms of containers apart)")
+        by_scope: dict[str, float] = {}
+        for r, ms, _n in rows:
+            scope = r.split(":")[0] if ":" in r else "outside every scope"
+            by_scope[scope] = by_scope.get(scope, 0.0) + ms
+        out("   " + ", ".join(f"{s} {ms:.3f}" for s, ms in sorted(by_scope.items(),
+                                                                 key=lambda kv: -kv[1])))
+        for r, ms, n in rows[:top]:
+            out(f"   {ms:8.3f} ms  {n:4d}  {r}")
+
+
+if __name__ == "__main__":
+    print_tables(sys.argv[1], tuple(sys.argv[2:]))

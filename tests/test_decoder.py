@@ -283,6 +283,23 @@ def test_a_share_serves_what_the_reference_gives_for_the_same_share(tmp_path):
     got = served[0]["lp"][:9]
     np.testing.assert_allclose(
         got, np.take_along_axis(lp, served[0]["lp_ids"][:9].astype(np.int64), axis=-1), atol=2e-4)
+    # A launch wide enough that the dispatch's row bound is under its picks (64 x 3 picks, half the
+    # experts held: 128 rows of 192): the compact branch runs in the prefill launch and in no step
+    # (3 lanes), the answer is the reference's and the narrow launches' tokens.
+    from tpuserve.obs import Metrics
+
+    metrics = Metrics()
+    model.bind_metrics(metrics)
+    wide_launch, out, _ = serve(model, params, prompts, [9], chunk=64)
+    np.testing.assert_allclose(wide_launch[0]["lp"][:9], np.take_along_axis(
+        lp, wide_launch[0]["lp_ids"][:9].astype(np.int64), axis=-1), atol=2e-4)
+    assert np.array_equal(wide_launch[0]["tokens"], served[0]["tokens"])
+    model.observe_step(out)
+    c = metrics.counter_values()
+    assert c["moe_layers_compact_total{model=share,phase=prefill}"] \
+        == c["moe_layers_total{model=share,phase=prefill}"] == 4         # 4 sparse layers, 1 launch
+    assert c["moe_layers_total{model=share,phase=decode}"] == 4 * 10
+    assert c.get("moe_layers_compact_total{model=share,phase=decode}", 0) == 0
     item = model.host_decode(json.dumps({"prompt_ids": [48, 95], "logprobs": 2}).encode(),
                              "application/json")
     assert list(item[0][:2]) == [0, 47]
@@ -482,6 +499,12 @@ def test_through_the_engine_both_cache_kinds_come_back_and_the_counters_move(tmp
     assert routed == sparse * k * (sum(map(len, prompts)) + sum(m - 1 for m in max_news))
     assert c["moe_experts_hit_total{model=eng,phase=decode}"] \
         <= c["moe_expert_steps_total{model=eng,phase=decode}"]
+    # every expert is held here: expert layers ran, none could leave a pick behind
+    assert c["moe_layers_total{model=eng,phase=prefill}"] \
+        == sparse * c["gen_prefill_chunks_total{model=eng}"]
+    assert c["moe_layers_total{model=eng,phase=decode}"] * ARCH["num_experts"] \
+        == c["moe_expert_steps_total{model=eng,phase=decode}"]
+    assert not any(v for name, v in c.items() if name.startswith("moe_layers_compact_total"))
     assert c["gen_kv_ring_steps_total{model=eng}"] > 0 and c["gen_kv_page_steps_total{model=eng}"] > 0
 
 

@@ -291,6 +291,24 @@ def test_a_share_serves_what_the_reference_gives_for_the_same_share(tmp_path):
                          launches=[[(0, 0, 8)], [(0, 8, 4), (1, 0, 4)], [(0, 12, 1), (1, 4, 2)]])
     for g in gaps(arch, prompts, served):
         assert float(np.abs(g).max()) < 2e-5
+    # A launch wide enough that the dispatch's row bound is under its picks (32 x 5 picks, a quarter
+    # of the experts held: 128 rows of 160): the compact branch runs in every prefill launch and in
+    # no step (3 lanes), the answer is the reference's and the narrow launches' tokens.
+    from tpuserve.obs import Metrics
+
+    metrics = Metrics()
+    model.bind_metrics(metrics)
+    wide_launches, out, _ = serve(model, params, prompts, [5, 4], chunk=32)
+    for g in gaps(arch, prompts, wide_launches):
+        assert float(np.abs(g).max()) < 2e-5
+    for a, b in zip(wide_launches, served):
+        assert np.array_equal(a["tokens"], b["tokens"])
+    model.observe_step(out)
+    c = metrics.counter_values()
+    assert c["moe_layers_compact_total{model=shared,phase=prefill}"] \
+        == c["moe_layers_total{model=shared,phase=prefill}"] == 2 * 2     # 2 layers x 2 launches
+    assert c["moe_layers_total{model=shared,phase=decode}"] == 2 * 6
+    assert c.get("moe_layers_compact_total{model=shared,phase=decode}", 0) == 0
 
 
 # -- (d) a slot's next tenant, free and frozen lanes ------------------------------------------------
@@ -438,6 +456,12 @@ def test_through_the_engine_two_requests_move_the_counters_by_what_was_served(tm
     assert routed == n_e * k * (tokens + steps)
     assert c["moe_expert_steps_total{model=eng,phase=decode}"] \
         >= c["moe_experts_hit_total{model=eng,phase=decode}"] > 0
+    # every expert is held here: expert layers ran, none could leave a pick behind
+    assert c["moe_layers_total{model=eng,phase=prefill}"] \
+        == n_e * c["gen_prefill_chunks_total{model=eng}"]
+    assert c["moe_layers_total{model=eng,phase=decode}"] * 16 \
+        == c["moe_expert_steps_total{model=eng,phase=decode}"]
+    assert not any(v for name, v in c.items() if name.startswith("moe_layers_compact_total"))
     assert c["gen_context_tokens_total{model=eng,phase=prefill}"] == 19 * 20 // 2 + 5 * 6 // 2
     # /stats: the state's bytes a slot and in all, beside pages
     kv = eng.pipeline_stats()["kv"]

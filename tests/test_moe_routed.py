@@ -127,3 +127,162 @@ def test_the_tiles_of_both_expert_layers_on_the_chip():
     assert [(_tile(k), _tile(n)) for k, n in ((3072, 1024), (1024, 3072))] == [(1024, 1024)] * 2
     assert [(_tile(k), _tile(n)) for k, n in ((1024, 2688), (2688, 1024))] == \
         [(1024, 896), (896, 1024)]
+
+
+# -- the dispatch carries only the held picks (ISSUE 37) ------------------------------------------
+
+def _layer(seed, first, count, of, k, t, dtype, body, masked, d=32, f=24, bias=None):
+    """A routed layer's inputs: (x, weights, experts, w_in, w_out, live)."""
+    from tpuserve.ops.moe import topk_route
+
+    rng = np.random.default_rng(seed)
+    dt = jnp.dtype(dtype)
+    x = jnp.asarray(rng.standard_normal((t, d)), dt)
+    w, e = topk_route(jnp.asarray(rng.standard_normal((t, of)), jnp.float32), k,
+                      scoring="sigmoid", select_bias=bias, scale=2.5)
+    w_in = tuple(jnp.asarray(rng.standard_normal((count, d, f)) / 4, dt)
+                 for _ in range(2 if body == "swiglu" else 1))
+    w_out = jnp.asarray(rng.standard_normal((count, f, d)) / 4, dt)
+    live = jnp.asarray(rng.random(t) < 0.9) if masked else None
+    return x, w, e, w_in, w_out, live
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+# (first, count, of, k, t, masked, dtype, body, (d, f)): sizes at which the row bound is under t * k
+# and the launch's held picks fit it. The last two have kernel widths in whole tiles of 128 and rows
+# in whole row tiles: on the chip those go through megablox, at 128 rows a tile in `compact`
+# and in `wide` (1,024 picks), and at 128 against 256 (8,192 picks).
+COMPACT_CASES = [
+    (0, 2, 8, 4, 96, False, "float32", "relu2", (32, 24)),
+    (4, 4, 16, 4, 256, True, "float32", "swiglu", (32, 24)),
+    (8, 8, 16, 2, 300, True, "bfloat16", "relu2", (32, 24)),
+    (6, 2, 8, 3, 128, False, "bfloat16", "swiglu", (16, 8)),
+    (96, 32, 128, 6, 200, True, "float32", "relu2", (8, 8)),
+    (0, 1, 4, 1, 1024, False, "float32", "swiglu", (8, 8)),
+    (4, 4, 16, 4, 256, True, "bfloat16", "relu2", (256, 128)),
+    (0, 4, 16, 8, 1024, True, "bfloat16", "swiglu", (128, 128)),
+]
+
+
+def _both_branches(monkeypatch, layer, first, fn, of):
+    """ONE compiled program of the layer, run twice: its ``cond`` taking the
+    branch the test names (the layer's own predicate is computed and reported
+    as ever). What serving needs: which branch a launch takes depends on who
+    else is in it, and the program is the same either way."""
+    from tpuserve.ops import moe
+
+    x, w, e, w_in, w_out, live = layer
+    real = jax.lax.cond
+
+    def run(x, w, e, take_compact):
+        own = []
+
+        def cond(pred, *branches):   # the layer's own is the first traced; a kernel's inner ones stay
+            own.append(pred)
+            return real(take_compact if len(own) == 1 else pred, *branches)
+
+        monkeypatch.setattr(jax.lax, "cond", cond)
+        try:
+            return moe.held_experts(x, w, e, first, w_in, w_out, fn, live=live, of=of)
+        finally:
+            monkeypatch.setattr(jax.lax, "cond", real)
+
+    program = jax.jit(run)
+    return program(x, w, e, True), program(x, w, e, False)
+
+
+@pytest.mark.parametrize("first,count,of,k,t,masked,dtype,body,widths", COMPACT_CASES)
+def test_the_compact_branch_is_bit_identical_to_wide(first, count, of, k, t, masked, dtype, body,
+                                                     widths, monkeypatch):
+    from tpuserve.ops import moe
+
+    layer = _layer(7, first, count, of, k, t, dtype, body, masked, *widths)
+    x, w, e, w_in, w_out, live = layer
+    fn = moe.swiglu if body == "swiglu" else moe.relu2
+    bound = moe._row_bound(t * k, count, of)
+    assert bound < t * k and bound % 128 == 0
+    (got, stats), (wide, wide_stats) = _both_branches(monkeypatch, layer, first, fn, of)
+    assert 0 < int(stats["routed_held"]) <= bound            # the held picks fit: both are valid
+    assert np.array_equal(_bits(got), _bits(wide))
+    assert float(np.abs(np.asarray(got, np.float32)).max()) > 0
+    # ... and the program without the second branch (no width given) counts the same and answers
+    # the same to the last bits of a float32 sum (another program: its sum over k may associate
+    # otherwise, as seen on the chip)
+    alone, alone_stats = jax.jit(lambda *a: moe.held_experts(
+        *a, first, w_in, w_out, fn, live=live))(x, w, e)
+    assert int(stats["compact"]) == int(wide_stats["compact"]) == 1    # what the layer itself chose
+    assert int(alone_stats["compact"]) == 0
+    for name in ("routed_held", "routed_absent", "experts_hit"):
+        assert int(stats[name]) == int(wide_stats[name]) == int(alone_stats[name])
+    assert int(stats["routed_held"]) + int(stats["routed_absent"]) == \
+        k * (t if live is None else int(np.sum(live)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(alone), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype,masked", [("float32", False), ("float32", True),
+                                          ("bfloat16", True)])
+def test_held_picks_over_the_bound_take_wide_and_lose_nothing(dtype, masked, monkeypatch):
+    """A selection bias pulls every pick onto the held range: the launch's
+    held picks exceed the compact rows, `wide` runs, and the answer is the
+    per-token sum in float32 of the picks' experts."""
+    from tpuserve.ops import moe
+
+    first, count, of, k, t = 4, 4, 16, 3, 128
+    bias = jnp.zeros((of,), jnp.float32).at[first:first + count].set(10.0)
+    x, w, e, (w1,), w2, live = _layer(9, first, count, of, k, t, dtype, "relu2", masked, bias=bias)
+    assert np.all((np.asarray(e) >= first) & (np.asarray(e) < first + count))
+    bound = moe._row_bound(t * k, count, of)
+    got, stats = jax.jit(lambda *a: moe.held_experts(
+        *a, first, (w1,), w2, moe.relu2, live=live, of=of))(x, w, e)
+    n_live = t if live is None else int(np.sum(live))
+    assert int(stats["routed_held"]) == k * n_live > bound
+    assert int(stats["compact"]) == 0 and int(stats["routed_absent"]) == 0
+    # the same program made to take `compact` here would leave picks behind: the lever of the
+    # test above moves something, and the layer's own predicate is what keeps it from happening
+    (short, _), (whole, _) = _both_branches(monkeypatch, (x, w, e, (w1,), w2, live), first,
+                                            moe.relu2, of)
+    assert np.array_equal(_bits(got), _bits(whole)) and not np.array_equal(_bits(short), _bits(whole))
+    f32 = [np.asarray(a, np.float32) for a in (x, w1, w2)]
+    el = np.asarray(e) - first
+    want = sum(np.asarray(w)[:, j:j + 1] * np.einsum("tf,tfd->td", np.square(np.maximum(
+        np.einsum("td,tdf->tf", f32[0], f32[1][el[:, j]]), 0)).astype(x.dtype).astype(np.float32),
+        f32[2][el[:, j]]) for j in range(k))
+    if live is not None:
+        want = np.where(np.asarray(live)[:, None], want, 0.0)
+    # float32 products take the TPU's default precision (one bfloat16 pass) where a test runs there
+    tol = 3e-2 if dtype == "bfloat16" else 1e-1 if jax.default_backend() == "tpu" else 2e-4
+    np.testing.assert_allclose(np.asarray(got), want, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("first,count,of,k,t,cond", [
+    (0, 16, 16, 4, 256, False),      # every expert held: no absent pick to leave behind
+    (4, 4, 16, 4, 8, False),         # a launch so small that the bound rounds up to t * k
+    (4, 4, None, 4, 256, False),     # a caller that gives no router width
+    (4, 4, 16, 4, 256, True)])
+def test_where_nothing_can_be_left_behind_the_program_has_no_cond(first, count, of, k, t, cond):
+    from tpuserve.ops import moe
+
+    x, w, e, w_in, w_out, _ = _layer(3, first, count, of or 16, k, t, "float32", "relu2", False)
+    text = str(jax.make_jaxpr(lambda *a: moe.held_experts(
+        *a, first, w_in, w_out, moe.relu2, of=of))(x, w, e))
+    assert ("cond[" in text) == cond
+    _, stats = moe.held_experts(x, w, e, first, w_in, w_out, moe.relu2, of=of)
+    assert int(stats["compact"]) == int(cond)
+
+
+@pytest.mark.parametrize("picks,count,of,rows", [
+    (22528, 128, 512, 7168), (5632, 128, 512, 1792),       # Nemotron's launch and step
+    (10240, 128, 256, 6400), (1280, 128, 256, 896),        # Laguna's
+    (16384, 256, 256, 16384), (128, 256, 256, 128),        # JoyAI's: every expert held
+    (176, 128, 512, 128), (40, 2, 8, 40), (4096 * 4, 1, 4, 5120)])
+def test_the_row_bound_is_the_expected_held_picks_with_slack_in_whole_row_tiles(picks, count, of,
+                                                                               rows):
+    from tpuserve.ops.moe import COMPACT_SLACK, _row_bound, _row_tile
+
+    assert _row_bound(picks, count, of) == rows
+    if rows < picks:
+        assert rows % _row_tile(rows) == 0 and rows >= picks * count / of * COMPACT_SLACK
+        assert rows - _row_tile(rows) < picks * count / of * COMPACT_SLACK

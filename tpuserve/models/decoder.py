@@ -123,7 +123,7 @@ def apply_rope(x: jax.Array, pos: jax.Array, inv_freq: np.ndarray,
 
 
 class DecoderServing(PagedLM):
-    ACC = 5  # device-side sums a phase (kv_page_signature says which)
+    ACC = 6  # device-side sums a phase (kv_page_signature says which)
 
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)
@@ -234,8 +234,9 @@ class DecoderServing(PagedLM):
         page = S((self.kv, pages, page_tokens, self.hd), self.dtype)
         ring = S((slots + 1, self.window, self.kv, self.hd), self.dtype)
         # ``acc``'s columns: picks of live tokens on held and on absent
-        # experts, held experts hit, held experts x sparse layers run, and the
-        # context (positions a live token attends from) summed over live tokens.
+        # experts, held experts hit, held experts x sparse layers run, the
+        # context (positions a live token attends from) summed over live
+        # tokens, and sparse layers whose dispatch took the compact branch.
         return {
             "kf": [page for _ in self.full_layers], "vf": [page for _ in self.full_layers],
             "kw": [ring for _ in self.win_layers], "vw": [ring for _ in self.win_layers],
@@ -276,16 +277,15 @@ class DecoderServing(PagedLM):
         w, e = topk_route(r, self.top_k, normalize=self.norm_topk,
                           scale=self.route_scale)
         y, stats = held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"],
-                                       lp["e_up"], lp["e_down"], live=live)
+                                       lp["e_up"], lp["e_down"], live=live,
+                                       of=self.n_experts)
         return y + self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"]), stats
 
     def _accumulate(self, acc, phase: int, stats_list, context):
-        row = jnp.zeros((self.ACC,), jnp.uint32).at[4].set(context.astype(jnp.uint32))
-        for st in stats_list:
-            row = row.at[:4].add(jnp.stack([
-                st["routed_held"], st["routed_absent"], st["experts_hit"],
-                jnp.int32(self.e_count)]).astype(jnp.uint32))
-        return acc.at[phase].add(row)
+        row = jnp.stack([jnp.asarray(v, jnp.int32) for v in (
+            *self._expert_sums(stats_list), context,
+            sum(st["compact"] for st in stats_list))])
+        return acc.at[phase].add(row.astype(jnp.uint32))
 
     def _prefill_window(self, q, k, v, ring_k, ring_v, qpos, rpos, kpos, ok):
         """A window layer's attention of one launch, tile by tile: q (K, T,
@@ -411,7 +411,8 @@ class DecoderServing(PagedLM):
 
     # -- host side ----------------------------------------------------------------
     def bind_metrics(self, metrics: Any) -> None:
-        self._counters = [self._expert_counters(metrics, ph) for ph in GEN_PHASES]
+        self._counters = [self._expert_counters(metrics, ph)
+                          + [self._compact_counter(metrics, ph)] for ph in GEN_PHASES]
 
 
 def create(cfg: ModelConfig) -> DecoderServing:

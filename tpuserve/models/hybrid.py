@@ -89,9 +89,9 @@ def softplus_inverse(y: float) -> float:
 class HybridServing(PagedLM):
     # Device-side sums a phase: the expert layer's four and the context, as
     # ``decoder`` has them, then live tokens through a scan layer, slot states
-    # read and written, and (prefill) pieces that started from zeros / from a
-    # stored state.
-    ACC = 9
+    # read and written, (prefill) pieces that started from zeros / from a
+    # stored state, and expert layers whose dispatch took the compact branch.
+    ACC = 10
 
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)
@@ -409,7 +409,7 @@ class HybridServing(PagedLM):
                           scoring="sigmoid", select_bias=lp["e_bias"])
         lat = _mm(u, lp["w_a"]).astype(self.dtype)
         y, stats = held_experts(lat, w, e, self.e_first, (lp["e_w1"],), lp["e_w2"], relu2,
-                                live=live)
+                                live=live, of=self.n_experts)
         return _mm(y.astype(self.dtype), lp["w_b"]) \
             + self._relu2(u, lp["s_w1"], lp["s_w2"]), stats
 
@@ -417,11 +417,9 @@ class HybridServing(PagedLM):
                     zero=0, carried=0):
         n_m = len(self.m_layers)
         row = jnp.stack([jnp.asarray(v, jnp.int32) for v in (
-            sum(st["routed_held"] for st in stats_list),
-            sum(st["routed_absent"] for st in stats_list),
-            sum(st["experts_hit"] for st in stats_list),
-            self.e_count * len(stats_list), context,
-            tokens * n_m, rows * n_m, zero, carried)])
+            *self._expert_sums(stats_list), context,
+            tokens * n_m, rows * n_m, zero, carried,
+            sum(st["compact"] for st in stats_list))])
         return acc.at[phase].add(row.astype(jnp.uint32))
 
     # -- prefill ------------------------------------------------------------------
@@ -505,7 +503,8 @@ class HybridServing(PagedLM):
         self._counters = [self._expert_counters(metrics, ph) + [
             metrics.counter(f"ssm_tokens_total{{model={name},phase={ph}}}"),
             metrics.counter(f"ssm_state_rows_total{{model={name},phase={ph}}}"),
-        ] + (pieces if ph == "prefill" else [None, None]) for ph in GEN_PHASES]
+        ] + (pieces if ph == "prefill" else [None, None])
+            + [self._compact_counter(metrics, ph)] for ph in GEN_PHASES]
 
 
 def create(cfg: ModelConfig) -> HybridServing:

@@ -90,8 +90,9 @@ class LatentServing(PagedLM):
     # Device-side sums a phase: the expert layers' four and the context (as
     # ``decoder``), then cache rows attended over (each once a piece or a
     # lane: the least a launch reads), cache rows the walk gathered (whole key
-    # blocks a tile or a lane, a layer), and launches by form.
-    ACC = 9
+    # blocks a tile or a lane, a layer), launches by form, and expert layers
+    # whose dispatch took the compact branch (none where every expert is held).
+    ACC = 10
     TILE_ROWS = KEY_BLOCK
     kv_page_leaves = ("ckv", "kr")
 
@@ -121,6 +122,7 @@ class LatentServing(PagedLM):
         self.sparse_layers = list(range(self.first_dense, self.n_layers))
         self.dense_width = int(a["intermediate_size"])
         self.n_experts = int(a.get("n_routed_experts", 0))
+        self.e_first, self.e_count = 0, self.n_experts   # one chip a layer whole
         self.top_k = int(a.get("num_experts_per_tok", 0))
         self.expert_width = int(a.get("moe_intermediate_size", 0))
         self.shared_width = self.expert_width * int(a.get("n_shared_experts", 0))
@@ -317,17 +319,15 @@ class LatentServing(PagedLM):
                        precision=jax.lax.Precision.HIGHEST)
         w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
                           scoring="sigmoid", select_bias=lp["e_bias"])
-        y, stats = held_experts_swiglu(u, w, e, 0, lp["e_gate"], lp["e_up"], lp["e_down"],
-                                       live=live)
+        y, stats = held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"], lp["e_up"],
+                                       lp["e_down"], live=live, of=self.n_experts)
         return y + self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"]), stats
 
     def _accumulate(self, acc, phase: int, stats_list, context, attended, walked, form: str):
         row = jnp.stack([jnp.asarray(v, jnp.int32) for v in (
-            sum(st["routed_held"] for st in stats_list),
-            sum(st["routed_absent"] for st in stats_list),
-            sum(st["experts_hit"] for st in stats_list),
-            self.n_experts * len(stats_list), context, attended, walked,
-            form == "absorbed", form == "expanded")])
+            *self._expert_sums(stats_list), context, attended, walked,
+            form == "absorbed", form == "expanded",
+            sum(st["compact"] for st in stats_list))])
         return acc.at[phase].add(row.astype(jnp.uint32))
 
     # -- prefill ------------------------------------------------------------------
@@ -410,7 +410,7 @@ class LatentServing(PagedLM):
             metrics.counter(f"mla_rows_attended_total{{model={name},phase={ph}}}"),
             metrics.counter(f"mla_rows_walked_total{{model={name},phase={ph}}}"),
         ] + [metrics.counter(f"mla_launches_total{{model={name},phase={ph},form={form}}}")
-             for form in FORMS] for ph in GEN_PHASES]
+             for form in FORMS] + [self._compact_counter(metrics, ph)] for ph in GEN_PHASES]
 
 
 def create(cfg: ModelConfig) -> LatentServing:

@@ -77,6 +77,18 @@ def head_share(name: str, idx: int, of: int, heads: list[int], kv_full: int):
     return held, [idx * h for h in held], kv, idx * kv_full // of
 
 
+class _ExpertSteps:
+    """The counters one column of ``acc`` feeds: held experts x expert layers
+    run as it is summed, and expert layers run (the same over ``held``)."""
+
+    def __init__(self, steps: Any, layers: Any, held: int) -> None:
+        self.steps, self.layers, self.held = steps, layers, held
+
+    def inc(self, amount: float) -> None:
+        self.steps.inc(amount)
+        self.layers.inc(amount / self.held)
+
+
 class PagedLM(GenerativeModel):
     supports_kv_paging = True
     kv_page_leaves = ("kf", "vf")  # K and V by head; a family with another row says so
@@ -532,15 +544,33 @@ class PagedLM(GenerativeModel):
                 if v and c is not None:
                     c.inc(float(v))
 
+    def _expert_sums(self, stats_list: list) -> tuple:
+        """``acc``'s first four columns of one launch, from its expert layers'
+        ``held_experts`` counts: picks of live tokens on held and on absent
+        experts, held experts hit, held experts x expert layers run."""
+        return (sum(st["routed_held"] for st in stats_list),
+                sum(st["routed_absent"] for st in stats_list),
+                sum(st["experts_hit"] for st in stats_list),
+                self.e_count * len(stats_list))
+
     def _expert_counters(self, metrics: Any, ph: str) -> list:
         """The counters of ``acc``'s first five columns in phase ``ph``: the
-        expert layers' four and the context read."""
+        expert layers' four and the context read. The fourth column sums held
+        experts x expert layers run, so it feeds ``moe_layers_total`` too,
+        in its own unit."""
         name = self.name
         return [metrics.counter(f"moe_tokens_routed_total{{model={name},phase={ph},held=yes}}"),
                 metrics.counter(f"moe_tokens_routed_total{{model={name},phase={ph},held=no}}"),
                 metrics.counter(f"moe_experts_hit_total{{model={name},phase={ph}}}"),
-                metrics.counter(f"moe_expert_steps_total{{model={name},phase={ph}}}"),
+                _ExpertSteps(metrics.counter(f"moe_expert_steps_total{{model={name},phase={ph}}}"),
+                             metrics.counter(f"moe_layers_total{{model={name},phase={ph}}}"),
+                             self.e_count),
                 metrics.counter(f"gen_context_tokens_total{{model={name},phase={ph}}}")]
+
+    def _compact_counter(self, metrics: Any, ph: str) -> Any:
+        """The counter of ``acc``'s last column: expert layers run whose
+        dispatch carried the compact row bound (``ops/moe.py``)."""
+        return metrics.counter(f"moe_layers_compact_total{{model={self.name},phase={ph}}}")
 
     def host_decode(self, payload: bytes, content_type: str) -> Any:
         body = json.loads(payload.decode("utf-8"))

@@ -128,6 +128,35 @@ class SwitchFFN(nn.Module):
 # it). No capacity, no dropped token: the picks are sorted by expert and go
 # through a grouped product (``_grouped_dot``) in groups of whatever size the
 # router gave.
+#
+# Two branches bring the sorted picks through the products (ISSUE 37), chosen
+# on the device by the launch's own count of held picks. ``wide`` carries all
+# ``t * k`` picks, held or not, as this layer always did. ``compact`` carries
+# the first ``R`` of the sorted picks, a static bound (``_row_bound``) sized
+# from the share of the router's width that is held: the held picks sort
+# first, so where they number at most ``R`` they are all among those rows.
+# Both hand the SAME ``(k * t, D)`` block of rows in pick order (a held pick's
+# row is its product's, bit for bit, however many rows went with it) to ONE
+# weighted sum after the ``cond``, so a program's ``y`` is bit-identical
+# whichever branch ran: which one runs depends on who else is in the launch,
+# a request's tokens do not. Where every expert is held, or ``R`` would not
+# be below ``t * k``, there is one branch and no ``cond``: the program this
+# layer always traced to (ANOTHER program: its sum over k may associate
+# otherwise, which on the chip moved the last bit of 2% of a test's sums).
+
+# The compact branch's rows over the EXPECTED held picks of a launch
+# (``t * k * count / of``). Were picks independent, a launch's held picks
+# would stand within a few hundredths of their mean (Nemotron's prefill
+# launch: 22,528 picks, 5,632 held, sd 65; its step 5,632 / 1,408 / 33;
+# Laguna's 10,240 / 5,120 / 51 and 1,280 / 640 / 18), so 1.25 and the tile's
+# rounding leave 11 and 14 sd over the two steps' means and over 20 over the
+# launches' (bounds 7,168 / 1,792 / 6,400 / 896). Real routers are skewed
+# and correlated across a prompt's tokens: a launch that exceeds the bound
+# takes ``wide`` and loses nothing but time, and ``stats["compact"]`` says how
+# often. A larger slack gives rows back (each costs its gather, its convert
+# and its way back); the bound is rounded up to a row tile anyway, which at
+# these sizes adds 2-12%.
+COMPACT_SLACK = 1.25
 
 
 def topk_route(logits: jax.Array, k: int, *, normalize: bool = True,
@@ -162,6 +191,11 @@ def _tile(n: int) -> int:
                  if n % t == 0), 0)
 
 
+def _row_tile(rows: int) -> int:
+    """The grouped product's tile of the rows: megablox wants whole tiles."""
+    return 128 if rows <= 4096 else 256
+
+
 def _grouped_dot(rows: int, dtype, *kernel_shapes):
     """``(lhs (rows, K), rhs (G, K, N), group sizes (G,)) -> (rows, N)``
     float32, rows of group g through ``rhs[g]``. On the TPU, for bfloat16
@@ -170,7 +204,7 @@ def _grouped_dot(rows: int, dtype, *kernel_shapes):
     1,280 rows, against 5.2 ms and 3.0 ms for reading the kernels once;
     5.6 against 9.6 ms for 20,480 rows); ``jax.lax.ragged_dot`` everywhere
     else. Chosen when the program is traced, from what can be seen then."""
-    tm = 128 if rows <= 4096 else 256
+    tm = _row_tile(rows)
     tiles = all(_tile(kk) and _tile(n) for _g, kk, n in kernel_shapes)
     if jax.default_backend() == "tpu" and dtype == jnp.bfloat16 \
             and rows % tm == 0 and tiles:
@@ -195,16 +229,30 @@ def relu2(a: jax.Array) -> jax.Array:
     return jnp.square(jax.nn.relu(a))
 
 
-def held_experts_swiglu(x, weights, experts, first, w_gate, w_up, w_down, live=None):
+def _row_bound(picks: int, count: int, of: "int | None") -> int:
+    """The compact branch's static rows for a launch of ``picks`` = t * k
+    picks where ``count`` of the router's ``of`` experts are held: the
+    expected held picks times ``COMPACT_SLACK``, up to a whole row tile of
+    ``_grouped_dot``. ``picks`` where that is no fewer (tiny launches), where
+    every expert is held, or where the caller gave no width: no compaction."""
+    if of is None or count >= of:
+        return picks
+    want = math.ceil(picks * count / of * COMPACT_SLACK)
+    tm = _row_tile(want)
+    return min(picks, -(-want // tm) * tm)
+
+
+def held_experts_swiglu(x, weights, experts, first, w_gate, w_up, w_down, live=None,
+                        of=None):
     """This chip's part of a routed SwiGLU layer: :func:`held_experts` with
     the two in-kernels ``w_gate``/``w_up`` and the SiLU gate."""
     return held_experts(x, weights, experts, first, (w_gate, w_up), w_down, swiglu,
-                        live=live)
+                        live=live, of=of)
 
 
 def held_experts(x: jax.Array, weights: jax.Array, experts: jax.Array,
                  first: int, w_in: "tuple[jax.Array, ...]", w_out: jax.Array,
-                 body, live: "jax.Array | None" = None
+                 body, live: "jax.Array | None" = None, of: "int | None" = None
                  ) -> tuple[jax.Array, dict]:
     """This chip's part of a routed expert layer, whatever an expert is.
 
@@ -214,40 +262,82 @@ def held_experts(x: jax.Array, weights: jax.Array, experts: jax.Array,
     expert's hidden rows from its in-products (:func:`swiglu` of two,
     :func:`relu2` of one). ``live`` (T,)
     bool marks the tokens that are real (padding and frozen lanes route too,
-    since shapes are static, but count for nothing and add nothing).
+    since shapes are static, but count for nothing and add nothing). ``of``
+    is the router's width (the experts ``experts`` ranges over), from which
+    the compact branch's row bound is sized; without it there is no such
+    branch.
+
+    The sorted picks go through gather, products and body in one of two
+    branches (the header comment above): ``compact`` carries
+    ``_row_bound(T * k, count, of)`` rows where the launch's held live picks
+    fit them, ``wide`` all ``T * k`` otherwise. Whichever runs, the program
+    gives bit-identical ``y`` on the same input, and where the bound is not
+    below ``T * k`` the function is ``wide`` alone, with no ``cond``. Call it
+    once a layer, not under ``vmap`` (a ``cond`` there runs both branches).
 
     Returns ``y`` (T, D) float32, the sum over the held experts a token
     picked of weight x expert(token), and the counts the serving loop sums
     into its counters: ``routed_held``/``routed_absent`` (picks of live
-    tokens on held / absent experts) and ``experts_hit`` (held experts with
-    at least one live pick)."""
+    tokens on held / absent experts), ``experts_hit`` (held experts with
+    at least one live pick) and ``compact`` (1 where the compact branch
+    ran, else 0)."""
     t, k = experts.shape
     count = w_out.shape[0]
-    local = experts - jnp.int32(first)
-    held = (local >= 0) & (local < count)
-    if live is not None:
-        held_live = held & live[:, None]
-        absent_live = (~held) & live[:, None]
+    bound = _row_bound(k * t, count, of)
+    # The two scopes name this layer's operations in a device trace
+    # (scripts/op_table.py): what carries rows, and the experts themselves.
+    with jax.named_scope("moe_dispatch"):
+        local = experts - jnp.int32(first)
+        held = (local >= 0) & (local < count)
+        if live is not None:
+            held_live = held & live[:, None]
+            absent_live = (~held) & live[:, None]
+        else:
+            held_live, absent_live = held, ~held
+        # Absent (and dead) picks sort behind every held expert, into rows
+        # past the groups' sum. Picks are laid out pick-major (pick j of token
+        # i at j * T + i), so that the way back is a split of the leading
+        # dimension.
+        key = jnp.where(held_live, local, count).T.reshape(k * t)
+        order = jnp.argsort(key, stable=True)
+        # Counted by comparison, not by a scatter-add of ones: the chip runs a
+        # scatter's updates one after another (0.2 ms a layer for 22,528 picks,
+        # my chip runs, PR 37) and a compare-and-sum in microseconds.
+        sizes = jnp.sum(key[:, None] == jnp.arange(count, dtype=jnp.int32)[None, :], axis=0,
+                        dtype=jnp.int32)
+        n_held = jnp.sum(held_live, dtype=jnp.int32)
+
+    def rows_of(picks, mode=None):
+        """The sorted picks ``picks`` (a prefix of ``order``) through the
+        experts, then every pick's row in pick order: (k * T, D) float32
+        (``mode``: what ``take`` does with a pick past the rows carried)."""
+        with jax.named_scope("moe_dispatch"):
+            xs = jnp.take(x, picks % t, axis=0)
+        with jax.named_scope("moe_experts"):
+            dot = _grouped_dot(picks.shape[0], x.dtype, w_in[0].shape, w_out.shape)
+            h = body(*(dot(xs, w, sizes) for w in w_in)).astype(x.dtype)
+            out = dot(h, w_out, sizes)
+        with jax.named_scope("moe_dispatch"):
+            return jnp.take(out, jnp.argsort(order), axis=0, mode=mode)
+
+    # Rows past the groups' sum hold whatever the product left there (seen on
+    # the chip: neither implementation zeroes them), and a pick that
+    # ``compact`` did not carry reads its last row: both are selected away
+    # below, not multiplied by zero.
+    if bound < k * t:
+        fits = n_held <= bound
+        out = jax.lax.cond(fits, lambda: rows_of(order[:bound], mode="clip"),
+                           lambda: rows_of(order))
     else:
-        held_live, absent_live = held, ~held
-    # Absent (and dead) picks sort behind every held expert, into rows past
-    # the groups' sum. Picks are laid out pick-major (pick j of token i at
-    # j * T + i), so that the way back is a split of the leading dimension.
-    key = jnp.where(held_live, local, count).T.reshape(k * t)
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
-    xs = jnp.take(x, order % t, axis=0)
-    dot = _grouped_dot(t * k, x.dtype, w_in[0].shape, w_out.shape)
-    h = body(*(dot(xs, w, sizes) for w in w_in)).astype(x.dtype)
-    out = dot(h, w_out, sizes)
-    # Back to pick order, weighted, summed over a token's k picks. Rows past
-    # the groups' sum hold whatever the product left there (seen on the chip:
-    # neither implementation zeroes them), so they are selected away, not
-    # multiplied by zero.
-    out = jnp.take(out, jnp.argsort(order), axis=0).reshape(k, t, -1)
-    y = jnp.sum(jnp.where(held_live.T[:, :, None], out * weights.T[:, :, None], 0.0),
-                axis=0)
-    stats = {"routed_held": jnp.sum(held_live, dtype=jnp.int32),
-             "routed_absent": jnp.sum(absent_live, dtype=jnp.int32),
-             "experts_hit": jnp.sum(sizes > 0, dtype=jnp.int32)}
+        fits = jnp.bool_(False)
+        out = rows_of(order)
+    with jax.named_scope("moe_dispatch"):
+        # Weighted, summed over a token's k picks.
+        out = out.reshape(k, t, -1)
+        y = jnp.sum(jnp.where(held_live.T[:, :, None], out * weights.T[:, :, None], 0.0),
+                    axis=0)
+        stats = {"routed_held": n_held,
+                 "routed_absent": jnp.sum(absent_live, dtype=jnp.int32),
+                 "experts_hit": jnp.sum(sizes > 0, dtype=jnp.int32),
+                 "compact": fits.astype(jnp.int32)}
     return y, stats
