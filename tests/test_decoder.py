@@ -544,3 +544,17 @@ def test_two_kind_ledger_never_double_hands_and_returns_all():
     with pytest.raises(ValueError):
         PageLedger(4, 4, rings=1)
     assert PageLedger(4, 4).ring_of(0) == 0 and PageLedger(4, 4).can_cover(3)
+
+
+def test_a_tied_head_is_the_embedding_transposed(tmp_path):
+    """``tie_word_embeddings``: no ``head`` is drawn, and the logits are those
+    of an untied model whose head holds the same embedding's transpose."""
+    tied = make_model(tmp_path, dict(ARCH, tie_word_embeddings=True), name="tied")
+    untied = make_model(tmp_path, name="untied")
+    pt, pu = tied.init_params(jax.random.key(0)), untied.init_params(jax.random.key(0))
+    assert "head" not in pt and "head" in pu
+    np.testing.assert_array_equal(np.asarray(pt["embed"]), np.asarray(pu["embed"]))
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((5, tied.d)), tied.dtype)
+    want = untied._head(dict(pu, head=pu["embed"].T), x)
+    # float32 sums in another order (the contraction runs over the other operand's axis)
+    np.testing.assert_allclose(tied._head(pt, x), want, rtol=1e-5, atol=2e-5)

@@ -388,3 +388,43 @@ def test_the_tile_kernel_of_latent_attention_compiles_for_the_v5e_at_the_cells_w
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
     assert "tpu_custom_call" in text and "tile_attention" in text
+
+
+def test_decode_over_packed_pages_compiles_for_the_v5e_at_the_cells_widths(one_chip, tmp_path,
+                                                                           monkeypatch):
+    """`paged_lm._decode_full` over pools whose rows hold two KV heads of 64
+    (ISSUE 40), at the cell's sizes: 80 lanes, 32 query heads over 8 KV heads,
+    1,280 pages of 128 tokens, a block table of 12 pages. The TPU branch is
+    steered by the backend's name here, in the test: jax's paged-attention
+    kernel takes the 128-wide packed rows and eight padded query heads a
+    pair, in compute blocks of 4 pages, and XLA copies no pool around it."""
+    import json
+
+    from tpuserve.config import ModelConfig
+    from tpuserve.models import build
+
+    arch = {"vocab_size": 256, "hidden_size": 2048, "num_hidden_layers": 1,
+            "layer_types": ["attention"], "mamba_n_heads": 64, "mamba_d_head": 64,
+            "mamba_n_groups": 1, "mamba_d_state": 128, "num_attention_heads": 32,
+            "num_key_value_heads": 8, "shared_intermediate_size": 256,
+            "attention_multiplier": 0.015625, "position_embedding_type": "nope"}
+    path = tmp_path / "arch.json"
+    path.write_text(json.dumps(arch))
+    model = build(ModelConfig(name="packed", family="hybrid_ffn", dtype="bfloat16",
+                              batch_buckets=[1], options={
+                                  "config_file": str(path), "max_prompt_tokens": 1024,
+                                  "max_new_tokens": 512}))
+    S = jax.ShapeDtypeStruct
+    pool = S(model._page_shape(1280, 128), jnp.bfloat16, sharding=one_chip)
+    assert pool.shape == (4, 1280, 128, 128)
+    q = S((80, 32, 64), jnp.bfloat16, sharding=one_chip)
+    bt = S((80, 12), jnp.int32, sharding=one_chip)
+    pos = S((80,), jnp.int32, sharding=one_chip)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
+    try:
+        text = jax.jit(model._decode_full).lower(q, pool, pool, bt, pos).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert "tpu_custom_call" in text
+    assert not [ln for ln in text.split("\n") if " copy(" in ln and "1280,128,128" in ln]

@@ -469,3 +469,17 @@ def test_through_the_engine_two_requests_move_the_counters_by_what_was_served(tm
     assert kv["state_bytes_per_slot"] == per_slot and kv["state_bytes"] == per_slot * SLOTS
     assert metrics.gauge(f"gen_state_bytes{{model=eng}}").value == per_slot * SLOTS
     assert kv["reserved"] == 0 and kv["pages"] > 0
+
+
+def test_a_tied_head_is_the_embedding_transposed(tmp_path):
+    """``tie_word_embeddings``: no ``head`` is drawn, and the logits are those
+    of an untied model whose head holds the same embedding's transpose."""
+    tied = make_model(tmp_path, dict(ARCH, tie_word_embeddings=True), name="tied")
+    untied = make_model(tmp_path, name="untied")
+    pt, pu = tied.init_params(jax.random.key(0)), untied.init_params(jax.random.key(0))
+    assert "head" not in pt and "head" in pu
+    np.testing.assert_array_equal(np.asarray(pt["embed"]), np.asarray(pu["embed"]))
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((5, tied.d)), tied.dtype)
+    want = untied._head(dict(pu, head=pu["embed"].T), x)
+    # float32 sums in another order (the contraction runs over the other operand's axis)
+    np.testing.assert_allclose(tied._head(pt, x), want, rtol=1e-5, atol=2e-5)

@@ -372,7 +372,7 @@ def test_the_page_signature_holds_one_latent_row_a_token_a_layer_and_no_leaf_by_
     ("rope_scaling", {"type": "yarn", "factor": 40}, NotImplementedError),
     ("q_lora_rank", None, NotImplementedError),
     ("attention_bias", True, NotImplementedError),
-    ("tie_word_embeddings", True, NotImplementedError),
+    ("hidden_act", "gelu", NotImplementedError),
     ("n_group", 8, NotImplementedError),
     ("topk_group", 4, NotImplementedError),
     ("scoring_func", "softmax", NotImplementedError),
@@ -496,3 +496,17 @@ def test_through_the_engine_two_requests_move_the_counters_by_what_was_served(tm
     assert kv["kv_bytes"] == 3 * ROW * 4 * PAGE * kv["pages"]
     assert metrics.gauge("gen_kv_row_bytes{model=eng}").value == 3 * ROW * 4
     assert kv["state_bytes"] == 0 and kv["reserved"] == 0 and kv["pages"] > 0
+
+
+def test_a_tied_head_is_the_embedding_transposed(tmp_path):
+    """``tie_word_embeddings``: no ``head`` is drawn, and the logits are those
+    of an untied model whose head holds the same embedding's transpose."""
+    tied = make_model(tmp_path, dict(ARCH, tie_word_embeddings=True), name="tied")
+    untied = make_model(tmp_path, name="untied")
+    pt, pu = tied.init_params(jax.random.key(0)), untied.init_params(jax.random.key(0))
+    assert "head" not in pt and "head" in pu
+    np.testing.assert_array_equal(np.asarray(pt["embed"]), np.asarray(pu["embed"]))
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((5, tied.d)), tied.dtype)
+    want = untied._head(dict(pu, head=pu["embed"].T), x)
+    # float32 sums in another order (the contraction runs over the other operand's axis)
+    np.testing.assert_allclose(tied._head(pt, x), want, rtol=1e-5, atol=2e-5)

@@ -20,6 +20,11 @@ Families (BASELINE.json ``configs``):
                    pattern string (Mamba-2 state-space, attention, routed
                    experts in a latent), built from a published config.json:
                    a recurrent state a slot beside the paged KV (ISSUE 32)
+- hybrid_ffn     — a language model whose layers are two sublayers each (a
+                   mixer by a list: Mamba-2 or attention with no position
+                   term, then a dense SwiGLU) under the embedding, residual,
+                   attention and logit multipliers, with a tied head, built
+                   from a published config.json (ISSUE 40)
 - mla            — a language model with latent attention (MLA: one
                    compressed row a token a layer in the page pool, an
                    absorbed decode and an expanded prefill form) and routed
@@ -45,6 +50,7 @@ _REGISTRY: dict[str, str] = {
     "textgen": "tpuserve.models.textgen",
     "decoder": "tpuserve.models.decoder",
     "hybrid": "tpuserve.models.hybrid",
+    "hybrid_ffn": "tpuserve.models.hybrid_ffn",
     "mla": "tpuserve.models.mla",
     "toy": "tpuserve.models.toy",
 }

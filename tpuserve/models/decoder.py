@@ -129,7 +129,7 @@ class DecoderServing(PagedLM):
         super().__init__(cfg)
         a = read_config_file(cfg)
         self.dtype = jnp.dtype(cfg.dtype)
-        for key, want in (("attention_bias", False), ("tie_word_embeddings", False),
+        for key, want in (("attention_bias", False),
                           ("moe_apply_router_weight_on_input", False)):
             if a.get(key, want) != want:
                 raise NotImplementedError(f"{cfg.name}: {key} = {a[key]!r}")
@@ -158,6 +158,7 @@ class DecoderServing(PagedLM):
         self.route_scale = float(a.get("moe_routed_scaling_factor", 1.0))
         self.softcap = float(a.get("moe_router_logit_softcapping", 0) or 0)
         self.vocab_full = int(a["vocab_size"])
+        self.tied = bool(a.get("tie_word_embeddings", False))
         kv_full = int(a["num_key_value_heads"])
         # -- the share --------------------------------------------------------
         share = a.get("share", {})
@@ -195,10 +196,7 @@ class DecoderServing(PagedLM):
         """(path, shape held here, full shape, start, role, fan-in) of every
         matrix, in a fixed order."""
         d, hd, s = self.d, self.hd, self.scales
-        yield (("embed",), (self.vocab, d), (self.vocab_full, d), (self.v_first, 0),
-               s["embed"], 1)
-        yield (("head",), (d, self.vocab), (d, self.vocab_full), (0, self.v_first),
-               s["head"], d)
+        yield from self._vocab_tensors()
         for i in range(self.n_layers):
             L = f"layer{i}"
             hf, h, h0 = self.heads_full[i], self.heads[i], self.h_first[i]
@@ -231,7 +229,7 @@ class DecoderServing(PagedLM):
 
     def kv_page_signature(self, slots: int, pages: int, page_tokens: int) -> Any:
         S = jax.ShapeDtypeStruct
-        page = S((self.kv, pages, page_tokens, self.hd), self.dtype)
+        page = S(self._page_shape(pages, page_tokens), self.dtype)
         ring = S((slots + 1, self.window, self.kv, self.hd), self.dtype)
         # ``acc``'s columns: picks of live tokens on held and on absent
         # experts, held experts hit, held experts x sparse layers run, the

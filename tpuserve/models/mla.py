@@ -100,8 +100,8 @@ class LatentServing(PagedLM):
         super().__init__(cfg)
         a = read_config_file(cfg)
         self.dtype = jnp.dtype(cfg.dtype)
-        for key, want in (("attention_bias", False), ("tie_word_embeddings", False),
-                          ("rope_scaling", None), ("n_group", 1), ("topk_group", 1),
+        for key, want in (("attention_bias", False), ("rope_scaling", None),
+                          ("n_group", 1), ("topk_group", 1),
                           ("scoring_func", "sigmoid"), ("topk_method", "noaux_tc"),
                           ("hidden_act", "silu"), ("moe_layer_freq", 1), ("share", None)):
             if a.get(key, want) != want:
@@ -130,6 +130,7 @@ class LatentServing(PagedLM):
         self.route_scale = float(a.get("routed_scaling_factor", 1.0))
         self.vocab_full = self.vocab = int(a["vocab_size"])
         self.v_first = 0
+        self.tied = bool(a.get("tie_word_embeddings", False))
         self.scales = {**DEFAULT_SCALES, **a.get("weight_scales", {})}
         self._serve_options(cfg, a)
 
@@ -152,8 +153,7 @@ class LatentServing(PagedLM):
         def whole(path, shape, role, fan_in):
             return path, shape, shape, (0,) * len(shape), s[role], fan_in
 
-        yield whole(("embed",), (self.vocab, d), "embed", 1)
-        yield whole(("head",), (d, self.vocab), "head", d)
+        yield from self._vocab_tensors()
         for i in range(self.n_layers):
             L = f"layer{i}"
             yield whole((L, "w_qa"), (d, self.q_rank), "q_a", d)

@@ -17,7 +17,17 @@ request. A family adds its layers: ``_tensors``, ``_gains``,
 
 A subclass sets, in its constructor: ``dtype``, ``d``, ``eps``,
 ``vocab_full``, ``v_first``, ``vocab``, ``scales``, where it attends by head
-``hd`` and ``kv`` (KV heads held), and calls ``_serve_options``.
+``hd`` and ``kv`` (KV heads held), and calls ``_serve_options``. It may set
+``tied`` (the head is the embedding transposed: no ``head`` is drawn) and
+``score_scale`` (what the scores are multiplied by, where the config says).
+
+PAGES OF NARROW HEADS (ISSUE 40). A row of the K or V pool holds as many KV
+heads side by side as fill the 128 lanes (``_kv_pack``: two heads of 64), so a
+pool is ``(KV / pack, pages, P, pack * hd)`` and a page row is never half a
+lane row. A write is the same scatter of rows; prefill's key blocks are taken
+apart by head after the gather; decode on the TPU hands the paged-attention
+kernel each query head zero-padded into its own KV head's part of the row
+(the score is the same sum), and keeps that part of the context.
 """
 
 from __future__ import annotations
@@ -92,6 +102,18 @@ class _ExpertSteps:
 class PagedLM(GenerativeModel):
     supports_kv_paging = True
     kv_page_leaves = ("kf", "vf")  # K and V by head; a family with another row says so
+    tied = False        # the head is the embedding transposed
+    score_scale = None  # attention's scores times this; None: hd ** -0.5
+    # Positions a compute block of the decode kernel holds at most, where the
+    # pool's rows are packed. The kernel reads a block whole whatever the
+    # lane's length, so a small one follows the live context and a large one
+    # saves steps of its loop: four layers of 80 lanes at 300 live positions
+    # took 2.52 / 2.17 / 1.59 / 1.66 / 1.86 / 2.23 ms at 128 / 256 / 384 / 512 /
+    # 768 / 1,536 positions (scripts/bench_attn_decode.py, my chip run, PR 40).
+    PACKED_DECODE_BLOCK = 512
+
+    def _scale(self) -> float:
+        return self.hd ** -0.5 if self.score_scale is None else float(self.score_scale)
 
     def _serve_options(self, cfg: ModelConfig, a: dict) -> None:
         """What is served of the model: the context, the draw's seed and
@@ -135,6 +157,16 @@ class PagedLM(GenerativeModel):
         unless the family has some (a router's selection bias)."""
         return ()
 
+    def _vocab_tensors(self):
+        """The embedding's held rows and, unless the head is tied to it, the
+        head's held columns (``_tensors``' first entries)."""
+        d, s = self.d, self.scales
+        yield (("embed",), (self.vocab, d), (self.vocab_full, d), (self.v_first, 0),
+               s["embed"], 1)
+        if not self.tied:
+            yield (("head",), (d, self.vocab), (d, self.vocab_full), (0, self.v_first),
+                   s["head"], d)
+
     def _drawn(self) -> Any:
         return jax.jit(self.draw_params, static_argnums=0)(self.draw_seed or 0)
 
@@ -170,6 +202,24 @@ class PagedLM(GenerativeModel):
 
     def kv_pages_per_slot(self, page_tokens: int) -> int:
         return -(-self.max_ctx // int(page_tokens))
+
+    def _kv_pack(self) -> int:
+        """KV heads a pool's row holds side by side: as many as fill the 128
+        lanes where they do so exactly, else one."""
+        pack = 128 // self.hd if self.hd < 128 and 128 % self.hd == 0 else 1
+        return pack if self.kv % pack == 0 else 1
+
+    def _page_shape(self, pages: int, page_tokens: int) -> tuple:
+        pack = self._kv_pack()
+        return (self.kv // pack, pages, page_tokens, pack * self.hd)
+
+    def _by_head(self, blk):
+        """Gathered pages (KV / pack, n, P, pack * hd) -> (KV, n * P, hd)."""
+        kvp, n, P, w = blk.shape
+        if w == self.hd:
+            return blk.reshape(kvp, n * P, w)
+        return blk.reshape(kvp, n * P, w // self.hd, self.hd).transpose(0, 2, 1, 3) \
+            .reshape(self.kv, n * P, self.hd)
 
     def _lane_signature(self, slots: int, page_tokens: int) -> dict:
         """The per-lane part of the paged state block: a slot's block-table
@@ -214,7 +264,7 @@ class PagedLM(GenerativeModel):
         g = q.shape[-2] // kvh
         qg = q.reshape(q.shape[:-2] + (kvh, g, q.shape[-1]))
         s = jnp.einsum("...tkgd,...ckd->...kgtc", qg, k,
-                       preferred_element_type=jnp.float32) * (self.hd ** -0.5)
+                       preferred_element_type=jnp.float32) * self._scale()
         s = jnp.where(mask[..., None, None, :, :], s, NEG)
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         o = jnp.einsum("...kgtc,...ckd->...tkgd", p, v,
@@ -225,7 +275,9 @@ class PagedLM(GenerativeModel):
     def _write_pages(pool, page, off, rows):
         """``rows`` (T, KV, hd) into the pool (KV, pages, P, hd) at (page[t],
         off[t]) of every KV head: as ONE scatter of rows into the pool seen
-        as (KV * pages * P, hd). (Scattered over two middle dimensions, the
+        as (KV * pages * P, hd). A pool of packed rows, (KV / pack, pages, P,
+        pack * hd), takes the same ``rows``: a token's heads lie side by
+        side. (Scattered over two middle dimensions, the
         compiler copied the whole pool to another layout and back, eight
         times a step: 13 of a step's 33 ms, my chip run, PR 28.) A pool
         with no heads, (pages, P, width), takes ``rows`` (T, width): one
@@ -242,8 +294,13 @@ class PagedLM(GenerativeModel):
         return _mm(h, w_down)
 
     def _head(self, params, x):
-        """(T, d) -> (T, vocab held) float32 logits."""
-        return _mm(rms_norm(x, params["norm_f"], self.eps), params["head"])
+        """(T, d) -> (T, vocab held) float32 logits. A tied head is the
+        embedding's held rows, contracted over ``d`` where they lie."""
+        h = rms_norm(x, params["norm_f"], self.eps)
+        if self.tied:
+            return jnp.einsum("td,vd->tv", h, params["embed"],
+                              preferred_element_type=jnp.float32)
+        return _mm(h, params["head"])
 
     def _sample(self, logits, seed, position, temp):
         """Greedy where temp == 0, Gumbel-max otherwise, keyed by the
@@ -424,12 +481,12 @@ class PagedLM(GenerativeModel):
 
         def block(j):
             pg = jax.lax.dynamic_slice(rowp, (j * kb,), (kb,))
-            kblk = jnp.take(kp, pg, axis=1).reshape(self.kv, kb * P, self.hd)
-            vblk = jnp.take(vp, pg, axis=1).reshape(self.kv, kb * P, self.hd)
+            kblk = self._by_head(jnp.take(kp, pg, axis=1))
+            vblk = self._by_head(jnp.take(vp, pg, axis=1))
             kpos = j * kb * P + jnp.arange(kb * P)
             see = kpos[None, :] <= qpos[:, None]
             s = jnp.einsum("tkgd,kcd->kgtc", qg, kblk,
-                           preferred_element_type=jnp.float32) * (self.hd ** -0.5)
+                           preferred_element_type=jnp.float32) * self._scale()
             return jnp.where(see[None, None], s, NEG), lambda p: jnp.einsum(
                 "kgtc,kcd->kgtd", p.astype(vblk.dtype), vblk,
                 preferred_element_type=jnp.float32)
@@ -469,13 +526,32 @@ class PagedLM(GenerativeModel):
         return new
 
     # -- decode -------------------------------------------------------------------
+    @staticmethod
+    def _pad_queries(q, kv: int, pack: int):
+        """q (b, H, hd) -> (b, H, pack * hd): each query head in the part of
+        a packed row that its own KV head fills, zeros in the others, so that
+        its product with the whole row is its product with its own head."""
+        b, H, hd = q.shape
+        own = jnp.eye(pack, dtype=q.dtype)[None, None, :, None, :, None]
+        return (q.reshape(b, kv // pack, pack, H // kv, 1, hd) * own).reshape(b, H, pack * hd)
+
+    @staticmethod
+    def _own_part(o, kv: int, pack: int):
+        """The context over packed rows (b, H, pack * hd) -> (b, H, hd): of
+        each query head, the part that its own KV head's values fill."""
+        b, H, w = o.shape
+        o = o.reshape(b, kv // pack, pack, H // kv, pack, w // pack)
+        return jnp.einsum("bkjgid,ji->bkjgd", o, jnp.eye(pack, dtype=o.dtype)) \
+            .reshape(b, H, w // pack)
+
     def _decode_full(self, q, kp, vp, bt, pos):
         """One full layer's decode attention through the block table: q
-        (b, H, hd), pages (KV, pages, P, hd), bt (b, pps) -> (b, H, hd)
-        float32. On the TPU a kernel that reads live pages only; elsewhere
-        (tests, toys) a gather of the padded block table."""
+        (b, H, hd), pools (KV / pack, pages, P, pack * hd), bt (b, pps) -> (b,
+        H, hd) float32. On the TPU a kernel that reads live pages only;
+        elsewhere (tests, toys) a gather of the padded block table."""
+        pack = kp.shape[-1] // self.hd
         on_tpu = jax.default_backend() == "tpu" and self.dtype == jnp.bfloat16 \
-            and self.hd % 128 == 0 and kp.shape[2] % 8 == 0
+            and kp.shape[-1] % 128 == 0 and kp.shape[2] % 8 == 0
         if on_tpu:  # tps-ok[TPS503]: backend and static shapes, at trace time
             # The Pallas paged-attention kernel (my chip runs, PR 28: 0.8 ms a
             # layer for 128 lanes holding 172,000 positions, within 0.002 of
@@ -483,14 +559,22 @@ class PagedLM(GenerativeModel):
             from jax.experimental.pallas.ops.tpu.paged_attention import \
                 paged_attention
 
-            ppcb = max(c for c in range(1, 33) if bt.shape[1] % c == 0)
-            qs = (q.astype(jnp.float32) * (self.hd ** -0.5)).astype(q.dtype)
-            return paged_attention(qs, kp, vp, pos + 1, bt,
-                                   pages_per_compute_block=ppcb
-                                   ).astype(jnp.float32)
+            # A compute block is read whole whatever the lane's length: over
+            # packed rows it is held to PACKED_DECODE_BLOCK positions, so that
+            # what a step reads follows the live context and not max_ctx.
+            most = 32 if pack == 1 else max(1, self.PACKED_DECODE_BLOCK // kp.shape[2])
+            ppcb = max(c for c in range(1, most + 1) if bt.shape[1] % c == 0)
+            qs = (q.astype(jnp.float32) * self._scale()).astype(q.dtype)
+            if pack > 1:
+                qs = self._pad_queries(qs, self.kv, pack)
+            o = paged_attention(qs, kp, vp, pos + 1, bt,
+                                pages_per_compute_block=ppcb).astype(jnp.float32)
+            return self._own_part(o, self.kv, pack) if pack > 1 else o
         b, (P, pps) = q.shape[0], (kp.shape[2], bt.shape[1])
-        kc = jnp.take(kp, bt, axis=1).reshape(self.kv, b, pps * P, self.hd)
-        vc = jnp.take(vp, bt, axis=1).reshape(self.kv, b, pps * P, self.hd)
+        kc = self._by_head(jnp.take(kp, bt.reshape(-1), axis=1)).reshape(
+            self.kv, b, pps * P, self.hd)
+        vc = self._by_head(jnp.take(vp, bt.reshape(-1), axis=1)).reshape(
+            self.kv, b, pps * P, self.hd)
         mask = (jnp.arange(pps * P)[None, :] <= pos[:, None])[:, None, :]
         return self._attend(q[:, None], kc.transpose(1, 2, 0, 3),
                             vc.transpose(1, 2, 0, 3), mask)[:, 0]
@@ -565,7 +649,11 @@ class PagedLM(GenerativeModel):
                 _ExpertSteps(metrics.counter(f"moe_expert_steps_total{{model={name},phase={ph}}}"),
                              metrics.counter(f"moe_layers_total{{model={name},phase={ph}}}"),
                              self.e_count),
-                metrics.counter(f"gen_context_tokens_total{{model={name},phase={ph}}}")]
+                self._context_counter(metrics, ph)]
+
+    def _context_counter(self, metrics: Any, ph: str) -> Any:
+        """Positions attended from, summed over live tokens."""
+        return metrics.counter(f"gen_context_tokens_total{{model={self.name},phase={ph}}}")
 
     def _compact_counter(self, metrics: Any, ph: str) -> Any:
         """The counter of ``acc``'s last column: expert layers run whose
