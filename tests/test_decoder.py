@@ -482,7 +482,11 @@ def test_through_the_engine_both_cache_kinds_come_back_and_the_counters_move(tmp
     assert phases - {None} <= set(LOOP_PHASES)
     for name, seen_iters in iters.items():
         assert seen_iters <= iters["tpuserve.gen_loop"] | {max(iters["tpuserve.gen_loop"]) + 1}, name
-    assert iters["tpuserve.gen_step"] == iters["tpuserve.gen_fetch"]
+    # a fetch carries the pass of the STEP it waits for (ISSUE 41: a pass dispatches step k and
+    # reads out(k-1)); the one step never fetched is the last, dispatched ahead and then dropped
+    # unread because every slot had retired
+    assert iters["tpuserve.gen_fetch"] <= iters["tpuserve.gen_step"]
+    assert iters["tpuserve.gen_step"] - iters["tpuserve.gen_fetch"] <= {max(iters["tpuserve.gen_step"])}
     # five requests through three slots: pages and rings were handed out again
     assert eng.pages.n_reserved == 0 and eng.pages.n_reserved_rings == 0
     assert eng.pages.n_free_rings == SLOTS

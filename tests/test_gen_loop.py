@@ -397,3 +397,108 @@ def test_the_counter_readers_read_the_programs_scrapes(dec_rt):  # noqa: F811
     p50, p95 = reader("gen_token_gap_ms_p50")(run), reader("gen_token_gap_ms_p95")(run)
     assert 0 < first < 1e3 * wall and 0 < p50 <= p95 < 1e3 * wall
     assert sum(v for k, v in delta.items() if k.startswith("gen_first_unit_ms_count")) == 6
+
+
+# -- one step queued ahead (ISSUE 41) -----------------------------------------------------------
+
+def test_steps_ahead_reads_none_for_a_lone_one_step_request_and_all_but_the_first_of_a_busy_stretch(dec_rt):  # noqa: F811
+    """`gen_steps_ahead_total` counts, beside `gen_iterations_total`, the steps
+    dispatched while the step before them was unread. A request of one token
+    is done at its arming: out(0) says so, and step 1, dispatched ahead of that
+    reading, is dropped unread once no lane is left (it counts in neither).
+    A busy stretch of n steps accounted for has n - 1 of them ahead."""
+    model, _ = dec_rt
+
+    async def go(eng, metrics):
+        iters = metrics.counter(f"gen_iterations_total{{model={eng.name}}}")
+        ahead = metrics.counter(f"gen_steps_ahead_total{{model={eng.name}}}")
+        assert (await eng.submit(dec_item(model, 5, max_new=1)))["n_tokens"] == 1
+        await asyncio.sleep(0.05)  # the loop reaches its wait
+        lone = (iters.value, ahead.value, eng._ahead)
+        await asyncio.gather(eng.submit(dec_item(model, 6, max_new=7, first=30)),
+                             eng.submit(dec_item(model, 4, max_new=3, first=50)))
+        await asyncio.sleep(0.05)
+        return lone, (iters.value, ahead.value, eng._ahead)
+
+    (i1, a1, left1), (i2, a2, left2) = run_engine(dec_rt, go)
+    assert (i1, a1, left1) == (1, 0, None)
+    assert i2 - i1 >= 6 and a2 - a1 == (i2 - i1) - 1 and left2 is None
+
+
+def test_the_step_ahead_reader_reads_the_programs_scrapes_and_none_from_the_parents(dec_rt):  # noqa: F811
+    model, _ = dec_rt
+
+    async def go(eng, metrics):
+        await eng.submit(dec_item(model, 5))  # warm: the first scrape finds every family
+        await asyncio.sleep(0.02)
+        start = prom.parse(metrics.render_prometheus())
+        await asyncio.gather(*[eng.submit(dec_item(model, 4 + i, max_new=8, first=1 + 9 * i))
+                               for i in range(6)])
+        await asyncio.sleep(0.02)
+        return prom.delta(prom.parse(metrics.render_prometheus()), start), eng.name
+
+    delta, name = run_engine(dec_rt, go)
+    run = {"metrics_delta": delta, "model_name": name, "notes": []}
+    iters = sum(prom.select(delta, "gen_iterations_total", model=name).values())
+    got = reader("gen_step_ahead_pct")(run)
+    assert got == pytest.approx(100.0 * (iters - 1) / iters) and 80 < got < 100
+    # the parent's program has no such counter: the same scrapes without it
+    parent = {k: v for k, v in delta.items() if not k.startswith("gen_steps_ahead_total")}
+    assert len(parent) == len(delta) - 1
+    assert reader("gen_step_ahead_pct")({**run, "metrics_delta": parent}) is None
+    assert reader("gen_step_ahead_pct")({}) is None
+    # and it stands in BENCHMARK.json as the generation engine's, for the generating cells
+    entry = next(m for m in spec.load_benchmark()["per_layer"] if m["name"] == "gen_step_ahead_pct")
+    assert (entry["layer"], entry["moves"], entry["source"], entry["unit"], entry["better"]) == (
+        "generation engine", "items_per_s", "program_counter", "%", "higher")
+    assert len(entry["workloads"]) == 4
+
+
+# The loop of ISSUE 41 in the reader's eyes. Times in ms, window_s = 0.100, the chip busy from 0 to 92:
+# Chip  S1 jit_step [0, 20)  S2 [20, 40)  X jit_extract [40, 41)  P jit_prefill_fn [41, 51)  S3 [51, 71)
+#       S4 [71, 91)  X [91, 92)
+# Pass 2: gen_step(2) [1, 1.4) queues S2 behind S1, which runs; gen_fetch [1.4, 20.5) waits for S1's
+#         out-block: it carries iter 1, its STEP's; account, emit, retire with gen_extract(2) [24, 24.3)
+# Pass 3: gen_pack(3), gen_prefill(3) [27.5, 28) queue behind S2; gen_step(3) [29.5, 30) -> S3 at 51;
+#         gen_fetch(2) [30, 40.6); pass 4: gen_step(4) [44, 44.4) -> S4 at 71; gen_fetch(3) [44.4, 71.5);
+#         gen_extract(4) [74, 74.3); pass 5: gen_fetch(4) [76, 91.4)
+AHEAD_CHIP = [("jit_step(7)", 0, 20), ("jit_step(7)", 20, 20), ("jit_extract(5)", 40, 1),
+              ("jit_prefill_fn(9)", 41, 10), ("jit_step(7)", 51, 20), ("jit_step(7)", 71, 20),
+              ("jit_extract(5)", 91, 1)]
+AHEAD_PHASES = [(2, "step", 0.5, 21), (2, "account", 21, 23), (2, "emit", 23, 23.5), (2, "retire", 23.5, 25),
+                (3, "sweep", 25, 25.5), (3, "admit", 25.5, 26), (3, "prefill", 26, 29), (3, "step", 29, 41),
+                (3, "account", 41, 43), (3, "emit", 43, 43.2), (3, "retire", 43.2, 43.5),
+                (4, "sweep", 43.5, 43.8), (4, "step", 43.8, 72), (4, "account", 72, 73.5),
+                (4, "retire", 73.5, 75), (5, "sweep", 75, 75.5), (5, "step", 75.5, 92)]
+
+
+def ahead_workers(fetch_iter_is_the_steps: bool):
+    f = 0 if fetch_iter_is_the_steps else 1   # what a fetch would carry were it its pass's number
+    return [("fetch", "gen_step", 2, 1, 1.4), ("fetch", "gen_fetch", 1 + f, 1.4, 20.5),
+            ("fetch", "gen_extract", 2, 24, 24.3), ("h2d", "gen_pack", 3, 26.5, 27.5),
+            ("h2d", "gen_prefill", 3, 27.5, 28), ("fetch", "gen_step", 3, 29.5, 30),
+            ("fetch", "gen_fetch", 2 + f, 30, 40.6), ("fetch", "gen_step", 4, 44, 44.4),
+            ("fetch", "gen_fetch", 3 + f, 44.4, 71.5), ("fetch", "gen_extract", 4, 74, 74.3),
+            ("fetch", "gen_fetch", 4 + f, 76, 91.4)]
+
+
+def test_the_clock_check_pairs_calls_that_queue_a_whole_step_before_their_module_starts(monkeypatch):
+    """With a step queued ahead every compiled call returns long before its
+    module begins, so the check's upper bound (call.start <= module.start) is a
+    step slack and the lower one (a fetch ends after ITS step's module) decides:
+    it holds because `tpuserve.gen_fetch` carries its step's `iter`. The chip is
+    never idle between programs, so nothing but the window's edges is idle."""
+    monkeypatch.setitem(globals(), "CHIP", AHEAD_CHIP)
+    att, reduced = analyse(xspace(phases=AHEAD_PHASES, workers=ahead_workers(True)))
+    ck = att["clock"]
+    assert ck["offset_ms"] == 0.0 and ck["shifts"] == (1, 0) and ck["pairs"] == (3, 1)
+    assert ck["bounds_ms"] == pytest.approx((-0.4, 13.5))
+    assert sorted(ck["call_to_module_ms"]) == pytest.approx([13.5, 19.0, 21.5, 27.0])
+    assert sorted(ck["fetch_after_module_ms"]) == pytest.approx([0.4, 0.5, 0.6])
+    idle_ms = {k: v * 1e3 for k, v in att["totals_s"].items()}
+    assert idle_ms["unknown"] == pytest.approx(8.0) and sum(idle_ms.values()) == pytest.approx(8.0)
+    assert sum(idle_ms.values()) == pytest.approx((reduced["window_s"] - reduced["busy_s"]) * 1e3)
+    # were the fetch to carry its PASS's number, each would be held against the module of the step the
+    # same hop dispatched, which ends a step later: no pairing within 10 ms, nothing attributed
+    att, _ = analyse(xspace(phases=AHEAD_PHASES, workers=ahead_workers(False)))
+    assert att["clock"] is None

@@ -709,3 +709,126 @@ def test_http_sd15_through_engine():
             await client.close()
 
     run(go())
+
+
+# ---------------------------------------------------------------------------
+# A finished lane is frozen (ISSUE 41): the contract the step ahead rests on
+# ---------------------------------------------------------------------------
+# The engine reads step k's out-block while step k+1 runs, so a lane that
+# out(k) reports done has ridden step k+1 by the time its extract reads it,
+# and its pages may be another request's by then. Every registered generating
+# family is held to GenerativeModel.step's docstring here, through its own
+# compiled programs: the two without a benchmark cell and the mesh decode
+# path included.
+
+SD_OPTS = dict(steps=3, vocab_size=128, text_layers=1, text_d_model=16, text_heads=2,
+               unet_ch=8, unet_mults=[1, 2], unet_res=1, unet_attn_levels=[0],
+               unet_heads=2, vae_ch=8, vae_mults=[1, 2])
+FROZEN_SLOTS = 3
+
+
+def _frozen_case(name, tmp_path):
+    """(model, runtime arguments, [genserve] keys, short item, long item)."""
+    from tpuserve.config import ParallelConfig
+
+    ids = lambda model, n, max_new, first=1: model.host_decode(json.dumps(  # noqa: E731
+        {"prompt_ids": list(range(first, first + n)), "max_new_tokens": max_new}).encode(),
+        "application/json")
+    if name.startswith("textgen"):
+        sharded = "sharded" in name
+        model = build(tg_cfg(parallelism="sharded", tp=2) if sharded else tg_cfg())
+        rt_kw = {"parallel": ParallelConfig(n_chips=4)} if sharded else {}
+        gc = dict(kv_paging=True, kv_page_tokens=8) if "paged" in name else {}
+        return (model, rt_kw, gc, prompt_item(model, "short", seed=2, max_new=4),
+                prompt_item(model, "a long one", seed=1, max_new=40))
+    if name == "sd15":
+        model = build(ModelConfig(name="sd", family="sd15", batch_buckets=[1], dtype="float32",
+                                  parallelism="single", image_size=32, options=dict(SD_OPTS)))
+        item = lambda p, s: model.host_decode(  # noqa: E731
+            json.dumps({"prompt": p, "seed": s}).encode(), "application/json")
+        return model, {}, {}, item("a fox", 1), item("a hen", 2)
+    maker = {"decoder": "tests.test_decoder", "hybrid": "tests.test_hybrid",
+             "hybrid_ffn": "tests.test_hybrid_ffn", "mla": "tests.test_mla"}[name]
+    import importlib
+    model = importlib.import_module(maker).make_model(str(tmp_path), name="fz")
+    return (model, {}, dict(kv_paging=True, kv_page_tokens=4, prefill_chunk=8),
+            ids(model, 5, 4), ids(model, 6, 10, first=20))
+
+
+FROZEN_CASES = ["textgen-dense", "textgen-paged", "textgen-sharded-dense", "textgen-sharded-paged",
+                "sd15", "decoder", "hybrid", "hybrid_ffn", "mla"]
+
+
+def test_the_frozen_lane_cases_name_every_registered_generating_family():
+    import importlib
+
+    from tpuserve import models
+    from tpuserve.genserve.model import GenerativeModel
+    generating = {
+        f for f in models.families()
+        if any(isinstance(v, type) and issubclass(v, GenerativeModel) and v is not GenerativeModel
+               for v in vars(importlib.import_module(models._REGISTRY[f])).values())}
+    assert generating == {c.split("-")[0] for c in FROZEN_CASES}
+
+
+@pytest.mark.parametrize("case", FROZEN_CASES)
+def test_a_lane_whose_out_block_said_done_is_not_changed_by_one_more_step(case, tmp_path):
+    import jax
+    import numpy as np
+
+    from tpuserve.genserve.model import PrefillPiece
+    model, rt_kw, gc, short, long_ = _frozen_case(case, tmp_path)
+    rt = build_runtime(model, compile_forward=False, **rt_kw)
+    eng = GenEngine(model, rt, Metrics(), GenserveConfig(slots=FROZEN_SLOTS, **gc))
+    eng.compile()
+    slots, lane, other = FROZEN_SLOTS, 1, 0
+    state = eng._host_zeros(eng._state_struct)
+    pps = eng._pps
+
+    def fold(state, slot, item):
+        if not eng.paging:
+            return rt.run_program("insert", state, np.int32(slot), item)
+        row = eng._cache_row(list(range(1 + slot * pps, 1 + (slot + 1) * pps)), slot + 1)
+        n, chunk = model.prompt_tokens(item), eng._prefill_chunk
+        for s in range(0, n, chunk):
+            state = rt.run_program("prefill", state, model.pack_prefill(
+                [PrefillPiece(slot, item, s, min(chunk, n - s), row)], chunk, eng._prefill_pieces))
+        return state
+
+    # the short request first, so that the other lane is still at work when it is done
+    state = fold(state, lane, short)
+    state, out = rt.run_program("step", state)
+    state = fold(state, other, long_)
+    assert not bool(np.asarray(out["done"])[lane])
+    for _ in range(eng._max_steps_guard):
+        state, out = rt.run_program("step", state)
+        if bool(np.asarray(out["done"])[lane]):
+            break
+    assert bool(np.asarray(out["done"])[lane]) and not bool(np.asarray(out["done"])[other])
+    before = jax.tree_util.tree_map(np.array, state)  # the step donates its block
+    answer = jax.tree_util.tree_map(np.array, rt.run_program("extract", state, np.int32(lane)))
+    state, out2 = rt.run_program("step", state)
+    assert bool(np.asarray(out2["done"])[lane])
+    after = jax.tree_util.tree_map(np.array, state)
+    # the lane's rows of every leaf, and the pages and the ring it holds, bit for bit;
+    # the other lane's step was a real one (something of the block did change)
+    n_pages = eng.pages.pages if eng.paging else -1
+    mine = np.arange(1 + lane * pps, 1 + (lane + 1) * pps)
+    held, changed = 0, False
+    flat_b = jax.tree_util.tree_flatten_with_path(before)[0]
+    for (path, b), a in zip(flat_b, jax.tree_util.tree_leaves(after)):
+        where = jax.tree_util.keystr(path)
+        changed |= not np.array_equal(a, b)
+        if b.ndim and b.shape[0] == slots:
+            np.testing.assert_array_equal(a[lane], b[lane], err_msg=where)
+        elif n_pages in b.shape:
+            ax = b.shape.index(n_pages)
+            np.testing.assert_array_equal(np.take(a, mine, ax), np.take(b, mine, ax), err_msg=where)
+            held += 1
+        elif b.ndim and b.shape[0] == slots + 1:  # a window ring a slot, ring 0 the sentinel
+            np.testing.assert_array_equal(a[lane + 1], b[lane + 1], err_msg=where)
+            held += 1
+    assert changed and (held > 0) == eng.paging
+    # so what extract reads of the lane after the step ahead is what it read before it
+    again = jax.tree_util.tree_map(np.array, rt.run_program("extract", state, np.int32(lane)))
+    jax.tree_util.tree_map(np.testing.assert_array_equal, answer, again)

@@ -470,17 +470,13 @@ class ByHand:
         await self.eng.stop()
 
     async def iterate(self, n=1):
-        eng = self.eng
+        """`n` passes of the loop (`GenEngine._pass`: sweep, admit, launch, dispatch
+        step k, read out(k-1), account, emit, retire), then the event loop's turn, so
+        that the tasks answering what a pass read of a retired slot have run."""
         for _ in range(n):
-            eng._expire_pending()
-            eng._evict_expired()
-            await eng._admit()
-            await eng._advance_prefills()
-            if eng.arena.n_active:
-                out = await eng.stages.run(eng.name, "fetch", eng._step_sync)
-                eng._c_iterations.inc()
-                eng._count_step(out, eng._stamp("account"))
-                await eng._retire(out)
+            await self.eng._pass()
+        while self.eng._finishing:
+            await asyncio.sleep(0)
 
     def count(self, family):
         return self.metrics.counter(f"{family}{{model={self.eng.name}}}").value
@@ -697,3 +693,192 @@ def test_textgen_takes_one_prompt_a_launch_exactly_as_before(chunked_rt, monkeyp
         return out
 
     assert packed == run(loop())
+
+
+# ---------------------------------------------------------------------------
+# One step queued ahead (ISSUE 41): a pass dispatches step k and reads out(k-1)
+# ---------------------------------------------------------------------------
+
+def lp_item(model, ids, max_new):
+    body = {"prompt_ids": list(ids), "max_new_tokens": max_new, "logprobs": 2}
+    return model.host_decode(json.dumps(body).encode(), "application/json")
+
+
+def test_a_slot_armed_in_the_pass_its_occupant_retired_is_not_read_from_the_older_out_block(
+        dec_rt, monkeypatch):
+    """Three requests of different lengths. A (3 tokens) is seen done in
+    out(1) and retires in pass 3; pass 4 hands its slot (the arena gives the
+    last one freed first) to C, whose one-launch prompt is armed there (hold
+    0) BEFORE step 3 is dispatched, and then reads out(2): a step dispatched
+    before C's arming, in which slot 0 is still A's lane, done. C is not retired, counted or given a first token
+    from it; every answer is what the programs give by hand, without the
+    engine (tests/test_decoder.py `serve`)."""
+    import numpy as np
+
+    from tests.test_decoder import serve
+    model, rt = dec_rt
+    prompts = [list(range(1, 6)), list(range(20, 26)), list(range(40, 44))]
+    max_news = [3, 9, 4]
+
+    async def go():
+        async with ByHand(dec_rt, 0, monkeypatch, **paged_over(
+                kv_page_tokens=4, prefill_chunk=16)) as h:
+            eng = h.eng
+            first = h.metrics.histogram(f"gen_first_unit_ms{{model={eng.name}}}")
+            read = []   # what each pass's retire was given: (step, its `done`, who was armed when)
+            retire = eng._retire
+
+            async def spy(out, seq):
+                read.append((seq, np.array(out["done"]), {
+                    s: eng.arena.peek(s).armed_step for s in eng.arena.active_slots()}))
+                await retire(out, seq)
+            eng._retire = spy
+            a, b = (eng.submit(lp_item(model, p, n)) for p, n in zip(prompts[:2], max_news))
+            await h.iterate(3)
+            assert [r[0] for r in read] == [0, 1] and read[1][1][0]      # out(1): A done
+            assert eng.arena.active_slots() == [1] and not a.done()     # released; its extract unread
+            assert len(eng._extracts) == 1 and eng._ahead.seq == 2
+            tokens0, firsts0 = h.count("gen_decode_tokens_total"), first.n
+            c = eng.submit(lp_item(model, prompts[2], max_news[2]))
+            await h.iterate()
+            seq, done, armed = read[2]
+            assert seq == 2 and done[0] and armed == {1: 0, 0: 3}       # A's lane, C's slot
+            info = eng.arena.peek(0)
+            assert info.item is not None and not c.done() and info.first_unit_at is None
+            assert info.iterations == 0 and info.since_step == 3
+            assert h.count("gen_decode_tokens_total") == tokens0 + 1    # B alone decoded in step 2
+            assert first.n == firsts0 and (await a)["n_tokens"] == 3    # A answered a pass after
+            await h.iterate()
+            assert read[3][0] == 3 and not read[3][1][0] and first.n == firsts0 + 1
+            await h.iterate(8)
+            res = await asyncio.gather(a, b, c)
+            assert eng.pages.n_reserved == 0 and eng.pages.n_reserved_rings == 0
+            return res
+
+    res = run(go())
+    want, _, _ = serve(model, rt.params_per_mesh[0], [np.asarray(p, np.int32) for p in prompts],
+                       max_news, slots=3)
+    for got, ref, n in zip(res, want, max_news):
+        assert got["tokens"] == ref["tokens"][:n].tolist() and got["n_tokens"] == n
+        np.testing.assert_allclose(got["logprobs"]["values"], ref["lp"][:n, :2], atol=1e-4)
+
+
+def test_the_loop_serves_a_mixed_batch_through_reused_slots_as_the_programs_do_by_hand(dec_rt):
+    """The running loop, sixteen requests of different `max_new` through six
+    slots (every slot reused, most of them in the pass after a retirement):
+    tokens and log-probabilities are the programs' own."""
+    import numpy as np
+
+    from tests.test_decoder import serve
+    model, rt = dec_rt
+    prompts = [list(range(1 + 5 * i, 1 + 5 * i + 3 + i % 5)) for i in range(16)]
+    max_news = [2, 9, 1, 5, 12, 3, 7, 4, 1, 6, 2, 11, 3, 8, 5, 2]
+    eng, metrics = make_engine(dec_rt, slots=6, **paged_over(kv_page_tokens=4, prefill_chunk=16))
+
+    async def go():
+        await eng.start()
+        res = await asyncio.gather(*[eng.submit(lp_item(model, p, n))
+                                     for p, n in zip(prompts, max_news)])
+        await eng.drain(asyncio.get_running_loop().time() + 30)
+        left = (eng._ahead, list(eng._extracts), dict(eng._finishing))
+        await eng.stop()
+        return res, left
+
+    res, left = run(go())
+    assert left == (None, [], {})
+    want, _, _ = serve(model, rt.params_per_mesh[0], [np.asarray(p, np.int32) for p in prompts],
+                       max_news, slots=16)
+    for got, ref, n in zip(res, want, max_news):
+        assert got["tokens"] == ref["tokens"][:n].tolist() and got["n_tokens"] == n
+        np.testing.assert_allclose(got["logprobs"]["values"], ref["lp"][:n, :2], atol=1e-4)
+    assert eng.arena.n_free == 6 and eng.pages.n_free == eng.pages.usable
+    iters = metrics.counter(f"gen_iterations_total{{model={eng.name}}}").value
+    ahead = metrics.counter(f"gen_steps_ahead_total{{model={eng.name}}}").value
+    assert iters > 12 and ahead == iters - 1   # one busy stretch: every step but its first
+
+
+def ledger(eng):
+    return (eng.arena.n_active, eng.arena.n_free, eng.pages.n_reserved, eng.pages.n_free,
+            eng.pages.n_reserved_rings, eng.pages.n_free_rings)
+
+
+@pytest.mark.parametrize("how", ["evict", "fail", "stop"])
+def test_a_slot_that_goes_with_a_step_in_flight_leaves_nothing_unread_or_unaccounted(
+        dec_rt, monkeypatch, how):
+    import time
+
+    from tpuserve.batcher import DeadlineExceeded
+    model, _ = dec_rt
+
+    async def go():
+        async with ByHand(dec_rt, 0, monkeypatch, **paged_over(
+                kv_page_tokens=4, prefill_chunk=16)) as h:
+            eng = h.eng
+            empty = ledger(eng)
+            a = eng.submit(dec_item(model, 5, max_new=10), deadline_at=time.perf_counter() + 3600)
+            b = eng.submit(dec_item(model, 6, max_new=10, first=20))
+            await h.iterate(3)
+            assert eng._ahead is not None and eng._ahead.seq == 2 and ledger(eng)[0] == 2
+            iters = h.count("gen_iterations_total")
+            if how == "evict":
+                # A's deadline passes while step 2, which still holds its lane live, is queued
+                eng.arena.peek(0).deadline_at = time.perf_counter() - 1.0
+                await h.iterate()
+                with pytest.raises(DeadlineExceeded):
+                    await a
+                assert eng.arena.active_slots() == [1] and h.count("gen_evictions_total") == 1
+                assert h.count("gen_iterations_total") == iters + 1   # out(2) read, for B
+                # the slot goes to C at once; out(3), dispatched before C's arming, is not C's
+                c = eng.submit(dec_item(model, 4, max_new=3, first=40))
+                await h.iterate(12)
+                assert [(await f)["n_tokens"] for f in (b, c)] == [10, 3]
+            elif how == "fail":
+                await eng._fail_active(RuntimeError("device said no"))
+                for f in (a, b):
+                    with pytest.raises(RuntimeError, match="device said no"):
+                        await f
+                # the failed block's out-block is dropped, never read beside the new block
+                assert eng._ahead is None and ledger(eng) == empty
+                await h.iterate(2)
+                assert h.count("gen_iterations_total") == iters
+                c = eng.submit(dec_item(model, 4, max_new=3, first=40))
+                await h.iterate(6)
+                assert (await c)["n_tokens"] == 3
+                assert h.count("gen_iterations_total") == iters + 2   # C's own two steps, no older one
+            else:
+                a2 = eng.submit(dec_item(model, 3, max_new=2, first=60))
+                await h.iterate(2)   # a2 done in out(3): its extract dispatched, unread
+                assert len(eng._extracts) == 1 and not a2.done()
+                await eng.stop()
+                for f in (a, b, a2):
+                    with pytest.raises(RuntimeError, match="shutting down"):
+                        await f
+                assert eng._ahead is None and eng._extracts == [] and not eng._finishing
+            # a pass that finds no lane dispatches nothing ahead; what was ahead is dropped
+            await h.iterate(2) if how != "stop" else None
+            assert eng._ahead is None and eng._extracts == [] and not eng._finishing
+            assert ledger(eng) == empty
+            return True
+
+    assert run(go())
+
+
+def test_drain_waits_for_the_answers_of_slots_that_are_free_already(dec_rt):
+    """`drain` returns once every accepted request is ANSWERED: a retired
+    slot is free a pass before its extract is read and its answer set."""
+    model, _ = dec_rt
+    eng, _ = make_engine(dec_rt, slots=6, **paged_over(kv_page_tokens=4, prefill_chunk=16))
+
+    async def go():
+        await eng.start()
+        futs = [eng.submit(dec_item(model, 4 + i, max_new=2 + 3 * (i % 4), first=1 + 9 * i))
+                for i in range(9)]
+        ok = await eng.drain(asyncio.get_running_loop().time() + 30)
+        state = (ok, [f.done() for f in futs], eng._ahead, list(eng._extracts),
+                 len(eng._finishing), ledger(eng)[:3])
+        await eng.stop()
+        return state
+
+    ok, done, ahead, extracts, finishing, led = run(go())
+    assert ok and all(done) and ahead is None and extracts == [] and finishing == 0
+    assert led == (0, 6, 0)

@@ -46,6 +46,16 @@ class SlotInfo:
     # clock; ISSUE 36), streamed or not; the stream keeps its own stamp of
     # the first unit it EMITTED.
     first_unit_at: float | None = None
+    # Which steps are this request's (ISSUE 41). The engine reads a step's
+    # out-block one pass after it dispatched the step, so an out-block can be
+    # OLDER than the slot's occupant: what it says of the lane is the previous
+    # occupant's. ``since_step`` is the number of the first step dispatched
+    # after the request took the slot (older out-blocks do not count among its
+    # iterations); ``armed_step`` the number of the first step dispatched after
+    # its lane was armed for decode (None while its prompt is in prefill):
+    # only an out-block of that step or a later one speaks of this request.
+    since_step: int = 0
+    armed_step: int | None = None
     meta: dict = field(default_factory=dict)
 
 

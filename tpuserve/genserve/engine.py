@@ -26,13 +26,31 @@ granularity over a fixed block of generative slots:
 The engine exposes the ModelBatcher surface (submit/start/stop/drain/
 revive_group_loops/pipeline_stats), so the existing front door — deadlines,
 breakers, result cache + coalescing, canaries, watchdog revival, graceful
-drain, the router tier — holds for multi-step requests unchanged. Blocking
-device work hops through the server's shared StageExecutors ("h2d" for
-inserts, "fetch" for step/extract readback, "postproc" for finalize), so
-generation shares the pipeline's stage-granularity scheduling and metrics.
+drain, the router tier — holds for multi-step requests unchanged. Device
+work hops through the server's shared StageExecutors ("h2d" for inserts and
+prefill launches, "fetch" for the step's dispatch and every readback,
+"postproc" for finalize), so generation shares the pipeline's
+stage-granularity scheduling and metrics.
 
-All engine state is event-loop-only (the step loop owns every mutation);
-there is deliberately no lock to witness.
+THE LOOP KEEPS ONE STEP QUEUED AHEAD (ISSUE 41). A pass of ``_step_loop``
+is: sweep (expire, evict), admit, launch the waiting prefill pieces, then
+DISPATCH step k and only then wait for the out-block of step k-1 (the one
+place a pass blocks on the device: the chip runs step k meanwhile), account,
+emit and retire from out(k-1). Retiring dispatches the finished slots'
+``extract`` and frees slot and pages at once; the extract's outputs are read
+in the NEXT pass's one wait and finalized in a task of their own. On the chip
+the order of programs is step, extract(s), prefill launch(es), step, ...; the
+next one is always already queued when one ends. What this asks of a family
+is in ``GenerativeModel.step``'s docstring (a lane whose out-block said
+``done`` is frozen), and what it costs: the loop learns of ``done`` one step
+late, so a finished lane rides one more step and its answer leaves up to one
+step after its last token. Because a slot can be re-armed in the pass its
+previous occupant retires, an out-block may be OLDER than a lane's arming:
+``SlotInfo.since_step`` / ``armed_step`` say which steps are a request's.
+
+All engine state is event-loop-only (the step loop owns every mutation; a
+stage thread touches ``_state`` only while the loop awaits it); there is
+deliberately no lock to witness.
 """
 
 from __future__ import annotations
@@ -57,7 +75,8 @@ from tpuserve.hostpipe import StageExecutors
 from tpuserve.obs import (GEN_STREAM_REASONS, PRIORITIES, Metrics, trace_call,
                           trace_mark, trace_span)
 from tpuserve.utils.locks import new_lock
-from tpuserve.utils.retrace import allow_transfers, host_fetch
+from tpuserve.utils.retrace import (allow_transfers, host_fetch,
+                                    host_fetch_together)
 
 log = logging.getLogger("tpuserve.genserve")
 
@@ -107,6 +126,30 @@ class _GenRequest:
     ctx: Any = None
     # Emission channel for a streamed request (ISSUE 17); None for unary.
     stream: "GenStream | None" = None
+
+
+@dataclass
+class _StepAhead:
+    """A step the device holds whose out-block the host has not read."""
+    out: Any        # the out-block, still on the device
+    seq: int        # the step's number (``SlotInfo.armed_step`` compares to it)
+    iter: int       # the pass that dispatched it: its spans' ``iter``
+    at: float       # when it was dispatched (the loop's ``step`` stamp)
+    ahead: bool     # dispatched while the step before it was still unread
+
+
+@dataclass
+class _Extract:
+    """A dispatched ``extract`` whose outputs the host has not read: a
+    retired slot's (``info`` is out of the arena already) or a stream's
+    preview (``info`` still rides). Read in the next pass's one wait."""
+    slot: int
+    info: SlotInfo
+    out: Any        # the outputs on the device; an Exception if the dispatch raised
+    t0: float       # when the dispatch began
+    iter: int
+    early: bool = False
+    preview: bool = False
 
 
 def _retrieve_exception(fut: asyncio.Future) -> None:
@@ -234,6 +277,10 @@ class GenEngine:
         # Hot-path metric handles, prebound once (the batcher discipline).
         self._c_iterations = metrics.counter(
             f"gen_iterations_total{{model={name}}}")
+        # Of those, the steps dispatched while the step before them was
+        # still unread (ISSUE 41): the step ahead engaged.
+        self._c_steps_ahead = metrics.counter(
+            f"gen_steps_ahead_total{{model={name}}}")
         self._c_admitted = metrics.counter(
             f"gen_admitted_total{{model={name}}}")
         self._c_fold_ins = metrics.counter(
@@ -354,6 +401,15 @@ class GenEngine:
         self._phase_t = 0.0
         self._iter = 0
         self._last_decode_at: float | None = None
+        # The step ahead (ISSUE 41): how many steps were dispatched (the next
+        # one's number), the one whose out-block is unread, when the last
+        # out-block reached the host, the extracts dispatched and unread, and
+        # the tasks that finalize and answer what was read.
+        self._n_steps = 0
+        self._ahead: _StepAhead | None = None
+        self._last_out_at = 0.0
+        self._extracts: list[_Extract] = []
+        self._finishing: dict[asyncio.Task, SlotInfo] = {}
 
     # -- compilation ----------------------------------------------------------
     def compile(self) -> None:
@@ -520,7 +576,18 @@ class GenEngine:
             self._terminate_stream(req.stream, "shutdown", str(err))
             if not req.future.done():
                 req.future.set_exception(err)
-        for info in self.arena.release_all():
+        # The step ahead's out-block is dropped unread; a retired request
+        # whose extract was not read, or whose answer a task was still
+        # finalizing, is failed like one that still held its slot.
+        self._ahead = None
+        finishing, self._finishing = self._finishing, {}
+        for t in finishing:
+            t.cancel()
+        await asyncio.gather(*finishing, return_exceptions=True)
+        retired = [x.info for x in self._extracts if not x.preview] \
+            + list(finishing.values())
+        self._extracts = []
+        for info in retired + self.arena.release_all():
             self._terminate_stream(info.stream, "shutdown", str(err))
             if not info.future.done():
                 info.future.set_exception(err)
@@ -561,12 +628,12 @@ class GenEngine:
         loop = asyncio.get_running_loop()
         self._stream_kill_at = time.perf_counter() + self.gcfg.stream_drain_s
         try:
-            while self._pending or self.arena.n_active:
+            while self._busy():
                 timeout = deadline - loop.time()
                 if timeout <= 0:
                     break
                 self._idle_event.clear()
-                if not self._pending and not self.arena.n_active:
+                if not self._busy():
                     break
                 try:
                     await asyncio.wait_for(self._idle_event.wait(), timeout)
@@ -575,7 +642,7 @@ class GenEngine:
         finally:
             self._stream_kill_at = None
         self._maybe_idle()
-        return not self._pending and not self.arena.n_active
+        return not self._busy()
 
     # -- submission (event loop) ----------------------------------------------
     def submit(self, item: Any, group: Any = None,
@@ -715,16 +782,21 @@ class GenEngine:
             except asyncio.TimeoutError:
                 continue
 
-    async def _emit_step_units(self, out: dict) -> None:
-        """Flush each streaming slot's newly produced units for this
-        iteration (the per-iteration flushing Orca's frame makes natural),
-        plus the family's optional preview extract — which reuses the
-        compiled extract program, so previews never add a compile."""
+    async def _emit_step_units(self, out: dict, seq: int) -> None:
+        """Flush each streaming slot's newly produced units for step ``seq``
+        (the per-iteration flushing Orca's frame makes natural), plus the
+        family's optional preview extract — which reuses the compiled extract
+        program, so previews never add a compile. A slot armed after step
+        ``seq`` was dispatched is passed over: what the out-block says of its
+        lane is its previous occupant's. A preview's extract is dispatched
+        here and read in the next pass's wait, like a retired slot's."""
         model = self.model
+        previews = []
         for slot in self.arena.active_slots():
             info = self.arena.peek(slot)
             stream = info.stream
-            if stream is None or stream.terminated or info.future.done():
+            if stream is None or stream.terminated or info.future.done() \
+                    or not self._rides(info, seq):
                 continue
             try:
                 units = model.stream_units(out, slot, stream.state)
@@ -744,22 +816,21 @@ class GenEngine:
                                   tid=self.name, slot=slot)
             for u in units:
                 await self._emit_unit(stream, u)
-            if model.stream_wants_preview(out, slot, stream.state):
-                try:
-                    extracted = await self.stages.run(
-                        self.name, "fetch", self._extract_sync, slot)
-                    u = model.stream_preview_unit(extracted, stream.state)
-                except asyncio.CancelledError:
-                    raise
-                except Exception:  # noqa: BLE001 — a preview is best-effort
-                    log.exception("preview extract failed for %s slot %d",
-                                  self.name, slot)
-                else:
-                    await self._emit_unit(stream, u)
+            if "preview_unread" not in info.meta \
+                    and model.stream_wants_preview(out, slot, stream.state):
+                previews.append(slot)
+        for x in await self._dispatch_extracts(previews):
+            x.preview = x.info.meta["preview_unread"] = True
+
+    def _busy(self) -> bool:
+        """Is any accepted request unanswered: queued, holding a slot, or
+        retired with its extract unread or its answer still being finalized
+        (the slot of such a request is free already)?"""
+        return bool(self._pending or self.arena.n_active or self._extracts
+                    or self._finishing)
 
     def _maybe_idle(self) -> None:
-        if self._idle_event is not None and not self._pending \
-                and not self.arena.n_active:
+        if self._idle_event is not None and not self._busy():
             self._idle_event.set()
 
     # -- gauge publication (event loop) ---------------------------------------
@@ -856,7 +927,6 @@ class GenEngine:
         return now
 
     async def _step_loop(self) -> None:
-        name = self.name
         self._phase, self._phase_t = "sweep", time.perf_counter()
         # The loop condition (not just task cancellation) gates each
         # iteration: asyncio.wait_for can swallow a cancel that lands the
@@ -865,86 +935,144 @@ class GenEngine:
         # forever. _running goes False before stop() cancels, so either
         # path exits.
         while self._running:
-            self._stamp("sweep")
-            self._iter += 1
-            if self.injector is not None:
-                # Chaos: an escaped exception kills this task — exactly the
-                # failure revive_group_loops exists to repair.
-                self.injector.check("kill_group_loop", name)
-            self._expire_pending()
-            self._evict_expired()
-            if not self.arena.n_active and not self._pending:
-                self._stamp("wait")
-                self._last_decode_at = None  # no token gap across a wait
-                self._maybe_idle()
-                self._work_event.clear()
-                if not self._pending and not self.arena.n_active:
-                    await self._work_event.wait()
+            if await self._pass():
                 continue
+            self._stamp("wait")
+            self._last_decode_at = None  # no token gap across a wait
+            self._maybe_idle()
+            self._work_event.clear()
+            if not self._pending and not self.arena.n_active:
+                await self._work_event.wait()
+
+    async def _pass(self) -> bool:
+        """One pass of the loop; False when it found nothing to do and
+        nothing of the device's unread (the loop then waits for work).
+
+        The pass blocks on the device in ONE place: ``_step_sync`` dispatches
+        step k and then waits for the out-block of step k-1, which the chip
+        finished (or is finishing) while the host did the rest of the last
+        pass. Everything else a pass does for the device is a dispatch: the
+        prefill launches and a retired slot's extract queue behind the step
+        that runs."""
+        name = self.name
+        self._stamp("sweep")
+        self._iter += 1
+        if self.injector is not None:
+            # Chaos: an escaped exception kills this task — exactly the
+            # failure revive_group_loops exists to repair.
+            self.injector.check("kill_group_loop", name)
+        self._expire_pending()
+        self._evict_expired()
+        if self.arena.n_active or self._pending:
             self._stamp("admit")
             await self._admit()
             self._stamp("prefill")
             await self._advance_prefills()
-            if not self.arena.n_active:
-                continue
-            try:
-                t0 = self._stamp("step")
-                if self.injector is not None:
-                    delay = self.injector.delay_s("slow_dispatch", name)
-                    if delay > 0:
-                        await asyncio.sleep(delay)
-                        t0 = self._stamp("step")  # the phase's, not the step's
-                    self.injector.check("batch_error", name)
-                out = await self.stages.run(name, "fetch", self._step_sync)
-                t1 = self._stamp("account")
-                step_s = t1 - t0
-                # Step events per traced slot (ISSUE 12): every mid-flight
-                # request's tree shows each iteration it rode, tagged with
-                # its slot — bounded by the model's own step cap, and what
-                # makes "why was THIS generation slow" answerable span by
-                # span. The histogram exemplar samples one rider.
-                wall = time.time()
-                ex_tid = None
-                for s in self.arena.active_slots():
-                    info = self.arena.peek(s)
-                    if info.ctx is not None:
-                        if ex_tid is None:
-                            ex_tid = info.ctx.trace_id
-                        info.ctx.span("gen_step", wall - step_s, wall,
-                                      tid=name, slot=s,
-                                      iteration=info.iterations)
-                self._h_step.observe(step_s * 1e3, trace_id=ex_tid)
-                self._observe_step(step_s * 1e3)
-                self._c_device_seconds.inc(step_s)
-                if self.device_time_cb is not None:
-                    self.device_time_cb(step_s)
-                self._c_iterations.inc()
-                self._c_replica_steps.inc()
-                self._count_step(out, t1)
-            except asyncio.CancelledError:
-                raise
-            except Exception as e:  # noqa: BLE001 — contained per batch
-                await self._fail_active(e)
-                continue
-            self._stamp("emit")
-            await self._emit_step_units(out)
-            self._stamp("retire")
-            await self._retire(out)
+        elif self._ahead is None and not self._extracts:
+            return False
+        try:
+            t0 = self._stamp("step")
+            if self.injector is not None and self.arena.n_active:
+                delay = self.injector.delay_s("slow_dispatch", name)
+                if delay > 0:
+                    await asyncio.sleep(delay)
+                    t0 = self._stamp("step")  # the phase's, not the step's
+                self.injector.check("batch_error", name)
+            # A pass that finds no lane to step dispatches nothing ahead,
+            # and the out-block of a step that no request rides any more
+            # (every slot went while it was queued) is dropped unread: it
+            # counts among neither the iterations nor the steps ahead.
+            go = self.arena.n_active > 0
+            prev = self._ahead if go else None
+            self._ahead = None
+            if not go and not self._extracts:
+                return True
+            queued, out, fetched = await self.stages.run(
+                name, "fetch", self._step_sync, go, prev,
+                list(self._extracts))
+            del self._extracts[:len(fetched)]  # read: no longer the device's
+            if go:
+                self._ahead = _StepAhead(queued, self._n_steps, self._iter,
+                                         t0, prev is not None)
+                self._n_steps += 1
+            t1 = self._stamp("account")
+            for x, got in fetched:
+                self._h_extract.observe((t1 - x.t0) * 1e3)
+                if x.preview:
+                    await self._emit_preview(x, got)
+                else:
+                    self._track(self._finish(x, got), x.info)
+            if prev is None:
+                return True
+            # The host's step time (``gen_step_ms``, ``device_seconds_total``,
+            # ``predicted_service_s``): from one out-block's arrival to the
+            # next, or from the step's dispatch where nothing was ahead of
+            # it. Whatever the chip ran between two steps (a retired slot's
+            # extract, the prefill launches) is inside it, as it was inside
+            # the blocking loop's dispatch-to-fetch.
+            step_s = t1 - max(prev.at, self._last_out_at)
+            self._last_out_at = t1
+            # Step events per traced slot (ISSUE 12): every mid-flight
+            # request's tree shows each iteration it rode, tagged with
+            # its slot — bounded by the model's own step cap, and what
+            # makes "why was THIS generation slow" answerable span by
+            # span. The histogram exemplar samples one rider.
+            wall = time.time()
+            ex_tid = None
+            for s in self.arena.active_slots():
+                info = self.arena.peek(s)
+                if info.ctx is not None and prev.seq >= info.since_step:
+                    if ex_tid is None:
+                        ex_tid = info.ctx.trace_id
+                    info.ctx.span("gen_step", wall - step_s, wall,
+                                  tid=name, slot=s,
+                                  iteration=info.iterations)
+            self._h_step.observe(step_s * 1e3, trace_id=ex_tid)
+            self._observe_step(step_s * 1e3)
+            self._c_device_seconds.inc(step_s)
+            if self.device_time_cb is not None:
+                self.device_time_cb(step_s)
+            self._c_iterations.inc()
+            if prev.ahead:
+                self._c_steps_ahead.inc()
+            self._c_replica_steps.inc()
+            self._count_step(out, prev.seq, t1)
+        except asyncio.CancelledError:
+            raise
+        except Exception as e:  # noqa: BLE001 — contained per batch
+            await self._fail_active(e)
+            return True
+        self._stamp("emit")
+        await self._emit_step_units(out, prev.seq)
+        self._stamp("retire")
+        await self._retire(out, prev.seq)
+        return True
 
-    def _count_step(self, out: dict, at: float) -> None:
-        """One step's part of the per-step sums: the lanes that decoded a
-        token in it, the pages and rings reserved while it ran, and whatever
-        the family sums on the device (``observe_step``). ``at`` is when the
-        step's out-block reached the host (the ``step`` phase's end): a lane
-        of a paged family that decodes for the first time has its first
-        token there (``gen_first_unit_ms``, streamed or not: a streamed
-        request is observed where its unit is emitted), and two such
-        moments in a row are one token gap (``gen_token_gap_ms``)."""
+    @staticmethod
+    def _rides(info: SlotInfo, seq: int) -> bool:
+        """Does step ``seq``'s out-block speak of THIS request's lane? Not
+        while its prompt is in prefill (the lane's device done-flag is its
+        freeze, not completion), and not when the step was dispatched before
+        the lane was armed: the loop reads out(k) a pass after it dispatched
+        step k, and a slot released in that pass and armed again in it shows
+        in out(k) as its previous occupant left it (``done`` included)."""
+        return info.armed_step is not None and seq >= info.armed_step
+
+    def _count_step(self, out: dict, seq: int, at: float) -> None:
+        """Step ``seq``'s part of the per-step sums: the lanes that decoded a
+        token in it, the pages and rings reserved when its out-block was
+        read, and whatever the family sums on the device (``observe_step``).
+        ``at`` is when the step's out-block reached the host (the ``step``
+        phase's end): a lane of a paged family that decodes for the first time
+        has its first token there (``gen_first_unit_ms``, streamed or not: a
+        streamed request is observed where its unit is emitted), and two such
+        moments in a row are one token gap (``gen_token_gap_ms``). A lane
+        armed after the step was dispatched did not decode in it."""
         self.model.observe_step(out)
         decoded = 0
         for s in self.arena.active_slots():
             info = self.arena.peek(s)
-            if "prefill_next" in info.meta:
+            if not self._rides(info, seq):
                 continue
             decoded += 1
             if info.first_unit_at is None:
@@ -971,17 +1099,51 @@ class GenEngine:
         lock = self._dispatch_lock
         return lock if lock is not None else nullcontext()
 
-    def _step_sync(self) -> dict:
-        """One compiled iteration over the slot block + the small host
-        fetch of the out pytree. Runs on the fetch stage executor."""
-        with self._dispatch_guard():
-            with trace_span("tpuserve.gen_step", model=self.name,
-                            lanes=self.arena.n_active, iter=self._iter):
-                self._state, out = self.runtime.run_program(
-                    "step", self._state, replica=self.replica)
+    def _dispatched(self, outputs: Any) -> Any:
+        """The end of a dispatch section. Where the group's CPU-backend lock
+        is installed the section lasts until the program has run, as it did
+        when every dispatch was followed by its fetch: forced host devices
+        share the host's cores, and programs of several replicas in flight at
+        once spin-wait against each other. On an accelerator (no lock) this
+        is nothing, and the program runs on while the host goes on."""
+        if self._dispatch_lock is not None:
+            jax.block_until_ready(outputs)
+        return outputs
+
+    def _step_sync(self, go: bool, prev: "_StepAhead | None",
+                   extracts: "list[_Extract]") -> tuple:
+        """The pass's one wait, on the fetch stage executor: dispatch the
+        next step if there is a lane to step (``go``; the call returns once
+        the chip has it queued), THEN block until the out-block of the step
+        before it is on the host, and the outputs of the extracts dispatched
+        in the last pass, which the chip ran right behind that step. Returns
+        (the new step's out-block on the device or None, the older one's on
+        the host or None, [(extract, outputs or the exception its read
+        raised)]).
+        ``tpuserve.gen_fetch`` carries the ``iter`` of the step whose
+        out-block it waits for, not the pass's."""
+        queued = out = None
+        if go:
+            with self._dispatch_guard():
+                with trace_span("tpuserve.gen_step", model=self.name,
+                                lanes=self.arena.n_active, iter=self._iter):
+                    self._state, queued = self._dispatched(
+                        self.runtime.run_program("step", self._state,
+                                                 replica=self.replica))
+        if prev is not None:
             with trace_span("tpuserve.gen_fetch", model=self.name,
-                            iter=self._iter):
-                return host_fetch(out)
+                            iter=prev.iter):
+                out = host_fetch_together(prev.out)
+        fetched = []
+        for x in extracts:
+            got = x.out
+            if not isinstance(got, Exception):
+                try:
+                    got = host_fetch_together(got)
+                except Exception as e:  # noqa: BLE001 — contained to this slot
+                    got = e
+            fetched.append((x, got))
+        return queued, out, fetched
 
     def _insert_sync(self, slot: int, item: Any) -> None:
         with self._dispatch_guard():
@@ -1079,18 +1241,145 @@ class GenEngine:
                 m["prefill_held"] = 0
                 m["prefill_next"] = p.start + p.length
                 if m["prefill_next"] >= m["prefill_n"]:
-                    # The program armed the lane: decode owns it now.
+                    # The program armed the lane: decode owns it from the
+                    # next step dispatched on (an out-block of an earlier
+                    # step, still unread, is not this request's).
                     del m["prefill_next"]
                     self._prefilling.remove(p.slot)
+                    self.arena.peek(p.slot).armed_step = self._n_steps
 
-    def _extract_sync(self, slot: int) -> Any:
-        with self._dispatch_guard(), trace_span(
-                "tpuserve.gen_extract", model=self.name, slot=slot,
-                iter=self._iter):
-            return host_fetch(
-                self.runtime.run_program("extract", self._state,
-                                         np.int32(slot),
-                                         replica=self.replica))
+    def _extract_dispatch_sync(self, slots: "list[int]") -> list:
+        """Dispatch ``extract`` for each slot, in order, and wait for none:
+        the programs queue behind the step the chip is running, and read the
+        state block as that step leaves it (a finished lane is frozen, so
+        the rows are the ones its ``done`` was said of). One slot's dispatch
+        that raises is that slot's failure: its place holds the exception."""
+        outs = []
+        for slot in slots:
+            try:
+                with self._dispatch_guard(), trace_span(
+                        "tpuserve.gen_extract", model=self.name, slot=slot,
+                        iter=self._iter):
+                    outs.append(self._dispatched(self.runtime.run_program(
+                        "extract", self._state, np.int32(slot),
+                        replica=self.replica)))
+            except Exception as e:  # noqa: BLE001 — contained to this slot
+                outs.append(e)
+        return outs
+
+    async def _dispatch_extracts(self, slots: "list[int]") -> "list[_Extract]":
+        """Queue the slots' extracts on the device (one hop to the fetch
+        stage for all of them) and note them unread: the next pass's one
+        wait reads them behind its out-block."""
+        if not slots:
+            return []
+        t0 = self._stamp(self._phase)
+        outs = await self.stages.run(self.name, "fetch",
+                                     self._extract_dispatch_sync, slots)
+        new = [_Extract(slot, self.arena.peek(slot), out, t0, self._iter)
+               for slot, out in zip(slots, outs)]
+        self._extracts += new
+        return new
+
+    def _track(self, coro: Any, info: SlotInfo) -> None:
+        """Run ``coro``, which answers ``info``'s request, as a task off the
+        loop's path; ``drain`` and ``_maybe_idle`` count it until it ends,
+        and ``stop`` answers for it if it has not."""
+        task = asyncio.get_running_loop().create_task(coro)
+        self._finishing[task] = info
+
+        def done(t: asyncio.Task) -> None:
+            self._finishing.pop(t, None)
+            if not t.cancelled() and t.exception() is not None:
+                log.error("finishing a retired slot of %s failed: %r",
+                          self.name, t.exception())
+            self._maybe_idle()
+        task.add_done_callback(done)
+
+    async def _emit_preview(self, x: _Extract, extracted: Any) -> None:
+        """A stream's preview unit from its extract's outputs, read a pass
+        after they were asked for. Best-effort and droppable."""
+        info = x.info
+        info.meta.pop("preview_unread", None)
+        stream = info.stream
+        if stream is None or stream.terminated:
+            return
+        if isinstance(extracted, Exception):
+            log.error("preview extract failed for %s slot %d: %r",
+                      self.name, x.slot, extracted)
+            return
+        try:
+            u = self.model.stream_preview_unit(extracted, stream.state)
+        except Exception:  # noqa: BLE001 — a preview is best-effort
+            log.exception("preview unit failed for %s slot %d",
+                          self.name, x.slot)
+        else:
+            await self._emit_unit(stream, u)
+
+    async def _finish(self, x: _Extract, extracted: Any) -> None:
+        """The rest of a retirement, OFF the loop's path (a task a slot):
+        ``finalize`` on the postproc stage, the stream's terminal burst, the
+        future's result and the request's counters. The slot and its pages
+        went back when the extract was dispatched; ``extracted`` is what the
+        next pass's wait read of it, or the exception that raised."""
+        info, slot = x.info, x.slot
+        trace_id = info.ctx.trace_id if info.ctx is not None else None
+        try:
+            if isinstance(extracted, Exception):
+                raise extracted
+            result = await self.stages.run(
+                self.name, "postproc", trace_call, "tpuserve.gen_finalize",
+                {"model": self.name, "slot": slot, "iter": x.iter},
+                self.model.finalize, extracted, info.item)
+            if info.stream is not None and not info.stream.terminated:
+                # Terminal burst: the family's final units (sd15's
+                # image, then done with finish reason + usage). The
+                # done unit goes through _terminate_stream so its
+                # delivery is unconditional and the per-reason
+                # counter sees a "done".
+                finals = self.model.stream_final_units(extracted, result)
+                for u in finals[:-1]:
+                    await self._emit_unit(info.stream, u)
+                self._terminate_stream(
+                    info.stream, "done",
+                    unit=finals[-1] if finals else {"type": "done"})
+        except asyncio.CancelledError:  # stop(), which answers for this one
+            raise
+        except Exception as e:  # noqa: BLE001 — contained to this slot
+            log.error("retire failed for %s slot %d: %r", self.name, slot, e)
+            self._c_batch_errors.inc()
+            if self.breaker is not None:
+                self.breaker.record_failure()
+            self._terminate_stream(info.stream, "engine_error", str(e))
+            if not info.future.done():
+                info.future.set_exception(e)
+            return
+        if not info.future.done():
+            info.future.set_result(result)
+        self._c_items.inc()
+        units = self.model.result_units(result)
+        self._c_units.inc(units)
+        self._c_replica_units.inc(units)
+        self._observe_retire(info.iterations)
+        if x.early:
+            self._c_early_exits.inc()
+        if self.breaker is not None:
+            self.breaker.record_success()
+        t1 = time.perf_counter()
+        wall1 = time.time()
+        trace_mark("tpuserve.gen_retire", x.t0, t1, model=self.name,
+                   slot=slot)
+        if info.ctx is not None:
+            # Retire event: extract + finalize for this slot, the
+            # tail of the request's step-span stack.
+            info.ctx.span("retire", wall1 - (t1 - x.t0), wall1,
+                          tid=self.name, slot=slot,
+                          iterations=info.iterations)
+        self.metrics.tracer.add(
+            f"gen[{info.iterations}it]",
+            wall1 - (t1 - info.enqueued_at), wall1,
+            tid=self.name, trace_id=trace_id, slot=slot,
+            iterations=info.iterations)
 
     # -- scheduling passes ----------------------------------------------------
     def _expire_pending(self) -> None:
@@ -1136,7 +1425,11 @@ class GenEngine:
         freed slot's device lanes hold stale state until the next insert
         overwrites them; their own done-flag freezes them within the
         model's step bound, so the garbage compute is bounded and the
-        ledger stays exact."""
+        ledger stays exact. A step that still holds the lane live may be
+        queued on the chip when the slot goes (the step ahead, ISSUE 41):
+        its out-block is read for the lanes that remain, and a request that
+        takes the slot meanwhile is passed over in it (``_rides``: the step
+        was dispatched before that request's arming)."""
         now = time.perf_counter()
         kill_at = self._stream_kill_at
         for slot in self.arena.active_slots():
@@ -1213,7 +1506,8 @@ class GenEngine:
             info = SlotInfo(item=req.item, future=req.future,
                             deadline_at=req.deadline_at,
                             enqueued_at=req.enqueued_at, admitted_at=now,
-                            ctx=req.ctx, stream=req.stream)
+                            ctx=req.ctx, stream=req.stream,
+                            since_step=self._n_steps)
             slot = self.arena.acquire(info)
             t0 = now
             try:
@@ -1260,6 +1554,9 @@ class GenEngine:
                 else:
                     await self.stages.run(self.name, "h2d",
                                           self._insert_sync, slot, req.item)
+                    # The insert armed the lane: the next step dispatched
+                    # is the first that is this request's.
+                    info.armed_step = self._n_steps
             except asyncio.CancelledError:
                 raise
             except Exception as e:  # noqa: BLE001
@@ -1291,14 +1588,22 @@ class GenEngine:
                 self._c_fold_ins.inc()
         self._publish_active()
 
-    async def _retire(self, out: dict) -> None:
-        """Account the iteration and retire every finished slot
-        immediately — a short sequence exits the instant its own work is
-        done, regardless of what the rest of the block still owes."""
-        for slot in self.arena.active_slots():
-            self.arena.peek(slot).iterations += 1
+    async def _retire(self, out: dict, seq: int) -> None:
+        """Account step ``seq``'s iteration and retire every slot its
+        out-block reports finished — a short sequence exits as soon as the
+        host learns its own work is done, regardless of what the rest of the
+        block still owes. Retiring awaits no device read: the finished slots'
+        extracts are DISPATCHED (they queue behind the step ahead, in which
+        the finished lane rode frozen) and slot and pages go back at once, so
+        the pass's admit can hand them on; the device's own order puts the
+        extract's read of the slot's rows before a new occupant's prefill
+        writes them. The outputs are read in the next pass's wait and
+        answered by ``_finish``."""
+        done: list[int] = []
         for slot in self.arena.active_slots():
             info = self.arena.peek(slot)
+            if seq >= info.since_step:  # an older step is not this request's
+                info.iterations += 1
             if info.future.done():
                 if info.stream is not None:
                     self._c_disconnects.inc()
@@ -1318,74 +1623,13 @@ class GenEngine:
                 self._c_batch_errors.inc()
                 self._release_slot(slot)
                 continue
-            if "prefill_next" in info.meta:
-                # Mid-prefill: the lane's device done-flag is its FREEZE
-                # (interleaved decode steps skip it), not completion.
-                continue
-            if not self.model.is_finished(out, slot):
-                continue
-            early = self.arena.n_active > 1 or bool(self._pending)
-            trace_id = info.ctx.trace_id if info.ctx is not None else None
-            t0 = self._stamp("retire")
-            try:
-                extracted = await self.stages.run(
-                    self.name, "fetch", self._extract_sync, slot)
-                self._h_extract.observe((self._stamp("retire") - t0) * 1e3,
-                                        trace_id=trace_id)
-                result = await self.stages.run(
-                    self.name, "postproc", trace_call, "tpuserve.gen_finalize",
-                    {"model": self.name, "slot": slot, "iter": self._iter},
-                    self.model.finalize, extracted, info.item)
-            except asyncio.CancelledError:
-                raise
-            except Exception as e:  # noqa: BLE001 — contained to this slot
-                log.exception("retire failed for %s slot %d", self.name, slot)
-                self._c_batch_errors.inc()
-                if self.breaker is not None:
-                    self.breaker.record_failure()
-                self._terminate_stream(info.stream, "engine_error", str(e))
-                if not info.future.done():
-                    info.future.set_exception(e)
-            else:
-                if info.stream is not None and not info.stream.terminated:
-                    # Terminal burst: the family's final units (sd15's
-                    # image, then done with finish reason + usage). The
-                    # done unit goes through _terminate_stream so its
-                    # delivery is unconditional and the per-reason
-                    # counter sees a "done".
-                    finals = self.model.stream_final_units(extracted, result)
-                    for u in finals[:-1]:
-                        await self._emit_unit(info.stream, u)
-                    self._terminate_stream(
-                        info.stream, "done",
-                        unit=finals[-1] if finals else {"type": "done"})
-                if not info.future.done():
-                    info.future.set_result(result)
-                self._c_items.inc()
-                units = self.model.result_units(result)
-                self._c_units.inc(units)
-                self._c_replica_units.inc(units)
-                self._observe_retire(info.iterations)
-                if early:
-                    self._c_early_exits.inc()
-                if self.breaker is not None:
-                    self.breaker.record_success()
-                t1 = self._stamp("retire")
-                wall1 = time.time()
-                trace_mark("tpuserve.gen_retire", t0, t1, model=self.name,
-                           slot=slot)
-                if info.ctx is not None:
-                    # Retire event: extract + finalize for this slot, the
-                    # tail of the request's step-span stack.
-                    info.ctx.span("retire", wall1 - (t1 - t0), wall1,
-                                  tid=self.name, slot=slot,
-                                  iterations=info.iterations)
-                self.metrics.tracer.add(
-                    f"gen[{info.iterations}it]",
-                    wall1 - (t1 - info.enqueued_at), wall1,
-                    tid=self.name, trace_id=trace_id, slot=slot,
-                    iterations=info.iterations)
-            self._release_slot(slot)
+            if self._rides(info, seq) and self.model.is_finished(out, slot):
+                done.append(slot)
+        left = self.arena.n_active
+        for i, x in enumerate(await self._dispatch_extracts(done)):
+            # An early exit leaves others at work: all but the block's last.
+            x.early = left - i > 1 or bool(self._pending)
+            self._release_slot(x.slot)
         self._publish_active()
         self._maybe_idle()
 
@@ -1412,6 +1656,14 @@ class GenEngine:
             self.pages.release_all()
             self._update_kv_gauges()
         self._state = self._host_zeros(self._state_struct)
+        # The step ahead was a step of the failed block: its out-block is
+        # dropped, never read beside the new one (nor its ``acc`` into
+        # ``observe_step``). An extract dispatched before the failure keeps
+        # its place: its outputs are its own buffers, and if the failure
+        # reached them its read raises for that request alone. A preview's
+        # slot is gone with the rest.
+        self._ahead = None
+        self._extracts = [x for x in self._extracts if not x.preview]
         self._last_decode_at = None  # no lane is left to feel a gap
         self._publish_active()
         self._maybe_idle()

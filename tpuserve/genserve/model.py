@@ -94,7 +94,19 @@ class GenerativeModel(ServingModel):
         """Jittable: one iteration over all slots -> (new_state, out).
         ``out`` is the small per-step host fetch and must contain
         ``"done"``: (slots,) bool — True once a slot's sequence finished.
-        Free slots hold zeros; the step must be NaN-safe on them."""
+        Free slots hold zeros; the step must be NaN-safe on them.
+
+        A FINISHED LANE IS FROZEN (ISSUE 41): a lane whose out-block said
+        ``done`` is not changed by any later ``step`` — every leaf of the
+        state block keeps that lane's rows bit for bit, and the lane writes
+        no page or ring of its own (a paged family sends its write to the
+        sentinel, page 0) — until an insert or a prefill launch gives the
+        lane to another request. The engine keeps one step queued ahead of
+        the one it reads, so it learns of ``done`` one step late: the lane
+        rides that step, ``extract`` reads its rows AFTER it, and the slot's
+        pages may already be another request's. ``step`` takes the donated
+        state block and nothing from the host, so nothing else is asked.
+        ``tests/test_genserve.py`` holds every registered family to this."""
 
     @abc.abstractmethod
     def extract(self, params: Any, state: Any, slot: Any) -> Any:

@@ -367,8 +367,13 @@ class TextGenServing(GenerativeModel):
             q = (hx @ lp["wq"].astype(dt)).reshape(b, h, hd)
             k = (hx @ lp["wk"].astype(dt)).reshape(b, h, hd)
             v = (hx @ lp["wv"].astype(dt)).reshape(b, h, hd)
-            kc = kc.at[rows, i, jnp.clip(pos, 0, c - 1)].set(k)
-            vc = vc.at[rows, i, jnp.clip(pos, 0, c - 1)].set(v)
+            # A finished lane writes nothing (GenerativeModel.step: frozen
+            # bit for bit; the step after its last would else fill the
+            # cache row of the position behind its final token).
+            at = jnp.clip(pos, 0, c - 1)
+            keep = state["done"][:, None, None]
+            kc = kc.at[rows, i, at].set(jnp.where(keep, kc[rows, i, at], k))
+            vc = vc.at[rows, i, at].set(jnp.where(keep, vc[rows, i, at], v))
             s = (jnp.einsum("bhd,bchd->bhc", q, kc[:, i])
                  .astype(jnp.float32) * (hd ** -0.5)) + mask[:, None, :]
             a = jax.nn.softmax(s, axis=-1).astype(dt)

@@ -52,3 +52,12 @@ def host_fetch(tree: Any) -> Any:
     transfers nobody meant to make."""
     with jax.transfer_guard_device_to_host("allow"):
         return jax.tree_util.tree_map(np.asarray, tree)
+
+
+def host_fetch_together(tree: Any) -> Any:
+    """``host_fetch`` as ONE ``jax.device_get``: every leaf's copy to the
+    host is started before the first is awaited, so the wait is the slowest
+    leaf's and not their sum (the generation engine's out-block of five small
+    arrays, ISSUE 41). Leaves already on the host pass through."""
+    with jax.transfer_guard_device_to_host("allow"):
+        return jax.device_get(tree)
