@@ -732,7 +732,8 @@ def _frozen_case(name, tmp_path):
     from tpuserve.config import ParallelConfig
 
     ids = lambda model, n, max_new, first=1: model.host_decode(json.dumps(  # noqa: E731
-        {"prompt_ids": list(range(first, first + n)), "max_new_tokens": max_new}).encode(),
+        {"prompt_ids": list(range(model.v_first + first, model.v_first + first + n)),
+         "max_new_tokens": max_new}).encode(),
         "application/json")
     if name.startswith("textgen"):
         sharded = "sharded" in name
@@ -748,7 +749,8 @@ def _frozen_case(name, tmp_path):
             json.dumps({"prompt": p, "seed": s}).encode(), "application/json")
         return model, {}, {}, item("a fox", 1), item("a hen", 2)
     maker = {"decoder": "tests.test_decoder", "hybrid": "tests.test_hybrid",
-             "hybrid_ffn": "tests.test_hybrid_ffn", "mla": "tests.test_mla"}[name]
+             "hybrid_ffn": "tests.test_hybrid_ffn", "mla": "tests.test_mla",
+             "mla_sc": "tests.test_mla_sc"}[name]
     import importlib
     model = importlib.import_module(maker).make_model(str(tmp_path), name="fz")
     return (model, {}, dict(kv_paging=True, kv_page_tokens=4, prefill_chunk=8),
@@ -756,7 +758,7 @@ def _frozen_case(name, tmp_path):
 
 
 FROZEN_CASES = ["textgen-dense", "textgen-paged", "textgen-sharded-dense", "textgen-sharded-paged",
-                "sd15", "decoder", "hybrid", "hybrid_ffn", "mla"]
+                "sd15", "decoder", "hybrid", "hybrid_ffn", "mla", "mla_sc"]
 
 
 def test_the_frozen_lane_cases_name_every_registered_generating_family():

@@ -167,7 +167,7 @@ COMPACT_CASES = [
 ]
 
 
-def _both_branches(monkeypatch, layer, first, fn, of):
+def _both_branches(monkeypatch, layer, first, fn, of, n_real=None):
     """ONE compiled program of the layer, run twice: its ``cond`` taking the
     branch the test names (the layer's own predicate is computed and reported
     as ever). What serving needs: which branch a launch takes depends on who
@@ -186,7 +186,8 @@ def _both_branches(monkeypatch, layer, first, fn, of):
 
         monkeypatch.setattr(jax.lax, "cond", cond)
         try:
-            return moe.held_experts(x, w, e, first, w_in, w_out, fn, live=live, of=of)
+            return moe.held_experts(x, w, e, first, w_in, w_out, fn, live=live, of=of,
+                                    real=n_real)
         finally:
             monkeypatch.setattr(jax.lax, "cond", real)
 
@@ -277,7 +278,10 @@ def test_where_nothing_can_be_left_behind_the_program_has_no_cond(first, count, 
     (22528, 128, 512, 7168), (5632, 128, 512, 1792),       # Nemotron's launch and step
     (10240, 128, 256, 6400), (1280, 128, 256, 896),        # Laguna's
     (16384, 256, 256, 16384), (128, 256, 256, 128),        # JoyAI's: every expert held
-    (176, 128, 512, 128), (40, 2, 8, 40), (4096 * 4, 1, 4, 5120)])
+    (176, 128, 512, 128), (40, 2, 8, 40), (4096 * 4, 1, 4, 5120),
+    # 16 held of a router 768 wide (512 real experts, then 256 zero-compute outputs), 12 picks:
+    # a step of 256 lanes and a prefill launch of 1,024 rows (ISSUE 42)
+    (3072, 16, 768, 128), (12288, 16, 768, 384)])
 def test_the_row_bound_is_the_expected_held_picks_with_slack_in_whole_row_tiles(picks, count, of,
                                                                                rows):
     from tpuserve.ops.moe import COMPACT_SLACK, _row_bound, _row_tile
@@ -286,3 +290,94 @@ def test_the_row_bound_is_the_expected_held_picks_with_slack_in_whole_row_tiles(
     if rows < picks:
         assert rows % _row_tile(rows) == 0 and rows >= picks * count / of * COMPACT_SLACK
         assert rows - _row_tile(rows) < picks * count / of * COMPACT_SLACK
+
+
+# -- zero-compute picks (ISSUE 42) ------------------------------------------------------------------
+
+# (first, count, real, zero, k, t, masked, dtype): the router is real + zero wide; the first case's
+# row bound is under t * k (two branches), the second holds every real expert (no cond), the last
+# has more zero-compute outputs than real ones.
+ZERO_CASES = [
+    (4, 4, 16, 8, 4, 256, True, "float32"),
+    (0, 16, 16, 8, 4, 64, False, "float32"),
+    (2, 2, 8, 4, 3, 40, True, "bfloat16"),
+    (0, 4, 8, 24, 6, 128, True, "float32"),
+]
+
+
+@pytest.mark.parametrize("first,count,real,zero,k,t,masked,dtype", ZERO_CASES)
+def test_zero_compute_picks_add_their_weight_times_the_token_and_are_neither_held_nor_absent(
+        first, count, real, zero, k, t, masked, dtype, monkeypatch):
+    from tpuserve.ops import moe
+
+    of = real + zero
+    x, w, e, w_in, w_out, live = _layer(13, first, count, of, k, t, dtype, "swiglu", masked)
+    got, st = jax.jit(lambda *a: moe.held_experts(
+        *a, first, w_in, w_out, moe.swiglu, live=live, of=of, real=real))(x, w, e)
+    plain, st0 = jax.jit(lambda *a: moe.held_experts(
+        *a, first, w_in, w_out, moe.swiglu, live=live, of=of))(x, w, e)
+    # the held experts' part is the program's that was told of no zero-compute width (to a float32
+    # multiply-add's rounding: the compiler may fuse the term's product and its sum) ...
+    alive = np.ones((t,), bool) if live is None else np.asarray(live)
+    is_zero = (np.asarray(e) >= real) & alive[:, None]
+    w_zero = np.sum(np.where(is_zero, np.asarray(w), 0.0), axis=1, dtype=np.float32)
+    want = np.asarray(plain) + w_zero[:, None] * np.asarray(x, np.float32)
+    tol = 1e-6 if dtype == "float32" else 1e-2
+    np.testing.assert_allclose(np.asarray(got), want, rtol=tol, atol=tol)
+    # ... and a zero-compute pick is counted once, as its own kind
+    n_zero = int(is_zero.sum())
+    assert 0 < n_zero == int(st["routed_zero"]) and "routed_zero" not in st0
+    assert int(st["routed_held"]) == int(st0["routed_held"])
+    assert int(st["routed_absent"]) == int(st0["routed_absent"]) - n_zero
+    assert int(st["routed_held"]) + int(st["routed_absent"]) + n_zero == k * int(alive.sum())
+    assert int(st["experts_hit"]) == int(st0["experts_hit"])
+    assert int(st["compact"]) == int(st0["compact"]) == int(moe._row_bound(t * k, count, of) < t * k)
+    if int(st["compact"]):
+        (a, _), (b, _) = _both_branches(monkeypatch, (x, w, e, w_in, w_out, live), first,
+                                        moe.swiglu, of, n_real=real)
+        assert np.array_equal(_bits(a), _bits(b)) and np.array_equal(_bits(a), _bits(got))
+
+
+@pytest.mark.parametrize("real", [None, 16])
+def test_without_a_zero_compute_output_the_layer_is_the_one_it_always_was(real):
+    """A caller that passes no zero-compute width traces to a program with no
+    `moe_zero` scope and no third count; one whose router has no zero-compute
+    output (`real` = the router's width) answers the same bits."""
+    from tpuserve.ops import moe
+
+    x, w, e, w_in, w_out, live = _layer(5, 4, 4, 16, 4, 256, "float32", "swiglu", True)
+    fn = lambda *a: moe.held_experts(  # noqa: E731
+        *a, 4, w_in, w_out, moe.swiglu, live=live, of=16, real=real)
+    text = str(jax.make_jaxpr(fn)(x, w, e))
+    got, st = jax.jit(fn)(x, w, e)
+    old = _held_experts_swiglu_pr31(x, w, e, 4, *w_in, w_out, live)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(old), rtol=1e-5, atol=1e-5)
+    assert ("routed_zero" in st) == (real is not None)
+    if real is None:
+        before = str(jax.make_jaxpr(lambda *a: moe.held_experts(
+            *a, 4, w_in, w_out, moe.swiglu, live=live, of=16))(x, w, e))
+        assert text == before and "moe_zero" not in text
+    else:
+        assert int(st["routed_zero"]) == 0
+
+
+def test_a_token_with_every_pick_zero_compute_and_one_with_none():
+    from tpuserve.ops import moe
+
+    real, zero, k, d = 8, 4, 3, 6
+    rng = np.random.default_rng(21)
+    logits = np.full((2, real + zero), -5.0, np.float32)
+    logits[0, [8, 9, 11]] = [3.0, 2.0, 1.0]     # token 0: three zero-compute outputs
+    logits[1, [0, 5, 6]] = [3.0, 2.0, 1.0]      # token 1: three real experts, of which 5 and 6 held
+    w, e = moe.topk_route(jnp.asarray(logits), k, normalize=False, scale=6.0)
+    x = jnp.asarray(rng.standard_normal((2, d)), jnp.float32)
+    w_in = tuple(jnp.asarray(rng.standard_normal((4, d, 5)), jnp.float32) for _ in range(2))
+    w_out = jnp.asarray(rng.standard_normal((4, 5, d)), jnp.float32)
+    y, st = moe.held_experts(x, w, e, 4, w_in, w_out, moe.swiglu, of=real + zero, real=real)
+    assert (int(st["routed_zero"]), int(st["routed_held"]), int(st["routed_absent"])) == (3, 2, 1)
+    np.testing.assert_allclose(y[0], np.sum(w[0]) * np.asarray(x[0]), rtol=1e-6)
+    p = np.asarray(jax.nn.softmax(jnp.asarray(logits), axis=-1))
+    want = sum(6.0 * p[1, g] * (np.asarray(jax.nn.silu(x[1] @ w_in[0][g - 4])) *
+                                np.asarray(x[1] @ w_in[1][g - 4])) @ np.asarray(w_out[g - 4])
+               for g in (5, 6))
+    np.testing.assert_allclose(y[1], want, rtol=2e-4, atol=2e-4)

@@ -1808,6 +1808,9 @@ class GenEngine:
                 "state_bytes_per_slot": self.slot_state_bytes() // self.slots,
                 "state_bytes": self.slot_state_bytes(),
             }
+        share = self.model.share_stats()
+        if share is not None:
+            stats["share"] = share
         # Per-replica rows (ISSUE 20): one row for a single engine, one per
         # member for a GenEngineGroup (which overrides the aggregate keys
         # above and composes these) — uniform shape either way.

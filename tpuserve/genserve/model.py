@@ -192,6 +192,12 @@ class GenerativeModel(ServingModel):
     # ``gen_kv_row_bytes``; ``kv.kv_bytes``).
     kv_page_leaves: tuple = ("kp", "vp")
 
+    def share_stats(self) -> "dict | None":
+        """Host-side: what of each layer this chip holds, where the model is
+        one chip's share of a deployment (/stats ``pipeline.models.<m>.share``);
+        None for a model that is whole."""
+        return None
+
     def observe_step(self, step_out: dict) -> None:
         """Host-side, after every fetched step: a family that sums counts on
         the device (experts hit, context read) moves them into its own

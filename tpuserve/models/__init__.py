@@ -30,6 +30,11 @@ Families (BASELINE.json ``configs``):
                    absorbed decode and an expanded prefill form) and routed
                    SwiGLU experts, built from a published config.json
                    (ISSUE 34)
+- mla_sc         — ``mla``'s attention in DOUBLE layers: two latent attentions
+                   and two dense SwiGLUs a layer, and a routed layer on a
+                   shortcut whose router also picks zero-compute (identity)
+                   outputs; a share of the experts and the vocabulary; a
+                   grouped decode walk (ISSUE 42)
 - toy            — a linear classifier for tests and drills
 """
 
@@ -52,6 +57,7 @@ _REGISTRY: dict[str, str] = {
     "hybrid": "tpuserve.models.hybrid",
     "hybrid_ffn": "tpuserve.models.hybrid_ffn",
     "mla": "tpuserve.models.mla",
+    "mla_sc": "tpuserve.models.mla_sc",
     "toy": "tpuserve.models.toy",
 }
 

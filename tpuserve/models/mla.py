@@ -109,15 +109,9 @@ class LatentServing(PagedLM):
         if not a.get("q_lora_rank"):
             raise NotImplementedError(f"{cfg.name}: q_lora_rank = {a.get('q_lora_rank')!r} "
                                       "(queries without a low-rank projection)")
-        self.d = int(a["hidden_size"])
         self.n_layers = int(a["num_hidden_layers"])
         self.eps = float(a.get("rms_norm_eps", 1e-6))
-        self.heads = int(a["num_attention_heads"])
-        self.q_rank, self.r = int(a["q_lora_rank"]), int(a["kv_lora_rank"])
-        self.dn, self.dr, self.dv = (int(a[k]) for k in (
-            "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"))
-        self.rope = rope_inv_freq({"rope_theta": float(a.get("rope_theta", 10000.0))}, self.dr)
-        self.rope_interleave = bool(a.get("rope_interleave", False))
+        self._read_attention(a)
         self.first_dense = int(a.get("first_k_dense_replace", 0))
         self.sparse_layers = list(range(self.first_dense, self.n_layers))
         self.dense_width = int(a["intermediate_size"])
@@ -134,21 +128,60 @@ class LatentServing(PagedLM):
         self.scales = {**DEFAULT_SCALES, **a.get("weight_scales", {})}
         self._serve_options(cfg, a)
 
+    def _read_attention(self, a: dict) -> None:
+        """The attention's numbers, under the key names every published
+        latent-attention config shares."""
+        self.d = int(a["hidden_size"])
+        self.heads = int(a["num_attention_heads"])
+        self.q_rank, self.r = int(a["q_lora_rank"]), int(a["kv_lora_rank"])
+        self.dn, self.dr, self.dv = (int(a[k]) for k in (
+            "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"))
+        self.rope = rope_inv_freq({"rope_theta": float(a.get("rope_theta", 10000.0))}, self.dr)
+        self.rope_interleave = bool(a.get("rope_interleave", False))
+
     # -- params ---------------------------------------------------------------
+    # Factors on the two latents after their norms (a config that scales them
+    # says so; 1: none, and the program is the one without them).
+    q_scale = kv_scale = 1.0
+
+    def _attentions(self):
+        """The path of every attention's tensors: one a layer, beside the
+        layer's others."""
+        return [(f"layer{i}",) for i in range(self.n_layers)]
+
     def _gains(self):
         yield ("norm_f",), (self.d,)
         for i in range(self.n_layers):
-            for name, n in (("norm1", self.d), ("norm2", self.d), ("q_norm", self.q_rank),
-                            ("kv_norm", self.r)):
-                yield (f"layer{i}", name), (n,)
+            yield (f"layer{i}", "norm1"), (self.d,)
+            yield (f"layer{i}", "norm2"), (self.d,)
+            yield from self._attention_gains((f"layer{i}",))
+
+    def _attention_gains(self, at: tuple):
+        yield (*at, "q_norm"), (self.q_rank,)
+        yield (*at, "kv_norm"), (self.r,)
+
+    def _attention_tensors(self, at: tuple):
+        """One attention's matrices under the path ``at``. ``W_qb`` and
+        ``W_kva`` are drawn in their two parts (``w_qb_nope`` | ``w_qb_rope``,
+        ``w_kva_c`` | ``w_kva_r``), ``W_kvb`` in its key and its value side
+        (``w_kb``, ``w_vb``), each a tensor of its own; ``draw_params`` joins
+        the first two pairs."""
+        d, s, h = self.d, self.scales, self.heads
+        for name, shape, role, fan_in in (
+                ("w_qa", (d, self.q_rank), "q_a", d),
+                ("w_qb_nope", (self.q_rank, h, self.dn), "q_b", self.q_rank),
+                ("w_qb_rope", (self.q_rank, h, self.dr), "q_b", self.q_rank),
+                ("w_kva_c", (d, self.r), "kv_a", d),
+                ("w_kva_r", (d, self.dr), "k_rope", d),
+                ("w_kb", (self.r, h, self.dn), "k_b", self.r),
+                ("w_vb", (self.r, h, self.dv), "v", self.r),
+                ("wo", (h, self.dv, d), "o", h * self.dv)):
+            yield (*at, name), shape, shape, (0,) * len(shape), s[role], fan_in
 
     def _tensors(self):
         """(path, shape, full shape, start, role, fan-in) of every matrix, in
-        a fixed order. ``W_qb`` and ``W_kva`` are drawn in their two parts
-        (``w_qb_nope`` | ``w_qb_rope``, ``w_kva_c`` | ``w_kva_r``), ``W_kvb`` in
-        its key and its value side (``w_kb``, ``w_vb``), each a tensor of its
-        own; ``draw_params`` joins the first two pairs."""
-        d, s, h = self.d, self.scales, self.heads
+        a fixed order."""
+        d, s = self.d, self.scales
 
         def whole(path, shape, role, fan_in):
             return path, shape, shape, (0,) * len(shape), s[role], fan_in
@@ -156,14 +189,7 @@ class LatentServing(PagedLM):
         yield from self._vocab_tensors()
         for i in range(self.n_layers):
             L = f"layer{i}"
-            yield whole((L, "w_qa"), (d, self.q_rank), "q_a", d)
-            yield whole((L, "w_qb_nope"), (self.q_rank, h, self.dn), "q_b", self.q_rank)
-            yield whole((L, "w_qb_rope"), (self.q_rank, h, self.dr), "q_b", self.q_rank)
-            yield whole((L, "w_kva_c"), (d, self.r), "kv_a", d)
-            yield whole((L, "w_kva_r"), (d, self.dr), "k_rope", d)
-            yield whole((L, "w_kb"), (self.r, h, self.dn), "k_b", self.r)
-            yield whole((L, "w_vb"), (self.r, h, self.dv), "v", self.r)
-            yield whole((L, "wo"), (h, self.dv, d), "o", h * self.dv)
+            yield from self._attention_tensors((L,))
             if i < self.first_dense:
                 f = self.dense_width
                 for name in ("w_gate", "w_up"):
@@ -188,8 +214,10 @@ class LatentServing(PagedLM):
 
     def draw_params(self, seed: int) -> Any:
         p = super().draw_params(seed)
-        for i in range(self.n_layers):
-            lp = p[f"layer{i}"]
+        for at in self._attentions():
+            lp = p
+            for key in at:
+                lp = lp[key]
             lp["w_qb"] = jnp.concatenate([lp.pop("w_qb_nope"), lp.pop("w_qb_rope")], axis=2)
             lp["w_kva"] = jnp.concatenate([lp.pop("w_kva_c"), lp.pop("w_kva_r")], axis=1)
         return p
@@ -213,17 +241,28 @@ class LatentServing(PagedLM):
             + tile_rows * 2 * h * (self.dn + self.dr + self.dv)
         return "expanded" if expanded < tile_rows * 2 * h * (2 * r + self.dr) else "absorbed"
 
+    @staticmethod
+    def _gain(gain: jax.Array, factor: float) -> jax.Array:
+        """A norm's gain times the latent's factor, in float32 (the norm
+        multiplies there, before it rounds to the served type); the gain
+        itself where the factor is 1."""
+        return gain if factor == 1.0 else gain.astype(jnp.float32) * jnp.float32(factor)
+
     def _project(self, lp: dict, u: jax.Array, pos: jax.Array):
         """``u`` (T, d) normed stream at positions ``pos`` (T,) -> q_nope (T, H,
         nope), rotated q_rope (T, H, rope), and what a token keeps: the normed
-        ``c_kv`` (T, r) and the rotated ``k_r`` (T, rope)."""
+        ``c_kv`` (T, r) and the rotated ``k_r`` (T, rope). ``q_scale`` (on the
+        query's latent: ``W_qb`` is linear, so both parts of ``q`` carry it)
+        and ``kv_scale`` (on ``c_kv`` before ``W_kvb``, so the CACHED row
+        carries it and ``k_r`` does not) ride on the two norms' gains."""
         dt = self.dtype
         inv, factor, dim = self.rope
-        c_q = rms_norm(_mm(u, lp["w_qa"]).astype(dt), lp["q_norm"], self.eps)
+        c_q = rms_norm(_mm(u, lp["w_qa"]).astype(dt), self._gain(lp["q_norm"], self.q_scale),
+                       self.eps)
         q = jnp.einsum("tq,qhk->thk", c_q, lp["w_qb"],
                        preferred_element_type=jnp.float32).astype(dt)
         kva = _mm(u, lp["w_kva"]).astype(dt)
-        c_kv = rms_norm(kva[:, :self.r], lp["kv_norm"], self.eps)
+        c_kv = rms_norm(kva[:, :self.r], self._gain(lp["kv_norm"], self.kv_scale), self.eps)
         k_r = apply_rope(kva[:, None, self.r:], pos, inv, factor, dim,
                          self.rope_interleave)[:, 0]
         q_rope = apply_rope(q[..., self.dn:], pos, inv, factor, dim, self.rope_interleave)

@@ -403,24 +403,24 @@ class PagedLM(GenerativeModel):
             axis=1)[:, 0], 0)
         return page, cpos % P
 
-    @staticmethod
-    def _block_pages(P: int, pps: int) -> int:
-        """Pages a key block of ``KEY_BLOCK`` positions holds."""
-        return max(1, min(KEY_BLOCK // P, pps))
+    key_block = None  # key positions a block of this family's walk; None: KEY_BLOCK
 
-    @staticmethod
-    def _key_blocks(row, P: int):
+    def _block_pages(self, P: int, pps: int) -> int:
+        """Pages a key block of ``key_block`` (else ``KEY_BLOCK``) positions
+        holds."""
+        return max(1, min((self.key_block or KEY_BLOCK) // P, pps))
+
+    def _key_blocks(self, row, P: int):
         """The walk of one block-table row ``row`` (pps,) of pages of ``P``
         positions in key blocks -> (pages a block, the row padded to whole
         blocks)."""
-        kb = PagedLM._block_pages(P, row.shape[0])
+        kb = self._block_pages(P, row.shape[0])
         return kb, jnp.pad(row, (0, -row.shape[0] % kb))
 
-    @staticmethod
-    def _blocks_needed(last, P: int, pps: int):
+    def _blocks_needed(self, last, P: int, pps: int):
         """Key blocks a walk up to position ``last`` takes (traced: a
         prompt's first tile reads one block, not the padded context)."""
-        kb = PagedLM._block_pages(P, pps)
+        kb = self._block_pages(P, pps)
         return jnp.minimum(last // (kb * P) + 1, -(-pps // kb))
 
     @staticmethod

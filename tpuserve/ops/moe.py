@@ -1,4 +1,7 @@
-"""Switch-style mixture-of-experts FFN with expert parallelism (EP).
+"""Two expert layers. First a Switch-style mixture-of-experts FFN with expert
+parallelism (EP), below; then, from ``topk_route`` on, the top-k layer of a
+published sparse decoder of which one chip holds a share (``held_experts``:
+held, absent and zero-compute picks; its own header comment further down).
 
 Expert parallelism is the last axis in SURVEY.md §2.1's strategy table;
 none of the judged configs is an MoE, so it was scoped out of v1 — this
@@ -120,12 +123,22 @@ class SwitchFFN(nn.Module):
 # -- top-k routing over a share of the experts --------------------------------
 # The other expert layer of this module (ISSUE 28): what a published sparse
 # decoder runs, held by one chip of several. The router keeps its published
-# width and scores EVERY expert; this chip holds experts
-# [first, first + count) and computes their part of the layer's result for
-# the tokens routed to them. Picks that land on absent experts add nothing
-# here (the chip that holds them adds their part in a deployment, through an
-# exchange this module does not have: on one chip the layer runs without
-# it). No capacity, no dropped token: the picks are sorted by expert and go
+# width and scores EVERY output; this chip holds experts
+# [first, first + count). A live token's pick is one of THREE kinds (ISSUE 42):
+#
+# - HELD: an expert this chip holds. Its row goes through the expert's
+#   products here and adds weight x expert(token).
+# - ABSENT: a real expert another chip holds. It adds nothing here (the chip
+#   that holds it adds its part in a deployment, through an exchange this
+#   module does not have: on one chip the layer runs without it).
+# - ZERO-COMPUTE: where the caller says how many of the router's outputs are
+#   real experts (``real``), a pick at ``real`` or above is an identity: it has
+#   no weights, enters no gather and no product, and adds weight x token. Every chip of the layer computes that term alike for the tokens it
+#   has (it counts once), so it is neither held nor absent. Its key sorts with
+#   the absent picks', and its weights are summed a token under the scope
+#   ``moe_zero``.
+#
+# No capacity, no dropped token: the held picks are sorted by expert and go
 # through a grouped product (``_grouped_dot``) in groups of whatever size the
 # router gave.
 #
@@ -243,17 +256,17 @@ def _row_bound(picks: int, count: int, of: "int | None") -> int:
 
 
 def held_experts_swiglu(x, weights, experts, first, w_gate, w_up, w_down, live=None,
-                        of=None):
+                        of=None, real=None):
     """This chip's part of a routed SwiGLU layer: :func:`held_experts` with
     the two in-kernels ``w_gate``/``w_up`` and the SiLU gate."""
     return held_experts(x, weights, experts, first, (w_gate, w_up), w_down, swiglu,
-                        live=live, of=of)
+                        live=live, of=of, real=real)
 
 
 def held_experts(x: jax.Array, weights: jax.Array, experts: jax.Array,
                  first: int, w_in: "tuple[jax.Array, ...]", w_out: jax.Array,
-                 body, live: "jax.Array | None" = None, of: "int | None" = None
-                 ) -> tuple[jax.Array, dict]:
+                 body, live: "jax.Array | None" = None, of: "int | None" = None,
+                 real: "int | None" = None) -> tuple[jax.Array, dict]:
     """This chip's part of a routed expert layer, whatever an expert is.
 
     ``x`` (T, D); ``weights``/``experts`` (T, k) from :func:`topk_route`;
@@ -265,7 +278,10 @@ def held_experts(x: jax.Array, weights: jax.Array, experts: jax.Array,
     since shapes are static, but count for nothing and add nothing). ``of``
     is the router's width (the experts ``experts`` ranges over), from which
     the compact branch's row bound is sized; without it there is no such
-    branch.
+    branch. ``real`` is how many of those outputs are real experts: a pick at
+    ``real`` or above is ZERO-COMPUTE (the header comment above) and adds
+    weight x token; without it every pick outside the held range is absent,
+    and the program is the one this function always traced to.
 
     The sorted picks go through gather, products and body in one of two
     branches (the header comment above): ``compact`` carries
@@ -276,11 +292,13 @@ def held_experts(x: jax.Array, weights: jax.Array, experts: jax.Array,
     once a layer, not under ``vmap`` (a ``cond`` there runs both branches).
 
     Returns ``y`` (T, D) float32, the sum over the held experts a token
-    picked of weight x expert(token), and the counts the serving loop sums
-    into its counters: ``routed_held``/``routed_absent`` (picks of live
-    tokens on held / absent experts), ``experts_hit`` (held experts with
-    at least one live pick) and ``compact`` (1 where the compact branch
-    ran, else 0)."""
+    picked of weight x expert(token) (plus, with ``real``, the zero-compute
+    picks' weight x token), and the counts the serving loop sums into its
+    counters: ``routed_held``/``routed_absent`` (picks of live tokens on held
+    experts / on real experts held elsewhere), with ``real`` ``routed_zero``
+    (their zero-compute picks; the three sum to k x live tokens),
+    ``experts_hit`` (held experts with at least one live pick) and
+    ``compact`` (1 where the compact branch ran, else 0)."""
     t, k = experts.shape
     count = w_out.shape[0]
     bound = _row_bound(k * t, count, of)
@@ -289,11 +307,17 @@ def held_experts(x: jax.Array, weights: jax.Array, experts: jax.Array,
     with jax.named_scope("moe_dispatch"):
         local = experts - jnp.int32(first)
         held = (local >= 0) & (local < count)
+        absent = ~held
+        if real is not None:
+            zero_live = experts >= jnp.int32(real)
+            absent = absent & ~zero_live
         if live is not None:
             held_live = held & live[:, None]
-            absent_live = (~held) & live[:, None]
+            absent_live = absent & live[:, None]
+            if real is not None:
+                zero_live = zero_live & live[:, None]
         else:
-            held_live, absent_live = held, ~held
+            held_live, absent_live = held, absent
         # Absent (and dead) picks sort behind every held expert, into rows
         # past the groups' sum. Picks are laid out pick-major (pick j of token
         # i at j * T + i), so that the way back is a split of the leading
@@ -340,4 +364,9 @@ def held_experts(x: jax.Array, weights: jax.Array, experts: jax.Array,
                  "routed_absent": jnp.sum(absent_live, dtype=jnp.int32),
                  "experts_hit": jnp.sum(sizes > 0, dtype=jnp.int32),
                  "compact": fits.astype(jnp.int32)}
+    if real is not None:
+        with jax.named_scope("moe_zero"):
+            w_zero = jnp.sum(jnp.where(zero_live, weights, 0.0), axis=1)
+            y = y + w_zero[:, None] * x.astype(jnp.float32)
+            stats["routed_zero"] = jnp.sum(zero_live, dtype=jnp.int32)
     return y, stats
