@@ -4,7 +4,8 @@ that fill every slot to a context of `--context` tokens and decode steps of
 every lane over those contexts, on the cell's drawn weights, timed by the
 host's clock around a dependent read, then traced, with a table by operation
 of one launch and one step (each operation under the program's `mla_prefill`
-/ `mla_decode` scope or outside it, by what it is).
+/ `mla_decode` scope or outside it, by what it is; the kernel that walks a
+prefill tile's key blocks, `tile_walk`, in a row of its own).
 
     chiprun -- python scripts/bench_mla.py [--context 9216] [--chunk 2048]
     python scripts/bench_mla.py --rehearse --config benchmark/configs/rehearsal-mla-tiny.json
@@ -38,7 +39,8 @@ from tpuserve.config import ModelConfig  # noqa: E402
 from tpuserve.genserve.model import PrefillPiece  # noqa: E402
 from tpuserve.models import build  # noqa: E402
 
-KINDS = (("mla_prefill", "latent attention (scope mla_prefill)"),
+KINDS = (("tile_walk", "the tiles' walks of their key blocks (kernel tile_walk, in mla_prefill)"),
+         ("mla_prefill", "latent attention (scope mla_prefill)"),
          ("mla_decode", "latent attention (scope mla_decode)"),
          ("gmm", "grouped products of the routed experts"), ("sort", "sort and un-sort of the picks"),
          ("top_k", "top-k"), ("scatter", "scatters outside the scope"),

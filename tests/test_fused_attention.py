@@ -371,23 +371,69 @@ def test_kernel_compiles_for_the_v5e_at_the_cells_widths(one_chip, heads,
     assert " copy(" not in text and " transpose(" not in text
 
 
-def test_the_tile_kernel_of_latent_attention_compiles_for_the_v5e_at_the_cells_widths(one_chip):
-    """`ops/tile_attention.py` at the JoyAI cell's sizes (ISSUE 34): 32 heads,
-    a tile and a key block of 1,024, keys 192 wide and values 128: Mosaic
-    takes the 192-wide contraction and the scalar-prefetched offset."""
-    from tpuserve.ops.tile_attention import tile_attention
+@pytest.mark.parametrize("family,heads,tile,block_pages,pps", [
+    ("mla", 32, 1024, 8, 194), ("mla_sc", 64, 256, 2, 22)])
+def test_a_prefill_tiles_walk_is_one_kernel_call_on_the_v5e_at_the_cells_widths(
+        one_chip, tmp_path, monkeypatch, family, heads, tile, block_pages, pps):
+    """`ops/tile_attention.py` under the families' map over a launch's tiles
+    (ISSUE 43), at the two cells' sizes: JoyAI's 32 heads in tiles and key
+    blocks of 1,024, LongCat's 64 heads in tiles and key blocks of 256, a
+    latent row of 512 and two rotary keys of 64 side by side, two tiles of a
+    launch. The TPU branch is steered by the backend's name here, in the test.
+    Mosaic takes the kernel (the pools passed once a page of a block, the
+    block-table row and the tile's position by scalar prefetch, the walk's
+    length a traced grid bound); the program holds ONE custom call a tile and
+    no `while` at all (none over key blocks, none over tiles); nothing float32
+    by head and tile (a partial context, a statistic) and no copy of a pool
+    is in it."""
+    import json
 
-    def shape(*dims):
-        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+    from tpuserve.config import ModelConfig
+    from tpuserve.models import build
 
+    arch = {"vocab_size": 256, "hidden_size": 1024, "num_attention_heads": heads,
+            "q_lora_rank": 256, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+            "qk_rope_head_dim": 64, "v_head_dim": 128}
+    arch.update({"num_hidden_layers": 1, "intermediate_size": 256, "first_k_dense_replace": 1}
+                if family == "mla" else
+                {"num_layers": 1, "ffn_hidden_size": 256, "expert_ffn_hidden_size": 256,
+                 "n_routed_experts": 8, "zero_expert_num": 4, "moe_topk": 2,
+                 "attention_method": "MLA"})
+    path = tmp_path / "arch.json"
+    path.write_text(json.dumps(arch))
+    model = build(ModelConfig(name="walk", family=family, dtype="bfloat16", batch_buckets=[1],
+                              options={"config_file": str(path),
+                                       "max_prompt_tokens": pps * 128 - 768,
+                                       "max_new_tokens": 768}))
+    assert model.TILE_ROWS == tile and model._form(tile) == "expanded" \
+        and model.kv_pages_per_slot(128) == pps and model._block_pages(128, pps) == block_pages
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    K, pages = 2, 3200
+    lp = {"w_kb": shape(512, heads, 128), "w_vb": shape(512, heads, 128)}
+
+    def attend(lp, qn, qr, ckv, kr, rows, qpos, last):
+        t = {"K": K, "T": tile, "rows": rows, "qpos": qpos, "last": last}
+        return model._attend_tiles(lp, qn, qr, (ckv, kr), t, "expanded")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
     try:
-        text = jax.jit(lambda q, k, v, off: tile_attention(q, k, v, off, scale=192 ** -0.5)).lower(
-            shape(32, 1024, 192), shape(32, 1024, 192), shape(32, 1024, 128),
-            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile().as_text()
+        text = jax.jit(attend).lower(
+            lp, shape(K * tile, heads, 128), shape(K * tile, heads, 64),
+            shape(pages, 128, 512), shape(pages, 64, 128), shape(K, pps, dtype=jnp.int32),
+            shape(K, tile, dtype=jnp.int32), shape(K, dtype=jnp.int32)).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
-    assert "tpu_custom_call" in text and "tile_attention" in text
+    calls = [ln for ln in text.split("\n") if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == K and all("tile_walk" in ln for ln in calls)
+    assert " while(" not in text
+    for partial in (f"f32[{heads},{tile},128]", f"f32[{tile},{heads},128]", f"f32[{heads},{tile}]"):
+        assert partial not in text
+    assert not [ln for ln in text.split("\n")
+                if " copy(" in ln and (f"{pages},128,512" in ln or f"{pages},64,128" in ln)]
 
 
 def test_decode_over_packed_pages_compiles_for_the_v5e_at_the_cells_widths(one_chip, tmp_path,

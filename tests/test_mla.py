@@ -212,46 +212,114 @@ def test_the_absorbed_and_the_expanded_form_agree_on_the_same_cache(whole, monke
     np.testing.assert_allclose(outs[1], want, atol=2e-5)
 
 
-@pytest.mark.parametrize("offset", [0, 128, 256, -128])
-def test_the_tile_kernel_is_causal_attention_of_one_key_block_with_its_softmax_state(
-        offset, monkeypatch):
-    """In the Pallas interpreter: a block whole before the tile (offset 256),
-    the block the tile lies in (0), one that begins inside it (128), and one
-    whose first rows see no key (-128: those rows come back with a max so low
-    that a merge gives them no weight)."""
+def walk_case(h, t, page, kb, pos0, live, blocks, dtype=jnp.float32, widths=(32, 16, 16, 8),
+              seed=0):
+    """The kernel's arguments for one tile of ``t`` rows at position ``pos0``
+    whose first ``live`` rows are a prompt's (none: a tile of no piece, whose
+    last live position ``_tiles`` gives as 0), over pools of pages of ``page``
+    positions (two positions' rotary keys a row) and a shuffled block-table row
+    of ``blocks`` key blocks of ``kb`` pages, and the plain float32 attention
+    of the rows over the keys and values made from the same latents."""
+    r, dn, dv, dr = widths
+    rng = np.random.default_rng(seed)
+    pages = blocks * kb + 2
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)  # noqa: E731
+    qn, qr = draw(h, t, dn), draw(h, t, dr)
+    ckv, kr = draw(pages, page, r), draw(pages, page // 2, 2 * dr)
+    w_kb, w_vb = draw(h, r, dn) / r ** 0.5, draw(h, r, dv) / r ** 0.5
+    rows = jnp.asarray(rng.permutation(pages - 1)[: blocks * kb] + 1, jnp.int32)
+    last = pos0 + live - 1 if live else 0
+    need = last // (kb * page) + 1
+    args = (jnp.concatenate([qn, qr, qr], axis=-1), jnp.concatenate([w_kb, w_vb], axis=-1),
+            ckv, kr, rows, jnp.int32(need), jnp.int32(pos0))
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    n = last + 1 if live else kb * page   # a tile of no piece sees the block it walks
+    c_kv = f32(ckv[rows]).reshape(-1, r)[:n]
+    k_r = f32(kr[rows]).reshape(-1, dr)[:n]
+    rounded = lambda a: f32(a.astype(dtype))  # noqa: E731  (the expansion is kept as served)
+    k = rounded(jnp.einsum("cr,hrn->hcn", c_kv, f32(w_kb)))
+    v = rounded(jnp.einsum("cr,hrv->hcv", c_kv, f32(w_vb)))
+    scale = (dn + dr) ** -0.5
+    s = (jnp.einsum("htn,hcn->htc", f32(qn), k) + jnp.einsum("htd,cd->htc", f32(qr), k_r)) * scale
+    see = jnp.arange(n)[None, :] <= (pos0 + jnp.arange(t))[:, None]
+    want = jnp.einsum("htc,hcv->htv", jax.nn.softmax(jnp.where(see[None], s, -jnp.inf), -1), v)
+    return args, {"block_pages": kb, "scale": scale}, np.asarray(want[:, :live or t])
+
+
+# (heads, tile rows, page, pages a key block, first position, live rows, blocks of the row)
+WALKS = {
+    "one-block-needed-of-four": (2, 64, 16, 4, 0, 64, 4),
+    "a-tile-in-its-prompts-first-block": (2, 32, 16, 4, 32, 32, 3),
+    "four-blocks-ending-on-the-diagonal-block": (2, 64, 16, 4, 192, 64, 5),
+    "the-last-live-row-mid-page": (2, 64, 16, 4, 128, 21, 4),
+    "a-tile-of-no-piece": (2, 64, 16, 4, 448, 0, 3),
+    "32-heads-of-1024-rows": (32, 1024, 128, 8, 1024, 1024, 3),
+    "64-heads-of-256-rows": (64, 256, 128, 2, 512, 256, 4),
+    "bfloat16": (4, 128, 64, 2, 256, 128, 4),
+}
+
+
+@pytest.mark.parametrize("case", list(WALKS))
+def test_the_kernels_walk_is_causal_attention_over_the_pools_as_they_lie(case, monkeypatch):
+    """``ops/tile_attention.py`` in the Pallas interpreter against plain float32
+    attention over keys and values made from the same latents: the running
+    softmax over the blocks a tile needs and no further, the diagonal inside a
+    block and between sub-blocks (steered to 32 x 32 at the toy tiles), the
+    rotary keys two positions a row, the cells' two shapes at toy widths. A
+    tile of no piece (``has`` false: its last live position reads 0) walks one
+    block of whatever its row names, all of it seen. In float32 the
+    kernel rounds nothing, so it stands within float32's sums in another
+    order; in bfloat16 its probabilities round into the second product, a few
+    thousandths, as the walk in XLA does."""
     from tpuserve.ops import tile_attention as ta
 
-    monkeypatch.setattr(ta, "BLOCK_Q", 128)
-    monkeypatch.setattr(ta, "BLOCK_K", 128)
-    rng = np.random.default_rng(abs(offset) + 7)
-    h, t, c, dk, dv = 2, 256, 256, 192, 128
-    q, k, v = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-               for shape in ((h, t, dk), (h, c, dk), (h, c, dv)))
-    acc, m, l = ta.tile_attention(q, k, v, jnp.int32(offset), scale=dk ** -0.5, interpret=True)
-    s = jnp.einsum("htd,hcd->htc", q.astype(jnp.float32), k.astype(jnp.float32)) * dk ** -0.5
-    see = jnp.arange(c)[None, :] <= jnp.arange(t)[:, None] + offset
-    some = np.asarray(see.any(axis=1))
-    s = jnp.where(see[None], s, -jnp.inf)
-    want = jnp.einsum("htc,hcd->htd", jax.nn.softmax(s, -1), v.astype(jnp.float32))
-    # bfloat16 probabilities into the second product: a few thousandths
-    np.testing.assert_allclose((acc / l[..., None])[:, some], want[:, some], atol=6e-3)
-    np.testing.assert_allclose(m[:, some], s.max(-1)[:, some], atol=1e-5)
-    assert bool(jnp.all(m[:, ~some] < -1e29))
-    assert ta.fits(1024, 1024, 192, 128, jnp.bfloat16) and not ta.fits(1024, 1024, 192, 128, jnp.float32) \
-        and not ta.fits(4, 8, 24, 16, jnp.bfloat16)
+    h, t, page, kb, pos0, live, blocks = WALKS[case]
+    if t < 256:
+        monkeypatch.setattr(ta, "BLOCK_Q", 32)
+        monkeypatch.setattr(ta, "BLOCK_K", 32)
+    bf = case == "bfloat16"
+    args, kw, want = walk_case(h, t, page, kb, pos0, live, blocks,
+                               jnp.bfloat16 if bf else jnp.float32,
+                               (128, 128, 128, 64) if bf else (32, 16, 16, 8))
+    got = ta.tile_walk(*args, **kw, interpret=True)
+    assert got.shape == (t, h, want.shape[2]) and got.dtype == args[0].dtype
+    assert bool(jnp.all(jnp.isfinite(got.astype(jnp.float32))))   # the rows past the prompt too
+    got = np.asarray(got.astype(jnp.float32)).transpose(1, 0, 2)
+    np.testing.assert_allclose(got[:, :want.shape[1]], want, atol=6e-3 if bf else 2e-5)
+    assert ta.fits(1024, 128, 8, 512, 128, 128, 128, jnp.bfloat16) \
+        and ta.fits(256, 128, 2, 512, 128, 128, 128, jnp.bfloat16) \
+        and not ta.fits(1024, 128, 8, 512, 128, 128, 128, jnp.float32) \
+        and not ta.fits(4, 4, 2, 32, 16, 16, 128, jnp.bfloat16)
 
 
-def test_on_the_tpu_the_expanded_form_goes_through_the_kernel_and_is_the_same_attention(
-        tmp_path, monkeypatch):
-    """The family's trace-time choice, steered here and not by an option: with
-    the backend named `tpu` and the kernel run in the interpreter, an expanded
-    tile over three key blocks (a merge of three kernel calls) is what the
-    einsum pair gives on the CPU, to bfloat16's rounding."""
+def interpret_the_kernel(monkeypatch):
+    """The kernel runs in the Pallas interpreter -> a list that grows by one
+    each time a call of it is traced."""
     import functools
 
     from tpuserve.ops import tile_attention as ta
 
-    arch = dict(ARCH, num_attention_heads=2, kv_lora_rank=64, qk_nope_head_dim=64,
+    calls = []
+    monkeypatch.setattr(ta, "tile_walk", functools.partial(
+        lambda *a, f=ta.tile_walk, **k: calls.append(1) or f(*a, interpret=True, **k)))
+    return calls
+
+
+def steer_to_the_kernel(monkeypatch):
+    """The family's trace-time choice, steered here and not by an option:
+    every expanded tile walks in the kernel, interpreted (the backend's name
+    would steer the experts' kernels too)."""
+    monkeypatch.setattr(mla.LatentServing, "_walk", lambda self, form, *a: (
+        "kernel" if form == "expanded" else "xla"))
+    return interpret_the_kernel(monkeypatch)
+
+
+def test_on_the_tpu_an_expanded_tile_walks_in_the_kernel_and_is_the_same_attention(
+        tmp_path, monkeypatch):
+    """With the backend named `tpu` and the kernel run in the interpreter, an
+    expanded tile over three key blocks is ONE kernel call, and what the
+    einsum walk gives on the CPU, to bfloat16's rounding."""
+    arch = dict(ARCH, num_attention_heads=2, kv_lora_rank=128, qk_nope_head_dim=128,
                 qk_rope_head_dim=64, v_head_dim=128)
     model = make_model(tmp_path, arch, name="kern", dtype="bfloat16", max_prompt_tokens=384,
                        max_new_tokens=0)
@@ -261,18 +329,49 @@ def test_on_the_tpu_the_expanded_form_goes_through_the_kernel_and_is_the_same_at
     qn, qr, c_kv, k_r = model._project(lp, u, jnp.arange(n))
     pages = jnp.arange(1, 4)
     at = (pages[jnp.arange(n) // page], jnp.arange(n) % page)
-    pools = (model._write_pages(jnp.zeros((4, page, 64), jnp.bfloat16), *at, c_kv),
+    pools = (model._write_pages(jnp.zeros((4, page, 128), jnp.bfloat16), *at, c_kv),
              model._write_keys(jnp.zeros((4, page // 2, 128), jnp.bfloat16), *at, k_r, runs=True))
     monkeypatch.setattr(paged_lm, "KEY_BLOCK", page)
     args = (lp, qn[-T:], qr[-T:], pools, pages, jnp.arange(n - T, n), jnp.int32(n - 1), "expanded")
+    assert model._walk("expanded", T, pools, 3) == "xla"
     plain = model._attend_tile(*args)
-    calls = []
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(ta, "tile_attention", functools.partial(
-        lambda *a, f=ta.tile_attention, **k: calls.append(1) or f(*a, interpret=True, **k)))
+    calls = interpret_the_kernel(monkeypatch)
+    assert model._walk("expanded", T, pools, 3) == "kernel" \
+        and model._walk("absorbed", T, pools, 3) == "xla"
     kernel = model._attend_tile(*args)
-    assert calls and kernel.shape == plain.shape == (T, 2, 128)
-    np.testing.assert_allclose(kernel, plain, atol=2e-2)
+    assert len(calls) == 1 and kernel.shape == plain.shape == (T, 2, 128)
+    np.testing.assert_allclose(kernel.astype(jnp.float32), plain, atol=2e-2)
+
+
+def test_a_launchs_tiles_walk_different_block_table_rows_in_the_kernel(tmp_path, monkeypatch):
+    """A launch of four tiles of 64 rows: two pieces of two prompts and a tile
+    of no piece side by side, each tile over its own prompt's pages (a
+    later piece over what an earlier launch cached), in float32: the kernel's
+    walk (interpreted) and the einsum walk give the same state and the same
+    served log-probabilities, and the device's sums say which walk ran."""
+    model = make_model(tmp_path, name="tiles", tile_rows=64, max_prompt_tokens=320)
+    monkeypatch.setattr(paged_lm, "KEY_BLOCK", 16 * PAGE)   # key blocks of 64 positions
+    params = model.init_params(jax.random.key(0))
+    prompts = [np.random.default_rng(1).integers(0, 96, n) for n in (300, 70, 150)]
+    news, chunk = [3, 4, 2], 256
+    packed = [[(0, 0, 128), (1, 0, 64)], [(0, 128, 128), (1, 64, 6), (2, 0, 64)],
+              [(2, 64, 86), (0, 256, 44)]]
+    assert model.kv_prefill_pieces(chunk, PAGE) == 4
+    plain, out, _ = serve(model, params, prompts, news, chunk=chunk, launches=packed)
+    acc = np.asarray(out["acc"]).astype(np.int64)
+    assert acc[0, 10] == 0 and acc[0, 11] == 2 + 1 + 2 + 1 + 1 + 2 + 1   # tiles of a piece
+    assert acc[1, 10] == 0 and acc[1, 11] == sum(n - 1 for n in news)     # live lanes a step
+    calls = steer_to_the_kernel(monkeypatch)
+    kernel, out, _ = serve(model, params, prompts, news, chunk=chunk, launches=packed)
+    assert len(calls) == 3 * 4   # traced once: a call a tile of a layer, side by side
+    acc = np.asarray(out["acc"]).astype(np.int64)
+    assert acc[0, 10] == 10 and acc[0, 11] == 0 and acc[1, 10] == 0
+    for a, b in zip(kernel, plain):
+        assert np.array_equal(a["tokens"], b["tokens"])
+        np.testing.assert_allclose(a["lp"], b["lp"], atol=5e-5)
+    for g in gaps(ARCH, prompts, kernel):
+        assert float(np.abs(g).max()) < 5e-5
 
 
 # -- (c) bfloat16: a tolerance that a lower-precision cache and a dropped rotary part fail ------

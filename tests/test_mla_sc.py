@@ -119,8 +119,8 @@ def test_chunked_prefill_then_grouped_decode_is_the_reference_one_causal_pass(
     acc = np.asarray(out["acc"]).astype(np.int64)
     tokens, steps = sum(len(p) for p in PROMPTS), sum(n - 1 for n in MAX_NEWS)
     for phase, n in ((0, tokens), (1, steps)):
-        assert acc[phase, 0] + acc[phase, 1] + acc[phase, 10] == 2 * K * n
-        assert acc[phase, 10] > 0
+        assert acc[phase, 0] + acc[phase, 1] + acc[phase, 12] == 2 * K * n
+        assert acc[phase, 12] > 0
     assert (acc[0, 1] == 0) == (case == "every-expert-held")
     assert acc[0, 4] == sum(n * (n + 1) // 2 for n in (19, 5, 11, 2))
     # rows attended and walked sum over the four attentions; a step is absorbed
@@ -128,6 +128,42 @@ def test_chunked_prefill_then_grouped_decode_is_the_reference_one_causal_pass(
         assert acc[0, 5] == 4 * sum(start + n for launch in PACKED for _s, start, n in launch)
     assert acc[1, 5] == 4 * acc[1, 4] > 0 and acc[1, 6] >= acc[1, 5]
     assert acc[1, 7] == max(MAX_NEWS) + 1 and acc[1, 8] == 0
+    # every tile of a piece and every live lane of a step walked in XLA (the CPU has no kernel)
+    if launches is not None:
+        assert acc[0, 10] == 0 and acc[0, 11] == 2 + 2 + 1 + 1 + 1 + 1 + 2 + 1
+    assert acc[1, 10] == 0 and acc[1, 11] == steps
+
+
+def test_prefill_through_the_kernels_walk_is_prefill_through_the_einsum_walk(tmp_path, monkeypatch):
+    """Tiles of 64 rows (past the toy's break-even: the expanded form) over key
+    blocks of 64 positions, launches of four tiles that carry pieces of
+    several prompts and later pieces over what earlier launches cached: with
+    every tile steered to the kernel (run in the Pallas interpreter; steered
+    here, in the test) the served tokens are the einsum walk's and the
+    log-probabilities stand within the family's tolerance, of each other and
+    of the reference; the device's sums say which walk ran."""
+    from tests.test_mla import steer_to_the_kernel
+
+    model = make_model(tmp_path, name="tiles", max_prompt_tokens=320)
+    model.TILE_ROWS, model.key_block = 64, 64
+    assert model._form(64) == "expanded" and model.kv_prefill_pieces(256, PAGE) == 4
+    params = model.init_params(jax.random.key(0))
+    prompts = [np.random.default_rng(1).integers(0, 64, n) for n in (300, 70, 150)]
+    news = [3, 4, 2]
+    packed = [[(0, 0, 128), (1, 0, 64)], [(0, 128, 128), (1, 64, 6), (2, 0, 64)],
+              [(2, 64, 86), (0, 256, 44)]]
+    plain, out, _ = serve(model, params, prompts, news, chunk=256, launches=packed, slots=SLOTS)
+    acc = np.asarray(out["acc"]).astype(np.int64)
+    assert acc[0, 10] == 0 and acc[0, 11] == 10
+    calls = steer_to_the_kernel(monkeypatch)
+    kernel, out, _ = serve(model, params, prompts, news, chunk=256, launches=packed, slots=SLOTS)
+    assert len(calls) == 4 * 4   # traced once: a call a tile of an attention, side by side
+    acc = np.asarray(out["acc"]).astype(np.int64)
+    assert acc[0, 10] == 10 and acc[0, 11] == 0 and acc[1, 10] == 0 and acc[1, 11] > 0
+    for a, b in zip(kernel, plain):
+        assert np.array_equal(a["tokens"], b["tokens"])
+        np.testing.assert_allclose(a["lp"], b["lp"], atol=TOL)
+    assert worst(ARCH, prompts, kernel) < TOL
 
 
 # -- (b) what may not be left out ---------------------------------------------------------------
@@ -267,9 +303,10 @@ def test_the_page_signature_holds_two_latent_rows_a_layer_and_the_attention_is_m
     sig = model.kv_page_signature(SLOTS, 9, PAGE)
     assert len(sig["ckv"]) == len(sig["kr"]) == 4
     assert sig["ckv"][3].shape == (9, PAGE, 32) and sig["kr"][3].shape == (9, 2, 128)
-    assert sig["acc"].shape == (2, 11) and model.kv_page_leaves == ("ckv", "kr")
+    assert sig["acc"].shape == (2, 13) and model.kv_page_leaves == ("ckv", "kr")
     # shared, not copied: the attention's functions are `mla.LatentServing`'s own
-    for name in ("_project", "_write_keys", "_attend_tile", "_form", "_attn_out"):
+    for name in ("_project", "_write_keys", "_attend_tile", "_attend_tiles", "_walk", "_form",
+                 "_attn_out"):
         assert getattr(type(model), name) is getattr(mla.LatentServing, name)
     assert model.q_scale == pytest.approx((64 / 48) ** 0.5) and model.kv_scale == pytest.approx(2 ** 0.5)
     fresh = build(model.cfg)

@@ -33,7 +33,7 @@ multiples of 128 stay as they lie. A prefill launch writes its rotary keys
 reads a lane's row of 128, sets its own part and writes the row back.
 
 TWO FORMS OF ONE FUNCTION, over the same walk of a block table in key blocks
-(``paged_lm._over_key_blocks``). *Absorbed*: ``q_lat_h = q_nope_h (W_kvb^K_h)^T``,
+(as many as a tile's last live position needs). *Absorbed*: ``q_lat_h = q_nope_h (W_kvb^K_h)^T``,
 ``score = [q_lat_h | q_rope_h] . [c_kv | k_r](s)``, ``o_h = (sum_s p c_kv(s))
 W_kvb^V_h``: the cache is read as it lies, every head over one row whose
 first ``kv_lora_rank`` columns are also the value; ``2 H (2 r + rope)``
@@ -44,6 +44,19 @@ operations at a tile's static width when the program is traced: a decode
 step (a tile of one query a lane) is absorbed; a prefill tile of
 ``TILE_ROWS`` rows is expanded, and that is why this family's tiles are that
 wide (at the published sizes the forms break even at 171 rows).
+
+WHERE THE WALK RUNS (``_walk``, chosen when a program is traced from what it
+observes: the form, the backend, the dtype and the shapes; no option). An
+expanded tile on the TPU in bfloat16 walks in ONE call of the Pallas kernel
+``ops/tile_attention.py``: the kernel's sequential grid axis runs over the
+tile's key blocks, it reads their pages from the two pools as they lie
+through the block-table row, makes ``k_nope`` and ``v`` on the chip, keeps the
+softmax's state and the accumulator in fast memory from the first block to
+the last and writes the tile's context once (ISSUE 43). Everything else (a
+step, the CPU, float32, a shape the kernel does not take) walks in XLA:
+``paged_lm._over_key_blocks``, a ``fori_loop`` of gathers and einsums under a
+running softmax, the exact fallback. ``mla_tiles_total{walk=kernel|xla}``
+counts a launch's tiles by which.
 
 FEED-FORWARD: the first ``first_k_dense_replace`` layers a dense SwiGLU of
 ``intermediate_size``; the others ``s = sigmoid(u W_r)`` in float32, the
@@ -84,15 +97,18 @@ DEFAULT_SCALES = {
     "router": 1.0, "router_bias": 0.02,
 }
 FORMS = ("absorbed", "expanded")
+WALKS = ("kernel", "xla")
 
 
 class LatentServing(PagedLM):
     # Device-side sums a phase: the expert layers' four and the context (as
     # ``decoder``), then cache rows attended over (each once a piece or a
     # lane: the least a launch reads), cache rows the walk gathered (whole key
-    # blocks a tile or a lane, a layer), launches by form, and expert layers
-    # whose dispatch took the compact branch (none where every expert is held).
-    ACC = 10
+    # blocks a tile or a lane, a layer), launches by form, expert layers whose
+    # dispatch took the compact branch (none where every expert is held), and
+    # the tiles whose walk over key blocks ran in the kernel and in XLA
+    # (``_walk``: a launch's tiles of a piece; a step's live lanes).
+    ACC = 12
     TILE_ROWS = KEY_BLOCK
     kv_page_leaves = ("ckv", "kr")
 
@@ -286,13 +302,26 @@ class LatentServing(PagedLM):
         mine = (jnp.arange(lanes)[None, :] // self.dr) == (off % g)[:, None]
         return flat.at[at].set(jnp.where(mine, jnp.tile(k_r, (1, g)), flat[at])).reshape(pool.shape)
 
+    def _walk(self, form: str, T: int, pools, pps: int) -> str:
+        """Where a tile of ``T`` queries walks its key blocks, chosen when the
+        program is traced: ``kernel`` (an expanded tile on the TPU at shapes
+        the kernel takes: one call a tile, ``ops/tile_attention.py``) or
+        ``xla`` (everything else: ``_over_key_blocks``)."""
+        ckv, kr = pools
+        P = ckv.shape[1]
+        if form == "expanded" and jax.default_backend() == "tpu" and ta.fits(  # tps-ok[TPS503]: at trace time
+                T, P, self._block_pages(P, pps), self.r, self.dn, self.dv, kr.shape[2], self.dtype):
+            return "kernel"
+        return "xla"
+
     def _attend_tile(self, lp: dict, qn, qr, pools, row, qpos, last, form: str):
         """One tile's attention: q_nope ``qn`` (T, H, nope) and rotated q_rope
         ``qr`` (T, H, rope) at positions ``qpos`` (T,), over the latent rows of
         its prompt's pages (``pools``: the layer's ``ckv`` and ``kr``;
         block-table row ``row``) up to position ``last``, in the ``form``
-        given -> (T, H, v) float32. Every row of the launch is in the pages
-        before any tile reads them."""
+        given -> (T, H, v): float32 from the walk in XLA, the served type from
+        the kernel (``_attn_out`` rounds to it either way). Every row of the
+        launch is in the pages before any tile reads them."""
         dt, r, h = self.dtype, self.r, self.heads
         ckv, kr = pools
         T, P = qn.shape[0], ckv.shape[1]
@@ -308,23 +337,16 @@ class LatentServing(PagedLM):
             return (jnp.take(ckv, pg, axis=0).reshape(c, r).astype(dt),
                     jnp.take(kr, pg, axis=0).reshape(c, self.dr).astype(dt))
 
-        # On the TPU the expanded form's scores stay on the chip: one kernel
-        # call a key block (``ops/tile_attention.py`` says why), its parts
-        # merged here. A tile's positions are consecutive, so ``qpos[0]`` less
-        # the block's first position is the causal offset.
-        if form == "expanded" and jax.default_backend() == "tpu" \
-                and ta.fits(T, c, self.dn + self.dr, self.dv, dt):  # tps-ok[TPS503]: at trace time
-            q = jnp.concatenate([qn, qr], axis=-1).transpose(1, 0, 2)
-
-            def whole_block(j):
-                c_kv, k_r = latents(j)
-                k_nope = jnp.einsum("cr,rhn->hcn", c_kv, lp["w_kb"], **f32).astype(dt)
-                val = jnp.einsum("cr,rhv->hcv", c_kv, lp["w_vb"], **f32).astype(dt)
-                keys = jnp.concatenate(
-                    [k_nope, jnp.broadcast_to(k_r[None], (h, c, self.dr))], axis=-1)
-                return ta.tile_attention(q, keys, val, qpos[0] - j * c, scale=scale)
-
-            return self._merge_key_blocks(need, (h, T), self.dv, whole_block).transpose(1, 0, 2)
+        # On the TPU an expanded tile's whole walk is ONE kernel call, which
+        # reads the pages through ``rowp`` and keeps the running softmax, the
+        # accumulator and the expanded keys on the chip
+        # (``ops/tile_attention.py``). A tile's positions are consecutive, so
+        # ``qpos[0]`` is all it needs of them.
+        if self._walk(form, T, pools, row.shape[0]) == "kernel":
+            q = jnp.concatenate([qn] + [qr] * (P // kr.shape[1]), axis=-1).transpose(1, 0, 2)
+            w_kvb = jnp.concatenate([lp["w_kb"], lp["w_vb"]], axis=-1).transpose(1, 0, 2)
+            return ta.tile_walk(q, w_kvb, ckv, kr, rowp, need, qpos[0], block_pages=kb,
+                                scale=scale)
 
         def block(j):
             c_kv, k_r = latents(j)
@@ -346,6 +368,26 @@ class LatentServing(PagedLM):
             return jnp.einsum("htr,rhv->thv", o.astype(dt), lp["w_vb"], **f32)
         return o.transpose(1, 0, 2)
 
+    def _attend_tiles(self, lp: dict, qn, qr, pools, t: dict, form: str):
+        """A launch's attention, tile by tile (``t``: ``_tiles``): ``qn`` (C, H,
+        nope), ``qr`` (C, H, rope) -> (C, H, v)."""
+        K, T = t["K"], t["T"]
+        tiles = (qn.reshape((K, T) + qn.shape[1:]), qr.reshape((K, T) + qr.shape[1:]),
+                 t["rows"], t["qpos"], t["last"])
+        one = lambda a: self._attend_tile(lp, *a[:2], pools, *a[2:], form)  # noqa: E731
+        if self._walk(form, T, pools, t["rows"].shape[1]) == "kernel":
+            # a kernel call a tile, side by side: nothing loops around them
+            return jnp.concatenate([one([v[k] for v in tiles]) for k in range(K)])
+        o = jax.lax.map(one, tiles)
+        return o.reshape((K * T,) + o.shape[2:])
+
+    def _tile_walks(self, t: dict, pools, form: str):
+        """A launch's tiles that belong to a piece, counted under the walk
+        they take: (kernel, xla)."""
+        tiles = jnp.sum(t["has"])
+        kernel = self._walk(form, t["T"], pools, t["rows"].shape[1]) == "kernel"
+        return (tiles, 0) if kernel else (0, tiles)
+
     def _attn_out(self, lp, o):
         return jnp.einsum("thv,hvd->td", o.astype(self.dtype), lp["wo"],
                           preferred_element_type=jnp.float32)
@@ -362,11 +404,16 @@ class LatentServing(PagedLM):
                                        lp["e_down"], live=live, of=self.n_experts)
         return y + self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"]), stats
 
-    def _accumulate(self, acc, phase: int, stats_list, context, attended, walked, form: str):
-        row = jnp.stack([jnp.asarray(v, jnp.int32) for v in (
-            *self._expert_sums(stats_list), context, attended, walked,
-            form == "absorbed", form == "expanded",
-            sum(st["compact"] for st in stats_list))])
+    def _sums(self, stats_list, context, attended, walked, form: str, walks) -> tuple:
+        """One launch's row of ``acc`` (``walks``: its tiles or lanes by walk,
+        (kernel, xla): every attention of a launch walks alike, so a tile
+        counts once)."""
+        return (*self._expert_sums(stats_list), context, attended, walked,
+                form == "absorbed", form == "expanded",
+                sum(st["compact"] for st in stats_list), *walks)
+
+    def _accumulate(self, acc, phase: int, *sums):
+        row = jnp.stack([jnp.asarray(v, jnp.int32) for v in self._sums(*sums)])
         return acc.at[phase].add(row.astype(jnp.uint32))
 
     # -- prefill ------------------------------------------------------------------
@@ -376,11 +423,10 @@ class LatentServing(PagedLM):
         piece and over the latent rows earlier launches left in that slot's
         pages."""
         t = self._tiles(launch, chunk)
-        K, T = t["K"], t["T"]
         start, length = launch["start"], launch["length"]
         valid, cpos = t["valid"], t["cpos"]
         P, pps = state["ckv"][0].shape[1], state["bt"].shape[1]
-        form = self._form(T)
+        form = self._form(t["T"])
         x = jnp.take(params["embed"], launch["ids"], axis=0)
         w_page, off = self._page_of(t, P, pps)
         ckv, kr, stats = list(state["ckv"]), list(state["kr"]), []
@@ -390,12 +436,7 @@ class LatentServing(PagedLM):
                 qn, qr, c_kv, k_r = self._project(lp, rms_norm(x, lp["norm1"], self.eps), cpos)
                 ckv[i] = self._write_pages(ckv[i], w_page, off, c_kv.astype(ckv[i].dtype))
                 kr[i] = self._write_keys(kr[i], w_page, off, k_r, runs=True)
-                o = jax.lax.map(
-                    lambda a, lp=lp, pools=(ckv[i], kr[i]): self._attend_tile(
-                        lp, *a[:2], pools, *a[2:], form),
-                    (qn.reshape((K, T) + qn.shape[1:]), qr.reshape((K, T) + qr.shape[1:]),
-                     t["rows"], t["qpos"], t["last"]))
-                y = self._attn_out(lp, o.reshape((K * T,) + o.shape[2:]))
+                y = self._attn_out(lp, self._attend_tiles(lp, qn, qr, (ckv[i], kr[i]), t, form))
             x = x + y.astype(self.dtype)
             y, st = self._ffn(lp, i, rms_norm(x, lp["norm2"], self.eps), valid)
             if st is not None:
@@ -404,7 +445,8 @@ class LatentServing(PagedLM):
         walked = jnp.sum(self._blocks_needed(t["last"], P, pps)) * self._block_pages(P, pps) * P
         new = dict(state, ckv=ckv, kr=kr, acc=self._accumulate(
             state["acc"], 0, stats, jnp.sum(jnp.where(valid, cpos + 1, 0)),
-            jnp.sum(jnp.where(length > 0, start + length, 0)), walked, form))
+            jnp.sum(jnp.where(length > 0, start + length, 0)), walked, form,
+            self._tile_walks(t, (ckv[0], kr[0]), form)))
         return self._arm(params, state, new, launch, t, x, {})
 
     # -- decode -------------------------------------------------------------------
@@ -439,7 +481,8 @@ class LatentServing(PagedLM):
         context = jnp.sum(jnp.where(live, pos + 1, 0))
         walked = jnp.sum(self._blocks_needed(last, P, bt.shape[1])) \
             * self._block_pages(P, bt.shape[1]) * P
-        acc = self._accumulate(state["acc"], 1, stats, context, context, walked, form)
+        acc = self._accumulate(state["acc"], 1, stats, context, context, walked, form,
+                               (0, jnp.sum(live)))
         return self._emit(params, state, dict(state, ckv=ckv, kr=kr), x, live, pos, acc)
 
     # -- host side ----------------------------------------------------------------
@@ -449,7 +492,9 @@ class LatentServing(PagedLM):
             metrics.counter(f"mla_rows_attended_total{{model={name},phase={ph}}}"),
             metrics.counter(f"mla_rows_walked_total{{model={name},phase={ph}}}"),
         ] + [metrics.counter(f"mla_launches_total{{model={name},phase={ph},form={form}}}")
-             for form in FORMS] + [self._compact_counter(metrics, ph)] for ph in GEN_PHASES]
+             for form in FORMS] + [self._compact_counter(metrics, ph)] + [
+            metrics.counter(f"mla_tiles_total{{model={name},phase={ph},walk={walk}}}")
+            for walk in WALKS] for ph in GEN_PHASES]
 
 
 def create(cfg: ModelConfig) -> LatentServing:

@@ -1,8 +1,9 @@
 """A decoder-only language model whose layers are DOUBLE and carry their
 routed experts on a SHORTCUT (ISSUE 42): two latent attentions (``mla``'s, by
-inheritance: its projections, its two page leaves, its two forms and its
-kernel) and two dense SwiGLUs a layer, and one routed layer that reads the
-first sublayer's normed stream and joins the stream at the layer's end.
+inheritance: its projections, its two page leaves, its two forms, its map over
+a launch's tiles and its kernel) and two dense SwiGLUs a layer, and one routed
+layer that reads the first sublayer's normed stream and joins the stream at
+the layer's end.
 Built from a published ``config.json`` and served through the generation
 engine as ``mla`` is. Nothing here knows a model's name.
 
@@ -44,9 +45,11 @@ norms are whole.
 
 TILES AND THE DECODE WALK. A prefill tile is ``TILE_ROWS`` = 256 rows (the
 forms break even at 171 at the published head sizes, so the expanded form and
-its kernel stay) over key blocks of ``key_block`` = 256 positions: a launch of
-1,024 rows carries up to four prompts' pieces, and a short prompt's tile reads
-two pages, not eight. A decode step attends its lanes IN GROUPS: the step
+its kernel stay: on the TPU one call of ``ops/tile_attention.py`` a tile walks
+the tile's key blocks, as in ``mla``, through ``mla``'s own ``_attend_tiles``)
+over key blocks of ``key_block`` = 256 positions: a launch of 1,024 rows
+carries up to four prompts' pieces, and a short prompt's tile reads two pages,
+not eight. A decode step attends its lanes IN GROUPS: the step
 runs in order of context length, ``DECODE_GROUP`` lanes walk the key blocks
 side by side as far as the longest of them needs, absorbed (``_attend_lanes``);
 ``mla`` walks its lanes one after another, which at hundreds of lanes and eight
@@ -75,9 +78,9 @@ DEFAULT_SCALES = {**mla.DEFAULT_SCALES, "router": 1.75}
 
 
 class ShortcutLatentServing(mla.LatentServing):
-    # ``mla``'s ten sums a phase and the live picks on zero-compute outputs.
+    # ``mla``'s twelve sums a phase and the live picks on zero-compute outputs.
     # Rows attended and walked sum over every attention that ran.
-    ACC = 11
+    ACC = 13
     TILE_ROWS = 256
     key_block = 256
     DECODE_GROUP = 32   # lanes that walk their key blocks side by side
@@ -255,14 +258,10 @@ class ShortcutLatentServing(mla.LatentServing):
                                 self._group_blocks(last, P, pps)))
         return jnp.einsum("bhr,rhv->bhv", o.reshape(B, h, r).astype(dt), lp["w_vb"], **f32)
 
-    def _accumulate(self, acc, phase: int, stats_list, context, attended, walked, form: str):
+    def _sums(self, stats_list, context, attended, walked, form: str, walks) -> tuple:
         n = 2 * self.n_layers
-        row = jnp.stack([jnp.asarray(v, jnp.int32) for v in (
-            *self._expert_sums(stats_list), context, n * attended, n * walked,
-            form == "absorbed", form == "expanded",
-            sum(st["compact"] for st in stats_list),
-            sum(st["routed_zero"] for st in stats_list))])
-        return acc.at[phase].add(row.astype(jnp.uint32))
+        return (*super()._sums(stats_list, context, n * attended, n * walked, form, walks),
+                sum(st["routed_zero"] for st in stats_list))
 
     # -- prefill ------------------------------------------------------------------
     def prefill_chunk(self, params: Any, state: Any, launch: Any, *, chunk: int) -> Any:
@@ -270,11 +269,10 @@ class ShortcutLatentServing(mla.LatentServing):
         within itself and over the latent rows earlier launches left in its
         slot's pages, through every layer's two attentions."""
         t = self._tiles(launch, chunk)
-        K, T = t["K"], t["T"]
         start, length = launch["start"], launch["length"]
         valid, cpos = t["valid"], t["cpos"]
         P, pps = state["ckv"][0].shape[1], state["bt"].shape[1]
-        form = self._form(T)
+        form = self._form(t["T"])
         x = jnp.take(params["embed"], launch["ids"], axis=0)
         w_page, off = self._page_of(t, P, pps)
         ckv, kr, stats = list(state["ckv"]), list(state["kr"]), []
@@ -284,11 +282,8 @@ class ShortcutLatentServing(mla.LatentServing):
                 qn, qr, c_kv, k_r = self._project(lp, u, cpos)
                 ckv[at] = self._write_pages(ckv[at], w_page, off, c_kv.astype(ckv[at].dtype))
                 kr[at] = self._write_keys(kr[at], w_page, off, k_r, runs=True)
-                o = jax.lax.map(
-                    lambda a: self._attend_tile(lp, *a[:2], (ckv[at], kr[at]), *a[2:], form),
-                    (qn.reshape((K, T) + qn.shape[1:]), qr.reshape((K, T) + qr.shape[1:]),
-                     t["rows"], t["qpos"], t["last"]))
-                return self._attn_out(lp, o.reshape((K * T,) + o.shape[2:]))
+                return self._attn_out(
+                    lp, self._attend_tiles(lp, qn, qr, (ckv[at], kr[at]), t, form))
 
         for i in range(self.n_layers):
             lp = params[f"layer{i}"]
@@ -298,7 +293,8 @@ class ShortcutLatentServing(mla.LatentServing):
         walked = jnp.sum(self._blocks_needed(t["last"], P, pps)) * self._block_pages(P, pps) * P
         new = dict(state, ckv=ckv, kr=kr, acc=self._accumulate(
             state["acc"], 0, stats, jnp.sum(jnp.where(valid, cpos + 1, 0)),
-            jnp.sum(jnp.where(length > 0, start + length, 0)), walked, form))
+            jnp.sum(jnp.where(length > 0, start + length, 0)), walked, form,
+            self._tile_walks(t, (ckv[0], kr[0]), form)))
         return self._arm(params, state, new, launch, t, x, {})
 
     # -- decode -------------------------------------------------------------------
@@ -335,7 +331,8 @@ class ShortcutLatentServing(mla.LatentServing):
         context = jnp.sum(jnp.where(live, pos + 1, 0))
         walked = jnp.sum(self._group_blocks(last, P, pps)) \
             * self._group(pos.shape[0]) * self._block_pages(P, pps) * P
-        acc = self._accumulate(state["acc"], 1, stats, context, context, walked, "absorbed")
+        acc = self._accumulate(state["acc"], 1, stats, context, context, walked, "absorbed",
+                               (0, jnp.sum(live)))
         x = jnp.take(x, jnp.argsort(order), axis=0)
         return self._emit(params, state, dict(state, ckv=ckv, kr=kr), x, live, pos, acc)
 

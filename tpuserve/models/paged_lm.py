@@ -5,9 +5,8 @@
 layers are: the model's ``config.json`` and what is served of it, the draw of
 every tensor by recipe, the per-lane block of the paged state, a launch's
 tile arithmetic (ISSUE 31's packed prefill), the walk of a block table in key
-blocks under a running softmax (``_key_blocks``, ``_over_key_blocks``,
-``_merge_key_blocks``: what attention by head and latent attention share,
-ISSUE 34), paged full attention
+blocks under a running softmax (``_key_blocks``, ``_over_key_blocks``: what
+attention by head and latent attention share, ISSUE 34), paged full attention
 by head for prefill tiles and for decode, page writes (rows by head or one
 latent row a token), the head, the sampler with its served
 log-probabilities, the arming of a lane and a step's token bookkeeping, the
@@ -438,26 +437,6 @@ class PagedLM(GenerativeModel):
             scale = jnp.exp(m - m2)
             acc = acc * scale[..., None] + weigh(p)
             return m2, l * scale + jnp.sum(p, axis=-1), acc
-
-        m0 = jnp.full(lead, NEG, jnp.float32)
-        _m, l, acc = jax.lax.fori_loop(
-            0, need, body, (m0, jnp.zeros_like(m0),
-                            jnp.zeros(lead + (width,), jnp.float32)))
-        return acc / l[..., None]
-
-    @staticmethod
-    def _merge_key_blocks(need, lead: tuple, width: int, block):
-        """As ``_over_key_blocks`` where a kernel attends a whole key block at
-        once: ``block(j)`` -> (the block's un-normalised context (*lead,
-        width) float32, its rows' max (*lead,) and sum (*lead,) float32).
-        A row that sees no key of a block comes with a max so low that the
-        block's part weighs nothing."""
-        def body(j, carry):
-            m, l, acc = carry
-            part, m_j, l_j = block(j)
-            m2 = jnp.maximum(m, m_j)
-            old, new = jnp.exp(m - m2), jnp.exp(m_j - m2)
-            return m2, l * old + l_j * new, acc * old[..., None] + part * new[..., None]
 
         m0 = jnp.full(lead, NEG, jnp.float32)
         _m, l, acc = jax.lax.fori_loop(
