@@ -4,8 +4,11 @@ that fill every slot to a context of `--context` tokens and decode steps of
 every lane over those contexts, on the cell's drawn weights, timed by the
 host's clock around a dependent read, then traced, with a table by operation
 of one launch and one step (each operation under the program's `mla_prefill`
-/ `mla_decode` scope or outside it, by what it is; the kernel that walks a
-prefill tile's key blocks, `tile_walk`, in a row of its own).
+/ `mla_decode` scope or outside it, by what it is; the kernels that walk a
+prefill tile's key blocks, `tile_walk`, and a step's lanes' key blocks,
+`lane_walk`, in rows of their own). The decode step and ONE attention of it
+alone are timed at BOTH walks, the kernel's and XLA's (`decode_walks`), each
+with the cache rows it walked over the rows attended.
 
     chiprun -- python scripts/bench_mla.py [--context 9216] [--chunk 2048]
     python scripts/bench_mla.py --rehearse --config benchmark/configs/rehearsal-mla-tiny.json
@@ -40,6 +43,7 @@ from tpuserve.genserve.model import PrefillPiece  # noqa: E402
 from tpuserve.models import build  # noqa: E402
 
 KINDS = (("tile_walk", "the tiles' walks of their key blocks (kernel tile_walk, in mla_prefill)"),
+         ("lane_walk", "the lanes' walks of their key blocks (kernel lane_walk, in mla_decode)"),
          ("mla_prefill", "latent attention (scope mla_prefill)"),
          ("mla_decode", "latent attention (scope mla_decode)"),
          ("gmm", "grouped products of the routed experts"), ("sort", "sort and un-sort of the picks"),
@@ -90,6 +94,84 @@ def by_operation(path: str, f) -> list[dict]:
     return out
 
 
+ALONE_REPS = 8   # attentions chained in one program when one is timed alone
+
+
+def xla_walk(cls):
+    """`cls` with a step's walk left in XLA where the kernel would take it
+    (steered here, in the script: the program has no such option)."""
+    class XlaWalk(cls):
+        def _walk(self, form, T, pools, pps):
+            return "xla" if form == "absorbed" else super()._walk(form, T, pools, pps)
+
+    return XlaWalk
+
+
+def decode_walks(models: dict, params, lp, state, iters: int, emit, on_tpu: bool):
+    """A decode step and ONE attention of it alone (the first attention's
+    weights `lp` and pools, drawn queries, every lane at the context `state`
+    holds), at each of `models` {what: model}: the family's own (the kernel
+    on the TPU) beside the walk in XLA. A row a model: the step's median, the
+    attention's (`ALONE_REPS` chained in one program; the kernel's with its
+    work list built inside; XLA's grouped walk on lanes sorted beforehand) and the rows the step walked over the
+    rows it attended, from the device's own sums. Every model steps from the
+    SAME lanes (positions, counts and flags are put back; the pools keep what
+    the steps wrote) -> (state, {what: jitted step})."""
+    steps = {}
+    lanes = {k: np.asarray(v) for k, v in state.items() if k not in ("ckv", "kr")}
+    for what, m in models.items():
+        state = dict(state, **{k: jnp.asarray(v) for k, v in lanes.items()})
+        live = state["armed"] & ~state["done"]
+        pos = jnp.clip(state["pos"], 0, m.max_ctx - 1)
+        last = jnp.where(live, pos, 0)
+        bt, pools = state["bt"], (state["ckv"][0], state["kr"][0])
+        b = pos.shape[0]
+        qn = jax.random.normal(jax.random.key(1), (b, m.heads, m.dn), m.dtype)
+        qr = jax.random.normal(jax.random.key(2), (b, m.heads, m.dr), m.dtype)
+        walk = m._step_walk(pools, bt, last)[0]
+        if walk == "kernel":
+            one = lambda lp, qn, qr, pools, bt, pos, last, m=m: m._walk_lanes(  # noqa: E731
+                lp, qn, qr, pools, m._step_walk(pools, bt, last)[1])
+        elif hasattr(m, "_attend_lanes"):
+            order = jnp.argsort(last)
+            qn, qr, bt, pos, last = (v[order] for v in (qn, qr, bt, pos, last))
+            one = m._attend_lanes
+        else:
+            one = lambda lp, qn, qr, pools, bt, pos, last, m=m: jax.lax.map(  # noqa: E731
+                lambda a: m._attend_tile(lp, *a[:2], pools, *a[2:], "absorbed"),
+                (qn[:, None], qr[:, None], bt, pos[:, None], last))
+
+        def alone(lp, qn, *rest, one=one):
+            """ALONE_REPS attentions in one program, each waiting for the one
+            before it: a single dispatch is longer than the attention."""
+            o = one(lp, qn, *rest)
+            for _ in range(ALONE_REPS - 1):
+                o = one(lp, qn + (jnp.sum(o) * 0).astype(qn.dtype), *rest)
+            return o
+
+        alone, att = jax.jit(alone), []
+        for _ in range(iters + 2):
+            t0 = time.perf_counter()
+            jax.block_until_ready(alone(lp, qn, qr, pools, bt, pos, last))
+            att.append((time.perf_counter() - t0) / ALONE_REPS)
+        fn = steps[what] = jax.jit(m.step, donate_argnums=(1,))
+        times = []
+        for _ in range(iters + 2):
+            t0 = time.perf_counter()
+            state, out = fn(params, state)
+            np.asarray(out["n_new"])
+            times.append(time.perf_counter() - t0)
+        moved = np.asarray(state["acc"]).astype(np.int64)[1] - lanes["acc"].astype(np.int64)[1]
+        row = {"case": f"decode step, {what}", "walk": walk, "lanes_live": int(jnp.sum(live)),
+               "rows_walked_over_attended": float(moved[6]) / max(1.0, float(moved[5])),
+               "lanes_by_walk": {"kernel": int(moved[10]), "xla": int(moved[11])}}
+        if on_tpu:
+            row.update(first_s=times[0], step_median_ms=statistics.median(times[2:]) * 1e3,
+                       attention_alone_median_ms=statistics.median(att[2:]) * 1e3)
+        emit(**row)
+    return state, steps
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default=os.path.join(
@@ -129,7 +211,6 @@ def main() -> None:
                                    model.kv_page_signature(slots, pages, P))
     prefill = jax.jit(lambda p, s, l: model.prefill_chunk(p, s, l, chunk=chunk),
                       donate_argnums=(1,))
-    step = jax.jit(model.step, donate_argnums=(1,))
     rng = np.random.default_rng(args.seed)
     need = -(-(context + sz["max_new"]) // P)
     items = []
@@ -166,16 +247,14 @@ def main() -> None:
             emit(case=f"prefill launch of {chunk} tokens at position {start}", launches=len(times),
                  median_ms=statistics.median(times[1:] if start == 0 else times) * 1e3,
                  **({"first_s": first} if start == 0 else {}))
-    times = []
-    for _ in range(args.iters + 2):
-        t0 = time.perf_counter()
-        state, out = step(params, state)
-        np.asarray(out["n_new"])
-        times.append(time.perf_counter() - t0)
-    assert int(np.sum(np.asarray(out["n_new"]) > 1)) == slots, "every lane decodes"
+    in_xla = xla_walk(type(model))(model.cfg)
+    state, steps = decode_walks(
+        {f"{slots} live lanes at context {context}, the family's own walk": model,
+         f"{slots} live lanes at context {context}, the walk left in XLA": in_xla},
+        params, params["layer0"], state, args.iters, emit, on_tpu)
+    step = next(iter(steps.values()))
+    assert int(np.sum(np.asarray(state["n_new"]) > 1)) == slots, "every lane decodes"
     if on_tpu:
-        emit(case=f"decode step, {slots} live lanes at context {context}", first_s=times[0],
-             median_ms=statistics.median(times[2:]) * 1e3)
         stats = jax.devices()[0].memory_stats() or {}
         emit(case="memory", peak_bytes_in_use=stats.get("peak_bytes_in_use"),
              peak_bytes_reserved=stats.get("peak_bytes_reserved"))

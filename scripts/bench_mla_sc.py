@@ -2,12 +2,15 @@
 """The mla_sc family's two programs alone, on the chip, at the cell's sizes and
 the mix's contexts: every slot filled with a prompt drawn from the mix's
 lengths and advanced a drawn part of its answer, then a decode step of every
-lane and packed prefill launches, each AT SEVERAL SETTINGS of what the family
-sets for itself (`DECODE_GROUP`, `key_block`, `TILE_ROWS`) and at `mla`'s own
-(lanes walked one after another over key blocks of 1,024; one prompt a launch
-in a tile as wide as the launch), timed by the host's clock around a dependent
-read; then one trace, with a table by operation of one step and one launch at
-the family's own settings (`scripts/bench_mla.py` `by_operation`).
+lane and ONE attention of it alone at BOTH walks (the kernel's, at several
+cells, beside XLA's grouped walk, at several groupings, and `mla`'s lanes one
+after another: `scripts/bench_mla.py` `decode_walks`, each row with the cache
+rows walked over the rows attended) and packed prefill launches AT SEVERAL
+SETTINGS of what the family sets for itself (`key_block`, `TILE_ROWS`) and at
+`mla`'s own (one prompt a launch in a tile as wide as the launch), timed by the
+host's clock around a dependent read; then one trace, with a table by
+operation of one step and one launch at the family's own settings
+(`scripts/bench_mla.py` `by_operation`).
 
     chiprun -- python scripts/bench_mla_sc.py [--only step|prefill]
     python scripts/bench_mla_sc.py --rehearse --config benchmark/configs/rehearsal-mla_sc-tiny.json
@@ -43,9 +46,12 @@ from tpuserve.models import mla_sc  # noqa: E402
 bench_mla.KINDS = (("moe_layer", "the routed layer (scope moe_layer)"),) + bench_mla.KINDS
 
 
-class LaneByLane(mla_sc.ShortcutLatentServing):
-    """`mla`'s decode walk under this family's layer: the lanes one after
-    another, each over key blocks of `key_block` positions."""
+InXla = bench_mla.xla_walk(mla_sc.ShortcutLatentServing)
+
+
+class LaneByLane(InXla):
+    """`mla`'s decode walk in XLA under this family's layer: the lanes one
+    after another, each over key blocks of `key_block` positions."""
 
     def _attend_lanes(self, lp, qn, qr, pools, bt, pos, last):
         return jax.lax.map(
@@ -169,30 +175,24 @@ def main() -> None:
     print(f"contexts: mean {context.mean():.0f}, median {np.median(context):.0f}, "
           f"max {context.max()}", flush=True)
 
-    def timed_step(m, what):
-        nonlocal state
-        fn = jax.jit(m.step, donate_argnums=(1,))
-        times = []
-        for _ in range(args.iters + 2):
-            t0 = time.perf_counter()
-            state, out = fn(params, state)
-            np.asarray(out["n_new"])
-            times.append(time.perf_counter() - t0)
-        if on_tpu:
-            emit(case=f"decode step, {what}", first_s=times[0],
-                 median_ms=statistics.median(times[2:]) * 1e3)
-        return fn
-
     own_step = None
     if args.only != "prefill":
-        own_step = timed_step(model, f"the family's own: groups of {model.DECODE_GROUP} lanes, "
-                              f"key blocks of {model.key_block}")
-        if not args.rehearse:
-            for g, kb in ((64, 256), (16, 256), (32, 128), (32, 512), (128, 256)):
-                timed_step(make(DECODE_GROUP=g, key_block=kb),
-                           f"groups of {g} lanes, key blocks of {kb}")
-        timed_step(make(LaneByLane, key_block=None if not args.rehearse else P),
-                   "mla's walk: lane by lane, key blocks of 1,024")
+        walks = {f"the family's own walk, cells of {model.step_keys} keys": model,
+                 f"the walk left in XLA: groups of {model.DECODE_GROUP} lanes, key blocks of "
+                 f"{model.key_block}": make(InXla)}
+        if args.rehearse:
+            walks["mla's walk in XLA: lane by lane"] = make(LaneByLane, key_block=P)
+        else:
+            for keys in (256, 1024):
+                walks[f"the family's own walk, cells of {keys} keys"] = make(step_keys=keys)
+            for g, kb in ((64, 256), (16, 256), (32, 512)):
+                walks[f"the walk left in XLA: groups of {g} lanes, key blocks of {kb}"] = make(
+                    InXla, DECODE_GROUP=g, key_block=kb)
+            walks["mla's walk in XLA: lane by lane, key blocks of 1,024"] = make(
+                LaneByLane, key_block=None)
+        state, steps = bench_mla.decode_walks(walks, params, params["layer0"]["attn0"], state,
+                                              args.iters, emit, on_tpu)
+        own_step = next(iter(steps.values()))
     if args.only != "step" and not args.rehearse:
         timed_prefill(make(), 2 * chunk, "twice the width")
         timed_prefill(make(TILE_ROWS=chunk, key_block=None), chunk,

@@ -436,6 +436,72 @@ def test_a_prefill_tiles_walk_is_one_kernel_call_on_the_v5e_at_the_cells_widths(
                 if " copy(" in ln and (f"{pages},128,512" in ln or f"{pages},64,128" in ln)]
 
 
+@pytest.mark.parametrize("family,heads,lanes,block_pages,pps,pages", [
+    ("mla", 32, 16, 8, 194, 3200), ("mla_sc", 64, 256, 4, 22, 2048)])
+def test_a_steps_walk_is_one_kernel_call_an_attention_on_the_v5e_at_the_cells_widths(
+        one_chip, tmp_path, monkeypatch, family, heads, lanes, block_pages, pps, pages):
+    """`ops/lane_attention.py` under the families' step (ISSUE 44), at the two
+    cells' sizes: JoyAI's 16 lanes of 32 heads over cells of 8 pages,
+    LongCat's 256 lanes of 64 heads over cells of 4, a latent row of 512
+    and two rotary keys of 64 side by side. The TPU branch is steered by the
+    backend's name here, in the test. Mosaic takes the kernel (the pools
+    passed once a page of a block, the work list by scalar prefetch, its
+    length a traced grid bound, the rotary keys laid out a position a row by
+    strided stores); one attention of a step is ONE custom call
+    and no `while` at all (none over lanes, groups or key blocks), nothing
+    float32 by lane, head and key (a block's scores) is in the program, and
+    no copy of a pool."""
+    import json
+
+    from tpuserve.config import ModelConfig
+    from tpuserve.models import build
+
+    arch = {"vocab_size": 256, "hidden_size": 1024, "num_attention_heads": heads,
+            "q_lora_rank": 256, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+            "qk_rope_head_dim": 64, "v_head_dim": 128}
+    arch.update({"num_hidden_layers": 1, "intermediate_size": 256, "first_k_dense_replace": 1}
+                if family == "mla" else
+                {"num_layers": 1, "ffn_hidden_size": 256, "expert_ffn_hidden_size": 256,
+                 "n_routed_experts": 8, "zero_expert_num": 4, "moe_topk": 2,
+                 "attention_method": "MLA"})
+    path = tmp_path / "arch.json"
+    path.write_text(json.dumps(arch))
+    model = build(ModelConfig(name="walk", family=family, dtype="bfloat16", batch_buckets=[1],
+                              options={"config_file": str(path),
+                                       "max_prompt_tokens": pps * 128 - 768,
+                                       "max_new_tokens": 768}))
+    assert model._form(1) == "absorbed" and model.kv_pages_per_slot(128) == pps \
+        and model.step_keys // 128 == block_pages
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    lp = {"w_kb": shape(512, heads, 128), "w_vb": shape(512, heads, 128)}
+
+    def attend(lp, qn, qr, ckv, kr, bt, last):
+        walk, work, _ = model._step_walk((ckv, kr), bt, last)
+        assert walk == "kernel"
+        return model._walk_lanes(lp, qn, qr, (ckv, kr), work)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
+    try:
+        text = jax.jit(attend).lower(
+            lp, shape(lanes, heads, 128), shape(lanes, heads, 64), shape(pages, 128, 512),
+            shape(pages, 64, 128), shape(lanes, pps, dtype=jnp.int32),
+            shape(lanes, dtype=jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    calls = [ln for ln in text.split("\n") if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "lane_walk" in calls[0]
+    assert " while(" not in text
+    keys = block_pages * 128
+    for scores in (f"f32[{lanes},{heads},{keys}]", f"f32[{heads},{keys}]"):
+        assert scores not in text
+    assert not [ln for ln in text.split("\n")
+                if " copy(" in ln and (f"{pages},128,512" in ln or f"{pages},64,128" in ln)]
+
+
 def test_decode_over_packed_pages_compiles_for_the_v5e_at_the_cells_widths(one_chip, tmp_path,
                                                                            monkeypatch):
     """`paged_lm._decode_full` over pools whose rows hold two KV heads of 64

@@ -374,6 +374,226 @@ def test_a_launchs_tiles_walk_different_block_table_rows_in_the_kernel(tmp_path,
         assert float(np.abs(g).max()) < 5e-5
 
 
+# -- (b2) a step's walk: every lane's own key blocks in one kernel call (ISSUE 44) -----------------
+
+def lane_case(h, page, kb, pps, lasts, live, dtype=jnp.float32, widths=(32, 8), seed=0):
+    """The decode kernel's arguments for lanes whose last positions are
+    ``lasts`` (``live`` false: a lane that is not live, which the step gives
+    position 0), over pools of pages of ``page`` positions (two positions'
+    rotary keys a row) and a shuffled block table of ``pps`` pages a lane in
+    key blocks of ``kb`` pages, and the plain float32 absorbed attention of
+    each lane over its own latent rows as they lie."""
+    from tpuserve.ops import lane_attention as la
+
+    r, dr = widths
+    rng = np.random.default_rng(seed)
+    b = len(lasts)
+    pages = b * pps + 1
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)  # noqa: E731
+    ql, qr = draw(b, h, r), draw(b, h, dr)
+    ckv, kr = draw(pages, page, r), draw(pages, page // 2, 2 * dr)
+    bt = jnp.asarray(rng.permutation(pages - 1)[: b * pps].reshape(b, pps) + 1, jnp.int32)
+    last = jnp.asarray(np.where(live, lasts, 0), jnp.int32)
+    work = la.work_list(last, bt, page, kb)
+    scale = (r + dr) ** -0.5
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    want = []
+    for lane in range(b):
+        n = int(last[lane]) + 1
+        c_kv = f32(ckv[bt[lane]]).reshape(-1, r)[:n]
+        k_r = f32(kr[bt[lane]]).reshape(-1, dr)[:n]
+        s = (f32(ql[lane]) @ c_kv.T + f32(qr[lane]) @ k_r.T) * scale
+        want.append(jax.nn.softmax(s, -1) @ c_kv)
+    return (ql, jnp.concatenate([qr, qr], axis=-1), ckv, kr, work), scale, bt, \
+        np.asarray(jnp.stack(want))
+
+
+# (heads, page, pages a key block, pages a lane): the two cells' shapes at toy widths
+LANE_SHAPES = {"32-heads-8-pages-a-cell": (32, 16, 8, 20), "64-heads-2-pages-a-cell": (64, 16, 2, 11)}
+# the lane under test among neighbours of other lengths, one of them not live
+LANE_ENDS = {"one-key": lambda P, c, n: 0, "a-page-less-one": lambda P, c, n: P - 2,
+             "exactly-a-page": lambda P, c, n: P - 1, "a-page-plus-one": lambda P, c, n: P,
+             "several-key-blocks": lambda P, c, n: 2 * c + 3,
+             "the-longest-the-table-holds": lambda P, c, n: n - 1}
+
+
+@pytest.mark.parametrize("lane", list(LANE_ENDS))
+@pytest.mark.parametrize("shape", list(LANE_SHAPES))
+def test_the_decode_kernel_is_each_lanes_attention_over_its_own_pages_as_they_lie(shape, lane):
+    """``ops/lane_attention.py`` in the Pallas interpreter against plain float32
+    absorbed attention over the pools as they lie: lanes of very different
+    lengths side by side in ONE call, each walking its own key blocks and no
+    further (the running softmax from a lane's first block to its last, the
+    mask inside the last), the rotary keys two positions a row, a lane that
+    is not live among them (one item; its row comes back finite). In float32
+    the kernel rounds nothing, so it stands within float32's sums in another
+    order."""
+    from tpuserve.ops import lane_attention as la
+
+    h, page, kb, pps = LANE_SHAPES[shape]
+    c, most = kb * page, pps * page
+    lasts = np.array([most // 2, LANE_ENDS[lane](page, c, most), 5, c, most - 1, 3])
+    live = np.array([True, True, False, True, True, True])
+    args, scale, _, want = lane_case(h, page, kb, pps, lasts, live)
+    got = la.lane_walk(*args, scale=scale, interpret=True)
+    assert got.shape == want.shape and got.dtype == args[0].dtype
+    assert bool(jnp.all(jnp.isfinite(got)))   # the lane that is not live too
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+    assert int(args[4]["items"]) == int(np.sum(np.where(live, lasts, 0) // c + 1))
+
+
+@pytest.mark.parametrize("shape", list(LANE_SHAPES))
+def test_the_decode_kernel_in_bfloat16_rounds_as_the_walk_in_xla_does(shape):
+    """At whole 128-lane widths in bfloat16 (what ``fits`` takes): the
+    probabilities round into the second product and the context to the
+    served type, a few thousandths."""
+    from tpuserve.ops import lane_attention as la
+
+    h, page, kb, pps = LANE_SHAPES[shape]
+    lasts = np.array([pps * page - 1, 0, page, 7])
+    args, scale, _, want = lane_case(h, page, kb, pps, lasts, np.array([True, True, True, False]),
+                                     jnp.bfloat16, (128, 64))
+    got = la.lane_walk(*args, scale=scale, interpret=True)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)), want, atol=1e-2)
+    assert la.fits(128, 512, 128, jnp.bfloat16) and la.fits(16, 128, 128, jnp.bfloat16) \
+        and not la.fits(128, 512, 128, jnp.float32) and not la.fits(8, 128, 128, jnp.bfloat16) \
+        and not la.fits(16, 32, 128, jnp.bfloat16) and not la.fits(16, 128, 64, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("kb", [1, 2, 8])
+def test_the_work_list_is_every_lanes_own_blocks_and_nothing_else(kb):
+    """The list a step builds once: lane after lane, a lane's key blocks in
+    order; its length the sum of the live lanes' own blocks and ONE item a
+    lane that is not live (its row's first block: nothing uninitialised reaches
+    the stream); an item's pages the lane's own, and page 0 where a page lies
+    whole past the lane's position; past its length only padding a cell never
+    reads."""
+    from tpuserve.ops import lane_attention as la
+
+    page, pps = 16, 11
+    lasts = np.array([0, 15, 16, 100, 175, 47, 31])
+    live = np.array([True, True, True, True, True, False, True])
+    last = np.where(live, lasts, 0)
+    bt = np.arange(1, 1 + len(lasts) * pps, dtype=np.int32).reshape(len(lasts), pps)
+    work = jax.tree_util.tree_map(np.asarray, la.work_list(jnp.asarray(last), jnp.asarray(bt),
+                                                           page, kb))
+    need = last // (kb * page) + 1
+    n = int(work["items"])
+    assert n == need.sum() == need[live].sum() + 1
+    assert work["lane"].shape == work["block"].shape == (len(lasts) * -(-pps // kb),)
+    np.testing.assert_array_equal(work["lane"][:n], np.repeat(np.arange(len(lasts)), need))
+    np.testing.assert_array_equal(work["block"][:n], np.concatenate([np.arange(k) for k in need]))
+    pages = work["pages"].reshape(-1, kb)
+    for item in range(n):
+        lane, at = work["lane"][item], work["block"][item] * kb + np.arange(kb)
+        mine = np.pad(bt[lane], (0, -pps % kb))[at]
+        np.testing.assert_array_equal(pages[item], np.where(at * page <= last[lane], mine, 0))
+    assert work["lane"].max() < len(lasts) and work["block"].max() < -(-pps // kb) \
+        and 0 <= pages.min() and pages.max() <= bt.max()
+
+
+class NamedTpu:
+    """``jax`` as ``mla`` sees it with the backend named ``tpu``: the family's
+    trace-time choice (``_walk``) takes its TPU branch, and nothing else does
+    (the name itself would steer the experts' kernels too)."""
+
+    default_backend = staticmethod(lambda: "tpu")
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+
+def on_the_tpu_with_the_kernels_interpreted(monkeypatch):
+    """-> a list that grows by one each time a call of the decode kernel is
+    traced."""
+    import functools
+
+    from tpuserve.ops import lane_attention as la
+
+    calls = []
+    monkeypatch.setattr(mla, "jax", NamedTpu())
+    monkeypatch.setattr(la, "lane_walk", functools.partial(
+        lambda *a, f=la.lane_walk, **k: calls.append(1) or f(*a, interpret=True, **k)))
+    return calls
+
+
+def steps_both_walks(model, params, prompts, news, monkeypatch, slots, page, steps=4):
+    """Prefill once (XLA), then ``steps`` steps: each step both ways FROM THE
+    SAME STATE, the walk in XLA and (backend named ``tpu``, kernel
+    interpreted) the walk the family chooses -> per step (XLA's, the other's)
+    (state, out), and the kernel calls traced a trace of the step."""
+    _, _, state = serve(model, params, prompts, news, chunk=page, slots=slots, steps=0, page=page)
+    plain, before, plains = jax.jit(model.step), [state], []
+    for _ in range(steps):   # traced and run before anything is steered
+        plains.append(plain(params, before[-1]))
+        before.append(plains[-1][0])
+    with monkeypatch.context() as m:
+        calls, traces = on_the_tpu_with_the_kernels_interpreted(m), []
+        steered = jax.jit(lambda p, s: traces.append(1) or model.step(p, s))
+        pairs = [(a, steered(params, s)) for a, s in zip(plains, before)]
+    return pairs, len(calls) / len(traces)
+
+
+LANE_ARCH = dict(ARCH, num_attention_heads=4, kv_lora_rank=128, qk_rope_head_dim=64)
+
+
+def same_step(pairs, lanes_live, atol=3e-2):
+    """The steered step is the XLA step: the same tokens' log-probabilities
+    and pools to bfloat16's rounding (the kernel sums a lane's blocks in
+    float32 in another order; its output rounds to the served type as the
+    walk's does), the same positions and flags to the bit."""
+    for (sa, oa), (sb, ob) in pairs:
+        for key in ("pos", "n_new", "done", "armed"):
+            np.testing.assert_array_equal(np.asarray(sa[key]), np.asarray(sb[key]))
+        np.testing.assert_allclose(np.asarray(sa["lp"]), np.asarray(sb["lp"]), atol=atol)
+        for a, b in zip(sa["ckv"] + sa["kr"], sb["ckv"] + sb["kr"]):
+            np.testing.assert_allclose(np.asarray(a.astype(jnp.float32)),
+                                       np.asarray(b.astype(jnp.float32)), atol=atol)
+        np.testing.assert_array_equal(np.asarray(oa["n_new"]), np.asarray(ob["n_new"]))
+    first_x, first_k = (np.asarray(s["acc"]).astype(np.int64) for s, _ in pairs[0])
+    assert first_x[1, 10] == 0 and first_x[1, 11] == lanes_live
+    return first_k
+
+
+def test_on_the_tpu_a_step_walks_every_lane_in_one_kernel_call_an_attention(tmp_path, monkeypatch):
+    """With the backend named `tpu` and the kernel run in the interpreter, a
+    step of a bfloat16 model at widths the kernel takes is ONE kernel call a
+    layer for every lane (contexts of 40, 9 and 21 tokens over key blocks of
+    two pages, a fourth lane never armed), the XLA step's state and
+    log-probabilities to bfloat16's rounding, and the device's sums count
+    every live lane under `walk=kernel`, none under `xla`, and the cache rows
+    of each lane's OWN whole key blocks."""
+    model = make_model(tmp_path, LANE_ARCH, name="lanes", dtype="bfloat16", max_prompt_tokens=48)
+    monkeypatch.setattr(paged_lm, "KEY_BLOCK", 32)
+    model.step_keys = 32   # the kernel's cells as wide, steered here as the tiles are
+    params = model.init_params(jax.random.key(0))
+    prompts = [np.random.default_rng(3).integers(0, 96, n) for n in (40, 9, 21)]
+    pairs, calls = steps_both_walks(model, params, prompts, [8, 8, 8], monkeypatch, 4, 16)
+    assert calls == model.n_layers   # a call an attention
+    acc = same_step(pairs, 3)
+    assert acc[1, 10] == 3 and acc[1, 11] == 0 and acc[1, 7] == 1
+    assert acc[1, 5] == 41 + 10 + 22 and acc[1, 6] == (2 + 1 + 1 + 1) * 32
+
+
+@pytest.mark.parametrize("refused", ["float32", "a-page-of-8"])
+def test_a_shape_the_decode_kernel_refuses_walks_in_xla_and_counts_there(
+        tmp_path, monkeypatch, refused):
+    """The backend named `tpu`, a shape `fits` does not take: the step is the
+    XLA step to the bit, no kernel call is traced, the lanes count under
+    `walk=xla`."""
+    dtype, page = ("float32", 16) if refused == "float32" else ("bfloat16", 8)
+    model = make_model(tmp_path, LANE_ARCH, name="refused", dtype=dtype, max_prompt_tokens=48)
+    monkeypatch.setattr(paged_lm, "KEY_BLOCK", 32)
+    model.step_keys = 32
+    params = model.init_params(jax.random.key(0))
+    prompts = [np.random.default_rng(3).integers(0, 96, n) for n in (40, 9)]
+    pairs, calls = steps_both_walks(model, params, prompts, [8, 8], monkeypatch, 3, page, steps=2)
+    assert not calls
+    acc = same_step(pairs, 2, atol=0)
+    assert acc[1, 10] == 0 and acc[1, 11] == 2
+
+
 # -- (c) bfloat16: a tolerance that a lower-precision cache and a dropped rotary part fail ------
 
 def test_bfloat16_serves_within_a_tolerance_that_a_lower_cache_or_no_rotary_part_fails(tmp_path):

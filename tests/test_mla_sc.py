@@ -432,3 +432,53 @@ def test_through_the_engine_requests_move_the_counters_by_what_was_served(tmp_pa
     row = 32 + 64
     assert stats["kv"]["row_bytes_per_token"] == 4 * row * 4   # four attentions, one row each
     assert metrics.gauge("gen_kv_row_bytes{model=eng}").value == 4 * row * 4
+
+
+# -- (h) a step's walk in the kernel (ISSUE 44) ------------------------------------------------------
+
+LANE_ARCH = dict(ARCH, kv_lora_rank=128)
+
+
+def test_on_the_tpu_a_step_walks_every_lane_in_one_kernel_call_an_attention(tmp_path, monkeypatch):
+    """With the backend named `tpu` and the kernel run in the interpreter, a
+    step of a bfloat16 model at widths the kernel takes is ONE call of
+    `ops/lane_attention.py` an attention (four: two layers of two) for every
+    lane in the lanes' OWN order (nothing is sorted, nothing permuted back):
+    contexts of 40, 9, 21 and 10 tokens over key blocks of two pages, two
+    lanes never armed. It is the grouped XLA step's state and log-probabilities
+    to bfloat16's rounding, and the device's sums count every live lane under
+    `walk=kernel`, none under `xla`, and each lane's own whole key blocks
+    where the groups walked as far as their longest lane."""
+    from tests.test_mla import same_step, steps_both_walks
+
+    model = make_model(tmp_path, LANE_ARCH, name="lanes", dtype="bfloat16", max_prompt_tokens=48)
+    model.key_block = model.step_keys = 32
+    params = model.init_params(jax.random.key(0))
+    prompts = [np.random.default_rng(3).integers(0, 64, n) for n in (40, 9, 21, 10)]
+    pairs, calls = steps_both_walks(model, params, prompts, [8] * 4, monkeypatch, 6, 16)
+    assert calls == 2 * model.n_layers
+    acc = same_step(pairs, 4)
+    assert acc[1, 10] == 4 and acc[1, 11] == 0 and acc[1, 7] == 1
+    own = (2 + 1 + 1 + 1 + 1 + 1) * 32   # a lane that is not live: one block
+    assert acc[1, 5] == 4 * (41 + 10 + 22 + 11) and acc[1, 6] == 4 * own
+    grouped = np.asarray(pairs[0][0][0]["acc"]).astype(np.int64)   # pairs in order of context
+    assert grouped[1, 6] == 4 * 2 * (1 + 1 + 2) * 32 > acc[1, 6]
+
+
+@pytest.mark.parametrize("refused", ["float32", "a-page-of-8"])
+def test_a_shape_the_decode_kernel_refuses_walks_in_groups_and_counts_there(
+        tmp_path, monkeypatch, refused):
+    """The backend named `tpu`, a shape `fits` does not take: the step is the
+    grouped XLA step to the bit, no kernel call is traced, the lanes count
+    under `walk=xla`."""
+    from tests.test_mla import same_step, steps_both_walks
+
+    dtype, page = ("float32", 16) if refused == "float32" else ("bfloat16", 8)
+    model = make_model(tmp_path, LANE_ARCH, name="refused", dtype=dtype, max_prompt_tokens=48)
+    model.key_block = model.step_keys = 32
+    params = model.init_params(jax.random.key(0))
+    prompts = [np.random.default_rng(3).integers(0, 64, n) for n in (40, 9)]
+    pairs, calls = steps_both_walks(model, params, prompts, [8, 8], monkeypatch, 4, page, steps=2)
+    assert not calls
+    acc = same_step(pairs, 2, atol=0)
+    assert acc[1, 10] == 0 and acc[1, 11] == 2

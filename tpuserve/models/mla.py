@@ -46,17 +46,27 @@ step (a tile of one query a lane) is absorbed; a prefill tile of
 wide (at the published sizes the forms break even at 171 rows).
 
 WHERE THE WALK RUNS (``_walk``, chosen when a program is traced from what it
-observes: the form, the backend, the dtype and the shapes; no option). An
-expanded tile on the TPU in bfloat16 walks in ONE call of the Pallas kernel
+observes: the form, the tile's width, the backend, the dtype and the shapes;
+no option). On the TPU in bfloat16, at shapes the kernels take, both walks
+stay on the chip and read the pages of the two pools as they lie through the
+block table, each page once. An EXPANDED TILE walks in ONE call of
 ``ops/tile_attention.py``: the kernel's sequential grid axis runs over the
-tile's key blocks, it reads their pages from the two pools as they lie
-through the block-table row, makes ``k_nope`` and ``v`` on the chip, keeps the
+tile's key blocks, it makes ``k_nope`` and ``v`` on the chip, keeps the
 softmax's state and the accumulator in fast memory from the first block to
-the last and writes the tile's context once (ISSUE 43). Everything else (a
-step, the CPU, float32, a shape the kernel does not take) walks in XLA:
-``paged_lm._over_key_blocks``, a ``fori_loop`` of gathers and einsums under a
-running softmax, the exact fallback. ``mla_tiles_total{walk=kernel|xla}``
-counts a launch's tiles by which.
+the last and writes the tile's context once (ISSUE 43). A STEP walks in ONE
+call an attention of ``ops/lane_attention.py`` for every lane (ISSUE 44): the
+step builds, once for all its attentions, the list of (lane, key block) items
+that exist (``_step_walk``: each lane as far as ITS position needs), the
+kernel's one sequential grid axis runs over that list, a lane's softmax state
+and its (H, r) float32 accumulator stay in fast memory from its first block
+to its last, and a page's latents serve both products; ``q_lat = q_nope
+W_kb^T`` before the call and ``ctx W_vb`` after it are whole-batch products
+in XLA (``_walk_lanes``). Everything else (the CPU, float32, a shape a kernel
+does not take) walks in XLA: ``paged_lm._over_key_blocks``, a ``fori_loop`` of
+gathers and einsums under a running softmax, one a tile and, in a step, one a
+lane after another: the exact fallbacks.
+``mla_tiles_total{phase=,walk=kernel|xla}`` counts a launch's tiles and a
+step's live lanes by which.
 
 FEED-FORWARD: the first ``first_k_dense_replace`` layers a dense SwiGLU of
 ``intermediate_size``; the others ``s = sigmoid(u W_r)`` in float32, the
@@ -83,6 +93,7 @@ from tpuserve.models.decoder import apply_rope, rope_inv_freq
 from tpuserve.models.paged_lm import (KEY_BLOCK, LOGPROBS, NEG, PagedLM,  # noqa: F401
                                       _mm, read_config_file, rms_norm)
 from tpuserve.obs import GEN_PHASES
+from tpuserve.ops import lane_attention as la
 from tpuserve.ops import tile_attention as ta
 from tpuserve.ops.moe import held_experts_swiglu, topk_route
 
@@ -110,6 +121,10 @@ class LatentServing(PagedLM):
     # (``_walk``: a launch's tiles of a piece; a step's live lanes).
     ACC = 12
     TILE_ROWS = KEY_BLOCK
+    # Key positions a cell of the step's kernel walks (``ops/lane_attention.py``
+    # says what a cell costs): at contexts of thousands a prefill tile's key
+    # block, and anything from there up reads alike (PERF.md section 6, PR 44).
+    step_keys = KEY_BLOCK
     kv_page_leaves = ("ckv", "kr")
 
     def __init__(self, cfg: ModelConfig) -> None:
@@ -304,15 +319,21 @@ class LatentServing(PagedLM):
 
     def _walk(self, form: str, T: int, pools, pps: int) -> str:
         """Where a tile of ``T`` queries walks its key blocks, chosen when the
-        program is traced: ``kernel`` (an expanded tile on the TPU at shapes
-        the kernel takes: one call a tile, ``ops/tile_attention.py``) or
-        ``xla`` (everything else: ``_over_key_blocks``)."""
+        program is traced: ``kernel`` (on the TPU at shapes a kernel takes: an
+        expanded tile, one call a tile, ``ops/tile_attention.py``; a step's
+        absorbed tiles of one query, one call an attention for every lane,
+        ``ops/lane_attention.py``) or ``xla`` (everything else:
+        ``_over_key_blocks``)."""
         ckv, kr = pools
         P = ckv.shape[1]
-        if form == "expanded" and jax.default_backend() == "tpu" and ta.fits(  # tps-ok[TPS503]: at trace time
-                T, P, self._block_pages(P, pps), self.r, self.dn, self.dv, kr.shape[2], self.dtype):
-            return "kernel"
-        return "xla"
+        if jax.default_backend() != "tpu":  # tps-ok[TPS503]: at trace time
+            return "xla"
+        if form == "expanded":
+            fits = ta.fits(T, P, self._block_pages(P, pps), self.r, self.dn, self.dv,
+                           kr.shape[2], self.dtype)
+        else:
+            fits = T == 1 and la.fits(P, self.r, kr.shape[2], self.dtype)
+        return "kernel" if fits else "xla"
 
     def _attend_tile(self, lp: dict, qn, qr, pools, row, qpos, last, form: str):
         """One tile's attention: q_nope ``qn`` (T, H, nope) and rotated q_rope
@@ -381,12 +402,42 @@ class LatentServing(PagedLM):
         o = jax.lax.map(one, tiles)
         return o.reshape((K * T,) + o.shape[2:])
 
+    def _step_walk(self, pools, bt, last):
+        """A step's walk, chosen once for all its attentions (``_walk`` at a
+        tile of one query): (``kernel`` or ``xla``, the kernel's work list or
+        None, the cache rows the walk reads an attention: whole key blocks of
+        each lane's own need, of ``step_keys`` positions in the kernel)."""
+        P, pps = pools[0].shape[1], bt.shape[1]
+        walk = self._walk("absorbed", 1, pools, pps)
+        if walk == "kernel":
+            kb = max(1, min(self.step_keys // P, pps))
+            work = la.work_list(last, bt, P, kb)
+            return walk, work, work["items"] * kb * P
+        return walk, None, jnp.sum(self._blocks_needed(last, P, pps)) * self._block_pages(P, pps) * P
+
+    def _walk_lanes(self, lp: dict, qn, qr, pools, work: dict):
+        """A step's attention in ONE kernel call (``ops/lane_attention.py``),
+        absorbed: q_nope ``qn`` (B, H, nope) and rotated q_rope ``qr`` (B, H,
+        rope), every lane over its own key blocks by the step's work list ->
+        (B, H, v) float32. The two whole-batch products around the call stay
+        XLA's."""
+        ckv, kr = pools
+        f32 = {"preferred_element_type": jnp.float32}
+        q_lat = jnp.einsum("bhn,rhn->bhr", qn, lp["w_kb"], **f32).astype(self.dtype)
+        o = la.lane_walk(q_lat, jnp.concatenate([qr] * (ckv.shape[1] // kr.shape[1]), axis=-1),
+                         ckv, kr, work, scale=(self.dn + self.dr) ** -0.5)
+        return jnp.einsum("bhr,rhv->bhv", o, lp["w_vb"], **f32)
+
+    @staticmethod
+    def _by_walk(walk: str, n):
+        """``n`` tiles or lanes counted under the walk they took: (kernel, xla)."""
+        return (n, 0) if walk == "kernel" else (0, n)
+
     def _tile_walks(self, t: dict, pools, form: str):
         """A launch's tiles that belong to a piece, counted under the walk
-        they take: (kernel, xla)."""
-        tiles = jnp.sum(t["has"])
-        kernel = self._walk(form, t["T"], pools, t["rows"].shape[1]) == "kernel"
-        return (tiles, 0) if kernel else (0, tiles)
+        they take."""
+        return self._by_walk(self._walk(form, t["T"], pools, t["rows"].shape[1]),
+                             jnp.sum(t["has"]))
 
     def _attn_out(self, lp, o):
         return jnp.einsum("thv,hvd->td", o.astype(self.dtype), lp["wo"],
@@ -462,27 +513,29 @@ class LatentServing(PagedLM):
         # its result is discarded.
         last = jnp.where(live, pos, 0)
         ckv, kr, stats = list(state["ckv"]), list(state["kr"]), []
+        walk, work, walked = self._step_walk((ckv[0], kr[0]), bt, last)
         for i in range(self.n_layers):
             lp = params[f"layer{i}"]
             with jax.named_scope("mla_decode"):
                 qn, qr, c_kv, k_r = self._project(lp, rms_norm(x, lp["norm1"], self.eps), pos)
                 ckv[i] = self._write_pages(ckv[i], w_page, off, c_kv.astype(ckv[i].dtype))
                 kr[i] = self._write_keys(kr[i], w_page, off, k_r, runs=False)
-                o = jax.lax.map(
-                    lambda a, lp=lp, pools=(ckv[i], kr[i]): self._attend_tile(
-                        lp, *a[:2], pools, *a[2:], form),
-                    (qn[:, None], qr[:, None], bt, pos[:, None], last))
-                y = self._attn_out(lp, o[:, 0])
+                if walk == "kernel":
+                    o = self._walk_lanes(lp, qn, qr, (ckv[i], kr[i]), work)
+                else:
+                    o = jax.lax.map(
+                        lambda a, lp=lp, pools=(ckv[i], kr[i]): self._attend_tile(
+                            lp, *a[:2], pools, *a[2:], form),
+                        (qn[:, None], qr[:, None], bt, pos[:, None], last))[:, 0]
+                y = self._attn_out(lp, o)
             x = x + y.astype(self.dtype)
             y, st = self._ffn(lp, i, rms_norm(x, lp["norm2"], self.eps), live)
             if st is not None:
                 stats.append(st)
             x = x + y.astype(self.dtype)
         context = jnp.sum(jnp.where(live, pos + 1, 0))
-        walked = jnp.sum(self._blocks_needed(last, P, bt.shape[1])) \
-            * self._block_pages(P, bt.shape[1]) * P
         acc = self._accumulate(state["acc"], 1, stats, context, context, walked, form,
-                               (0, jnp.sum(live)))
+                               self._by_walk(walk, jnp.sum(live)))
         return self._emit(params, state, dict(state, ckv=ckv, kr=kr), x, live, pos, acc)
 
     # -- host side ----------------------------------------------------------------

@@ -1,7 +1,7 @@
 """A decoder-only language model whose layers are DOUBLE and carry their
 routed experts on a SHORTCUT (ISSUE 42): two latent attentions (``mla``'s, by
 inheritance: its projections, its two page leaves, its two forms, its map over
-a launch's tiles and its kernel) and two dense SwiGLUs a layer, and one routed
+a launch's tiles and its two kernels) and two dense SwiGLUs a layer, and one routed
 layer that reads the first sublayer's normed stream and joins the stream at
 the layer's end.
 Built from a published ``config.json`` and served through the generation
@@ -49,11 +49,17 @@ its kernel stay: on the TPU one call of ``ops/tile_attention.py`` a tile walks
 the tile's key blocks, as in ``mla``, through ``mla``'s own ``_attend_tiles``)
 over key blocks of ``key_block`` = 256 positions: a launch of 1,024 rows
 carries up to four prompts' pieces, and a short prompt's tile reads two pages,
-not eight. A decode step attends its lanes IN GROUPS: the step
-runs in order of context length, ``DECODE_GROUP`` lanes walk the key blocks
-side by side as far as the longest of them needs, absorbed (``_attend_lanes``);
-``mla`` walks its lanes one after another, which at hundreds of lanes and eight
-attentions a step is thousands of serial walks.
+not eight. A decode step is absorbed and walks where ``mla``'s does
+(``_step_walk``). On the TPU at shapes the kernel takes, ONE call of
+``ops/lane_attention.py`` an attention walks every lane's own key blocks
+(``mla._walk_lanes``): hundreds of lanes of very different lengths side by
+side, each as far as its own position needs, the stream in the lanes' own
+order. Elsewhere the step attends its lanes IN GROUPS in XLA: it runs in order
+of context length (sorted once, the last stream put back before it is
+sampled), and ``DECODE_GROUP`` lanes walk the key blocks side by side as far
+as the longest of them needs (``_attend_lanes``), because ``mla``'s XLA walk,
+one lane after another, is thousands of serial walks at hundreds of lanes and
+eight attentions a step.
 """
 
 from __future__ import annotations
@@ -83,7 +89,8 @@ class ShortcutLatentServing(mla.LatentServing):
     ACC = 13
     TILE_ROWS = 256
     key_block = 256
-    DECODE_GROUP = 32   # lanes that walk their key blocks side by side
+    step_keys = 512     # contexts of hundreds: four pages a cell read fastest (PERF.md 6, PR 44)
+    DECODE_GROUP = 32   # lanes that walk their key blocks side by side in XLA
 
     def __init__(self, cfg: ModelConfig) -> None:
         PagedLM.__init__(self, cfg)
@@ -299,29 +306,35 @@ class ShortcutLatentServing(mla.LatentServing):
 
     # -- decode -------------------------------------------------------------------
     def step(self, params: Any, state: Any) -> tuple[Any, dict]:
-        """One token a live lane. The layers run over the lanes in order of
-        context length (``_attend_lanes`` groups neighbours), and the last
-        stream goes back to the lanes' own order before it is sampled."""
+        """One token a live lane. Where the kernel walks (``_step_walk``) every
+        lane walks its own key blocks and the stream keeps the lanes' order.
+        Where XLA walks, the layers run over the lanes in order of context
+        length (``_attend_lanes`` groups neighbours), and the last stream goes
+        back to the lanes' own order before it is sampled."""
         live = state["armed"] & ~state["done"]
         pos = jnp.clip(state["pos"], 0, self.max_ctx - 1)
         P, pps = state["ckv"][0].shape[1], state["bt"].shape[1]
+        ckv, kr, stats = list(state["ckv"]), list(state["kr"]), []
         # A lane that is not live walks one block of whatever its row names:
         # its result is discarded.
-        order = jnp.argsort(jnp.where(live, pos, 0))
-        live_o, pos_o, bt = live[order], pos[order], state["bt"][order]
+        walk, work, walked = self._step_walk((ckv[0], kr[0]), state["bt"], jnp.where(live, pos, 0))
+        order = None if walk == "kernel" else jnp.argsort(jnp.where(live, pos, 0))
+        in_order = lambda v: v if order is None else v[order]  # noqa: E731
+        live_o, pos_o, bt = in_order(live), in_order(pos), in_order(state["bt"])
         last = jnp.where(live_o, pos_o, 0)
-        x = jnp.take(params["embed"], state["last"][order], axis=0)
+        x = jnp.take(params["embed"], in_order(state["last"]), axis=0)
         page_of = jnp.take_along_axis(bt, (pos_o // P)[:, None], axis=1)[:, 0]
         w_page, off = jnp.where(live_o, page_of, 0), pos_o % P
-        ckv, kr, stats = list(state["ckv"]), list(state["kr"]), []
 
         def attend(at: int, lp: dict, u):
             with jax.named_scope("mla_decode"):
                 qn, qr, c_kv, k_r = self._project(lp, u, pos_o)
                 ckv[at] = self._write_pages(ckv[at], w_page, off, c_kv.astype(ckv[at].dtype))
                 kr[at] = self._write_keys(kr[at], w_page, off, k_r, runs=False)
-                return self._attn_out(
-                    lp, self._attend_lanes(lp, qn, qr, (ckv[at], kr[at]), bt, pos_o, last))
+                pools = (ckv[at], kr[at])
+                return self._attn_out(lp, self._walk_lanes(lp, qn, qr, pools, work)
+                                      if walk == "kernel" else
+                                      self._attend_lanes(lp, qn, qr, pools, bt, pos_o, last))
 
         for i in range(self.n_layers):
             lp = params[f"layer{i}"]
@@ -329,11 +342,12 @@ class ShortcutLatentServing(mla.LatentServing):
                                 lambda j, u, i=i, lp=lp: attend(2 * i + j, lp[f"attn{j}"], u))
             stats.append(st)
         context = jnp.sum(jnp.where(live, pos + 1, 0))
-        walked = jnp.sum(self._group_blocks(last, P, pps)) \
-            * self._group(pos.shape[0]) * self._block_pages(P, pps) * P
+        if walk == "xla":   # whole key blocks as far as each group's longest lane needs
+            walked = jnp.sum(self._group_blocks(last, P, pps)) \
+                * self._group(pos.shape[0]) * self._block_pages(P, pps) * P
+            x = jnp.take(x, jnp.argsort(order), axis=0)
         acc = self._accumulate(state["acc"], 1, stats, context, context, walked, "absorbed",
-                               (0, jnp.sum(live)))
-        x = jnp.take(x, jnp.argsort(order), axis=0)
+                               self._by_walk(walk, jnp.sum(live)))
         return self._emit(params, state, dict(state, ckv=ckv, kr=kr), x, live, pos, acc)
 
     # -- host side ----------------------------------------------------------------

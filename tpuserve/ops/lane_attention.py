@@ -1,0 +1,178 @@
+"""A decode step's latent attention, every live lane over its OWN key blocks,
+as one Pallas TPU kernel call an attention (ISSUE 44): ``tile_attention``'s
+sibling for tiles of one query a lane, in the ABSORBED form.
+
+``work_list(last, bt, page, block_pages)``, once a step and shared by every
+attention of it: the (lane, key block) items that exist, lane after lane, a
+lane's blocks in order: ``last`` (B,) the last position a lane attends (0
+for a lane that is not live: ONE item, its row's first block, of which it sees
+key 0, so its output row is finite and nothing uninitialised reaches the
+stream), ``bt`` (B, pps) the block table. Built in XLA from a cumulative sum
+and a search: ``items`` (traced) is the list's length, the sum of the lanes'
+own ``last // (block_pages x page) + 1``; ``lane``, ``block`` (N,) and
+``pages`` (N x block_pages,) are padded to the most a table holds, N = B x
+ceil(pps / block_pages). A page of an item that lies whole past the lane's
+position reads page 0 (the engine's sentinel): a neighbour that does the same
+fetches nothing.
+
+``lane_walk(q_lat, q_rope, ckv, kr, work)``: ``q_lat`` (B, H, r) = ``q_nope
+W_kb^T`` and ``q_rope`` (B, H, g x rope), the rotary part ``g`` times (below);
+``ckv`` (pages, P, r) and ``kr`` (pages, P / g, g x rope) the layer's pools AS
+THEY LIE -> the normalised latent context (B, H, r) in ``q_lat``'s type;
+``ctx W_vb`` stays the caller's.
+
+Grid (``items``,): ONE sequential axis over the list, its length a TRACED
+grid bound, so no cell is launched, fetched or skipped for a block no lane
+has (a cell costs about as much skipped as worked, and a padded (lanes,
+longest) grid is mostly skipped cells). ``lane``, ``block``, ``pages`` and
+``last`` arrive by scalar prefetch: a cell reads its item's ``block_pages``
+pages straight from the pools through ``pages`` (the pools are passed once a
+page of a block, each with its own index map) and the lane's queries through
+``lane``; the output's block index is the lane too, so a lane's context is
+written back when the lane changes. A lane's running max, sum and (H, r)
+float32 accumulator stay in VMEM scratch from its first block (``block ==
+0``) to its last (the one that holds ``last``), which divides and writes. A
+page's latents are read ONCE and serve both products from fast memory: scores
+``[q_lat | q_rope] . [c_kv | k_r]`` and context ``p . c_kv``. Products in
+bfloat16 with float32 accumulation, the softmax in float32, ``p.astype(dtype)
+@ c_kv``: ``mla_sc._attend_lanes``' and ``mla._attend_tile``'s. No lane's
+gathered rows, scores or partial context exist in device memory.
+
+THE ROTARY KEY's leaf holds ``g`` positions side by side in a row of 128
+lanes, so position ``s`` of a page is part ``s % g`` of row ``s // g``. A cell
+lays a page's leaf out a position a row with ``g`` strided stores into a
+float32 scratch (rows ``a, a + g, ...`` take the leaf's rows with every part
+but ``a`` zeroed: exact), and the query's rotary part repeated ``g`` times
+then gives ``q_rope . k_r(s)`` as one contraction of 128 lanes. Nothing but
+loads, selects and stores: ``tile_attention``'s 0/1 product put a second trip
+through the matrix unit in front of the scores' product, a fifth of a cell's
+time at a step's sizes (ISSUE 44, on the chip).
+
+WHAT A CELL COSTS (v5e, on the chip, ISSUE 44): its parts add up, they do not
+overlap: about 0.06 us an operand for the pipeline's bookkeeping whether the
+cell works or not, 0.2 us for the two reductions across lanes, 0.13 us a page
+for the three products at 64 query rows; the pages' copies hide behind them.
+So the pages a cell (``block_pages``, the caller's) trade cells for rows past
+a lane's end: 4 read fastest at contexts of hundreds, 8 and more at thousands.
+
+Off the TPU ``interpret=True`` runs the same code in the Pallas interpreter
+(tests); the families call it on the TPU alone.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NEG = -1e30
+
+
+def work_list(last: jax.Array, bt: jax.Array, page: int, block_pages: int) -> dict:
+    B, pps = bt.shape
+    kb, c = block_pages, block_pages * page
+    nb = -(-pps // kb)
+    last = jnp.clip(last, 0, pps * page - 1).astype(jnp.int32)
+    need = last // c + 1
+    ends = jnp.cumsum(need)
+    n = jnp.arange(B * nb, dtype=jnp.int32)
+    lane = jnp.minimum(jnp.searchsorted(ends, n, side="right", method="compare_all"),
+                       B - 1).astype(jnp.int32)
+    block = jnp.clip(n - (ends - need)[lane], 0, nb - 1).astype(jnp.int32)
+    at = block[:, None] * kb + jnp.arange(kb, dtype=jnp.int32)[None, :]
+    pages = jnp.pad(bt, ((0, 0), (0, nb * kb - pps)))[lane[:, None], at]
+    pages = jnp.where(at * page <= last[lane][:, None], pages, 0)
+    return {"lane": lane, "block": block, "pages": pages.reshape(-1).astype(jnp.int32),
+            "last": last, "items": ends[-1].astype(jnp.int32)}
+
+
+def _kernel(lane_ref, block_ref, pages_ref, last_ref, ql_ref, qr_ref, *refs, scale: float,
+            kb: int, g: int):
+    del pages_ref   # the index maps read it
+    ckv_refs, kr_refs, o_ref = refs[:kb], refs[kb:2 * kb], refs[2 * kb]
+    m_ref, l_ref, acc_ref, kx_ref = refs[2 * kb + 1:]
+    P, dt = ckv_refs[0].shape[0], ql_ref.dtype
+    h, lanes = qr_ref.shape
+    n = pl.program_id(0)
+    j, last = block_ref[n], last_ref[lane_ref[n]]
+    f32 = {"preferred_element_type": jnp.float32}
+    nt = (((1,), (1,)), ((), ()))
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    iota = lambda shape, axis: jax.lax.broadcasted_iota(jnp.int32, shape, axis)  # noqa: E731
+    part = iota((P // g, lanes), 1) // (lanes // g)
+    q_lat, q_rope = ql_ref[...], qr_ref[...]
+    s = []
+    for i in range(kb):
+        k_r = kr_refs[i][...]
+        if g > 1:   # a position a row: rows a, a + g, ... keep part a of the leaf's rows
+            for a in range(g):
+                kx_ref[pl.ds(a, P // g, stride=g), :] = jnp.where(
+                    part == a, k_r.astype(jnp.float32), 0.0)
+            k_r = kx_ref[...].astype(dt)
+        s.append(jax.lax.dot_general(q_lat, ckv_refs[i][...], nt, **f32)
+                 + jax.lax.dot_general(q_rope, k_r, nt, **f32))
+    s = jnp.concatenate(s, axis=1) if kb > 1 else s[0]
+    s = jnp.where(j * (kb * P) + iota(s.shape, 1) <= last, s * scale, NEG)
+    m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    pv = sum(jnp.dot(p[:, i * P:(i + 1) * P].astype(dt), ckv_refs[i][...], **f32)
+             for i in range(kb))
+    acc_ref[...] = acc_ref[...] * alpha + pv
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                                  l_ref.shape)
+
+    @pl.when((j + 1) * (kb * P) > last)
+    def _():
+        o_ref[...] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+def fits(page: int, r: int, kr_lanes: int, dtype) -> bool:
+    """Shapes the kernel takes: bfloat16, whole sublane tiles a page, the
+    latent's and the rotary leaf's rows whole 128-lane tiles."""
+    return dtype == jnp.bfloat16 and page % 16 == 0 and r % 128 == 0 and kr_lanes % 128 == 0
+
+
+def lane_walk(q_lat: jax.Array, q_rope: jax.Array, ckv: jax.Array, kr: jax.Array, work: dict, *,
+              scale: float, interpret: bool = False) -> jax.Array:
+    b, h, r = q_lat.shape
+    n_pages, P = ckv.shape[:2]
+    lanes = kr.shape[2]
+    g = P // kr.shape[1]
+    kb = work["pages"].shape[0] // work["lane"].shape[0]
+    pages = jnp.clip(work["pages"], 0, n_pages - 1)
+    page = lambda i: lambda n, lane, block, pages, last: (pages[n * kb + i], 0, 0)  # noqa: E731
+    by_lane = lambda n, lane, block, pages, last: (lane[n], 0, 0)  # noqa: E731
+    item = jnp.dtype(q_lat.dtype).itemsize
+    # the cell's blocks twice (the pipeline's two buffers), its scratch, and
+    # the float32 values of a block's scores and of its context
+    vmem = 2 * item * (h * (2 * r + lanes) + kb * P * (r + lanes)) \
+        + 4 * h * (r + 256) + 4 * (3 * h * kb * P + 2 * h * r + P * lanes)
+    return pl.pallas_call(
+        functools.partial(_kernel, scale=scale, kb=kb, g=g),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(work["items"],),
+            in_specs=[pl.BlockSpec((None, h, r), by_lane), pl.BlockSpec((None, h, lanes), by_lane)]
+            + [pl.BlockSpec((None, P, r), page(i)) for i in range(kb)]
+            + [pl.BlockSpec((None, P // g, lanes), page(i)) for i in range(kb)],
+            out_specs=pl.BlockSpec((None, h, r), by_lane),
+            scratch_shapes=[pltpu.VMEM((h, 128), jnp.float32), pltpu.VMEM((h, 128), jnp.float32),
+                            pltpu.VMEM((h, r), jnp.float32), pltpu.VMEM((P, lanes), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, r), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(vmem + (16 << 20), 100 << 20)),
+        interpret=interpret, name="lane_walk",
+    )(work["lane"], work["block"], pages, work["last"], q_lat, q_rope, *([ckv] * kb),
+      *([kr] * kb))
