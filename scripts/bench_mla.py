@@ -113,8 +113,8 @@ def decode_walks(models: dict, params, lp, state, iters: int, emit, on_tpu: bool
     holds), at each of `models` {what: model}: the family's own (the kernel
     on the TPU) beside the walk in XLA. A row a model: the step's median, the
     attention's (`ALONE_REPS` chained in one program; the kernel's with its
-    work list built inside; XLA's grouped walk on lanes sorted beforehand) and the rows the step walked over the
-    rows it attended, from the device's own sums. Every model steps from the
+    work list built inside; XLA's lane by lane) and the rows the step walked
+    over the rows it attended, from the device's own sums. Every model steps from the
     SAME lanes (positions, counts and flags are put back; the pools keep what
     the steps wrote) -> (state, {what: jitted step})."""
     steps = {}
@@ -132,10 +132,6 @@ def decode_walks(models: dict, params, lp, state, iters: int, emit, on_tpu: bool
         if walk == "kernel":
             one = lambda lp, qn, qr, pools, bt, pos, last, m=m: m._walk_lanes(  # noqa: E731
                 lp, qn, qr, pools, m._step_walk(pools, bt, last)[1])
-        elif hasattr(m, "_attend_lanes"):
-            order = jnp.argsort(last)
-            qn, qr, bt, pos, last = (v[order] for v in (qn, qr, bt, pos, last))
-            one = m._attend_lanes
         else:
             one = lambda lp, qn, qr, pools, bt, pos, last, m=m: jax.lax.map(  # noqa: E731
                 lambda a: m._attend_tile(lp, *a[:2], pools, *a[2:], "absorbed"),

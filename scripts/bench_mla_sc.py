@@ -3,9 +3,9 @@
 the mix's contexts: every slot filled with a prompt drawn from the mix's
 lengths and advanced a drawn part of its answer, then a decode step of every
 lane and ONE attention of it alone at BOTH walks (the kernel's, at several
-cells, beside XLA's grouped walk, at several groupings, and `mla`'s lanes one
-after another: `scripts/bench_mla.py` `decode_walks`, each row with the cache
-rows walked over the rows attended) and packed prefill launches AT SEVERAL
+cells, beside the fallback in XLA, `mla`'s lanes one after another, at two
+widths of key block: `scripts/bench_mla.py` `decode_walks`, each row with the
+cache rows walked over the rows attended) and packed prefill launches AT SEVERAL
 SETTINGS of what the family sets for itself (`key_block`, `TILE_ROWS`) and at
 `mla`'s own (one prompt a launch in a tile as wide as the launch), timed by the
 host's clock around a dependent read; then one trace, with a table by
@@ -46,17 +46,9 @@ from tpuserve.models import mla_sc  # noqa: E402
 bench_mla.KINDS = (("moe_layer", "the routed layer (scope moe_layer)"),) + bench_mla.KINDS
 
 
+# The step's walk left in XLA under this family's layer: `mla`'s fallback, the
+# lanes one after another, each over key blocks of `key_block` positions.
 InXla = bench_mla.xla_walk(mla_sc.ShortcutLatentServing)
-
-
-class LaneByLane(InXla):
-    """`mla`'s decode walk in XLA under this family's layer: the lanes one
-    after another, each over key blocks of `key_block` positions."""
-
-    def _attend_lanes(self, lp, qn, qr, pools, bt, pos, last):
-        return jax.lax.map(
-            lambda a: self._attend_tile(lp, *a[:2], pools, *a[2:], "absorbed"),
-            (qn[:, None], qr[:, None], bt, pos[:, None], last))[:, 0]
 
 
 def main() -> None:
@@ -178,18 +170,13 @@ def main() -> None:
     own_step = None
     if args.only != "prefill":
         walks = {f"the family's own walk, cells of {model.step_keys} keys": model,
-                 f"the walk left in XLA: groups of {model.DECODE_GROUP} lanes, key blocks of "
-                 f"{model.key_block}": make(InXla)}
-        if args.rehearse:
-            walks["mla's walk in XLA: lane by lane"] = make(LaneByLane, key_block=P)
-        else:
+                 f"the walk left in XLA: lane by lane, key blocks of {model.key_block}":
+                 make(InXla, key_block=model.key_block)}
+        if not args.rehearse:
             for keys in (256, 1024):
                 walks[f"the family's own walk, cells of {keys} keys"] = make(step_keys=keys)
-            for g, kb in ((64, 256), (16, 256), (32, 512)):
-                walks[f"the walk left in XLA: groups of {g} lanes, key blocks of {kb}"] = make(
-                    InXla, DECODE_GROUP=g, key_block=kb)
-            walks["mla's walk in XLA: lane by lane, key blocks of 1,024"] = make(
-                LaneByLane, key_block=None)
+            walks["the walk left in XLA: lane by lane, key blocks of 1,024"] = make(
+                InXla, key_block=None)
         state, steps = bench_mla.decode_walks(walks, params, params["layer0"]["attn0"], state,
                                               args.iters, emit, on_tpu)
         own_step = next(iter(steps.values()))

@@ -540,47 +540,3 @@ def test_decode_over_packed_pages_compiles_for_the_v5e_at_the_cells_widths(one_c
         jax.config.update("jax_enable_compilation_cache", True)
     assert "tpu_custom_call" in text
     assert not [ln for ln in text.split("\n") if " copy(" in ln and "1280,128,128" in ln]
-
-
-def test_the_grouped_latent_decode_compiles_for_the_v5e_at_the_cells_widths(one_chip, tmp_path):
-    """`mla_sc._attend_lanes` (ISSUE 42) at the LongCat cell's sizes: 256 lanes
-    of 64 heads over pools of 2,048 pages of 128 tokens (a latent row of 512
-    and two rotary keys of 64 side by side), a block table of 22 pages: eight
-    groups of 32 lanes walk key blocks of 256 positions, and XLA copies no
-    pool around the walk's gathers."""
-    import json
-
-    from tpuserve.config import ModelConfig
-    from tpuserve.models import build
-
-    arch = {"vocab_size": 256, "hidden_size": 6144, "num_layers": 1, "num_attention_heads": 64,
-            "q_lora_rank": 1536, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
-            "qk_rope_head_dim": 64, "v_head_dim": 128, "ffn_hidden_size": 256,
-            "expert_ffn_hidden_size": 256, "n_routed_experts": 8, "zero_expert_num": 4,
-            "moe_topk": 2, "attention_method": "MLA"}
-    path = tmp_path / "arch.json"
-    path.write_text(json.dumps(arch))
-    model = build(ModelConfig(name="grouped", family="mla_sc", dtype="bfloat16",
-                              batch_buckets=[1], options={
-                                  "config_file": str(path), "max_prompt_tokens": 2048,
-                                  "max_new_tokens": 768}))
-
-    def shape(*dims, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
-
-    sig = model.kv_page_signature(256, 2048, 128)
-    assert sig["ckv"][0].shape == (2048, 128, 512) and sig["kr"][0].shape == (2048, 64, 128)
-    assert sig["bt"].shape == (256, 22) and model._group(256) == 32
-    lp = {"w_kb": shape(512, 64, 128), "w_vb": shape(512, 64, 128)}
-    lanes = shape(256, dtype=jnp.int32)
-    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
-    try:
-        text = jax.jit(model._attend_lanes).lower(
-            lp, shape(256, 64, 128), shape(256, 64, 64),
-            (shape(2048, 128, 512), shape(2048, 64, 128)), shape(256, 22, dtype=jnp.int32),
-            lanes, lanes).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-    assert " while(" in text
-    assert not [ln for ln in text.split("\n")
-                if " copy(" in ln and ("2048,128,512" in ln or "2048,64,128" in ln)]

@@ -1,6 +1,6 @@
 """The `mla_sc` family (ISSUE 42) against its plain reference at a small size
-on the CPU, in float32: packed, chunked prefill and the grouped decode walk
-through pages of latent rows equal the reference's one causal pass; each of
+on the CPU, in float32: packed, chunked prefill and decode through pages of
+latent rows equal the reference's one causal pass; each of
 four omissions (a cache row in a lower type, the zero-compute term, the
 shortcut, a latent factor) fails the same tolerance tenfold; the 32-chip
 deployment's shares add up to the uncut layer; the three kinds of pick sum to
@@ -43,11 +43,11 @@ K = ARCH["moe_topk"]
 TOL = 5e-5
 
 
-def make_model(tmp_path, arch=ARCH, name="sc", dtype="float32", group=2, **options):
-    """The family's tiles and key blocks are 256 wide and its decode groups 32
-    lanes at the published sizes; a toy launch of 8 rows over 4 slots is
-    steered to tiles of one page, key blocks of two and groups of two lanes
-    here, in the test and not through an option of the program."""
+def make_model(tmp_path, arch=ARCH, name="sc", dtype="float32", **options):
+    """The family's tiles and key blocks are 256 wide at the published sizes;
+    a toy launch of 8 rows over 4 slots is steered to tiles of one page and
+    key blocks of two here, in the test and not through an option of the
+    program."""
     path = os.path.join(tmp_path, f"{name}.json")
     with open(path, "w", encoding="utf-8") as f:
         json.dump(arch, f)
@@ -56,7 +56,7 @@ def make_model(tmp_path, arch=ARCH, name="sc", dtype="float32", group=2, **optio
                                "max_prompt_tokens": MAX_PROMPT, "max_new_tokens": MAX_NEW,
                                **options})
     model = build(cfg)
-    model.TILE_ROWS, model.key_block, model.DECODE_GROUP = PAGE, 2 * PAGE, group
+    model.TILE_ROWS, model.key_block = PAGE, 2 * PAGE
     return model
 
 
@@ -93,23 +93,20 @@ def worst(arch, prompts, served):
 # -- (a) the served function is the reference's one causal pass -----------------------------
 
 @pytest.mark.parametrize("case", ["packed-over-four-launches", "a-prompt-a-launch",
-                                  "one-group-of-every-lane", "every-expert-held"])
-def test_chunked_prefill_then_grouped_decode_is_the_reference_one_causal_pass(
-        whole, tmp_path, case):
+                                  "every-expert-held"])
+def test_chunked_prefill_then_decode_is_the_reference_one_causal_pass(whole, tmp_path, case):
     """Logits, not tokens. Prompts of 19, 5, 11 and 2 tokens over launches of
-    8 rows in tiles of a page; decode in groups of two lanes in order of
-    context, over key blocks of two pages (the longest lane walks four)."""
+    8 rows in tiles of a page; decode lane by lane, each over its own key
+    blocks of two pages (the longest lane walks four)."""
     model, params = whole
     arch, launches = ARCH, PACKED
     if case == "a-prompt-a-launch":
         launches = None
-    elif case == "one-group-of-every-lane":
-        model = make_model(tmp_path, name="one", group=SLOTS)
     elif case == "every-expert-held":
         arch = {k: v for k, v in ARCH.items() if k != "share"}
         model = make_model(tmp_path, arch, name="all")
         params = model.init_params(jax.random.key(0))
-    assert model.kv_prefill_pieces(CHUNK, PAGE) == 2 and model._group(SLOTS) in (2, SLOTS)
+    assert model.kv_prefill_pieces(CHUNK, PAGE) == 2
     served, out, _ = serve(model, params, PROMPTS, MAX_NEWS, chunk=CHUNK, launches=launches,
                            slots=SLOTS)
     assert bool(np.all(np.asarray(out["done"])))
@@ -306,11 +303,11 @@ def test_the_page_signature_holds_two_latent_rows_a_layer_and_the_attention_is_m
     assert sig["acc"].shape == (2, 13) and model.kv_page_leaves == ("ckv", "kr")
     # shared, not copied: the attention's functions are `mla.LatentServing`'s own
     for name in ("_project", "_write_keys", "_attend_tile", "_attend_tiles", "_walk", "_form",
-                 "_attn_out"):
+                 "_attn_out", "_attention", "_step_plan", "_prefill_plan"):
         assert getattr(type(model), name) is getattr(mla.LatentServing, name)
     assert model.q_scale == pytest.approx((64 / 48) ** 0.5) and model.kv_scale == pytest.approx(2 ** 0.5)
     fresh = build(model.cfg)
-    assert (fresh.TILE_ROWS, fresh.key_block, fresh.DECODE_GROUP) == (256, 256, 32)
+    assert (fresh.TILE_ROWS, fresh.key_block, fresh.step_keys) == (256, 256, 512)
     assert fresh.kv_prefill_pieces(1024, 128) == 4 and fresh.kv_prefill_pieces(2048, 128) == 8
     assert model.share_stats() == {"experts_held": [4, 4], "experts": 16, "zero_experts": 8,
                                    "vocab_rows": [16, 64], "vocab": 96}
@@ -443,12 +440,11 @@ def test_on_the_tpu_a_step_walks_every_lane_in_one_kernel_call_an_attention(tmp_
     """With the backend named `tpu` and the kernel run in the interpreter, a
     step of a bfloat16 model at widths the kernel takes is ONE call of
     `ops/lane_attention.py` an attention (four: two layers of two) for every
-    lane in the lanes' OWN order (nothing is sorted, nothing permuted back):
-    contexts of 40, 9, 21 and 10 tokens over key blocks of two pages, two
-    lanes never armed. It is the grouped XLA step's state and log-probabilities
-    to bfloat16's rounding, and the device's sums count every live lane under
-    `walk=kernel`, none under `xla`, and each lane's own whole key blocks
-    where the groups walked as far as their longest lane."""
+    lane: contexts of 40, 9, 21 and 10 tokens over key blocks of two pages,
+    two lanes never armed. It is the XLA step's (`mla`'s fallback, lane by
+    lane) state and log-probabilities to bfloat16's rounding, and the device's
+    sums count every live lane under `walk=kernel`, none under `xla`, and each
+    lane's own whole key blocks, as the fallback counts them at cells as wide."""
     from tests.test_mla import same_step, steps_both_walks
 
     model = make_model(tmp_path, LANE_ARCH, name="lanes", dtype="bfloat16", max_prompt_tokens=48)
@@ -461,16 +457,16 @@ def test_on_the_tpu_a_step_walks_every_lane_in_one_kernel_call_an_attention(tmp_
     assert acc[1, 10] == 4 and acc[1, 11] == 0 and acc[1, 7] == 1
     own = (2 + 1 + 1 + 1 + 1 + 1) * 32   # a lane that is not live: one block
     assert acc[1, 5] == 4 * (41 + 10 + 22 + 11) and acc[1, 6] == 4 * own
-    grouped = np.asarray(pairs[0][0][0]["acc"]).astype(np.int64)   # pairs in order of context
-    assert grouped[1, 6] == 4 * 2 * (1 + 1 + 2) * 32 > acc[1, 6]
+    by_lane = np.asarray(pairs[0][0][0]["acc"]).astype(np.int64)   # the fallback's own sums
+    assert by_lane[1, 6] == 4 * own and by_lane[1, 5] == acc[1, 5]
 
 
 @pytest.mark.parametrize("refused", ["float32", "a-page-of-8"])
-def test_a_shape_the_decode_kernel_refuses_walks_in_groups_and_counts_there(
+def test_a_shape_the_decode_kernel_refuses_walks_lane_by_lane_in_xla_and_counts_there(
         tmp_path, monkeypatch, refused):
     """The backend named `tpu`, a shape `fits` does not take: the step is the
-    grouped XLA step to the bit, no kernel call is traced, the lanes count
-    under `walk=xla`."""
+    XLA step (lane by lane, `mla`'s fallback) to the bit, no kernel call is
+    traced, the lanes count under `walk=xla`."""
     from tests.test_mla import same_step, steps_both_walks
 
     dtype, page = ("float32", 16) if refused == "float32" else ("bfloat16", 8)
@@ -482,3 +478,36 @@ def test_a_shape_the_decode_kernel_refuses_walks_in_groups_and_counts_there(
     assert not calls
     acc = same_step(pairs, 2, atol=0)
     assert acc[1, 10] == 0 and acc[1, 11] == 2
+
+
+@pytest.mark.parametrize("family", ["mla", "mla_sc"])
+def test_the_fallback_step_is_mlas_and_the_reference_across_a_key_blocks_edge(tmp_path, family):
+    """Off the TPU a step attends lane by lane in XLA, `mla_sc` through the very
+    function `mla` does (`mla._attention`'s map over `_attend_tile`). Prompts of
+    7 and 15 tokens end one short of an edge of the key blocks of two pages (8
+    positions): the first step's walk takes a block more than the prompt's did,
+    and a lane of each length steps beside the other. Either family's served
+    log-probabilities are its float32 reference's, and it counts the whole key
+    blocks of every lane's own need, an attention."""
+    from tests import test_mla
+
+    assert mla_sc.ShortcutLatentServing.step is mla.LatentServing.step
+    if family == "mla":
+        model, arch, attentions = test_mla.make_model(tmp_path, name="edge"), test_mla.ARCH, 1
+        model.key_block = 2 * PAGE   # its own, where `mla` walks the module's KEY_BLOCK
+        gaps_of = test_mla.gaps
+    else:
+        model, arch, attentions, gaps_of = make_model(tmp_path, name="edge"), ARCH, 4, gaps
+    assert model._block_pages(PAGE, model.kv_pages_per_slot(PAGE)) == 2
+    params = model.init_params(jax.random.key(0))
+    lengths, news = (7, 15), [4, 4]
+    prompts = [np.random.default_rng(9).integers(0, 64, n) for n in lengths]
+    served, out, _ = serve(model, params, prompts, news, chunk=CHUNK, slots=SLOTS)
+    assert [int(s["n_new"]) for s in served] == news
+    assert max(float(np.abs(g).max()) for g in gaps_of(arch, prompts, served)) < TOL
+    # Five steps: a lane at position p walks p // 8 + 1 blocks of 8 rows in each of its
+    # three live steps, and one once it is done, as the two lanes never armed do.
+    blocks = sum(p // 8 + 1 for n in lengths for p in range(n, n + 3)) + 2 * 2 + 5 * (SLOTS - 2)
+    acc = np.asarray(out["acc"]).astype(np.int64)
+    assert acc[1, 10] == 0 and acc[1, 11] == 2 * 3   # every live lane of every step in XLA
+    assert acc[1, 6] == attentions * blocks * 8 and acc[1, 5] == attentions * acc[1, 4]

@@ -33,8 +33,8 @@ Families (BASELINE.json ``configs``):
 - mla_sc         — ``mla``'s attention in DOUBLE layers: two latent attentions
                    and two dense SwiGLUs a layer, and a routed layer on a
                    shortcut whose router also picks zero-compute (identity)
-                   outputs; a share of the experts and the vocabulary; a
-                   grouped decode walk (ISSUE 42)
+                   outputs; a share of the experts and the vocabulary
+                   (ISSUE 42)
 - toy            — a linear classifier for tests and drills
 """
 

@@ -53,10 +53,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpuserve.config import ModelConfig
-from tpuserve.models.paged_lm import (KEY_BLOCK, LOGPROBS, MAX_PIECES, NEG,  # noqa: F401
-                                      PagedLM, _mm, head_share, read_config_file,
+from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN,  # noqa: F401
+                                      EXPERT_COLUMNS, KEY_BLOCK, LOGPROBS, MAX_PIECES,
+                                      NEG, PagedLM, _mm, head_share, read_config_file,
                                       rms_norm)
-from tpuserve.obs import GEN_PHASES
 from tpuserve.ops.moe import held_experts_swiglu, topk_route
 
 # Standard deviations of the drawn tensors, by role (``weight_scales`` in the
@@ -123,7 +123,10 @@ def apply_rope(x: jax.Array, pos: jax.Array, inv_freq: np.ndarray,
 
 
 class DecoderServing(PagedLM):
-    ACC = 6  # device-side sums a phase (kv_page_signature says which)
+    cache_leaves = ("kf", "vf", "kw", "vw")  # pages of the full layers, rings of the window layers
+    # The expert layers' four, the context, and sparse layers whose dispatch
+    # took the compact branch.
+    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, COMPACT_COLUMN)
 
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)
@@ -227,18 +230,14 @@ class DecoderServing(PagedLM):
     def kv_ring_tokens(self) -> int:
         return self.window if self.win_layers else 0
 
-    def kv_page_signature(self, slots: int, pages: int, page_tokens: int) -> Any:
+    def _cache_signature(self, slots: int, pages: int, page_tokens: int) -> dict:
         S = jax.ShapeDtypeStruct
         page = S(self._page_shape(pages, page_tokens), self.dtype)
         ring = S((slots + 1, self.window, self.kv, self.hd), self.dtype)
-        # ``acc``'s columns: picks of live tokens on held and on absent
-        # experts, held experts hit, held experts x sparse layers run, the
-        # context (positions a live token attends from) summed over live
-        # tokens, and sparse layers whose dispatch took the compact branch.
         return {
             "kf": [page for _ in self.full_layers], "vf": [page for _ in self.full_layers],
             "kw": [ring for _ in self.win_layers], "vw": [ring for _ in self.win_layers],
-            "ring": S((slots,), jnp.int32), **self._lane_signature(slots, page_tokens),
+            "ring": S((slots,), jnp.int32),   # a lane: the slot's ring
         }
 
     # -- device math --------------------------------------------------------------
@@ -279,12 +278,6 @@ class DecoderServing(PagedLM):
                                        of=self.n_experts)
         return y + self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"]), stats
 
-    def _accumulate(self, acc, phase: int, stats_list, context):
-        row = jnp.stack([jnp.asarray(v, jnp.int32) for v in (
-            *self._expert_sums(stats_list), context,
-            sum(st["compact"] for st in stats_list))])
-        return acc.at[phase].add(row.astype(jnp.uint32))
-
     def _prefill_window(self, q, k, v, ring_k, ring_v, qpos, rpos, kpos, ok):
         """A window layer's attention of one launch, tile by tile: q (K, T,
         H, hd) at positions ``qpos`` (K, T); k and v (C, KV, hd), the
@@ -311,106 +304,80 @@ class DecoderServing(PagedLM):
         return self._attend(q, jnp.concatenate([ring_k, near(k, 0)], axis=1),
                             jnp.concatenate([ring_v, near(v, 0)], axis=1), mask)
 
-    def prefill_chunk(self, params: Any, state: Any, launch: Any, *, chunk: int) -> Any:
-        """One launch of ``pack_prefill``: piece j is tokens [start[j],
-        start[j] + length[j]) of the prompt in slot[j], causal within the
-        piece and over what earlier launches left in that slot's caches. A
-        token sees its own prompt only, at its own positions; a piece that
-        ends its prompt samples the first token at its own last row and arms
-        its own lane."""
-        t = self._tiles(launch, chunk)
-        K, T, W = t["K"], t["T"], max(self.window, 1)   # W = 1: no window layer reads it
-        P = state["kf"][0].shape[2] if self.full_layers else 1
-        start, piece, has, end = launch["start"], t["piece"], t["has"], t["end"]
-        qpos, cpos, of_piece, valid = t["qpos"], t["cpos"], t["of_piece"], t["valid"]
-        rings = launch["ring"][piece]                                     # by tile
-        x = jnp.take(params["embed"], launch["ids"], axis=0)
-        w_page, off = self._page_of(t, P, state["bt"].shape[1])
-        # Window layers: of a piece's positions that fall on one ring place
-        # only the last lands; the rest, and padding, go to ring 0.
-        w_ring = jnp.where(valid & (cpos >= jnp.repeat(end, T) - W),
-                           jnp.repeat(rings, T), 0)
+    # -- what a launch works out once ------------------------------------------------
+    def _tiles(self, launch: Any, chunk: int) -> dict:
+        t = PagedLM._tiles(launch, chunk)
+        return {**t, "rings": launch["ring"][t["piece"]]}   # by tile
+
+    def _prefill_plan(self, state, launch, t: dict) -> dict:
+        """And the window layers' ring places: where each row lands, and what
+        each tile's ring held before the launch."""
+        m = super()._prefill_plan(state, launch, t)
+        T, W = t["T"], max(self.window, 1)   # W = 1: no window layer reads it
+        piece, has, valid, cpos = t["piece"], t["has"], t["valid"], t["cpos"]
+        # Of a piece's positions that fall on one ring place only the last
+        # lands; the rest, and padding, go to ring 0.
+        w_ring = jnp.where(valid & (cpos >= jnp.repeat(t["end"], T) - W),
+                           jnp.repeat(t["rings"], T), 0)
         roff = cpos % W
         # What a ring held before this launch: place r has the newest
         # position <= start - 1 that is r modulo W.
-        before = (start[piece] - 1)[:, None]
+        before = (launch["start"][piece] - 1)[:, None]
         rpos = jnp.where(has[:, None], before - ((before - jnp.arange(W)[None, :]) % W), -1)
-        own = valid[None, :] & (of_piece[None, :] == piece[:, None]) & has[:, None]
-        kf, vf, kw, vw = (list(state[k]) for k in ("kf", "vf", "kw", "vw"))
-        stats = []
-        for i in range(self.n_layers):
-            lp = params[f"layer{i}"]
-            q, k, v, gate = self._qkv(lp, i, rms_norm(x, lp["norm1"], self.eps), cpos)
-            qt = q.reshape((K, T) + q.shape[1:])
-            if self.layer_types[i] == "full_attention":
-                j = self.full_layers.index(i)
-                kf[j] = self._write_pages(kf[j], w_page, off, k)
-                vf[j] = self._write_pages(vf[j], w_page, off, v)
-                o = self._prefill_full_tiles(qt, kf[j], vf[j], t)
-            else:
-                j = self.win_layers.index(i)
-                o = self._prefill_window(qt, k, v, jnp.take(kw[j], rings, axis=0),
-                                         jnp.take(vw[j], rings, axis=0), qpos, rpos,
-                                         cpos, own)
-                kw[j] = kw[j].at[w_ring, roff].set(k)
-                vw[j] = vw[j].at[w_ring, roff].set(v)
-            x = x + self._attn_out(lp, o.reshape(q.shape), gate).astype(self.dtype)
-            y, st = self._ffn(lp, i, rms_norm(x, lp["norm2"], self.eps), valid)
-            if st is not None:
-                stats.append(st)
-            x = x + y.astype(self.dtype)
-        new = dict(state, kf=kf, vf=vf, kw=kw, vw=vw,
-                   acc=self._accumulate(state["acc"], 0, stats,
-                                        jnp.sum(jnp.where(valid, cpos + 1, 0))))
-        return self._arm(params, state, new, launch, t, x, {"ring": launch["ring"]})
+        own = valid[None, :] & (t["of_piece"][None, :] == piece[:, None]) & has[:, None]
+        return {**m, "w_ring": w_ring, "roff": roff, "rpos": rpos, "own": own,
+                "lanes": {"ring": launch["ring"]}}
 
-    # -- decode -------------------------------------------------------------------
-    def step(self, params: Any, state: Any) -> tuple[Any, dict]:
+    def _step_plan(self, state, live, pos) -> dict:
+        m = super()._step_plan(state, live, pos)
         W = max(self.window, 1)
-        live = state["armed"] & ~state["done"]
-        pos = jnp.clip(state["pos"], 0, self.max_ctx - 1)
-        x = jnp.take(params["embed"], state["last"], axis=0)
-        P = state["kf"][0].shape[2] if self.full_layers else 1
-        page_of = jnp.take_along_axis(state["bt"], (pos // P)[:, None], axis=1)[:, 0]
-        w_page = jnp.where(live, page_of, 0)
-        off = pos % P
         w_ring = jnp.where(live, state["ring"], 0)
         roff = pos % W
         # Ring place r holds the newest position <= pos that is r modulo W.
         rpos = pos[:, None] - ((pos[:, None] - jnp.arange(W)[None, :]) % W)
-        mask_win = (rpos >= 0)[:, None, :]
-        kf, vf, kw, vw = (list(state[k]) for k in ("kf", "vf", "kw", "vw"))
-        stats = []
-        for i in range(self.n_layers):
-            lp = params[f"layer{i}"]
-            q, k, v, gate = self._qkv(lp, i, rms_norm(x, lp["norm1"], self.eps), pos)
-            if self.layer_types[i] == "full_attention":
-                j = self.full_layers.index(i)
-                kf[j] = self._write_pages(kf[j], w_page, off, k)
-                vf[j] = self._write_pages(vf[j], w_page, off, v)
-                o = self._decode_full(q, kf[j], vf[j], state["bt"], pos)
-            else:
-                j = self.win_layers.index(i)
-                kw[j] = kw[j].at[w_ring, roff].set(k)
-                vw[j] = vw[j].at[w_ring, roff].set(v)
-                # A free lane reads ring 0, which every free lane writes: its
-                # result is discarded.
-                o = self._attend(q[:, None], jnp.take(kw[j], w_ring, axis=0),
-                                 jnp.take(vw[j], w_ring, axis=0), mask_win)[:, 0]
-            x = x + self._attn_out(lp, o, gate).astype(self.dtype)
-            y, st = self._ffn(lp, i, rms_norm(x, lp["norm2"], self.eps), live)
-            if st is not None:
-                stats.append(st)
-            x = x + y.astype(self.dtype)
-        acc = self._accumulate(state["acc"], 1, stats,
-                               jnp.sum(jnp.where(live, pos + 1, 0)))
-        return self._emit(params, state, dict(state, kf=kf, vf=vf, kw=kw, vw=vw),
-                          x, live, pos, acc)
+        return {**m, "w_ring": w_ring, "roff": roff, "mask_win": (rpos >= 0)[:, None, :]}
 
-    # -- host side ----------------------------------------------------------------
-    def bind_metrics(self, metrics: Any) -> None:
-        self._counters = [self._expert_counters(metrics, ph)
-                          + [self._compact_counter(metrics, ph)] for ph in GEN_PHASES]
+    # -- the layer ---------------------------------------------------------------------
+    def _attend_full(self, q, k, v, kp, vp, m: dict):
+        """A full layer's attention in either phase: the launch's rows into
+        the pages, then the tiles' walks or the lanes' decode -> (o as ``q``
+        lies, the two pools)."""
+        t = m["t"]
+        qt = None if t is None else q.reshape((t["K"], t["T"]) + q.shape[1:])
+        kp = self._write_pages(kp, m["w_page"], m["off"], k)
+        vp = self._write_pages(vp, m["w_page"], m["off"], v)
+        if t is None:
+            return self._decode_full(q, kp, vp, m["bt"], m["pos"]), kp, vp
+        return self._prefill_full_tiles(qt, kp, vp, t).reshape(q.shape), kp, vp
+
+    def _attend_window(self, q, k, v, rk, rv, m: dict):
+        """A window layer's attention in either phase -> (o as ``q`` lies, the
+        two rings). A step writes its row and reads its ring (a free lane
+        reads ring 0, which every free lane writes: its result is discarded);
+        a launch reads what the rings held before it and itself, then writes."""
+        t, w_ring, roff = m["t"], m["w_ring"], m["roff"]
+        if t is None:
+            rk, rv = rk.at[w_ring, roff].set(k), rv.at[w_ring, roff].set(v)
+            return self._attend(q[:, None], jnp.take(rk, w_ring, axis=0),
+                                jnp.take(rv, w_ring, axis=0), m["mask_win"])[:, 0], rk, rv
+        o = self._prefill_window(q.reshape((t["K"], t["T"]) + q.shape[1:]), k, v,
+                                 jnp.take(rk, t["rings"], axis=0),
+                                 jnp.take(rv, t["rings"], axis=0), t["qpos"], m["rpos"],
+                                 m["pos"], m["own"])
+        rk, rv = rk.at[w_ring, roff].set(k), rv.at[w_ring, roff].set(v)
+        return o.reshape(q.shape), rk, rv
+
+    def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
+        q, k, v, gate = self._qkv(lp, i, rms_norm(x, lp["norm1"], self.eps), m["pos"])
+        if self.layer_types[i] == "full_attention":
+            j = self.full_layers.index(i)
+            o, c["kf"][j], c["vf"][j] = self._attend_full(q, k, v, c["kf"][j], c["vf"][j], m)
+        else:
+            j = self.win_layers.index(i)
+            o, c["kw"][j], c["vw"][j] = self._attend_window(q, k, v, c["kw"][j], c["vw"][j], m)
+        x = x + self._attn_out(lp, o, gate).astype(self.dtype)
+        y, st = self._ffn(lp, i, rms_norm(x, lp["norm2"], self.eps), m["live"])
+        return x + y.astype(self.dtype), st
 
 
 def create(cfg: ModelConfig) -> DecoderServing:

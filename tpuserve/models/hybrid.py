@@ -64,10 +64,10 @@ import jax
 import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
-from tpuserve.models.mixers import Mamba2Mixer, PlainAttention
-from tpuserve.models.paged_lm import (LOGPROBS, PagedLM, _mm,  # noqa: F401
-                                      head_share, read_config_file, rms_norm)
-from tpuserve.obs import GEN_PHASES
+from tpuserve.models.mixers import SSM_COLUMNS, PatternMixers
+from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN,  # noqa: F401
+                                      EXPERT_COLUMNS, LOGPROBS, PagedLM, _mm, head_share,
+                                      read_config_file, rms_norm)
 from tpuserve.ops.moe import held_experts, relu2, topk_route
 
 # Standard deviations of the drawn tensors, by role (``weight_scales`` in the
@@ -82,12 +82,10 @@ DEFAULT_SCALES = {
 }
 
 
-class HybridServing(Mamba2Mixer, PlainAttention, PagedLM):
-    # Device-side sums a phase: the expert layer's four and the context, as
-    # ``decoder`` has them, then live tokens through a scan layer, slot states
-    # read and written, (prefill) pieces that started from zeros / from a
-    # stored state, and expert layers whose dispatch took the compact branch.
-    ACC = 10
+class HybridServing(PatternMixers, PagedLM):
+    # The expert layer's four and the context, as ``decoder`` has them, then the
+    # scan layers' four, and expert layers whose dispatch took the compact branch.
+    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, *SSM_COLUMNS, COMPACT_COLUMN)
 
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)
@@ -179,14 +177,6 @@ class HybridServing(Mamba2Mixer, PlainAttention, PagedLM):
         self._join_mamba(p)
         return p
 
-    # -- shapes -----------------------------------------------------------------
-    def kv_page_signature(self, slots: int, pages: int, page_tokens: int) -> Any:
-        page = jax.ShapeDtypeStruct(self._page_shape(pages, page_tokens), self.dtype)
-        return {
-            "kf": [page for _ in self.a_layers], "vf": [page for _ in self.a_layers],
-            **self._mamba_signature(slots), **self._lane_signature(slots, page_tokens),
-        }
-
     # -- device math --------------------------------------------------------------
     def _relu2(self, u, w1, w2):
         return _mm(relu2(_mm(u, w1)).astype(self.dtype), w2)
@@ -203,85 +193,13 @@ class HybridServing(Mamba2Mixer, PlainAttention, PagedLM):
         return _mm(y.astype(self.dtype), lp["w_b"]) \
             + self._relu2(u, lp["s_w1"], lp["s_w2"]), stats
 
-    def _accumulate(self, acc, phase: int, stats_list, context, tokens, rows,
-                    zero=0, carried=0):
-        row = jnp.stack([jnp.asarray(v, jnp.int32) for v in (
-            *self._expert_sums(stats_list), context,
-            *self._ssm_sums(tokens, rows, zero, carried),
-            sum(st["compact"] for st in stats_list))])
-        return acc.at[phase].add(row.astype(jnp.uint32))
-
-    # -- prefill ------------------------------------------------------------------
-    def prefill_chunk(self, params: Any, state: Any, launch: Any, *, chunk: int) -> Any:
-        """One launch of ``pack_prefill``: piece j is tokens [start[j],
-        start[j] + length[j]) of the prompt in slot[j], causal within the
-        piece and over what earlier launches left in that slot's pages and
-        state."""
-        t = self._tiles(launch, chunk)
-        K, T = t["K"], t["T"]
-        slot, start, length = launch["slot"], launch["start"], launch["length"]
-        valid, cpos = t["valid"], t["cpos"]
-        x = jnp.take(params["embed"], launch["ids"], axis=0)
-        if self.a_layers:
-            w_page, off = self._page_of(t, state["kf"][0].shape[2], state["bt"].shape[1])
-        kf, vf, ssm, conv = (list(state[k]) for k in ("kf", "vf", "ssm", "conv"))
-        stats = []
-        for i, kind in enumerate(self.pattern):
-            lp = params[f"layer{i}"]
-            u = rms_norm(x, lp["norm"], self.eps)
-            if kind == "M":
-                j = self.m_layers.index(i)
-                y, ssm[j], conv[j] = self._mamba_prefill(
-                    lp, u, t, ssm[j], conv[j], slot, start, length)
-            elif kind == "*":
-                j = self.a_layers.index(i)
-                y, kf[j], vf[j] = self._attn_prefill(lp, u, t, kf[j], vf[j], w_page, off)
-            else:
-                y, st = self._experts(lp, u, valid)
-                stats.append(st)
-            x = x + y.astype(self.dtype)
-        has = length > 0
-        new = dict(state, kf=kf, vf=vf, ssm=ssm, conv=conv, acc=self._accumulate(
-            state["acc"], 0, stats, jnp.sum(jnp.where(valid, cpos + 1, 0)),
-            jnp.sum(valid), jnp.sum(has), jnp.sum(has & (start == 0)),
-            jnp.sum(has & (start > 0))))
-        return self._arm(params, state, new, launch, t, x, {})
-
-    # -- decode -------------------------------------------------------------------
-    def step(self, params: Any, state: Any) -> tuple[Any, dict]:
-        live = state["armed"] & ~state["done"]
-        pos = jnp.clip(state["pos"], 0, self.max_ctx - 1)
-        x = jnp.take(params["embed"], state["last"], axis=0)
-        if self.a_layers:
-            P = state["kf"][0].shape[2]
-            page_of = jnp.take_along_axis(state["bt"], (pos // P)[:, None], axis=1)[:, 0]
-            w_page, off = jnp.where(live, page_of, 0), pos % P
-        kf, vf, ssm, conv = (list(state[k]) for k in ("kf", "vf", "ssm", "conv"))
-        stats = []
-        for i, kind in enumerate(self.pattern):
-            lp = params[f"layer{i}"]
-            u = rms_norm(x, lp["norm"], self.eps)
-            if kind == "M":
-                j = self.m_layers.index(i)
-                y, ssm[j], conv[j] = self._mamba_step(lp, u, live, ssm[j], conv[j])
-            elif kind == "*":
-                j = self.a_layers.index(i)
-                y, kf[j], vf[j] = self._attn_step(lp, u, kf[j], vf[j], state["bt"], pos,
-                                                   w_page, off)
-            else:
-                y, st = self._experts(lp, u, live)
-                stats.append(st)
-            x = x + y.astype(self.dtype)
-        n_live = jnp.sum(live)
-        acc = self._accumulate(state["acc"], 1, stats,
-                               jnp.sum(jnp.where(live, pos + 1, 0)), n_live, n_live)
-        return self._emit(params, state, dict(state, kf=kf, vf=vf, ssm=ssm, conv=conv),
-                          x, live, pos, acc)
-
-    # -- host side ----------------------------------------------------------------
-    def bind_metrics(self, metrics: Any) -> None:
-        self._counters = [self._expert_counters(metrics, ph) + self._ssm_counters(metrics, ph)
-                          + [self._compact_counter(metrics, ph)] for ph in GEN_PHASES]
+    def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
+        u = rms_norm(x, lp["norm"], self.eps)
+        if self.pattern[i] == "E":
+            y, st = self._experts(lp, u, m["live"])
+        else:
+            y, st = self._mixer(i, lp, u, c, m), None
+        return x + y.astype(self.dtype), st
 
 
 def create(cfg: ModelConfig) -> HybridServing:

@@ -40,13 +40,11 @@ from __future__ import annotations
 
 from typing import Any
 
-import jax
 import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
-from tpuserve.models.mixers import Mamba2Mixer, PlainAttention
-from tpuserve.models.paged_lm import PagedLM, read_config_file, rms_norm
-from tpuserve.obs import GEN_PHASES
+from tpuserve.models.mixers import SSM_COLUMNS, PatternMixers
+from tpuserve.models.paged_lm import CONTEXT_COLUMN, PagedLM, read_config_file, rms_norm
 
 # Standard deviations of the drawn tensors, by role (``weight_scales`` in the
 # config file overrides any): ``hybrid``'s, where the roles are the same.
@@ -58,11 +56,8 @@ DEFAULT_SCALES = {
 KINDS = ("mamba", "attention")
 
 
-class HybridFfnServing(Mamba2Mixer, PlainAttention, PagedLM):
-    # Device-side sums a phase: the context (positions a live token attends
-    # from), live tokens through a scan layer, slot states read and written,
-    # (prefill) pieces that started from zeros / from a stored state.
-    ACC = 5
+class HybridFfnServing(PatternMixers, PagedLM):
+    COLUMNS = (CONTEXT_COLUMN, *SSM_COLUMNS)   # the context and the scan layers' four
 
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)
@@ -140,14 +135,6 @@ class HybridFfnServing(Mamba2Mixer, PlainAttention, PagedLM):
         self._join_mamba(p)
         return p
 
-    # -- shapes -----------------------------------------------------------------
-    def kv_page_signature(self, slots: int, pages: int, page_tokens: int) -> Any:
-        page = jax.ShapeDtypeStruct(self._page_shape(pages, page_tokens), self.dtype)
-        return {
-            "kf": [page for _ in self.a_layers], "vf": [page for _ in self.a_layers],
-            **self._mamba_signature(slots), **self._lane_signature(slots, page_tokens),
-        }
-
     # -- device math --------------------------------------------------------------
     def _embed(self, params, ids):
         x = jnp.take(params["embed"], ids, axis=0)
@@ -165,73 +152,9 @@ class HybridFfnServing(Mamba2Mixer, PlainAttention, PagedLM):
     def _head(self, params, x):
         return super()._head(params, x) / self.logits_scaling
 
-    def _accumulate(self, acc, phase: int, context, tokens, rows, zero=0, carried=0):
-        row = jnp.stack([jnp.asarray(v, jnp.int32) for v in (
-            context, *self._ssm_sums(tokens, rows, zero, carried))])
-        return acc.at[phase].add(row.astype(jnp.uint32))
-
-    # -- prefill ------------------------------------------------------------------
-    def prefill_chunk(self, params: Any, state: Any, launch: Any, *, chunk: int) -> Any:
-        """One launch of ``pack_prefill``: piece j is tokens [start[j],
-        start[j] + length[j]) of the prompt in slot[j], causal within the
-        piece and over what earlier launches left in that slot's pages and
-        state."""
-        t = self._tiles(launch, chunk)
-        slot, start, length = launch["slot"], launch["start"], launch["length"]
-        valid, cpos = t["valid"], t["cpos"]
-        x = self._embed(params, launch["ids"])
-        if self.a_layers:
-            w_page, off = self._page_of(t, state["kf"][0].shape[2], state["bt"].shape[1])
-        kf, vf, ssm, conv = (list(state[k]) for k in ("kf", "vf", "ssm", "conv"))
-        for i, kind in enumerate(self.kinds):
-            lp = params[f"layer{i}"]
-            u = rms_norm(x, lp["norm1"], self.eps)
-            if kind == "mamba":
-                j = self.m_layers.index(i)
-                y, ssm[j], conv[j] = self._mamba_prefill(
-                    lp, u, t, ssm[j], conv[j], slot, start, length)
-            else:
-                j = self.a_layers.index(i)
-                y, kf[j], vf[j] = self._attn_prefill(lp, u, t, kf[j], vf[j], w_page, off)
-            x = self._ffn(lp, self._add(x, y))
-        has = length > 0
-        new = dict(state, kf=kf, vf=vf, ssm=ssm, conv=conv, acc=self._accumulate(
-            state["acc"], 0, jnp.sum(jnp.where(valid, cpos + 1, 0)),
-            jnp.sum(valid), jnp.sum(has), jnp.sum(has & (start == 0)),
-            jnp.sum(has & (start > 0))))
-        return self._arm(params, state, new, launch, t, x, {})
-
-    # -- decode -------------------------------------------------------------------
-    def step(self, params: Any, state: Any) -> tuple[Any, dict]:
-        live = state["armed"] & ~state["done"]
-        pos = jnp.clip(state["pos"], 0, self.max_ctx - 1)
-        x = self._embed(params, state["last"])
-        if self.a_layers:
-            P = state["kf"][0].shape[2]
-            page_of = jnp.take_along_axis(state["bt"], (pos // P)[:, None], axis=1)[:, 0]
-            w_page, off = jnp.where(live, page_of, 0), pos % P
-        kf, vf, ssm, conv = (list(state[k]) for k in ("kf", "vf", "ssm", "conv"))
-        for i, kind in enumerate(self.kinds):
-            lp = params[f"layer{i}"]
-            u = rms_norm(x, lp["norm1"], self.eps)
-            if kind == "mamba":
-                j = self.m_layers.index(i)
-                y, ssm[j], conv[j] = self._mamba_step(lp, u, live, ssm[j], conv[j])
-            else:
-                j = self.a_layers.index(i)
-                y, kf[j], vf[j] = self._attn_step(lp, u, kf[j], vf[j], state["bt"], pos,
-                                                   w_page, off)
-            x = self._ffn(lp, self._add(x, y))
-        n_live = jnp.sum(live)
-        acc = self._accumulate(state["acc"], 1, jnp.sum(jnp.where(live, pos + 1, 0)),
-                               n_live, n_live)
-        return self._emit(params, state, dict(state, kf=kf, vf=vf, ssm=ssm, conv=conv),
-                          x, live, pos, acc)
-
-    # -- host side ----------------------------------------------------------------
-    def bind_metrics(self, metrics: Any) -> None:
-        self._counters = [[self._context_counter(metrics, ph)] + self._ssm_counters(metrics, ph)
-                          for ph in GEN_PHASES]
+    def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
+        y = self._mixer(i, lp, rms_norm(x, lp["norm1"], self.eps), c, m)
+        return self._ffn(lp, self._add(x, y)), None
 
 
 def create(cfg: ModelConfig) -> HybridFfnServing:

@@ -2,9 +2,11 @@
 (ISSUE 40; moved out of ``hybrid.py`` so that ``hybrid_ffn.py`` is not a copy):
 a Mamba-2 state-space layer with its state a slot, and attention by head with
 no rotary embedding over the paged KV. Both are mix-ins over
-``paged_lm.PagedLM``: they bring tensors, device math, the slot blocks'
-shapes and the counters, and know nothing of how a family orders its layers,
+``paged_lm.PagedLM``: they bring tensors, device math, the caches' shapes and
+the columns of ``acc``, and know nothing of how a family orders its layers,
 names its config keys or adds a mixer's output to the stream.
+``PatternMixers`` is the two together: layer ``i``'s mixer, whichever it is, in
+whichever phase (``_mixer``), for ``paged_lm``'s loop.
 
 ``Mamba2Mixer`` (a family calls ``_mamba_setup`` in its constructor and sets
 ``m_layers``): ``[z | xBC | dt] = u W_in``; a depthwise causal convolution and
@@ -31,12 +33,11 @@ to ``W_o``, runs under ``jax.named_scope("attn_decode")``.
 from __future__ import annotations
 
 import math
-from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from tpuserve.models.paged_lm import _mm
+from tpuserve.models.paged_lm import Column, _mm, counted, series
 
 
 def softplus_inverse(y: float) -> float:
@@ -259,21 +260,42 @@ class Mamba2Mixer:
             new_conv = jnp.where(keep, seq[:, 1:], conv)
         return out, new_ssm, new_conv
 
-    # -- counters -------------------------------------------------------------------
-    def _ssm_sums(self, tokens, rows, zero=0, carried=0) -> tuple:
-        """``acc``'s four columns of one launch: live tokens through a scan
-        layer and slot states read and written (both times the layers), and
-        (prefill) pieces that started from zeros / from a stored state."""
-        n_m = len(self.m_layers)
-        return tokens * n_m, rows * n_m, zero, carried
+    def _mamba(self, lp, u, ssm, conv, m: dict):
+        """One Mamba-2 layer in the phase the plan ``m`` is of."""
+        if m["t"] is None:
+            return self._mamba_step(lp, u, m["live"], ssm, conv)
+        return self._mamba_prefill(lp, u, m["t"], ssm, conv, m["slot"], m["start"], m["length"])
 
-    def _ssm_counters(self, metrics: Any, ph: str) -> list:
-        """The counters of those four columns in phase ``ph``."""
-        name = self.name
-        return [metrics.counter(f"ssm_tokens_total{{model={name},phase={ph}}}"),
-                metrics.counter(f"ssm_state_rows_total{{model={name},phase={ph}}}"),
-                ] + ([metrics.counter(f"ssm_pieces_total{{model={name},start={start}}}")
-                      for start in ("zero", "carried")] if ph == "prefill" else [None, None])
+    # -- counters -------------------------------------------------------------------
+    def _counts(self, m: dict) -> dict:
+        """And live tokens (through a scan layer), slot states read and
+        written and, in a launch, the pieces that started from zeros and
+        from a stored state."""
+        if m["t"] is None:
+            n_live = jnp.sum(m["live"])
+            return {**super()._counts(m), "tokens": n_live, "rows": n_live,
+                    "zero": 0, "carried": 0}
+        start, has = m["start"], m["length"] > 0
+        return {**super()._counts(m), "tokens": jnp.sum(m["live"]), "rows": jnp.sum(has),
+                "zero": jnp.sum(has & (start == 0)), "carried": jnp.sum(has & (start > 0))}
+
+
+def _pieces(start: str):
+    """``ssm_pieces_total{model=,start=}``: a launch's pieces, so prefill's alone."""
+    return lambda model, metrics, ph: metrics.counter(
+        f"ssm_pieces_total{{model={model.name},start={start}}}") if ph == "prefill" else None
+
+
+# Live tokens through a scan layer and slot states read and written (both
+# times the scan layers), and (prefill) pieces that started from zeros / from a
+# stored state.
+SSM_COLUMNS = (
+    Column(lambda model, stats, counts: counts["tokens"] * len(model.m_layers),
+           series("ssm_tokens_total")),
+    Column(lambda model, stats, counts: counts["rows"] * len(model.m_layers),
+           series("ssm_state_rows_total")),
+    Column(counted("zero"), _pieces("zero")),
+    Column(counted("carried"), _pieces("carried")))
 
 
 class PlainAttention:
@@ -314,3 +336,31 @@ class PlainAttention:
             kp, vp = self._write_pages(kp, w_page, off, k), self._write_pages(vp, w_page, off, v)
             y = self._attn_out(lp, self._decode_full(q, kp, vp, bt, pos))
         return y, kp, vp
+
+    def _attn(self, lp, u, kp, vp, m: dict):
+        """One attention layer in the phase the plan ``m`` is of."""
+        if m["t"] is None:
+            return self._attn_step(lp, u, kp, vp, m["bt"], m["pos"], m["w_page"], m["off"])
+        return self._attn_prefill(lp, u, m["t"], kp, vp, m["w_page"], m["off"])
+
+
+class PatternMixers(Mamba2Mixer, PlainAttention):
+    """A family whose layer ``i`` has ONE of the two mixers (``m_layers``,
+    ``a_layers``): the four cache leaves and the layer's mixer."""
+    cache_leaves = ("kf", "vf", "ssm", "conv")
+
+    def _cache_signature(self, slots: int, pages: int, page_tokens: int) -> dict:
+        page = jax.ShapeDtypeStruct(self._page_shape(pages, page_tokens), self.dtype)
+        return {"kf": [page for _ in self.a_layers], "vf": [page for _ in self.a_layers],
+                **self._mamba_signature(slots)}
+
+    def _mixer(self, i: int, lp, u, c: dict, m: dict):
+        """Layer ``i``'s mixer on the normed stream ``u`` -> (T, d) float32;
+        the layer's caches in ``c`` are replaced."""
+        if i in self.m_layers:
+            j = self.m_layers.index(i)
+            y, c["ssm"][j], c["conv"][j] = self._mamba(lp, u, c["ssm"][j], c["conv"][j], m)
+        else:
+            j = self.a_layers.index(i)
+            y, c["kf"][j], c["vf"][j] = self._attn(lp, u, c["kf"][j], c["vf"][j], m)
+        return y

@@ -90,9 +90,10 @@ import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
 from tpuserve.models.decoder import apply_rope, rope_inv_freq
-from tpuserve.models.paged_lm import (KEY_BLOCK, LOGPROBS, NEG, PagedLM,  # noqa: F401
-                                      _mm, read_config_file, rms_norm)
-from tpuserve.obs import GEN_PHASES
+from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN,  # noqa: F401
+                                      EXPERT_COLUMNS, KEY_BLOCK, LOGPROBS, NEG, Column,
+                                      PagedLM, _mm, counted, read_config_file, rms_norm,
+                                      series)
 from tpuserve.ops import lane_attention as la
 from tpuserve.ops import tile_attention as ta
 from tpuserve.ops.moe import held_experts_swiglu, topk_route
@@ -112,20 +113,30 @@ WALKS = ("kernel", "xla")
 
 
 class LatentServing(PagedLM):
-    # Device-side sums a phase: the expert layers' four and the context (as
-    # ``decoder``), then cache rows attended over (each once a piece or a
-    # lane: the least a launch reads), cache rows the walk gathered (whole key
-    # blocks a tile or a lane, a layer), launches by form, expert layers whose
-    # dispatch took the compact branch (none where every expert is held), and
-    # the tiles whose walk over key blocks ran in the kernel and in XLA
-    # (``_walk``: a launch's tiles of a piece; a step's live lanes).
-    ACC = 12
+    # The expert layers' four and the context (as ``decoder``), then cache rows
+    # attended over (each once a piece or a lane: the least a launch reads),
+    # cache rows the walk gathered (whole key blocks a tile or a lane, a
+    # layer), launches by form, expert layers whose dispatch took the compact
+    # branch (none where every expert is held), and the tiles whose walk over
+    # key blocks ran in the kernel and in XLA (``_walk``: a launch's tiles of a
+    # piece; a step's live lanes; every attention of a launch walks alike, so a
+    # tile counts once).
+    COLUMNS = (
+        *EXPERT_COLUMNS, CONTEXT_COLUMN,
+        Column(counted("attended"), series("mla_rows_attended_total")),
+        Column(counted("walked"), series("mla_rows_walked_total")),
+        *(Column(lambda model, stats, counts, form=form: counts["form"] == form,
+                 series("mla_launches_total", f",form={form}")) for form in FORMS),
+        COMPACT_COLUMN,
+        *(Column(lambda model, stats, counts, walk=walk:
+                 counts["tiles"] if counts["walk"] == walk else 0,
+                 series("mla_tiles_total", f",walk={walk}")) for walk in WALKS))
     TILE_ROWS = KEY_BLOCK
     # Key positions a cell of the step's kernel walks (``ops/lane_attention.py``
     # says what a cell costs): at contexts of thousands a prefill tile's key
     # block, and anything from there up reads alike (PERF.md section 6, PR 44).
     step_keys = KEY_BLOCK
-    kv_page_leaves = ("ckv", "kr")
+    kv_page_leaves = cache_leaves = ("ckv", "kr")
 
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)
@@ -254,14 +265,13 @@ class LatentServing(PagedLM):
         return p
 
     # -- shapes -----------------------------------------------------------------
-    def kv_page_signature(self, slots: int, pages: int, page_tokens: int) -> Any:
+    def _cache_signature(self, slots: int, pages: int, page_tokens: int) -> dict:
         S = jax.ShapeDtypeStruct
         g = 128 // self.dr if 128 % self.dr == 0 else 1   # positions a row of 128 lanes
         g = g if page_tokens % g == 0 else 1
-        layers = range(self.n_layers)
-        return {"ckv": [S((pages, page_tokens, self.r), self.dtype) for _ in layers],
-                "kr": [S((pages, page_tokens // g, g * self.dr), self.dtype) for _ in layers],
-                **self._lane_signature(slots, page_tokens)}
+        return {"ckv": [S((pages, page_tokens, self.r), self.dtype) for _ in self._attentions()],
+                "kr": [S((pages, page_tokens // g, g * self.dr), self.dtype)
+                       for _ in self._attentions()]}
 
     # -- device math --------------------------------------------------------------
     def _form(self, tile_rows: int) -> str:
@@ -428,17 +438,6 @@ class LatentServing(PagedLM):
                          ckv, kr, work, scale=(self.dn + self.dr) ** -0.5)
         return jnp.einsum("bhr,rhv->bhv", o, lp["w_vb"], **f32)
 
-    @staticmethod
-    def _by_walk(walk: str, n):
-        """``n`` tiles or lanes counted under the walk they took: (kernel, xla)."""
-        return (n, 0) if walk == "kernel" else (0, n)
-
-    def _tile_walks(self, t: dict, pools, form: str):
-        """A launch's tiles that belong to a piece, counted under the walk
-        they take."""
-        return self._by_walk(self._walk(form, t["T"], pools, t["rows"].shape[1]),
-                             jnp.sum(t["has"]))
-
     def _attn_out(self, lp, o):
         return jnp.einsum("thv,hvd->td", o.astype(self.dtype), lp["wo"],
                           preferred_element_type=jnp.float32)
@@ -455,99 +454,66 @@ class LatentServing(PagedLM):
                                        lp["e_down"], live=live, of=self.n_experts)
         return y + self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"]), stats
 
-    def _sums(self, stats_list, context, attended, walked, form: str, walks) -> tuple:
-        """One launch's row of ``acc`` (``walks``: its tiles or lanes by walk,
-        (kernel, xla): every attention of a launch walks alike, so a tile
-        counts once)."""
-        return (*self._expert_sums(stats_list), context, attended, walked,
-                form == "absorbed", form == "expanded",
-                sum(st["compact"] for st in stats_list), *walks)
-
-    def _accumulate(self, acc, phase: int, *sums):
-        row = jnp.stack([jnp.asarray(v, jnp.int32) for v in self._sums(*sums)])
-        return acc.at[phase].add(row.astype(jnp.uint32))
-
-    # -- prefill ------------------------------------------------------------------
-    def prefill_chunk(self, params: Any, state: Any, launch: Any, *, chunk: int) -> Any:
-        """One launch of ``pack_prefill``: piece j is tokens [start[j],
-        start[j] + length[j]) of the prompt in slot[j], causal within the
-        piece and over the latent rows earlier launches left in that slot's
-        pages."""
-        t = self._tiles(launch, chunk)
-        start, length = launch["start"], launch["length"]
-        valid, cpos = t["valid"], t["cpos"]
-        P, pps = state["ckv"][0].shape[1], state["bt"].shape[1]
+    # -- what a launch works out once, its layer, its counts -----------------------------
+    def _prefill_plan(self, state, launch, t: dict) -> dict:
+        """And the form and the walk of the launch's tiles."""
+        pools, pps = (state["ckv"][0], state["kr"][0]), state["bt"].shape[1]
         form = self._form(t["T"])
-        x = jnp.take(params["embed"], launch["ids"], axis=0)
-        w_page, off = self._page_of(t, P, pps)
-        ckv, kr, stats = list(state["ckv"]), list(state["kr"]), []
-        for i in range(self.n_layers):
-            lp = params[f"layer{i}"]
-            with jax.named_scope("mla_prefill"):
-                qn, qr, c_kv, k_r = self._project(lp, rms_norm(x, lp["norm1"], self.eps), cpos)
-                ckv[i] = self._write_pages(ckv[i], w_page, off, c_kv.astype(ckv[i].dtype))
-                kr[i] = self._write_keys(kr[i], w_page, off, k_r, runs=True)
-                y = self._attn_out(lp, self._attend_tiles(lp, qn, qr, (ckv[i], kr[i]), t, form))
-            x = x + y.astype(self.dtype)
-            y, st = self._ffn(lp, i, rms_norm(x, lp["norm2"], self.eps), valid)
-            if st is not None:
-                stats.append(st)
-            x = x + y.astype(self.dtype)
-        walked = jnp.sum(self._blocks_needed(t["last"], P, pps)) * self._block_pages(P, pps) * P
-        new = dict(state, ckv=ckv, kr=kr, acc=self._accumulate(
-            state["acc"], 0, stats, jnp.sum(jnp.where(valid, cpos + 1, 0)),
-            jnp.sum(jnp.where(length > 0, start + length, 0)), walked, form,
-            self._tile_walks(t, (ckv[0], kr[0]), form)))
-        return self._arm(params, state, new, launch, t, x, {})
+        return {**super()._prefill_plan(state, launch, t), "scope": "mla_prefill", "form": form,
+                "walk": self._walk(form, t["T"], pools, pps), "P": pools[0].shape[1], "pps": pps}
 
-    # -- decode -------------------------------------------------------------------
-    def step(self, params: Any, state: Any) -> tuple[Any, dict]:
-        live = state["armed"] & ~state["done"]
-        pos = jnp.clip(state["pos"], 0, self.max_ctx - 1)
-        P, bt = state["ckv"][0].shape[1], state["bt"]
-        form = self._form(1)
-        x = jnp.take(params["embed"], state["last"], axis=0)
-        page_of = jnp.take_along_axis(bt, (pos // P)[:, None], axis=1)[:, 0]
-        w_page, off = jnp.where(live, page_of, 0), pos % P
-        # A lane that is not live walks one block of whatever its row names:
-        # its result is discarded.
+    def _step_plan(self, state, live, pos) -> dict:
+        """And the step's walk, chosen once for all its attentions. A lane
+        that is not live walks one block of whatever its row names: its
+        result is discarded."""
+        m = super()._step_plan(state, live, pos)
         last = jnp.where(live, pos, 0)
-        ckv, kr, stats = list(state["ckv"]), list(state["kr"]), []
-        walk, work, walked = self._step_walk((ckv[0], kr[0]), bt, last)
-        for i in range(self.n_layers):
-            lp = params[f"layer{i}"]
-            with jax.named_scope("mla_decode"):
-                qn, qr, c_kv, k_r = self._project(lp, rms_norm(x, lp["norm1"], self.eps), pos)
-                ckv[i] = self._write_pages(ckv[i], w_page, off, c_kv.astype(ckv[i].dtype))
-                kr[i] = self._write_keys(kr[i], w_page, off, k_r, runs=False)
-                if walk == "kernel":
-                    o = self._walk_lanes(lp, qn, qr, (ckv[i], kr[i]), work)
-                else:
-                    o = jax.lax.map(
-                        lambda a, lp=lp, pools=(ckv[i], kr[i]): self._attend_tile(
-                            lp, *a[:2], pools, *a[2:], form),
-                        (qn[:, None], qr[:, None], bt, pos[:, None], last))[:, 0]
-                y = self._attn_out(lp, o)
-            x = x + y.astype(self.dtype)
-            y, st = self._ffn(lp, i, rms_norm(x, lp["norm2"], self.eps), live)
-            if st is not None:
-                stats.append(st)
-            x = x + y.astype(self.dtype)
-        context = jnp.sum(jnp.where(live, pos + 1, 0))
-        acc = self._accumulate(state["acc"], 1, stats, context, context, walked, form,
-                               self._by_walk(walk, jnp.sum(live)))
-        return self._emit(params, state, dict(state, ckv=ckv, kr=kr), x, live, pos, acc)
+        walk, work, walked = self._step_walk((state["ckv"][0], state["kr"][0]), m["bt"], last)
+        return {**m, "scope": "mla_decode", "form": self._form(1), "last": last, "walk": walk,
+                "work": work, "walked": walked}
 
-    # -- host side ----------------------------------------------------------------
-    def bind_metrics(self, metrics: Any) -> None:
-        name = self.name
-        self._counters = [self._expert_counters(metrics, ph) + [
-            metrics.counter(f"mla_rows_attended_total{{model={name},phase={ph}}}"),
-            metrics.counter(f"mla_rows_walked_total{{model={name},phase={ph}}}"),
-        ] + [metrics.counter(f"mla_launches_total{{model={name},phase={ph},form={form}}}")
-             for form in FORMS] + [self._compact_counter(metrics, ph)] + [
-            metrics.counter(f"mla_tiles_total{{model={name},phase={ph},walk={walk}}}")
-            for walk in WALKS] for ph in GEN_PHASES]
+    def _attention(self, lp: dict, u, at: int, c: dict, m: dict):
+        """Attention ``at`` of the model on the normed stream ``u`` in the
+        phase the plan ``m`` is of: the rows' latents into the pages, then
+        the launch's tiles (``_attend_tiles``) or the step's lanes, in the
+        kernel (``_walk_lanes``) or one after another in XLA -> (T, d)
+        float32."""
+        t = m["t"]
+        qn, qr, c_kv, k_r = self._project(lp, u, m["pos"])
+        c["ckv"][at] = self._write_pages(c["ckv"][at], m["w_page"], m["off"],
+                                         c_kv.astype(c["ckv"][at].dtype))
+        c["kr"][at] = self._write_keys(c["kr"][at], m["w_page"], m["off"], k_r, runs=t is not None)
+        pools = (c["ckv"][at], c["kr"][at])
+        if t is not None:
+            o = self._attend_tiles(lp, qn, qr, pools, t, m["form"])
+        elif m["walk"] == "kernel":
+            o = self._walk_lanes(lp, qn, qr, pools, m["work"])
+        else:
+            o = jax.lax.map(
+                lambda a: self._attend_tile(lp, *a[:2], pools, *a[2:], m["form"]),
+                (qn[:, None], qr[:, None], m["bt"], m["pos"][:, None], m["last"]))[:, 0]
+        return self._attn_out(lp, o)
+
+    def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
+        with jax.named_scope(m["scope"]):
+            y = self._attention(lp, rms_norm(x, lp["norm1"], self.eps), i, c, m)
+        x = x + y.astype(self.dtype)
+        y, st = self._ffn(lp, i, rms_norm(x, lp["norm2"], self.eps), m["live"])
+        return x + y.astype(self.dtype), st
+
+    def _counts(self, m: dict) -> dict:
+        """And the rows attended over and walked, the launch's form, and its
+        tiles (a step's live lanes) with the walk they took."""
+        t = m["t"]
+        if t is not None:
+            P, pps = m["P"], m["pps"]
+            walked = jnp.sum(self._blocks_needed(t["last"], P, pps)) * self._block_pages(P, pps) * P
+        c = {**super()._counts(m), "form": m["form"], "walk": m["walk"]}
+        if t is None:
+            return {**c, "attended": c["context"], "walked": m["walked"],
+                    "tiles": jnp.sum(m["live"])}
+        return {**c, "attended": jnp.sum(jnp.where(m["length"] > 0, m["start"] + m["length"], 0)),
+                "walked": walked, "tiles": jnp.sum(t["has"])}
 
 
 def create(cfg: ModelConfig) -> LatentServing:

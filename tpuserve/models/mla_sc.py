@@ -1,9 +1,9 @@
 """A decoder-only language model whose layers are DOUBLE and carry their
 routed experts on a SHORTCUT (ISSUE 42): two latent attentions (``mla``'s, by
 inheritance: its projections, its two page leaves, its two forms, its map over
-a launch's tiles and its two kernels) and two dense SwiGLUs a layer, and one routed
-layer that reads the first sublayer's normed stream and joins the stream at
-the layer's end.
+a launch's tiles, its step's walk and its two kernels) and two dense SwiGLUs a
+layer, and one routed layer that reads the first sublayer's normed stream and
+joins the stream at the layer's end.
 Built from a published ``config.json`` and served through the generation
 engine as ``mla`` is. Nothing here knows a model's name.
 
@@ -49,17 +49,14 @@ its kernel stay: on the TPU one call of ``ops/tile_attention.py`` a tile walks
 the tile's key blocks, as in ``mla``, through ``mla``'s own ``_attend_tiles``)
 over key blocks of ``key_block`` = 256 positions: a launch of 1,024 rows
 carries up to four prompts' pieces, and a short prompt's tile reads two pages,
-not eight. A decode step is absorbed and walks where ``mla``'s does
-(``_step_walk``). On the TPU at shapes the kernel takes, ONE call of
-``ops/lane_attention.py`` an attention walks every lane's own key blocks
-(``mla._walk_lanes``): hundreds of lanes of very different lengths side by
-side, each as far as its own position needs, the stream in the lanes' own
-order. Elsewhere the step attends its lanes IN GROUPS in XLA: it runs in order
-of context length (sorted once, the last stream put back before it is
-sampled), and ``DECODE_GROUP`` lanes walk the key blocks side by side as far
-as the longest of them needs (``_attend_lanes``), because ``mla``'s XLA walk,
-one lane after another, is thousands of serial walks at hundreds of lanes and
-eight attentions a step.
+not eight. A decode step is ``mla``'s (``_step_plan``, ``_attention``): absorbed;
+on the TPU at shapes the kernel takes, ONE call of ``ops/lane_attention.py`` an
+attention walks every lane's own key blocks in cells of ``step_keys`` = 512
+positions, hundreds of lanes of very different lengths side by side, each as
+far as its own position needs; elsewhere the lanes walk one after another in
+XLA. Both programs are ``paged_lm``'s loop over ``_layer``; what is this
+family's own is the layer, the routed layer's third kind of pick and one
+column of ``acc``.
 """
 
 from __future__ import annotations
@@ -72,8 +69,7 @@ import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
 from tpuserve.models import mla
-from tpuserve.models.paged_lm import NEG, PagedLM, read_config_file, rms_norm
-from tpuserve.obs import GEN_PHASES
+from tpuserve.models.paged_lm import Column, PagedLM, read_config_file, rms_norm, series, summed
 from tpuserve.ops.moe import held_experts_swiglu, topk_route
 
 # As ``mla``'s, but the router: a softmax over hundreds of outputs is flat at
@@ -84,13 +80,13 @@ DEFAULT_SCALES = {**mla.DEFAULT_SCALES, "router": 1.75}
 
 
 class ShortcutLatentServing(mla.LatentServing):
-    # ``mla``'s twelve sums a phase and the live picks on zero-compute outputs.
-    # Rows attended and walked sum over every attention that ran.
-    ACC = 13
+    # ``mla``'s twelve columns and the live picks on zero-compute outputs. Rows
+    # attended and walked sum over every attention that ran (``_counts``).
+    COLUMNS = (*mla.LatentServing.COLUMNS,
+               Column(summed("routed_zero"), series("moe_routed_zero_total")))
     TILE_ROWS = 256
     key_block = 256
     step_keys = 512     # contexts of hundreds: four pages a cell read fastest (PERF.md 6, PR 44)
-    DECODE_GROUP = 32   # lanes that walk their key blocks side by side in XLA
 
     def __init__(self, cfg: ModelConfig) -> None:
         PagedLM.__init__(self, cfg)
@@ -179,11 +175,6 @@ class ShortcutLatentServing(mla.LatentServing):
         for i in range(self.n_layers):
             yield ((f"layer{i}", "e_bias"), (n,), (n,), (0,), -b3, b3)
 
-    # -- shapes -----------------------------------------------------------------
-    def kv_page_signature(self, slots: int, pages: int, page_tokens: int) -> Any:
-        sig = super().kv_page_signature(slots, pages, page_tokens)
-        return {**sig, "ckv": sig["ckv"] * 2, "kr": sig["kr"] * 2}
-
     def share_stats(self) -> dict:
         """``/stats``: what of each layer is held here."""
         return {"experts_held": [self.e_first, self.e_count], "experts": self.n_experts,
@@ -203,10 +194,15 @@ class ShortcutLatentServing(mla.LatentServing):
                                    lp["e_down"], live=live, of=self.n_experts + self.n_zero,
                                    real=self.n_experts)
 
-    def _layer(self, lp, x, live, attend):
-        """One double layer (module docstring); ``attend(j, u)`` is attention
-        ``j`` of the layer on the normed stream, (T, d) float32."""
-        dt, eps = self.dtype, self.eps
+    def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
+        """One double layer (module docstring), in either phase: attention
+        ``j`` of layer ``i`` is the model's attention ``2 i + j``."""
+        dt, eps, live = self.dtype, self.eps, m["live"]
+
+        def attend(j: int, u):
+            with jax.named_scope(m["scope"]):
+                return self._attention(lp[f"attn{j}"], u, 2 * i + j, c, m)
+
         a0 = x + attend(0, rms_norm(x, lp["norm_in0"], eps)).astype(dt)
         u0 = rms_norm(a0, lp["norm_post0"], eps)
         with jax.named_scope("moe_layer"):
@@ -216,146 +212,9 @@ class ShortcutLatentServing(mla.LatentServing):
         u1 = rms_norm(a1, lp["norm_post1"], eps)
         return a1 + (self._swiglu(u1, **lp["mlp1"]) + s).astype(dt), stats
 
-    def _group(self, lanes: int) -> int:
-        """Lanes a group of the decode walk: the most, up to ``DECODE_GROUP``,
-        that divide the slots."""
-        return next(g for g in range(min(lanes, self.DECODE_GROUP), 0, -1) if lanes % g == 0)
-
-    def _group_blocks(self, last, P: int, pps: int):
-        """Key blocks each group of the decode walk takes: what its last
-        (longest) lane needs. ``last`` (B,) ascending -> (B / G,)."""
-        return self._blocks_needed(last.reshape(-1, self._group(last.shape[0]))[:, -1], P, pps)
-
-    def _attend_lanes(self, lp: dict, qn, qr, pools, bt, pos, last):
-        """A step's attention, absorbed: q_nope ``qn`` (B, H, nope) and rotated
-        q_rope ``qr`` (B, H, rope) of B lanes IN ORDER OF ``last``, each over
-        the latent rows of its own pages (``bt`` (B, pps)) up to its position
-        ``pos`` -> (B, H, v) float32. ``DECODE_GROUP`` lanes walk the key blocks
-        side by side, as many as the group's last (longest) lane needs; a lane
-        that needs fewer sees nothing in the others (its first block always
-        holds a key it sees, so its softmax's state is sound)."""
-        dt, r, h = self.dtype, self.r, self.heads
-        ckv, kr = pools
-        B, P, pps = qn.shape[0], ckv.shape[1], bt.shape[1]
-        G = self._group(B)
-        kb = self._block_pages(P, pps)
-        c = kb * P
-        f32 = {"preferred_element_type": jnp.float32}
-        scale = (self.dn + self.dr) ** -0.5
-        q_lat = jnp.einsum("bhn,rhn->bhr", qn, lp["w_kb"], **f32).astype(dt)
-        btp = jnp.pad(bt, ((0, 0), (0, -pps % kb)))
-
-        def group(a):
-            ql, qro, rows, p, need = a
-
-            def block(j):
-                pg = jax.lax.dynamic_slice(rows, (0, j * kb), (G, kb))
-                c_kv = jnp.take(ckv, pg, axis=0).reshape(G, c, r).astype(dt)
-                k_r = jnp.take(kr, pg, axis=0).reshape(G, c, self.dr).astype(dt)
-                see = (j * c + jnp.arange(c))[None, :] <= p[:, None]
-                s = jnp.einsum("ghr,gcr->ghc", ql, c_kv, **f32) \
-                    + jnp.einsum("ghd,gcd->ghc", qro, k_r, **f32)
-                return jnp.where(see[:, None], s * scale, NEG), \
-                    lambda pr: jnp.einsum("ghc,gcr->ghr", pr.astype(dt), c_kv, **f32)
-
-            return self._over_key_blocks(need, (G, h), r, block)
-
-        by_group = lambda v: v.reshape((B // G, G) + v.shape[1:])  # noqa: E731
-        o = jax.lax.map(group, (by_group(q_lat), by_group(qr), by_group(btp), by_group(pos),
-                                self._group_blocks(last, P, pps)))
-        return jnp.einsum("bhr,rhv->bhv", o.reshape(B, h, r).astype(dt), lp["w_vb"], **f32)
-
-    def _sums(self, stats_list, context, attended, walked, form: str, walks) -> tuple:
-        n = 2 * self.n_layers
-        return (*super()._sums(stats_list, context, n * attended, n * walked, form, walks),
-                sum(st["routed_zero"] for st in stats_list))
-
-    # -- prefill ------------------------------------------------------------------
-    def prefill_chunk(self, params: Any, state: Any, launch: Any, *, chunk: int) -> Any:
-        """As ``mla``'s: one launch of ``pack_prefill``, each piece causal
-        within itself and over the latent rows earlier launches left in its
-        slot's pages, through every layer's two attentions."""
-        t = self._tiles(launch, chunk)
-        start, length = launch["start"], launch["length"]
-        valid, cpos = t["valid"], t["cpos"]
-        P, pps = state["ckv"][0].shape[1], state["bt"].shape[1]
-        form = self._form(t["T"])
-        x = jnp.take(params["embed"], launch["ids"], axis=0)
-        w_page, off = self._page_of(t, P, pps)
-        ckv, kr, stats = list(state["ckv"]), list(state["kr"]), []
-
-        def attend(at: int, lp: dict, u):
-            with jax.named_scope("mla_prefill"):
-                qn, qr, c_kv, k_r = self._project(lp, u, cpos)
-                ckv[at] = self._write_pages(ckv[at], w_page, off, c_kv.astype(ckv[at].dtype))
-                kr[at] = self._write_keys(kr[at], w_page, off, k_r, runs=True)
-                return self._attn_out(
-                    lp, self._attend_tiles(lp, qn, qr, (ckv[at], kr[at]), t, form))
-
-        for i in range(self.n_layers):
-            lp = params[f"layer{i}"]
-            x, st = self._layer(lp, x, valid,
-                                lambda j, u, i=i, lp=lp: attend(2 * i + j, lp[f"attn{j}"], u))
-            stats.append(st)
-        walked = jnp.sum(self._blocks_needed(t["last"], P, pps)) * self._block_pages(P, pps) * P
-        new = dict(state, ckv=ckv, kr=kr, acc=self._accumulate(
-            state["acc"], 0, stats, jnp.sum(jnp.where(valid, cpos + 1, 0)),
-            jnp.sum(jnp.where(length > 0, start + length, 0)), walked, form,
-            self._tile_walks(t, (ckv[0], kr[0]), form)))
-        return self._arm(params, state, new, launch, t, x, {})
-
-    # -- decode -------------------------------------------------------------------
-    def step(self, params: Any, state: Any) -> tuple[Any, dict]:
-        """One token a live lane. Where the kernel walks (``_step_walk``) every
-        lane walks its own key blocks and the stream keeps the lanes' order.
-        Where XLA walks, the layers run over the lanes in order of context
-        length (``_attend_lanes`` groups neighbours), and the last stream goes
-        back to the lanes' own order before it is sampled."""
-        live = state["armed"] & ~state["done"]
-        pos = jnp.clip(state["pos"], 0, self.max_ctx - 1)
-        P, pps = state["ckv"][0].shape[1], state["bt"].shape[1]
-        ckv, kr, stats = list(state["ckv"]), list(state["kr"]), []
-        # A lane that is not live walks one block of whatever its row names:
-        # its result is discarded.
-        walk, work, walked = self._step_walk((ckv[0], kr[0]), state["bt"], jnp.where(live, pos, 0))
-        order = None if walk == "kernel" else jnp.argsort(jnp.where(live, pos, 0))
-        in_order = lambda v: v if order is None else v[order]  # noqa: E731
-        live_o, pos_o, bt = in_order(live), in_order(pos), in_order(state["bt"])
-        last = jnp.where(live_o, pos_o, 0)
-        x = jnp.take(params["embed"], in_order(state["last"]), axis=0)
-        page_of = jnp.take_along_axis(bt, (pos_o // P)[:, None], axis=1)[:, 0]
-        w_page, off = jnp.where(live_o, page_of, 0), pos_o % P
-
-        def attend(at: int, lp: dict, u):
-            with jax.named_scope("mla_decode"):
-                qn, qr, c_kv, k_r = self._project(lp, u, pos_o)
-                ckv[at] = self._write_pages(ckv[at], w_page, off, c_kv.astype(ckv[at].dtype))
-                kr[at] = self._write_keys(kr[at], w_page, off, k_r, runs=False)
-                pools = (ckv[at], kr[at])
-                return self._attn_out(lp, self._walk_lanes(lp, qn, qr, pools, work)
-                                      if walk == "kernel" else
-                                      self._attend_lanes(lp, qn, qr, pools, bt, pos_o, last))
-
-        for i in range(self.n_layers):
-            lp = params[f"layer{i}"]
-            x, st = self._layer(lp, x, live_o,
-                                lambda j, u, i=i, lp=lp: attend(2 * i + j, lp[f"attn{j}"], u))
-            stats.append(st)
-        context = jnp.sum(jnp.where(live, pos + 1, 0))
-        if walk == "xla":   # whole key blocks as far as each group's longest lane needs
-            walked = jnp.sum(self._group_blocks(last, P, pps)) \
-                * self._group(pos.shape[0]) * self._block_pages(P, pps) * P
-            x = jnp.take(x, jnp.argsort(order), axis=0)
-        acc = self._accumulate(state["acc"], 1, stats, context, context, walked, "absorbed",
-                               self._by_walk(walk, jnp.sum(live)))
-        return self._emit(params, state, dict(state, ckv=ckv, kr=kr), x, live, pos, acc)
-
-    # -- host side ----------------------------------------------------------------
-    def bind_metrics(self, metrics: Any) -> None:
-        super().bind_metrics(metrics)
-        for ph, counters in zip(GEN_PHASES, self._counters):
-            counters.append(
-                metrics.counter(f"moe_routed_zero_total{{model={self.name},phase={ph}}}"))
+    def _counts(self, m: dict) -> dict:
+        n, c = 2 * self.n_layers, super()._counts(m)
+        return {**c, "attended": n * c["attended"], "walked": n * c["walked"]}
 
 
 def create(cfg: ModelConfig) -> ShortcutLatentServing:

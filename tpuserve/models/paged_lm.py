@@ -9,12 +9,26 @@ blocks under a running softmax (``_key_blocks``, ``_over_key_blocks``: what
 attention by head and latent attention share, ISSUE 34), paged full attention
 by head for prefill tiles and for decode, page writes (rows by head or one
 latent row a token), the head, the sampler with its served
-log-probabilities, the arming of a lane and a step's token bookkeeping, the
-device's sums into counters, and the whole host side of a ``:generate``
-request. A family adds its layers: ``_tensors``, ``_gains``,
-``kv_page_signature``, ``prefill_chunk``, ``step``, ``bind_metrics``.
+log-probabilities, the arming of a lane and a step's token bookkeeping, and
+the whole host side of a ``:generate`` request.
 
-A subclass sets, in its constructor: ``dtype``, ``d``, ``eps``,
+THE TWO PROGRAMS ARE HERE, ONCE (ISSUE 45). ``prefill_chunk`` and ``step``:
+tiles or live lanes, the embedding, the launch's plan, the caches out of the
+state as lists, ``for i in layers: _layer``, one row into ``acc``, ``_arm`` or
+``_emit``. A family supplies its parameters (``_gains``, ``_tensors``,
+``_vectors``, under ``layer{i}``); its per-layer caches (``cache_leaves``,
+``_cache_signature``: ``kv_page_signature`` is those and ``_lane_signature``);
+ONE ``_layer(i, lp, x, caches, m)`` for both phases -> (the stream, the
+layer's expert counts or None), where ``m`` is the launch's plan and what
+differs between the phases lives in the mixers' paired methods, chosen by
+``m["t"]`` (the tiles; None in a step); ``COLUMNS``, what ``acc`` holds: a
+``Column`` a column says ONCE what a launch adds to it and the counter it
+feeds in each phase, and the state's width, the launch's row, ``bind_metrics``
+and ``observe_step`` all read that list; and, where its own differ from the
+defaults, ``_embed``, ``_prefill_plan`` / ``_step_plan`` (a ring's places, a
+walk's work list) and ``_counts``.
+
+A subclass sets, in its constructor: ``dtype``, ``d``, ``eps``, ``n_layers``,
 ``vocab_full``, ``v_first``, ``vocab``, ``scales``, where it attends by head
 ``hd`` and ``kv`` (KV heads held), and calls ``_serve_options``. It may set
 ``tied`` (the head is the embedding transposed: no ``head`` is drawn) and
@@ -33,7 +47,7 @@ from __future__ import annotations
 
 import json
 import math
-from typing import Any
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +56,7 @@ import numpy as np
 from tpuserve.config import ModelConfig
 from tpuserve.genserve.model import GenerativeModel, PrefillPiece
 from tpuserve.models import seeded
+from tpuserve.obs import GEN_PHASES
 
 LOGPROBS = 8  # top log-probabilities kept per generated position
 NEG = -1e9
@@ -98,9 +113,54 @@ class _ExpertSteps:
         self.layers.inc(amount / self.held)
 
 
+class Column(NamedTuple):
+    """One column of ``acc``. ``sums(model, stats, counts)``: what one launch
+    adds, from its expert layers' counts (``stats``: a dict a layer that has
+    experts) and its own ``_counts``. ``counter(model, metrics, phase)``: the
+    counter the column feeds in that phase, None where it means nothing
+    there."""
+    sums: Callable
+    counter: Callable
+
+
+def counted(key: str) -> Callable:
+    """A launch's own count ``key`` (``_counts``)."""
+    return lambda model, stats, counts: counts[key]
+
+
+def summed(key: str) -> Callable:
+    """The expert layers' count ``key``, over the layers that ran."""
+    return lambda model, stats, counts: sum(st[key] for st in stats)
+
+
+def series(name: str, labels: str = "") -> Callable:
+    """The counter ``name{model=,phase=<labels>}``."""
+    return lambda model, metrics, ph: metrics.counter(
+        f"{name}{{model={model.name},phase={ph}{labels}}}")
+
+
+# Picks of live tokens on held and on absent experts, held experts hit, held
+# experts x expert layers run (which feeds ``moe_layers_total`` too, in its own
+# unit): ``ops/moe.py``'s ``held_experts`` counts, summed over the layers.
+EXPERT_COLUMNS = (
+    Column(summed("routed_held"), series("moe_tokens_routed_total", ",held=yes")),
+    Column(summed("routed_absent"), series("moe_tokens_routed_total", ",held=no")),
+    Column(summed("experts_hit"), series("moe_experts_hit_total")),
+    Column(lambda model, stats, counts: model.e_count * len(stats),
+           lambda model, metrics, ph: _ExpertSteps(
+               series("moe_expert_steps_total")(model, metrics, ph),
+               series("moe_layers_total")(model, metrics, ph), model.e_count)))
+# Positions attended from, summed over live tokens.
+CONTEXT_COLUMN = Column(counted("context"), series("gen_context_tokens_total"))
+# Expert layers run whose dispatch carried the compact row bound (``ops/moe.py``).
+COMPACT_COLUMN = Column(summed("compact"), series("moe_layers_compact_total"))
+
+
 class PagedLM(GenerativeModel):
     supports_kv_paging = True
     kv_page_leaves = ("kf", "vf")  # K and V by head; a family with another row says so
+    cache_leaves = kv_page_leaves  # every per-layer cache the loop hands a layer: those and more
+    COLUMNS: tuple = ()  # what ``acc`` holds, a ``Column`` each: the family's
     tied = False        # the head is the embedding transposed
     score_scale = None  # attention's scores times this; None: hd ** -0.5
     # Positions a compute block of the decode kernel holds at most, where the
@@ -116,7 +176,7 @@ class PagedLM(GenerativeModel):
 
     def _serve_options(self, cfg: ModelConfig, a: dict) -> None:
         """What is served of the model: the context, the draw's seed and
-        scales, and the counters' state (``acc`` columns: the family's)."""
+        scales, and the counters' state (a row of ``COLUMNS`` a phase)."""
         o = cfg.options
         self.max_prompt = int(o.get("max_prompt_tokens", 64))
         self.max_new = int(o.get("max_new_tokens", 32))
@@ -124,7 +184,7 @@ class PagedLM(GenerativeModel):
         seed = o.get("draw_weights_seed")
         self.draw_seed = None if seed is None else int(seed)
         self._counters: list | None = None
-        self._seen = np.zeros((2, self.ACC), np.uint32)
+        self._seen = np.zeros((len(GEN_PHASES), len(self.COLUMNS)), np.uint32)
 
     # -- params ---------------------------------------------------------------
     def draw_params(self, seed: int) -> Any:
@@ -220,6 +280,15 @@ class PagedLM(GenerativeModel):
         return blk.reshape(kvp, n * P, w // self.hd, self.hd).transpose(0, 2, 1, 3) \
             .reshape(self.kv, n * P, self.hd)
 
+    def kv_page_signature(self, slots: int, pages: int, page_tokens: int) -> Any:
+        return {**self._cache_signature(slots, pages, page_tokens),
+                **self._lane_signature(slots, page_tokens)}
+
+    def _cache_signature(self, slots: int, pages: int, page_tokens: int) -> dict:
+        """{leaf: a shape a layer that keeps one} for ``cache_leaves`` (and
+        whatever further lanes the family keeps)."""
+        raise NotImplementedError
+
     def _lane_signature(self, slots: int, page_tokens: int) -> dict:
         """The per-lane part of the paged state block: a slot's block-table
         row, its position and sampling parameters, the tokens and
@@ -235,9 +304,9 @@ class PagedLM(GenerativeModel):
             "tokens": S((slots, n), i32),
             "lp_ids": S((slots, n, LOGPROBS), i32),
             "lp": S((slots, n, LOGPROBS), jnp.float32),
-            # Cumulative, wrapping; row 0 prefill chunks, row 1 decode steps
-            # (the family's ``bind_metrics`` says what each column sums).
-            "acc": S((2, self.ACC), jnp.uint32),
+            # Cumulative, wrapping; row 0 prefill chunks, row 1 decode steps,
+            # a column of ``COLUMNS`` each.
+            "acc": S((len(GEN_PHASES), len(self.COLUMNS)), jnp.uint32),
         }
 
     def pages_needed(self, item: Any, page_tokens: int) -> int:
@@ -314,6 +383,87 @@ class PagedLM(GenerativeModel):
         tok = jax.vmap(one)(logits, seed, position, temp)
         lp, ids = jax.lax.top_k(jax.nn.log_softmax(logits, axis=-1), LOGPROBS)
         return tok, ids.astype(jnp.int32), lp
+
+    # -- the two programs ---------------------------------------------------------
+    def prefill_chunk(self, params: Any, state: Any, launch: Any, *, chunk: int) -> Any:
+        """One launch of ``pack_prefill``: piece j is tokens [start[j],
+        start[j] + length[j]) of the prompt in slot[j], causal within the
+        piece and over what earlier launches left in that slot's caches. A
+        token sees its own prompt only, at its own positions; a piece that
+        ends its prompt samples the first token at its own last row and arms
+        its own lane."""
+        t = self._tiles(launch, chunk)
+        x = self._embed(params, launch["ids"])
+        m = self._prefill_plan(state, launch, t)
+        x, new = self._layers(params, state, x, m)
+        return self._arm(params, state, new, launch, t, x, m["lanes"])
+
+    def step(self, params: Any, state: Any) -> tuple[Any, dict]:
+        """One token a live lane."""
+        live = state["armed"] & ~state["done"]
+        pos = jnp.clip(state["pos"], 0, self.max_ctx - 1)
+        x = self._embed(params, state["last"])
+        m = self._step_plan(state, live, pos)
+        x, new = self._layers(params, state, x, m)
+        return self._emit(params, state, new, x, live, pos)
+
+    def _layers(self, params, state, x, m: dict):
+        """The stream through every layer and the launch's row into ``acc``
+        (row 0 a prefill launch, row 1 a step) -> (the stream, the state with
+        the caches and ``acc`` as the launch leaves them)."""
+        caches = {leaf: list(state[leaf]) for leaf in self.cache_leaves}
+        stats = []
+        for i in range(self.n_layers):
+            x, st = self._layer(i, params[f"layer{i}"], x, caches, m)
+            if st is not None:
+                stats.append(st)
+        counts = self._counts(m)
+        sums = [col.sums(self, stats, counts) for col in self.COLUMNS]
+        row = jnp.stack([jnp.asarray(v, jnp.int32) for v in sums])
+        acc = state["acc"].at[int(m["t"] is None)].add(row.astype(jnp.uint32))
+        return x, dict(state, **caches, acc=acc)
+
+    def _layer(self, i: int, lp: dict, x, caches: dict, m: dict):
+        """The stream ``x`` through layer ``i`` (its parameters ``lp``) in the
+        phase the plan ``m`` is of; the layer's caches, ``caches[leaf][j]``,
+        are replaced in place -> (the stream, the layer's expert counts or
+        None)."""
+        raise NotImplementedError
+
+    def _embed(self, params, ids):
+        return jnp.take(params["embed"], ids, axis=0)
+
+    def _page_tokens(self, state) -> int:
+        """Positions a page of the first page leaf holds (1 where no layer
+        keeps pages: nothing is written through the address then)."""
+        pools = state[self.kv_page_leaves[0]]
+        return pools[0].shape[-2] if pools else 1
+
+    def _prefill_plan(self, state, launch, t: dict) -> dict:
+        """What a prefill launch works out once, before its layers: the tiles
+        ``t``, the live rows and their positions (under the names a step
+        has them), the pieces, the page and offset every row is written at, and
+        ``lanes``: further lanes the family arms from the launch (a ring's
+        index). A family adds its own."""
+        w_page, off = self._page_of(t, self._page_tokens(state), state["bt"].shape[1])
+        return {"t": t, "live": t["valid"], "pos": t["cpos"], "w_page": w_page, "off": off,
+                "lanes": {}, **{f: launch[f] for f in ("slot", "start", "length")}}
+
+    def _step_plan(self, state, live, pos) -> dict:
+        """What a step works out once, before its layers: the live lanes,
+        their positions and block table, and the page and offset each lane's
+        row is written at (a lane that is not live: the sentinel). ``t`` is
+        None: no tiles, a step."""
+        P = self._page_tokens(state)
+        page_of = jnp.take_along_axis(state["bt"], (pos // P)[:, None], axis=1)[:, 0]
+        return {"t": None, "live": live, "pos": pos, "bt": state["bt"],
+                "w_page": jnp.where(live, page_of, 0), "off": pos % P}
+
+    def _counts(self, m: dict) -> dict:
+        """A launch's own counts, by the names ``COLUMNS`` reads them under
+        (``counted``), after its layers: here the context, positions attended
+        from summed over live tokens."""
+        return {"context": jnp.sum(jnp.where(m["live"], m["pos"] + 1, 0))}
 
     # -- prefill ------------------------------------------------------------------
     # One launch of the static width C carries the waiting pieces of up to K
@@ -558,10 +708,11 @@ class PagedLM(GenerativeModel):
         return self._attend(q[:, None], kc.transpose(1, 2, 0, 3),
                             vc.transpose(1, 2, 0, 3), mask)[:, 0]
 
-    def _emit(self, params, state, new: dict, x, live, pos, acc) -> tuple[Any, dict]:
-        """The end of a decode step: every live lane samples its next token
-        from its last row ``x`` (b, d), keeps it with its log-probabilities,
-        and moves on; the others stay as they were."""
+    def _emit(self, params, state, new: dict, x, live, pos) -> tuple[Any, dict]:
+        """The end of a decode step (``new``: the state with the caches and
+        ``acc`` as the step leaves them): every live lane samples its next
+        token from its last row ``x`` (b, d), keeps it with its
+        log-probabilities, and moves on; the others stay as they were."""
         rows = jnp.arange(pos.shape[0])
         nxt = jnp.clip(pos + 1, 0, self.max_ctx - 1)
         tok, lp_ids, lp_vals = self._sample(self._head(params, x), state["seed"],
@@ -579,9 +730,9 @@ class PagedLM(GenerativeModel):
         done2 = state["done"] | (live & (n_new2 >= state["max_new"]))
         new = dict(new, tokens=tokens, lp_ids=new_lp_ids, lp=new_lp, n_new=n_new2,
                    done=done2, pos=jnp.where(live, nxt, state["pos"]),
-                   last=jnp.where(live, tok, state["last"]), acc=acc)
+                   last=jnp.where(live, tok, state["last"]))
         return new, {"done": done2 | ~state["armed"], "n_new": n_new2,
-                     "first": tokens[:, 0], "last": new["last"], "acc": acc}
+                     "first": tokens[:, 0], "last": new["last"], "acc": new["acc"]}
 
     def extract(self, params: Any, state: Any, slot: Any) -> Any:
         idx = jax.lax.dynamic_index_in_dim
@@ -592,8 +743,8 @@ class PagedLM(GenerativeModel):
     def observe_step(self, step_out: dict) -> None:
         """The device's cumulative counts (prefill chunks and steps since
         the last fetch) into the program's counters (``bind_metrics``: a
-        counter a phase and a column of ``acc``, None where a column means
-        nothing in a phase)."""
+        counter a phase and a column of ``COLUMNS``, None where a column
+        means nothing in a phase)."""
         if self._counters is None:
             return
         now = np.asarray(step_out["acc"], np.uint32)
@@ -607,37 +758,9 @@ class PagedLM(GenerativeModel):
                 if v and c is not None:
                     c.inc(float(v))
 
-    def _expert_sums(self, stats_list: list) -> tuple:
-        """``acc``'s first four columns of one launch, from its expert layers'
-        ``held_experts`` counts: picks of live tokens on held and on absent
-        experts, held experts hit, held experts x expert layers run."""
-        return (sum(st["routed_held"] for st in stats_list),
-                sum(st["routed_absent"] for st in stats_list),
-                sum(st["experts_hit"] for st in stats_list),
-                self.e_count * len(stats_list))
-
-    def _expert_counters(self, metrics: Any, ph: str) -> list:
-        """The counters of ``acc``'s first five columns in phase ``ph``: the
-        expert layers' four and the context read. The fourth column sums held
-        experts x expert layers run, so it feeds ``moe_layers_total`` too,
-        in its own unit."""
-        name = self.name
-        return [metrics.counter(f"moe_tokens_routed_total{{model={name},phase={ph},held=yes}}"),
-                metrics.counter(f"moe_tokens_routed_total{{model={name},phase={ph},held=no}}"),
-                metrics.counter(f"moe_experts_hit_total{{model={name},phase={ph}}}"),
-                _ExpertSteps(metrics.counter(f"moe_expert_steps_total{{model={name},phase={ph}}}"),
-                             metrics.counter(f"moe_layers_total{{model={name},phase={ph}}}"),
-                             self.e_count),
-                self._context_counter(metrics, ph)]
-
-    def _context_counter(self, metrics: Any, ph: str) -> Any:
-        """Positions attended from, summed over live tokens."""
-        return metrics.counter(f"gen_context_tokens_total{{model={self.name},phase={ph}}}")
-
-    def _compact_counter(self, metrics: Any, ph: str) -> Any:
-        """The counter of ``acc``'s last column: expert layers run whose
-        dispatch carried the compact row bound (``ops/moe.py``)."""
-        return metrics.counter(f"moe_layers_compact_total{{model={self.name},phase={ph}}}")
+    def bind_metrics(self, metrics: Any) -> None:
+        self._counters = [[col.counter(self, metrics, ph) for col in self.COLUMNS]
+                          for ph in GEN_PHASES]
 
     def host_decode(self, payload: bytes, content_type: str) -> Any:
         body = json.loads(payload.decode("utf-8"))

@@ -35,7 +35,7 @@ float32 accumulator stay in VMEM scratch from its first block (``block ==
 page's latents are read ONCE and serve both products from fast memory: scores
 ``[q_lat | q_rope] . [c_kv | k_r]`` and context ``p . c_kv``. Products in
 bfloat16 with float32 accumulation, the softmax in float32, ``p.astype(dtype)
-@ c_kv``: ``mla_sc._attend_lanes``' and ``mla._attend_tile``'s. No lane's
+@ c_kv``: ``mla._attend_tile``'s, which is the fallback in XLA. No lane's
 gathered rows, scores or partial context exist in device memory.
 
 THE ROTARY KEY's leaf holds ``g`` positions side by side in a row of 128
