@@ -688,7 +688,7 @@ def test_the_page_signature_holds_one_latent_row_a_token_a_layer_and_no_leaf_by_
 
 
 @pytest.mark.parametrize("key,value,error", [
-    ("rope_scaling", {"type": "yarn", "factor": 40}, NotImplementedError),
+    ("rope_scaling", {"type": "linear", "factor": 40}, NotImplementedError),   # yarn alone is read
     ("q_lora_rank", None, NotImplementedError),
     ("attention_bias", True, NotImplementedError),
     ("hidden_act", "gelu", NotImplementedError),

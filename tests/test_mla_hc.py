@@ -1,0 +1,377 @@
+"""The `mla_hc` family (ISSUE 46) against its plain reference at a small size
+on the CPU: prefill in one and in several launches and decode through the
+paged cache equal the reference's full pass over four residual streams;
+bfloat16 maps, any one map frozen to its bias or the yarn factor on cos and
+sin fail the written tolerance; one stream with unit maps is `mla`'s layer bit
+for bit; the Sinkhorn's sums; yarn against numbers worked by hand; the other
+families' programs lower to the text they lowered to before `mla.py` changed;
+the counter and the engine."""
+
+from __future__ import annotations
+
+import hashlib
+import importlib
+import importlib.util
+import json
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tests import mla_hc_reference as ref
+from tests import test_mla as tm
+from tpuserve.config import ModelConfig
+from tpuserve.models import build, mla, mla_hc
+from tpuserve.ops import hyper
+
+YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1, "mscale_all_dim": 1,
+        "original_max_position_embeddings": 4096, "type": "yarn"}
+ARCH = {**{k: v for k, v in tm.ARCH.items() if k != "rope_interleave"}, "rope_theta": 10000,
+        "rope_scaling": YARN, "hc_mult": 4, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
+        "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30}
+SEED, PAGE, CHUNK, SLOTS = tm.SEED, tm.PAGE, tm.CHUNK, tm.SLOTS
+PROMPTS, MAX_NEWS, PACKED = tm.PROMPTS, tm.MAX_NEWS, tm.PACKED
+# float32 against float32: sums in another order (launches, key blocks, the absorbed
+# form, experts grouped by a sort, the maps with the tokens last); read 2.4e-6
+TOL = 5e-5
+
+
+def make_model(tmp_path, arch=ARCH, name="hc", dtype="float32", family="mla_hc", **options):
+    path = os.path.join(tmp_path, f"{name}.json")
+    with open(path, "w", encoding="utf-8") as f:
+        json.dump(arch, f)
+    cfg = ModelConfig(name=name, family=family, dtype=dtype, batch_buckets=[1],
+                      options={"config_file": path, "draw_weights_seed": SEED,
+                               "max_prompt_tokens": tm.MAX_PROMPT, "max_new_tokens": tm.MAX_NEW,
+                               **options})
+    model = build(cfg)
+    model.TILE_ROWS = PAGE   # a toy launch of 8 rows in tiles of a page, steered in the test
+    return model
+
+
+@pytest.fixture(scope="module")
+def whole(tmp_path_factory):
+    model = make_model(tmp_path_factory.mktemp("mla_hc"))
+    return model, model.init_params(jax.random.key(0))
+
+
+def gaps(prompts, served, arch=ARCH):
+    """Per request: served minus reference log-probabilities at the ids the
+    server named, teacher-forced on the served tokens."""
+    m = ref.Model(arch, SEED, "float32")
+    seqs = [np.concatenate([p, s["tokens"][: s["n_new"] - 1]]) for p, s in zip(prompts, served)]
+    out = []
+    for s, lp in zip(served, ref.log_probs(m, seqs, [len(p) - 1 for p in prompts])):
+        n = int(s["n_new"])
+        out.append(s["lp"][:n] - np.take_along_axis(lp, s["lp_ids"][:n], axis=-1))
+    return out
+
+
+def worst(prompts, served, arch=ARCH) -> float:
+    return max(float(np.abs(g).max()) for g in gaps(prompts, served, arch))
+
+
+# -- (a) the served function is the reference's full pass ------------------------------------
+
+@pytest.mark.parametrize("case", ["packed-over-five-launches", "a-prompt-a-launch"])
+def test_chunked_prefill_then_decode_is_the_reference_full_pass(whole, case):
+    """Logits, not tokens: the pieces of three prompts packed into five launches
+    (a prompt over four of them, a later launch attending to latents an earlier
+    one cached, padded tails), or each prompt a launch at a time; then decode
+    through the pages."""
+    model, params = whole
+    served, out, _ = tm.serve(model, params, PROMPTS, MAX_NEWS,
+                              launches=PACKED if case.startswith("packed") else None)
+    assert bool(np.all(np.asarray(out["done"])))
+    assert [int(s["n_new"]) for s in served] == MAX_NEWS
+    assert worst(PROMPTS, served) < TOL
+    # the device's sums: two sublayers a layer took their maps, for every live token
+    acc = np.asarray(out["acc"]).astype(np.int64)
+    tokens = sum(len(p) for p in PROMPTS)
+    assert acc[0, 12] == 2 * ARCH["num_hidden_layers"] * tokens
+    assert acc[1, 12] == 2 * ARCH["num_hidden_layers"] * (sum(MAX_NEWS) - len(MAX_NEWS))
+
+
+def frozen(monkeypatch, which: str):
+    """One of p, q, r held at zero: its map is its bias alone."""
+    n = ARCH["hc_mult"]
+    cols = {"p": slice(0, n), "q": slice(n, 2 * n), "r": slice(2 * n, None)}[which]
+    real = hyper.maps
+    monkeypatch.setattr(hyper, "maps", lambda x, hp, *a: real(
+        x, dict(hp, phi=hp["phi"].at[:, cols].set(0)), *a))
+
+
+def bfloat16_maps(monkeypatch):
+    """The maps at bfloat16's precision: the stream as they read it, Phi and
+    the three maps themselves rounded to it."""
+    real = hyper.maps
+    bf = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    monkeypatch.setattr(hyper, "maps", lambda x, hp, *a: tuple(
+        bf(h) for h in real(bf(x), dict(hp, phi=bf(hp["phi"])), *a)))
+
+
+@pytest.mark.parametrize("fault", ["bfloat16-maps", "p-frozen", "q-frozen", "r-frozen",
+                                   "yarn-on-cos-and-sin"])
+def test_each_fault_fails_the_tolerance_tenfold(tmp_path, monkeypatch, fault):
+    """What the tolerance is for: maps in the served type's precision where the
+    issue says float32; any one of the three maps frozen to its bias (each
+    DEPENDS ON ITS INPUT at the drawn scales); transformers' default yarn, the
+    magnitude 0.1 ln(64) + 1 on cos and sin (which squares onto the rotary
+    part of a score only), where DeepSeek's convention puts it on the score."""
+    model = make_model(tmp_path, name="faulty")
+    if fault == "bfloat16-maps":
+        bfloat16_maps(monkeypatch)
+    elif fault.endswith("frozen"):
+        frozen(monkeypatch, fault[0])
+    else:
+        inv, _one, dim = model.rope
+        model.rope = (inv, 0.1 * math.log(64) + 1.0, dim)
+        model.score_scale = (model.dn + model.dr) ** -0.5
+    served, _, _ = tm.serve(model, model.init_params(jax.random.key(0)), PROMPTS, MAX_NEWS,
+                            launches=PACKED)
+    assert worst(PROMPTS, served) > 10 * TOL
+
+
+# -- (b) one stream with unit maps is mla's layer ---------------------------------------------
+
+def test_one_stream_with_unit_maps_is_mlas_layer_bit_for_bit(tmp_path, monkeypatch):
+    """`n = 1`, `H_pre = H_post = H_res = 1`: entry by copy is the embedding,
+    the mixes are `1 x` and `x + y`, the exit's sum has one term."""
+    one = dict(ARCH, hc_mult=1)
+    plain = make_model(tmp_path, one, name="plain", family="mla")
+    hc = make_model(tmp_path, one, name="one")
+    assert isinstance(hc, mla.LatentServing) and type(plain) is mla.LatentServing
+    monkeypatch.setattr(hyper, "maps", lambda x, hp, n, *a: (
+        jnp.ones((1, x.shape[0])), jnp.ones((1, x.shape[0])), jnp.ones((1, 1, x.shape[0]))))
+    want, _, _ = tm.serve(plain, plain.init_params(jax.random.key(0)), PROMPTS, MAX_NEWS,
+                          launches=PACKED)
+    got, _, _ = tm.serve(hc, hc.init_params(jax.random.key(0)), PROMPTS, MAX_NEWS,
+                         launches=PACKED)
+    for a, b in zip(got, want):
+        assert np.array_equal(a["tokens"], b["tokens"]) and np.array_equal(a["lp"], b["lp"])
+
+
+# -- (c) the residual map is doubly stochastic --------------------------------------------------
+
+def sums_off(h_res) -> float:
+    """How far the rows' and the columns' sums lie from 1, at worst."""
+    h = np.asarray(h_res, np.float64)
+    return max(float(np.abs(h.sum(axis=0) - 1).max()), float(np.abs(h.sum(axis=1) - 1).max()))
+
+
+def test_after_twenty_iterations_h_res_sums_to_one_by_row_and_by_column(whole):
+    """At the drawn scales (every sublayer of the toy, 4,096 streams of unit
+    deviation each: `p`, `q` and `r` have the deviation they have at the
+    published widths, `Phi`'s scale being over sqrt(n d)), and under the
+    clamp's extremes: logits of +-1000 are held to +-30, and every entry high,
+    every entry low (`hc_eps` then outweighs the first sums), the diagonal
+    high and a permutation high all come out doubly stochastic. (A pattern of
+    high entries WITHOUT total support, one that holds no permutation through
+    each of them, has no doubly stochastic scaling and the iteration crawls:
+    the drawn scales, a bell about 1.25 on the diagonal, never reach one.)"""
+    model, params = whole
+    x = jnp.asarray(np.random.default_rng(4).standard_normal((4096, 4 * 64)), jnp.float32)
+    args = (4, model.eps, model.hc_iters, model.hc_eps, model.hc_clamp)
+    for i in range(model.n_layers):
+        for k in mla_hc.SUBLAYERS:
+            h_pre, h_post, h_res = hyper.maps(x, params[f"layer{i}"][k], *args)
+            assert h_res.shape == (4, 4, 4096) and sums_off(h_res) < 1e-4
+            assert 0 < float(h_pre.min()) and float(h_pre.max()) < 1
+            assert 0 < float(h_post.min()) and float(h_post.max()) < 2
+            diag = np.stack([np.asarray(h_res[j, j]) for j in range(4)])
+            assert 0.4 < diag.mean() < 0.65 and diag.std() > 0.04   # keeps itself, mixes, by token
+    eye, perm = np.eye(4), np.eye(4)[[2, 0, 3, 1]]
+    for pattern in (np.ones((4, 4)), -np.ones((4, 4)), 2 * eye - 1, 2 * perm - 1):
+        logits = jnp.clip(jnp.asarray(1000.0 * pattern, jnp.float32)[:, :, None], *model.hc_clamp)
+        assert float(jnp.abs(logits).max()) == 30.0
+        assert sums_off(hyper.sinkhorn(jnp.exp(logits), 20, 1e-6)) < 1e-4
+    both = hyper.sinkhorn(jnp.exp(jnp.asarray(60.0 * eye - 30.0)[:, :, None]), 20, 1e-6)
+    np.testing.assert_allclose(np.asarray(both[:, :, 0]), eye, atol=1e-6)
+
+
+# -- (d) yarn, DeepSeek's convention -----------------------------------------------------------------
+
+def test_yarn_puts_the_magnitude_on_the_score_and_leaves_cos_and_sin(tmp_path):
+    """By hand, at the published numbers (64 rotary columns, base 10000, factor
+    64, 32 and 1 rotations over 4,096 positions): the correction range is pairs
+    10 to 23 (64 ln(4096 / (32 x 2 pi)) / (2 ln 10000) = 10.47, 64 ln(4096 / (2
+    pi)) / (2 ln 10000) = 22.52); pair 5 keeps 10000^(-10/64) = 0.237137, pair 30
+    is 10000^(-60/64) / 64 = 2.77856e-6, pair 16 lies 6/13 up the ramp: 0.01 x
+    (7/13 + 6/13/64) = 0.00545673. m = 0.1 ln 64 + 1 = 1.415888: cos and sin
+    times m / m = 1, every score times m^2 = 2.004740 over sqrt(nope + rope)."""
+    model = make_model(tmp_path, name="yarn")
+    inv, on_cos_sin, dim = model.rope
+    assert dim == 64 and on_cos_sin == 1.0
+    np.testing.assert_allclose(inv[[5, 30, 16]], [0.237137, 2.77856e-6, 0.00545673], rtol=2e-6)
+    np.testing.assert_allclose(inv[:11], 10000.0 ** (-np.arange(11) / 32.0), rtol=1e-6)
+    np.testing.assert_allclose(inv[23:], 10000.0 ** (-np.arange(23, 32) / 32.0) / 64, rtol=1e-6)
+    assert mla.yarn_magnitudes(YARN) == pytest.approx((1.0, 2.004740), rel=1e-6)
+    assert model.score_scale == pytest.approx(2.004740 / math.sqrt(16 + 64), rel=1e-6)
+    m = ref.Model(ARCH, SEED, "float32")   # the reference writes the same numbers down again
+    np.testing.assert_allclose(m.inv_freq, inv, rtol=1e-6)
+    assert (m.on_cos_sin, m.score_scale) == pytest.approx((1.0, model.score_scale), rel=1e-6)
+    # a rotation keeps a key's length: nothing rides on cos and sin
+    lp = model.init_params(jax.random.key(0))["layer0"]
+    u = jnp.asarray(np.random.default_rng(5).standard_normal((6, 64)), jnp.float32)
+    k_r = model._project(lp, u, jnp.arange(100, 106))[3]
+    np.testing.assert_allclose(np.linalg.norm(k_r, axis=-1),
+                               np.linalg.norm((u @ lp["w_kva"])[:, 32:], axis=-1), rtol=1e-5)
+    # ONE score, nope and rope part alike: two keys, the weights a softmax of 2.0048 / sqrt(80) x
+    # (q_nope . k_nope + q_rope . k_r)
+    qn, qr, c_kv, k_r = model._project(lp, u[:2], jnp.arange(2))
+    pool = (model._write_pages(jnp.zeros((3, PAGE, 32), jnp.float32), jnp.ones(2, jnp.int32),
+                               jnp.arange(2), c_kv),
+            model._write_keys(jnp.zeros((3, PAGE // 2, 128), jnp.float32), jnp.ones(2, jnp.int32),
+                              jnp.arange(2), k_r, runs=True))
+    got = model._attend_tile(lp, qn[1:], qr[1:], pool, jnp.ones(1, jnp.int32), jnp.ones(1, jnp.int32),
+                             jnp.int32(1), "absorbed")
+    k = jnp.einsum("cr,rhn->chn", c_kv, lp["w_kb"])
+    s = (jnp.einsum("hn,chn->hc", qn[1], k) + jnp.einsum("hr,cr->hc", qr[1], k_r)) \
+        * 2.004740 / math.sqrt(80)
+    want = jnp.einsum("hc,chv->hv", jax.nn.softmax(s, axis=-1),
+                      jnp.einsum("cr,rhv->chv", c_kv, lp["w_vb"]))
+    np.testing.assert_allclose(got[0], want, atol=2e-5)
+
+
+@pytest.mark.parametrize("family", ["mla", "mla_hc"])
+def test_a_rope_scaling_that_is_not_yarn_is_refused(tmp_path, family):
+    with pytest.raises(NotImplementedError, match="rope_scaling"):
+        make_model(tmp_path, dict(ARCH, rope_scaling={"type": "linear", "factor": 4}),
+                   name="linear", family=family)
+
+
+# -- (e) the other families' programs are the parent's ------------------------------------------
+
+def lowered_sha256(family: str, tmp: str) -> dict:
+    """{program: sha256 of its lowered text} of a family's toy model, as
+    `scripts/lower_programs.py --toys` writes them."""
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "scripts", "lower_programs.py")
+    spec = importlib.util.spec_from_file_location("lower_programs_for_test", path)
+    lp = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(lp)
+    t = importlib.import_module(f"tests.test_{family}")
+    lowered = lp.lower(t.make_model(tmp), t.SLOTS, 0, t.PAGE, t.CHUNK)
+    return {program: hashlib.sha256(low.as_text().encode()).hexdigest()
+            for program, low in lowered.items()}
+
+
+@pytest.mark.parametrize("family", ["decoder", "hybrid", "hybrid_ffn", "mla", "mla_sc"])
+def test_the_other_families_programs_lower_to_the_parents_text(family, tmp_path):
+    """`mla.py` changed under JoyAI's and LongCat's cells (the softmax scale in
+    one place, `rope_scaling` read) and `paged_lm`'s loop serves a sixth family:
+    the five older families' two programs lower, at their toy sizes on the CPU,
+    to the text they lowered to at the parent commit (PR 45, fb9a7ba), whose
+    hashes `tests/fixtures/lowered_toys_pr45.json` holds (written by this
+    function on a `git archive` of that commit). A PR that MEANS to change a
+    program, or another jax, writes the file anew; the cells' own programs are
+    compared for a described v5e by `scripts/lower_programs.py --v5e --cells`."""
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures",
+                           "lowered_toys_pr45.json"), encoding="utf-8") as f:
+        want = json.load(f)
+    if want["jax"] != jax.__version__:
+        pytest.skip(f"the fixture was written under jax {want['jax']}")
+    assert lowered_sha256(family, str(tmp_path)) == want["sha256"][family]
+
+
+# -- (f) the references, and through the engine ---------------------------------------------------
+
+def test_the_repo_and_the_benchmark_copies_of_the_reference_agree():
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmark", "reference", "mla_hc.py")
+    spec = importlib.util.spec_from_file_location("benchmark_reference_mla_hc_for_test", path)
+    theirs = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(theirs)
+    assert (theirs.POST_BIAS, theirs.RES_DIAGONAL, theirs.RES_ALPHA, theirs.DEFAULT_SCALES) == \
+        (ref.POST_BIAS, ref.RES_DIAGONAL, ref.RES_ALPHA, ref.DEFAULT_SCALES) == \
+        (mla_hc.POST_BIAS, mla_hc.RES_DIAGONAL, mla_hc.RES_ALPHA, mla_hc.DEFAULT_SCALES)
+    seqs = [np.random.default_rng(6).integers(0, 96, n) for n in (17, 5)]
+    a = ref.log_probs(ref.Model(ARCH, SEED, "bfloat16"), seqs, [9, 0])
+    b = theirs.log_probs(theirs.Model(ARCH, SEED, "bfloat16"), seqs, [9, 0])
+    for x, y in zip(a, b):
+        assert np.array_equal(x, y)
+    low = theirs.log_probs(theirs.Model(ARCH, SEED, "bfloat16"), seqs, [9, 0], True)
+    assert float(np.abs(low[0] - a[0]).max()) > 1e-3  # the control's rounding is seen
+
+
+def test_the_references_pass_in_two_calls_is_its_pass_in_one():
+    """The check's pass is made in two calls (the prompts while the server
+    starts, the served tokens after, continued from the cached rows; the
+    streams keep nothing between tokens): the same hidden states as one pass
+    over the whole sequences, the control too."""
+    m = ref.Model(ARCH, SEED, "bfloat16")
+    seqs = [np.random.default_rng(7).integers(0, 96, n) for n in (17, 9)]
+    cut = (11, 8)
+    for low in (False, True):
+        one = ref.hidden_states(m, seqs, low)
+        layers, last, carry = ref.prompt_pass(m, [s[:c] for s, c in zip(seqs, cut)], low)
+        tails, _ = ref.forward(m, layers, [s[c:] for s, c in zip(seqs, cut)], carry, low)
+        for h, h0, tail, c in zip(one, last, tails, cut):
+            # the exit's sum of four streams: values of tens, float32 sums in another order
+            np.testing.assert_allclose(h0, h[c - 1:c], rtol=2e-5, atol=1e-4)
+            np.testing.assert_allclose(tail, h[c:], rtol=2e-5, atol=1e-4)
+
+
+def test_the_draw_is_the_references_and_the_maps_are_float32(whole):
+    model, params = whole
+    m = ref.Model(ARCH, SEED, "float32")
+    for k in mla_hc.SUBLAYERS:
+        mine, theirs = params["layer1"][k], m.maps(1, k)
+        assert set(mine) == set(theirs) == {"phi", "alpha", "b_pre", "b_post", "b_res"}
+        for name in mine:
+            np.testing.assert_array_equal(np.asarray(mine[name]), np.asarray(theirs[name]))
+        assert mine["phi"].shape == (4 * 64, 2 * 4 + 16)
+        assert all(mine[v].dtype == jnp.float32 for v in ("alpha", "b_pre", "b_post", "b_res"))
+    bf = make_model(os.path.dirname(model.cfg.options["config_file"]), name="bf", dtype="bfloat16")
+    drawn = jax.eval_shape(lambda: bf.draw_params(0))["layer0"]["hc1"]
+    assert drawn["phi"].dtype == jnp.bfloat16 and drawn["alpha"].dtype == jnp.float32
+    x = jnp.ones((3, 4 * 64), jnp.bfloat16)
+    maps = jax.eval_shape(lambda hp: hyper.maps(x, hp, 4, 1e-6, 20, 1e-6, (-30.0, 30.0)), drawn)
+    assert [h.dtype for h in maps] == [jnp.float32] * 3       # whatever the served type
+    assert jax.eval_shape(hyper.mix_in, x, maps[0]).dtype == jnp.bfloat16
+
+
+def test_through_the_engine_requests_move_hc_maps_total_by_what_was_served(tmp_path):
+    import asyncio
+
+    from tpuserve.config import GenserveConfig
+    from tpuserve.genserve import GenEngine
+    from tpuserve.obs import Metrics
+    from tpuserve.runtime import build_runtime
+
+    model = make_model(tmp_path, name="eng")
+    rt = build_runtime(model, compile_forward=False)
+    metrics = Metrics()
+    eng = GenEngine(model, rt, metrics, GenserveConfig(
+        slots=SLOTS, kv_paging=True, kv_page_tokens=PAGE, prefill_chunk=CHUNK))
+    eng.compile()
+    model.bind_metrics(metrics)
+    max_news = [6, 9]
+
+    async def go():
+        await eng.start()
+        futs = [eng.submit(model.host_decode(json.dumps(
+            {"prompt_ids": p.tolist(), "max_new_tokens": m, "logprobs": 8}).encode(),
+            "application/json")) for p, m in zip(PROMPTS[:2], max_news)]
+        out = await asyncio.gather(*futs)
+        await eng.stop()
+        return out
+
+    loop = asyncio.new_event_loop()
+    try:
+        results = loop.run_until_complete(go())
+    finally:
+        loop.close()
+    by_hand, _, _ = tm.serve(model, rt.params_per_mesh[0], PROMPTS[:2], max_news, slots=SLOTS)
+    for got, want, n in zip(results, by_hand, max_news):
+        assert got["tokens"] == want["tokens"][:n].tolist() and got["n_tokens"] == n
+        np.testing.assert_allclose(got["logprobs"]["values"], want["lp"][:n], atol=1e-4)
+    c = metrics.counter_values()
+    assert c["hc_maps_total{model=eng,phase=prefill}"] == 6 * (19 + 5)
+    assert c["hc_maps_total{model=eng,phase=decode}"] == 6 * ((6 - 1) + (9 - 1))
+    assert c["mla_launches_total{model=eng,phase=decode,form=absorbed}"] \
+        == c["gen_iterations_total{model=eng}"]
+    assert eng.pipeline_stats()["kv"]["row_bytes_per_token"] == 3 * (32 + 64) * 4   # mla's rows

@@ -35,6 +35,11 @@ Families (BASELINE.json ``configs``):
                    shortcut whose router also picks zero-compute (identity)
                    outputs; a share of the experts and the vocabulary
                    (ISSUE 42)
+- mla_hc         — ``mla``'s layer under a HYPER-CONNECTED residual of several
+                   streams: each sublayer reads a mix of the streams and writes
+                   into all of them through its own pre, post and
+                   Sinkhorn-projected residual maps; yarn on the latent
+                   attention in DeepSeek's convention (ISSUE 46)
 - toy            — a linear classifier for tests and drills
 """
 
@@ -58,6 +63,7 @@ _REGISTRY: dict[str, str] = {
     "hybrid_ffn": "tpuserve.models.hybrid_ffn",
     "mla": "tpuserve.models.mla",
     "mla_sc": "tpuserve.models.mla_sc",
+    "mla_hc": "tpuserve.models.mla_hc",
     "toy": "tpuserve.models.toy",
 }
 

@@ -16,8 +16,11 @@ RoPE(q_rope, t)``; ``[c_kv | k_r] = u W_kva`` (``kv_lora_rank`` | rope);
 every head; ``[k_nope_h | v_h] = c_kv W_kvb``. ``score_h(t, s) = (q_nope_h(t) .
 k_nope_h(s) + q_rope_h(t) . k_r(s)) / sqrt(qk_nope + qk_rope)``, causal
 softmax in float32, ``o_h = sum_s p v_h(s)``, out ``= concat_h(o_h) W_o``.
-Rotary: plain (``rope_scaling`` null), ``rope_theta`` over the rotary columns;
-with ``rope_interleave`` the columns (2i, 2i + 1) turn as a pair.
+Rotary: ``rope_theta`` over the rotary columns, plain where ``rope_scaling`` is
+null; with ``rope_interleave`` the columns (2i, 2i + 1) turn as a pair. A yarn
+``rope_scaling`` is read in DeepSeek's convention (``_read_attention``): yarn's
+frequencies, cos and sin times ``m(mscale) / m(mscale_all_dim)`` and EVERY
+score times ``m(mscale_all_dim)^2``, with ``m(a) = 0.1 a ln(factor) + 1``.
 
 THE CACHE: the normed ``c_kv`` and the rotated ``k_r`` of a token, one row of
 ``kv_lora_rank + qk_rope_head_dim`` values a layer, in pages of the engine's
@@ -83,6 +86,7 @@ Requests, weights by recipe and the served log-probabilities are
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -110,6 +114,22 @@ DEFAULT_SCALES = {
 }
 FORMS = ("absorbed", "expanded")
 WALKS = ("kernel", "xla")
+
+
+def yarn_magnitudes(rs: dict) -> tuple[float, float]:
+    """A yarn ``rope_scaling`` in DeepSeek's convention (the family whose key
+    names these are) -> (the factor on cos and sin, the factor on every
+    score): with ``m(a) = 0.1 a ln(factor) + 1``, ``m(mscale) /
+    m(mscale_all_dim)`` and ``m(mscale_all_dim)^2``. THE MAGNITUDE GOES ON
+    THE SCORE: ``rope_inv_freq``'s own default, ``0.1 ln(factor) + 1`` on cos
+    and sin, would square onto the rotary part of a score only."""
+    factor = float(rs["factor"])
+
+    def m(a: float) -> float:
+        return 0.1 * a * math.log(factor) + 1.0 if factor > 1 and a else 1.0
+
+    all_dim = float(rs.get("mscale_all_dim", 0) or 0)
+    return m(float(rs.get("mscale", 1))) / m(all_dim), m(all_dim) ** 2
 
 
 class LatentServing(PagedLM):
@@ -142,8 +162,7 @@ class LatentServing(PagedLM):
         super().__init__(cfg)
         a = read_config_file(cfg)
         self.dtype = jnp.dtype(cfg.dtype)
-        for key, want in (("attention_bias", False), ("rope_scaling", None),
-                          ("n_group", 1), ("topk_group", 1),
+        for key, want in (("attention_bias", False), ("n_group", 1), ("topk_group", 1),
                           ("scoring_func", "sigmoid"), ("topk_method", "noaux_tc"),
                           ("hidden_act", "silu"), ("moe_layer_freq", 1), ("share", None)):
             if a.get(key, want) != want:
@@ -178,8 +197,20 @@ class LatentServing(PagedLM):
         self.q_rank, self.r = int(a["q_lora_rank"]), int(a["kv_lora_rank"])
         self.dn, self.dr, self.dv = (int(a[k]) for k in (
             "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"))
-        self.rope = rope_inv_freq({"rope_theta": float(a.get("rope_theta", 10000.0))}, self.dr)
         self.rope_interleave = bool(a.get("rope_interleave", False))
+        # What every score is multiplied by, the nope and the rope part alike:
+        # both walks in XLA and both kernels' ``scale=`` take it from here.
+        self.score_scale = (self.dn + self.dr) ** -0.5
+        rp, rs = {"rope_theta": float(a.get("rope_theta", 10000.0))}, a.get("rope_scaling")
+        if rs is not None:
+            if rs.get("type", rs.get("rope_type")) != "yarn":
+                raise NotImplementedError(f"{self.name}: rope_scaling = {rs!r}")
+            on_cos_sin, on_score = yarn_magnitudes(rs)
+            rp.update({k: rs[k] for k in ("factor", "original_max_position_embeddings",
+                                          "beta_fast", "beta_slow") if k in rs},
+                      rope_type="yarn", attention_factor=on_cos_sin)
+            self.score_scale *= on_score
+        self.rope = rope_inv_freq(rp, self.dr)
 
     # -- params ---------------------------------------------------------------
     # Factors on the two latents after their norms (a config that scales them
@@ -359,7 +390,7 @@ class LatentServing(PagedLM):
         kb, rowp = self._key_blocks(row, P)
         need, c = self._blocks_needed(last, P, row.shape[0]), kb * P
         f32 = {"preferred_element_type": jnp.float32}
-        scale = (self.dn + self.dr) ** -0.5
+        scale = self.score_scale
         if form == "absorbed":
             qn = jnp.einsum("thn,rhn->thr", qn, lp["w_kb"], **f32).astype(dt)   # q_lat
 
@@ -435,7 +466,7 @@ class LatentServing(PagedLM):
         f32 = {"preferred_element_type": jnp.float32}
         q_lat = jnp.einsum("bhn,rhn->bhr", qn, lp["w_kb"], **f32).astype(self.dtype)
         o = la.lane_walk(q_lat, jnp.concatenate([qr] * (ckv.shape[1] // kr.shape[1]), axis=-1),
-                         ckv, kr, work, scale=(self.dn + self.dr) ** -0.5)
+                         ckv, kr, work, scale=self.score_scale)
         return jnp.einsum("bhr,rhv->bhv", o, lp["w_vb"], **f32)
 
     def _attn_out(self, lp, o):
