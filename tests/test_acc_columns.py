@@ -38,7 +38,8 @@ SERIES = {
     "hybrid_ffn": CONTEXT + SSM,
     "mla": MLA,
     "mla_sc": MLA + ["moe_routed_zero_total{model=M,phase=PH}"],
-    "mla_hc": MLA + ["hc_maps_total{model=M,phase=PH}"],
+    "mla_hc": MLA + ["hc_maps_total{model=M,phase=PH,path=kernel}",
+                     "hc_maps_total{model=M,phase=PH,path=xla}"],
 }
 # The fourth expert column sums held experts x expert layers run, so it feeds
 # the layers' counter too, over the experts held.

@@ -540,3 +540,65 @@ def test_decode_over_packed_pages_compiles_for_the_v5e_at_the_cells_widths(one_c
         jax.config.update("jax_enable_compilation_cache", True)
     assert "tpu_custom_call" in text
     assert not [ln for ln in text.split("\n") if " copy(" in ln and "1280,128,128" in ln]
+
+
+def test_a_sublayers_hyper_connection_is_two_kernel_calls_on_the_v5e_at_the_cells_widths(
+        one_chip, tmp_path, monkeypatch):
+    """`ops/hyper.py` `enter` and `leave` under the `mla_hc` family's sublayer
+    (ISSUE 47), at the cell's sizes: a launch of 4,096 rows of four streams of
+    3,584 values, `Phi` 14,336 x 24 in bfloat16. The TPU branch is steered by
+    the backend's name here, in the test. Mosaic takes both kernels (the
+    product with the stream as the transposed operand, the transposition of a
+    tile's maps, sublane sums in the Sinkhorn, dynamic lane offsets in the
+    mixes); the sublayer is TWO custom calls, no float32 value of the stream's
+    size and no copy of the stream is in the program; a step's 64 rows keep
+    XLA and have none."""
+    import json
+
+    from tpuserve.config import ModelConfig
+    from tpuserve.models import build
+
+    n, d, rows = 4, 3584, 4096
+    arch = {"vocab_size": 256, "hidden_size": d, "num_attention_heads": 2, "q_lora_rank": 128,
+            "kv_lora_rank": 512, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+            "v_head_dim": 128, "num_hidden_layers": 1, "intermediate_size": 256,
+            "first_k_dense_replace": 1, "hc_mult": n}
+    path = tmp_path / "arch.json"
+    path.write_text(json.dumps(arch))
+    model = build(ModelConfig(name="hc", family="mla_hc", dtype="bfloat16", batch_buckets=[1],
+                              options={"config_file": str(path), "max_prompt_tokens": 1024,
+                                       "max_new_tokens": 128}))
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    hp = {"phi": shape(n * d, 2 * n + n * n), "alpha": shape(3, dtype=f32),
+          "b_pre": shape(n, dtype=f32), "b_post": shape(n, dtype=f32),
+          "b_res": shape(n, n, dtype=f32)}
+
+    paths = []
+
+    def sublayer(hp, x, w):
+        plan = {}
+        out, _ = model._sublayer(hp, x, plan, lambda u: (jnp.dot(
+            u, w, preferred_element_type=f32), None))
+        paths.append(plan["hc_path"])
+        return out
+
+    def compiled(rows):
+        lowered = jax.jit(sublayer).lower(hp, shape(rows, n * d), shape(d, d))
+        return lowered.compile().as_text().split("\n")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
+    try:
+        launch, step = compiled(rows), compiled(64)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert paths == ["kernel", "xla"]
+    calls = [ln for ln in launch if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 2 and "hc_enter" in calls[0] and "hc_leave" in calls[1]
+    assert not [ln for ln in launch if f"f32[{rows},{n * d}]" in ln]
+    assert not [ln for ln in launch if " copy(" in ln and f"[{rows},{n * d}]" in ln]
+    assert not [ln for ln in step if "tpu_custom_call" in ln]

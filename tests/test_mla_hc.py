@@ -89,10 +89,43 @@ def test_chunked_prefill_then_decode_is_the_reference_full_pass(whole, case):
     assert [int(s["n_new"]) for s in served] == MAX_NEWS
     assert worst(PROMPTS, served) < TOL
     # the device's sums: two sublayers a layer took their maps, for every live token
+    # (columns 12 and 13: in the kernels and in XLA, which is where the CPU mixes)
     acc = np.asarray(out["acc"]).astype(np.int64)
     tokens = sum(len(p) for p in PROMPTS)
-    assert acc[0, 12] == 2 * ARCH["num_hidden_layers"] * tokens
-    assert acc[1, 12] == 2 * ARCH["num_hidden_layers"] * (sum(MAX_NEWS) - len(MAX_NEWS))
+    assert acc[0, 13] == 2 * ARCH["num_hidden_layers"] * tokens
+    assert acc[1, 13] == 2 * ARCH["num_hidden_layers"] * (sum(MAX_NEWS) - len(MAX_NEWS))
+    assert not acc[:, 12].any()
+
+
+def test_a_launch_through_the_kernels_then_decode_is_the_reference_full_pass(
+        tmp_path, monkeypatch):
+    """ISSUE 47: on the TPU a launch's maps and mixes are two kernel calls a
+    sublayer. Steered here as the walk's kernels are (`tests/test_mla.py`), in
+    the test and not by an option: a toy of one lane tile a stream, launches of
+    16 rows (a prompt's tail and a short prompt pad theirs with dead rows), both
+    kernels in the interpreter at a row tile of the launch; the steps' three
+    lanes keep XLA. Logits against the float32 reference at the file's
+    tolerance, and the device's sums say which path mixed what."""
+    import functools
+
+    arch = dict(ARCH, hidden_size=128)
+    model = make_model(tmp_path, arch, name="kern")
+    calls = []
+    monkeypatch.setattr(mla_hc.HyperLatentServing, "_hc_path",
+                        lambda self, x: "kernel" if x.shape[0] == 16 else "xla")
+    for name in ("enter", "leave"):
+        monkeypatch.setattr(hyper, name, functools.partial(
+            lambda *a, f=getattr(hyper, name), **k: calls.append(f.__name__) or f(
+                *a, tile=16, interpret=True, **k)))
+    served, out, _ = tm.serve(model, model.init_params(jax.random.key(0)), PROMPTS, MAX_NEWS,
+                              chunk=16)
+    layers = arch["num_hidden_layers"]
+    assert calls == ["enter", "leave"] * 2 * layers   # traced once: two calls a sublayer
+    assert [int(s["n_new"]) for s in served] == MAX_NEWS
+    assert worst(PROMPTS, served, arch) < TOL
+    acc = np.asarray(out["acc"]).astype(np.int64)
+    assert acc[0, 12] == 2 * layers * sum(len(p) for p in PROMPTS) and acc[0, 13] == 0
+    assert acc[1, 13] == 2 * layers * (sum(MAX_NEWS) - len(MAX_NEWS)) and acc[1, 12] == 0
 
 
 def frozen(monkeypatch, which: str):
@@ -370,8 +403,9 @@ def test_through_the_engine_requests_move_hc_maps_total_by_what_was_served(tmp_p
         assert got["tokens"] == want["tokens"][:n].tolist() and got["n_tokens"] == n
         np.testing.assert_allclose(got["logprobs"]["values"], want["lp"][:n], atol=1e-4)
     c = metrics.counter_values()
-    assert c["hc_maps_total{model=eng,phase=prefill}"] == 6 * (19 + 5)
-    assert c["hc_maps_total{model=eng,phase=decode}"] == 6 * ((6 - 1) + (9 - 1))
+    assert c["hc_maps_total{model=eng,phase=prefill,path=xla}"] == 6 * (19 + 5)
+    assert c["hc_maps_total{model=eng,phase=decode,path=xla}"] == 6 * ((6 - 1) + (9 - 1))
+    assert not any(v for k, v in c.items() if k.startswith("hc_maps_total") and "kernel" in k)
     assert c["mla_launches_total{model=eng,phase=decode,form=absorbed}"] \
         == c["gen_iterations_total{model=eng}"]
     assert eng.pipeline_stats()["kv"]["row_bytes_per_token"] == 3 * (32 + 64) * 4   # mla's rows

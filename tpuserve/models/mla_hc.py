@@ -37,11 +37,18 @@ sigmoid(POST_BIAS): what keeps the streams' RMS from growing as a plain
 residual's would) and ``b_res`` = ``RES_DIAGONAL`` x identity (a stream mostly
 keeps itself, and really mixes).
 
-WHAT IS COUNTED AND NAMED: ``hc_maps_total{phase=}``, live tokens times
-sublayers mapped (two a layer: over the tokens it reads 2 x layers, and
-anything else means a sublayer ran without its maps); the scope ``hc_mix``
-around the maps and both mixes of every sublayer, outside ``mla_prefill`` /
-``mla_decode``.
+WHERE THE MAPS AND THE MIXES RUN is chosen when a program is traced
+(``_hc_path``, as ``mla._walk`` chooses a walk): ``kernel`` on the TPU at
+shapes ``hyper.fits`` takes (a bfloat16 launch of whole row tiles: two kernel
+calls a sublayer, ``hyper.enter`` and ``hyper.leave``, ISSUE 47), ``xla``
+everywhere else (the CPU, float32, a step's few rows: ``hyper.maps``,
+``mix_in``, ``mix_out``).
+
+WHAT IS COUNTED AND NAMED: ``hc_maps_total{phase=,path=kernel|xla}``, live
+tokens times sublayers mapped, by where they were (two a layer: over the
+tokens it reads 2 x layers, and anything else means a sublayer ran without its
+maps); the scope ``hc_mix`` around the maps and both mixes of every sublayer,
+outside ``mla_prefill`` / ``mla_decode``.
 """
 
 from __future__ import annotations
@@ -69,8 +76,12 @@ SUBLAYERS = ("hc1", "hc2")   # a layer's two sets of maps: attention's, the feed
 
 
 class HyperLatentServing(mla.LatentServing):
-    # ``mla``'s twelve columns and the sublayers mapped, over live tokens.
-    COLUMNS = (*mla.LatentServing.COLUMNS, Column(counted("hc_maps"), series("hc_maps_total")))
+    # ``mla``'s twelve columns and the sublayers mapped, over live tokens, by
+    # where the launch's maps and mixes ran (``_hc_path``: the walks' two names).
+    COLUMNS = (*mla.LatentServing.COLUMNS,
+               *(Column(lambda model, stats, counts, path=path:
+                        counts["hc_maps"] if counts["hc_path"] == path else 0,
+                        series("hc_maps_total", f",path={path}")) for path in mla.WALKS))
     # A launch of 4,096 rows in eight tiles of 512 (the expanded form and its
     # kernel from 171 rows up): a prompt of 2,100 tokens pads a fifth tile of
     # 512 where it would pad a third of 1,024. 8.27 against 7.93 requests/s on
@@ -127,16 +138,29 @@ class HyperLatentServing(mla.LatentServing):
         xs = x.astype(jnp.float32).reshape(x.shape[0], self.n_streams, self.d)
         return super()._head(params, jnp.sum(xs, axis=1).astype(x.dtype))
 
+    def _hc_path(self, x) -> str:
+        """Where a launch's maps and mixes run, chosen when the program is
+        traced: ``kernel`` (on the TPU at shapes the kernels take) or ``xla``."""
+        if jax.default_backend() != "tpu":  # tps-ok[TPS503]: at trace time
+            return "xla"
+        fits = hyper.fits(x.shape[0], self.n_streams, self.d, x.dtype)
+        return "kernel" if fits else "xla"
+
     def _sublayer(self, hp: dict, x, m: dict, f):
         """One sublayer ``f`` (the mixed stream (T, d) -> ((T, d) float32, its
         counts)) under its maps ``hp``: (T, n d) -> ((T, n d), the counts)."""
+        n, path = self.n_streams, m.setdefault("hc_path", self._hc_path(x))
+        a = (n, self.eps, self.hc_iters, self.hc_eps, self.hc_clamp)
         with jax.named_scope("hc_mix"):
-            h_pre, h_post, h_res = hyper.maps(x, hp, self.n_streams, self.eps, self.hc_iters,
-                                              self.hc_eps, self.hc_clamp)
-            u = hyper.mix_in(x, h_pre)
+            if path == "kernel":
+                u, h = hyper.enter(x, hp, *a)
+            else:
+                h_pre, h_post, h_res = hyper.maps(x, hp, *a)
+                u = hyper.mix_in(x, h_pre)
         y, st = f(u)
         with jax.named_scope("hc_mix"):
-            x = hyper.mix_out(x, h_res, h_post, y)
+            x = hyper.leave(x, y, h, n) if path == "kernel" else \
+                hyper.mix_out(x, h_res, h_post, y)
         m["hc_maps"] = m.get("hc_maps", 0) + 1   # at trace time: sublayers that took their maps
         return x, st
 
@@ -150,7 +174,8 @@ class HyperLatentServing(mla.LatentServing):
             lp, i, rms_norm(u, lp["norm2"], self.eps), m["live"]))
 
     def _counts(self, m: dict) -> dict:
-        return {**super()._counts(m), "hc_maps": m.get("hc_maps", 0) * jnp.sum(m["live"])}
+        return {**super()._counts(m), "hc_maps": m.get("hc_maps", 0) * jnp.sum(m["live"]),
+                "hc_path": m.get("hc_path", "xla")}
 
 
 def create(cfg: ModelConfig) -> HyperLatentServing:
