@@ -275,7 +275,7 @@ def test_where_nothing_can_be_left_behind_the_program_has_no_cond(first, count, 
 
 
 @pytest.mark.parametrize("picks,count,of,rows", [
-    (22528, 128, 512, 7168), (5632, 128, 512, 1792),       # Nemotron's launch and step
+    (22528, 128, 512, 7040), (5632, 128, 512, 1792),       # Nemotron's launch and step
     (10240, 128, 256, 6400), (1280, 128, 256, 896),        # Laguna's
     (16384, 256, 256, 16384), (128, 256, 256, 128),        # JoyAI's: every expert held
     (176, 128, 512, 128), (40, 2, 8, 40), (4096 * 4, 1, 4, 5120),
@@ -288,8 +288,9 @@ def test_the_row_bound_is_the_expected_held_picks_with_slack_in_whole_row_tiles(
 
     assert _row_bound(picks, count, of) == rows
     if rows < picks:
-        assert rows % _row_tile(rows) == 0 and rows >= picks * count / of * COMPACT_SLACK
-        assert rows - _row_tile(rows) < picks * count / of * COMPACT_SLACK
+        tm = _row_tile(picks / of)
+        assert rows % tm == 0 and rows >= picks * count / of * COMPACT_SLACK
+        assert rows - tm < picks * count / of * COMPACT_SLACK
 
 
 # -- zero-compute picks (ISSUE 42) ------------------------------------------------------------------
@@ -381,3 +382,185 @@ def test_a_token_with_every_pick_zero_compute_and_one_with_none():
                                 np.asarray(x[1] @ w_in[1][g - 4])) @ np.asarray(w_out[g - 4])
                for g in (5, 6))
     np.testing.assert_allclose(y[1], want, rtol=2e-4, atol=2e-4)
+
+
+# -- the grouped product's tiles follow what an expert gets (ISSUE 48) ----------------------------
+
+# (launch rows, step rows, k, count, of, D, F): the five routed cells (`benchmark/configs/*.json`:
+# Laguna, Nemotron's latent experts, JoyAI, LongCat's 16 of 512 + 256 zero-compute, Xing)
+CELL_SHAPES = {
+    "laguna": (1024, 128, 10, 128, 256, 3072, 1024),
+    "nemotron": (1024, 256, 22, 128, 512, 1024, 2688),
+    "joyai": (4096, 16, 8, 256, 256, 2048, 768),
+    "longcat": (1024, 256, 12, 16, 768, 6144, 2048),
+    "xing": (4096, 64, 4, 64, 64, 3584, 1024),
+}
+
+
+# (launch, step) -> ((tm, tk, tn) of the in-product, of the out-product): what the sweep on the
+# chip led to (PERF.md section 6, PR 48). K is whole but for LongCat's 6144-deep and Xing's
+# 3584-deep in-kernels, whose blocks fit at no N tile of 512 or more (those keep the cut tiles:
+# Xing's two programs are the parent's); only Xing's launch gives an expert a tile of 256 rows.
+CELL_TILES = {
+    "laguna": (((128, 3072, 512), (128, 1024, 1536)),) * 2,
+    "nemotron": (((128, 1024, 896), (128, 2688, 512)),) * 2,
+    "joyai": (((128, 2048, 768), (128, 768, 2048)),) * 2,
+    "longcat": (((128, 1024, 1024), (128, 2048, 768)),) * 2,
+    "xing": (((256, 896, 1024), (256, 1024, 896)), ((128, 896, 1024), (128, 1024, 896))),
+}
+
+
+@pytest.mark.parametrize("phase", ["launch", "step"])
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_the_rules_tiles_at_a_cells_shapes(cell, phase):
+    """At each cell's launch and step: the kernel's tiles divide K and N in
+    multiples of 128 and fit the stated fast-memory budget beside the row
+    tile's blocks, no wider N tile would, K is whole wherever an N tile of
+    `TN_LEAST` leaves it room, the row tile follows the rows an expert expects
+    and the compact branch carries a whole number of them."""
+    from tpuserve.ops import moe
+
+    launch, step, k, count, of, d, f = CELL_SHAPES[cell]
+    picks = (launch if phase == "launch" else step) * k
+    expects = picks / of
+    bound = moe._row_bound(picks, count, of)
+    assert bound <= picks and (bound == picks or bound % moe._row_tile(expects) == 0)
+    assert bound == picks or bound >= picks * count / of * moe.COMPACT_SLACK
+    for (kk, n), want in zip(((d, f), (f, d)), CELL_TILES[cell][phase == "step"]):
+        tk, tn = moe._kernel_tiles(kk, n)
+        tm = moe._row_tile(expects, tk, tn)
+        assert (tm, tk, tn) == want
+        assert kk % tk == 0 and n % tn == 0 and tn % 128 == 0 and bound % tm == 0
+        assert tm == (256 if expects >= 256 else 128)
+        assert moe._tile_bytes(tm, tk, tn) <= moe.TILE_BUDGET < 16 * 2 ** 20
+        whole = moe._tile_bytes(moe.ROW_TILE, kk, moe.TN_LEAST) <= moe.TILE_BUDGET
+        assert (tk == kk) == whole, "K whole wherever it fits: an expert's kernel is fetched once"
+        if whole:
+            wider = [t for t in range(tn + 128, n + 1, 128) if n % t == 0]
+            assert all(moe._tile_bytes(moe.ROW_TILE, tk, t) > moe.TILE_BUDGET for t in wider)
+
+
+@pytest.mark.parametrize("expects,tiles,tm", [
+    (0.5, (2048, 768), 128), (4, (1024, 896), 128), (128, (2048, 768), 128),
+    (255, (1024, 896), 128), (256, (1024, 896), 256), (4096, (2048, 512), 256),
+    (4096, (2048, 768), 128),    # the blocks of 256 rows would not fit beside this kernel block
+    (256, (0, 0), 256), (40, (0, 0), 128)])   # what `_row_bound` rounds to: no kernel named
+def test_the_row_tile_follows_the_rows_an_expert_expects(expects, tiles, tm):
+    from tpuserve.ops import moe
+
+    assert moe._row_tile(expects, *tiles) == tm
+    assert moe._tile_bytes(tm, *tiles) <= moe.TILE_BUDGET
+
+
+@pytest.mark.parametrize("rows", [128, 896, 4096, 6400, 32768])
+@pytest.mark.parametrize("k,n", [(3072, 1024), (2048, 768), (2688, 1024), (3584, 1024)])
+def test_the_kernels_tiles_are_the_kernels_own(monkeypatch, rows, k, n):
+    """(tk, tn) handed to megablox for a kernel do not change with the rows
+    carried nor with the rows an expert expects: a row's sum over K is the same
+    whoever shares its launch."""
+    from tpuserve.ops import moe
+
+    tilings = _recorded_gmm(monkeypatch, run=False)
+    for expects in (1.0, 255.0, 4096.0 if rows % 256 == 0 else 40.0):
+        moe._grouped_dot(rows, jnp.bfloat16, (64, k, n), (64, n, k), expects=expects)(
+            jax.ShapeDtypeStruct((rows, k), jnp.bfloat16),
+            jax.ShapeDtypeStruct((64, k, n), jnp.bfloat16), None)
+    assert {t[2:] for t in tilings} == {moe._kernel_tiles(k, n)} and len(tilings) == 3
+    assert all(rows % t[1] == 0 for t in tilings)
+
+
+@pytest.mark.parametrize("k,n,tiles", [
+    (16384, 1024, (1024, 1024)),   # no N tile leaves room for a K this deep: the old rule stands
+    (6144, 2048, (1024, 1024)),    # LongCat's in-kernels: K whole fits only under `TN_LEAST`
+    (3584, 1024, (896, 1024)),     # Xing's: at an N tile of 256, and the step lost what the launch won
+    (1000, 1024, (0, 1024)),       # K in no whole tiles: `ragged_dot`
+    (2048, 64, (1024, 0)), (128, 128, (128, 128)), (256, 512, (256, 512)), (512, 256, (512, 256))])
+def test_where_k_whole_does_not_fit_the_cut_tiles_stand(k, n, tiles):
+    from tpuserve.ops.moe import _kernel_tiles, _tile
+
+    assert _kernel_tiles(k, n) == tiles
+    if tiles[0] != k or n < 512:
+        assert tiles == (_tile(k), _tile(n))
+
+
+def _recorded_gmm(monkeypatch, run=True):
+    """The backend named `tpu` and megablox run in the interpreter (or, with
+    `run` false, not at all): -> the list of (rows, tm, tk, tn)
+    `_grouped_dot` handed it, one a product traced."""
+    import functools
+
+    from jax.experimental.pallas.ops.tpu import megablox
+
+    tilings = []
+    real = megablox.gmm
+
+    def gmm(lhs, rhs, sizes, *, tiling, **kw):
+        tilings.append((lhs.shape[0], *tiling))
+        return real(lhs, rhs, sizes, tiling=tiling, interpret=True, **kw) if run else None
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(megablox, "gmm", functools.wraps(real)(gmm))
+    return tilings
+
+
+@pytest.mark.parametrize("first,count,of,k,t,body,dims", [
+    (4, 4, 16, 4, 256, "relu2", (256, 128)), (0, 4, 16, 8, 1024, "swiglu", (128, 128)),
+    (0, 8, 8, 2, 128, "swiglu", (128, 256))])
+def test_the_kernels_tiles_do_not_change_with_the_rows_nor_the_rows_bits(monkeypatch, first, count,
+                                                                        of, k, t, body, dims):
+    """`compact` and `wide` hand megablox the same (tk, tn) for a kernel and
+    the same row tile, whatever rows each carries, and a held pick's row is the
+    same bits in both (the interpreter stands in for the chip)."""
+    from tpuserve.ops import moe
+
+    tilings = _recorded_gmm(monkeypatch)
+    layer = _layer(11, first, count, of, k, t, "bfloat16", body, True, *dims)
+    x, w, e, w_in, w_out, live = layer
+    fn = moe.swiglu if body == "swiglu" else moe.relu2
+    bound = moe._row_bound(k * t, count, of)
+    (compact, cs), (wide, ws) = _both_branches(monkeypatch, layer, first, fn, of) \
+        if bound < k * t else [jax.jit(lambda *a: moe.held_experts(
+            *a, first, w_in, w_out, fn, live=live, of=of))(x, w, e)] * 2
+    assert (_bits(compact) == _bits(wide)).all()
+    d, f = dims
+    by_kernel = {}
+    for rows, tm, tk, tn in tilings:
+        assert rows in (bound, k * t) and rows % tm == 0
+        assert tm == moe._row_tile(k * t / of, tk, tn)
+        by_kernel.setdefault(tk, set()).add((tk, tn))
+    assert {rows for rows, *_ in tilings} == {bound, k * t}
+    assert by_kernel == {d: {moe._kernel_tiles(d, f)}, f: {moe._kernel_tiles(f, d)}}
+    plain = jax.jit(lambda *a: moe.held_experts(
+        *a, first, w_in, w_out, fn, live=live))(x, w, e)[0]   # no `of`: one branch
+    np.testing.assert_allclose(np.asarray(compact), np.asarray(plain), rtol=2e-2, atol=2e-2)
+
+
+# (K, N, sizes, rows): K whole at the rule's tiles, through megablox in the interpreter.
+GMM_CASES = {
+    "a tile straddles three experts": (256, 384, [40, 50, 166, 128], 384),
+    "an empty expert": (384, 256, [100, 0, 28, 128], 256),
+    "rows past the groups' sum": (256, 256, [30, 60, 20], 256),
+    "an expert of several tiles": (128, 384, [300, 10, 74], 384),
+    "every expert empty but one": (256, 128, [0, 0, 128, 0], 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GMM_CASES))
+def test_megablox_at_the_rules_tiles_is_the_ragged_dot(case):
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from tpuserve.ops import moe
+
+    k, n, sizes, rows = GMM_CASES[case]
+    rng = np.random.default_rng(len(case))
+    lhs = jnp.asarray(rng.standard_normal((rows, k)), jnp.bfloat16)
+    rhs = jnp.asarray(rng.standard_normal((len(sizes), k, n)) / 16, jnp.bfloat16)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    tiles = (moe._row_tile(rows / len(sizes), *moe._kernel_tiles(k, n)), *moe._kernel_tiles(k, n))
+    assert tiles[1] == k and rows % tiles[0] == 0
+    got = gmm(lhs, rhs, sizes, preferred_element_type=jnp.float32, tiling=tiles, interpret=True)
+    want = jax.lax.ragged_dot(lhs, rhs, sizes, preferred_element_type=jnp.float32)
+    live = int(sizes.sum())
+    assert got.dtype == jnp.float32 and got.shape == (rows, n)
+    np.testing.assert_allclose(np.asarray(got[:live]), np.asarray(want[:live]), rtol=1e-5,
+                               atol=1e-5)

@@ -163,12 +163,13 @@ class SwitchFFN(nn.Module):
 # launch: 22,528 picks, 5,632 held, sd 65; its step 5,632 / 1,408 / 33;
 # Laguna's 10,240 / 5,120 / 51 and 1,280 / 640 / 18), so 1.25 and the tile's
 # rounding leave 11 and 14 sd over the two steps' means and over 20 over the
-# launches' (bounds 7,168 / 1,792 / 6,400 / 896). Real routers are skewed
+# launches' (bounds 7,040 / 1,792 / 6,400 / 896: whole row tiles of 128, what
+# ``_row_tile`` gives at 5-44 rows an expert). Real routers are skewed
 # and correlated across a prompt's tokens: a launch that exceeds the bound
 # takes ``wide`` and loses nothing but time, and ``stats["compact"]`` says how
 # often. A larger slack gives rows back (each costs its gather, its convert
 # and its way back); the bound is rounded up to a row tile anyway, which at
-# these sizes adds 2-12%.
+# these sizes adds 0-12%.
 COMPACT_SLACK = 1.25
 
 
@@ -197,37 +198,91 @@ def topk_route(logits: jax.Array, k: int, *, normalize: bool = True,
 
 
 def _tile(n: int) -> int:
-    """The grouped product's tile of a kernel dimension ``n``: the largest
-    multiple of 128, at most 1024, that divides it (1024 -> 1024, 3072 ->
-    1024, 2688 -> 896); 0 where none does."""
+    """The grouped product's tile of a kernel dimension ``n`` where it has to be
+    cut: the largest multiple of 128, at most 1024, that divides it (1024 ->
+    1024, 3072 -> 1024, 2688 -> 896); 0 where none does."""
     return next((t for t in range(min(1024, n) // 128 * 128, 0, -128)
                  if n % t == 0), 0)
 
 
-def _row_tile(rows: int) -> int:
-    """The grouped product's tile of the rows: megablox wants whole tiles."""
-    return 128 if rows <= 4096 else 256
+# The grouped product's three tiles (ISSUE 48; the sweep is PERF.md section 6,
+# PR 48, ``scripts/bench_gmm.py``). megablox walks a grid of (N tiles, row-tile
+# visits, K pieces), a row tile visited once for every expert that has rows in
+# it, and its kernel block is (expert, K piece, N tile). On the chip a product
+# of these sizes runs at the rate its kernel blocks arrive (one block in flight
+# at a time: 300-380 GB/s of the chip's 819), so what the tiles decide is how
+# many bytes of kernel a launch fetches:
+#
+# - With K in ONE piece the kernel block's index stays put over an expert's
+#   consecutive visits, and an expert's kernel is fetched once an N tile. With K
+#   cut, the block changes at every grid step and the kernel is fetched again
+#   for every row tile that touches the expert: a drawn router's groups start
+#   anywhere, so nearly every tile is shared and the kernels arrive twice. So K
+#   stays whole wherever the blocks fit the kernel's fast memory, and N's tile
+#   shrinks to make the room (down to ``TN_LEAST``: each N tile walks the rows
+#   again).
+# - The row tile matters little beside that (the rows' blocks are small): the
+#   matrix unit's own 128, and 256 where an expert expects a whole tile of
+#   that and the blocks still fit.
+#
+# The kernel's tiles depend on the kernel's shape and the dtype ALONE, never on
+# the rows: a row's sum over K must not depend on who shares its launch (the
+# ``compact`` and ``wide`` branches below give bit-identical rows).
+TILE_BUDGET = 14.5 * 2 ** 20  # of 16 MiB: ``_tile_bytes`` ran up to 14.4, the chip refused 15.25
+ROW_TILE = 128                # the matrix unit's own height
+ROW_TILE_WIDE = 256           # where an expert expects that many rows or more
+TN_LEAST = 512                # under it the rows' extra walks cost what K whole saves
 
 
-def _grouped_dot(rows: int, dtype, *kernel_shapes):
+def _tile_bytes(tm: int, tk: int, tn: int) -> int:
+    """Fast memory megablox's blocks take at tiles ``(tm, tk, tn)`` of 128 or
+    256 bfloat16 rows, as the chip's compiler counted them in the sweep: the
+    kernel's block four times (the pipeline's two buffers and the product's
+    operand), the rows' block and the float32 result's twice."""
+    return (4 * tk * tn + 2 * tm * tk) * 2 + 2 * tm * tn * 4
+
+
+def _kernel_tiles(k: int, n: int) -> tuple[int, int]:
+    """``(tk, tn)`` of a ``(K, N)`` kernel: K whole and the largest multiple
+    of 128 dividing N whose blocks fit ``TILE_BUDGET`` at ``ROW_TILE`` rows;
+    where none of ``TN_LEAST`` or more fits (or K is no multiple of 128),
+    ``_tile`` of each."""
+    if k % 128 == 0:
+        for tn in range(n // 128 * 128, TN_LEAST - 1, -128):
+            if n % tn == 0 and _tile_bytes(ROW_TILE, k, tn) <= TILE_BUDGET:
+                return k, tn
+    return _tile(k), _tile(n)
+
+
+def _row_tile(expects: float, tk: int = 0, tn: int = 0) -> int:
+    """The grouped product's tile of the rows, from the rows an expert
+    ``expects`` of the launch (its picks over the router's width) and the
+    kernel's tiles the rows go through (none: what the widest product of the
+    launch may take, which ``_row_bound`` rounds to): megablox wants whole
+    tiles."""
+    wide = expects >= ROW_TILE_WIDE and _tile_bytes(ROW_TILE_WIDE, tk, tn) <= TILE_BUDGET
+    return ROW_TILE_WIDE if wide else ROW_TILE
+
+
+def _grouped_dot(rows: int, dtype, *kernel_shapes, expects: "float | None" = None):
     """``(lhs (rows, K), rhs (G, K, N), group sizes (G,)) -> (rows, N)``
     float32, rows of group g through ``rhs[g]``. On the TPU, for bfloat16
-    rows in whole tiles, the megablox Pallas kernel (one layer's three
-    products at 128 experts of 3072 x 1024, my chip runs, PR 28: 3.6 ms for
-    1,280 rows, against 5.2 ms and 3.0 ms for reading the kernels once;
-    5.6 against 9.6 ms for 20,480 rows); ``jax.lax.ragged_dot`` everywhere
-    else. Chosen when the program is traced, from what can be seen then."""
-    tm = _row_tile(rows)
-    tiles = all(_tile(kk) and _tile(n) for _g, kk, n in kernel_shapes)
+    rows in whole tiles, the megablox Pallas kernel at the tiles of the comment
+    above (``expects``: the rows an expert expects, ``rows`` over the groups
+    where the caller does not say); ``jax.lax.ragged_dot`` everywhere else.
+    Chosen when the program is traced, from what can be seen then."""
+    if expects is None:
+        expects = rows / kernel_shapes[0][0]
+    tiles = {}
+    for _g, kk, n in kernel_shapes:
+        tk, tn = _kernel_tiles(kk, n)
+        tiles[kk, n] = (_row_tile(expects, tk, tn), tk, tn)
     if jax.default_backend() == "tpu" and dtype == jnp.bfloat16 \
-            and rows % tm == 0 and tiles:
+            and all(tk and tn and rows % tm == 0 for tm, tk, tn in tiles.values()):
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        def dot(lhs, rhs, sizes):
-            _g, kk, n = rhs.shape
-            return gmm(lhs, rhs, sizes, preferred_element_type=jnp.float32,
-                       tiling=(tm, _tile(kk), _tile(n)))
-        return dot
+        return lambda lhs, rhs, sizes: gmm(
+            lhs, rhs, sizes, preferred_element_type=jnp.float32, tiling=tiles[rhs.shape[1:]])
     return lambda lhs, rhs, sizes: jax.lax.ragged_dot(
         lhs, rhs, sizes, preferred_element_type=jnp.float32)
 
@@ -251,7 +306,7 @@ def _row_bound(picks: int, count: int, of: "int | None") -> int:
     if of is None or count >= of:
         return picks
     want = math.ceil(picks * count / of * COMPACT_SLACK)
-    tm = _row_tile(want)
+    tm = _row_tile(picks / of)
     return min(picks, -(-want // tm) * tm)
 
 
@@ -338,7 +393,8 @@ def held_experts(x: jax.Array, weights: jax.Array, experts: jax.Array,
         with jax.named_scope("moe_dispatch"):
             xs = jnp.take(x, picks % t, axis=0)
         with jax.named_scope("moe_experts"):
-            dot = _grouped_dot(picks.shape[0], x.dtype, w_in[0].shape, w_out.shape)
+            dot = _grouped_dot(picks.shape[0], x.dtype, w_in[0].shape, w_out.shape,
+                               expects=k * t / (of or count))
             h = body(*(dot(xs, w, sizes) for w in w_in)).astype(x.dtype)
             out = dot(h, w_out, sizes)
         with jax.named_scope("moe_dispatch"):
