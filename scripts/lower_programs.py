@@ -41,7 +41,7 @@ import sys
 import tempfile
 from types import SimpleNamespace
 
-FAMILIES = ("decoder", "hybrid", "hybrid_ffn", "mla", "mla_sc", "mla_hc")
+FAMILIES = ("decoder", "hybrid", "hybrid_ffn", "mla", "mla_sc", "mla_hc", "decoder_sink")
 
 
 def kernels_without_places(text: str) -> tuple[str, int]:

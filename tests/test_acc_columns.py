@@ -1,7 +1,7 @@
 """What ``acc`` holds is stated once a family (``paged_lm.Column``, ISSUE 45):
 the state's width, the row a launch adds, the counters ``bind_metrics`` binds
 and what ``observe_step`` feeds all follow the family's ``COLUMNS``. Here, for
-each of the six generating families at its toy size: one prefill launch and
+each of the seven generating families at its toy size: one prefill launch and
 one step on the CPU, then the device's sums into a registry. The names below
 are the series as they have been served since each family came (the benchmark's
 readers find them by these letters), written down apart from the code."""
@@ -40,6 +40,10 @@ SERIES = {
     "mla_sc": MLA + ["moe_routed_zero_total{model=M,phase=PH}"],
     "mla_hc": MLA + ["hc_maps_total{model=M,phase=PH,path=kernel}",
                      "hc_maps_total{model=M,phase=PH,path=xla}"],
+    "decoder_sink": EXPERTS + CONTEXT + COMPACT + [
+        "attn_rows_attended_total{model=M,phase=PH}", "attn_rows_walked_total{model=M,phase=PH}",
+        "attn_walks_total{model=M,phase=PH,walk=kernel}",
+        "attn_walks_total{model=M,phase=PH,walk=xla}"],
 }
 # The fourth expert column sums held experts x expert layers run, so it feeds
 # the layers' counter too, over the experts held.

@@ -502,6 +502,73 @@ def test_a_steps_walk_is_one_kernel_call_an_attention_on_the_v5e_at_the_cells_wi
                 if " copy(" in ln and (f"{pages},128,512" in ln or f"{pages},64,128" in ln)]
 
 
+def test_a_global_layers_decode_is_one_kernel_call_on_the_v5e_at_the_cells_widths(
+        one_chip, tmp_path, monkeypatch):
+    """`ops/lane_attention.py` `head_walk` under `decoder_sink`'s step (ISSUE 49)
+    at the cell's sizes: 384 lanes of 64 query heads on 4 KV heads, keys in a
+    passing part of 128 and a turning part of 64 two heads a row, values of
+    128 in a pool of their own, 4,608 pages of 128 tokens, a block table of 24
+    pages, cells of 4 pages. The TPU branch is steered by the backend's name
+    here, in the test. Mosaic takes the kernel (all four KV heads of a page in
+    one block, the work list by scalar prefetch, its length a traced grid
+    bound); a global layer's walk of a step, with its rows' write into the
+    three pools, is ONE custom call and no `while`, nothing float32 by lane,
+    head and key is in the program, no gather of the padded table, and no copy
+    of a pool."""
+    import json
+
+    from tpuserve.config import ModelConfig
+    from tpuserve.models import build
+
+    arch = {"vocab_size": 256, "hidden_size": 1024, "intermediate_size": 256,
+            "num_hidden_layers": 2, "hybrid_layer_pattern": [0, 1], "moe_layer_freq": [0, 0],
+            "num_attention_heads": 64, "num_key_value_heads": 4, "head_dim": 192,
+            "v_head_dim": 128, "swa_num_attention_heads": 64, "swa_num_key_value_heads": 8,
+            "swa_head_dim": 192, "swa_v_head_dim": 128, "partial_rotary_factor": 0.334,
+            "rope_theta": 10000000, "swa_rope_theta": 10000, "sliding_window": 128,
+            "add_swa_attention_sink_bias": True, "attention_value_scale": 0.707}
+    path = tmp_path / "arch.json"
+    path.write_text(json.dumps(arch))
+    model = build(ModelConfig(name="walk", family="decoder_sink", dtype="bfloat16",
+                              batch_buckets=[1], options={
+                                  "config_file": str(path), "max_prompt_tokens": 2048,
+                                  "max_new_tokens": 1024}))
+    lanes, pages, P = 384, 4608, 128
+    pps, heads = model.kv_pages_per_slot(P), model._heads(0)
+    assert pps == 24 and heads == (4, 192, 128) and model.step_keys // P == 4
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    sig = model.kv_page_signature(lanes, pages, P)
+    pools = tuple(shape(*sig[leaf][0].shape) for leaf in model.kv_page_leaves)
+    assert [p.shape for p in pools] == [(4, pages, P, 128), (2, pages, P, 128), (4, pages, P, 128)]
+
+    def attend(q, k, v, pools, bt, pos, live):
+        state = {"bt": bt, "kn": [pools[0]], "kr": [pools[1]], "vf": [pools[2]],
+                 "ring": jnp.zeros((lanes,), jnp.int32)}
+        m = model._step_plan(state, live, pos)
+        assert m["walk"] == "kernel"
+        return model._attend_global(q, k, v, pools, m, heads)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
+    try:
+        # the pools donated, as the engine donates the state they are part of
+        text = jax.jit(attend, donate_argnums=(3,)).lower(
+            shape(lanes, 64, 192), shape(lanes, 4, 192), shape(lanes, 4, 128), pools,
+            shape(lanes, pps, dtype=jnp.int32), shape(lanes, dtype=jnp.int32),
+            shape(lanes, dtype=jnp.bool_)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    calls = [ln for ln in text.split("\n") if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "head_walk" in calls[0]
+    assert " while(" not in text
+    for scores in (f"f32[{lanes},64,512]", "f32[64,512]", f"[4,{lanes},{pps * P},"):
+        assert scores not in text
+    assert not [ln for ln in text.split("\n") if " copy(" in ln and f"{pages},128,128" in ln]
+
+
 def test_decode_over_packed_pages_compiles_for_the_v5e_at_the_cells_widths(one_chip, tmp_path,
                                                                            monkeypatch):
     """`paged_lm._decode_full` over pools whose rows hold two KV heads of 64

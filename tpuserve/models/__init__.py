@@ -40,6 +40,12 @@ Families (BASELINE.json ``configs``):
                    into all of them through its own pre, post and
                    Sinkhorn-projected residual maps; yarn on the latent
                    attention in DeepSeek's convention (ISSUE 46)
+- decoder_sink   — ``decoder``'s sibling for window and global attention whose
+                   keys are wider than their values and whose KV heads and
+                   cache rows go by the layer's kind, a learned sink in the
+                   window layers' softmax, sigmoid-routed experts with no
+                   shared one, and a grouped decode walk over the global
+                   layers' pages (ISSUE 49)
 - toy            — a linear classifier for tests and drills
 """
 
@@ -64,6 +70,7 @@ _REGISTRY: dict[str, str] = {
     "mla": "tpuserve.models.mla",
     "mla_sc": "tpuserve.models.mla_sc",
     "mla_hc": "tpuserve.models.mla_hc",
+    "decoder_sink": "tpuserve.models.decoder_sink",
     "toy": "tpuserve.models.toy",
 }
 

@@ -128,9 +128,16 @@ class DecoderServing(PagedLM):
     # took the compact branch.
     COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, COMPACT_COLUMN)
 
+    value_scale = 1.0   # a factor on the values before they are cached; 1: none
+
+    def _arch(self, a: dict) -> dict:
+        """The model's config file under the key names this constructor
+        reads: as it is; a sibling whose published names differ translates."""
+        return a
+
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)
-        a = read_config_file(cfg)
+        a = self._arch(read_config_file(cfg))
         self.dtype = jnp.dtype(cfg.dtype)
         for key, want in (("attention_bias", False),
                           ("moe_apply_router_weight_on_input", False)):
@@ -198,30 +205,38 @@ class DecoderServing(PagedLM):
     def _tensors(self):
         """(path, shape held here, full shape, start, role, fan-in) of every
         matrix, in a fixed order."""
-        d, hd, s = self.d, self.hd, self.scales
+        d, s = self.d, self.scales
         yield from self._vocab_tensors()
         for i in range(self.n_layers):
             L = f"layer{i}"
             hf, h, h0 = self.heads_full[i], self.heads[i], self.h_first[i]
-            for name, scale in (("wq", s["qk"]), ("wk", s["qk"]), ("wv", s["v"])):
-                nf, n, n0 = (hf, h, h0) if name == "wq" else \
-                    (self.kv_full, self.kv, self.kv_first)
-                yield ((L, name), (d, n, hd), (d, nf, hd), (0, n0, 0), scale, d)
+            kv, dk, dv = self._heads(i)
+            kvf = kv * (self.kv_full // self.kv)   # of the layer whole
+            yield ((L, "wq"), (d, h, dk), (d, hf, dk), (0, h0, 0), s["qk"], d)
+            yield ((L, "wk"), (d, kv, dk), (d, kvf, dk), (0, self.kv_first, 0), s["qk"], d)
+            yield ((L, "wv"), (d, kv, dv), (d, kvf, dv), (0, self.kv_first, 0), s["v"], d)
             if self.gated:
                 yield ((L, "wg"), (d, h), (d, hf), (0, h0), s["gate"], d)
-            yield ((L, "wo"), (h, hd, d), (hf, hd, d), (h0, 0, 0), s["o"], hf * hd)
+            yield ((L, "wo"), (h, dv, d), (hf, dv, d), (h0, 0, 0), s["o"], hf * dv)
             if self.mlp_types[i] == "dense":
                 f = self.dense_width
                 for name in ("w_gate", "w_up"):
                     yield ((L, name), (d, f), (d, f), (0, 0), s["ffn_in"], d)
                 yield ((L, "w_down"), (f, d), (f, d), (0, 0), s["ffn_out"], f)
                 continue
-            e, ec, e0, f, fs = (self.n_experts, self.e_count, self.e_first,
-                                self.expert_width, self.shared_width)
-            yield ((L, "router"), (d, e), (d, e), (0, 0), s["router"], d)
-            for name in ("e_gate", "e_up"):
-                yield ((L, name), (ec, d, f), (e, d, f), (e0, 0, 0), s["ffn_in"], d)
-            yield ((L, "e_down"), (ec, f, d), (e, f, d), (e0, 0, 0), s["ffn_out"], f)
+            yield from self._sparse_tensors(L)
+
+    def _sparse_tensors(self, L: str):
+        """A sparse layer's matrices: the router, the held experts, the
+        shared expert."""
+        d, s = self.d, self.scales
+        e, ec, e0, f, fs = (self.n_experts, self.e_count, self.e_first,
+                            self.expert_width, self.shared_width)
+        yield ((L, "router"), (d, e), (d, e), (0, 0), s["router"], d)
+        for name in ("e_gate", "e_up"):
+            yield ((L, name), (ec, d, f), (e, d, f), (e0, 0, 0), s["ffn_in"], d)
+        yield ((L, "e_down"), (ec, f, d), (e, f, d), (e0, 0, 0), s["ffn_out"], f)
+        if fs:
             for name in ("s_gate", "s_up"):
                 yield ((L, name), (d, fs), (d, fs), (0, 0), s["ffn_in"], d)
             yield ((L, "s_down"), (fs, d), (fs, d), (0, 0), s["ffn_out"], fs)
@@ -243,50 +258,60 @@ class DecoderServing(PagedLM):
     # -- device math --------------------------------------------------------------
     def _qkv(self, lp: dict, i: int, u: jax.Array, pos: jax.Array):
         """``u`` (T, d) normed stream at positions ``pos`` (T,) -> rotated q
-        (T, H, hd), rotated k and v (T, KV, hd), the gate (T, H) or None."""
+        (T, H, dk), rotated k (T, KV, dk), v (T, KV, dv) times ``value_scale``,
+        the gate (T, H) or None."""
         dt = self.dtype
         inv, factor, dim = self.rope[self.layer_types[i]]
         q = jnp.einsum("td,dhk->thk", u, lp["wq"],
                        preferred_element_type=jnp.float32).astype(dt)
         k = jnp.einsum("td,dhk->thk", u, lp["wk"],
                        preferred_element_type=jnp.float32).astype(dt)
-        v = jnp.einsum("td,dhk->thk", u, lp["wv"],
-                       preferred_element_type=jnp.float32).astype(dt)
+        v = jnp.einsum("td,dhk->thk", u, lp["wv"], preferred_element_type=jnp.float32)
+        if self.value_scale != 1.0:
+            v = v * jnp.float32(self.value_scale)
+        v = v.astype(dt)
         gate = jax.nn.sigmoid(_mm(u, lp["wg"])) if self.gated else None
         return (apply_rope(q, pos, inv, factor, dim),
                 apply_rope(k, pos, inv, factor, dim), v, gate)
 
     def _attn_out(self, lp, o, gate):
-        """o (T, H, hd) float32 -> (T, d): gated by head, through W_o."""
+        """o (T, H, dv) float32 -> (T, d): gated by head, through W_o."""
         if gate is not None:
             o = o * gate[..., None]
         return jnp.einsum("thk,hkd->td", o.astype(self.dtype), lp["wo"],
                           preferred_element_type=jnp.float32)
 
+    scoring = "softmax"   # the router's scores: a softmax, or a sigmoid of each logit alone
+
     def _ffn(self, lp, i, u, live):
-        """(T, d) -> ((T, d) float32, the expert layer's counts or None)."""
+        """(T, d) -> ((T, d) float32, the expert layer's counts or None). A
+        sparse layer: the router in float32, ``topk_route`` by ``scoring``
+        (with the layer's selection bias ``e_bias`` where it has one), the
+        held experts' part, and the shared expert where there is one."""
         if self.mlp_types[i] == "dense":
             return self._swiglu(u, lp["w_gate"], lp["w_up"], lp["w_down"]), None
         r = jnp.matmul(u.astype(jnp.float32), lp["router"].astype(jnp.float32),
                        precision=jax.lax.Precision.HIGHEST)
         if self.softcap > 0:
             r = self.softcap * jnp.tanh(r / self.softcap)
-        w, e = topk_route(r, self.top_k, normalize=self.norm_topk,
-                          scale=self.route_scale)
+        w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
+                          scoring=self.scoring, select_bias=lp.get("e_bias"))
         y, stats = held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"],
                                        lp["e_up"], lp["e_down"], live=live,
                                        of=self.n_experts)
+        if not self.shared_width:
+            return y, stats
         return y + self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"]), stats
 
-    def _prefill_window(self, q, k, v, ring_k, ring_v, qpos, rpos, kpos, ok):
+    def _prefill_window(self, q, k, v, ring_k, ring_v, qpos, rpos, kpos, ok, sink=None):
         """A window layer's attention of one launch, tile by tile: q (K, T,
-        H, hd) at positions ``qpos`` (K, T); k and v (C, KV, hd), the
-        launch's own rows at positions ``kpos`` (``ok`` (K, C) where a row is
-        a live token of the tile's prompt); ``ring_k``/``ring_v`` (K, W, KV,
-        hd), what each tile's ring held BEFORE the launch, at positions
+        H, dk) at positions ``qpos`` (K, T); k (C, KV, dk) and v (C, KV, dv),
+        the launch's own rows at positions ``kpos`` (``ok`` (K, C) where a row
+        is a live token of the tile's prompt); ``ring_k``/``ring_v`` (K, W, KV,
+        width), what each tile's ring held BEFORE the launch, at positions
         ``rpos`` (K, W), negative where nothing was written. A tile sees its
         ring and the W rows before its own last: a query sees no further
-        back, so the scores are (T, 2W + T) a head."""
+        back, so the scores are (T, 2W + T) a head. ``sink``: ``_attend``'s."""
         n_tiles, T = qpos.shape
         W = self.window
         # Row r of the launch at W + r; tile t reads rows [tT - W, tT + T).
@@ -302,7 +327,7 @@ class DecoderServing(PagedLM):
         mask = (dist >= 0) & (dist < W) \
             & jnp.concatenate([rpos >= 0, seen], axis=1)[:, None, :]
         return self._attend(q, jnp.concatenate([ring_k, near(k, 0)], axis=1),
-                            jnp.concatenate([ring_v, near(v, 0)], axis=1), mask)
+                            jnp.concatenate([ring_v, near(v, 0)], axis=1), mask, sink)
 
     # -- what a launch works out once ------------------------------------------------
     def _tiles(self, launch: Any, chunk: int) -> dict:
@@ -348,24 +373,34 @@ class DecoderServing(PagedLM):
         vp = self._write_pages(vp, m["w_page"], m["off"], v)
         if t is None:
             return self._decode_full(q, kp, vp, m["bt"], m["pos"]), kp, vp
-        return self._prefill_full_tiles(qt, kp, vp, t).reshape(q.shape), kp, vp
+        return self._prefill_full_tiles(qt, (kp, vp), t).reshape(q.shape), kp, vp
 
-    def _attend_window(self, q, k, v, rk, rv, m: dict):
-        """A window layer's attention in either phase -> (o as ``q`` lies, the
+    def _attend_window(self, q, k, v, rk, rv, m: dict, sink=None):
+        """A window layer's attention in either phase -> (o (T, H, dv), the
         two rings). A step writes its row and reads its ring (a free lane
         reads ring 0, which every free lane writes: its result is discarded);
-        a launch reads what the rings held before it and itself, then writes."""
+        a launch reads what the rings held before it and itself, then writes.
+        A ring is (slots + 1, W, KV, width), or the same flattened over heads
+        (a family whose head is no whole number of 128 lanes): rows go in as
+        the ring lies, and what is read comes back by head. ``sink``:
+        ``_attend``'s."""
         t, w_ring, roff = m["t"], m["w_ring"], m["roff"]
+
+        def put(ring, rows):
+            return ring.at[w_ring, roff].set(rows.reshape(rows.shape[:1] + ring.shape[2:]))
+
+        def held(ring, at, rows):
+            return jnp.take(ring, at, axis=0).reshape(at.shape + ring.shape[1:2] + rows.shape[1:])
+
         if t is None:
-            rk, rv = rk.at[w_ring, roff].set(k), rv.at[w_ring, roff].set(v)
-            return self._attend(q[:, None], jnp.take(rk, w_ring, axis=0),
-                                jnp.take(rv, w_ring, axis=0), m["mask_win"])[:, 0], rk, rv
+            rk, rv = put(rk, k), put(rv, v)
+            return self._attend(q[:, None], held(rk, w_ring, k), held(rv, w_ring, v),
+                                m["mask_win"], sink)[:, 0], rk, rv
         o = self._prefill_window(q.reshape((t["K"], t["T"]) + q.shape[1:]), k, v,
-                                 jnp.take(rk, t["rings"], axis=0),
-                                 jnp.take(rv, t["rings"], axis=0), t["qpos"], m["rpos"],
-                                 m["pos"], m["own"])
-        rk, rv = rk.at[w_ring, roff].set(k), rv.at[w_ring, roff].set(v)
-        return o.reshape(q.shape), rk, rv
+                                 held(rk, t["rings"], k), held(rv, t["rings"], v),
+                                 t["qpos"], m["rpos"], m["pos"], m["own"], sink)
+        rk, rv = put(rk, k), put(rv, v)
+        return o.reshape(q.shape[:-1] + o.shape[-1:]), rk, rv
 
     def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
         q, k, v, gate = self._qkv(lp, i, rms_norm(x, lp["norm1"], self.eps), m["pos"])

@@ -325,7 +325,7 @@ class PlainAttention:
         the pages before any tile reads them."""
         q, k, v = self._qkv(lp, u)
         kp, vp = self._write_pages(kp, w_page, off, k), self._write_pages(vp, w_page, off, v)
-        o = self._prefill_full_tiles(q.reshape((t["K"], t["T"]) + q.shape[1:]), kp, vp, t)
+        o = self._prefill_full_tiles(q.reshape((t["K"], t["T"]) + q.shape[1:]), (kp, vp), t)
         return self._attn_out(lp, o.reshape(q.shape)), kp, vp
 
     def _attn_step(self, lp, u, kp, vp, bt, pos, w_page, off):

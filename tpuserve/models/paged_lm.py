@@ -30,7 +30,12 @@ walk's work list) and ``_counts``.
 
 A subclass sets, in its constructor: ``dtype``, ``d``, ``eps``, ``n_layers``,
 ``vocab_full``, ``v_first``, ``vocab``, ``scales``, where it attends by head
-``hd`` and ``kv`` (KV heads held), and calls ``_serve_options``. It may set
+``hd`` and ``kv`` (KV heads held), and calls ``_serve_options``. WHAT A LAYER'S
+HEADS ARE is read in one place, ``_heads(i)`` -> ``Heads(kv, dk, dv)`` (ISSUE
+49): KV heads held, a key's (and a query's) width, a value's. By default every
+layer has ``kv`` heads of ``hd`` both ways; a family whose keys are wider than
+its values, or whose KV heads go by the layer's kind, says so there, and the
+tensors, the pools' shapes, the prefill walk and ``_attend`` follow it. It may set
 ``tied`` (the head is the embedding transposed: no ``head`` is drawn) and
 ``score_scale`` (what the scores are multiplied by, where the config says).
 
@@ -111,6 +116,14 @@ class _ExpertSteps:
     def inc(self, amount: float) -> None:
         self.steps.inc(amount)
         self.layers.inc(amount / self.held)
+
+
+class Heads(NamedTuple):
+    """A layer's attention by head, as its tensors and its cache see it: KV
+    heads held, the width of a key (and of a query), the width of a value."""
+    kv: int
+    dk: int
+    dv: int
 
 
 class Column(NamedTuple):
@@ -262,23 +275,33 @@ class PagedLM(GenerativeModel):
     def kv_pages_per_slot(self, page_tokens: int) -> int:
         return -(-self.max_ctx // int(page_tokens))
 
-    def _kv_pack(self) -> int:
+    def _heads(self, i: int | None = None) -> Heads:
+        """Layer ``i``'s attention by head (module docstring): here every
+        layer alike, ``kv`` heads of ``hd`` for keys and values."""
+        return Heads(self.kv, self.hd, self.hd)
+
+    def _kv_pack(self, kv: int | None = None, width: int | None = None) -> int:
         """KV heads a pool's row holds side by side: as many as fill the 128
-        lanes where they do so exactly, else one."""
-        pack = 128 // self.hd if self.hd < 128 and 128 % self.hd == 0 else 1
-        return pack if self.kv % pack == 0 else 1
+        lanes where they do so exactly, else one (``kv`` heads of ``width``
+        values; by default the model's)."""
+        kv, width = self.kv if kv is None else kv, self.hd if width is None else width
+        pack = 128 // width if width < 128 and 128 % width == 0 else 1
+        return pack if kv % pack == 0 else 1
 
-    def _page_shape(self, pages: int, page_tokens: int) -> tuple:
-        pack = self._kv_pack()
-        return (self.kv // pack, pages, page_tokens, pack * self.hd)
+    def _page_shape(self, pages: int, page_tokens: int, kv: int | None = None,
+                    width: int | None = None) -> tuple:
+        kv, width = self.kv if kv is None else kv, self.hd if width is None else width
+        pack = self._kv_pack(kv, width)
+        return (kv // pack, pages, page_tokens, pack * width)
 
-    def _by_head(self, blk):
-        """Gathered pages (KV / pack, n, P, pack * hd) -> (KV, n * P, hd)."""
+    def _by_head(self, blk, width: int | None = None):
+        """Gathered pages (KV / pack, n, P, pack * width) -> (KV, n * P, width)."""
         kvp, n, P, w = blk.shape
-        if w == self.hd:
+        width = self.hd if width is None else width
+        if w == width:
             return blk.reshape(kvp, n * P, w)
-        return blk.reshape(kvp, n * P, w // self.hd, self.hd).transpose(0, 2, 1, 3) \
-            .reshape(self.kv, n * P, self.hd)
+        return blk.reshape(kvp, n * P, w // width, width).transpose(0, 2, 1, 3) \
+            .reshape(kvp * (w // width), n * P, width)
 
     def kv_page_signature(self, slots: int, pages: int, page_tokens: int) -> Any:
         return {**self._cache_signature(slots, pages, page_tokens),
@@ -324,20 +347,28 @@ class PagedLM(GenerativeModel):
         return self.max_new
 
     # -- device math --------------------------------------------------------------
-    def _attend(self, q, k, v, mask):
-        """q (..., T, H, hd), k and v (..., C, KV, hd), mask (..., T, C) True
-        where a query may see a key -> (..., T, H, hd) in float32. Query head
-        h reads KV head h // (H / KV)."""
+    def _attend(self, q, k, v, mask, sink=None):
+        """q (..., T, H, dk), k (..., C, KV, dk), v (..., C, KV, dv), mask (...,
+        T, C) True where a query may see a key -> (..., T, H, dv) in float32.
+        Query head h reads KV head h // (H / KV). ``sink`` (H,) float32: one
+        logit a head with no value, a column beside the scores that joins the
+        softmax's denominator and is dropped after it."""
         kvh = k.shape[-2]
         g = q.shape[-2] // kvh
         qg = q.reshape(q.shape[:-2] + (kvh, g, q.shape[-1]))
         s = jnp.einsum("...tkgd,...ckd->...kgtc", qg, k,
                        preferred_element_type=jnp.float32) * self._scale()
         s = jnp.where(mask[..., None, None, :, :], s, NEG)
-        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        if sink is None:
+            p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        else:
+            col = jnp.broadcast_to(sink.astype(jnp.float32).reshape(kvh, g, 1, 1),
+                                   s.shape[:-1] + (1,))
+            p = jax.nn.softmax(jnp.concatenate([s, col], axis=-1),
+                               axis=-1)[..., :-1].astype(v.dtype)
         o = jnp.einsum("...kgtc,...ckd->...tkgd", p, v,
                        preferred_element_type=jnp.float32)
-        return o.reshape(q.shape)
+        return o.reshape(q.shape[:-1] + (v.shape[-1],))
 
     @staticmethod
     def _write_pages(pool, page, off, rows):
@@ -594,24 +625,37 @@ class PagedLM(GenerativeModel):
                             jnp.zeros(lead + (width,), jnp.float32)))
         return acc / l[..., None]
 
-    def _prefill_full(self, q, kp, vp, row, qpos, last):
-        """A full layer's attention of one tile, q (T, H, hd) at positions
+    def _pages_by_head(self, pool, pg, width: int):
+        """The pages ``pg`` (n,) of one pool by head, (KV, n x P, width); of a
+        block table ``pg`` (lanes, n), each lane's own: (KV, lanes, n x P,
+        width)."""
+        blk = self._by_head(jnp.take(pool, pg.reshape(-1), axis=1), width)
+        return blk if pg.ndim == 1 else blk.reshape(blk.shape[0], pg.shape[0], -1, width)
+
+    def _key_block(self, pools: tuple, pg, heads: Heads):
+        """The pages ``pg`` of a layer's pools -> (keys, values) by head
+        (``_pages_by_head``). ``pools``: the K and the V pool as ``_page_shape``
+        lays them; a family that keeps a key in parts joins them here."""
+        kp, vp = pools
+        return self._pages_by_head(kp, pg, heads.dk), self._pages_by_head(vp, pg, heads.dv)
+
+    def _prefill_full(self, q, pools, row, qpos, last, heads: Heads):
+        """A full layer's attention of one tile, q (T, H, dk) at positions
         ``qpos``, over its prompt's pages (block-table row ``row``) up to the
         tile's last live position ``last``: key blocks of ``KEY_BLOCK``
         positions, as many as that position needs (a traced count: a
         prompt's first tile reads one block, not the padded context), summed
-        with a running softmax in float32. Every row of the launch is in the
-        pages before any tile reads them."""
-        T, P = q.shape[0], kp.shape[2]
+        with a running softmax in float32 -> (T, H, dv). Every row of the
+        launch is in the pages before any tile reads them."""
+        T, P = q.shape[0], pools[0].shape[2]
         kb, rowp = self._key_blocks(row, P)
-        g = q.shape[1] // self.kv
-        qg = q.reshape(T, self.kv, g, self.hd)
+        g = q.shape[1] // heads.kv
+        qg = q.reshape(T, heads.kv, g, heads.dk)
         need = self._blocks_needed(last, P, row.shape[0])
 
         def block(j):
             pg = jax.lax.dynamic_slice(rowp, (j * kb,), (kb,))
-            kblk = self._by_head(jnp.take(kp, pg, axis=1))
-            vblk = self._by_head(jnp.take(vp, pg, axis=1))
+            kblk, vblk = self._key_block(pools, pg, heads)
             kpos = j * kb * P + jnp.arange(kb * P)
             see = kpos[None, :] <= qpos[:, None]
             s = jnp.einsum("tkgd,kcd->kgtc", qg, kblk,
@@ -620,14 +664,15 @@ class PagedLM(GenerativeModel):
                 "kgtc,kcd->kgtd", p.astype(vblk.dtype), vblk,
                 preferred_element_type=jnp.float32)
 
-        o = self._over_key_blocks(need, (self.kv, g, T), self.hd, block)
-        return o.transpose(2, 0, 1, 3).reshape(q.shape)
+        o = self._over_key_blocks(need, (heads.kv, g, T), heads.dv, block)
+        return o.transpose(2, 0, 1, 3).reshape(T, q.shape[1], heads.dv)
 
-    def _prefill_full_tiles(self, qt, kp, vp, t: dict):
-        """``_prefill_full`` tile by tile: qt (K, T, H, hd) over each tile's
-        own prompt's pages."""
+    def _prefill_full_tiles(self, qt, pools, t: dict, heads: Heads | None = None):
+        """``_prefill_full`` tile by tile: qt (K, T, H, dk) over each tile's
+        own prompt's pages -> (K, T, H, dv)."""
+        heads = heads or self._heads()
         return jax.lax.map(
-            lambda a: self._prefill_full(a[0], kp, vp, *a[1:]),
+            lambda a: self._prefill_full(a[0], pools, *a[1:], heads),
             (qt, t["rows"], t["qpos"], t["last"]))
 
     def _arm(self, params, state, new: dict, launch: Any, t: dict, x, extra: dict) -> dict:
@@ -677,7 +722,13 @@ class PagedLM(GenerativeModel):
         """One full layer's decode attention through the block table: q
         (b, H, hd), pools (KV / pack, pages, P, pack * hd), bt (b, pps) -> (b,
         H, hd) float32. On the TPU a kernel that reads live pages only;
-        elsewhere (tests, toys) a gather of the padded block table."""
+        elsewhere (tests, toys) a gather of the padded block table
+        (``_decode_gather``). The kernel is JAX's own, which takes ONE width
+        for queries, keys and values and K and V pools of one shape, and the
+        guard below sends every other pool to the gather: so a family whose
+        keys are wider than its values, or lie in parts, never comes here on
+        the chip: it brings a walk of its own and keeps the gather for the
+        CPU (``decoder_sink``)."""
         pack = kp.shape[-1] // self.hd
         on_tpu = jax.default_backend() == "tpu" and self.dtype == jnp.bfloat16 \
             and kp.shape[-1] % 128 == 0 and kp.shape[2] % 8 == 0
@@ -699,11 +750,15 @@ class PagedLM(GenerativeModel):
             o = paged_attention(qs, kp, vp, pos + 1, bt,
                                 pages_per_compute_block=ppcb).astype(jnp.float32)
             return self._own_part(o, self.kv, pack) if pack > 1 else o
-        b, (P, pps) = q.shape[0], (kp.shape[2], bt.shape[1])
-        kc = self._by_head(jnp.take(kp, bt.reshape(-1), axis=1)).reshape(
-            self.kv, b, pps * P, self.hd)
-        vc = self._by_head(jnp.take(vp, bt.reshape(-1), axis=1)).reshape(
-            self.kv, b, pps * P, self.hd)
+        return self._decode_gather(q, (kp, vp), bt, pos, self._heads())
+
+    def _decode_gather(self, q, pools, bt, pos, heads: Heads):
+        """Decode attention as plain XLA: every lane's PADDED block-table row
+        gathered from the pools, (KV, b, pps x P, width) of keys and of values
+        written and read a layer a step -> (b, H, dv) float32. Exact, and what
+        the CPU runs; no path for the chip at a cell's size."""
+        P, pps = pools[0].shape[2], bt.shape[1]
+        kc, vc = self._key_block(pools, bt, heads)
         mask = (jnp.arange(pps * P)[None, :] <= pos[:, None])[:, None, :]
         return self._attend(q[:, None], kc.transpose(1, 2, 0, 3),
                             vc.transpose(1, 2, 0, 3), mask)[:, 0]

@@ -55,6 +55,26 @@ for the three products at 64 query rows; the pages' copies hide behind them.
 So the pages a cell (``block_pages``, the caller's) trade cells for rows past
 a lane's end: 4 read fastest at contexts of hundreds, 8 and more at thousands.
 
+ATTENTION BY HEAD walks the same list (ISSUE 49): ``head_walk(q_pass, q_turn,
+kn, kr, v, work)`` is ``lane_walk``'s grid, work list and scalar prefetch for
+GROUPED heads whose keys are wider than their values and lie in parts. The
+pools AS THEY LIE: ``kn`` (KV, pages, P, dn) the part of a key that passes the
+rotary by, ``kr`` (KV / pack, pages, P, pack x dr) the part that turns, ``pack``
+KV heads side by side in a row of 128 lanes (2 at the 64 columns that turn of
+192), ``v`` (KV, pages, P, dv) the values, a pool of their own. ``q_pass`` (B, H,
+dn) and ``q_turn`` (B, H, pack x dr), each query head's turning part in its OWN
+KV head's place of the row and zeros in the others', so that its product with
+the whole row is its product with its own head's key (``paged_lm._pad_queries``) ->
+the
+normalised context (B, H, dv). A cell is one (lane, key block) item for EVERY
+KV head: it reads the block's pages of the three pools through ``pages``, all
+heads of a page in one block, holds the lane's H query rows (H / KV a KV head:
+query head h reads KV head h // (H / KV)), their running max, sum and (H, dv)
+float32 accumulator in VMEM from the lane's first block to its last, and
+writes the context once. Scores ``q_pass . kn + q_turn . kr`` a KV head, one
+softmax pass over all H rows, context ``p . v`` a KV head. Nothing of a lane
+exists in device memory but its pages.
+
 Off the TPU ``interpret=True`` runs the same code in the Pallas interpreter
 (tests); the families call it on the TPU alone.
 """
@@ -176,3 +196,92 @@ def lane_walk(q_lat: jax.Array, q_rope: jax.Array, ckv: jax.Array, kr: jax.Array
         interpret=interpret, name="lane_walk",
     )(work["lane"], work["block"], pages, work["last"], q_lat, q_rope, *([ckv] * kb),
       *([kr] * kb))
+
+
+# -- attention by head: grouped queries, keys in two parts, values of their own ------------
+
+def _head_kernel(lane_ref, block_ref, pages_ref, last_ref, qn_ref, qr_ref, *refs, scale: float,
+                 kb: int, kv: int, pack: int):
+    del pages_ref   # the index maps read it
+    kn_refs, kr_refs, v_refs, o_ref = refs[:kb], refs[kb:2 * kb], refs[2 * kb:3 * kb], refs[3 * kb]
+    m_ref, l_ref, acc_ref = refs[3 * kb + 1:]
+    P, dt = kn_refs[0].shape[1], qn_ref.dtype
+    g = qn_ref.shape[0] // kv
+    n = pl.program_id(0)
+    j, last = block_ref[n], last_ref[lane_ref[n]]
+    f32 = {"preferred_element_type": jnp.float32}
+    nt = (((1,), (1,)), ((), ()))
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    q_pass, q_turn = qn_ref[...], qr_ref[...]
+    s = []
+    for h in range(kv):   # KV head h: its g query rows over its own keys of every page
+        rows = slice(h * g, (h + 1) * g)
+        of_pages = [jax.lax.dot_general(q_pass[rows], kn_refs[i][h], nt, **f32)
+                    + jax.lax.dot_general(q_turn[rows], kr_refs[i][h // pack], nt, **f32)
+                    for i in range(kb)]
+        s.append(jnp.concatenate(of_pages, axis=1) if kb > 1 else of_pages[0])
+    s = jnp.concatenate(s, axis=0) if kv > 1 else s[0]            # (H, kb x P)
+    at = j * (kb * P) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(at <= last, s * scale, NEG)
+    m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    pv = [sum(jnp.dot(p[h * g:(h + 1) * g, i * P:(i + 1) * P].astype(dt), v_refs[i][h], **f32)
+              for i in range(kb)) for h in range(kv)]
+    acc_ref[...] = acc_ref[...] * alpha + (jnp.concatenate(pv, axis=0) if kv > 1 else pv[0])
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                                  l_ref.shape)
+
+    @pl.when((j + 1) * (kb * P) > last)
+    def _():
+        o_ref[...] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+def head_fits(page: int, heads: int, kv: int, dn: int, kr_lanes: int, dv: int, dtype) -> bool:
+    """Shapes ``head_walk`` takes: bfloat16, whole sublane tiles a page and a
+    KV head's group of query rows, every part's rows whole 128-lane tiles."""
+    return dtype == jnp.bfloat16 and page % 16 == 0 and heads % kv == 0 \
+        and (heads // kv) % 16 == 0 and dn % 128 == 0 and kr_lanes % 128 == 0 and dv % 128 == 0
+
+
+def head_walk(q_pass: jax.Array, q_turn: jax.Array, kn: jax.Array, kr: jax.Array, v: jax.Array,
+              work: dict, *, scale: float, interpret: bool = False) -> jax.Array:
+    b, h, dn = q_pass.shape
+    kv, n_pages, P = kn.shape[:3]
+    lanes, dv = kr.shape[3], v.shape[3]
+    pack = kv // kr.shape[0]
+    kb = work["pages"].shape[0] // work["lane"].shape[0]
+    pages = jnp.clip(work["pages"], 0, n_pages - 1)
+    page = lambda i: lambda n, lane, block, pages, last: (0, pages[n * kb + i], 0, 0)  # noqa: E731
+    by_lane = lambda n, lane, block, pages, last: (lane[n], 0, 0)  # noqa: E731
+    item = jnp.dtype(q_pass.dtype).itemsize
+    # the cell's blocks twice (the pipeline's two buffers), its scratch, and
+    # the float32 values of a block's scores and of its context
+    vmem = 2 * item * (h * (dn + lanes + dv) + kb * P * (kv * (dn + dv) + kv // pack * lanes)) \
+        + 4 * h * (dv + 256) + 4 * (3 * h * kb * P + 2 * h * dv)
+    return pl.pallas_call(
+        functools.partial(_head_kernel, scale=scale, kb=kb, kv=kv, pack=pack),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(work["items"],),
+            in_specs=[pl.BlockSpec((None, h, dn), by_lane), pl.BlockSpec((None, h, lanes), by_lane)]
+            + [pl.BlockSpec((kv, None, P, dn), page(i)) for i in range(kb)]
+            + [pl.BlockSpec((kv // pack, None, P, lanes), page(i)) for i in range(kb)]
+            + [pl.BlockSpec((kv, None, P, dv), page(i)) for i in range(kb)],
+            out_specs=pl.BlockSpec((None, h, dv), by_lane),
+            scratch_shapes=[pltpu.VMEM((h, 128), jnp.float32), pltpu.VMEM((h, 128), jnp.float32),
+                            pltpu.VMEM((h, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, dv), q_pass.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(vmem + (16 << 20), 100 << 20)),
+        interpret=interpret, name="head_walk",
+    )(work["lane"], work["block"], pages, work["last"], q_pass, q_turn, *([kn] * kb),
+      *([kr] * kb), *([v] * kb))
