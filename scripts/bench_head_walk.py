@@ -13,6 +13,10 @@ does not fit.
     chiprun -- python scripts/bench_head_walk.py
     python scripts/bench_head_walk.py --rehearse
 
+And ONE window layer's read of every lane's full ring (ISSUE 50, `ring_cases`;
+`--only ring`): the same kernel over the rings in place, beside the gather in
+XLA and a plain pass over the rings.
+
 This is where `decoder_sink.SinkDecoderServing.step_keys` comes from (PERF.md
 section 6, PR 49). One JSON line a case on stdout and in
 `chiprun_out/bench_head_walk/`. Off the TPU it walks a toy shape in the
@@ -71,6 +75,65 @@ def device_ms(fn, args: tuple, name: str, out_dir: str, iters: int) -> float | N
     return float(np.median(per_call)) if per_call else None
 
 
+def ring_cases(model, sz: dict, flops, peaks, out_dir: str, iters: int, on_tpu: bool, rng) -> list:
+    """ONE window layer's read of every lane's FULL ring (ISSUE 50): `head_walk`
+    in place through the ring index with the sink as its operand, the query
+    rows as they lie (8 a KV head: every row over each head's keys) and padded
+    to 16 a KV head by the caller (the kernel's other path), beside the gather
+    and softmax in XLA that every other backend takes, a plain pass over the
+    rings, and the least time by `flops.ring_read`."""
+    w = sz["by_kind"]["window"]
+    lanes, W = sz["slots"], sz["win_tokens"]
+    h, kv, dk, dv, dr = w["heads"], w["kv_heads"], w["dk"], w["dv"], 64
+    if not on_tpu:
+        lanes = 4
+    bf = jnp.bfloat16
+    rings = tuple(jnp.asarray(rng.standard_normal((lanes + 1, W, kv * width)), bf)
+                  for width in (dk - dr, dr, dv))
+    q = jnp.asarray(2.0 * rng.standard_normal((lanes, h, dk)), bf)
+    sink = jnp.asarray(rng.uniform(8, 12, h), jnp.float32)
+    ring = jnp.arange(1, lanes + 1, dtype=jnp.int32)
+    pos = jnp.asarray(rng.integers(W, 3000, lanes), jnp.int32)
+    ops, nbytes = flops.ring_read(dict(sz, kinds=["window"]), float(lanes), float(pos.sum()))
+    base = {"spread": "full rings", "lanes": lanes, "least_ms": None if not peaks else 1e3 * max(
+        ops / peaks["bf16_flops_per_s"], nbytes / peaks["hbm_bytes_per_s"])}
+    lines = []
+
+    def emit(case: str, ms) -> None:
+        lines.append({**base, "case": case, "ms": ms})
+        print(json.dumps(lines[-1]), flush=True)
+
+    def walk(q, rings, ring, pos, sink, rows: int):
+        g = h // kv
+        if rows > g:   # a KV head's group padded with rows of zeros, dropped after
+            q = jnp.pad(q.reshape(lanes, kv, g, dk), ((0, 0), (0, 0), (0, rows - g), (0, 0))) \
+                .reshape(lanes, kv * rows, dk)
+            sink = jnp.pad(sink.reshape(kv, g), ((0, 0), (0, rows - g))).reshape(-1)
+        o = la.head_walk(q[..., dr:], model._pad_queries(q[..., :dr], kv, 2), *rings,
+                         la.ring_work(ring, jnp.minimum(pos, W - 1)), scale=dk ** -0.5, kv=kv,
+                         sink=sink, interpret=not on_tpu)
+        return o.reshape(lanes, kv, rows, dv)[:, :, :g].reshape(lanes, h, dv)
+
+    for rows in (h // kv, 16):
+        fn = jax.jit(lambda *a, rows=rows: walk(*a, rows))
+        emit(f"head_walk of the rings in place, {rows} query rows a KV head",
+             device_ms(fn, (q, rings, ring, pos, sink), "head_walk", out_dir, iters))
+
+    def xla(q, rings, ring, pos, sink):
+        kn, kr, v = (jnp.take(x, ring, axis=0).reshape(lanes, W, kv, -1) for x in rings)
+        return model._attend(q[:, None], jnp.concatenate([kr, kn], axis=-1), v,
+                             jnp.ones((lanes, 1, W), bool), sink)[:, 0]
+
+    if not on_tpu:   # the CPU's backend has no bfloat16 product of these shapes
+        q, rings = q.astype(jnp.float32), tuple(x.astype(jnp.float32) for x in rings)
+    emit("the rings gathered and attended in XLA",
+         device_ms(jax.jit(xla), (q, rings, ring, pos, sink), None, out_dir, iters))
+    if on_tpu:
+        emit("a pass over the rings (read and written)",
+             device_ms(jax.jit(lambda r: tuple(x + 1 for x in r)), (rings,), None, out_dir, iters))
+    return lines
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default=os.path.join(
@@ -78,6 +141,7 @@ def main() -> None:
     ap.add_argument("--context", type=int, default=700, help="the fixed case's live positions")
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--only", choices=("global", "ring"), help="one kind's cases alone")
     args = ap.parse_args()
     on_tpu = jax.default_backend() == "tpu"
     if not on_tpu and not args.rehearse:
@@ -113,7 +177,7 @@ def main() -> None:
     contexts = {"fixed": np.full(lanes, min(args.context, pps * P)),
                 "mix": np.minimum(drawn.astype(np.int64), pps * P)}
     lines = []
-    for spread, ctx in contexts.items():
+    for spread, ctx in contexts.items() if args.only != "ring" else ():
         live = -(-ctx // P)
         n_pages = int(live.sum()) + 1
         kn = jnp.asarray(rng.standard_normal((kv, n_pages, P, dk - dr)), bf)
@@ -155,6 +219,8 @@ def main() -> None:
         gargs = (q[:few], kn, kr, vf, bt[:few], last[:few])
         emit(f"the gather of the padded table, {few} lanes",
              device_ms(gather, gargs, None, out_dir, args.iters), lanes=few)
+    if args.only != "global":
+        lines += ring_cases(model, sz, flops, peaks, out_dir, args.iters, on_tpu, rng)
     with open(os.path.join(out_dir, "cases.jsonl"), "w", encoding="utf-8") as f:
         f.write("".join(json.dumps(line) + "\n" for line in lines))
 

@@ -25,7 +25,9 @@ sys.path.insert(0, REPO)
 from benchmark.ssm_window import scope_map  # noqa: E402
 from benchmark.trace_reduce import DEVICE_PLANE, MODULES_LINE, OPS_LINE, op_name  # noqa: E402
 
-SCOPES = ("moe_experts", "moe_dispatch", "ssm_scan", "ssm_update", "mla_prefill", "mla_decode")
+# An inner scope before the one that holds it: a row is the first that matches.
+SCOPES = ("moe_experts", "moe_dispatch", "ssm_scan", "ssm_update", "mla_prefill", "mla_decode",
+          "attn_ring", "attn_full_walk", "attn_prefill", "attn_decode")
 CONTAINERS = ("while", "conditional", "call")
 
 

@@ -502,19 +502,9 @@ def test_a_steps_walk_is_one_kernel_call_an_attention_on_the_v5e_at_the_cells_wi
                 if " copy(" in ln and (f"{pages},128,512" in ln or f"{pages},64,128" in ln)]
 
 
-def test_a_global_layers_decode_is_one_kernel_call_on_the_v5e_at_the_cells_widths(
-        one_chip, tmp_path, monkeypatch):
-    """`ops/lane_attention.py` `head_walk` under `decoder_sink`'s step (ISSUE 49)
-    at the cell's sizes: 384 lanes of 64 query heads on 4 KV heads, keys in a
-    passing part of 128 and a turning part of 64 two heads a row, values of
-    128 in a pool of their own, 4,608 pages of 128 tokens, a block table of 24
-    pages, cells of 4 pages. The TPU branch is steered by the backend's name
-    here, in the test. Mosaic takes the kernel (all four KV heads of a page in
-    one block, the work list by scalar prefetch, its length a traced grid
-    bound); a global layer's walk of a step, with its rows' write into the
-    three pools, is ONE custom call and no `while`, nothing float32 by lane,
-    head and key is in the program, no gather of the padded table, and no copy
-    of a pool."""
+def _sink_cell(tmp_path, one_chip):
+    """`decoder_sink` at the cell's heads on a hidden size of 1,024 and the
+    cell's lanes, pages and rings, as shapes on the described chip."""
     import json
 
     from tpuserve.config import ModelConfig
@@ -534,39 +524,97 @@ def test_a_global_layers_decode_is_one_kernel_call_on_the_v5e_at_the_cells_width
                                   "config_file": str(path), "max_prompt_tokens": 2048,
                                   "max_new_tokens": 1024}))
     lanes, pages, P = 384, 4608, 128
-    pps, heads = model.kv_pages_per_slot(P), model._heads(0)
-    assert pps == 24 and heads == (4, 192, 128) and model.step_keys // P == 4
 
     def shape(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     sig = model.kv_page_signature(lanes, pages, P)
-    pools = tuple(shape(*sig[leaf][0].shape) for leaf in model.kv_page_leaves)
+    leaves = {leaf: shape(*sig[leaf][0].shape) for leaf in model.cache_leaves}
+    lane = {"bt": shape(lanes, model.kv_pages_per_slot(P), dtype=jnp.int32),
+            "pos": shape(lanes, dtype=jnp.int32), "live": shape(lanes, dtype=jnp.bool_),
+            "ring": shape(lanes, dtype=jnp.int32)}
+    return model, shape, leaves, lane
+
+
+def _compiled_text(fn, donate: int, *args) -> str:
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
+    try:
+        return jax.jit(fn, donate_argnums=(donate,)).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+
+
+def test_a_global_layers_decode_is_one_kernel_call_on_the_v5e_at_the_cells_widths(
+        one_chip, tmp_path, monkeypatch):
+    """`ops/lane_attention.py` `head_walk` under `decoder_sink`'s step (ISSUE 49)
+    at the cell's sizes: 384 lanes of 64 query heads on 4 KV heads, keys in a
+    passing part of 128 and a turning part of 64 two heads a row, values of
+    128 in a pool of their own, 4,608 pages of 128 tokens, a block table of 24
+    pages, cells of 4 pages. The TPU branch is steered by the backend's name
+    here, in the test. Mosaic takes the kernel (all four KV heads of a page in
+    one block, the work list by scalar prefetch, its length a traced grid
+    bound); a global layer's walk of a step, with its rows' write into the
+    three pools, is ONE custom call and no `while`, nothing float32 by lane,
+    head and key is in the program, no gather of the padded table, and no copy
+    of a pool."""
+    model, shape, leaves, lane = _sink_cell(tmp_path, one_chip)
+    lanes, pages, P = 384, 4608, 128
+    pps, heads = model.kv_pages_per_slot(P), model._heads(0)
+    assert pps == 24 and heads == (4, 192, 128) and model.step_keys // P == 4
+    pools = tuple(leaves[leaf] for leaf in model.kv_page_leaves)
     assert [p.shape for p in pools] == [(4, pages, P, 128), (2, pages, P, 128), (4, pages, P, 128)]
 
-    def attend(q, k, v, pools, bt, pos, live):
-        state = {"bt": bt, "kn": [pools[0]], "kr": [pools[1]], "vf": [pools[2]],
-                 "ring": jnp.zeros((lanes,), jnp.int32)}
+    def attend(q, k, v, pools, bt, pos, live, ring):
+        state = {"bt": bt, "kn": [pools[0]], "kr": [pools[1]], "vf": [pools[2]], "ring": ring}
         m = model._step_plan(state, live, pos)
         assert m["walk"] == "kernel"
         return model._attend_global(q, k, v, pools, m, heads)
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
-    try:
-        # the pools donated, as the engine donates the state they are part of
-        text = jax.jit(attend, donate_argnums=(3,)).lower(
-            shape(lanes, 64, 192), shape(lanes, 4, 192), shape(lanes, 4, 128), pools,
-            shape(lanes, pps, dtype=jnp.int32), shape(lanes, dtype=jnp.int32),
-            shape(lanes, dtype=jnp.bool_)).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
+    # the pools donated, as the engine donates the state they are part of
+    text = _compiled_text(attend, 3, shape(lanes, 64, 192), shape(lanes, 4, 192),
+                          shape(lanes, 4, 128), pools, *lane.values())
     calls = [ln for ln in text.split("\n") if " custom-call(" in ln and "tpu_custom_call" in ln]
     assert len(calls) == 1 and "head_walk" in calls[0]
     assert " while(" not in text
     for scores in (f"f32[{lanes},64,512]", "f32[64,512]", f"[4,{lanes},{pps * P},"):
         assert scores not in text
     assert not [ln for ln in text.split("\n") if " copy(" in ln and f"{pages},128,128" in ln]
+
+
+def test_a_window_layers_decode_reads_its_ring_in_place_on_the_v5e_at_the_cells_widths(
+        one_chip, tmp_path, monkeypatch):
+    """The same kernel over a window layer's rings (ISSUE 50) at the cell's
+    sizes: 384 lanes of 64 query heads on 8 KV heads (8 rows a head: every row
+    over each head's keys), 385 rings of 128 places, a place a row of 1,024,
+    512 and 1,024 values (the key's part that passes, its part that turns,
+    the values: 8 heads side by side), the learned sink an operand. Mosaic
+    takes it (a ring a block, a head's columns cut out of it on whole lane
+    tiles); a window layer's step, with its row's write into the three rings,
+    is ONE custom call: no gathered ring (nothing by lane, place and 1,536
+    columns), no float32 scores by lane, no `while`, and no copy of a ring."""
+    model, shape, leaves, lane = _sink_cell(tmp_path, one_chip)
+    lanes, heads = 384, model._heads(1)
+    assert heads == (8, 192, 128) and model.window == 128
+    rings = tuple(leaves[leaf] for leaf in ("kwn", "kwr", "vw"))
+    assert [r.shape for r in rings] == [(385, 128, 1024), (385, 128, 512), (385, 128, 1024)]
+
+    def attend(q, k, v, rings, sink, bt, pos, live, ring):
+        m = model._step_plan({"bt": bt, "kn": [leaves["kn"]], "ring": ring}, live, pos)
+        assert m["ring_walk"] == "kernel" and m["ring_work"]["pages"].shape == (lanes,)
+        return model._attend_ring(q, k, v, rings, m, sink, heads)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compiled_text(attend, 3, shape(lanes, 64, 192), shape(lanes, 8, 192),
+                          shape(lanes, 8, 128), rings, shape(64, dtype=jnp.float32),
+                          *lane.values())
+    calls = [ln for ln in text.split("\n") if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "head_walk" in calls[0]
+    assert " while(" not in text
+    for gathered in (f"[{lanes},128,1536]", f"[{lanes},128,8,192]", f"[{lanes},128,1024]",
+                     f"f32[{lanes},8,8,1,128]"):
+        assert gathered not in text
+    assert not [ln for ln in text.split("\n") if " copy(" in ln and "[385,128," in ln]
 
 
 def test_decode_over_packed_pages_compiles_for_the_v5e_at_the_cells_widths(one_chip, tmp_path,

@@ -375,30 +375,24 @@ class DecoderServing(PagedLM):
             return self._decode_full(q, kp, vp, m["bt"], m["pos"]), kp, vp
         return self._prefill_full_tiles(qt, (kp, vp), t).reshape(q.shape), kp, vp
 
-    def _attend_window(self, q, k, v, rk, rv, m: dict, sink=None):
+    def _attend_window(self, q, k, v, rk, rv, m: dict):
         """A window layer's attention in either phase -> (o (T, H, dv), the
         two rings). A step writes its row and reads its ring (a free lane
         reads ring 0, which every free lane writes: its result is discarded);
         a launch reads what the rings held before it and itself, then writes.
-        A ring is (slots + 1, W, KV, width), or the same flattened over heads
-        (a family whose head is no whole number of 128 lanes): rows go in as
-        the ring lies, and what is read comes back by head. ``sink``:
-        ``_attend``'s."""
+        A ring is (slots + 1, W, KV, width)."""
         t, w_ring, roff = m["t"], m["w_ring"], m["roff"]
 
         def put(ring, rows):
-            return ring.at[w_ring, roff].set(rows.reshape(rows.shape[:1] + ring.shape[2:]))
-
-        def held(ring, at, rows):
-            return jnp.take(ring, at, axis=0).reshape(at.shape + ring.shape[1:2] + rows.shape[1:])
+            return ring.at[w_ring, roff].set(rows)
 
         if t is None:
             rk, rv = put(rk, k), put(rv, v)
-            return self._attend(q[:, None], held(rk, w_ring, k), held(rv, w_ring, v),
-                                m["mask_win"], sink)[:, 0], rk, rv
+            return self._attend(q[:, None], jnp.take(rk, w_ring, axis=0),
+                                jnp.take(rv, w_ring, axis=0), m["mask_win"])[:, 0], rk, rv
         o = self._prefill_window(q.reshape((t["K"], t["T"]) + q.shape[1:]), k, v,
-                                 held(rk, t["rings"], k), held(rv, t["rings"], v),
-                                 t["qpos"], m["rpos"], m["pos"], m["own"], sink)
+                                 jnp.take(rk, t["rings"], axis=0), jnp.take(rv, t["rings"], axis=0),
+                                 t["qpos"], m["rpos"], m["pos"], m["own"])
         rk, rv = put(rk, k), put(rv, v)
         return o.reshape(q.shape[:-1] + o.shape[-1:]), rk, rv
 

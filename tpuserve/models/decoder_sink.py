@@ -42,26 +42,35 @@ pages, P, pack x dr) the part that turns, ``pack`` KV heads side by side in a
 row (``paged_lm._kv_pack``: two at 64 columns), and ``vf`` (KV, pages, P, dv):
 dk + dv values a token a KV head and no more (a 192-wide leaf would be padded
 to 256 on the device). A window layer keeps a slot's last ``sliding_window``
-positions in one ring a slot, flattened over heads: ``kw`` (slots + 1, W, KV x
-dk), ``vw`` (slots + 1, W, KV x dv).
+positions in one ring a slot, in three leaves as a key lies in its two parts,
+a place a ROW with its heads side by side: ``kwn`` (slots + 1, W, KV x (dk -
+dr)), ``kwr`` (slots + 1, W, KV x dr), ``vw`` (slots + 1, W, KV x dv): a token
+is ONE row of each to write (laid out by head as the pools are, a token was 20
+rows, and the scatter's cost is by row: 5.8 ms a prefill launch and 2.7 ms a
+step on the chip, ISSUE 50).
 
-WHERE A GLOBAL LAYER'S DECODE WALKS (chosen when ``step`` is traced from the
-backend, the dtype and the shapes; no option). On the TPU in bfloat16 ONE call
-of ``ops/lane_attention.py`` ``head_walk`` a global layer a step: every live
-lane over its OWN key blocks by the step's work list (``_step_plan``: built
-once for all global layers), all KV heads of a page in one cell, the softmax's
-state and the accumulator in fast memory from a lane's first block to its
-last; JAX's stock paged-attention kernel takes neither keys wider than values
-nor a key in parts (``paged_lm._decode_full``). Everywhere else the gather of
-the padded block table (``_decode_gather``), exact. Prefill walks key blocks
-in XLA (``_prefill_full``); the window layers are ``decoder``'s ring plans in
-plain XLA, both phases. ``attn_walks_total{phase=,walk=kernel|xla}`` counts the
-global layers' lanes (a launch's tiles) by which.
+WHERE A STEP ATTENDS (chosen when ``step`` is traced from the backend, the
+dtype and the shapes, a kind at a time: ``_walk``; no option). On the TPU in
+bfloat16 ONE call of ``ops/lane_attention.py`` ``head_walk`` a layer a step. A
+global layer: every live lane over its OWN key blocks by the step's work list
+(``_step_plan``: built once for all global layers), all KV heads of a page in
+one cell, the softmax's state and the accumulator in fast memory from a lane's
+first block to its last; JAX's stock paged-attention kernel takes neither keys
+wider than values nor a key in parts (``paged_lm._decode_full``). A window
+layer (ISSUE 50): every lane's ring IN PLACE through its ring index, one cell a
+lane (``ring_work``), the learned sink an operand that joins the denominator
+where the cell divides, the 8 query rows a KV head taken together. Everywhere
+else the gather of the padded block table (``_decode_gather``) and of the
+rings (``_attend_ring``), exact. Prefill is plain XLA for both kinds: a global
+layer walks key blocks (``_prefill_full``), a window layer reads its ring and
+the launch's own rows (``decoder``'s ``_prefill_window``).
+``attn_walks_total{phase=,walk=kernel|xla}`` counts every attention layer's
+lanes (a launch's tiles) by which.
 
 In a trace: ``attn_decode`` is every attention mixer of a step from the three
 projections to ``W_o``'s product (``attn_prefill`` in a launch); inside it
 ``attn_full_walk`` a global layer's page writes and walk, ``attn_ring`` a window
-layer's ring write, read and softmax.
+layer's ring write and read (a step: the kernel's call).
 
 NOT SERVED: multi-token-prediction layers and input towers (vision, audio);
 requests carry token ids. Requests, weights by recipe, the share and the
@@ -89,10 +98,11 @@ DEFAULT_SCALES = {**dec.DEFAULT_SCALES, "router": 1.0, "router_bias": 0.02,
                   "sink_low": 8.0, "sink_high": 12.0}
 KINDS = ("full_attention", "sliding_attention")
 WALKS = ("kernel", "xla")
+RING_LEAVES = ("kwn", "kwr", "vw")   # a window layer's ring: a key's two parts, the values
 
 
 class SinkDecoderServing(dec.DecoderServing):
-    cache_leaves = ("kn", "kr", "vf", "kw", "vw")
+    cache_leaves = ("kn", "kr", "vf", "kwn", "kwr", "vw")
     kv_page_leaves = ("kn", "kr", "vf")
     # ``decoder``'s columns, then the global layers' key rows: the live rows
     # they had to see, the rows of the blocks they read (whole key blocks; the
@@ -101,8 +111,7 @@ class SinkDecoderServing(dec.DecoderServing):
         *dec.DecoderServing.COLUMNS,
         Column(counted("attended"), series("attn_rows_attended_total")),
         Column(counted("walked"), series("attn_rows_walked_total")),
-        *(Column(lambda model, stats, counts, walk=walk:
-                 counts["walks"] if counts["walk"] == walk else 0,
+        *(Column(lambda model, stats, counts, walk=walk: counts["walks"][walk],
                  series("attn_walks_total", f",walk={walk}")) for walk in WALKS))
     scoring = "sigmoid"
     # Key positions a cell of the step's kernel walks: a lane reads whole cells,
@@ -200,12 +209,14 @@ class SinkDecoderServing(dec.DecoderServing):
             return [S(self._page_shape(pages, page_tokens, g.kv, width), self.dtype)
                     for _ in self.full_layers]
 
-        def ring(width):
+        def ring(width):   # a slot's ring: ``window`` places, a place a row with its heads
             return [S((slots + 1, self.window, w.kv * width), self.dtype)
                     for _ in self.win_layers]
 
+        wr = self.turning.get(KINDS[1], 0)
         return {"kn": page(g.dk - dr), "kr": page(dr), "vf": page(g.dv),
-                "kw": ring(w.dk), "vw": ring(w.dv), "ring": S((slots,), jnp.int32)}
+                "kwn": ring(w.dk - wr), "kwr": ring(wr), "vw": ring(w.dv),
+                "ring": S((slots,), jnp.int32)}
 
     # -- the global layers' walk ------------------------------------------------------
     def _key_block(self, pools: tuple, pg, heads: Heads):
@@ -217,33 +228,40 @@ class SinkDecoderServing(dec.DecoderServing):
                                  self._pages_by_head(kn, pg, heads.dk - dr)], axis=-1),
                 self._pages_by_head(vf, pg, heads.dv))
 
-    def _walk(self, pools: tuple) -> str:
-        """Where a step's global layers walk, chosen when the step is traced:
-        ``kernel`` on the TPU at shapes ``head_walk`` takes, else ``xla``."""
-        if jax.default_backend() != "tpu" or not pools[0]:  # tps-ok[TPS503]: at trace time
+    def _walk(self, kind: str, page: int) -> str:
+        """Where a step's layers of ``kind`` attend, chosen when the step is
+        traced: ``kernel`` on the TPU at shapes ``head_walk`` takes (pages of
+        ``page`` positions: a window layer's ring is one), else ``xla``."""
+        if jax.default_backend() != "tpu" or kind not in self.layer_types:  # tps-ok[TPS503]
             return "xla"
-        kn, kr, vf = (p[0] for p in pools)
-        g = self.by_kind[KINDS[0]]
-        fits = la.head_fits(kn.shape[2], self.heads[self.full_layers[0]], g.kv, kn.shape[3],
-                            kr.shape[3], vf.shape[3], self.dtype)
+        i, h, dr = self.layer_types.index(kind), self.by_kind[kind], self.turning[kind]
+        fits = la.head_fits(page, self.heads[i], h.kv, h.dk - dr, self._kv_pack(h.kv, dr) * dr,
+                            h.dv, self.dtype)
         return "kernel" if fits else "xla"
 
     def _prefill_plan(self, state, launch, t: dict) -> dict:
-        return {**super()._prefill_plan(state, launch, t), "walk": "xla",
+        return {**super()._prefill_plan(state, launch, t), "walk": "xla", "ring_walk": "xla",
                 "P": self._page_tokens(state), "pps": state["bt"].shape[1]}
 
     def _step_plan(self, state, live, pos) -> dict:
-        """And the global layers' walk, chosen once for all of them, with the
-        kernel's work list: each live lane as far as ITS position needs; a
-        lane that is not live walks one block and its result is discarded."""
+        """And where each kind attends, chosen once for all its layers, with
+        the kernel's work lists. The global layers': each live lane as far as
+        ITS position needs; a lane that is not live walks one block and its
+        result is discarded. The window layers': one item a lane, its ring (a
+        page of ``window`` places, of which the first ``pos + 1`` hold a key
+        until the ring is full)."""
         m = super()._step_plan(state, live, pos)
-        walk = self._walk(tuple(state[leaf] for leaf in self.kv_page_leaves))
         P, (b, pps) = self._page_tokens(state), m["bt"].shape
-        if walk == "kernel":
+        m["walk"], m["ring_walk"] = self._walk(KINDS[0], P), self._walk(KINDS[1], self.window)
+        m["work"], m["walked"] = None, b * pps * P
+        if m["walk"] == "kernel":
             kb = max(1, min(self.step_keys // P, pps))
-            work = la.work_list(jnp.where(live, pos, 0), m["bt"], P, kb)
-            return {**m, "walk": walk, "work": work, "walked": work["items"] * kb * P}
-        return {**m, "walk": walk, "work": None, "walked": b * pps * P}
+            m["work"] = la.work_list(jnp.where(live, pos, 0), m["bt"], P, kb)
+            m["walked"] = m["work"]["items"] * kb * P
+        if m["ring_walk"] == "kernel":
+            m["ring_work"] = la.ring_work(
+                m["w_ring"], jnp.where(live, jnp.minimum(pos, self.window - 1), 0))
+        return m
 
     def _attend_global(self, q, k, v, pools: tuple, m: dict, heads: Heads):
         """A global layer's attention in either phase: the launch's rows into
@@ -264,38 +282,79 @@ class SinkDecoderServing(dec.DecoderServing):
             return o.astype(jnp.float32), pools
         return self._decode_gather(q, pools, m["bt"], m["pos"], heads), pools
 
+    def _attend_ring(self, q, k, v, rings: tuple, m: dict, sink, heads: Heads):
+        """A window layer's attention in either phase -> (o (T, H, dv), the
+        three rings: a key's part that passes, its part that turns, the
+        values, each (slots + 1, W, KV x width), a place a row with its heads
+        side by side, so a token is ONE row of each to write). A step writes
+        its row and reads its ring: in ``head_walk`` where the plan says so, IN
+        PLACE through the lane's ring index with the sink as its operand, else
+        gathered in XLA (a free lane reads ring 0, which every free lane
+        writes: its result is discarded). A launch reads what the rings held
+        before it and itself, then writes."""
+        dr, t, w_ring, roff = self.turning[KINDS[1]], m["t"], m["w_ring"], m["roff"]
+        new = (k[..., dr:], k[..., :dr], v)
+
+        def put():
+            return tuple(ring.at[w_ring, roff].set(rows.reshape(rows.shape[0], -1))
+                         for ring, rows in zip(rings, new))
+
+        def held(rings, at):   # keys (n, W, KV, dk) as a query's columns lie, values (n, W, KV, dv)
+            by_head = at.shape + (self.window, heads.kv, -1)
+            kn, kr, vw = (jnp.take(ring, at, axis=0).reshape(by_head) for ring in rings)
+            return jnp.concatenate([kr, kn], axis=-1), vw
+
+        if t is not None:
+            o = self._prefill_window(q.reshape((t["K"], t["T"]) + q.shape[1:]), k, v,
+                                     *held(rings, t["rings"]), t["qpos"], m["rpos"], m["pos"],
+                                     m["own"], sink)
+            return o.reshape(q.shape[:-1] + o.shape[-1:]), put()
+        rings = put()
+        if m["ring_walk"] == "kernel":
+            q_turn = self._pad_queries(q[..., :dr], heads.kv, self._kv_pack(heads.kv, dr))
+            o = la.head_walk(q[..., dr:], q_turn, *rings, m["ring_work"], scale=self._scale(),
+                             kv=heads.kv, sink=sink)
+            return o.astype(jnp.float32), rings
+        return self._attend(q[:, None], *held(rings, w_ring), m["mask_win"], sink)[:, 0], rings
+
     # -- the layer, its counts ---------------------------------------------------------
     def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
-        kind = self.layer_types[i]
+        full = self.layer_types[i] == KINDS[0]
+        leaves, j = (self.kv_page_leaves, self.full_layers.index(i)) if full \
+            else (RING_LEAVES, self.win_layers.index(i))
         with jax.named_scope("attn_decode" if m["t"] is None else "attn_prefill"):
             q, k, v, _ = self._qkv(lp, i, rms_norm(x, lp["norm1"], self.eps), m["pos"])
-            if kind == KINDS[0]:
-                j = self.full_layers.index(i)
+            held = tuple(c[leaf][j] for leaf in leaves)
+            if full:
                 with jax.named_scope("attn_full_walk"):
-                    o, (c["kn"][j], c["kr"][j], c["vf"][j]) = self._attend_global(
-                        q, k, v, (c["kn"][j], c["kr"][j], c["vf"][j]), m, self._heads(i))
+                    o, held = self._attend_global(q, k, v, held, m, self._heads(i))
             else:
-                j = self.win_layers.index(i)
                 with jax.named_scope("attn_ring"):
-                    o, c["kw"][j], c["vw"][j] = self._attend_window(
-                        q, k, v, c["kw"][j], c["vw"][j], m, lp.get("sink"))
+                    o, held = self._attend_ring(q, k, v, held, m, lp.get("sink"),
+                                                  self._heads(i))
+            for leaf, pool in zip(leaves, held):
+                c[leaf][j] = pool
             y = self._attn_out(lp, o, None)
         x = x + y.astype(self.dtype)
         y, st = self._ffn(lp, i, rms_norm(x, lp["norm2"], self.eps), m["live"])
         return x + y.astype(self.dtype), st
 
     def _counts(self, m: dict) -> dict:
-        """And the global layers' key rows (attended, walked) and lanes or
-        tiles, all of them together, with the walk they took."""
+        """And the global layers' key rows (attended, walked), and every
+        attention layer's lanes (a launch's tiles) by the walk its kind took:
+        the global layers' over their pages and the window layers' of their
+        rings."""
         c, t, n = super()._counts(m), m["t"], len(self.full_layers)
+        each = jnp.sum(m["live"] if t is None else t["has"])
+        c = {**c, "walks": {walk: each * ((m["walk"] == walk) * n
+                                          + (m["ring_walk"] == walk) * len(self.win_layers))
+                            for walk in WALKS}}
         if t is None:
-            return {**c, "walk": m["walk"], "attended": c["context"] * n,
-                    "walked": m["walked"] * n, "walks": jnp.sum(m["live"]) * n}
+            return {**c, "attended": c["context"] * n, "walked": m["walked"] * n}
         P, pps = m["P"], m["pps"]
         blocks = jnp.sum(jnp.where(t["has"], self._blocks_needed(t["last"], P, pps), 0))
-        return {**c, "walk": m["walk"], "walks": jnp.sum(t["has"]) * n,
-                "attended": jnp.sum(jnp.where(m["length"] > 0, m["start"] + m["length"], 0)) * n,
-                "walked": blocks * self._block_pages(P, pps) * P * n}
+        attended = jnp.sum(jnp.where(m["length"] > 0, m["start"] + m["length"], 0))
+        return {**c, "attended": attended * n, "walked": blocks * self._block_pages(P, pps) * P * n}
 
 
 def create(cfg: ModelConfig) -> Any:

@@ -75,6 +75,27 @@ writes the context once. Scores ``q_pass . kn + q_turn . kr`` a KV head, one
 softmax pass over all H rows, context ``p . v`` a KV head. Nothing of a lane
 exists in device memory but its pages.
 
+THE SAME CELL READS A WINDOW LAYER'S RING IN PLACE (ISSUE 50). A slot's ring is
+one page of ``W`` positions, a position a ROW with its KV heads side by side
+(``kn`` (slots + 1, W, KV x dn), ``kr`` (slots + 1, W, KV x dr), ``v`` (slots +
+1, W, KV x dv): a token is one row of each to write; pools that lie so are told
+by their three dimensions and ``kv``). ``ring_work`` is the work list: ONE item
+a lane, its ring's index for the page, ``min(pos, W - 1)`` for the last place
+that holds a key; places lie in a ring in no order, which a softmax does not
+mind, so nothing is rotated or copied. A cell reads a ring of each leaf as one
+block and cuts a head's columns out of it on whole lane tiles (``part``). THE
+SINK (``sink`` (H,) float32) is one more operand where there is one: ``exp(sink
+- m)`` joins the sum where a lane's last block divides, and the numerator gets
+nothing. QUERY GROUPS OF 8 ROWS (64 query heads on 8 KV heads) are no whole
+sublane tile of bfloat16: there EVERY query row goes over each KV head's keys
+and keeps its own head's scores, and the context sums each head's values under
+that head's rows' weights alone (``_split`` says which from the shapes: groups
+of 16 rows take the path they took, to the bit). On the chip (v5e, 384 lanes of
+full rings, ``scripts/bench_head_walk.py --only ring``, ISSUE 50): 0.44 ms a
+layer alone and 0.385 in the cell's step, against 0.38 for the same rings with
+each group padded to 16 rows by the caller, 0.72 for a plain pass that reads
+and writes the rings, and 1.97 for the gather and softmax in XLA.
+
 Off the TPU ``interpret=True`` runs the same code in the Pallas interpreter
 (tests); the families call it on the TPU alone.
 """
@@ -201,16 +222,26 @@ def lane_walk(q_lat: jax.Array, q_rope: jax.Array, ckv: jax.Array, kr: jax.Array
 # -- attention by head: grouped queries, keys in two parts, values of their own ------------
 
 def _head_kernel(lane_ref, block_ref, pages_ref, last_ref, qn_ref, qr_ref, *refs, scale: float,
-                 kb: int, kv: int, pack: int):
+                 kb: int, kv: int, pack: int, split: bool, sunk: bool):
     del pages_ref   # the index maps read it
-    kn_refs, kr_refs, v_refs, o_ref = refs[:kb], refs[kb:2 * kb], refs[2 * kb:3 * kb], refs[3 * kb]
-    m_ref, l_ref, acc_ref = refs[3 * kb + 1:]
-    P, dt = kn_refs[0].shape[1], qn_ref.dtype
+    kn_refs, kr_refs, v_refs = refs[:kb], refs[kb:2 * kb], refs[2 * kb:3 * kb]
+    sink_ref = refs[3 * kb] if sunk else None
+    o_ref, m_ref, l_ref, acc_ref = refs[3 * kb + sunk:]
+    P, dt = kn_refs[0].shape[-2], qn_ref.dtype
     g = qn_ref.shape[0] // kv
     n = pl.program_id(0)
     j, last = block_ref[n], last_ref[lane_ref[n]]
     f32 = {"preferred_element_type": jnp.float32}
     nt = (((1,), (1,)), ((), ()))
+
+    def part(ref, h: int, like):
+        """Head (or packed row of heads) ``h`` of a page's block, (P, width):
+        a block by head is (heads, P, width); a block of rows with their
+        heads side by side, (P, heads x width), is cut on whole lane tiles."""
+        if len(ref.shape) == 3:
+            return ref[h]
+        width = like.shape[-1]
+        return ref[:, h * width:(h + 1) * width]
 
     @pl.when(j == 0)
     def _():
@@ -219,62 +250,128 @@ def _head_kernel(lane_ref, block_ref, pages_ref, last_ref, qn_ref, qr_ref, *refs
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q_pass, q_turn = qn_ref[...], qr_ref[...]
-    s = []
-    for h in range(kv):   # KV head h: its g query rows over its own keys of every page
-        rows = slice(h * g, (h + 1) * g)
-        of_pages = [jax.lax.dot_general(q_pass[rows], kn_refs[i][h], nt, **f32)
-                    + jax.lax.dot_general(q_turn[rows], kr_refs[i][h // pack], nt, **f32)
-                    for i in range(kb)]
-        s.append(jnp.concatenate(of_pages, axis=1) if kb > 1 else of_pages[0])
-    s = jnp.concatenate(s, axis=0) if kv > 1 else s[0]            # (H, kb x P)
+    if split:   # KV head h: its g query rows over its own keys of every page
+        s = []
+        for h in range(kv):
+            rows = slice(h * g, (h + 1) * g)
+            of_pages = [jax.lax.dot_general(q_pass[rows], part(kn_refs[i], h, q_pass), nt, **f32)
+                        + jax.lax.dot_general(q_turn[rows], part(kr_refs[i], h // pack, q_turn),
+                                              nt, **f32)
+                        for i in range(kb)]
+            s.append(jnp.concatenate(of_pages, axis=1) if kb > 1 else of_pages[0])
+        s = jnp.concatenate(s, axis=0) if kv > 1 else s[0]            # (H, kb x P)
+    else:       # EVERY query row over KV head h's keys, and a row keeps its own head's
+        own = jax.lax.broadcasted_iota(jnp.int32, (kv * g, kb * P), 0) // g
+        turned = [[jax.lax.dot_general(q_turn, part(kr_refs[i], r, q_turn), nt, **f32)
+                   for i in range(kb)]
+                  for r in range(kv // pack)]   # a row of ``pack`` heads once: ``q_turn``'s zeros
+        s = None
+        for h in range(kv):
+            of_pages = [jax.lax.dot_general(q_pass, part(kn_refs[i], h, q_pass), nt, **f32)
+                        + turned[h // pack][i] for i in range(kb)]
+            s_h = jnp.concatenate(of_pages, axis=1) if kb > 1 else of_pages[0]
+            s = s_h if s is None else jnp.where(own == h, s_h, s)
     at = j * (kb * P) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(at <= last, s * scale, NEG)
     m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
-    pv = [sum(jnp.dot(p[h * g:(h + 1) * g, i * P:(i + 1) * P].astype(dt), v_refs[i][h], **f32)
-              for i in range(kb)) for h in range(kv)]
-    acc_ref[...] = acc_ref[...] * alpha + (jnp.concatenate(pv, axis=0) if kv > 1 else pv[0])
+    if split:
+        pv = [sum(jnp.dot(p[h * g:(h + 1) * g, i * P:(i + 1) * P].astype(dt),
+                          part(v_refs[i], h, o_ref), **f32)
+                  for i in range(kb)) for h in range(kv)]
+    else:
+        pv = [sum(jnp.dot(jnp.where(own[:, :P] == h, p[:, i * P:(i + 1) * P], 0.0).astype(dt),
+                          part(v_refs[i], h, o_ref), **f32) for i in range(kb) for h in range(kv))]
+    acc_ref[...] = acc_ref[...] * alpha + (jnp.concatenate(pv, axis=0) if len(pv) > 1 else pv[0])
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
                                   l_ref.shape)
 
     @pl.when((j + 1) * (kb * P) > last)
     def _():
-        o_ref[...] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+        acc, total = acc_ref[...], l_ref[:, :1]
+        if sunk:   # one more term of the denominator, nothing of the numerator
+            total = total + jnp.exp(sink_ref[:, :1] - m_ref[:, :1])
+        o_ref[...] = (acc / total).astype(o_ref.dtype)
+
+
+def _split(heads: int, kv: int) -> bool:
+    """Whether a KV head's group of query rows is whole sublane tiles (16 rows
+    of bfloat16), so that a cell takes each group's rows apart. Where it is
+    not (8 rows a KV head), EVERY row goes over each KV head's keys and keeps
+    its own head's scores: the matrix unit loads a block of keys once whatever
+    the rows that pass it, so the rows of the other groups cost it little, and
+    no slice cuts a tile."""
+    return (heads // kv) % 16 == 0
 
 
 def head_fits(page: int, heads: int, kv: int, dn: int, kr_lanes: int, dv: int, dtype) -> bool:
     """Shapes ``head_walk`` takes: bfloat16, whole sublane tiles a page and a
-    KV head's group of query rows, every part's rows whole 128-lane tiles."""
+    KV head's group of query rows (``_split``) or else all the query rows
+    together, every part's rows whole 128-lane tiles."""
     return dtype == jnp.bfloat16 and page % 16 == 0 and heads % kv == 0 \
-        and (heads // kv) % 16 == 0 and dn % 128 == 0 and kr_lanes % 128 == 0 and dv % 128 == 0
+        and (_split(heads, kv) or heads % 16 == 0) \
+        and dn % 128 == 0 and kr_lanes % 128 == 0 and dv % 128 == 0
+
+
+def ring_work(ring: jax.Array, last: jax.Array) -> dict:
+    """``work_list``'s dict for lanes that each read ONE block and no more: a
+    window layer's ring, a page of ``W`` positions a slot. ``ring`` (B,) the
+    page a lane reads (the sentinel for a lane that is not live), ``last`` (B,)
+    the last place of it that holds a key (0: one key, a finite row). One item
+    a lane, in the lanes' order; positions lie in a ring in no order, which a
+    softmax does not mind."""
+    b = ring.shape[0]
+    lane = jnp.arange(b, dtype=jnp.int32)
+    return {"lane": lane, "block": jnp.zeros_like(lane), "pages": ring.astype(jnp.int32),
+            "last": last.astype(jnp.int32), "items": jnp.int32(b)}
 
 
 def head_walk(q_pass: jax.Array, q_turn: jax.Array, kn: jax.Array, kr: jax.Array, v: jax.Array,
-              work: dict, *, scale: float, interpret: bool = False) -> jax.Array:
+              work: dict, *, scale: float, kv: int | None = None, sink: jax.Array | None = None,
+              interpret: bool = False) -> jax.Array:
+    """``sink`` (H,) float32, or None: a learned logit a query head that joins
+    the softmax's denominator where a lane's last block divides, ``exp(sink -
+    m)`` beside the keys' sum, and adds nothing to the context. An operand
+    only where there is one: without it the kernel is what it was.
+
+    ``kv``: the KV heads of pools that hold a position a ROW with its heads
+    side by side, ``kn`` (pages, P, KV x dn), ``kr`` (pages, P, KV x dr), ``v``
+    (pages, P, KV x dv): a window layer's rings, a page a slot, written a row
+    a token. A cell reads a page of each as ONE block and cuts a head's
+    columns out of it on whole lane tiles; all else is the same cell."""
     b, h, dn = q_pass.shape
-    kv, n_pages, P = kn.shape[:3]
-    lanes, dv = kr.shape[3], v.shape[3]
-    pack = kv // kr.shape[0]
+    lanes, flat = q_turn.shape[2], kn.ndim == 3
+    if flat:
+        (n_pages, P), dv = kn.shape[:2], v.shape[2] // kv
+        pack = kv * lanes // kr.shape[2]
+        blocks = [(None, P, x.shape[2]) for x in (kn, kr, v)]
+    else:
+        (kv, n_pages, P), dv = kn.shape[:3], v.shape[3]
+        pack = kv // kr.shape[0]
+        blocks = [(kv, None, P, dn), (kv // pack, None, P, lanes), (kv, None, P, dv)]
+    heads = () if flat else (0,)   # a block by head: every head of its page
+    page = lambda i: lambda n, lane, block, pages, last: (*heads, pages[n * kb + i], 0, 0)  # noqa: E731
     kb = work["pages"].shape[0] // work["lane"].shape[0]
     pages = jnp.clip(work["pages"], 0, n_pages - 1)
-    page = lambda i: lambda n, lane, block, pages, last: (0, pages[n * kb + i], 0, 0)  # noqa: E731
     by_lane = lambda n, lane, block, pages, last: (lane[n], 0, 0)  # noqa: E731
     item = jnp.dtype(q_pass.dtype).itemsize
     # the cell's blocks twice (the pipeline's two buffers), its scratch, and
     # the float32 values of a block's scores and of its context
     vmem = 2 * item * (h * (dn + lanes + dv) + kb * P * (kv * (dn + dv) + kv // pack * lanes)) \
         + 4 * h * (dv + 256) + 4 * (3 * h * kb * P + 2 * h * dv)
+    sunk = sink is not None
+    sinks = [jnp.broadcast_to(sink.astype(jnp.float32)[:, None], (h, 128))] if sunk else []
     return pl.pallas_call(
-        functools.partial(_head_kernel, scale=scale, kb=kb, kv=kv, pack=pack),
+        functools.partial(_head_kernel, scale=scale, kb=kb, kv=kv, pack=pack,
+                          split=_split(h, kv), sunk=sunk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(work["items"],),
             in_specs=[pl.BlockSpec((None, h, dn), by_lane), pl.BlockSpec((None, h, lanes), by_lane)]
-            + [pl.BlockSpec((kv, None, P, dn), page(i)) for i in range(kb)]
-            + [pl.BlockSpec((kv // pack, None, P, lanes), page(i)) for i in range(kb)]
-            + [pl.BlockSpec((kv, None, P, dv), page(i)) for i in range(kb)],
+            + [pl.BlockSpec(block, page(i)) for block in blocks for i in range(kb)]
+            + [pl.BlockSpec((h, 128), lambda n, lane, block, pages, last: (0, 0))] * sunk,
             out_specs=pl.BlockSpec((None, h, dv), by_lane),
             scratch_shapes=[pltpu.VMEM((h, 128), jnp.float32), pltpu.VMEM((h, 128), jnp.float32),
                             pltpu.VMEM((h, dv), jnp.float32)]),
@@ -284,4 +381,4 @@ def head_walk(q_pass: jax.Array, q_turn: jax.Array, kn: jax.Array, kr: jax.Array
             vmem_limit_bytes=min(vmem + (16 << 20), 100 << 20)),
         interpret=interpret, name="head_walk",
     )(work["lane"], work["block"], pages, work["last"], q_pass, q_turn, *([kn] * kb),
-      *([kr] * kb), *([v] * kb))
+      *([kr] * kb), *([v] * kb), *sinks)
