@@ -1,0 +1,13 @@
+"""CPU time (user + system) of the server's threads of role `decode` over the
+items answered in the window: `host_thread_cpu_seconds_total{role=decode}` as
+the difference of the two scrapes. The program walks `/proc/self/task` when it
+is scraped and names each thread by what it started it as; `decode` is the
+decode pool (threads named `tpuserve_<n>`): bodies parsed, texts tokenized. A
+count of host work; it says nothing of the device. None where the program has
+no such counter."""
+
+from benchmark import host_time
+
+
+def read(run: dict):
+    return host_time.role_cpu_ms_per_item(run, "decode")

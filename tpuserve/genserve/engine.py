@@ -93,6 +93,11 @@ PREFILL_HOLD = 2
 # ``gen_loop_seconds_total{phase=}`` and of the ``tpuserve.gen_loop`` marks.
 LOOP_PHASES = ("sweep", "admit", "prefill", "step", "account", "emit",
                "retire", "wait")
+# What ``account`` is made of (ISSUE 51), the labels of
+# ``gen_account_seconds_total{part=}``: handing the read extracts to their
+# tasks, the request trees' ``gen_step`` events, and the rest (histograms,
+# counters, ``_count_step``, the family's ``observe_step``).
+ACCOUNT_PARTS = ("finish", "trees", "sums")
 
 
 class KVPressure(QueueFull):
@@ -309,10 +314,18 @@ class GenEngine:
             f"gen_first_unit_ms{{model={name}}}")
         self._h_token_gap = metrics.histogram(
             f"gen_token_gap_ms{{model={name}}}")
-        # The loop's wall time by phase (ISSUE 36): the eight sum to it.
+        # The loop's wall time by phase (ISSUE 36): the eight sum to it. Its
+        # thread's CPU time beside it (ISSUE 51): in a phase that holds no
+        # await, wall less CPU is time the thread wanted to run and did not.
         self._c_loop = {p: metrics.counter(
             f"gen_loop_seconds_total{{model={name},phase={p}}}")
             for p in LOOP_PHASES}
+        self._c_loop_cpu = {p: metrics.counter(
+            f"gen_loop_cpu_seconds_total{{model={name},phase={p}}}")
+            for p in LOOP_PHASES}
+        self._c_account = {p: metrics.counter(
+            f"gen_account_seconds_total{{model={name},part={p}}}")
+            for p in ACCOUNT_PARTS}
         self._c_streams = metrics.counter(f"gen_streams_total{{model={name}}}")
         self._c_disconnects = metrics.counter(
             f"gen_client_disconnects_total{{model={name}}}")
@@ -399,6 +412,7 @@ class GenEngine:
         # out-block last reached the host (None across a wait).
         self._phase = "sweep"
         self._phase_t = 0.0
+        self._phase_cpu = 0.0
         self._iter = 0
         self._last_decode_at: float | None = None
         # The step ahead (ISSUE 41): how many steps were dispatched (the next
@@ -913,21 +927,27 @@ class GenEngine:
     def _stamp(self, phase: str) -> float:
         """The loop's ONE clock reading at a boundary (ISSUE 36). Entering
         another phase ends the one the loop was in: its length goes to
-        ``gen_loop_seconds_total{phase=}`` and, while a profiler session is
-        on, into the trace as a ``tpuserve.gen_loop`` mark. Within a phase
-        it only reads the clock, so that whatever reports a piece of the
-        loop's time (``gen_step_ms``, ``gen_insert_ms``, ``gen_extract_ms``,
-        the request trees' events) takes it from these readings."""
+        ``gen_loop_seconds_total{phase=}``, the CPU time its thread took in
+        it to ``gen_loop_cpu_seconds_total{phase=}`` (ISSUE 51: whatever else
+        the event loop ran during a phase's awaits is in it), and, while a
+        profiler session is on, into the trace as a ``tpuserve.gen_loop``
+        mark. Within a phase it only reads the clock, so that whatever
+        reports a piece of the loop's time (``gen_step_ms``,
+        ``gen_insert_ms``, ``gen_extract_ms``, the request trees' events)
+        takes it from these readings."""
         now = time.perf_counter()
         if phase != self._phase:
+            cpu = time.thread_time()
             self._c_loop[self._phase].inc(now - self._phase_t)
+            self._c_loop_cpu[self._phase].inc(cpu - self._phase_cpu)
             trace_mark("tpuserve.gen_loop", self._phase_t, now,
                        model=self.name, phase=self._phase, iter=self._iter)
-            self._phase, self._phase_t = phase, now
+            self._phase, self._phase_t, self._phase_cpu = phase, now, cpu
         return now
 
     async def _step_loop(self) -> None:
         self._phase, self._phase_t = "sweep", time.perf_counter()
+        self._phase_cpu = time.thread_time()
         # The loop condition (not just task cancellation) gates each
         # iteration: asyncio.wait_for can swallow a cancel that lands the
         # same tick its inner future completes, and a step loop that
@@ -1002,6 +1022,8 @@ class GenEngine:
                     await self._emit_preview(x, got)
                 else:
                     self._track(self._finish(x, got), x.info)
+            t_finish = time.perf_counter()
+            self._c_account["finish"].inc(t_finish - t1)
             if prev is None:
                 return True
             # The host's step time (``gen_step_ms``, ``device_seconds_total``,
@@ -1027,6 +1049,8 @@ class GenEngine:
                     info.ctx.span("gen_step", wall - step_s, wall,
                                   tid=name, slot=s,
                                   iteration=info.iterations)
+            t_trees = time.perf_counter()
+            self._c_account["trees"].inc(t_trees - t_finish)
             self._h_step.observe(step_s * 1e3, trace_id=ex_tid)
             self._observe_step(step_s * 1e3)
             self._c_device_seconds.inc(step_s)
@@ -1042,7 +1066,7 @@ class GenEngine:
         except Exception as e:  # noqa: BLE001 — contained per batch
             await self._fail_active(e)
             return True
-        self._stamp("emit")
+        self._c_account["sums"].inc(self._stamp("emit") - t_trees)
         await self._emit_step_units(out, prev.seq)
         self._stamp("retire")
         await self._retire(out, prev.seq)
@@ -1823,10 +1847,15 @@ class GenEngine:
         ``gen_iterations_total``: the model's counters, which a group's
         members share)."""
         iters = self._c_iterations.value
+
+        def per_iteration(counters: dict) -> dict | None:
+            return {p: round(c.value * 1e3 / iters, 3)
+                    for p, c in counters.items()} if iters else None
+
         return {"iterations": iters,
-                "ms_per_iteration": {
-                    p: round(c.value * 1e3 / iters, 3)
-                    for p, c in self._c_loop.items()} if iters else None}
+                "ms_per_iteration": per_iteration(self._c_loop),
+                "cpu_ms_per_iteration": per_iteration(self._c_loop_cpu),
+                "account_ms_per_iteration": per_iteration(self._c_account)}
 
     def _prefill_stats(self) -> dict:
         """Launches of the prefill program and what they carried (the

@@ -29,10 +29,13 @@ lock).
 from __future__ import annotations
 
 import bisect
+import gc
 import heapq
 import json
 import math
 import os
+import sys
+import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -613,6 +616,10 @@ class Metrics:
         self.exemplars = exemplars
         self.tracer = Tracer(trace_capacity)
         self.started_at = time.time()
+        # Sums that are kept elsewhere as plain numbers and reach their
+        # counters only when somebody reads them (``publish``; ISSUE 51).
+        self._on_scrape: list = []
+        self._scrape_lock = new_lock("obs.Metrics.publish")
 
     # -- registry -----------------------------------------------------------
     def histogram(self, name: str) -> Histogram:
@@ -903,8 +910,28 @@ class Metrics:
         self.gauge(f"model_version{{model={model}}}").set(float(version))
 
     # -- export -------------------------------------------------------------
+    def on_scrape(self, fn) -> None:
+        """``fn()`` runs before every rendering of ``/metrics`` and every
+        sample of the telemetry store: the place for a sum that must not be
+        counted where it arises (a collector's callback may take no lock)
+        or that costs something to read (a walk of ``/proc``)."""
+        self._on_scrape.append(fn)
+
+    def publish(self) -> None:
+        """Bring the ``on_scrape`` sums up to date. Two readers at once (an
+        ingest loop's ``/metrics`` and the sampler's tick) do not both walk:
+        the second goes on with what the first is publishing."""
+        if not self._on_scrape or not self._scrape_lock.acquire(blocking=False):
+            return
+        try:
+            for fn in self._on_scrape:
+                fn()
+        finally:
+            self._scrape_lock.release()
+
     def render_prometheus(self) -> str:
         """Prometheus text exposition format."""
+        self.publish()
         lines: list[str] = []
         with self._lock:
             counters = list(self._counters.values())
@@ -1074,6 +1101,146 @@ def trace_mark(name: str, start_s: float, end_s: float, **args) -> None:
     with ann(name, dur_us=round((end_s - start_s) * 1e6),
              ago_us=round((now - end_s) * 1e6), **args):
         pass
+
+
+# -- the host's own time (ISSUE 51) ---------------------------------------------
+# What the process's threads did that no request's span shows: the collector's
+# pauses, and the CPU each kind of thread took. Both are sums kept as plain
+# numbers and published into a registry when it is read (``Metrics.publish``):
+# nothing here starts a thread, samples, or runs on a request's path.
+
+# A collection at least this long is also written into the profiler's trace.
+GC_MARK_S = 1e-3
+GC_GENERATIONS = (0, 1, 2)
+_gc_seconds = [0.0, 0.0, 0.0]
+_gc_collections = [0, 0, 0]
+_gc_t0 = 0.0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """The entry in ``gc.callbacks``. The interpreter runs one collection at a
+    time (``gcstate->collecting``) and calls this in the thread that collects,
+    so ``start`` and ``stop`` come in pairs and one clock reading is enough.
+    It takes no lock (a lock whose holder's allocation started this
+    collection would never be released) and, for a short collection, builds
+    nothing: generation 0 runs thousands of times a second."""
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        return
+    t1 = time.perf_counter()
+    took = t1 - _gc_t0
+    gen = info["generation"]
+    _gc_seconds[gen] += took
+    _gc_collections[gen] += 1
+    # ``_annotation`` and not ``_trace_annotation()``: a collector's callback
+    # is no place to import jax; ``HostClocks`` resolves it beforehand.
+    if took >= GC_MARK_S and _annotation is not None:
+        trace_mark("tpuserve.gc", _gc_t0, t1, generation=gen,
+                   collected=info["collected"])
+
+
+# role on host_thread_cpu_seconds_total{role=}: a closed set, taken from the
+# names the program gives its threads. ``runtime`` is a thread of the process
+# that ``threading.enumerate()`` does not know (XLA's, the TPU driver's, the
+# profiler's); ``other`` a Python thread with none of the names below (the
+# telemetry sampler, asyncio's default executor, a library's).
+THREAD_ROLES = ("event_loop", "decode", "stage", "compile", "runtime", "other")
+_ROLE_BY_PREFIX = (("MainThread", "event_loop"), ("tpuserve-ingest-", "event_loop"),
+                   ("tpuserve_", "decode"), ("pipe-", "stage"),
+                   ("compile_", "compile"))
+
+
+def thread_role(name: str | None) -> str:
+    """The role of a thread by its ``Thread.name``; None is a thread Python
+    did not start."""
+    if name is None:
+        return "runtime"
+    for prefix, role in _ROLE_BY_PREFIX:
+        if name.startswith(prefix):
+            return role
+    return "other"
+
+
+def _thread_cpu_seconds() -> dict[int, float]:
+    """user + system seconds of every thread of this process, by its kernel
+    id, from ``/proc/self/task/<tid>/stat``; empty where there is no such
+    file (not Linux)."""
+    out: dict[int, float] = {}
+    tick = 1.0 / os.sysconf("SC_CLK_TCK")
+    try:
+        tids = os.listdir("/proc/self/task")
+    except OSError:
+        return out
+    for tid in tids:
+        try:
+            # os.open and not open(): a server on the TPU has a few hundred
+            # threads, and the buffered file object is 40% of a walk.
+            fd = os.open(f"/proc/self/task/{tid}/stat", os.O_RDONLY)
+            try:
+                raw = os.read(fd, 1024)
+            finally:
+                os.close(fd)
+            # The command may hold spaces and brackets: fields count from
+            # the last ")". utime and stime are the 14th and 15th.
+            fields = raw.rsplit(b")", 1)[1].split()
+            out[int(tid)] = (int(fields[11]) + int(fields[12])) * tick
+        except (OSError, IndexError, ValueError):
+            continue  # the thread ended between the listing and the read
+    return out
+
+
+class HostClocks:
+    """``host_gc_seconds_total{generation=}``, ``host_gc_collections_total``
+    and ``host_thread_cpu_seconds_total{role=}`` of one registry, brought up
+    to date whenever the registry is read. Built where the server builds its
+    ``Metrics``; ``close`` at its stop takes the collector's callback away.
+
+    The collector's sums are the process's (one callback, however many
+    registries a test process holds) and each registry counts them from its
+    own start. A thread's CPU is charged to the role it has when it is read,
+    by the difference from its last reading, so a role's sum never goes back:
+    not when a thread ends (what it did after its last reading is lost, as is
+    a thread that lived between two readings) and not when its id is used
+    again (a reading below the last is a new thread's)."""
+
+    def __init__(self, metrics: Metrics) -> None:
+        self._gc_s = [metrics.counter(f"host_gc_seconds_total{{generation={g}}}")
+                      for g in GC_GENERATIONS]
+        self._gc_n = [metrics.counter(f"host_gc_collections_total{{generation={g}}}")
+                      for g in GC_GENERATIONS]
+        self._cpu = {r: metrics.counter(f"host_thread_cpu_seconds_total{{role={r}}}")
+                     for r in THREAD_ROLES}
+        self._gc_seen = (list(_gc_seconds), list(_gc_collections))
+        self._last: dict[int, float] = {}
+        self.install()
+        metrics.on_scrape(self.publish)
+
+    def install(self) -> None:
+        """Once, however often it is called."""
+        if "jax" in sys.modules:  # never the one to import it (the router)
+            _trace_annotation()
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+    def close(self) -> None:
+        if _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+
+    def publish(self) -> None:
+        seen_s, seen_n = self._gc_seen
+        for g in GC_GENERATIONS:
+            s, n = _gc_seconds[g], _gc_collections[g]
+            self._gc_s[g].inc(s - seen_s[g])
+            self._gc_n[g].inc(n - seen_n[g])
+            seen_s[g], seen_n[g] = s, n
+        names = {t.native_id: t.name for t in threading.enumerate()}
+        now = _thread_cpu_seconds()
+        for tid, cpu in now.items():
+            before = self._last.get(tid, 0.0)
+            self._cpu[thread_role(names.get(tid))].inc(
+                cpu - before if cpu >= before else cpu)
+        self._last = now
 
 
 def percentile(values: Iterable[float], q: float) -> float:

@@ -69,8 +69,9 @@ from tpuserve.faults import CircuitBreaker, FaultInjector, Watchdog
 from tpuserve.genserve import GenEngine, GenEngineGroup, KVPressure
 from tpuserve.hostpipe import StageExecutors
 from tpuserve.lifecycle import ModelLifecycle, ReloadRejected
-from tpuserve.obs import (PRIORITIES, FlightRecorder, Metrics, TraceContext,
-                          exposition_content_type, spans_to_chrome, trace_call)
+from tpuserve.obs import (PRIORITIES, FlightRecorder, HostClocks, Metrics,
+                          TraceContext, exposition_content_type,
+                          spans_to_chrome, trace_call)
 from tpuserve.runtime import ModelRuntime, build_runtime, configure_jax
 from tpuserve.scheduler import FleetScheduler
 from tpuserve.scheduler.tenants import TenantLedger
@@ -164,6 +165,9 @@ class ServerState:
         self.cfg = cfg
         self.metrics = Metrics(cfg.trace_capacity,
                                exemplars=cfg.trace.exemplars)
+        # The host's own time (ISSUE 51): the collector's pauses and the
+        # CPU by kind of thread, read when the registry is.
+        self.host_clocks = HostClocks(self.metrics)
         # Tail-latency flight recorder (ISSUE 12, docs/OBSERVABILITY.md):
         # complete span trees for the slowest-N requests per model plus
         # every errored/shed request, served at /debug/slow and
@@ -818,6 +822,7 @@ class ServerState:
             await b.stop()
         self.stages.shutdown()
         self.pool.shutdown(wait=False, cancel_futures=True)
+        self.host_clocks.close()
         if self.events is not None:
             self.events.close()  # flush/close the JSONL sink fd
 

@@ -108,6 +108,7 @@ class TimeSeriesStore:
         store lock only afterwards — no nesting between the two families.
         """
         now = time.time() if now is None else now
+        self.metrics.publish()  # the sums a registry keeps until it is read
         with self.metrics._lock:
             counters = list(self.metrics._counters.values())
             gauges = list(self.metrics._gauges.values())
