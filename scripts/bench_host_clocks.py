@@ -10,9 +10,12 @@ to know (`chiprun -- python scripts/bench_host_clocks.py`, half a minute).
    that has the fields it touches, in microseconds a call.
 3. One publication (`HostClocks.publish`: the collector's sums and a walk of
    `/proc/self/task`) with a few threads and with 200 parked ones, in
-   microseconds, and the request trees' events the issue suspected:
-   `TraceContext.span` for 384 lanes a step, and `gc.collect(2)` over the
-   98,304 spans that 384 requests of 256 tokens keep alive.
+   microseconds.
+4. The request trees before and after ISSUE 52 (`trees`): a span a riding
+   lane a step against one record a step and one span a request, and
+   `gc.collect(2)` over the start-up heap and over the 196,608 spans that 384
+   requests of 512 tokens kept alive, with the heap walked and frozen (jax
+   and the engine are imported: a serving process's heap is a few times it).
 
 Prints one JSON line.
 """
@@ -29,7 +32,8 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from tpuserve import obs  # noqa: E402
-from tpuserve.genserve.engine import LOOP_PHASES, GenEngine  # noqa: E402
+from tpuserve.genserve.engine import (LOOP_PHASES, GenEngine,  # noqa: E402
+                                      _StepRecord)
 
 
 def per_call_us(fn, n: int) -> float:
@@ -118,25 +122,57 @@ def publication() -> dict:
 
 
 def trees() -> dict:
-    lanes, tokens = 384, 256
+    """The request trees before and after ISSUE 52, at 384 lanes: a span a
+    riding lane a step (written out here: the program has no such caller
+    left) against one record a step and one `gen_steps` span a request; and a
+    full collection over what each keeps alive for 384 requests of 512
+    tokens, the start-up heap walked with it and frozen."""
+    lanes, tokens = 384, 512
     ctxs = [obs.TraceContext() for _ in range(lanes)]
     wall = time.time()
 
-    def a_step():
+    def a_step_before():
         for s, ctx in enumerate(ctxs):
             ctx.span("gen_step", wall - 0.02, wall, tid="model", slot=s, iteration=7)
 
-    step_us = best(a_step, 20, rounds=3)
+    record = _StepRecord()
+    seq = [0]
+
+    def a_step():
+        record.put(seq[0], wall, 0.02)
+        seq[0] += 1
+
+    def a_retirement():
+        start, end, args = record.ridden(seq[0] - tokens, tokens)
+        ctxs[0].span("gen_steps", start, end, tid="model", slot=0, steps=tokens, **args)
+
+    def full_ms() -> float:
+        t0 = time.perf_counter()
+        gc.collect(2)
+        return (time.perf_counter() - t0) * 1e3
+
+    out = {"trees_step_384_lanes_before_us": best(a_step_before, 20, rounds=3),
+           "trees_step_record_us": best(a_step, 20_000)}
+    for _ in range(tokens):
+        a_step()
+    out["trees_retirement_us"] = best(a_retirement, 2_000)
     for ctx in ctxs:
         del ctx.spans[:]
     gc.collect()
+    out["gc_collect2_start_up_heap_ms"] = full_ms()
     for _ in range(tokens):
-        a_step()
-    t0 = time.perf_counter()
-    gc.collect(2)
-    full_ms = (time.perf_counter() - t0) * 1e3
-    return {"trees_step_384_lanes_us": step_us, "spans_alive": lanes * tokens,
-            "gc_collect2_over_them_ms": full_ms}
+        a_step_before()
+    out.update(spans_alive_before=lanes * tokens, gc_collect2_over_them_ms=full_ms())
+    for ctx in ctxs:
+        del ctx.spans[:]
+    gc.collect()
+    gc.freeze()
+    out.update(frozen_objects=gc.get_freeze_count(), gc_collect2_frozen_ms=full_ms())
+    for _ in range(tokens):
+        a_step_before()
+    out["gc_collect2_over_them_frozen_ms"] = full_ms()
+    gc.unfreeze()
+    return out
 
 
 def main() -> int:

@@ -124,6 +124,94 @@ def test_the_server_installs_it_with_its_metrics_and_stop_removes_it():
         gc.callbacks.append(obs._on_gc)
 
 
+# -- the start-up heap, frozen while a server of the process serves (ISSUE 52) -------------------------
+
+def toy_state():
+    state = ServerState(ServerConfig(
+        models=[ModelConfig(name="toy", family="toy", batch_buckets=[1], dtype="float32",
+                            num_classes=10, parallelism="single")], decode_threads=1))
+    state.build()
+    return state
+
+
+def frozen_gauge(state):
+    state.metrics.publish()
+    return state.metrics.gauge("host_gc_frozen_objects").value
+
+
+def still(held, now=None):
+    """The frozen count as it stands (or as the gauge read it), which has to be
+    `held` less the few frozen objects whose last reference went since (they are
+    freed like any other)."""
+    now = gc.get_freeze_count() if now is None else now
+    assert 0.99 * held <= now <= held
+    return now
+
+
+def test_a_started_server_holds_the_heap_frozen_and_a_stopped_one_leaves_it_as_it_was():
+    async def go():
+        before, thresholds, was_on = gc.get_freeze_count(), gc.get_threshold(), gc.isenabled()
+        state = toy_state()
+        assert gc.get_freeze_count() == before and frozen_gauge(state) == before  # built is not ready
+        await state.start()
+        try:
+            held = gc.get_freeze_count()
+            assert held > max(before, 10_000) and still(held, frozen_gauge(state))  # jax alone is more
+            assert 'host_gc_frozen_objects ' in state.metrics.render_prometheus()
+            # nothing else of the collector is touched: what comes after the freeze is collected as before
+            assert gc.get_threshold() == thresholds and gc.isenabled() == was_on
+            ring = []
+            ring.append(ring)
+            del ring
+            assert gc.collect() >= 1 and still(held)
+            state.host_clocks.freeze_heap()  # once, however often it is asked
+            assert still(held)
+        finally:
+            await state.stop()
+        # all of it is given back (the few hundred objects the interpreter itself starts with frozen too)
+        assert gc.get_freeze_count() == frozen_gauge(state) <= before
+    asyncio.run(go())
+
+
+def test_two_servers_of_a_process_freeze_once_and_the_last_to_stop_unfreezes():
+    async def go():
+        before = gc.get_freeze_count()
+        a, b = toy_state(), toy_state()
+        await a.start()
+        held = gc.get_freeze_count()
+        await b.start()  # what B allocated since is not worth a second walk
+        try:
+            assert still(held) > before
+            await a.stop()
+            assert still(held) and still(held, frozen_gauge(b))  # B still serves
+        finally:
+            await b.stop()
+        assert gc.get_freeze_count() <= before
+    asyncio.run(go())
+
+
+def test_closing_clocks_that_never_froze_leaves_another_servers_freeze_alone():
+    metrics = obs.Metrics()
+    had = obs._on_gc in gc.callbacks
+    holder, other = obs.HostClocks(metrics), obs.HostClocks(obs.Metrics())
+    before = gc.get_freeze_count()
+    try:
+        holder.freeze_heap()
+        held = gc.get_freeze_count()
+        assert held > before
+        other.close()  # it holds nothing
+        assert still(held)
+        holder.close()
+        assert gc.get_freeze_count() <= before
+        holders = obs._heap_holders
+        holder.close()  # twice is once
+        assert obs._heap_holders == holders >= 0
+    finally:
+        holder.close()
+        if had:
+            gc.callbacks.append(obs._on_gc)
+
+
 def session(log_dir):
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0

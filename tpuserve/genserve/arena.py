@@ -36,7 +36,7 @@ class SlotInfo:
     admitted_at: float = 0.0
     iterations: int = 0
     # Request trace context (obs.TraceContext, ISSUE 12): the engine tags
-    # this slot's fold-in/step/evict/retire events with its trace id.
+    # this slot's fold-in/steps/evict/retire events with its trace id.
     ctx: Any = None
     # Emission channel for a streamed request (engine.GenStream, ISSUE 17);
     # None for unary. Rides the ledger so every release path — retire,
@@ -83,6 +83,10 @@ class SlotArena:
 
     def peek(self, slot: int) -> SlotInfo:
         return self._active[slot]
+
+    def oldest(self) -> SlotInfo | None:
+        """The active request admitted first, without a list of them all."""
+        return next(iter(self._active.values()), None)
 
     def acquire(self, info: SlotInfo) -> int:
         """Hand out a free slot to ``info``; raises SlotCorrupted if the

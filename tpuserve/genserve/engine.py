@@ -95,9 +95,14 @@ LOOP_PHASES = ("sweep", "admit", "prefill", "step", "account", "emit",
                "retire", "wait")
 # What ``account`` is made of (ISSUE 51), the labels of
 # ``gen_account_seconds_total{part=}``: handing the read extracts to their
-# tasks, the request trees' ``gen_step`` events, and the rest (histograms,
-# counters, ``_count_step``, the family's ``observe_step``).
+# tasks, what the request trees cost (ISSUE 52: the step's one record and one
+# ``gen_steps`` span a retired request), and the rest (histograms, counters,
+# ``_count_step``, the family's ``observe_step``).
 ACCOUNT_PARTS = ("finish", "trees", "sums")
+# How many of the engine's last steps ``_StepRecord`` holds. A request that
+# rode more has the longest of its last ``STEP_RECORD`` in its ``gen_steps``
+# span (``held`` says how many), its count exact all the same.
+STEP_RECORD = 4096
 
 
 class KVPressure(QueueFull):
@@ -141,6 +146,46 @@ class _StepAhead:
     iter: int       # the pass that dispatched it: its spans' ``iter``
     at: float       # when it was dispatched (the loop's ``step`` stamp)
     ahead: bool     # dispatched while the step before it was still unread
+
+
+class _StepRecord:
+    """What a step leaves behind for the request trees (ISSUE 52): its
+    number, when its out-block reached the host (wall clock) and how long it
+    took, in three arrays of ``size`` plain numbers written round and round.
+    Nothing is allocated a step and nothing here is a container the collector
+    walks, however many lanes ride; a request's ``gen_steps`` span is made
+    from it once, when the request goes."""
+
+    def __init__(self, size: int = STEP_RECORD) -> None:
+        self.size = size
+        self._seq = np.full(size, -1, np.int64)
+        self._end = np.zeros(size)
+        self._step_s = np.zeros(size)
+
+    def put(self, seq: int, end: float, step_s: float) -> None:
+        at = seq % self.size
+        self._seq[at], self._end[at], self._step_s[at] = seq, end, step_s
+
+    def ridden(self, first: int, steps: int) -> tuple | None:
+        """Steps ``first`` .. ``first + steps - 1`` as one span, from those
+        of them still held: (the start of the oldest, the end of the newest,
+        on the wall clock in seconds, the span's args: the longest's
+        ``longest_ms`` and its ``longest_iteration``, counted from 0 at
+        ``first``, and ``held`` where fewer than ``steps`` are). None where
+        none is held."""
+        seqs = np.arange(max(first, first + steps - self.size), first + steps)
+        at = seqs % self.size
+        ok = self._seq[at] == seqs
+        if not ok.any():
+            return None
+        seqs, at = seqs[ok], at[ok]
+        took = self._step_s[at]
+        longest = int(took.argmax())
+        args = {"longest_ms": round(float(took[longest]) * 1e3, 3),
+                "longest_iteration": int(seqs[longest]) - first}
+        if len(seqs) < steps:
+            args["held"] = len(seqs)
+        return float(self._end[at[0]] - took[0]), float(self._end[at[-1]]), args
 
 
 @dataclass
@@ -420,6 +465,7 @@ class GenEngine:
         # out-block reached the host, the extracts dispatched and unread, and
         # the tasks that finalize and answer what was read.
         self._n_steps = 0
+        self._steps = _StepRecord()
         self._ahead: _StepAhead | None = None
         self._last_out_at = 0.0
         self._extracts: list[_Extract] = []
@@ -1016,6 +1062,33 @@ class GenEngine:
                                          t0, prev is not None)
                 self._n_steps += 1
             t1 = self._stamp("account")
+            wall = time.time()
+            # The request trees (ISSUE 52). A step leaves ONE record, of plain
+            # numbers, whatever rides it (a span a riding lane a step was the
+            # loop's pass and the collector's walk), and a request's tree gets
+            # its steps as one span when it goes (``_steps_span``): here for
+            # those whose extracts were read, before the tasks that answer
+            # them exist. The histogram's exemplar samples one rider.
+            for x, _ in fetched:
+                if not x.preview:
+                    self._steps_span(x.info, x.slot)
+            if prev is not None:
+                # The host's step time (``gen_step_ms``,
+                # ``device_seconds_total``, ``predicted_service_s``): from one
+                # out-block's arrival to the next, or from the step's dispatch
+                # where nothing was ahead of it. Whatever the chip ran between
+                # two steps (a retired slot's extract, the prefill launches)
+                # is inside it, as it was inside the blocking loop's
+                # dispatch-to-fetch.
+                step_s = t1 - max(prev.at, self._last_out_at)
+                self._last_out_at = t1
+                self._steps.put(prev.seq, wall, step_s)
+                rider = self.arena.oldest()  # rides the step if any request does
+                ex_tid = rider.ctx.trace_id if (
+                    rider is not None and rider.ctx is not None
+                    and prev.seq >= rider.since_step) else None
+            t_trees = time.perf_counter()
+            self._c_account["trees"].inc(t_trees - t1)
             for x, got in fetched:
                 self._h_extract.observe((t1 - x.t0) * 1e3)
                 if x.preview:
@@ -1023,34 +1096,9 @@ class GenEngine:
                 else:
                     self._track(self._finish(x, got), x.info)
             t_finish = time.perf_counter()
-            self._c_account["finish"].inc(t_finish - t1)
+            self._c_account["finish"].inc(t_finish - t_trees)
             if prev is None:
                 return True
-            # The host's step time (``gen_step_ms``, ``device_seconds_total``,
-            # ``predicted_service_s``): from one out-block's arrival to the
-            # next, or from the step's dispatch where nothing was ahead of
-            # it. Whatever the chip ran between two steps (a retired slot's
-            # extract, the prefill launches) is inside it, as it was inside
-            # the blocking loop's dispatch-to-fetch.
-            step_s = t1 - max(prev.at, self._last_out_at)
-            self._last_out_at = t1
-            # Step events per traced slot (ISSUE 12): every mid-flight
-            # request's tree shows each iteration it rode, tagged with
-            # its slot — bounded by the model's own step cap, and what
-            # makes "why was THIS generation slow" answerable span by
-            # span. The histogram exemplar samples one rider.
-            wall = time.time()
-            ex_tid = None
-            for s in self.arena.active_slots():
-                info = self.arena.peek(s)
-                if info.ctx is not None and prev.seq >= info.since_step:
-                    if ex_tid is None:
-                        ex_tid = info.ctx.trace_id
-                    info.ctx.span("gen_step", wall - step_s, wall,
-                                  tid=name, slot=s,
-                                  iteration=info.iterations)
-            t_trees = time.perf_counter()
-            self._c_account["trees"].inc(t_trees - t_finish)
             self._h_step.observe(step_s * 1e3, trace_id=ex_tid)
             self._observe_step(step_s * 1e3)
             self._c_device_seconds.inc(step_s)
@@ -1066,11 +1114,26 @@ class GenEngine:
         except Exception as e:  # noqa: BLE001 — contained per batch
             await self._fail_active(e)
             return True
-        self._c_account["sums"].inc(self._stamp("emit") - t_trees)
+        self._c_account["sums"].inc(self._stamp("emit") - t_finish)
         await self._emit_step_units(out, prev.seq)
         self._stamp("retire")
         await self._retire(out, prev.seq)
         return True
+
+    def _steps_span(self, info: SlotInfo, slot: int) -> None:
+        """The steps a request rode as ONE span of its tree (ISSUE 52),
+        written when it goes (retired, evicted, failed with the block): from
+        the start of the first to the end of the last, ``steps`` of them, and
+        the longest by its length and its place among them, so that a
+        stretched step still shows in every rider's tree. A request that rode
+        none gets none."""
+        if info.ctx is None or not info.iterations:
+            return
+        span = self._steps.ridden(info.since_step, info.iterations)
+        if span is not None:
+            start, end, args = span
+            info.ctx.span("gen_steps", start, end, tid=self.name, slot=slot,
+                          steps=info.iterations, **args)
 
     @staticmethod
     def _rides(info: SlotInfo, seq: int) -> bool:
@@ -1394,8 +1457,8 @@ class GenEngine:
         trace_mark("tpuserve.gen_retire", x.t0, t1, model=self.name,
                    slot=slot)
         if info.ctx is not None:
-            # Retire event: extract + finalize for this slot, the
-            # tail of the request's step-span stack.
+            # Retire event: extract + finalize for this slot, behind the
+            # request's ``gen_steps`` span.
             info.ctx.span("retire", wall1 - (t1 - x.t0), wall1,
                           tid=self.name, slot=slot,
                           iterations=info.iterations)
@@ -1478,6 +1541,7 @@ class GenEngine:
                 self._c_deadline.inc()
                 self._c_evictions.inc()
                 if info.ctx is not None:
+                    self._steps_span(info, slot)
                     wall = time.time()
                     info.ctx.span("evict", wall, wall, tid=self.name,
                                   slot=slot, iterations=info.iterations)
@@ -1492,6 +1556,7 @@ class GenEngine:
                     f"{info.iterations} iteration(s)"))
                 self._c_evictions.inc()
                 if info.ctx is not None:
+                    self._steps_span(info, slot)
                     wall = time.time()
                     info.ctx.span("evict", wall, wall, tid=self.name,
                                   slot=slot, iterations=info.iterations,
@@ -1667,11 +1732,13 @@ class GenEngine:
         if self.breaker is not None:
             self.breaker.record_failure()
         wall = time.time()
-        for info in self.arena.release_all():
+        for slot in self.arena.active_slots():
+            info = self.arena.release(slot)
             self._terminate_stream(info.stream, "engine_error", str(e))
             if not info.future.done():
                 info.future.set_exception(e)
             if info.ctx is not None:
+                self._steps_span(info, slot)
                 info.ctx.span("engine_failure", wall, wall, tid=self.name,
                               iterations=info.iterations,
                               error=type(e).__name__)

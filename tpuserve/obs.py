@@ -34,6 +34,7 @@ import heapq
 import json
 import math
 import os
+import random
 import sys
 import threading
 import time
@@ -219,7 +220,12 @@ _SPAN_ID_HEX = 16   # 64-bit span id
 
 
 def _hex_id(nbytes: int) -> str:
-    return os.urandom(nbytes).hex()
+    """A fresh id of ``nbytes`` random bytes, in hex. From ``random`` (seeded
+    from the system's entropy at import, and again in a forked child) and not
+    ``os.urandom``: an id names a span, it guards nothing, and a system call
+    that lets go of the GIL for every span is what the engine's loop waited
+    on (8 us a call on the chip's host, and the wait for the GIL after it)."""
+    return f"{random.getrandbits(8 * nbytes):0{2 * nbytes}x}"
 
 
 def valid_trace_id(value) -> bool:
@@ -1115,6 +1121,9 @@ GC_GENERATIONS = (0, 1, 2)
 _gc_seconds = [0.0, 0.0, 0.0]
 _gc_collections = [0, 0, 0]
 _gc_t0 = 0.0
+# The ``HostClocks`` of this process that hold the start-up heap frozen
+# (ISSUE 52): the first freezes, the last to close unfreezes.
+_heap_holders = 0
 
 
 def _on_gc(phase: str, info: dict) -> None:
@@ -1191,10 +1200,11 @@ def _thread_cpu_seconds() -> dict[int, float]:
 
 
 class HostClocks:
-    """``host_gc_seconds_total{generation=}``, ``host_gc_collections_total``
-    and ``host_thread_cpu_seconds_total{role=}`` of one registry, brought up
-    to date whenever the registry is read. Built where the server builds its
-    ``Metrics``; ``close`` at its stop takes the collector's callback away.
+    """``host_gc_seconds_total{generation=}``, ``host_gc_collections_total``,
+    ``host_gc_frozen_objects`` and ``host_thread_cpu_seconds_total{role=}`` of
+    one registry, brought up to date whenever the registry is read. Built
+    where the server builds its ``Metrics``; ``close`` at its stop takes the
+    collector's callback away and gives the frozen heap back.
 
     The collector's sums are the process's (one callback, however many
     registries a test process holds) and each registry counts them from its
@@ -1211,8 +1221,10 @@ class HostClocks:
                       for g in GC_GENERATIONS]
         self._cpu = {r: metrics.counter(f"host_thread_cpu_seconds_total{{role={r}}}")
                      for r in THREAD_ROLES}
+        self._g_frozen = metrics.gauge("host_gc_frozen_objects")
         self._gc_seen = (list(_gc_seconds), list(_gc_collections))
         self._last: dict[int, float] = {}
+        self._holds_heap = False
         self.install()
         metrics.on_scrape(self.publish)
 
@@ -1223,11 +1235,36 @@ class HostClocks:
         if _on_gc not in gc.callbacks:
             gc.callbacks.append(_on_gc)
 
+    def freeze_heap(self) -> None:
+        """The start-up heap out of the collector's sight (ISSUE 52), once
+        however often it is called: what the process built before it turned
+        ready (jax, the programs, the config trees) lives as long as it
+        serves, and a full collection that walks it stops every thread for a
+        step's length and more to find nothing. One full collection, then
+        ``gc.freeze()``: whatever is allocated afterwards is collected as
+        before, at the thresholds as they were. Called where the server
+        turns ready, on its loop, as ``close`` is at its stop."""
+        global _heap_holders
+        if self._holds_heap:
+            return
+        self._holds_heap = True
+        if _heap_holders == 0:
+            gc.collect()
+            gc.freeze()
+        _heap_holders += 1
+
     def close(self) -> None:
+        global _heap_holders
         if _on_gc in gc.callbacks:
             gc.callbacks.remove(_on_gc)
+        if self._holds_heap:
+            self._holds_heap = False
+            _heap_holders -= 1
+            if _heap_holders == 0:
+                gc.unfreeze()  # a process that starts many servers is left as found
 
     def publish(self) -> None:
+        self._g_frozen.set(float(gc.get_freeze_count()))
         seen_s, seen_n = self._gc_seen
         for g in GC_GENERATIONS:
             s, n = _gc_seconds[g], _gc_collections[g]

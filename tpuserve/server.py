@@ -537,6 +537,10 @@ class ServerState:
                 self._blackbox_snapshot)
             self.blackbox.start()
         self.watchdog.start()
+        # Ready: every model compiled and prewarmed, every loop started, the
+        # canaries through. What the process holds now it holds while it
+        # serves: out of the collector's sight (ISSUE 52), until ``stop``.
+        self.host_clocks.freeze_heap()
 
     # Counter families worth carrying in the black-box snapshot: the
     # serving volume and failure tallies a postmortem reader checks first.
