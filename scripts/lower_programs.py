@@ -41,7 +41,8 @@ import sys
 import tempfile
 from types import SimpleNamespace
 
-FAMILIES = ("decoder", "hybrid", "hybrid_ffn", "mla", "mla_sc", "mla_hc", "decoder_sink")
+FAMILIES = ("decoder", "hybrid", "hybrid_ffn", "mla", "mla_sc", "mla_hc", "decoder_sink",
+            "hybrid_delta")
 
 
 def kernels_without_places(text: str) -> tuple[str, int]:

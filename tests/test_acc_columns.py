@@ -1,7 +1,7 @@
 """What ``acc`` holds is stated once a family (``paged_lm.Column``, ISSUE 45):
 the state's width, the row a launch adds, the counters ``bind_metrics`` binds
 and what ``observe_step`` feeds all follow the family's ``COLUMNS``. Here, for
-each of the seven generating families at its toy size: one prefill launch and
+each of the eight generating families at its toy size: one prefill launch and
 one step on the CPU, then the device's sums into a registry. The names below
 are the series as they have been served since each family came (the benchmark's
 readers find them by these letters), written down apart from the code."""
@@ -44,6 +44,9 @@ SERIES = {
         "attn_rows_attended_total{model=M,phase=PH}", "attn_rows_walked_total{model=M,phase=PH}",
         "attn_walks_total{model=M,phase=PH,walk=kernel}",
         "attn_walks_total{model=M,phase=PH,walk=xla}"],
+    "hybrid_delta": EXPERTS + CONTEXT + SSM + COMPACT + [
+        "decode:delta_steps_total{model=M,phase=decode,path=kernel}",
+        "decode:delta_steps_total{model=M,phase=decode,path=xla}"],
 }
 # The fourth expert column sums held experts x expert layers run, so it feeds
 # the layers' counter too, over the experts held.

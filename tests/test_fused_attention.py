@@ -717,3 +717,52 @@ def test_a_sublayers_hyper_connection_is_two_kernel_calls_on_the_v5e_at_the_cell
     assert not [ln for ln in launch if f"f32[{rows},{n * d}]" in ln]
     assert not [ln for ln in launch if " copy(" in ln and f"[{rows},{n * d}]" in ln]
     assert not [ln for ln in step if "tpu_custom_call" in ln]
+
+
+def test_a_steps_delta_rule_update_is_one_kernel_call_a_layer_on_the_v5e_at_the_cells_widths(
+        one_chip, tmp_path, monkeypatch):
+    """`ops/delta_update.py` under the `hybrid_delta` family's step (ISSUE 53),
+    at the cell's sizes: 192 lanes of 64 heads of 128 x 128 float32 a layer on a
+    hidden size of 1,024. The TPU branch is steered by the backend's name here,
+    in the test. Mosaic takes the kernel (the transposition of a cell's packed
+    vectors, column slices broadcast along lanes, sublane sums); a delta-rule
+    layer of a step is ONE custom call whose state operand is the state leaf
+    itself and whose result is written over it (the step's state donated), and
+    no other float32 value of a state's size, no copy of one, is in the
+    program."""
+    import json
+
+    from tpuserve.config import ModelConfig
+    from tpuserve.models import build
+
+    lanes, heads, hd = 192, 64, 128
+    arch = {"vocab_size": 256, "hidden_size": 1024, "num_hidden_layers": 3, "gqa_layers": [0],
+            "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": hd, "num_heads": heads,
+                                   "num_kv_heads": None},
+            "num_attention_heads": 8, "num_key_value_heads": 1, "head_dim": 128,
+            "use_rope": False, "use_gqa_gate": True, "kda_use_full_proj": False,
+            "kda_allow_neg_eigval": True, "first_k_dense_replace": 0, "n_routed_experts": 8,
+            "n_shared_experts": 1, "num_experts_per_tok": 2, "moe_intermediate_size": 128}
+    path = tmp_path / "arch.json"
+    path.write_text(json.dumps(arch))
+    model = build(ModelConfig(name="hd", family="hybrid_delta", dtype="bfloat16",
+                              batch_buckets=[1],
+                              options={"config_file": str(path), "max_prompt_tokens": 256,
+                                       "max_new_tokens": 128}))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(place, jax.eval_shape(lambda: model.draw_params(0)))
+    state = jax.tree_util.tree_map(place, model.kv_page_signature(lanes, 64, 128))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
+    try:
+        text = jax.jit(model.step, donate_argnums=1).lower(params, state).compile() \
+            .as_text().split("\n")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    big = f"f32[{lanes},{heads},{hd},{hd}]"
+    calls = [ln for ln in text if " custom-call(" in ln and "delta_update" in ln.split("=")[0]]
+    assert len(calls) == 2 and all(big in ln for ln in calls)
+    # Nothing else MAKES a value of a state's size: no decayed copy, no `S'^T k` of it.
+    assert not [ln for ln in text if f"= {big}" in ln and " parameter(" not in ln
+                and " get-tuple-element(" not in ln and " bitcast(" not in ln]
+    assert not [ln for ln in text if " copy(" in ln and big in ln]

@@ -751,7 +751,8 @@ def _frozen_case(name, tmp_path):
     maker = {"decoder": "tests.test_decoder", "hybrid": "tests.test_hybrid",
              "hybrid_ffn": "tests.test_hybrid_ffn", "mla": "tests.test_mla",
              "mla_sc": "tests.test_mla_sc", "mla_hc": "tests.test_mla_hc",
-             "decoder_sink": "tests.test_decoder_sink"}[name]
+             "decoder_sink": "tests.test_decoder_sink",
+             "hybrid_delta": "tests.test_hybrid_delta"}[name]
     import importlib
     model = importlib.import_module(maker).make_model(str(tmp_path), name="fz")
     return (model, {}, dict(kv_paging=True, kv_page_tokens=4, prefill_chunk=8),
@@ -760,7 +761,7 @@ def _frozen_case(name, tmp_path):
 
 FROZEN_CASES = ["textgen-dense", "textgen-paged", "textgen-sharded-dense", "textgen-sharded-paged",
                 "sd15", "decoder", "hybrid", "hybrid_ffn", "mla", "mla_sc", "mla_hc",
-                "decoder_sink"]
+                "decoder_sink", "hybrid_delta"]
 
 
 def test_the_frozen_lane_cases_name_every_registered_generating_family():

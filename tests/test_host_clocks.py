@@ -605,7 +605,9 @@ def test_every_new_metric_has_its_reader_and_lists_only_cells():
     bench = spec.load_benchmark()
     cells = [w["name"] for w in bench["workloads"]]
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW_METRICS):] == list(NEW_METRICS)  # appended
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(next(iter(NEW_METRICS)))   # appended together; later PRs append after them
+    assert names[at:at + len(NEW_METRICS)] == list(NEW_METRICS)
     generating = next(m for m in bench["per_layer"] if m["name"] == "gen_step_ahead_pct")["workloads"]
     for name in NEW_METRICS:
         m = entries[name]
@@ -620,4 +622,4 @@ def test_every_new_metric_has_its_reader_and_lists_only_cells():
             assert m["layer"] == ("device" if name == "idle_host_gc_pct" else "HTTP ingest")
         assert m["source"] == ("device_trace" if name == "idle_host_gc_pct" else "program_counter")
         assert m["better"] == ("higher" if name == "gen_loop_cpu_share_pct" else "lower")
-    assert len(bench["per_layer"]) == 79 and len(cells) == 9
+    assert len(bench["per_layer"]) >= 79 and len(cells) >= 9

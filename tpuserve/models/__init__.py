@@ -46,6 +46,13 @@ Families (BASELINE.json ``configs``):
                    window layers' softmax, sigmoid-routed experts with no
                    shared one, and a grouped decode walk over the global
                    layers' pages (ISSUE 49)
+- hybrid_delta   — ``hybrid_ffn``'s sibling for a model whose recurrent layers
+                   are gated delta-rule linear attention (a decay a channel, a
+                   state that is read before it is written, one kernel call a
+                   step) and whose softmax layers have no position term and an
+                   elementwise output gate, with sigmoid-routed experts and a
+                   shared one in every layer; a share of the experts and the
+                   vocabulary (ISSUE 53)
 - toy            — a linear classifier for tests and drills
 """
 
@@ -71,6 +78,7 @@ _REGISTRY: dict[str, str] = {
     "mla_sc": "tpuserve.models.mla_sc",
     "mla_hc": "tpuserve.models.mla_hc",
     "decoder_sink": "tpuserve.models.decoder_sink",
+    "hybrid_delta": "tpuserve.models.hybrid_delta",
     "toy": "tpuserve.models.toy",
 }
 

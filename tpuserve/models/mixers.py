@@ -1,33 +1,68 @@
-"""The two mixers that the families built from a pattern of layers share
-(ISSUE 40; moved out of ``hybrid.py`` so that ``hybrid_ffn.py`` is not a copy):
-a Mamba-2 state-space layer with its state a slot, and attention by head with
-no rotary embedding over the paged KV. Both are mix-ins over
-``paged_lm.PagedLM``: they bring tensors, device math, the caches' shapes and
-the columns of ``acc``, and know nothing of how a family orders its layers,
-names its config keys or adds a mixer's output to the stream.
-``PatternMixers`` is the two together: layer ``i``'s mixer, whichever it is, in
+"""The mixers that the families built from a pattern of layers share (ISSUE 40;
+moved out of ``hybrid.py`` so that ``hybrid_ffn.py`` is not a copy): two
+RECURRENT mixers with their state a slot (a Mamba-2 state-space layer; since
+ISSUE 53 a gated delta-rule linear-attention layer with a decay a channel), and
+attention by head with no rotary embedding over the paged KV. All are mix-ins
+over ``paged_lm.PagedLM``: they bring tensors, device math, the caches' shapes
+and the columns of ``acc``, and know nothing of how a family orders its
+layers, names its config keys or adds a mixer's output to the stream.
+``PatternMixers`` (Mamba-2) and ``DeltaPatternMixers`` (delta rule) are one
+recurrent mixer and attention together: layer ``i``'s mixer, whichever it is, in
 whichever phase (``_mixer``), for ``paged_lm``'s loop.
+
+WHAT A RECURRENT MIXER OWES THE LOOP (``RecurrentMixer``; both keep it). A
+layer keeps, A SLOT, two leaves (``kv_slot_state = ("ssm", "conv")``): a float32
+state (``ssm[l][slot]``) and the last ``conv_kernel - 1`` rows of its
+convolution's input in the served type (``conv[l][slot]``). A request's FIRST
+piece starts from zeros whatever the slot held; a later piece from what the
+slot holds; within a launch the tiles of one piece pass the state on and a tile
+of another slot does not see it; padded rows leave it as it was; a piece of no
+tokens writes nothing; a decode step leaves the state of a lane that is not
+live untouched. Prefill computes the recurrence by chunks (a quadratic form
+inside a tile, the state passed between a piece's tiles by a ``lax.scan``),
+under ``jax.named_scope("ssm_scan")``: the scan alone, from the convolution to
+the gated norm; a decode step is one application, under
+``jax.named_scope("ssm_update")``: the whole mixer, from the projections to the
+out-projection. The four ``SSM_COLUMNS`` count both mixers' work alike.
 
 ``Mamba2Mixer`` (a family calls ``_mamba_setup`` in its constructor and sets
 ``m_layers``): ``[z | xBC | dt] = u W_in``; a depthwise causal convolution and
 SiLU over ``xBC``; per head ``S_t = a_t S_{t-1} + delta_t x_t (x) B_t``, ``y_t =
 S_t C_t + D x_t`` with ``delta = softplus(dt + dt_bias)``, ``a = exp(-exp(A_log)
-delta)``; ``y <- RMSNorm_group(y silu(z))``; out ``= y W_out``. A layer keeps, A
-SLOT, a float32 state (H, P, N) and the last ``conv_kernel - 1`` rows of its
-convolution's input (``ssm[l][slot]``, ``conv[l][slot]``). A request's FIRST
-piece starts from zeros whatever the slot held; a later piece from what the
-slot holds; within a launch the tiles of one piece pass the state on and a
-tile of another slot does not see it; padded rows leave it as it was (``delta
-= 0`` there: ``a = 1``, no input); a decode step leaves the state of a lane
-that is not live untouched. Prefill computes the recurrence by chunks (the
-quadratic form inside a tile, the state passed between a piece's tiles by a
-``lax.scan``), under ``jax.named_scope("ssm_scan")``; a decode step is one
-application, under ``jax.named_scope("ssm_update")``.
+delta)``; ``y <- RMSNorm_group(y silu(z))``; out ``= y W_out``. The state is (H,
+P, N); a padded row has ``delta = 0`` (``a = 1``, no input).
+
+``DeltaMixer`` (``_delta_setup``; ``m_layers`` too): ``q~, k~, v~ = u W_q, u W_k,
+u W_v`` (H heads of D each); a depthwise causal convolution with no bias and
+SiLU over each; ``q <- q / |q| / sqrt(D)``, ``k <- k / |k|`` (float32); a log-decay A
+CHANNEL ``g = -exp(A_log[h]) softplus(((u W_fa) W_fb) + dt_bias)`` (H, D), ``a =
+exp(g)``; ``beta = beta_scale sigmoid(u W_b)`` (2 where the step may pass 1);
+``S' = Diag(a) S_{t-1}``, ``S_t = S' + beta k (v - S'^T k)^T``, ``o = S_t^T q``: the
+decay BEFORE the correction, the correction reads the decayed state, the read
+is of the state after the token's own write; ``y = RMSNorm(o; g_o) sigmoid((u
+W_ga) W_gb + b_g)`` (one gain of D shared by the heads); out ``= y W_o``. The
+state is (H, D, D) float32; a padded row has ``g = 0`` and ``beta = 0``. A step's
+update is ONE kernel call (``ops/delta_update.py``, under
+``jax.named_scope("delta_update")``: the state read once and written once in
+place) on the TPU and the plain form elsewhere, chosen when the step is traced
+(``_delta_path``); ``delta_steps_total{path=kernel|xla}`` counts live lanes x
+layers by which. A launch's chunk is a prefill tile: with ``G`` the running sum
+of ``g`` inside it, ``(I + A) U = beta (V - (K e^G) S_0)`` for the strictly lower
+``A[t, s] = beta_t sum_c k_t k_s e^(G_t - G_s)`` (a unit triangular solve, made
+ONCE a tile for the right sides ``beta V`` and ``beta K e^G``: ``U = U_0 - W S_0``;
+the inverse by blocks, ``unit_lower_inverse``), ``o = (Q e^G) S_0 + B U`` with
+``B[t, s] = sum_c q_t k_s e^(G_t - G_s)`` for ``s <= t``, ``S_C = M S_0 + N`` with ``M = Diag(e^(G_C)) - Kd^T W``, ``N = Kd^T U_0``, ``Kd =
+K e^(G_C - G)``: the scan between a piece's tiles is one product a tile. EVERY
+EXPONENT TAKEN IS <= 0: ``A`` and ``B`` are made by sub-blocks of ``SUB`` rows,
+a block of the diagonal from the differences themselves (three indices), a
+block under it from two factors referred to the row block's first row.
 
 ``PlainAttention`` (a family sets ``a_layers``, ``heads``, ``kv``, ``hd`` and
 their full counts): grouped KV heads, causal, NO position term, no bias; K and
-V in pages of the engine's ledger. A step's whole mixer, from the projections
-to ``W_o``, runs under ``jax.named_scope("attn_decode")``.
+V in pages of the engine's ledger; where the family sets ``attn_gate``, the
+context times ``sigmoid(u W_g)``, elementwise by head, before ``W_o``. A step's
+whole mixer, from the projections to ``W_o``, runs under
+``jax.named_scope("attn_decode")``.
 """
 
 from __future__ import annotations
@@ -38,14 +73,83 @@ import jax
 import jax.numpy as jnp
 
 from tpuserve.models.paged_lm import Column, _mm, counted, series
+from tpuserve.ops import delta_update as du
 
 
 def softplus_inverse(y: float) -> float:
     return y + math.log(-math.expm1(-y))
 
 
-class Mamba2Mixer:
+class RecurrentMixer:
+    """What both recurrent mixers keep a slot, what a launch's scan does for
+    either, and what a launch counts of them (module docstring)."""
     kv_slot_state = ("ssm", "conv")  # the leaves that are a block a slot
+
+    def _tiles_conv(self, t: dict, rows, c0, w, bias=None):
+        """The depthwise causal convolution of a launch's packed ``rows`` (C,
+        channels), tile by tile: a tile's rows behind the k-1 rows before them,
+        the piece's stored rows ``c0`` (K, k-1, channels) for the tile that
+        opens it, else the tile before. ``w`` (k, channels), ``bias`` (channels,)
+        or None -> (the tiles that open a piece (K,), the live rows (K, T), the
+        rows with what is before them (K, k-1 + T, channels), the convolved rows
+        (K, T, channels) float32)."""
+        K, T, kc = t["K"], t["T"], self.conv_k - 1
+        if T < kc:
+            raise ValueError(f"{self.name}: a tile of {T} rows is shorter than the "
+                             f"convolution's {kc} stored rows")
+        opens = t["tiles"] == t["first_tile"][t["piece"]]   # a tile that opens its piece
+        live = t["valid"].reshape(K, T)
+        xt = rows.reshape(K, T, -1)
+        prev = jnp.where(opens[:, None, None], c0[t["piece"]],
+                         jnp.roll(xt[:, T - kc:], 1, axis=0))
+        seq = jnp.concatenate([prev, xt], axis=1)                         # (K, kc + T, ch)
+        w = w.astype(jnp.float32)
+        b = None if bias is None else bias.astype(jnp.float32)
+        conv = sum(seq[:, j:j + T].astype(jnp.float32) * w[j] for j in range(kc + 1))
+        return opens, live, seq, conv if b is None else b + conv
+
+    def _piece_ends(self, t: dict, live, seq):
+        """By piece: its last tile, and the k-1 rows of ``seq`` that end at its
+        last live row (a piece shorter than that keeps rows it came with).
+        ``live`` (K, T)."""
+        kc = self.conv_k - 1
+        last_tile = jnp.clip(t["first_tile"] + t["n_tiles"] - 1, 0, t["K"] - 1)
+        n_last = jnp.sum(live[last_tile], axis=1)
+        tail = jnp.take_along_axis(
+            seq[last_tile], (n_last[:, None] + jnp.arange(kc)[None, :])[:, :, None], axis=1)
+        return last_tile, tail
+
+    @staticmethod
+    def _piece_starts(ssm, conv, slot, start):
+        """What each piece of a launch starts from: zeros where it opens its
+        prompt, else what its slot holds -> (state (K, ...) float32, rows)."""
+        fresh = (start == 0)[:, None, None]
+        at = jnp.minimum(slot, ssm.shape[0] - 1)
+        return (jnp.where(fresh[..., None], 0.0, ssm[at].astype(jnp.float32)),
+                jnp.where(fresh, jnp.zeros((), conv.dtype), conv[at]))
+
+    @staticmethod
+    def _store_pieces(ssm, conv, slot, length, s_end, c_end):
+        """Each piece's state and rows into its slot. A piece of no tokens
+        writes nothing: its slot is out of range."""
+        to = jnp.where(length > 0, slot, ssm.shape[0])
+        return (ssm.at[to].set(s_end.astype(ssm.dtype), mode="drop"),
+                conv.at[to].set(c_end.astype(conv.dtype), mode="drop"))
+
+    def _counts(self, m: dict) -> dict:
+        """And live tokens (through a scan layer), slot states read and
+        written and, in a launch, the pieces that started from zeros and
+        from a stored state."""
+        if m["t"] is None:
+            n_live = jnp.sum(m["live"])
+            return {**super()._counts(m), "tokens": n_live, "rows": n_live,
+                    "zero": 0, "carried": 0}
+        start, has = m["start"], m["length"] > 0
+        return {**super()._counts(m), "tokens": jnp.sum(m["live"]), "rows": jnp.sum(has),
+                "zero": jnp.sum(has & (start == 0)), "carried": jnp.sum(has & (start > 0))}
+
+
+class Mamba2Mixer(RecurrentMixer):
 
     def _mamba_setup(self, name: str, *, heads: int, head_dim: int, groups: int, state: int,
                      conv_kernel: int, conv_bias: bool, share: list,
@@ -162,23 +266,10 @@ class Mamba2Mixer:
         H) of the packed rows; ``s0`` (K, H, P, N) float32 and ``c0`` (K, k-1,
         channels) what each PIECE starts from. -> y (C, H, P) float32 and,
         by piece, the state and the convolution's rows it ends with."""
-        K, T, kc = t["K"], t["T"], self.conv_k - 1
-        if T < kc:
-            raise ValueError(f"{self.name}: a tile of {T} rows is shorter than the "
-                             f"convolution's {kc} stored rows")
+        K, T = t["K"], t["T"]
         H, P, G, N = self.mh, self.mp, self.mg, self.mn
-        piece, tiles = t["piece"], t["tiles"]
-        opens = tiles == t["first_tile"][piece]          # a tile that opens its piece
-        live = t["valid"].reshape(K, T)
-        xt = xbc.reshape(K, T, -1)
-        # The convolution: a tile's rows behind the k-1 rows before them, the
-        # piece's stored rows for the tile that opens it, else the tile before.
-        prev = jnp.where(opens[:, None, None], c0[piece],
-                         jnp.roll(xt[:, T - kc:], 1, axis=0))
-        seq = jnp.concatenate([prev, xt], axis=1)                         # (K, kc + T, ch)
-        w = lp["conv_w"].astype(jnp.float32)
-        conv = lp["conv_b"].astype(jnp.float32) + sum(
-            seq[:, j:j + T].astype(jnp.float32) * w[j] for j in range(kc + 1))
+        piece = t["piece"]
+        opens, live, seq, conv = self._tiles_conv(t, xbc, c0, lp["conv_w"], lp["conv_b"])
         x, B, C = self._split_xbc(conv)
         delta, la = self._decay(lp, dt.reshape(K, T, H), live)
         cum = jnp.cumsum(la, axis=1)                                      # (K, T, H)
@@ -210,12 +301,7 @@ class Mamba2Mixer:
             "kgjpn,ktgn->ktgjp", s_in.astype(self.dtype).reshape(K, G, H // G, P, N), C,
             preferred_element_type=jnp.float32).reshape(K, T, H, P)
         y = y + lp["D"][:, None] * x.astype(jnp.float32)
-        # By piece: its last tile's state, and the k-1 rows that end at its
-        # last live row (a piece shorter than that keeps rows it came with).
-        last_tile = jnp.clip(t["first_tile"] + t["n_tiles"] - 1, 0, K - 1)
-        n_last = jnp.sum(live[last_tile], axis=1)
-        tail = jnp.take_along_axis(
-            seq[last_tile], (n_last[:, None] + jnp.arange(kc)[None, :])[:, :, None], axis=1)
+        last_tile, tail = self._piece_ends(t, live, seq)
         return y.reshape(K * T, H, P), s_out[last_tile], tail
 
     def _mamba_prefill(self, lp, u, t, ssm, conv, slot, start, length):
@@ -224,16 +310,10 @@ class Mamba2Mixer:
         are outside it."""
         z, xbc, dt = self._split_in(lp, u)
         with jax.named_scope("ssm_scan"):
-            fresh = (start == 0)[:, None, None]
-            at = jnp.minimum(slot, ssm.shape[0] - 1)
-            s0 = jnp.where(fresh[..., None], 0.0, ssm[at].astype(jnp.float32))
-            c0 = jnp.where(fresh, jnp.zeros((), conv.dtype), conv[at])
+            s0, c0 = self._piece_starts(ssm, conv, slot, start)
             y, s_end, c_end = self._scan_tiles(lp, xbc, dt, t, s0, c0)
             g = self._gated_norm(lp, y, z)
-            # A piece of no tokens writes nothing: its slot is out of range.
-            to = jnp.where(length > 0, slot, ssm.shape[0])
-            ssm = ssm.at[to].set(s_end.astype(ssm.dtype), mode="drop")
-            conv = conv.at[to].set(c_end.astype(conv.dtype), mode="drop")
+            ssm, conv = self._store_pieces(ssm, conv, slot, length, s_end, c_end)
         return self._out_proj(lp, g), ssm, conv
 
     def _mamba_step(self, lp, u, live, ssm, conv):
@@ -266,19 +346,6 @@ class Mamba2Mixer:
             return self._mamba_step(lp, u, m["live"], ssm, conv)
         return self._mamba_prefill(lp, u, m["t"], ssm, conv, m["slot"], m["start"], m["length"])
 
-    # -- counters -------------------------------------------------------------------
-    def _counts(self, m: dict) -> dict:
-        """And live tokens (through a scan layer), slot states read and
-        written and, in a launch, the pieces that started from zeros and
-        from a stored state."""
-        if m["t"] is None:
-            n_live = jnp.sum(m["live"])
-            return {**super()._counts(m), "tokens": n_live, "rows": n_live,
-                    "zero": 0, "carried": 0}
-        start, has = m["start"], m["length"] > 0
-        return {**super()._counts(m), "tokens": jnp.sum(m["live"]), "rows": jnp.sum(has),
-                "zero": jnp.sum(has & (start == 0)), "carried": jnp.sum(has & (start > 0))}
-
 
 def _pieces(start: str):
     """``ssm_pieces_total{model=,start=}``: a launch's pieces, so prefill's alone."""
@@ -298,7 +365,281 @@ SSM_COLUMNS = (
     Column(counted("carried"), _pieces("carried")))
 
 
+PATHS = ("kernel", "xla")   # where a step's delta-rule update ran
+_HI = {"precision": jax.lax.Precision.HIGHEST, "preferred_element_type": jnp.float32}
+
+
+def unit_lower_inverse(a: jax.Array, sub: int) -> jax.Array:
+    """``(I + a)^-1`` for ``a`` (..., T, T) float32 strictly lower triangular, in
+    a dozen batched products: the ``T / sub`` diagonal blocks of ``sub`` rows by
+    their own finite series, ``(I - a)(I + a^2)(I + a^4)...`` (``a`` is nilpotent),
+    then pairs of neighbours joined, ``[[P, 0], [-Q a21 P, Q]]``, until one block is
+    left. (XLA's own triangular solve inverts a tile's blocks one row after
+    another: 5.3 ms a layer a launch at the cell's 512 tiles by heads, half the
+    scan: my chip run, PR 53.) Where ``T / sub`` is no power of two the whole
+    matrix is one block."""
+    T, lead = a.shape[-1], a.shape[:-2]
+    n = T // sub if T % sub == 0 else 1
+    if n & (n - 1):
+        n = 1
+    m = T // n
+    a = a.reshape((-1, T, T))
+
+    def blocks(first: int, step: int):
+        """Blocks (i, j) of the n x n grid of m x m blocks at i n + j = first,
+        first + step, ..., as ONE batch (tiles x blocks, m, m): a transpose and
+        a strided slice, no gather."""
+        flat = a.reshape((-1, n, m, n, m)).swapaxes(2, 3).reshape((-1, n * n, m, m))
+        return flat[:, first::step].reshape((-1, m, m))
+
+    def mm(x, y):
+        return jnp.matmul(x, y, **_HI)
+
+    diag = blocks(0, n + 1)                                                # (tiles x n, m, m)
+    inv, power, terms = jnp.eye(m, dtype=a.dtype) - diag, diag, 2
+    while terms < m:
+        power = mm(power, power)
+        inv = inv + mm(inv, power)
+        terms *= 2
+    while n > 1:
+        inv = inv.reshape((-1, 2, m, m))
+        p, q = inv[:, 0], inv[:, 1]
+        low = -mm(q, mm(blocks(n, 2 * (n + 1)), p))                        # -Q a21 P
+        inv = jnp.concatenate([jnp.concatenate([p, jnp.zeros_like(p)], axis=-1),
+                               jnp.concatenate([low, q], axis=-1)], axis=-2)
+        n, m = n // 2, 2 * m
+    return inv.reshape(lead + (T, T))
+
+
+class DeltaMixer(RecurrentMixer):
+    SUB = 16       # rows of a sub-block of the pair tables (module docstring)
+    L2_EPS = 1e-6  # under the root of q's and k's norms
+
+    def _delta_setup(self, *, heads: int, head_dim: int, rank: int, conv_kernel: int,
+                     beta_scale: float) -> None:
+        """The layer's numbers: ``heads`` of ``head_dim`` for keys and values
+        alike, the low ``rank`` of the decay's and the gate's projections, and
+        what multiplies the sigmoid of the step (2: a step past 1, an
+        eigenvalue under 0)."""
+        self.kh, self.kd, self.k_rank = heads, head_dim, rank
+        self.conv_k, self.beta_scale = conv_kernel, float(beta_scale)
+        self.conv_ch = 3 * heads * head_dim
+
+    # -- params ---------------------------------------------------------------
+    def _delta_gains(self):
+        for i in self.m_layers:
+            yield (f"layer{i}", "o_norm"), (self.kd,)
+
+    def _delta_tensors(self):
+        """q, k and v are drawn as three tensors (and the convolution's three),
+        ``_join_delta`` joins them into ``w_qkv`` and ``conv_w``."""
+        d, s, h, D, r, k = self.d, self.scales, self.kh, self.kd, self.k_rank, self.conv_k
+        for i in self.m_layers:
+            L = f"layer{i}"
+            for part in ("q", "k", "v"):
+                yield ((L, f"w{part}"), (d, h, D), (d, h, D), (0, 0, 0), s["kda_in"], d)
+                yield ((L, f"conv_{part}"), (k, h, D), (k, h, D), (0, 0, 0), s["conv"], k)
+            for part, role in (("f", "kda_decay"), ("g", "kda_gate")):
+                yield ((L, f"w_{part}a"), (d, r), (d, r), (0, 0), s[role], d)
+                yield ((L, f"w_{part}b"), (r, h, D), (r, h, D), (0, 0, 0), s[role], r)
+            yield ((L, "w_b"), (d, h), (d, h), (0, 0), s["kda_beta"], d)
+            yield ((L, "w_out"), (h, D, d), (h, D, d), (0, 0, 0), s["kda_out"], h * D)
+
+    def _delta_vectors(self):
+        """A layer's float32 vectors, drawn INSIDE a range: ``A_log`` a head (A
+        in ``decay_rate``), ``dt_bias`` a channel (softplus of it in
+        ``decay_step``) and the gate's bias ``b_g`` a channel (about 0)."""
+        h, D, s = self.kh, self.kd, self.scales
+        lo, hi = (softplus_inverse(v) for v in s["decay_step"])
+        for i in self.m_layers:
+            L = f"layer{i}"
+            yield ((L, "A_log"), (h,), (h,), (0,), *(math.log(v) for v in s["decay_rate"]))
+            yield ((L, "dt_bias"), (h, D), (h, D), (0, 0), lo, hi)
+            b3 = 3.0 * s["gate_bias"]
+            yield ((L, "b_g"), (h, D), (h, D), (0, 0), -b3, b3)
+
+    def _join_delta(self, p: dict) -> None:
+        for i in self.m_layers:
+            lp, flat = p[f"layer{i}"], lambda t: t.reshape(t.shape[0], -1)
+            lp["w_qkv"] = jnp.concatenate([flat(lp.pop(f"w{c}")) for c in "qkv"], axis=1)
+            lp["conv_w"] = jnp.concatenate([flat(lp.pop(f"conv_{c}")) for c in "qkv"], axis=1)
+
+    def _delta_signature(self, slots: int) -> dict:
+        S = jax.ShapeDtypeStruct
+        return {"ssm": [S((slots, self.kh, self.kd, self.kd), jnp.float32)
+                        for _ in self.m_layers],
+                "conv": [S((slots, self.conv_k - 1, self.conv_ch), self.dtype)
+                         for _ in self.m_layers]}
+
+    # -- device math --------------------------------------------------------------
+    def _delta_heads(self, conv: jax.Array):
+        """Convolved (..., channels) float32 -> q, k, v (..., H, D) float32 after
+        the SiLU: q of length 1 / sqrt(D), k of length 1."""
+        a = jax.nn.silu(conv).reshape(conv.shape[:-1] + (3, self.kh, self.kd))
+        q, k, v = a[..., 0, :, :], a[..., 1, :, :], a[..., 2, :, :]
+
+        def unit(x):
+            return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + self.L2_EPS)
+
+        return unit(q) * self.kd ** -0.5, unit(k), v
+
+    def _decay_beta(self, lp: dict, u: jax.Array, live: jax.Array):
+        """``u`` (T, d), ``live`` (T,) -> (the log-decay a channel ``g`` (T, H, D)
+        <= 0, ``beta`` (T, H)), float32, both zero where a row is not live: its
+        state passes unchanged."""
+        f = jnp.einsum("tr,rhc->thc", _mm(u, lp["w_fa"]).astype(self.dtype), lp["w_fb"],
+                       preferred_element_type=jnp.float32)
+        g = -jnp.exp(lp["A_log"])[:, None] * jax.nn.softplus(f + lp["dt_bias"])
+        beta = self.beta_scale * jax.nn.sigmoid(_mm(u, lp["w_b"]))
+        return jnp.where(live[:, None, None], g, 0.0), jnp.where(live[:, None], beta, 0.0)
+
+    def _delta_gated(self, lp: dict, u: jax.Array, o: jax.Array) -> jax.Array:
+        """o (T, H, D) float32 normed over a head (one gain of D for all heads),
+        times the sigmoid gate from ``u`` -> (T, H, D) in the served type."""
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + self.eps) \
+            * lp["o_norm"].astype(jnp.float32)
+        gate = jnp.einsum("tr,rhc->thc", _mm(u, lp["w_ga"]).astype(self.dtype), lp["w_gb"],
+                          preferred_element_type=jnp.float32) + lp["b_g"]
+        return (o * jax.nn.sigmoid(gate)).astype(self.dtype)
+
+    def _delta_out(self, lp: dict, y: jax.Array) -> jax.Array:
+        return jnp.einsum("thp,hpd->td", y, lp["w_out"], preferred_element_type=jnp.float32)
+
+    def _pair_tables(self, q, k, G):
+        """q, k, G (..., T, D) float32, ``G`` the running sum of the log-decay
+        inside a tile -> (``sum_c k_t k_s e^(G_t - G_s)`` for s < t, ``sum_c q_t k_s
+        e^(G_t - G_s)`` for s <= t), each (..., T, T), zero elsewhere. No exponent
+        taken is above 0: a block of ``SUB`` x ``SUB`` on the diagonal takes the
+        differences themselves, a row block's part under the diagonal the two
+        factors ``e^(G_t - G_i)`` and ``e^(G_i - G_s)`` about its own first row i."""
+        T, D = G.shape[-2:]
+        sub = self.SUB if T % self.SUB == 0 else T
+        n, lead = T // sub, G.shape[:-2]
+        qb, kb, Gb = (x.reshape(lead + (n, sub, D)) for x in (q, k, G))
+        at = jnp.arange(sub)
+        e = jnp.exp(jnp.where((at[:, None] >= at[None, :])[:, :, None],
+                              Gb[..., :, None, :] - Gb[..., None, :, :], -jnp.inf))
+        on = [jnp.sum(x[..., :, None, :] * kb[..., None, :, :] * e, axis=-1) for x in (kb, qb)]
+        strict = (at[:, None] > at[None, :])
+        on[0] = jnp.where(strict, on[0], 0.0)
+        if n == 1:
+            return on[0][..., 0, :, :], on[1][..., 0, :, :]
+        first = Gb[..., :1, :]                                             # (..., n, 1, D)
+        right = k[..., None, :, :] * jnp.exp(jnp.minimum(first - G[..., None, :, :], 0.0))
+        left = jnp.exp(Gb - first)
+        blocks = jnp.arange(n)
+        under = (blocks[:, None] > blocks[None, :])[:, None, :, None]      # (n, 1, n, 1)
+        same = (blocks[:, None] == blocks[None, :])[:, None, :, None]
+        out = []
+        for x, d in zip((kb, qb), on):
+            off = jnp.einsum("...itc,...isc->...its", x * left, right, **_HI) \
+                .reshape(lead + (n, sub, n, sub))
+            full = jnp.where(under, off, jnp.where(same, d[..., :, :, None, :], 0.0))
+            out.append(full.reshape(lead + (T, T)))
+        return tuple(out)
+
+    def _delta_tiles(self, lp: dict, qkv, g, beta, t: dict, s0, c0):
+        """The chunked delta rule of one launch (module docstring): ``qkv`` (C,
+        channels) before the convolution, ``g`` (C, H, D) and ``beta`` (C, H) of
+        the packed rows (zero where a row is not live); ``s0`` (K, H, D, D)
+        float32 and ``c0`` (K, k-1, channels) what each PIECE starts from. -> o
+        (C, H, D) float32 and, by piece, the state and the convolution's rows
+        it ends with."""
+        K, T, H, D = t["K"], t["T"], self.kh, self.kd
+        opens, live, seq, conv = self._tiles_conv(t, qkv, c0, lp["conv_w"])
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in self._delta_heads(conv))   # (K, H, T, D)
+        G = jnp.cumsum(g.reshape(K, T, H, D).transpose(0, 2, 1, 3), axis=2)
+        b = beta.reshape(K, T, H).transpose(0, 2, 1)[..., None]                 # (K, H, T, 1)
+        kk, qk = self._pair_tables(q, k, G)
+        eG = jnp.exp(G)
+        # (I + A) [U_0 | W] = beta [V | K e^G]: one unit triangular inverse a tile.
+        sol = jnp.einsum("khts,khsv->khtv", unit_lower_inverse(b * kk, self.SUB),
+                         b * jnp.concatenate([v, k * eG], axis=-1), **_HI)
+        u0, w = sol[..., :D], sol[..., D:]
+        to_end = k * jnp.exp(G[:, :, -1:, :] - G)                               # K e^(G_C - G)
+        keep = jnp.eye(D, dtype=jnp.float32) * eG[:, :, -1, :, None] \
+            - jnp.einsum("khtc,khte->khce", to_end, w, **_HI)
+        add = jnp.einsum("khtc,khtv->khcv", to_end, u0, **_HI)
+
+        def pass_on(carry, tile):
+            opens_j, start_j, keep_j, add_j = tile
+            s_in = jnp.where(opens_j, start_j, carry)
+            s_out = jnp.einsum("hce,hev->hcv", keep_j, s_in, **_HI) + add_j
+            return s_out, (s_in, s_out)
+
+        _, (s_in, s_out) = jax.lax.scan(
+            pass_on, jnp.zeros((H, D, D), jnp.float32), (opens, s0[t["piece"]], keep, add))
+        u = u0 - jnp.einsum("khtc,khcv->khtv", w, s_in, **_HI)
+        o = jnp.einsum("khtc,khcv->khtv", q * eG, s_in, **_HI) \
+            + jnp.einsum("khts,khsv->khtv", qk, u, **_HI)
+        last_tile, tail = self._piece_ends(t, live, seq)
+        return o.transpose(0, 2, 1, 3).reshape(K * T, H, D), s_out[last_tile], tail
+
+    def _delta_prefill(self, lp, u, t, ssm, conv, slot, start, length):
+        """One delta-rule layer of a launch. The scope ``ssm_scan`` is the scan
+        alone, from the convolution to the gated norm: the projections are
+        outside it."""
+        qkv = _mm(u, lp["w_qkv"]).astype(self.dtype)
+        g, beta = self._decay_beta(lp, u, t["valid"])
+        with jax.named_scope("ssm_scan"):
+            s0, c0 = self._piece_starts(ssm, conv, slot, start)
+            o, s_end, c_end = self._delta_tiles(lp, qkv, g, beta, t, s0, c0)
+            y = self._delta_gated(lp, u, o)
+            ssm, conv = self._store_pieces(ssm, conv, slot, length, s_end, c_end)
+        return self._delta_out(lp, y), ssm, conv
+
+    def _delta_path(self, ssm) -> str:
+        """Where a step's update runs, chosen when the step is traced: the
+        kernel on the TPU at shapes it takes, else the plain form."""
+        on_tpu = jax.default_backend() == "tpu" and du.supported(ssm)
+        return "kernel" if on_tpu else "xla"  # tps-ok[TPS503]: backend and static shapes
+
+    def _delta_step(self, lp, u, live, ssm, conv, path: str):
+        """One application of the rule for every lane: the state of a lane that
+        is not live stays as it was. The scope ``ssm_update`` is the whole
+        mixer, from the projections to the out-projection; inside it
+        ``delta_update`` is the state's update and read alone."""
+        with jax.named_scope("ssm_update"):
+            qkv = _mm(u, lp["w_qkv"]).astype(self.dtype)
+            seq = jnp.concatenate([conv, qkv[:, None]], axis=1)          # (b, k, ch)
+            q, k, v = self._delta_heads(jnp.sum(
+                seq.astype(jnp.float32) * lp["conv_w"].astype(jnp.float32)[None], axis=1))
+            g, beta = self._decay_beta(lp, u, live)
+            with jax.named_scope("delta_update"):
+                update = du.delta_update if path == "kernel" else du.delta_step
+                o, new_ssm = update(ssm, q, k, v, jnp.exp(g), beta, live)
+            out = self._delta_out(lp, self._delta_gated(lp, u, o))
+            new_conv = jnp.where(live[:, None, None], seq[:, 1:], conv)
+        return out, new_ssm, new_conv
+
+    def _delta(self, lp, u, ssm, conv, m: dict):
+        """One delta-rule layer in the phase the plan ``m`` is of."""
+        if m["t"] is None:
+            return self._delta_step(lp, u, m["live"], ssm, conv, m["delta_path"])
+        return self._delta_prefill(lp, u, m["t"], ssm, conv, m["slot"], m["start"], m["length"])
+
+    def _step_plan(self, state, live, pos) -> dict:
+        """And where the step's updates run, chosen once for all its layers."""
+        return {**super()._step_plan(state, live, pos),
+                "delta_path": self._delta_path(state["ssm"][0])}
+
+    def _counts(self, m: dict) -> dict:
+        """And, a step, its live lanes by where their updates ran."""
+        c = super()._counts(m)
+        return {**c, "paths": {p: c["tokens"] if m.get("delta_path") == p else 0 for p in PATHS}}
+
+
+# A step's delta-rule updates (live lanes x layers) by where they ran.
+DELTA_COLUMNS = tuple(
+    Column(lambda model, stats, counts, path=path: counts["paths"][path] * len(model.m_layers),
+           lambda model, metrics, ph, path=path: series("delta_steps_total", f",path={path}")(
+               model, metrics, ph) if ph == "decode" else None)
+    for path in PATHS)
+
+
 class PlainAttention:
+    attn_gate = False  # the context times sigmoid(u W_g), elementwise by head, before W_o
+
     def _attention_tensors(self):
         d, hd, s = self.d, self.hd, self.scales
         for i in self.a_layers:
@@ -310,6 +651,9 @@ class PlainAttention:
                        (0, self.kv_first, 0), scale, d)
             yield ((L, "wo"), (self.heads, hd, d), (self.heads_full, hd, d),
                    (self.h_first, 0, 0), s["o"], self.heads_full * hd)
+            if self.attn_gate:
+                yield ((L, "wg"), (d, self.heads, hd), (d, self.heads_full, hd),
+                       (0, self.h_first, 0), s["gate"], d)
 
     def _qkv(self, lp: dict, u: jax.Array):
         return tuple(jnp.einsum("td,dhk->thk", u, lp[w],
@@ -320,13 +664,21 @@ class PlainAttention:
         return jnp.einsum("thk,hkd->td", o.astype(self.dtype), lp["wo"],
                           preferred_element_type=jnp.float32)
 
+    def _gated(self, lp, u, o):
+        """The context ``o`` (T, H, hd) float32 times ``sigmoid(u W_g)`` where the
+        family gates its attention; ``o`` itself elsewhere."""
+        if not self.attn_gate:
+            return o
+        return o * jax.nn.sigmoid(jnp.einsum("td,dhk->thk", u, lp["wg"],
+                                             preferred_element_type=jnp.float32))
+
     def _attn_prefill(self, lp, u, t: dict, kp, vp, w_page, off):
         """One attention layer of a launch: every row of the launch is in
         the pages before any tile reads them."""
         q, k, v = self._qkv(lp, u)
         kp, vp = self._write_pages(kp, w_page, off, k), self._write_pages(vp, w_page, off, v)
         o = self._prefill_full_tiles(q.reshape((t["K"], t["T"]) + q.shape[1:]), (kp, vp), t)
-        return self._attn_out(lp, o.reshape(q.shape)), kp, vp
+        return self._attn_out(lp, self._gated(lp, u, o.reshape(q.shape))), kp, vp
 
     def _attn_step(self, lp, u, kp, vp, bt, pos, w_page, off):
         """One attention layer of a decode step. The scope ``attn_decode`` is
@@ -334,7 +686,7 @@ class PlainAttention:
         with jax.named_scope("attn_decode"):
             q, k, v = self._qkv(lp, u)
             kp, vp = self._write_pages(kp, w_page, off, k), self._write_pages(vp, w_page, off, v)
-            y = self._attn_out(lp, self._decode_full(q, kp, vp, bt, pos))
+            y = self._attn_out(lp, self._gated(lp, u, self._decode_full(q, kp, vp, bt, pos)))
         return y, kp, vp
 
     def _attn(self, lp, u, kp, vp, m: dict):
@@ -344,23 +696,35 @@ class PlainAttention:
         return self._attn_prefill(lp, u, m["t"], kp, vp, m["w_page"], m["off"])
 
 
-class PatternMixers(Mamba2Mixer, PlainAttention):
-    """A family whose layer ``i`` has ONE of the two mixers (``m_layers``,
-    ``a_layers``): the four cache leaves and the layer's mixer."""
+class _Pattern:
+    """A family whose layer ``i`` has ONE of two mixers, a recurrent one
+    (``m_layers``) or attention (``a_layers``): the four cache leaves and the
+    layer's mixer. ``_recurrent`` and ``_state_signature`` are the recurrent
+    mixer's."""
     cache_leaves = ("kf", "vf", "ssm", "conv")
 
     def _cache_signature(self, slots: int, pages: int, page_tokens: int) -> dict:
         page = jax.ShapeDtypeStruct(self._page_shape(pages, page_tokens), self.dtype)
         return {"kf": [page for _ in self.a_layers], "vf": [page for _ in self.a_layers],
-                **self._mamba_signature(slots)}
+                **self._state_signature(slots)}
 
     def _mixer(self, i: int, lp, u, c: dict, m: dict):
         """Layer ``i``'s mixer on the normed stream ``u`` -> (T, d) float32;
         the layer's caches in ``c`` are replaced."""
         if i in self.m_layers:
             j = self.m_layers.index(i)
-            y, c["ssm"][j], c["conv"][j] = self._mamba(lp, u, c["ssm"][j], c["conv"][j], m)
+            y, c["ssm"][j], c["conv"][j] = self._recurrent(lp, u, c["ssm"][j], c["conv"][j], m)
         else:
             j = self.a_layers.index(i)
             y, c["kf"][j], c["vf"][j] = self._attn(lp, u, c["kf"][j], c["vf"][j], m)
         return y
+
+
+class PatternMixers(_Pattern, Mamba2Mixer, PlainAttention):
+    """Mamba-2 or plain attention."""
+    _recurrent, _state_signature = Mamba2Mixer._mamba, Mamba2Mixer._mamba_signature
+
+
+class DeltaPatternMixers(_Pattern, DeltaMixer, PlainAttention):
+    """The gated delta rule or plain attention."""
+    _recurrent, _state_signature = DeltaMixer._delta, DeltaMixer._delta_signature
