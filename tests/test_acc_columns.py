@@ -46,7 +46,9 @@ SERIES = {
         "attn_walks_total{model=M,phase=PH,walk=xla}"],
     "hybrid_delta": EXPERTS + CONTEXT + SSM + COMPACT + [
         "decode:delta_steps_total{model=M,phase=decode,path=kernel}",
-        "decode:delta_steps_total{model=M,phase=decode,path=xla}"],
+        "decode:delta_steps_total{model=M,phase=decode,path=xla}",
+        "prefill:delta_scans_total{model=M,phase=prefill,path=kernel}",
+        "prefill:delta_scans_total{model=M,phase=prefill,path=xla}"],
 }
 # The fourth expert column sums held experts x expert layers run, so it feeds
 # the layers' counter too, over the experts held.

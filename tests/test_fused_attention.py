@@ -766,3 +766,69 @@ def test_a_steps_delta_rule_update_is_one_kernel_call_a_layer_on_the_v5e_at_the_
     assert not [ln for ln in text if f"= {big}" in ln and " parameter(" not in ln
                 and " get-tuple-element(" not in ln and " bitcast(" not in ln]
     assert not [ln for ln in text if " copy(" in ln and big in ln]
+
+
+def test_a_launchs_chunked_delta_rule_is_one_kernel_call_a_layer_on_the_v5e_at_the_cells_widths(
+        one_chip, tmp_path, monkeypatch):
+    """`ops/delta_scan.py` under the `hybrid_delta` family's launch (ISSUE 54),
+    at the cell's sizes: 8 tiles of 128 rows, 64 heads of 128 channels, on a
+    hidden size of 1,024. The TPU branch is steered by the backend's name here,
+    in the test. Mosaic takes the kernel (lane offsets and sublane strides that
+    follow the loop over a cell's heads, rolls along lanes and sublanes, the
+    transposed products, the slices of half a table); a delta-rule layer of a
+    launch is ONE custom call that reads what the convolution gives and g where
+    XLA keeps them, so the scope has no `lax.scan` over tiles (the scatter of the pieces' states
+    is a `while` of its own) and
+    makes no copy of what the convolution gives (no q, k, v by head in device
+    memory)."""
+    import json
+
+    from tpuserve.config import ModelConfig
+    from tpuserve.models import build
+
+    K, T, heads, hd, slots = 8, 128, 64, 128, 16
+    arch = {"vocab_size": 256, "hidden_size": 1024, "num_hidden_layers": 2, "gqa_layers": [0],
+            "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": hd, "num_heads": heads,
+                                   "num_kv_heads": None},
+            "num_attention_heads": 8, "num_key_value_heads": 1, "head_dim": 128,
+            "use_rope": False, "use_gqa_gate": True, "kda_use_full_proj": False,
+            "kda_allow_neg_eigval": True, "first_k_dense_replace": 0, "n_routed_experts": 8,
+            "n_shared_experts": 1, "num_experts_per_tok": 2, "moe_intermediate_size": 128}
+    path = tmp_path / "arch.json"
+    path.write_text(json.dumps(arch))
+    model = build(ModelConfig(name="hd", family="hybrid_delta", dtype="bfloat16",
+                              batch_buckets=[1],
+                              options={"config_file": str(path), "max_prompt_tokens": 2048,
+                                       "max_new_tokens": 128}))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    lp = jax.tree_util.tree_map(place, jax.eval_shape(lambda: model.draw_params(0)))["layer1"]
+    sig = model.kv_page_signature(slots, 64, 128)
+
+    def shape(*dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def layer(lp, u, ssm, conv, slot, start, length):
+        launch = {"slot": slot, "start": start, "length": length,
+                  "pages": jnp.zeros((K, 1), jnp.int32)}
+        t = model._tiles(launch, K * T)
+        return model._delta_prefill(lp, u, t, ssm, conv, slot, start, length, model._scan_path(t))
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
+    try:
+        text = jax.jit(layer).lower(
+            lp, shape(K * T, 1024, dtype=jnp.bfloat16), place(sig["ssm"][0]),
+            place(sig["conv"][0]), shape(K), shape(K), shape(K)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    lines = text.split("\n")
+    calls = [ln for ln in lines if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "delta_scan" in calls[0].split("=")[0]
+    assert f"f32[{K},{T},{3 * heads * hd}]" in calls[0] and f"f32[{K},{T},{heads},{hd}]" in calls[0]
+    assert not [ln for ln in lines if " while(" in ln and "scan/while" in ln]   # no lax.scan
+    # q, k and v are never made by head outside the call: nothing of their size but the
+    # convolution's own result, and no copy or transpose of that
+    rows = (f"f32[{K},{T},{3 * heads * hd}]", f"f32[{K * T},{3 * heads * hd}]",
+            f"f32[{K},{T},3,{heads},{hd}]", f"f32[{K * T},3,{heads},{hd}]")
+    assert not [ln for ln in lines if (" copy(" in ln or " transpose(" in ln)
+                and any(s in ln.split("=")[1][:60] for s in rows)]

@@ -19,9 +19,10 @@ slot holds; within a launch the tiles of one piece pass the state on and a tile
 of another slot does not see it; padded rows leave it as it was; a piece of no
 tokens writes nothing; a decode step leaves the state of a lane that is not
 live untouched. Prefill computes the recurrence by chunks (a quadratic form
-inside a tile, the state passed between a piece's tiles by a ``lax.scan``),
-under ``jax.named_scope("ssm_scan")``: the scan alone, from the convolution to
-the gated norm; a decode step is one application, under
+inside a tile, the state passed between a piece's tiles by a ``lax.scan`` or,
+for the delta rule on the TPU, inside one kernel call), under
+``jax.named_scope("ssm_scan")``: the scan alone, from the convolution to the
+gated norm; a decode step is one application, under
 ``jax.named_scope("ssm_update")``: the whole mixer, from the projections to the
 out-projection. The four ``SSM_COLUMNS`` count both mixers' work alike.
 
@@ -55,7 +56,19 @@ the inverse by blocks, ``unit_lower_inverse``), ``o = (Q e^G) S_0 + B U`` with
 K e^(G_C - G)``: the scan between a piece's tiles is one product a tile. EVERY
 EXPONENT TAKEN IS <= 0: ``A`` and ``B`` are made by sub-blocks of ``SUB`` rows,
 a block of the diagonal from the differences themselves (three indices), a
-block under it from two factors referred to the row block's first row.
+block under it from two factors referred to the row block's first row. WHERE A
+LAUNCH'S CHUNKED RULE RUNS is chosen when the launch is traced, as the step's
+is (``_scan_path``: the backend and the static shapes, no option): on the TPU,
+at tiles of whole 128-row tables and heads of 128 channels, ONE kernel call a
+layer (``ops/delta_scan.py``, ISSUE 54: it takes what the convolution gives and
+g and beta where they lie, holds a tile's tables, inverse and products in fast
+memory and passes the state from tile to tile inside the call; nothing of a
+tile but o and a piece's last state goes back to device memory); elsewhere the
+plain form (``_delta_heads`` and ``_delta_chunks``: XLA fusions of plain
+``jnp``, some 140 device operations a layer, the state passed on by a
+``lax.scan``), which is also what the kernel is held to in the tests.
+``delta_scans_total{phase=prefill,path=kernel|xla}`` counts a launch's delta-rule
+layers by which. Both are float32 with every product at ``HIGHEST``.
 
 ``PlainAttention`` (a family sets ``a_layers``, ``heads``, ``kv``, ``hd`` and
 their full counts): grouped KV heads, causal, NO position term, no bias; K and
@@ -73,6 +86,7 @@ import jax
 import jax.numpy as jnp
 
 from tpuserve.models.paged_lm import Column, _mm, counted, series
+from tpuserve.ops import delta_scan as ds
 from tpuserve.ops import delta_update as du
 
 
@@ -538,18 +552,14 @@ class DeltaMixer(RecurrentMixer):
             out.append(full.reshape(lead + (T, T)))
         return tuple(out)
 
-    def _delta_tiles(self, lp: dict, qkv, g, beta, t: dict, s0, c0):
-        """The chunked delta rule of one launch (module docstring): ``qkv`` (C,
-        channels) before the convolution, ``g`` (C, H, D) and ``beta`` (C, H) of
-        the packed rows (zero where a row is not live); ``s0`` (K, H, D, D)
-        float32 and ``c0`` (K, k-1, channels) what each PIECE starts from. -> o
-        (C, H, D) float32 and, by piece, the state and the convolution's rows
-        it ends with."""
-        K, T, H, D = t["K"], t["T"], self.kh, self.kd
-        opens, live, seq, conv = self._tiles_conv(t, qkv, c0, lp["conv_w"])
-        q, k, v = (x.transpose(0, 2, 1, 3) for x in self._delta_heads(conv))   # (K, H, T, D)
-        G = jnp.cumsum(g.reshape(K, T, H, D).transpose(0, 2, 1, 3), axis=2)
-        b = beta.reshape(K, T, H).transpose(0, 2, 1)[..., None]                 # (K, H, T, 1)
+    def _delta_chunks(self, q, k, v, g, b, opens, s0):
+        """The plain form of a launch's chunked rule (module docstring), what
+        every backend but the TPU runs and what the kernel is held to: ``q``,
+        ``k``, ``v``, ``g`` (K, H, T, D) float32 by tile, ``b`` (K, H, T), ``opens``
+        (K,) and ``s0`` (K, H, D, D) what each TILE would start from if it opens
+        its piece -> (o (K, H, T, D), the state each tile ends with)."""
+        D = self.kd
+        G, b = jnp.cumsum(g, axis=2), b[..., None]                              # (K, H, T, 1)
         kk, qk = self._pair_tables(q, k, G)
         eG = jnp.exp(G)
         # (I + A) [U_0 | W] = beta [V | K e^G]: one unit triangular inverse a tile.
@@ -568,14 +578,34 @@ class DeltaMixer(RecurrentMixer):
             return s_out, (s_in, s_out)
 
         _, (s_in, s_out) = jax.lax.scan(
-            pass_on, jnp.zeros((H, D, D), jnp.float32), (opens, s0[t["piece"]], keep, add))
+            pass_on, jnp.zeros(s0.shape[1:], jnp.float32), (opens, s0, keep, add))
         u = u0 - jnp.einsum("khtc,khcv->khtv", w, s_in, **_HI)
         o = jnp.einsum("khtc,khcv->khtv", q * eG, s_in, **_HI) \
             + jnp.einsum("khts,khsv->khtv", qk, u, **_HI)
-        last_tile, tail = self._piece_ends(t, live, seq)
-        return o.transpose(0, 2, 1, 3).reshape(K * T, H, D), s_out[last_tile], tail
+        return o, s_out
 
-    def _delta_prefill(self, lp, u, t, ssm, conv, slot, start, length):
+    def _delta_tiles(self, lp: dict, qkv, g, beta, t: dict, s0, c0, path: str = "xla"):
+        """The chunked delta rule of one launch (module docstring): ``qkv`` (C,
+        channels) before the convolution, ``g`` (C, H, D) and ``beta`` (C, H) of
+        the packed rows (zero where a row is not live); ``s0`` (K, H, D, D)
+        float32 and ``c0`` (K, k-1, channels) what each PIECE starts from;
+        ``path``: ONE kernel call (``ops/delta_scan.py``) or the plain form. -> o
+        (C, H, D) float32 and, by piece, the state and the convolution's rows
+        it ends with."""
+        K, T, H, D = t["K"], t["T"], self.kh, self.kd
+        opens, live, seq, conv = self._tiles_conv(t, qkv, c0, lp["conv_w"])
+        g, beta = g.reshape(K, T, H, D), beta.reshape(K, T, H)
+        last_tile, tail = self._piece_ends(t, live, seq)
+        if path == "kernel":
+            o, s_end = ds.delta_scan(conv, g, beta, s0, opens, t["piece"], l2_eps=self.L2_EPS)
+        else:
+            q, k, v = self._delta_heads(conv)                                   # (K, T, H, D)
+            o, s_out = self._delta_chunks(*(x.transpose(0, 2, 1, 3) for x in (q, k, v, g)),
+                                          beta.transpose(0, 2, 1), opens, s0[t["piece"]])
+            o, s_end = o.transpose(0, 2, 1, 3), s_out[last_tile]
+        return o.reshape(K * T, H, D), s_end, tail
+
+    def _delta_prefill(self, lp, u, t, ssm, conv, slot, start, length, path: str):
         """One delta-rule layer of a launch. The scope ``ssm_scan`` is the scan
         alone, from the convolution to the gated norm: the projections are
         outside it."""
@@ -583,10 +613,16 @@ class DeltaMixer(RecurrentMixer):
         g, beta = self._decay_beta(lp, u, t["valid"])
         with jax.named_scope("ssm_scan"):
             s0, c0 = self._piece_starts(ssm, conv, slot, start)
-            o, s_end, c_end = self._delta_tiles(lp, qkv, g, beta, t, s0, c0)
+            o, s_end, c_end = self._delta_tiles(lp, qkv, g, beta, t, s0, c0, path)
             y = self._delta_gated(lp, u, o)
             ssm, conv = self._store_pieces(ssm, conv, slot, length, s_end, c_end)
         return self._delta_out(lp, y), ssm, conv
+
+    def _scan_path(self, t: dict) -> str:
+        """Where a launch's chunked rule runs, chosen when the launch is
+        traced: the kernel on the TPU at shapes it takes, else the plain form."""
+        on_tpu = jax.default_backend() == "tpu" and ds.supported(t["T"], self.kh, self.kd)
+        return "kernel" if on_tpu else "xla"  # tps-ok[TPS503]: backend and static shapes
 
     def _delta_path(self, ssm) -> str:
         """Where a step's update runs, chosen when the step is traced: the
@@ -616,7 +652,12 @@ class DeltaMixer(RecurrentMixer):
         """One delta-rule layer in the phase the plan ``m`` is of."""
         if m["t"] is None:
             return self._delta_step(lp, u, m["live"], ssm, conv, m["delta_path"])
-        return self._delta_prefill(lp, u, m["t"], ssm, conv, m["slot"], m["start"], m["length"])
+        return self._delta_prefill(lp, u, m["t"], ssm, conv, m["slot"], m["start"], m["length"],
+                                   m["scan_path"])
+
+    def _prefill_plan(self, state, launch, t: dict) -> dict:
+        """And where the launch's chunked rules run, chosen once for all its layers."""
+        return {**super()._prefill_plan(state, launch, t), "scan_path": self._scan_path(t)}
 
     def _step_plan(self, state, live, pos) -> dict:
         """And where the step's updates run, chosen once for all its layers."""
@@ -624,17 +665,27 @@ class DeltaMixer(RecurrentMixer):
                 "delta_path": self._delta_path(state["ssm"][0])}
 
     def _counts(self, m: dict) -> dict:
-        """And, a step, its live lanes by where their updates ran."""
+        """And, a step, its live lanes by where their updates ran; a launch,
+        itself by where its chunked rules ran."""
         c = super()._counts(m)
-        return {**c, "paths": {p: c["tokens"] if m.get("delta_path") == p else 0 for p in PATHS}}
+        return {**c, "paths": {p: c["tokens"] if m.get("delta_path") == p else 0 for p in PATHS},
+                "scans": {p: int(m.get("scan_path") == p) for p in PATHS}}
 
 
-# A step's delta-rule updates (live lanes x layers) by where they ran.
-DELTA_COLUMNS = tuple(
-    Column(lambda model, stats, counts, path=path: counts["paths"][path] * len(model.m_layers),
-           lambda model, metrics, ph, path=path: series("delta_steps_total", f",path={path}")(
-               model, metrics, ph) if ph == "decode" else None)
-    for path in PATHS)
+def _by_path(count: str, name: str, phase: str) -> tuple:
+    """A column a path: ``counts[count][path]`` times the delta-rule layers into
+    ``name{model=,phase=,path=}``, in ``phase`` alone."""
+    return tuple(
+        Column(lambda model, stats, counts, path=path: counts[count][path] * len(model.m_layers),
+               lambda model, metrics, ph, path=path: series(name, f",path={path}")(
+                   model, metrics, ph) if ph == phase else None)
+        for path in PATHS)
+
+
+# A step's delta-rule updates (live lanes x layers) and a launch's chunked
+# rules (layers), each by where it ran.
+DELTA_COLUMNS = (*_by_path("paths", "delta_steps_total", "decode"),
+                 *_by_path("scans", "delta_scans_total", "prefill"))
 
 
 class PlainAttention:
