@@ -10,7 +10,7 @@ alone. Operations nest on that line (a `while` or a `conditional` and what runs
 inside it), so the containers are listed apart and left out of the sum.
 
 Used by `scripts/bench_hybrid.py` and `scripts/bench_prefill.py`; reads any
-`*.xplane.pb`:  python scripts/op_table.py <trace.xplane.pb> [module prefix ...]
+`*.xplane.pb`:  python scripts/op_table.py [--longest] <trace.xplane.pb> [module prefix ...]
 """
 
 from __future__ import annotations
@@ -27,7 +27,8 @@ from benchmark.trace_reduce import DEVICE_PLANE, MODULES_LINE, OPS_LINE, op_name
 
 # An inner scope before the one that holds it: a row is the first that matches.
 SCOPES = ("moe_experts", "moe_dispatch", "ssm_scan", "ssm_update", "mla_prefill", "mla_decode",
-          "attn_ring", "attn_full_walk", "attn_prefill", "attn_decode")
+          "attn_ring", "attn_full_walk", "attn_prefill", "attn_decode",
+          "eva_summarise", "eva_prefill", "eva_decode")
 CONTAINERS = ("while", "conditional", "call")
 
 
@@ -52,9 +53,11 @@ def _kind(instruction: str) -> str:
     return re.sub(r"[.\d]+$", "", instruction) or instruction
 
 
-def last_launches(path: str) -> dict[str, dict]:
+def last_launches(path: str, longest: bool = False) -> dict[str, dict]:
     """{module's base name: {"name", "ns", "ops": [(instruction, ns, op_name)]}}
-    for the LAST launch of each program on the first chip that ran any."""
+    for the LAST launch of each program on the first chip that ran any
+    (``longest``: the longest instead: of a cell's traced window, whose launches
+    differ in their live rows and whose last the trace's end may cut)."""
     from jax.profiler import ProfileData
 
     names = scope_map(path)
@@ -65,8 +68,11 @@ def last_launches(path: str) -> dict[str, dict]:
             continue
         out = {}
         for ev in lines[MODULES_LINE].events:
-            out[ev.name.split("(")[0]] = {"name": ev.name, "lo": int(ev.start_ns),
-                                          "ns": int(ev.duration_ns), "ops": []}
+            base = ev.name.split("(")[0]
+            if longest and base in out and out[base]["ns"] >= int(ev.duration_ns):
+                continue
+            out[base] = {"name": ev.name, "lo": int(ev.start_ns), "ns": int(ev.duration_ns),
+                         "ops": []}
         for ev in lines[OPS_LINE].events:
             for base, m in out.items():
                 if m["lo"] <= ev.start_ns < m["lo"] + m["ns"]:
@@ -96,8 +102,9 @@ def table(ops: list[tuple[str, int, str]]) -> tuple[list[tuple[str, float, int]]
         inside / 1e6
 
 
-def print_tables(path: str, prefixes: tuple[str, ...] = (), top: int = 28, out=print) -> None:
-    for base, m in last_launches(path).items():
+def print_tables(path: str, prefixes: tuple[str, ...] = (), top: int = 28, out=print,
+                 longest: bool = False) -> None:
+    for base, m in last_launches(path, longest).items():
         if prefixes and not base.startswith(prefixes) or m["ns"] < 1e6:
             continue   # not asked for, or a program of under a millisecond
         rows, inside = table(m["ops"])
@@ -115,4 +122,5 @@ def print_tables(path: str, prefixes: tuple[str, ...] = (), top: int = 28, out=p
 
 
 if __name__ == "__main__":
-    print_tables(sys.argv[1], tuple(sys.argv[2:]))
+    args = [a for a in sys.argv[1:] if a != "--longest"]
+    print_tables(args[0], tuple(args[1:]), longest="--longest" in sys.argv)

@@ -1,7 +1,7 @@
 """What ``acc`` holds is stated once a family (``paged_lm.Column``, ISSUE 45):
 the state's width, the row a launch adds, the counters ``bind_metrics`` binds
 and what ``observe_step`` feeds all follow the family's ``COLUMNS``. Here, for
-each of the eight generating families at its toy size: one prefill launch and
+each of the nine generating families at its toy size: one prefill launch and
 one step on the CPU, then the device's sums into a registry. The names below
 are the series as they have been served since each family came (the benchmark's
 readers find them by these letters), written down apart from the code."""
@@ -49,6 +49,12 @@ SERIES = {
         "decode:delta_steps_total{model=M,phase=decode,path=xla}",
         "prefill:delta_scans_total{model=M,phase=prefill,path=kernel}",
         "prefill:delta_scans_total{model=M,phase=prefill,path=xla}"],
+    "eva": CONTEXT + [
+        "eva_rows_attended_total{model=M,phase=PH,kind=exact}",
+        "eva_rows_attended_total{model=M,phase=PH,kind=summary}",
+        "eva_chunks_summarised_total{model=M,phase=PH}", "eva_windows_closed_total{model=M,phase=PH}",
+        "decode:eva_decode_steps_total{model=M,phase=decode,path=walk}",
+        "decode:eva_decode_steps_total{model=M,phase=decode,path=gather}"],
 }
 # The fourth expert column sums held experts x expert layers run, so it feeds
 # the layers' counter too, over the experts held.

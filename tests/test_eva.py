@@ -1,0 +1,424 @@
+"""The `eva` family (ISSUE 55) against its plain reference at a small size on the
+CPU: packed chunked prefill and decode through a ring a slot and pages of
+summary rows in one pool a layer, against the reference's full pass (windows
+that close in decode, inside a piece, at a piece's last row; a prompt of
+exactly a window; a padded tail); each wrong reading of the layer failing; the
+virtual block table's walk (jax's kernel in the Pallas interpreter where it
+runs here, else its contract in plain jnp) against the gather; a lane that is
+not live keeping ring, pages and lanes to the bit; the cache's geometry and what
+`/stats` says of it; the weights recipe; the counters; and the two copies of
+the reference."""
+
+from __future__ import annotations
+
+import contextlib
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tests import eva_reference as ref
+from tpuserve.config import GenserveConfig, ModelConfig
+from tpuserve.genserve.model import PrefillPiece
+from tpuserve.models import build
+from tpuserve.models import decoder as dec
+from tpuserve.models import eva
+from tpuserve.models import seeded
+
+# Two layers; 4 query heads of 16 on 2 KV heads (the cell has no grouping; the
+# walk is written for any); a window of 16 in chunks of 4, so a page is 4
+# summary rows and stands for 16 positions; two prediction blocks of 40 ids.
+ARCH = {"model_type": "evabyte", "attention_class": "eva", "attention_bias": False,
+        "chunk_size": 4, "window_size": 16, "num_chunks": None, "fp32_ln": False,
+        "fp32_logits": True, "fp32_skip_add": True, "hidden_act": "silu", "hidden_size": 64,
+        "intermediate_size": 96, "mixedp_attn": True, "norm_add_unit_offset": True,
+        "num_attention_heads": 4, "num_key_value_heads": 2, "num_hidden_layers": 2,
+        "num_pred_heads": 2, "rms_norm_eps": 1e-5, "rope_scaling": None, "rope_theta": 100000,
+        "tie_word_embeddings": False, "vocab_size": 40}
+SEED = 11
+MAX_PROMPT, MAX_NEW, PAGE, CHUNK, SLOTS = 40, 24, 4, 8, 3
+# float32 program against a float32 reference: what differs is the order of
+# sums (a running softmax over the exact part and key blocks of summary pages,
+# a ring read in ring order, rows pooled from a cache): 1e-6 on
+# log-probabilities of a few units; 2e-4 leaves room for a longer toy.
+ATOL = 2e-4
+# A wrong reading must move some served log-probability by at least this: two
+# hundred tolerances, a tenth of a nat.
+WRONG_BY = 0.04
+
+
+def make_model(tmp_path, arch=ARCH, name="eva", dtype="float32", **options):
+    path = os.path.join(tmp_path, f"{name}.json")
+    with open(path, "w", encoding="utf-8") as f:
+        json.dump(arch, f)
+    cfg = ModelConfig(name=name, family="eva", dtype=dtype, batch_buckets=[1],
+                      options={"config_file": path, "draw_weights_seed": SEED,
+                               "max_prompt_tokens": MAX_PROMPT, "max_new_tokens": MAX_NEW,
+                               **options})
+    return build(cfg)
+
+
+def zeros(struct):
+    return jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), struct)
+
+
+def serve(model, params, prompts, max_news, chunk=CHUNK, launches=None, slots=SLOTS, page=PAGE,
+          steps=None, state=None, steer=None):
+    """What the engine does, by hand: the prompts' pieces through the prefill
+    program (``launches``: lists of (slot, start, length); else a prompt alone,
+    a chunk a launch), then steps until every lane is done -> (extract() a
+    slot, the last step's out-block, the state)."""
+    pps = model.kv_pages_per_slot(page)
+    if state is None:
+        state = zeros(model.kv_page_signature(slots, slots * pps + 1, page))
+    k = model.kv_prefill_pieces(chunk, page)
+    prefill = jax.jit(model.prefill_chunk, static_argnames=("chunk",))
+    step = jax.jit(model.step)
+    if launches is None:
+        launches = [[(slot, start, min(chunk, len(prompts[slot]) - start))]
+                    for slot in range(len(prompts))
+                    for start in range(0, len(prompts[slot]), chunk)]
+
+    def piece(slot, start, length):
+        ids = np.zeros((model.max_prompt,), np.int32)
+        ids[: len(prompts[slot])] = prompts[slot]
+        item = (ids, np.int32(len(prompts[slot])), np.int32(3), np.int32(max_news[slot]),
+                np.float32(0.0), np.int32(dec.LOGPROBS))
+        cache = {"pages": np.arange(1 + slot * pps, 1 + (slot + 1) * pps, dtype=np.int32),
+                 "ring": np.int32(slot + 1)}
+        return PrefillPiece(slot, item, start, length, cache)
+
+    for pieces in launches:
+        state = prefill(params, state, model.pack_prefill([piece(*p) for p in pieces], chunk, k),
+                        chunk=chunk)
+    out = None
+    with (steer or contextlib.nullcontext)():
+        for _ in range(max(max_news) + 1 if steps is None else steps):
+            state, out = step(params, state)
+    if steps is None:
+        assert bool(np.all(np.asarray(out["done"])[: len(prompts)]))
+    return [jax.tree_util.tree_map(np.asarray, model.extract(params, state, np.int32(s)))
+            for s in range(len(prompts))], out, state
+
+
+@pytest.fixture(scope="module")
+def whole(tmp_path_factory):
+    model = make_model(str(tmp_path_factory.mktemp("eva")))
+    return model, model.init_params(jax.random.key(0))
+
+
+def prompts_of(lengths, seed=5):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, ARCH["vocab_size"], n).astype(np.int32) for n in lengths]
+
+
+def gaps(got, prompts, news, arch=ARCH, wrong="", low=False):
+    """Per request: |served - reference| over the served top-8 log-probabilities
+    of every generated position, the reference teacher-forced on the served
+    tokens in one full pass."""
+    m = ref.Model(arch, SEED, "float32", wrong=wrong)
+    seqs = [np.concatenate([p, g["tokens"][:n - 1]]) for p, g, n in zip(prompts, got, news)]
+    lps = ref.log_probs(m, seqs, [len(p) - 1 for p in prompts], low)
+    return [np.abs(np.take_along_axis(lp, g["lp_ids"][:n], axis=-1) - g["lp"][:n])
+            for lp, g, n in zip(lps, got, news)]
+
+
+# Each case: prompt lengths, tokens asked for, the launches (None: a prompt
+# alone, a chunk of 8 a launch, so a window's edge at 16 or 32 is a piece's
+# LAST row). Decode: 14 + 24 closes windows at steps 2 and 18; 16 + 5 begins
+# its ring again at the first step.
+CASES = {
+    "alone: edges in decode, at a piece's last row, a padded tail":
+        ((14, 37, 3), [24, 12, 7], None),
+    "a prompt of exactly a window, and one a row short of it":
+        ((16, 15, 32), [5, 5, 5], None),
+    "packed: edges INSIDE a piece, pieces of three prompts in a launch":
+        ((14, 37, 3), [24, 12, 7],
+         [[(1, 0, 8)], [(1, 8, 4), (0, 0, 4)], [(1, 12, 8)], [(0, 4, 8)], [(1, 20, 8)],
+          [(0, 12, 2), (2, 0, 3)], [(1, 28, 8)], [(1, 36, 1)]]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_prefill_then_decode_through_ring_and_pages_is_the_reference_full_pass(whole, case):
+    model, params = whole
+    lengths, news, launches = CASES[case]
+    prompts = prompts_of(lengths)
+    got, out, _ = serve(model, params, prompts, news, launches=launches)
+    for g, gap, n in zip(got, gaps(got, prompts, news), news):
+        assert int(g["n_new"]) == n
+        assert float(gap.max()) < ATOL, case
+    # the device's sums: every prompt token and every step's live token, by the rows its
+    # index sets hold
+    acc = np.asarray(out["acc"]).astype(int)
+    W, P = model.window, model.rows
+    pos_p = np.concatenate([np.arange(n) for n in lengths])
+    pos_d = np.concatenate([np.arange(n, n + new - 1) for n, new in zip(lengths, news)])
+    for row, pos in zip(acc, (pos_p, pos_d)):
+        exact, summary = int(np.sum(pos % W + 1)), int(np.sum(pos // W * P))
+        assert list(row[:3]) == [exact + summary, exact, summary]
+        assert row[3] == model.n_layers * int(np.sum(pos % model.chunk == model.chunk - 1))
+        assert row[4] == int(np.sum(pos % W == W - 1))
+    assert list(acc[0, 5:]) == [0, 0] and list(acc[1, 5:]) == [0, model.n_layers * len(pos_d)]
+
+
+@pytest.fixture(scope="module")
+def answers(whole):
+    """The first case's served answers: what every wrong reading is held against."""
+    model, params = whole
+    lengths, news, _ = CASES[sorted(CASES)[1]]
+    prompts = prompts_of(lengths)
+    got, _, _ = serve(model, params, prompts, news)
+    return prompts, news, got
+
+
+@pytest.mark.parametrize("wrong", ref.WRONG + ("chunk_of_2", "chunk_of_8", "lowp"))
+def test_each_wrong_reading_of_the_layer_is_told_from_the_served_one(answers, wrong):
+    prompts, news, got = answers
+    assert max(float(g.max()) for g in gaps(got, prompts, news)) < ATOL
+    if wrong.startswith("chunk_of_"):
+        gap = gaps(got, prompts, news, arch={**ARCH, "chunk_size": int(wrong[-1])})
+    elif wrong == "lowp":   # the check's control: inputs at 3 mantissa bits, the stream in bfloat16
+        gap = gaps(got, prompts, news, low=True)
+    else:
+        gap = gaps(got, prompts, news, wrong=wrong)
+    # two layers of a toy: a stream in bfloat16 alone moves a log-probability by 0.01, fifty
+    # tolerances (the cell's control rounds the products' inputs too)
+    assert max(float(g.max()) for g in gap) > (0.005 if wrong == "bf16_stream" else WRONG_BY), wrong
+
+
+def test_the_summary_rows_hold_a_visible_part_of_a_softmaxs_mass(whole):
+    """What a lane reads of its past through summaries matters: with the
+    summaries left out, positions past the first window move by far more than
+    the tolerance, and positions inside it not at all."""
+    model, params = whole
+    prompts, news = prompts_of((14,)), [24]
+    got, _, _ = serve(model, params, prompts, news)
+    gap = gaps(got, prompts, news, wrong="no_summaries")[0]
+    assert float(gap[:2].max()) < ATOL          # positions 13, 14, 15: the first window
+    assert float(np.median(gap[3:].max(axis=-1))) > WRONG_BY
+
+
+# -- a step's walk of the virtual block table --------------------------------------------
+
+class NamedTpu:
+    """``jax`` as ``eva`` sees it with the backend named ``tpu``: the family's
+    trace-time choice takes its TPU branch, and nothing else does."""
+
+    default_backend = staticmethod(lambda: "tpu")
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+
+def paged_attention_in_plain_jnp(q, k_pages, v_pages, lengths, page_indices, *,
+                                 pages_per_compute_block):
+    """The contract of jax's TPU kernel, for a CPU: q (b, H, W) unscaled scores
+    over pages (KV, pages, P, W), lanes of `lengths` rows of their tables."""
+    assert page_indices.shape[1] % pages_per_compute_block == 0
+    b, H, W = q.shape
+    kv = k_pages.shape[0]
+    k = jnp.take(k_pages, page_indices, axis=1).reshape(kv, b, -1, W)
+    v = jnp.take(v_pages, page_indices, axis=1).reshape(kv, b, -1, W)
+    s = jnp.einsum("bkgw,kbcw->bkgc", q.reshape(b, kv, H // kv, W).astype(jnp.float32),
+                   k.astype(jnp.float32))
+    s = jnp.where(jnp.arange(k.shape[2])[None, None, None, :] < lengths[:, None, None, None],
+                  s, -jnp.inf)
+    o = jnp.einsum("bkgc,kbcw->bkgw", jax.nn.softmax(s, axis=-1), v.astype(jnp.float32))
+    return o.reshape(b, H, W).astype(q.dtype)
+
+
+@contextlib.contextmanager
+def in_the_walk(seen=None):
+    """What is traced inside takes ``eva``'s TPU branch at the toy's shapes,
+    jax's kernel replaced by its contract (``seen`` gets each call's table and
+    lengths)."""
+    from jax.experimental.pallas.ops.tpu import paged_attention as pa
+
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(eva, "jax", NamedTpu())
+        m.setattr(eva.EvaServing, "_walks", lambda self, P: True)
+
+        def walk(q, kp, vp, lengths, table, **kw):
+            if seen is not None:
+                seen.append((table, lengths))
+            return paged_attention_in_plain_jnp(q, kp, vp, lengths, table, **kw)
+
+        m.setattr(pa, "paged_attention", walk)
+        yield
+
+
+def test_a_steps_walk_of_the_virtual_table_is_the_gather_and_reads_live_rows_only(whole):
+    """Every step on the walk's path: the same answers as on the gather's, a
+    table of summary pages then ring pages, lengths the rows the index sets
+    hold, a lane that is not live one row of the sentinel, and the counter."""
+    model, params = whole
+    prompts, news = prompts_of((14, 37, 3)), [24, 12, 7]
+    plain, _, _ = serve(model, params, prompts, news)
+    got, out, _ = serve(model, params, prompts, news, steer=in_the_walk)
+    for a, b in zip(plain, got):
+        np.testing.assert_array_equal(a["tokens"], b["tokens"])
+        np.testing.assert_allclose(a["lp"], b["lp"], atol=ATOL)
+    acc = np.asarray(out["acc"]).astype(int)
+    assert acc[1, 5] == model.n_layers * sum(n - 1 for n in news) and acc[1, 6] == 0
+    # the plan of one step, as arrays: lane 0 live at position 21, lane 1 at 37, lane 2 free
+    pps, c, P = model.kv_pages_per_slot(PAGE), model.chunk, model.rows
+    state = zeros(model.kv_page_signature(SLOTS, SLOTS * pps + 1, PAGE))
+    bt = np.arange(1, 1 + SLOTS * pps).reshape(SLOTS, pps).astype(np.int32)
+    state = dict(state, bt=jnp.asarray(bt), ring=jnp.asarray([1, 2, 3], jnp.int32))
+    with in_the_walk():
+        m = model._step_plan(state, jnp.asarray([True, True, False]),
+                             jnp.asarray([21, 37, 9], jnp.int32))
+    first = c * (SLOTS + 1)
+    table, seen = np.asarray(m["table"]), np.asarray(m["rows_seen"])
+    assert m["path"] == "walk" and table.shape[1] % model.walk_block == 0
+    assert list(seen) == [1 * P + 6, 2 * P + 6, 1]
+    assert list(table[0, :1 + c]) == [first + bt[0, 0]] + [c * 1 + i for i in range(c)]
+    assert list(table[1, :2 + c]) == [first + bt[1, 0], first + bt[1, 1]] + [c * 2 + i
+                                                                              for i in range(c)]
+    assert not table[2].any() and not table[0, 1 + c:].any()
+    # position 37 does not end a chunk, 21 does not either; 23 would: its summary's place
+    m = model._step_plan(state, jnp.asarray([True, True, False]),
+                         jnp.asarray([23, 37, 9], jnp.int32))
+    assert list(np.asarray(m["sum_page"])) == [first + bt[0, 1], first, first]
+    assert list(np.asarray(m["sum_off"]))[:1] == [(23 % 16) // c]
+    assert list(np.asarray(m["ring_page"])) == [c * 1 + 7 // P, c * 2 + 5 // P, 0 + 9 // P]
+
+
+def test_jaxs_kernel_walks_the_virtual_table_in_the_interpreter(tmp_path):
+    """jax's own ``paged_attention`` over a pool of rings and summary pages, at
+    the cell's head width, in the TPU interpreter where this jax has one:
+    against the gather of the same table."""
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention
+
+    interpret = getattr(pltpu, "force_tpu_interpret_mode", None)
+    if interpret is None:
+        pytest.skip("this jax has no TPU interpreter to force")
+    arch = {**ARCH, "hidden_size": 256, "num_attention_heads": 2, "num_key_value_heads": 2,
+            "window_size": 32, "chunk_size": 4}
+    model = make_model(str(tmp_path), arch, name="wide", dtype="bfloat16")
+    assert model.hd == 128 and model.rows == 8
+    rng = np.random.default_rng(3)
+    slots, pps, c, P = 2, 3, 4, 8
+    pages = c * (slots + 1) + slots * pps + 1
+    kp = jnp.asarray(rng.standard_normal((2, pages, P, 128)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((2, pages, P, 128)), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((slots, 2, 128)), jnp.bfloat16)
+    state = {"bt": jnp.asarray(np.arange(1, 1 + slots * pps).reshape(slots, pps), jnp.int32),
+             "ring": jnp.asarray([1, 2], jnp.int32), "pos": jnp.zeros((slots,), jnp.int32),
+             "kf": [kp]}
+    m = model._step_plan(state, jnp.asarray([True, True]), jnp.asarray([70, 13], jnp.int32))
+    want = model._decode_gather(q, (kp, vp), m["table"], m["rows_seen"] - 1, model._heads())
+    qs = (q.astype(jnp.float32) * model._scale()).astype(q.dtype)
+    try:
+        with interpret():
+            got = paged_attention(qs, kp, vp, m["rows_seen"], m["table"],
+                                  pages_per_compute_block=model.walk_block)
+    except Exception as e:  # noqa: BLE001 — an interpreter that lacks what the kernel uses
+        pytest.skip(f"the TPU interpreter does not run jax's kernel here: {type(e).__name__}")
+    # bfloat16 queries scaled before the product and a bfloat16 context: 2 ** -8 of values near 1
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=3e-2)
+    assert float(jnp.abs(want).max()) > 0.3
+
+
+# -- free and frozen lanes, a slot's next tenant ----------------------------------------------
+
+def test_a_lane_that_is_not_live_keeps_ring_pages_and_lanes_to_the_bit(whole):
+    model, params = whole
+    prompts, news = prompts_of((14, 37, 3)), [24, 12, 7]
+    _, _, state = serve(model, params, prompts, news)
+    again, _ = jax.jit(model.step)(params, state)   # every lane is done
+    c = model.chunk
+    live_rings = slice(c, None)                     # ring 0 and page 0 are the sentinels
+    for leaf in ("kf", "vf"):
+        for a, b in zip(state[leaf], again[leaf]):
+            a, b = np.asarray(a), np.asarray(b)
+            np.testing.assert_array_equal(a[:, live_rings][:, :c * SLOTS], b[:, live_rings][:, :c * SLOTS])
+            np.testing.assert_array_equal(a[:, c * (SLOTS + 1) + 1:], b[:, c * (SLOTS + 1) + 1:])
+    for leaf in ("pos", "n_new", "tokens", "lp", "last", "ring", "bt"):
+        np.testing.assert_array_equal(np.asarray(state[leaf]), np.asarray(again[leaf]))
+    # a shorter tenant in a slot whose ring and pages hold the last one's rows
+    short = prompts_of((5, 9, 20), seed=8)
+    reused, _, _ = serve(model, params, short, [6, 6, 6], state=again)
+    fresh, _, _ = serve(model, params, short, [6, 6, 6])
+    for a, b in zip(reused, fresh):
+        np.testing.assert_array_equal(a["tokens"][:6], b["tokens"][:6])
+        np.testing.assert_array_equal(a["lp"][:6], b["lp"][:6])
+
+
+# -- geometry, /stats, the recipe ----------------------------------------------------------
+
+def test_the_caches_geometry_a_page_stands_for_a_window(whole, tmp_path):
+    from tpuserve.genserve.engine import GenEngine
+    from tpuserve.obs import Metrics
+    from tpuserve.runtime import build_runtime
+
+    model, _ = whole
+    assert model.kv_ring_tokens() == 16 and model.rows == 4
+    assert model.kv_page_span(PAGE) == 16 and model.kv_ring_pages(PAGE) == 4
+    assert model.kv_pages_per_slot(PAGE) == 4                       # ceil((40 + 24) / 16)
+    item = lambda n, new: (None, np.int32(n), None, np.int32(new))  # noqa: E731
+    assert [model.pages_needed(item(n, new), PAGE) for n, new in ((1, 1), (10, 6), (10, 7),
+                                                                 (40, 24))] == [1, 1, 2, 4]
+    sig = model.kv_page_signature(SLOTS, 9, PAGE)
+    assert [s.shape for s in sig["kf"]] == [(2, 4 * (SLOTS + 1) + 9, 4, 16)] * 2
+    assert sig["bt"].shape == (SLOTS, 4) and sig["ring"].shape == (SLOTS,)
+    with pytest.raises(ValueError, match="kv_page_tokens"):
+        model.kv_page_signature(SLOTS, 9, 8)
+    with pytest.raises(ValueError, match="at most a window"):
+        model.kv_prefill_pieces(32, PAGE)
+    with pytest.raises(NotImplementedError, match="num_chunks"):
+        make_model(str(tmp_path), {**ARCH, "num_chunks": 8}, name="n")
+    # what the engine says of it: a position's bytes in the pages are a page's over the
+    # window it stands for; the rings' part of the pool apart from the pages'
+    rt = build_runtime(model, compile_forward=False)
+    eng = GenEngine(model, rt, Metrics(), GenserveConfig(
+        slots=SLOTS, kv_paging=True, kv_page_tokens=PAGE, kv_pages=9, prefill_chunk=CHUNK))
+    eng.compile()
+    page = 2 * 2 * 2 * 4 * 16 * 4          # layers x (K, V) x KV heads x rows x hd x float32
+    kv = eng.pipeline_stats()["kv"]
+    assert kv["row_bytes_per_token"] == page // 16 and kv["page_positions"] == 16
+    assert kv["page_bytes"] == 9 * page and kv["ring_bytes"] == (SLOTS + 1) * 4 * page
+    assert kv["kv_bytes"] == kv["page_bytes"] + kv["ring_bytes"]
+    assert kv["rings"] == SLOTS + 1 and kv["page_tokens"] == PAGE
+
+
+def test_the_weights_are_the_recipes_and_the_references(whole):
+    model, params = whole
+    m = ref.Model(ARCH, SEED, "float32")
+    w = m.layer(1)
+    lp = params["layer1"]
+    for name in ("wq", "wk", "wv", "wo", "phi", "mu", "w_gate", "w_up", "w_down"):
+        np.testing.assert_array_equal(np.asarray(lp[name]), w[name], err_msg=name)
+    np.testing.assert_array_equal(np.asarray(lp["norm1"]), w["g1"])
+    np.testing.assert_array_equal(np.asarray(lp["norm2"]), w["g2"])
+    np.testing.assert_array_equal(np.asarray(params["norm_f"]), m.gain("norm_f"))
+    assert float(np.abs(w["g1"]).max()) <= 0.25 and float(np.abs(w["g1"]).max()) > 0.1
+    # the head holds every prediction block; block 0 is what is served
+    assert params["head"].shape == (64, 2 * 40)
+    np.testing.assert_array_equal(np.asarray(params["head"][:, :40]), m.head())
+    np.testing.assert_array_equal(np.asarray(params["embed"]), m.embed())
+    assert np.asarray(seeded.draw(SEED, "layer1/phi", (4, 16), 0.18, jnp.float32)).std() \
+        == pytest.approx(0.18, rel=0.2)
+    # phi decides a chunk's weights: the largest of four is well above a quarter
+    u = np.random.default_rng(1).standard_normal((64, 64)).astype(np.float32)
+    k = np.einsum("td,dhk->thk", u, w["wk"]).reshape(16, 4, 2, 16)
+    wt = jax.nn.softmax(np.einsum("mchd,hd->mch", k, w["phi"]), axis=1)
+    assert 0.4 < float(np.mean(np.max(wt, axis=1))) < 0.8
+
+
+def test_the_benchmarks_copy_of_the_reference_gives_the_same_numbers():
+    from benchmark import spec
+
+    bench = spec.load_module("reference", "eva")
+    assert bench.WRONG == ref.WRONG and bench.DEFAULT_SCALES == ref.DEFAULT_SCALES
+    seqs = prompts_of((37, 5), seed=2)
+    for wrong, low in (("", False), ("", True), ("split_softmax", False)):
+        a = ref.log_probs(ref.Model(ARCH, SEED, "float32", wrong=wrong), seqs, [0, 0], low)
+        b = bench.log_probs(bench.Model(ARCH, SEED, "float32", wrong=wrong), seqs, [0, 0], low)
+        for x, y in zip(a, b):
+            np.testing.assert_array_equal(x, y)

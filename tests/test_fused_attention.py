@@ -832,3 +832,58 @@ def test_a_launchs_chunked_delta_rule_is_one_kernel_call_a_layer_on_the_v5e_at_t
             f"f32[{K},{T},3,{heads},{hd}]", f"f32[{K * T},3,{heads},{hd}]")
     assert not [ln for ln in lines if (" copy(" in ln or " transpose(" in ln)
                 and any(s in ln.split("=")[1][:60] for s in rows)]
+
+
+def test_evas_step_walks_rings_and_pages_in_place_on_the_v5e_at_the_cells_widths(
+        one_chip, tmp_path, monkeypatch):
+    """The `eva` family's two programs (ISSUE 55) at the cell's widths, two layers
+    of them: 32 heads of 128, a window of 2,048 in chunks of 16, 24 slots' rings
+    and 160 pages of 128 summary rows in one pool a layer. The TPU branch is
+    steered by the backend's name here, in the test. A step is one call of
+    jax's `paged_attention` a layer over the virtual block table, and NO copy,
+    transpose or gather of a whole pool exists in it (a pool crossed to another
+    layout once a layer a step when the chunk's rows were gathered from the pool
+    seen flat: 5 ms each by the compiler's own estimate); nor in a launch."""
+    import json
+
+    from tpuserve.config import ModelConfig
+    from tpuserve.models import build
+
+    slots, pages, P, chunk = 24, 160, 128, 1024
+    arch = {"model_type": "evabyte", "attention_class": "eva", "attention_bias": False,
+            "chunk_size": 16, "window_size": 2048, "num_chunks": None, "fp32_ln": False,
+            "fp32_logits": True, "fp32_skip_add": True, "hidden_act": "silu",
+            "hidden_size": 4096, "intermediate_size": 11008, "norm_add_unit_offset": True,
+            "num_attention_heads": 32, "num_key_value_heads": 32, "num_hidden_layers": 2,
+            "num_pred_heads": 8, "rms_norm_eps": 1e-5, "rope_scaling": None,
+            "rope_theta": 100000, "tie_word_embeddings": False, "vocab_size": 320}
+    path = tmp_path / "arch.json"
+    path.write_text(json.dumps(arch))
+    model = build(ModelConfig(name="eva", family="eva", dtype="bfloat16", batch_buckets=[1],
+                              options={"config_file": str(path), "max_prompt_tokens": 24576,
+                                       "max_new_tokens": 512}))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(place, jax.eval_shape(lambda: model.draw_params(0)))
+    state = jax.tree_util.tree_map(place, model.kv_page_signature(slots, pages, P))
+    k = model.kv_prefill_pieces(chunk, P)
+    assert (k, model.kv_pages_per_slot(P), state["kf"][0].shape) == (8, 13, (32, 560, 128, 128))
+    launch = {"ids": (chunk,), "pages": (k, 13), "temp": (k,),
+              **{f: (k,) for f in ("slot", "start", "length", "n", "seed", "max_new", "ring")}}
+    launch = {f: jax.ShapeDtypeStruct(dims, jnp.float32 if f == "temp" else jnp.int32,
+                                      sharding=one_chip) for f, dims in launch.items()}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
+    try:
+        step = jax.jit(model.step, donate_argnums=(1,)).lower(params, state).compile().as_text()
+        fill = jax.jit(lambda p, s, la: model.prefill_chunk(p, s, la, chunk=chunk),
+                       donate_argnums=(1,)).lower(params, state, launch).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    calls = [ln for ln in step.split("\n") if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 2 and all("eva_decode" in ln for ln in calls)
+    whole = ("[32,560,128,128]", "[32,71680,128]", "[32,4480,16,128]")
+    for text in (step, fill):
+        moved = [ln.split("=")[0] for ln in text.split("\n")
+                 if any(f" {op}(" in ln for op in ("copy", "transpose", "gather"))
+                 and any(f"bf16{dims}" in ln.split("=")[1].split("(")[0] for dims in whole)]
+        assert not moved, moved

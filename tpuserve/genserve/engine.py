@@ -64,6 +64,7 @@ from dataclasses import dataclass, field
 from typing import Any
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from tpuserve.batcher import DeadlineExceeded, QueueFull
@@ -579,7 +580,7 @@ class GenEngine:
         # PJRT program load must come off replica k's first request too,
         # not just replica 0's.
         for r in range(getattr(rt, "n_replicas", 1)):
-            state = self._host_zeros(self._state_struct)
+            state = self._zero_state(r)
             with self._dispatch_guard():
                 if self.paging:
                     for launch in self._canary_launches(item):
@@ -601,9 +602,23 @@ class GenEngine:
         return jax.tree_util.tree_map(
             lambda s: np.zeros(tuple(s.shape), s.dtype), struct)
 
+    def _zero_state(self, replica: "int | None" = None) -> Any:
+        """The state block as zeros, where a program will take it. A mesh of
+        ONE device gets them made there: host zeros cross to the device when
+        the first program takes them, and a block of 8.75 GiB took 30 s to
+        cross, twice a start-up (prewarm, then the serving block: my chip
+        run, PR 55). A mesh of several takes host zeros, which the program
+        places by its own specs."""
+        mesh = self.runtime.meshes[self.replica if replica is None else replica]
+        if mesh.size != 1:
+            return self._host_zeros(self._state_struct)
+        with jax.default_device(mesh.devices.flat[0]):
+            return jax.tree_util.tree_map(
+                lambda s: jnp.zeros(tuple(s.shape), s.dtype), self._state_struct)
+
     # -- lifecycle ------------------------------------------------------------
     async def start(self) -> None:
-        self._state = self._host_zeros(self._state_struct)
+        self._state = self._zero_state()
         if self.pages is not None:
             peers = [e for e in (self.peers or [self])
                      if e.pages is not None]
@@ -1746,7 +1761,7 @@ class GenEngine:
         if self.pages is not None:
             self.pages.release_all()
             self._update_kv_gauges()
-        self._state = self._host_zeros(self._state_struct)
+        self._state = self._zero_state()
         # The step ahead was a step of the failed block: its out-block is
         # dropped, never read beside the new one (nor its ``acc`` into
         # ``observe_step``). An extract dispatched before the failure keeps
@@ -1771,7 +1786,7 @@ class GenEngine:
         model, rt = self.model, self.runtime
         r = self.replica
         item = model.canary_item()
-        state = self._host_zeros(self._state_struct)
+        state = self._zero_state()
         with self._dispatch_guard():
             if self.paging:
                 for launch in self._canary_launches(item):
@@ -1895,6 +1910,13 @@ class GenEngine:
                 "queued_pages": self._queued_pages(),
                 "kv_bytes": self.kv_cache_bytes(),
                 "row_bytes_per_token": self.kv_row_bytes(),
+                # Positions a page stands for (its rows, unless a row sums
+                # several positions up) and, where the rings lie in the page
+                # leaves, what of ``kv_bytes`` is rings and what pages.
+                "page_positions": int(self.model.kv_page_span(self.pages.page_tokens)),
+                **({"ring_bytes": self._ring_pages() * self._pool_page_bytes(),
+                    "page_bytes": self.pages.pages * self._pool_page_bytes()}
+                   if self._ring_pages() else {}),
                 # The third kind (ISSUE 32): a fixed block a slot, beside pages.
                 "state_bytes_per_slot": self.slot_state_bytes() // self.slots,
                 "state_bytes": self.slot_state_bytes(),
@@ -1984,8 +2006,18 @@ class GenEngine:
         ``mla``. 0 without paging."""
         if self.pages is None:
             return 0
+        return self._pool_page_bytes() \
+            // int(self.model.kv_page_span(self.pages.page_tokens))
+
+    def _ring_pages(self) -> int:
+        """Pages of the page leaves that the rings take, where the family keeps
+        its rings in them (``kv_ring_pages``: the sentinel's and a slot's each)."""
+        return (self.slots + 1) * int(self.model.kv_ring_pages(self.pages.page_tokens))
+
+    def _pool_page_bytes(self) -> int:
+        """Device bytes of ONE page of the page leaves, all layers."""
         return self._leaf_bytes(self.model.kv_page_leaves) \
-            // (self.pages.pages * self.pages.page_tokens)
+            // (self.pages.pages + self._ring_pages())
 
 
 class GenEngineGroup:

@@ -192,6 +192,23 @@ class GenerativeModel(ServingModel):
     # ``gen_kv_row_bytes``; ``kv.kv_bytes``).
     kv_page_leaves: tuple = ("kp", "vp")
 
+    def kv_page_span(self, page_tokens: int) -> int:
+        """Host-side: positions of context ONE page of the pools stands for.
+        Its rows (the default), unless a row is a pool of several positions'
+        rows (ISSUE 55: ``eva``'s page of ``window / chunk`` summary rows stands
+        for a whole window): the engine divides a page's bytes by it for
+        ``kv.row_bytes_per_token``."""
+        return int(page_tokens)
+
+    def kv_ring_pages(self, page_tokens: int) -> int:
+        """Host-side: pages of the ``kv_page_leaves``' page dimension that ONE
+        ring takes, for a family that keeps its rings in the SAME leaves as its
+        pages, before them (ISSUE 55: a ring's row has a page row's shape, so a
+        walk reads both through one table); the leaves then hold ``(slots + 1)``
+        rings' pages and the ledger's. 0 (the default): rings are leaves of
+        their own."""
+        return 0
+
     def share_stats(self) -> "dict | None":
         """Host-side: what of each layer this chip holds, where the model is
         one chip's share of a deployment (/stats ``pipeline.models.<m>.share``);

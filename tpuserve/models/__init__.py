@@ -53,6 +53,13 @@ Families (BASELINE.json ``configs``):
                    elementwise output gate, with sigmoid-routed experts and a
                    shared one in every layer; a share of the experts and the
                    vocabulary (ISSUE 53)
+- eva            — ``decoder``'s sibling for EVA attention: every query over
+                   the exact keys of its own ALIGNED window and one
+                   learned-pooled summary row a chunk of every earlier window
+                   in one softmax; a ring a slot beside pages of summary rows
+                   in one pool a layer, walked as one virtual block table; a
+                   float32 stream, gains ``1 + g``, a head of several
+                   prediction blocks of which the first is served (ISSUE 55)
 - toy            — a linear classifier for tests and drills
 """
 
@@ -79,6 +86,7 @@ _REGISTRY: dict[str, str] = {
     "mla_hc": "tpuserve.models.mla_hc",
     "decoder_sink": "tpuserve.models.decoder_sink",
     "hybrid_delta": "tpuserve.models.hybrid_delta",
+    "eva": "tpuserve.models.eva",
     "toy": "tpuserve.models.toy",
 }
 
