@@ -6,13 +6,12 @@ slots' rings of 16 pages and 160 summary pages of 128 rows), timed ON THE DEVICE
 (the program's own line in a trace, not the host's clock), the lanes' contexts
 drawn from the mix or all alike, in both walks the repo has:
 
-- jax's `paged_attention` (what `tpuserve/models/eva.py` `_walk` calls) at
-  several `pages_per_compute_block` (`EvaServing.walk_block` comes from here);
-- `ops/lane_attention.py` `head_walk` over the same table as a flat work list.
-  That kernel takes a key in TWO parts (one that passes the rotary by, one that
-  turns) and EVA's keys are one part of 128 columns, so it is fed the K pool as
-  both, the turning part's queries zero: it reads K twice, and its time is an
-  UPPER bound on what a one-part variant of it would take;
+- `ops/lane_attention.py` `head_walk` with a key in ONE part (ISSUE 56; what
+  `tpuserve/models/eva.py` `_walk` calls) over the table as a flat work list of
+  the (lane, key block) items that exist, at several pages a block
+  (`EvaServing.walk_block` comes from here);
+- jax's `paged_attention` at several `pages_per_compute_block`, the yardstick:
+  what `_walk` called until ISSUE 56 (at 8 pages);
 
 beside (1) a plain pass over the pages the walk had to read and (2) the least
 time by `benchmark/flops/eva.py` `attend_decode` for one layer.
@@ -60,7 +59,7 @@ def tables(pos: np.ndarray, W: int, c: int, P: int, pps: int, slots: int, wide: 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rehearse", action="store_true")
-    ap.add_argument("--blocks", default="1,2,4,8,16")
+    ap.add_argument("--blocks", default="1,2,4,8")
     ap.add_argument("--iters", type=int, default=8)
     args = ap.parse_args()
     on_tpu = jax.default_backend() == "tpu"
@@ -112,13 +111,12 @@ def main() -> int:
                 lines.append({**base, "walk": "paged_attention", "block_pages": block, "ms": ms})
             # the same table as a flat work list of (lane, key block) items
             work = la.work_list(jnp.asarray(seen - 1), t, P, block)
-            zeros = jnp.zeros_like(q)
-            fn = jax.jit(lambda q, z, kp, vp, work: la.head_walk(
-                q, z, kp, kp, vp, work, scale=hd ** -0.5, interpret=not on_tpu))
+            fn = jax.jit(lambda q, kp, vp, work: la.head_walk(
+                q, None, kp, None, vp, work, scale=hd ** -0.5, interpret=not on_tpu))
             if on_tpu or block == 4:
-                ms = device_ms(fn, (q, zeros, kp, vp, work), None, out_dir, args.iters)
-                lines.append({**base, "walk": "head_walk (K read twice)", "block_pages": block,
-                              "ms": ms})
+                ms = device_ms(fn, (q, kp, vp, work), None, out_dir, args.iters)
+                lines.append({**base, "walk": "head_walk (a key in one part)",
+                              "block_pages": block, "items": int(work["items"]), "ms": ms})
         if on_tpu:   # a plain pass over the pages the walk had to read: in and out
             table, _ = tables(pos, W, c, P, pps, slots, pps + c)
             need = np.unique(np.concatenate([row[:-(-int(r) // P)] for row, r in zip(

@@ -53,7 +53,7 @@ SERIES = {
         "eva_rows_attended_total{model=M,phase=PH,kind=exact}",
         "eva_rows_attended_total{model=M,phase=PH,kind=summary}",
         "eva_chunks_summarised_total{model=M,phase=PH}", "eva_windows_closed_total{model=M,phase=PH}",
-        "decode:eva_decode_steps_total{model=M,phase=decode,path=walk}",
+        "decode:eva_decode_steps_total{model=M,phase=decode,path=head_walk}",
         "decode:eva_decode_steps_total{model=M,phase=decode,path=gather}"],
 }
 # The fourth expert column sums held experts x expert layers run, so it feeds

@@ -588,6 +588,7 @@ def test_head_fits_takes_groups_of_8_rows_together_and_of_16_apart():
     assert la.head_fits(128, 64, 8, 128, 128, 128, bf) and not la._split(64, 8)
     assert la.head_fits(128, 64, 4, 128, 128, 128, bf) and la._split(64, 4)
     assert la.head_fits(16, 32, 2, 128, 128, 128, bf) and la._split(32, 2)
+    assert la.head_fits(128, 32, 32, 128, 0, 128, bf) and not la._split(32, 32)   # a key in one part
     for refused in ((128, 8, 4, 128, 128, 128, bf),       # 8 query rows in all: half a tile
                     (128, 64, 8, 128, 128, 128, jnp.float32), (128, 64, 8, 64, 128, 128, bf),
                     (8, 64, 8, 128, 128, 128, bf), (128, 60, 8, 128, 128, 128, bf)):

@@ -3,8 +3,9 @@ CPU: packed chunked prefill and decode through a ring a slot and pages of
 summary rows in one pool a layer, against the reference's full pass (windows
 that close in decode, inside a piece, at a piece's last row; a prompt of
 exactly a window; a padded tail); each wrong reading of the layer failing; the
-virtual block table's walk (jax's kernel in the Pallas interpreter where it
-runs here, else its contract in plain jnp) against the gather; a lane that is
+virtual block table's walk (`head_walk` with a key in one part, ISSUE 56, in
+the Pallas interpreter: the toy's step against the gather's step, and at the
+cell's heads against a plain float32 softmax); a lane that is
 not live keeping ring, pages and lanes to the bit; the cache's geometry and what
 `/stats` says of it; the weights recipe; the counters; and the two copies of
 the reference."""
@@ -27,6 +28,7 @@ from tpuserve.models import build
 from tpuserve.models import decoder as dec
 from tpuserve.models import eva
 from tpuserve.models import seeded
+from tpuserve.ops import lane_attention as la
 
 # Two layers; 4 query heads of 16 on 2 KV heads (the cell has no grouping; the
 # walk is written for any); a window of 16 in chunks of 4, so a page is 4
@@ -214,54 +216,42 @@ class NamedTpu:
         return getattr(jax, name)
 
 
-def paged_attention_in_plain_jnp(q, k_pages, v_pages, lengths, page_indices, *,
-                                 pages_per_compute_block):
-    """The contract of jax's TPU kernel, for a CPU: q (b, H, W) unscaled scores
-    over pages (KV, pages, P, W), lanes of `lengths` rows of their tables."""
-    assert page_indices.shape[1] % pages_per_compute_block == 0
-    b, H, W = q.shape
-    kv = k_pages.shape[0]
-    k = jnp.take(k_pages, page_indices, axis=1).reshape(kv, b, -1, W)
-    v = jnp.take(v_pages, page_indices, axis=1).reshape(kv, b, -1, W)
-    s = jnp.einsum("bkgw,kbcw->bkgc", q.reshape(b, kv, H // kv, W).astype(jnp.float32),
-                   k.astype(jnp.float32))
-    s = jnp.where(jnp.arange(k.shape[2])[None, None, None, :] < lengths[:, None, None, None],
-                  s, -jnp.inf)
-    o = jnp.einsum("bkgc,kbcw->bkgw", jax.nn.softmax(s, axis=-1), v.astype(jnp.float32))
-    return o.reshape(b, H, W).astype(q.dtype)
-
-
 @contextlib.contextmanager
 def in_the_walk(seen=None):
-    """What is traced inside takes ``eva``'s TPU branch at the toy's shapes,
-    jax's kernel replaced by its contract (``seen`` gets each call's table and
-    lengths)."""
-    from jax.experimental.pallas.ops.tpu import paged_attention as pa
-
+    """What is traced inside takes ``eva``'s TPU branch at the toy's shapes
+    (which the interpreter takes and ``head_fits`` would refuse), ``head_walk``
+    in the Pallas interpreter (``seen`` gets each call's operands and work
+    list)."""
     with pytest.MonkeyPatch.context() as m:
         m.setattr(eva, "jax", NamedTpu())
         m.setattr(eva.EvaServing, "_walks", lambda self, P: True)
 
-        def walk(q, kp, vp, lengths, table, **kw):
+        def walk(*a, f=la.head_walk, **k):
             if seen is not None:
-                seen.append((table, lengths))
-            return paged_attention_in_plain_jnp(q, kp, vp, lengths, table, **kw)
+                seen.append(a)
+            return f(*a, interpret=True, **k)
 
-        m.setattr(pa, "paged_attention", walk)
+        m.setattr(la, "head_walk", walk)
         yield
 
 
 def test_a_steps_walk_of_the_virtual_table_is_the_gather_and_reads_live_rows_only(whole):
-    """Every step on the walk's path: the same answers as on the gather's, a
-    table of summary pages then ring pages, lengths the rows the index sets
-    hold, a lane that is not live one row of the sentinel, and the counter."""
+    """Every step in the kernel (``head_walk`` with a key in one part, the
+    interpreter): the same answers as on the gather's path, ONE work list a
+    step shared by the layers, a table of summary pages then ring pages,
+    lengths the rows the index sets hold, a lane that is not live one row of
+    the sentinel, and the counter."""
     model, params = whole
     prompts, news = prompts_of((14, 37, 3)), [24, 12, 7]
     plain, _, _ = serve(model, params, prompts, news)
-    got, out, _ = serve(model, params, prompts, news, steer=in_the_walk)
+    seen = []
+    got, out, _ = serve(model, params, prompts, news, steer=lambda: in_the_walk(seen))
     for a, b in zip(plain, got):
         np.testing.assert_array_equal(a["tokens"], b["tokens"])
         np.testing.assert_allclose(a["lp"], b["lp"], atol=ATOL)
+    # one trace: a call a layer, a key in one part, every layer the same work list
+    assert len(seen) == model.n_layers and all(a[1] is None and a[3] is None for a in seen)
+    assert all(a[5] is seen[0][5] for a in seen)
     acc = np.asarray(out["acc"]).astype(int)
     assert acc[1, 5] == model.n_layers * sum(n - 1 for n in news) and acc[1, 6] == 0
     # the plan of one step, as arrays: lane 0 live at position 21, lane 1 at 37, lane 2 free
@@ -273,56 +263,98 @@ def test_a_steps_walk_of_the_virtual_table_is_the_gather_and_reads_live_rows_onl
         m = model._step_plan(state, jnp.asarray([True, True, False]),
                              jnp.asarray([21, 37, 9], jnp.int32))
     first = c * (SLOTS + 1)
-    table, seen = np.asarray(m["table"]), np.asarray(m["rows_seen"])
-    assert m["path"] == "walk" and table.shape[1] % model.walk_block == 0
-    assert list(seen) == [1 * P + 6, 2 * P + 6, 1]
+    table, rows = np.asarray(m["table"]), np.asarray(m["rows_seen"])
+    assert m["path"] == "head_walk" and table.shape[1] == pps + c
+    assert list(rows) == [1 * P + 6, 2 * P + 6, 1]
     assert list(table[0, :1 + c]) == [first + bt[0, 0]] + [c * 1 + i for i in range(c)]
     assert list(table[1, :2 + c]) == [first + bt[1, 0], first + bt[1, 1]] + [c * 2 + i
                                                                               for i in range(c)]
     assert not table[2].any() and not table[0, 1 + c:].any()
-    # position 37 does not end a chunk, 21 does not either; 23 would: its summary's place
+    # the work list: each lane's blocks as far as ITS rows go, the free lane's one
+    work, kb = m["work"], model.walk_block
+    need = [-(-int(r) // (kb * P)) for r in rows]
+    assert int(work["items"]) == sum(need)
+    assert list(np.asarray(work["lane"])[:sum(need)]) == [b for b, n in enumerate(need)
+                                                          for _ in range(n)]
+    assert list(np.asarray(work["last"])) == [int(r) - 1 for r in rows]
+    # off the TPU the plan holds no work list and the gather reads the same table
     m = model._step_plan(state, jnp.asarray([True, True, False]),
                          jnp.asarray([23, 37, 9], jnp.int32))
+    assert m["path"] == "gather" and m["work"] is None
+    # position 37 does not end a chunk, 21 does not either; 23 does: its summary's place
     assert list(np.asarray(m["sum_page"])) == [first + bt[0, 1], first, first]
     assert list(np.asarray(m["sum_off"]))[:1] == [(23 % 16) // c]
     assert list(np.asarray(m["ring_page"])) == [c * 1 + 7 // P, c * 2 + 5 // P, 0 + 9 // P]
 
 
-def test_jaxs_kernel_walks_the_virtual_table_in_the_interpreter(tmp_path):
-    """jax's own ``paged_attention`` over a pool of rings and summary pages, at
-    the cell's head width, in the TPU interpreter where this jax has one:
-    against the gather of the same table."""
-    from jax.experimental.pallas import tpu as pltpu
-    from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention
+# A step's lanes at the cell's heads (32 query rows on 32 KV heads of 128) over a
+# window of 64 in chunks of 4: a page of 16 rows, a ring of 4 pages. The position
+# each lane's step is at (its row is in the ring already), live or not.
+WALK_CASES = {
+    "a-lane-in-its-first-window": ([37, 5, 0], [True] * 3),           # no summary page
+    "a-lane-at-a-windows-last-place": ([63, 191, 127], [True] * 3),   # j = W - 1
+    "lanes-at-three-and-a-half-windows": ([224, 225, 239], [True] * 3),
+    "a-lane-that-is-not-live-beside-live-ones": ([100, 230, 40], [True, False, True]),
+}
 
-    interpret = getattr(pltpu, "force_tpu_interpret_mode", None)
-    if interpret is None:
-        pytest.skip("this jax has no TPU interpreter to force")
-    arch = {**ARCH, "hidden_size": 256, "num_attention_heads": 2, "num_key_value_heads": 2,
-            "window_size": 32, "chunk_size": 4}
-    model = make_model(str(tmp_path), arch, name="wide", dtype="bfloat16")
-    assert model.hd == 128 and model.rows == 8
-    rng = np.random.default_rng(3)
-    slots, pps, c, P = 2, 3, 4, 8
-    pages = c * (slots + 1) + slots * pps + 1
-    kp = jnp.asarray(rng.standard_normal((2, pages, P, 128)), jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((2, pages, P, 128)), jnp.bfloat16)
-    q = jnp.asarray(rng.standard_normal((slots, 2, 128)), jnp.bfloat16)
-    state = {"bt": jnp.asarray(np.arange(1, 1 + slots * pps).reshape(slots, pps), jnp.int32),
-             "ring": jnp.asarray([1, 2], jnp.int32), "pos": jnp.zeros((slots,), jnp.int32),
-             "kf": [kp]}
-    m = model._step_plan(state, jnp.asarray([True, True]), jnp.asarray([70, 13], jnp.int32))
-    want = model._decode_gather(q, (kp, vp), m["table"], m["rows_seen"] - 1, model._heads())
-    qs = (q.astype(jnp.float32) * model._scale()).astype(q.dtype)
-    try:
-        with interpret():
-            got = paged_attention(qs, kp, vp, m["rows_seen"], m["table"],
-                                  pages_per_compute_block=model.walk_block)
-    except Exception as e:  # noqa: BLE001 — an interpreter that lacks what the kernel uses
-        pytest.skip(f"the TPU interpreter does not run jax's kernel here: {type(e).__name__}")
-    # bfloat16 queries scaled before the product and a bfloat16 context: 2 ** -8 of values near 1
-    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=3e-2)
-    assert float(jnp.abs(want).max()) > 0.3
+
+@pytest.fixture(scope="module")
+def cell_heads(tmp_path_factory):
+    arch = {**ARCH, "hidden_size": 4096, "num_attention_heads": 32, "num_key_value_heads": 32,
+            "window_size": 64, "chunk_size": 4}
+    model = make_model(str(tmp_path_factory.mktemp("heads")), arch, name="heads",
+                       dtype="bfloat16", max_prompt_tokens=232)
+    assert (model.hd, model.rows, model.kv) == (128, 16, 32)
+    return model
+
+
+@pytest.mark.parametrize("block_pages", [1, 2], ids=["one-page-a-block", "two-pages-a-block"])
+@pytest.mark.parametrize("case", list(WALK_CASES))
+def test_the_walk_with_a_key_in_one_part_is_plain_float32_attention(cell_heads, monkeypatch, case,
+                                                                    block_pages):
+    """`head_walk` with NO second key part (ISSUE 56) in the Pallas
+    interpreter, at EVA's shapes (32 query rows on 32 KV heads: every row over
+    each head's keys, a row keeping its own head's), over the step plan's own
+    virtual table and work list in a pool of rings and summary pages: against
+    a plain float32 softmax over each lane's rows taken from the pool one by
+    one. bfloat16 products with float32 sums in the kernel and a context
+    rounded to bfloat16: 2 ** -8 of values near 1."""
+    model = cell_heads
+    pos, live = (np.asarray(x) for x in WALK_CASES[case])
+    slots, (c, P, W), hd = len(pos), (model.chunk, model.rows, model.window), model.hd
+    pps = model.kv_pages_per_slot(P)
+    rng = np.random.default_rng(int(pos.sum()) + block_pages)
+    n_pages = c * (slots + 1) + slots * pps + 1
+    kp, vp = (jnp.asarray(rng.standard_normal((model.kv, n_pages, P, hd)), jnp.bfloat16)
+              for _ in range(2))
+    q = jnp.asarray(2.0 * rng.standard_normal((slots, model.kv, hd)), jnp.bfloat16)
+    state = {"bt": jnp.asarray(rng.permutation(np.arange(1, 1 + slots * pps))
+                               .reshape(slots, pps), jnp.int32),
+             "ring": jnp.asarray(rng.permutation(np.arange(1, slots + 1)), jnp.int32),
+             "pos": jnp.zeros((slots,), jnp.int32), "kf": [kp]}
+    monkeypatch.setattr(eva, "jax", NamedTpu())
+    monkeypatch.setattr(model, "walk_block", block_pages)
+    assert model._walks(P)                      # the cell's shapes fit the kernel as they are
+    m = model._step_plan(state, jnp.asarray(live), jnp.asarray(pos, jnp.int32))
+    rows = np.where(live, pos // W * P + pos % W + 1, 1)
+    assert m["path"] == "head_walk" and list(np.asarray(m["rows_seen"])) == list(rows)
+    assert int(m["work"]["items"]) == sum(-(-int(r) // (block_pages * P)) for r in rows)
+    got = np.asarray(la.head_walk(q, None, kp, None, vp, m["work"], scale=model._scale(),
+                                  interpret=True).astype(jnp.float32))
+    assert got.shape == (slots, model.kv, hd) and np.isfinite(got).all()   # a free lane's too
+    table = np.asarray(m["table"])
+    k32, v32, q32 = (np.asarray(x.astype(jnp.float32)) for x in (kp, vp, q))
+    for b in np.flatnonzero(live):
+        keys, values = (x[:, table[b]].reshape(model.kv, -1, hd)[:, :rows[b]] for x in (k32, v32))
+        s = np.einsum("hd,hcd->hc", q32[b], keys) * hd ** -0.5
+        p = np.exp(s - s.max(axis=-1, keepdims=True))
+        want = np.einsum("hc,hcd->hd", p / p.sum(axis=-1, keepdims=True), values)
+        np.testing.assert_allclose(got[b], want, atol=2e-2)
+        assert np.abs(want).max() > 0.3
+    # and the program's own gather of the same table says the same
+    xla = np.asarray(model._decode_gather(q, (kp, vp), m["table"], m["rows_seen"] - 1,
+                                          model._heads()))
+    np.testing.assert_allclose(got[live], xla[live], atol=2e-2)
 
 
 # -- free and frozen lanes, a slot's next tenant ----------------------------------------------

@@ -839,11 +839,14 @@ def test_evas_step_walks_rings_and_pages_in_place_on_the_v5e_at_the_cells_widths
     """The `eva` family's two programs (ISSUE 55) at the cell's widths, two layers
     of them: 32 heads of 128, a window of 2,048 in chunks of 16, 24 slots' rings
     and 160 pages of 128 summary rows in one pool a layer. The TPU branch is
-    steered by the backend's name here, in the test. A step is one call of
-    jax's `paged_attention` a layer over the virtual block table, and NO copy,
-    transpose or gather of a whole pool exists in it (a pool crossed to another
-    layout once a layer a step when the chunk's rows were gathered from the pool
-    seen flat: 5 ms each by the compiler's own estimate); nor in a launch."""
+    steered by the backend's name here, in the test. A step is ONE call of the
+    repo's own `head_walk` a layer (ISSUE 56: a key in one part, the pools as
+    they lie; Mosaic takes it at 32 query rows on 32 KV heads) over the virtual
+    block table's work list, under `eva_decode`; jax's `paged_attention` is in
+    neither program; and NO copy, transpose or gather of a whole pool exists in
+    a step (a pool crossed to another layout once a layer a step when the
+    chunk's rows were gathered from the pool seen flat: 5 ms each by the
+    compiler's own estimate), nor in a launch."""
     import json
 
     from tpuserve.config import ModelConfig
@@ -880,7 +883,8 @@ def test_evas_step_walks_rings_and_pages_in_place_on_the_v5e_at_the_cells_widths
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
     calls = [ln for ln in step.split("\n") if " custom-call(" in ln and "tpu_custom_call" in ln]
-    assert len(calls) == 2 and all("eva_decode" in ln for ln in calls)
+    assert len(calls) == 2 and all("eva_decode" in ln and "head_walk" in ln for ln in calls)
+    assert "paged_attention" not in step and "paged_attention" not in fill
     whole = ("[32,560,128,128]", "[32,71680,128]", "[32,4480,16,128]")
     for text in (step, fill):
         moved = [ln.split("=")[0] for ln in text.split("\n")

@@ -44,9 +44,12 @@ pool page ``c (slots + 1) + p`` (its page 0 the summaries' sentinel).
 A STEP (scope ``eva_decode``) writes its row to the ring and then attends ONE
 virtual block table a lane, READ IN PLACE: the lane's ``i // W`` summary pages
 followed by its ring's ``c`` pages, of virtual length ``(i // W) (W / c) + i % W
-+ 1``. On the TPU in bfloat16 jax's ``paged_attention`` walks it (lengths and a
-table, as ``decoder``'s full layers); elsewhere the gather of the padded table
-(``_decode_gather``). ``eva_decode_steps_total{path=walk|gather}`` says which.
++ 1``. On the TPU in bfloat16 the repo's own ``ops/lane_attention.py``
+``head_walk`` walks it (ISSUE 56): ONE flat work list a step of the (lane, key
+block) items that exist, shared by the layers, a cell an item for ALL heads
+over the pools as they lie, a key in one part, the scores scaled in float32
+inside the cell; elsewhere the gather of the padded table (``_decode_gather``).
+``eva_decode_steps_total{path=head_walk|gather}`` says which.
 Where ``i % c == c - 1`` the step then pools the chunk's rows from the ring and
 writes the summary row (scope ``eva_summarise``); a lane whose chunk has not
 ended writes to the sentinel.
@@ -78,6 +81,7 @@ from tpuserve.config import ModelConfig
 from tpuserve.models import decoder as dec
 from tpuserve.models.paged_lm import (CONTEXT_COLUMN, NEG, Column, _mm, counted,
                                       read_config_file, series)
+from tpuserve.ops import lane_attention as la
 
 # What this family draws otherwise than ``decoder``: ``phi`` so that a chunk's
 # weights are decided (``phi . k`` of standard deviation 2: the largest of 16
@@ -85,7 +89,7 @@ from tpuserve.models.paged_lm import (CONTEXT_COLUMN, NEG, Column, _mm, counted,
 # softmax's mass against a window's exact rows (the cell's configuration file
 # says how much), the gains' ``g`` inside [-gain, gain] about 0 (a gain is 1 + g).
 DEFAULT_SCALES = {**dec.DEFAULT_SCALES, "qk": 1.0, "phi": 0.18, "mu": 1.0, "gain": 0.25}
-PATHS = ("walk", "gather")
+PATHS = ("head_walk", "gather")
 WINDOW_KIND = "sliding_attention"   # what ``decoder`` calls a layer that keeps a ring
 
 
@@ -110,12 +114,12 @@ class EvaServing(dec.DecoderServing):
         Column(counted("chunks"), series("eva_chunks_summarised_total")),
         Column(counted("windows"), series("eva_windows_closed_total")),
         *_decode_path("paths", "eva_decode_steps_total"))
-    # Pages a compute block of the step's walk holds: the kernel reads a block
-    # whole whatever the lane's length, so small ones follow the live rows and
-    # large ones save steps of its loop. One layer of 24 lanes at the mix's
-    # contexts (26,663 rows) took 3.07 / 1.88 / 1.34 / 1.04 / 1.16 ms at 1 / 2 /
-    # 4 / 8 / 16 pages (scripts/bench_eva_walk.py, my chip run, PR 55).
-    walk_block = 8
+    # Pages a cell of the step's walk holds (``head_walk``'s key block): a cell
+    # reads its pages whole whatever the lane's length, so small ones follow the
+    # live rows and large ones save cells. One layer of 24 lanes at the mix's
+    # contexts (26,663 rows) took 0.61 / 0.65 / 0.70 / 0.79 ms at 1 / 2 / 4 / 8 pages
+    # (scripts/bench_eva_walk.py, my chip run, PR 56).
+    walk_block = 1
     key_block = 512   # summary rows a block of a launch's walk
 
     def __init__(self, cfg: ModelConfig) -> None:
@@ -254,24 +258,26 @@ class EvaServing(dec.DecoderServing):
     def _step_plan(self, state, live, pos) -> dict:
         """``decoder``'s ring places, and: each lane's VIRTUAL block table (its
         closed windows' summary pages, then its ring's pages) with its virtual
-        length, the path the walk takes, and where the chunk that this step
-        may end is pooled from and written to. A lane that is not live walks
+        length, the path the walk takes with (in the kernel) its work list,
+        and where the chunk that this step may end is pooled from and written to. A lane that is not live walks
         one row of the rings' sentinel and writes to the sentinels."""
         m = super()._step_plan(state, live, pos)
         c, P, W, first = self.chunk, self.rows, self.window, self._first_page(state)
         bt, n, j = state["bt"], pos // W, pos % W
         pps = bt.shape[1]
-        wide = -(-(pps + c) // self.walk_block) * self.walk_block
-        i = jnp.arange(wide)[None, :]
+        i = jnp.arange(pps + c)[None, :]
         closed, ring = n[:, None], m["w_ring"][:, None]
-        table = jnp.where(i < closed, first + bt[:, jnp.minimum(jnp.arange(wide), pps - 1)],
+        table = jnp.where(i < closed, first + bt[:, jnp.minimum(jnp.arange(pps + c), pps - 1)],
                           jnp.where(i < closed + c, c * ring + i - closed, 0))
+        table = jnp.where(live[:, None], table, 0).astype(jnp.int32)
+        rows_seen = jnp.where(live, n * P + j + 1, 1).astype(jnp.int32)
         ends = live & (j % c == c - 1)
         page = jnp.take_along_axis(bt, jnp.minimum(n, pps - 1)[:, None], axis=1)[:, 0]
         walk = jax.default_backend() == "tpu" and self._walks(P)   # tps-ok[TPS503]: at trace time
-        return {**m, "first": first, "ends": ends, "path": "walk" if walk else "gather",
-                "table": jnp.where(live[:, None], table, 0).astype(jnp.int32),
-                "rows_seen": jnp.where(live, n * P + j + 1, 1).astype(jnp.int32),
+        # ONE work list a step, the layers' alike: the (lane, key block) items that exist
+        work = la.work_list(rows_seen - 1, table, P, self.walk_block) if walk else None
+        return {**m, "first": first, "ends": ends, "path": "head_walk" if walk else "gather",
+                "table": table, "rows_seen": rows_seen, "work": work,
                 "ring_page": c * m["w_ring"] + j // P, "ring_off": j % P,
                 # the chunk the lane's position lies in, among the pool's runs of c rows
                 "chunk_at": (c * m["w_ring"] + j // P) * (P // c) + (j % P) // c,
@@ -292,20 +298,15 @@ class EvaServing(dec.DecoderServing):
 
     # -- the mixer ---------------------------------------------------------------------
     def _walks(self, P: int) -> bool:
-        """Shapes jax's kernel takes: bfloat16, a head's row whole 128-lane
-        tiles, a page whole sublane tiles."""
-        return self.dtype == jnp.bfloat16 and self.hd % 128 == 0 and P % 8 == 0
+        """Shapes ``head_walk`` takes, a key in one part."""
+        return la.head_fits(P, self.heads[0], self.kv, self.hd, 0, self.hd, self.dtype)
 
     def _walk(self, q, kp, vp, m: dict):
         """A step's attention over the virtual table, in place: q (b, H, hd) ->
         (b, H, hd) float32."""
-        if m["path"] == "walk":
-            from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention
-
-            # the kernel does not scale the scores, so the queries are
-            qs = (q.astype(jnp.float32) * self._scale()).astype(q.dtype)
-            return paged_attention(qs, kp, vp, m["rows_seen"], m["table"],
-                                   pages_per_compute_block=self.walk_block).astype(jnp.float32)
+        if m["path"] == "head_walk":
+            return la.head_walk(q, None, kp, None, vp, m["work"],
+                                scale=self._scale()).astype(jnp.float32)
         return self._decode_gather(q, (kp, vp), m["table"], m["rows_seen"] - 1, self._heads())
 
     def _tile(self, a: dict, kp, vp, k, v, kpos, first: int):

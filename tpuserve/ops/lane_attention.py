@@ -57,7 +57,8 @@ a lane's end: 4 read fastest at contexts of hundreds, 8 and more at thousands.
 
 ATTENTION BY HEAD walks the same list (ISSUE 49): ``head_walk(q_pass, q_turn,
 kn, kr, v, work)`` is ``lane_walk``'s grid, work list and scalar prefetch for
-GROUPED heads whose keys are wider than their values and lie in parts. The
+GROUPED heads whose keys are wider than their values and lie in parts (or in
+ONE part: the last paragraph but one). The
 pools AS THEY LIE: ``kn`` (KV, pages, P, dn) the part of a key that passes the
 rotary by, ``kr`` (KV / pack, pages, P, pack x dr) the part that turns, ``pack``
 KV heads side by side in a row of 128 lanes (2 at the 64 columns that turn of
@@ -95,6 +96,24 @@ full rings, ``scripts/bench_head_walk.py --only ring``, ISSUE 50): 0.44 ms a
 layer alone and 0.385 in the cell's step, against 0.38 for the same rings with
 each group padded to 16 rows by the caller, 0.72 for a plain pass that reads
 and writes the rings, and 1.97 for the gather and softmax in XLA.
+
+A KEY IN ONE PART (ISSUE 56): ``head_walk(q, None, k, None, v, work)``. Where
+``kr`` and ``q_turn`` are None the cell has no second key operand: it fetches
+nothing for it and takes ONE product a KV head for the scores, ``q . k``; the
+work list, the scalar prefetch, the lane's running max, sum and accumulator and
+the last block's divide are the same cell (told from the operands when the call
+is traced; with both parts the kernel lowers to the text it had). The ``eva``
+family's step walks its virtual block table so (a lane's summary pages, then its
+ring's pages, both in ONE pool by head): 32 query rows on 32 KV heads, one row a
+KV head, so every row goes over each head's keys and keeps its own head's. A
+(head, page) is then two products whose 128 x 128 key or value tile the matrix
+unit holds as weights, 128 cycles each whatever the rows that pass: 2.2 us a
+page of 128 rows over four units, behind the 2.6 us its 2 MiB take to arrive.
+On the chip (v5e, 24 lanes at the cell's mix of contexts, 26,663 rows,
+``scripts/bench_eva_walk.py``, ISSUE 56): 0.61 / 0.65 / 0.70 / 0.79 ms a layer at
+1 / 2 / 4 / 8 pages a cell against a least of 0.53 (0.56 for the 220 pages read),
+where jax's ``paged_attention`` (one query row a cell, a compute block read
+whole) took 3.07 / 1.88 / 1.34 / 1.04.
 
 Off the TPU ``interpret=True`` runs the same code in the Pallas interpreter
 (tests); the families call it on the TPU alone.
@@ -219,14 +238,17 @@ def lane_walk(q_lat: jax.Array, q_rope: jax.Array, ckv: jax.Array, kr: jax.Array
       *([kr] * kb))
 
 
-# -- attention by head: grouped queries, keys in two parts, values of their own ------------
+# -- attention by head: grouped queries, keys in two parts or one, values of their own -----
 
-def _head_kernel(lane_ref, block_ref, pages_ref, last_ref, qn_ref, qr_ref, *refs, scale: float,
-                 kb: int, kv: int, pack: int, split: bool, sunk: bool):
+def _head_kernel(lane_ref, block_ref, pages_ref, last_ref, qn_ref, *refs, scale: float,
+                 kb: int, kv: int, pack: int, split: bool, turns: bool, sunk: bool):
     del pages_ref   # the index maps read it
-    kn_refs, kr_refs, v_refs = refs[:kb], refs[kb:2 * kb], refs[2 * kb:3 * kb]
-    sink_ref = refs[3 * kb] if sunk else None
-    o_ref, m_ref, l_ref, acc_ref = refs[3 * kb + sunk:]
+    qr_ref, refs = (refs[0], refs[1:]) if turns else (None, refs)
+    kn_refs, refs = refs[:kb], refs[kb:]
+    kr_refs, refs = (refs[:kb], refs[kb:]) if turns else ((), refs)
+    v_refs, refs = refs[:kb], refs[kb:]
+    sink_ref, refs = (refs[0], refs[1:]) if sunk else (None, refs)
+    o_ref, m_ref, l_ref, acc_ref = refs
     P, dt = kn_refs[0].shape[-2], qn_ref.dtype
     g = qn_ref.shape[0] // kv
     n = pl.program_id(0)
@@ -249,26 +271,30 @@ def _head_kernel(lane_ref, block_ref, pages_ref, last_ref, qn_ref, qr_ref, *refs
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_pass, q_turn = qn_ref[...], qr_ref[...]
+    q_pass, q_turn = qn_ref[...], qr_ref[...] if turns else None
+
+    def passed(q, h: int, i: int):   # rows of the passing part over KV head h's keys of page i
+        return jax.lax.dot_general(q, part(kn_refs[i], h, q_pass), nt, **f32)
+
+    def turned(q, r: int, i: int):   # and of the turning part, over row r of ``pack`` heads
+        return jax.lax.dot_general(q, part(kr_refs[i], r, q_turn), nt, **f32)
+
     if split:   # KV head h: its g query rows over its own keys of every page
         s = []
         for h in range(kv):
             rows = slice(h * g, (h + 1) * g)
-            of_pages = [jax.lax.dot_general(q_pass[rows], part(kn_refs[i], h, q_pass), nt, **f32)
-                        + jax.lax.dot_general(q_turn[rows], part(kr_refs[i], h // pack, q_turn),
-                                              nt, **f32)
-                        for i in range(kb)]
+            of_pages = [passed(q_pass[rows], h, i) + turned(q_turn[rows], h // pack, i) if turns
+                        else passed(q_pass[rows], h, i) for i in range(kb)]
             s.append(jnp.concatenate(of_pages, axis=1) if kb > 1 else of_pages[0])
         s = jnp.concatenate(s, axis=0) if kv > 1 else s[0]            # (H, kb x P)
     else:       # EVERY query row over KV head h's keys, and a row keeps its own head's
         own = jax.lax.broadcasted_iota(jnp.int32, (kv * g, kb * P), 0) // g
-        turned = [[jax.lax.dot_general(q_turn, part(kr_refs[i], r, q_turn), nt, **f32)
-                   for i in range(kb)]
-                  for r in range(kv // pack)]   # a row of ``pack`` heads once: ``q_turn``'s zeros
+        if turns:   # a row of ``pack`` heads once: ``q_turn``'s zeros
+            rows_turned = [[turned(q_turn, r, i) for i in range(kb)] for r in range(kv // pack)]
         s = None
         for h in range(kv):
-            of_pages = [jax.lax.dot_general(q_pass, part(kn_refs[i], h, q_pass), nt, **f32)
-                        + turned[h // pack][i] for i in range(kb)]
+            of_pages = [passed(q_pass, h, i) + rows_turned[h // pack][i] if turns
+                        else passed(q_pass, h, i) for i in range(kb)]
             s_h = jnp.concatenate(of_pages, axis=1) if kb > 1 else of_pages[0]
             s = s_h if s is None else jnp.where(own == h, s_h, s)
     at = j * (kb * P) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -310,7 +336,8 @@ def _split(heads: int, kv: int) -> bool:
 def head_fits(page: int, heads: int, kv: int, dn: int, kr_lanes: int, dv: int, dtype) -> bool:
     """Shapes ``head_walk`` takes: bfloat16, whole sublane tiles a page and a
     KV head's group of query rows (``_split``) or else all the query rows
-    together, every part's rows whole 128-lane tiles."""
+    together, every part's rows whole 128-lane tiles (``kr_lanes`` 0: a key in
+    one part, ``dn`` the whole of it)."""
     return dtype == jnp.bfloat16 and page % 16 == 0 and heads % kv == 0 \
         and (_split(heads, kv) or heads % 16 == 0) \
         and dn % 128 == 0 and kr_lanes % 128 == 0 and dv % 128 == 0
@@ -329,10 +356,15 @@ def ring_work(ring: jax.Array, last: jax.Array) -> dict:
             "last": last.astype(jnp.int32), "items": jnp.int32(b)}
 
 
-def head_walk(q_pass: jax.Array, q_turn: jax.Array, kn: jax.Array, kr: jax.Array, v: jax.Array,
-              work: dict, *, scale: float, kv: int | None = None, sink: jax.Array | None = None,
-              interpret: bool = False) -> jax.Array:
-    """``sink`` (H,) float32, or None: a learned logit a query head that joins
+def head_walk(q_pass: jax.Array, q_turn: jax.Array | None, kn: jax.Array, kr: jax.Array | None,
+              v: jax.Array, work: dict, *, scale: float, kv: int | None = None,
+              sink: jax.Array | None = None, interpret: bool = False) -> jax.Array:
+    """``q_turn`` and ``kr`` None: A KEY IN ONE PART (``kn`` is the whole key,
+    ``q_pass`` the whole query). The cell then has no second key operand,
+    fetches nothing for it and takes one product a KV head for the scores; all
+    else is the same cell. With both parts the kernel is what it was.
+
+    ``sink`` (H,) float32, or None: a learned logit a query head that joins
     the softmax's denominator where a lane's last block divides, ``exp(sink -
     m)`` beside the keys' sum, and adds nothing to the context. An operand
     only where there is one: without it the kernel is what it was.
@@ -343,15 +375,17 @@ def head_walk(q_pass: jax.Array, q_turn: jax.Array, kn: jax.Array, kr: jax.Array
     a token. A cell reads a page of each as ONE block and cuts a head's
     columns out of it on whole lane tiles; all else is the same cell."""
     b, h, dn = q_pass.shape
-    lanes, flat = q_turn.shape[2], kn.ndim == 3
+    turns, flat = kr is not None, kn.ndim == 3
+    lanes = q_turn.shape[2] if turns else 0
     if flat:
         (n_pages, P), dv = kn.shape[:2], v.shape[2] // kv
-        pack = kv * lanes // kr.shape[2]
-        blocks = [(None, P, x.shape[2]) for x in (kn, kr, v)]
+        pack = kv * lanes // kr.shape[2] if turns else 1
+        blocks = [(None, P, x.shape[2]) for x in (kn, kr, v) if x is not None]
     else:
         (kv, n_pages, P), dv = kn.shape[:3], v.shape[3]
-        pack = kv // kr.shape[0]
+        pack = kv // kr.shape[0] if turns else 1
         blocks = [(kv, None, P, dn), (kv // pack, None, P, lanes), (kv, None, P, dv)]
+        blocks = blocks if turns else blocks[::2]
     heads = () if flat else (0,)   # a block by head: every head of its page
     page = lambda i: lambda n, lane, block, pages, last: (*heads, pages[n * kb + i], 0, 0)  # noqa: E731
     kb = work["pages"].shape[0] // work["lane"].shape[0]
@@ -366,10 +400,11 @@ def head_walk(q_pass: jax.Array, q_turn: jax.Array, kn: jax.Array, kr: jax.Array
     sinks = [jnp.broadcast_to(sink.astype(jnp.float32)[:, None], (h, 128))] if sunk else []
     return pl.pallas_call(
         functools.partial(_head_kernel, scale=scale, kb=kb, kv=kv, pack=pack,
-                          split=_split(h, kv), sunk=sunk),
+                          split=_split(h, kv), turns=turns, sunk=sunk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(work["items"],),
-            in_specs=[pl.BlockSpec((None, h, dn), by_lane), pl.BlockSpec((None, h, lanes), by_lane)]
+            in_specs=[pl.BlockSpec((None, h, dn), by_lane)]
+            + [pl.BlockSpec((None, h, lanes), by_lane)] * turns
             + [pl.BlockSpec(block, page(i)) for block in blocks for i in range(kb)]
             + [pl.BlockSpec((h, 128), lambda n, lane, block, pages, last: (0, 0))] * sunk,
             out_specs=pl.BlockSpec((None, h, dv), by_lane),
@@ -380,5 +415,5 @@ def head_walk(q_pass: jax.Array, q_turn: jax.Array, kn: jax.Array, kr: jax.Array
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=min(vmem + (16 << 20), 100 << 20)),
         interpret=interpret, name="head_walk",
-    )(work["lane"], work["block"], pages, work["last"], q_pass, q_turn, *([kn] * kb),
-      *([kr] * kb), *([v] * kb), *sinks)
+    )(work["lane"], work["block"], pages, work["last"], q_pass, *([q_turn] * turns),
+      *([kn] * kb), *([kr] * kb * turns), *([v] * kb), *sinks)
