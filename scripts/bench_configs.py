@@ -75,8 +75,7 @@ FAMILIES: dict[str, dict] = {
     # fills at 8 CFG lanes), and concurrency 8 keeps the pipelined
     # dispatcher's next batch assembled while the current one denoises —
     # the r4 shape (buckets [1], concurrency 2) left the device idle
-    # between readbacks. unet_attention stays dense: the flash variant
-    # measured 2.4-2.8x SLOWER at SD head dims (same table).
+    # between readbacks.
     "sd15": dict(
         model=dict(name="sd15", family="sd15", batch_buckets=[1, 2, 4],
                    deadline_ms=150.0, dtype="bfloat16", image_size=512,

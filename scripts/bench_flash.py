@@ -2,7 +2,7 @@
 """Attention alone, on the chip: the XLA pair that `_masked_attention` lowers
 to against the kernels that could replace it, at the serving buckets' shapes.
 
-This is the go / no-go measurement behind `tpuserve.ops.flash_attention`'s
+This is the go / no-go measurement behind `tpuserve.ops.fused_attention`'s
 shape rule (`attention_path`): one jitted call a candidate, inputs in the
 `(B, H*D, S)` layout the projections write on the TPU, every reshape or
 transpose a candidate needs inside its jit, median of `--iters` timed calls after two
@@ -33,7 +33,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from tpuserve.models.bert import _masked_attention  # noqa: E402
-from tpuserve.ops.flash_attention import flash_attention, fused_attention  # noqa: E402
+from tpuserve.ops.fused_attention import fused_attention  # noqa: E402
 
 SHAPES = [(256, 512, 16, 64), (256, 512, 12, 64), (32, 512, 16, 64),
           (256, 256, 16, 64), (256, 128, 16, 64), (256, 128, 12, 64),
@@ -52,10 +52,6 @@ def candidates(shape: tuple, only: "list[str] | None") -> dict:
         return three(_masked_attention(four(q), four(k), four(v),
                                        bias[:, None, None, :]))
     out["dense"] = dense
-
-    def tiled(q, k, v, bias):
-        return three(flash_attention(four(q), four(k), four(v), bias))
-    out["tiled128"] = tiled
 
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         BlockSizes, SegmentIds)

@@ -103,7 +103,6 @@ def served():
     return model, rt
 
 
-@pytest.mark.slow
 def test_buckets_cross_product(served):
     model, rt = served
     assert model.buckets() == [(1, 8), (1, 16), (2, 8), (2, 16)]
@@ -179,7 +178,7 @@ def _forward(model, path, monkeypatch):
     import jax
 
     if path == "fused":
-        fa = importlib.import_module("tpuserve.ops.flash_attention")
+        fa = importlib.import_module("tpuserve.ops.fused_attention")
         monkeypatch.setattr(fa, "platform_here", lambda: "tpu")
         monkeypatch.setattr(fa, "_interpret_here", lambda: True)
     return jax.jit(model.forward)
@@ -284,17 +283,13 @@ def test_assemble_lays_the_documents_of_a_row_one_after_another():
     ({}, True),
     ({"parallelism": "replica"}, False),
     ({"parallelism": "sharded"}, False),
-    ({"parallelism": "pipeline"}, False),
-    ({"options": {**TINY, "attention": "flash"}}, False),
     ({"options": {**TINY, "moe_experts": 2}}, False),
-    ({"options": {**TINY, "attention": "dense"}}, True),
     ({"quantize": "int8c"}, True),
 ])
 def test_rows_are_shared_where_the_program_keeps_documents_apart(over, want):
     """Decided from the configuration the model was built with, by no key
-    of its own: one device, a dense feed-forward, the XLA pair or the
-    whole-sequence kernel. Everything else answers one item a row and a
-    program of (ids, mask)."""
+    of its own: one device and a dense feed-forward. A mesh and the routed
+    feed-forward answer one item a row and a program of (ids, mask)."""
     model = build(tiny_cfg(**over))
     assert model.packs_rows is want
     assert model.row_shape(16) == ((16, model.ROW_ITEMS) if want else (1, 1))
@@ -303,67 +298,30 @@ def test_rows_are_shared_where_the_program_keeps_documents_apart(over, want):
     assert len(model.input_signature((2, 16))) == (3 if want else 2)
 
 
-# -- sequence-parallel serving -----------------------------------------------
+# -- the one exception: a mesh takes (ids, mask) and one document a row ---------
 
-@pytest.mark.slow
-@pytest.mark.parametrize("impl", ["ring", "ulysses"])
-def test_sequence_parallel_serving_matches_dense(impl):
-    """attention=ring|ulysses + sp=2 on the sharded 8-device mesh:
-    seq-sharded activations (K/V ppermute rotation vs head all-to-all),
-    identical logits incl. a padded lane; the AOT-compiled path runs."""
-    import jax
-
+@pytest.mark.parametrize("mode", ["sharded", "replica"])
+def test_a_mesh_answers_as_one_device_with_shared_rows(mode):
+    """The same documents through `build_runtime` on the suite's eight CPU
+    devices, one a row, and on one device, two of them in one row: the same
+    top-k, from the same seeded weights."""
     from tpuserve.runtime import build_runtime
 
-    sp_model = build(tiny_cfg(parallelism="sharded", sp=2, batch_buckets=[4],
-                              seq_buckets=[16],
-                              options={**TINY, "attention": impl}))
-    rt = build_runtime(sp_model)  # binds the mesh + AOT-compiles SP forward
-    dense = build(tiny_cfg(batch_buckets=[4], seq_buckets=[16]))
-
-    items = [dense.host_decode(
-        json.dumps({"text": f"sequence parallel serving {i}"}).encode(),
-        "application/json") for i in range(3)]  # 3 of 4 lanes real
-    # Each its own batch: the one-device model's rows may be shared (its
-    # program takes segments), the mesh's keep one document a row.
-    batch = sp_model.assemble(items, (4, 16))
-    params = dense.init_params(jax.random.key(0))  # same tree either impl
-    # Same params: the runtime loaded its own; rerun the SP forward with
-    # dense's params for the apples-to-apples check.
-    out_sp = jax.jit(sp_model.forward)(params, batch)
-    out_dense = jax.jit(dense.forward)(params, dense.assemble(items, (4, 16)))
-    np.testing.assert_allclose(np.asarray(out_sp["probs"])[:3],
-                               np.asarray(out_dense["probs"])[:3], atol=1e-5)
-    assert np.asarray(rt.run((4, 16), batch)["probs"]).shape == (4, 4)
-
-
-def test_ulysses_rejects_indivisible_heads():
-    with pytest.raises(ValueError, match="heads"):
-        build(tiny_cfg(parallelism="sharded", sp=4, seq_buckets=[16],
-                       options={**TINY, "attention": "ulysses", "heads": 2}))
-
-
-def test_ring_requires_divisible_seq_buckets():
-    with pytest.raises(ValueError, match="divisible"):
-        build(tiny_cfg(parallelism="sharded", sp=4, seq_buckets=[8, 18],
-                       options={**TINY, "attention": "ring"}))
-
-
-def test_ring_rejects_replica_mode():
-    with pytest.raises(ValueError, match="replica"):
-        build(tiny_cfg(parallelism="replica",
-                       options={**TINY, "attention": "ring"}))
-
-
-def test_ring_without_bound_mesh_errors_clearly():
-    import jax
-
-    model = build(tiny_cfg(parallelism="sharded", sp=2, batch_buckets=[4],
-                           seq_buckets=[16], options={**TINY, "attention": "ring"}))
-    params = model.init_params(jax.random.key(0))
-    batch = model.assemble([model.host_decode(b"hello", "text/plain")], (4, 16))
-    with pytest.raises(ValueError, match="bind_mesh"):
-        model.forward(params, batch)
+    tiny = dict(TINY, layers=1)
+    mesh_model = build(tiny_cfg(parallelism=mode, batch_buckets=[8],
+                                seq_buckets=[16], options=tiny))
+    one = build(tiny_cfg(batch_buckets=[8], seq_buckets=[16], options=tiny))
+    assert one.packs_rows and not mesh_model.packs_rows
+    assert len(mesh_model.input_signature((8, 16))) == 2
+    docs = _documents([5, 9, 16, 3])
+    rt_mesh, rt_one = build_runtime(mesh_model), build_runtime(one)
+    bucket = (8, 16)
+    assert bucket in rt_mesh.executables
+    got = rt_mesh.fetch(rt_mesh.run(bucket, mesh_model.assemble(docs, bucket)))
+    want = rt_one.fetch(rt_one.run(
+        bucket, one.assemble(docs, bucket, [0, 0, 1, 2])))
+    np.testing.assert_array_equal(got["indices"][:4], want["indices"][:4])
+    np.testing.assert_allclose(got["probs"][:4], want["probs"][:4], atol=1e-5)
 
 
 def test_nonpositive_sp_rejected_at_config():
@@ -373,7 +331,6 @@ def test_nonpositive_sp_rejected_at_config():
 
 # -- HTTP end-to-end ----------------------------------------------------------
 
-@pytest.mark.slow
 def test_bert_http_end_to_end():
     from aiohttp.test_utils import TestClient, TestServer
 

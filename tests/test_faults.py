@@ -338,10 +338,22 @@ def test_429_carries_retry_after_and_stats_robustness(loop):
 # ---------------------------------------------------------------------------
 
 def test_watchdog_revives_killed_group_loop(loop):
-    cfg = toy_server_cfg(watchdog_interval_s=0.05)
+    # The server starts with the watchdog off (interval 0), so that the dead
+    # task is seen before any sweep can revive it; the periodic sweep is
+    # started by hand once it has been seen. Every wait is on its condition
+    # with a deadline, never a fixed sleep: beside five other workers the
+    # loop's death and the sweep each took longer than a sleep allowed.
+    cfg = toy_server_cfg(watchdog_interval_s=0.0)
     state = ServerState(cfg)
     state.build()
     app = make_app(state)
+
+    async def until(cond, what: str, deadline_s: float = 5.0) -> None:
+        for _ in range(int(deadline_s / 0.001)):
+            if cond():
+                return
+            await asyncio.sleep(0.001)
+        raise AssertionError(f"not within {deadline_s} s: {what}")
 
     async def go():
         client = TestClient(TestServer(app))
@@ -354,17 +366,18 @@ def test_watchdog_revives_killed_group_loop(loop):
             r = await client.post("/v1/models/toy:predict",
                                   data=npy_image(), headers=NPY)
             assert r.status == 200
-            await asyncio.sleep(0.02)
             (task,) = b._tasks.values()
-            assert task.done()
+            await until(task.done, "the group loop's task is dead")
             assert isinstance(task.exception(), FaultInjected)
-
-            await asyncio.sleep(0.2)  # >= a few watchdog sweeps
-            (task,) = b._tasks.values()
-            assert not task.done()  # revived
             restarts = state.metrics.counter(
                 "watchdog_restarts_total{model=toy,component=group_loop}")
-            assert restarts.value >= 1
+            assert restarts.value == 0  # dead and NOT yet revived
+
+            state.watchdog.interval_s = 0.05
+            state.watchdog.start()
+            await until(lambda: restarts.value >= 1, "a sweep revived it")
+            (task,) = b._tasks.values()
+            assert not task.done()  # revived
             r = await client.post("/v1/models/toy:predict",
                                   data=npy_image(), headers=NPY)
             assert r.status == 200  # serving again through the revived loop

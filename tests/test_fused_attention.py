@@ -1,8 +1,6 @@
-"""The whole-sequence attention kernel (`ops.flash_attention.fused_attention`)
+"""The whole-sequence attention kernel (`ops.fused_attention.fused_attention`)
 in the Pallas interpreter, the rule that routes BERT's buckets to it
 (`attention_path`), and what the runtime shows of the choice."""
-
-import importlib
 
 import jax
 import jax.numpy as jnp
@@ -13,10 +11,7 @@ from tpuserve.config import ModelConfig
 from tpuserve.models import build
 from tpuserve.models.bert import _masked_attention, _segment_bias
 from tpuserve.obs import Metrics
-
-# `tpuserve.ops.flash_attention` as an attribute is the function: the package
-# imports it under the module's name.
-fa = importlib.import_module("tpuserve.ops.flash_attention")
+from tpuserve.ops import fused_attention as fa
 
 B, H, D = 2, 2, 64
 # (bucket, live keys of the first row; the second row is full)
@@ -109,6 +104,31 @@ def test_fused_refuses_what_it_cannot_tile(shape):
     x = jnp.zeros(shape, jnp.bfloat16)
     with pytest.raises(ValueError, match="use dense attention"):
         fa.fused_attention(x, x, x, jnp.ones(shape[:2]), block_h=2)
+
+
+def test_unknown_platform_raises(monkeypatch):
+    """interpret=None interprets on cpu and compiles on tpu; any other
+    platform raises instead of silently taking the interpreter."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    x = jnp.zeros((1, 128, 1, 16), jnp.bfloat16)  # a shape no test compiled
+    with pytest.raises(ValueError, match="platform 'gpu'"):
+        fa.fused_attention(x, x, x, jnp.ones((1, 128)))
+
+
+@pytest.mark.parametrize("mask", ["segments", "key-mask"])
+def test_a_row_with_no_live_key_stays_finite_and_alone(mask):
+    """The padding rows of a part-filled launch (segment numbers all 0; a
+    key mask all 0): the kernel's answer there is finite, and every other
+    row is bit for bit what it is in a launch of its own."""
+    q, k, v, live, _ = _inputs(512, 300)
+    live = live.copy()
+    live[1] = False
+    m = jnp.asarray(live.astype(np.int32) if mask == "segments" else live)
+    out = np.asarray(fa.fused_attention(q, k, v, m), np.float32)
+    assert np.isfinite(out).all()
+    alone = np.asarray(fa.fused_attention(q[:1], k[:1], v[:1], m[:1]),
+                       np.float32)
+    assert np.array_equal(out[:1], alone)
 
 
 # -- documents that share a row --------------------------------------------------
@@ -250,21 +270,14 @@ def _trace(model, bucket=(2, 512)):
     return model.traced_paths(bucket)
 
 
-@pytest.mark.parametrize("stated", ["dense", "flash"])
-def test_a_stated_attention_is_never_overruled(stated, monkeypatch):
-    monkeypatch.setattr(fa, "platform_here", lambda: "tpu")
-    monkeypatch.setattr(fa, "_interpret_here", lambda: True)
-    assert _trace(build(_bert_cfg(attention=stated))) == {"attention": stated}
-
-
 def test_the_cpu_keeps_the_xla_path():
     assert _trace(build(_bert_cfg())) == {"attention": "dense"}
 
 
 @pytest.mark.parametrize("parallelism,want", [
     ("single", "fused"), ("replica", "fused"), ("sharded", "dense")])
-def test_unset_attention_chooses_on_one_device_only(parallelism, want,
-                                                    monkeypatch):
+def test_attention_is_chosen_on_one_device_only(parallelism, want,
+                                                monkeypatch):
     """Steered to the TPU's answer in the test, never by an option: a mesh
     keeps the path GSPMD can partition."""
     monkeypatch.setattr(fa, "platform_here", lambda: "tpu")
@@ -274,14 +287,39 @@ def test_unset_attention_chooses_on_one_device_only(parallelism, want,
     assert _trace(model) == {"attention": want}
 
 
+# The two cells' widths (google-research/bert, uncased_L-12_H-768_A-12 and
+# uncased_L-24_H-1024_A-16) at the cells' four buckets.
+CELL_WIDTHS = {"base": dict(layers=12, d_model=768, heads=12, d_ff=3072),
+               "large": dict(layers=24, d_model=1024, heads=16, d_ff=4096)}
+
+
+@pytest.mark.parametrize("bucket", [(32, 128), (32, 512), (256, 128), (256, 512)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("width", sorted(CELL_WIDTHS))
+def test_the_cells_buckets_keep_their_path(width, bucket, monkeypatch):
+    """What both BERT cells run on the chip, traced where no chip is: the
+    kernel at 512, the XLA pair at 128, documents sharing rows (three
+    leaves). Who changes `attention_path` or BERT's build changes this."""
+    monkeypatch.setattr(fa, "platform_here", lambda: "tpu")
+    monkeypatch.setattr(fa, "_interpret_here", lambda: False)  # traced, never lowered
+    model = build(ModelConfig(
+        name=width, family="bert", dtype="bfloat16", num_classes=5,
+        parallelism="single", batch_buckets=[32, 256], seq_buckets=[128, 512],
+        options={"vocab_size": 512, **CELL_WIDTHS[width]}))
+    assert len(model.input_signature(bucket)) == 3
+    want = "fused" if bucket[1] == 512 else "dense"
+    assert _trace(model, bucket) == {"attention": want}
+
+
 def test_chosen_kernel_serves_the_dense_answer(monkeypatch):
-    dense = build(_bert_cfg(attention="dense"))
+    dense = build(_bert_cfg())     # traced on the CPU: the XLA pair
     chosen = build(_bert_cfg())
     params = dense.init_params(jax.random.key(0))
     items = [dense.host_decode(b'{"text": "%s"}' % t, "application/json")
              for t in (b"one live text", b"and a longer one " * 40)]
     batch = dense.assemble(items, (2, 512))
     want = jax.jit(dense.forward)(params, batch)
+    assert dense.traced_paths((2, 512)) == {"attention": "dense"}
     monkeypatch.setattr(fa, "platform_here", lambda: "tpu")
     monkeypatch.setattr(fa, "_interpret_here", lambda: True)
     got = jax.jit(chosen.forward)(params, batch)

@@ -367,28 +367,7 @@ def test_staged_canary_runs_short_generation(tg_rt):
     assert rt.compiles_total == c0  # canaries never compile
 
 
-def test_flash_prefill_matches_dense(tg_rt):
-    """options.attention='flash' routes the bidirectional prompt prefill
-    through the seeded Pallas kernel; greedy token streams must match the
-    dense twin exactly (same seeded weights)."""
-    model_d, _ = tg_rt
-    model_f = build(tg_cfg(options={**TG_OPTS, "attention": "flash"}))
-    rt_d = build_runtime(model_d)
-    rt_f = build_runtime(model_f)
-    item = prompt_item(model_d, "flash parity prompt", seed=21, max_new=9)
-    out_d = rt_d.fetch(rt_d.run((1,), model_d.assemble([item], (1,))))
-    out_f = rt_f.fetch(rt_f.run((1,), model_f.assemble([item], (1,))))
-    res_d = model_d.host_postprocess(out_d, 1)[0]
-    res_f = model_f.host_postprocess(out_f, 1)[0]
-    assert res_d["tokens"] == res_f["tokens"]
-
-
 def test_textgen_option_validation():
-    with pytest.raises(ValueError, match="attention"):
-        build(tg_cfg(options={**TG_OPTS, "attention": "magic"}))
-    with pytest.raises(ValueError, match="divisible by 8"):
-        build(tg_cfg(options={**TG_OPTS, "attention": "flash",
-                              "prompt_len": 12}))
     with pytest.raises(ValueError, match="heads"):
         build(tg_cfg(options={**TG_OPTS, "d_model": 33}))
 

@@ -433,3 +433,23 @@ def test_build_roofline_aggregate_chip_ceiling():
         value_img_s=500.0)
     assert single["pct_of_chip_ceiling"] == pytest.approx(50.0)
     assert single["n_chips"] == 1
+
+
+# -- the driver's entries (__graft_entry__) -----------------------------------
+
+def test_graft_entry_traces_to_top_five_of_eight():
+    """`entry()` hands the driver a forward that traces: compiled and run by
+    the slow tests of test_train.py only."""
+    import jax
+
+    import __graft_entry__ as g
+
+    fn, args = g.entry()
+    out = jax.eval_shape(fn, *args)
+    assert out["indices"].shape == (8, 5)
+
+
+def test_graft_dryrun_replica_serving_on_eight_devices():
+    import __graft_entry__ as g
+
+    g._dryrun_replica_serving(8)

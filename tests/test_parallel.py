@@ -82,6 +82,30 @@ def test_sharded_matmul_matches_single_device():
     np.testing.assert_allclose(out, x @ w, rtol=1e-5)
 
 
+def test_gspmd_partitions_a_sequence_sharded_block_itself():
+    """What the train step rests on since its ring / Ulysses arm went: the
+    training block attends densely, and under a (data, model, seq) = (2, 2,
+    2) mesh with the activation sharded on "seq" (and its kernels by the
+    train step's own rules) it answers as on one device."""
+    from jax.sharding import NamedSharding
+
+    from tpuserve.train import TRAIN_PARTITION_RULES, Block, TrainConfig
+
+    mesh = make_mesh(MeshPlan(tp=2, sp=2))
+    assert dict(mesh.shape) == {"data": 2, "model": 2, "seq": 2}
+    block = Block(TrainConfig(d_model=16, n_heads=2, d_ff=32, max_seq=8))
+    x = np.random.default_rng(0).normal(size=(4, 8, 16)).astype(np.float32)
+    params = block.init(jax.random.key(0), x)
+    want = np.asarray(jax.jit(block.apply)(params, x))
+
+    act = NamedSharding(mesh, P("data", "seq", None))
+    f = jax.jit(block.apply, out_shardings=act)
+    got = f(shard_pytree(params, TRAIN_PARTITION_RULES, mesh),
+            jax.device_put(x, act))
+    assert got.sharding.spec == P("data", "seq", None)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+
+
 def test_pad_batch_to_mesh():
     mesh = make_mesh()
     assert pad_batch_to_mesh(1, mesh) == 8

@@ -37,36 +37,6 @@ def sd_model():
     return m, m.init_params(jax.random.key(0)), jax.jit(m.forward)
 
 
-def test_unet_flash_self_attention_matches_dense():
-    """options.unet_attention='flash' routes spatial self-attention >= 1024
-    tokens through the Pallas kernel with the head dim zero-padded to lane
-    alignment; the padding is mathematically exact, so one UNet step must
-    match the dense path to accumulation tolerance, with an identical param
-    tree (the torch import mappers must keep working)."""
-    import jax.numpy as jnp
-
-    # latent 32x32 -> 1024 tokens at attention level 0: the flash path.
-    cfg_d = sd_cfg(image_size=64)
-    cfg_f = sd_cfg(image_size=64,
-                   options={**TINY, "unet_attention": "flash"})
-    md, mf = build(cfg_d), build(cfg_f)
-    params = md.init_params(jax.random.key(0))
-    assert jax.tree_util.tree_structure(params) == jax.tree_util.tree_structure(
-        mf.init_params(jax.random.key(0)))
-    lat = jax.random.normal(jax.random.key(1), (2, 32, 32, 4), jnp.float32)
-    t = jnp.array([500, 500], jnp.int32)
-    ctx = jax.random.normal(jax.random.key(2), (2, MAX_TOKENS, 32), jnp.float32)
-    eps_d = md.unet.apply(params["unet"], lat, t, ctx)
-    eps_f = mf.unet.apply(params["unet"], lat, t, ctx)
-    np.testing.assert_allclose(np.asarray(eps_d), np.asarray(eps_f),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_unet_attention_option_validated():
-    with pytest.raises(ValueError, match="unet_attention"):
-        build(sd_cfg(options={**TINY, "unet_attention": "magic"}))
-
-
 def test_ddim_schedule_math():
     ts, a_t, a_prev = ddim_schedule(10)
     assert ts.shape == a_t.shape == a_prev.shape == (10,)

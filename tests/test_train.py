@@ -44,21 +44,6 @@ def test_loss_decreases():
     assert losses[-1] < losses[0], losses
 
 
-def test_train_step_with_ulysses_attention():
-    """Full sharded train step with seq_attention="ulysses" converges too."""
-    mesh = make_mesh(mesh_plan_for(8))
-    cfg = TrainConfig(n_layers=1, d_model=32, d_ff=64, vocab=64, max_seq=16,
-                      seq_attention="ulysses")
-    model, params, tx, opt_state, shardings = make_train_state(mesh, cfg)
-    step, _ = make_train_step(model, tx, mesh, shardings)
-    batch = synthetic_batch(cfg, 8, seed=0)
-    losses = []
-    for _ in range(6):
-        params, opt_state, loss = step(params, opt_state, dict(batch))
-        losses.append(float(loss))
-    assert losses[-1] < losses[0], losses
-
-
 def test_tp_params_actually_sharded():
     mesh = make_mesh(mesh_plan_for(8))
     cfg = TrainConfig()

@@ -10,8 +10,8 @@ idiomatically for JAX/XLA on TPU:
   bucketed sequence lengths, deadline flush, dispatch pipelining) that runs
 - AOT-compiled XLA executables (``tpuserve.runtime``) over a
 - ``jax.sharding.Mesh`` (``tpuserve.parallel``: data-parallel sharded-batch,
-  replica groups, tensor-parallel partition rules; ``tpuserve.ops`` adds ring
-  attention for sequence-parallel long-context work), with
+  replica groups, tensor-parallel partition rules; ``tpuserve.ops`` holds the
+  Pallas kernels), with
 - on-device resize/normalize preprocessing (``tpuserve.preproc``),
 - TF SavedModel weight import with parity checks (``tpuserve.savedmodel``),
 - first-class observability (``tpuserve.obs``).

@@ -213,8 +213,7 @@ def measure_chip_img_s(batch: int | None = None, family: str = "resnet50",
     accepting any family then crashing on image-only inputs is now a clear
     error up front). `batch`/`bucket`/`iters` override the preset;
     `mcfg_extra` shallow-merges over the preset's ModelConfig kwargs (e.g.
-    {"seq_buckets": [512], "options": {"attention": "flash"}} for the
-    flash-vs-dense sweep).
+    {"seq_buckets": [512]} for a long-bucket sweep).
 
     Returns {"img_s", "ms_per_batch", "batch", "bucket", "gflops_per_item",
     "achieved_tflops_s", "mfu_pct"?, "device"} or {"error": str}.

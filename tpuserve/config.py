@@ -547,9 +547,9 @@ class ParallelConfig:
     - ``mode = "single"`` — first device only (dev mode).
     - ``mode = ""`` (default) — every model keeps its own ``parallelism``.
 
-    A non-empty mode overrides EVERY configured model (including
-    ``pipeline`` models — the override is deliberate and total, so a
-    drill can flatten a fleet to one layout with one override flag)."""
+    A non-empty mode overrides EVERY configured model (the override is
+    deliberate and total, so a drill can flatten a fleet to one layout with
+    one override flag)."""
 
     # "" = respect per-model `parallelism`; "replica" / "sharded" /
     # "single" override every model's mode at build time.
@@ -567,7 +567,7 @@ class ParallelConfig:
         if self.mode not in ("", "replica", "sharded", "single"):
             raise ValueError(
                 f"parallel.mode must be one of '', 'replica', 'sharded', "
-                f"'single'; got {self.mode!r} (pipeline is per-model only)")
+                f"'single'; got {self.mode!r}")
         if self.n_chips < 0 or self.data < 0:
             raise ValueError("parallel.n_chips/data must be >= 0")
 
@@ -971,6 +971,30 @@ class WorkerConfig:
                 "worker.port_base/drain_timeout_s must be >= 0")
 
 
+# Keys retired in PR 57, by (family, options key), with what decides now.
+# ``options`` is an open table, so a key that no code reads would be ignored
+# in silence: these are refused by name instead.
+_RETIRED_OPTIONS = {
+    ("bert", "attention"): "ops.fused_attention.attention_path chooses each bucket's attention "
+                           "while it is traced, from platform, dtype, length and head width",
+    ("bert", "pp_micro"): "parallelism is 'sharded', 'replica' or 'single'",
+    ("textgen", "attention"): "prefill attends with the XLA einsum pair",
+    ("sd15", "unet_attention"): "every UNet level runs nn.dot_product_attention",
+}
+
+
+def _refuse_retired(cfg: "ModelConfig") -> None:
+    if cfg.parallelism == "pipeline":
+        raise ValueError(
+            f"{cfg.name}: parallelism = 'pipeline' was retired (PR 57): a model is laid "
+            "over devices as 'sharded', 'replica' or 'single'")
+    for key in cfg.options:
+        if (cfg.family, key) in _RETIRED_OPTIONS:
+            raise ValueError(
+                f"{cfg.name}: options.{key} was retired (PR 57) and is not read: "
+                f"{_RETIRED_OPTIONS[cfg.family, key]}; remove the key")
+
+
 @dataclass
 class ModelConfig:
     """Per-model serving configuration."""
@@ -1023,19 +1047,11 @@ class ModelConfig:
     wire_format: str = "rgb8"
     # Parallelism mode: "sharded" (one executable, batch sharded over the
     # mesh), "replica" (one executable per device, independent queues),
-    # "single" (first device only), or "pipeline" (layer stack split into
-    # `pp` GPipe stages over a ("stage",) mesh — families whose depth is a
-    # homogeneous block stack, e.g. BERT; for models too deep/large for one
-    # device's memory). SURVEY.md §2.1.
+    # or "single" (first device only). SURVEY.md §2.1.
     parallelism: str = "sharded"
     # Tensor-parallel axis size carved out of the mesh (1 = TP off).
     tp: int = 1
-    # Pipeline stage count for parallelism = "pipeline" (0 = all devices).
-    pp: int = 0
-    # Sequence-parallel axis size (1 = SP off). With BERT's
-    # options.attention = "ring", activations shard their seq dim over this
-    # axis and attention rotates K/V around the ICI ring — long-context
-    # serving beyond one chip's attention memory.
+    # The mesh's "seq" axis (1 = off): `textgen` shards its KV pages over it.
     sp: int = 1
     # Model-specific knobs (e.g. SD: num_steps, guidance_scale; detect: score
     # threshold). Kept open-ended on purpose.
@@ -1096,6 +1112,7 @@ class ModelConfig:
     breaker_retry_after_s: float = 5.0
 
     def __post_init__(self) -> None:
+        _refuse_retired(self)
         if self.tp < 1 or self.sp < 1:
             raise ValueError(
                 f"tp and sp must be >= 1, got tp={self.tp} sp={self.sp}")

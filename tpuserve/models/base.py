@@ -166,13 +166,6 @@ class ServingModel(abc.ABC):
         launches under it. Default: nothing was chosen."""
         return {}
 
-    def prepare_host_params(self, params: Any) -> Any:
-        """Restructure loaded host params for the serving mode before
-        sharding (runtime calls this between load and device_put). Default
-        identity; the pipeline mode uses it to restack the layer stack into
-        stage-major leaves with a leading ("stage",)-shardable dim."""
-        return params
-
     def int8c_native_kernel_paths(self) -> list[str]:
         """Regexes of param paths this model computes in int8 NATIVELY
         (``quantize = "int8c"``): those kernels stay ``{"q8", "q8_scale"}``
@@ -280,28 +273,14 @@ class ServingModel(abc.ABC):
         return out
 
     # -- parallelism --------------------------------------------------------
-    def bind_mesh(self, mesh: Any) -> None:
-        """Runtime hands the model its serving mesh before params/compile.
-
-        Default no-op. Families whose forward needs mesh-aware ops override —
-        e.g. BERT's ring attention closes over the mesh's "seq" axis.
-        """
-
     def partition_rules(self) -> list[tuple[str, P]]:
         """Ordered (regex, PartitionSpec) rules for params; default replicate."""
         return [(".*", P())]
 
     def batch_spec(self) -> Any:
-        """PartitionSpec pytree for the batch input (leading dim = data axis).
-        Pipeline mode's ("stage",) mesh has no data axis: batches replicate
-        and the model microbatches internally."""
-        if self.cfg.parallelism == "pipeline":
-            return P()
+        """PartitionSpec pytree for the batch input (leading dim = data axis)."""
         return P("data")
 
     def out_spec(self) -> Any:
-        """PartitionSpec pytree for forward outputs (replicated under
-        pipeline — the last stage's psum already replicates them)."""
-        if self.cfg.parallelism == "pipeline":
-            return P()
+        """PartitionSpec pytree for forward outputs."""
         return P("data")

@@ -46,11 +46,9 @@ class BertBlock(nn.Module):
     d_ff: int
     dtype: Any = jnp.bfloat16
     # "dense" (XLA einsum) | "fused" (Pallas kernel, a whole short sequence
-    # a step) | "flash" (Pallas kernel, tiled) | "ring" / "ulysses"
-    # (sequence-parallel over the serving mesh's "seq" axis).
+    # a step): BertServing.forward sets it for each bucket it traces.
     attention_impl: str = "dense"
     ln_eps: float = 1e-12  # original BERT value; keeps imported weights exact
-    mesh: Any = None  # required for "ring" / "ulysses"
     # > 0: replace the dense FFN with a Switch MoE over this many experts
     # (tpuserve.ops.moe); expert dims shard on "model" for EP serving.
     moe_experts: int = 0
@@ -65,75 +63,20 @@ class BertBlock(nn.Module):
         # explicit additive bias inside attention_fn so the semantics stay
         # bucket-invariant (padded keys get -1e9 before the f32 softmax).
         # ``segments`` (B, S) numbers the documents that share a row (0 =
-        # padding): mask_bias is then (B, 1, S, S), built from it, and only
-        # the two paths that keep documents apart may run.
-        if segments is not None and (
-                self.moe_experts
-                or self.attention_impl not in ("fused", "dense")):
+        # padding): mask_bias is then (B, 1, S, S), built from it.
+        if segments is not None and self.moe_experts:
             raise ValueError(
-                f"attention={self.attention_impl!r}, moe_experts="
-                f"{self.moe_experts}: cannot keep the documents of a shared "
-                "row apart")
+                f"moe_experts={self.moe_experts}: the routed feed-forward "
+                "counts capacity by row and cannot keep the documents of a "
+                "shared row apart")
         if self.attention_impl == "fused":
-            from tpuserve.ops.flash_attention import fused_attention
+            from tpuserve.ops.fused_attention import fused_attention
 
             # The kernel takes segment numbers; a 0 / 1 key mask (a live
             # key's bias is 0.0) is one document a row.
             seg = (mask_bias[:, 0, 0, :] == 0.0 if segments is None
                    else segments)
             fn = lambda q, k, v, **kw: fused_attention(q, k, v, seg)  # noqa: E731
-        elif self.attention_impl == "flash":
-            from tpuserve.ops.flash_attention import flash_attention
-
-            # mask_bias is (B, 1, 1, S) additive; flash takes per-key (B, S).
-            if self.mesh is not None:
-                # Sharded serving: GSPMD cannot auto-partition a Mosaic
-                # kernel, so shard_map runs it per device on the local shard
-                # (batch on "data", heads on "model" when tp divides them) —
-                # the supported composition that used to be a build-time
-                # rejection (VERDICT r3 next 3).
-                from jax.sharding import PartitionSpec as P
-
-                head_axis = ("model"
-                             if self.heads % self.mesh.shape["model"] == 0
-                             else None)
-                qkv_spec = P("data", None, head_axis, None)
-
-                def fn(q, k, v, **kw):  # noqa: ANN001
-                    f = jax.shard_map(
-                        lambda q_, k_, v_, b_: flash_attention(q_, k_, v_, b_),
-                        mesh=self.mesh,
-                        in_specs=(qkv_spec, qkv_spec, qkv_spec,
-                                  P("data", None)),
-                        out_specs=qkv_spec,
-                        # Pallas interpreter + vma tracking don't compose
-                        # (see tpuserve.ops.ring_attention).
-                        check_vma=False)
-                    return f(q, k, v, mask_bias[:, 0, 0, :])
-            else:
-                fn = lambda q, k, v, **kw: flash_attention(  # noqa: E731
-                    q, k, v, mask_bias[:, 0, 0, :])
-        elif self.attention_impl in ("ring", "ulysses"):
-            from jax.sharding import PartitionSpec as P
-
-            from tpuserve.ops import ring_attention, ulysses_attention
-
-            if self.mesh is None:
-                raise ValueError(
-                    f"attention={self.attention_impl!r} needs the serving "
-                    "mesh: the runtime calls bind_mesh(mesh); do the same "
-                    "before forward")
-            # Activations reshard (batch on "data", seq on "seq") at the
-            # shard_map boundary; the op then moves K/V (ring: ppermute
-            # rotation) or heads (ulysses: all-to-all) over ICI. Heads stay
-            # tensor-parallel when tp divides them.
-            sp_attn = (ring_attention if self.attention_impl == "ring"
-                       else ulysses_attention)
-            head_axis = ("model"
-                         if self.heads % self.mesh.shape["model"] == 0 else None)
-            fn = lambda q, k, v, **kw: sp_attn(  # noqa: E731
-                q, k, v, self.mesh, key_padding=mask_bias[:, 0, 0, :],
-                spec=P("data", "seq", head_axis, None))
         else:
             fn = lambda q, k, v, **kw: _masked_attention(q, k, v, mask_bias)  # noqa: E731
         if self.quantize_compute:
@@ -214,7 +157,6 @@ class BertClassifier(nn.Module):
     dtype: Any = jnp.bfloat16
     attention_impl: str = "dense"
     ln_eps: float = 1e-12
-    mesh: Any = None
     moe_experts: int = 0
     moe_capacity_factor: float = 1.25
     quantize_compute: bool = False
@@ -244,7 +186,7 @@ class BertClassifier(nn.Module):
         for i in range(self.layers):
             x = BertBlock(self.heads, self.d_ff, dtype=self.dtype,
                           attention_impl=self.attention_impl,
-                          ln_eps=self.ln_eps, mesh=self.mesh,
+                          ln_eps=self.ln_eps,
                           moe_experts=self.moe_experts,
                           moe_capacity_factor=self.moe_capacity_factor,
                           quantize_compute=self.quantize_compute,
@@ -265,60 +207,11 @@ class BertServing(ServingModel):
         super().__init__(cfg)
         self._tokenize_obs = None  # bind_metrics
         opt = cfg.options
-        # options.attention unset: on one device the path is chosen for each
-        # bucket while its program is traced (forward); a mesh keeps the XLA
-        # path, which GSPMD can partition. A stated value holds everywhere.
-        self._choose_attention = "attention" not in opt and \
-            cfg.parallelism in ("single", "replica")
+        # On one device the attention path is chosen for each bucket while
+        # its program is traced (forward); a mesh keeps the XLA pair, which
+        # GSPMD can partition.
+        self._choose_attention = cfg.parallelism in ("single", "replica")
         self._attention_traced: dict[tuple, str] = {}
-        attention = str(opt.get("attention", "dense"))
-        if attention not in ("dense", "flash", "ring", "ulysses"):
-            raise ValueError("options.attention must be 'dense', 'flash', "
-                             f"'ring', or 'ulysses', got {attention!r}")
-        # attention='flash' + parallelism='sharded' is supported: bind_mesh
-        # routes the kernel through shard_map (GSPMD can't auto-partition a
-        # Mosaic call; per-device local execution is the composition).
-        # Pipeline serving (parallelism = "pipeline"): the homogeneous block
-        # stack splits into GPipe stages over a ("stage",) mesh, one stage's
-        # params per device (tpuserve.parallel.pipeline). v1 composes with
-        # dense attention only: flash/ring/ulysses close over meshes or
-        # kernels that would nest shard_maps, and MoE routing would need
-        # expert state inside the stage scan.
-        self.pipeline_capable = True
-        self._stage_mesh = None
-        if cfg.parallelism == "pipeline":
-            if attention != "dense":
-                raise ValueError(
-                    "parallelism='pipeline' supports options.attention="
-                    f"'dense' only, got {attention!r}")
-            if int(opt.get("moe_experts", 0)):
-                raise ValueError(
-                    "parallelism='pipeline' does not compose with "
-                    "options.moe_experts")
-        if attention in ("ring", "ulysses"):
-            if cfg.parallelism == "replica":
-                # One shared module can't close over N per-replica meshes;
-                # SP over a 1-device replica is pointless anyway.
-                raise ValueError(
-                    f"options.attention={attention!r} requires parallelism="
-                    "'sharded' or 'single' (replica mode has one mesh per "
-                    "device)")
-            bad = [s for s in cfg.seq_buckets if s % cfg.sp]
-            if bad:
-                raise ValueError(
-                    f"{attention} attention shards the seq dim over "
-                    f"sp={cfg.sp}; seq buckets {bad} are not divisible")
-        if attention == "ulysses":
-            # The all-to-all deals LOCAL heads (after any tp split) across
-            # the seq axis; mirror the op's check at build time so a bad
-            # config fails with guidance, not at AOT compile.
-            heads = int(opt.get("heads", 12))
-            local = heads // cfg.tp if heads % cfg.tp == 0 else heads
-            if local % cfg.sp:
-                raise ValueError(
-                    f"ulysses attention deals heads over sp={cfg.sp}; "
-                    f"local heads {local} (heads={heads}, tp={cfg.tp}) "
-                    "are not divisible")
         moe_experts = int(opt.get("moe_experts", 0))
         if moe_experts and cfg.parallelism == "sharded" and cfg.tp > 1 \
                 and moe_experts % cfg.tp:
@@ -350,10 +243,6 @@ class BertServing(ServingModel):
             max_seq=self.max_seq,
             num_classes=cfg.num_classes,
             dtype=self.dtype,
-            # "dense" = XLA einsum; "flash" = Pallas fused kernel
-            # (tpuserve.ops.flash_attention); "ring"/"ulysses" =
-            # sequence-parallel over the serving mesh (tpuserve.ops).
-            attention_impl=attention,
             # options.moe_experts=N serves a Switch-MoE FFN variant with the
             # expert dim sharded on "model" (expert parallelism).
             moe_experts=moe_experts,
@@ -364,12 +253,8 @@ class BertServing(ServingModel):
             quantize_compute=cfg.quantize == "int8c",
         )
         self.top_k = min(5, cfg.num_classes)
-        # Documents may share a row where the program can keep them apart:
-        # one device and no mesh, a dense feed-forward (a routed one counts
-        # its capacity by row), and an attention path that takes segments
-        # (the XLA pair, or the whole-sequence kernel chosen per bucket).
-        self.packs_rows = (cfg.parallelism == "single" and not moe_experts
-                           and attention == "dense")
+        # The rule and its one exception are input_signature's docstring.
+        self.packs_rows = cfg.parallelism == "single" and not moe_experts
 
     def int8c_native_kernel_paths(self) -> list[str]:
         """The kernels the int8c modules consume natively: FFN matmuls
@@ -382,25 +267,6 @@ class BertServing(ServingModel):
             return []
         return [r"mlp_(up|down)/kernel$",
                 r"attn/(query|key|value|out)/kernel$"]
-
-    def bind_mesh(self, mesh: Any) -> None:
-        """Mesh-aware attention closes over the serving mesh: ring/ulysses
-        always; flash only in sharded mode (it shard_maps over the mesh —
-        replica/single modes call the kernel directly). Pipeline mode stores
-        the ("stage",) mesh for _pipeline_forward and validates the layer
-        split here (the stage count is only known once the mesh exists)."""
-        if self.cfg.parallelism == "pipeline":
-            s = int(mesh.shape["stage"])
-            if self.module.layers % s:
-                raise ValueError(
-                    f"pipeline: layers={self.module.layers} must split "
-                    f"evenly over {s} stages; adjust options.layers or pp")
-            self._stage_mesh = mesh
-            return
-        if self.module.attention_impl in ("ring", "ulysses") or (
-                self.module.attention_impl == "flash"
-                and self.cfg.parallelism == "sharded"):
-            self.module = self.module.clone(mesh=mesh)
 
     def import_tf_variables(self, flat: dict) -> Any:
         """HF transformers TFBert(ForSequenceClassification) -> this pytree.
@@ -502,12 +368,7 @@ class BertServing(ServingModel):
         s = min(self.cfg.seq_buckets)
         ids = jnp.zeros((1, s), jnp.int32)
         mask = jnp.ones((1, s), jnp.int32)
-        # Init through the dense-attention twin: the attention impl doesn't
-        # change the param tree, and init runs on the host CPU (runtime pins
-        # it there), where the compiled Pallas kernel can't execute.
-        init_module = (self.module.clone(attention_impl="dense")
-                       if self.module.attention_impl != "dense" else self.module)
-        return init_module.init(rng, ids, mask)
+        return self.module.init(rng, ids, mask)
 
     # -- shapes --------------------------------------------------------------
     def buckets(self) -> list[tuple]:
@@ -521,9 +382,13 @@ class BertServing(ServingModel):
         return (self.cfg.batch_buckets[-1], s)
 
     def input_signature(self, bucket: tuple) -> Any:
-        """(ids, mask); where rows are shared (ids, segments, cls_at): each
-        token's document within its row, and every document's [CLS] as a
-        flat position in arrival order."""
+        """(ids, segments, cls_at): each token's document within its row
+        (several whole documents share a row, each attending only to
+        itself) and every document's [CLS] as a flat position in arrival
+        order. The one exception: a mesh (``parallelism = "replica"`` or
+        ``"sharded"``) and the routed feed-forward (``options.moe_experts``,
+        which counts capacity by row) take (ids, mask) and one document a
+        row."""
         b, s = bucket
         sig = (jax.ShapeDtypeStruct((b, s), jnp.int32),
                jax.ShapeDtypeStruct((b, s), jnp.int32))
@@ -544,20 +409,17 @@ class BertServing(ServingModel):
     # -- device side ---------------------------------------------------------
     def forward(self, params: Any, batch: Any) -> dict:
         ids, mask, *cls_at = batch   # mask: segment numbers beside cls_at
-        if self.cfg.parallelism == "pipeline":
-            logits = self._pipeline_logits(params, ids, mask)
-        else:
-            module = self.module
-            if self._choose_attention:
-                from tpuserve.ops.flash_attention import attention_path, platform_here
+        module = self.module
+        if self._choose_attention:
+            from tpuserve.ops.fused_attention import attention_path, platform_here
 
-                module = module.clone(attention_impl=attention_path(
-                    platform_here(), self.dtype, ids.shape[1],
-                    module.d_model // module.heads))
-            # Runs while the bucket is traced, never per call: the record of
-            # what this bucket's program holds (traced_paths).
-            self._attention_traced[tuple(ids.shape)] = module.attention_impl
-            logits = module.apply(params, ids, mask, *cls_at)
+            module = module.clone(attention_impl=attention_path(
+                platform_here(), self.dtype, ids.shape[1],
+                module.d_model // module.heads))
+        # Runs while the bucket is traced, never per call: the record of
+        # what this bucket's program holds (traced_paths).
+        self._attention_traced[tuple(ids.shape)] = module.attention_impl
+        logits = module.apply(params, ids, mask, *cls_at)
         probs = jax.nn.softmax(logits, axis=-1)
         top_p, top_i = jax.lax.top_k(probs, self.top_k)
         return {"probs": top_p, "indices": top_i}
@@ -565,88 +427,6 @@ class BertServing(ServingModel):
     def traced_paths(self, bucket: tuple) -> dict:
         path = self._attention_traced.get(tuple(bucket))
         return {"attention": path} if path else {}
-
-    # -- pipeline serving (parallelism = "pipeline") -------------------------
-    def prepare_host_params(self, params: Any) -> Any:
-        """Restack the flax tree stage-major for GPipe serving: layer i's
-        block params land in stage i // (L/S), slot i %% (L/S), stacked so
-        every ``staged/blk{j}`` leaf has a leading (S, ...) dim sharded on
-        the "stage" axis — each device materializes 1/S of the trunk, the
-        memory point of PP. Embed/pooler/classifier stay replicated under
-        ``unstaged``. Inverse mapping keeps checkpoints portable: any
-        weights loadable in single mode load identically here."""
-        if self.cfg.parallelism != "pipeline":
-            return params
-        if self._stage_mesh is None:
-            raise RuntimeError("bind_mesh must run before prepare_host_params")
-        s = int(self._stage_mesh.shape["stage"])
-        p = dict(params["params"])
-        per = self.module.layers // s
-        layers = [p.pop(f"layer{i}") for i in range(self.module.layers)]
-        staged = {
-            f"blk{j}": jax.tree_util.tree_map(
-                lambda *xs: np.stack(xs),
-                *[layers[st * per + j] for st in range(s)])
-            for j in range(per)
-        }
-        return {"unstaged": p, "staged": staged}
-
-    def _pp_micro(self, b: int, s: int) -> int:
-        """Microbatch count: options.pp_micro, else the largest divisor of
-        the bucket batch <= 2*S (enough microbatches to amortize the
-        (S-1)-tick pipeline bubble without shrinking the per-tick matmul
-        below MXU-filling sizes)."""
-        override = int(self.cfg.options.get("pp_micro", 0))
-        if override:
-            if b % override:
-                raise ValueError(
-                    f"options.pp_micro={override} must divide every batch "
-                    f"bucket; {b} is not divisible")
-            return override
-        return max(d for d in range(1, b + 1) if b % d == 0 and d <= 2 * s)
-
-    def _pipeline_logits(self, params: Any, ids, mask):
-        """BertClassifier.__call__ restructured as embed (replicated) ->
-        GPipe trunk (pipeline_forward over the stage mesh) -> head
-        (replicated). The padding mask rides the microbatch stream as one
-        extra channel so stage_fn stays shape-preserving."""
-        from tpuserve.parallel.pipeline import pipeline_forward
-
-        mod = self.module
-        mesh = self._stage_mesh
-        s_axis = int(mesh.shape["stage"])
-        per = mod.layers // s_axis
-        dt = mod.dtype
-        u = params["unstaged"]
-        b, seq = ids.shape
-
-        x = nn.Embed(mod.vocab_size, mod.d_model, dtype=dt).apply(
-            {"params": u["embed"]}, ids)
-        x = x + u["pos_embed"][None, :seq, :].astype(dt)
-        x = nn.LayerNorm(epsilon=mod.ln_eps, dtype=dt).apply(
-            {"params": u["ln_embed"]}, x)
-
-        block = BertBlock(mod.heads, mod.d_ff, dtype=dt,
-                          attention_impl="dense", ln_eps=mod.ln_eps)
-
-        def stage_fn(sp, x_aug):
-            h, maskc = x_aug[..., : mod.d_model], x_aug[..., mod.d_model]
-            bias = (1.0 - maskc.astype(jnp.float32))[:, None, None, :] * -1e9
-            for j in range(per):
-                h = block.apply({"params": sp[f"blk{j}"]}, h, bias)
-            return jnp.concatenate([h, maskc[..., None]], axis=-1)
-
-        x_aug = jnp.concatenate([x, mask.astype(dt)[..., None]], axis=-1)
-        n_micro = self._pp_micro(b, s_axis)
-        xs = x_aug.reshape(n_micro, b // n_micro, seq, mod.d_model + 1)
-        ys = pipeline_forward(stage_fn, params["staged"], xs, mesh)
-        x = ys.reshape(b, seq, mod.d_model + 1)[..., : mod.d_model]
-
-        cls = x[:, 0, :]
-        pooled = jnp.tanh(nn.Dense(mod.d_model, dtype=dt).apply(
-            {"params": u["pooler"]}, cls))
-        return nn.Dense(mod.num_classes, dtype=jnp.float32).apply(
-            {"params": u["classifier"]}, pooled)
 
     # -- host side -----------------------------------------------------------
     def host_decode(self, payload: bytes, content_type: str) -> np.ndarray:
@@ -763,10 +543,6 @@ class BertServing(ServingModel):
 
     # -- parallelism ---------------------------------------------------------
     def partition_rules(self):
-        if self.cfg.parallelism == "pipeline":
-            # Stage-stacked trunk on the ("stage",) axis; embed/head
-            # replicated (prepare_host_params produced this layout).
-            return [(r"^staged/", P("stage")), (r".*", P())]
         if self.cfg.tp <= 1:
             return [(".*", P())]
         return [

@@ -127,48 +127,6 @@ def timestep_embedding(t: jax.Array, dim: int) -> jax.Array:
     return jnp.concatenate([jnp.cos(args), jnp.sin(args)], axis=-1)
 
 
-def _flash_unet_attention_fn(q, k, v, bias=None, mask=None, **kw):
-    """``flax.linen.MultiHeadDotProductAttention`` attention_fn that routes
-    UNet spatial self-attention through the fused Pallas kernel
-    (tpuserve.ops.flash_attention) instead of materializing the (N, N)
-    score matrix to HBM twice — at 512 px the level-0 self-attention is
-    N = 4096 tokens, the single largest HBM-traffic site in the denoise
-    step (BASELINE.md "SD 1.5 chip profile").
-
-    SD head dims (40/80/160) are mostly not lane-aligned; the kernel takes
-    them zero-padded to the next multiple of 64. Padding is mathematically
-    exact: zero lanes add nothing to q.k scores, and padded V columns only
-    produce output columns that are sliced off. The kernel scales by
-    padded_d**-0.5 internally, so q is pre-scaled by (padded_d/d)**0.5 to
-    land on the true d**-0.5. Hooking attention_fn (not replacing the
-    module) keeps the param tree identical to the dense path — the torch
-    import mappers (sd15_import) are untouched.
-
-    Small token counts (N < 1024: the 16 px and 8 px levels, and all
-    77-key cross-attention, which never takes this path) fall back to
-    flax's dense attention — at those sizes the score matrix fits cache
-    and the kernel's padded lanes would cost more than they save.
-    """
-    n = q.shape[1]
-    if bias is not None or mask is not None or n < 1024:
-        return nn.dot_product_attention(
-            q, k, v, bias=bias, mask=mask,
-            dtype=kw.get("dtype"), deterministic=True)
-    from tpuserve.ops.flash_attention import flash_attention
-
-    d = q.shape[-1]
-    dp = -(-d // 64) * 64
-
-    def pad_d(x):
-        if d == dp:
-            return x
-        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, dp - d)])
-
-    qf = pad_d(q) * jnp.asarray((dp / d) ** 0.5, q.dtype)
-    out = flash_attention(qf, pad_d(k), pad_d(v))
-    return out[..., :d]
-
-
 class ResBlock(nn.Module):
     out_ch: int
     dtype: Any = jnp.bfloat16
@@ -194,24 +152,18 @@ class TransformerBlock(nn.Module):
 
     heads: int
     dtype: Any = jnp.bfloat16
-    # "dense" | "flash": spatial self-attention impl (cross-attention over
-    # the 77 text keys always stays dense — see _flash_unet_attention_fn).
-    attention_impl: str = "dense"
 
     @nn.compact
     def __call__(self, x, ctx):  # x (B,N,C), ctx (B,77,Dtxt)
         d = x.shape[-1]
 
-        def attn(name: str, self_attn: bool = False):
-            fn = (_flash_unet_attention_fn
-                  if self_attn and self.attention_impl == "flash"
-                  else nn.dot_product_attention)
+        def attn(name: str):
             return nn.MultiHeadDotProductAttention(
                 num_heads=self.heads, dtype=self.dtype, deterministic=True,
-                attention_fn=fn, name=name)
+                name=name)
 
         h = _ln("ln1")(x).astype(self.dtype)
-        x = x + attn("self_attn", self_attn=True)(h, h, h)
+        x = x + attn("self_attn")(h, h, h)
         h = _ln("ln2")(x).astype(self.dtype)
         x = x + attn("cross_attn")(h, ctx, ctx)
         h = _ln("ln3")(x).astype(self.dtype)
@@ -224,7 +176,6 @@ class TransformerBlock(nn.Module):
 class SpatialTransformer(nn.Module):
     heads: int
     dtype: Any = jnp.bfloat16
-    attention_impl: str = "dense"
 
     @nn.compact
     def __call__(self, x, ctx):  # (B,H,W,C)
@@ -233,7 +184,6 @@ class SpatialTransformer(nn.Module):
         h = nn.Conv(c, (1, 1), dtype=self.dtype, name="proj_in")(h)
         h = h.reshape(b, hh * ww, c)
         h = TransformerBlock(self.heads, dtype=self.dtype,
-                             attention_impl=self.attention_impl,
                              name="block")(h, ctx)
         h = h.reshape(b, hh, ww, c)
         return x + nn.Conv(c, (1, 1), dtype=self.dtype, name="proj_out")(h)
@@ -248,7 +198,6 @@ class UNet(nn.Module):
     attn_levels: Sequence[int] = (0, 1, 2)
     heads: int = 8
     dtype: Any = jnp.bfloat16
-    attention_impl: str = "dense"  # spatial self-attention: "dense" | "flash"
 
     @nn.compact
     def __call__(self, x, t, ctx):  # x (B,h,w,4), t (B,), ctx (B,77,D)
@@ -268,7 +217,6 @@ class UNet(nn.Module):
                              name=f"down{i}_res{j}")(h, temb)
                 if i in self.attn_levels:
                     h = SpatialTransformer(self.heads, dtype=self.dtype,
-                                           attention_impl=self.attention_impl,
                                            name=f"down{i}_attn{j}")(h, ctx)
                 skips.append(h)
             if i != len(self.mults) - 1:
@@ -282,7 +230,6 @@ class UNet(nn.Module):
         # Middle.
         h = ResBlock(h.shape[-1], dtype=self.dtype, name="mid_res1")(h, temb)
         h = SpatialTransformer(self.heads, dtype=self.dtype,
-                               attention_impl=self.attention_impl,
                                name="mid_attn")(h, ctx)
         h = ResBlock(h.shape[-1], dtype=self.dtype, name="mid_res2")(h, temb)
         # Up path.
@@ -293,7 +240,6 @@ class UNet(nn.Module):
                              name=f"up{i}_res{j}")(h, temb)
                 if i in self.attn_levels:
                     h = SpatialTransformer(self.heads, dtype=self.dtype,
-                                           attention_impl=self.attention_impl,
                                            name=f"up{i}_attn{j}")(h, ctx)
             if i != 0:
                 b, hh, ww, c = h.shape
@@ -442,18 +388,13 @@ class SD15Serving(GenerativeModel):
             d_model=int(o.get("text_d_model", 768)),
             heads=int(o.get("text_heads", 12)),
             dtype=self.dtype)
-        unet_attention = str(o.get("unet_attention", "dense"))
-        if unet_attention not in ("dense", "flash"):
-            raise ValueError("options.unet_attention must be 'dense' or "
-                             f"'flash', got {unet_attention!r}")
         self.unet = UNet(
             model_ch=int(o.get("unet_ch", 320)),
             mults=tuple(o.get("unet_mults", (1, 2, 4, 4))),
             num_res=int(o.get("unet_res", 2)),
             attn_levels=tuple(o.get("unet_attn_levels", (0, 1, 2))),
             heads=int(o.get("unet_heads", 8)),
-            dtype=self.dtype,
-            attention_impl=unet_attention)
+            dtype=self.dtype)
         self.vae = VAEDecoder(
             ch=int(o.get("vae_ch", 128)),
             mults=tuple(o.get("vae_mults", (1, 2, 4, 4))),

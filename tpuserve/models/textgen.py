@@ -2,10 +2,8 @@
 iteration-level engine exists for.
 
 A prefix-LM decoder: the prompt is encoded **bidirectionally** in one
-prefill pass (which is exactly the per-key-bias shape the seeded Pallas
-flash-attention kernel supports — ``options.attention = "flash"`` routes
-prefill through ``tpuserve.ops.flash_attention``; generated tokens then
-decode strictly left-to-right against the KV cache). Sampling is seeded and
+prefill pass; generated tokens then decode strictly left-to-right against
+the KV cache. Sampling is seeded and
 positional (``fold_in(fold_in(key(0), seed), position)``), so identical
 (prompt, seed, temperature, max_new_tokens) requests produce identical
 token streams across processes, batch compositions, and — the property
@@ -80,10 +78,6 @@ class TextGenServing(GenerativeModel):
                 f"options.d_model={self.d_model} must divide by "
                 f"heads={self.heads}")
         self.head_dim = self.d_model // self.heads
-        self.attention = str(o.get("attention", "dense"))
-        if self.attention not in ("dense", "flash"):
-            raise ValueError("options.attention must be 'dense' or 'flash', "
-                             f"got {self.attention!r}")
         # Switch-MoE FFN variant (ISSUE 20): 0 = dense MLP (the default and
         # the historical RNG stream); >= 2 replaces every layer's MLP with
         # top-1 routing over ops.moe.switch_route.
@@ -91,10 +85,6 @@ class TextGenServing(GenerativeModel):
         if self.moe_experts == 1 or self.moe_experts < 0:
             raise ValueError("options.moe_experts must be 0 (dense MLP) "
                              f"or >= 2 experts, got {self.moe_experts}")
-        if self.attention == "flash" and self.max_prompt % 8:
-            raise ValueError(
-                f"options.attention='flash' needs prompt_len "
-                f"({self.max_prompt}) divisible by 8 (TPU tile rows)")
         vocab_file = o.get("vocab_file")
         if vocab_file:
             self.tokenizer = WordPieceTokenizer.from_vocab_file(vocab_file)
@@ -242,11 +232,7 @@ class TextGenServing(GenerativeModel):
     # -- shared device math ---------------------------------------------------
     def _attend_prefill(self, q, k, v, key_bias):
         """(B, P, H, hd) bidirectional attention with an additive per-key
-        padding bias (B, P) — flash kernel or the dense twin."""
-        if self.attention == "flash":
-            from tpuserve.ops.flash_attention import flash_attention
-
-            return flash_attention(q, k, v, key_bias)
+        padding bias (B, P)."""
         scale = q.shape[-1] ** -0.5
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
         s = s + key_bias[:, None, None, :]

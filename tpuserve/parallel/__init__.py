@@ -11,12 +11,6 @@ Submodules:
 - ``mesh``        — mesh construction (dp/tp/sp axes, host-major multi-host grid)
 - ``partition``   — regex partition rules -> PartitionSpec pytrees
 - ``distributed`` — jax.distributed.initialize seam for multi-host pods
-- ``pipeline``    — GPipe-style pipeline parallelism over a "stage" axis
-                    (stage-sharded stacked params, ppermute microbatch flow)
-
-Sequence parallelism for long contexts lives at the op level:
-``tpuserve.ops.ring_attention`` (shard_map + ppermute over the "seq" axis)
-and ``tpuserve.ops.ulysses`` (head all-to-all).
 """
 
 from tpuserve.parallel.distributed import (  # noqa: F401
@@ -32,11 +26,6 @@ from tpuserve.parallel.mesh import (  # noqa: F401
     local_device_count,
     plan_for,
     select_devices,
-)
-from tpuserve.parallel.pipeline import (  # noqa: F401
-    make_stage_mesh,
-    pipeline_forward,
-    stack_stage_params,
 )
 from tpuserve.parallel.partition import (  # noqa: F401
     match_partition_rules,

@@ -4,7 +4,8 @@ Axis conventions used throughout tpuserve:
 
 - ``"data"``  — data parallel: batches sharded across it, params replicated.
 - ``"model"`` — tensor parallel: weight matrices sharded across it.
-- ``"seq"``   — sequence/context parallel (ring attention) for long inputs.
+- ``"seq"``   — sequence/context parallel: ``textgen`` shards its KV pages
+  over it and the training dry run its activations (GSPMD partitions both).
 
 An inference mesh is usually ``("data",)`` or ``("data", "model")``; the
 training step used by the multi-chip dry run adds ``"seq"``. The same code

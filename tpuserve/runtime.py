@@ -260,7 +260,7 @@ class ModelRuntime:
         # it); by the time a runtime exists, cfg.parallelism is the truth.
         self.pcfg = parallel if parallel is not None else ParallelConfig()
         self.mode = self.cfg.parallelism
-        if self.mode not in ("sharded", "replica", "single", "pipeline"):
+        if self.mode not in ("sharded", "replica", "single"):
             raise ValueError(f"unknown parallelism mode {self.mode!r}")
         if self.cfg.quantize not in (None, "int8", "int8c"):
             raise ValueError(f"unknown quantize mode {self.cfg.quantize!r}")
@@ -286,33 +286,11 @@ class ModelRuntime:
             self.meshes = [make_mesh(MeshPlan(), devices=[d]) for d in devs]
         elif self.mode == "single":
             self.meshes = [make_mesh(MeshPlan(), devices=[devs[0]])]
-        elif self.mode == "pipeline":
-            # GPipe stages over a ("stage",) mesh: each device holds 1/S of
-            # the layer stack's params (tpuserve.parallel.pipeline). The
-            # model pipelines its own depth, so it must opt in.
-            if not getattr(model, "pipeline_capable", False):
-                raise ValueError(
-                    f"{model.name}: parallelism='pipeline' needs a family "
-                    f"with a homogeneous block stack; {self.cfg.family!r} "
-                    "does not support it (BERT does) — use 'sharded', "
-                    "'replica', or 'single'")
-            if self.cfg.quantize:
-                raise ValueError(
-                    "parallelism='pipeline' does not compose with quantize "
-                    "modes yet; drop one of the two")
-            from tpuserve.parallel.pipeline import make_stage_mesh
-
-            n = self.cfg.pp or len(devs)
-            self.meshes = [make_stage_mesh(n)]
         else:
             self.meshes = [mesh if mesh is not None
                            else make_mesh(plan_for(self.pcfg, tp=self.cfg.tp,
                                                    sp=self.cfg.sp),
                                           devices=devs)]
-        # Mesh-aware models (e.g. BERT ring attention) rebuild their forward
-        # around the serving mesh; must precede param load and compilation.
-        model.bind_mesh(self.meshes[0])
-
         if self.mode == "sharded":
             # Sharded-batch executables need batch % data-axis == 0; normalize
             # buckets up to mesh multiples (batch=1 latency work belongs in
@@ -400,8 +378,7 @@ class ModelRuntime:
         if drawn is not None:
             self.params_per_mesh = drawn
             return
-        self.params_per_mesh = self._shard_onto_meshes(
-            self.model.prepare_host_params(self._load_host_params()))
+        self.params_per_mesh = self._shard_onto_meshes(self._load_host_params())
 
     def _params_drawn_on_device(self) -> "list | None":
         """Weights by recipe (ISSUE 28): a family with ``device_params``
@@ -519,8 +496,6 @@ class ModelRuntime:
             return f"sharded@d{self.meshes[0].shape['data']}"
         if self.mode == "replica":
             return f"replica@{len(self.meshes)}"
-        if self.mode == "pipeline":
-            return f"pipeline@{dict(self.meshes[0].shape).get('stage', 1)}"
         return self.mode
 
     def variant_key(self, bucket: tuple) -> VariantKey:
@@ -701,8 +676,7 @@ class ModelRuntime:
         SAME program is compiled once per replica mesh (mirroring
         ``_compile_bucket``), so one ``GenEngine`` per replica dispatches
         via ``run_program(..., replica=i)`` with no cross-engine contention
-        on compiled state. Pipeline mode does not compose — the engine
-        owns whole-model state, stage-stacked params don't.
+        on compiled state.
 
         ``arg_structs`` leaves are replicated (P()) onto the mesh unless
         ``arg_specs`` (a tuple parallel to ``arg_structs`` of
@@ -717,11 +691,6 @@ class ModelRuntime:
         indexes into ``args`` (0 = the first arg after params) and is
         honored off-CPU only — on the CPU backend device_put may alias
         host memory (the assembly-arena rule)."""
-        if self.mode == "pipeline":
-            raise ValueError(
-                f"{self.model.name}: generative programs do not compose "
-                "with the pipeline layout (the engine owns whole-model "
-                "state; stage-stacked params do not)")
         t0 = time.perf_counter()
         donate = ()
         if donate_argnums and jax.default_backend() != "cpu":
@@ -1018,7 +987,7 @@ class ModelRuntime:
                 raise NaNDetected(
                     f"candidate weights for {name} hold NaN/Inf in {bad}; "
                     "candidate rejected")
-        fresh = self._shard_onto_meshes(self.model.prepare_host_params(params))
+        fresh = self._shard_onto_meshes(params)
         old = self.params_per_mesh
         if old:
             same_struct = (jax.tree_util.tree_structure(old[0])

@@ -313,9 +313,8 @@ class ServerState:
             jax.profiler.start_server(self.cfg.profiler_port)
         # [parallel] mode override (docs/PERFORMANCE.md "Serving on the
         # mesh"): applied at the CONFIG level, before the model is built,
-        # so family-level mode validation (e.g. BERT ring attention
-        # rejecting replica) and the model's own batch_spec see the real
-        # serving mode.
+        # so family-level mode validation and the model's own batch_spec
+        # see the real serving mode.
         if self.cfg.parallel.mode:
             for mcfg in self.cfg.models:
                 if mcfg.parallelism != self.cfg.parallel.mode:

@@ -11,10 +11,9 @@ matmuls) over ICI.
 The model is a compact pre-LN transformer encoder LM trained with masked-token
 cross-entropy via optax.adamw. Everything is shape-static and scans-free at
 this size; jax.checkpoint on the block stack trades FLOPs for HBM when
-layers/seq grow. With ``TrainConfig.seq_attention`` set to "ring" or
-"ulysses" the blocks use ``tpuserve.ops.ring_attention`` /
-``tpuserve.ops.ulysses`` over the mesh's "seq" axis instead of dense
-attention, so the dry run exercises real sequence parallelism.
+layers/seq grow. Attention is dense: GSPMD partitions an activation that is
+sharded on "seq" itself (tests/test_parallel.py holds it to one device's
+answer).
 """
 
 from __future__ import annotations
@@ -45,10 +44,6 @@ class TrainConfig:
     max_seq: int = 32
     lr: float = 1e-3
     remat: bool = False
-    # Sequence-parallel attention over the mesh "seq" axis: "dense" (no SP),
-    # "ring" (K/V ppermute rotation, tpuserve.ops.ring_attention), or
-    # "ulysses" (head all-to-all, tpuserve.ops.ulysses).
-    seq_attention: str = "dense"
     # Mixture-of-experts FFN: 0 = dense MLP; N > 0 = Switch top-1 routing
     # over N experts (tpuserve.ops.moe), expert dim sharded on "model" (EP).
     moe_experts: int = 0
@@ -59,38 +54,13 @@ class TrainConfig:
 class Block(nn.Module):
     cfg: TrainConfig
     dtype: Any = jnp.float32
-    mesh: Any = None  # required when cfg.seq_attention != "dense"
 
     @nn.compact
     def __call__(self, x, mask=None):
         c = self.cfg
-        attention_fn = nn.dot_product_attention
-        if c.seq_attention != "dense":
-            from tpuserve.ops import ring_attention, ulysses_attention
-
-            if c.seq_attention not in ("ring", "ulysses"):
-                raise ValueError(f"unknown seq_attention {c.seq_attention!r}")
-            if self.mesh is None:
-                raise ValueError(f"TrainConfig.seq_attention={c.seq_attention!r} "
-                                 "requires passing mesh= to the module")
-            sp_attn = ring_attention if c.seq_attention == "ring" else ulysses_attention
-            # Keep heads tensor-parallel when tp divides them; otherwise
-            # replicate heads (still seq- and data-parallel). Ulysses further
-            # needs the local heads divisible by sp (validated in the op).
-            head_axis = "model" if c.n_heads % self.mesh.shape["model"] == 0 else None
-            spec = P("data", "seq", head_axis, None)
-
-            def attention_fn(query, key, value, mask=None, **_kw):  # noqa: ANN001
-                if mask is not None:
-                    raise NotImplementedError(
-                        "sequence-parallel train path takes no attention mask; "
-                        "pass padding via loss masking instead")
-                return sp_attn(query, key, value, self.mesh, spec=spec)
-
         h = nn.LayerNorm(dtype=self.dtype, name="ln1")(x)
         h = nn.MultiHeadDotProductAttention(num_heads=c.n_heads, dtype=self.dtype,
-                                            deterministic=True, name="attn",
-                                            attention_fn=attention_fn)(h)
+                                            deterministic=True, name="attn")(h)
         x = x + h
         h = nn.LayerNorm(dtype=self.dtype, name="ln2")(x)
         if c.moe_experts:
@@ -112,7 +82,6 @@ class Block(nn.Module):
 class TransformerLM(nn.Module):
     cfg: TrainConfig
     dtype: Any = jnp.float32
-    mesh: Any = None
 
     @nn.compact
     def __call__(self, tokens, mask=None):
@@ -124,7 +93,7 @@ class TransformerLM(nn.Module):
         if c.remat:
             block = nn.remat(Block)
         for i in range(c.n_layers):
-            x = block(c, dtype=self.dtype, mesh=self.mesh, name=f"block{i}")(x, mask)
+            x = block(c, dtype=self.dtype, name=f"block{i}")(x, mask)
         x = nn.LayerNorm(dtype=self.dtype, name="ln_f")(x)
         return nn.Dense(c.vocab, dtype=jnp.float32, name="lm_head")(x)
 
@@ -147,10 +116,8 @@ TRAIN_PARTITION_RULES: list[tuple[str, P]] = [
 
 def make_train_state(mesh: Mesh, cfg: TrainConfig, rng: jax.Array | None = None):
     """Init params + opt state, sharded by the TP rules over `mesh`."""
-    model = TransformerLM(cfg, mesh=mesh)
+    model = TransformerLM(cfg)
     rng = rng if rng is not None else jax.random.key(0)
-    # Init batch must divide the data axis: ring attention shard_maps the
-    # activations over ("data", "seq") even at init time.
     tokens = jnp.zeros((mesh.shape["data"], cfg.max_seq), jnp.int32)
     params = model.init(rng, tokens)["params"]
 
@@ -233,7 +200,7 @@ def restore_train_state(path: str, mesh: Mesh, cfg: TrainConfig):
 
     import orbax.checkpoint as ocp
 
-    model = TransformerLM(cfg, mesh=mesh)
+    model = TransformerLM(cfg)
     tokens = jnp.zeros((mesh.shape["data"], cfg.max_seq), jnp.int32)
     params_shape = jax.eval_shape(model.init, jax.random.key(0), tokens)["params"]
     specs = match_partition_rules(TRAIN_PARTITION_RULES, params_shape)
@@ -285,17 +252,15 @@ def mesh_plan_for(n_devices: int) -> MeshPlan:
 def dryrun(devices: list, steps: int = 1) -> float:
     """One (or more) real sharded train step(s) on the given devices.
 
-    When the mesh has a real "seq" axis (sp > 1), attention runs through
-    tpuserve.ops.ring_attention so the dry run exercises genuine sequence
-    parallelism (K/V ppermute around the ring), alongside DP and TP. When
+    When the mesh has a real "seq" axis (sp > 1), the batch is sharded on
+    it and GSPMD partitions the dense attention, alongside DP and TP. When
     the "model" axis is real (tp > 1), the FFN runs as a Switch MoE with
     the expert dim sharded over it — expert parallelism in the same step.
     """
     n = len(devices)
     plan = mesh_plan_for(n)
     mesh = make_mesh(plan, devices=devices)
-    cfg = TrainConfig(seq_attention="ring" if plan.sp > 1 else "dense",
-                      moe_experts=2 * plan.tp if plan.tp > 1 else 0)
+    cfg = TrainConfig(moe_experts=2 * plan.tp if plan.tp > 1 else 0)
     model, params, tx, opt_state, shardings = make_train_state(mesh, cfg)
     step, _ = make_train_step(model, tx, mesh, shardings)
     batch_size = max(4, 2 * mesh.shape["data"])
