@@ -19,6 +19,24 @@ time by `benchmark/flops/eva.py` `attend_decode` for one layer.
     chiprun -- python scripts/bench_eva_walk.py
     python scripts/bench_eva_walk.py --rehearse
 
+AND A LAUNCH'S ATTENTION ALONE (ISSUE 58; `--only launch`): ONE layer's tiles
+of a prefill launch of the cell's shape (1,024 rows in 8 tiles of 128) over the
+same pool, through the model's own plan and `_attend_tiles`, on both paths:
+
+- `ops/launch_attention.py` `launch_walk` over the launch's flat work list of the
+  (tile, key page) items that exist (ring pages that hold the tile's window, the
+  launch's own rows laid as pages, summary pages), the transposes that lay the
+  own rows as pages included;
+- `_tile` in XLA a tile at a time (`lax.map`), the fallback and what a launch
+  ran until ISSUE 58;
+
+at the mix's launches: a full launch of one piece at window offsets 0 and 1,024
+with 0, 3 and 11 closed windows behind it, and a launch of two pieces of two
+prompts; beside the items, the pages' bytes at the chip's rate (the least a cell
+can take: it is bound by its page's arrival) and `flops/eva.py` `attend_prefill`'s
+least for one layer, the pooling apart; `paths_differ_by` is the largest gap
+between the two paths' live rows (bfloat16 products, float32 sums: some 2e-3).
+
 One JSON line a case on stdout and in `chiprun_out/bench_eva_walk/`. Off the TPU
 it walks a toy shape once (`--rehearse`) and prints no time.
 """
@@ -26,6 +44,8 @@ it walks a toy shape once (`--rehearse`) and prints no time.
 from __future__ import annotations
 
 import argparse
+import contextlib
+import functools
 import json
 import os
 import sys
@@ -41,7 +61,10 @@ import numpy as np  # noqa: E402
 from bench_head_walk import device_ms  # noqa: E402
 
 from benchmark import spec  # noqa: E402
+from tpuserve.config import ModelConfig  # noqa: E402
+from tpuserve.models import build  # noqa: E402
 from tpuserve.ops import lane_attention as la  # noqa: E402
+from tpuserve.ops import launch_attention as lat  # noqa: E402
 
 
 def tables(pos: np.ndarray, W: int, c: int, P: int, pps: int, slots: int, wide: int):
@@ -56,11 +79,116 @@ def tables(pos: np.ndarray, W: int, c: int, P: int, pps: int, slots: int, wide: 
     return table, (n * P + j + 1).astype(np.int32)
 
 
+def launch_cases(sz: dict, flops, peaks, kp, vp, out_dir: str, iters: int, on_tpu: bool,
+                 rng) -> list:
+    """ONE layer's attention of a prefill launch (ISSUE 58) through the model's
+    own `_prefill_plan` and `_attend_tiles`, the kernel and `_tile` in XLA."""
+    arch_path = os.path.join(out_dir, "arch.json")
+    with open(arch_path, "w", encoding="utf-8") as f:
+        json.dump(sz["arch"], f)
+    model = build(ModelConfig(   # for its plan and its tiles; no weight is drawn
+        name="m", family="eva", dtype="bfloat16", batch_buckets=[1],
+        options={"config_file": arch_path, "max_prompt_tokens": sz["max_prompt"],
+                 "max_new_tokens": sz["max_new"]}))
+    W, P, c, slots, pps = model.window, model.rows, model.chunk, sz["slots"], sz["pages_per_slot"]
+    C = min(sz["prefill_chunk"], W)
+    K = model.kv_prefill_pieces(C, P)
+    T, (H, kv, hd) = C // K, (model.heads[0], model.kv, model.hd)
+    if kp.shape[-1] != hd:   # the rehearsal's toy heads: the interpreter takes any width
+        kp, vp = (jnp.asarray(rng.standard_normal(kp.shape[:3] + (hd,)), kp.dtype)
+                  for _ in range(2))
+    state = {"bt": jnp.asarray(1 + np.arange(slots * pps).reshape(slots, pps) % (
+        kp.shape[1] - c * (slots + 1) - 1), jnp.int32), "pos": jnp.zeros((slots,), jnp.int32),
+        "kf": [kp]}
+    bt = np.asarray(state["bt"])
+    # (slot, start, length) a piece: one full piece at an offset of its window with
+    # closed windows behind it, and two pieces of two prompts
+    cases = {f"full-at-{off}-after-{n}-windows": [(1, n * W + off, C)]
+             for off in (0, W // 2) for n in (0, 3, 11) if n * W + off + C <= sz["max_ctx"]}
+    cases["two-pieces"] = [(1, 3 * W + W // 2, C // 2), (2, W // 4, C // 2 - T // 2)]
+    lines = []
+    for name, pieces in cases.items():
+        launch = {f: np.zeros((K,), np.int32) for f in ("slot", "start", "length", "ring")}
+        launch["pages"] = np.zeros((K, pps), np.int32)
+        for j, (slot, start, length) in enumerate(pieces):
+            launch["slot"][j], launch["start"][j], launch["length"][j] = slot, start, length
+            launch["ring"][j], launch["pages"][j] = slot + 1, bt[slot]
+        launch = {f: jnp.asarray(x) for f, x in launch.items()}
+        q = jnp.asarray(2.0 * rng.standard_normal((C, H, hd)), jnp.bfloat16)
+        k, v = (jnp.asarray(rng.standard_normal((C, kv, hd)), jnp.bfloat16) for _ in range(2))
+        # what the tokens attend, and the least the layer's attention needs for it
+        pos = np.concatenate([np.arange(s, s + n) for _, s, n in pieces])
+        rows = float(np.sum(pos % W + 1 + pos // W * P))
+        ops, nbytes = flops.attend_prefill({**sz, "layers": 1, "head_dim": hd, "d_model": H * hd},
+                                           float(len(pos)), rows)
+        ops -= 4.0 * H * hd * len(pos)   # the pooling's, which is not in this call
+        least = max(ops / peaks["bf16_flops_per_s"], nbytes / peaks["hbm_bytes_per_s"]) * 1e3 \
+            if peaks else None
+        base = {"case": name, "tokens": len(pos), "rows": rows, "least_ms": least}
+        outs = {}
+        for path in ("tile_kernel", "xla"):
+            def attend(q, k, v, kp, vp, launch, path=path):
+                with steered(path, on_tpu):
+                    m = model._prefill_plan(state, launch, model._tiles(launch, C))
+                    assert m["tile_path"] == path
+                    o = model._attend_tiles(q, k, v, kp, vp, m)[0]
+                return o, (m["work"]["items"] if m["work"] else 0)
+            fn = jax.jit(attend)
+            outs[path], items = fn(q, k, v, kp, vp, launch)
+            line = {**base, "path": path,
+                    "ms": device_ms(lambda *a, fn=fn: fn(*a)[0], (q, k, v, kp, vp, launch), None,
+                                    out_dir, iters)}
+            if path == "tile_kernel":   # a cell is bound by its page's arrival: K and V
+                page_ms = 2.0 * kv * P * hd * 2 / peaks["hbm_bytes_per_s"] * 1e3 if peaks else None
+                line.update(items=int(items), pages_ms=page_ms and int(items) * page_ms,
+                            kernel_ms=device_ms(lambda *a, fn=fn: fn(*a)[0],
+                                                (q, k, v, kp, vp, launch), "launch_walk",
+                                                out_dir, iters))
+            lines.append(line)
+        if len(outs) == 2:
+            live = np.concatenate([np.arange(j * T, j * T + n) for j, n in zip(
+                np.cumsum([0] + [-(-n // T) for _, _, n in pieces[:-1]]),
+                [n for _, _, n in pieces])])
+            a, b = (np.asarray(o, np.float32)[live] for o in outs.values())
+            lines[-1]["paths_differ_by"] = float(np.abs(a - b).max())
+            assert np.isfinite(np.asarray(outs["tile_kernel"])).all()
+    return lines
+
+
+class _Named:
+    """`jax` as `eva` sees it with the backend named: the family's trace-time
+    choice takes that backend's branch, and nothing else does."""
+
+    def __init__(self, backend: str) -> None:
+        self.default_backend = lambda: backend
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+
+@contextlib.contextmanager
+def steered(path: str, on_tpu: bool):
+    """What is traced inside takes `path`: the kernel where the backend is named
+    the TPU (off it, in the Pallas interpreter at whatever shapes), `_tile` in XLA
+    where it is named another."""
+    from tpuserve.models import eva
+    was = eva.jax, lat.launch_walk, eva.EvaServing._tiles_fit
+    eva.jax = _Named("tpu" if path == "tile_kernel" else "cpu")
+    if not on_tpu:
+        eva.EvaServing._tiles_fit = lambda model, T: True
+        lat.launch_walk = functools.partial(was[1], interpret=True)
+    try:
+        yield
+    finally:
+        eva.jax, lat.launch_walk, eva.EvaServing._tiles_fit = was
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--blocks", default="1,2,4,8")
     ap.add_argument("--iters", type=int, default=8)
+    ap.add_argument("--only", choices=("step", "launch"), help="one phase's cases alone")
     args = ap.parse_args()
     on_tpu = jax.default_backend() == "tpu"
     if not on_tpu and not args.rehearse:
@@ -93,7 +221,9 @@ def main() -> int:
     from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention
 
     lines = []
-    for name, pos in cases.items():
+    if args.only != "step":
+        lines += launch_cases(sz, flops, peaks, kp, vp, out_dir, args.iters, on_tpu, rng)
+    for name, pos in cases.items() if args.only != "launch" else ():
         rows = float(np.sum(pos % W + 1 + pos // W * P))
         ops, nbytes = flops.attend_decode({**sz, "layers": 1, "head_dim": hd, "d_model": kv * hd},
                                           float(slots), rows)

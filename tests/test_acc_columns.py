@@ -54,7 +54,9 @@ SERIES = {
         "eva_rows_attended_total{model=M,phase=PH,kind=summary}",
         "eva_chunks_summarised_total{model=M,phase=PH}", "eva_windows_closed_total{model=M,phase=PH}",
         "decode:eva_decode_steps_total{model=M,phase=decode,path=head_walk}",
-        "decode:eva_decode_steps_total{model=M,phase=decode,path=gather}"],
+        "decode:eva_decode_steps_total{model=M,phase=decode,path=gather}",
+        "prefill:eva_prefill_tiles_total{model=M,phase=prefill,path=tile_kernel}",
+        "prefill:eva_prefill_tiles_total{model=M,phase=prefill,path=xla}"],
 }
 # The fourth expert column sums held experts x expert layers run, so it feeds
 # the layers' counter too, over the experts held.

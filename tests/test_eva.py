@@ -5,7 +5,10 @@ that close in decode, inside a piece, at a piece's last row; a prompt of
 exactly a window; a padded tail); each wrong reading of the layer failing; the
 virtual block table's walk (`head_walk` with a key in one part, ISSUE 56, in
 the Pallas interpreter: the toy's step against the gather's step, and at the
-cell's heads against a plain float32 softmax); a lane that is
+cell's heads against a plain float32 softmax); a launch's attention in ONE
+kernel call a layer (`ops/launch_attention.py` `launch_walk`, ISSUE 58, in the
+interpreter: against `_tile` in XLA, a plain float32 softmax over each row's
+visible keys, and the work list against the masks); a lane that is
 not live keeping ring, pages and lanes to the bit; the cache's geometry and what
 `/stats` says of it; the weights recipe; the counters; and the two copies of
 the reference."""
@@ -29,6 +32,7 @@ from tpuserve.models import decoder as dec
 from tpuserve.models import eva
 from tpuserve.models import seeded
 from tpuserve.ops import lane_attention as la
+from tpuserve.ops import launch_attention as lat
 
 # Two layers; 4 query heads of 16 on 2 KV heads (the cell has no grouping; the
 # walk is written for any); a window of 16 in chunks of 4, so a page is 4
@@ -68,11 +72,12 @@ def zeros(struct):
 
 
 def serve(model, params, prompts, max_news, chunk=CHUNK, launches=None, slots=SLOTS, page=PAGE,
-          steps=None, state=None, steer=None):
+          steps=None, state=None, steer=None, steer_launch=None):
     """What the engine does, by hand: the prompts' pieces through the prefill
     program (``launches``: lists of (slot, start, length); else a prompt alone,
     a chunk a launch), then steps until every lane is done -> (extract() a
-    slot, the last step's out-block, the state)."""
+    slot, the last step's out-block, the state). ``steer`` and ``steer_launch``:
+    a context the steps, the launches are traced and run in."""
     pps = model.kv_pages_per_slot(page)
     if state is None:
         state = zeros(model.kv_page_signature(slots, slots * pps + 1, page))
@@ -93,9 +98,10 @@ def serve(model, params, prompts, max_news, chunk=CHUNK, launches=None, slots=SL
                  "ring": np.int32(slot + 1)}
         return PrefillPiece(slot, item, start, length, cache)
 
-    for pieces in launches:
-        state = prefill(params, state, model.pack_prefill([piece(*p) for p in pieces], chunk, k),
-                        chunk=chunk)
+    with (steer_launch or contextlib.nullcontext)():
+        for pieces in launches:
+            state = prefill(params, state, model.pack_prefill([piece(*p) for p in pieces], chunk,
+                                                              k), chunk=chunk)
     out = None
     with (steer or contextlib.nullcontext)():
         for _ in range(max(max_news) + 1 if steps is None else steps):
@@ -144,12 +150,16 @@ CASES = {
 }
 
 
+@pytest.mark.parametrize("path", eva.TILE_PATHS[::-1])
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_prefill_then_decode_through_ring_and_pages_is_the_reference_full_pass(whole, case):
+def test_prefill_then_decode_through_ring_and_pages_is_the_reference_full_pass(whole, case, path):
+    """``path``: the launches' attention a tile at a time in XLA, and in ONE
+    kernel call a layer (ISSUE 58) through the Pallas interpreter."""
     model, params = whole
     lengths, news, launches = CASES[case]
     prompts = prompts_of(lengths)
-    got, out, _ = serve(model, params, prompts, news, launches=launches)
+    got, out, _ = serve(model, params, prompts, news, launches=launches,
+                        steer_launch=in_the_launch if path == "tile_kernel" else None)
     for g, gap, n in zip(got, gaps(got, prompts, news), news):
         assert int(g["n_new"]) == n
         assert float(gap.max()) < ATOL, case
@@ -164,7 +174,12 @@ def test_prefill_then_decode_through_ring_and_pages_is_the_reference_full_pass(w
         assert list(row[:3]) == [exact + summary, exact, summary]
         assert row[3] == model.n_layers * int(np.sum(pos % model.chunk == model.chunk - 1))
         assert row[4] == int(np.sum(pos % W == W - 1))
-    assert list(acc[0, 5:]) == [0, 0] and list(acc[1, 5:]) == [0, model.n_layers * len(pos_d)]
+    # a step's lanes and a launch's tiles, times the layers, by the path each took
+    T = CHUNK // model.kv_prefill_pieces(CHUNK, PAGE)
+    tiles = model.n_layers * sum(-(-n // T) for of in launches or [[(0, 0, min(CHUNK, n - s))]
+                                 for n in lengths for s in range(0, n, CHUNK)] for _, _, n in of)
+    assert list(acc[0, 5:]) == [0, 0] + [tiles * (path == p) for p in eva.TILE_PATHS]
+    assert list(acc[1, 5:]) == [0, model.n_layers * len(pos_d), 0, 0]
 
 
 @pytest.fixture(scope="module")
@@ -355,6 +370,138 @@ def test_the_walk_with_a_key_in_one_part_is_plain_float32_attention(cell_heads, 
     xla = np.asarray(model._decode_gather(q, (kp, vp), m["table"], m["rows_seen"] - 1,
                                           model._heads()))
     np.testing.assert_allclose(got[live], xla[live], atol=2e-2)
+
+
+# -- a launch's attention in one kernel call a layer --------------------------------------------
+
+@contextlib.contextmanager
+def in_the_launch(seen=None):
+    """What is traced inside takes ``eva``'s TPU branch for a LAUNCH at the
+    toy's shapes (which the interpreter takes and ``fits`` would refuse),
+    ``launch_walk`` in the Pallas interpreter (``seen`` gets each call's
+    operands and work list); a step stays on the gather."""
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(eva, "jax", NamedTpu())
+        m.setattr(eva.EvaServing, "_tiles_fit", lambda self, T: True)
+        m.setattr(eva.EvaServing, "_walks", lambda self, P: False)
+
+        def walk(*a, f=lat.launch_walk, **k):
+            if seen is not None:
+                seen.append(a)
+            return f(*a, interpret=True, **k)
+
+        m.setattr(lat, "launch_walk", walk)
+        yield
+
+
+# One launch of 4 tiles over a synthetic pool (every ring place, every page full of
+# random rows: a row wrongly seen moves the answer): its pieces (slot, start,
+# length) in WINDOWS, each at the next free tile. A window is 4 pages.
+LAUNCH_CASES = {
+    "a-prompts-first-tiles: no ring, no summary": [(0, 0, 1)],
+    "mid-window: ring rows behind, up to the window's end": [(1, 0.5, 0.5)],
+    "across-a-windows-edge: the window the launch closed through its summary": [(2, 0.5, 1)],
+    "deep: three closed windows, the ring's pages of the fourth": [(1, 3.5, 0.5)],
+    "two-pieces-of-two-prompts, one shorter than a page": [(0, 1.5, 0.5), (2, 0.5, 0.125)],
+    "a-padded-tail-and-a-tile-of-no-piece": [(1, 1.5, 0.375), (0, 0, 0.0625)],
+}
+LAUNCH_MODELS = {"toy-float32-grouped": (1, 1e-4), "cell-heads-bfloat16": (2, 2e-2),
+                 "cell-heads-tiles-of-two-pages": (2, 2e-2)}
+
+
+@pytest.mark.parametrize("kind", list(LAUNCH_MODELS))
+@pytest.mark.parametrize("case", list(LAUNCH_CASES))
+def test_a_launchs_tiles_in_one_kernel_call_are_the_tile_in_xla_and_plain_attention(
+        whole, cell_heads, case, kind):
+    """``launch_walk`` (ISSUE 58) in the Pallas interpreter over the launch's
+    own plan and work list, against ``_tile`` in XLA on the same plan and
+    against a plain float32 softmax over each live row's visible keys taken
+    from the pool and the launch one by one (its own window's positions below
+    its piece's start from the ring, the launch's own up to itself, every row
+    of its prompt's earlier windows' summary pages). And the work list: exactly
+    the pages that hold a key some row of the tile sees, none twice, the
+    sentinel for a tile of no piece. The toy in float32 with 2 query heads a KV
+    head; the cell's heads in bfloat16 (2 ** -8 of values near 1), a tile one
+    page and two."""
+    model = whole[0] if kind.startswith("toy") else cell_heads
+    slots, (c, P, W), hd = 3, (model.chunk, model.rows, model.window), model.hd
+    T = P * (2 if kind.endswith("two-pages") else 1)
+    K, tol = 4, LAUNCH_MODELS[kind][1]
+    C, pps, dtype = K * T, 4, model.dtype
+    pieces = [(slot, int(start * W), int(n * W)) for slot, start, n in LAUNCH_CASES[case]]
+    rng = np.random.default_rng(len(case) + T)
+    n_pages = c * (slots + 1) + slots * pps + 1
+    kp, vp = (jnp.asarray(rng.standard_normal((model.kv, n_pages, P, hd)), dtype) for _ in range(2))
+    q = jnp.asarray(2.0 * rng.standard_normal((C, model.heads[0], hd)), dtype)
+    k, v = (jnp.asarray(rng.standard_normal((C, model.kv, hd)), dtype) for _ in range(2))
+    bt = rng.permutation(np.arange(1, 1 + slots * pps)).reshape(slots, pps).astype(np.int32)
+    rings = rng.permutation(np.arange(1, slots + 1)).astype(np.int32)
+    state = {"bt": jnp.asarray(bt), "pos": jnp.zeros((slots,), jnp.int32), "kf": [kp]}
+    launch = {f: np.zeros((K,), np.int32) for f in ("slot", "start", "length", "ring")}
+    launch["pages"] = np.zeros((K, pps), np.int32)
+    for j, (slot, start, length) in enumerate(pieces):
+        launch["slot"][j], launch["start"][j], launch["length"][j] = slot, start, length
+        launch["ring"][j], launch["pages"][j] = rings[slot], bt[slot]
+    launch = {f: jnp.asarray(x) for f, x in launch.items()}
+    plain = model._prefill_plan(state, launch, model._tiles(launch, C))
+    with in_the_launch():
+        m = model._prefill_plan(state, launch, model._tiles(launch, C))
+        got = np.asarray(model._attend_tiles(q, k, v, kp, vp, m)[0])
+    assert (plain["tile_path"], plain["work"], m["tile_path"]) == ("xla", None, "tile_kernel")
+    xla = np.asarray(model._attend_tiles(q, k, v, kp, vp, plain)[0])
+    assert got.shape == q.shape and got.dtype == np.float32 and np.isfinite(got).all()
+
+    # each live row's visible keys, one by one: (operand, page, row) of the pools or the launch
+    first, g = c * (slots + 1), model.heads[0] // model.kv
+    k32, v32, q32, ko32, vo32 = (np.asarray(x.astype(jnp.float32)) for x in (kp, vp, q, k, v))
+    at, seen_pages, live = 0, {}, []
+    for slot, start, length in pieces:
+        for i in range(length):
+            pos, tile = start + i, (at + i) // T
+            w0 = pos // W * W
+            keys = [(0, c * rings[slot] + (t % W) // P, t % P) for t in range(w0, start)] \
+                + [(1, (at + t - start) // P, (at + t - start) % P)
+                   for t in range(max(w0, start), pos + 1)] \
+                + [(0, first + bt[slot, n], r) for n in range(pos // W) for r in range(P)]
+            seen_pages.setdefault(tile, set()).update((src, pg) for src, pg, _ in keys)
+            kk = np.stack([(ko32[pg * P + r] if src else k32[:, pg, r]) for src, pg, r in keys], 1)
+            vv = np.stack([(vo32[pg * P + r] if src else v32[:, pg, r]) for src, pg, r in keys], 1)
+            sc = np.einsum("kgd,kcd->kgc", q32[at + i].reshape(model.kv, g, hd), kk) * hd ** -0.5
+            pr = np.exp(sc - sc.max(axis=-1, keepdims=True))
+            want = np.einsum("kgc,kcd->kgd", pr / pr.sum(axis=-1, keepdims=True), vv)
+            np.testing.assert_allclose(got[at + i], want.reshape(-1, hd), atol=tol)
+            live.append(at + i)
+        at += -(-length // T) * T
+    np.testing.assert_allclose(got[live], xla[live], atol=tol)
+
+    # the work list: tile after tile, exactly the pages seen, none twice
+    work = {f: np.asarray(x) for f, x in m["work"].items()}
+    n = int(work["items"])
+    has = np.asarray(m["t"]["has"])
+    assert n == sum(len(seen_pages[t]) if has[t] else 1 for t in range(K))
+    items = list(zip(work["tile"][:n], work["own"][:n],
+                     np.where(work["own"][:n] == 1, work["page"][:n], work["pool"][:n])))
+    assert len(set(items)) == n and list(work["tile"][:n]) == sorted(work["tile"][:n])
+    for t in range(K):
+        mine = {(int(own), int(pg)) for tile, own, pg in items if tile == t}
+        assert mine == (seen_pages[t] if has[t] else {(0, 0)}), (t, mine)
+    firsts = [i for i in range(n) if work["step"][i] == 0]
+    assert [work["tile"][i] for i in firsts] == list(range(K))                  # a tile's first
+    assert [int(i) - 1 for i in firsts[1:]] + [n - 1] == list(np.flatnonzero(work["left"][:n] == 0))
+
+
+def test_the_cells_launch_fits_the_kernel_and_off_the_tpu_the_plan_holds_no_list(cell_heads):
+    """The cell's shapes (32 heads on 32 KV heads of 128, pages of 128 rows,
+    tiles of 128 in a window of 2,048) fit ``launch_walk`` as they are; what
+    does not (float32, a tile that is no whole 128 rows or straddles windows)
+    stays in XLA; chosen by the backend and the shapes, nothing else."""
+    assert lat.fits(128, 128, 2048, 32, 32, 128, jnp.bfloat16)
+    assert lat.fits(256, 128, 2048, 32, 8, 128, jnp.bfloat16)
+    for bad in ((128, 128, 2048, 32, 32, 128, jnp.float32), (64, 64, 2048, 32, 32, 128, jnp.bfloat16),
+                (384, 128, 2048, 32, 32, 128, jnp.bfloat16), (128, 128, 2048, 32, 32, 64, jnp.bfloat16),
+                (128, 128, 2048, 32, 5, 128, jnp.bfloat16)):
+        assert not lat.fits(*bad), bad
+    assert not cell_heads._tiles_fit(16)       # the test's pages of 16 rows: the interpreter's alone
 
 
 # -- free and frozen lanes, a slot's next tenant ----------------------------------------------

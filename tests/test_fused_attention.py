@@ -881,7 +881,8 @@ def test_evas_step_walks_rings_and_pages_in_place_on_the_v5e_at_the_cells_widths
     repo's own `head_walk` a layer (ISSUE 56: a key in one part, the pools as
     they lie; Mosaic takes it at 32 query rows on 32 KV heads) over the virtual
     block table's work list, under `eva_decode`; jax's `paged_attention` is in
-    neither program; and NO copy, transpose or gather of a whole pool exists in
+    neither program; a LAUNCH is ONE call of `launch_walk` a layer (ISSUE 58)
+    with no loop; and NO copy, transpose or gather of a whole pool exists in
     a step (a pool crossed to another layout once a layer a step when the
     chunk's rows were gathered from the pool seen flat: 5 ms each by the
     compiler's own estimate), nor in a launch."""
@@ -923,6 +924,12 @@ def test_evas_step_walks_rings_and_pages_in_place_on_the_v5e_at_the_cells_widths
     calls = [ln for ln in step.split("\n") if " custom-call(" in ln and "tpu_custom_call" in ln]
     assert len(calls) == 2 and all("eva_decode" in ln and "head_walk" in ln for ln in calls)
     assert "paged_attention" not in step and "paged_attention" not in fill
+    # a launch's attention is ONE call of `launch_walk` a layer (ISSUE 58) under
+    # `eva_prefill`: no loop over tiles or pages, no page taken from a pool
+    calls = [ln for ln in fill.split("\n") if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 2 and all("eva_prefill" in ln and "launch_walk" in ln for ln in calls)
+    scoped = "".join(ln for ln in fill.split("\n") if "eva_prefill" in ln)
+    assert " while(" not in scoped and "dynamic-slice(" not in scoped and " gather(" not in scoped
     whole = ("[32,560,128,128]", "[32,71680,128]", "[32,4480,16,128]")
     for text in (step, fill):
         moved = [ln.split("=")[0] for ln in text.split("\n")

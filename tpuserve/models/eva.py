@@ -54,17 +54,23 @@ Where ``i % c == c - 1`` the step then pools the chunk's rows from the ring and
 writes the summary row (scope ``eva_summarise``); a lane whose chunk has not
 ended writes to the sentinel.
 
-A LAUNCH (scope ``eva_prefill``, plain XLA) first pools every chunk that ends
-inside it (``eva_summarise``: a piece starts at a tile's edge and a tile is
-whole chunks, so such a chunk's rows are all the launch's own) and writes the
-summaries to their pages; then each tile attends what its ring held BEFORE
-the launch and the launch's own rows, both masked by WINDOW INDEX (``t // W ==
-i // W and t <= i``), joined under one running softmax with the summary pages
-of every earlier window through the block table, a window the launch itself
-closes among them; the ring is written last, a tile's rows as whole pages of
-it (a tile is whole pages: one scatter of slabs, where a scatter of rows by
-head took 0.65 ms a pool on the chip). A launch is at most W rows, so it
-crosses one window's edge at most and writes no ring place twice.
+A LAUNCH (scope ``eva_prefill``) first pools every chunk that ends inside it
+(``eva_summarise``: a piece starts at a tile's edge and a tile is whole chunks,
+so such a chunk's rows are all the launch's own) and writes the summaries to
+their pages; then each tile attends what its ring held BEFORE the launch and
+the launch's own rows, both masked by WINDOW INDEX (``t // W == i // W and t <=
+i``), joined under one running softmax with the summary pages of every earlier
+window through the block table, a window the launch itself closes among them;
+the ring is written last, a tile's rows as whole pages of it (a tile is whole
+pages: one scatter of slabs, where a scatter of rows by head took 0.65 ms a
+pool on the chip). A launch is at most W rows, so it crosses one window's edge
+at most and writes no ring place twice. On the TPU in bfloat16 the attention is
+ONE call a layer of ``ops/launch_attention.py`` ``launch_walk`` (ISSUE 58) over
+ONE flat work list a launch of the (tile, key page) items that exist (a tile's
+ring pages that hold its window, the launch's own rows laid as pages, its
+summary pages), a cell an item for all heads, ring and pages read in place;
+elsewhere a tile at a time in XLA (``_tile``).
+``eva_prefill_tiles_total{path=tile_kernel|xla}`` says which.
 
 Requests carry ``prompt_ids``; for a byte-level model an id IS a byte (0-255)
 or one of the special ids above them.
@@ -82,6 +88,7 @@ from tpuserve.models import decoder as dec
 from tpuserve.models.paged_lm import (CONTEXT_COLUMN, NEG, Column, _mm, counted,
                                       read_config_file, series)
 from tpuserve.ops import lane_attention as la
+from tpuserve.ops import launch_attention as lat
 
 # What this family draws otherwise than ``decoder``: ``phi`` so that a chunk's
 # weights are decided (``phi . k`` of standard deviation 2: the largest of 16
@@ -89,31 +96,34 @@ from tpuserve.ops import lane_attention as la
 # softmax's mass against a window's exact rows (the cell's configuration file
 # says how much), the gains' ``g`` inside [-gain, gain] about 0 (a gain is 1 + g).
 DEFAULT_SCALES = {**dec.DEFAULT_SCALES, "qk": 1.0, "phi": 0.18, "mu": 1.0, "gain": 0.25}
-PATHS = ("head_walk", "gather")
+PATHS = ("head_walk", "gather")      # a step's walk
+TILE_PATHS = ("tile_kernel", "xla")  # a launch's tiles
 WINDOW_KIND = "sliding_attention"   # what ``decoder`` calls a layer that keeps a ring
 
 
-def _decode_path(count: str, name: str) -> tuple:
-    """A column a path, in the decode phase alone: ``counts[count][path]`` into
-    ``name{model=,phase=decode,path=}``."""
+def _by_path(count: str, name: str, phase: str, paths: tuple) -> tuple:
+    """A column a path, in ``phase`` alone: ``counts[count][path]`` into
+    ``name{model=,phase=,path=}``."""
     return tuple(
         Column(lambda model, stats, counts, path=path: counts[count][path],
                lambda model, metrics, ph, path=path: series(name, f",path={path}")(
-                   model, metrics, ph) if ph == "decode" else None)
-        for path in PATHS)
+                   model, metrics, ph) if ph == phase else None)
+        for path in paths)
 
 
 class EvaServing(dec.DecoderServing):
     cache_leaves = kv_page_leaves = ("kf", "vf")   # ONE pool a layer: rings, then pages
     # The rows a token attends (exact and summary: what the cache's bytes go
-    # by), each kind, chunks pooled, windows closed, a step's lanes by path.
+    # by), each kind, chunks pooled, windows closed, a step's lanes and a
+    # launch's tiles by path.
     COLUMNS = (
         CONTEXT_COLUMN,
         Column(counted("exact"), series("eva_rows_attended_total", ",kind=exact")),
         Column(counted("summary"), series("eva_rows_attended_total", ",kind=summary")),
         Column(counted("chunks"), series("eva_chunks_summarised_total")),
         Column(counted("windows"), series("eva_windows_closed_total")),
-        *_decode_path("paths", "eva_decode_steps_total"))
+        *_by_path("paths", "eva_decode_steps_total", "decode", PATHS),
+        *_by_path("tiles", "eva_prefill_tiles_total", "prefill", TILE_PATHS))
     # Pages a cell of the step's walk holds (``head_walk``'s key block): a cell
     # reads its pages whole whatever the lane's length, so small ones follow the
     # live rows and large ones save cells. One layer of 24 lanes at the mix's
@@ -238,7 +248,8 @@ class EvaServing(dec.DecoderServing):
     def _prefill_plan(self, state, launch, t: dict) -> dict:
         """``decoder``'s ring places, and: the pool addresses of the ring's
         rows; the launch's rows by chunk with where each whole chunk's summary
-        goes (the summaries' sentinel for one that is not whole)."""
+        goes (the summaries' sentinel for one that is not whole); the path the
+        tiles' attention takes with (in the kernel) its work list."""
         m = super()._prefill_plan(state, launch, t)
         c, P, W, first = self.chunk, self.rows, self.window, self._first_page(state)
         ends = (t["valid"] & (t["cpos"] % c == c - 1)).reshape(-1, c)[:, -1]   # by chunk of rows
@@ -252,7 +263,14 @@ class EvaServing(dec.DecoderServing):
         # again); a run of no live row goes to the rings' sentinel.
         head, run_pos = t["valid"][::P], t["cpos"][::P]
         ring_runs = jnp.where(head, c * jnp.repeat(t["rings"], t["T"] // P) + run_pos % W // P, 0)
+        kernel = jax.default_backend() == "tpu" and self._tiles_fit(t["T"])   # tps-ok[TPS503]: at trace time
+        # ONE work list a launch, the layers' alike: the (tile, key page) items that exist
+        work = lat.launch_list(
+            t["has"], t["qpos"][:, 0], launch["start"][t["piece"]], t["end"],
+            t["first_tile"][t["piece"]] * (t["T"] // P), c * t["rings"], first + t["rows"],
+            tile=t["T"], page=P, window=W) if kernel else None
         return {**m, "first": first, "ends": ends, "ring_runs": ring_runs,
+                "tile_path": "tile_kernel" if kernel else "xla", "work": work,
                 "sum_page": first + jnp.where(ends, page, 0), "sum_off": (at % W) // c}
 
     def _step_plan(self, state, live, pos) -> dict:
@@ -286,15 +304,17 @@ class EvaServing(dec.DecoderServing):
     def _counts(self, m: dict) -> dict:
         """The rows the live tokens' index sets hold, exact and summary (their
         sum is the ``context``), chunks pooled, windows closed, and a step's
-        lanes x layers by the path their walk took."""
+        lanes x layers and a launch's tiles x layers by the path each took."""
         live, pos = m["live"], m["pos"]
         exact = jnp.sum(jnp.where(live, pos % self.window + 1, 0))
         summary = jnp.sum(jnp.where(live, (pos // self.window) * self.rows, 0))
         lanes = jnp.sum(live) * self.n_layers
+        tiles = 0 if m["t"] is None else jnp.sum(m["t"]["has"]) * self.n_layers
         return {"context": exact + summary, "exact": exact, "summary": summary,
                 "chunks": jnp.sum(m["ends"]) * self.n_layers,
                 "windows": jnp.sum(live & (pos % self.window == self.window - 1)),
-                "paths": {p: lanes * (m.get("path") == p) for p in PATHS}}
+                "paths": {p: lanes * (m.get("path") == p) for p in PATHS},
+                "tiles": {p: tiles * (m.get("tile_path") == p) for p in TILE_PATHS}}
 
     # -- the mixer ---------------------------------------------------------------------
     def _walks(self, P: int) -> bool:
@@ -308,6 +328,10 @@ class EvaServing(dec.DecoderServing):
             return la.head_walk(q, None, kp, None, vp, m["work"],
                                 scale=self._scale()).astype(jnp.float32)
         return self._decode_gather(q, (kp, vp), m["table"], m["rows_seen"] - 1, self._heads())
+
+    def _tiles_fit(self, T: int) -> bool:
+        """Shapes ``launch_walk`` takes."""
+        return lat.fits(T, self.rows, self.window, self.heads[0], self.kv, self.hd, self.dtype)
 
     def _tile(self, a: dict, kp, vp, k, v, kpos, first: int):
         """One tile's attention: ``a["q"]`` (T, H, hd) at positions ``a["qpos"]``
@@ -360,6 +384,23 @@ class EvaServing(dec.DecoderServing):
         _, total, acc = jax.lax.fori_loop(0, -(-closed // kb), body, carry)
         return (acc / total[..., None]).transpose(2, 0, 1, 3).reshape(T, H, hd)
 
+    def _attend_tiles(self, q, k, v, kp, vp, m: dict):
+        """A launch's attention, every tile: q (C, H, hd), the launch's own
+        rows k, v (C, KV, hd), the pools -> (o as ``q`` lies, float32, and k, v
+        laid as pages by head, (KV, C / P, P, hd): what the kernel reads and
+        the rings are then written from)."""
+        t = m["t"]
+        ko, vo = (rows.reshape((-1, self.rows) + rows.shape[1:]).transpose(2, 0, 1, 3)
+                  for rows in (k, v))
+        if m["tile_path"] == "tile_kernel":
+            return lat.launch_walk(q, kp, vp, ko, vo, m["work"], scale=self._scale()), ko, vo
+        o = jax.lax.map(
+            lambda a: self._tile(a, kp, vp, k, v, m["pos"], m["first"]),
+            {"q": q.reshape((t["K"], t["T"]) + q.shape[1:]), "qpos": t["qpos"],
+             "ring": t["rings"], "rpos": m["rpos"], "own": m["own"], "row": t["rows"],
+             "last": t["last"]})
+        return o.reshape(q.shape), ko, vo
+
     def _attend_eva(self, lp: dict, q, k, v, kp, vp, m: dict):
         """The EVA mixer in either phase -> (o as ``q`` lies, float32, the two
         pools)."""
@@ -385,20 +426,14 @@ class EvaServing(dec.DecoderServing):
             by_chunk = (-1, self.chunk) + k.shape[1:]
             ks, vs = self._pool(lp, k.reshape(by_chunk), v.reshape(by_chunk))
         kp, vp = put(m["sum_page"], m["sum_off"], ks, vs)
+
         with jax.named_scope("eva_prefill"):
             # The pools hold the rings as the launch found them (its own rows
             # land there last) and, by now, this launch's summaries.
-            o = jax.lax.map(
-                lambda a: self._tile(a, kp, vp, k, v, m["pos"], m["first"]),
-                {"q": q.reshape((t["K"], t["T"]) + q.shape[1:]), "qpos": t["qpos"],
-                 "ring": t["rings"], "rpos": m["rpos"], "own": m["own"], "row": t["rows"],
-                 "last": t["last"]})
-
-        def runs(pool, rows):   # whole pages of the rings: ONE scatter of C / P slabs
-            by_page = rows.reshape((-1, self.rows) + rows.shape[1:]).transpose(2, 0, 1, 3)
-            return pool.at[:, m["ring_runs"]].set(by_page)
-
-        return o.reshape(q.shape), runs(kp, k), runs(vp, v)
+            o, ko, vo = self._attend_tiles(q, k, v, kp, vp, m)
+        # whole pages of the rings: ONE scatter of C / P slabs a pool
+        runs = m["ring_runs"]
+        return o, kp.at[:, runs].set(ko), vp.at[:, runs].set(vo)
 
     def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
         q, k, v, _ = self._qkv(lp, i, self._norm(x, lp["norm1"]), m["pos"])
