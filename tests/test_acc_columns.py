@@ -1,7 +1,7 @@
 """What ``acc`` holds is stated once a family (``paged_lm.Column``, ISSUE 45):
 the state's width, the row a launch adds, the counters ``bind_metrics`` binds
 and what ``observe_step`` feeds all follow the family's ``COLUMNS``. Here, for
-each of the nine generating families at its toy size: one prefill launch and
+each of the ten generating families at its toy size: one prefill launch and
 one step on the CPU, then the device's sums into a registry. The names below
 are the series as they have been served since each family came (the benchmark's
 readers find them by these letters), written down apart from the code."""
@@ -49,6 +49,7 @@ SERIES = {
         "decode:delta_steps_total{model=M,phase=decode,path=xla}",
         "prefill:delta_scans_total{model=M,phase=prefill,path=kernel}",
         "prefill:delta_scans_total{model=M,phase=prefill,path=xla}"],
+    "hybrid_conv": EXPERTS + CONTEXT + SSM + COMPACT,
     "eva": CONTEXT + [
         "eva_rows_attended_total{model=M,phase=PH,kind=exact}",
         "eva_rows_attended_total{model=M,phase=PH,kind=summary}",

@@ -1917,7 +1917,9 @@ class GenEngine:
                 **({"ring_bytes": self._ring_pages() * self._pool_page_bytes(),
                     "page_bytes": self.pages.pages * self._pool_page_bytes()}
                    if self._ring_pages() else {}),
-                # The third kind (ISSUE 32): a fixed block a slot, beside pages.
+                # The third kind (ISSUE 32): a fixed block a slot, beside pages: the
+                # leaves the family's recurrent mixer names, and no other (a float32
+                # state and the convolution's rows; the rows ALONE for a short convolution).
                 "state_bytes_per_slot": self.slot_state_bytes() // self.slots,
                 "state_bytes": self.slot_state_bytes(),
             }
@@ -1983,8 +1985,9 @@ class GenEngine:
 
     def slot_state_bytes(self) -> int:
         """Device bytes of the leaves the family keeps as one block A SLOT
-        (``kv_slot_state``: a recurrent layer's state, the same size whatever
-        the context), all slots; 0 for a family without any."""
+        (``kv_slot_state``: a recurrent layer's state, or where the layer is a
+        short convolution its stored rows alone, the same size whatever the
+        context), all slots; 0 for a family without any."""
         return self._leaf_bytes(self.model.kv_slot_state)
 
     def _leaf_bytes(self, keys: tuple) -> int:

@@ -181,9 +181,11 @@ class GenerativeModel(ServingModel):
 
     # The third cache kind (ISSUE 32): keys of ``kv_page_signature`` whose
     # leaves are ONE BLOCK A SLOT (leading dimension ``slots``), the same size
-    # at any context: a recurrent layer's state. The family addresses them by
-    # slot and starts a request's first piece from zeros; the engine only
-    # reports their bytes (/stats ``kv.state_bytes_per_slot``, ``gen_state_bytes``).
+    # at any context: a recurrent layer's state (ISSUE 59: the leaves its mixer
+    # names, one or several; nothing is allocated beside them). The family
+    # addresses them by slot and starts a request's first piece from zeros; the
+    # engine only reports their bytes (/stats ``kv.state_bytes_per_slot``,
+    # ``gen_state_bytes``).
     kv_slot_state: tuple = ()
 
     # Keys of ``kv_page_signature`` whose leaves are the PAGE POOLS (a

@@ -60,6 +60,13 @@ Families (BASELINE.json ``configs``):
                    in one pool a layer, walked as one virtual block table; a
                    float32 stream, gains ``1 + g``, a head of several
                    prediction blocks of which the first is served (ISSUE 55)
+- hybrid_conv    — ``hybrid_delta``'s sibling for a model whose recurrent layers
+                   are gated short convolutions (a slot's whole state is the
+                   convolution's last rows: one leaf, no float32 state) and
+                   whose attention has an RMSNorm a head on queries and keys
+                   and a rotary embedding, with dense SwiGLUs in the leading
+                   layers and sigmoid-routed experts with no shared one in the
+                   rest, all held (ISSUE 59)
 - toy            — a linear classifier for tests and drills
 """
 
@@ -87,6 +94,7 @@ _REGISTRY: dict[str, str] = {
     "decoder_sink": "tpuserve.models.decoder_sink",
     "hybrid_delta": "tpuserve.models.hybrid_delta",
     "eva": "tpuserve.models.eva",
+    "hybrid_conv": "tpuserve.models.hybrid_conv",
     "toy": "tpuserve.models.toy",
 }
 

@@ -65,7 +65,51 @@ DEFAULT_SCALES = {
 }
 
 
-class HybridDeltaServing(DeltaPatternMixers, PagedLM):
+class RoutedExperts:
+    """Sigmoid-routed SwiGLU experts in the layers a family names (``e_layers``),
+    with a selection bias and, where ``shared_width``, a shared expert on the
+    same rows: their tensors, their biases and the sublayer (module docstring,
+    "Experts"). A mix-in over ``PagedLM`` that ``hybrid_conv`` shares. The family
+    sets ``n_experts``, ``top_k``, ``expert_width``, ``shared_width``,
+    ``norm_topk``, ``route_scale`` and the part held, ``e_first`` and ``e_count``."""
+    route_eps = 0.0  # added to the picks' sum under their weights, where a model's block does
+
+    def _expert_tensors(self):
+        d, s = self.d, self.scales
+        e, ec, e0, f, fs = (self.n_experts, self.e_count, self.e_first,
+                            self.expert_width, self.shared_width)
+        for i in self.e_layers:
+            L = f"layer{i}"
+            yield ((L, "router"), (d, e), (d, e), (0, 0), s["router"], d)
+            for name in ("e_gate", "e_up"):
+                yield ((L, name), (ec, d, f), (e, d, f), (e0, 0, 0), s["ffn_in"], d)
+            yield ((L, "e_down"), (ec, f, d), (e, f, d), (e0, 0, 0), s["ffn_out"], f)
+            if fs:
+                for name in ("s_gate", "s_up"):
+                    yield ((L, name), (d, fs), (d, fs), (0, 0), s["ffn_in"], d)
+                yield ((L, "s_down"), (fs, d), (fs, d), (0, 0), s["ffn_out"], fs)
+
+    def _expert_vectors(self):
+        """Every router's selection bias (small, about 0: it changes some picks)."""
+        b3 = 3.0 * self.scales["router_bias"]
+        for i in self.e_layers:
+            yield ((f"layer{i}", "e_bias"), (self.n_experts,), (self.n_experts,), (0,), -b3, b3)
+
+    def _ffn(self, lp, u, live):
+        """(T, d) -> ((T, d) float32: the held experts' part and the shared
+        expert, the expert layer's counts)."""
+        r = jnp.matmul(u.astype(jnp.float32), lp["router"].astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+        w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
+                          scoring="sigmoid", select_bias=lp["e_bias"], eps=self.route_eps)
+        y, stats = held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"], lp["e_up"],
+                                       lp["e_down"], live=live, of=self.n_experts)
+        if self.shared_width:
+            y = y + self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"])
+        return y, stats
+
+
+class HybridDeltaServing(DeltaPatternMixers, RoutedExperts, PagedLM):
     # The expert layer's four and the context, the recurrent layers' four, the
     # compact dispatches, and a step's updates by where they ran.
     COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, *SSM_COLUMNS, COMPACT_COLUMN, *DELTA_COLUMNS)
@@ -86,6 +130,7 @@ class HybridDeltaServing(DeltaPatternMixers, PagedLM):
         if not set(self.a_layers) <= set(range(self.n_layers)):
             raise ValueError(f"{cfg.name}: gqa_layers {self.a_layers} of {self.n_layers} layers")
         self.m_layers = [i for i in range(self.n_layers) if i not in self.a_layers]
+        self.e_layers = list(range(self.n_layers))
         lin = a["linear_attn_config"]
         if lin.get("num_kv_heads") not in (None, lin["num_heads"]):
             raise NotImplementedError(f"{cfg.name}: linear_attn_config.num_kv_heads = "
@@ -129,30 +174,15 @@ class HybridDeltaServing(DeltaPatternMixers, PagedLM):
     def _tensors(self):
         """(path, shape held here, full shape, start, role, fan-in) of every
         matrix, in a fixed order."""
-        d, s = self.d, self.scales
-        e, ec, e0, f, fs = (self.n_experts, self.e_count, self.e_first,
-                            self.expert_width, self.shared_width)
         yield from self._vocab_tensors()
         yield from self._delta_tensors()
         yield from self._attention_tensors()
-        for i in range(self.n_layers):
-            L = f"layer{i}"
-            yield ((L, "router"), (d, e), (d, e), (0, 0), s["router"], d)
-            for name in ("e_gate", "e_up"):
-                yield ((L, name), (ec, d, f), (e, d, f), (e0, 0, 0), s["ffn_in"], d)
-            yield ((L, "e_down"), (ec, f, d), (e, f, d), (e0, 0, 0), s["ffn_out"], f)
-            if fs:
-                for name in ("s_gate", "s_up"):
-                    yield ((L, name), (d, fs), (d, fs), (0, 0), s["ffn_in"], d)
-                yield ((L, "s_down"), (fs, d), (fs, d), (0, 0), s["ffn_out"], fs)
+        yield from self._expert_tensors()
 
     def _vectors(self):
-        """The delta rule's float32 vectors, and every router's selection
-        bias (small, about 0: it changes some picks)."""
+        """The delta rule's float32 vectors, and every router's selection bias."""
         yield from self._delta_vectors()
-        b3 = 3.0 * self.scales["router_bias"]
-        for i in range(self.n_layers):
-            yield ((f"layer{i}", "e_bias"), (self.n_experts,), (self.n_experts,), (0,), -b3, b3)
+        yield from self._expert_vectors()
 
     def draw_params(self, seed: int) -> Any:
         p = super().draw_params(seed)
@@ -165,19 +195,6 @@ class HybridDeltaServing(DeltaPatternMixers, PagedLM):
                 "vocab_rows": [self.v_first, self.vocab], "vocab": self.vocab_full}
 
     # -- device math --------------------------------------------------------------
-    def _ffn(self, lp, u, live):
-        """(T, d) -> ((T, d) float32: the held experts' part and the shared
-        expert, the expert layer's counts)."""
-        r = jnp.matmul(u.astype(jnp.float32), lp["router"].astype(jnp.float32),
-                       precision=jax.lax.Precision.HIGHEST)
-        w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
-                          scoring="sigmoid", select_bias=lp["e_bias"])
-        y, stats = held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"], lp["e_up"],
-                                       lp["e_down"], live=live, of=self.n_experts)
-        if self.shared_width:
-            y = y + self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"])
-        return y, stats
-
     def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
         y = self._mixer(i, lp, rms_norm(x, lp["norm1"], self.eps), c, m)
         x = x + y.astype(self.dtype)

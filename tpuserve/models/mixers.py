@@ -1,30 +1,37 @@
 """The mixers that the families built from a pattern of layers share (ISSUE 40;
-moved out of ``hybrid.py`` so that ``hybrid_ffn.py`` is not a copy): two
+moved out of ``hybrid.py`` so that ``hybrid_ffn.py`` is not a copy): three
 RECURRENT mixers with their state a slot (a Mamba-2 state-space layer; since
-ISSUE 53 a gated delta-rule linear-attention layer with a decay a channel), and
-attention by head with no rotary embedding over the paged KV. All are mix-ins
+ISSUE 53 a gated delta-rule linear-attention layer with a decay a channel; since
+ISSUE 59 a gated short convolution, whose whole state is its convolution's
+rows), and attention by head over the paged KV, with no position term
+(``PlainAttention``) or with an RMSNorm a head on queries and keys and then a
+rotary embedding (``RotaryAttention``). All are mix-ins
 over ``paged_lm.PagedLM``: they bring tensors, device math, the caches' shapes
 and the columns of ``acc``, and know nothing of how a family orders its
 layers, names its config keys or adds a mixer's output to the stream.
-``PatternMixers`` (Mamba-2) and ``DeltaPatternMixers`` (delta rule) are one
-recurrent mixer and attention together: layer ``i``'s mixer, whichever it is, in
-whichever phase (``_mixer``), for ``paged_lm``'s loop.
+``PatternMixers`` (Mamba-2), ``DeltaPatternMixers`` (delta rule) and
+``ConvPatternMixers`` (short convolution) are one recurrent mixer and an
+attention together: layer ``i``'s mixer, whichever it is, in whichever phase
+(``_mixer``), for ``paged_lm``'s loop.
 
-WHAT A RECURRENT MIXER OWES THE LOOP (``RecurrentMixer``; both keep it). A
-layer keeps, A SLOT, two leaves (``kv_slot_state = ("ssm", "conv")``): a float32
-state (``ssm[l][slot]``) and the last ``conv_kernel - 1`` rows of its
-convolution's input in the served type (``conv[l][slot]``). A request's FIRST
+WHAT A RECURRENT MIXER OWES THE LOOP (``RecurrentMixer``; all three keep it). A
+layer keeps, A SLOT, THE LEAVES ITS MIXER NAMES (``kv_slot_state``), each a
+block ``leaf[l][slot]``, and nothing else is allocated: Mamba-2 and the delta
+rule name two, ``("ssm", "conv")``, a float32 state and the last ``conv_kernel -
+1`` rows of the convolution's input in the served type; the short convolution
+names ONE, ``("conv",)``, those rows alone (it has no other state). A request's FIRST
 piece starts from zeros whatever the slot held; a later piece from what the
 slot holds; within a launch the tiles of one piece pass the state on and a tile
 of another slot does not see it; padded rows leave it as it was; a piece of no
 tokens writes nothing; a decode step leaves the state of a lane that is not
 live untouched. Prefill computes the recurrence by chunks (a quadratic form
 inside a tile, the state passed between a piece's tiles by a ``lax.scan`` or,
-for the delta rule on the TPU, inside one kernel call), under
+for the delta rule on the TPU, inside one kernel call; the short convolution
+has nothing to pass on but a tile's last rows, and no scan), under
 ``jax.named_scope("ssm_scan")``: the scan alone, from the convolution to the
-gated norm; a decode step is one application, under
+gated norm (the short convolution: to its gate); a decode step is one application, under
 ``jax.named_scope("ssm_update")``: the whole mixer, from the projections to the
-out-projection. The four ``SSM_COLUMNS`` count both mixers' work alike.
+out-projection. The four ``SSM_COLUMNS`` count all three mixers' work alike.
 
 ``Mamba2Mixer`` (a family calls ``_mamba_setup`` in its constructor and sets
 ``m_layers``): ``[z | xBC | dt] = u W_in``; a depthwise causal convolution and
@@ -70,12 +77,24 @@ plain form (``_delta_heads`` and ``_delta_chunks``: XLA fusions of plain
 ``delta_scans_total{phase=prefill,path=kernel|xla}`` counts a launch's delta-rule
 layers by which. Both are float32 with every product at ``HIGHEST``.
 
+``ConvMixer`` (``_conv_setup``; ``m_layers`` too): ``[B | C | z] = u W_in``, three
+thirds of ``d`` each; ``b = B * z``, THE ROW A SLOT KEEPS (the last ``conv_kernel -
+1`` of them, in the served type); ``c_i = sum_j w[j] b_{i - k + 1 + j}``, depthwise
+and causal, in float32, no bias and NO activation; out ``= (C * c) W_out``. A
+launch's rows are independent but for their ``k - 1`` neighbours: ``_tiles_conv``
+and ``_piece_ends`` are the whole of its ``ssm_scan``.
+
 ``PlainAttention`` (a family sets ``a_layers``, ``heads``, ``kv``, ``hd`` and
 their full counts): grouped KV heads, causal, NO position term, no bias; K and
 V in pages of the engine's ledger; where the family sets ``attn_gate``, the
 context times ``sigmoid(u W_g)``, elementwise by head, before ``W_o``. A step's
 whole mixer, from the projections to ``W_o``, runs under
-``jax.named_scope("attn_decode")``.
+``jax.named_scope("attn_decode")``. ``RotaryAttention`` is that with the rows'
+positions read where q and k are made (``_qkv(lp, u, pos)``: ``m["pos"]``, a
+step's lanes' or a launch's rows'): ``q <- rope(RMSNorm(q; g_q), pos)``, ``k``
+alike, the norm over a head's ``hd`` columns with ONE gain for all heads, FIRST,
+then the rotary over all ``hd`` columns in pairs ``(j, j + hd / 2)`` at
+``rope_theta``; the pages hold k after both.
 """
 
 from __future__ import annotations
@@ -84,8 +103,10 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from tpuserve.models.paged_lm import Column, _mm, counted, series
+from tpuserve.models.decoder import apply_rope
+from tpuserve.models.paged_lm import Column, _mm, counted, rms_norm, series
 from tpuserve.ops import delta_scan as ds
 from tpuserve.ops import delta_update as du
 
@@ -95,9 +116,10 @@ def softplus_inverse(y: float) -> float:
 
 
 class RecurrentMixer:
-    """What both recurrent mixers keep a slot, what a launch's scan does for
-    either, and what a launch counts of them (module docstring)."""
-    kv_slot_state = ("ssm", "conv")  # the leaves that are a block a slot
+    """What a recurrent mixer keeps a slot (the leaves it names), what a
+    launch's scan does for any of them, and what a launch counts of them
+    (module docstring)."""
+    kv_slot_state = ("ssm", "conv")  # the leaves that are a block a slot: a mixer names its own
 
     def _tiles_conv(self, t: dict, rows, c0, w, bias=None):
         """The depthwise causal convolution of a launch's packed ``rows`` (C,
@@ -134,21 +156,25 @@ class RecurrentMixer:
         return last_tile, tail
 
     @staticmethod
-    def _piece_starts(ssm, conv, slot, start):
-        """What each piece of a launch starts from: zeros where it opens its
-        prompt, else what its slot holds -> (state (K, ...) float32, rows)."""
+    def _piece_starts(slot, start, *, rows: tuple, states: tuple = ()) -> tuple:
+        """What each piece of a launch starts from, a leaf of its slot's state:
+        zeros where it opens its prompt, else what its slot holds. ``states``:
+        the leaves a scan computes on in float32, (slots, ., ., .) each;
+        ``rows``: the convolution's stored rows (slots, k-1, channels), which
+        stay in their own type -> (the states (K, ...) float32, the rows)."""
         fresh = (start == 0)[:, None, None]
-        at = jnp.minimum(slot, ssm.shape[0] - 1)
-        return (jnp.where(fresh[..., None], 0.0, ssm[at].astype(jnp.float32)),
-                jnp.where(fresh, jnp.zeros((), conv.dtype), conv[at]))
+        at = jnp.minimum(slot, rows[0].shape[0] - 1)
+        return (tuple(jnp.where(fresh[..., None], 0.0, s[at].astype(jnp.float32))
+                      for s in states),
+                tuple(jnp.where(fresh, jnp.zeros((), r.dtype), r[at]) for r in rows))
 
     @staticmethod
-    def _store_pieces(ssm, conv, slot, length, s_end, c_end):
-        """Each piece's state and rows into its slot. A piece of no tokens
-        writes nothing: its slot is out of range."""
-        to = jnp.where(length > 0, slot, ssm.shape[0])
-        return (ssm.at[to].set(s_end.astype(ssm.dtype), mode="drop"),
-                conv.at[to].set(c_end.astype(conv.dtype), mode="drop"))
+    def _store_pieces(leaves: tuple, slot, length, ends: tuple) -> tuple:
+        """Each piece's ``ends`` (a (K, ...) a leaf) into its slot. A piece of
+        no tokens writes nothing: its slot is out of range."""
+        to = jnp.where(length > 0, slot, leaves[0].shape[0])
+        return tuple(x.at[to].set(e.astype(x.dtype), mode="drop")
+                     for x, e in zip(leaves, ends))
 
     def _counts(self, m: dict) -> dict:
         """And live tokens (through a scan layer), slot states read and
@@ -324,10 +350,10 @@ class Mamba2Mixer(RecurrentMixer):
         are outside it."""
         z, xbc, dt = self._split_in(lp, u)
         with jax.named_scope("ssm_scan"):
-            s0, c0 = self._piece_starts(ssm, conv, slot, start)
+            (s0,), (c0,) = self._piece_starts(slot, start, states=(ssm,), rows=(conv,))
             y, s_end, c_end = self._scan_tiles(lp, xbc, dt, t, s0, c0)
             g = self._gated_norm(lp, y, z)
-            ssm, conv = self._store_pieces(ssm, conv, slot, length, s_end, c_end)
+            ssm, conv = self._store_pieces((ssm, conv), slot, length, (s_end, c_end))
         return self._out_proj(lp, g), ssm, conv
 
     def _mamba_step(self, lp, u, live, ssm, conv):
@@ -612,10 +638,10 @@ class DeltaMixer(RecurrentMixer):
         qkv = _mm(u, lp["w_qkv"]).astype(self.dtype)
         g, beta = self._decay_beta(lp, u, t["valid"])
         with jax.named_scope("ssm_scan"):
-            s0, c0 = self._piece_starts(ssm, conv, slot, start)
+            (s0,), (c0,) = self._piece_starts(slot, start, states=(ssm,), rows=(conv,))
             o, s_end, c_end = self._delta_tiles(lp, qkv, g, beta, t, s0, c0, path)
             y = self._delta_gated(lp, u, o)
-            ssm, conv = self._store_pieces(ssm, conv, slot, length, s_end, c_end)
+            ssm, conv = self._store_pieces((ssm, conv), slot, length, (s_end, c_end))
         return self._delta_out(lp, y), ssm, conv
 
     def _scan_path(self, t: dict) -> str:
@@ -688,6 +714,76 @@ DELTA_COLUMNS = (*_by_path("paths", "delta_steps_total", "decode"),
                  *_by_path("scans", "delta_scans_total", "prefill"))
 
 
+class ConvMixer(RecurrentMixer):
+    """The gated short convolution (module docstring): a slot's whole state is
+    the convolution's last rows, so this mixer names ONE leaf."""
+    kv_slot_state = ("conv",)
+
+    def _conv_setup(self, *, conv_kernel: int) -> None:
+        """The layer's number: the taps (``conv_L_cache``); its channels are
+        the stream's ``d``."""
+        self.conv_k = int(conv_kernel)
+
+    # -- params ---------------------------------------------------------------
+    def _conv_tensors(self):
+        """``w_in`` is the published ``in_proj``, its thirds B, C and the
+        convolved input in that order; the taps (k, d), tap k - 1 on the
+        current row."""
+        d, s, k = self.d, self.scales, self.conv_k
+        for i in self.m_layers:
+            L = f"layer{i}"
+            yield ((L, "w_in"), (d, 3 * d), (d, 3 * d), (0, 0), s["conv_in"], d)
+            yield ((L, "conv_w"), (k, d), (k, d), (0, 0), s["conv_tap"], k)
+            yield ((L, "w_out"), (d, d), (d, d), (0, 0), s["conv_out"], d)
+
+    def _conv_signature(self, slots: int) -> dict:
+        return {"conv": [jax.ShapeDtypeStruct((slots, self.conv_k - 1, self.d), self.dtype)
+                         for _ in self.m_layers]}
+
+    # -- device math --------------------------------------------------------------
+    def _conv_in(self, lp: dict, u: jax.Array):
+        """``u`` (T, d) -> (b = B * z, the row a slot keeps, and the gate C),
+        both (T, d) in the served type."""
+        d = self.d
+        bcz = _mm(u, lp["w_in"]).astype(self.dtype)
+        return bcz[:, :d] * bcz[:, 2 * d:], bcz[:, d:2 * d]
+
+    def _conv_gate(self, gate: jax.Array, c: jax.Array) -> jax.Array:
+        """The gate C on the convolved rows ``c`` (T, d) float32 -> the served type."""
+        return (gate.astype(jnp.float32) * c).astype(self.dtype)
+
+    def _conv_prefill(self, lp, u, t, conv, slot, start, length):
+        """One short-convolution layer of a launch. The scope ``ssm_scan`` is
+        from ``b`` to ``C * c``: the two projections are outside it. Nothing
+        passes from tile to tile but a tile's last rows."""
+        b, gate = self._conv_in(lp, u)
+        with jax.named_scope("ssm_scan"):
+            _none, (c0,) = self._piece_starts(slot, start, rows=(conv,))
+            _opens, live, seq, c = self._tiles_conv(t, b, c0, lp["conv_w"])
+            _last, c_end = self._piece_ends(t, live, seq)
+            (conv,) = self._store_pieces((conv,), slot, length, (c_end,))
+            y = self._conv_gate(gate, c.reshape(b.shape))
+        return _mm(y, lp["w_out"]), conv
+
+    def _conv_step(self, lp, u, live, conv):
+        """One row a lane: the rows of a lane that is not live stay as they
+        were. The scope ``ssm_update`` is the whole mixer, from ``W_in`` to
+        ``W_out``."""
+        with jax.named_scope("ssm_update"):
+            b, gate = self._conv_in(lp, u)
+            seq = jnp.concatenate([conv, b[:, None]], axis=1)            # (lanes, k, d)
+            c = jnp.sum(seq.astype(jnp.float32) * lp["conv_w"].astype(jnp.float32)[None], axis=1)
+            out = _mm(self._conv_gate(gate, c), lp["w_out"])
+            new_conv = jnp.where(live[:, None, None], seq[:, 1:], conv)
+        return out, new_conv
+
+    def _short_conv(self, lp, u, conv, m: dict):
+        """One short-convolution layer in the phase the plan ``m`` is of."""
+        if m["t"] is None:
+            return self._conv_step(lp, u, m["live"], conv)
+        return self._conv_prefill(lp, u, m["t"], conv, m["slot"], m["start"], m["length"])
+
+
 class PlainAttention:
     attn_gate = False  # the context times sigmoid(u W_g), elementwise by head, before W_o
 
@@ -706,7 +802,9 @@ class PlainAttention:
                 yield ((L, "wg"), (d, self.heads, hd), (d, self.heads_full, hd),
                        (0, self.h_first, 0), s["gate"], d)
 
-    def _qkv(self, lp: dict, u: jax.Array):
+    def _qkv(self, lp: dict, u: jax.Array, pos: "jax.Array | None" = None):
+        """q, k, v by head of the rows ``u`` at positions ``pos`` (T,): here the
+        positions are read by nothing."""
         return tuple(jnp.einsum("td,dhk->thk", u, lp[w],
                                 preferred_element_type=jnp.float32).astype(self.dtype)
                      for w in ("wq", "wk", "wv"))
@@ -723,10 +821,10 @@ class PlainAttention:
         return o * jax.nn.sigmoid(jnp.einsum("td,dhk->thk", u, lp["wg"],
                                              preferred_element_type=jnp.float32))
 
-    def _attn_prefill(self, lp, u, t: dict, kp, vp, w_page, off):
+    def _attn_prefill(self, lp, u, t: dict, kp, vp, pos, w_page, off):
         """One attention layer of a launch: every row of the launch is in
         the pages before any tile reads them."""
-        q, k, v = self._qkv(lp, u)
+        q, k, v = self._qkv(lp, u, pos)
         kp, vp = self._write_pages(kp, w_page, off, k), self._write_pages(vp, w_page, off, v)
         o = self._prefill_full_tiles(q.reshape((t["K"], t["T"]) + q.shape[1:]), (kp, vp), t)
         return self._attn_out(lp, self._gated(lp, u, o.reshape(q.shape))), kp, vp
@@ -735,7 +833,7 @@ class PlainAttention:
         """One attention layer of a decode step. The scope ``attn_decode`` is
         the whole mixer, from the projections to ``W_o``'s product."""
         with jax.named_scope("attn_decode"):
-            q, k, v = self._qkv(lp, u)
+            q, k, v = self._qkv(lp, u, pos)
             kp, vp = self._write_pages(kp, w_page, off, k), self._write_pages(vp, w_page, off, v)
             y = self._attn_out(lp, self._gated(lp, u, self._decode_full(q, kp, vp, bt, pos)))
         return y, kp, vp
@@ -744,15 +842,43 @@ class PlainAttention:
         """One attention layer in the phase the plan ``m`` is of."""
         if m["t"] is None:
             return self._attn_step(lp, u, kp, vp, m["bt"], m["pos"], m["w_page"], m["off"])
-        return self._attn_prefill(lp, u, m["t"], kp, vp, m["w_page"], m["off"])
+        return self._attn_prefill(lp, u, m["t"], kp, vp, m["pos"], m["w_page"], m["off"])
+
+
+class RotaryAttention(PlainAttention):
+    """``PlainAttention`` with an RMSNorm a head on q and on k (one gain of
+    ``hd`` for all heads; float32 inside, its result in the served type) and
+    THEN a rotary embedding over all ``hd`` columns in pairs ``(j, j + hd / 2)``
+    at the rows' absolute positions. A family sets ``rope_theta`` beside
+    ``PlainAttention``'s numbers and yields ``_qk_gains`` among its vectors."""
+
+    def _qk_gains(self):
+        """The two norms' gains, float32 vectors drawn INSIDE ``scales["qk_gain"]``
+        (a gain that is the same in every column commutes with the rotary: a
+        check would be blind to their order)."""
+        lo, hi = self.scales["qk_gain"]
+        for i in self.a_layers:
+            for name in ("q_norm", "k_norm"):
+                yield ((f"layer{i}", name), (self.hd,), (self.hd,), (0,), lo, hi)
+
+    def _qkv(self, lp: dict, u: jax.Array, pos: jax.Array):
+        q, k, v = super()._qkv(lp, u, pos)
+        inv = self.rope_theta ** (-np.arange(0, self.hd, 2, dtype=np.float64) / self.hd)
+        return tuple(apply_rope(rms_norm(x, lp[g], self.eps), pos, inv.astype(np.float32), 1.0,
+                                self.hd) for x, g in ((q, "q_norm"), (k, "k_norm"))) + (v,)
 
 
 class _Pattern:
     """A family whose layer ``i`` has ONE of two mixers, a recurrent one
-    (``m_layers``) or attention (``a_layers``): the four cache leaves and the
-    layer's mixer. ``_recurrent`` and ``_state_signature`` are the recurrent
+    (``m_layers``) or attention (``a_layers``): the cache leaves (attention's
+    pages, then the leaves the recurrent mixer names) and the layer's mixer.
+    ``_recurrent`` (``(lp, u, *the layer's leaves, m)`` -> the output and the
+    leaves as it leaves them) and ``_state_signature`` are the recurrent
     mixer's."""
-    cache_leaves = ("kf", "vf", "ssm", "conv")
+
+    @property
+    def cache_leaves(self) -> tuple:
+        return ("kf", "vf") + self.kv_slot_state
 
     def _cache_signature(self, slots: int, pages: int, page_tokens: int) -> dict:
         page = jax.ShapeDtypeStruct(self._page_shape(pages, page_tokens), self.dtype)
@@ -763,8 +889,10 @@ class _Pattern:
         """Layer ``i``'s mixer on the normed stream ``u`` -> (T, d) float32;
         the layer's caches in ``c`` are replaced."""
         if i in self.m_layers:
-            j = self.m_layers.index(i)
-            y, c["ssm"][j], c["conv"][j] = self._recurrent(lp, u, c["ssm"][j], c["conv"][j], m)
+            j, kept = self.m_layers.index(i), self.kv_slot_state
+            y, *new = self._recurrent(lp, u, *(c[leaf][j] for leaf in kept), m)
+            for leaf, value in zip(kept, new):
+                c[leaf][j] = value
         else:
             j = self.a_layers.index(i)
             y, c["kf"][j], c["vf"][j] = self._attn(lp, u, c["kf"][j], c["vf"][j], m)
@@ -779,3 +907,9 @@ class PatternMixers(_Pattern, Mamba2Mixer, PlainAttention):
 class DeltaPatternMixers(_Pattern, DeltaMixer, PlainAttention):
     """The gated delta rule or plain attention."""
     _recurrent, _state_signature = DeltaMixer._delta, DeltaMixer._delta_signature
+
+
+class ConvPatternMixers(_Pattern, ConvMixer, RotaryAttention):
+    """The gated short convolution or attention with query/key norms and a
+    rotary embedding."""
+    _recurrent, _state_signature = ConvMixer._short_conv, ConvMixer._conv_signature

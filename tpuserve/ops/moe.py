@@ -175,12 +175,13 @@ COMPACT_SLACK = 1.25
 
 def topk_route(logits: jax.Array, k: int, *, normalize: bool = True,
                scale: float = 1.0, scoring: str = "softmax",
-               select_bias: "jax.Array | None" = None
+               select_bias: "jax.Array | None" = None, eps: float = 0.0
                ) -> tuple[jax.Array, jax.Array]:
     """(T, E) router logits -> (weights (T, k) float32, experts (T, k)
     int32): scores over all E in float32 (``scoring``: a ``softmax``, or a
     ``sigmoid`` of each logit alone), the k largest, their weights over the
-    k's own sum where ``normalize``, times ``scale``. With ``select_bias``
+    k's own sum (plus ``eps``, where a model's published block adds one to
+    the denominator) where ``normalize``, times ``scale``. With ``select_bias``
     (E,) the k are picked by score plus bias and weighted by the score
     alone: the bias moves picks, never weights."""
     if scoring not in ("softmax", "sigmoid"):
@@ -193,7 +194,8 @@ def topk_route(logits: jax.Array, k: int, *, normalize: bool = True,
         _, e = jax.lax.top_k(p + select_bias.astype(jnp.float32), k)
         w = jnp.take_along_axis(p, e, axis=-1)
     if normalize:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
+        total = jnp.sum(w, axis=-1, keepdims=True)
+        w = w / (total + jnp.float32(eps) if eps else total)
     return w * jnp.float32(scale), e.astype(jnp.int32)
 
 
