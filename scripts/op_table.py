@@ -28,7 +28,7 @@ from benchmark.trace_reduce import DEVICE_PLANE, MODULES_LINE, OPS_LINE, op_name
 # An inner scope before the one that holds it: a row is the first that matches.
 SCOPES = ("moe_experts", "moe_dispatch", "ssm_scan", "ssm_update", "mla_prefill", "mla_decode",
           "attn_ring", "attn_full_walk", "attn_prefill", "attn_decode",
-          "eva_summarise", "eva_prefill", "eva_decode")
+          "eva_summarise", "eva_prefill", "eva_decode", "sample")
 CONTAINERS = ("while", "conditional", "call")
 
 
@@ -37,15 +37,16 @@ def dispatch_of(model, tokens: int, acc_delta) -> dict:
     phase's row of ``acc`` by ``acc_delta``: the picks of a launch of ``tokens``
     rows, the rows its compact branch carries (``ops/moe.py`` ``_row_bound``),
     and the layers that ran each branch (the row's fourth column is held
-    experts x expert layers run, its last the layers that ran compact)."""
+    experts x expert layers run, ``COMPACT_COLUMN`` the layers that ran compact)."""
+    from tpuserve.models.paged_lm import COMPACT_COLUMN
     from tpuserve.ops.moe import _row_bound
 
     picks = tokens * model.top_k
     ran = int(acc_delta[3]) // max(1, model.e_count)
+    compact = int(acc_delta[model.COLUMNS.index(COMPACT_COLUMN)])
     return {"picks": picks, "rows_carried_compact": _row_bound(picks, model.e_count,
                                                                model.n_experts),
-            "expert_layers_run": ran, "compact": int(acc_delta[-1]),
-            "wide": ran - int(acc_delta[-1])}
+            "expert_layers_run": ran, "compact": compact, "wide": ran - compact}
 
 
 def _kind(instruction: str) -> str:

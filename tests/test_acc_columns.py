@@ -24,6 +24,8 @@ EXPERTS = ["moe_tokens_routed_total{model=M,phase=PH,held=yes}",
            "moe_experts_hit_total{model=M,phase=PH}", "moe_expert_steps_total{model=M,phase=PH}"]
 CONTEXT = ["gen_context_tokens_total{model=M,phase=PH}"]
 COMPACT = ["moe_layers_compact_total{model=M,phase=PH}"]
+SAMPLE = ["decode:gen_sample_steps_total{model=M,path=greedy}",
+          "decode:gen_sample_steps_total{model=M,path=drawn}"]
 SSM = ["ssm_tokens_total{model=M,phase=PH}", "ssm_state_rows_total{model=M,phase=PH}",
        "prefill:ssm_pieces_total{model=M,start=zero}",
        "prefill:ssm_pieces_total{model=M,start=carried}"]
@@ -31,16 +33,17 @@ MLA = EXPERTS + CONTEXT + [
     "mla_rows_attended_total{model=M,phase=PH}", "mla_rows_walked_total{model=M,phase=PH}",
     "mla_launches_total{model=M,phase=PH,form=absorbed}",
     "mla_launches_total{model=M,phase=PH,form=expanded}"] + COMPACT + [
-    "mla_tiles_total{model=M,phase=PH,walk=kernel}", "mla_tiles_total{model=M,phase=PH,walk=xla}"]
+    "mla_tiles_total{model=M,phase=PH,walk=kernel}", "mla_tiles_total{model=M,phase=PH,walk=xla}"] \
+    + SAMPLE
 SERIES = {
-    "decoder": EXPERTS + CONTEXT + COMPACT,
-    "hybrid": EXPERTS + CONTEXT + SSM + COMPACT,
-    "hybrid_ffn": CONTEXT + SSM,
+    "decoder": EXPERTS + CONTEXT + COMPACT + SAMPLE,
+    "hybrid": EXPERTS + CONTEXT + SSM + COMPACT + SAMPLE,
+    "hybrid_ffn": CONTEXT + SSM + SAMPLE,
     "mla": MLA,
     "mla_sc": MLA + ["moe_routed_zero_total{model=M,phase=PH}"],
     "mla_hc": MLA + ["hc_maps_total{model=M,phase=PH,path=kernel}",
                      "hc_maps_total{model=M,phase=PH,path=xla}"],
-    "decoder_sink": EXPERTS + CONTEXT + COMPACT + [
+    "decoder_sink": EXPERTS + CONTEXT + COMPACT + SAMPLE + [
         "attn_rows_attended_total{model=M,phase=PH}", "attn_rows_walked_total{model=M,phase=PH}",
         "attn_walks_total{model=M,phase=PH,walk=kernel}",
         "attn_walks_total{model=M,phase=PH,walk=xla}"],
@@ -48,8 +51,8 @@ SERIES = {
         "decode:delta_steps_total{model=M,phase=decode,path=kernel}",
         "decode:delta_steps_total{model=M,phase=decode,path=xla}",
         "prefill:delta_scans_total{model=M,phase=prefill,path=kernel}",
-        "prefill:delta_scans_total{model=M,phase=prefill,path=xla}"],
-    "hybrid_conv": EXPERTS + CONTEXT + SSM + COMPACT,
+        "prefill:delta_scans_total{model=M,phase=prefill,path=xla}"] + SAMPLE,
+    "hybrid_conv": EXPERTS + CONTEXT + SSM + COMPACT + SAMPLE,
     "eva": CONTEXT + [
         "eva_rows_attended_total{model=M,phase=PH,kind=exact}",
         "eva_rows_attended_total{model=M,phase=PH,kind=summary}",
@@ -57,7 +60,7 @@ SERIES = {
         "decode:eva_decode_steps_total{model=M,phase=decode,path=head_walk}",
         "decode:eva_decode_steps_total{model=M,phase=decode,path=gather}",
         "prefill:eva_prefill_tiles_total{model=M,phase=prefill,path=tile_kernel}",
-        "prefill:eva_prefill_tiles_total{model=M,phase=prefill,path=xla}"],
+        "prefill:eva_prefill_tiles_total{model=M,phase=prefill,path=xla}"] + SAMPLE,
 }
 # The fourth expert column sums held experts x expert layers run, so it feeds
 # the layers' counter too, over the experts held.

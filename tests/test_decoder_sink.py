@@ -216,16 +216,16 @@ def test_chunked_prefill_then_decode_is_the_full_forward_pass(whole, served):
     context = [sum(n * (n + 1) // 2 for n in PROMPTS),
                sum(sum(range(p + 1, p + n)) for p, n in zip(PROMPTS, NEWS))]
     assert [acc[0, 4], acc[1, 4]] == context
-    names = [c.counter(model, _Names(), "decode").name for c in model.COLUMNS[6:]]
+    names = [c.counter(model, _Names(), "decode").name for c in model.COLUMNS[8:]]
     assert names == [f"attn_rows_attended_total{{model=sink,phase=decode}}",
                      f"attn_rows_walked_total{{model=sink,phase=decode}}",
                      f"attn_walks_total{{model=sink,phase=decode,walk=kernel}}",
                      f"attn_walks_total{{model=sink,phase=decode,walk=xla}}"]
-    assert acc[1, 6] == n_global * context[1]                    # attended: the live rows
-    assert acc[1, 7] >= acc[1, 6]                                # walked: whole blocks or the table
+    assert acc[1, 8] == n_global * context[1]                    # attended: the live rows
+    assert acc[1, 9] >= acc[1, 8]                                # walked: whole blocks or the table
     # a live lane an attention layer a step, global or window, all on the one path
     lanes = n_attn * sum(n - 1 for n in NEWS)
-    assert [acc[1, 8], acc[1, 9]] == ([lanes, 0] if path == "kernel" else [0, lanes])
+    assert [acc[1, 10], acc[1, 11]] == ([lanes, 0] if path == "kernel" else [0, lanes])
 
 
 class _Names:
@@ -619,11 +619,11 @@ def test_a_step_steered_to_the_kernel_is_the_step_in_xla(wide):
     np.testing.assert_allclose(np.asarray(steered_state["lp"][:2, 1]),
                                np.asarray(plain_state["lp"][:2, 1]), atol=5e-2)
     acc_x, acc_k = np.asarray(plain["acc"]).astype(int), np.asarray(steered["acc"]).astype(int)
-    assert acc_x[1, 8] == 0 and acc_x[1, 9] == 14 and acc_k[1, 8] == 14 and acc_k[1, 9] == 0
-    assert acc_k[1, 6] == acc_x[1, 6] == 2 * (138 + 6)           # attended: the live rows
+    assert acc_x[1, 10] == 0 and acc_x[1, 11] == 14 and acc_k[1, 10] == 14 and acc_k[1, 11] == 0
+    assert acc_k[1, 8] == acc_x[1, 8] == 2 * (138 + 6)           # attended: the live rows
     kb = max(1, wide.step_keys // 16)
     # walked: a cell a lane (the one that is not live walks one too), not the padded table
-    assert acc_k[1, 7] == 2 * SLOTS * kb * 16 < acc_x[1, 7] == 2 * SLOTS * 192 * 16
+    assert acc_k[1, 9] == 2 * SLOTS * kb * 16 < acc_x[1, 9] == 2 * SLOTS * 192 * 16
 
 
 # -- through the engine ---------------------------------------------------------------------

@@ -233,9 +233,9 @@ def test_the_counter_says_where_a_launchs_chunked_rules_ran(tmp_path):
     names = [c.counter(model, Names(), "prefill") for c in model.COLUMNS]
     at = {p: names.index(f"delta_scans_total{{model=hd,phase=prefill,path={p}}}")
           for p in mixers.PATHS}
-    assert all(c.counter(model, Names(), "decode") is None
-               for c in model.COLUMNS[-2:])
-    base = {"tokens": 5, "rows": 1, "zero": 1, "carried": 0, "context": 15}
+    assert all(model.COLUMNS[i].counter(model, Names(), "decode") is None for i in at.values())
+    base = {"tokens": 5, "rows": 1, "zero": 1, "carried": 0, "context": 15,
+            "sample": {"greedy": 0, "drawn": 0}}
     for path in mixers.PATHS:
         counts = {**base, "paths": dict.fromkeys(mixers.PATHS, 0),
                   "scans": {p: int(p == path) for p in mixers.PATHS}}

@@ -92,9 +92,9 @@ def test_chunked_prefill_then_decode_is_the_reference_full_pass(whole, case):
     # (columns 12 and 13: in the kernels and in XLA, which is where the CPU mixes)
     acc = np.asarray(out["acc"]).astype(np.int64)
     tokens = sum(len(p) for p in PROMPTS)
-    assert acc[0, 13] == 2 * ARCH["num_hidden_layers"] * tokens
-    assert acc[1, 13] == 2 * ARCH["num_hidden_layers"] * (sum(MAX_NEWS) - len(MAX_NEWS))
-    assert not acc[:, 12].any()
+    assert acc[0, 15] == 2 * ARCH["num_hidden_layers"] * tokens
+    assert acc[1, 15] == 2 * ARCH["num_hidden_layers"] * (sum(MAX_NEWS) - len(MAX_NEWS))
+    assert not acc[:, 14].any()
 
 
 def test_a_launch_through_the_kernels_then_decode_is_the_reference_full_pass(
@@ -124,8 +124,8 @@ def test_a_launch_through_the_kernels_then_decode_is_the_reference_full_pass(
     assert [int(s["n_new"]) for s in served] == MAX_NEWS
     assert worst(PROMPTS, served, arch) < TOL
     acc = np.asarray(out["acc"]).astype(np.int64)
-    assert acc[0, 12] == 2 * layers * sum(len(p) for p in PROMPTS) and acc[0, 13] == 0
-    assert acc[1, 13] == 2 * layers * (sum(MAX_NEWS) - len(MAX_NEWS)) and acc[1, 12] == 0
+    assert acc[0, 14] == 2 * layers * sum(len(p) for p in PROMPTS) and acc[0, 15] == 0
+    assert acc[1, 15] == 2 * layers * (sum(MAX_NEWS) - len(MAX_NEWS)) and acc[1, 14] == 0
 
 
 def frozen(monkeypatch, which: str):

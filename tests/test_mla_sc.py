@@ -116,8 +116,8 @@ def test_chunked_prefill_then_decode_is_the_reference_one_causal_pass(whole, tmp
     acc = np.asarray(out["acc"]).astype(np.int64)
     tokens, steps = sum(len(p) for p in PROMPTS), sum(n - 1 for n in MAX_NEWS)
     for phase, n in ((0, tokens), (1, steps)):
-        assert acc[phase, 0] + acc[phase, 1] + acc[phase, 12] == 2 * K * n
-        assert acc[phase, 12] > 0
+        assert acc[phase, 0] + acc[phase, 1] + acc[phase, 14] == 2 * K * n
+        assert acc[phase, 14] > 0
     assert (acc[0, 1] == 0) == (case == "every-expert-held")
     assert acc[0, 4] == sum(n * (n + 1) // 2 for n in (19, 5, 11, 2))
     # rows attended and walked sum over the four attentions; a step is absorbed
@@ -300,7 +300,7 @@ def test_the_page_signature_holds_two_latent_rows_a_layer_and_the_attention_is_m
     sig = model.kv_page_signature(SLOTS, 9, PAGE)
     assert len(sig["ckv"]) == len(sig["kr"]) == 4
     assert sig["ckv"][3].shape == (9, PAGE, 32) and sig["kr"][3].shape == (9, 2, 128)
-    assert sig["acc"].shape == (2, 13) and model.kv_page_leaves == ("ckv", "kr")
+    assert sig["acc"].shape == (2, 15) and model.kv_page_leaves == ("ckv", "kr")
     # shared, not copied: the attention's functions are `mla.LatentServing`'s own
     for name in ("_project", "_write_keys", "_attend_tile", "_attend_tiles", "_walk", "_form",
                  "_attn_out", "_attention", "_step_plan", "_prefill_plan"):

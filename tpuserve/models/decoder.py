@@ -55,8 +55,8 @@ import numpy as np
 from tpuserve.config import ModelConfig
 from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN,  # noqa: F401
                                       EXPERT_COLUMNS, KEY_BLOCK, LOGPROBS, MAX_PIECES,
-                                      NEG, PagedLM, _mm, head_share, read_config_file,
-                                      rms_norm)
+                                      NEG, SAMPLE_COLUMNS, PagedLM, _mm, head_share,
+                                      read_config_file, rms_norm)
 from tpuserve.ops.moe import held_experts_swiglu, topk_route
 
 # Standard deviations of the drawn tensors, by role (``weight_scales`` in the
@@ -124,9 +124,9 @@ def apply_rope(x: jax.Array, pos: jax.Array, inv_freq: np.ndarray,
 
 class DecoderServing(PagedLM):
     cache_leaves = ("kf", "vf", "kw", "vw")  # pages of the full layers, rings of the window layers
-    # The expert layers' four, the context, and sparse layers whose dispatch
-    # took the compact branch.
-    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, COMPACT_COLUMN)
+    # The expert layers' four, the context, sparse layers whose dispatch took
+    # the compact branch, and the steps by the sampler's branch.
+    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, COMPACT_COLUMN, *SAMPLE_COLUMNS)
 
     value_scale = 1.0   # a factor on the values before they are cached; 1: none
 

@@ -85,7 +85,7 @@ import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
 from tpuserve.models import decoder as dec
-from tpuserve.models.paged_lm import (CONTEXT_COLUMN, NEG, Column, _mm, counted,
+from tpuserve.models.paged_lm import (CONTEXT_COLUMN, NEG, SAMPLE_COLUMNS, Column, _mm, counted,
                                       read_config_file, series)
 from tpuserve.ops import lane_attention as la
 from tpuserve.ops import launch_attention as lat
@@ -115,7 +115,7 @@ class EvaServing(dec.DecoderServing):
     cache_leaves = kv_page_leaves = ("kf", "vf")   # ONE pool a layer: rings, then pages
     # The rows a token attends (exact and summary: what the cache's bytes go
     # by), each kind, chunks pooled, windows closed, a step's lanes and a
-    # launch's tiles by path.
+    # launch's tiles by path, and the steps by the sampler's branch.
     COLUMNS = (
         CONTEXT_COLUMN,
         Column(counted("exact"), series("eva_rows_attended_total", ",kind=exact")),
@@ -123,7 +123,8 @@ class EvaServing(dec.DecoderServing):
         Column(counted("chunks"), series("eva_chunks_summarised_total")),
         Column(counted("windows"), series("eva_windows_closed_total")),
         *_by_path("paths", "eva_decode_steps_total", "decode", PATHS),
-        *_by_path("tiles", "eva_prefill_tiles_total", "prefill", TILE_PATHS))
+        *_by_path("tiles", "eva_prefill_tiles_total", "prefill", TILE_PATHS),
+        *SAMPLE_COLUMNS)
     # Pages a cell of the step's walk holds (``head_walk``'s key block): a cell
     # reads its pages whole whatever the lane's length, so small ones follow the
     # live rows and large ones save cells. One layer of 24 lanes at the mix's
@@ -310,7 +311,8 @@ class EvaServing(dec.DecoderServing):
         summary = jnp.sum(jnp.where(live, (pos // self.window) * self.rows, 0))
         lanes = jnp.sum(live) * self.n_layers
         tiles = 0 if m["t"] is None else jnp.sum(m["t"]["has"]) * self.n_layers
-        return {"context": exact + summary, "exact": exact, "summary": summary,
+        return {**super()._counts(m), "context": exact + summary,   # the rows, not the positions
+                "exact": exact, "summary": summary,
                 "chunks": jnp.sum(m["ends"]) * self.n_layers,
                 "windows": jnp.sum(live & (pos % self.window == self.window - 1)),
                 "paths": {p: lanes * (m.get("path") == p) for p in PATHS},

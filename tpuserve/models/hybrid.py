@@ -66,8 +66,8 @@ import jax.numpy as jnp
 from tpuserve.config import ModelConfig
 from tpuserve.models.mixers import SSM_COLUMNS, PatternMixers
 from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN,  # noqa: F401
-                                      EXPERT_COLUMNS, LOGPROBS, PagedLM, _mm, head_share,
-                                      read_config_file, rms_norm)
+                                      EXPERT_COLUMNS, LOGPROBS, SAMPLE_COLUMNS, PagedLM, _mm,
+                                      head_share, read_config_file, rms_norm)
 from tpuserve.ops.moe import held_experts, relu2, topk_route
 
 # Standard deviations of the drawn tensors, by role (``weight_scales`` in the
@@ -84,8 +84,9 @@ DEFAULT_SCALES = {
 
 class HybridServing(PatternMixers, PagedLM):
     # The expert layer's four and the context, as ``decoder`` has them, then the
-    # scan layers' four, and expert layers whose dispatch took the compact branch.
-    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, *SSM_COLUMNS, COMPACT_COLUMN)
+    # scan layers' four, expert layers whose dispatch took the compact branch,
+    # and the steps by the sampler's branch.
+    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, *SSM_COLUMNS, COMPACT_COLUMN, *SAMPLE_COLUMNS)
 
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)

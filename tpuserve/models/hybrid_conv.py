@@ -51,8 +51,8 @@ import jax.numpy as jnp
 from tpuserve.config import ModelConfig
 from tpuserve.models.hybrid_delta import RoutedExperts
 from tpuserve.models.mixers import SSM_COLUMNS, ConvPatternMixers
-from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN, EXPERT_COLUMNS, PagedLM,
-                                      read_config_file, rms_norm)
+from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN, EXPERT_COLUMNS,
+                                      SAMPLE_COLUMNS, PagedLM, read_config_file, rms_norm)
 
 # Standard deviations of the drawn tensors, by role (``weight_scales`` in the
 # config file overrides any). q and k are normed by head, so ``qk`` moves
@@ -70,8 +70,9 @@ KINDS = ("conv", "full_attention")
 
 class HybridConvServing(ConvPatternMixers, RoutedExperts, PagedLM):
     # The expert layer's four (a dense layer counts nothing in them) and the
-    # context, the recurrent layers' four, and the compact dispatches.
-    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, *SSM_COLUMNS, COMPACT_COLUMN)
+    # context, the recurrent layers' four, the compact dispatches, and the
+    # steps by the sampler's branch.
+    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, *SSM_COLUMNS, COMPACT_COLUMN, *SAMPLE_COLUMNS)
     route_eps = 1e-6  # the published block's: weights over their own sum plus this
 
     def __init__(self, cfg: ModelConfig) -> None:

@@ -48,8 +48,8 @@ import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
 from tpuserve.models.mixers import DELTA_COLUMNS, SSM_COLUMNS, DeltaPatternMixers
-from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN, EXPERT_COLUMNS, PagedLM,
-                                      read_config_file, rms_norm)
+from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN, EXPERT_COLUMNS,
+                                      SAMPLE_COLUMNS, PagedLM, read_config_file, rms_norm)
 from tpuserve.ops.moe import held_experts_swiglu, topk_route
 
 # Standard deviations of the drawn tensors, by role (``weight_scales`` in the
@@ -111,8 +111,10 @@ class RoutedExperts:
 
 class HybridDeltaServing(DeltaPatternMixers, RoutedExperts, PagedLM):
     # The expert layer's four and the context, the recurrent layers' four, the
-    # compact dispatches, and a step's updates by where they ran.
-    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, *SSM_COLUMNS, COMPACT_COLUMN, *DELTA_COLUMNS)
+    # compact dispatches, a step's updates by where they ran, and the steps by
+    # the sampler's branch.
+    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, *SSM_COLUMNS, COMPACT_COLUMN, *DELTA_COLUMNS,
+               *SAMPLE_COLUMNS)
 
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)

@@ -44,7 +44,8 @@ import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
 from tpuserve.models.mixers import SSM_COLUMNS, PatternMixers
-from tpuserve.models.paged_lm import CONTEXT_COLUMN, PagedLM, read_config_file, rms_norm
+from tpuserve.models.paged_lm import (CONTEXT_COLUMN, SAMPLE_COLUMNS, PagedLM, read_config_file,
+                                      rms_norm)
 
 # Standard deviations of the drawn tensors, by role (``weight_scales`` in the
 # config file overrides any): ``hybrid``'s, where the roles are the same.
@@ -57,7 +58,8 @@ KINDS = ("mamba", "attention")
 
 
 class HybridFfnServing(PatternMixers, PagedLM):
-    COLUMNS = (CONTEXT_COLUMN, *SSM_COLUMNS)   # the context and the scan layers' four
+    # The context, the scan layers' four, and the steps by the sampler's branch.
+    COLUMNS = (CONTEXT_COLUMN, *SSM_COLUMNS, *SAMPLE_COLUMNS)
 
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)

@@ -96,8 +96,8 @@ from tpuserve.config import ModelConfig
 from tpuserve.models.decoder import apply_rope, rope_inv_freq
 from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN,  # noqa: F401
                                       EXPERT_COLUMNS, KEY_BLOCK, LOGPROBS, NEG, Column,
-                                      PagedLM, _mm, counted, read_config_file, rms_norm,
-                                      series)
+                                      SAMPLE_COLUMNS, PagedLM, _mm, counted, read_config_file,
+                                      rms_norm, series)
 from tpuserve.ops import lane_attention as la
 from tpuserve.ops import tile_attention as ta
 from tpuserve.ops.moe import held_experts_swiglu, topk_route
@@ -140,7 +140,7 @@ class LatentServing(PagedLM):
     # branch (none where every expert is held), and the tiles whose walk over
     # key blocks ran in the kernel and in XLA (``_walk``: a launch's tiles of a
     # piece; a step's live lanes; every attention of a launch walks alike, so a
-    # tile counts once).
+    # tile counts once), and the steps by the sampler's branch.
     COLUMNS = (
         *EXPERT_COLUMNS, CONTEXT_COLUMN,
         Column(counted("attended"), series("mla_rows_attended_total")),
@@ -150,7 +150,8 @@ class LatentServing(PagedLM):
         COMPACT_COLUMN,
         *(Column(lambda model, stats, counts, walk=walk:
                  counts["tiles"] if counts["walk"] == walk else 0,
-                 series("mla_tiles_total", f",walk={walk}")) for walk in WALKS))
+                 series("mla_tiles_total", f",walk={walk}")) for walk in WALKS),
+        *SAMPLE_COLUMNS)
     TILE_ROWS = KEY_BLOCK
     # Key positions a cell of the step's kernel walks (``ops/lane_attention.py``
     # says what a cell costs): at contexts of thousands a prefill tile's key

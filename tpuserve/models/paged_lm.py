@@ -67,6 +67,7 @@ LOGPROBS = 8  # top log-probabilities kept per generated position
 NEG = -1e9
 MAX_PIECES = 8    # prompts' pieces one prefill launch takes at most
 KEY_BLOCK = 1024  # key positions a block of a full layer's prefill attention
+TOP_GROUP = 128   # neighbouring logits a group of the sampler's top-k (``_top_logits``)
 
 
 def rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
@@ -167,6 +168,14 @@ EXPERT_COLUMNS = (
 CONTEXT_COLUMN = Column(counted("context"), series("gen_context_tokens_total"))
 # Expert layers run whose dispatch carried the compact row bound (``ops/moe.py``).
 COMPACT_COLUMN = Column(summed("compact"), series("moe_layers_compact_total"))
+# Steps by whether their sampler made its Gumbel draw (``_sample``'s branch):
+# ``gen_sample_steps_total{model=,path=greedy|drawn}``.
+SAMPLE_COLUMNS = tuple(
+    Column(lambda model, stats, counts, path=path: counts["sample"][path],
+           lambda model, metrics, ph, path=path: metrics.counter(
+               f"gen_sample_steps_total{{model={model.name},path={path}}}")
+           if ph == "decode" else None)
+    for path in ("greedy", "drawn"))
 
 
 class PagedLM(GenerativeModel):
@@ -401,19 +410,62 @@ class PagedLM(GenerativeModel):
                               preferred_element_type=jnp.float32)
         return _mm(h, params["head"])
 
-    def _sample(self, logits, seed, position, temp):
+    @staticmethod
+    def _top_logits(logits):
+        """(rows, V) float32 -> the ``LOGPROBS`` largest logits a row and their
+        ids, both (rows, LOGPROBS), as ``jax.lax.top_k`` of the whole row gives
+        them (ties to the lower id), in ONE read of the row: the maxima of its
+        groups of ``TOP_GROUP`` neighbours, the ``LOGPROBS`` groups of largest
+        maximum (an entry among the row's largest lies in one of them), and
+        the largest of those groups' entries, the groups in ascending order so
+        that a tie falls as it does over the whole row. A row that is no whole
+        number of groups is padded with -inf; one of ``LOGPROBS`` groups or
+        fewer goes straight to ``top_k``."""
+        rows, v = logits.shape
+        n = -(-v // TOP_GROUP)
+        if n <= LOGPROBS:
+            return jax.lax.top_k(logits, LOGPROBS)
+        # Eight rows are a tile of the logits on the TPU: kept apart, the view by
+        # groups, and the groups as a table of rows to take from, are the same
+        # bytes. As (rows, n, TOP_GROUP) the compiler first copied every logit
+        # into another layout, 134 MB of scratch at 512 x 65,536: the maxima took
+        # 0.58 ms where they take 0.19 and the take 0.44 where it takes 0.04 (my
+        # chip run, PR 60; ``scripts/bench_sampler.py`` by operation).
+        tile = 8 if rows % 8 == 0 else 1
+        groups = jnp.pad(logits, ((0, 0), (0, n * TOP_GROUP - v)), constant_values=-jnp.inf) \
+            .reshape(rows // tile, tile, n, TOP_GROUP)
+        _, held = jax.lax.top_k(jnp.max(groups, axis=-1).reshape(rows, n), LOGPROBS)
+        held = jnp.sort(held, axis=-1)
+        row = jnp.arange(rows)[:, None]
+        taken = jnp.take(groups.transpose(0, 2, 1, 3).reshape(rows * n, TOP_GROUP),
+                         ((row // tile) * n + held) * tile + row % tile, axis=0)
+        vals, at = jax.lax.top_k(taken.reshape(rows, LOGPROBS * TOP_GROUP), LOGPROBS)
+        return vals, jnp.take_along_axis(held, at // TOP_GROUP, axis=1) * TOP_GROUP \
+            + at % TOP_GROUP
+
+    @staticmethod
+    def _sample(logits, seed, position, temp, drawn):
         """Greedy where temp == 0, Gumbel-max otherwise, keyed by the
         request's seed and the position sampled for; also the top
-        log-probabilities of the distribution sampled from."""
-        def one(lg, sd, pos, t):
+        log-probabilities of the distribution sampled from: log-softmax's own
+        formula on the ``LOGPROBS`` largest logits (``_top_logits``), whose
+        first is the row's maximum and the greedy token. ``drawn`` (the
+        launch's: some live row has temp > 0) guards the draw: no random number
+        is made in a launch whose live rows are all greedy."""
+        def one(lg, sd, pos, t, greedy):
             key = jax.random.fold_in(jax.random.fold_in(jax.random.key(0), sd), pos)
             g = jax.random.gumbel(key, lg.shape, jnp.float32)
             sampled = jnp.argmax(lg / jnp.where(t > 0, t, 1.0) + g)
-            return jnp.where(t > 0, sampled, jnp.argmax(lg)).astype(jnp.int32)
+            return jnp.where(t > 0, sampled, greedy).astype(jnp.int32)
 
-        tok = jax.vmap(one)(logits, seed, position, temp)
-        lp, ids = jax.lax.top_k(jax.nn.log_softmax(logits, axis=-1), LOGPROBS)
-        return tok, ids.astype(jnp.int32), lp
+        with jax.named_scope("sample"):
+            vals, ids = PagedLM._top_logits(logits)
+            top = vals[:, :1]
+            lp = (vals - top) - jnp.log(jnp.sum(jnp.exp(logits - top), axis=-1, keepdims=True))
+            tok = jax.lax.cond(
+                drawn, lambda: jax.vmap(one)(logits, seed, position, temp, ids[:, 0]),
+                lambda: ids[:, 0])
+        return tok, ids, lp
 
     # -- the two programs ---------------------------------------------------------
     def prefill_chunk(self, params: Any, state: Any, launch: Any, *, chunk: int) -> Any:
@@ -425,18 +477,19 @@ class PagedLM(GenerativeModel):
         its own lane."""
         t = self._tiles(launch, chunk)
         x = self._embed(params, launch["ids"])
-        m = self._prefill_plan(state, launch, t)
+        m = dict(self._prefill_plan(state, launch, t),
+                 drawn=jnp.any((launch["length"] > 0) & (launch["temp"] > 0)))
         x, new = self._layers(params, state, x, m)
-        return self._arm(params, state, new, launch, t, x, m["lanes"])
+        return self._arm(params, state, new, launch, t, x, m["lanes"], m["drawn"])
 
     def step(self, params: Any, state: Any) -> tuple[Any, dict]:
         """One token a live lane."""
         live = state["armed"] & ~state["done"]
         pos = jnp.clip(state["pos"], 0, self.max_ctx - 1)
         x = self._embed(params, state["last"])
-        m = self._step_plan(state, live, pos)
+        m = dict(self._step_plan(state, live, pos), drawn=jnp.any(live & (state["temp"] > 0)))
         x, new = self._layers(params, state, x, m)
-        return self._emit(params, state, new, x, live, pos)
+        return self._emit(params, state, new, x, live, pos, m["drawn"])
 
     def _layers(self, params, state, x, m: dict):
         """The stream through every layer and the launch's row into ``acc``
@@ -493,8 +546,11 @@ class PagedLM(GenerativeModel):
     def _counts(self, m: dict) -> dict:
         """A launch's own counts, by the names ``COLUMNS`` reads them under
         (``counted``), after its layers: here the context, positions attended
-        from summed over live tokens."""
-        return {"context": jnp.sum(jnp.where(m["live"], m["pos"] + 1, 0))}
+        from summed over live tokens, and a step under the branch its sampler
+        takes (a prefill launch under neither)."""
+        step = m["t"] is None
+        return {"context": jnp.sum(jnp.where(m["live"], m["pos"] + 1, 0)),
+                "sample": {"greedy": step & ~m["drawn"], "drawn": step & m["drawn"]}}
 
     # -- prefill ------------------------------------------------------------------
     # One launch of the static width C carries the waiting pieces of up to K
@@ -675,17 +731,18 @@ class PagedLM(GenerativeModel):
             lambda a: self._prefill_full(a[0], pools, *a[1:], heads),
             (qt, t["rows"], t["qpos"], t["last"]))
 
-    def _arm(self, params, state, new: dict, launch: Any, t: dict, x, extra: dict) -> dict:
+    def _arm(self, params, state, new: dict, launch: Any, t: dict, x, extra: dict,
+             drawn) -> dict:
         """The end of a prefill launch: each piece that ends its prompt
         samples at its own last row and arms its own lane; a piece of no
         tokens writes nothing (its slot is out of range). ``extra``: further
-        lanes the family keeps (a ring's index)."""
+        lanes the family keeps (a ring's index); ``drawn``: the launch's."""
         slot, start, length, n = (launch[f] for f in ("slot", "start", "length", "n"))
         K, T, C = t["K"], t["T"], t["C"]
         is_final = (length > 0) & (start + length >= n)
         h_last = jnp.take(x, jnp.clip(t["first_tile"] * T + n - 1 - start, 0, C - 1), axis=0)
         first, lp_ids, lp_vals = self._sample(
-            self._head(params, h_last), launch["seed"], n, launch["temp"])
+            self._head(params, h_last), launch["seed"], n, launch["temp"], drawn)
         at = jnp.where(length > 0, slot, state["pos"].shape[0])
         lanes = {"bt": launch["pages"], **extra,
                  "tokens": jnp.zeros((K, self.max_new), jnp.int32).at[:, 0].set(first),
@@ -763,15 +820,16 @@ class PagedLM(GenerativeModel):
         return self._attend(q[:, None], kc.transpose(1, 2, 0, 3),
                             vc.transpose(1, 2, 0, 3), mask)[:, 0]
 
-    def _emit(self, params, state, new: dict, x, live, pos) -> tuple[Any, dict]:
+    def _emit(self, params, state, new: dict, x, live, pos, drawn) -> tuple[Any, dict]:
         """The end of a decode step (``new``: the state with the caches and
         ``acc`` as the step leaves them): every live lane samples its next
         token from its last row ``x`` (b, d), keeps it with its
-        log-probabilities, and moves on; the others stay as they were."""
+        log-probabilities, and moves on; the others stay as they were.
+        ``drawn``: the launch's."""
         rows = jnp.arange(pos.shape[0])
         nxt = jnp.clip(pos + 1, 0, self.max_ctx - 1)
         tok, lp_ids, lp_vals = self._sample(self._head(params, x), state["seed"],
-                                            nxt, state["temp"])
+                                            nxt, state["temp"], drawn)
         n_new = state["n_new"]
         at = jnp.clip(n_new, 0, self.max_new - 1)
         keep = ~live
