@@ -26,8 +26,8 @@ from benchmark.ssm_window import scope_map  # noqa: E402
 from benchmark.trace_reduce import DEVICE_PLANE, MODULES_LINE, OPS_LINE, op_name  # noqa: E402
 
 # An inner scope before the one that holds it: a row is the first that matches.
-SCOPES = ("moe_experts", "moe_dispatch", "ssm_scan", "ssm_update", "mla_prefill", "mla_decode",
-          "attn_ring", "attn_full_walk", "attn_prefill", "attn_decode",
+SCOPES = ("moe_route", "moe_experts", "moe_dispatch", "ssm_scan", "ssm_update", "mla_prefill",
+          "mla_decode", "attn_ring", "attn_full_walk", "attn_prefill", "attn_decode",
           "eva_summarise", "eva_prefill", "eva_decode", "sample")
 CONTAINERS = ("while", "conditional", "call")
 

@@ -4,6 +4,8 @@ sigmoid scores with a selection bias, an expert's body of two kernels or of
 three, one sort and one grouped product for both. Tier-1 (`tests/test_moe.py`
 is marked slow as a whole: the Switch layer's mesh tests)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -130,6 +132,52 @@ def test_the_tiles_of_both_expert_layers_on_the_chip():
 
 
 # -- the dispatch carries only the held picks (ISSUE 37) ------------------------------------------
+
+def _topk_route_pr60(logits, k, *, normalize=True, scale=1.0, scoring="softmax",
+                     select_bias=None, eps=0.0):
+    """The router as PR 60 had it (the picks' scores by ``take_along_axis``, a
+    gather of scalars), kept here as the oracle of bit-equality."""
+    x = logits.astype(jnp.float32)
+    p = jax.nn.softmax(x, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(x)
+    _, e = jax.lax.top_k(p + select_bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(p, e, axis=-1)
+    if normalize:
+        total = jnp.sum(w, axis=-1, keepdims=True)
+        w = w / (total + jnp.float32(eps) if eps else total)
+    return w * jnp.float32(scale), e.astype(jnp.int32)
+
+
+@pytest.mark.parametrize("eps", [0.0, 1e-6])
+@pytest.mark.parametrize("normalize", [True, False])
+@pytest.mark.parametrize("of,k", [(of, k) for of in (16, 64, 512) for k in (2, 4, 8, 22)
+                                  if k <= of])
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+def test_the_picks_weights_by_comparison_are_the_gathers_bit_for_bit(scoring, of, k, normalize,
+                                                                     eps):
+    """With a selection bias the picks' weights are the scores at the picks
+    (ISSUE 61): read by comparison (the maximum over a row of the score at
+    the pick and nothing elsewhere), they are what ``take_along_axis`` read to
+    the last bit, normalised or not, and the program holds no gather."""
+    from tpuserve.ops.moe import topk_route
+
+    rng = np.random.default_rng(of * 100 + k)
+    logits = jnp.asarray(2.0 * rng.standard_normal((96, of)), jnp.float32).at[:, 3].set(-9.0)
+    p = jax.nn.softmax(logits, -1) if scoring == "softmax" else jax.nn.sigmoid(logits)
+    # a bias as wide as the scores themselves, and one that lifts every row's last expert
+    # among its picks: at least one pick of every row moves
+    bias = jnp.asarray(rng.uniform(-1, 1, of) * 2 * float(jnp.std(p)), jnp.float32).at[3].set(4.0)
+    kw = dict(normalize=normalize, scale=2.5, scoring=scoring, select_bias=bias, eps=eps)
+    new = jax.jit(lambda x: topk_route(x, k, **kw))
+    old = jax.jit(lambda x: _topk_route_pr60(x, k, **kw))
+    (w, e), (w0, e0) = new(logits), old(logits)
+    assert w.dtype == jnp.float32 and e.dtype == jnp.int32 and w.shape == e.shape == (96, k)
+    assert np.array_equal(np.asarray(e), np.asarray(e0))
+    assert np.array_equal(_bits(w), _bits(w0))
+    _, unbiased = jax.lax.top_k(p, k)
+    assert np.all(np.any(np.sort(np.asarray(e), -1) != np.sort(np.asarray(unbiased), -1), -1))
+    assert "gather" not in new.lower(logits).as_text()
+    assert "gather" in old.lower(logits).as_text()
+
 
 def _layer(seed, first, count, of, k, t, dtype, body, masked, d=32, f=24, bias=None):
     """A routed layer's inputs: (x, weights, experts, w_in, w_out, live)."""
@@ -272,6 +320,82 @@ def test_where_nothing_can_be_left_behind_the_program_has_no_cond(first, count, 
     assert ("cond[" in text) == cond
     _, stats = moe.held_experts(x, w, e, first, w_in, w_out, moe.relu2, of=of)
     assert int(stats["compact"]) == int(cond)
+
+
+def _held_experts_pr60(x, weights, experts, first, w_in, w_out, body, live=None, of=None):
+    """``held_experts``' ``y`` as PR 60 had it: jax's own ``fill`` on both takes
+    (a bounds compare and a select over the block), ``clip`` on ``compact``'s
+    way back alone. The oracle of bit-equality for ISSUE 61."""
+    from tpuserve.ops.moe import _grouped_dot, _row_bound
+
+    t, k = experts.shape
+    count = w_out.shape[0]
+    bound = _row_bound(k * t, count, of)
+    local = experts - jnp.int32(first)
+    held = (local >= 0) & (local < count)
+    held_live = held & live[:, None] if live is not None else held
+    key = jnp.where(held_live, local, count).T.reshape(k * t)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(count, dtype=jnp.int32)[None, :], axis=0,
+                    dtype=jnp.int32)
+
+    def rows_of(picks, mode=None):
+        xs = jnp.take(x, picks % t, axis=0)
+        dot = _grouped_dot(picks.shape[0], x.dtype, w_in[0].shape, w_out.shape,
+                           expects=k * t / (of or count))
+        h = body(*(dot(xs, w, sizes) for w in w_in)).astype(x.dtype)
+        return jnp.take(dot(h, w_out, sizes), jnp.argsort(order), axis=0, mode=mode)
+
+    if bound < k * t:
+        out = jax.lax.cond(jnp.sum(held_live, dtype=jnp.int32) <= bound,
+                           lambda: rows_of(order[:bound], mode="clip"), lambda: rows_of(order))
+    else:
+        out = rows_of(order)
+    out = out.reshape(k, t, -1)
+    return jnp.sum(jnp.where(held_live.T[:, :, None], out * weights.T[:, :, None], 0.0), axis=0)
+
+
+def _fills(text, rows, width):
+    """What jax's ``fill`` leaves in a lowered text: the NaN it fills with, and a
+    select over a whole (rows, width) block."""
+    return len(re.findall(r"dense<0x7FC0(0000)?> : tensor<(f32|bf16)>", text)), len(re.findall(
+        rf"stablehlo\.select [^\n]*, tensor<{rows}x{width}x(f32|bf16)>\n", text))
+
+
+# (first, count, of, k, t, masked, dtype, body, branches): every expert held and a caller without a
+# width trace ONE branch (JoyAI's, Xing's and LFM2's programs), a share of the experts two
+@pytest.mark.parametrize("first,count,of,k,t,masked,dtype,body,branches", [
+    (0, 16, 16, 4, 256, True, "bfloat16", "swiglu", 1),
+    (0, 16, 16, 4, 256, False, "float32", "relu2", 1),
+    (4, 4, None, 4, 256, True, "float32", "swiglu", 1),
+    (4, 4, None, 2, 300, True, "bfloat16", "relu2", 1),
+    (4, 4, 16, 4, 256, True, "float32", "swiglu", 2),
+    (8, 8, 16, 2, 300, True, "bfloat16", "relu2", 2),
+    (4, 4, 16, 4, 256, False, "bfloat16", "swiglu", 2)])
+def test_no_take_checks_an_index_the_layer_made_and_y_is_the_parents(first, count, of, k, t, masked,
+                                                                      dtype, body, branches):
+    """Both takes of ``held_experts`` are the gather alone (ISSUE 61):
+    ``picks % t`` and the way back's permutation are in range by construction,
+    so ``y`` is what the checked takes gave to the last bit, in the program of
+    one branch and in the one of two, and the program of one branch holds no
+    fill: no NaN to fill with and no select over the (k t, D) block between
+    the gather and the weighted sum (whose own select is over (k, t, D))."""
+    from tpuserve.ops import moe
+
+    d, f = 32, 24
+    x, w, e, w_in, w_out, live = _layer(5, first, count, of or 16, k, t, dtype, body, masked, d, f)
+    fn = moe.swiglu if body == "swiglu" else moe.relu2
+    new = jax.jit(lambda *a: moe.held_experts(*a, first, w_in, w_out, fn, live=live, of=of)[0])
+    old = jax.jit(lambda *a: _held_experts_pr60(*a, first, w_in, w_out, fn, live=live, of=of))
+    got = new(x, w, e)
+    assert got.dtype == jnp.float32 and float(np.abs(np.asarray(got)).max()) > 0
+    assert np.array_equal(_bits(got), _bits(old(x, w, e)))
+    text, parents = new.lower(x, w, e).as_text(), old.lower(x, w, e).as_text()
+    assert ("stablehlo.case" in text or "stablehlo.if" in text) == (branches == 2)
+    assert _fills(text, k * t, d) == (0, 0)
+    # the lever moves something: x's and the way back's (and x's again in `compact`, of fewer rows)
+    assert _fills(parents, k * t, d) == (branches + 1, 2)
+    assert text.count('"stablehlo.gather"(') == parents.count('"stablehlo.gather"(') == 2 * branches
 
 
 @pytest.mark.parametrize("picks,count,of,rows", [

@@ -142,6 +142,14 @@ class SwitchFFN(nn.Module):
 # through a grouped product (``_grouped_dot``) in groups of whatever size the
 # router gave.
 #
+# Three scopes name the layer's operations in a device trace: ``moe_route``
+# (``topk_route``: scores, the k largest, their weights), ``moe_dispatch``
+# (what carries rows to the products and back, and the weighted sum) and
+# ``moe_experts`` (the grouped products and the body); ``moe_zero`` where there
+# are zero-compute picks. The layer takes nothing by a gather of scalars and
+# checks no index it made itself (ISSUE 61): the picks' weights are read by
+# comparison, and both takes of rows are the gather alone.
+#
 # Two branches bring the sorted picks through the products (ISSUE 37), chosen
 # on the device by the launch's own count of held picks. ``wide`` carries all
 # ``t * k`` picks, held or not, as this layer always did. ``compact`` carries
@@ -186,17 +194,27 @@ def topk_route(logits: jax.Array, k: int, *, normalize: bool = True,
     alone: the bias moves picks, never weights."""
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"scoring {scoring!r}: softmax or sigmoid")
-    x = logits.astype(jnp.float32)
-    p = jax.nn.softmax(x, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(x)
-    if select_bias is None:
-        w, e = jax.lax.top_k(p, k)
-    else:
-        _, e = jax.lax.top_k(p + select_bias.astype(jnp.float32), k)
-        w = jnp.take_along_axis(p, e, axis=-1)
-    if normalize:
-        total = jnp.sum(w, axis=-1, keepdims=True)
-        w = w / (total + jnp.float32(eps) if eps else total)
-    return w * jnp.float32(scale), e.astype(jnp.int32)
+    with jax.named_scope("moe_route"):
+        x = logits.astype(jnp.float32)
+        p = jax.nn.softmax(x, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(x)
+        if select_bias is None:
+            w, e = jax.lax.top_k(p, k)
+        else:
+            _, e = jax.lax.top_k(p + select_bias.astype(jnp.float32), k)
+            # The picks' scores by comparison, not by a gather of scalars: the
+            # chip takes those one after another (16 ns each: 1.77 ms of
+            # Nemotron's launch, my chip runs, PR 37). ONE entry of a row is at
+            # a pick, so the maximum over the row of (the score there, nothing
+            # elsewhere) is ``p[t, e[t, j]]`` to the bit. A maximum and not a
+            # sum of (the score there, zero elsewhere), which is as exact alone:
+            # XLA merges that sum over E with the sum over k below into one
+            # over both, whose order moved a total's last bit (ISSUE 61).
+            at = e[..., None] == jnp.arange(p.shape[-1], dtype=e.dtype)
+            w = jnp.max(jnp.where(at, p[..., None, :], -jnp.inf), axis=-1)
+        if normalize:
+            total = jnp.sum(w, axis=-1, keepdims=True)
+            w = w / (total + jnp.float32(eps) if eps else total)
+        return w * jnp.float32(scale), e.astype(jnp.int32)
 
 
 def _tile(n: int) -> int:
@@ -388,19 +406,23 @@ def held_experts(x: jax.Array, weights: jax.Array, experts: jax.Array,
                         dtype=jnp.int32)
         n_held = jnp.sum(held_live, dtype=jnp.int32)
 
-    def rows_of(picks, mode=None):
+    def rows_of(picks):
         """The sorted picks ``picks`` (a prefix of ``order``) through the
-        experts, then every pick's row in pick order: (k * T, D) float32
-        (``mode``: what ``take`` does with a pick past the rows carried)."""
+        experts, then every pick's row in pick order: (k * T, D) float32.
+        Neither take checks an index this function made (``clip`` lowers to
+        the gather alone; jax's own ``fill`` adds a bounds compare and a select
+        over the whole block): ``picks % t`` lies in [0, T) and the way back is
+        a permutation of [0, k * T), of which ``compact`` carries the first
+        rows and a pick past them reads the last."""
         with jax.named_scope("moe_dispatch"):
-            xs = jnp.take(x, picks % t, axis=0)
+            xs = jnp.take(x, picks % t, axis=0, mode="clip")
         with jax.named_scope("moe_experts"):
             dot = _grouped_dot(picks.shape[0], x.dtype, w_in[0].shape, w_out.shape,
                                expects=k * t / (of or count))
             h = body(*(dot(xs, w, sizes) for w in w_in)).astype(x.dtype)
             out = dot(h, w_out, sizes)
         with jax.named_scope("moe_dispatch"):
-            return jnp.take(out, jnp.argsort(order), axis=0, mode=mode)
+            return jnp.take(out, jnp.argsort(order), axis=0, mode="clip")
 
     # Rows past the groups' sum hold whatever the product left there (seen on
     # the chip: neither implementation zeroes them), and a pick that
@@ -408,8 +430,7 @@ def held_experts(x: jax.Array, weights: jax.Array, experts: jax.Array,
     # below, not multiplied by zero.
     if bound < k * t:
         fits = n_held <= bound
-        out = jax.lax.cond(fits, lambda: rows_of(order[:bound], mode="clip"),
-                           lambda: rows_of(order))
+        out = jax.lax.cond(fits, lambda: rows_of(order[:bound]), lambda: rows_of(order))
     else:
         fits = jnp.bool_(False)
         out = rows_of(order)
