@@ -1,7 +1,7 @@
 """What ``acc`` holds is stated once a family (``paged_lm.Column``, ISSUE 45):
 the state's width, the row a launch adds, the counters ``bind_metrics`` binds
 and what ``observe_step`` feeds all follow the family's ``COLUMNS``. Here, for
-each of the ten generating families at its toy size: one prefill launch and
+each of the eleven generating families at its toy size: one prefill launch and
 one step on the CPU, then the device's sums into a registry. The names below
 are the series as they have been served since each family came (the benchmark's
 readers find them by these letters), written down apart from the code."""
@@ -43,6 +43,11 @@ SERIES = {
     "mla_sc": MLA + ["moe_routed_zero_total{model=M,phase=PH}"],
     "mla_hc": MLA + ["hc_maps_total{model=M,phase=PH,path=kernel}",
                      "hc_maps_total{model=M,phase=PH,path=xla}"],
+    "mla_sel": MLA + ["sel_pairs_scored_total{model=M,phase=PH}",
+                      "sel_pairs_kept_total{model=M,phase=PH}",
+                      "sel_rows_walked_total{model=M,phase=PH}",
+                      "sel_queries_total{model=M,phase=PH,path=dense}",
+                      "sel_queries_total{model=M,phase=PH,path=picked}"],
     "decoder_sink": EXPERTS + CONTEXT + COMPACT + SAMPLE + [
         "attn_rows_attended_total{model=M,phase=PH}", "attn_rows_walked_total{model=M,phase=PH}",
         "attn_walks_total{model=M,phase=PH,walk=kernel}",

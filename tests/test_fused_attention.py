@@ -540,6 +540,84 @@ def test_a_steps_walk_is_one_kernel_call_an_attention_on_the_v5e_at_the_cells_wi
                 if " copy(" in ln and (f"{pages},128,512" in ln or f"{pages},64,128" in ln)]
 
 
+def test_attention_over_picks_is_kernel_calls_on_the_v5e_at_the_cells_widths(
+        one_chip, tmp_path, monkeypatch):
+    """The `mla_sel` family's walks (ISSUE 62) at its cell's sizes: 128 heads in
+    tiles and key blocks of 1,024, 64 index heads of 128 over a third leaf of
+    128 values a token, 16 lanes, block tables of 258 pages. A tile past
+    `index_topk`: `tile_scores` (the index keys' pages read in place through the
+    block table, the walk's length a traced grid bound) and `tile_walk` under
+    the rows' picks, beside the plain `tile_walk` of a tile under it, a `cond`
+    between them; a step: `lane_scores` and `lane_walk` under the lanes' picks
+    by ONE work list, beside the plain walk. Mosaic takes all four; nothing
+    float32 by index head, row and key (a block's products before the ReLU) is
+    in either program, and no copy of a pool."""
+    import json
+
+    from tpuserve.config import ModelConfig
+    from tpuserve.models import build
+
+    heads, tile, pps, pages, lanes = 128, 1024, 258, 4224, 16
+    arch = {"vocab_size": 256, "hidden_size": 1024, "num_attention_heads": heads,
+            "q_lora_rank": 256, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+            "qk_rope_head_dim": 64, "v_head_dim": 128, "num_hidden_layers": 1,
+            "intermediate_size": 256, "first_k_dense_replace": 1, "index_n_heads": 64,
+            "index_head_dim": 128, "index_topk": 2048}
+    path = tmp_path / "arch.json"
+    path.write_text(json.dumps(arch))
+    model = build(ModelConfig(name="walk", family="mla_sel", dtype="bfloat16", batch_buckets=[1],
+                              options={"config_file": str(path), "max_prompt_tokens": 32768,
+                                       "max_new_tokens": 256}))
+    assert model.TILE_ROWS == tile and model._form(tile) == "expanded" \
+        and model.kv_pages_per_slot(128) == pps and model._block_pages(128, pps) == 8
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    K = 2
+    lp = {"w_kb": shape(512, heads, 128), "w_vb": shape(512, heads, 128)}
+    pools = (shape(pages, 128, 512), shape(pages, 64, 128), shape(pages, 128, 128))
+
+    def tiles(lp, qn, qr, qi, wi, ckv, kr, ik, rows, qpos, last):
+        t = {"K": K, "T": tile, "rows": rows, "qpos": qpos, "last": last}
+        return model._attend_tiles(lp, qn, qr, (ckv, kr), t, "expanded", (qi, wi, ik))
+
+    def step(lp, qn, qr, qi, wi, ckv, kr, ik, bt, pos):
+        walk, work, _ = model._step_walk((ckv, kr), bt, pos)
+        assert walk == "kernel"
+        m = {"walk": walk, "work": work, "bt": bt, "last": pos, "pos": pos, "form": "absorbed"}
+        return model._attend_lanes(lp, qn, qr, qi, wi, (ckv, kr), ik, m)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
+    try:
+        launch = jax.jit(tiles).lower(
+            lp, shape(K * tile, heads, 128), shape(K * tile, heads, 64),
+            shape(K * tile, 64, 128), shape(K * tile, 64, dtype=jnp.float32), *pools,
+            shape(K, pps, dtype=jnp.int32), shape(K, tile, dtype=jnp.int32),
+            shape(K, dtype=jnp.int32)).compile().as_text()
+        a_step = jax.jit(step).lower(
+            lp, shape(lanes, heads, 128), shape(lanes, heads, 64), shape(lanes, 64, 128),
+            shape(lanes, 64, dtype=jnp.float32), *pools, shape(lanes, pps, dtype=jnp.int32),
+            shape(lanes, dtype=jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+
+    def calls(text):
+        return sorted(name for ln in text.split("\n")
+                      if " custom-call(" in ln and "tpu_custom_call" in ln
+                      for name in ("tile_scores", "tile_walk", "lane_scores", "lane_walk")
+                      if name in ln)
+
+    assert calls(launch) == ["tile_scores"] * K + ["tile_walk"] * 2 * K
+    assert calls(a_step) == ["lane_scores", "lane_walk", "lane_walk"]
+    for text in (launch, a_step):
+        for products in (f"f32[64,{tile},1024]", f"f32[{tile},64,1024]", "f32[16,64,1024]"):
+            assert products not in text
+        assert not [ln for ln in text.split("\n") if " copy(" in ln and any(
+            f"{pages},{rows}" in ln for rows in ("128,512", "64,128", "128,128"))]
+
+
 def _sink_cell(tmp_path, one_chip):
     """`decoder_sink` at the cell's heads on a hidden size of 1,024 and the
     cell's lanes, pages and rings, as shapes on the described chip."""

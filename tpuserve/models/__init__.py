@@ -67,6 +67,11 @@ Families (BASELINE.json ``configs``):
                    and a rotary embedding, with dense SwiGLUs in the leading
                    layers and sigmoid-routed experts with no shared one in the
                    rest, all held (ISSUE 59)
+- mla_sel        — ``mla``'s sibling whose attention runs over the positions a
+                   learned indexer picks (an index key a token in a third page
+                   leaf, the exact ``index_topk`` largest scores a query), with
+                   group-limited routed experts and a share of each layer
+                   (ISSUE 62)
 - toy            — a linear classifier for tests and drills
 """
 
@@ -95,6 +100,7 @@ _REGISTRY: dict[str, str] = {
     "hybrid_delta": "tpuserve.models.hybrid_delta",
     "eva": "tpuserve.models.eva",
     "hybrid_conv": "tpuserve.models.hybrid_conv",
+    "mla_sel": "tpuserve.models.mla_sel",
     "toy": "tpuserve.models.toy",
 }
 

@@ -158,14 +158,16 @@ class LatentServing(PagedLM):
     # block, and anything from there up reads alike (PERF.md section 6, PR 44).
     step_keys = KEY_BLOCK
     kv_page_leaves = cache_leaves = ("ckv", "kr")
+    # The one value the family takes of a key that names a mechanism; any other is refused.
+    TAKES = (("attention_bias", False), ("n_group", 1), ("topk_group", 1),
+             ("scoring_func", "sigmoid"), ("topk_method", "noaux_tc"),
+             ("hidden_act", "silu"), ("moe_layer_freq", 1), ("share", None))
 
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)
         a = read_config_file(cfg)
         self.dtype = jnp.dtype(cfg.dtype)
-        for key, want in (("attention_bias", False), ("n_group", 1), ("topk_group", 1),
-                          ("scoring_func", "sigmoid"), ("topk_method", "noaux_tc"),
-                          ("hidden_act", "silu"), ("moe_layer_freq", 1), ("share", None)):
+        for key, want in self.TAKES:
             if a.get(key, want) != want:
                 raise NotImplementedError(f"{cfg.name}: {key} = {a[key]!r}")
         if not a.get("q_lora_rank"):
@@ -217,6 +219,7 @@ class LatentServing(PagedLM):
     # Factors on the two latents after their norms (a config that scales them
     # says so; 1: none, and the program is the one without them).
     q_scale = kv_scale = 1.0
+    groups = None   # (n_group, topk_group) where the picks are group-limited (``mla_sel``)
 
     def _attentions(self):
         """The path of every attention's tensors: one a layer, beside the
@@ -270,14 +273,19 @@ class LatentServing(PagedLM):
                     yield whole((L, name), (d, f), "ffn_in", d)
                 yield whole((L, "w_down"), (f, d), "ffn_out", f)
                 continue
-            e, f, fs = self.n_experts, self.expert_width, self.shared_width
-            yield whole((L, "router"), (d, e), "router", d)
-            for name in ("e_gate", "e_up"):
-                yield whole((L, name), (e, d, f), "ffn_in", d)
-            yield whole((L, "e_down"), (e, f, d), "expert_out", f)
+            fs = self.shared_width
+            yield whole((L, "router"), (d, self.n_experts), "router", d)
+            yield from self._expert_tensors(L, whole)
             for name in ("s_gate", "s_up"):
                 yield whole((L, name), (d, fs), "ffn_in", d)
             yield whole((L, "s_down"), (fs, d), "ffn_out", fs)
+
+    def _expert_tensors(self, L: str, whole):
+        """Layer ``L``'s routed experts: every one held, three tensors a layer."""
+        d, e, f = self.d, self.n_experts, self.expert_width
+        for name in ("e_gate", "e_up"):
+            yield whole((L, name), (e, d, f), "ffn_in", d)
+        yield whole((L, "e_down"), (e, f, d), "expert_out", f)
 
     def _vectors(self):
         """The router's selection bias: small, about 0, so that it changes
@@ -321,17 +329,22 @@ class LatentServing(PagedLM):
         itself where the factor is 1."""
         return gain if factor == 1.0 else gain.astype(jnp.float32) * jnp.float32(factor)
 
-    def _project(self, lp: dict, u: jax.Array, pos: jax.Array):
+    def _query_latent(self, lp: dict, u: jax.Array) -> jax.Array:
+        """``c_q`` (T, q_lora_rank), the query's normed latent."""
+        return rms_norm(_mm(u, lp["w_qa"]).astype(self.dtype),
+                        self._gain(lp["q_norm"], self.q_scale), self.eps)
+
+    def _project(self, lp: dict, u: jax.Array, pos: jax.Array, c_q: jax.Array | None = None):
         """``u`` (T, d) normed stream at positions ``pos`` (T,) -> q_nope (T, H,
         nope), rotated q_rope (T, H, rope), and what a token keeps: the normed
         ``c_kv`` (T, r) and the rotated ``k_r`` (T, rope). ``q_scale`` (on the
         query's latent: ``W_qb`` is linear, so both parts of ``q`` carry it)
         and ``kv_scale`` (on ``c_kv`` before ``W_kvb``, so the CACHED row
-        carries it and ``k_r`` does not) ride on the two norms' gains."""
+        carries it and ``k_r`` does not) ride on the two norms' gains. ``c_q``:
+        the query's latent where the caller has it already."""
         dt = self.dtype
         inv, factor, dim = self.rope
-        c_q = rms_norm(_mm(u, lp["w_qa"]).astype(dt), self._gain(lp["q_norm"], self.q_scale),
-                       self.eps)
+        c_q = self._query_latent(lp, u) if c_q is None else c_q
         q = jnp.einsum("tq,qhk->thk", c_q, lp["w_qb"],
                        preferred_element_type=jnp.float32).astype(dt)
         kva = _mm(u, lp["w_kva"]).astype(dt)
@@ -377,14 +390,16 @@ class LatentServing(PagedLM):
             fits = T == 1 and la.fits(P, self.r, kr.shape[2], self.dtype)
         return "kernel" if fits else "xla"
 
-    def _attend_tile(self, lp: dict, qn, qr, pools, row, qpos, last, form: str):
+    def _attend_tile(self, lp: dict, qn, qr, pools, row, qpos, last, form: str, keep=None):
         """One tile's attention: q_nope ``qn`` (T, H, nope) and rotated q_rope
         ``qr`` (T, H, rope) at positions ``qpos`` (T,), over the latent rows of
         its prompt's pages (``pools``: the layer's ``ckv`` and ``kr``;
         block-table row ``row``) up to position ``last``, in the ``form``
         given -> (T, H, v): float32 from the walk in XLA, the served type from
         the kernel (``_attn_out`` rounds to it either way). Every row of the
-        launch is in the pages before any tile reads them."""
+        launch is in the pages before any tile reads them. ``keep`` (T, key
+        blocks x c) float32, or None: a row attends a key only where it is
+        above 0 (attention over picks, ``mla_sel``)."""
         dt, r, h = self.dtype, self.r, self.heads
         ckv, kr = pools
         T, P = qn.shape[0], ckv.shape[1]
@@ -409,11 +424,13 @@ class LatentServing(PagedLM):
             q = jnp.concatenate([qn] + [qr] * (P // kr.shape[1]), axis=-1).transpose(1, 0, 2)
             w_kvb = jnp.concatenate([lp["w_kb"], lp["w_vb"]], axis=-1).transpose(1, 0, 2)
             return ta.tile_walk(q, w_kvb, ckv, kr, rowp, need, qpos[0], block_pages=kb,
-                                scale=scale)
+                                scale=scale, **({} if keep is None else {"keep": keep}))
 
         def block(j):
             c_kv, k_r = latents(j)
             see = (j * c + jnp.arange(c))[None, :] <= qpos[:, None]
+            if keep is not None:
+                see = see & (jax.lax.dynamic_slice(keep, (0, j * c), (T, c)) > 0)
             s = jnp.einsum("thd,cd->htc", qr, k_r, **f32)
             if form == "absorbed":
                 s = s + jnp.einsum("thr,cr->htc", qn, c_kv, **f32)
@@ -457,17 +474,18 @@ class LatentServing(PagedLM):
             return walk, work, work["items"] * kb * P
         return walk, None, jnp.sum(self._blocks_needed(last, P, pps)) * self._block_pages(P, pps) * P
 
-    def _walk_lanes(self, lp: dict, qn, qr, pools, work: dict):
+    def _walk_lanes(self, lp: dict, qn, qr, pools, work: dict, keep=None):
         """A step's attention in ONE kernel call (``ops/lane_attention.py``),
         absorbed: q_nope ``qn`` (B, H, nope) and rotated q_rope ``qr`` (B, H,
         rope), every lane over its own key blocks by the step's work list ->
         (B, H, v) float32. The two whole-batch products around the call stay
-        XLA's."""
+        XLA's. ``keep`` (B, key blocks x c) float32, or None: ``_attend_tile``'s."""
         ckv, kr = pools
         f32 = {"preferred_element_type": jnp.float32}
         q_lat = jnp.einsum("bhn,rhn->bhr", qn, lp["w_kb"], **f32).astype(self.dtype)
         o = la.lane_walk(q_lat, jnp.concatenate([qr] * (ckv.shape[1] // kr.shape[1]), axis=-1),
-                         ckv, kr, work, scale=self.score_scale)
+                         ckv, kr, work, scale=self.score_scale,
+                         **({} if keep is None else {"keep": keep}))
         return jnp.einsum("bhr,rhv->bhv", o, lp["w_vb"], **f32)
 
     def _attn_out(self, lp, o):
@@ -481,7 +499,8 @@ class LatentServing(PagedLM):
         r = jnp.matmul(u.astype(jnp.float32), lp["router"].astype(jnp.float32),
                        precision=jax.lax.Precision.HIGHEST)
         w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
-                          scoring="sigmoid", select_bias=lp["e_bias"])
+                          scoring="sigmoid", select_bias=lp["e_bias"],
+                          **({"groups": self.groups} if self.groups else {}))
         y, stats = held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"], lp["e_up"],
                                        lp["e_down"], live=live, of=self.n_experts)
         return y + self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"]), stats

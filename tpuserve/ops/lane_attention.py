@@ -150,8 +150,9 @@ def work_list(last: jax.Array, bt: jax.Array, page: int, block_pages: int) -> di
 
 
 def _kernel(lane_ref, block_ref, pages_ref, last_ref, ql_ref, qr_ref, *refs, scale: float,
-            kb: int, g: int):
+            kb: int, g: int, picked: bool = False):
     del pages_ref   # the index maps read it
+    keep_ref, refs = (refs[0], refs[1:]) if picked else (None, refs)
     ckv_refs, kr_refs, o_ref = refs[:kb], refs[kb:2 * kb], refs[2 * kb]
     m_ref, l_ref, acc_ref, kx_ref = refs[2 * kb + 1:]
     P, dt = ckv_refs[0].shape[0], ql_ref.dtype
@@ -181,7 +182,10 @@ def _kernel(lane_ref, block_ref, pages_ref, last_ref, ql_ref, qr_ref, *refs, sca
         s.append(jax.lax.dot_general(q_lat, ckv_refs[i][...], nt, **f32)
                  + jax.lax.dot_general(q_rope, k_r, nt, **f32))
     s = jnp.concatenate(s, axis=1) if kb > 1 else s[0]
-    s = jnp.where(j * (kb * P) + iota(s.shape, 1) <= last, s * scale, NEG)
+    see = j * (kb * P) + iota(s.shape, 1) <= last
+    if picked:   # and the lane picked the key
+        see = see & (keep_ref[...] > 0)
+    s = jnp.where(see, s * scale, NEG)
     m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
@@ -205,7 +209,15 @@ def fits(page: int, r: int, kr_lanes: int, dtype) -> bool:
 
 
 def lane_walk(q_lat: jax.Array, q_rope: jax.Array, ckv: jax.Array, kr: jax.Array, work: dict, *,
-              scale: float, interpret: bool = False) -> jax.Array:
+              scale: float, keep: jax.Array | None = None,
+              interpret: bool = False) -> jax.Array:
+    """``keep`` (B, key blocks x block_pages x P) float32, or None: ATTENTION
+    OVER PICKS (ISSUE 62). A lane attends key ``s`` only where ``keep[lane, s] >
+    0`` (and ``s <= last``): a cell reads its item's columns of the lane's row
+    beside the pages. A lane whose keys so far are all left out carries weights
+    of one until its first kept key, which scales them away (every lane keeps
+    one somewhere). An operand only where there is one: without it the kernel
+    is what it was."""
     b, h, r = q_lat.shape
     n_pages, P = ckv.shape[:2]
     lanes = kr.shape[2]
@@ -219,11 +231,16 @@ def lane_walk(q_lat: jax.Array, q_rope: jax.Array, ckv: jax.Array, kr: jax.Array
     # the float32 values of a block's scores and of its context
     vmem = 2 * item * (h * (2 * r + lanes) + kb * P * (r + lanes)) \
         + 4 * h * (r + 256) + 4 * (3 * h * kb * P + 2 * h * r + P * lanes)
+    picked = keep is not None
+    if picked:   # a lane's row as (1, columns): the block's rows are the whole dimension
+        keep = keep.reshape(b, 1, -1)
     return pl.pallas_call(
-        functools.partial(_kernel, scale=scale, kb=kb, g=g),
+        functools.partial(_kernel, scale=scale, kb=kb, g=g, **({"picked": True} if picked else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(work["items"],),
             in_specs=[pl.BlockSpec((None, h, r), by_lane), pl.BlockSpec((None, h, lanes), by_lane)]
+            + [pl.BlockSpec((None, 1, kb * P),
+                            lambda n, lane, block, pages, last: (lane[n], 0, block[n]))] * picked
             + [pl.BlockSpec((None, P, r), page(i)) for i in range(kb)]
             + [pl.BlockSpec((None, P // g, lanes), page(i)) for i in range(kb)],
             out_specs=pl.BlockSpec((None, h, r), by_lane),
@@ -234,8 +251,8 @@ def lane_walk(q_lat: jax.Array, q_rope: jax.Array, ckv: jax.Array, kr: jax.Array
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=min(vmem + (16 << 20), 100 << 20)),
         interpret=interpret, name="lane_walk",
-    )(work["lane"], work["block"], pages, work["last"], q_lat, q_rope, *([ckv] * kb),
-      *([kr] * kb))
+    )(work["lane"], work["block"], pages, work["last"], q_lat, q_rope, *([keep] * picked),
+      *([ckv] * kb), *([kr] * kb))
 
 
 # -- attention by head: grouped queries, keys in two parts or one, values of their own -----

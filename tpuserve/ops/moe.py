@@ -181,26 +181,61 @@ class SwitchFFN(nn.Module):
 COMPACT_SLACK = 1.25
 
 
+def _kept_groups(by: jax.Array, n_group: int, topk_group: int) -> jax.Array:
+    """``by`` (T, E), what the picks go by -> ``by`` with every entry outside
+    the token's ``topk_group`` best of ``n_group`` equal groups of neighbouring
+    outputs at -inf. A group's score is the sum of its TWO largest entries,
+    both by maxima (the second: the largest with the first's place left out,
+    so two equal entries count twice); a group stays where fewer than
+    ``topk_group`` groups beat it (a higher score, or the same at a lower
+    number: ``top_k``'s order), counted by comparison over the n_group^2
+    pairs. No sort and no gather."""
+    t, e = by.shape
+    g = by.reshape(t, n_group, e // n_group)
+    first = jnp.max(g, axis=-1, keepdims=True)
+    place = jnp.arange(e // n_group, dtype=jnp.int32)
+    at = jnp.min(jnp.where(g == first, place, e), axis=-1, keepdims=True)
+    score = first[..., 0] + jnp.max(jnp.where(place == at, -jnp.inf, g), axis=-1)   # (T, G)
+    number = jnp.arange(n_group, dtype=jnp.int32)
+    beats = (score[:, None, :] > score[:, :, None]) \
+        | ((score[:, None, :] == score[:, :, None]) & (number[None, :] < number[:, None]))
+    kept = jnp.sum(beats, axis=-1, dtype=jnp.int32) < topk_group
+    return jnp.where(kept[..., None], g, -jnp.inf).reshape(t, e)
+
+
 def topk_route(logits: jax.Array, k: int, *, normalize: bool = True,
                scale: float = 1.0, scoring: str = "softmax",
-               select_bias: "jax.Array | None" = None, eps: float = 0.0
-               ) -> tuple[jax.Array, jax.Array]:
+               select_bias: "jax.Array | None" = None, eps: float = 0.0,
+               groups: "tuple[int, int] | None" = None) -> tuple[jax.Array, jax.Array]:
     """(T, E) router logits -> (weights (T, k) float32, experts (T, k)
     int32): scores over all E in float32 (``scoring``: a ``softmax``, or a
     ``sigmoid`` of each logit alone), the k largest, their weights over the
     k's own sum (plus ``eps``, where a model's published block adds one to
     the denominator) where ``normalize``, times ``scale``. With ``select_bias``
     (E,) the k are picked by score plus bias and weighted by the score
-    alone: the bias moves picks, never weights."""
+    alone: the bias moves picks, never weights. With ``groups`` = (n_group,
+    topk_group) the picks are GROUP-LIMITED (ISSUE 62): the outputs lie in
+    ``n_group`` equal groups of neighbours, a group's score is the sum of the
+    two largest of what the picks go by (score plus bias) in it, and the k are
+    the largest among the ``topk_group`` best groups' outputs
+    (``_kept_groups``). Without ``groups``: the program this function always
+    traced to."""
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"scoring {scoring!r}: softmax or sigmoid")
+    if groups is not None and (select_bias is None or logits.shape[-1] % groups[0]
+                               or k > groups[1] * (logits.shape[-1] // groups[0])):
+        raise ValueError(f"groups {groups!r} of {logits.shape[-1]} outputs for {k} picks, "
+                         "by score plus bias")
     with jax.named_scope("moe_route"):
         x = logits.astype(jnp.float32)
         p = jax.nn.softmax(x, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(x)
         if select_bias is None:
             w, e = jax.lax.top_k(p, k)
         else:
-            _, e = jax.lax.top_k(p + select_bias.astype(jnp.float32), k)
+            by = p + select_bias.astype(jnp.float32)
+            if groups is not None:
+                by = _kept_groups(by, *groups)
+            _, e = jax.lax.top_k(by, k)
             # The picks' scores by comparison, not by a gather of scalars: the
             # chip takes those one after another (16 ns each: 1.77 ms of
             # Nemotron's launch, my chip runs, PR 37). ONE entry of a row is at

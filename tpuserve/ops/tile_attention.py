@@ -63,8 +63,9 @@ HEAD_ROWS = 4096   # query rows (heads x tile rows) a cell holds
 
 
 def _kernel(pos0_ref, rows_ref, q_ref, w_ref, *refs, scale: float, kb: int, g: int, dn: int,
-            bq: int, bk: int):
+            bq: int, bk: int, picked: bool = False):
     del rows_ref   # the index maps read it
+    keep_ref, refs = (refs[0], refs[1:]) if picked else (None, refs)
     ckv_refs, kr_refs, o_ref = refs[:kb], refs[kb:2 * kb], refs[2 * kb]
     c_ref, k_ref, v_ref, m_ref, l_ref, acc_ref = refs[2 * kb + 1:]
     hb, t, _ = q_ref.shape
@@ -97,7 +98,10 @@ def _kernel(pos0_ref, rows_ref, q_ref, w_ref, *refs, scale: float, kb: int, g: i
         at_q, at_k = pl.ds(qi * bq, bq), pl.ds(ki * bk, bk)
         s = jax.lax.dot_general(q_ref[h, at_q, :], k_ref[at_k, :],
                                 (((1,), (1,)), ((), ())), **f32) * scale
-        s = jnp.where(ki * bk + iota((bq, bk), 1) <= qi * bq + iota((bq, bk), 0) + off, s, NEG)
+        see = ki * bk + iota((bq, bk), 1) <= qi * bq + iota((bq, bk), 0) + off
+        if picked:   # and the row picked the key
+            see = see & (keep_ref[at_q, at_k] > 0)
+        s = jnp.where(see, s, NEG)
         m_prev, l_prev = m_ref[h, at_q, :1], l_ref[h, at_q, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -145,7 +149,14 @@ def fits(t: int, page: int, block_pages: int, r: int, dn: int, dv: int, kr_lanes
 
 def tile_walk(q: jax.Array, w_kvb: jax.Array, ckv: jax.Array, kr: jax.Array, rows: jax.Array,
               need: jax.Array, pos0: jax.Array, *, block_pages: int, scale: float,
-              interpret: bool = False) -> jax.Array:
+              keep: jax.Array | None = None, interpret: bool = False) -> jax.Array:
+    """``keep`` (T, key blocks x c) float32, or None: ATTENTION OVER PICKS (ISSUE
+    62). Row ``i`` attends key ``s`` only where ``keep[i, s] > 0`` (and ``s <=
+    pos0 + i``): a cell reads its key block's (T, c) columns of it beside the
+    pages. A row whose keys so far are all left out carries weights of one
+    until its first kept key, which scales them away (every row keeps one
+    somewhere). An operand only where there is one: without it the kernel is
+    what it was."""
     h, t, dq = q.shape
     r, dkv = w_kvb.shape[1:]
     pages, P = ckv.shape[:2]
@@ -162,11 +173,16 @@ def tile_walk(q: jax.Array, w_kvb: jax.Array, ckv: jax.Array, kr: jax.Array, row
     # the float32 values of a head's expansion and of a sub-block's softmax
     vmem = 2 * item * (hb * t * (dq + dv) + hb * r * dkv + c * (r + lanes)) \
         + item * c * (r + dq + dv) + 4 * hb * t * (dv + 256) + 4 * (c * dkv + 4 * bq * bk)
+    picked = keep is not None
+    if picked:
+        vmem += 2 * 4 * t * c
     return pl.pallas_call(
-        functools.partial(_kernel, scale=scale, kb=kb, g=g, dn=dn, bq=bq, bk=bk),
+        functools.partial(_kernel, scale=scale, kb=kb, g=g, dn=dn, bq=bq, bk=bk,
+                          **({"picked": True} if picked else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(h // hb, need.astype(jnp.int32)),
             in_specs=[pl.BlockSpec((hb, t, dq), by_head), pl.BlockSpec((hb, r, dkv), by_head)]
+            + [pl.BlockSpec((t, c), lambda hi, j, pos0, rows: (0, j))] * picked
             + [pl.BlockSpec((None, P, r), page(i)) for i in range(kb)]
             + [pl.BlockSpec((None, P // g, lanes), page(i)) for i in range(kb)],
             out_specs=pl.BlockSpec((t, hb * dv), lambda hi, j, pos0, rows: (0, hi)),
@@ -180,5 +196,5 @@ def tile_walk(q: jax.Array, w_kvb: jax.Array, ckv: jax.Array, kr: jax.Array, row
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=min(vmem + (16 << 20), 100 << 20)),
         interpret=interpret, name="tile_walk",
-    )(jnp.reshape(pos0, (1,)).astype(jnp.int32), rows, q, w_kvb, *([ckv] * kb),
-      *([kr] * kb)).reshape(t, h, dv)
+    )(jnp.reshape(pos0, (1,)).astype(jnp.int32), rows, q, w_kvb, *([keep] * picked),
+      *([ckv] * kb), *([kr] * kb)).reshape(t, h, dv)
