@@ -1,20 +1,19 @@
 #!/usr/bin/env python
 """A decode step's EVA attention alone, on the chip (ISSUE 55): ONE layer's walk
 of every lane's virtual block table (its closed windows' summary pages, then its
-ring's pages) over a synthetic pool at the cell's shape (32 KV heads of 128, 24
-slots' rings of 16 pages and 160 summary pages of 128 rows), timed ON THE DEVICE
-(the program's own line in a trace, not the host's clock), the lanes' contexts
-drawn from the mix or all alike, in both walks the repo has:
-
-- `ops/lane_attention.py` `head_walk` with a key in ONE part (ISSUE 56; what
-  `tpuserve/models/eva.py` `_walk` calls) over the table as a flat work list of
-  the (lane, key block) items that exist, at several pages a block
-  (`EvaServing.walk_block` comes from here);
-- jax's `paged_attention` at several `pages_per_compute_block`, the yardstick:
-  what `_walk` called until ISSUE 56 (at 8 pages);
-
-beside (1) a plain pass over the pages the walk had to read and (2) the least
-time by `benchmark/flops/eva.py` `attend_decode` for one layer.
+ring's pages) over a synthetic pool at the cell's shape (24 slots' rings of 16
+pages and 160 summary pages of 128 rows, a row a position's 32 KV heads of 128
+side by side: the family's pools since ISSUE 63), timed ON THE DEVICE (the
+program's own line in a trace, not the host's clock), the lanes' contexts drawn
+from the mix or all alike: `ops/lane_attention.py` `head_walk` with a key in ONE
+part (ISSUE 56; what `tpuserve/models/eva.py` `_walk` calls, `kv=`) over the
+table as a flat work list of the (lane, key block) items that exist, at several
+pages a block (`EvaServing.walk_block` comes from here), beside (1) a plain pass
+over the pages the walk had to read and (2) the least time by
+`benchmark/flops/eva.py` `attend_decode` for one layer. (jax's `paged_attention`,
+the yardstick until ISSUE 63, reads pools by head, which the family has no more:
+3.07 / 1.88 / 1.34 / 1.04 ms at 1 / 2 / 4 / 8 pages a block where this walk took
+0.61, PERF.md, PR 56.)
 
     chiprun -- python scripts/bench_eva_walk.py
     python scripts/bench_eva_walk.py --rehearse
@@ -25,8 +24,7 @@ same pool, through the model's own plan and `_attend_tiles`, on both paths:
 
 - `ops/launch_attention.py` `launch_walk` over the launch's flat work list of the
   (tile, key page) items that exist (ring pages that hold the tile's window, the
-  launch's own rows laid as pages, summary pages), the transposes that lay the
-  own rows as pages included;
+  launch's own rows seen as pages, summary pages);
 - `_tile` in XLA a tile at a time (`lax.map`), the fallback and what a launch
   ran until ISSUE 58;
 
@@ -94,11 +92,11 @@ def launch_cases(sz: dict, flops, peaks, kp, vp, out_dir: str, iters: int, on_tp
     C = min(sz["prefill_chunk"], W)
     K = model.kv_prefill_pieces(C, P)
     T, (H, kv, hd) = C // K, (model.heads[0], model.kv, model.hd)
-    if kp.shape[-1] != hd:   # the rehearsal's toy heads: the interpreter takes any width
-        kp, vp = (jnp.asarray(rng.standard_normal(kp.shape[:3] + (hd,)), kp.dtype)
+    if kp.shape[-1] != kv * hd:   # the rehearsal's toy heads: the interpreter takes any width
+        kp, vp = (jnp.asarray(rng.standard_normal(kp.shape[:2] + (kv * hd,)), kp.dtype)
                   for _ in range(2))
     state = {"bt": jnp.asarray(1 + np.arange(slots * pps).reshape(slots, pps) % (
-        kp.shape[1] - c * (slots + 1) - 1), jnp.int32), "pos": jnp.zeros((slots,), jnp.int32),
+        kp.shape[0] - c * (slots + 1) - 1), jnp.int32), "pos": jnp.zeros((slots,), jnp.int32),
         "kf": [kp]}
     bt = np.asarray(state["bt"])
     # (slot, start, length) a piece: one full piece at an offset of its window with
@@ -131,7 +129,7 @@ def launch_cases(sz: dict, flops, peaks, kp, vp, out_dir: str, iters: int, on_tp
                 with steered(path, on_tpu):
                     m = model._prefill_plan(state, launch, model._tiles(launch, C))
                     assert m["tile_path"] == path
-                    o = model._attend_tiles(q, k, v, kp, vp, m)[0]
+                    o = model._attend_tiles(q, k, v, kp, vp, m)
                 return o, (m["work"]["items"] if m["work"] else 0)
             fn = jax.jit(attend)
             outs[path], items = fn(q, k, v, kp, vp, launch)
@@ -208,8 +206,8 @@ def main() -> int:
     dtype = jnp.bfloat16
     rng = np.random.default_rng(0)
     pool_pages = c * (slots + 1) + max(pages, slots * pps + 1)
-    kp = jnp.asarray(rng.standard_normal((kv, pool_pages, P, hd)), dtype)
-    vp = jnp.asarray(rng.standard_normal((kv, pool_pages, P, hd)), dtype)
+    kp = jnp.asarray(rng.standard_normal((pool_pages, P, kv * hd)), dtype)
+    vp = jnp.asarray(rng.standard_normal((pool_pages, P, kv * hd)), dtype)
     q = jnp.asarray(rng.standard_normal((slots, kv, hd)), dtype)
     mix = np.clip(np.exp(rng.normal(np.log(6144), 0.6, slots)), 1024, 24576).astype(int) \
         + rng.integers(0, 256, slots)
@@ -218,8 +216,6 @@ def main() -> int:
              "first-window": np.full(slots, W // 2)}
     if args.rehearse:
         cases = {"toy": rng.integers(1, sz["max_ctx"], slots)}
-    from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention
-
     lines = []
     if args.only != "step":
         lines += launch_cases(sz, flops, peaks, kp, vp, out_dir, args.iters, on_tpu, rng)
@@ -233,16 +229,10 @@ def main() -> int:
         for block in [int(b) for b in args.blocks.split(",")]:
             wide = -(-(pps + c) // block) * block
             table, seen = tables(pos, W, c, P, pps, slots, wide)
-            t, n = jnp.asarray(table), jnp.asarray(seen)
-            if on_tpu:
-                fn = jax.jit(lambda q, kp, vp, n, t, block=block: paged_attention(
-                    q, kp, vp, n, t, pages_per_compute_block=block))
-                ms = device_ms(fn, (q, kp, vp, n, t), None, out_dir, args.iters)
-                lines.append({**base, "walk": "paged_attention", "block_pages": block, "ms": ms})
-            # the same table as a flat work list of (lane, key block) items
-            work = la.work_list(jnp.asarray(seen - 1), t, P, block)
+            # the table as a flat work list of (lane, key block) items
+            work = la.work_list(jnp.asarray(seen - 1), jnp.asarray(table), P, block)
             fn = jax.jit(lambda q, kp, vp, work: la.head_walk(
-                q, None, kp, None, vp, work, scale=hd ** -0.5, interpret=not on_tpu))
+                q, None, kp, None, vp, work, scale=hd ** -0.5, kv=kv, interpret=not on_tpu))
             if on_tpu or block == 4:
                 ms = device_ms(fn, (q, kp, vp, work), None, out_dir, args.iters)
                 lines.append({**base, "walk": "head_walk (a key in one part)",
@@ -252,7 +242,8 @@ def main() -> int:
             need = np.unique(np.concatenate([row[:-(-int(r) // P)] for row, r in zip(
                 table, pos // W * P + pos % W + 1)]))
             idx = jnp.asarray(need)
-            fn = jax.jit(lambda kp, vp, idx: (jnp.take(kp, idx, axis=1) + 1, jnp.take(vp, idx, axis=1) + 1))
+            fn = jax.jit(lambda kp, vp, idx: (jnp.take(kp, idx, axis=0) + 1,
+                                              jnp.take(vp, idx, axis=0) + 1))
             lines.append({**base, "walk": "plain pass over the pages read (in and out)",
                           "pages": int(len(need)), "ms": device_ms(fn, (kp, vp, idx), None, out_dir,
                                                                    args.iters)})

@@ -11,13 +11,20 @@ interpreter: against `_tile` in XLA, a plain float32 softmax over each row's
 visible keys, and the work list against the masks); a lane that is
 not live keeping ring, pages and lanes to the bit; the cache's geometry and what
 `/stats` says of it; the weights recipe; the counters; and the two copies of
-the reference."""
+the reference. THE POOLS HOLD A POSITION AS ONE ROW, its KV heads side by side
+(ISSUE 63): the flat pools against the parent's by-head pools and by-head
+kernel (`tests/fixtures/eva_by_head_pr62.npz`), and the lowered programs'
+scatters counted."""
 
 from __future__ import annotations
 
 import contextlib
+import functools
+import hashlib
 import json
 import os
+import re
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +76,25 @@ def make_model(tmp_path, arch=ARCH, name="eva", dtype="float32", **options):
 
 def zeros(struct):
     return jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), struct)
+
+
+def side_by_side(pool):
+    """A pool by head (KV, pages, P, hd) -> the family's: a position one row,
+    its heads side by side, (pages, P, KV x hd)."""
+    kv, pages, P, hd = pool.shape
+    return pool.transpose(1, 2, 0, 3).reshape(pages, P, kv * hd)
+
+
+@pytest.fixture(scope="module")
+def by_head():
+    """What the PARENT's tree (a9b7e16: pools by head, `launch_walk` over blocks
+    by head) gave on this file's values: `pools/<leaf>/<layer>` the toy's pools
+    after the packed case's launches and six steps, laid side by side;
+    `launch/<kind>/<case>` its kernel's output in the interpreter on a launch
+    case's values (the toy's whole, the cell heads' by sha256)."""
+    with np.load(os.path.join(os.path.dirname(__file__), "fixtures",
+                              "eva_by_head_pr62.npz")) as f:
+        return dict(f)
 
 
 def serve(model, params, prompts, max_news, chunk=CHUNK, launches=None, slots=SLOTS, page=PAGE,
@@ -330,7 +356,8 @@ def test_the_walk_with_a_key_in_one_part_is_plain_float32_attention(cell_heads, 
     """`head_walk` with NO second key part (ISSUE 56) in the Pallas
     interpreter, at EVA's shapes (32 query rows on 32 KV heads: every row over
     each head's keys, a row keeping its own head's), over the step plan's own
-    virtual table and work list in a pool of rings and summary pages: against
+    virtual table and work list in a pool of rings and summary pages, a
+    position ONE row with its heads side by side (``kv=``, ISSUE 63): against
     a plain float32 softmax over each lane's rows taken from the pool one by
     one. bfloat16 products with float32 sums in the kernel and a context
     rounded to bfloat16: 2 ** -8 of values near 1."""
@@ -346,7 +373,7 @@ def test_the_walk_with_a_key_in_one_part_is_plain_float32_attention(cell_heads, 
     state = {"bt": jnp.asarray(rng.permutation(np.arange(1, 1 + slots * pps))
                                .reshape(slots, pps), jnp.int32),
              "ring": jnp.asarray(rng.permutation(np.arange(1, slots + 1)), jnp.int32),
-             "pos": jnp.zeros((slots,), jnp.int32), "kf": [kp]}
+             "pos": jnp.zeros((slots,), jnp.int32), "kf": [side_by_side(kp)]}
     monkeypatch.setattr(eva, "jax", NamedTpu())
     monkeypatch.setattr(model, "walk_block", block_pages)
     assert model._walks(P)                      # the cell's shapes fit the kernel as they are
@@ -354,8 +381,9 @@ def test_the_walk_with_a_key_in_one_part_is_plain_float32_attention(cell_heads, 
     rows = np.where(live, pos // W * P + pos % W + 1, 1)
     assert m["path"] == "head_walk" and list(np.asarray(m["rows_seen"])) == list(rows)
     assert int(m["work"]["items"]) == sum(-(-int(r) // (block_pages * P)) for r in rows)
-    got = np.asarray(la.head_walk(q, None, kp, None, vp, m["work"], scale=model._scale(),
-                                  interpret=True).astype(jnp.float32))
+    flat = side_by_side(kp), side_by_side(vp)   # the pools as the family keeps them
+    got = np.asarray(la.head_walk(q, None, flat[0], None, flat[1], m["work"], scale=model._scale(),
+                                  kv=model.kv, interpret=True).astype(jnp.float32))
     assert got.shape == (slots, model.kv, hd) and np.isfinite(got).all()   # a free lane's too
     table = np.asarray(m["table"])
     k32, v32, q32 = (np.asarray(x.astype(jnp.float32)) for x in (kp, vp, q))
@@ -367,8 +395,7 @@ def test_the_walk_with_a_key_in_one_part_is_plain_float32_attention(cell_heads, 
         np.testing.assert_allclose(got[b], want, atol=2e-2)
         assert np.abs(want).max() > 0.3
     # and the program's own gather of the same table says the same
-    xla = np.asarray(model._decode_gather(q, (kp, vp), m["table"], m["rows_seen"] - 1,
-                                          model._heads()))
+    xla = np.asarray(model._decode_gather(q, flat, m["table"], m["rows_seen"] - 1, model._heads()))
     np.testing.assert_allclose(got[live], xla[live], atol=2e-2)
 
 
@@ -409,29 +436,27 @@ LAUNCH_MODELS = {"toy-float32-grouped": (1, 1e-4), "cell-heads-bfloat16": (2, 2e
                  "cell-heads-tiles-of-two-pages": (2, 2e-2)}
 
 
-@pytest.mark.parametrize("kind", list(LAUNCH_MODELS))
-@pytest.mark.parametrize("case", list(LAUNCH_CASES))
-def test_a_launchs_tiles_in_one_kernel_call_are_the_tile_in_xla_and_plain_attention(
-        whole, cell_heads, case, kind):
-    """``launch_walk`` (ISSUE 58) in the Pallas interpreter over the launch's
-    own plan and work list, against ``_tile`` in XLA on the same plan and
-    against a plain float32 softmax over each live row's visible keys taken
-    from the pool and the launch one by one (its own window's positions below
-    its piece's start from the ring, the launch's own up to itself, every row
-    of its prompt's earlier windows' summary pages). And the work list: exactly
-    the pages that hold a key some row of the tile sees, none twice, the
-    sentinel for a tile of no piece. The toy in float32 with 2 query heads a KV
-    head; the cell's heads in bfloat16 (2 ** -8 of values near 1), a tile one
-    page and two."""
-    model = whole[0] if kind.startswith("toy") else cell_heads
+@pytest.fixture(scope="module")
+def launched(whole, cell_heads):
+    """(case, kind) -> ``launch_case``'s, made once for the tests that share it."""
+    return functools.cache(lambda case, kind: launch_case(
+        whole[0] if kind.startswith("toy") else cell_heads, case, kind))
+
+
+def launch_case(model, case: str, kind: str) -> dict:
+    """One launch of ``LAUNCH_CASES`` at a model of ``LAUNCH_MODELS``: the
+    values (the pools drawn by head, as the parent's tree drew them, and laid
+    side by side), the plan off the TPU and in the kernel, and the kernel's
+    output in the interpreter."""
     slots, (c, P, W), hd = 3, (model.chunk, model.rows, model.window), model.hd
     T = P * (2 if kind.endswith("two-pages") else 1)
-    K, tol = 4, LAUNCH_MODELS[kind][1]
-    C, pps, dtype = K * T, 4, model.dtype
+    K, pps, dtype = 4, 4, model.dtype
+    C = K * T
     pieces = [(slot, int(start * W), int(n * W)) for slot, start, n in LAUNCH_CASES[case]]
     rng = np.random.default_rng(len(case) + T)
     n_pages = c * (slots + 1) + slots * pps + 1
-    kp, vp = (jnp.asarray(rng.standard_normal((model.kv, n_pages, P, hd)), dtype) for _ in range(2))
+    kh, vh = (jnp.asarray(rng.standard_normal((model.kv, n_pages, P, hd)), dtype) for _ in range(2))
+    kp, vp = side_by_side(kh), side_by_side(vh)
     q = jnp.asarray(2.0 * rng.standard_normal((C, model.heads[0], hd)), dtype)
     k, v = (jnp.asarray(rng.standard_normal((C, model.kv, hd)), dtype) for _ in range(2))
     bt = rng.permutation(np.arange(1, 1 + slots * pps)).reshape(slots, pps).astype(np.int32)
@@ -446,10 +471,51 @@ def test_a_launchs_tiles_in_one_kernel_call_are_the_tile_in_xla_and_plain_attent
     plain = model._prefill_plan(state, launch, model._tiles(launch, C))
     with in_the_launch():
         m = model._prefill_plan(state, launch, model._tiles(launch, C))
-        got = np.asarray(model._attend_tiles(q, k, v, kp, vp, m)[0])
+        got = np.asarray(model._attend_tiles(q, k, v, kp, vp, m))
     assert (plain["tile_path"], plain["work"], m["tile_path"]) == ("xla", None, "tile_kernel")
-    xla = np.asarray(model._attend_tiles(q, k, v, kp, vp, plain)[0])
     assert got.shape == q.shape and got.dtype == np.float32 and np.isfinite(got).all()
+    return SimpleNamespace(model=model, T=T, K=K, slots=slots, pieces=pieces, bt=bt, rings=rings,
+                           q=q, k=k, v=v, kp=kp, vp=vp, plain=plain, m=m, got=got)
+
+
+@pytest.mark.parametrize("kind", list(LAUNCH_MODELS))
+@pytest.mark.parametrize("case", list(LAUNCH_CASES))
+def test_the_kernel_over_whole_rows_is_the_parents_kernel_by_head_to_the_bit(
+        launched, by_head, case, kind):
+    """``launch_walk`` over pools and own rows that hold a position as ONE row
+    (ISSUE 63: a page one block, a head's columns cut out of it) against what
+    the parent's kernel gave over the same values by head (a page a block of
+    heads): the work list, the masks, the running softmax and the order of
+    every sum are the parent's, so every bit is."""
+    got = launched(case, kind).got
+    want = by_head[f"launch/{kind}/{case.split(':')[0]}"]
+    if want.dtype == np.uint8:   # the cell heads' MiB a case: its digest
+        got = np.frombuffer(hashlib.sha256(got.tobytes()).digest(), np.uint8)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("kind", list(LAUNCH_MODELS))
+@pytest.mark.parametrize("case", list(LAUNCH_CASES))
+def test_a_launchs_tiles_in_one_kernel_call_are_the_tile_in_xla_and_plain_attention(
+        launched, case, kind):
+    """``launch_walk`` (ISSUE 58) in the Pallas interpreter over the launch's
+    own plan and work list, against ``_tile`` in XLA on the same plan and
+    against a plain float32 softmax over each live row's visible keys taken
+    from the pool and the launch one by one (its own window's positions below
+    its piece's start from the ring, the launch's own up to itself, every row
+    of its prompt's earlier windows' summary pages). And the work list: exactly
+    the pages that hold a key some row of the tile sees, none twice, the
+    sentinel for a tile of no piece. The toy in float32 with 2 query heads a KV
+    head; the cell's heads in bfloat16 (2 ** -8 of values near 1), a tile one
+    page and two."""
+    lc = launched(case, kind)
+    model, T, K, slots, pieces = lc.model, lc.T, lc.K, lc.slots, lc.pieces
+    bt, rings = lc.bt, lc.rings
+    q, k, v, m, got = lc.q, lc.k, lc.v, lc.m, lc.got
+    kp, vp = (pool.reshape(pool.shape[:2] + (model.kv, -1)).transpose(2, 0, 1, 3)
+              for pool in (lc.kp, lc.vp))        # by head: what the keys one by one are taken from
+    (c, P, W), hd, tol = (model.chunk, model.rows, model.window), model.hd, LAUNCH_MODELS[kind][1]
+    xla = np.asarray(model._attend_tiles(q, k, v, lc.kp, lc.vp, lc.plain))
 
     # each live row's visible keys, one by one: (operand, page, row) of the pools or the launch
     first, g = c * (slots + 1), model.heads[0] // model.kv
@@ -516,8 +582,8 @@ def test_a_lane_that_is_not_live_keeps_ring_pages_and_lanes_to_the_bit(whole):
     for leaf in ("kf", "vf"):
         for a, b in zip(state[leaf], again[leaf]):
             a, b = np.asarray(a), np.asarray(b)
-            np.testing.assert_array_equal(a[:, live_rings][:, :c * SLOTS], b[:, live_rings][:, :c * SLOTS])
-            np.testing.assert_array_equal(a[:, c * (SLOTS + 1) + 1:], b[:, c * (SLOTS + 1) + 1:])
+            np.testing.assert_array_equal(a[live_rings][:c * SLOTS], b[live_rings][:c * SLOTS])
+            np.testing.assert_array_equal(a[c * (SLOTS + 1) + 1:], b[c * (SLOTS + 1) + 1:])
     for leaf in ("pos", "n_new", "tokens", "lp", "last", "ring", "bt"):
         np.testing.assert_array_equal(np.asarray(state[leaf]), np.asarray(again[leaf]))
     # a shorter tenant in a slot whose ring and pages hold the last one's rows
@@ -527,6 +593,81 @@ def test_a_lane_that_is_not_live_keeps_ring_pages_and_lanes_to_the_bit(whole):
     for a, b in zip(reused, fresh):
         np.testing.assert_array_equal(a["tokens"][:6], b["tokens"][:6])
         np.testing.assert_array_equal(a["lp"][:6], b["lp"][:6])
+
+
+# -- a position is ONE row of a pool ------------------------------------------------------------
+
+PACKED = "packed: edges INSIDE a piece, pieces of three prompts in a launch"
+
+
+@pytest.fixture(scope="module")
+def packed_state(whole):
+    """The state after the packed case's launches (an edge inside a piece, a
+    padded tail) and six steps, of which positions 15, 39 and 3 end a chunk."""
+    model, params = whole
+    lengths, news, launches = CASES[PACKED]
+    return serve(model, params, prompts_of(lengths), news, launches=launches, steps=6)[2]
+
+
+@pytest.mark.parametrize("layer", range(ARCH["num_hidden_layers"]))
+@pytest.mark.parametrize("leaf", eva.EvaServing.cache_leaves)
+def test_the_pools_hold_the_parents_rows_by_head_side_by_side(packed_state, by_head, leaf, layer):
+    """Row for row what the parent's pools by head held after the same launches
+    and steps (rings written as slabs, summaries by a launch and by a step,
+    rows by a step), a position's heads laid side by side; the sentinels
+    apart, which lanes that are not live write in no stated order."""
+    got, want = np.asarray(packed_state[leaf][layer]), by_head[f"pools/{leaf}/{layer}"]
+    c = ARCH["chunk_size"]
+    first = c * (SLOTS + 1)
+    assert got.shape == want.shape == (first + SLOTS * 4 + 1, PAGE, 2 * 16)
+    written = np.r_[c:first, first + 1:got.shape[0]]
+    np.testing.assert_array_equal(got[written], want[written])
+    assert np.abs(want[written]).max() > 0.1 and np.any(want[first + 1:])
+
+
+def scatters(text: str) -> list:
+    """(operand, updates) dimensions of every scatter of a StableHLO text."""
+    dims = lambda t: tuple(int(n) for n in t.split("x")[:-1])  # noqa: E731
+    return [(dims(a), dims(u)) for a, u in re.findall(
+        r"\}\) : \(tensor<([^>]+)>, tensor<[^>]+>, tensor<([^>]+)>\) -> tensor", text)]
+
+
+@pytest.mark.parametrize("path", ["kernel", "xla"])
+@pytest.mark.parametrize("program", ["step", "launch"])
+def test_a_lowered_program_writes_a_token_as_one_row_and_moves_no_pool(whole, program, path):
+    """THE GUARD OF ISSUE 63's mechanism, in the lowered text of the toy's two
+    programs on either path: a layer writes each pool TWICE and no more (a
+    step: the ring's rows and the summaries', ``B`` whole rows each; a launch:
+    ``C / c`` summary rows, then the rings' ``C / P`` pages as slabs), every
+    update a position's ``KV x hd`` values in one piece, and no transpose
+    takes or gives anything of a pool's size (by head a token was ``KV``
+    pieces a scatter and a launch's rows were transposed to be laid as pages)."""
+    model, _ = whole
+    params = jax.eval_shape(lambda: model.init_params(jax.random.key(0)))
+    pps = model.kv_pages_per_slot(PAGE)
+    state = model.kv_page_signature(SLOTS, SLOTS * pps + 1, PAGE)
+    pages, P, row = state["kf"][0].shape
+    if program == "step":
+        with in_the_walk() if path == "kernel" else contextlib.nullcontext():
+            text = jax.jit(model.step).lower(params, state).as_text()
+        want = [((pages * P, row), (SLOTS, row))] * 4
+    else:
+        k = model.kv_prefill_pieces(CHUNK, PAGE)
+        launch = {"ids": (CHUNK,), "pages": (k, pps), "temp": (k,),
+                  **{f: (k,) for f in ("slot", "start", "length", "n", "seed", "max_new", "ring")}}
+        launch = {f: jax.ShapeDtypeStruct(dims, jnp.float32 if f == "temp" else jnp.int32)
+                  for f, dims in launch.items()}
+        with in_the_launch() if path == "kernel" else contextlib.nullcontext():
+            text = jax.jit(lambda p, s, ln: model.prefill_chunk(p, s, ln, chunk=CHUNK)).lower(
+                params, state, launch).as_text()
+        want = [((pages * P, row), (CHUNK // model.chunk, row))] * 2 \
+            + [((pages, P, row), (CHUNK // P, P, row))] * 2
+    of_pools = [s for s in scatters(text) if np.prod(s[0]) == pages * P * row]
+    assert of_pools == want * model.n_layers, of_pools
+    moved = [ln for ln in text.split("\n") if "stablehlo.transpose" in ln
+             and any(np.prod([int(n) for n in t.split("x")[:-1]] or [1]) >= pages * P * row
+                     for t in re.findall(r"tensor<([^>]+)>", ln))]
+    assert not moved, moved
 
 
 # -- geometry, /stats, the recipe ----------------------------------------------------------
@@ -544,7 +685,7 @@ def test_the_caches_geometry_a_page_stands_for_a_window(whole, tmp_path):
     assert [model.pages_needed(item(n, new), PAGE) for n, new in ((1, 1), (10, 6), (10, 7),
                                                                  (40, 24))] == [1, 1, 2, 4]
     sig = model.kv_page_signature(SLOTS, 9, PAGE)
-    assert [s.shape for s in sig["kf"]] == [(2, 4 * (SLOTS + 1) + 9, 4, 16)] * 2
+    assert [s.shape for s in sig["kf"]] == [(4 * (SLOTS + 1) + 9, 4, 2 * 16)] * 2   # a row: 2 heads
     assert sig["bt"].shape == (SLOTS, 4) and sig["ring"].shape == (SLOTS,)
     with pytest.raises(ValueError, match="kv_page_tokens"):
         model.kv_page_signature(SLOTS, 9, 8)

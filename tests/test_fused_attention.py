@@ -963,7 +963,9 @@ def test_evas_step_walks_rings_and_pages_in_place_on_the_v5e_at_the_cells_widths
     with no loop; and NO copy, transpose or gather of a whole pool exists in
     a step (a pool crossed to another layout once a layer a step when the
     chunk's rows were gathered from the pool seen flat: 5 ms each by the
-    compiler's own estimate), nor in a launch."""
+    compiler's own estimate), nor in a launch. The pools hold a position as ONE
+    row, its heads side by side (ISSUE 63), and a program scatters into each
+    pool twice a layer."""
     import json
 
     from tpuserve.config import ModelConfig
@@ -986,7 +988,7 @@ def test_evas_step_walks_rings_and_pages_in_place_on_the_v5e_at_the_cells_widths
     params = jax.tree_util.tree_map(place, jax.eval_shape(lambda: model.draw_params(0)))
     state = jax.tree_util.tree_map(place, model.kv_page_signature(slots, pages, P))
     k = model.kv_prefill_pieces(chunk, P)
-    assert (k, model.kv_pages_per_slot(P), state["kf"][0].shape) == (8, 13, (32, 560, 128, 128))
+    assert (k, model.kv_pages_per_slot(P), state["kf"][0].shape) == (8, 13, (560, 128, 4096))
     launch = {"ids": (chunk,), "pages": (k, 13), "temp": (k,),
               **{f: (k,) for f in ("slot", "start", "length", "n", "seed", "max_new", "ring")}}
     launch = {f: jax.ShapeDtypeStruct(dims, jnp.float32 if f == "temp" else jnp.int32,
@@ -1008,9 +1010,16 @@ def test_evas_step_walks_rings_and_pages_in_place_on_the_v5e_at_the_cells_widths
     assert len(calls) == 2 and all("eva_prefill" in ln and "launch_walk" in ln for ln in calls)
     scoped = "".join(ln for ln in fill.split("\n") if "eva_prefill" in ln)
     assert " while(" not in scoped and "dynamic-slice(" not in scoped and " gather(" not in scoped
-    whole = ("[32,560,128,128]", "[32,71680,128]", "[32,4480,16,128]")
+    whole = ("[560,128,4096]", "[71680,4096]", "[4480,16,4096]")
     for text in (step, fill):
         moved = [ln.split("=")[0] for ln in text.split("\n")
                  if any(f" {op}(" in ln for op in ("copy", "transpose", "gather"))
                  and any(f"bf16{dims}" in ln.split("=")[1].split("(")[0] for dims in whole)]
         assert not moved, moved
+    # a token is ONE row of a pool (ISSUE 63): a layer writes each pool twice, a step its
+    # ring's rows and its summaries', a launch its summaries and then its rings' pages as slabs
+    wrote = [[ln.split("=")[1].split("{")[0].strip() for ln in text.split("\n") if " scatter(" in ln
+              and any(f"bf16{dims}" in ln.split("=")[1].split("(")[0] for dims in whole)]
+             for text in (step, fill)]
+    assert wrote[0] == ["bf16[71680,4096]"] * 8, wrote[0]
+    assert sorted(wrote[1]) == ["bf16[560,128,4096]"] * 4 + ["bf16[71680,4096]"] * 4, wrote[1]

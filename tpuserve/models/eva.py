@@ -37,7 +37,9 @@ of its context, one PAGE of ``W / c`` summary rows (row ``(i % W) // c`` of page
 ``kv_page_tokens`` is ROWS a page, ``W / c`` of them, and a page stands for W
 positions (``kv_page_span``): ``pages_needed`` is ``ceil((prompt + new) / W)``.
 A summary row has a token's shape, so ring and pages lie in ONE pool a layer,
-``(KV, c x (slots + 1) + pages, W / c, hd)`` for K and for V: ring ``r`` is pages
+``(c x (slots + 1) + pages, W / c, KV x hd)`` for K and for V, a position ONE ROW
+with its KV heads side by side (ISSUE 63: a token is one row of each pool to
+write, where pools by head took ``KV`` pieces a token): ring ``r`` is pages
 ``c r .. c r + c - 1`` (ring 0 the rings' sentinel), the ledger's page ``p`` is
 pool page ``c (slots + 1) + p`` (its page 0 the summaries' sentinel).
 
@@ -47,8 +49,9 @@ followed by its ring's ``c`` pages, of virtual length ``(i // W) (W / c) + i % W
 + 1``. On the TPU in bfloat16 the repo's own ``ops/lane_attention.py``
 ``head_walk`` walks it (ISSUE 56): ONE flat work list a step of the (lane, key
 block) items that exist, shared by the layers, a cell an item for ALL heads
-over the pools as they lie, a key in one part, the scores scaled in float32
-inside the cell; elsewhere the gather of the padded table (``_decode_gather``).
+over the pools as they lie (a head's columns cut out of a page's rows on whole
+lane tiles: ``kv=``), a key in one part, the scores scaled in float32 inside the
+cell; elsewhere the gather of the padded table (``_decode_gather``).
 ``eva_decode_steps_total{path=head_walk|gather}`` says which.
 Where ``i % c == c - 1`` the step then pools the chunk's rows from the ring and
 writes the summary row (scope ``eva_summarise``); a lane whose chunk has not
@@ -62,13 +65,14 @@ the launch's own rows, both masked by WINDOW INDEX (``t // W == i // W and t <=
 i``), joined under one running softmax with the summary pages of every earlier
 window through the block table, a window the launch itself closes among them;
 the ring is written last, a tile's rows as whole pages of it (a tile is whole
-pages: one scatter of slabs, where a scatter of rows by head took 0.65 ms a
-pool on the chip). A launch is at most W rows, so it crosses one window's edge
-at most and writes no ring place twice. On the TPU in bfloat16 the attention is
+pages: one scatter of slabs, each a page's contiguous bytes). A launch is at
+most W rows, so it crosses one window's edge at most and writes no ring place
+twice. On the TPU in bfloat16 the attention is
 ONE call a layer of ``ops/launch_attention.py`` ``launch_walk`` (ISSUE 58) over
 ONE flat work list a launch of the (tile, key page) items that exist (a tile's
-ring pages that hold its window, the launch's own rows laid as pages, its
-summary pages), a cell an item for all heads, ring and pages read in place;
+ring pages that hold its window, the launch's own rows seen as pages (a view of
+the projections' output), its summary pages), a cell an item for all heads, ring
+and pages read in place;
 elsewhere a tile at a time in XLA (``_tile``).
 ``eva_prefill_tiles_total{path=tile_kernel|xla}`` says which.
 
@@ -213,7 +217,7 @@ class EvaServing(dec.DecoderServing):
             raise ValueError(f"{self.name}: [genserve] kv_page_tokens = {page_tokens}: a page is "
                              f"a window's summary rows, window_size / chunk_size = {self.rows}")
         S = jax.ShapeDtypeStruct
-        pool = S((self.kv, self.chunk * (slots + 1) + pages, self.rows, self.hd), self.dtype)
+        pool = S((self.chunk * (slots + 1) + pages, self.rows, self.kv * self.hd), self.dtype)
         return {"kf": [pool] * self.n_layers, "vf": [pool] * self.n_layers,
                 "ring": S((slots,), jnp.int32)}
 
@@ -327,9 +331,22 @@ class EvaServing(dec.DecoderServing):
         """A step's attention over the virtual table, in place: q (b, H, hd) ->
         (b, H, hd) float32."""
         if m["path"] == "head_walk":
-            return la.head_walk(q, None, kp, None, vp, m["work"],
-                                scale=self._scale()).astype(jnp.float32)
+            return la.head_walk(q, None, kp, None, vp, m["work"], scale=self._scale(),
+                                kv=self.kv).astype(jnp.float32)
         return self._decode_gather(q, (kp, vp), m["table"], m["rows_seen"] - 1, self._heads())
+
+    def _rows_by_head(self, rows):
+        """Rows with their heads side by side (..., KV x hd) -> (..., KV, hd)."""
+        return rows.reshape(rows.shape[:-1] + (self.kv, self.hd))
+
+    def _decode_gather(self, q, pools, bt, pos, heads):
+        """``paged_lm``'s, over pools of whole rows: every lane's padded table
+        gathered as flat pages, the heads split after."""
+        b, pps = bt.shape
+        kc, vc = (self._rows_by_head(jnp.take(pool, bt.reshape(-1), axis=0)
+                                     .reshape(b, pps * self.rows, -1)) for pool in pools)
+        mask = (jnp.arange(pps * self.rows)[None, :] <= pos[:, None])[:, None, :]
+        return self._attend(q[:, None], kc, vc, mask)[:, 0]
 
     def _tiles_fit(self, T: int) -> bool:
         """Shapes ``launch_walk`` takes."""
@@ -347,12 +364,16 @@ class EvaServing(dec.DecoderServing):
         c, P, W, g = self.chunk, self.rows, self.window, H // heads.kv
         qg = a["q"].reshape(T, heads.kv, g, hd)
         win = (a["qpos"] // W)[:, None]
+
+        def by_head(rows):   # rows as they lie, (..., KV x hd) or (n, KV, hd) -> (KV, n, hd)
+            return rows.reshape(-1, heads.kv, hd).transpose(1, 0, 2)
+
         # The ring's pages lie one after another in the pool: ONE slice of each pool
         # (as 16 pages taken one by one they were 8.7 ms of a launch on the chip).
-        rk, rv = (jax.lax.dynamic_slice_in_dim(pool, c * a["ring"], c, axis=1)
-                  .reshape(heads.kv, W, hd) for pool in (kp, vp))
-        ek = jnp.concatenate([rk, k.transpose(1, 0, 2)], axis=1)
-        ev = jnp.concatenate([rv, v.transpose(1, 0, 2)], axis=1)
+        rk, rv = (by_head(jax.lax.dynamic_slice_in_dim(pool, c * a["ring"], c, axis=0))
+                  for pool in (kp, vp))
+        ek = jnp.concatenate([rk, by_head(k)], axis=1)
+        ev = jnp.concatenate([rv, by_head(v)], axis=1)
         see = jnp.concatenate(
             [(a["rpos"] >= 0)[None, :] & (a["rpos"][None, :] // W == win),
              a["own"][None, :] & (kpos[None, :] // W == win)
@@ -375,7 +396,7 @@ class EvaServing(dec.DecoderServing):
         def body(j, carry):
             top, total, acc = carry
             pg = first + jax.lax.dynamic_slice(rowp, (j * kb,), (kb,))
-            sk, sv = self._key_block((kp, vp), pg, heads)                # (KV, kb x P, hd)
+            sk, sv = (by_head(jnp.take(pool, pg, axis=0)) for pool in (kp, vp))  # (KV, kb x P, hd)
             of = j * kb + jnp.arange(kb * P) // P                        # the window a row sums up
             s = jnp.where((of[None, :] < win)[None, None], scores(sk), NEG)
             top2 = jnp.maximum(top, jnp.max(s, axis=-1))
@@ -388,29 +409,31 @@ class EvaServing(dec.DecoderServing):
 
     def _attend_tiles(self, q, k, v, kp, vp, m: dict):
         """A launch's attention, every tile: q (C, H, hd), the launch's own
-        rows k, v (C, KV, hd), the pools -> (o as ``q`` lies, float32, and k, v
-        laid as pages by head, (KV, C / P, P, hd): what the kernel reads and
-        the rings are then written from)."""
+        rows k, v (C, KV, hd), the pools -> o as ``q`` lies, float32."""
         t = m["t"]
-        ko, vo = (rows.reshape((-1, self.rows) + rows.shape[1:]).transpose(2, 0, 1, 3)
-                  for rows in (k, v))
         if m["tile_path"] == "tile_kernel":
-            return lat.launch_walk(q, kp, vp, ko, vo, m["work"], scale=self._scale()), ko, vo
+            return lat.launch_walk(q, kp, vp, self._as_pages(k), self._as_pages(v), m["work"],
+                                   scale=self._scale())
         o = jax.lax.map(
             lambda a: self._tile(a, kp, vp, k, v, m["pos"], m["first"]),
             {"q": q.reshape((t["K"], t["T"]) + q.shape[1:]), "qpos": t["qpos"],
              "ring": t["rings"], "rpos": m["rpos"], "own": m["own"], "row": t["rows"],
              "last": t["last"]})
-        return o.reshape(q.shape), ko, vo
+        return o.reshape(q.shape)
+
+    def _as_pages(self, rows):
+        """Rows by head (n, KV, hd), n whole pages -> the same bytes as pages of
+        whole rows, (n / P, P, KV x hd)."""
+        return rows.reshape(-1, self.rows, self.kv * self.hd)
 
     def _attend_eva(self, lp: dict, q, k, v, kp, vp, m: dict):
         """The EVA mixer in either phase -> (o as ``q`` lies, float32, the two
         pools)."""
         t = m["t"]
 
-        def put(page, off, rows_k, rows_v):
-            return (self._write_pages(kp, page, off, rows_k),
-                    self._write_pages(vp, page, off, rows_v))
+        def put(page, off, rows_k, rows_v):   # a token ONE row of each pool: its heads side by side
+            return (self._write_pages(kp, page, off, rows_k.reshape(rows_k.shape[0], -1)),
+                    self._write_pages(vp, page, off, rows_v.reshape(rows_v.shape[0], -1)))
 
         if t is None:
             kp, vp = put(m["ring_page"], m["ring_off"], k, v)
@@ -418,11 +441,10 @@ class EvaServing(dec.DecoderServing):
                 o = self._walk(q, kp, vp, m)
             with jax.named_scope("eva_summarise"):
                 # A pool as runs of c rows (a page is whole runs: no row moves),
-                # of which each lane takes one: (KV, b, c, hd).
-                runs = (self.kv, -1, self.chunk, self.hd)
-                ck = jnp.take(kp.reshape(runs), m["chunk_at"], axis=1)
-                cv = jnp.take(vp.reshape(runs), m["chunk_at"], axis=1)
-                ks, vs = self._pool(lp, ck.transpose(1, 2, 0, 3), cv.transpose(1, 2, 0, 3))
+                # of which each lane takes one: (b, c, KV, hd).
+                runs = (-1, self.chunk, self.kv * self.hd)
+                ks, vs = self._pool(lp, *(self._rows_by_head(jnp.take(
+                    pool.reshape(runs), m["chunk_at"], axis=0)) for pool in (kp, vp)))
             return o, *put(m["sum_page"], m["sum_off"], ks, vs)
         with jax.named_scope("eva_summarise"):
             by_chunk = (-1, self.chunk) + k.shape[1:]
@@ -432,10 +454,10 @@ class EvaServing(dec.DecoderServing):
         with jax.named_scope("eva_prefill"):
             # The pools hold the rings as the launch found them (its own rows
             # land there last) and, by now, this launch's summaries.
-            o, ko, vo = self._attend_tiles(q, k, v, kp, vp, m)
-        # whole pages of the rings: ONE scatter of C / P slabs a pool
+            o = self._attend_tiles(q, k, v, kp, vp, m)
+        # whole pages of the rings: ONE scatter of C / P slabs a pool, a slab a page's bytes
         runs = m["ring_runs"]
-        return o, kp.at[:, runs].set(ko), vp.at[:, runs].set(vo)
+        return o, kp.at[runs].set(self._as_pages(k)), vp.at[runs].set(self._as_pages(v))
 
     def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
         q, k, v, _ = self._qkv(lp, i, self._norm(x, lp["norm1"]), m["pos"])

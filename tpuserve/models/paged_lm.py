@@ -389,7 +389,9 @@ class PagedLM(GenerativeModel):
         compiler copied the whole pool to another layout and back, eight
         times a step: 13 of a step's 33 ms, my chip run, PR 28.) A pool
         with no heads, (pages, P, width), takes ``rows`` (T, width): one
-        latent row a token."""
+        latent row a token, or (``eva``, ISSUE 63) a token's KV heads side by
+        side in one row, ``width`` = KV x hd: T row copies where a pool by
+        head takes T x KV."""
         if pool.ndim == 3:
             return PagedLM._write_pages(pool[None], page, off, rows[:, None])[0]
         kv, n_pages, p_tokens, hd = pool.shape

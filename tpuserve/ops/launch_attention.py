@@ -10,9 +10,10 @@ aligned window of ``W`` positions and the summary rows of every earlier window.
 
 ``launch_list(...)``, once a launch and shared by its layers: the (tile, key
 page) items that EXIST, tile after tile, a tile's items in order. A page is
-``P`` rows of every KV head; a launch is ``K`` tiles of ``T`` rows, a tile
-whole pages of one piece of one prompt inside one window (``W % T == 0``), its
-rows at consecutive positions from ``qpos0``. A tile's items are
+``P`` rows, a row a position's KV heads side by side; a launch is ``K`` tiles
+of ``T`` rows, a tile whole pages of one piece of one prompt inside one window
+(``W % T == 0``), its rows at consecutive positions from ``qpos0``. A tile's
+items are
 
 (i)   the pages of its RING that hold a position of the tile's own window
       written BEFORE the launch: place ``r`` of the ring holds position ``w0 +
@@ -21,7 +22,7 @@ rows at consecutive positions from ``qpos0``. A tile's items are
       none where the piece begins its window or the tile lies past a window's
       edge that the launch itself crossed;
 (ii)  the launch's OWN rows by page, of the tile's own piece and window, up to
-      the tile's own last live page: the second operand, ``(KV, C / P, P, hd)``
+      the tile's own last live page: the second operand, ``(C / P, P, KV x hd)``
       (they are not in the ring yet: the ring is written after the attention,
       because a launch that crosses a window's edge overwrites places its
       earlier tiles still read);
@@ -44,16 +45,20 @@ An operand's page index at an item that reads the OTHER operand is the one it
 held last (``_held``): the pipeline fetches nothing for an index that stays.
 
 ``launch_walk(q, kp, vp, ko, vo, work)``: ``q`` (C, H, hd) the launch's
-queries, ``kp``, ``vp`` (KV, pages, P, hd) the layer's pools AS THEY LIE,
-``ko``, ``vo`` (KV, C / P, P, hd) the launch's own rows laid as pages -> the
-normalised context (C, H, hd) float32. Grid (``items``,), sequential. A cell
-holds its tile's (T, H x hd) queries (the output's block index is the tile
+queries, ``kp``, ``vp`` (pages, P, KV x hd) the layer's pools AS THEY LIE (ISSUE
+63: a position one row with its KV heads side by side), ``ko``, ``vo`` (C / P, P,
+KV x hd) the launch's own rows seen as pages (the projections' output, no copy)
+-> the normalised context (C, H, hd) float32. Grid (``items``,), sequential. A
+cell holds its tile's (T, H x hd) queries (the output's block index is the tile
 too, so a tile's context is written back when the tile changes), one page of K
-and one of V of the operand the item reads, and for each query head takes the
-scores of its rows over its KV head's keys in bfloat16 with float32
-accumulation, scales them in float32, masks them, carries ONE running softmax
-in float32 across the three kinds of item, and adds ``p.astype(bfloat16) @ v``
-to the tile's accumulator; a tile's last item divides and writes. This is
+and one of V of the operand the item reads, each ONE contiguous block (P, KV x
+hd) of which a query head reads its KV head's columns ``[g hd, (g + 1) hd)``,
+whole lane tiles (``lane_attention._head_kernel``'s ``part`` under ``kv=``), and
+for each query head takes the scores of its rows over its KV head's keys in
+bfloat16 with float32 accumulation, scales them in float32, masks them, carries
+ONE running softmax in float32 across the three kinds of item, and adds
+``p.astype(bfloat16) @ v`` to the tile's accumulator; a tile's last item
+divides and writes. This is
 ``eva._tile``'s arithmetic term for term, which is the fallback in XLA: no
 gathered page, no score over a page that holds no visible key, no mask or
 concatenated block in device memory. The exact items come first, and each
@@ -144,9 +149,8 @@ def launch_list(has: jax.Array, qpos0: jax.Array, start: jax.Array, end: jax.Arr
 def _kernel(tile_ref, step_ref, left_ref, own_ref, pool_ref, page_ref, at_ref, hi_ref, qpos_ref,
             q_ref, kp_ref, vp_ref, ko_ref, vo_ref, o_ref, m_ref, l_ref, acc_ref, *, scale: float):
     del pool_ref, page_ref   # the index maps read them
-    kv, P, hd = kp_ref.shape
-    T, dt = q_ref.shape[0], q_ref.dtype
-    H = q_ref.shape[1] // hd
+    P, T, dt, hd = kp_ref.shape[0], q_ref.shape[0], q_ref.dtype, acc_ref.shape[1]
+    H, kv = q_ref.shape[1] // hd, kp_ref.shape[1] // hd
     g = H // kv
     pair = 2 if kv % 2 == 0 else 1   # KV heads whose softmax goes as one block (below)
     n = pl.program_id(0)
@@ -165,10 +169,14 @@ def _kernel(tile_ref, step_ref, left_ref, own_ref, pool_ref, page_ref, at_ref, h
     see = at_ref[n] + col <= jnp.minimum(qpos_ref[tile_ref[n]] + row, hi_ref[n])
 
     def attend(k_ref, v_ref):
+        def part(ref, h: int):   # KV head h of a page's rows, (P, hd): whole lane tiles
+            return ref[:, h * hd:(h + 1) * hd]
+
         for h0 in range(0, kv, pair):
             heads = range(h0 * g, (h0 + pair) * g)   # query heads; head j's rows: j T .. j T + T - 1
-            s = [jnp.where(see, jax.lax.dot_general(q_ref[:, j * hd:(j + 1) * hd], k_ref[j // g],
-                                                    nt, **f32) * scale, NEG) for j in heads]
+            s = [jnp.where(see, jax.lax.dot_general(q_ref[:, j * hd:(j + 1) * hd],
+                                                    part(k_ref, j // g), nt, **f32) * scale, NEG)
+                 for j in heads]
             s = jnp.concatenate(s, axis=0) if len(s) > 1 else s[0]
             rows = slice(heads[0] * T, (heads[-1] + 1) * T)
             m_prev = m_ref[rows, :1]
@@ -176,7 +184,8 @@ def _kernel(tile_ref, step_ref, left_ref, own_ref, pool_ref, page_ref, at_ref, h
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
             pb = p.astype(dt)
-            pv = [jnp.dot(pb[i * T:(i + 1) * T], v_ref[j // g], **f32) for i, j in enumerate(heads)]
+            pv = [jnp.dot(pb[i * T:(i + 1) * T], part(v_ref, j // g), **f32)
+                  for i, j in enumerate(heads)]
             acc_ref[rows] = acc_ref[rows] * alpha + (jnp.concatenate(pv, axis=0) if len(pv) > 1
                                                      else pv[0])
             m_ref[rows] = jnp.broadcast_to(m_new, (len(heads) * T, m_ref.shape[1]))
@@ -203,12 +212,13 @@ def fits(tile: int, page: int, window: int, heads: int, kv: int, hd: int, dtype)
 def launch_walk(q: jax.Array, kp: jax.Array, vp: jax.Array, ko: jax.Array, vo: jax.Array,
                 work: dict, *, scale: float, interpret: bool = False) -> jax.Array:
     C, H, hd = q.shape
-    kv, n_pages, P = kp.shape[:3]
+    n_pages, P, row = kp.shape
+    kv = row // hd
     K = work["qpos0"].shape[0]
     T, g = C // K, H // kv
     by_tile = lambda n, tile, *_: (tile[n], 0)  # noqa: E731
-    pool = lambda n, tile, step, left, own, pool, page, *_: (0, pool[n], 0, 0)  # noqa: E731
-    launch = lambda n, tile, step, left, own, pool, page, *_: (0, page[n], 0, 0)  # noqa: E731
+    pool = lambda n, tile, step, left, own, pool, page, *_: (pool[n], 0, 0)  # noqa: E731
+    launch = lambda n, tile, step, left, own, pool, page, *_: (page[n], 0, 0)  # noqa: E731
     item = jnp.dtype(q.dtype).itemsize
     # the cell's blocks twice (the pipeline's two buffers), its scratch, and the
     # float32 values of a head's scores, weights and context
@@ -219,8 +229,8 @@ def launch_walk(q: jax.Array, kp: jax.Array, vp: jax.Array, ko: jax.Array, vo: j
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=9, grid=(work["items"],),
             in_specs=[pl.BlockSpec((T, H * hd), by_tile)]
-            + [pl.BlockSpec((kv, None, P, hd), pool)] * 2
-            + [pl.BlockSpec((kv, None, P, hd), launch)] * 2,
+            + [pl.BlockSpec((None, P, row), pool)] * 2
+            + [pl.BlockSpec((None, P, row), launch)] * 2,
             out_specs=pl.BlockSpec((T, H * hd), by_tile),
             scratch_shapes=[pltpu.VMEM((H * T, 128), jnp.float32),
                             pltpu.VMEM((H * T, P), jnp.float32),
