@@ -1,7 +1,7 @@
 """What ``acc`` holds is stated once a family (``paged_lm.Column``, ISSUE 45):
 the state's width, the row a launch adds, the counters ``bind_metrics`` binds
 and what ``observe_step`` feeds all follow the family's ``COLUMNS``. Here, for
-each of the eleven generating families at its toy size: one prefill launch and
+each of the twelve generating families at its toy size: one prefill launch and
 one step on the CPU, then the device's sums into a registry. The names below
 are the series as they have been served since each family came (the benchmark's
 readers find them by these letters), written down apart from the code."""
@@ -58,6 +58,7 @@ SERIES = {
         "prefill:delta_scans_total{model=M,phase=prefill,path=kernel}",
         "prefill:delta_scans_total{model=M,phase=prefill,path=xla}"] + SAMPLE,
     "hybrid_conv": EXPERTS + CONTEXT + SSM + COMPACT + SAMPLE,
+    "hybrid_ffn_moe": EXPERTS + CONTEXT + SSM + COMPACT + SAMPLE,
     "eva": CONTEXT + [
         "eva_rows_attended_total{model=M,phase=PH,kind=exact}",
         "eva_rows_attended_total{model=M,phase=PH,kind=summary}",

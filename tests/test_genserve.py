@@ -732,7 +732,8 @@ def _frozen_case(name, tmp_path):
              "mla_sc": "tests.test_mla_sc", "mla_hc": "tests.test_mla_hc",
              "decoder_sink": "tests.test_decoder_sink",
              "hybrid_delta": "tests.test_hybrid_delta", "eva": "tests.test_eva",
-             "hybrid_conv": "tests.test_hybrid_conv", "mla_sel": "tests.test_mla_sel"}[name]
+             "hybrid_conv": "tests.test_hybrid_conv", "mla_sel": "tests.test_mla_sel",
+             "hybrid_ffn_moe": "tests.test_hybrid_ffn_moe"}[name]
     import importlib
     model = importlib.import_module(maker).make_model(str(tmp_path), name="fz")
     return (model, {}, dict(kv_paging=True, kv_page_tokens=4, prefill_chunk=8),
@@ -741,7 +742,7 @@ def _frozen_case(name, tmp_path):
 
 FROZEN_CASES = ["textgen-dense", "textgen-paged", "textgen-sharded-dense", "textgen-sharded-paged",
                 "sd15", "decoder", "hybrid", "hybrid_ffn", "mla", "mla_sc", "mla_hc",
-                "decoder_sink", "hybrid_delta", "eva", "hybrid_conv", "mla_sel"]
+                "decoder_sink", "hybrid_delta", "eva", "hybrid_conv", "mla_sel", "hybrid_ffn_moe"]
 
 
 def test_the_frozen_lane_cases_name_every_registered_generating_family():

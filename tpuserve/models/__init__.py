@@ -72,6 +72,12 @@ Families (BASELINE.json ``configs``):
                    leaf, the exact ``index_topk`` largest scores a query), with
                    group-limited routed experts and a share of each layer
                    (ISSUE 62)
+- hybrid_ffn_moe — ``hybrid_ffn``'s sibling whose second sublayer is a routed
+                   block in EVERY layer, behind the Mamba-2 mixers and behind
+                   attention alike: softmax-routed SwiGLU experts with no
+                   selection bias (the picks' weights a softmax over the picked
+                   alone) and one shared expert, under the residual multiplier;
+                   a share of the experts and the vocabulary (ISSUE 64)
 - toy            — a linear classifier for tests and drills
 """
 
@@ -101,6 +107,7 @@ _REGISTRY: dict[str, str] = {
     "eva": "tpuserve.models.eva",
     "hybrid_conv": "tpuserve.models.hybrid_conv",
     "mla_sel": "tpuserve.models.mla_sel",
+    "hybrid_ffn_moe": "tpuserve.models.hybrid_ffn_moe",
     "toy": "tpuserve.models.toy",
 }
 

@@ -66,15 +66,22 @@ DEFAULT_SCALES = {
 
 
 class RoutedExperts:
-    """Sigmoid-routed SwiGLU experts in the layers a family names (``e_layers``),
-    with a selection bias and, where ``shared_width``, a shared expert on the
-    same rows: their tensors, their biases and the sublayer (module docstring,
-    "Experts"). A mix-in over ``PagedLM`` that ``hybrid_conv`` shares. The family
+    """Routed SwiGLU experts in the layers a family names (``e_layers``) and,
+    where ``shared_width``, a shared expert on the same rows: their tensors,
+    their biases and the sublayer (module docstring, "Experts"). A mix-in over
+    ``PagedLM`` that ``hybrid_conv`` and ``hybrid_ffn_moe`` share. The family
     sets ``n_experts``, ``top_k``, ``expert_width``, ``shared_width``,
-    ``norm_topk``, ``route_scale`` and the part held, ``e_first`` and ``e_count``."""
+    ``norm_topk``, ``route_scale`` and the part held, ``e_first`` and ``e_count``;
+    as class attributes, where its router is not this module's: ``route_scoring``
+    (what ``topk_route`` scores by) and ``route_bias`` (whether a layer has a
+    selection bias: a family without one draws none)."""
     route_eps = 0.0  # added to the picks' sum under their weights, where a model's block does
+    route_scoring = "sigmoid"
+    route_bias = True
 
     def _expert_tensors(self):
+        """The experts' second kernel is drawn at ``expert_out`` where the
+        family's scales have that role, else at ``ffn_out`` as the shared one's."""
         d, s = self.d, self.scales
         e, ec, e0, f, fs = (self.n_experts, self.e_count, self.e_first,
                             self.expert_width, self.shared_width)
@@ -83,7 +90,8 @@ class RoutedExperts:
             yield ((L, "router"), (d, e), (d, e), (0, 0), s["router"], d)
             for name in ("e_gate", "e_up"):
                 yield ((L, name), (ec, d, f), (e, d, f), (e0, 0, 0), s["ffn_in"], d)
-            yield ((L, "e_down"), (ec, f, d), (e, f, d), (e0, 0, 0), s["ffn_out"], f)
+            yield ((L, "e_down"), (ec, f, d), (e, f, d), (e0, 0, 0),
+                   s.get("expert_out", s["ffn_out"]), f)
             if fs:
                 for name in ("s_gate", "s_up"):
                     yield ((L, name), (d, fs), (d, fs), (0, 0), s["ffn_in"], d)
@@ -91,21 +99,34 @@ class RoutedExperts:
 
     def _expert_vectors(self):
         """Every router's selection bias (small, about 0: it changes some picks)."""
+        if not self.route_bias:
+            return
         b3 = 3.0 * self.scales["router_bias"]
         for i in self.e_layers:
             yield ((f"layer{i}", "e_bias"), (self.n_experts,), (self.n_experts,), (0,), -b3, b3)
 
-    def _ffn(self, lp, u, live):
-        """(T, d) -> ((T, d) float32: the held experts' part and the shared
-        expert, the expert layer's counts)."""
+    def _routed(self, lp, u, live):
+        """(T, d) -> ((T, d) float32: the held experts' part, the expert
+        layer's counts)."""
         r = jnp.matmul(u.astype(jnp.float32), lp["router"].astype(jnp.float32),
                        precision=jax.lax.Precision.HIGHEST)
         w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
-                          scoring="sigmoid", select_bias=lp["e_bias"], eps=self.route_eps)
-        y, stats = held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"], lp["e_up"],
-                                       lp["e_down"], live=live, of=self.n_experts)
+                          scoring=self.route_scoring,
+                          select_bias=lp["e_bias"] if self.route_bias else None,
+                          eps=self.route_eps)
+        return held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"], lp["e_up"],
+                                   lp["e_down"], live=live, of=self.n_experts)
+
+    def _shared(self, lp, u):
+        """(T, d) -> (T, d) float32: the shared expert, whole here."""
+        return self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"])
+
+    def _ffn(self, lp, u, live):
+        """(T, d) -> ((T, d) float32: the held experts' part and the shared
+        expert, the expert layer's counts)."""
+        y, stats = self._routed(lp, u, live)
         if self.shared_width:
-            y = y + self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"])
+            y = y + self._shared(lp, u)
         return y, stats
 
 

@@ -60,20 +60,24 @@ KINDS = ("mamba", "attention")
 class HybridFfnServing(PatternMixers, PagedLM):
     # The context, the scan layers' four, and the steps by the sampler's branch.
     COLUMNS = (CONTEXT_COLUMN, *SSM_COLUMNS, *SAMPLE_COLUMNS)
+    # What this family refuses and a sibling with a routed block serves
+    # (``hybrid_ffn_moe``): experts in the config, and a share of them.
+    ROUTED = False
+    SHARE_KEYS = ("vocab_rows",)
 
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)
         a = read_config_file(cfg)
         self.dtype = jnp.dtype(cfg.dtype)
+        dense = () if self.ROUTED else (("num_local_experts", 0), ("num_experts_per_tok", 0))
         for key, want in (("attention_bias", False), ("mamba_proj_bias", False),
                           ("position_embedding_type", "nope"), ("hidden_act", "silu"),
-                          ("normalization_function", "rmsnorm"), ("num_local_experts", 0),
-                          ("num_experts_per_tok", 0)):
+                          ("normalization_function", "rmsnorm"), *dense):
             if a.get(key, want) != want:
                 raise NotImplementedError(f"{cfg.name}: {key} = {a[key]!r}")
         share = a.get("share", {})
-        if set(share) - {"vocab_rows"}:
-            raise NotImplementedError(f"{cfg.name}: share = {share!r} (every layer is whole here)")
+        if set(share) - set(self.SHARE_KEYS):
+            raise NotImplementedError(f"{cfg.name}: share = {share!r} (of {self.SHARE_KEYS} here)")
         self.d = int(a["hidden_size"])
         self.kinds = [str(k) for k in a["layer_types"]]
         self.n_layers = int(a.get("num_hidden_layers", len(self.kinds)))
