@@ -47,7 +47,9 @@ SERIES = {
                       "sel_pairs_kept_total{model=M,phase=PH}",
                       "sel_rows_walked_total{model=M,phase=PH}",
                       "sel_queries_total{model=M,phase=PH,path=dense}",
-                      "sel_queries_total{model=M,phase=PH,path=picked}"],
+                      "sel_queries_total{model=M,phase=PH,path=picked}",
+                      "sel_threshold_tiles_total{model=M,phase=PH,path=kernel}",
+                      "sel_threshold_tiles_total{model=M,phase=PH,path=xla}"],
     "decoder_sink": EXPERTS + CONTEXT + COMPACT + SAMPLE + [
         "attn_rows_attended_total{model=M,phase=PH}", "attn_rows_walked_total{model=M,phase=PH}",
         "attn_walks_total{model=M,phase=PH,walk=kernel}",

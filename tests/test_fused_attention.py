@@ -546,12 +546,16 @@ def test_attention_over_picks_is_kernel_calls_on_the_v5e_at_the_cells_widths(
     tiles and key blocks of 1,024, 64 index heads of 128 over a third leaf of
     128 values a token, 16 lanes, block tables of 258 pages. A tile past
     `index_topk`: `tile_scores` (the index keys' pages read in place through the
-    block table, the walk's length a traced grid bound) and `tile_walk` under
-    the rows' picks, beside the plain `tile_walk` of a tile under it, a `cond`
-    between them; a step: `lane_scores` and `lane_walk` under the lanes' picks
-    by ONE work list, beside the plain walk. Mosaic takes all four; nothing
-    float32 by index head, row and key (a block's products before the ReLU) is
-    in either program, and no copy of a pool."""
+    block table, the walk's length a traced grid bound; since ISSUE 65 it keeps
+    a row sub-tile's order keys over the padded 33,792 key places in 34.6 MB of
+    scratch and leaves each row's threshold beside the scores) and `tile_walk`
+    under the pair, beside the plain `tile_walk` of a tile under it, a `cond`
+    between them: NOTHING of XLA's runs over a tile's scores (no operation
+    but the two kernels' calls has a (1,024, 33,792) operand or result); a
+    step: `lane_scores` and `lane_walk` under the lanes' picks by ONE work
+    list, beside the plain walk. Mosaic takes all four; nothing float32 by
+    index head, row and key (a block's products before the ReLU) is in
+    either program, and no copy of a pool."""
     import json
 
     from tpuserve.config import ModelConfig
@@ -611,6 +615,9 @@ def test_attention_over_picks_is_kernel_calls_on_the_v5e_at_the_cells_widths(
 
     assert calls(launch) == ["tile_scores"] * K + ["tile_walk"] * 2 * K
     assert calls(a_step) == ["lane_scores", "lane_walk", "lane_walk"]
+    wide = [ln for ln in launch.split("\n") if f"[{tile},33792]" in ln]
+    assert len(wide) == 3 * K and all(
+        " custom-call(" in ln or " get-tuple-element(%tile_scores" in ln for ln in wide), wide[:3]
     for text in (launch, a_step):
         for products in (f"f32[64,{tile},1024]", f"f32[{tile},64,1024]", "f32[16,64,1024]"):
             assert products not in text

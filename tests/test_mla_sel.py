@@ -123,8 +123,11 @@ def test_packed_chunked_prefill_then_decode_is_the_reference_one_causal_pass(
     for g in gaps(ARCH, prompts, served):
         assert float(np.abs(g).max()) < TOL
     acc = np.asarray(state["acc"])
-    assert acc.shape[1] == len(model.COLUMNS) == len(mla_sel.mla.LatentServing.COLUMNS) + 5
-    scored, kept, walked, dense, picked = (int(acc[0, -5 + j]) for j in range(5))
+    assert acc.shape[1] == len(model.COLUMNS) == len(mla_sel.mla.LatentServing.COLUMNS) + 7
+    scored, kept, walked, dense, picked = (int(acc[0, -7 + j]) for j in range(5))
+    # picked tiles by where their thresholds were found: off the TPU in XLA, and a
+    # step has no tiles
+    assert int(acc[0, -2]) == 0 and 0 < int(acc[0, -1]) <= picked and not acc[1, -2:].any()
     want = [p for prompt in prompts for p in range(len(prompt))]
     assert picked == sum(p >= TOPK for p in want) and dense == sum(p < TOPK for p in want)
     assert scored == sum(p + 1 for p in want if p >= TOPK) and kept == TOPK * picked
@@ -158,6 +161,14 @@ def test_the_programs_picks_are_the_references_top_k(whole):
         got, want = np.asarray(keep)[:, :len(seq)] > 0, picked[i][0]
         assert (got == want).all(), f"layer {i}: {np.argwhere(got != want)[:5]}"
         assert (got.sum(axis=1) == np.minimum(TOPK, np.arange(len(seq)) + 1)).all()
+        # and through the kernel (ISSUE 65): its scores at or above its thresholds
+        pad = lambda a: jnp.pad(a, ((0, 1),) + ((0, 0),) * (a.ndim - 1))  # noqa: E731
+        scores, least = ix.tile_scores(pad(qi), pad(wi), pool, jnp.arange(pool.shape[0]),
+                                       jnp.int32(pool.shape[0] // 2), jnp.int32(0), k=TOPK,
+                                       block_pages=2, interpret=True)
+        see = np.arange(scores.shape[1])[None, :] <= np.arange(scores.shape[0])[:, None]
+        got = (see & np.asarray(scores >= least[:, :1]))[:len(seq), :len(seq)]
+        assert (got == want).all(), f"layer {i}, the kernel: {np.argwhere(got != want)[:5]}"
 
 
 @pytest.mark.parametrize("rows,width,k", [(7, 40, 5), (3, 96, 1), (16, 64, 64), (4, 33, 50)])
@@ -268,12 +279,16 @@ def test_the_tile_kernels_scores_are_the_fallbacks_and_unneeded_blocks_are_left(
     qi = jnp.asarray(rng.standard_normal((32, 16, 128)), jnp.bfloat16)
     w = jnp.asarray(rng.standard_normal((32, 16)), jnp.float32)
     monkeypatch.setattr(ix, "ROWS", 16)   # two row sub-tiles
-    got = ix.tile_scores(qi, w, ik, row, jnp.int32(5), block_pages=2, interpret=True)
+    got, least = ix.tile_scores(qi, w, ik, row, jnp.int32(5), jnp.int32(120), k=50,
+                                block_pages=2, interpret=True)
     want = ix.scores_xla(qi, w, ik, row)
-    assert got.shape == want.shape == (32, 192)
+    assert got.shape == want.shape == (32, 192) and least.shape == (32, 128)
     np.testing.assert_allclose(got[:, :160], want[:, :160], rtol=1e-5, atol=1e-4)
     qpos = 120 + jnp.arange(32)
-    assert bool(jnp.all(ix.picks(got, qpos, 50, jnp.int32(5), 32) == ix.picks(want, qpos, 50)))
+    keep = ix.picks(want, qpos, 50)
+    assert bool(jnp.all(ix.picks(got, qpos, 50, jnp.int32(5), 32) == keep))
+    see = jnp.arange(192)[None, :] <= qpos[:, None]
+    assert bool(jnp.all((see & (got >= least[:, :1])) == (keep > 0)))
 
 
 def test_the_lane_kernels_scores_and_the_walk_under_picks_are_the_fallbacks():
@@ -319,9 +334,12 @@ def test_the_tile_kernels_walk_under_picks_is_a_masked_softmax(monkeypatch):
     w_kvb = jnp.asarray(rng.standard_normal((h, 128, dn + 128)) * 0.1, jnp.bfloat16)
     rows = jnp.asarray(rng.permutation(np.arange(1, 40))[:12], jnp.int32)
     qpos = pos0 + jnp.arange(t)
-    keep = ix.picks(jnp.asarray(rng.standard_normal((t, 192)), jnp.float32), qpos, 40)
+    scores = jnp.asarray(rng.standard_normal((t, 192)), jnp.float32)
+    keep = ix.picks(scores, qpos, 40)
+    least = jnp.broadcast_to(ix.thresholds(scores, qpos, 40)[:, None], (t, 128))
     o = ta.tile_walk(q, w_kvb, ckv, kr, rows, jnp.int32((pos0 + t - 1) // 32 + 1),
-                     jnp.int32(pos0), block_pages=2, scale=0.1, keep=keep, interpret=True)
+                     jnp.int32(pos0), block_pages=2, scale=0.1, keep=(scores, least),
+                     interpret=True)
     c = jnp.take(ckv, rows, axis=0).reshape(192, 128).astype(jnp.float32)
     k2 = jnp.take(kr, rows, axis=0).reshape(192, 64).astype(jnp.float32)
     kv = jnp.einsum("cr,hrn->hcn", c, w_kvb.astype(jnp.float32)).astype(jnp.bfloat16) \
@@ -332,6 +350,169 @@ def test_the_tile_kernels_walk_under_picks_is_a_masked_softmax(monkeypatch):
     np.testing.assert_allclose(o.astype(jnp.float32),
                                jnp.einsum("htc,hcv->thv", p, kv[:, :, dn:]), atol=0.02)
 
+
+def _bits(x):
+    return np.asarray(jax.lax.bitcast_convert_type(jnp.asarray(x, jnp.float32), jnp.uint32))
+
+
+@pytest.mark.parametrize("need", [1, 3, 6])
+@pytest.mark.parametrize("case", ["drawn", "ties", "zeros", "under-k"])
+def test_the_kernels_threshold_is_kth_keys_to_the_bit(case, need, monkeypatch):
+    """`tile_scores`' second output (ISSUE 65: each row's `k`-th largest order
+    key, found in the kernel's scratch) against `kth_key` over `sort_keys` of
+    the kernel's own scores under the causal mask, bit for bit, walking one,
+    several and all of the block table's six key blocks. `drawn`: signed head
+    weights, so scores of both signs; `ties`: a position's key is its
+    neighbour's and the block table names a page several times, so every score
+    stands twice or more, about the threshold too; `zeros`: most index keys
+    are zero rows, all 16 ReLUs zero on them, many scores of +0.0 (a score of
+    -0.0 cannot leave the kernel: its sum starts at +0.0; `thresholds` and the
+    walk's compare meet one below); `under-k`: half the rows see fewer than `k`
+    keys and keep all (-inf). The pages of the blocks past `need` hold NaN and
+    +inf: a kernel that read them would score them."""
+    rng = np.random.default_rng(need * 7 + len(case))
+    t, page, kb = 32, 16, 2
+    c, pos0 = page * kb, need * page * kb - 32
+    pool = rng.standard_normal((40, page, 128))
+    table = rng.permutation(np.arange(1, 40))[:12]
+    if case == "ties":
+        pool = np.round(pool)
+        pool[:, 1::2] = pool[:, ::2]      # a position's key is its neighbour's
+        table[2:need * kb:2] = table[0]   # and a page stands in the table several times
+    if case == "zeros":
+        pool[rng.random((40, page)) < 0.7] = 0.0
+    pool[table[need * kb:]] = np.where(rng.random((12 - need * kb, page, 128)) < 0.5,
+                                       np.nan, np.inf)
+    k = pos0 + 17 if case == "under-k" else 20
+    qi = jnp.asarray(rng.standard_normal((t, 16, 128)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((t, 16)), jnp.float32)
+    monkeypatch.setattr(ix, "ROWS", 16)          # two row sub-tiles,
+    monkeypatch.setattr(ix, "SEARCH_ROWS", 8)    # each searched in two parts
+    scores, least = ix.tile_scores(qi, w, jnp.asarray(pool, jnp.bfloat16),
+                                   jnp.asarray(table, jnp.int32), jnp.int32(need),
+                                   jnp.int32(pos0), k=k, block_pages=kb, interpret=True)
+    scores, qpos = scores[:, :need * c], pos0 + jnp.arange(t)
+    assert bool(jnp.all(jnp.isfinite(scores)))
+    see = jnp.arange(need * c)[None, :] <= qpos[:, None]
+    keys = jnp.where(see, ix.sort_keys(scores), jnp.uint32(0))
+    kth = ix.kth_key(keys, k, jnp.int32(need), c)
+    assert (np.asarray(kth) == np.asarray(ix.kth_key(keys, k))).all()
+    assert (_bits(least) == _bits(ix.key_float(kth))[:, None]).all()
+    found = np.asarray(kth) > 0
+    assert (np.asarray(ix.sort_keys(least[:, 0]))[found] == np.asarray(kth)[found]).all()
+    assert np.isneginf(np.asarray(least[:, 0])[~found]).all()
+    # the walk's compare keeps what `picks` keeps
+    keep = np.asarray(see & (scores >= least[:, :1]))
+    assert (keep == (np.asarray(ix.picks(scores, qpos, k)) > 0)).all()
+    if case == "under-k":
+        assert (~found).sum() == 16 and (keep[~found] == np.asarray(see)[~found]).all()
+    if case in ("ties", "zeros"):   # a row keeps more than k: equal scores about the threshold
+        assert (keep.sum(axis=1) > k).any()
+    if case == "drawn":
+        assert (np.asarray(least[:, 0])[found] < 0).any() or need == 1
+
+
+def test_thresholds_are_the_picks_compare_with_both_zeros_and_infinities():
+    """`thresholds` (the pair's second half where the scores are XLA's): a
+    float32 compare against it keeps what `picks` keeps, -0.0 beside +0.0 about
+    the threshold, infinities, rows under `k`."""
+    x = np.asarray([[0.0, -0.0, -1.0, 0.0, -0.0, 2.0, -3.0, -0.0],
+                    [np.inf, -np.inf, 1.0, 1.0, -np.inf, 0.5, 1.0, -2.0],
+                    [3.0, 1.0, 1.0, 0.5, 2.0, -1.0, -1.0, -1.0]], np.float32)
+    for k in (1, 2, 4, 6, 8, 9):
+        for qpos in ([7, 7, 7], [3, 5, 0]):
+            least = ix.thresholds(jnp.asarray(x), jnp.asarray(qpos), k)
+            see = np.arange(8)[None, :] <= np.asarray(qpos)[:, None]
+            keep = np.asarray(ix.picks(jnp.asarray(x), jnp.asarray(qpos), k)) > 0
+            assert ((see & (x >= np.asarray(least)[:, None])) == keep).all(), (k, qpos)
+    assert np.asarray(ix.thresholds(jnp.asarray(x), jnp.asarray([7, 7, 7]), 4)).tolist() \
+        == [0.0, 1.0, 1.0]
+
+
+def _picked_walk(seed, pos0, ties):
+    """The values `tests/fixtures/tile_walk_mask_pr64.npz` was written on (from
+    the parent tree, 16f1c60: `tile_walk(keep=picks(scores, qpos, 40))` in the
+    interpreter)."""
+    rng = np.random.default_rng(seed)
+    t, h, dn = 32, 4, 128
+    ckv = jnp.asarray(rng.standard_normal((40, 16, 128)), jnp.bfloat16)
+    kr = jnp.asarray(rng.standard_normal((40, 8, 128)), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((h, t, dn + 128)) * 0.3, jnp.bfloat16)
+    q = q.at[:, :, dn + 64:].set(q[:, :, dn:dn + 64])
+    w_kvb = jnp.asarray(rng.standard_normal((h, 128, dn + 128)) * 0.1, jnp.bfloat16)
+    rows = jnp.asarray(rng.permutation(np.arange(1, 40))[:12], jnp.int32)
+    scores = rng.standard_normal((t, 192)).astype(np.float32)
+    if ties:   # many equal scores about the threshold, zeros of both signs
+        scores = np.round(scores * 2) / 2
+        scores[::3][scores[::3] == 0] = -0.0
+    return q, w_kvb, ckv, kr, rows, jnp.asarray(scores), pos0 + jnp.arange(t)
+
+
+@pytest.mark.parametrize("case,seed,pos0,ties", [("drawn", 2, 130, False), ("ties", 3, 130, True),
+                                                 ("under-k", 4, 20, True)])
+def test_the_tile_kernels_walk_under_the_pair_is_the_parents_under_the_mask(
+        case, seed, pos0, ties, monkeypatch):
+    """`tile_walk(keep=(scores, thresholds))` (ISSUE 65) against what the parent
+    tree's `tile_walk(keep=picks(...))` answered on the same values, TO THE
+    BIT; the picks the fixture holds are this tree's `picks` too. `ties`: a
+    third of the rows hold -0.0 beside +0.0; `under-k`: rows that see fewer
+    than 40 keys keep all."""
+    with np.load(os.path.join(os.path.dirname(__file__), "fixtures",
+                              "tile_walk_mask_pr64.npz")) as f:
+        want, kept = f[f"walk/{case}"], np.unpackbits(f[f"keep/{case}"], axis=1)[:, :192]
+    monkeypatch.setattr(ta, "BLOCK_Q", 16)
+    monkeypatch.setattr(ta, "BLOCK_K", 32)
+    q, w_kvb, ckv, kr, rows, scores, qpos = _picked_walk(seed, pos0, ties)
+    assert (kept == (np.asarray(ix.picks(scores, qpos, 40)) > 0)).all()
+    if ties:
+        assert (_bits(scores) == 1 << 31).any() and (_bits(scores) == 0).any()
+    least = jnp.broadcast_to(ix.thresholds(scores, qpos, 40)[:, None], (32, 128))
+    o = ta.tile_walk(q, w_kvb, ckv, kr, rows, jnp.int32((pos0 + 31) // 32 + 1), jnp.int32(pos0),
+                     block_pages=2, scale=0.1, keep=(scores, least), interpret=True)
+    assert (np.asarray(o.astype(jnp.float32)) == want).all()
+
+
+def test_a_launchs_picked_tiles_through_both_kernels_are_the_fallbacks_and_count_as_such(
+        tmp_path, monkeypatch):
+    """The toy program with its tiles steered to the kernels, interpreted
+    (`tests/test_mla.py` `steer_to_the_kernel`, and the indexer's likewise): a
+    picked tile is ONE `tile_scores` call, which leaves scores and thresholds,
+    and one `tile_walk` under the pair; pieces of three prompts over three
+    launches serve the tokens and log-probabilities of the walks in XLA under
+    `picks`' mask and stand within float32's sums of the reference, whose
+    picks are `jax.lax.top_k`'s; `sel_threshold_tiles_total` counts the same
+    picked tiles under `path=kernel` where it counted them under `path=xla`."""
+    import functools
+
+    from tests.test_mla import steer_to_the_kernel
+
+    model = make_model(tmp_path, name="tiles", tile_rows=64, max_prompt_tokens=320)
+    monkeypatch.setattr(paged_lm, "KEY_BLOCK", 16 * PAGE)   # key blocks of 64 positions
+    params = model.init_params(jax.random.key(0))
+    prompts = [np.random.default_rng(1).integers(0, 64, n) for n in (300, 70, 150)]
+    news, chunk = [3, 4, 2], 256
+    packed = [[(0, 0, 128), (1, 0, 64)], [(0, 128, 128), (1, 64, 6), (2, 0, 64)],
+              [(2, 64, 86), (0, 256, 44)]]
+    plain, _, state = serve(model, params, prompts, news, chunk=chunk, launches=packed)
+    acc = np.asarray(state["acc"]).astype(np.int64)
+    assert acc[0, -2] == 0 and acc[0, -1] == 10   # every tile of a piece ends past 6 keys
+    walks, scores, masks = steer_to_the_kernel(monkeypatch), [], []
+    monkeypatch.setattr(mla_sel.SelectedLatentServing, "_index_walk",
+                        lambda self, T, ik: "kernel" if T > 1 else "xla")
+    monkeypatch.setattr(ix, "tile_scores", functools.partial(
+        lambda *a, f=ix.tile_scores, **k: scores.append(1) or f(*a, interpret=True, **k)))
+    monkeypatch.setattr(ix, "picks", functools.partial(
+        lambda x, *a, f=ix.picks, **k: masks.append(x.shape[0]) or f(x, *a, **k)))
+    kernel, _, state = serve(model, params, prompts, news, chunk=chunk, launches=packed)
+    # traced once: a tile of a layer is picked or `mla`'s own; a mask is a step's lane's alone
+    assert len(scores) == 3 * 4 and len(walks) == 3 * 4 * 2 and set(masks) == {1}
+    acc = np.asarray(state["acc"]).astype(np.int64)
+    assert acc[0, -2] == 10 and acc[0, -1] == 0 and not acc[1, -2:].any()
+    for a, b in zip(kernel, plain):
+        assert np.array_equal(a["tokens"], b["tokens"])
+        np.testing.assert_allclose(a["lp"], b["lp"], atol=TOL)
+    for g in gaps(ARCH, prompts, kernel):
+        assert float(np.abs(g).max()) < TOL
 
 # -- (e) group-limited picks ----------------------------------------------------------------------
 
@@ -557,6 +738,11 @@ def test_through_the_engine_the_third_leaf_is_in_stats_and_the_counters_move(tmp
         assert c[f"sel_pairs_kept_total{{model=eng,phase={phase}}}"] == TOPK * len(picked)
         assert c[f"sel_rows_walked_total{{model=eng,phase={phase}}}"] \
             >= c[f"sel_pairs_scored_total{{model=eng,phase={phase}}}"]
+    # a launch's picked tiles (of 4 rows: the 19-token prompt's last four) by where their
+    # thresholds were found: off the TPU in XLA; a step has none
+    assert c["sel_threshold_tiles_total{model=eng,phase=prefill,path=xla}"] == 4
+    assert not any(v for k, v in c.items() if k.startswith("sel_threshold_tiles_total")
+                   and ("path=kernel" in k or "phase=decode" in k))
     assert c["mla_rows_attended_total{model=eng,phase=decode}"] \
         == sum(min(TOPK, s + 1) for s in steps)
     assert c["gen_context_tokens_total{model=eng,phase=decode}"] == sum(s + 1 for s in steps)

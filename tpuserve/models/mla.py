@@ -397,9 +397,11 @@ class LatentServing(PagedLM):
         block-table row ``row``) up to position ``last``, in the ``form``
         given -> (T, H, v): float32 from the walk in XLA, the served type from
         the kernel (``_attn_out`` rounds to it either way). Every row of the
-        launch is in the pages before any tile reads them. ``keep`` (T, key
-        blocks x c) float32, or None: a row attends a key only where it is
-        above 0 (attention over picks, ``mla_sel``)."""
+        launch is in the pages before any tile reads them. ``keep``, or None:
+        attention over picks (``mla_sel``): for the walk in XLA (T, key blocks
+        x c) float32, a row attends a key only where it is above 0; for the
+        kernel the pair ``tile_walk`` takes, the rows' index scores and a
+        threshold a row."""
         dt, r, h = self.dtype, self.r, self.heads
         ckv, kr = pools
         T, P = qn.shape[0], ckv.shape[1]
@@ -479,7 +481,8 @@ class LatentServing(PagedLM):
         absorbed: q_nope ``qn`` (B, H, nope) and rotated q_rope ``qr`` (B, H,
         rope), every lane over its own key blocks by the step's work list ->
         (B, H, v) float32. The two whole-batch products around the call stay
-        XLA's. ``keep`` (B, key blocks x c) float32, or None: ``_attend_tile``'s."""
+        XLA's. ``keep`` (B, key blocks x c) float32, or None: a lane attends a
+        key only where it is above 0 (``mla_sel``)."""
         ckv, kr = pools
         f32 = {"preferred_element_type": jnp.float32}
         q_lat = jnp.einsum("bhn,rhn->bhr", qn, lp["w_kb"], **f32).astype(self.dtype)

@@ -27,15 +27,20 @@ key, one whole row of 128 lanes a token at the published width, written where
 ``kr`` is written; ``/stats`` ``kv.row_bytes_per_token`` counts all three.
 
 THE WALKS. A launch's tile whose last position is under ``index_topk`` is
-``mla``'s own. Another: the tile's index scores over its block table (a kernel
-on the TPU, the ``ik`` pages read in place; ``sel_index``), each ROW's own picks,
-and ``mla``'s walk of every key block with the rows' picks as a mask beside the
-causal one (``keep``; ``sel_attend``): exact, and it reads and scores every
-cached row of the prompt where the picks are a part of them
-(``sel_rows_walked_total`` over ``sel_pairs_kept_total`` says how many). A step
-likewise: every lane's scores by ``lane_attention``'s work list, the lanes'
-picks, ONE masked ``lane_walk`` an attention. Off the TPU, in float32 or at
-shapes no kernel takes, the exact fallbacks in XLA.
+``mla``'s own. Another: the tile's index scores over its block table AND each
+row's threshold, its ``index_topk``-th largest score (ONE kernel on the TPU, the
+``ik`` pages read in place, the threshold found in fast memory; ``sel_index``),
+and ``mla``'s walk of every key block, a row keeping a key where its score is
+at or above its threshold beside the causal mask (``keep``: the scores and the
+thresholds, compared in the walk's kernel; ``sel_attend``): exact, nothing of
+XLA's runs over a tile's scores (``sel_threshold_tiles_total{path=kernel}``),
+and it reads and scores every cached row of the prompt where the picks are a
+part of them (``sel_rows_walked_total`` over ``sel_pairs_kept_total`` says how
+many). A step likewise: every lane's scores by ``lane_attention``'s work list,
+the lanes' picks (``picks``: sixteen rows' thresholds in XLA and a float32
+mask), ONE masked ``lane_walk`` an attention. Off the TPU, in float32 or at
+shapes no kernel takes, the exact fallbacks in XLA (``picks``' mask for the
+walks in XLA; ``thresholds`` where the walk alone is the kernel).
 
 FEED-FORWARD: ``mla``'s own function, its picks group-limited (``n_group``,
 ``topk_group``: ``mla``'s ``groups``, ``ops/moe.py`` ``topk_route(groups=)``). THE SHARE, as ``mla_sc`` reads it:
@@ -72,19 +77,26 @@ DEFAULT_SCALES = {**mla.DEFAULT_SCALES, "q_b": 1.5, "k_rope": 1.5, "k_b": 1.5,
                   "index_q": 1.0, "index_k": 1.0, "index_w": 1.0, "index_beta": 0.1}
 INDEX_EPS = 1e-6   # the index key's LayerNorm
 PATHS = ("dense", "picked")
+THRESHOLDS = ("kernel", "xla")
 
 
 class SelectedLatentServing(mla.LatentServing):
     # ``mla``'s twelve columns (its rows attended count PICKED positions in a
     # step) and, an attention layer: the (query, key) pairs the indexer scored
     # and the picks it kept (live queries past ``index_topk``), the cache rows
-    # their walks read and scored a query, and the live queries by path.
+    # their walks read and scored a query, the live queries by path, and a
+    # launch's picked tiles by where their rows' thresholds were found
+    # (``_prefill_plan``, chosen when the program is traced; a step has no tiles).
     COLUMNS = (*mla.LatentServing.COLUMNS,
                Column(counted("sel_scored"), series("sel_pairs_scored_total")),
                Column(counted("sel_kept"), series("sel_pairs_kept_total")),
                Column(counted("sel_walked"), series("sel_rows_walked_total")),
                *(Column(counted(f"sel_{path}"), series("sel_queries_total", f",path={path}"))
-                 for path in PATHS))
+                 for path in PATHS),
+               *(Column(lambda model, stats, counts, path=path:
+                        counts["sel_tiles"] if counts["sel_threshold"] == path else 0,
+                        series("sel_threshold_tiles_total", f",path={path}"))
+                 for path in THRESHOLDS))
     kv_page_leaves = cache_leaves = ("ckv", "kr", "ik")
     TAKES = tuple(kv for kv in mla.LatentServing.TAKES
                   if kv[0] not in ("n_group", "topk_group", "share"))
@@ -188,19 +200,26 @@ class SelectedLatentServing(mla.LatentServing):
             and (T == 1 or T % min(ix.ROWS, T) == 0 and T % 8 == 0)
         return "kernel" if ok else "xla"
 
-    def _tile_keep(self, qi, wi, ik, row, qpos, last):
+    def _tile_keep(self, qi, wi, ik, row, qpos, last, pair: bool = False):
         """One tile's picks: its rows' index scores over the prompt's pages
         (block-table row ``row``) as far as ``last`` needs, and each row's
-        ``index_topk`` largest -> (T, key blocks x c) float32, ``_attend_tile``'s
-        ``keep``."""
-        P = ik.shape[1]
+        ``index_topk``-th largest as a threshold -> ``_attend_tile``'s ``keep``:
+        ``pair`` (the walk is the kernel's) the scores (T, key blocks x c)
+        float32 and the thresholds (T, 128) float32 as ``tile_walk`` reads
+        them, from the one kernel that makes both where it takes the shapes,
+        else the float32 mask of ``picks``."""
+        T, P, k = qi.shape[0], ik.shape[1], self.index_topk
         kb, rowp = self._key_blocks(row, P)
         need = self._blocks_needed(last, P, row.shape[0])
         with jax.named_scope("sel_index"):
-            if self._index_walk(qi.shape[0], ik) == "kernel":
-                scores = ix.tile_scores(qi, wi, ik, rowp, need, block_pages=kb)
-                return ix.picks(scores, qpos, self.index_topk, need, kb * P)
-            return ix.picks(ix.scores_xla(qi, wi, ik, rowp), qpos, self.index_topk)
+            if T > 1 and self._index_walk(T, ik) == "kernel":
+                scores, least = ix.tile_scores(qi, wi, ik, rowp, need, qpos[0], k=k,
+                                               block_pages=kb)
+                return (scores, least) if pair else ix.picks(scores, qpos, k, need, kb * P)
+            scores = ix.scores_xla(qi, wi, ik, rowp)
+            if pair:
+                return scores, jnp.broadcast_to(ix.thresholds(scores, qpos, k)[:, None], (T, 128))
+            return ix.picks(scores, qpos, k)
 
     def _attend_tiles(self, lp: dict, qn, qr, pools, t: dict, form: str, index: tuple):
         """``mla``'s, a tile whose last position is past ``index_topk`` under
@@ -210,12 +229,13 @@ class SelectedLatentServing(mla.LatentServing):
         K, T = t["K"], t["T"]
         split = lambda a: a.reshape((K, T) + a.shape[1:])  # noqa: E731
         tiles = (split(qn), split(qr), split(qi), split(wi), t["rows"], t["qpos"], t["last"])
+        kernel = self._walk(form, T, pools, t["rows"].shape[1]) == "kernel"
 
         def one(a):
             qn, qr, qi, wi, row, qpos, last = a
 
             def picked():
-                keep = self._tile_keep(qi, wi, ik, row, qpos, last)
+                keep = self._tile_keep(qi, wi, ik, row, qpos, last, pair=kernel)
                 with jax.named_scope("sel_attend"):
                     return self._attend_tile(lp, qn, qr, pools, row, qpos, last, form, keep) \
                         .astype(self.dtype)
@@ -225,7 +245,7 @@ class SelectedLatentServing(mla.LatentServing):
                 lambda: self._attend_tile(lp, qn, qr, pools, row, qpos, last, form)
                 .astype(self.dtype))
 
-        if self._walk(form, T, pools, t["rows"].shape[1]) == "kernel":
+        if kernel:
             return jnp.concatenate([one([v[k] for v in tiles]) for k in range(K)])
         o = jax.lax.map(one, tiles)
         return o.reshape((K * T,) + o.shape[2:])
@@ -276,6 +296,15 @@ class SelectedLatentServing(mla.LatentServing):
         return self._attn_out(lp, o)
 
     # -- a launch's counts ---------------------------------------------------------
+    def _prefill_plan(self, state, launch, t: dict) -> dict:
+        """And where the launch's picked tiles find their rows' thresholds:
+        ``kernel`` (``tile_scores``, in fast memory) where both it and the
+        walk that reads the pair are kernels, else ``xla`` (``kth_key`` over
+        device memory)."""
+        m = super()._prefill_plan(state, launch, t)
+        both = m["walk"] == "kernel" and self._index_walk(t["T"], state["ik"][0]) == "kernel"
+        return {**m, "threshold": "kernel" if both else "xla"}
+
     def _step_plan(self, state, live, pos) -> dict:
         """And the cache rows each lane's walk reads (whole key blocks)."""
         m = super()._step_plan(state, live, pos)
@@ -290,15 +319,18 @@ class SelectedLatentServing(mla.LatentServing):
     def _counts(self, m: dict) -> dict:
         """And, an attention layer: the pairs scored and kept and the rows
         walked of the live queries past ``index_topk``, and the live queries by
-        path; a step's rows attended are its lanes' picks."""
+        path; a step's rows attended are its lanes' picks; a launch's picked
+        tiles (one that holds no piece ends at 0) under their thresholds' path."""
         c, t, k = super()._counts(m), m["t"], self.index_topk
         picked = m["live"] & (m["pos"] >= k)
         if t is None:
             rows = m["lane_rows"]
-            c = {**c, "attended": jnp.sum(jnp.where(m["live"], jnp.minimum(m["pos"] + 1, k), 0))}
+            c = {**c, "attended": jnp.sum(jnp.where(m["live"], jnp.minimum(m["pos"] + 1, k), 0)),
+                 "sel_tiles": 0, "sel_threshold": "xla"}
         else:
             rows = jnp.repeat(self._blocks_needed(t["last"], m["P"], m["pps"])
                               * self._block_pages(m["P"], m["pps"]) * m["P"], t["T"])
+            c = {**c, "sel_tiles": jnp.sum(t["last"] >= k), "sel_threshold": m["threshold"]}
         return {**c, "sel_scored": jnp.sum(jnp.where(picked, m["pos"] + 1, 0)),
                 "sel_kept": k * jnp.sum(picked), "sel_walked": jnp.sum(jnp.where(picked, rows, 0)),
                 "sel_dense": jnp.sum(m["live"] & ~picked), "sel_picked": jnp.sum(picked)}

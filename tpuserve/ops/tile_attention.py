@@ -40,6 +40,14 @@ part ``s % g`` of row ``s // g``: a 0/1 product repeats each row ``g`` times
 elsewhere; the query's rotary part repeated ``g`` times then gives ``q_rope .
 k_r(s)`` as one contraction of 128 lanes.
 
+ATTENTION OVER PICKS (``keep=``, ISSUES 62 and 65): the rows' index scores and
+a threshold a row, made by ``ops/index_select.py`` (in fast memory, by the
+kernel that makes the scores). A cell reads its key block's (T, c) float32
+columns of the scores beside the pages and the (T, 128) thresholds, and a row
+sees a key only where its score is at or above its threshold: one compare
+beside the causal one, no mask in device memory, nothing added to the head
+loop. Without ``keep`` the kernel's text is what it was.
+
 Why a kernel: at the cell's sizes the walk in XLA moved 150-200 MB a (tile,
 key block) (the expanded keys and values written, read once a query block,
 a float32 partial context and two statistics padded to 128 lanes written and
@@ -65,7 +73,7 @@ HEAD_ROWS = 4096   # query rows (heads x tile rows) a cell holds
 def _kernel(pos0_ref, rows_ref, q_ref, w_ref, *refs, scale: float, kb: int, g: int, dn: int,
             bq: int, bk: int, picked: bool = False):
     del rows_ref   # the index maps read it
-    keep_ref, refs = (refs[0], refs[1:]) if picked else (None, refs)
+    (keep_ref, least_ref), refs = (refs[:2], refs[2:]) if picked else ((None, None), refs)
     ckv_refs, kr_refs, o_ref = refs[:kb], refs[kb:2 * kb], refs[2 * kb]
     c_ref, k_ref, v_ref, m_ref, l_ref, acc_ref = refs[2 * kb + 1:]
     hb, t, _ = q_ref.shape
@@ -99,8 +107,8 @@ def _kernel(pos0_ref, rows_ref, q_ref, w_ref, *refs, scale: float, kb: int, g: i
         s = jax.lax.dot_general(q_ref[h, at_q, :], k_ref[at_k, :],
                                 (((1,), (1,)), ((), ())), **f32) * scale
         see = ki * bk + iota((bq, bk), 1) <= qi * bq + iota((bq, bk), 0) + off
-        if picked:   # and the row picked the key
-            see = see & (keep_ref[at_q, at_k] > 0)
+        if picked:   # and the row picked the key: its score is at or above its threshold
+            see = see & (keep_ref[at_q, at_k] >= least_ref[at_q, :1])
         s = jnp.where(see, s, NEG)
         m_prev, l_prev = m_ref[h, at_q, :1], l_ref[h, at_q, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -149,14 +157,18 @@ def fits(t: int, page: int, block_pages: int, r: int, dn: int, dv: int, kr_lanes
 
 def tile_walk(q: jax.Array, w_kvb: jax.Array, ckv: jax.Array, kr: jax.Array, rows: jax.Array,
               need: jax.Array, pos0: jax.Array, *, block_pages: int, scale: float,
-              keep: jax.Array | None = None, interpret: bool = False) -> jax.Array:
-    """``keep`` (T, key blocks x c) float32, or None: ATTENTION OVER PICKS (ISSUE
-    62). Row ``i`` attends key ``s`` only where ``keep[i, s] > 0`` (and ``s <=
-    pos0 + i``): a cell reads its key block's (T, c) columns of it beside the
-    pages. A row whose keys so far are all left out carries weights of one
-    until its first kept key, which scales them away (every row keeps one
-    somewhere). An operand only where there is one: without it the kernel is
-    what it was."""
+              keep: tuple | None = None, interpret: bool = False) -> jax.Array:
+    """``keep``, or None: ATTENTION OVER PICKS (ISSUE 62), as the pair the
+    indexer leaves (ISSUE 65; ``ops/index_select.py`` ``tile_scores``, or
+    ``scores_xla`` and ``thresholds``): the rows' index SCORES (T, key blocks x
+    c) float32 and each row's THRESHOLD (T, 128) float32, a row's value across
+    the lanes (as ``m_ref`` lies). Row ``i`` attends key ``s`` only where
+    ``scores[i, s] >= threshold[i]`` (and ``s <= pos0 + i``): a cell reads its
+    key block's (T, c) columns of the scores beside the pages and compares; no
+    mask is made in device memory. A row whose keys so far are all left out
+    carries weights of one until its first kept key, which scales them away
+    (every row keeps one somewhere). Operands only where there are any:
+    without them the kernel is what it was."""
     h, t, dq = q.shape
     r, dkv = w_kvb.shape[1:]
     pages, P = ckv.shape[:2]
@@ -175,14 +187,15 @@ def tile_walk(q: jax.Array, w_kvb: jax.Array, ckv: jax.Array, kr: jax.Array, row
         + item * c * (r + dq + dv) + 4 * hb * t * (dv + 256) + 4 * (c * dkv + 4 * bq * bk)
     picked = keep is not None
     if picked:
-        vmem += 2 * 4 * t * c
+        vmem += 2 * 4 * t * (c + 128)
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, kb=kb, g=g, dn=dn, bq=bq, bk=bk,
                           **({"picked": True} if picked else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(h // hb, need.astype(jnp.int32)),
             in_specs=[pl.BlockSpec((hb, t, dq), by_head), pl.BlockSpec((hb, r, dkv), by_head)]
-            + [pl.BlockSpec((t, c), lambda hi, j, pos0, rows: (0, j))] * picked
+            + [pl.BlockSpec((t, c), lambda hi, j, pos0, rows: (0, j)),
+               pl.BlockSpec((t, 128), lambda hi, j, pos0, rows: (0, 0))] * picked
             + [pl.BlockSpec((None, P, r), page(i)) for i in range(kb)]
             + [pl.BlockSpec((None, P // g, lanes), page(i)) for i in range(kb)],
             out_specs=pl.BlockSpec((t, hb * dv), lambda hi, j, pos0, rows: (0, hi)),
@@ -196,5 +209,5 @@ def tile_walk(q: jax.Array, w_kvb: jax.Array, ckv: jax.Array, kr: jax.Array, row
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=min(vmem + (16 << 20), 100 << 20)),
         interpret=interpret, name="tile_walk",
-    )(jnp.reshape(pos0, (1,)).astype(jnp.int32), rows, q, w_kvb, *([keep] * picked),
+    )(jnp.reshape(pos0, (1,)).astype(jnp.int32), rows, q, w_kvb, *(keep or ()),
       *([ckv] * kb), *([kr] * kb)).reshape(t, h, dv)
