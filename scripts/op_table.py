@@ -4,10 +4,15 @@ PERF.md section 5 ("a launch's N ms by operation") in one call.
 An operation's event on a chip's `XLA Ops` line is named by its HLO text; the
 trace's own copy of the program (`benchmark/ssm_window.py` `scope_map`) gives
 each instruction the `op_name` it was traced under, `jit(step)/.../moe_dispatch/
-sort`. A row of the table is a `jax.named_scope` of the program (`SCOPES`)
-with the kind of instruction under it, or, outside every scope, the kind
-alone. Operations nest on that line (a `while` or a `conditional` and what runs
-inside it), so the containers are listed apart and left out of the sum.
+sort`. A row of the table is a `jax.named_scope` of the program, the innermost
+on the instruction's path, with the kind of instruction under it, or, outside
+every scope, the kind alone. What is a scope is told by the path's shape, by
+`benchmark/launch_scopes.py`'s rule (no list here: a scope a PR adds to the
+program is a row with no edit), and a traced run of a cell prints that file's
+table by CHAIN of scopes for both programs; this one is by operation, of any
+program of any trace. Operations nest on that line (a `while` or a
+`conditional` and what runs inside it), so the containers are listed apart and
+left out of the sum.
 
 Used by `scripts/bench_hybrid.py` and `scripts/bench_prefill.py`; reads any
 `*.xplane.pb`:  python scripts/op_table.py [--longest] <trace.xplane.pb> [module prefix ...]
@@ -16,20 +21,14 @@ Used by `scripts/bench_hybrid.py` and `scripts/bench_prefill.py`; reads any
 from __future__ import annotations
 
 import os
-import re
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+from benchmark.launch_scopes import CONTAINERS, chain, kind, scopes_of  # noqa: E402
 from benchmark.ssm_window import scope_map  # noqa: E402
 from benchmark.trace_reduce import DEVICE_PLANE, MODULES_LINE, OPS_LINE, op_name  # noqa: E402
-
-# An inner scope before the one that holds it: a row is the first that matches.
-SCOPES = ("moe_route", "moe_experts", "moe_dispatch", "ssm_scan", "ssm_update", "mla_prefill",
-          "mla_decode", "attn_ring", "attn_full_walk", "attn_prefill", "attn_decode",
-          "eva_summarise", "eva_prefill", "eva_decode", "sample")
-CONTAINERS = ("while", "conditional", "call")
 
 
 def dispatch_of(model, tokens: int, acc_delta) -> dict:
@@ -47,11 +46,6 @@ def dispatch_of(model, tokens: int, acc_delta) -> dict:
     return {"picks": picks, "rows_carried_compact": _row_bound(picks, model.e_count,
                                                                model.n_experts),
             "expert_layers_run": ran, "compact": compact, "wide": ran - compact}
-
-
-def _kind(instruction: str) -> str:
-    """`fusion.12` -> `fusion`; `gather_fusion.3` -> `gather_fusion`."""
-    return re.sub(r"[.\d]+$", "", instruction) or instruction
 
 
 def last_launches(path: str, longest: bool = False) -> dict[str, dict]:
@@ -87,15 +81,15 @@ def table(ops: list[tuple[str, int, str]]) -> tuple[list[tuple[str, float, int]]
     """[(row, ms, operations)] most time first, and the containers' ms."""
     rows: dict[str, list] = {}
     inside = 0
+    known = scopes_of(traced_as for _inst, _ns, traced_as in ops)
     for inst, ns, traced_as in ops:
-        kind = _kind(inst)
-        if kind in CONTAINERS:
+        what = kind(inst)
+        if what in CONTAINERS:
             inside += ns
             continue
-        scope = next((s for s in SCOPES if f"/{s}/" in traced_as or traced_as.endswith("/" + s)),
-                     None)
+        scopes = chain(traced_as, known)
         prim = traced_as.rsplit("/", 1)[-1] if traced_as else ""
-        row = f"{scope}: {kind}" if scope else f"{kind} ({prim})" if prim else kind
+        row = f"{scopes[-1]}: {what}" if scopes else f"{what} ({prim})" if prim else what
         got = rows.setdefault(row, [0, 0])
         got[0] += ns
         got[1] += 1

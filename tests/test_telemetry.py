@@ -160,6 +160,44 @@ def test_sampler_thread_stops_cleanly():
                for t in threading.enumerate())
 
 
+class _LateEvent(threading.Event):
+    """An event whose timed wait comes back ``late_s`` after it was due, as a
+    thread's does when the machine or the process is held."""
+
+    def __init__(self, late_s: float) -> None:
+        super().__init__()
+        self.late_s = late_s
+
+    def wait(self, timeout=None):
+        woke = super().wait(timeout)
+        if not woke:
+            time.sleep(self.late_s)
+        return woke
+
+
+def test_sampler_counts_how_late_it_woke():
+    """``host_stall_seconds_total`` (ISSUE 66): what a wait of ``interval_s``
+    took beyond ``interval_s``, summed. A thread whose wake-up is held back 50
+    ms a tick counts 50 ms a tick; one that wakes on time counts the
+    scheduler's own lateness, a small part of that."""
+    def run(late_s: float, ticks: int = 4):
+        m = Metrics(16)
+        store = TimeSeriesStore(m, capacity=8)
+        s = MetricSampler(store, 0.02)
+        s._stop_ev = _LateEvent(late_s)
+        s.start()
+        deadline = time.time() + 10.0
+        while store.samples_total < ticks and time.time() < deadline:
+            time.sleep(0.005)
+        s.stop()
+        assert not s.is_alive() and store.samples_total >= ticks
+        return m.counter("host_stall_seconds_total").value / store.samples_total
+
+    held, quiet = run(0.05), run(0.0)
+    assert 0.045 <= held < 0.2, held
+    assert 0.0 <= quiet < 0.02, quiet
+
+
 # ---------------------------------------------------------------------------
 # SLO engine
 # ---------------------------------------------------------------------------

@@ -56,8 +56,8 @@ from tpuserve.config import ModelConfig
 from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN,  # noqa: F401
                                       EXPERT_COLUMNS, KEY_BLOCK, LOGPROBS, MAX_PIECES,
                                       NEG, SAMPLE_COLUMNS, PagedLM, _mm, head_share,
-                                      read_config_file, rms_norm)
-from tpuserve.ops.moe import held_experts_swiglu, topk_route
+                                      read_config_file, rms_norm, scoped)
+from tpuserve.ops.moe import held_experts_swiglu, router_logits, topk_route
 
 # Standard deviations of the drawn tensors, by role (``weight_scales`` in the
 # config file overrides any). Projections keep a unit-RMS stream at unit RMS;
@@ -120,6 +120,12 @@ def apply_rope(x: jax.Array, pos: jax.Array, inv_freq: np.ndarray,
     x1, x2, rest = xf[..., :dim // 2], xf[..., dim // 2:dim], xf[..., dim:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1).astype(x.dtype)
+
+
+def _attn_scope(t) -> str:
+    """The scope a layer's attention stands under in a trace, by the phase
+    its plan is of (``t``: the tiles; None in a step)."""
+    return "attn_decode" if t is None else "attn_prefill"
 
 
 class DecoderServing(PagedLM):
@@ -256,6 +262,7 @@ class DecoderServing(PagedLM):
         }
 
     # -- device math --------------------------------------------------------------
+    @scoped("proj")
     def _qkv(self, lp: dict, i: int, u: jax.Array, pos: jax.Array):
         """``u`` (T, d) normed stream at positions ``pos`` (T,) -> rotated q
         (T, H, dk), rotated k (T, KV, dk), v (T, KV, dv) times ``value_scale``,
@@ -274,6 +281,7 @@ class DecoderServing(PagedLM):
         return (apply_rope(q, pos, inv, factor, dim),
                 apply_rope(k, pos, inv, factor, dim), v, gate)
 
+    @scoped("proj")
     def _attn_out(self, lp, o, gate):
         """o (T, H, dv) float32 -> (T, d): gated by head, through W_o."""
         if gate is not None:
@@ -290,15 +298,16 @@ class DecoderServing(PagedLM):
         held experts' part, and the shared expert where there is one."""
         if self.mlp_types[i] == "dense":
             return self._swiglu(u, lp["w_gate"], lp["w_up"], lp["w_down"]), None
-        r = jnp.matmul(u.astype(jnp.float32), lp["router"].astype(jnp.float32),
-                       precision=jax.lax.Precision.HIGHEST)
-        if self.softcap > 0:
-            r = self.softcap * jnp.tanh(r / self.softcap)
-        w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
-                          scoring=self.scoring, select_bias=lp.get("e_bias"))
-        y, stats = held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"],
-                                       lp["e_up"], lp["e_down"], live=live,
-                                       of=self.n_experts)
+        with jax.named_scope("moe_layer"):
+            r = router_logits(u, lp["router"])
+            if self.softcap > 0:
+                with jax.named_scope("moe_route"):
+                    r = self.softcap * jnp.tanh(r / self.softcap)
+            w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
+                              scoring=self.scoring, select_bias=lp.get("e_bias"))
+            y, stats = held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"],
+                                           lp["e_up"], lp["e_down"], live=live,
+                                           of=self.n_experts)
         if not self.shared_width:
             return y, stats
         return y + self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"]), stats
@@ -368,12 +377,13 @@ class DecoderServing(PagedLM):
         the pages, then the tiles' walks or the lanes' decode -> (o as ``q``
         lies, the two pools)."""
         t = m["t"]
-        qt = None if t is None else q.reshape((t["K"], t["T"]) + q.shape[1:])
-        kp = self._write_pages(kp, m["w_page"], m["off"], k)
-        vp = self._write_pages(vp, m["w_page"], m["off"], v)
-        if t is None:
-            return self._decode_full(q, kp, vp, m["bt"], m["pos"]), kp, vp
-        return self._prefill_full_tiles(qt, (kp, vp), t).reshape(q.shape), kp, vp
+        with jax.named_scope(_attn_scope(t)):
+            qt = None if t is None else q.reshape((t["K"], t["T"]) + q.shape[1:])
+            kp = self._write_pages(kp, m["w_page"], m["off"], k)
+            vp = self._write_pages(vp, m["w_page"], m["off"], v)
+            if t is None:
+                return self._decode_full(q, kp, vp, m["bt"], m["pos"]), kp, vp
+            return self._prefill_full_tiles(qt, (kp, vp), t).reshape(q.shape), kp, vp
 
     def _attend_window(self, q, k, v, rk, rv, m: dict):
         """A window layer's attention in either phase -> (o (T, H, dv), the
@@ -383,18 +393,21 @@ class DecoderServing(PagedLM):
         A ring is (slots + 1, W, KV, width)."""
         t, w_ring, roff = m["t"], m["w_ring"], m["roff"]
 
+        @scoped("cache_write")
         def put(ring, rows):
             return ring.at[w_ring, roff].set(rows)
 
-        if t is None:
+        with jax.named_scope(_attn_scope(t)):
+            if t is None:
+                rk, rv = put(rk, k), put(rv, v)
+                return self._attend(q[:, None], jnp.take(rk, w_ring, axis=0),
+                                    jnp.take(rv, w_ring, axis=0), m["mask_win"])[:, 0], rk, rv
+            o = self._prefill_window(q.reshape((t["K"], t["T"]) + q.shape[1:]), k, v,
+                                     jnp.take(rk, t["rings"], axis=0),
+                                     jnp.take(rv, t["rings"], axis=0),
+                                     t["qpos"], m["rpos"], m["pos"], m["own"])
             rk, rv = put(rk, k), put(rv, v)
-            return self._attend(q[:, None], jnp.take(rk, w_ring, axis=0),
-                                jnp.take(rv, w_ring, axis=0), m["mask_win"])[:, 0], rk, rv
-        o = self._prefill_window(q.reshape((t["K"], t["T"]) + q.shape[1:]), k, v,
-                                 jnp.take(rk, t["rings"], axis=0), jnp.take(rv, t["rings"], axis=0),
-                                 t["qpos"], m["rpos"], m["pos"], m["own"])
-        rk, rv = put(rk, k), put(rv, v)
-        return o.reshape(q.shape[:-1] + o.shape[-1:]), rk, rv
+            return o.reshape(q.shape[:-1] + o.shape[-1:]), rk, rv
 
     def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
         q, k, v, gate = self._qkv(lp, i, rms_norm(x, lp["norm1"], self.eps), m["pos"])

@@ -86,7 +86,8 @@ import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
 from tpuserve.models import decoder as dec
-from tpuserve.models.paged_lm import Column, Heads, counted, read_config_file, rms_norm, series
+from tpuserve.models.paged_lm import (Column, Heads, counted, read_config_file, rms_norm,
+                                      scoped, series)
 from tpuserve.ops import lane_attention as la
 
 # What this family draws otherwise than ``decoder``: sigmoid scores are decided
@@ -295,6 +296,7 @@ class SinkDecoderServing(dec.DecoderServing):
         dr, t, w_ring, roff = self.turning[KINDS[1]], m["t"], m["w_ring"], m["roff"]
         new = (k[..., dr:], k[..., :dr], v)
 
+        @scoped("cache_write")
         def put():
             return tuple(ring.at[w_ring, roff].set(rows.reshape(rows.shape[0], -1))
                          for ring, rows in zip(rings, new))
@@ -322,7 +324,7 @@ class SinkDecoderServing(dec.DecoderServing):
         full = self.layer_types[i] == KINDS[0]
         leaves, j = (self.kv_page_leaves, self.full_layers.index(i)) if full \
             else (RING_LEAVES, self.win_layers.index(i))
-        with jax.named_scope("attn_decode" if m["t"] is None else "attn_prefill"):
+        with jax.named_scope(dec._attn_scope(m["t"])):
             q, k, v, _ = self._qkv(lp, i, rms_norm(x, lp["norm1"], self.eps), m["pos"])
             held = tuple(c[leaf][j] for leaf in leaves)
             if full:

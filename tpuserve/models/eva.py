@@ -90,7 +90,7 @@ import jax.numpy as jnp
 from tpuserve.config import ModelConfig
 from tpuserve.models import decoder as dec
 from tpuserve.models.paged_lm import (CONTEXT_COLUMN, NEG, SAMPLE_COLUMNS, Column, _mm, counted,
-                                      read_config_file, series)
+                                      read_config_file, scoped, series)
 from tpuserve.ops import lane_attention as la
 from tpuserve.ops import launch_attention as lat
 
@@ -222,6 +222,7 @@ class EvaServing(dec.DecoderServing):
                 "ring": S((slots,), jnp.int32)}
 
     # -- device math --------------------------------------------------------------
+    @scoped("norm")
     def _norm(self, x, g):
         """RMSNorm in float32 with the gain ``1 + g``, its result in the served type."""
         xf = x.astype(jnp.float32)
@@ -457,7 +458,8 @@ class EvaServing(dec.DecoderServing):
             o = self._attend_tiles(q, k, v, kp, vp, m)
         # whole pages of the rings: ONE scatter of C / P slabs a pool, a slab a page's bytes
         runs = m["ring_runs"]
-        return o, kp.at[runs].set(self._as_pages(k)), vp.at[runs].set(self._as_pages(v))
+        with jax.named_scope("cache_write"):
+            return o, kp.at[runs].set(self._as_pages(k)), vp.at[runs].set(self._as_pages(v))
 
     def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
         q, k, v, _ = self._qkv(lp, i, self._norm(x, lp["norm1"]), m["pos"])

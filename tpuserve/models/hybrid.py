@@ -67,8 +67,8 @@ from tpuserve.config import ModelConfig
 from tpuserve.models.mixers import SSM_COLUMNS, PatternMixers
 from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN,  # noqa: F401
                                       EXPERT_COLUMNS, LOGPROBS, SAMPLE_COLUMNS, PagedLM, _mm,
-                                      head_share, read_config_file, rms_norm)
-from tpuserve.ops.moe import held_experts, relu2, topk_route
+                                      head_share, read_config_file, rms_norm, scoped)
+from tpuserve.ops.moe import held_experts, relu2, router_logits, topk_route
 
 # Standard deviations of the drawn tensors, by role (``weight_scales`` in the
 # config file overrides any). The state's two sides (B, C) and the query/key
@@ -179,20 +179,23 @@ class HybridServing(PatternMixers, PagedLM):
         return p
 
     # -- device math --------------------------------------------------------------
+    @scoped("ffn_dense")
     def _relu2(self, u, w1, w2):
         return _mm(relu2(_mm(u, w1)).astype(self.dtype), w2)
 
     def _experts(self, lp, u, live):
         """(T, d) -> ((T, d) float32, the expert layer's counts)."""
-        r = jnp.matmul(u.astype(jnp.float32), lp["router"].astype(jnp.float32),
-                       precision=jax.lax.Precision.HIGHEST)
-        w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
-                          scoring="sigmoid", select_bias=lp["e_bias"])
-        lat = _mm(u, lp["w_a"]).astype(self.dtype)
-        y, stats = held_experts(lat, w, e, self.e_first, (lp["e_w1"],), lp["e_w2"], relu2,
-                                live=live, of=self.n_experts)
-        return _mm(y.astype(self.dtype), lp["w_b"]) \
-            + self._relu2(u, lp["s_w1"], lp["s_w2"]), stats
+        with jax.named_scope("moe_layer"):
+            r = router_logits(u, lp["router"])
+            w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
+                              scoring="sigmoid", select_bias=lp["e_bias"])
+            with jax.named_scope("proj"):
+                lat = _mm(u, lp["w_a"]).astype(self.dtype)
+            y, stats = held_experts(lat, w, e, self.e_first, (lp["e_w1"],), lp["e_w2"], relu2,
+                                    live=live, of=self.n_experts)
+            with jax.named_scope("proj"):
+                y = _mm(y.astype(self.dtype), lp["w_b"])
+        return y + self._relu2(u, lp["s_w1"], lp["s_w2"]), stats
 
     def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
         u = rms_norm(x, lp["norm"], self.eps)

@@ -108,14 +108,17 @@ class RoutedExperts:
     def _routed(self, lp, u, live):
         """(T, d) -> ((T, d) float32: the held experts' part, the expert
         layer's counts)."""
-        r = jnp.matmul(u.astype(jnp.float32), lp["router"].astype(jnp.float32),
-                       precision=jax.lax.Precision.HIGHEST)
-        w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
-                          scoring=self.route_scoring,
-                          select_bias=lp["e_bias"] if self.route_bias else None,
-                          eps=self.route_eps)
-        return held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"], lp["e_up"],
-                                   lp["e_down"], live=live, of=self.n_experts)
+        with jax.named_scope("moe_layer"):
+            # Here and not under ``moe_route``: ``moe_dispatch_step_ms`` reads that
+            # scope with ``moe_dispatch`` where this block's launch is the cell's.
+            r = jnp.matmul(u.astype(jnp.float32), lp["router"].astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST)
+            w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
+                              scoring=self.route_scoring,
+                              select_bias=lp["e_bias"] if self.route_bias else None,
+                              eps=self.route_eps)
+            return held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"], lp["e_up"],
+                                       lp["e_down"], live=live, of=self.n_experts)
 
     def _shared(self, lp, u):
         """(T, d) -> (T, d) float32: the shared expert, whole here."""

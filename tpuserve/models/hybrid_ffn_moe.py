@@ -25,8 +25,9 @@ router's experts (a pick on another chip's expert adds nothing here; the shared
 expert, the router, the mixers and every norm are whole) and ``share.vocab_rows =
 [first, count]``. No code stands in for the other chips or their exchange.
 
-In a device trace ``jax.named_scope("moe_layer")`` holds the router, the picks,
-the dispatch and the experts' products (``moe_route``, ``moe_dispatch``,
+In a device trace ``jax.named_scope("moe_layer")`` (``RoutedExperts._routed``'s,
+as every routed family's block since ISSUE 66) holds the router, the picks, the
+dispatch and the experts' products (``moe_route``, ``moe_dispatch``,
 ``moe_experts`` inside it, ``ops/moe.py``) and ``jax.named_scope("moe_shared")``
 the shared expert.
 """
@@ -96,8 +97,7 @@ class HybridFfnMoeServing(RoutedExperts, HybridFfnServing):
         """The stream through the routed block -> (the stream, the expert
         layer's counts)."""
         v = rms_norm(x, lp["norm2"], self.eps)
-        with jax.named_scope("moe_layer"):
-            y, stats = self._routed(lp, v, live)
+        y, stats = self._routed(lp, v, live)   # under ``moe_layer``
         with jax.named_scope("moe_shared"):
             y = y + self._shared(lp, v)
         return self._add(x, y), stats

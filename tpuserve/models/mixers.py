@@ -89,7 +89,9 @@ their full counts): grouped KV heads, causal, NO position term, no bias; K and
 V in pages of the engine's ledger; where the family sets ``attn_gate``, the
 context times ``sigmoid(u W_g)``, elementwise by head, before ``W_o``. A step's
 whole mixer, from the projections to ``W_o``, runs under
-``jax.named_scope("attn_decode")``. ``RotaryAttention`` is that with the rows'
+``jax.named_scope("attn_decode")``, a launch's under ``attn_prefill``; inside
+either, and inside ``ssm_update``, the projections are ``proj`` and the pages'
+writes ``cache_write`` (``paged_lm``'s vocabulary). ``RotaryAttention`` is that with the rows'
 positions read where q and k are made (``_qkv(lp, u, pos)``: ``m["pos"]``, a
 step's lanes' or a launch's rows'): ``q <- rope(RMSNorm(q; g_q), pos)``, ``k``
 alike, the norm over a head's ``hd`` columns with ONE gain for all heads, FIRST,
@@ -106,7 +108,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpuserve.models.decoder import apply_rope
-from tpuserve.models.paged_lm import Column, _mm, counted, rms_norm, series
+from tpuserve.models.paged_lm import Column, _mm, counted, rms_norm, scoped, series
 from tpuserve.ops import delta_scan as ds
 from tpuserve.ops import delta_update as du
 
@@ -265,6 +267,7 @@ class Mamba2Mixer(RecurrentMixer):
                          for _ in self.m_layers]}
 
     # -- device math --------------------------------------------------------------
+    @scoped("proj")
     def _split_in(self, lp: dict, u: jax.Array):
         """``u`` (T, d) -> z (T, H, P), xBC (T, channels) before the
         convolution, dt (T, H) in float32."""
@@ -289,6 +292,7 @@ class Mamba2Mixer(RecurrentMixer):
         delta = jnp.where(live[..., None], jax.nn.softplus(dt + lp["dt_bias"]), 0.0)
         return delta, -jnp.exp(lp["A_log"]) * delta
 
+    @scoped("norm")
     def _gated_norm(self, lp: dict, y: jax.Array, z: jax.Array) -> jax.Array:
         """y (T, H, P) float32 gated by silu(z) and normed over each GROUP of
         heads (gate before norm) -> (T, H, P) in the served type."""
@@ -298,6 +302,7 @@ class Mamba2Mixer(RecurrentMixer):
         g = g.reshape(t, self.mh, self.mp) * lp["gate_norm"].astype(jnp.float32)
         return g.astype(self.dtype)
 
+    @scoped("proj")
     def _out_proj(self, lp: dict, g: jax.Array) -> jax.Array:
         return jnp.einsum("thp,hpd->td", g, lp["w_out"], preferred_element_type=jnp.float32)
 
@@ -523,6 +528,7 @@ class DeltaMixer(RecurrentMixer):
 
         return unit(q) * self.kd ** -0.5, unit(k), v
 
+    @scoped("proj")
     def _decay_beta(self, lp: dict, u: jax.Array, live: jax.Array):
         """``u`` (T, d), ``live`` (T,) -> (the log-decay a channel ``g`` (T, H, D)
         <= 0, ``beta`` (T, H)), float32, both zero where a row is not live: its
@@ -542,6 +548,7 @@ class DeltaMixer(RecurrentMixer):
                           preferred_element_type=jnp.float32) + lp["b_g"]
         return (o * jax.nn.sigmoid(gate)).astype(self.dtype)
 
+    @scoped("proj")
     def _delta_out(self, lp: dict, y: jax.Array) -> jax.Array:
         return jnp.einsum("thp,hpd->td", y, lp["w_out"], preferred_element_type=jnp.float32)
 
@@ -635,7 +642,8 @@ class DeltaMixer(RecurrentMixer):
         """One delta-rule layer of a launch. The scope ``ssm_scan`` is the scan
         alone, from the convolution to the gated norm: the projections are
         outside it."""
-        qkv = _mm(u, lp["w_qkv"]).astype(self.dtype)
+        with jax.named_scope("proj"):
+            qkv = _mm(u, lp["w_qkv"]).astype(self.dtype)
         g, beta = self._decay_beta(lp, u, t["valid"])
         with jax.named_scope("ssm_scan"):
             (s0,), (c0,) = self._piece_starts(slot, start, states=(ssm,), rows=(conv,))
@@ -662,7 +670,8 @@ class DeltaMixer(RecurrentMixer):
         mixer, from the projections to the out-projection; inside it
         ``delta_update`` is the state's update and read alone."""
         with jax.named_scope("ssm_update"):
-            qkv = _mm(u, lp["w_qkv"]).astype(self.dtype)
+            with jax.named_scope("proj"):
+                qkv = _mm(u, lp["w_qkv"]).astype(self.dtype)
             seq = jnp.concatenate([conv, qkv[:, None]], axis=1)          # (b, k, ch)
             q, k, v = self._delta_heads(jnp.sum(
                 seq.astype(jnp.float32) * lp["conv_w"].astype(jnp.float32)[None], axis=1))
@@ -741,6 +750,7 @@ class ConvMixer(RecurrentMixer):
                          for _ in self.m_layers]}
 
     # -- device math --------------------------------------------------------------
+    @scoped("proj")
     def _conv_in(self, lp: dict, u: jax.Array):
         """``u`` (T, d) -> (b = B * z, the row a slot keeps, and the gate C),
         both (T, d) in the served type."""
@@ -763,7 +773,8 @@ class ConvMixer(RecurrentMixer):
             _last, c_end = self._piece_ends(t, live, seq)
             (conv,) = self._store_pieces((conv,), slot, length, (c_end,))
             y = self._conv_gate(gate, c.reshape(b.shape))
-        return _mm(y, lp["w_out"]), conv
+        with jax.named_scope("proj"):
+            return _mm(y, lp["w_out"]), conv
 
     def _conv_step(self, lp, u, live, conv):
         """One row a lane: the rows of a lane that is not live stay as they
@@ -773,7 +784,9 @@ class ConvMixer(RecurrentMixer):
             b, gate = self._conv_in(lp, u)
             seq = jnp.concatenate([conv, b[:, None]], axis=1)            # (lanes, k, d)
             c = jnp.sum(seq.astype(jnp.float32) * lp["conv_w"].astype(jnp.float32)[None], axis=1)
-            out = _mm(self._conv_gate(gate, c), lp["w_out"])
+            y = self._conv_gate(gate, c)
+            with jax.named_scope("proj"):
+                out = _mm(y, lp["w_out"])
             new_conv = jnp.where(live[:, None, None], seq[:, 1:], conv)
         return out, new_conv
 
@@ -802,6 +815,7 @@ class PlainAttention:
                 yield ((L, "wg"), (d, self.heads, hd), (d, self.heads_full, hd),
                        (0, self.h_first, 0), s["gate"], d)
 
+    @scoped("proj")
     def _qkv(self, lp: dict, u: jax.Array, pos: "jax.Array | None" = None):
         """q, k, v by head of the rows ``u`` at positions ``pos`` (T,): here the
         positions are read by nothing."""
@@ -809,10 +823,12 @@ class PlainAttention:
                                 preferred_element_type=jnp.float32).astype(self.dtype)
                      for w in ("wq", "wk", "wv"))
 
+    @scoped("proj")
     def _attn_out(self, lp, o):
         return jnp.einsum("thk,hkd->td", o.astype(self.dtype), lp["wo"],
                           preferred_element_type=jnp.float32)
 
+    @scoped("proj")
     def _gated(self, lp, u, o):
         """The context ``o`` (T, H, hd) float32 times ``sigmoid(u W_g)`` where the
         family gates its attention; ``o`` itself elsewhere."""
@@ -823,11 +839,13 @@ class PlainAttention:
 
     def _attn_prefill(self, lp, u, t: dict, kp, vp, pos, w_page, off):
         """One attention layer of a launch: every row of the launch is in
-        the pages before any tile reads them."""
-        q, k, v = self._qkv(lp, u, pos)
-        kp, vp = self._write_pages(kp, w_page, off, k), self._write_pages(vp, w_page, off, v)
-        o = self._prefill_full_tiles(q.reshape((t["K"], t["T"]) + q.shape[1:]), (kp, vp), t)
-        return self._attn_out(lp, self._gated(lp, u, o.reshape(q.shape))), kp, vp
+        the pages before any tile reads them. The scope ``attn_prefill`` is the
+        whole mixer, as ``attn_decode`` is a step's."""
+        with jax.named_scope("attn_prefill"):
+            q, k, v = self._qkv(lp, u, pos)
+            kp, vp = self._write_pages(kp, w_page, off, k), self._write_pages(vp, w_page, off, v)
+            o = self._prefill_full_tiles(q.reshape((t["K"], t["T"]) + q.shape[1:]), (kp, vp), t)
+            return self._attn_out(lp, self._gated(lp, u, o.reshape(q.shape))), kp, vp
 
     def _attn_step(self, lp, u, kp, vp, bt, pos, w_page, off):
         """One attention layer of a decode step. The scope ``attn_decode`` is
@@ -861,6 +879,7 @@ class RotaryAttention(PlainAttention):
             for name in ("q_norm", "k_norm"):
                 yield ((f"layer{i}", name), (self.hd,), (self.hd,), (0,), lo, hi)
 
+    @scoped("proj")
     def _qkv(self, lp: dict, u: jax.Array, pos: jax.Array):
         q, k, v = super()._qkv(lp, u, pos)
         inv = self.rope_theta ** (-np.arange(0, self.hd, 2, dtype=np.float64) / self.hd)

@@ -97,10 +97,10 @@ from tpuserve.models.decoder import apply_rope, rope_inv_freq
 from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN,  # noqa: F401
                                       EXPERT_COLUMNS, KEY_BLOCK, LOGPROBS, NEG, Column,
                                       SAMPLE_COLUMNS, PagedLM, _mm, counted, read_config_file,
-                                      rms_norm, series)
+                                      rms_norm, scoped, series)
 from tpuserve.ops import lane_attention as la
 from tpuserve.ops import tile_attention as ta
-from tpuserve.ops.moe import held_experts_swiglu, topk_route
+from tpuserve.ops.moe import held_experts_swiglu, router_logits, topk_route
 
 # Standard deviations of the drawn tensors, by role (``weight_scales`` in the
 # config file overrides any). The query's up-projection, the keys'
@@ -329,11 +329,13 @@ class LatentServing(PagedLM):
         itself where the factor is 1."""
         return gain if factor == 1.0 else gain.astype(jnp.float32) * jnp.float32(factor)
 
+    @scoped("proj")
     def _query_latent(self, lp: dict, u: jax.Array) -> jax.Array:
         """``c_q`` (T, q_lora_rank), the query's normed latent."""
         return rms_norm(_mm(u, lp["w_qa"]).astype(self.dtype),
                         self._gain(lp["q_norm"], self.q_scale), self.eps)
 
+    @scoped("proj")
     def _project(self, lp: dict, u: jax.Array, pos: jax.Array, c_q: jax.Array | None = None):
         """``u`` (T, d) normed stream at positions ``pos`` (T,) -> q_nope (T, H,
         nope), rotated q_rope (T, H, rope), and what a token keeps: the normed
@@ -354,6 +356,7 @@ class LatentServing(PagedLM):
         q_rope = apply_rope(q[..., self.dn:], pos, inv, factor, dim, self.rope_interleave)
         return q[..., :self.dn], q_rope, c_kv, k_r
 
+    @scoped("cache_write")
     def _write_keys(self, pool, page, off, k_r, runs: bool):
         """Rotary keys ``k_r`` (T, rope) into their pool (pages, P / g, g x
         rope) at (page[t], off[t]). ``runs`` (a prefill launch): the rows come
@@ -410,7 +413,8 @@ class LatentServing(PagedLM):
         f32 = {"preferred_element_type": jnp.float32}
         scale = self.score_scale
         if form == "absorbed":
-            qn = jnp.einsum("thn,rhn->thr", qn, lp["w_kb"], **f32).astype(dt)   # q_lat
+            with jax.named_scope("proj"):
+                qn = jnp.einsum("thn,rhn->thr", qn, lp["w_kb"], **f32).astype(dt)   # q_lat
 
         def latents(j):
             pg = jax.lax.dynamic_slice(rowp, (j * kb,), (kb,))
@@ -447,7 +451,8 @@ class LatentServing(PagedLM):
 
         o = self._over_key_blocks(need, (h, T), r if form == "absorbed" else self.dv, block)
         if form == "absorbed":
-            return jnp.einsum("htr,rhv->thv", o.astype(dt), lp["w_vb"], **f32)
+            with jax.named_scope("proj"):
+                return jnp.einsum("htr,rhv->thv", o.astype(dt), lp["w_vb"], **f32)
         return o.transpose(1, 0, 2)
 
     def _attend_tiles(self, lp: dict, qn, qr, pools, t: dict, form: str):
@@ -485,12 +490,15 @@ class LatentServing(PagedLM):
         key only where it is above 0 (``mla_sel``)."""
         ckv, kr = pools
         f32 = {"preferred_element_type": jnp.float32}
-        q_lat = jnp.einsum("bhn,rhn->bhr", qn, lp["w_kb"], **f32).astype(self.dtype)
+        with jax.named_scope("proj"):
+            q_lat = jnp.einsum("bhn,rhn->bhr", qn, lp["w_kb"], **f32).astype(self.dtype)
         o = la.lane_walk(q_lat, jnp.concatenate([qr] * (ckv.shape[1] // kr.shape[1]), axis=-1),
                          ckv, kr, work, scale=self.score_scale,
                          **({} if keep is None else {"keep": keep}))
-        return jnp.einsum("bhr,rhv->bhv", o, lp["w_vb"], **f32)
+        with jax.named_scope("proj"):
+            return jnp.einsum("bhr,rhv->bhv", o, lp["w_vb"], **f32)
 
+    @scoped("proj")
     def _attn_out(self, lp, o):
         return jnp.einsum("thv,hvd->td", o.astype(self.dtype), lp["wo"],
                           preferred_element_type=jnp.float32)
@@ -499,13 +507,13 @@ class LatentServing(PagedLM):
         """(T, d) -> ((T, d) float32, the expert layer's counts or None)."""
         if i < self.first_dense:
             return self._swiglu(u, lp["w_gate"], lp["w_up"], lp["w_down"]), None
-        r = jnp.matmul(u.astype(jnp.float32), lp["router"].astype(jnp.float32),
-                       precision=jax.lax.Precision.HIGHEST)
-        w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
-                          scoring="sigmoid", select_bias=lp["e_bias"],
-                          **({"groups": self.groups} if self.groups else {}))
-        y, stats = held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"], lp["e_up"],
-                                       lp["e_down"], live=live, of=self.n_experts)
+        with jax.named_scope("moe_layer"):
+            r = router_logits(u, lp["router"])
+            w, e = topk_route(r, self.top_k, normalize=self.norm_topk, scale=self.route_scale,
+                              scoring="sigmoid", select_bias=lp["e_bias"],
+                              **({"groups": self.groups} if self.groups else {}))
+            y, stats = held_experts_swiglu(u, w, e, self.e_first, lp["e_gate"], lp["e_up"],
+                                           lp["e_down"], live=live, of=self.n_experts)
         return y + self._swiglu(u, lp["s_gate"], lp["s_up"], lp["s_down"]), stats
 
     # -- what a launch works out once, its layer, its counts -----------------------------

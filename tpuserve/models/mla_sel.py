@@ -64,7 +64,7 @@ import jax.numpy as jnp
 from tpuserve.config import ModelConfig
 from tpuserve.models import mla
 from tpuserve.models.decoder import apply_rope
-from tpuserve.models.paged_lm import Column, _mm, counted, read_config_file, series
+from tpuserve.models.paged_lm import Column, _mm, counted, read_config_file, scoped, series
 from tpuserve.ops import index_select as ix
 
 # ``mla``'s, the attention's three wide draws at 1.5 (every score carries the
@@ -176,6 +176,7 @@ class SelectedLatentServing(mla.LatentServing):
         inv, factor, dim = self.rope
         return apply_rope(x, pos, inv, factor, dim, self.rope_interleave)
 
+    @scoped("proj")
     def _project_index(self, lp: dict, u, c_q, pos):
         """-> the index queries (T, Hi, Di), their heads' weights (T, Hi)
         float32, and what a token keeps: its index key (T, Di)."""

@@ -46,10 +46,29 @@ lane row. A write is the same scatter of rows; prefill's key blocks are taken
 apart by head after the gather; decode on the TPU hands the paged-attention
 kernel each query head zero-padded into its own KV head's part of the row
 (the score is the same sum), and keeps that part of the context.
+
+WHAT A TRACE CALLS THE PARTS (ISSUE 66). Every heavy operation of the two
+programs lies under a ``jax.named_scope`` that names its KIND of work: the
+kernels' and mixers' own (``attn_*``, ``ssm_*``, ``mla_*``, ``moe_*``, ``eva_*``,
+``sel_*``, ``hc_mix``, ``delta_update``, ``sample``: each where its family says)
+and, for what stands between them, eight more: ``embed`` (the rows' gather, a
+family's multiplier), ``norm`` (``rms_norm`` and the families' own norms),
+``proj`` (a mixer's dense in- and out-projections outside its kernel or
+scan), ``ffn_dense`` (``_swiglu`` and its like: a dense feed-forward, a shared
+expert), ``cache_write`` (``_write_pages`` and the rings' and pools' other
+scatters), ``head`` (the last norm and the vocabulary product), ``plan`` (what a
+launch works out once, before its layers) and ``emit`` (what it leaves in the
+state after its sampler, and its row into ``acc``). A scope is metadata of the
+compiled program and costs nothing at run time. None holds a whole layer or a
+whole program; where an older scope already holds a projection or a norm
+(``ssm_update`` the whole Mamba mixer, ``mla_decode`` its projections) the new
+one nests inside it. ``benchmark/launch_scopes.py`` reads a trace by them, and
+``tests/test_program_scopes.py`` holds every family's programs to the rule.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import math
 from typing import Any, Callable, NamedTuple
@@ -70,6 +89,22 @@ KEY_BLOCK = 1024  # key positions a block of a full layer's prefill attention
 TOP_GROUP = 128   # neighbouring logits a group of the sampler's top-k (``_top_logits``)
 
 
+def scoped(name: str) -> Callable:
+    """``jax.named_scope(name)`` around every call of the function it decorates.
+    (``jax.named_scope`` is itself a decorator, but one object that keeps the
+    name stack it found on ITSELF: a function that calls itself, as
+    ``_write_pages`` does, or two threads that trace at once, leave the scope
+    on the stack.)"""
+    def wrap(f: Callable) -> Callable:
+        @functools.wraps(f)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return f(*args, **kwargs)
+        return inner
+    return wrap
+
+
+@scoped("norm")
 def rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
@@ -380,6 +415,7 @@ class PagedLM(GenerativeModel):
         return o.reshape(q.shape[:-1] + (v.shape[-1],))
 
     @staticmethod
+    @scoped("cache_write")
     def _write_pages(pool, page, off, rows):
         """``rows`` (T, KV, hd) into the pool (KV, pages, P, hd) at (page[t],
         off[t]) of every KV head: as ONE scatter of rows into the pool seen
@@ -399,6 +435,7 @@ class PagedLM(GenerativeModel):
         flat = pool.reshape(kv * n_pages * p_tokens, hd)
         return flat.at[at.reshape(-1)].set(rows.reshape(-1, hd)).reshape(pool.shape)
 
+    @scoped("ffn_dense")
     def _swiglu(self, u, w_gate, w_up, w_down):
         h = (jax.nn.silu(_mm(u, w_gate)) * _mm(u, w_up)).astype(self.dtype)
         return _mm(h, w_down)
@@ -477,19 +514,26 @@ class PagedLM(GenerativeModel):
         token sees its own prompt only, at its own positions; a piece that
         ends its prompt samples the first token at its own last row and arms
         its own lane."""
-        t = self._tiles(launch, chunk)
-        x = self._embed(params, launch["ids"])
-        m = dict(self._prefill_plan(state, launch, t),
-                 drawn=jnp.any((launch["length"] > 0) & (launch["temp"] > 0)))
+        with jax.named_scope("plan"):
+            t = self._tiles(launch, chunk)
+        with jax.named_scope("embed"):
+            x = self._embed(params, launch["ids"])
+        with jax.named_scope("plan"):
+            m = dict(self._prefill_plan(state, launch, t),
+                     drawn=jnp.any((launch["length"] > 0) & (launch["temp"] > 0)))
         x, new = self._layers(params, state, x, m)
         return self._arm(params, state, new, launch, t, x, m["lanes"], m["drawn"])
 
     def step(self, params: Any, state: Any) -> tuple[Any, dict]:
         """One token a live lane."""
-        live = state["armed"] & ~state["done"]
-        pos = jnp.clip(state["pos"], 0, self.max_ctx - 1)
-        x = self._embed(params, state["last"])
-        m = dict(self._step_plan(state, live, pos), drawn=jnp.any(live & (state["temp"] > 0)))
+        with jax.named_scope("plan"):
+            live = state["armed"] & ~state["done"]
+            pos = jnp.clip(state["pos"], 0, self.max_ctx - 1)
+        with jax.named_scope("embed"):
+            x = self._embed(params, state["last"])
+        with jax.named_scope("plan"):
+            m = dict(self._step_plan(state, live, pos),
+                     drawn=jnp.any(live & (state["temp"] > 0)))
         x, new = self._layers(params, state, x, m)
         return self._emit(params, state, new, x, live, pos, m["drawn"])
 
@@ -503,10 +547,11 @@ class PagedLM(GenerativeModel):
             x, st = self._layer(i, params[f"layer{i}"], x, caches, m)
             if st is not None:
                 stats.append(st)
-        counts = self._counts(m)
-        sums = [col.sums(self, stats, counts) for col in self.COLUMNS]
-        row = jnp.stack([jnp.asarray(v, jnp.int32) for v in sums])
-        acc = state["acc"].at[int(m["t"] is None)].add(row.astype(jnp.uint32))
+        with jax.named_scope("emit"):
+            counts = self._counts(m)
+            sums = [col.sums(self, stats, counts) for col in self.COLUMNS]
+            row = jnp.stack([jnp.asarray(v, jnp.int32) for v in sums])
+            acc = state["acc"].at[int(m["t"] is None)].add(row.astype(jnp.uint32))
         return x, dict(state, **caches, acc=acc)
 
     def _layer(self, i: int, lp: dict, x, caches: dict, m: dict):
@@ -741,21 +786,25 @@ class PagedLM(GenerativeModel):
         lanes the family keeps (a ring's index); ``drawn``: the launch's."""
         slot, start, length, n = (launch[f] for f in ("slot", "start", "length", "n"))
         K, T, C = t["K"], t["T"], t["C"]
-        is_final = (length > 0) & (start + length >= n)
-        h_last = jnp.take(x, jnp.clip(t["first_tile"] * T + n - 1 - start, 0, C - 1), axis=0)
-        first, lp_ids, lp_vals = self._sample(
-            self._head(params, h_last), launch["seed"], n, launch["temp"], drawn)
-        at = jnp.where(length > 0, slot, state["pos"].shape[0])
-        lanes = {"bt": launch["pages"], **extra,
-                 "tokens": jnp.zeros((K, self.max_new), jnp.int32).at[:, 0].set(first),
-                 "pos": jnp.where(is_final, n, 0), "n_new": jnp.where(is_final, 1, 0),
-                 "last": first, "armed": is_final,
-                 "done": is_final & (launch["max_new"] <= 1), "seed": launch["seed"],
-                 "max_new": launch["max_new"], "temp": launch["temp"]}
-        for name, val in lanes.items():
-            new[name] = state[name].at[at].set(val.astype(state[name].dtype), mode="drop")
-        for name, val in (("lp_ids", lp_ids), ("lp", lp_vals)):
-            new[name] = state[name].at[at, 0].set(val.astype(state[name].dtype), mode="drop")
+        with jax.named_scope("emit"):
+            is_final = (length > 0) & (start + length >= n)
+        with jax.named_scope("head"):
+            h_last = jnp.take(x, jnp.clip(t["first_tile"] * T + n - 1 - start, 0, C - 1), axis=0)
+            logits = self._head(params, h_last)
+        first, lp_ids, lp_vals = self._sample(logits, launch["seed"], n, launch["temp"], drawn)
+        with jax.named_scope("emit"):
+            at = jnp.where(length > 0, slot, state["pos"].shape[0])
+            lanes = {"bt": launch["pages"], **extra,
+                     "tokens": jnp.zeros((K, self.max_new), jnp.int32).at[:, 0].set(first),
+                     "pos": jnp.where(is_final, n, 0), "n_new": jnp.where(is_final, 1, 0),
+                     "last": first, "armed": is_final,
+                     "done": is_final & (launch["max_new"] <= 1), "seed": launch["seed"],
+                     "max_new": launch["max_new"], "temp": launch["temp"]}
+            for name, val in lanes.items():
+                new[name] = state[name].at[at].set(val.astype(state[name].dtype), mode="drop")
+            for name, val in (("lp_ids", lp_ids), ("lp", lp_vals)):
+                new[name] = state[name].at[at, 0].set(val.astype(state[name].dtype),
+                                                      mode="drop")
         return new
 
     # -- decode -------------------------------------------------------------------
@@ -828,26 +877,30 @@ class PagedLM(GenerativeModel):
         token from its last row ``x`` (b, d), keeps it with its
         log-probabilities, and moves on; the others stay as they were.
         ``drawn``: the launch's."""
-        rows = jnp.arange(pos.shape[0])
-        nxt = jnp.clip(pos + 1, 0, self.max_ctx - 1)
-        tok, lp_ids, lp_vals = self._sample(self._head(params, x), state["seed"],
-                                            nxt, state["temp"], drawn)
-        n_new = state["n_new"]
-        at = jnp.clip(n_new, 0, self.max_new - 1)
-        keep = ~live
-        tokens = state["tokens"].at[rows, at].set(
-            jnp.where(keep, state["tokens"][rows, at], tok))
-        new_lp_ids = state["lp_ids"].at[rows, at].set(
-            jnp.where(keep[:, None], state["lp_ids"][rows, at], lp_ids))
-        new_lp = state["lp"].at[rows, at].set(
-            jnp.where(keep[:, None], state["lp"][rows, at], lp_vals))
-        n_new2 = jnp.where(live, n_new + 1, n_new)
-        done2 = state["done"] | (live & (n_new2 >= state["max_new"]))
-        new = dict(new, tokens=tokens, lp_ids=new_lp_ids, lp=new_lp, n_new=n_new2,
-                   done=done2, pos=jnp.where(live, nxt, state["pos"]),
-                   last=jnp.where(live, tok, state["last"]))
-        return new, {"done": done2 | ~state["armed"], "n_new": n_new2,
-                     "first": tokens[:, 0], "last": new["last"], "acc": new["acc"]}
+        with jax.named_scope("emit"):
+            rows = jnp.arange(pos.shape[0])
+            nxt = jnp.clip(pos + 1, 0, self.max_ctx - 1)
+        with jax.named_scope("head"):
+            logits = self._head(params, x)
+        tok, lp_ids, lp_vals = self._sample(logits, state["seed"], nxt, state["temp"], drawn)
+        with jax.named_scope("emit"):
+            n_new = state["n_new"]
+            at = jnp.clip(n_new, 0, self.max_new - 1)
+            keep = ~live
+            tokens = state["tokens"].at[rows, at].set(
+                jnp.where(keep, state["tokens"][rows, at], tok))
+            new_lp_ids = state["lp_ids"].at[rows, at].set(
+                jnp.where(keep[:, None], state["lp_ids"][rows, at], lp_ids))
+            new_lp = state["lp"].at[rows, at].set(
+                jnp.where(keep[:, None], state["lp"][rows, at], lp_vals))
+            n_new2 = jnp.where(live, n_new + 1, n_new)
+            done2 = state["done"] | (live & (n_new2 >= state["max_new"]))
+            new = dict(new, tokens=tokens, lp_ids=new_lp_ids, lp=new_lp, n_new=n_new2,
+                       done=done2, pos=jnp.where(live, nxt, state["pos"]),
+                       last=jnp.where(live, tok, state["last"]))
+            out = {"done": done2 | ~state["armed"], "n_new": n_new2,
+                   "first": tokens[:, 0], "last": new["last"], "acc": new["acc"]}
+        return new, out
 
     def extract(self, params: Any, state: Any, slot: Any) -> Any:
         idx = jax.lax.dynamic_index_in_dim

@@ -203,6 +203,15 @@ def _kept_groups(by: jax.Array, n_group: int, topk_group: int) -> jax.Array:
     return jnp.where(kept[..., None], g, -jnp.inf).reshape(t, e)
 
 
+def router_logits(u: jax.Array, w: jax.Array) -> jax.Array:
+    """The router's product of the rows ``u`` (T, D) with ``w`` (D, E), both as
+    float32 at the highest precision (a pick is a comparison of two of them),
+    under the scope ``topk_route`` has."""
+    with jax.named_scope("moe_route"):
+        return jnp.matmul(u.astype(jnp.float32), w.astype(jnp.float32),
+                          precision=jax.lax.Precision.HIGHEST)
+
+
 def topk_route(logits: jax.Array, k: int, *, normalize: bool = True,
                scale: float = 1.0, scoring: str = "softmax",
                select_bias: "jax.Array | None" = None, eps: float = 0.0,

@@ -284,9 +284,20 @@ class MetricSampler(threading.Thread):
         self.hooks = list(hooks or [])
         self._stop_ev = threading.Event()
         self.ticks = self.store.metrics.counter("telemetry_samples_total")
+        # Seconds this thread woke LATER than it asked to: what a wait of
+        # ``interval_s`` took beyond that. A thread that sleeps on an event needs
+        # nothing but the processor, so what it is held back by holds every
+        # thread of the process back: a stall of the machine or of the whole
+        # process, whatever the cause, is counted here (and a quiet machine's
+        # few milliseconds a minute, the scheduler's own lateness).
+        self.stall = self.store.metrics.counter("host_stall_seconds_total")
 
     def run(self) -> None:
-        while not self._stop_ev.wait(self.interval_s):
+        while True:
+            asked = time.monotonic()
+            if self._stop_ev.wait(self.interval_s):
+                return
+            self.stall.inc(max(0.0, time.monotonic() - asked - self.interval_s))
             try:
                 self.tick()
             except Exception:  # one bad tick must not end sampling
