@@ -29,6 +29,8 @@ SAMPLE = ["decode:gen_sample_steps_total{model=M,path=greedy}",
 SSM = ["ssm_tokens_total{model=M,phase=PH}", "ssm_state_rows_total{model=M,phase=PH}",
        "prefill:ssm_pieces_total{model=M,start=zero}",
        "prefill:ssm_pieces_total{model=M,start=carried}"]
+SCANS = ["prefill:ssm_scans_total{model=M,phase=prefill,path=kernel}",
+         "prefill:ssm_scans_total{model=M,phase=prefill,path=xla}"]
 MLA = EXPERTS + CONTEXT + [
     "mla_rows_attended_total{model=M,phase=PH}", "mla_rows_walked_total{model=M,phase=PH}",
     "mla_launches_total{model=M,phase=PH,form=absorbed}",
@@ -37,8 +39,8 @@ MLA = EXPERTS + CONTEXT + [
     + SAMPLE
 SERIES = {
     "decoder": EXPERTS + CONTEXT + COMPACT + SAMPLE,
-    "hybrid": EXPERTS + CONTEXT + SSM + COMPACT + SAMPLE,
-    "hybrid_ffn": CONTEXT + SSM + SAMPLE,
+    "hybrid": EXPERTS + CONTEXT + SSM + COMPACT + SCANS + SAMPLE,
+    "hybrid_ffn": CONTEXT + SSM + SCANS + SAMPLE,
     "mla": MLA,
     "mla_sc": MLA + ["moe_routed_zero_total{model=M,phase=PH}"],
     "mla_hc": MLA + ["hc_maps_total{model=M,phase=PH,path=kernel}",
@@ -60,7 +62,7 @@ SERIES = {
         "prefill:delta_scans_total{model=M,phase=prefill,path=kernel}",
         "prefill:delta_scans_total{model=M,phase=prefill,path=xla}"] + SAMPLE,
     "hybrid_conv": EXPERTS + CONTEXT + SSM + COMPACT + SAMPLE,
-    "hybrid_ffn_moe": EXPERTS + CONTEXT + SSM + COMPACT + SAMPLE,
+    "hybrid_ffn_moe": EXPERTS + CONTEXT + SSM + COMPACT + SCANS + SAMPLE,
     "eva": CONTEXT + [
         "eva_rows_attended_total{model=M,phase=PH,kind=exact}",
         "eva_rows_attended_total{model=M,phase=PH,kind=summary}",

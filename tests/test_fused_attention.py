@@ -957,6 +957,71 @@ def test_a_launchs_chunked_delta_rule_is_one_kernel_call_a_layer_on_the_v5e_at_t
                 and any(s in ln.split("=")[1][:60] for s in rows)]
 
 
+@pytest.mark.parametrize("heads,groups", [(64, 1), (128, 1), (32, 2)])
+def test_a_launchs_mamba2_scan_is_one_kernel_call_a_layer_on_the_v5e_at_the_cells_widths(
+        one_chip, monkeypatch, heads, groups):
+    """`ops/ssm_scan.py` under `Mamba2Mixer`'s launch (ISSUE 67), at the three
+    Mamba-2 cells' sizes: 8 tiles of 128 rows, heads of 64 channels, a state of
+    128, bfloat16. The TPU branch is steered by the backend's name here, in the
+    test. Mosaic takes the kernel (the heads picked by products with a 0/1 matrix,
+    two heads a register selected by lane, the transposed product into the state,
+    y written rows on lanes); a Mamba-2 layer of a launch is ONE custom call that
+    reads x, B and C out of the activated rows where XLA keeps them and the
+    pieces' states in the slots' own block, in place, so the scope has no
+    `lax.scan` over tiles, no (tiles, heads, T, T) table of decays, no copy of
+    the activated rows' x part and no (tiles, heads, P, N) copy of the states."""
+    from tpuserve.models import mixers
+    from tpuserve.models.paged_lm import PagedLM
+
+    K, T, P, N, slots = 8, 128, 64, 128, 80   # a block too large to be staged whole
+
+    class Layer(mixers.Mamba2Mixer):
+        name, conv_k, eps, dtype = "layer", 4, 1e-5, jnp.dtype("bfloat16")
+        mh, mp, mg, mn, conv_ch = heads, P, groups, N, heads * P + 2 * groups * N
+
+    model, ch = Layer(), heads * P + 2 * groups * N
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    lp = {"conv_w": shape(4, ch, dtype=jnp.bfloat16), "conv_b": shape(ch, dtype=jnp.bfloat16),
+          "dt_bias": shape(heads), "A_log": shape(heads), "D": shape(heads),
+          "gate_norm": shape(heads, P, dtype=jnp.bfloat16)}
+
+    def layer(lp, z, xbc, dt, ssm, conv, slot, start, length):
+        t = PagedLM._tiles({"slot": slot, "start": start, "length": length,
+                            "pages": jnp.zeros((K, 1), jnp.int32)}, K * T)
+        assert model._scan_path(t, ssm) == "kernel"
+        with jax.named_scope("ssm_scan"):
+            y, ssm, conv = model._scan_slots(lp, xbc, dt, t, ssm, conv, slot, start, length)
+            return model._gated_norm(lp, y, z), ssm, conv
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
+    try:
+        ints = [shape(K, dtype=jnp.int32)] * 3
+        text = jax.jit(layer, donate_argnums=(4, 5)).lower(
+            lp, shape(K * T, heads, P), shape(K * T, ch, dtype=jnp.bfloat16), shape(K * T, heads),
+            shape(slots, heads, P, N), shape(slots, 3, ch, dtype=jnp.bfloat16),
+            *ints).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    lines = text.split("\n")
+    calls = [ln for ln in lines if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "ssm_scan" in calls[0].split("=")[0]
+    assert f"bf16[{K},{T},{ch}]" in calls[0] and f"f32[{heads * P},{K * T}]" in calls[0]
+    assert not [ln for ln in lines if " while(" in ln and "scan/while" in ln]   # no lax.scan
+    assert f"[{K},{heads},{T},{T}]" not in text                                # no table of decays
+    # nothing of x's size is cut out of the activated rows, and the pieces' states are neither
+    # gathered nor scattered: no (K, H, P, N) array, no copy of the slots' block
+    made = (f"bf16[{K},{T},{heads * P}]", f"bf16[{K},{T},{heads},{P}]",
+            f"bf16[{K * T},{heads},{P}]", f"f32[{slots},{heads},{P},{N}]",
+            f"f32[{slots},{heads * P},{N}]")
+    assert not [ln for ln in lines if (" copy(" in ln or " slice(" in ln or " slice-start(" in ln)
+                and any(s in ln.split(" = ")[1][:60] for s in made)]
+    assert f"f32[{K},{heads},{P},{N}]" not in text and f"f32[{K},{heads * P},{N}]" not in text
+
+
 def test_evas_step_walks_rings_and_pages_in_place_on_the_v5e_at_the_cells_widths(
         one_chip, tmp_path, monkeypatch):
     """The `eva` family's two programs (ISSUE 55) at the cell's widths, two layers

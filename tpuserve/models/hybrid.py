@@ -64,7 +64,7 @@ import jax
 import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
-from tpuserve.models.mixers import SSM_COLUMNS, PatternMixers
+from tpuserve.models.mixers import SCAN_COLUMNS, SSM_COLUMNS, PatternMixers
 from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN,  # noqa: F401
                                       EXPERT_COLUMNS, LOGPROBS, SAMPLE_COLUMNS, PagedLM, _mm,
                                       head_share, read_config_file, rms_norm, scoped)
@@ -85,8 +85,9 @@ DEFAULT_SCALES = {
 class HybridServing(PatternMixers, PagedLM):
     # The expert layer's four and the context, as ``decoder`` has them, then the
     # scan layers' four, expert layers whose dispatch took the compact branch,
-    # and the steps by the sampler's branch.
-    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, *SSM_COLUMNS, COMPACT_COLUMN, *SAMPLE_COLUMNS)
+    # a launch's scans by where they ran, and the steps by the sampler's branch.
+    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, *SSM_COLUMNS, COMPACT_COLUMN, *SCAN_COLUMNS,
+               *SAMPLE_COLUMNS)
 
     def __init__(self, cfg: ModelConfig) -> None:
         super().__init__(cfg)

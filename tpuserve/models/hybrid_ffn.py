@@ -43,7 +43,7 @@ from typing import Any
 import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
-from tpuserve.models.mixers import SSM_COLUMNS, PatternMixers
+from tpuserve.models.mixers import SCAN_COLUMNS, SSM_COLUMNS, PatternMixers
 from tpuserve.models.paged_lm import (CONTEXT_COLUMN, SAMPLE_COLUMNS, PagedLM, read_config_file,
                                       rms_norm)
 
@@ -58,8 +58,9 @@ KINDS = ("mamba", "attention")
 
 
 class HybridFfnServing(PatternMixers, PagedLM):
-    # The context, the scan layers' four, and the steps by the sampler's branch.
-    COLUMNS = (CONTEXT_COLUMN, *SSM_COLUMNS, *SAMPLE_COLUMNS)
+    # The context, the scan layers' four, a launch's scans by where they ran, and
+    # the steps by the sampler's branch.
+    COLUMNS = (CONTEXT_COLUMN, *SSM_COLUMNS, *SCAN_COLUMNS, *SAMPLE_COLUMNS)
     # What this family refuses and a sibling with a routed block serves
     # (``hybrid_ffn_moe``): experts in the config, and a share of them.
     ROUTED = False

@@ -40,7 +40,7 @@ from tpuserve.config import ModelConfig
 from tpuserve.models import hybrid_ffn
 from tpuserve.models.hybrid_delta import RoutedExperts
 from tpuserve.models.hybrid_ffn import HybridFfnServing
-from tpuserve.models.mixers import SSM_COLUMNS
+from tpuserve.models.mixers import SCAN_COLUMNS, SSM_COLUMNS
 from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN, EXPERT_COLUMNS,
                                       SAMPLE_COLUMNS, read_config_file, rms_norm)
 
@@ -52,8 +52,10 @@ DEFAULT_SCALES = {**hybrid_ffn.DEFAULT_SCALES, "router": 1.0, "expert_out": 1.0}
 
 class HybridFfnMoeServing(RoutedExperts, HybridFfnServing):
     # The expert layer's four and the context, the scan layers' four, the
-    # compact dispatches, and the steps by the sampler's branch.
-    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, *SSM_COLUMNS, COMPACT_COLUMN, *SAMPLE_COLUMNS)
+    # compact dispatches, a launch's scans by where they ran, and the steps by
+    # the sampler's branch.
+    COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, *SSM_COLUMNS, COMPACT_COLUMN, *SCAN_COLUMNS,
+               *SAMPLE_COLUMNS)
     ROUTED = True
     SHARE_KEYS = ("experts_held", "vocab_rows")
     route_scoring = "softmax"  # over all logits, the picks' weights over their own sum

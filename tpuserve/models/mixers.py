@@ -26,8 +26,8 @@ of another slot does not see it; padded rows leave it as it was; a piece of no
 tokens writes nothing; a decode step leaves the state of a lane that is not
 live untouched. Prefill computes the recurrence by chunks (a quadratic form
 inside a tile, the state passed between a piece's tiles by a ``lax.scan`` or,
-for the delta rule on the TPU, inside one kernel call; the short convolution
-has nothing to pass on but a tile's last rows, and no scan), under
+for Mamba-2 and the delta rule on the TPU, inside one kernel call; the short
+convolution has nothing to pass on but a tile's last rows, and no scan), under
 ``jax.named_scope("ssm_scan")``: the scan alone, from the convolution to the
 gated norm (the short convolution: to its gate); a decode step is one application, under
 ``jax.named_scope("ssm_update")``: the whole mixer, from the projections to the
@@ -38,7 +38,27 @@ out-projection. The four ``SSM_COLUMNS`` count all three mixers' work alike.
 SiLU over ``xBC``; per head ``S_t = a_t S_{t-1} + delta_t x_t (x) B_t``, ``y_t =
 S_t C_t + D x_t`` with ``delta = softplus(dt + dt_bias)``, ``a = exp(-exp(A_log)
 delta)``; ``y <- RMSNorm_group(y silu(z))``; out ``= y W_out``. The state is (H,
-P, N); a padded row has ``delta = 0`` (``a = 1``, no input).
+P, N); a padded row has ``delta = 0`` (``a = 1``, no input). A launch's chunk is
+a prefill tile: with ``cum`` the running sum of ``log a`` inside it, row t reads
+row s <= t through ``exp(cum_t - cum_s) delta_s (C_t . B_s)`` and the state the
+tile starts from through ``exp(cum_t)``; the tile leaves ``exp(cum_T) S + (x
+exp(cum_T - cum) delta)^T B``. Every exponent taken is <= 0; the three products
+take their operands in the served type and accumulate in float32. WHERE A
+LAUNCH'S CHUNKED SCAN RUNS is chosen when the launch is traced (``_scan_path``:
+the backend and the static shapes, no option): on the TPU, at tiles of 128 or
+256 rows, a state of whole 128-lane registers and groups of whole blocks of
+eight heads, with the slots' states in float32, ONE kernel call a layer
+(``_scan_slots``: ``ops/ssm_scan.py``, ISSUE 67: it reads x, B and C out of the
+activated rows where they lie and a piece's state out of its SLOT's block, which
+it writes in place, holds a tile's table in fast memory, passes the state from
+tile to tile inside the call and skips a tile with no live row; nothing of a
+tile but y goes back to device memory, and no copy of the pieces' states is
+gathered or scattered around it); elsewhere the plain form (``_scan_pieces`` and
+``_scan_tiles``: XLA fusions of plain ``jnp``, a (tiles, heads, T, T) float32
+table, the states stacked by a ``lax.scan`` between ``_piece_starts`` and
+``_store_pieces``), which is also what the kernel is held to in the tests.
+``ssm_scans_total{phase=prefill,path=kernel|xla}`` counts a launch's Mamba-2
+layers by which.
 
 ``DeltaMixer`` (``_delta_setup``; ``m_layers`` too): ``q~, k~, v~ = u W_q, u W_k,
 u W_v`` (H heads of D each); a depthwise causal convolution with no bias and
@@ -111,6 +131,7 @@ from tpuserve.models.decoder import apply_rope
 from tpuserve.models.paged_lm import Column, _mm, counted, rms_norm, scoped, series
 from tpuserve.ops import delta_scan as ds
 from tpuserve.ops import delta_update as du
+from tpuserve.ops import ssm_scan as ss
 
 
 def softplus_inverse(y: float) -> float:
@@ -306,18 +327,61 @@ class Mamba2Mixer(RecurrentMixer):
     def _out_proj(self, lp: dict, g: jax.Array) -> jax.Array:
         return jnp.einsum("thp,hpd->td", g, lp["w_out"], preferred_element_type=jnp.float32)
 
+    def _scan_rows(self, lp: dict, xbc, dt, t: dict, c0):
+        """What either path of a launch's scan starts from: ``xbc`` (C,
+        channels) and ``dt`` (C, H) of the packed rows, ``c0`` (K, k-1, channels)
+        the convolution's rows each PIECE starts from -> (the tiles that open a
+        piece (K,), the live rows (K, T), the convolved rows (K, T, channels)
+        float32, delta and the running sum of the log-decay down each tile (K,
+        T, H) float32, by piece its last tile and the convolution's rows it
+        ends with)."""
+        K, T, H = t["K"], t["T"], self.mh
+        opens, live, seq, conv = self._tiles_conv(t, xbc, c0, lp["conv_w"], lp["conv_b"])
+        delta, la = self._decay(lp, dt.reshape(K, T, H), live)
+        return (opens, live, conv, delta, jnp.cumsum(la, axis=1),
+                *self._piece_ends(t, live, seq))
+
+    def _scan_slots(self, lp: dict, xbc, dt, t: dict, ssm, conv, slot, start, length):
+        """The chunked scan of one launch as ONE kernel call (``ops/ssm_scan.py``)
+        ON THE SLOTS' BLOCK ``ssm`` (slots, H, P, N) float32, in place: the call
+        reads a piece's state out of its slot when the piece's first tile
+        begins (zeros where the piece opens its prompt) and writes what its last
+        tile leaves back into it, so no (K, H, P, N) copy of the pieces' states
+        is gathered before the scan nor scattered after it. -> y (C, H, P)
+        float32, the block, the convolution's rows (slots, k-1, channels)."""
+        _none, (c0,) = self._piece_starts(slot, start, rows=(conv,))
+        opens, live, rows, delta, cum, _last, tail = self._scan_rows(lp, xbc, dt, t, c0)
+        alive, piece = jnp.any(live, axis=1), t["piece"]       # live tiles come first, in a run
+        # A tile begins from zeros, from its slot's state, or goes on from the
+        # tile before; a launch of no live tile passes one slot's state through.
+        begins = jnp.where(alive & opens, jnp.where(start[piece] == 0, ss.ZEROS, ss.STORED),
+                           jnp.where(alive | (t["tiles"] > 0), ss.GOES_ON, ss.STORED))
+        of_tile = jnp.clip(slot[piece], 0, ssm.shape[0] - 1)
+        at = jnp.where(alive, of_tile, of_tile[jnp.maximum(jnp.sum(alive) - 1, 0)])
+        y, ssm = ss.ssm_scan(jax.nn.silu(rows).astype(self.dtype), delta, cum, lp["D"], ssm,
+                             begins, at, alive, head_dim=self.mp, state=self.mn)
+        (conv,) = self._store_pieces((conv,), slot, length, (tail,))
+        return y, ssm, conv
+
+    def _scan_pieces(self, lp: dict, xbc, dt, t: dict, ssm, conv, slot, start, length):
+        """The chunked scan of one launch in plain ``jnp``: the pieces' states
+        gathered from their slots, ``_scan_tiles``, the ends scattered back. ->
+        as ``_scan_slots``."""
+        (s0,), (c0,) = self._piece_starts(slot, start, states=(ssm,), rows=(conv,))
+        y, s_end, c_end = self._scan_tiles(lp, xbc, dt, t, s0, c0)
+        return (y, *self._store_pieces((ssm, conv), slot, length, (s_end, c_end)))
+
     def _scan_tiles(self, lp: dict, xbc, dt, t: dict, s0, c0):
-        """The chunked scan of one launch: ``xbc`` (C, channels) and ``dt`` (C,
-        H) of the packed rows; ``s0`` (K, H, P, N) float32 and ``c0`` (K, k-1,
-        channels) what each PIECE starts from. -> y (C, H, P) float32 and,
-        by piece, the state and the convolution's rows it ends with."""
+        """The plain form of one launch's chunked scan, what every backend but
+        the TPU runs and what the kernel is held to: ``xbc`` (C, channels) and
+        ``dt`` (C, H) of the packed rows; ``s0`` (K, H, P, N) float32 and ``c0``
+        (K, k-1, channels) what each PIECE starts from. -> y (C, H, P) float32
+        and, by piece, the state and the convolution's rows it ends with."""
         K, T = t["K"], t["T"]
         H, P, G, N = self.mh, self.mp, self.mg, self.mn
         piece = t["piece"]
-        opens, live, seq, conv = self._tiles_conv(t, xbc, c0, lp["conv_w"], lp["conv_b"])
+        opens, _live, conv, delta, cum, last_tile, tail = self._scan_rows(lp, xbc, dt, t, c0)
         x, B, C = self._split_xbc(conv)
-        delta, la = self._decay(lp, dt.reshape(K, T, H), live)
-        cum = jnp.cumsum(la, axis=1)                                      # (K, T, H)
         # Inside a tile, the quadratic form: row t reads row s <= t through
         # exp(cum_t - cum_s) delta_s (C_t . B_s).
         cb = jnp.einsum("ktgn,ksgn->kgts", C, B, preferred_element_type=jnp.float32)
@@ -346,19 +410,17 @@ class Mamba2Mixer(RecurrentMixer):
             "kgjpn,ktgn->ktgjp", s_in.astype(self.dtype).reshape(K, G, H // G, P, N), C,
             preferred_element_type=jnp.float32).reshape(K, T, H, P)
         y = y + lp["D"][:, None] * x.astype(jnp.float32)
-        last_tile, tail = self._piece_ends(t, live, seq)
         return y.reshape(K * T, H, P), s_out[last_tile], tail
 
-    def _mamba_prefill(self, lp, u, t, ssm, conv, slot, start, length):
-        """One Mamba-2 layer of a launch. The scope ``ssm_scan`` is the scan
-        alone, from the convolution to the gated norm: the two projections
-        are outside it."""
+    def _mamba_prefill(self, lp, u, t, ssm, conv, slot, start, length, path: str = "xla"):
+        """One Mamba-2 layer of a launch, its scan in the kernel or in the plain
+        form by ``path``. The scope ``ssm_scan`` is the scan alone, from the
+        convolution to the gated norm: the two projections are outside it."""
         z, xbc, dt = self._split_in(lp, u)
         with jax.named_scope("ssm_scan"):
-            (s0,), (c0,) = self._piece_starts(slot, start, states=(ssm,), rows=(conv,))
-            y, s_end, c_end = self._scan_tiles(lp, xbc, dt, t, s0, c0)
+            scan = self._scan_slots if path == "kernel" else self._scan_pieces
+            y, ssm, conv = scan(lp, xbc, dt, t, ssm, conv, slot, start, length)
             g = self._gated_norm(lp, y, z)
-            ssm, conv = self._store_pieces((ssm, conv), slot, length, (s_end, c_end))
         return self._out_proj(lp, g), ssm, conv
 
     def _mamba_step(self, lp, u, live, ssm, conv):
@@ -389,7 +451,24 @@ class Mamba2Mixer(RecurrentMixer):
         """One Mamba-2 layer in the phase the plan ``m`` is of."""
         if m["t"] is None:
             return self._mamba_step(lp, u, m["live"], ssm, conv)
-        return self._mamba_prefill(lp, u, m["t"], ssm, conv, m["slot"], m["start"], m["length"])
+        return self._mamba_prefill(lp, u, m["t"], ssm, conv, m["slot"], m["start"], m["length"],
+                                   m["scan_path"])
+
+    def _scan_path(self, t: dict, ssm) -> str:
+        """Where a launch's chunked scans run, chosen when the launch is
+        traced: the kernel on the TPU at shapes it takes, else the plain form."""
+        on_tpu = jax.default_backend() == "tpu" and ss.supported(
+            t["T"], self.mh, self.mp, self.mn, self.mg, ssm.dtype)
+        return "kernel" if on_tpu else "xla"  # tps-ok[TPS503]: backend and static shapes
+
+    def _prefill_plan(self, state, launch, t: dict) -> dict:
+        """And where the launch's chunked scans run, chosen once for all its layers."""
+        return {**super()._prefill_plan(state, launch, t),
+                "scan_path": self._scan_path(t, state["ssm"][0])}
+
+    def _counts(self, m: dict) -> dict:
+        """And a launch itself by where its chunked scans ran."""
+        return {**super()._counts(m), "scans": {p: int(m.get("scan_path") == p) for p in PATHS}}
 
 
 def _pieces(start: str):
@@ -410,7 +489,7 @@ SSM_COLUMNS = (
     Column(counted("carried"), _pieces("carried")))
 
 
-PATHS = ("kernel", "xla")   # where a step's delta-rule update ran
+PATHS = ("kernel", "xla")   # where a launch's chunked scan or a step's delta-rule update ran
 _HI = {"precision": jax.lax.Precision.HIGHEST, "preferred_element_type": jnp.float32}
 
 
@@ -708,7 +787,7 @@ class DeltaMixer(RecurrentMixer):
 
 
 def _by_path(count: str, name: str, phase: str) -> tuple:
-    """A column a path: ``counts[count][path]`` times the delta-rule layers into
+    """A column a path: ``counts[count][path]`` times the recurrent layers into
     ``name{model=,phase=,path=}``, in ``phase`` alone."""
     return tuple(
         Column(lambda model, stats, counts, path=path: counts[count][path] * len(model.m_layers),
@@ -721,6 +800,8 @@ def _by_path(count: str, name: str, phase: str) -> tuple:
 # rules (layers), each by where it ran.
 DELTA_COLUMNS = (*_by_path("paths", "delta_steps_total", "decode"),
                  *_by_path("scans", "delta_scans_total", "prefill"))
+# A launch's Mamba-2 scans (layers), by where they ran.
+SCAN_COLUMNS = _by_path("scans", "ssm_scans_total", "prefill")
 
 
 class ConvMixer(RecurrentMixer):
