@@ -43,7 +43,7 @@ import tempfile
 from types import SimpleNamespace
 
 FAMILIES = ("decoder", "hybrid", "hybrid_ffn", "mla", "mla_sc", "mla_hc", "decoder_sink",
-            "hybrid_delta", "eva", "hybrid_conv", "mla_sel", "hybrid_ffn_moe")
+            "hybrid_delta", "eva", "hybrid_conv", "mla_sel", "hybrid_ffn_moe", "hybrid_blk")
 
 
 def kernels_without_places(text: str) -> tuple[str, int]:

@@ -1,7 +1,7 @@
 """What ``acc`` holds is stated once a family (``paged_lm.Column``, ISSUE 45):
 the state's width, the row a launch adds, the counters ``bind_metrics`` binds
 and what ``observe_step`` feeds all follow the family's ``COLUMNS``. Here, for
-each of the twelve generating families at its toy size: one prefill launch and
+each of the thirteen generating families at its toy size: one prefill launch and
 one step on the CPU, then the device's sums into a registry. The names below
 are the series as they have been served since each family came (the benchmark's
 readers find them by these letters), written down apart from the code."""
@@ -63,6 +63,11 @@ SERIES = {
         "prefill:delta_scans_total{model=M,phase=prefill,path=xla}"] + SAMPLE,
     "hybrid_conv": EXPERTS + CONTEXT + SSM + COMPACT + SAMPLE,
     "hybrid_ffn_moe": EXPERTS + CONTEXT + SSM + COMPACT + SCANS + SAMPLE,
+    "hybrid_blk": CONTEXT + SSM + SCANS + [
+        "blk_blocks_scored_total{model=M,phase=PH}", "blk_keys_visible_total{model=M,phase=PH}",
+        "blk_keys_attended_total{model=M,phase=PH}", "blk_rows_read_total{model=M,phase=PH}",
+        "blk_queries_total{model=M,phase=PH,path=dense}",
+        "blk_queries_total{model=M,phase=PH,path=picked}"] + SAMPLE,
     "eva": CONTEXT + [
         "eva_rows_attended_total{model=M,phase=PH,kind=exact}",
         "eva_rows_attended_total{model=M,phase=PH,kind=summary}",

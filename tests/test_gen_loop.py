@@ -451,7 +451,7 @@ def test_the_step_ahead_reader_reads_the_programs_scrapes_and_none_from_the_pare
     entry = next(m for m in spec.load_benchmark()["per_layer"] if m["name"] == "gen_step_ahead_pct")
     assert (entry["layer"], entry["moves"], entry["source"], entry["unit"], entry["better"]) == (
         "generation engine", "items_per_s", "program_counter", "%", "higher")
-    assert len(entry["workloads"]) == 12
+    assert len(entry["workloads"]) == 13
 
 
 # The loop of ISSUE 41 in the reader's eyes. Times in ms, window_s = 0.100, the chip busy from 0 to 92:

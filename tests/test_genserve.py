@@ -733,16 +733,20 @@ def _frozen_case(name, tmp_path):
              "decoder_sink": "tests.test_decoder_sink",
              "hybrid_delta": "tests.test_hybrid_delta", "eva": "tests.test_eva",
              "hybrid_conv": "tests.test_hybrid_conv", "mla_sel": "tests.test_mla_sel",
-             "hybrid_ffn_moe": "tests.test_hybrid_ffn_moe"}[name]
+             "hybrid_ffn_moe": "tests.test_hybrid_ffn_moe",
+             "hybrid_blk": "tests.test_hybrid_blk"}[name]
     import importlib
-    model = importlib.import_module(maker).make_model(str(tmp_path), name="fz")
-    return (model, {}, dict(kv_paging=True, kv_page_tokens=4, prefill_chunk=8),
+    module = importlib.import_module(maker)
+    model = module.make_model(str(tmp_path), name="fz")
+    page = getattr(module, "FROZEN_PAGE", 4)   # a family whose pages must be wider says so
+    return (model, {}, dict(kv_paging=True, kv_page_tokens=page, prefill_chunk=8),
             ids(model, 5, 4), ids(model, 6, 10, first=20))
 
 
 FROZEN_CASES = ["textgen-dense", "textgen-paged", "textgen-sharded-dense", "textgen-sharded-paged",
                 "sd15", "decoder", "hybrid", "hybrid_ffn", "mla", "mla_sc", "mla_hc",
-                "decoder_sink", "hybrid_delta", "eva", "hybrid_conv", "mla_sel", "hybrid_ffn_moe"]
+                "decoder_sink", "hybrid_delta", "eva", "hybrid_conv", "mla_sel", "hybrid_ffn_moe",
+                "hybrid_blk"]
 
 
 def test_the_frozen_lane_cases_name_every_registered_generating_family():

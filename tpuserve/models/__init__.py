@@ -78,6 +78,14 @@ Families (BASELINE.json ``configs``):
                    selection bias (the picks' weights a softmax over the picked
                    alone) and one shared expert, under the residual multiplier;
                    a share of the experts and the vocabulary (ISSUE 64)
+- hybrid_blk     — ``hybrid_ffn``'s sibling for a model whose layers are linear
+                   attention with a constant decay a head (a (heads, D, D)
+                   float32 state a slot and no convolution rows) or softmax
+                   attention over the blocks of keys a query's KV group picks by
+                   scores over mean-pooled keys (a third page leaf; the first
+                   and the local blocks always kept; dense under ``dense_len``),
+                   each followed by a dense SwiGLU, under the embedding, depth
+                   and logit scalars (ISSUE 68)
 - toy            — a linear classifier for tests and drills
 """
 
@@ -108,6 +116,7 @@ _REGISTRY: dict[str, str] = {
     "hybrid_conv": "tpuserve.models.hybrid_conv",
     "mla_sel": "tpuserve.models.mla_sel",
     "hybrid_ffn_moe": "tpuserve.models.hybrid_ffn_moe",
+    "hybrid_blk": "tpuserve.models.hybrid_blk",
     "toy": "tpuserve.models.toy",
 }
 

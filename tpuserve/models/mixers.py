@@ -117,6 +117,21 @@ step's lanes' or a launch's rows'): ``q <- rope(RMSNorm(q; g_q), pos)``, ``k``
 alike, the norm over a head's ``hd`` columns with ONE gain for all heads, FIRST,
 then the rotary over all ``hd`` columns in pairs ``(j, j + hd / 2)`` at
 ``rope_theta``; the pages hold k after both.
+
+SINCE ISSUE 68, a FOURTH recurrent mixer and attention over picked blocks (their
+classes' docstrings have each at length; ``BlockPatternMixers`` is the pair).
+``LightningMixer`` (``_lightning_setup``; ``m_layers`` too): linear attention with
+a CONSTANT decay a head, ``S_t = lambda_h S_{t-1} + k_t^T v_t``, ``o_t = scale q_t
+S_t``, ``lambda_h = exp(-2^(-8 (h + 1) / H))``, q and k normed a head and turned
+(``HeadNorms``), the read normed a head and gated; a slot keeps ONE leaf, ``("ssm",)``,
+(H, D, D) float32, and NO convolution rows; a launch's chunk is a sub-tile of
+``SUB`` rows in the plain form on every backend (every head has its own q and k:
+``ops/ssm_scan.py`` takes groups of eight heads that share them).
+``BlockSelectAttention``: ``PlainAttention`` whose queries at or past ``dense_len``
+attend over the ``topk`` BLOCKS of keys their KV group picks by scores over
+mean-pooled keys (a THIRD page leaf ``kc``), the first and the local blocks
+always kept, under the scopes ``blk_pool``, ``blk_select`` and ``blk_attend`` inside
+``attn_prefill`` / ``attn_decode``.
 """
 
 from __future__ import annotations
@@ -128,9 +143,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpuserve.models.decoder import apply_rope
-from tpuserve.models.paged_lm import Column, _mm, counted, rms_norm, scoped, series
+from tpuserve.models.paged_lm import NEG, Column, _mm, counted, rms_norm, scoped, series
 from tpuserve.ops import delta_scan as ds
 from tpuserve.ops import delta_update as du
+from tpuserve.ops import index_select as ix
 from tpuserve.ops import ssm_scan as ss
 
 
@@ -179,14 +195,14 @@ class RecurrentMixer:
         return last_tile, tail
 
     @staticmethod
-    def _piece_starts(slot, start, *, rows: tuple, states: tuple = ()) -> tuple:
+    def _piece_starts(slot, start, *, rows: tuple = (), states: tuple = ()) -> tuple:
         """What each piece of a launch starts from, a leaf of its slot's state:
         zeros where it opens its prompt, else what its slot holds. ``states``:
         the leaves a scan computes on in float32, (slots, ., ., .) each;
         ``rows``: the convolution's stored rows (slots, k-1, channels), which
         stay in their own type -> (the states (K, ...) float32, the rows)."""
         fresh = (start == 0)[:, None, None]
-        at = jnp.minimum(slot, rows[0].shape[0] - 1)
+        at = jnp.minimum(slot, (rows or states)[0].shape[0] - 1)
         return (tuple(jnp.where(fresh[..., None], 0.0, s[at].astype(jnp.float32))
                       for s in states),
                 tuple(jnp.where(fresh, jnp.zeros((), r.dtype), r[at]) for r in rows))
@@ -1013,3 +1029,480 @@ class ConvPatternMixers(_Pattern, ConvMixer, RotaryAttention):
     """The gated short convolution or attention with query/key norms and a
     rotary embedding."""
     _recurrent, _state_signature = ConvMixer._short_conv, ConvMixer._conv_signature
+
+
+class HeadNorms:
+    """q and k by head as both mixers below make them: normed over a head's
+    columns where the family's ``qk_norm`` (ONE float32 gain of the width for
+    all heads), THEN turned by a rotary embedding where the mixer says so."""
+
+    def _qk_gains(self):
+        """Every layer's two gains where ``qk_norm``: float32 vectors drawn
+        INSIDE ``scales["qk_gain"]`` (``RotaryAttention``'s reason)."""
+        if self.qk_norm:
+            lo, hi = self.scales["qk_gain"]
+            for i in range(self.n_layers):
+                width = self.hd if i in self.a_layers else self.ld
+                for name in ("q_norm", "k_norm"):
+                    yield ((f"layer{i}", name), (width,), (width,), (0,), lo, hi)
+
+    @scoped("proj")
+    def _placed_qkv(self, lp: dict, u, pos, rope: bool, width: int):
+        """q, k, v by head of the rows ``u`` at positions ``pos``; the rotary
+        over all ``width`` columns in pairs ``(j, j + width / 2)`` at
+        ``rope_theta``."""
+        q, k, v = PlainAttention._qkv(self, lp, u)
+        if self.qk_norm:
+            q, k = rms_norm(q, lp["q_norm"], self.eps), rms_norm(k, lp["k_norm"], self.eps)
+        if rope:
+            inv = (self.rope_theta ** (-np.arange(0, width, 2, dtype=np.float64) / width)) \
+                .astype(np.float32)
+            q, k = apply_rope(q, pos, inv, 1.0, width), apply_rope(k, pos, inv, 1.0, width)
+        return q, k, v
+
+
+class LightningMixer(HeadNorms, RecurrentMixer):
+    """Linear attention with a CONSTANT decay a head (module docstring): a
+    slot's whole state is one (H, D, D) float32 block a layer, ``S[h, i, j] = sum_s
+    lambda_h^(t - s) k_s[i] v_s[j]``, and NO convolution rows: this mixer names
+    ONE leaf, ``("ssm",)``."""
+    kv_slot_state = ("ssm",)
+    SUB = 128   # rows of a sub-tile of a launch's chunked form
+
+    def _lightning_setup(self, *, heads: int, head_dim: int, scale: float, rope: bool,
+                         out_norm: bool, out_gate: bool) -> None:
+        """The layer's numbers: ``heads`` of ``head_dim`` for q, k and v alike
+        (every head its own q and k), what the read is multiplied by, and
+        whether q and k are turned, the read normed a head, and gated."""
+        self.lh, self.ld, self.l_scale = heads, head_dim, float(scale)
+        self.l_rope, self.l_norm, self.l_gate = rope, out_norm, out_gate
+        # log lambda_h = -2^(-8 (h + 1) / H): Lightning Attention's slopes, learned by nothing
+        self.l_log_decay = -np.exp2(-8.0 * np.arange(1, heads + 1) / heads).astype(np.float32)
+
+    # -- params ---------------------------------------------------------------
+    def _lightning_gains(self):
+        if self.l_norm:
+            for i in self.m_layers:
+                yield (f"layer{i}", "o_norm"), (self.ld,)
+
+    def _lightning_tensors(self):
+        d, s, h, D = self.d, self.scales, self.lh, self.ld
+        for i in self.m_layers:
+            L = f"layer{i}"
+            for name, role in (("wq", "lin_qk"), ("wk", "lin_qk"), ("wv", "lin_v"),
+                               *((("wg", "lin_gate"),) if self.l_gate else ())):
+                yield ((L, name), (d, h, D), (d, h, D), (0, 0, 0), s[role], d)
+            yield ((L, "wo"), (h, D, d), (h, D, d), (0, 0, 0), s["lin_o"], h * D)
+
+    def _lightning_signature(self, slots: int) -> dict:
+        return {"ssm": [jax.ShapeDtypeStruct((slots, self.lh, self.ld, self.ld), jnp.float32)
+                        for _ in self.m_layers]}
+
+    # -- device math --------------------------------------------------------------
+    def _lightning_qkv(self, lp: dict, u: jax.Array, pos: jax.Array):
+        """q, k (normed a head, then turned at ``pos``) and v of the rows ``u``
+        -> (T, H, D) each, in the served type."""
+        return self._placed_qkv(lp, u, pos, self.l_rope, self.ld)
+
+    @scoped("proj")
+    def _lightning_out(self, lp: dict, u: jax.Array, o: jax.Array) -> jax.Array:
+        """The read ``o`` (T, H, D) float32 normed over a head (one gain of D
+        for all heads), times ``sigmoid(u W_g)``, through ``W_o``."""
+        if self.l_norm:
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + self.eps) \
+                * lp["o_norm"].astype(jnp.float32)
+        if self.l_gate:
+            o = o * jax.nn.sigmoid(jnp.einsum("td,dhk->thk", u, lp["wg"],
+                                              preferred_element_type=jnp.float32))
+        return jnp.einsum("thk,hkd->td", o.astype(self.dtype), lp["wo"],
+                          preferred_element_type=jnp.float32)
+
+    def _lightning_tiles(self, q, k, v, t: dict, s0):
+        """The plain form of one launch's chunked recurrence, what every
+        backend runs today: q, k, v (C, H, D) of the packed rows; ``s0`` (K, H, D,
+        D) float32 what each PIECE starts from. A tile goes by sub-tiles of
+        ``SUB`` rows: with ``cum`` the running sum of ``log lambda`` over a
+        sub-tile's LIVE rows (a padded row: 0, and its v zeroed), row t reads row
+        s <= t through ``exp(cum_t - cum_s) (q_t . k_s)`` and the state the
+        sub-tile starts from through ``exp(cum_t)``; it leaves ``exp(cum_c) S + (k
+        exp(cum_c - cum))^T v``. Every exponent taken is <= 0; the products take
+        their operands in the served type and accumulate in float32. -> o (C, H,
+        D) float32 before ``lightning_scale`` and, by piece, the state it ends with."""
+        K, T, H = t["K"], t["T"], self.lh
+        c = self.SUB if T % self.SUB == 0 else T
+        n = T // c
+        live = t["valid"].reshape(K * n, c)
+        split = lambda a: a.reshape((K * n, c) + a.shape[1:])  # noqa: E731
+        q, k = split(q), split(k)
+        v = jnp.where(live[..., None, None], split(v), jnp.zeros((), v.dtype))
+        cum = jnp.cumsum(jnp.where(live[..., None], jnp.asarray(self.l_log_decay), 0.0), axis=1)
+        qk = jnp.einsum("kthd,kshd->khts", q, k, preferred_element_type=jnp.float32)
+        ch = cum.transpose(0, 2, 1)                                          # (Kn, H, c)
+        causal = jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]
+        m = jnp.exp(jnp.where(causal, ch[:, :, :, None] - ch[:, :, None, :], -jnp.inf)) * qk
+        o = jnp.einsum("khts,kshd->kthd", m.astype(self.dtype), v,
+                       preferred_element_type=jnp.float32)
+        to_end = jnp.exp(cum[:, -1:, :] - cum)                               # (Kn, c, H)
+        add = jnp.einsum("kshi,kshj->khij", (k * to_end[..., None]).astype(self.dtype), v,
+                         preferred_element_type=jnp.float32)
+        keep = jnp.exp(cum[:, -1, :])                                        # (Kn, H)
+        # a sub-tile opens its piece where its tile does and it is the tile's first
+        opens = jnp.repeat(t["tiles"] == t["first_tile"][t["piece"]], n) \
+            & (jnp.arange(K * n) % n == 0)
+
+        def pass_on(carry, sub):
+            opens_j, piece_j, keep_j, add_j = sub
+            s_in = jnp.where(opens_j, s0[piece_j], carry)
+            s_out = keep_j[:, None, None] * s_in + add_j
+            return s_out, (s_in, s_out)
+
+        _, (s_in, s_out) = jax.lax.scan(
+            pass_on, jnp.zeros((H, self.ld, self.ld), jnp.float32),
+            (opens, jnp.repeat(t["piece"], n), keep, add))
+        o = o + jnp.exp(cum)[..., None] * jnp.einsum(
+            "kthi,khij->kthj", q, s_in.astype(self.dtype), preferred_element_type=jnp.float32)
+        last_tile = jnp.clip(t["first_tile"] + t["n_tiles"] - 1, 0, K - 1)
+        return o.reshape(K * T, H, self.ld), s_out[last_tile * n + n - 1]
+
+    def _lightning_prefill(self, lp, u, t, pos, ssm, slot, start, length):
+        """One linear-attention layer of a launch. The scope ``ssm_scan`` is the
+        recurrence alone: the projections, the norms and the gate are outside."""
+        q, k, v = self._lightning_qkv(lp, u, pos)
+        with jax.named_scope("ssm_scan"):
+            (s0,), _none = self._piece_starts(slot, start, states=(ssm,))
+            o, s_end = self._lightning_tiles(q, k, v, t, s0)
+            (ssm,) = self._store_pieces((ssm,), slot, length, (s_end,))
+        return self._lightning_out(lp, u, o * self.l_scale), ssm
+
+    def _lightning_step(self, lp, u, live, pos, ssm):
+        """One application of the recurrence for every lane, the state read
+        once and written once: the state of a lane that is not live stays as it
+        was. The scope ``ssm_update`` is the whole mixer."""
+        with jax.named_scope("ssm_update"):
+            q, k, v = (x.astype(jnp.float32) for x in self._lightning_qkv(lp, u, pos))
+            s = jnp.exp(jnp.asarray(self.l_log_decay))[None, :, None, None] * ssm \
+                + k[..., :, None] * v[..., None, :]
+            o = jnp.sum(q[..., :, None] * s, axis=-2) * self.l_scale
+            out = self._lightning_out(lp, u, o)
+            new = jnp.where(live[:, None, None, None], s, ssm)
+        return out, new
+
+    def _lightning(self, lp, u, ssm, m: dict):
+        """One linear-attention layer in the phase the plan ``m`` is of."""
+        if m["t"] is None:
+            return self._lightning_step(lp, u, m["live"], m["pos"], ssm)
+        return self._lightning_prefill(lp, u, m["t"], m["pos"], ssm, m["slot"], m["start"],
+                                       m["length"])
+
+    def _counts(self, m: dict) -> dict:
+        """And a launch itself by where its chunked recurrences ran: the plain
+        form on every backend (``ops/ssm_scan.py`` takes groups of eight heads
+        that share q and k; here every head has its own)."""
+        return {**super()._counts(m),
+                "scans": {"kernel": 0, "xla": int(m["t"] is not None)}}
+
+
+class BlockSelectAttention(HeadNorms, PlainAttention):
+    """Attention by head, with no position term unless the family says so,
+    over THE BLOCKS OF KEYS A QUERY'S KV GROUP PICKS (module docstring's last
+    part; ISSUE 68). A family calls ``_blk_setup`` and sets ``PlainAttention``'s
+    numbers, ``qk_norm`` and ``attn_rope``.
+
+    A query at ``t < dense_len`` is ``PlainAttention``'s. Another: the pooled
+    keys ``Kc_g[j] = mean(k_g[stride j .. stride j + kernel - 1])`` (a THIRD page
+    leaf ``kc``, rows of KV x hd side by side, ``P / stride`` a page, a row
+    written when the position that completes its window is: ``blk_pool``);
+    ``p_h = softmax_j(scale q_h . Kc_g[j])`` over the windows that lie whole at or
+    before t, summed over the group's heads; a block's score the largest over
+    the windows that touch it, ``+inf`` for the first ``init`` blocks and the
+    ``local`` last; the ``topk`` best blocks a (query, KV group), found exactly
+    (``ops/index_select.py``'s threshold for a launch's rows, ``lax.top_k`` for a
+    step's lanes: 32 rows): ``blk_select``. Attention over the keys of those
+    blocks alone: ``blk_attend``. A LAUNCH walks every key block under the picks
+    as a mask a block (``paged_lm._prefill_full(keep=)``; a key block no row of
+    the tile picked is skipped): with 512 rows a tile and two groups, the rows'
+    picks together cover nearly every block, so a walk over picked blocks alone
+    would fetch each block once a row where this fetches it once a tile. A STEP
+    gathers, a (lane, KV group), its ``topk`` picked blocks through the block
+    table and NOTHING else of its pages. Pooled keys in the served type (the
+    mean in float32), scores, softmax, sums and picks in float32."""
+
+    def _blk_setup(self, name: str, sparse: dict) -> None:
+        g = lambda key: int(sparse[key])  # noqa: E731
+        self.b_kernel, self.b_stride, self.b_block = g("kernel_size"), g("kernel_stride"), \
+            g("block_size")
+        self.b_topk, self.b_init, self.dense_len = g("topk"), g("init_blocks"), g("dense_len")
+        if self.b_kernel % self.b_stride or self.b_block % self.b_stride \
+                or self.b_kernel > self.b_block + self.b_stride \
+                or g("window_size") % self.b_block or self.dense_len % self.b_block:
+            raise NotImplementedError(f"{name}: sparse_config = {sparse!r}: windows of whole "
+                                      "strides, blocks and a local window of whole blocks")
+        self.b_local = g("window_size") // self.b_block
+        if self.b_init + self.b_local > self.b_topk \
+                or self.dense_len < self.b_topk * self.b_block:
+            raise NotImplementedError(f"{name}: sparse_config = {sparse!r}: the forced blocks "
+                                      "among the picks, every picked query with topk blocks")
+
+    def _qkv(self, lp: dict, u, pos=None):
+        return self._placed_qkv(lp, u, pos, self.attn_rope, self.hd)
+
+    # -- the pooled keys ---------------------------------------------------------
+    def _pooled_shape(self, pages: int, page_tokens: int) -> tuple:
+        if page_tokens % self.b_block:
+            raise ValueError(f"{self.name}: kv_page_tokens = {page_tokens} is no whole number of "
+                             f"blocks of {self.b_block}")
+        return (pages * (page_tokens // self.b_stride), self.kv * self.hd)
+
+    @scoped("blk_pool")
+    def _pool_write(self, kc, kp, bt, at, ok):
+        """The pooled keys of the windows that END at positions ``at`` (R,) of
+        the prompts whose block-table rows are ``bt`` (R, pps), where ``ok``: the
+        mean, in float32, of the window's keys as the pages hold them, into
+        the window's row of ``kc``; the others into the sentinel's (page 0)."""
+        kv, _pages, P, hd = kp.shape
+        per = P // self.b_stride
+        span = at[:, None] - self.b_kernel + 1 + jnp.arange(self.b_kernel)[None, :]
+        span = jnp.clip(span, 0, bt.shape[1] * P - 1)
+        rows = jnp.take_along_axis(bt, span // P, axis=1) * P + span % P       # (R, kernel)
+        # rows of the pool seen flat, as ``_write_pages`` scatters them: taken along
+        # the pages' own dimension the compiler copies the whole pool to a layout
+        # with the heads inside (0.5 GiB a step: the cell compiled for a v5e, PR 68)
+        flat = (jnp.arange(kv)[:, None] * (kp.shape[1] * P) + rows.reshape(-1)[None, :])
+        keys = jnp.take(kp.reshape(-1, hd), flat.reshape(-1), axis=0) \
+            .reshape(kv, at.shape[0], self.b_kernel, hd)
+        mean = jnp.mean(keys.astype(jnp.float32), axis=2).transpose(1, 0, 2)   # (R, KV, hd)
+        j = jnp.maximum(at + 1 - self.b_kernel, 0) // self.b_stride
+        to = jnp.take_along_axis(bt, (j // per)[:, None], axis=1)[:, 0] * per + j % per
+        return kc.at[jnp.where(ok, to, 0)].set(mean.reshape(-1, kv * hd).astype(kc.dtype))
+
+    def _windows_done(self, pos):
+        """Positions that complete a window: its last, and the window whole."""
+        return ((pos + 1) % self.b_stride == 0) & (pos + 1 >= self.b_kernel)
+
+    def _pool_launch(self, kc, kp, t: dict):
+        """A launch's: a tile holds ``T / stride`` positions that may complete a
+        window, whatever the position its first row stands at."""
+        T, st = t["T"], self.b_stride
+        first = t["qpos"][:, 0]                                              # (K,)
+        at = (first + (st - 1 - first % st))[:, None] + st * jnp.arange(T // st)[None, :]
+        ok = self._windows_done(at) & (at < t["end"][:, None])
+        bt = jnp.repeat(t["rows"], T // st, axis=0)
+        return self._pool_write(kc, kp, bt, at.reshape(-1), ok.reshape(-1))
+
+    # -- the picks -----------------------------------------------------------------
+    def _block_scores(self, q, kc, row, qpos, spans: int, P: int):
+        """The rows' block scores over ONE prompt's pooled keys: q (R, H, hd) at
+        positions ``qpos`` (R,), the leaf ``kc``, the prompt's block-table row
+        ``row`` (n,) of pages of ``P`` positions -> (KV, R, spans) float32,
+        ``spans`` >= n x P / block: ``+inf`` a forced block, ``-inf`` a block past
+        the row's own."""
+        per, r = P // self.b_stride, self.b_block // self.b_stride
+        extra = self.b_kernel // self.b_stride - 1
+        R, H, hd = q.shape
+        g = H // self.kv
+        at = (row[:, None] * per + jnp.arange(per)[None, :]).reshape(-1)
+        pooled = jnp.take(kc, at, axis=0).reshape(-1, self.kv, hd)             # (J, KV, hd)
+        J = pooled.shape[0]
+        # ... on WHOLE lane tiles (a table of 1,029 pages has 4,116 windows): windows
+        # past the table exist for no row
+        pooled = jnp.pad(pooled, ((0, -J % 128), (0, 0), (0, 0)))
+        exists = (self.b_stride * jnp.arange(pooled.shape[0]) + self.b_kernel - 1)[None, :] \
+            <= jnp.minimum(qpos, self.b_stride * J - 1)[:, None]
+        # A group's heads as ROWS of one product a KV group, (g R, hd) x (hd, J): the
+        # windows lie on the lanes, where the softmax's two reductions are cheap. As
+        # "rkgd,jkd->krgj" the compiler laid the ROWS on the lanes and reduced over
+        # sublanes: 12 ms a tile of the cell, 131 of a launch's 205 (my chip run, PR 68).
+        qg = q.reshape(R, self.kv, g, hd).transpose(1, 2, 0, 3).reshape(self.kv, g * R, hd)
+        s = jnp.einsum("kmd,kjd->kmj", qg, pooled.transpose(1, 0, 2),
+                       preferred_element_type=jnp.float32) * self._scale()
+        seen = jnp.tile(exists, (g, 1))[None]                                 # (1, g R, J)
+        s = jnp.where(seen, s, NEG)
+        # The rows' maxima behind a barrier: fused with the subtraction that reads
+        # them, the compiler made the maximum a reduce-WINDOW as wide as two rows, a
+        # row's 4,224 windows times 8,447 (123 of a launch's 197 ms; my chip run, PR 68).
+        top = jax.lax.optimization_barrier(jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - top) * seen
+        p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+        sc = jnp.where(exists[None], jnp.sum(p.reshape(self.kv, g, R, -1), axis=1),
+                       -jnp.inf)[:, :, :J]                                    # (KV, R, J)
+        # a block's score: the largest over the windows that touch it, r b -
+        # extra .. r b + r - 1 (``extra`` windows begin in the block before)
+        nb = spans
+        sc = jnp.pad(sc, ((0, 0), (0, 0), (extra, (nb + 1) * r - extra - J)),
+                     constant_values=-jnp.inf).reshape(self.kv, R, nb + 1, r)
+        score = jnp.max(sc[:, :, :nb], axis=-1)
+        if extra:
+            score = jnp.maximum(score, jnp.max(sc[:, :, 1:, :extra], axis=-1))
+        b, own = jnp.arange(nb)[None, :], (qpos // self.b_block)[:, None]
+        forced = (b < self.b_init) | (own - b < self.b_local)
+        return jnp.where(b > own, -jnp.inf, jnp.where(forced, jnp.inf, score))
+
+    @scoped("blk_select")
+    def _tile_keep(self, q, kc, row, qpos, spans: int, P: int):
+        """One tile's picks as a mask a block, (KV, T, spans) bool: a row at or
+        past ``dense_len`` keeps its ``topk`` best blocks, another every block.
+        NEIGHBOURING BLOCKS TIE OFTEN, and exactly (the window that straddles
+        their edge is the largest of both): the blocks above the ``topk``-th
+        largest score (``index_select.kth_key``, no sort), then of the blocks AT
+        it the lowest indices, as many as are left, which is ``lax.top_k``'s
+        rule and the step's."""
+        score = self._block_scores(q, kc, row, qpos, spans, P)
+        kv, T, nb = score.shape
+        see = jnp.arange(nb)[None, :] <= jnp.tile(qpos // self.b_block, kv)[:, None]
+        keys = jnp.where(see, ix.sort_keys(score.reshape(kv * T, nb)), jnp.uint32(0))
+        kth = ix.kth_key(keys, self.b_topk)[:, None]
+        above, at = keys > kth, see & (keys == kth)
+        room = self.b_topk - jnp.sum(above, axis=1, keepdims=True)
+        kept = above | (at & (jnp.cumsum(at, axis=1) <= room))
+        return kept.reshape(kv, T, nb) | (qpos < self.dense_len)[None, :, None]
+
+    def _attend_tiles(self, q, pools, kc, t: dict):
+        """A launch's attention tile by tile: a tile whose last live position is
+        under ``dense_len`` is ``PlainAttention``'s; another walks under its
+        rows' picks."""
+        heads = self._heads()
+        P, pps = pools[0].shape[2], t["rows"].shape[1]
+        kb = self._block_pages(P, pps)
+        spans = -(-pps // kb) * kb * P // self.b_block
+
+        def one(a):
+            qt, row, qpos, last = a
+
+            def picked():
+                keep = self._tile_keep(qt, kc, row, qpos, spans, P)
+                with jax.named_scope("blk_attend"):
+                    return self._prefill_full(qt, pools, row, qpos, last, heads,
+                                              keep=(keep, self.b_block))
+
+            return jax.lax.cond(last >= self.dense_len, picked,
+                                lambda: self._prefill_full(qt, pools, row, qpos, last, heads))
+
+        return jax.lax.map(one, (q, t["rows"], t["qpos"], t["last"]))
+
+    def _decode_picked(self, q, kp, vp, kc, bt, pos):
+        """A step's lanes past ``dense_len``: each lane's block scores, its
+        ``topk`` blocks a KV group, and attention over those blocks' keys alone,
+        gathered through the block table -> (b, H, hd) float32. A lane under
+        ``dense_len`` computes on whatever it picks; its row is not used."""
+        b, H, hd = q.shape
+        kv, _pages, P, _ = kp.shape
+        g, B, k = H // kv, self.b_block, self.b_topk
+        nb = bt.shape[1] * P // B
+        with jax.named_scope("blk_select"):
+            score = jax.vmap(lambda ql, row, p: self._block_scores(
+                ql[None], kc, row, p[None], nb, P)[:, 0])(q, bt, pos)          # (b, KV, nb)
+            _, blocks = jax.lax.top_k(score, k)                                # (b, KV, k)
+        with jax.named_scope("blk_attend"):
+            per = P // B
+            at = jnp.take_along_axis(bt, (blocks // per).reshape(b, -1), axis=1) \
+                .reshape(b, kv, k) * per + blocks % per
+            spos = (blocks[..., None] * B + jnp.arange(B)).reshape(b, kv, k * B)
+            see = spos <= pos[:, None, None]
+            out = []
+            for j in range(kv):   # a KV group's blocks, out of the pool seen as blocks
+                own = j * (kp.shape[1] * per) + at[:, j]
+                kj, vj = (jnp.take(pool.reshape(-1, B, hd), own, axis=0).reshape(b, k * B, 1, hd)
+                          for pool in (kp, vp))
+                out.append(self._attend(q[:, None, j * g:(j + 1) * g], kj, vj,
+                                        see[:, j][:, None])[:, 0])
+            return jnp.concatenate(out, axis=1)
+
+    def _decode_blocks(self, q, kp, vp, kc, bt, pos, live):
+        """A step's attention: the lanes under ``dense_len`` over every key
+        (``_decode_full``, where there is one), the others over their picks (where
+        there is one)."""
+        dense = pos < self.dense_len
+        zero = lambda: jnp.zeros(q.shape, jnp.float32)  # noqa: E731
+        o_dense = jax.lax.cond(
+            jnp.any(live & dense),
+            lambda: self._decode_full(q, kp, vp, bt, jnp.where(dense, pos, 0)), zero)
+        o_picked = jax.lax.cond(jnp.any(live & ~dense),
+                                lambda: self._decode_picked(q, kp, vp, kc, bt, pos), zero)
+        return jnp.where(dense[:, None, None], o_dense, o_picked)
+
+    def _blk_prefill(self, lp, u, t: dict, kp, vp, kc, pos, w_page, off):
+        with jax.named_scope("attn_prefill"):
+            q, k, v = self._qkv(lp, u, pos)
+            kp, vp = self._write_pages(kp, w_page, off, k), self._write_pages(vp, w_page, off, v)
+            kc = self._pool_launch(kc, kp, t)
+            o = self._attend_tiles(q.reshape((t["K"], t["T"]) + q.shape[1:]), (kp, vp), kc, t)
+            return self._attn_out(lp, self._gated(lp, u, o.reshape(q.shape))), kp, vp, kc
+
+    def _blk_step(self, lp, u, kp, vp, kc, bt, pos, live, w_page, off):
+        with jax.named_scope("attn_decode"):
+            q, k, v = self._qkv(lp, u, pos)
+            kp, vp = self._write_pages(kp, w_page, off, k), self._write_pages(vp, w_page, off, v)
+            kc = self._pool_write(kc, kp, bt, pos, live & self._windows_done(pos))
+            o = self._decode_blocks(q, kp, vp, kc, bt, pos, live)
+            return self._attn_out(lp, self._gated(lp, u, o)), kp, vp, kc
+
+    def _blk_attn(self, lp, u, kp, vp, kc, m: dict):
+        """One attention layer in the phase the plan ``m`` is of."""
+        if m["t"] is None:
+            return self._blk_step(lp, u, kp, vp, kc, m["bt"], m["pos"], m["live"], m["w_page"],
+                                  m["off"])
+        return self._blk_prefill(lp, u, m["t"], kp, vp, kc, m["pos"], m["w_page"], m["off"])
+
+    def _counts(self, m: dict) -> dict:
+        """And, an attention layer, of the LIVE queries at or past ``dense_len``
+        (the picked): the blocks scored (a KV group each), the keys they may see
+        and the keys of their picked blocks, and the key rows their walks
+        fetched (a step: the picked blocks'; a launch: whole key blocks up to the
+        tile's last position); the live queries by path."""
+        c, t, B, k = super()._counts(m), m["t"], self.b_block, self.b_topk
+        picked = m["live"] & (m["pos"] >= self.dense_len)
+        if t is None:
+            rows = jnp.full(m["pos"].shape, k * B)
+        else:
+            P, pps = m["P"], m["pps"]
+            rows = jnp.repeat(self._blocks_needed(t["last"], P, pps)
+                              * self._block_pages(P, pps) * P, t["T"])
+        of = lambda x: jnp.sum(jnp.where(picked, x, 0))  # noqa: E731
+        return {**c, "blk_scored": of((m["pos"] // B + 1) * self.kv),
+                "blk_visible": of(m["pos"] + 1), "blk_attended": of((k - 1) * B + m["pos"] % B + 1),
+                "blk_read": of(rows), "blk_dense": jnp.sum(m["live"] & ~picked),
+                "blk_picked": jnp.sum(picked)}
+
+
+BLK_PATHS = ("dense", "picked")
+# An attention layer's picked queries' blocks scored, keys visible and attended
+# and key rows fetched (each times the attention layers), and the live queries
+# by path.
+BLK_COLUMNS = (
+    *(Column(lambda model, stats, counts, key=key: counts[key] * len(model.a_layers), series(name))
+      for key, name in (("blk_scored", "blk_blocks_scored_total"),
+                        ("blk_visible", "blk_keys_visible_total"),
+                        ("blk_attended", "blk_keys_attended_total"),
+                        ("blk_read", "blk_rows_read_total"))),
+    *(Column(lambda model, stats, counts, path=path: counts[f"blk_{path}"] * len(model.a_layers),
+             series("blk_queries_total", f",path={path}")) for path in BLK_PATHS))
+
+
+class BlockPatternMixers(_Pattern, LightningMixer, BlockSelectAttention):
+    """Linear attention with a constant decay or attention over picked blocks:
+    the cache leaves are the pages' three (K, V and the pooled keys) and the
+    linear-attention layers' state."""
+    _recurrent, _state_signature = LightningMixer._lightning, LightningMixer._lightning_signature
+    kv_page_leaves = ("kf", "vf", "kc")
+
+    @property
+    def cache_leaves(self) -> tuple:
+        return self.kv_page_leaves + self.kv_slot_state
+
+    def _cache_signature(self, slots: int, pages: int, page_tokens: int) -> dict:
+        pooled = jax.ShapeDtypeStruct(self._pooled_shape(pages, page_tokens), self.dtype)
+        return {**super()._cache_signature(slots, pages, page_tokens),
+                "kc": [pooled for _ in self.a_layers]}
+
+    def _prefill_plan(self, state, launch, t: dict) -> dict:
+        """And the pages' geometry, for the launch's counts."""
+        return {**super()._prefill_plan(state, launch, t),
+                "P": self._page_tokens(state), "pps": state["bt"].shape[1]}
+
+    def _mixer(self, i: int, lp, u, c: dict, m: dict):
+        if i in self.m_layers:
+            return super()._mixer(i, lp, u, c, m)
+        j = self.a_layers.index(i)
+        y, c["kf"][j], c["vf"][j], c["kc"][j] = self._blk_attn(
+            lp, u, c["kf"][j], c["vf"][j], c["kc"][j], m)
+        return y

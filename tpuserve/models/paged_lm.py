@@ -707,13 +707,14 @@ class PagedLM(GenerativeModel):
         return jnp.minimum(last // (kb * P) + 1, -(-pps // kb))
 
     @staticmethod
-    def _over_key_blocks(need, lead: tuple, width: int, block):
+    def _over_key_blocks(need, lead: tuple, width: int, block, skip=None):
         """Attention over key blocks 0 .. need - 1 (a traced count) under a
         running softmax in float32. ``block(j)`` -> (the block's masked
         scores (*lead, c) float32, a function of its un-normalised
         probabilities (*lead, c) -> what they weigh (*lead, width) float32).
-        -> (*lead, width)."""
-        def body(j, carry):
+        ``skip(j)`` (a walk under picks): a traced bool, True where no query
+        keeps a key of block j, which is then not fetched. -> (*lead, width)."""
+        def work(j, carry):
             m, l, acc = carry
             s, weigh = block(j)
             m2 = jnp.maximum(m, jnp.max(s, axis=-1))
@@ -721,6 +722,9 @@ class PagedLM(GenerativeModel):
             scale = jnp.exp(m - m2)
             acc = acc * scale[..., None] + weigh(p)
             return m2, l * scale + jnp.sum(p, axis=-1), acc
+
+        body = work if skip is None else lambda j, carry: jax.lax.cond(
+            skip(j), lambda c: c, lambda c: work(j, c), carry)
 
         m0 = jnp.full(lead, NEG, jnp.float32)
         _m, l, acc = jax.lax.fori_loop(
@@ -742,19 +746,29 @@ class PagedLM(GenerativeModel):
         kp, vp = pools
         return self._pages_by_head(kp, pg, heads.dk), self._pages_by_head(vp, pg, heads.dv)
 
-    def _prefill_full(self, q, pools, row, qpos, last, heads: Heads):
+    def _prefill_full(self, q, pools, row, qpos, last, heads: Heads, keep=None):
         """A full layer's attention of one tile, q (T, H, dk) at positions
         ``qpos``, over its prompt's pages (block-table row ``row``) up to the
         tile's last live position ``last``: key blocks of ``KEY_BLOCK``
         positions, as many as that position needs (a traced count: a
         prompt's first tile reads one block, not the padded context), summed
         with a running softmax in float32 -> (T, H, dv). Every row of the
-        launch is in the pages before any tile reads them."""
+        launch is in the pages before any tile reads them. ``keep`` = (mask
+        (KV, T, spans) bool, span): the walk UNDER PICKS, a row of KV group g
+        sees key s only where ``mask[g, row, s // span]`` besides (spans over
+        the row padded to whole key blocks); a key block none of whose spans
+        any row keeps is skipped."""
         T, P = q.shape[0], pools[0].shape[2]
         kb, rowp = self._key_blocks(row, P)
         g = q.shape[1] // heads.kv
         qg = q.reshape(T, heads.kv, g, heads.dk)
         need = self._blocks_needed(last, P, row.shape[0])
+
+        def kept(j):
+            """Block j's spans of the mask, (KV, T, spans a block)."""
+            mask, span = keep
+            n = kb * P // span
+            return jax.lax.dynamic_slice(mask, (0, 0, j * n), mask.shape[:2] + (n,))
 
         def block(j):
             pg = jax.lax.dynamic_slice(rowp, (j * kb,), (kb,))
@@ -763,11 +777,15 @@ class PagedLM(GenerativeModel):
             see = kpos[None, :] <= qpos[:, None]
             s = jnp.einsum("tkgd,kcd->kgtc", qg, kblk,
                            preferred_element_type=jnp.float32) * self._scale()
-            return jnp.where(see[None, None], s, NEG), lambda p: jnp.einsum(
+            see = see[None, None]
+            if keep is not None:
+                see = see & jnp.repeat(kept(j), keep[1], axis=-1)[:, None]
+            return jnp.where(see, s, NEG), lambda p: jnp.einsum(
                 "kgtc,kcd->kgtd", p.astype(vblk.dtype), vblk,
                 preferred_element_type=jnp.float32)
 
-        o = self._over_key_blocks(need, (heads.kv, g, T), heads.dv, block)
+        skip = None if keep is None else lambda j: ~jnp.any(kept(j))
+        o = self._over_key_blocks(need, (heads.kv, g, T), heads.dv, block, skip)
         return o.transpose(2, 0, 1, 3).reshape(T, q.shape[1], heads.dv)
 
     def _prefill_full_tiles(self, qt, pools, t: dict, heads: Heads | None = None):
