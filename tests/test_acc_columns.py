@@ -67,7 +67,9 @@ SERIES = {
         "blk_blocks_scored_total{model=M,phase=PH}", "blk_keys_visible_total{model=M,phase=PH}",
         "blk_keys_attended_total{model=M,phase=PH}", "blk_rows_read_total{model=M,phase=PH}",
         "blk_queries_total{model=M,phase=PH,path=dense}",
-        "blk_queries_total{model=M,phase=PH,path=picked}"] + SAMPLE,
+        "blk_queries_total{model=M,phase=PH,path=picked}",
+        "blk_selects_total{model=M,phase=PH,path=kernel}",
+        "blk_selects_total{model=M,phase=PH,path=xla}"] + SAMPLE,
     "eva": CONTEXT + [
         "eva_rows_attended_total{model=M,phase=PH,kind=exact}",
         "eva_rows_attended_total{model=M,phase=PH,kind=summary}",
@@ -145,3 +147,24 @@ def test_every_column_of_acc_moves_its_own_counter_and_no_other(family, tmp_path
             assert {k: after[k] - before.get(k, 0.0) for k in after
                     if after[k] != before.get(k, 0.0)} == pytest.approx(
                         fed_by(template, model, ph, 12.0)), (template, ph)
+
+
+def test_the_picks_layers_count_by_where_their_block_scores_were_made(tmp_path):
+    """ISSUE 69's two columns, `blk_selects_total{path=kernel|xla}`, in both phases
+    (a launch's picked tiles by the path its trace chose, a step's lanes the
+    plain form's), of the one family that picks blocks."""
+    t = importlib.import_module("tests.test_hybrid_blk")
+    model = t.make_model(str(tmp_path))
+    metrics = Metrics()
+    model.bind_metrics(metrics)
+    names = SERIES["hybrid_blk"]
+    acc = np.zeros((len(GEN_PHASES), len(names)), np.uint32)
+    j = names.index("blk_selects_total{model=M,phase=PH,path=kernel}")
+    assert names[j + 1] == "blk_selects_total{model=M,phase=PH,path=xla}"
+    acc[0, j], acc[1, j + 1] = 3, 5
+    model.observe_step({"acc": acc})
+    assert {k: v for k, v in metrics.counter_values().items() if v} == {
+        f"blk_selects_total{{model={model.name},phase=prefill,path=kernel}}": 3.0,
+        f"blk_selects_total{{model={model.name},phase=decode,path=xla}}": 5.0}
+    assert not [f for f, series in SERIES.items()
+                if f != "hybrid_blk" and any("blk_selects_total" in s for s in series)]

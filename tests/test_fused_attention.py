@@ -957,6 +957,58 @@ def test_a_launchs_chunked_delta_rule_is_one_kernel_call_a_layer_on_the_v5e_at_t
                 and any(s in ln.split("=")[1][:60] for s in rows)]
 
 
+def test_a_picked_tiles_block_scores_are_one_kernel_call_on_the_v5e_at_the_cells_widths(
+        one_chip, monkeypatch):
+    """`ops/block_scores.py` under `BlockSelectAttention._tile_keep` (ISSUE 69), at
+    the MiniCPM-SALA cell's sizes: a tile of 512 rows, 32 heads of 128 on 2 KV
+    groups, bfloat16, a table of 1,029 pages of 64 (4,116 windows in 9 window
+    blocks of 512, 1,040 spans). The TPU branch is steered by the backend's name
+    here, in the test. Mosaic takes the kernel (a sub-tile's scores held in
+    scratch, the window blocks a traced grid bound, a block's maximum by a roll
+    of one lane); a tile's picks are ONE custom call and the threshold's search:
+    nothing of (KV, g T, windows) float32 is in the program, whole or padded."""
+    from tpuserve.models import mixers
+
+    T, H, KV, hd, P, pps, spans = 512, 32, 2, 128, 64, 1029, 1040
+
+    class Layer(mixers.BlockSelectAttention):
+        name, heads, kv, dtype = "layer", H, KV, jnp.dtype("bfloat16")
+
+        def _scale(self):
+            return hd ** -0.5
+
+    model = Layer()
+    model.hd = hd
+    model._blk_setup("layer", {"kernel_size": 32, "kernel_stride": 16, "block_size": 64,
+                               "topk": 64, "init_blocks": 1, "window_size": 2048,
+                               "dense_len": 8192})
+
+    def shape(*dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def keep(q, kc, row, qpos, last):
+        path = model._select_path(T, pps, P)
+        assert path == "kernel"
+        return model._tile_keep(q, kc, row, qpos, spans, P, last, path)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
+    try:
+        text = jax.jit(keep).lower(
+            shape(T, H, hd, dtype=jnp.bfloat16), shape(16465 * 4, KV * hd, dtype=jnp.bfloat16),
+            shape(pps), shape(T), shape()).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    lines = text.split("\n")
+    calls = [ln for ln in lines if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "block_scores" in calls[0].split("=")[0]
+    assert f"f32[{KV},9,{T},128]" in calls[0].split("=")[1][:40]     # 128 block scores a window block
+    g = H // KV
+    for windows in (4116, 4224, 4608):       # the table's, the plain form's padding, the kernel's
+        assert f"f32[{KV},{g * T},{windows}]" not in text
+        assert f"f32[{KV},{g},{T},{windows}]" not in text
+
+
 @pytest.mark.parametrize("heads,groups", [(64, 1), (128, 1), (32, 2)])
 def test_a_launchs_mamba2_scan_is_one_kernel_call_a_layer_on_the_v5e_at_the_cells_widths(
         one_chip, monkeypatch, heads, groups):

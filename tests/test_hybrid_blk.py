@@ -24,6 +24,7 @@ from tpuserve.config import ModelConfig
 from tpuserve.genserve.model import PrefillPiece
 from tpuserve.models import build, mixers
 from tpuserve.models.paged_lm import LOGPROBS
+from tpuserve.ops import block_scores as bsc
 
 ref = spec.load_module("reference", "hybrid_blk")
 
@@ -162,6 +163,42 @@ def test_chunked_prefill_then_decode_is_the_reference_full_pass(whole, case):
     served, _out, _ = serve(model, params, prompts, max_news, launches=launches, page=page)
     assert [int(s["n_new"]) for s in served] == max_news
     assert worst(served, prompts) < TOL
+
+
+def test_prefill_then_decode_with_the_launches_block_scores_in_the_kernel(tmp_path, monkeypatch):
+    """ISSUE 69: every picked tile's block scores through `ops/block_scores.py` (in
+    the Pallas interpreter; the toy's heads of 16 are steered past `supported`),
+    pages of two blocks so that a tile is 16 rows: the reference's full pass
+    still, and the launches count themselves under `path=kernel`, the steps under
+    `path=xla`."""
+    traced, real = [], bsc.block_scores
+
+    def in_the_interpreter(*a, **kw):
+        traced.append(a[0].shape)
+        return real(*a, **kw, interpret=True)
+
+    monkeypatch.setattr(bsc, "block_scores", in_the_interpreter)
+    monkeypatch.setattr(mixers.BlockSelectAttention, "_select_path",
+                        lambda self, T, pps, P: "kernel")
+    model = make_model(str(tmp_path))
+    params = model.init_params(jax.random.key(0))
+    prompts, max_news = prompts_of(88, 17, seed=3), [9, 5]
+    served, out, _ = serve(model, params, prompts, max_news, page=2 * PAGE)
+    assert traced == [(16, 4, 16)]                       # one trace, the launch's tile
+    assert worst(served, prompts) < TOL
+    names = [c.counter(model, _Names(), "PH") for c in model.COLUMNS]
+    acc = np.asarray(out["acc"])
+    kernel, xla = (names.index(f"blk_selects_total{{model=hb,phase=PH,path={p}}}")
+                   for p in ("kernel", "xla"))
+    # the launch at 64..87 has picked rows, the two before it and the short prompt's none
+    assert acc[0, kernel] == 1 and acc[0, xla] == 0
+    # the long prompt's lane decodes past dense_len: 9 tokens, the first the launch's own
+    assert acc[1, kernel] == 0 and acc[1, xla] == 8
+
+
+class _Names:
+    """A registry that answers a counter's name."""
+    counter = staticmethod(lambda name: name)
 
 
 def test_the_picked_rows_really_drop_keys(whole):

@@ -131,7 +131,12 @@ S_t``, ``lambda_h = exp(-2^(-8 (h + 1) / H))``, q and k normed a head and turned
 attend over the ``topk`` BLOCKS of keys their KV group picks by scores over
 mean-pooled keys (a THIRD page leaf ``kc``), the first and the local blocks
 always kept, under the scopes ``blk_pool``, ``blk_select`` and ``blk_attend`` inside
-``attn_prefill`` / ``attn_decode``.
+``attn_prefill`` / ``attn_decode``. WHERE A LAUNCH'S PICKED TILES' BLOCK SCORES ARE
+MADE is chosen when the launch is traced (``_select_path``: the backend and the
+static shapes, no option; ISSUE 69): on the TPU ONE call of
+``ops/block_scores.py`` a tile (the softmax a head, the group sum and the block
+maximum in fast memory, over the window blocks the tile can see), elsewhere
+``_block_scores`` in XLA, which is also every step's.
 """
 
 from __future__ import annotations
@@ -144,6 +149,7 @@ import numpy as np
 
 from tpuserve.models.decoder import apply_rope
 from tpuserve.models.paged_lm import NEG, Column, _mm, counted, rms_norm, scoped, series
+from tpuserve.ops import block_scores as bsc
 from tpuserve.ops import delta_scan as ds
 from tpuserve.ops import delta_update as du
 from tpuserve.ops import index_select as ix
@@ -1217,7 +1223,10 @@ class BlockSelectAttention(HeadNorms, PlainAttention):
     the windows that touch it, ``+inf`` for the first ``init`` blocks and the
     ``local`` last; the ``topk`` best blocks a (query, KV group), found exactly
     (``ops/index_select.py``'s threshold for a launch's rows, ``lax.top_k`` for a
-    step's lanes: 32 rows): ``blk_select``. Attention over the keys of those
+    step's lanes: 32 rows): ``blk_select``. A launch's tile takes its block
+    scores from ONE kernel call on the TPU (``ops/block_scores.py``,
+    ``_select_path``), a step's lanes and every other backend from
+    ``_block_scores``. Attention over the keys of those
     blocks alone: ``blk_attend``. A LAUNCH walks every key block under the picks
     as a mask a block (``paged_lm._prefill_full(keep=)``; a key block no row of
     the tile picked is skipped): with 512 rows a tile and two groups, the rows'
@@ -1337,16 +1346,33 @@ class BlockSelectAttention(HeadNorms, PlainAttention):
         forced = (b < self.b_init) | (own - b < self.b_local)
         return jnp.where(b > own, -jnp.inf, jnp.where(forced, jnp.inf, score))
 
+    def _select_path(self, T: int, pps: int, P: int) -> str:
+        """Where a launch's picked tiles' block scores are made, chosen when the
+        launch is traced: ONE kernel call a tile (``ops/block_scores.py``) on
+        the TPU at shapes it takes, else the plain form."""
+        on_tpu = jax.default_backend() == "tpu" and bsc.supported(
+            T, self.heads, self.kv, self.hd, pps * (P // self.b_stride),
+            self.b_block // self.b_stride, self.b_kernel // self.b_stride - 1, self.dtype)
+        return "kernel" if on_tpu else "xla"  # tps-ok[TPS503]: backend and static shapes
+
     @scoped("blk_select")
-    def _tile_keep(self, q, kc, row, qpos, spans: int, P: int):
+    def _tile_keep(self, q, kc, row, qpos, spans: int, P: int, last=None, path: str = "xla"):
         """One tile's picks as a mask a block, (KV, T, spans) bool: a row at or
         past ``dense_len`` keeps its ``topk`` best blocks, another every block.
-        NEIGHBOURING BLOCKS TIE OFTEN, and exactly (the window that straddles
+        The block scores by ``path`` (``_select_path``; the kernel's end at the
+        window blocks that hold ``last``, the tile's last live position). NEIGHBOURING
+        BLOCKS TIE OFTEN, and exactly (the window that straddles
         their edge is the largest of both): the blocks above the ``topk``-th
         largest score (``index_select.kth_key``, no sort), then of the blocks AT
         it the lowest indices, as many as are left, which is ``lax.top_k``'s
         rule and the step's."""
-        score = self._block_scores(q, kc, row, qpos, spans, P)
+        if path == "kernel":
+            score = bsc.block_scores(
+                q, kc, row, qpos, last, spans=spans, page=P, kernel=self.b_kernel,
+                stride=self.b_stride, block=self.b_block, init=self.b_init, local=self.b_local,
+                scale=self._scale())
+        else:
+            score = self._block_scores(q, kc, row, qpos, spans, P)
         kv, T, nb = score.shape
         see = jnp.arange(nb)[None, :] <= jnp.tile(qpos // self.b_block, kv)[:, None]
         keys = jnp.where(see, ix.sort_keys(score.reshape(kv * T, nb)), jnp.uint32(0))
@@ -1356,10 +1382,10 @@ class BlockSelectAttention(HeadNorms, PlainAttention):
         kept = above | (at & (jnp.cumsum(at, axis=1) <= room))
         return kept.reshape(kv, T, nb) | (qpos < self.dense_len)[None, :, None]
 
-    def _attend_tiles(self, q, pools, kc, t: dict):
+    def _attend_tiles(self, q, pools, kc, t: dict, path: str = "xla"):
         """A launch's attention tile by tile: a tile whose last live position is
         under ``dense_len`` is ``PlainAttention``'s; another walks under its
-        rows' picks."""
+        rows' picks, their block scores by ``path``."""
         heads = self._heads()
         P, pps = pools[0].shape[2], t["rows"].shape[1]
         kb = self._block_pages(P, pps)
@@ -1369,7 +1395,7 @@ class BlockSelectAttention(HeadNorms, PlainAttention):
             qt, row, qpos, last = a
 
             def picked():
-                keep = self._tile_keep(qt, kc, row, qpos, spans, P)
+                keep = self._tile_keep(qt, kc, row, qpos, spans, P, last, path)
                 with jax.named_scope("blk_attend"):
                     return self._prefill_full(qt, pools, row, qpos, last, heads,
                                               keep=(keep, self.b_block))
@@ -1420,12 +1446,13 @@ class BlockSelectAttention(HeadNorms, PlainAttention):
                                 lambda: self._decode_picked(q, kp, vp, kc, bt, pos), zero)
         return jnp.where(dense[:, None, None], o_dense, o_picked)
 
-    def _blk_prefill(self, lp, u, t: dict, kp, vp, kc, pos, w_page, off):
+    def _blk_prefill(self, lp, u, t: dict, kp, vp, kc, pos, w_page, off, path: str):
         with jax.named_scope("attn_prefill"):
             q, k, v = self._qkv(lp, u, pos)
             kp, vp = self._write_pages(kp, w_page, off, k), self._write_pages(vp, w_page, off, v)
             kc = self._pool_launch(kc, kp, t)
-            o = self._attend_tiles(q.reshape((t["K"], t["T"]) + q.shape[1:]), (kp, vp), kc, t)
+            o = self._attend_tiles(q.reshape((t["K"], t["T"]) + q.shape[1:]), (kp, vp), kc, t,
+                                   path)
             return self._attn_out(lp, self._gated(lp, u, o.reshape(q.shape))), kp, vp, kc
 
     def _blk_step(self, lp, u, kp, vp, kc, bt, pos, live, w_page, off):
@@ -1441,16 +1468,20 @@ class BlockSelectAttention(HeadNorms, PlainAttention):
         if m["t"] is None:
             return self._blk_step(lp, u, kp, vp, kc, m["bt"], m["pos"], m["live"], m["w_page"],
                                   m["off"])
-        return self._blk_prefill(lp, u, m["t"], kp, vp, kc, m["pos"], m["w_page"], m["off"])
+        return self._blk_prefill(lp, u, m["t"], kp, vp, kc, m["pos"], m["w_page"], m["off"],
+                                 m["select_path"])
 
     def _counts(self, m: dict) -> dict:
         """And, an attention layer, of the LIVE queries at or past ``dense_len``
         (the picked): the blocks scored (a KV group each), the keys they may see
         and the keys of their picked blocks, and the key rows their walks
         fetched (a step: the picked blocks'; a launch: whole key blocks up to the
-        tile's last position); the live queries by path."""
+        tile's last position); the live queries by path; and the layer itself,
+        where it had a picked tile or lane, by where its block scores were made
+        (a step's lanes: the plain form)."""
         c, t, B, k = super()._counts(m), m["t"], self.b_block, self.b_topk
         picked = m["live"] & (m["pos"] >= self.dense_len)
+        path = m.get("select_path", "xla")
         if t is None:
             rows = jnp.full(m["pos"].shape, k * B)
         else:
@@ -1461,13 +1492,15 @@ class BlockSelectAttention(HeadNorms, PlainAttention):
         return {**c, "blk_scored": of((m["pos"] // B + 1) * self.kv),
                 "blk_visible": of(m["pos"] + 1), "blk_attended": of((k - 1) * B + m["pos"] % B + 1),
                 "blk_read": of(rows), "blk_dense": jnp.sum(m["live"] & ~picked),
-                "blk_picked": jnp.sum(picked)}
+                "blk_picked": jnp.sum(picked),
+                "selects": {p: jnp.any(picked) * int(p == path) for p in PATHS}}
 
 
 BLK_PATHS = ("dense", "picked")
 # An attention layer's picked queries' blocks scored, keys visible and attended
-# and key rows fetched (each times the attention layers), and the live queries
-# by path.
+# and key rows fetched (each times the attention layers), the live queries by
+# path, and the attention layers of a launch or a step that had a picked tile
+# or lane, by where their block scores were made.
 BLK_COLUMNS = (
     *(Column(lambda model, stats, counts, key=key: counts[key] * len(model.a_layers), series(name))
       for key, name in (("blk_scored", "blk_blocks_scored_total"),
@@ -1475,7 +1508,9 @@ BLK_COLUMNS = (
                         ("blk_attended", "blk_keys_attended_total"),
                         ("blk_read", "blk_rows_read_total"))),
     *(Column(lambda model, stats, counts, path=path: counts[f"blk_{path}"] * len(model.a_layers),
-             series("blk_queries_total", f",path={path}")) for path in BLK_PATHS))
+             series("blk_queries_total", f",path={path}")) for path in BLK_PATHS),
+    *(Column(lambda model, stats, counts, path=path: counts["selects"][path] * len(model.a_layers),
+             series("blk_selects_total", f",path={path}")) for path in PATHS))
 
 
 class BlockPatternMixers(_Pattern, LightningMixer, BlockSelectAttention):
@@ -1495,9 +1530,11 @@ class BlockPatternMixers(_Pattern, LightningMixer, BlockSelectAttention):
                 "kc": [pooled for _ in self.a_layers]}
 
     def _prefill_plan(self, state, launch, t: dict) -> dict:
-        """And the pages' geometry, for the launch's counts."""
-        return {**super()._prefill_plan(state, launch, t),
-                "P": self._page_tokens(state), "pps": state["bt"].shape[1]}
+        """And the pages' geometry, for the launch's counts, and where its
+        picked tiles' block scores are made, chosen once for all its layers."""
+        P, pps = self._page_tokens(state), state["bt"].shape[1]
+        return {**super()._prefill_plan(state, launch, t), "P": P, "pps": pps,
+                "select_path": self._select_path(t["T"], pps, P)}
 
     def _mixer(self, i: int, lp, u, c: dict, m: dict):
         if i in self.m_layers:
