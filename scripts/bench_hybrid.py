@@ -65,14 +65,14 @@ def main() -> None:
         options={"config_file": arch_path, "draw_weights_seed": args.seed,
                  "max_prompt_tokens": sz["max_prompt"], "max_new_tokens": sz["max_new"]}))
     slots, pages, P, chunk = sz["slots"], sz["kv_pages"], sz["page_tokens"], sz["prefill_chunk"]
-    pps = model.kv_pages_per_slot(P)
+    plan = model.kv_plan(slots, P, pages)
+    pps = plan.pages_per_slot
     k = model.kv_prefill_pieces(chunk, P)
     tile = chunk // k
     t0 = time.perf_counter()
     params = jax.block_until_ready(model.init_params(None))
     print(f"weights drawn in {time.perf_counter() - t0:.1f} s", flush=True)
-    state = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype),
-                                   model.kv_page_signature(slots, pages, P))
+    state = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), plan.state)
     prefill = jax.jit(lambda p, s, l: model.prefill_chunk(p, s, l, chunk=chunk),
                       donate_argnums=(1,))
     step = jax.jit(model.step, donate_argnums=(1,))

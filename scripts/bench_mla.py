@@ -195,7 +195,8 @@ def main() -> None:
                  "max_prompt_tokens": sz["max_prompt"], "max_new_tokens": sz["max_new"]}))
     slots, pages, P = sz["slots"], sz["kv_pages"], sz["page_tokens"]
     chunk = args.chunk or sz["prefill_chunk"]
-    pps = model.kv_pages_per_slot(P)
+    plan = model.kv_plan(slots, P, pages)
+    pps = plan.pages_per_slot
     k = model.kv_prefill_pieces(chunk, P)
     context = min(args.context, sz["max_prompt"]) // chunk * chunk or min(chunk, sz["max_prompt"])
     assert slots * -(-(context + sz["max_new"]) // P) < pages, "the pool holds every slot's context"
@@ -203,8 +204,7 @@ def main() -> None:
     params = jax.block_until_ready(model.init_params(None))
     print(f"weights drawn in {time.perf_counter() - t0:.1f} s; chunk {chunk} in {k} tiles of "
           f"{chunk // k} ({model._form(chunk // k)}), a step {model._form(1)}", flush=True)
-    state = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype),
-                                   model.kv_page_signature(slots, pages, P))
+    state = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), plan.state)
     prefill = jax.jit(lambda p, s, l: model.prefill_chunk(p, s, l, chunk=chunk),
                       donate_argnums=(1,))
     rng = np.random.default_rng(args.seed)

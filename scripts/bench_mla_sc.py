@@ -84,7 +84,8 @@ def main() -> None:
 
     model = make()
     slots, pages, P = sz["slots"], sz["kv_pages"], sz["page_tokens"]
-    chunk, pps = sz["prefill_chunk"], model.kv_pages_per_slot(P)
+    plan = model.kv_plan(slots, P, pages)
+    chunk, pps = sz["prefill_chunk"], plan.pages_per_slot
     if args.rehearse:   # a toy's tiles are pages
         model.TILE_ROWS = model.key_block = P
     rng = np.random.default_rng(args.seed)
@@ -102,8 +103,7 @@ def main() -> None:
     params = jax.block_until_ready(model.init_params(None))
     print(f"weights drawn in {time.perf_counter() - t0:.1f} s; {slots} lanes, prompts median "
           f"{int(np.median(prompts))}, max {int(prompts.max())}", flush=True)
-    state = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype),
-                                   model.kv_page_signature(slots, pages, P))
+    state = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), plan.state)
     items = []
     for slot in range(slots):
         ids = np.zeros((sz["max_prompt"],), np.int32)

@@ -112,8 +112,8 @@ def main() -> None:
                                 "max_new_tokens": served["max_new_tokens"]})
     model = dec.create(mcfg)
     params = jax.block_until_ready(model._drawn())
-    pps, K = model.kv_pages_per_slot(P), model.kv_prefill_pieces(C, P)
-    struct = model.kv_page_signature(slots, gen["kv_pages"] or slots * pps + 1, P)
+    plan = model.kv_plan(slots, P, gen["kv_pages"])
+    pps, K, struct = plan.pages_per_slot, model.kv_prefill_pieces(C, P), plan.state
     state = jax.tree_util.tree_map(lambda s: jax.numpy.zeros(s.shape, s.dtype), struct)
     dev = jax.devices()[0]
     lines = []

@@ -82,13 +82,12 @@ def lower(model, slots: int, pages: int, page_tokens: int, prefill_chunk: int,
 
     from tpuserve.genserve.model import PrefillPiece
 
-    pps = int(model.kv_pages_per_slot(page_tokens))
-    n_pages = pages or slots * pps + 1
+    plan = model.kv_plan(slots, page_tokens, pages)
     chunk = int(model.kv_prefill_chunk(prefill_chunk))
     k = int(model.kv_prefill_pieces(chunk, page_tokens))
-    state = model.kv_page_signature(slots, n_pages, page_tokens)
-    row = np.arange(1, pps + 1, dtype=np.int32)
-    cache = {"pages": row, "ring": np.int32(1)} if model.kv_ring_tokens() else row
+    state = plan.state
+    row = np.arange(1, plan.pages_per_slot + 1, dtype=np.int32)
+    cache = {"pages": row, "ring": np.int32(1)} if plan.ring_tokens else row
     item = model.canary_item()
     launch = model.pack_prefill(
         [PrefillPiece(0, item, 0, min(chunk, model.prompt_tokens(item)), cache)], chunk, k)

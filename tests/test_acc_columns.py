@@ -104,8 +104,8 @@ def test_every_column_of_acc_moves_its_own_counter_and_no_other(family, tmp_path
     names, n = SERIES[family], len(model.COLUMNS)
     metrics = Metrics()
     model.bind_metrics(metrics)
-    pps = model.kv_pages_per_slot(t.PAGE)
-    sig = model.kv_page_signature(t.SLOTS, t.SLOTS * pps + 1, t.PAGE)
+    plan = model.kv_plan(t.SLOTS, t.PAGE)
+    pps, sig = plan.pages_per_slot, plan.state
     # one list says it all: the state's width, the counters bound, a launch's row
     assert len(names) == n and sig["acc"].shape == (len(GEN_PHASES), n)
     assert [len(row) for row in model._counters] == [n] * len(GEN_PHASES)
@@ -115,7 +115,7 @@ def test_every_column_of_acc_moves_its_own_counter_and_no_other(family, tmp_path
         "prompt_ids": [model.v_first + i for i in (5, 3, 9, 1, 7, 2)], "max_new_tokens": 3,
     }).encode(), "application/json")
     row = np.arange(1, pps + 1, dtype=np.int32)
-    cache = {"pages": row, "ring": np.int32(1)} if model.kv_ring_tokens() else row
+    cache = {"pages": row, "ring": np.int32(1)} if plan.ring_tokens else row
     k = model.kv_prefill_pieces(t.CHUNK, t.PAGE)
     launch = model.pack_prefill([PrefillPiece(0, item, 0, 6, cache)], t.CHUNK, k)
     state = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), sig)

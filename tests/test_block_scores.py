@@ -216,8 +216,8 @@ def test_a_launch_takes_the_kernel_on_the_tpu_alone_and_at_its_shapes_alone(monk
 
 def _launch_text(model, params, steer=None) -> str:
     """The prefill program's lowered text for a launch of one piece."""
-    pps = model.kv_pages_per_slot(hb.PAGE)
-    state = hb.zeros(model.kv_page_signature(hb.SLOTS, hb.SLOTS * pps + 1, hb.PAGE))
+    pps = model.kv_plan(1, hb.PAGE).pages_per_slot
+    state = hb.zeros(model.kv_plan(hb.SLOTS, hb.PAGE).state)
     k = model.kv_prefill_pieces(hb.CHUNK, hb.PAGE)
     prompts = hb.prompts_of(90)
     launch = model.pack_prefill([hb.piece_of(model, prompts, [4], 0, 64, 26)], hb.CHUNK, k)

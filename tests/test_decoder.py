@@ -67,9 +67,9 @@ def serve(model, params, prompts, max_news, chunk=CHUNK, order=None, launches=No
     goes alone, a chunk a launch, in ``order``. ``state``: the block an earlier
     call left, its pages and rings handed out again. Returns extract() per
     slot, the last step's out-block and the state."""
-    pps = model.kv_pages_per_slot(PAGE)
+    pps = model.kv_plan(1, PAGE).pages_per_slot
     if state is None:
-        state = zeros(model.kv_page_signature(slots, slots * pps + 1, PAGE))
+        state = zeros(model.kv_plan(slots, PAGE).state)
     k = model.kv_prefill_pieces(chunk, PAGE)
     prefill = jax.jit(model.prefill_chunk, static_argnames=("chunk",))
     step = jax.jit(model.step)

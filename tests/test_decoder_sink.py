@@ -23,7 +23,7 @@ import pytest
 
 from tests import decoder_sink_reference as ref
 from tpuserve.config import ModelConfig
-from tpuserve.genserve.model import PrefillPiece
+from tpuserve.genserve.model import LeafKind, PrefillPiece
 from tpuserve.models import build
 from tpuserve.models import decoder as dec
 from tpuserve.models import decoder_sink as ds
@@ -113,8 +113,8 @@ def serve(model, params, prompts, max_news, chunk=CHUNK, launches=None, slots=SL
     a chunk a launch), then steps (traced under ``steer()``, where the caller
     has one: `in_the_kernel`) until every lane is done -> (extract() a slot,
     the last step's out-block, the state)."""
-    pps = model.kv_pages_per_slot(page)
-    state = zeros(model.kv_page_signature(slots, slots * pps + 1, page))
+    pps = model.kv_plan(1, page).pages_per_slot
+    state = zeros(model.kv_plan(slots, page).state)
     k = model.kv_prefill_pieces(chunk, page)
     prefill = jax.jit(model.prefill_chunk, static_argnames=("chunk",))
     step = jax.jit(model.step)
@@ -320,23 +320,24 @@ def test_the_page_pools_hold_320_values_a_token_a_kv_head_and_every_leaf_is_lane
     assert wide._heads(0) == Heads(4, 192, 128) and wide._heads(1) == Heads(8, 192, 128)
     assert wide.turning == {"full_attention": 64, "sliding_attention": 64}
     slots, pages, P = 384, 4608, 128
-    sig = wide.kv_page_signature(slots, pages, P)
+    plan = wide.kv_plan(slots, P, pages)
+    sig = plan.state
     assert [sig[leaf][0].shape for leaf in ("kn", "kr", "vf")] == [
         (4, pages, P, 128), (2, pages, P, 128), (4, pages, P, 128)]
     # a slot's ring: 128 places, a place a row with its 8 heads side by side, a key in two parts
     assert [sig[leaf][0].shape for leaf in ("kwn", "kwr", "vw")] == [
         (385, 128, 1024), (385, 128, 512), (385, 128, 1024)]
-    assert [len(sig[leaf]) for leaf in wide.cache_leaves] == [2, 2, 2, 5, 5, 5]
-    for leaf in wide.cache_leaves:
+    assert [len(sig[leaf]) for leaf in wide._leaves()] == [2, 2, 2, 5, 5, 5]
+    for leaf in wide._leaves():
         assert all(s.shape[-1] % 128 == 0 and s.dtype == jnp.bfloat16 for s in sig[leaf]), leaf
 
     def nbytes(leaves):
         return sum(int(np.prod(s.shape)) * 2 for leaf in leaves for s in sig[leaf])
 
     # what the engine's kv_row_bytes / kv_cache_bytes count: the page leaves' shapes
-    assert nbytes(wide.kv_page_leaves) == pages * 655_360
-    assert nbytes(wide.kv_page_leaves) // (pages * P) == 5_120
-    assert nbytes(("kwn", "kwr", "vw")) == 385 * 3_276_800
+    assert nbytes(plan.leaves(LeafKind.POOL)) == plan.pool_bytes == pages * 655_360
+    assert nbytes(plan.leaves(LeafKind.POOL)) // (pages * P) == plan.row_bytes == 5_120
+    assert nbytes(("kwn", "kwr", "vw")) == plan.ring_bytes == 385 * 3_276_800
 
 
 def test_the_draw_is_the_references_and_the_sink_is_float32(whole):

@@ -104,9 +104,9 @@ def serve(model, params, prompts, max_news, chunk=CHUNK, launches=None, slots=SL
     a chunk a launch), then steps until every lane is done -> (extract() a
     slot, the last step's out-block, the state). ``steer`` and ``steer_launch``:
     a context the steps, the launches are traced and run in."""
-    pps = model.kv_pages_per_slot(page)
+    pps = model.kv_plan(1, page).pages_per_slot
     if state is None:
-        state = zeros(model.kv_page_signature(slots, slots * pps + 1, page))
+        state = zeros(model.kv_plan(slots, page).state)
     k = model.kv_prefill_pieces(chunk, page)
     prefill = jax.jit(model.prefill_chunk, static_argnames=("chunk",))
     step = jax.jit(model.step)
@@ -296,8 +296,8 @@ def test_a_steps_walk_of_the_virtual_table_is_the_gather_and_reads_live_rows_onl
     acc = np.asarray(out["acc"]).astype(int)
     assert acc[1, 5] == model.n_layers * sum(n - 1 for n in news) and acc[1, 6] == 0
     # the plan of one step, as arrays: lane 0 live at position 21, lane 1 at 37, lane 2 free
-    pps, c, P = model.kv_pages_per_slot(PAGE), model.chunk, model.rows
-    state = zeros(model.kv_page_signature(SLOTS, SLOTS * pps + 1, PAGE))
+    pps, c, P = model.kv_plan(1, PAGE).pages_per_slot, model.chunk, model.rows
+    state = zeros(model.kv_plan(SLOTS, PAGE).state)
     bt = np.arange(1, 1 + SLOTS * pps).reshape(SLOTS, pps).astype(np.int32)
     state = dict(state, bt=jnp.asarray(bt), ring=jnp.asarray([1, 2, 3], jnp.int32))
     with in_the_walk():
@@ -364,7 +364,7 @@ def test_the_walk_with_a_key_in_one_part_is_plain_float32_attention(cell_heads, 
     model = cell_heads
     pos, live = (np.asarray(x) for x in WALK_CASES[case])
     slots, (c, P, W), hd = len(pos), (model.chunk, model.rows, model.window), model.hd
-    pps = model.kv_pages_per_slot(P)
+    pps = model.kv_plan(1, P).pages_per_slot
     rng = np.random.default_rng(int(pos.sum()) + block_pages)
     n_pages = c * (slots + 1) + slots * pps + 1
     kp, vp = (jnp.asarray(rng.standard_normal((model.kv, n_pages, P, hd)), jnp.bfloat16)
@@ -610,7 +610,7 @@ def packed_state(whole):
 
 
 @pytest.mark.parametrize("layer", range(ARCH["num_hidden_layers"]))
-@pytest.mark.parametrize("leaf", eva.EvaServing.cache_leaves)
+@pytest.mark.parametrize("leaf", ("kf", "vf"))
 def test_the_pools_hold_the_parents_rows_by_head_side_by_side(packed_state, by_head, leaf, layer):
     """Row for row what the parent's pools by head held after the same launches
     and steps (rings written as slabs, summaries by a launch and by a step,
@@ -644,8 +644,8 @@ def test_a_lowered_program_writes_a_token_as_one_row_and_moves_no_pool(whole, pr
     pieces a scatter and a launch's rows were transposed to be laid as pages)."""
     model, _ = whole
     params = jax.eval_shape(lambda: model.init_params(jax.random.key(0)))
-    pps = model.kv_pages_per_slot(PAGE)
-    state = model.kv_page_signature(SLOTS, SLOTS * pps + 1, PAGE)
+    pps = model.kv_plan(1, PAGE).pages_per_slot
+    state = model.kv_plan(SLOTS, PAGE).state
     pages, P, row = state["kf"][0].shape
     if program == "step":
         with in_the_walk() if path == "kernel" else contextlib.nullcontext():
@@ -678,17 +678,18 @@ def test_the_caches_geometry_a_page_stands_for_a_window(whole, tmp_path):
     from tpuserve.runtime import build_runtime
 
     model, _ = whole
-    assert model.kv_ring_tokens() == 16 and model.rows == 4
-    assert model.kv_page_span(PAGE) == 16 and model.kv_ring_pages(PAGE) == 4
-    assert model.kv_pages_per_slot(PAGE) == 4                       # ceil((40 + 24) / 16)
+    plan = model.kv_plan(SLOTS, PAGE, 9)
+    assert plan.ring_tokens == 16 and model.rows == 4 and model._leaves() == ("kf", "vf")
+    assert plan.page_positions == 16 and plan.ring_pages == 4
+    assert plan.pages_per_slot == 4                                 # ceil((40 + 24) / 16)
     item = lambda n, new: (None, np.int32(n), None, np.int32(new))  # noqa: E731
-    assert [model.pages_needed(item(n, new), PAGE) for n, new in ((1, 1), (10, 6), (10, 7),
-                                                                 (40, 24))] == [1, 1, 2, 4]
-    sig = model.kv_page_signature(SLOTS, 9, PAGE)
+    assert [plan.pages_for(model.context_tokens(item(n, new)))
+            for n, new in ((1, 1), (10, 6), (10, 7), (40, 24))] == [1, 1, 2, 4]
+    sig = plan.state
     assert [s.shape for s in sig["kf"]] == [(4 * (SLOTS + 1) + 9, 4, 2 * 16)] * 2   # a row: 2 heads
     assert sig["bt"].shape == (SLOTS, 4) and sig["ring"].shape == (SLOTS,)
     with pytest.raises(ValueError, match="kv_page_tokens"):
-        model.kv_page_signature(SLOTS, 9, 8)
+        model.kv_plan(SLOTS, 8, 9).state
     with pytest.raises(ValueError, match="at most a window"):
         model.kv_prefill_pieces(32, PAGE)
     with pytest.raises(NotImplementedError, match="num_chunks"):

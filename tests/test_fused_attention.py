@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from tpuserve.config import ModelConfig
+from tpuserve.genserve.model import LeafKind
 from tpuserve.models import build
 from tpuserve.models.bert import _masked_attention, _segment_bias
 from tpuserve.obs import Metrics
@@ -444,7 +445,7 @@ def test_a_prefill_tiles_walk_is_one_kernel_call_on_the_v5e_at_the_cells_widths(
                                        "max_prompt_tokens": pps * 128 - 768,
                                        "max_new_tokens": 768}))
     assert model.TILE_ROWS == tile and model._form(tile) == "expanded" \
-        and model.kv_pages_per_slot(128) == pps and model._block_pages(128, pps) == block_pages
+        and model.kv_plan(1, 128).pages_per_slot == pps and model._block_pages(128, pps) == block_pages
 
     def shape(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -508,7 +509,7 @@ def test_a_steps_walk_is_one_kernel_call_an_attention_on_the_v5e_at_the_cells_wi
                               options={"config_file": str(path),
                                        "max_prompt_tokens": pps * 128 - 768,
                                        "max_new_tokens": 768}))
-    assert model._form(1) == "absorbed" and model.kv_pages_per_slot(128) == pps \
+    assert model._form(1) == "absorbed" and model.kv_plan(1, 128).pages_per_slot == pps \
         and model.step_keys // 128 == block_pages
 
     def shape(*dims, dtype=jnp.bfloat16):
@@ -573,7 +574,7 @@ def test_attention_over_picks_is_kernel_calls_on_the_v5e_at_the_cells_widths(
                               options={"config_file": str(path), "max_prompt_tokens": 32768,
                                        "max_new_tokens": 256}))
     assert model.TILE_ROWS == tile and model._form(tile) == "expanded" \
-        and model.kv_pages_per_slot(128) == pps and model._block_pages(128, pps) == 8
+        and model.kv_plan(1, 128).pages_per_slot == pps and model._block_pages(128, pps) == 8
 
     def shape(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -651,9 +652,9 @@ def _sink_cell(tmp_path, one_chip):
     def shape(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    sig = model.kv_page_signature(lanes, pages, P)
-    leaves = {leaf: shape(*sig[leaf][0].shape) for leaf in model.cache_leaves}
-    lane = {"bt": shape(lanes, model.kv_pages_per_slot(P), dtype=jnp.int32),
+    sig = model.kv_plan(lanes, P, pages).state
+    leaves = {leaf: shape(*sig[leaf][0].shape) for leaf in model._leaves()}
+    lane = {"bt": shape(lanes, model.kv_plan(1, P).pages_per_slot, dtype=jnp.int32),
             "pos": shape(lanes, dtype=jnp.int32), "live": shape(lanes, dtype=jnp.bool_),
             "ring": shape(lanes, dtype=jnp.int32)}
     return model, shape, leaves, lane
@@ -682,9 +683,9 @@ def test_a_global_layers_decode_is_one_kernel_call_on_the_v5e_at_the_cells_width
     of a pool."""
     model, shape, leaves, lane = _sink_cell(tmp_path, one_chip)
     lanes, pages, P = 384, 4608, 128
-    pps, heads = model.kv_pages_per_slot(P), model._heads(0)
+    pps, heads = model.kv_plan(1, P).pages_per_slot, model._heads(0)
     assert pps == 24 and heads == (4, 192, 128) and model.step_keys // P == 4
-    pools = tuple(leaves[leaf] for leaf in model.kv_page_leaves)
+    pools = tuple(leaves[leaf] for leaf in model._leaves(LeafKind.POOL))
     assert [p.shape for p in pools] == [(4, pages, P, 128), (2, pages, P, 128), (4, pages, P, 128)]
 
     def attend(q, k, v, pools, bt, pos, live, ring):
@@ -874,7 +875,7 @@ def test_a_steps_delta_rule_update_is_one_kernel_call_a_layer_on_the_v5e_at_the_
                                        "max_new_tokens": 128}))
     place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
     params = jax.tree_util.tree_map(place, jax.eval_shape(lambda: model.draw_params(0)))
-    state = jax.tree_util.tree_map(place, model.kv_page_signature(lanes, 64, 128))
+    state = jax.tree_util.tree_map(place, model.kv_plan(lanes, 128, 64).state)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     jax.config.update("jax_enable_compilation_cache", False)  # unreadable here
     try:
@@ -925,7 +926,7 @@ def test_a_launchs_chunked_delta_rule_is_one_kernel_call_a_layer_on_the_v5e_at_t
                                        "max_new_tokens": 128}))
     place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
     lp = jax.tree_util.tree_map(place, jax.eval_shape(lambda: model.draw_params(0)))["layer1"]
-    sig = model.kv_page_signature(slots, 64, 128)
+    sig = model.kv_plan(slots, 128, 64).state
 
     def shape(*dims, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -1110,9 +1111,9 @@ def test_evas_step_walks_rings_and_pages_in_place_on_the_v5e_at_the_cells_widths
                                        "max_new_tokens": 512}))
     place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
     params = jax.tree_util.tree_map(place, jax.eval_shape(lambda: model.draw_params(0)))
-    state = jax.tree_util.tree_map(place, model.kv_page_signature(slots, pages, P))
+    state = jax.tree_util.tree_map(place, model.kv_plan(slots, P, pages).state)
     k = model.kv_prefill_pieces(chunk, P)
-    assert (k, model.kv_pages_per_slot(P), state["kf"][0].shape) == (8, 13, (560, 128, 4096))
+    assert (k, model.kv_plan(1, P).pages_per_slot, state["kf"][0].shape) == (8, 13, (560, 128, 4096))
     launch = {"ids": (chunk,), "pages": (k, 13), "temp": (k,),
               **{f: (k,) for f in ("slot", "start", "length", "n", "seed", "max_new", "ring")}}
     launch = {f: jax.ShapeDtypeStruct(dims, jnp.float32 if f == "temp" else jnp.int32,

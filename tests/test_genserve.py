@@ -749,6 +749,81 @@ FROZEN_CASES = ["textgen-dense", "textgen-paged", "textgen-sharded-dense", "text
                 "hybrid_blk"]
 
 
+# ---------------------------------------------------------------------------
+# What a slot keeps is said once (ISSUE 70): every paged family's CachePlan
+# ---------------------------------------------------------------------------
+# /stats ``kv`` at the toy geometry, as the commit before ISSUE 70 printed it
+# (six functions of the engine over four tuples a family), for the families whose
+# own files pin no number: (kv_bytes, row_bytes_per_token, page_positions,
+# state_bytes_per_slot, state_bytes).
+KV_PINNED = {"decoder": (28672, 256, 4, 0, 0), "hybrid_ffn_moe": (229376, 2048, 4, 45824, 137472),
+             "hybrid_blk": (117760, 320, 8, 12288, 36864), "textgen": (63488, 256, 8, 0, 0)}
+PLAN_CASES = [c for c in FROZEN_CASES if "-" not in c and c != "sd15"] + ["textgen-paged"]
+
+
+@pytest.mark.parametrize("case", PLAN_CASES)
+def test_the_plan_gives_every_leaf_one_kind_and_its_bytes_add_up(case, tmp_path):
+    import importlib
+
+    import jax
+    import numpy as np
+
+    from tpuserve.genserve.model import LeafKind
+    family = case.split("-")[0]
+    model, rt_kw, gc, _short, _long = _frozen_case(case, tmp_path)
+    if family == "textgen":
+        slots, page = FROZEN_SLOTS, gc["kv_page_tokens"]
+    else:
+        toy = importlib.import_module(f"tests.test_{family}")
+        slots, page = toy.SLOTS, toy.PAGE
+    plan = model.kv_plan(slots, page)
+    assert plan.pages == slots * plan.pages_per_slot + 1 and plan.slots == slots
+    nbytes = lambda tree: sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize  # noqa: E731
+                              for x in jax.tree_util.tree_leaves(tree))
+    # one kind a leaf, and the leaf is shaped as its kind says it is addressed
+    by_kind = {kind: plan.leaves(kind) for kind in LeafKind}
+    assert sorted(sum(by_kind.values(), ())) == sorted(plan.state) and set(plan.kinds) <= set(plan.state)
+    pooled = plan.pages + (slots + 1) * plan.ring_pages
+    for leaf in plan.state:
+        for x in jax.tree_util.tree_leaves(plan.state[leaf]):
+            kind = plan.kind(leaf)
+            if kind is LeafKind.POOL:
+                assert any(d and d % pooled == 0 for d in x.shape), (leaf, x.shape)
+            elif kind is LeafKind.RINGS:
+                assert x.shape[0] == slots + 1 and plan.ring_tokens in x.shape, (leaf, x.shape)
+            elif kind is LeafKind.SLOT:
+                assert x.shape[0] == slots, (leaf, x.shape)
+            else:
+                assert pooled not in x.shape and (not x.shape or x.shape[0] != slots + 1), leaf
+    if hasattr(model, "_leaves"):   # what the programs find their leaves by is the plan's
+        assert {leaf: plan.kind(leaf) for leaf in model._leaves()} == plan.kinds
+    # the bytes by kind are the state's, and the derived ones divide them
+    assert [plan.bytes_of(kind) for kind in LeafKind] \
+        == [nbytes([plan.state[leaf] for leaf in by_kind[kind]]) for kind in LeafKind]
+    assert sum(plan.bytes_of(kind) for kind in LeafKind) == nbytes(plan.state)
+    assert plan.pool_bytes == pooled * plan.page_bytes > 0
+    assert plan.page_bytes == plan.page_positions * plan.row_bytes
+    assert plan.ring_bytes == plan.bytes_of(LeafKind.RINGS) \
+        + (slots + 1) * plan.ring_pages * plan.page_bytes
+    assert bool(plan.ring_bytes) == bool(plan.ring_tokens)
+    assert plan.slot_bytes % slots == 0
+    # a request at the family's longest context takes a whole row of the block table
+    worst = (None, np.int32(model.max_prompt), None, np.int32(model.max_new))
+    assert plan.pages_for(model.context_tokens(worst)) == plan.pages_per_slot
+    assert plan.pages_for(1) == 1 and plan.state["bt"].shape == (slots, plan.pages_per_slot)
+    # and /stats says the plan's numbers
+    rt = build_runtime(model, compile_forward=False, **rt_kw)
+    eng = GenEngine(model, rt, Metrics(), GenserveConfig(
+        slots=slots, kv_paging=True, kv_page_tokens=page, prefill_chunk=gc.get("prefill_chunk", 0)))
+    kv = eng.pipeline_stats()["kv"]
+    said = (kv["kv_bytes"], kv["row_bytes_per_token"], kv["page_positions"],
+            kv["state_bytes_per_slot"], kv["state_bytes"])
+    assert said == (plan.pool_bytes, plan.row_bytes, plan.page_positions,
+                    plan.slot_bytes // slots, plan.slot_bytes)
+    assert ("ring_bytes" in kv) == bool(plan.ring_pages)
+    assert said == KV_PINNED.get(family, said)
+
+
 def test_the_frozen_lane_cases_name_every_registered_generating_family():
     import importlib
 
@@ -773,7 +848,7 @@ def test_a_lane_whose_out_block_said_done_is_not_changed_by_one_more_step(case, 
     eng.compile()
     slots, lane, other = FROZEN_SLOTS, 1, 0
     state = eng._host_zeros(eng._state_struct)
-    pps = eng._pps
+    pps = eng.plan.pages_per_slot if eng.paging else 0
 
     def fold(state, slot, item):
         if not eng.paging:
@@ -806,7 +881,7 @@ def test_a_lane_whose_out_block_said_done_is_not_changed_by_one_more_step(case, 
     mine = np.arange(1 + lane * pps, 1 + (lane + 1) * pps)
     # a family whose rings lie in the page leaves, before the pages (ISSUE 55): a ring is
     # ``per_ring`` pages of them, ring 0 the sentinel
-    per_ring = model.kv_ring_pages(eng.pages.page_tokens) if eng.paging else 0
+    per_ring = eng.plan.ring_pages if eng.paging else 0
     pooled = n_pages + (slots + 1) * per_ring if per_ring else -1
     held, changed = 0, False
     flat_b = jax.tree_util.tree_flatten_with_path(before)[0]

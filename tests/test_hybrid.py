@@ -57,7 +57,7 @@ def zeros(struct, state_dtype=None):
 
 
 def piece_of(model, prompts, max_news, slot, start, length):
-    pps = model.kv_pages_per_slot(PAGE)
+    pps = model.kv_plan(1, PAGE).pages_per_slot
     ids = np.zeros((MAX_PROMPT,), np.int32)
     ids[: len(prompts[slot])] = prompts[slot]
     item = (ids, np.int32(len(prompts[slot])), np.int32(3), np.int32(max_news[slot]),
@@ -72,9 +72,9 @@ def serve(model, params, prompts, max_news, chunk=CHUNK, launches=None, state=No
     program, then steps until every lane is done. ``launches``: a list of
     launches, each a list of (slot, start, length); without it each prompt
     goes alone, a chunk a launch. ``state``: the block an earlier call left."""
-    pps = model.kv_pages_per_slot(PAGE)
+    pps = model.kv_plan(1, PAGE).pages_per_slot
     if state is None:
-        state = zeros(model.kv_page_signature(slots, slots * pps + 1, PAGE), state_dtype)
+        state = zeros(model.kv_plan(slots, PAGE).state, state_dtype)
     k = model.kv_prefill_pieces(chunk, PAGE)
     prefill = jax.jit(model.prefill_chunk, static_argnames=("chunk",))
     step = jax.jit(model.step)

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import json
 import os
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,7 @@ import pytest
 
 from benchmark import spec
 from tpuserve.config import ModelConfig
-from tpuserve.genserve.model import PrefillPiece
+from tpuserve.genserve.model import LeafKind, PrefillPiece
 from tpuserve.models import build, mixers
 from tpuserve.models.paged_lm import LOGPROBS
 from tpuserve.ops import block_scores as bsc
@@ -73,7 +74,7 @@ def zeros(struct):
 
 
 def piece_of(model, prompts, max_news, slot, start, length, page=PAGE):
-    pps = model.kv_pages_per_slot(page)
+    pps = model.kv_plan(1, page).pages_per_slot
     ids = np.zeros((model.max_prompt,), np.int32)
     ids[: len(prompts[slot])] = prompts[slot]
     item = (ids, np.int32(len(prompts[slot])), np.int32(3), np.int32(max_news[slot]),
@@ -88,9 +89,9 @@ def serve(model, params, prompts, max_news, chunk=CHUNK, launches=None, state=No
     program, then steps until every lane is done. ``launches``: a list of
     launches, each a list of (slot, start, length); without it each prompt
     goes alone, a chunk a launch."""
-    pps = model.kv_pages_per_slot(page)
+    pps = model.kv_plan(1, page).pages_per_slot
     if state is None:
-        state = zeros(model.kv_page_signature(slots, slots * pps + 1, page))
+        state = zeros(model.kv_plan(slots, page).state)
     k = model.kv_prefill_pieces(chunk, page)
     prefill = jax.jit(model.prefill_chunk, static_argnames=("chunk",))
     step = jax.jit(model.step)
@@ -365,16 +366,20 @@ def test_a_step_is_one_application_and_a_lane_that_is_not_live_keeps_its_state(w
 
 def test_the_family_keeps_three_page_leaves_and_one_state_leaf(whole):
     model, _ = whole
-    assert model.kv_slot_state == ("ssm",) and model.kv_page_leaves == ("kf", "vf", "kc")
-    assert model.cache_leaves == ("kf", "vf", "kc", "ssm")
-    sig = model.kv_page_signature(SLOTS, 20, PAGE)
+    plan = model.kv_plan(SLOTS, PAGE, 20)
+    sig = plan.state
+    assert plan.leaves(LeafKind.SLOT) == ("ssm",)
+    assert plan.leaves(LeafKind.POOL) == model._leaves(LeafKind.POOL) == ("kf", "vf", "kc")
+    assert sorted(model._leaves()) == sorted(("kf", "vf", "kc", "ssm"))
     assert "conv" not in sig
     assert [s.shape for s in sig["ssm"]] == [(SLOTS, 4, 16, 16)] * 3
     assert [s.shape for s in sig["kf"]] == [(2, 20, PAGE, 16)] == [s.shape for s in sig["vf"]]
     assert [s.shape for s in sig["kc"]] == [(20 * PAGE // 2, 2 * 16)]      # four rows a page
-    assert mixers.PatternMixers.kv_slot_state == ("ssm", "conv")
+    assert tuple(mixers.PatternMixers._state_signature(
+        SimpleNamespace(mh=1, mp=1, mn=1, conv_k=2, conv_ch=1, dtype=jnp.float32, m_layers=[0]),
+        1)) == ("ssm", "conv")
     with pytest.raises(ValueError, match="whole number of blocks"):
-        model.kv_page_signature(SLOTS, 20, 4)
+        model.kv_plan(SLOTS, 4, 20).state
 
 
 @pytest.mark.parametrize("key,value", [
@@ -423,5 +428,5 @@ def test_the_programs_tree_is_the_configurations_deployment_table():
         + kinds.count("lightning-attn") * table["lightning_layer"] \
         + table["embedding_head_gain"] == table["model"]
     assert model.residual_scale == pytest.approx(1.4 / 32 ** 0.5) and model.logits_scaling == 16.0
-    sig = model.kv_page_signature(16, 16624, 64)
+    sig = model.kv_plan(16, 64, 16624).state
     assert sig["ssm"][0].shape == (16, 32, 128, 128) and sig["kc"][0].shape == (16624 * 4, 256)

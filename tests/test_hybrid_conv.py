@@ -13,6 +13,7 @@ import asyncio
 import importlib.util
 import json
 import os
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,7 @@ import pytest
 
 from tests import hybrid_conv_reference as ref
 from tpuserve.config import ModelConfig
-from tpuserve.genserve.model import PrefillPiece
+from tpuserve.genserve.model import LeafKind, PrefillPiece
 from tpuserve.models import build, hybrid_conv, mixers
 from tpuserve.models.paged_lm import LOGPROBS
 from tpuserve.ops import moe
@@ -71,7 +72,7 @@ def zeros(struct):
 
 
 def piece_of(model, prompts, max_news, slot, start, length):
-    pps = model.kv_pages_per_slot(PAGE)
+    pps = model.kv_plan(1, PAGE).pages_per_slot
     ids = np.zeros((model.max_prompt,), np.int32)
     ids[: len(prompts[slot])] = prompts[slot]
     item = (ids, np.int32(len(prompts[slot])), np.int32(3), np.int32(max_news[slot]),
@@ -86,9 +87,9 @@ def serve(model, params, prompts, max_news, chunk=CHUNK, launches=None, state=No
     program, then steps until every lane is done. ``launches``: a list of
     launches, each a list of (slot, start, length); without it each prompt
     goes alone, a chunk a launch."""
-    pps = model.kv_pages_per_slot(PAGE)
+    pps = model.kv_plan(1, PAGE).pages_per_slot
     if state is None:
-        state = zeros(model.kv_page_signature(slots, slots * pps + 1, PAGE))
+        state = zeros(model.kv_plan(slots, PAGE).state)
     k = model.kv_prefill_pieces(chunk, PAGE)
     prefill = jax.jit(model.prefill_chunk, static_argnames=("chunk",))
     step = jax.jit(model.step)
@@ -213,13 +214,17 @@ def test_the_mixer_names_one_leaf_and_nothing_else_is_allocated(whole):
     layer in the served type, no `ssm` leaf of any shape; the other two
     recurrent mixers still name their two."""
     model, _ = whole
-    assert model.kv_slot_state == ("conv",) and model.cache_leaves == ("kf", "vf", "conv")
-    sig = model.kv_page_signature(SLOTS, 20, PAGE)
+    plan = model.kv_plan(SLOTS, PAGE, 20)
+    sig = plan.state
+    assert plan.leaves(LeafKind.SLOT) == ("conv",) and model._leaves() == ("kf", "vf", "conv")
     assert "ssm" not in sig
     assert [s.shape for s in sig["conv"]] == [(SLOTS, 2, 64)] * 4
     assert [s.shape for s in sig["kf"]] == [(2, 20, PAGE, 16)] * 2
-    assert mixers.PatternMixers.kv_slot_state == mixers.DeltaPatternMixers.kv_slot_state \
-        == ("ssm", "conv")
+    ns = SimpleNamespace(mh=1, mp=1, mn=1, kh=1, kd=1, conv_k=2, conv_ch=1, dtype=jnp.float32,
+                         m_layers=[0])
+    assert [(leaf, s.kind) for leaf, s in mixers.Mamba2Mixer._mamba_signature(ns, 1).items()] \
+        == [(leaf, s.kind) for leaf, s in mixers.DeltaMixer._delta_signature(ns, 1).items()] \
+        == [("ssm", LeafKind.SLOT), ("conv", LeafKind.SLOT)]
     assert mixers.RecurrentMixer._piece_starts(
         jnp.asarray([0]), jnp.asarray([0]), rows=(jnp.ones((2, 2, 3)),))[0] == ()
 
@@ -346,7 +351,7 @@ def test_the_published_sizes_give_the_bytes_a_token_and_a_slot_that_stats_report
     model = make_model(tmp_path, arch, name="pub", dtype="bfloat16",
                        max_prompt_tokens=served["max_prompt_tokens"],
                        max_new_tokens=served["max_new_tokens"])
-    sig = model.kv_page_signature(512, 4608, 128)
+    sig = model.kv_plan(512, 128, 4608).state
     nbytes = lambda leaves: sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in leaves)  # noqa: E731
     assert "ssm" not in sig and [s.shape for s in sig["conv"]] == [(512, 2, 2048)] * 8
     assert all(s.dtype == jnp.bfloat16 for s in sig["conv"])

@@ -72,7 +72,7 @@ def zeros(struct, state_dtype=None):
 
 
 def piece_of(model, prompts, max_news, slot, start, length):
-    pps = model.kv_pages_per_slot(PAGE)
+    pps = model.kv_plan(1, PAGE).pages_per_slot
     ids = np.zeros((model.max_prompt,), np.int32)
     ids[: len(prompts[slot])] = prompts[slot]
     item = (ids, np.int32(len(prompts[slot])), np.int32(3), np.int32(max_news[slot]),
@@ -87,9 +87,9 @@ def serve(model, params, prompts, max_news, chunk=CHUNK, launches=None, state=No
     program, then steps until every lane is done. ``launches``: a list of
     launches, each a list of (slot, start, length); without it each prompt
     goes alone, a chunk a launch."""
-    pps = model.kv_pages_per_slot(PAGE)
+    pps = model.kv_plan(1, PAGE).pages_per_slot
     if state is None:
-        state = zeros(model.kv_page_signature(slots, slots * pps + 1, PAGE), state_dtype)
+        state = zeros(model.kv_plan(slots, PAGE).state, state_dtype)
     k = model.kv_prefill_pieces(chunk, PAGE)
     prefill = jax.jit(model.prefill_chunk, static_argnames=("chunk",))
     step = jax.jit(model.step)
@@ -380,7 +380,7 @@ def test_the_published_sizes_give_the_bytes_a_token_and_a_slot_that_stats_report
                          "assumed", "serve", "check")}
     model = make_model(tmp_path, arch, name="pub", dtype="bfloat16",
                        max_prompt_tokens=1024, max_new_tokens=512)
-    sig = model.kv_page_signature(80, 1280, 128)
+    sig = model.kv_plan(80, 128, 1280).state
     assert [s.shape for s in sig["kf"]] == [(4, 1280, 128, 128)] * 4
     nbytes = lambda leaves: sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in leaves)  # noqa: E731
     assert nbytes(sig["kf"] + sig["vf"]) // (1280 * 128) == 8192

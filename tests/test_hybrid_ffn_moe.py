@@ -355,7 +355,7 @@ def test_the_cells_tree_holds_what_its_deployment_table_says(tmp_path):
     assert (len(model.m_layers), len(model.a_layers)) \
         == (table["mamba_layers"], table["attention_layers"]) == (9, 1)
     # the cache beside it, as `/stats` will report it and the configuration says
-    sig = model.kv_page_signature(96, 2048, 128)
+    sig = model.kv_plan(96, 128, 2048).state
     nbytes = lambda leaves: sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in leaves)  # noqa: E731
     assert nbytes(sig["ssm"] + sig["conv"]) // 96 == 38_204_928 == 9 * (4 * 2 ** 20 + 50_688)
     assert nbytes(sig["kf"] + sig["vf"]) == 2 ** 30 and model._scale() == 0.0078125
@@ -369,8 +369,8 @@ def test_the_routed_block_is_named_in_the_programs_and_hybrid_ffns_are_not(whole
     from tests import test_hybrid_ffn
 
     def stacks(model):
-        pps = model.kv_pages_per_slot(PAGE)
-        sig = model.kv_page_signature(SLOTS, SLOTS * pps + 1, PAGE)
+        pps = model.kv_plan(1, PAGE).pages_per_slot
+        sig = model.kv_plan(SLOTS, PAGE).state
         params = jax.eval_shape(lambda: model.draw_params(0))
         text = jax.jit(model.step).lower(params, sig).as_text(debug_info=True)
         # the operations' name stacks alone (`scripts/lower_programs.py` `name_stacks`):

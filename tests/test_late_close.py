@@ -13,23 +13,134 @@ replaces.
 """
 
 import asyncio
+import concurrent.futures as cf
+import heapq
 import math
 import random
+import sys
 import threading
 import time
 from collections import deque
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
+from tpuserve import batcher as batcher_module
 from tpuserve.batcher import DeadlineExceeded, ModelBatcher
 from tpuserve.config import ModelConfig, PipelineConfig
-from tpuserve.hostpipe import AdmissionGate, SlotPool
+from tpuserve.hostpipe import AdmissionGate, SlotPool, StageExecutors
 from tpuserve.obs import Metrics
 
 
-def run(coro):
-    loop = asyncio.new_event_loop()
+class VirtualClock:
+    """A clock the test moves (D16): ``perf_counter`` for the batcher and for
+    this file, ``time()`` for the event loop, ``sleep`` for the fake launch. It
+    moves only when the loop has nothing left to run AND every stage thread
+    is idle or asleep on it, and then straight to whatever is due first, a
+    timer of the loop or a sleeper: a hop between threads takes no time and a
+    launch takes what ``FifoDevice`` says, whatever else the host runs."""
+
+    def __init__(self):
+        self.now = 0.0
+        self._cond = threading.Condition()
+        self._busy = 0         # stage threads at work: not idle, not asleep here
+        self._sleepers = []    # (wakes at, order, event)
+        self._order = 0
+
+    def perf_counter(self):
+        return self.now
+
+    def sleep(self, seconds):
+        """From a stage thread: until the clock has moved ``seconds`` on."""
+        if seconds <= 0:
+            return
+        woken = threading.Event()
+        with self._cond:
+            self._order += 1
+            heapq.heappush(self._sleepers, (self.now + seconds, self._order, woken))
+            self._busy -= 1
+            self._cond.notify_all()
+        woken.wait()
+
+    def _work(self, n):
+        with self._cond:
+            self._busy += n
+            self._cond.notify_all()
+
+    def select(self, real_select, timeout):
+        """The loop's selector: what is ready now; else, once no stage thread
+        is at work, the clock moved to what is due first."""
+        if timeout == 0:
+            return real_select(0)
+        while True:
+            with self._cond:
+                if not self._cond.wait_for(lambda: not self._busy, timeout=60):
+                    raise RuntimeError("a stage thread has worked for a minute")
+            events = real_select(0)   # a thread posts its result BEFORE it counts as idle
+            if events:
+                return events
+            with self._cond:
+                wakes = self._sleepers[0][0] if self._sleepers else math.inf
+                timer = math.inf if timeout is None else self.now + timeout
+                if wakes == timer == math.inf:
+                    return real_select(0.01)   # nothing of ours is due: a real wait
+                if timer < wakes:
+                    self.now = timer
+                    return []
+                self.now = max(self.now, wakes)
+                while self._sleepers and self._sleepers[0][0] <= self.now:
+                    self._busy += 1
+                    heapq.heappop(self._sleepers)[2].set()
+
+    def loop(self):
+        """An event loop whose time is this clock's."""
+        loop = asyncio.SelectorEventLoop()
+        real_select = loop._selector.select
+        loop._selector.select = lambda timeout=None: self.select(real_select, timeout)
+        loop.time = lambda: self.now
+        return loop
+
+    def stages(self, cfg, metrics):
+        """The batcher's stage pools, each counting its threads' work here."""
+        clock, stages = self, StageExecutors(cfg, metrics)
+
+        class Pool:
+            def __init__(self, inner):
+                self.inner, self.shutdown = inner, inner.shutdown
+
+            def submit(self, fn, *args):
+                done = cf.Future()
+
+                def work():
+                    try:
+                        done.set_result(fn(*args))
+                    except BaseException as e:  # noqa: BLE001
+                        done.set_exception(e)
+                    finally:
+                        clock._work(-1)
+
+                clock._work(1)
+                self.inner.submit(work)
+                return done
+
+        stages._pools = {stage: Pool(pool) for stage, pool in stages._pools.items()}
+        return stages
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    """The batcher and this file on a ``VirtualClock`` (``make`` and ``run``
+    take it): the tests that count launches do so under it."""
+    clock = VirtualClock()
+    shim = SimpleNamespace(perf_counter=clock.perf_counter, sleep=clock.sleep, time=time.time)
+    monkeypatch.setattr(batcher_module, "time", shim)
+    monkeypatch.setattr(sys.modules[__name__], "time", shim)
+    return clock
+
+
+def run(coro, clock=None):
+    loop = clock.loop() if clock is not None else asyncio.new_event_loop()
     try:
         return loop.run_until_complete(coro)
     finally:
@@ -120,7 +231,7 @@ class FifoDevice:
 
 
 def make(launch_s=0.03, assemble_s=0.0, parent_rule=False, depth=2,
-         buckets=(4, 32), **cfg_over):
+         buckets=(4, 32), clock=None, **cfg_over):
     base = dict(name="fake", family="toy", batch_buckets=list(buckets),
                 deadline_ms=5.0, dtype="float32", num_classes=10,
                 parallelism="single", max_queue=4096, max_inflight=2)
@@ -128,8 +239,9 @@ def make(launch_s=0.03, assemble_s=0.0, parent_rule=False, depth=2,
     model = Model(ModelConfig(**base), assemble_s)
     dev = FifoDevice(launch_s)
     metrics = Metrics()
-    b = ModelBatcher(model, dev, metrics,
-                     pipeline_cfg=PipelineConfig(depth=depth, assemble_ahead=2))
+    pipeline_cfg = PipelineConfig(depth=depth, assemble_ahead=2)
+    b = ModelBatcher(model, dev, metrics, pipeline_cfg=pipeline_cfg,
+                     stages=clock and clock.stages(pipeline_cfg, metrics))
     model.batcher = b
     b.parent_rule = parent_rule
     return b, model, dev, metrics
@@ -167,15 +279,15 @@ async def closed_loop(b, callers, seconds, think_s, seed=0):
 
 # -- (a) the gain: fuller launches from the same outstanding work -------------
 
-def run_closed(parent_rule, callers=64, seconds=1.6, think_s=0.006, **kw):
+def run_closed(parent_rule, callers=64, seconds=1.6, think_s=0.006, clock=None, **kw):
     async def go():
-        b, model, dev, metrics = make(parent_rule=parent_rule, **kw)
+        b, model, dev, metrics = make(parent_rule=parent_rule, clock=clock, **kw)
         await start(b)
         n = await closed_loop(b, callers, seconds, think_s=think_s)
         await b.stop()
         return b, model, dev, metrics, n
 
-    return run(go())
+    return run(go(), clock)
 
 
 def test_closed_loop_of_two_buckets_outstanding_fills_launches():
@@ -202,17 +314,17 @@ def test_the_parents_cap_leaves_launches_half_empty():
 
 # -- (b) nothing is assembled before the device section has room --------------
 
-def test_no_batch_is_assembled_before_a_slot_is_free():
+def test_no_batch_is_assembled_before_a_slot_is_free(clock):
     """Staging small against a launch: every batch's assembly begins with a
     device-section slot free for it, from the first batch on (before a
     measurement the gate counts the device section), and no more than the
-    device section holds is ever closed. The launch is 100 ms so that the
+    device section holds is ever closed. Under the test's own clock (D16): the
     claim's premise, a reserve (twice close -> launch: two thread hops and
-    the assembly) under ONE LAUNCH, holds on a host whose other cores run
-    five more test workers: at 30 ms a launch and a reserve held under 10 ms
-    the test failed there by its own margins, not by the rule's."""
-    b, model, dev, _, _ = run_closed(parent_rule=False, launch_s=0.1,
-                                     seconds=1.6)
+    the assembly) under ONE LAUNCH, was the host's to break, and on a host
+    whose other cores ran five more test workers it did, at 30 ms a launch and
+    again at 100; here an assembly takes the 2 ms it is given and a hop none."""
+    b, model, dev, _, _ = run_closed(parent_rule=False, launch_s=0.1, assemble_s=0.002,
+                                     seconds=1.6, clock=clock)
     assert len(model.assembled) > 10
     assert b._reserve_ms() < 0.5 * b._device_ms[(32,)]
     assert all(in_use < b.depth for _, _, in_use in model.assembled), \
@@ -457,7 +569,7 @@ def test_close_limit_at_a_bucket_edge(n, queued, device, stage_ms, want):
     assert b._close_limit(n, queued, None) == want
 
 
-def test_low_concurrency_stays_in_the_small_bucket():
+def test_low_concurrency_stays_in_the_small_bucket(clock):
     """8 outstanding over buckets [4, 32] where a 32-wide launch costs 8x a
     4-wide one, and batches that flush small (a short timer) and grow at
     the close. With both buckets measured the close stops at the edge of
@@ -465,7 +577,7 @@ def test_low_concurrency_stays_in_the_small_bucket():
     and answers more than a close that always takes everything."""
     def go(guard):
         async def inner():
-            b, _, dev, _ = make(buckets=(4, 32), deadline_ms=0.5)
+            b, _, dev, _ = make(buckets=(4, 32), deadline_ms=0.5, clock=clock)
             dev.launch_s = {4: 0.004, 32: 0.032}
             if not guard:
                 b._close_limit = lambda n, queued, group: 32
@@ -476,7 +588,7 @@ def test_low_concurrency_stays_in_the_small_bucket():
             await b.stop()
             return dev, n
 
-        return run(inner())
+        return run(inner(), clock)
 
     dev, n = go(guard=True)
     wide = [k for k, bucket, _, _ in dev.launches[4:] if bucket == 32]

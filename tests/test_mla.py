@@ -18,7 +18,7 @@ import pytest
 
 from tests import mla_reference as ref
 from tpuserve.config import ModelConfig
-from tpuserve.genserve.model import PrefillPiece
+from tpuserve.genserve.model import LeafKind, PrefillPiece
 from tpuserve.models import build, paged_lm
 from tpuserve.models import mla
 
@@ -63,7 +63,7 @@ def zeros(struct, cache_dtype=None):
 
 
 def piece_of(model, prompts, max_news, page, slot, start, length):
-    pps = model.kv_pages_per_slot(page)
+    pps = model.kv_plan(1, page).pages_per_slot
     ids = np.zeros((model.max_prompt,), np.int32)
     ids[: len(prompts[slot])] = prompts[slot]
     item = (ids, np.int32(len(prompts[slot])), np.int32(3), np.int32(max_news[slot]),
@@ -76,9 +76,9 @@ def serve(model, params, prompts, max_news, chunk=CHUNK, launches=None, state=No
           slots=SLOTS, cache_dtype=None, steps=None, page=PAGE):
     """What the engine does, by hand: the prompts' pieces through the prefill
     program, then steps until every lane is done."""
-    pps = model.kv_pages_per_slot(page)
+    pps = model.kv_plan(1, page).pages_per_slot
     if state is None:
-        state = zeros(model.kv_page_signature(slots, slots * pps + 1, page), cache_dtype)
+        state = zeros(model.kv_plan(slots, page).state, cache_dtype)
     k = model.kv_prefill_pieces(chunk, page)
     prefill = jax.jit(model.prefill_chunk, static_argnames=("chunk",))
     step = jax.jit(model.step)
@@ -152,7 +152,7 @@ def test_packed_chunked_prefill_then_decode_is_the_reference_one_causal_pass(
         assert model._form(PAGE) == "absorbed"
         prompts, news, chunk, packed = PROMPTS, MAX_NEWS, CHUNK, PACKED
     assert model.kv_prefill_pieces(chunk, PAGE) == 2
-    kr = model.kv_page_signature(SLOTS, 9, PAGE)["kr"][0]
+    kr = model.kv_plan(SLOTS, PAGE, 9).state["kr"][0]
     assert kr.shape == ((9, 4, 8) if case == "rope-8-a-position-a-row" else (9, 2, 128))
     params = model.init_params(jax.random.key(0))
     served, out, _ = serve(model, params, prompts, news, chunk=chunk, launches=packed)
@@ -188,7 +188,7 @@ def test_the_absorbed_and_the_expanded_form_agree_on_the_same_cache(whole, monke
     n, T = 22, 8
     u = jnp.asarray(rng.standard_normal((n, 64)), jnp.float32)
     qn, qr, c_kv, k_r = model._project(lp, u, jnp.arange(n))
-    pps = model.kv_pages_per_slot(PAGE)
+    pps = model.kv_plan(1, PAGE).pages_per_slot
     pages = jnp.arange(1, 1 + pps)
     at = (pages[jnp.arange(n) // PAGE], jnp.arange(n) % PAGE)
     pool = (model._write_pages(jnp.zeros((1 + pps, PAGE, R), jnp.float32), *at, c_kv),
@@ -657,7 +657,7 @@ def test_a_slot_reused_answers_as_alone_and_a_free_lane_writes_only_the_sentinel
     for _ in range(3):
         stepped, out = jax.jit(model.step)(params, stepped)
     assert int(out["n_new"][1]) == 4 and int(out["n_new"][0]) == 0
-    pps = model.kv_pages_per_slot(PAGE)
+    pps = model.kv_plan(1, PAGE).pages_per_slot
     for a, b in zip(mid["ckv"] + mid["kr"], stepped["ckv"] + stepped["kr"]):
         np.testing.assert_array_equal(np.asarray(a[1:1 + pps]), np.asarray(b[1:1 + pps]))
         assert not np.array_equal(np.asarray(a[1 + pps:1 + 2 * pps]),
@@ -668,17 +668,18 @@ def test_a_slot_reused_answers_as_alone_and_a_free_lane_writes_only_the_sentinel
 
 def test_the_page_signature_holds_one_latent_row_a_token_a_layer_and_no_leaf_by_head(whole, tmp_path):
     model, _ = whole
-    sig = model.kv_page_signature(SLOTS, 10, PAGE)
+    plan = model.kv_plan(SLOTS, PAGE, 10)
+    sig = plan.state
     # 32 + 64 values a token a layer, each leaf whole rows of 128 lanes or its own width
     assert [c.shape for c in sig["ckv"]] == [(10, PAGE, R)] * 3
     assert [c.shape for c in sig["kr"]] == [(10, PAGE // 2, 2 * ROPE)] * 3
     per_token = sum(int(np.prod(leaf[0].shape)) for leaf in (sig["ckv"], sig["kr"])) // (10 * PAGE)
     assert per_token == ROW
-    assert model.kv_page_leaves == ("ckv", "kr") and model.kv_slot_state == ()
-    assert set(sig) == {"ckv", "kr"} | set(model._lane_signature(SLOTS, PAGE))
+    assert plan.leaves(LeafKind.POOL) == ("ckv", "kr") and plan.leaves(LeafKind.SLOT) == ()
+    assert set(sig) == {"ckv", "kr"} | set(model._lane_signature(SLOTS, plan.pages_per_slot))
     # the published sizes: 512 + 64 = 576 values, two positions' rotary keys a row of 128 lanes
     big = make_model(tmp_path, dict(ARCH, kv_lora_rank=512, qk_rope_head_dim=64), name="big")
-    sig = big.kv_page_signature(16, 3200, 128)
+    sig = big.kv_plan(16, 128, 3200).state
     assert sig["ckv"][0].shape == (3200, 128, 512) and sig["kr"][0].shape == (3200, 64, 128)
     # a latent row goes where it is told and nowhere else
     pool = model._write_pages(jnp.zeros((3, PAGE, R)), jnp.asarray([2, 0]), jnp.asarray([1, 3]),

@@ -22,6 +22,7 @@ import pytest
 from tests import mla_sc_reference as ref
 from tests.test_mla import serve
 from tpuserve.config import ModelConfig
+from tpuserve.genserve.model import LeafKind
 from tpuserve.models import build, mla, mla_sc
 from tpuserve.ops import moe
 
@@ -297,10 +298,11 @@ def test_a_token_whose_picks_are_all_zero_compute_and_one_with_none(whole, kind)
 
 def test_the_page_signature_holds_two_latent_rows_a_layer_and_the_attention_is_mlas(whole):
     model, params = whole
-    sig = model.kv_page_signature(SLOTS, 9, PAGE)
+    plan = model.kv_plan(SLOTS, PAGE, 9)
+    sig = plan.state
     assert len(sig["ckv"]) == len(sig["kr"]) == 4
     assert sig["ckv"][3].shape == (9, PAGE, 32) and sig["kr"][3].shape == (9, 2, 128)
-    assert sig["acc"].shape == (2, 15) and model.kv_page_leaves == ("ckv", "kr")
+    assert sig["acc"].shape == (2, 15) and plan.leaves(LeafKind.POOL) == ("ckv", "kr")
     # shared, not copied: the attention's functions are `mla.LatentServing`'s own
     for name in ("_project", "_write_keys", "_attend_tile", "_attend_tiles", "_walk", "_form",
                  "_attn_out", "_attention", "_step_plan", "_prefill_plan"):
@@ -498,7 +500,7 @@ def test_the_fallback_step_is_mlas_and_the_reference_across_a_key_blocks_edge(tm
         gaps_of = test_mla.gaps
     else:
         model, arch, attentions, gaps_of = make_model(tmp_path, name="edge"), ARCH, 4, gaps
-    assert model._block_pages(PAGE, model.kv_pages_per_slot(PAGE)) == 2
+    assert model._block_pages(PAGE, model.kv_plan(1, PAGE).pages_per_slot) == 2
     params = model.init_params(jax.random.key(0))
     lengths, news = (7, 15), [4, 4]
     prompts = [np.random.default_rng(9).integers(0, 64, n) for n in lengths]

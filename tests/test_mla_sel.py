@@ -24,6 +24,7 @@ import pytest
 from benchmark import spec
 from tests.test_mla import piece_of, serve  # noqa: F401
 from tpuserve.config import ModelConfig
+from tpuserve.genserve.model import LeafKind
 from tpuserve.models import build, mla_sel, paged_lm
 from tpuserve.ops import index_select as ix
 from tpuserve.ops import lane_attention as la
@@ -667,18 +668,19 @@ def test_the_cells_tree_holds_what_its_deployment_table_says(tmp_path):
     assert sorted(cfg["reduced"]) == ["first_k_dense_replace", "n_routed_experts",
                                       "num_hidden_layers", "vocab_size"]
     # a token's rows: 1,408 B a layer in three leaves; the served context's pages
-    sig = model.kv_page_signature(16, 4224, 128)
+    sig = model.kv_plan(16, 128, 4224).state
     assert [sig[leaf][0].shape for leaf in ("ckv", "kr", "ik")] \
         == [(4224, 128, 512), (4224, 64, 128), (4224, 128, 128)]
     assert sum(size(sig[leaf]) for leaf in ("ckv", "kr", "ik")) * 2 // (4224 * 128) == 1408 * 5
-    assert model.kv_pages_per_slot(128) == 258 and model.kv_prefill_pieces(2048, 128) == 2
+    assert model.kv_plan(1, 128).pages_per_slot == 258 and model.kv_prefill_pieces(2048, 128) == 2
 
 
 def test_the_page_signature_holds_three_leaves_and_a_third_is_refused_by_the_parent(
         whole, tmp_path):
     model, _ = whole
-    sig = model.kv_page_signature(SLOTS, 10, PAGE)
-    assert model.kv_page_leaves == model.cache_leaves == ("ckv", "kr", "ik")
+    plan = model.kv_plan(SLOTS, PAGE, 10)
+    sig = plan.state
+    assert plan.leaves(LeafKind.POOL) == model._leaves() == ("ckv", "kr", "ik")
     assert [x.shape for x in sig["ik"]] == [(10, PAGE, 128)] * 3
     assert [x.shape for x in sig["ckv"]] == [(10, PAGE, 32)] * 3
     from tpuserve.models import mla

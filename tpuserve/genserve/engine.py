@@ -70,7 +70,7 @@ import numpy as np
 from tpuserve.batcher import DeadlineExceeded, QueueFull
 from tpuserve.config import GenserveConfig, PipelineConfig
 from tpuserve.genserve.arena import SlotArena, SlotInfo
-from tpuserve.genserve.model import GenerativeModel, PrefillPiece
+from tpuserve.genserve.model import CachePlan, GenerativeModel, PrefillPiece
 from tpuserve.genserve.pages import PageLedger
 from tpuserve.hostpipe import StageExecutors
 from tpuserve.obs import (GEN_STREAM_REASONS, PRIORITIES, Metrics, trace_call,
@@ -278,36 +278,34 @@ class GenEngine:
         self.slots = self.gcfg.slots or max(self.cfg.batch_buckets)
         self.arena = SlotArena(self.slots)
         # Paged KV cache (ISSUE 18): only families that ship the paged
-        # programs opt in — with kv_paging on, sd15 (no paged contract)
-        # keeps the dense slab byte-for-byte.
-        self.paging = bool(self.gcfg.kv_paging) \
-            and bool(getattr(model, "supports_kv_paging", False))
+        # programs answer a plan (WHAT A SLOT KEEPS: the ledger, the state
+        # block and /stats ``kv`` are read off it) — with kv_paging on, sd15
+        # (no paged contract) keeps the dense slab byte-for-byte.
+        self.plan: CachePlan | None = model.kv_plan(
+            self.slots, self.gcfg.kv_page_tokens, self.gcfg.kv_pages) \
+            if self.gcfg.kv_paging else None
+        self.paging = self.plan is not None
         if self.gcfg.kv_paging and not self.paging:
             log.info("%s: [genserve] kv_paging is on but the family has no "
                      "paged programs — dense state slab kept",
                      model.cfg.name)
         self.pages: PageLedger | None = None
-        self._pps = 0            # block-table width (pages per max-ctx slot)
         self._prefill_chunk = 0  # static width of the prefill program
         self._prefill_pieces = 1  # prompts' pieces a launch takes (K)
         self._prefill_tile = 0   # rows a tile: a piece takes whole tiles
-        self._ring_tokens = 0    # window-ring length (0: the family has none)
         # Slots with prompt left to launch, in order of admission.
         self._prefilling: list[int] = []
         if self.paging:
-            pt = self.gcfg.kv_page_tokens
-            self._pps = int(model.kv_pages_per_slot(pt))
-            n_pages = self.gcfg.kv_pages or (self.slots * self._pps + 1)
-            if n_pages < self._pps + 1:
+            plan, pt = self.plan, self.plan.page_tokens
+            if plan.pages < plan.pages_per_slot + 1:
                 raise ValueError(
-                    f"{model.cfg.name}: [genserve] kv_pages={n_pages} cannot "
-                    f"cover one max-context request ({self._pps} pages + the "
-                    "sentinel)")
+                    f"{model.cfg.name}: [genserve] kv_pages={plan.pages} cannot "
+                    f"cover one max-context request ({plan.pages_per_slot} "
+                    "pages + the sentinel)")
             # A family with window layers keeps one ring a slot beside the
             # full pages (ISSUE 28): the same ledger owns both pools.
-            self._ring_tokens = int(model.kv_ring_tokens())
             self.pages = PageLedger(
-                n_pages, pt, rings=self.slots + 1 if self._ring_tokens else 0)
+                plan.pages, pt, rings=self.slots + 1 if plan.ring_tokens else 0)
             self._prefill_chunk = int(
                 model.kv_prefill_chunk(self.gcfg.prefill_chunk))
             self._prefill_pieces = int(
@@ -485,10 +483,9 @@ class GenEngine:
             # Page indices are TRACED (like slot indices), so this one
             # registration serves every page assignment the ledger ever
             # makes — the zero-recompile obligation extends to page churn.
-            self._state_struct = model.kv_page_signature(
-                self.slots, self.pages.pages, self.pages.page_tokens)
-            self._g_state_bytes.set(float(self.slot_state_bytes()))
-            self._g_kv_row_bytes.set(float(self.kv_row_bytes()))
+            self._state_struct = self.plan.state
+            self._g_state_bytes.set(float(self.plan.slot_bytes))
+            self._g_kv_row_bytes.set(float(self.plan.row_bytes))
         else:
             self._state_struct = model.state_signature(self.slots)
         geometry = {"kv_paging": self.paging, "slots": self.slots,
@@ -767,7 +764,7 @@ class GenEngine:
             # exactly like the dense queue draining) and shed with a
             # clear-time hint once projected demand exceeds that: at
             # that point the page pool, not compute, is the bottleneck.
-            need = self.model.pages_needed(item, self.pages.page_tokens)
+            need = self.plan.pages_for(self.model.context_tokens(item))
             projected = self.pages.n_reserved + self._queued_pages() + need
             if projected > 2 * self.pages.usable:
                 self._c_shed.inc()
@@ -963,9 +960,9 @@ class GenEngine:
         block-table row (its pages in position order, padded with the
         sentinel, page 0, past its reservation) and, for a family with
         window layers, its ring beside it."""
-        row = np.zeros((self._pps,), np.int32)
+        row = np.zeros((self.plan.pages_per_slot,), np.int32)
         row[:len(page_list)] = page_list
-        if not self._ring_tokens:
+        if not self.plan.ring_tokens:
             return row
         return {"pages": row, "ring": np.int32(ring)}
 
@@ -973,7 +970,7 @@ class GenEngine:
         """One request's whole prompt as the launches of a lone slot 0 over
         the first pages and ring (compile's shapes and prewarm, the staged
         canary): a launch a chunk."""
-        row = self._cache_row(list(range(1, self._pps + 1)), 1)
+        row = self._cache_row(list(range(1, self.plan.pages_per_slot + 1)), 1)
         n, chunk = self.model.prompt_tokens(item), self._prefill_chunk
         return [self.model.pack_prefill(
             [PrefillPiece(0, item, s, min(chunk, n - s), row)], chunk,
@@ -1904,24 +1901,24 @@ class GenEngine:
             "loop": self._loop_stats(),
         }
         if self.pages is not None:
+            plan = self.plan
             stats["kv"] = {
                 **self.pages.stats(),
                 **self._prefill_stats(),
                 "queued_pages": self._queued_pages(),
-                "kv_bytes": self.kv_cache_bytes(),
-                "row_bytes_per_token": self.kv_row_bytes(),
+                "kv_bytes": plan.pool_bytes,
+                "row_bytes_per_token": plan.row_bytes,
                 # Positions a page stands for (its rows, unless a row sums
-                # several positions up) and, where the rings lie in the page
-                # leaves, what of ``kv_bytes`` is rings and what pages.
-                "page_positions": int(self.model.kv_page_span(self.pages.page_tokens)),
-                **({"ring_bytes": self._ring_pages() * self._pool_page_bytes(),
-                    "page_bytes": self.pages.pages * self._pool_page_bytes()}
-                   if self._ring_pages() else {}),
-                # The third kind (ISSUE 32): a fixed block a slot, beside pages: the
-                # leaves the family's recurrent mixer names, and no other (a float32
-                # state and the convolution's rows; the rows ALONE for a short convolution).
-                "state_bytes_per_slot": self.slot_state_bytes() // self.slots,
-                "state_bytes": self.slot_state_bytes(),
+                # several positions up) and, where the rings lie in the
+                # pools, what of ``kv_bytes`` is rings and what pages.
+                "page_positions": plan.page_positions,
+                **({"ring_bytes": plan.ring_bytes,
+                    "page_bytes": plan.pages * plan.page_bytes}
+                   if plan.ring_pages else {}),
+                # The third kind (ISSUE 32): a fixed block a slot beside the pages,
+                # the leaves the family's recurrent mixer states and no other.
+                "state_bytes_per_slot": plan.slot_bytes // self.slots,
+                "state_bytes": plan.slot_bytes,
             }
         share = self.model.share_stats()
         if share is not None:
@@ -1983,44 +1980,13 @@ class GenEngine:
             row["kv"] = self.pages.snapshot()
         return row
 
-    def slot_state_bytes(self) -> int:
-        """Device bytes of the leaves the family keeps as one block A SLOT
-        (``kv_slot_state``: a recurrent layer's state, or where the layer is a
-        short convolution its stored rows alone, the same size whatever the
-        context), all slots; 0 for a family without any."""
-        return self._leaf_bytes(self.model.kv_slot_state)
-
-    def _leaf_bytes(self, keys: tuple) -> int:
-        if not isinstance(self._state_struct, dict):
-            return 0
-        leaves = jax.tree_util.tree_leaves([self._state_struct.get(k) for k in keys])
-        return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize for x in leaves)
-
     def kv_cache_bytes(self) -> int:
-        """Device bytes the KV storage leaves occupy (the dense slab k/v or
-        the family's page pools, ``kv_page_leaves``) — the denominator of
-        the bench's fixed-memory slot-count comparison."""
-        return self._leaf_bytes(("k", "v") + self.model.kv_page_leaves)
+        """Device bytes of the plan's page pools (paged engines only)."""
+        return self.plan.pool_bytes
 
     def kv_row_bytes(self) -> int:
-        """Device bytes ONE position of context takes in the page pools, all
-        layers, reckoned from the signature (``kv_page_leaves`` over pages x
-        page tokens): K and V by head for ``decoder``, one latent row for
-        ``mla``. 0 without paging."""
-        if self.pages is None:
-            return 0
-        return self._pool_page_bytes() \
-            // int(self.model.kv_page_span(self.pages.page_tokens))
-
-    def _ring_pages(self) -> int:
-        """Pages of the page leaves that the rings take, where the family keeps
-        its rings in them (``kv_ring_pages``: the sentinel's and a slot's each)."""
-        return (self.slots + 1) * int(self.model.kv_ring_pages(self.pages.page_tokens))
-
-    def _pool_page_bytes(self) -> int:
-        """Device bytes of ONE page of the page leaves, all layers."""
-        return self._leaf_bytes(self.model.kv_page_leaves) \
-            // (self.pages.pages + self._ring_pages())
+        """Device bytes ONE position of context takes in them, all layers."""
+        return self.plan.row_bytes
 
 
 class GenEngineGroup:
@@ -2123,9 +2089,6 @@ class GenEngineGroup:
     @property
     def paging(self) -> bool:
         return self.engines[0].paging
-
-    def kv_cache_bytes(self) -> int:
-        return sum(e.kv_cache_bytes() for e in self.engines)
 
     # -- lifecycle ------------------------------------------------------------
     def compile(self) -> None:
@@ -2252,8 +2215,8 @@ class GenEngineGroup:
                 "acquires_total": sum(e.pages.acquires_total for e in paged),
                 **e0._prefill_stats(),
                 "queued_pages": sum(e._queued_pages() for e in paged),
-                "kv_bytes": self.kv_cache_bytes(),
-                "row_bytes_per_token": e0.kv_row_bytes(),
+                "kv_bytes": sum(e.plan.pool_bytes for e in paged),
+                "row_bytes_per_token": e0.plan.row_bytes,
             }
         stats["per_replica"] = [e.replica_row() for e in self.engines]
         return stats

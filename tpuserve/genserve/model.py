@@ -34,10 +34,13 @@ so two prompts differing only in seed can never alias
 from __future__ import annotations
 
 import abc
+import enum
+import functools
 import json
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, Callable
 
+import jax
 import numpy as np
 
 from tpuserve.models.base import ServingModel
@@ -54,6 +57,97 @@ class PrefillPiece:
     start: int
     length: int
     cache: Any
+
+
+class LeafKind(enum.Enum):
+    """What a leaf of the paged state block is, by how it is addressed."""
+    POOL = "pool"    # pages, reached through a slot's block table; page 0 the sentinel
+    RINGS = "rings"  # a ring a slot and the sentinel's (ring 0): the last ``ring_tokens`` positions
+    SLOT = "slot"    # one block a slot, the same size at any context (a recurrent layer's state)
+    LANE = "lane"    # everything else: the block table, a lane's counters, its tokens
+
+
+@dataclass(frozen=True)
+class Leaf:
+    """A cache leaf as a family's signature states it: its kind and its shapes (a list of one
+    a layer that keeps it). The kind rides on the plan only: ``CachePlan.state`` is the shapes."""
+    kind: LeafKind
+    shapes: Any
+
+
+pool, rings, slot_block = (functools.partial(Leaf, kind)
+                           for kind in (LeafKind.POOL, LeafKind.RINGS, LeafKind.SLOT))
+
+
+@dataclass(frozen=True)
+class CachePlan:
+    """What a slot keeps, said once (ISSUE 70): ``GenerativeModel.kv_plan``'s answer, which
+    the engine and /stats ``kv`` read. Page 0 is the write-sink sentinel — free/done lanes
+    scribble there, live lanes never attend through it."""
+    state: Any            # the state block's signature: {leaf: shapes}
+    kinds: dict           # {cache leaf: LeafKind}; a leaf of ``state`` not named is a lane
+    slots: int
+    pages: int            # the ledger's pages, the sentinel among them
+    page_tokens: int      # ROWS a page
+    pages_per_slot: int   # the block table's width: a slot's longest context in pages
+    page_positions: int   # positions a page stands for: its rows, unless a row sums several up
+    ring_tokens: int = 0  # positions a slot's ring holds; 0: the family has no rings
+    ring_pages: int = 0   # pages of the POOLS a ring takes, where rings lie there (before the
+    #                       ledger's pages); 0: rings are leaves of their own
+
+    @classmethod
+    def build(cls, signature: Callable[[int, int], dict], *, slots: int, page_tokens: int,
+              pages: int, max_tokens: int, page_positions: int = 0, ring_tokens: int = 0,
+              ring_pages: int = 0) -> "CachePlan":
+        """The plan of ``signature(pages, pages_per_slot)`` -> {leaf: a ``Leaf``,
+        or a lane's bare shape} for slots of at most ``max_tokens`` positions.
+        ``pages = 0``: the worst case, every slot full, and the sentinel."""
+        positions = int(page_positions or page_tokens)
+        pps = -(-int(max_tokens) // positions)
+        pages = int(pages) or slots * pps + 1
+        sig = signature(pages, pps)
+        return cls({k: v.shapes if isinstance(v, Leaf) else v for k, v in sig.items()},
+                   {k: v.kind for k, v in sig.items() if isinstance(v, Leaf)},
+                   slots, pages, int(page_tokens), pps, positions, ring_tokens, ring_pages)
+
+    def kind(self, leaf: str) -> LeafKind:
+        return self.kinds.get(leaf, LeafKind.LANE)
+
+    def leaves(self, kind: LeafKind) -> tuple:
+        return tuple(leaf for leaf in self.state if self.kind(leaf) is kind)
+
+    def bytes_of(self, kind: LeafKind) -> int:
+        """Device bytes of the leaves of ``kind``, all layers."""
+        return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize for x in
+                   jax.tree_util.tree_leaves([self.state[k] for k in self.leaves(kind)]))
+
+    def pages_for(self, tokens: int) -> int:
+        """Pages that cover ``tokens`` positions of one slot's context."""
+        return -(-int(tokens) // self.page_positions)
+
+    @functools.cached_property
+    def pool_bytes(self) -> int:
+        return self.bytes_of(LeafKind.POOL)
+
+    @property
+    def page_bytes(self) -> int:
+        """ONE page of the pools, all layers."""
+        return self.pool_bytes // (self.pages + (self.slots + 1) * self.ring_pages)
+
+    @property
+    def row_bytes(self) -> int:
+        """ONE position of context in the pools, all layers."""
+        return self.page_bytes // self.page_positions
+
+    @property
+    def ring_bytes(self) -> int:
+        """The rings, the sentinel's too: leaves of their own, or their pages of the pools."""
+        return self.bytes_of(LeafKind.RINGS) + (self.slots + 1) * self.ring_pages * self.page_bytes
+
+    @functools.cached_property
+    def slot_bytes(self) -> int:
+        """The blocks a slot, all slots."""
+        return self.bytes_of(LeafKind.SLOT)
 
 
 class GenerativeModel(ServingModel):
@@ -147,69 +241,23 @@ class GenerativeModel(ServingModel):
         hide mixed output lengths)."""
         return 1.0
 
-    # -- paged KV contract (ISSUE 18) -----------------------------------------
-    # Families that answer supports_kv_paging = True swap the dense
-    # per-slot state slab for a global pool of fixed-size KV pages plus a
-    # per-slot block table, and swap init_state for an incremental
-    # prefill_chunk program. The engine keeps the page ledger
-    # (tpuserve.genserve.pages.PageLedger) host-side; EVERY page index the
-    # compiled programs consume is traced, so one compiled step/prefill
-    # serves every page assignment — the same zero-recompile obligation
-    # slot indices already carry (runtime.register_program).
+    # -- paged KV contract (ISSUE 18; one description since ISSUE 70) -----------
+    # A family with paged programs swaps the dense per-slot state slab for a
+    # global pool of fixed-size KV pages plus a per-slot block table, and
+    # init_state for an incremental prefill_chunk program. WHAT A SLOT KEEPS it
+    # says ONCE, in the ``CachePlan`` that ``kv_plan`` returns; the engine builds
+    # its ledger (genserve.pages.PageLedger) from it and reads /stats ``kv`` off
+    # it. EVERY page index the compiled programs consume is traced, so one
+    # compiled step/prefill serves every page assignment — the same zero-recompile
+    # obligation slot indices already carry (runtime.register_program).
 
-    # Opt-in marker; families without paged programs (sd15) keep the
-    # dense slab even when [genserve] kv_paging is on.
-    supports_kv_paging = False
-
-    def kv_page_signature(self, slots: int, pages: int,
-                          page_tokens: int) -> Any:
-        """Pytree of jax.ShapeDtypeStruct for the PAGED state block: the
-        global page pool (leading dim ``pages``), the per-slot block table
-        of page indices, and the same per-slot scalar lanes the dense
-        signature carries. Page 0 is the write-sink sentinel — free/done
-        lanes scribble there, live lanes never attend through it."""
-        raise NotImplementedError
-
-    def kv_ring_tokens(self) -> int:
-        """Host-side: positions in a slot's WINDOW RING, the second cache
-        kind (ISSUE 28): a family with sliding-window layers keeps each
-        slot's last ``window`` positions of those layers in one ring a slot,
-        beside the full pages. The engine's ledger then hands out a ring
-        with the pages and a prefill piece's ``cache`` is ``{"pages": row,
-        "ring": index}`` instead of the bare row. 0: no rings (the default)."""
-        return 0
-
-    # The third cache kind (ISSUE 32): keys of ``kv_page_signature`` whose
-    # leaves are ONE BLOCK A SLOT (leading dimension ``slots``), the same size
-    # at any context: a recurrent layer's state (ISSUE 59: the leaves its mixer
-    # names, one or several; nothing is allocated beside them). The family
-    # addresses them by slot and starts a request's first piece from zeros; the
-    # engine only reports their bytes (/stats ``kv.state_bytes_per_slot``,
-    # ``gen_state_bytes``).
-    kv_slot_state: tuple = ()
-
-    # Keys of ``kv_page_signature`` whose leaves are the PAGE POOLS (a
-    # dimension of ``pages`` and one of ``page_tokens``): the engine reckons a
-    # position's bytes from them (/stats ``kv.row_bytes_per_token``,
-    # ``gen_kv_row_bytes``; ``kv.kv_bytes``).
-    kv_page_leaves: tuple = ("kp", "vp")
-
-    def kv_page_span(self, page_tokens: int) -> int:
-        """Host-side: positions of context ONE page of the pools stands for.
-        Its rows (the default), unless a row is a pool of several positions'
-        rows (ISSUE 55: ``eva``'s page of ``window / chunk`` summary rows stands
-        for a whole window): the engine divides a page's bytes by it for
-        ``kv.row_bytes_per_token``."""
-        return int(page_tokens)
-
-    def kv_ring_pages(self, page_tokens: int) -> int:
-        """Host-side: pages of the ``kv_page_leaves``' page dimension that ONE
-        ring takes, for a family that keeps its rings in the SAME leaves as its
-        pages, before them (ISSUE 55: a ring's row has a page row's shape, so a
-        walk reads both through one table); the leaves then hold ``(slots + 1)``
-        rings' pages and the ledger's. 0 (the default): rings are leaves of
-        their own."""
-        return 0
+    def kv_plan(self, slots: int, page_tokens: int,
+                pages: int = 0) -> "CachePlan | None":
+        """Host-side: the paged state block for ``slots`` slots and ``pages`` pages
+        of ``page_tokens`` rows, the sentinel among them (0: the family's worst
+        case, every slot at its longest context). None (the default): no paged
+        programs (sd15); the dense slab stays even when [genserve] kv_paging is on."""
+        return None
 
     def share_stats(self) -> "dict | None":
         """Host-side: what of each layer this chip holds, where the model is
@@ -222,15 +270,11 @@ class GenerativeModel(ServingModel):
         the device (experts hit, context read) moves them into its own
         counters here (``bind_metrics`` bound them). Nothing by default."""
 
-    def kv_pages_per_slot(self, page_tokens: int) -> int:
-        """Host-side: block-table width — pages covering one slot's
-        worst-case context (ceil(max_ctx / page_tokens))."""
-        raise NotImplementedError
-
-    def pages_needed(self, item: Any, page_tokens: int) -> int:
-        """Host-side: pages this request reserves at fold-in — its prompt
-        PLUS its full decode budget, so an admitted sequence can never hit
-        mid-decode page exhaustion (budgeted admission, Clockwork P3)."""
+    def context_tokens(self, item: Any) -> int:
+        """Host-side: positions of context this request may reach, its prompt
+        PLUS its full decode budget. The engine reserves the pages for them
+        (``CachePlan.pages_for``) at fold-in, so an admitted sequence can never
+        hit mid-decode page exhaustion (budgeted admission, Clockwork P3)."""
         raise NotImplementedError
 
     def prompt_tokens(self, item: Any) -> int:

@@ -26,11 +26,10 @@ leak one kind, and a ring is never double-handed any more than a page is.
 
 WHAT A PAGE IS (ISSUE 55). The ledger counts pages and rings and knows nothing
 of what a row holds: ``page_tokens`` is ROWS a page. For most families a row is
-a position of context. A family whose row sums several positions up says so
-itself (``GenerativeModel.kv_page_span``: ``eva``'s page of ``window / chunk``
-summary rows stands for a whole window, and its ``pages_needed`` counts windows)
-and may keep its rings in the page leaves (``kv_ring_pages``); nothing here
-changes for it.
+a position of context. A family whose row sums several positions up says so in
+its plan (``CachePlan.page_positions``: ``eva``'s page of ``window / chunk``
+summary rows stands for a whole window, and ``pages_for`` counts windows) and
+may keep its rings in the pools (``ring_pages``); nothing here changes for it.
 
 Event-loop-side only (the engine's step loop owns all mutation), so there
 is deliberately no lock to witness.

@@ -87,6 +87,9 @@ Families (BASELINE.json ``configs``):
                    each followed by a dense SwiGLU, under the embedding, depth
                    and logit scalars (ISSUE 68)
 - toy            — a linear classifier for tests and drills
+
+A family with paged programs says what a slot keeps ONCE: ``kv_plan`` ->
+``genserve.model.CachePlan`` (each cache leaf with its kind: ``paged_lm``).
 """
 
 from __future__ import annotations

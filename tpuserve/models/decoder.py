@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpuserve.config import ModelConfig
+from tpuserve.genserve.model import CachePlan, pool, rings
 from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN,  # noqa: F401
                                       EXPERT_COLUMNS, KEY_BLOCK, LOGPROBS, MAX_PIECES,
                                       NEG, SAMPLE_COLUMNS, PagedLM, _mm, head_share,
@@ -129,7 +130,6 @@ def _attn_scope(t) -> str:
 
 
 class DecoderServing(PagedLM):
-    cache_leaves = ("kf", "vf", "kw", "vw")  # pages of the full layers, rings of the window layers
     # The expert layers' four, the context, sparse layers whose dispatch took
     # the compact branch, and the steps by the sampler's branch.
     COLUMNS = (*EXPERT_COLUMNS, CONTEXT_COLUMN, COMPACT_COLUMN, *SAMPLE_COLUMNS)
@@ -248,16 +248,19 @@ class DecoderServing(PagedLM):
             yield ((L, "s_down"), (fs, d), (fs, d), (0, 0), s["ffn_out"], fs)
 
     # -- shapes -----------------------------------------------------------------
-    def kv_ring_tokens(self) -> int:
-        return self.window if self.win_layers else 0
+    def kv_plan(self, slots: int, page_tokens: int, pages: int = 0, **geometry) -> CachePlan:
+        """And one ring a slot, of ``window`` positions, where a layer has a window."""
+        return super().kv_plan(slots, page_tokens, pages, **geometry,
+                               ring_tokens=self.window if self.win_layers else 0)
 
     def _cache_signature(self, slots: int, pages: int, page_tokens: int) -> dict:
         S = jax.ShapeDtypeStruct
         page = S(self._page_shape(pages, page_tokens), self.dtype)
         ring = S((slots + 1, self.window, self.kv, self.hd), self.dtype)
-        return {
-            "kf": [page for _ in self.full_layers], "vf": [page for _ in self.full_layers],
-            "kw": [ring for _ in self.win_layers], "vw": [ring for _ in self.win_layers],
+        n_full, n_win = len(self.full_layers), len(self.win_layers)
+        return {   # pages of the full layers, rings of the window layers
+            "kf": pool([page] * n_full), "vf": pool([page] * n_full),
+            "kw": rings([ring] * n_win), "vw": rings([ring] * n_win),
             "ring": S((slots,), jnp.int32),   # a lane: the slot's ring
         }
 

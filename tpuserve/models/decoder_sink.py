@@ -85,6 +85,7 @@ import jax
 import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
+from tpuserve.genserve.model import LeafKind, pool, rings
 from tpuserve.models import decoder as dec
 from tpuserve.models.paged_lm import (Column, Heads, counted, read_config_file, rms_norm,
                                       scoped, series)
@@ -99,12 +100,9 @@ DEFAULT_SCALES = {**dec.DEFAULT_SCALES, "router": 1.0, "router_bias": 0.02,
                   "sink_low": 8.0, "sink_high": 12.0}
 KINDS = ("full_attention", "sliding_attention")
 WALKS = ("kernel", "xla")
-RING_LEAVES = ("kwn", "kwr", "vw")   # a window layer's ring: a key's two parts, the values
 
 
 class SinkDecoderServing(dec.DecoderServing):
-    cache_leaves = ("kn", "kr", "vf", "kwn", "kwr", "vw")
-    kv_page_leaves = ("kn", "kr", "vf")
     # ``decoder``'s columns, then the global layers' key rows: the live rows
     # they had to see, the rows of the blocks they read (whole key blocks; the
     # padded table in the gather), and their lanes (a launch's tiles) by walk.
@@ -215,8 +213,9 @@ class SinkDecoderServing(dec.DecoderServing):
                     for _ in self.win_layers]
 
         wr = self.turning.get(KINDS[1], 0)
-        return {"kn": page(g.dk - dr), "kr": page(dr), "vf": page(g.dv),
-                "kwn": ring(w.dk - wr), "kwr": ring(wr), "vw": ring(w.dv),
+        # a key's two parts (the one that passes, the one that turns), then the values
+        return {"kn": pool(page(g.dk - dr)), "kr": pool(page(dr)), "vf": pool(page(g.dv)),
+                "kwn": rings(ring(w.dk - wr)), "kwr": rings(ring(wr)), "vw": rings(ring(w.dv)),
                 "ring": S((slots,), jnp.int32)}
 
     # -- the global layers' walk ------------------------------------------------------
@@ -322,8 +321,8 @@ class SinkDecoderServing(dec.DecoderServing):
     # -- the layer, its counts ---------------------------------------------------------
     def _layer(self, i: int, lp: dict, x, c: dict, m: dict):
         full = self.layer_types[i] == KINDS[0]
-        leaves, j = (self.kv_page_leaves, self.full_layers.index(i)) if full \
-            else (RING_LEAVES, self.win_layers.index(i))
+        leaves, j = (self._leaves(LeafKind.POOL), self.full_layers.index(i)) if full \
+            else (self._leaves(LeafKind.RINGS), self.win_layers.index(i))
         with jax.named_scope(dec._attn_scope(m["t"])):
             q, k, v, _ = self._qkv(lp, i, rms_norm(x, lp["norm1"], self.eps), m["pos"])
             held = tuple(c[leaf][j] for leaf in leaves)

@@ -34,8 +34,8 @@ THE CACHE. A slot holds, a layer, a RING of W exact rows written at ``i % W``
 (places ``0 .. i % W`` are the current window's, the rest stale) and, a window
 of its context, one PAGE of ``W / c`` summary rows (row ``(i % W) // c`` of page
 ``i // W``, written when position ``i`` ends a chunk). So the ledger's
-``kv_page_tokens`` is ROWS a page, ``W / c`` of them, and a page stands for W
-positions (``kv_page_span``): ``pages_needed`` is ``ceil((prompt + new) / W)``.
+``kv_page_tokens`` is ROWS a page, ``W / c`` of them, and a page stands for W positions
+(``kv_plan``'s ``page_positions``): a request takes ``ceil((prompt + new) / W)`` pages.
 A summary row has a token's shape, so ring and pages lie in ONE pool a layer,
 ``(c x (slots + 1) + pages, W / c, KV x hd)`` for K and for V, a position ONE ROW
 with its KV heads side by side (ISSUE 63: a token is one row of each pool to
@@ -82,12 +82,11 @@ or one of the special ids above them.
 
 from __future__ import annotations
 
-from typing import Any
-
 import jax
 import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
+from tpuserve.genserve.model import CachePlan, pool
 from tpuserve.models import decoder as dec
 from tpuserve.models.paged_lm import (CONTEXT_COLUMN, NEG, SAMPLE_COLUMNS, Column, _mm, counted,
                                       read_config_file, scoped, series)
@@ -116,7 +115,6 @@ def _by_path(count: str, name: str, phase: str, paths: tuple) -> tuple:
 
 
 class EvaServing(dec.DecoderServing):
-    cache_leaves = kv_page_leaves = ("kf", "vf")   # ONE pool a layer: rings, then pages
     # The rows a token attends (exact and summary: what the cache's bytes go
     # by), each kind, chunks pooled, windows closed, a step's lanes and a
     # launch's tiles by path, and the steps by the sampler's branch.
@@ -192,17 +190,12 @@ class EvaServing(dec.DecoderServing):
             yield ((f"layer{i}", "mu"), h, h, (0, 0), s["mu"], 1)
 
     # -- shapes: a page is ``rows`` summary rows and stands for a window ---------
-    def kv_page_span(self, page_tokens: int) -> int:
-        return self.window
-
-    def kv_ring_pages(self, page_tokens: int) -> int:
-        return self.window // int(page_tokens)
-
-    def kv_pages_per_slot(self, page_tokens: int) -> int:
-        return -(-self.max_ctx // self.window)
-
-    def pages_needed(self, item: Any, page_tokens: int) -> int:
-        return -(-(int(item[1]) + int(item[3])) // self.window)
+    def kv_plan(self, slots: int, page_tokens: int, pages: int = 0) -> CachePlan:
+        if int(page_tokens) != self.rows:
+            raise ValueError(f"{self.name}: [genserve] kv_page_tokens = {page_tokens}: a page is "
+                             f"a window's summary rows, window_size / chunk_size = {self.rows}")
+        return super().kv_plan(slots, page_tokens, pages, page_positions=self.window,
+                               ring_pages=self.chunk)
 
     def kv_prefill_pieces(self, chunk: int, page_tokens: int) -> int:
         k = super().kv_prefill_pieces(chunk, page_tokens)
@@ -213,12 +206,9 @@ class EvaServing(dec.DecoderServing):
         return k
 
     def _cache_signature(self, slots: int, pages: int, page_tokens: int) -> dict:
-        if int(page_tokens) != self.rows:
-            raise ValueError(f"{self.name}: [genserve] kv_page_tokens = {page_tokens}: a page is "
-                             f"a window's summary rows, window_size / chunk_size = {self.rows}")
-        S = jax.ShapeDtypeStruct
-        pool = S((self.chunk * (slots + 1) + pages, self.rows, self.kv * self.hd), self.dtype)
-        return {"kf": [pool] * self.n_layers, "vf": [pool] * self.n_layers,
+        S = jax.ShapeDtypeStruct   # ONE pool a layer: the rings' pages, then the ledger's
+        both = S((self.chunk * (slots + 1) + pages, self.rows, self.kv * self.hd), self.dtype)
+        return {"kf": pool([both] * self.n_layers), "vf": pool([both] * self.n_layers),
                 "ring": S((slots,), jnp.int32)}
 
     # -- device math --------------------------------------------------------------

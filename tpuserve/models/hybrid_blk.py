@@ -48,8 +48,8 @@ THE CACHE: K (after the norm) and V of the attention layers in pages of the
 engine's ledger and ``kc``, the pooled keys, ``kv_page_tokens / kernel_stride`` rows
 of KV x head_dim a page (``kv_page_tokens`` must be whole blocks); ``ssm[l][slot]``,
 (H, D, D) float32, for every linear-attention layer, and NO convolution rows
-(``kv_slot_state = ("ssm",)``). Requests, weights by recipe and the served
-log-probabilities are ``decoder``'s (``paged_lm``).
+(``_lightning_signature``'s one ``slot_block``). Requests, weights by recipe and the
+served log-probabilities are ``decoder``'s (``paged_lm``).
 """
 
 from __future__ import annotations

@@ -39,9 +39,9 @@ served type; no bias anywhere (``conv_bias`` must be false):
 
 THE CACHE: K (after norm and rotary) and V of the attention layers in pages of
 the engine's ledger; ``conv[l][slot]``, (k - 1, hidden) in the served type, for
-every convolution layer, and NOTHING else a slot (``kv_slot_state = ("conv",)``).
-EVERY LAYER IS WHOLE HERE: a ``share`` is refused. Requests, weights by recipe
-and the served log-probabilities are ``decoder``'s (``paged_lm``).
+every convolution layer, and NOTHING else a slot (``_conv_signature``'s one
+``slot_block``). EVERY LAYER IS WHOLE HERE: a ``share`` is refused. Requests,
+weights by recipe and the served log-probabilities are ``decoder``'s (``paged_lm``).
 """
 
 from __future__ import annotations

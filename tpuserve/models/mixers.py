@@ -15,11 +15,12 @@ attention together: layer ``i``'s mixer, whichever it is, in whichever phase
 (``_mixer``), for ``paged_lm``'s loop.
 
 WHAT A RECURRENT MIXER OWES THE LOOP (``RecurrentMixer``; all three keep it). A
-layer keeps, A SLOT, THE LEAVES ITS MIXER NAMES (``kv_slot_state``), each a
-block ``leaf[l][slot]``, and nothing else is allocated: Mamba-2 and the delta
-rule name two, ``("ssm", "conv")``, a float32 state and the last ``conv_kernel -
-1`` rows of the convolution's input in the served type; the short convolution
-names ONE, ``("conv",)``, those rows alone (it has no other state). A request's FIRST
+layer keeps, A SLOT, THE LEAVES ITS MIXER NAMES (the ``slot_block``s of its own
+signature, ``_mamba_signature`` and its like), each a block ``leaf[l][slot]``,
+and nothing else is allocated: Mamba-2 and the delta rule name two, ``ssm`` and
+``conv``, a float32 state and the last ``conv_kernel - 1`` rows of the convolution's
+input in the served type; the short convolution names ONE, ``conv``, those rows
+alone (it has no other state). A request's FIRST
 piece starts from zeros whatever the slot held; a later piece from what the
 slot holds; within a launch the tiles of one piece pass the state on and a tile
 of another slot does not see it; padded rows leave it as it was; a piece of no
@@ -147,6 +148,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpuserve.genserve.model import CachePlan, LeafKind, pool, slot_block
 from tpuserve.models.decoder import apply_rope
 from tpuserve.models.paged_lm import NEG, Column, _mm, counted, rms_norm, scoped, series
 from tpuserve.ops import block_scores as bsc
@@ -161,10 +163,9 @@ def softplus_inverse(y: float) -> float:
 
 
 class RecurrentMixer:
-    """What a recurrent mixer keeps a slot (the leaves it names), what a
-    launch's scan does for any of them, and what a launch counts of them
-    (module docstring)."""
-    kv_slot_state = ("ssm", "conv")  # the leaves that are a block a slot: a mixer names its own
+    """What a recurrent mixer keeps a slot (the ``slot_block`` leaves of its
+    own signature), what a launch's scan does for any of them, and what a
+    launch counts of them (module docstring)."""
 
     def _tiles_conv(self, t: dict, rows, c0, w, bias=None):
         """The depthwise causal convolution of a launch's packed ``rows`` (C,
@@ -304,10 +305,10 @@ class Mamba2Mixer(RecurrentMixer):
 
     def _mamba_signature(self, slots: int) -> dict:
         S = jax.ShapeDtypeStruct
-        return {"ssm": [S((slots, self.mh, self.mp, self.mn), jnp.float32)
-                        for _ in self.m_layers],
-                "conv": [S((slots, self.conv_k - 1, self.conv_ch), self.dtype)
-                         for _ in self.m_layers]}
+        return {"ssm": slot_block([S((slots, self.mh, self.mp, self.mn), jnp.float32)
+                                   for _ in self.m_layers]),
+                "conv": slot_block([S((slots, self.conv_k - 1, self.conv_ch), self.dtype)
+                                    for _ in self.m_layers])}
 
     # -- device math --------------------------------------------------------------
     @scoped("proj")
@@ -612,10 +613,10 @@ class DeltaMixer(RecurrentMixer):
 
     def _delta_signature(self, slots: int) -> dict:
         S = jax.ShapeDtypeStruct
-        return {"ssm": [S((slots, self.kh, self.kd, self.kd), jnp.float32)
-                        for _ in self.m_layers],
-                "conv": [S((slots, self.conv_k - 1, self.conv_ch), self.dtype)
-                         for _ in self.m_layers]}
+        return {"ssm": slot_block([S((slots, self.kh, self.kd, self.kd), jnp.float32)
+                                   for _ in self.m_layers]),
+                "conv": slot_block([S((slots, self.conv_k - 1, self.conv_ch), self.dtype)
+                                    for _ in self.m_layers])}
 
     # -- device math --------------------------------------------------------------
     def _delta_heads(self, conv: jax.Array):
@@ -829,7 +830,6 @@ SCAN_COLUMNS = _by_path("scans", "ssm_scans_total", "prefill")
 class ConvMixer(RecurrentMixer):
     """The gated short convolution (module docstring): a slot's whole state is
     the convolution's last rows, so this mixer names ONE leaf."""
-    kv_slot_state = ("conv",)
 
     def _conv_setup(self, *, conv_kernel: int) -> None:
         """The layer's number: the taps (``conv_L_cache``); its channels are
@@ -849,8 +849,8 @@ class ConvMixer(RecurrentMixer):
             yield ((L, "w_out"), (d, d), (d, d), (0, 0), s["conv_out"], d)
 
     def _conv_signature(self, slots: int) -> dict:
-        return {"conv": [jax.ShapeDtypeStruct((slots, self.conv_k - 1, self.d), self.dtype)
-                         for _ in self.m_layers]}
+        return {"conv": slot_block([jax.ShapeDtypeStruct((slots, self.conv_k - 1, self.d),
+                                                         self.dtype) for _ in self.m_layers])}
 
     # -- device math --------------------------------------------------------------
     @scoped("proj")
@@ -998,20 +998,16 @@ class _Pattern:
     leaves as it leaves them) and ``_state_signature`` are the recurrent
     mixer's."""
 
-    @property
-    def cache_leaves(self) -> tuple:
-        return ("kf", "vf") + self.kv_slot_state
-
     def _cache_signature(self, slots: int, pages: int, page_tokens: int) -> dict:
         page = jax.ShapeDtypeStruct(self._page_shape(pages, page_tokens), self.dtype)
-        return {"kf": [page for _ in self.a_layers], "vf": [page for _ in self.a_layers],
-                **self._state_signature(slots)}
+        return {"kf": pool([page for _ in self.a_layers]),
+                "vf": pool([page for _ in self.a_layers]), **self._state_signature(slots)}
 
     def _mixer(self, i: int, lp, u, c: dict, m: dict):
         """Layer ``i``'s mixer on the normed stream ``u`` -> (T, d) float32;
         the layer's caches in ``c`` are replaced."""
         if i in self.m_layers:
-            j, kept = self.m_layers.index(i), self.kv_slot_state
+            j, kept = self.m_layers.index(i), self._leaves(LeafKind.SLOT)
             y, *new = self._recurrent(lp, u, *(c[leaf][j] for leaf in kept), m)
             for leaf, value in zip(kept, new):
                 c[leaf][j] = value
@@ -1071,8 +1067,7 @@ class LightningMixer(HeadNorms, RecurrentMixer):
     """Linear attention with a CONSTANT decay a head (module docstring): a
     slot's whole state is one (H, D, D) float32 block a layer, ``S[h, i, j] = sum_s
     lambda_h^(t - s) k_s[i] v_s[j]``, and NO convolution rows: this mixer names
-    ONE leaf, ``("ssm",)``."""
-    kv_slot_state = ("ssm",)
+    ONE leaf, ``ssm``."""
     SUB = 128   # rows of a sub-tile of a launch's chunked form
 
     def _lightning_setup(self, *, heads: int, head_dim: int, scale: float, rope: bool,
@@ -1101,8 +1096,8 @@ class LightningMixer(HeadNorms, RecurrentMixer):
             yield ((L, "wo"), (h, D, d), (h, D, d), (0, 0, 0), s["lin_o"], h * D)
 
     def _lightning_signature(self, slots: int) -> dict:
-        return {"ssm": [jax.ShapeDtypeStruct((slots, self.lh, self.ld, self.ld), jnp.float32)
-                        for _ in self.m_layers]}
+        return {"ssm": slot_block([jax.ShapeDtypeStruct((slots, self.lh, self.ld, self.ld),
+                                                        jnp.float32) for _ in self.m_layers])}
 
     # -- device math --------------------------------------------------------------
     def _lightning_qkv(self, lp: dict, u: jax.Array, pos: jax.Array):
@@ -1257,9 +1252,6 @@ class BlockSelectAttention(HeadNorms, PlainAttention):
 
     # -- the pooled keys ---------------------------------------------------------
     def _pooled_shape(self, pages: int, page_tokens: int) -> tuple:
-        if page_tokens % self.b_block:
-            raise ValueError(f"{self.name}: kv_page_tokens = {page_tokens} is no whole number of "
-                             f"blocks of {self.b_block}")
         return (pages * (page_tokens // self.b_stride), self.kv * self.hd)
 
     @scoped("blk_pool")
@@ -1518,16 +1510,17 @@ class BlockPatternMixers(_Pattern, LightningMixer, BlockSelectAttention):
     the cache leaves are the pages' three (K, V and the pooled keys) and the
     linear-attention layers' state."""
     _recurrent, _state_signature = LightningMixer._lightning, LightningMixer._lightning_signature
-    kv_page_leaves = ("kf", "vf", "kc")
 
-    @property
-    def cache_leaves(self) -> tuple:
-        return self.kv_page_leaves + self.kv_slot_state
+    def kv_plan(self, slots: int, page_tokens: int, pages: int = 0) -> CachePlan:
+        if page_tokens % self.b_block:
+            raise ValueError(f"{self.name}: kv_page_tokens = {page_tokens} is no whole number of "
+                             f"blocks of {self.b_block}")
+        return super().kv_plan(slots, page_tokens, pages)
 
     def _cache_signature(self, slots: int, pages: int, page_tokens: int) -> dict:
         pooled = jax.ShapeDtypeStruct(self._pooled_shape(pages, page_tokens), self.dtype)
         return {**super()._cache_signature(slots, pages, page_tokens),
-                "kc": [pooled for _ in self.a_layers]}
+                "kc": pool([pooled for _ in self.a_layers])}
 
     def _prefill_plan(self, state, launch, t: dict) -> dict:
         """And the pages' geometry, for the launch's counts, and where its

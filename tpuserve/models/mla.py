@@ -93,6 +93,7 @@ import jax
 import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
+from tpuserve.genserve.model import pool
 from tpuserve.models.decoder import apply_rope, rope_inv_freq
 from tpuserve.models.paged_lm import (COMPACT_COLUMN, CONTEXT_COLUMN,  # noqa: F401
                                       EXPERT_COLUMNS, KEY_BLOCK, LOGPROBS, NEG, Column,
@@ -157,7 +158,6 @@ class LatentServing(PagedLM):
     # says what a cell costs): at contexts of thousands a prefill tile's key
     # block, and anything from there up reads alike (PERF.md section 6, PR 44).
     step_keys = KEY_BLOCK
-    kv_page_leaves = cache_leaves = ("ckv", "kr")
     # The one value the family takes of a key that names a mechanism; any other is refused.
     TAKES = (("attention_bias", False), ("n_group", 1), ("topk_group", 1),
              ("scoring_func", "sigmoid"), ("topk_method", "noaux_tc"),
@@ -309,9 +309,10 @@ class LatentServing(PagedLM):
         S = jax.ShapeDtypeStruct
         g = 128 // self.dr if 128 % self.dr == 0 else 1   # positions a row of 128 lanes
         g = g if page_tokens % g == 0 else 1
-        return {"ckv": [S((pages, page_tokens, self.r), self.dtype) for _ in self._attentions()],
-                "kr": [S((pages, page_tokens // g, g * self.dr), self.dtype)
-                       for _ in self._attentions()]}
+        return {"ckv": pool([S((pages, page_tokens, self.r), self.dtype)
+                             for _ in self._attentions()]),
+                "kr": pool([S((pages, page_tokens // g, g * self.dr), self.dtype)
+                            for _ in self._attentions()])}
 
     # -- device math --------------------------------------------------------------
     def _form(self, tile_rows: int) -> str:

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from tpuserve.config import ModelConfig
+from tpuserve.genserve.model import pool
 from tpuserve.models import mla
 from tpuserve.models.decoder import apply_rope
 from tpuserve.models.paged_lm import Column, _mm, counted, read_config_file, scoped, series
@@ -97,7 +98,6 @@ class SelectedLatentServing(mla.LatentServing):
                         counts["sel_tiles"] if counts["sel_threshold"] == path else 0,
                         series("sel_threshold_tiles_total", f",path={path}"))
                  for path in THRESHOLDS))
-    kv_page_leaves = cache_leaves = ("ckv", "kr", "ik")
     TAKES = tuple(kv for kv in mla.LatentServing.TAKES
                   if kv[0] not in ("n_group", "topk_group", "share"))
 
@@ -167,7 +167,8 @@ class SelectedLatentServing(mla.LatentServing):
     def _cache_signature(self, slots: int, pages: int, page_tokens: int) -> dict:
         S = jax.ShapeDtypeStruct
         return {**super()._cache_signature(slots, pages, page_tokens),
-                "ik": [S((pages, page_tokens, self.i_dim), self.dtype) for _ in self._attentions()]}
+                "ik": pool([S((pages, page_tokens, self.i_dim), self.dtype)
+                            for _ in self._attentions()])}
 
     # -- device math --------------------------------------------------------------
     def _turn(self, x, pos):

@@ -16,8 +16,8 @@ THE TWO PROGRAMS ARE HERE, ONCE (ISSUE 45). ``prefill_chunk`` and ``step``:
 tiles or live lanes, the embedding, the launch's plan, the caches out of the
 state as lists, ``for i in layers: _layer``, one row into ``acc``, ``_arm`` or
 ``_emit``. A family supplies its parameters (``_gains``, ``_tensors``,
-``_vectors``, under ``layer{i}``); its per-layer caches (``cache_leaves``,
-``_cache_signature``: ``kv_page_signature`` is those and ``_lane_signature``);
+``_vectors``, under ``layer{i}``); its per-layer caches, each leaf ONCE with its
+kind (``_cache_signature``: ``kv_plan`` is those and ``_lane_signature``);
 ONE ``_layer(i, lp, x, caches, m)`` for both phases -> (the stream, the
 layer's expert counts or None), where ``m`` is the launch's plan and what
 differs between the phases lives in the mixers' paired methods, chosen by
@@ -78,7 +78,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpuserve.config import ModelConfig
-from tpuserve.genserve.model import GenerativeModel, PrefillPiece
+from tpuserve.genserve.model import CachePlan, GenerativeModel, Leaf, LeafKind, PrefillPiece
 from tpuserve.models import seeded
 from tpuserve.obs import GEN_PHASES
 
@@ -214,9 +214,6 @@ SAMPLE_COLUMNS = tuple(
 
 
 class PagedLM(GenerativeModel):
-    supports_kv_paging = True
-    kv_page_leaves = ("kf", "vf")  # K and V by head; a family with another row says so
-    cache_leaves = kv_page_leaves  # every per-layer cache the loop hands a layer: those and more
     COLUMNS: tuple = ()  # what ``acc`` holds, a ``Column`` each: the family's
     tied = False        # the head is the embedding transposed
     score_scale = None  # attention's scores times this; None: hd ** -0.5
@@ -316,9 +313,6 @@ class PagedLM(GenerativeModel):
                 jax.ShapeDtypeStruct((), jnp.float32),          # temperature
                 jax.ShapeDtypeStruct((), i32))                  # logprobs asked
 
-    def kv_pages_per_slot(self, page_tokens: int) -> int:
-        return -(-self.max_ctx // int(page_tokens))
-
     def _heads(self, i: int | None = None) -> Heads:
         """Layer ``i``'s attention by head (module docstring): here every
         layer alike, ``kv`` heads of ``hd`` for keys and values."""
@@ -347,23 +341,39 @@ class PagedLM(GenerativeModel):
         return blk.reshape(kvp, n * P, w // width, width).transpose(0, 2, 1, 3) \
             .reshape(kvp * (w // width), n * P, width)
 
-    def kv_page_signature(self, slots: int, pages: int, page_tokens: int) -> Any:
-        return {**self._cache_signature(slots, pages, page_tokens),
-                **self._lane_signature(slots, page_tokens)}
+    def kv_plan(self, slots: int, page_tokens: int, pages: int = 0, **geometry) -> CachePlan:
+        """``_cache_signature`` and ``_lane_signature`` as one plan; ``geometry`` is a family's
+        own (``CachePlan.build``): its rings' length, what a page stands for if not its rows."""
+        return CachePlan.build(
+            lambda pages, pps: {**self._cache_signature(slots, pages, page_tokens),
+                                **self._lane_signature(slots, pps)},
+            slots=slots, page_tokens=page_tokens, pages=pages, max_tokens=self.max_ctx,
+            **geometry)
 
     def _cache_signature(self, slots: int, pages: int, page_tokens: int) -> dict:
-        """{leaf: a shape a layer that keeps one} for ``cache_leaves`` (and
-        whatever further lanes the family keeps)."""
+        """THE statement of a leaf's name, kind and shapes: {leaf: ``pool`` / ``rings`` /
+        ``slot_block`` of a shape a layer that keeps one} (bare: a further lane of the family's)."""
         raise NotImplementedError
 
-    def _lane_signature(self, slots: int, page_tokens: int) -> dict:
+    @functools.cached_property
+    def _kinds(self) -> dict:
+        """{cache leaf: its kind} as ``_cache_signature`` states them, in its order (the same at
+        any geometry): what the programs, which have the state alone, find their leaves by."""
+        return {leaf: s.kind for leaf, s in self._cache_signature(1, 1, 1).items()
+                if isinstance(s, Leaf)}
+
+    def _leaves(self, *kinds: LeafKind) -> tuple:
+        """The cache leaves of ``kinds`` (of every kind where none is named)."""
+        return tuple(leaf for leaf, kind in self._kinds.items() if not kinds or kind in kinds)
+
+    def _lane_signature(self, slots: int, pps: int) -> dict:
         """The per-lane part of the paged state block: a slot's block-table
         row, its position and sampling parameters, the tokens and
         log-probabilities generated so far, and the device's sums."""
         S = jax.ShapeDtypeStruct
         i32, n = jnp.int32, self.max_new
         return {
-            "bt": S((slots, self.kv_pages_per_slot(page_tokens)), i32),
+            "bt": S((slots, pps), i32),
             "pos": S((slots,), i32), "n_new": S((slots,), i32),
             "last": S((slots,), i32), "armed": S((slots,), jnp.bool_),
             "done": S((slots,), jnp.bool_), "seed": S((slots,), i32),
@@ -376,8 +386,8 @@ class PagedLM(GenerativeModel):
             "acc": S((len(GEN_PHASES), len(self.COLUMNS)), jnp.uint32),
         }
 
-    def pages_needed(self, item: Any, page_tokens: int) -> int:
-        return -(-(int(item[1]) + int(item[3])) // int(page_tokens))
+    def context_tokens(self, item: Any) -> int:
+        return int(item[1]) + int(item[3])
 
     def prompt_tokens(self, item: Any) -> int:
         return int(item[1])
@@ -541,7 +551,7 @@ class PagedLM(GenerativeModel):
         """The stream through every layer and the launch's row into ``acc``
         (row 0 a prefill launch, row 1 a step) -> (the stream, the state with
         the caches and ``acc`` as the launch leaves them)."""
-        caches = {leaf: list(state[leaf]) for leaf in self.cache_leaves}
+        caches = {leaf: list(state[leaf]) for leaf in self._leaves()}
         stats = []
         for i in range(self.n_layers):
             x, st = self._layer(i, params[f"layer{i}"], x, caches, m)
@@ -565,9 +575,9 @@ class PagedLM(GenerativeModel):
         return jnp.take(params["embed"], ids, axis=0)
 
     def _page_tokens(self, state) -> int:
-        """Positions a page of the first page leaf holds (1 where no layer
-        keeps pages: nothing is written through the address then)."""
-        pools = state[self.kv_page_leaves[0]]
+        """Rows a page of the first pool holds (1 where no layer keeps pages:
+        nothing is written through the address then)."""
+        pools = state[self._leaves(LeafKind.POOL)[0]]
         return pools[0].shape[-2] if pools else 1
 
     def _prefill_plan(self, state, launch, t: dict) -> dict:

@@ -40,7 +40,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from tpuserve.config import ModelConfig
-from tpuserve.genserve.model import GenerativeModel
+from tpuserve.genserve.model import CachePlan, GenerativeModel, pool
 from tpuserve.parallel.mesh import MODEL_AXIS, SEQ_AXIS, can_shard
 from tpuserve.text import WordPieceTokenizer, synthetic_vocab
 
@@ -417,7 +417,7 @@ class TextGenServing(GenerativeModel):
     def step(self, params: Any, state: Any) -> tuple[Any, dict]:
         # The state pytree's own shape selects the path (a host-side
         # structural check at trace time): a paged engine allocates the
-        # kv_page_signature block, a dense one the state_signature block.
+        # ``kv_plan`` block, a dense one the state_signature block.
         if "kp" in state:  # tps-ok[TPS503]: pytree structure check at trace time
             return self._paged_decode_step(params, state)
         return self._decode_step(params, state)
@@ -442,34 +442,26 @@ class TextGenServing(GenerativeModel):
     # write-sink sentinel: free and frozen lanes scribble there instead
     # of into pages the ledger may have re-handed to another request.
 
-    supports_kv_paging = True
-
-    def kv_pages_per_slot(self, page_tokens: int) -> int:
-        return -(-self.max_ctx // int(page_tokens))
-
-    def kv_page_signature(self, slots: int, pages: int,
-                          page_tokens: int) -> Any:
+    def kv_plan(self, slots: int, page_tokens: int, pages: int = 0) -> CachePlan:
         ln, h, hd = self.layers, self.heads, self.head_dim
-        pps = self.kv_pages_per_slot(page_tokens)
-        return {
-            "kp": jax.ShapeDtypeStruct(
-                (pages, ln, page_tokens, h, hd), self.dtype),
-            "vp": jax.ShapeDtypeStruct(
-                (pages, ln, page_tokens, h, hd), self.dtype),
-            "bt": jax.ShapeDtypeStruct((slots, pps), jnp.int32),
-            "pos": jax.ShapeDtypeStruct((slots,), jnp.int32),
-            "tokens": jax.ShapeDtypeStruct((slots, self.max_new), jnp.int32),
-            "n_new": jax.ShapeDtypeStruct((slots,), jnp.int32),
-            "last": jax.ShapeDtypeStruct((slots,), jnp.int32),
-            "done": jax.ShapeDtypeStruct((slots,), jnp.bool_),
-            "seed": jax.ShapeDtypeStruct((slots,), jnp.int32),
-            "max_new": jax.ShapeDtypeStruct((slots,), jnp.int32),
-            "temp": jax.ShapeDtypeStruct((slots,), jnp.float32),
-        }
+        S, i32 = jax.ShapeDtypeStruct, jnp.int32
 
-    def pages_needed(self, item: Any, page_tokens: int) -> int:
-        _ids, n, _seed, max_new, _temp = item
-        return -(-(int(n) + int(max_new)) // int(page_tokens))
+        def signature(pages: int, pps: int) -> dict:
+            page = S((pages, ln, page_tokens, h, hd), self.dtype)
+            return {
+                "kp": pool(page), "vp": pool(page),
+                "bt": S((slots, pps), i32), "pos": S((slots,), i32),
+                "tokens": S((slots, self.max_new), i32), "n_new": S((slots,), i32),
+                "last": S((slots,), i32), "done": S((slots,), jnp.bool_),
+                "seed": S((slots,), i32), "max_new": S((slots,), i32),
+                "temp": S((slots,), jnp.float32),
+            }
+
+        return CachePlan.build(signature, slots=slots, page_tokens=page_tokens, pages=pages,
+                               max_tokens=self.max_ctx)
+
+    def context_tokens(self, item: Any) -> int:
+        return int(item[1]) + int(item[3])
 
     def prompt_tokens(self, item: Any) -> int:
         return int(item[1])
